@@ -1,0 +1,473 @@
+"""The quickest proof that the system still starts on the chip.
+
+Drives the two main paths once through the entry points a user calls, at
+the full width of the headline model (60 000 x 784 float32, k = 10, L2,
+all-pairs with self-exclusion, ``bench.py``'s configuration), and checks
+what comes out by the repo's own means. No number printed here is a
+benchmark result: the times say that a phase ran, not how fast the system
+is.
+
+Phases, each printing one line with its wall time:
+
+- ``device``   jax comes up on a TPU whose ``device_kind`` has a shipped
+               profile (an unknown device is an error, never a default).
+- ``allknn``   ``api.all_knn`` on the serial backend, first call (compile)
+               and one warm call timed apart; recall@10 against the
+               float64 oracle on a 256-row sample.
+- ``pallas``   both Pallas variants, compiled by Mosaic (the lowering must
+               hold a ``tpu_custom_call``), against the serial backend on
+               the same rows.
+- ``ring``     with more than one chip: both ring schedules over
+               min(4, chips) devices against the serial result, the
+               shardings spanning that many devices, and the fused
+               rotation (``ring_fusion="fused"``, in-kernel remote DMA).
+               With one chip it says ``not run`` and the summary carries
+               ``"ring_devices": 0``.
+- ``serve``    ``python -m mpi_knn_tpu serve`` over the same corpus: waits
+               for ``/healthz`` ready, posts requests of several sizes from
+               two tenants through ``frontend.loadgen``'s client, compares
+               the ids with ``all_knn(X, queries=Q)``, parses ``/metrics``,
+               requires that no request compiled anything, and checks the
+               exit code of a SIGTERM shutdown.
+
+One process per chip: this parent never imports jax. It runs the compute
+phases in one child, reaps it, and only then starts the server child.
+
+The last stdout line is one JSON object with exactly these keys, the device
+as jax reports it: ``{"ok": true, "device": {"platform": "tpu", "kind":
+"...", "count": 1}}``. The line before it, ``summary: platform=... {...}``,
+carries the per-phase ``ok`` and seconds, ``ring_devices`` and
+``compile_cache_dir``. The exit code is 0 only if every phase passed.
+Without a TPU the device phase fails and neither line is printed. ``--tiny``
+cuts the row counts (never the width) and ``--allow-cpu`` lets the script
+run on the CPU for debugging the script itself; every line then says
+``platform=cpu``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import subprocess
+import sys
+import tempfile
+import time
+import traceback
+
+import numpy as np
+
+from mpi_knn_tpu.frontend import loadgen
+from mpi_knn_tpu.obs.metrics import parse_prometheus
+
+K = 10
+RECALL_GATE = 0.999
+SERVE_ROWS = (1, 16, 300, 1024)  # three buckets of a 256-row base
+SERVE_TILES = (1024, 8192)
+
+
+def say(phase: str, platform: str, ok: bool, seconds: float, detail: str):
+    print(
+        f"{phase}: {'ok' if ok else 'FAILED'} platform={platform} "
+        f"{detail} ({seconds:.2f} s)",
+        flush=True,
+    )
+
+
+def compare_neighbors(ids, dists, ref_ids, ref_dists) -> tuple[float, str]:
+    """(share of slots naming the same neighbor, what is wrong or ``""``).
+    A slot may differ only where both distances agree to f32 resolution:
+    two programs that round the same sums in a different order rank
+    near-equal candidates differently, more often the denser the corpus.
+    More than 2 % of such slots means something else is wrong."""
+    ids, ref_ids = np.asarray(ids), np.asarray(ref_ids)
+    dists, ref_dists = np.asarray(dists), np.asarray(ref_dists)
+    if ids.shape != ref_ids.shape:
+        return 0.0, f" mismatch: shape {ids.shape} != {ref_ids.shape}"
+    diff = ids != ref_ids
+    equal = 1.0 - float(diff.mean())
+    if not np.isfinite(dists).all():
+        return equal, " mismatch: non-finite distances"
+    tied = np.isclose(dists[diff], ref_dists[diff], rtol=1e-5, atol=0.0)
+    if not tied.all():
+        return equal, (f" mismatch: {int((~tied).sum())} slots differ "
+                       "beyond a tie")
+    if equal < 0.98:
+        return equal, f" mismatch: {1 - equal:.2%} of slots are tie flips"
+    return equal, ""
+
+
+# ---------------------------------------------------------------------------
+# the compute child: device, allknn, pallas, ring — one process, one chip
+
+
+def compute_child(args) -> int:
+    platform = "cpu" if args.allow_cpu else "tpu"
+    out = {"device": None, "phases": {}, "ring_devices": 0,
+           "compile_cache_dir": None}
+
+    def record(phase, ok, t0, detail, **extra):
+        seconds = time.perf_counter() - t0
+        out["phases"][phase] = {"ok": bool(ok),
+                                "seconds": round(seconds, 3), **extra}
+        say(phase, platform, ok, seconds, detail)
+        return ok
+
+    t0 = time.perf_counter()
+    try:
+        compute_phases(args, platform, out, record)
+    except Exception as e:  # noqa: BLE001 — a phase that raises has failed
+        traceback.print_exc()
+        record("raised", False, t0, f"{type(e).__name__}: {str(e)[:300]}")
+    with open(os.path.join(args.work, "compute.json"), "w") as f:
+        json.dump(out, f)
+    return 0 if all(p["ok"] for p in out["phases"].values()) else 1
+
+
+def compute_phases(args, platform, out, record) -> None:
+    # -- device -----------------------------------------------------------
+    t0 = time.perf_counter()
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
+
+    force_platform(platform)
+    out["compile_cache_dir"] = use_compile_cache()
+    import jax
+    import jax.numpy as jnp
+
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        record("device", False, t0, f"no {platform} device: {e}")
+        return
+    import importlib.metadata
+
+    import jaxlib
+
+    from mpi_knn_tpu.analysis.cost import profile_for_platform
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "absent"
+    kind = devices[0].device_kind
+    profile = profile_for_platform(devices[0].platform, kind)
+    out["device"] = {"platform": devices[0].platform, "kind": kind,
+                     "count": len(devices)}
+    if not record(
+        "device",
+        jax.default_backend() == platform and profile is not None,
+        t0,
+        f"kind={kind!r} count={len(devices)} profile={profile} "
+        f"jax={jax.__version__} jaxlib={jaxlib.__version__} libtpu={libtpu}",
+    ):
+        return
+
+    from bench import oracle_topk
+    from mpi_knn_tpu import KNNConfig, all_knn
+    from mpi_knn_tpu.data.mnist import load_mnist
+    from mpi_knn_tpu.utils.report import recall_at_k
+
+    m = 2000 if args.tiny else 60000
+    X, _, source = load_mnist(m=m)
+    cfg = KNNConfig(  # bench.py's configuration
+        k=K, backend="serial", query_tile=4096, corpus_tile=8192,
+        topk_method="exact", merge_schedule="twolevel",
+        matmul_precision="high",
+    )
+
+    # -- allknn -----------------------------------------------------------
+    def cache_entries():
+        try:
+            return len(os.listdir(out["compile_cache_dir"]))
+        except FileNotFoundError:
+            return 0
+
+    t0 = time.perf_counter()
+    entries = cache_entries()
+    Xd = jax.device_put(jnp.asarray(X))
+    jax.block_until_ready(Xd)
+    t1 = time.perf_counter()
+    serial = all_knn(Xd, config=cfg)
+    jax.block_until_ready((serial.dists, serial.ids))
+    compile_s = time.perf_counter() - t1
+    t1 = time.perf_counter()
+    serial = all_knn(Xd, config=cfg)
+    jax.block_until_ready((serial.dists, serial.ids))
+    warm_s = time.perf_counter() - t1
+    s_ids, s_dists = np.asarray(serial.ids), np.asarray(serial.dists)
+    sample = np.linspace(0, m - 1, num=min(256, m), dtype=np.int64)
+    recall = recall_at_k(s_ids[sample], oracle_topk(X, sample, K))
+    ok = (
+        s_ids.shape == (m, K)
+        and np.isfinite(s_dists).all()
+        and recall >= RECALL_GATE
+    )
+    record(
+        "allknn", ok, t0,
+        f"data={source} shape={list(X.shape)} k={K} first_call_s="
+        f"{compile_s:.2f} warm_call_s={warm_s:.3f} recall@{K}={recall:.4f} "
+        f"compile_cache_entries={entries}->{cache_entries()}",
+        first_call_s=round(compile_s, 3), warm_call_s=round(warm_s, 4),
+        recall=round(float(recall), 5),
+    )
+
+    # what the server must answer, computed through the one-shot API with
+    # the configuration the serve command builds from its flags
+    rng = np.random.default_rng(1)
+    n_q = sum(SERVE_ROWS)
+    Q = (X[rng.integers(0, m, n_q)]
+         + rng.standard_normal((n_q, X.shape[1])) * 8.0).astype(np.float32)
+    expect = all_knn(
+        Xd, queries=Q,
+        config=KNNConfig(k=K, backend="serial", query_tile=SERVE_TILES[0],
+                         corpus_tile=SERVE_TILES[1]),
+    )
+    np.savez(os.path.join(args.work, "expect.npz"), queries=Q,
+             ids=np.asarray(expect.ids), dists=np.asarray(expect.dists))
+
+    def lowers_to_mosaic(fn, *arrays) -> bool:
+        """Whether the program ``fn`` traces to holds a compiled Pallas
+        kernel (off a TPU the kernels are interpreted and leave none)."""
+        return "tpu_custom_call" in jax.jit(fn).lower(*arrays).as_text()
+
+    # -- pallas -----------------------------------------------------------
+    # Mosaic has no three-pass dot, so the kernels run at HIGHEST and are
+    # held against the serial backend at HIGHEST on the same rows (against
+    # the allknn phase's HIGH result, rounding alone reorders ~1 % of slots)
+    nq = 256 if args.tiny else 4096
+    rows = np.arange(nq, dtype=np.int32)
+    ref = all_knn(Xd, queries=Xd[:nq], query_ids=rows,
+                  config=cfg.replace(matmul_precision="highest"))
+    r_ids, r_dists = np.asarray(ref.ids), np.asarray(ref.dists)
+    for variant in ("tiles", "sweep"):
+        t0 = time.perf_counter()
+        pcfg = cfg.replace(backend="pallas", pallas_variant=variant,
+                           matmul_precision="highest")
+
+        def run(corpus, queries):
+            return all_knn(corpus, queries=queries, query_ids=rows,
+                           config=pcfg)
+
+        compiled = lowers_to_mosaic(run, Xd, Xd[:nq])
+        got = run(Xd, Xd[:nq])
+        jax.block_until_ready((got.dists, got.ids))
+        equal, bad = compare_neighbors(got.ids, got.dists, r_ids, r_dists)
+        record(
+            f"pallas-{variant}",
+            not bad and compiled == (platform == "tpu"),
+            t0,
+            f"queries={nq} corpus={m} "
+            f"{'compiled by Mosaic' if compiled else 'INTERPRETED'} "
+            f"ids_equal_serial={equal:.5f}{bad}",
+            compiled=compiled,
+        )
+
+    # -- ring -------------------------------------------------------------
+    if len(devices) == 1:
+        print(f"ring: not run (1 device) platform={platform}", flush=True)
+        return
+    from mpi_knn_tpu.serve import build_index
+
+    nd = min(4, len(devices))
+    out["ring_devices"] = nd
+    # where the ring puts the corpus (one placement for both schedules)
+    corpus = build_index(
+        X, cfg.replace(backend="ring-overlap", num_devices=nd)
+    ).corpus_sharded
+    shard_devices = {s.device for s in corpus.addressable_shards}
+    shard_rows = {s.data.shape[0] for s in corpus.addressable_shards}
+    for backend in ("ring-overlap", "ring"):
+        t0 = time.perf_counter()
+        got = all_knn(X, config=cfg.replace(backend=backend, num_devices=nd))
+        jax.block_until_ready((got.dists, got.ids))
+        equal, bad = compare_neighbors(got.ids, got.dists, s_ids, s_dists)
+        result_devices = len(got.ids.sharding.device_set)
+        spread = (
+            len(shard_devices) == nd
+            and shard_rows == {corpus.shape[0] // nd}
+            and result_devices == nd
+        )
+        record(
+            backend, not bad and spread, t0,
+            f"devices={nd} corpus_shards={sorted(d.id for d in shard_devices)}"
+            f" rows_per_shard={sorted(shard_rows)} result_devices="
+            f"{result_devices} ids_equal_serial={equal:.5f}{bad}",
+        )
+
+    # the fused collective-matmul rotation: on a TPU the kernel itself
+    # issues the remote DMAs. Its tiles are VMEM blocks, so they are named
+    # here (at bench.py's 4096 x 8192 Mosaic does not finish compiling),
+    # and like the other kernels it runs at HIGHEST and is held against
+    # the serial backend at HIGHEST on the rows that reference covers
+    t0 = time.perf_counter()
+    fcfg = cfg.replace(backend="ring-overlap", num_devices=nd,
+                       ring_fusion="fused", matmul_precision="highest",
+                       query_tile=64, corpus_tile=128)
+
+    def run_fused(corpus):
+        return all_knn(corpus, config=fcfg)
+
+    compiled = lowers_to_mosaic(run_fused, Xd)
+    got = run_fused(X)
+    jax.block_until_ready((got.dists, got.ids))
+    equal, bad = compare_neighbors(got.ids[:nq], got.dists[:nq],
+                                   r_ids, r_dists)
+    record(
+        "ring-overlap-fused",
+        not bad and compiled == (platform == "tpu"),
+        t0,
+        f"devices={nd} tiles=64x128 "
+        f"{'in-kernel remote DMA' if compiled else 'INTERPRETED'} "
+        f"ids_equal_serial={equal:.5f}{bad}",
+        compiled=compiled,
+    )
+
+
+# ---------------------------------------------------------------------------
+# the serve phase: the parent is the client, the server is a child
+
+
+def metric_total(samples: dict, name: str) -> float:
+    return sum(v for k, v in samples.items()
+               if k.split("{", 1)[0] == name)
+
+
+def serve_phase(args, platform: str, work: str) -> tuple[bool, str]:
+    expect = np.load(os.path.join(work, "expect.npz"))
+    ready = os.path.join(work, "serve.url")
+    argv = [
+        sys.executable, "-m", "mpi_knn_tpu", "serve",
+        "--data", "mnist", "--limit", str(2000 if args.tiny else 60000),
+        "--platform", platform, "--backend", "serial", "--k", str(K),
+        "--query-tile", str(SERVE_TILES[0]),
+        "--corpus-tile", str(SERVE_TILES[1]),
+        "--bucket", "256", "--max-batch-rows", "1024",
+        "--port", "0", "--ready-file", ready, "-q",
+    ]
+    with open(os.path.join(work, "serve.log"), "wb") as log:
+        proc = subprocess.Popen(argv, stdout=log, stderr=subprocess.STDOUT,
+                                start_new_session=True)
+    try:
+        deadline = time.monotonic() + 600
+        health = {}
+        while not health.get("ready"):
+            if proc.poll() is not None:
+                return False, f"server exited {proc.returncode} at start-up"
+            if time.monotonic() > deadline:
+                return False, f"not ready after 600 s: {health}"
+            time.sleep(0.5)
+            if os.path.exists(ready):
+                url = open(ready).read().strip()
+                try:
+                    health = loadgen.probe_server(url)
+                except OSError:
+                    pass  # a warming server answers 503
+        profile = (health.get("device_profile") or {}).get("name", "")
+        if not profile.startswith(platform):
+            return False, f"server runs under profile {profile!r}"
+
+        def compiled_total():
+            return metric_total(
+                parse_prometheus(loadgen.fetch_metrics(url)),
+                "serve_executables_compiled_total",
+            )
+
+        before = compiled_total()
+        sent, equal = 0, []
+        for rep in range(2):  # second pass: every bucket already used
+            lo = 0
+            for i, n in enumerate(SERVE_ROWS):
+                status, doc = loadgen.post_query(
+                    url, f"smoke-{(i + rep) % 2}",
+                    expect["queries"][lo:lo + n], timeout_s=120.0,
+                )
+                if status != 200:
+                    return False, f"{n}-row request answered {status}"
+                same, bad = compare_neighbors(
+                    doc["ids"], doc["dists"],
+                    expect["ids"][lo:lo + n], expect["dists"][lo:lo + n],
+                )
+                if bad:
+                    return False, f"{n}-row request:{bad}"
+                equal.append(same * n)
+                lo += n
+                sent += 1
+        after = compiled_total()
+        if after != before:
+            return False, (f"requests compiled {after - before:g} "
+                           "executables after warm-up")
+        proc.send_signal(signal.SIGTERM)
+        rc = proc.wait(timeout=60)
+        if rc != 0:
+            return False, f"SIGTERM shutdown exited {rc}"
+        return True, (
+            f"requests={sent} rows={list(SERVE_ROWS)} tenants=2 errors=0 "
+            f"ids_equal_all_knn={sum(equal) / (2 * sum(SERVE_ROWS)):.5f} "
+            f"buckets_warmed={health['warming']['total']}"
+            f" executables_compiled={before:g}+0 profile={profile} "
+            "sigterm_exit=0"
+        )
+    finally:
+        if proc.poll() is None:
+            os.killpg(proc.pid, signal.SIGKILL)
+            proc.wait()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="2 000 corpus rows instead of 60 000 (same width)")
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="run on the CPU platform; for debugging this "
+                    "script only, every line says platform=cpu")
+    ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--work", default=None, help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+    if args.child == "compute":
+        return compute_child(args)
+
+    platform = "cpu" if args.allow_cpu else "tpu"
+    with tempfile.TemporaryDirectory(prefix="chip-smoke-") as work:
+        if "jax" in sys.modules:
+            raise RuntimeError("chip_smoke's parent imported jax: it would "
+                               "hold the chip its children need")
+        child = [sys.executable, os.path.abspath(__file__),
+                 "--child", "compute", "--work", work]
+        child += ["--tiny"] * args.tiny + ["--allow-cpu"] * args.allow_cpu
+        rc = subprocess.run(child).returncode
+        try:
+            with open(os.path.join(work, "compute.json")) as f:
+                summary = json.load(f)
+        except OSError:
+            print(f"compute child exited {rc} without a summary",
+                  file=sys.stderr)
+            return 1
+        if not summary["phases"].get("device", {}).get("ok"):
+            return 1  # no accelerator: no result line
+
+        if rc == 0:
+            t0 = time.perf_counter()
+            try:
+                ok, detail = serve_phase(args, platform, work)
+            except Exception as e:  # noqa: BLE001 — a phase fails, loudly
+                ok, detail = False, f"{type(e).__name__}: {e}"
+            if not ok:
+                log = os.path.join(work, "serve.log")
+                if os.path.exists(log):
+                    sys.stderr.write(open(log, errors="replace").read()[-4000:])
+            seconds = time.perf_counter() - t0
+            summary["phases"]["serve"] = {"ok": ok,
+                                          "seconds": round(seconds, 3)}
+            say("serve", platform, ok, seconds, detail)
+
+    ok = rc == 0 and all(p["ok"] for p in summary["phases"].values())
+    device = summary.pop("device")
+    print(f"summary: platform={platform} {json.dumps(summary)}")
+    # the driver's contract: these keys and no others, on the last line
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,6 +9,7 @@ so it works on a laptop, in CI, and while the chip is dead.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 
 from mpi_knn_tpu.config import METRICS
@@ -170,8 +171,11 @@ def main(argv=None) -> int:
     if args.cache_dir:
         # compile-level reuse across cells and runs: thresholds zeroed so
         # even the tiny lint programs cache (the defaults skip sub-second
-        # compiles, which is every CPU lint cell)
-        jax.config.update("jax_compilation_cache_dir", args.cache_dir)
+        # compiles, which is every CPU lint cell). A cache directory
+        # placed from outside (JAX_COMPILATION_CACHE_DIR, which jax reads
+        # itself) is never overridden.
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", args.cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 

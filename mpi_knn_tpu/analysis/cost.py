@@ -105,18 +105,15 @@ def profile_for_platform(platform: str, device_kind: str = "") -> str | None:
 def detected_profile() -> dict | None:
     """The declared profile facts for the RUNNING process (lazy jax
     import — this module stays importable jax-free): ``{"name", ...}``
-    with the profile's numbers inlined, or ``None`` off the map. This is
-    what ``/healthz`` and the serve ``--report`` stamp, so an operator
-    reads a deployment's measured throughput next to the declared
-    roofline inputs the planner predicted it under."""
-    try:
-        import jax
+    with the profile's numbers inlined, or ``None`` for a device off the
+    map (a failure to reach the device propagates — it is not "no
+    profile"). This is what ``/healthz`` and the serve ``--report`` stamp,
+    so an operator reads a deployment's measured throughput next to the
+    declared roofline inputs the planner predicted it under."""
+    import jax
 
-        platform = jax.default_backend()
-        kind = getattr(jax.devices()[0], "device_kind", "")
-    except Exception:
-        return None
-    name = profile_for_platform(platform, kind)
+    dev = jax.devices()[0]
+    name = profile_for_platform(dev.platform, dev.device_kind)
     if name is None:
         return None
     return {"name": name, **get_profile(name)}

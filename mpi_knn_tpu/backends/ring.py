@@ -93,7 +93,6 @@ from mpi_knn_tpu.parallel.partition import (
     pad_rows_any,
     pad_to_multiple,
 )
-from mpi_knn_tpu.utils.compat import axis_size, pcast_varying, shard_map
 
 
 def bidir_rounds(num_dev: int) -> tuple[int, int]:
@@ -139,6 +138,25 @@ def fused_blocking_undefined_error() -> ValueError:
         "is no compute-then-send sequencing to certify. Use "
         "backend='ring-overlap', or ring_fusion='xla' for the blocking "
         "A/B baseline."
+    )
+
+
+def ring_shard_map(body, cfg: KNNConfig, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` for a ring body, shared with the resumable driver.
+
+    The varying-axes check stays on for the XLA ring. It is off for
+    ``ring_fusion="fused"``: jax evaluates an interpreted ``pallas_call``
+    with unvarying grid indices against device-varying blocks, which the
+    checker rejects inside jax's own interpreter (its error text says to
+    pass ``check_vma=False``). The fused bodies are the XLA bodies with the
+    per-round compute swapped for the kernel, so their specs make the same
+    replication claims the checked form already proves."""
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=cfg.ring_fusion != "fused",
     )
 
 
@@ -192,7 +210,7 @@ def _ring_knn_local(
     backends.ring_resumable) exactly one round runs and the rotated block(s)
     are returned alongside the merged carry, so the host owns the round
     cursor."""
-    num_dev = axis_size(axis)
+    num_dev = jax.lax.axis_size(axis)
     bidir = cfg.ring_schedule == "bidir"
     quantized = cfg.ring_transfer_dtype == "int8"
     fused = cfg.ring_fusion == "fused"
@@ -269,8 +287,8 @@ def _ring_knn_local(
         # on a 2-D mesh, where per-device queries differ) so the scan carry
         # type is stable from step 0
         vary = tuple(vary_axes) or (axis,)
-        carry_d = pcast_varying(carry_d, vary)
-        carry_i = pcast_varying(carry_i, vary)
+        carry_d = jax.lax.pcast(carry_d, vary, to="varying")
+        carry_i = jax.lax.pcast(carry_i, vary, to="varying")
 
     def compute(blk, blk_ids, blk_scl, cd, ci):
         """Tiled (q_local × b) step: all query tiles against all block tiles."""
@@ -653,9 +671,10 @@ def _ring_knn_sharded(
     qspec = _query_spec(q_axis, axis)
     cspec = P(axis)
     if corpus_scale is None:
-        fn = shard_map(
+        fn = ring_shard_map(
             body,
-            mesh=mesh,
+            cfg,
+            mesh,
             in_specs=(qspec, qspec, cspec, cspec),
             out_specs=(qspec, qspec),
         )
@@ -664,9 +683,10 @@ def _ring_knn_sharded(
     def with_scale(q, qi, c, cids, cscl):
         return body(q, qi, c, cids, block_scale=cscl)
 
-    fn = shard_map(
+    fn = ring_shard_map(
         with_scale,
-        mesh=mesh,
+        cfg,
+        mesh,
         in_specs=(qspec, qspec, cspec, cspec, cspec),
         out_specs=(qspec, qspec),
     )
@@ -714,9 +734,10 @@ def ring_serve_sharded(
         def with_carry(q, qi, cd, ci, c, cids):
             return body(q, qi, c, cids, carry_in=(cd, ci))
 
-        fn = shard_map(
+        fn = ring_shard_map(
             with_carry,
-            mesh=mesh,
+            cfg,
+            mesh,
             in_specs=(qspec, qspec, qspec, qspec, cspec, cspec),
             out_specs=(qspec, qspec),
         )
@@ -725,9 +746,10 @@ def ring_serve_sharded(
     def with_carry_scale(q, qi, cd, ci, c, cids, cscl):
         return body(q, qi, c, cids, carry_in=(cd, ci), block_scale=cscl)
 
-    fn = shard_map(
+    fn = ring_shard_map(
         with_carry_scale,
-        mesh=mesh,
+        cfg,
+        mesh,
         in_specs=(qspec, qspec, qspec, qspec, cspec, cspec, cspec),
         out_specs=(qspec, qspec),
     )

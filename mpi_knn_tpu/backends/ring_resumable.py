@@ -48,6 +48,7 @@ from mpi_knn_tpu.backends.ring import (
     blocking_undefined_on_mesh_error,
     parse_ring_mesh,
     quantize_ring_block,
+    ring_shard_map,
     ring_tiles,
 )
 from mpi_knn_tpu.ops.topk import init_topk
@@ -58,7 +59,6 @@ from mpi_knn_tpu.parallel.partition import (
     pad_rows,
     pad_rows_any,
 )
-from mpi_knn_tpu.utils.compat import shard_map
 from mpi_knn_tpu.utils.logs import log
 from mpi_knn_tpu.utils.checkpoint import (
     KNNCheckpoint,
@@ -124,9 +124,10 @@ def _ring_one_round(
             )
             return one(q, qid, blk, bids)
 
-        fn = shard_map(
+        fn = ring_shard_map(
             body,
-            mesh=mesh,
+            cfg,
+            mesh,
             in_specs=(qspec, qspec, cspec, cspec, qspec, qspec),
             out_specs=(cspec, cspec, qspec, qspec),
         )
@@ -147,9 +148,10 @@ def _ring_one_round(
         )
         return one(q, qid, blk, bids, block_scale=bscl)
 
-    fn = shard_map(
+    fn = ring_shard_map(
         body_q,
-        mesh=mesh,
+        cfg,
+        mesh,
         in_specs=(qspec, qspec, cspec, cspec, cspec, qspec, qspec),
         out_specs=(cspec, cspec, cspec, qspec, qspec),
     )
@@ -215,9 +217,10 @@ def _ring_one_round_bidir(
             )
             return one(q, qid, fb, fids, block_bwd=bb, block_bwd_ids=bids)
 
-        fn = shard_map(
+        fn = ring_shard_map(
             body,
-            mesh=mesh,
+            cfg,
+            mesh,
             in_specs=(qspec, qspec, cspec, cspec, cspec, cspec, qspec,
                       qspec),
             out_specs=(cspec, cspec, cspec, cspec, qspec, qspec),
@@ -246,9 +249,10 @@ def _ring_one_round_bidir(
             block_bwd_ids=bids, block_bwd_scale=bscl,
         )
 
-    fn = shard_map(
+    fn = ring_shard_map(
         body_q,
-        mesh=mesh,
+        cfg,
+        mesh,
         in_specs=(qspec, qspec, cspec, cspec, cspec, cspec, cspec, cspec,
                   qspec, qspec),
         out_specs=(cspec, cspec, cspec, cspec, cspec, cspec, qspec, qspec),

@@ -441,6 +441,15 @@ class KNNConfig:
                     "(DEFAULT compress, HIGHEST rerank); matmul_precision "
                     f"must be None, got {self.matmul_precision!r}"
                 )
+        if self.matmul_precision == "high" and (
+            self.backend == "pallas" or self.ring_fusion == "fused"
+        ):
+            raise ValueError(
+                "matmul_precision='high' does not exist inside the Pallas "
+                "kernels (backend='pallas', ring_fusion='fused'): Mosaic "
+                "lowers DEFAULT and HIGHEST dots only and refuses the "
+                "three-pass form — use 'highest' (or None) or 'default'"
+            )
         if self.query_bucket < 1:
             raise ValueError(
                 f"query_bucket must be >= 1, got {self.query_bucket}"

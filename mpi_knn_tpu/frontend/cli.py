@@ -184,13 +184,14 @@ def serve_main(argv=None) -> int:
 
         aotcache.set_cache_dir(args.cache_dir)
 
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         force_platform(
             args.platform,
             n_devices=(args.devices if args.platform == "cpu" else None),
         )
+    use_compile_cache()
 
     from mpi_knn_tpu.cli import load_corpus
     from mpi_knn_tpu.frontend.scheduler import SLOPolicy
@@ -598,9 +599,13 @@ def router_main(argv=None) -> int:
             import tempfile
 
             workdir = tempfile.mkdtemp(prefix="tknn-router-")
-        supervisor = ReplicaSupervisor(
-            args.spawn, serve_args, workdir=workdir
-        ).start()
+        try:
+            supervisor = ReplicaSupervisor(
+                args.spawn, serve_args, workdir=workdir
+            ).start()
+        except ValueError as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 2
     else:
         urls = [u.strip() for u in args.replicas.split(",") if u.strip()]
         if not urls:

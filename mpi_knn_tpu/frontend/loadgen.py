@@ -237,10 +237,11 @@ def fetch_metrics(url: str, timeout_s: float = 10.0) -> str:
         return resp.read().decode()
 
 
-def _post_query(url: str, tenant: str, q: np.ndarray,
-                timeout_s: float) -> tuple:
-    """(status, rows_served): one POST /query round trip (raw f32 body —
-    no JSON float inflation on the wire)."""
+def post_query(url: str, tenant: str, q: np.ndarray,
+               timeout_s: float) -> tuple:
+    """(status, response document): one POST /query round trip (raw f32
+    body — no JSON float inflation on the wire). The document is the
+    server's ``{"rows", "dists", "ids"}`` on a 200 and ``{}`` otherwise."""
     req = urllib.request.Request(
         url.rstrip("/") + "/query",
         data=np.ascontiguousarray(q, dtype="<f4").tobytes(),
@@ -252,18 +253,24 @@ def _post_query(url: str, tenant: str, q: np.ndarray,
     )
     try:
         with urllib.request.urlopen(req, timeout=timeout_s) as resp:
-            doc = json.loads(resp.read())
-            return resp.status, int(doc.get("rows", 0))
+            return resp.status, json.loads(resp.read())
     except urllib.error.HTTPError as e:
         e.read()
-        return e.code, 0
+        return e.code, {}
     except (urllib.error.URLError, OSError, TimeoutError, ValueError):
         # connection refused/reset, socket timeout, truncated body: the
         # exact failures an OVERLOADED server produces — they must land
         # in the report's error count, not kill the worker thread and
         # vanish from achieved/p99 (a load tool that loses its failures
         # under load flatters exactly what it exists to expose)
-        return 0, 0
+        return 0, {}
+
+
+def _post_query(url: str, tenant: str, q: np.ndarray,
+                timeout_s: float) -> tuple:
+    """(status, rows_served) of one :func:`post_query` round trip."""
+    status, doc = post_query(url, tenant, q, timeout_s)
+    return status, int(doc.get("rows", 0))
 
 
 def _conn_open(target: str, timeout_s: float):

@@ -1060,6 +1060,23 @@ class RouterHTTPServer:
 # replica supervisor
 
 
+def _children_platform(serve_args) -> str:
+    """The JAX platform ``mpi-knn serve`` children will come up on, read
+    without importing jax: their ``--platform`` flag when it forces one,
+    else the inherited ``JAX_PLATFORMS``, else ``"auto"`` (whatever jax
+    finds — a TPU when the host has one)."""
+    args = list(serve_args)
+    forced = "auto"
+    for i, a in enumerate(args):
+        if a == "--platform" and i + 1 < len(args):
+            forced = args[i + 1]
+        elif a.startswith("--platform="):
+            forced = a.split("=", 1)[1]
+    if forced != "auto":
+        return forced
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] or "auto"
+
+
 class ReplicaSupervisor:
     """N supervised ``mpi-knn serve`` children — one thread per slot
     looping :func:`~mpi_knn_tpu.resilience.worker.run_supervised`, so a
@@ -1068,12 +1085,29 @@ class ReplicaSupervisor:
     it never touches membership). Children bind ``--port 0`` and publish
     their URL to a per-slot ready file (atomic rename), which doubles as
     discovery: the prober re-reads it every cycle, so a restarted child
-    on a new port is found without any registration channel."""
+    on a new port is found without any registration channel.
+
+    Every child gets this process's environment and the same argv, so
+    nothing pins a child to a device. An accelerator belongs to one
+    process at a time: more than one child is accepted only when the
+    children are explicitly on the CPU platform (``--platform cpu`` in
+    the serve args, else ``JAX_PLATFORMS=cpu`` inherited)."""
 
     def __init__(self, count: int, serve_args, *, workdir: str,
                  restart_backoff_s: float = 0.5):
         if count < 1:
             raise ValueError("need at least one replica")
+        platform = _children_platform(serve_args)
+        if count > 1 and platform != "cpu":
+            raise ValueError(
+                f"--spawn {count} on platform {platform!r}: the children "
+                "share this process's environment, so all of them would "
+                "open the same accelerator, and a chip belongs to one "
+                "process at a time. This router cannot pin a child to a "
+                "chip. Use --spawn 1, run the children on the CPU "
+                "(--platform cpu after `--`), or start one `mpi-knn "
+                "serve` per chip yourself and front them with --replicas."
+            )
         self.count = count
         self.serve_args = list(serve_args)
         self.workdir = workdir

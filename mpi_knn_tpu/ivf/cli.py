@@ -101,10 +101,11 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
 
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         force_platform(args.platform)
+    use_compile_cache()
 
     from mpi_knn_tpu.cli import load_corpus
     from mpi_knn_tpu.ivf import build_ivf_index, save_ivf_index

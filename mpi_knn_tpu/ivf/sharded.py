@@ -84,7 +84,6 @@ from mpi_knn_tpu.ops.quant import (
 from mpi_knn_tpu.ops.topk import init_topk_tiles, merge_topk
 from mpi_knn_tpu.parallel.mesh import make_ring_mesh
 from mpi_knn_tpu.parallel.partition import pad_to_multiple
-from mpi_knn_tpu.utils.compat import shard_map
 
 # per-shard exchange stats vector: [routed (non-dropped probe routes this
 # shard's resident queries issued), dropped (probe-cap overflow), served
@@ -344,7 +343,7 @@ def ivf_sharded_serve_chunk(
                 qt, qidt, cd, ci, st, cent, cent_sq, bks, bids, bsqs, None
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(qspec, qspec, qspec, qspec, qspec, P(), P(),
@@ -356,7 +355,7 @@ def ivf_sharded_serve_chunk(
             centroids, centroid_sqs, buckets, bucket_ids, bucket_sqs,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard_search,
         mesh=mesh,
         in_specs=(qspec, qspec, qspec, qspec, qspec, P(), P(),

@@ -463,14 +463,12 @@ def _dma_round_kernel(
             pltpu.make_async_remote_copy(
                 blk_hbm_ref, land_blk_ref,
                 send_sem.at[_SEM_BLOCK], recv_sem.at[_SEM_BLOCK],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: right},
             ),
             pltpu.make_async_remote_copy(
                 bid_hbm_ref, land_bid_ref,
                 send_sem.at[_SEM_IDS], recv_sem.at[_SEM_IDS],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: right},
             ),
         ]
         if quantized:
@@ -478,8 +476,7 @@ def _dma_round_kernel(
                 pltpu.make_async_remote_copy(
                     scl_hbm_ref, land_scl_ref,
                     send_sem.at[_SEM_SCALE], recv_sem.at[_SEM_SCALE],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
+                    device_id={axis_name: right},
                 )
             )
         return copies
@@ -490,8 +487,8 @@ def _dma_round_kernel(
         # receiver has entered the kernel (its landing buffer is a kernel
         # output — live only inside the launch)
         barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(barrier, inc=1, device_id=left)
-        pltpu.semaphore_signal(barrier, inc=1, device_id=right)
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: left})
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: right})
         pltpu.semaphore_wait(barrier, 2)
         for copy in remote_copies():
             copy.start()
@@ -662,8 +659,8 @@ def _grid_rotation_kernel(
             copy.start()
             copy.wait()
         barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(barrier, inc=1, device_id=left)
-        pltpu.semaphore_signal(barrier, inc=1, device_id=right)
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: left})
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis_name: right})
         pltpu.semaphore_wait(barrier, 2)
 
     def remote_copies():
@@ -671,14 +668,12 @@ def _grid_rotation_kernel(
             pltpu.make_async_remote_copy(
                 slot_blk.at[slot], slot_blk.at[nxt],
                 send_sem.at[_SEM_BLOCK], recv_sem.at[_SEM_BLOCK],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: right},
             ),
             pltpu.make_async_remote_copy(
                 slot_bid.at[slot], slot_bid.at[nxt],
                 send_sem.at[_SEM_IDS], recv_sem.at[_SEM_IDS],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id={axis_name: right},
             ),
         ]
 
@@ -744,8 +739,7 @@ def _grid_rotation_kernel(
         # round n_r-3's release); a later release would leave the
         # semaphore nonzero at kernel exit.
         pltpu.semaphore_signal(
-            free_sem, inc=1, device_id=left,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            free_sem, inc=1, device_id={axis_name: left}
         )
 
     @pl.when(jnp.logical_and(r == n_r - 1, ci == n_c - 1))

@@ -175,16 +175,16 @@ def _plan_probe(compiled) -> dict:
     import jax
 
     from mpi_knn_tpu import plan as planner
-    from mpi_knn_tpu.analysis.cost import (
-        DEFAULT_PROFILE,
-        profile_for_platform,
-    )
+    from mpi_knn_tpu.analysis.cost import profile_for_platform
     from mpi_knn_tpu.analysis.memory import pjrt_memory_stats
 
-    name = profile_for_platform(
-        jax.default_backend(),
-        getattr(jax.devices()[0], "device_kind", ""),
-    ) or DEFAULT_PROFILE  # off-map hardware still exercises the planner
+    dev = jax.devices()[0]
+    name = profile_for_platform(dev.platform, dev.device_kind)
+    if name is None:
+        # never priced under another device's peaks
+        return {"ok": False, "profile": None,
+                "reason": f"no shipped device profile for "
+                          f"{dev.platform} {dev.device_kind!r}"}
     wl = planner.Workload(m=4096, d=64, k=10, recall_target=0.9,
                           qps=0.0, bucket=256)
     fleet = planner.Fleet(devices=1, profile=name)

@@ -67,7 +67,7 @@ from mpi_knn_tpu.utils.atomicio import atomic_write_bytes
 
 # bump when the entry layout (or anything about how executables are
 # rebuilt from entries) changes: old entries must MISS, not half-load
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 
 ENTRY_SUFFIX = ".aotx"
 
@@ -206,10 +206,13 @@ class AOTCache:
 
     Every entry is a single file ``<key>.aotx``: a pickle of
     ``{"format", "jax", "key", "sha256", "payload", "in_tree",
-    "out_tree", "meta"}`` where ``payload`` is the serialized PJRT
-    executable, the trees are the pickled arg/result pytree defs, and
-    ``sha256`` is the payload digest (truncation/bit-rot detection on
-    top of pickle's own framing). All read-side failures degrade to a
+    "out_tree", "device_ids", "meta"}`` where ``payload`` is the
+    serialized PJRT executable, the trees are the pickled arg/result
+    pytree defs, ``device_ids`` names the devices the executable was
+    compiled for (it is revived onto exactly those — a one-device
+    program loads in a multi-device process), and ``sha256`` is the
+    payload digest (truncation/bit-rot detection on top of pickle's own
+    framing). All read-side failures degrade to a
     miss — counted and warned, never raised into serving."""
 
     def __init__(self, path: str | os.PathLike):
@@ -260,8 +263,10 @@ class AOTCache:
                 )
             in_tree = pickle.loads(doc["in_tree"])
             out_tree = pickle.loads(doc["out_tree"])
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in doc["device_ids"]],
             )
             if expect_args is not None:
                 _check_args(compiled, expect_args)
@@ -297,6 +302,10 @@ class AOTCache:
                 "payload": payload,
                 "in_tree": pickle.dumps(in_tree),
                 "out_tree": pickle.dumps(out_tree),
+                "device_ids": [
+                    d.id
+                    for d in compiled.runtime_executable().local_devices()
+                ],
                 "meta": meta or {},
             }
             atomic_write_bytes(self.entry_path(key), pickle.dumps(doc))

@@ -330,9 +330,9 @@ def main(argv=None) -> int:
 
         aotcache.set_cache_dir(args.cache_dir)
 
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         # --platform cpu --devices N: size the virtual host mesh to the
         # request (a ring/sharded serve on a 1-CPU host would otherwise
         # fail with "only 1 visible" despite the explicit ask)
@@ -340,6 +340,7 @@ def main(argv=None) -> int:
             args.platform,
             n_devices=(args.devices if args.platform == "cpu" else None),
         )
+    use_compile_cache()
 
     from mpi_knn_tpu.cli import load_corpus
     from mpi_knn_tpu.serve import ServeSession, build_index

@@ -180,10 +180,11 @@ def main(argv=None) -> int:
         return rc
 
     # offline mode: jax only loads here (the online path is jax-free)
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         force_platform(args.platform)
+    use_compile_cache()
     if args.cache_dir:
         from mpi_knn_tpu.serve import aotcache
 

@@ -15,39 +15,14 @@ import time
 from typing import Dict, Optional
 
 import jax
-import numpy as np
 
 
 def device_sync(*trees):
-    """Genuinely wait for the device work producing these arrays.
-
-    ``block_until_ready`` alone is not trustworthy on every transport: on
-    tunneled/remote device platforms it can return once dispatch (not
-    execution) completes, which makes naive timing report near-zero. Fetching
-    a single element forces the runtime to materialize the result — a few
-    bytes of device-to-host traffic buys an honest clock reading. This is the
-    rebuild's answer to the reference's ``gettimeofday`` pair
+    """Wait for the device work producing these arrays (every leaf, every
+    shard) — the rebuild's answer to the reference's ``gettimeofday`` pair
     (``/root/reference/knn-serial.c:70,94-98``), which had the same
-    measure-the-real-work intent in a synchronous world.
-    """
-    leaves = [
-        leaf
-        for tree in trees
-        for leaf in jax.tree_util.tree_leaves(tree)
-        if isinstance(leaf, jax.Array)
-    ]
-    for leaf in leaves:
-        leaf.block_until_ready()
-    for leaf in leaves:
-        # one element from EVERY addressable shard — fetching only element
-        # (0,...,0) would materialize just the shard that holds it, leaving
-        # the other devices' work possibly in flight
-        shards = getattr(leaf, "addressable_shards", None) or []
-        datas = [s.data for s in shards] or [leaf]
-        for data in datas:
-            if any(dim == 0 for dim in data.shape):
-                continue
-            np.asarray(jax.device_get(data[(0,) * data.ndim]))
+    measure-the-real-work intent in a synchronous world."""
+    jax.block_until_ready(trees)
 
 
 class PhaseTimer:

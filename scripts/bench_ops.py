@@ -19,10 +19,11 @@ Two families, one JSON artifact:
   ``block``/``bf16``) over a fixed pre-computed distance tile.
 - ``ring_allknn``: the ring-schedule 2×2 (uni vs bidir × blocking/overlap)
   end to end on a virtual CPU mesh (``--ring-devices``, default 8; 0
-  disables the rows AND the CPU-platform forcing they require — pass 0 to
-  bench a real accelerator's per-op rows). On CPU the cells measure
-  schedule mechanics (collectives are memcpys), pinning the per-PR
-  trajectory; on a chip the same rows measure real ICI.
+  disables the rows). The cells measure schedule mechanics (collectives
+  are memcpys), pinning the per-PR trajectory. This script is CPU-only
+  and refuses any other platform: its parent process runs jax and then
+  spawns the cold-start children, which on an accelerator would find the
+  device already held.
 - ``query_knn``: steady-state serving throughput over a resident
   ``CorpusIndex`` (``mpi_knn_tpu.serve``) at three row buckets — per-batch
   p50/p99 latency and queries/sec, measured strictly AFTER warm-up so the
@@ -194,14 +195,22 @@ def main(argv=None) -> int:
 
     if args.ring_devices:
         # the ring rows need a multi-device mesh, which on a CPU host means
-        # forcing the virtual-device platform BEFORE jax initializes; this
-        # pins every row to CPU — deliberate for the trajectory artifact,
-        # opt out with --ring-devices 0 on a real accelerator
+        # forcing the virtual-device platform BEFORE jax initializes
         from mpi_knn_tpu.utils.platform import force_platform
 
         force_platform("cpu", n_devices=args.ring_devices)
 
     import jax
+
+    if jax.default_backend() != "cpu":
+        print(
+            f"bench_ops: refusing platform {jax.default_backend()!r} — "
+            "this process holds the device and later spawns "
+            "--cold-start-child processes; one process per chip. Run with "
+            "JAX_PLATFORMS=cpu (every row here is a CPU trajectory row).",
+            file=sys.stderr,
+        )
+        return 2
     import jax.numpy as jnp
     import numpy as np
 

@@ -57,8 +57,6 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-# v5e MXU peak (dense bf16 FLOP/s per chip); other TPUs can be passed in
-PEAK_BF16 = {"v5e": 197e12}
 PASS_FACTOR = {"highest": 6.0, "high": 3.0, "default": 1.0}
 
 # the committed ring_mfu.v1 row contract (measurements/ring_mfu.schema.json
@@ -72,6 +70,24 @@ RING_MFU_ROW_KEYS = frozenset({
     "ledger_certified", "m", "d", "k", "num_devices", "ring_schedule",
     "peak_bf16_tflops", "ts",
 })
+
+
+def peak_flops(args) -> float:
+    """bf16 peak FLOP/s of the device this process runs on: the override,
+    else the shipped profile for the detected ``device_kind``
+    (``analysis/device_profiles.json``). A device with no shipped profile
+    is an error — a utilization is never quoted against a guessed peak."""
+    if args.peak_tflops:
+        return args.peak_tflops * 1e12
+    from mpi_knn_tpu.analysis.cost import detected_profile
+
+    profile = detected_profile()
+    if profile is None:
+        raise SystemExit(
+            "profile_mfu: no shipped device profile for this device; pass "
+            "--peak-tflops or add it to analysis/device_profiles.json"
+        )
+    return float(profile["peak_flops"])
 
 
 def _ring_mfu_row(**kw) -> dict:
@@ -178,7 +194,7 @@ def ring_fusion_compare(args) -> int:
     X = (rng.random((args.m, args.d)) * 255.0).astype(np.float32)
     Xd = jax.device_put(jnp.asarray(X))
     device_sync(Xd)
-    peak = (args.peak_tflops or 197.0) * 1e12
+    peak = peak_flops(args)
     num_dev = jax.device_count()
 
     # R8's dense closed form at THIS run's shapes, summed over the mesh:
@@ -277,7 +293,8 @@ def main(argv=None) -> int:
                          "wedging the device must not take its data down)")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--peak-tflops", type=float, default=None,
-                    help="override bf16 peak (default: v5e 197)")
+                    help="override the bf16 peak (default: the shipped "
+                         "profile of the detected device)")
     ap.add_argument("--profile-dir", default=None)
     ap.add_argument("--json", default=None)
     ap.add_argument("--append-jsonl", default=None,
@@ -310,10 +327,11 @@ def main(argv=None) -> int:
         # must not leave the prior epoch's rows posing as this epoch's
         open(args.append_jsonl, "w").close()
 
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         force_platform(args.platform)
+    use_compile_cache()
 
     if args.ring_fusion_compare:
         return ring_fusion_compare(args)
@@ -335,7 +353,7 @@ def main(argv=None) -> int:
     Xd = jax.device_put(jnp.asarray(X))
     device_sync(Xd)
 
-    peak = (args.peak_tflops or 197.0) * 1e12
+    peak = peak_flops(args)
     # useful work: the −2·X·Yᵀ term of every (query, corpus) pair
     useful_flop = 2.0 * args.m * args.m * args.d
 

@@ -3,7 +3,7 @@
 approx top-k (VERDICT r2 next-step #3).
 
 One JSON line per measurement on stdout; a watchdog thread emits an honest
-failure line and hard-exits if the device transport wedges (same rationale
+failure line and hard-exits if the device stops responding (same rationale
 as bench.py). Scale up with --m; checkpointing is exercised separately by
 the resume tests — here the corpus is synthetic and regenerable, so the
 watchdog-kill-and-rerun loop is the failure plan.
@@ -100,10 +100,11 @@ def main(argv=None) -> int:
         t.daemon = True
         t.start()
 
-    if args.platform != "auto":
-        from mpi_knn_tpu.utils.platform import force_platform
+    from mpi_knn_tpu.utils.platform import force_platform, use_compile_cache
 
+    if args.platform != "auto":
         force_platform(args.platform)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -127,7 +127,6 @@ def test_r4_catches_injected_sharding_leak():
     the classic sharding leak: results stay correct, memory and bytes on
     the wire silently stop scaling with the ring."""
     from mpi_knn_tpu.parallel.mesh import make_ring_mesh
-    from mpi_knn_tpu.utils.compat import shard_map
 
     mesh = make_ring_mesh(None)
     axis = mesh.axis_names[0]
@@ -136,7 +135,7 @@ def test_r4_catches_injected_sharding_leak():
         return jax.lax.all_gather(blk, axis, axis=0, tiled=True)
 
     fn = jax.jit(
-        shard_map(leaky, mesh=mesh, in_specs=P(axis), out_specs=P())
+        jax.shard_map(leaky, mesh=mesh, in_specs=P(axis), out_specs=P())
     )
     texts = lowering.hlo_texts(
         fn.lower(jnp.zeros((128, 32), jnp.float32))
@@ -247,7 +246,6 @@ def test_r4_flags_any_collective_in_single_device_backends():
     """The same leaked program judged as a serial lowering: ANY collective
     is a violation there."""
     from mpi_knn_tpu.parallel.mesh import make_ring_mesh
-    from mpi_knn_tpu.utils.compat import shard_map
 
     mesh = make_ring_mesh(None)
     axis = mesh.axis_names[0]
@@ -256,7 +254,7 @@ def test_r4_flags_any_collective_in_single_device_backends():
         return jax.lax.all_gather(blk, axis, axis=0, tiled=True)
 
     fn = jax.jit(
-        shard_map(leaky, mesh=mesh, in_specs=P(axis), out_specs=P())
+        jax.shard_map(leaky, mesh=mesh, in_specs=P(axis), out_specs=P())
     )
     texts = lowering.hlo_texts(
         fn.lower(jnp.zeros((128, 32), jnp.float32))

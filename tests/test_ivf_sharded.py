@@ -555,11 +555,10 @@ def _lower_shard_body(body, shape=(8, 32)):
 
     from mpi_knn_tpu.analysis import lowering
     from mpi_knn_tpu.parallel.mesh import make_ring_mesh
-    from mpi_knn_tpu.utils.compat import shard_map
 
     mesh = make_ring_mesh(4)
     axis = mesh.axis_names[0]
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x: body(x, axis), mesh=mesh,
         in_specs=P(axis), out_specs=P(axis),
     ))

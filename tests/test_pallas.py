@@ -197,3 +197,82 @@ def test_pallas_cosine_subclamp_row_falls_back_to_serial(rng, variant):
         np.asarray(pal.dists), np.asarray(ser.dists), rtol=1e-5, atol=1e-6
     )
     np.testing.assert_array_equal(np.asarray(pal.ids), np.asarray(ser.ids))
+
+
+def test_pallas_prefix_queries_keep_their_identity(rng, variant):
+    """Queries that ARE the first corpus rows (query_ids = arange) keep
+    self-exclusion by grid position, like the all-pairs run they are a
+    prefix of; any other corpus identity cannot be honored by the kernels
+    and is refused, not silently dropped."""
+    X = _blobs(rng, m=192, d=16)
+    kw = dict(k=5, backend="pallas", pallas_variant=variant,
+              query_tile=32, corpus_tile=64)
+    full = all_knn(X, **kw)
+    head = all_knn(X, queries=X[:64], query_ids=np.arange(64), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(head.ids), np.asarray(full.ids)[:64]
+    )
+    with pytest.raises(ValueError, match="grid position"):
+        all_knn(X, queries=X[10:20], query_ids=np.arange(10, 20), **kw)
+
+
+def test_pallas_refuses_the_three_pass_dot():
+    """Mosaic lowers DEFAULT and HIGHEST dots only ("Unsupported dot
+    precision: HIGH" on the chip) — the config refuses the combination
+    everywhere rather than letting the CPU interpreter accept what the TPU
+    rejects."""
+    from mpi_knn_tpu import KNNConfig
+
+    with pytest.raises(ValueError, match="DEFAULT and HIGHEST"):
+        KNNConfig(backend="pallas", matmul_precision="high")
+    with pytest.raises(ValueError, match="DEFAULT and HIGHEST"):
+        KNNConfig(backend="ring-overlap", ring_fusion="fused",
+                  matmul_precision="high")
+
+
+@pytest.mark.parametrize("dim", [128, 784, 2048])
+def test_kernel_tiles_compile_under_mosaic_for_the_v5e(dim, variant):
+    """The tile clamp is derived from ``dim``: what ``kernel_tiles`` picks
+    must fit Mosaic's default 16 MiB scoped VMEM at any width. libtpu can
+    compile for a v5e topology with no chip present, so tier-1 asks Mosaic
+    itself (at 512 x 2048 it answers "Scoped allocation with size 26.00M
+    and limit 16.00M" for d = 784)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu.backends.pallas_backend import kernel_tiles
+    from mpi_knn_tpu.ops.pallas_knn import fused_knn_sweep, fused_knn_tiles
+    from tests.conftest import TPU_MODE
+
+    if TPU_MODE:
+        pytest.skip("on the chip every other test here compiles via Mosaic")
+    try:
+        device = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices[0]
+    except Exception as e:  # noqa: BLE001 — no compile-only TPU client here
+        pytest.skip(f"no compile-only TPU topology: {e}")
+
+    nq, m, k = 4096, 60000, 10
+    q_tile, c_tile = kernel_tiles(4096, 8192, nq, m, dim, k)
+    assert q_tile % 8 == 0 and c_tile % 128 == 0
+    kernel = fused_knn_tiles if variant == "tiles" else fused_knn_sweep
+    fn = jax.jit(functools.partial(
+        kernel, m_corpus=m, k=k, q_tile=q_tile, c_tile=c_tile,
+        all_pairs=False, interpret=False,
+    ))
+    sharding = SingleDeviceSharding(device)
+    # conftest turns x64 on for the f64 oracle paths; a TPU program is
+    # lowered without it (Mosaic's lowering recurses forever under x64)
+    with jax.enable_x64(False):
+        lowered = fn.lower(
+            jax.ShapeDtypeStruct((nq, dim), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((-(-m // c_tile) * c_tile, dim),
+                                 jnp.float32, sharding=sharding),
+        )
+        assert "tpu_custom_call" in lowered.as_text()
+        lowered.compile()  # raises with Mosaic's message if it does not fit

@@ -599,7 +599,7 @@ def test_bench_partial_round_banks_siblings_of_a_wedged_series():
     line, and emits a structured `"failed": true` line (not a bare
     watchdog error) for the wedged one. A third series with conflicting
     knobs exercises the usage-error path: exit-2 children are a config
-    bug, never banked and never fallback-triggering."""
+    bug and are never banked."""
     series = [
         {"name": "good"},
         # its own short leash: the overlay overrides the beat bound so
@@ -645,8 +645,8 @@ def test_bench_partial_round_banks_siblings_of_a_wedged_series():
     assert wedged["flight"]["records"] >= 1
 
     # supervisor notes: the kill reason and the usage-error refusal are
-    # on stderr for the operator, non-JSON (fold_round reads the last
-    # '{'-line as the context object)
+    # on stderr for the operator, non-JSON (harness tooling reads the
+    # last '{'-line as the context object)
     assert "beat starvation" in r.stderr
     assert "usage error" in r.stderr
 

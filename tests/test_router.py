@@ -204,6 +204,29 @@ def test_router_policy_validates():
         RouterPolicy(replay_buffer=0)
 
 
+def test_spawn_refuses_several_children_off_the_cpu(tmp_path, monkeypatch):
+    """One process per chip: spawned children share the router's
+    environment and argv, so nothing pins a child to a device. More than
+    one child is accepted only on an explicit CPU platform; the check is
+    made before any process starts and without importing jax."""
+    from mpi_knn_tpu.frontend.router import ReplicaSupervisor
+
+    def spawn(n, *serve_args):
+        return ReplicaSupervisor(n, serve_args, workdir=str(tmp_path))
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    spawn(3)  # inherited cpu: fine (never started)
+    with pytest.raises(ValueError, match="one process at a time"):
+        spawn(3, "--platform", "tpu")
+    with pytest.raises(ValueError, match="one process at a time"):
+        spawn(2, "--platform=tpu")
+    spawn(1, "--platform", "tpu")  # one child, one chip
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(ValueError, match="'auto'"):
+        spawn(2)  # whatever jax finds: a TPU when the host has one
+    spawn(2, "--platform", "cpu")
+
+
 # ---------------------------------------------------------------------------
 # the wire protocol over ModelReplica fleets
 
