@@ -298,6 +298,12 @@ def default_guards() -> GuardMap:
         "_recorder": "obs.spans:_reclock",
         "_env_recorder": "obs.spans:_reclock",
     }
+    g.module_waivers["obs.spans"] = {
+        "_active": "the resolved recorder, written under _reclock only; "
+        "the span helpers read the one reference bare (atomic under the "
+        "GIL) so the off path takes no lock: a span begun during a "
+        "set_recorder goes to the recorder it read, old or new",
+    }
 
     # -- resolution hints -------------------------------------------------
     g.attr_types.update({

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.vote import classify_from_labels
 from mpi_knn_tpu.types import ClassifyResult, KNNResult
 
@@ -52,6 +53,14 @@ def all_knn(
       global ids.
     """
     cfg = (config or KNNConfig()).replace(**overrides)
+    # entry until the last dispatch has returned (the result is not waited for)
+    with obs_spans.span(
+        "all_knn", cat="api", rows=len(corpus if queries is None else queries)
+    ):
+        return _all_knn(corpus, queries, cfg, mesh, query_ids)
+
+
+def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
     on_device = isinstance(corpus, jax.Array)
     if not on_device:
         corpus = np.asarray(corpus)

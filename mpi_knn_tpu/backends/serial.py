@@ -40,6 +40,7 @@ from mpi_knn_tpu.parallel.partition import (
 )
 
 
+@jax.named_scope("knn.dist")
 def masked_dist_tile(
     q_x: jax.Array,
     q_ids: jax.Array,
@@ -103,14 +104,15 @@ def local_tile_topk(
         )
         return ld.astype(out_dtype), li
     d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg)
-    return smallest_k(
-        d.astype(out_dtype),
-        blk_ids,
-        cfg.k,
-        method=cfg.topk_method,
-        recall_target=cfg.recall_target,
-        block=cfg.topk_block,
-    )
+    with jax.named_scope("knn.select"):
+        return smallest_k(
+            d.astype(out_dtype),
+            blk_ids,
+            cfg.k,
+            method=cfg.topk_method,
+            recall_target=cfg.recall_target,
+            block=cfg.topk_block,
+        )
 
 
 def knn_tile_step(
@@ -138,17 +140,18 @@ def knn_tile_step(
     else:
         d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg)
         all_d = jnp.concatenate([carry_d, d.astype(carry_d.dtype)], axis=-1)
-        all_i = jnp.concatenate(
-            [carry_i, jnp.broadcast_to(blk_ids[None, :], d.shape)], axis=-1
+        with jax.named_scope("knn.ids"):
+            tile_ids = jnp.broadcast_to(blk_ids[None, :], d.shape)
+        all_i = jnp.concatenate([carry_i, tile_ids], axis=-1)
+    with jax.named_scope("knn.merge"):
+        return smallest_k(
+            all_d,
+            all_i,
+            cfg.k,
+            method=cfg.topk_method,
+            recall_target=cfg.recall_target,
+            block=cfg.topk_block,
         )
-    return smallest_k(
-        all_d,
-        all_i,
-        cfg.k,
-        method=cfg.topk_method,
-        recall_target=cfg.recall_target,
-        block=cfg.topk_block,
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -253,21 +256,23 @@ def merge_tiles_into_carry(
         _, (ld, li) = jax.lax.scan(local, None, (tiles, tile_ids, tile_sqs))
         n_tiles = ld.shape[0]
         q_rows = carry_d.shape[0]
-        ld = jnp.moveaxis(ld, 0, 1).reshape(q_rows, n_tiles * cfg.k)
-        li = jnp.moveaxis(li, 0, 1).reshape(q_rows, n_tiles * cfg.k)
-        return cascade_smallest_k(
-            jnp.concatenate([carry_d, ld], axis=-1),
-            jnp.concatenate([carry_i, li], axis=-1),
-            cfg.k,
-            # survivors-of-survivors must merge exactly or recall decays
-            # multiplicatively; "block" is exact, "approx"/"bf16" are not
-            method=(
-                cfg.topk_method
-                if cfg.topk_method in ("exact", "block")
-                else "exact"
-            ),
-            block=cfg.topk_block,
-        )
+        with jax.named_scope("knn.merge"):
+            ld = jnp.moveaxis(ld, 0, 1).reshape(q_rows, n_tiles * cfg.k)
+            li = jnp.moveaxis(li, 0, 1).reshape(q_rows, n_tiles * cfg.k)
+            return cascade_smallest_k(
+                jnp.concatenate([carry_d, ld], axis=-1),
+                jnp.concatenate([carry_i, li], axis=-1),
+                cfg.k,
+                # survivors-of-survivors must merge exactly or recall
+                # decays multiplicatively; "block" is exact,
+                # "approx"/"bf16" are not
+                method=(
+                    cfg.topk_method
+                    if cfg.topk_method in ("exact", "block")
+                    else "exact"
+                ),
+                block=cfg.topk_block,
+            )
 
     def step(carry, tile):
         blk, blk_ids, blk_sq = tile
@@ -302,6 +307,7 @@ def effective_tiles(cfg: KNNConfig, m: int, nq: int) -> tuple[int, int]:
     return q_tile, cap_corpus_tile(q_tile, c_tile, cfg.max_tile_elems)
 
 
+@jax.named_scope("knn.retile")
 def prepare_tiles(corpus, queries, query_ids, cfg: KNNConfig, q_tile, c_tile):
     """Pad + reshape corpus/query arrays into device tile stacks. Host numpy
     inputs are padded on host then transferred once; device inputs are padded

@@ -472,14 +472,27 @@ class Frontend:
 
     def _run(self) -> None:
         try:
+            session = self.session
             while True:
                 with self._lock:
                     stopping = self._stop
+                    # a poll forms batches only out of pending rows: one
+                    # of an empty queue is the overload tick alone and
+                    # gets no span
+                    forming = None
+                    if self.scheduler.coalescer.pending_rows:
+                        forming = obs_spans.begin_span(
+                            "coalesce", cat="pump",
+                            sink=session.phase_sink("coalesce"),
+                        )
                     batches = self.scheduler.poll(
                         self._clock(), flush=stopping
                     )
                 for b in batches:
-                    self._dispatch(b)
+                    self._dispatch(b, forming)
+                    forming = None
+                # pending rows whose oldest has not waited max_wait_s yet
+                obs_spans.end_span(forming, batches=0)
                 if not batches:
                     # nothing formed: retire in-flight work so results
                     # are not held hostage to the NEXT batch arriving
@@ -500,7 +513,10 @@ class Frontend:
                             else max(0.0, wake - self._clock())
                         )
                         if not self._stop:
-                            self._work.wait(timeout=min(timeout, 0.05))
+                            with session.phase(
+                                "idle", cat="pump", flight=False
+                            ):
+                                self._work.wait(timeout=min(timeout, 0.05))
         except BaseException as e:  # noqa: BLE001 — fail tickets, re-raise
             with self._lock:
                 self._crashed = e
@@ -514,7 +530,16 @@ class Frontend:
                 self._dispatched.clear()
             raise
 
-    def _dispatch(self, batch) -> None:
+    def _dispatch(self, batch, forming=None) -> None:
+        """Stack one coalesced batch and hand it to the engine.
+        ``forming`` is the open ``coalesce`` span of the poll that formed
+        the batch (its first batch closes it; a later one of the same
+        poll opens its own around the stacking)."""
+        if forming is None:
+            forming = obs_spans.begin_span(
+                "coalesce", cat="pump",
+                sink=self.session.phase_sink("coalesce"),
+            )
         q = np.concatenate([r.queries for r in batch.parts], axis=0)
         self._metrics().histogram(
             "frontend_batch_fill_rows",
@@ -526,10 +551,20 @@ class Frontend:
             help="coalesced batches dispatched",
             labels={"reason": batch.reason},
         ).inc()
-        obs_spans.event(
-            "coalesce", cat="frontend", rows=batch.rows,
+        # queue wait: admission (Frontend.submit stamped arrival_s) to
+        # this hand-over, both on the pump's clock
+        now = self._clock()
+        waited = self._metrics().histogram(
+            "frontend_queue_wait_seconds",
+            help="per request: admitted to its batch handed to the engine",
+        )
+        for r in batch.parts:
+            waited.observe(now - r.arrival_s)
+        obs_spans.end_span(
+            forming, seq=self.session.next_seq, rows=batch.rows,
             requests=len(batch.parts), reason=batch.reason,
             oldest_wait_ms=round(batch.oldest_wait_s * 1e3, 3),
+            request_seqs=[r.seq for r in batch.parts],
         )
         self._dispatched.append(batch)
         for res in self.session.submit(q, tenants=batch.composition()):
@@ -537,12 +572,18 @@ class Frontend:
 
     def _scatter(self, res) -> None:
         batch = self._dispatched.pop(0)
-        dists, ids = res.dists, res.ids  # one D2H, padding stripped
-        with self._lock:
-            for req, start, stop in batch.slices():
-                t = self._tickets.pop(req.seq, None)
-                if t is not None:
-                    t._fulfill(dists[start:stop], ids[start:stop])
+        phase = self.session.phase
+        with phase("d2h", seq=res.seq, parent=res.span):
+            # padding stripped; a session with the NaN sentinel on has
+            # fetched dists at retire already (its own d2h span)
+            dists, ids = res.dists, res.ids
+        with phase("reply", seq=res.seq, parent=res.span,
+                   requests=len(batch.parts)):
+            with self._lock:
+                for req, start, stop in batch.slices():
+                    t = self._tickets.pop(req.seq, None)
+                    if t is not None:
+                        t._fulfill(dists[start:stop], ids[start:stop])
 
     def _metrics(self):
         return obs_metrics.get_registry()
@@ -785,28 +826,49 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             if self.path != "/query":
                 self._json(404, {"error": f"no such route {self.path}"})
                 return
+            # body read to response written, whatever the answer
+            span = obs_spans.begin_span(
+                "request", cat="http",
+                sink=obs_metrics.get_registry().histogram(
+                    "frontend_request_seconds",
+                    help="per /query request: body read to response "
+                    "written, on the handler's thread",
+                ).observe,
+            )
+            seen = {}
+            try:
+                seen = self._do_query(tenant)
+            finally:
+                obs_spans.end_span(span, **seen)
+
+        def _do_query(self, tenant: str) -> dict:
+            """Answer one POST /query; returns what the request's span
+            learns on the way (the admitted request's ``seq`` joins it
+            to its batch, and the status)."""
             try:
                 q = self._read_queries()
             except (ValueError, KeyError, TypeError) as e:
                 self._json(400, {"error": str(e)})
-                return
+                return {"status": 400}
             out = frontend.submit(tenant, q)
             if isinstance(out, Rejection):
                 self._reject(out)
-                return
+                return {"status": out.status}
+            seen = {"seq": out.request.seq, "rows": out.request.rows}
             try:
                 dists, ids = out.result(timeout=request_timeout_s)
             except TimeoutError as e:
                 self._json(504, {"error": str(e)})
-                return
+                return {**seen, "status": 504}
             except Exception as e:  # serving error (sentinel, …)
                 self._json(500, {"error": f"{type(e).__name__}: {e}"})
-                return
+                return {**seen, "status": 500}
             self._json(200, {
                 "rows": int(ids.shape[0]),
                 "dists": [[float(v) for v in row] for row in dists],
                 "ids": ids.tolist(),
             })
+            return {**seen, "status": 200}
 
         def do_GET(self):  # noqa: N802
             if self.path == "/metrics":

@@ -54,6 +54,9 @@ DEFAULT_LATENCY_BUCKETS_S = (
 COMPILE_BUCKETS_S = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 
 JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# a program found in jax's persistent compilation cache (at jax 0.9.0 the
+# compile event above fires around the lookup too, hit or miss)
+JAX_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _valid_metric_name(name: str) -> bool:
@@ -448,6 +451,12 @@ _jax_listener_installed = False
 
 
 def _jax_compile_listener(name: str, secs: float, **kw) -> None:
+    if name == JAX_CACHE_LOAD_EVENT:
+        get_registry().counter(
+            "jax_cache_loads_total",
+            help="programs loaded from jax's persistent compilation cache",
+        ).inc()
+        return
     if name != JAX_COMPILE_EVENT:
         return
     reg = get_registry()

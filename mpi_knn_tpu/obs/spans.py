@@ -40,10 +40,23 @@ the CI gate's contract. :func:`to_chrome_trace` exports the
 record as Chrome trace-event JSON loadable in Perfetto.
 
 Instrumented code uses the module-level :func:`span`/:func:`event`/
-:func:`begin_span`/:func:`end_span` helpers, which no-op unless a
-recorder is active — either installed explicitly (:func:`set_recorder`)
-or inherited from a supervisor via the ``TKNN_FLIGHT_RECORD`` env var
-(the ``maybe_beat`` convention: no mode flags at call sites).
+:func:`begin_span`/:func:`end_span` helpers: ONE call site, three sinks.
+
+- the flight record, when a recorder is active — installed explicitly
+  (:func:`set_recorder`) or inherited from a supervisor via the
+  ``TKNN_FLIGHT_RECORD`` env var, which is read when this module is
+  imported and at every :func:`get_recorder`/:func:`set_recorder`, never
+  per span (the ``maybe_beat`` convention: no mode flags at call sites);
+- the profiler's trace: a ``jax.profiler.TraceAnnotation`` named
+  ``knn:<cat>.<name>`` carrying the span's scalar attrs (``seq``,
+  ``parent``, ``rows``...), so the program's spans sit on the host plane
+  of the ``.xplane.pb`` on the device events' clock. Entered only when
+  jax is ALREADY imported in the process (``sys.modules``); inert outside
+  a profiler session;
+- a metric, when the call site passes ``sink=``: a callable given the
+  span's ``perf_counter`` seconds at its end (a counter's ``inc``, a
+  histogram's ``observe``) — every duration on ``/metrics`` that belongs
+  to a span comes from that span's own two clock reads.
 
 No jax import anywhere in this module.
 """
@@ -55,15 +68,12 @@ import itertools
 import json
 import math
 import os
+import sys
 import threading
 import time
 
 RECORDER_ENV = "TKNN_FLIGHT_RECORD"
-
-SPAN_CATEGORIES = (
-    "serve", "index", "compile", "bench", "retry", "heartbeat", "profile",
-    "frontend",
-)
+TRACE_PREFIX = "knn:"  # every annotation of the program in a profiler trace
 
 
 class FlightRecorder:
@@ -178,34 +188,59 @@ class FlightRecorder:
             rec["attrs"] = attrs
         self._write(rec)
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "", **attrs):
-        sid = self.begin(name, cat=cat, **attrs)
+    def _push(self, sid: int) -> None:
         stack = getattr(self._stack, "v", None)
         if stack is None:
             stack = self._stack.v = []
         stack.append(sid)
+
+    def _pop(self) -> None:
+        self._stack.v.pop()
+
+    @contextlib.contextmanager
+    def span(self, name: str, cat: str = "", **attrs):
+        sid = self.begin(name, cat=cat, **attrs)
+        self._push(sid)
         try:
             yield sid
         except BaseException as e:
-            stack.pop()
+            self._pop()
             self.end(sid, error=type(e).__name__)
             raise
         else:
-            stack.pop()
+            self._pop()
             self.end(sid)
 
 
 # ---------------------------------------------------------------------------
 # process-level recorder (explicit install wins over the env var)
 
-# module lock for the recorder globals (host-lint H1): get_recorder runs
-# on every instrumented thread — pump, HTTP handlers, warm pool — and an
+# module lock for the recorder globals (host-lint H1): resolution runs
+# on whichever thread installs or asks for the recorder, and an
 # unguarded lazy construction here could open two FlightRecorder handles
 # onto one path (duplicated, interleaved generations)
 _reclock = threading.Lock()
 _recorder: FlightRecorder | None = None
 _env_recorder: FlightRecorder | None = None
+# what the span helpers write to: resolved under _reclock, read bare
+_active: FlightRecorder | None = None
+
+
+def _resolve() -> FlightRecorder | None:
+    """Publish the active recorder: the explicitly installed one, else
+    one bound to ``TKNN_FLIGHT_RECORD`` (cached per path — supervisors
+    point each worker at a fresh file), else None."""
+    global _env_recorder, _active
+    with _reclock:
+        rec = _recorder
+        if rec is None:
+            path = os.environ.get(RECORDER_ENV)
+            if path:
+                if _env_recorder is None or _env_recorder.path != path:
+                    _env_recorder = FlightRecorder(path)
+                rec = _env_recorder
+        _active = rec
+        return rec
 
 
 def set_recorder(rec: FlightRecorder | None) -> None:
@@ -214,53 +249,117 @@ def set_recorder(rec: FlightRecorder | None) -> None:
     global _recorder
     with _reclock:
         prev, _recorder = _recorder, rec
+    _resolve()
     if prev is not None and prev is not rec:
         prev.close()
 
 
 def get_recorder() -> FlightRecorder | None:
-    """The active recorder: the explicitly installed one, else one bound
-    to ``TKNN_FLIGHT_RECORD`` (cached per path — supervisors point each
-    worker at a fresh file), else None."""
-    global _env_recorder
-    with _reclock:
-        if _recorder is not None:
-            return _recorder
-        path = os.environ.get(RECORDER_ENV)
-        if not path:
-            return None
-        if _env_recorder is None or _env_recorder.path != path:
-            _env_recorder = FlightRecorder(path)
-        return _env_recorder
+    """The active recorder, resolved anew (a ``TKNN_FLIGHT_RECORD`` set
+    after import arms the helpers from here on)."""
+    return _resolve()
 
 
-def begin_span(name: str, cat: str = "", **attrs) -> int | None:
-    """Begin a span that will be ended by a *different* call site
-    (e.g. serve dispatch → retire); no-op without a recorder."""
-    rec = get_recorder()
-    return None if rec is None else rec.begin(name, cat=cat, **attrs)
+_resolve()  # a supervised worker finds its flight file in its environment
 
 
-def end_span(sid: int | None, **attrs) -> None:
-    rec = get_recorder()
-    if rec is not None and sid is not None:
-        rec.end(sid, **attrs)
+_UNSAFE_IN_ANNOTATION = frozenset("#,=\n")  # the trace's own separators
+
+
+class _Span:
+    """One open span and where its end has to go."""
+
+    __slots__ = ("rec", "sid", "ann", "sink", "t0")
+
+
+def begin_span(name: str, cat: str = "", *, sink=None,
+               parent: _Span | None = None, flight: bool = True,
+               **attrs) -> _Span | None:
+    """Begin a span that :func:`end_span` ends, possibly at a different
+    call site (serve dispatch → retire). ``parent`` is another span's
+    handle, open or ended (None: the enclosing :func:`span` of this
+    thread, if any); ``sink`` gets the span's seconds at its end;
+    ``flight=False`` keeps it out of the flight record (an idle wait
+    twenty times a second would turn the ring over and push out what a
+    kill diagnosis needs). None when there is nowhere to write: no
+    recorder, no jax, no sink."""
+    rec = _active if flight else None
+    jp = sys.modules.get("jax.profiler")
+    if rec is None and jp is None and sink is None:
+        return None
+    h = _Span()
+    h.rec, h.sink, h.sid, h.ann = rec, sink, None, None
+    up = None if parent is None else parent.sid
+    if rec is not None:
+        if up is None:
+            up = rec._top()
+        h.sid = rec.begin(name, cat=cat, parent=up, **attrs)
+    if jp is not None:
+        ids = _scalars(attrs)
+        if h.sid is not None:  # the flight record's ids, for a joint read
+            ids["span"] = h.sid
+            if up is not None:
+                ids["parent"] = up
+        h.ann = jp.TraceAnnotation(f"{TRACE_PREFIX}{cat}.{name}", **ids)
+        h.ann.__enter__()
+    h.t0 = time.perf_counter() if sink is not None else 0.0
+    return h
+
+
+def end_span(h: _Span | None, **attrs) -> None:
+    """End a span; ``attrs`` are what was only known by now (a request's
+    ``seq`` after admission, a batch's latency)."""
+    if h is None:
+        return
+    if h.sink is not None:
+        h.sink(time.perf_counter() - h.t0)
+    if h.ann is not None:
+        if attrs:
+            h.ann.set_metadata(**_scalars(attrs))
+        h.ann.__exit__(None, None, None)
+    if h.rec is not None:
+        h.rec.end(h.sid, **attrs)
+
+
+def _scalars(attrs: dict) -> dict:
+    """The attrs an annotation can carry: whole numbers and plain words
+    (the trace encodes ``name#key=value,...#``)."""
+    return {
+        k: v for k, v in attrs.items()
+        if type(v) is int
+        or (type(v) is str and _UNSAFE_IN_ANNOTATION.isdisjoint(v))
+    }
 
 
 def event(name: str, cat: str = "", **attrs) -> None:
-    rec = get_recorder()
+    rec = _active
     if rec is not None:
         rec.event(name, cat=cat, **attrs)
 
 
 @contextlib.contextmanager
-def span(name: str, cat: str = "", **attrs):
-    rec = get_recorder()
-    if rec is None:
+def span(name: str, cat: str = "", *, sink=None,
+         parent: _Span | None = None, flight: bool = True, **attrs):
+    """A span around a block; spans begun inside it on this thread name
+    it as their flight-record parent. An exception ends it with
+    ``error=<type>``."""
+    h = begin_span(name, cat, sink=sink, parent=parent, flight=flight,
+                   **attrs)
+    if h is None:
         yield None
         return
-    with rec.span(name, cat=cat, **attrs) as sid:
-        yield sid
+    if h.rec is not None:
+        h.rec._push(h.sid)
+    failed = {}
+    try:
+        yield h
+    except BaseException as e:
+        failed = {"error": type(e).__name__}
+        raise
+    finally:
+        if h.rec is not None:
+            h.rec._pop()
+        end_span(h, **failed)
 
 
 # ---------------------------------------------------------------------------

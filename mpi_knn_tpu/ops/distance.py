@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 
+@jax.named_scope("knn.center")
 def center_for_l2(corpus, queries, all_pairs: bool):
     """Mean-center corpus (and queries consistently) before L2 distances.
 
@@ -69,6 +70,7 @@ def _dot_precision(x: jax.Array, precision: str | None):
     return jax.lax.Precision.HIGHEST
 
 
+@jax.named_scope("knn.norms")
 def sq_norms(x: jax.Array) -> jax.Array:
     """Row squared norms, accumulated at full precision. (r, d) -> (r,)."""
     acc = _acc_dtype(x)

@@ -103,6 +103,7 @@ def compress_tile(
     return 1.0 - sim
 
 
+@jax.named_scope("knn.rerank")
 def rerank_exact_topk(
     q_x: jax.Array,  # (q, d)
     q_ids: jax.Array | None,  # (q,) or None (no self-exclusion)

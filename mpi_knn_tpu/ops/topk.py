@@ -136,7 +136,8 @@ def smallest_k(
     """
     q, c = dists.shape
     if ids.ndim == 1:
-        ids = jnp.broadcast_to(ids[None, :], (q, c))
+        with jax.named_scope("knn.ids"):  # the tile-id plane
+            ids = jnp.broadcast_to(ids[None, :], (q, c))
     if k > c:
         pad = k - c
         dists = jnp.pad(dists, ((0, 0), (0, pad)), constant_values=_INF)
@@ -177,9 +178,10 @@ def smallest_k(
     else:
         neg, pos = jax.lax.top_k(-dists, k)
         vals = -neg
-    out_ids = jnp.take_along_axis(ids, pos, axis=-1)
-    # slots that hold +inf are by definition invalid
-    out_ids = jnp.where(jnp.isinf(vals), INVALID_ID, out_ids)
+    with jax.named_scope("knn.ids"):  # the survivors' id gather
+        out_ids = jnp.take_along_axis(ids, pos, axis=-1)
+        # slots that hold +inf are by definition invalid
+        out_ids = jnp.where(jnp.isinf(vals), INVALID_ID, out_ids)
     return vals, out_ids
 
 

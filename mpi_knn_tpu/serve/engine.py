@@ -954,6 +954,9 @@ class BatchResult:
     # static per-batch exchange bytes
     stats_padded: jax.Array | None = None
     exchange_bytes: int | None = None
+    # the batch's dispatch→retire span (obs.spans handle, None when
+    # nothing records): the phases of the batch name it as their parent
+    span: object = None
 
     @functools.cached_property
     def dists(self) -> np.ndarray:
@@ -1166,6 +1169,31 @@ class ServeSession:
         """The ladder rung new submissions dispatch under."""
         with self._stats_lock:
             return self.ladder[self._rung][0]
+
+    @property
+    def next_seq(self) -> int:
+        """The ``seq`` the next submitted batch will carry (for the
+        dispatching thread, which alone submits)."""
+        return self._seq
+
+    def phase_sink(self, phase: str):
+        """``sink=`` of a span of the thread that drives this session (the
+        front end's pump): its seconds go to
+        ``serve_batch_phase_seconds_total{phase=...}``, so the phases of a
+        window say where that thread's time went."""
+        return self._metrics.counter(
+            "serve_batch_phase_seconds_total",
+            help="seconds of the dispatching thread by phase: idle, "
+            "coalesce, prep, enqueue, wait, d2h, reply",
+            labels={"phase": phase},
+        ).inc
+
+    def phase(self, name: str, cat: str = "batch", **attrs):
+        """The span of one phase of the dispatching thread around a
+        block, feeding :meth:`phase_sink` of the same name."""
+        return obs_spans.span(
+            name, cat=cat, sink=self.phase_sink(name), **attrs
+        )
 
     def warm_snapshot(self) -> dict:
         """A consistent copy of ``warm_state`` for cross-thread readers
@@ -1381,7 +1409,8 @@ class ServeSession:
         all-inf row means every candidate was masked away. Neither may be
         returned as an answer or dropped silently: trip loudly, with the
         provenance an operator needs to find the batch."""
-        d = res.dists  # strips padding; cached, so retire pays D2H once
+        with self.phase("d2h", seq=res.seq, parent=res.span):
+            d = res.dists  # strips padding; cached: the one D2H of dists
         bad_nan = bool(np.isnan(d).any())
         bad_inf = bool(d.size) and bool(np.isinf(d).all(axis=1).any())
         if bad_inf and not bad_nan and res.exchange is not None \
@@ -1606,8 +1635,10 @@ class ServeSession:
             ).inc(n)
 
     def _retire(self) -> BatchResult:
-        res, t0, sid = self._inflight.popleft()
-        device_sync(res.dists_padded, res.ids_padded)
+        res, t0 = self._inflight.popleft()
+        sid = res.span
+        with self.phase("wait", seq=res.seq, parent=sid):
+            device_sync(res.dists_padded, res.ids_padded)
         res.latency_s = time.perf_counter() - t0
         with self._stats_lock:
             self.latencies.append(res.latency_s)
@@ -1711,21 +1742,29 @@ class ServeSession:
         self._metrics.counter(
             "serve_queries_total", help="query rows served (padding excluded)"
         ).inc(res.rows)
+        self._metrics.counter(
+            "serve_padded_rows_total",
+            help="rows of the padded batches retired (bucket height); "
+            "serve_queries_total over this is the fill ratio",
+        ).inc(res.dists_padded.shape[0])
         self._metrics.histogram(
             "serve_batch_latency_seconds",
             help="per-batch dispatch→device_sync latency",
         ).observe(res.latency_s)
         return res
 
-    def _dispatch(self, queries, cfg: KNNConfig):
+    def _dispatch(self, queries, cfg: KNNConfig, span=None):
         """One dispatch attempt under ``cfg`` (a ladder rung's config).
         The fault site models a transient transport failure; the poison
-        hook injects a NaN into the returned tile for sentinel tests."""
+        hook injects a NaN into the returned tile for sentinel tests.
+        ``span`` is the batch's span, parent of the two phases here."""
         fault_point("serve-batch")
         bucket = bucket_rows(queries.shape[0], cfg.query_bucket)
         exec_ = get_executable(self.index, cfg, bucket)
-        q2d, qids, rows = _prep_queries(self.index, cfg, exec_, queries)
-        d, i, stats = _run(self.index, cfg, exec_, q2d, qids)
+        with self.phase("prep", seq=self._seq, parent=span):
+            q2d, qids, rows = _prep_queries(self.index, cfg, exec_, queries)
+        with self.phase("enqueue", seq=self._seq, parent=span):
+            d, i, stats = _run(self.index, cfg, exec_, q2d, qids)
         return bucket, rows, poison_topk(d), i, stats, exec_.exchange_bytes
 
     def submit(self, queries, tenants=None) -> list[BatchResult]:
@@ -1781,7 +1820,7 @@ class ServeSession:
         try:
             if pol is not None and pol.max_retries > 0:
                 out = retry_with_backoff(
-                    lambda: self._dispatch(queries, cfg),
+                    lambda: self._dispatch(queries, cfg, sid),
                     retries=pol.max_retries,
                     base_s=pol.backoff_base_s,
                     max_s=pol.backoff_max_s,
@@ -1802,7 +1841,7 @@ class ServeSession:
                     ).inc(retries)
             else:
                 bucket, rows, d, i, stats, xbytes = self._dispatch(
-                    queries, cfg
+                    queries, cfg, sid
                 )
                 retries, backoffs = 0, ()
         except Exception as e:
@@ -1820,9 +1859,10 @@ class ServeSession:
             tenants=tenants,
             stats_padded=stats,
             exchange_bytes=xbytes,
+            span=sid,
         )
         self._seq += 1
-        self._inflight.append((res, t0, sid))
+        self._inflight.append((res, t0))
         done = []
         # bound the dispatch-ahead window: at depth d, batch t+d-1 may be
         # prepared/dispatched while batch t is still in flight; depth 1

@@ -363,7 +363,7 @@ note "serving front end gate (ISSUE 11: mpi-knn serve + loadgen)"
 # prove the operational artifacts are machine-readable: /metrics is
 # scraped over HTTP and re-parsed with the strict parse_prometheus (the
 # per-tenant labeled counters must survive the round trip), and the
-# flight record — coalesce events, batch spans with tenant composition —
+# flight record — coalesce spans, batch spans with tenant composition —
 # passes the schema gate. The coalescing/fairness/shedding BEHAVIOR is
 # tier-1 (tests/test_frontend*.py); this gate proves the network path
 # end to end through the CLIs. The frontend lint cell (the coalesced

@@ -107,7 +107,9 @@ def test_per_tenant_attribution_and_batch_spans(index, tmp_path):
         for t, n in (c or {}).items():
             served[t] = served.get(t, 0) + n
     assert served == {"alice": 16, "bob": 8}
-    assert any(e.get("name") == "coalesce" for e in events)
+    coalesced = [s for s in spans if s["name"] == "coalesce"
+                 and s["end_attrs"].get("rows")]
+    assert sum(s["end_attrs"]["rows"] for s in coalesced) == 24
 
 
 def test_rate_limited_tenant_gets_structured_429(index):

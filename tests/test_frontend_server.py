@@ -215,3 +215,140 @@ def test_mutation_seq_gap_is_409_and_refusals_consume_position(served):
                         json.dumps({"ids": [3]}).encode(),
                         {**hdr, "X-Mutation-Seq": str(a0 + 2)})
     assert status == 200 and doc["applied_seq"] == a0 + 2
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: the serving path's spans on /metrics and in the flight record
+
+_PHASES = ("idle", "coalesce", "prep", "enqueue", "wait", "d2h", "reply")
+
+
+def _scrape(url):
+    with urllib.request.urlopen(url + "/metrics", timeout=10) as resp:
+        return parse_prometheus(resp.read().decode())
+
+
+def _phase_seconds(samples):
+    return {p: samples.get(
+        f'serve_batch_phase_seconds_total{{phase="{p}"}}', 0.0)
+        for p in _PHASES}
+
+
+def test_served_batch_moves_every_new_sample(served):
+    """One request through HTTP -> coalescer -> engine moves the samples
+    the benchmark's new per-layer metrics read: a queue wait and a
+    request duration per request, the padded height of the batch beside
+    its real rows, and a positive time in every phase of the pump."""
+    srv, fe, _ = served
+    # let the pump reach its idle wait, so "idle" moves in the window too
+    import time
+
+    time.sleep(0.12)
+    before = _scrape(srv.url)
+    q = np.ones((5, DIM), dtype="<f4")
+    for _ in range(3):  # one at a time: three batches of 5 rows in 64
+        status, _ = _post(
+            srv.url, "/query", q.tobytes(),
+            {"Content-Type": "application/octet-stream", "X-Tenant": "t26"},
+        )
+        assert status == 200
+    time.sleep(0.12)
+    after = _scrape(srv.url)
+
+    def moved(name):
+        return after.get(name, 0.0) - before.get(name, 0.0)
+
+    assert moved("frontend_queue_wait_seconds_count") == 3
+    assert moved("frontend_queue_wait_seconds_sum") > 0.0
+    assert moved("frontend_request_seconds_count") == 3
+    # the handler's span holds the queue wait of its request
+    assert (moved("frontend_request_seconds_sum")
+            > moved("frontend_queue_wait_seconds_sum"))
+    assert moved("serve_batches_total") == 3
+    assert moved("serve_queries_total") == 15
+    assert moved("serve_padded_rows_total") == 3 * 64  # the bucket height
+    b, a = _phase_seconds(before), _phase_seconds(after)
+    for p in _PHASES:
+        assert a[p] - b[p] > 0.0, f"phase {p} did not move"
+
+
+def test_pump_phases_add_up_to_the_pump_threads_wall_time(served):
+    """The phases are a partition of the pump thread's time: over a
+    window with traffic their seconds sum to the window's length within
+    10 % (what is left out is bookkeeping between the spans; the window's
+    two ends may each cut one idle wait of at most 50 ms)."""
+    import time
+
+    srv, fe, _ = served
+    reg_before = _phase_seconds(_scrape(srv.url))
+    t0 = time.perf_counter()
+    q = np.ones((3, DIM), np.float32)
+    while time.perf_counter() - t0 < 2.0:
+        fe.submit("t26w", q).result(timeout=30)
+        time.sleep(0.01)
+    wall = time.perf_counter() - t0
+    reg_after = _phase_seconds(_scrape(srv.url))
+    total = sum(reg_after[p] - reg_before[p] for p in _PHASES)
+    assert abs(total - wall) <= 0.10 * wall, (total, wall)
+
+
+def test_flight_spans_join_request_to_batch_to_phases(tmp_path):
+    """Under a recorder: the handler's ``request`` span carries the
+    request's seq, the pump's ``coalesce`` span lists it beside the batch
+    seq it formed, and every phase of that batch names the engine's
+    ``batch`` span as its parent and carries the batch seq."""
+    from mpi_knn_tpu.obs.spans import (
+        FlightRecorder,
+        read_flight,
+        reconstruct_spans,
+        set_recorder,
+        validate_flight,
+    )
+
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(256, DIM)).astype(np.float32)
+    index = build_index(
+        X, KNNConfig(k=4, backend="serial", query_bucket=16,
+                     corpus_tile=128, query_tile=16),
+    )
+    flight = tmp_path / "flight.jsonl"
+    set_recorder(FlightRecorder(str(flight), fresh=True))
+    try:
+        fe = Frontend(
+            ServeSession(index, resilience=ResiliencePolicy()),
+            SLOPolicy(max_batch_rows=16, max_wait_s=0.002,
+                      max_queue_rows=1024),
+        ).start()
+        srv = FrontendHTTPServer(fe, port=0).start()
+        try:
+            status, _ = _post(
+                srv.url, "/query", X[:4].astype("<f4").tobytes(),
+                {"Content-Type": "application/octet-stream",
+                 "X-Tenant": "joined"},
+            )
+            assert status == 200
+        finally:
+            srv.stop()
+            fe.stop()
+    finally:
+        set_recorder(None)
+    records = read_flight(str(flight))
+    assert validate_flight(records) == []
+    spans, _ = reconstruct_spans(records)
+
+    def named(cat, name):
+        return [s for s in spans if (s["cat"], s["name"]) == (cat, name)]
+
+    (request,) = named("http", "request")
+    rseq = request["end_attrs"]["seq"]
+    assert request["end_attrs"]["status"] == 200
+    (coalesce,) = [s for s in named("pump", "coalesce")
+                   if rseq in (s["end_attrs"].get("request_seqs") or ())]
+    bseq = coalesce["end_attrs"]["seq"]
+    (batch,) = [s for s in named("serve", "batch")
+                if s["attrs"]["seq"] == bseq]
+    for phase in ("prep", "enqueue", "wait", "d2h", "reply"):
+        mine = [s for s in named("batch", phase) if s["attrs"]["seq"] == bseq]
+        assert mine, f"no {phase} span for batch {bseq}"
+        assert all(s["parent"] == batch["span"] for s in mine)
+    assert not named("pump", "idle")  # kept out of the flight record

@@ -593,6 +593,7 @@ def test_get_recorder_returns_one_instance_across_threads(
     monkeypatch.setenv(spans.RECORDER_ENV, str(tmp_path / "fl.jsonl"))
     monkeypatch.setattr(spans, "_env_recorder", None)
     monkeypatch.setattr(spans, "_recorder", None)
+    monkeypatch.setattr(spans, "_active", None)  # get_recorder publishes it
     got = []
     barrier = threading.Barrier(8)
 

@@ -322,12 +322,18 @@ def test_span_helpers_noop_without_recorder_env_arms_them(
     from mpi_knn_tpu.obs import spans as spans_mod
 
     monkeypatch.delenv(spans_mod.RECORDER_ENV, raising=False)
+    assert spans_mod.get_recorder() is None
     spans_mod.event("nothing")  # must not write anywhere / crash
-    assert spans_mod.begin_span("x") is None
+    h = spans_mod.begin_span("x")  # jax is loaded: an inert annotation
+    assert h is None or h.sid is None  # nothing for a flight record
+    spans_mod.end_span(h)
     spans_mod.end_span(None)
 
+    # the env var is read at import and by get_recorder/set_recorder,
+    # never per span: a process that sets it late re-arms explicitly
     path = str(tmp_path / "env.jsonl")
     monkeypatch.setenv(spans_mod.RECORDER_ENV, path)
+    assert spans_mod.get_recorder().path == path
     with spans_mod.span("from-env", cat="bench"):
         pass
     spans_mod.get_recorder().close()
@@ -1096,3 +1102,164 @@ def test_mixed_kind_family_guard_spans_labels():
     reg.counter("fam_total")
     reg.clear()
     reg.gauge("fam_total")  # clear() resets the family map too
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: one span instrument, three sinks (flight record, profiler
+# annotation, /metrics), and stable scope names on the kernels
+
+
+def test_span_off_path_reads_no_environ_and_writes_nothing(
+    tmp_path, monkeypatch
+):
+    """No recorder, no profiler session: the helpers resolve nothing per
+    call (the env var was read when the recorder was last installed or
+    asked for) and leave no file behind."""
+    from mpi_knn_tpu.obs import spans as spans_mod
+
+    monkeypatch.delenv(spans_mod.RECORDER_ENV, raising=False)
+    set_recorder(None)
+    assert spans_mod._active is None
+
+    class NoEnviron:
+        def __getattr__(self, name):
+            raise AssertionError(f"os.environ.{name} read on the off path")
+
+        __getitem__ = __contains__ = __getattr__
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(spans_mod.os, "environ", NoEnviron())
+    took = []
+    with spans_mod.span("a", cat="t", seq=1, sink=took.append):
+        h = spans_mod.begin_span("b", cat="t", seq=1)
+        spans_mod.end_span(h, rows=3)
+    spans_mod.event("c", cat="t")
+    monkeypatch.undo()
+    assert len(took) == 1 and took[0] >= 0.0  # the sink still got its span
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_spans_module_stays_jax_free_and_inert_without_jax():
+    """``obs/spans.py`` is imported by supervisors that must never load
+    jax: it looks jax up in ``sys.modules`` and never imports it, and with
+    jax absent and no recorder a span has nowhere to go at all."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, os; os.environ.pop('TKNN_FLIGHT_RECORD', None); "
+        "import mpi_knn_tpu.obs.spans as s; "
+        "assert 'jax' not in sys.modules, 'obs.spans imported jax'; "
+        "assert s.begin_span('x', cat='t', seq=1) is None; "
+        "took = []; h = s.begin_span('y', sink=took.append); "
+        "assert h.ann is None and h.sid is None; s.end_span(h); "
+        "assert len(took) == 1; "
+        "assert 'jax' not in sys.modules"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_span_feeds_flight_record_sink_and_profiler_annotation(tmp_path):
+    """One call site, three sinks: under a recorder and a profiler
+    session a span is a flight record with its parent, a ``knn:<cat>.
+    <name>`` event on the host plane of the ``.xplane.pb`` carrying its
+    scalar attrs (those known only at its end too), and a duration handed
+    to ``sink``. ``flight=False`` keeps a span out of the file only."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.obs import spans as spans_mod
+
+    path = str(tmp_path / "f.jsonl")
+    set_recorder(FlightRecorder(path))
+    took = []
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        batch = spans_mod.begin_span("batch", cat="serve", seq=7, rows=3)
+        with spans_mod.span("prep", cat="batch", seq=7, parent=batch,
+                            sink=took.append, words="a b", bad="x=1,y"):
+            jnp.ones((8, 8)).block_until_ready()
+        with spans_mod.span("idle", cat="pump", flight=False,
+                            sink=took.append):
+            pass
+        spans_mod.end_span(batch, latency_ms=2)
+    finally:
+        jax.profiler.stop_trace()
+        set_recorder(None)
+
+    spans, _ = reconstruct_spans(read_flight(path))
+    by_name = {s["name"]: s for s in spans}
+    assert set(by_name) == {"batch", "prep"}  # idle: flight=False
+    assert by_name["prep"]["parent"] == by_name["batch"]["span"]
+    assert by_name["prep"]["attrs"]["seq"] == 7
+    assert len(took) == 2 and all(t >= 0.0 for t in took)
+    assert took[0] == pytest.approx(by_name["prep"]["dur_s"], abs=0.05)
+
+    (pb,) = glob.glob(
+        str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(spans_mod.TRACE_PREFIX):
+                    found[e.name] = dict(e.stats)
+    assert set(found) == {"knn:serve.batch", "knn:batch.prep",
+                          "knn:pump.idle"}
+    assert found["knn:serve.batch"]["seq"] == 7
+    assert found["knn:serve.batch"]["latency_ms"] == 2  # set at its end
+    prep = found["knn:batch.prep"]
+    assert prep["seq"] == 7 and prep["words"] == "a b"
+    assert prep["parent"] == by_name["batch"]["span"]
+    assert "bad" not in prep  # would break the trace's name#k=v,...# form
+
+
+def test_cache_load_event_feeds_jax_cache_loads_total():
+    """The listener behind ``jax_compiles_total`` also counts jax's
+    persistent-cache retrievals; other events move neither."""
+    from mpi_knn_tpu.obs import metrics as metrics_mod
+
+    reg = get_registry()
+    loads = reg.counter("jax_cache_loads_total")
+    compiles = reg.counter("jax_compiles_total")
+    l0, c0 = loads.value, compiles.value
+    metrics_mod._jax_compile_listener(metrics_mod.JAX_CACHE_LOAD_EVENT, 0.01)
+    metrics_mod._jax_compile_listener("/jax/some/other/event", 0.01)
+    assert (loads.value, compiles.value) == (l0 + 1, c0)
+    metrics_mod._jax_compile_listener(metrics_mod.JAX_COMPILE_EVENT, 0.01)
+    assert (loads.value, compiles.value) == (l0 + 1, c0 + 1)
+
+
+@pytest.mark.parametrize("program", ["all_knn_step", "serve_bucket"])
+def test_kernel_scope_names_are_in_the_compiled_hlo(rng, program):
+    """The stable ``knn.*`` names the trace reduction will key on are in
+    the ``op_name`` metadata of the programs the benchmark's cells run:
+    the serial all-kNN step and a serve bucket."""
+    import re
+
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.backends.serial import knn_chunk_update
+    from mpi_knn_tpu.ops.topk import init_topk_tiles
+    from mpi_knn_tpu.serve.engine import lower_bucket
+
+    cfg = _cfg()
+    X = rng.standard_normal((64, 8)).astype(np.float32)
+    if program == "all_knn_step":
+        carry_d, carry_i = init_topk_tiles(1, 16, cfg.k)
+        lowered = knn_chunk_update.lower(
+            jnp.asarray(X[:16]).reshape(1, 16, 8),
+            jnp.arange(16, dtype=jnp.int32).reshape(1, 16),
+            jnp.asarray(X).reshape(2, 32, 8),
+            jnp.arange(64, dtype=jnp.int32).reshape(2, 32),
+            carry_d, carry_i, cfg,
+        )
+    else:
+        lowered, _, _ = lower_bucket(build_index(X, cfg), cfg, 16)
+    names = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+    scopes = {part for n in names for part in n.split("/")
+              if part.startswith("knn.")}
+    assert {"knn.dist", "knn.select", "knn.ids", "knn.merge",
+            "knn.norms"} <= scopes, sorted(scopes)
