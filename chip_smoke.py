@@ -198,6 +198,15 @@ def compute_phases(args, platform, out, record) -> None:
     s_ids, s_dists = np.asarray(serial.ids), np.asarray(serial.dists)
     sample = np.linspace(0, m - 1, num=min(256, m), dtype=np.int64)
     recall = recall_at_k(s_ids[sample], oracle_topk(X, sample, K))
+    # the per-tile selection's counter on one real tile of this data: the
+    # share of rows its certificate flags (a flagged row sends the whole
+    # tile step to the full-width top-k); None where the shape bypasses
+    from mpi_knn_tpu.ops.distance import pairwise_dist
+    from mpi_knn_tpu.ops.topk import lane_bin_flagged_share
+
+    Xc = Xd - jnp.mean(Xd, axis=0)
+    flagged = lane_bin_flagged_share(
+        pairwise_dist(Xc[:cfg.query_tile], Xc[-cfg.corpus_tile:]), K)
     ok = (
         s_ids.shape == (m, K)
         and np.isfinite(s_dists).all()
@@ -207,7 +216,8 @@ def compute_phases(args, platform, out, record) -> None:
         "allknn", ok, t0,
         f"data={source} shape={list(X.shape)} k={K} first_call_s="
         f"{compile_s:.2f} warm_call_s={warm_s:.3f} recall@{K}={recall:.4f} "
-        f"compile_cache_entries={entries}->{cache_entries()}",
+        f"compile_cache_entries={entries}->{cache_entries()} "
+        f"select_flagged_rows_and_tile={flagged}",
         first_call_s=round(compile_s, 3), warm_call_s=round(warm_s, 4),
         recall=round(float(recall), 5),
     )

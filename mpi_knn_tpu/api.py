@@ -13,6 +13,7 @@ import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import spans as obs_spans
+from mpi_knn_tpu.ops.topk import start_lane_bin_import
 from mpi_knn_tpu.ops.vote import classify_from_labels
 from mpi_knn_tpu.types import ClassifyResult, KNNResult
 
@@ -61,6 +62,7 @@ def all_knn(
 
 
 def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
+    start_lane_bin_import()  # under the corpus passes below
     on_device = isinstance(corpus, jax.Array)
     if not on_device:
         corpus = np.asarray(corpus)

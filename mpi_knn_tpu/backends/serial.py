@@ -91,7 +91,9 @@ def local_tile_topk(
     merge schedules share, switched on ``cfg.precision_policy``:
 
     - "exact": one distance pass at ``cfg.matmul_precision`` (HIGHEST by
-      default for f32), then ``smallest_k`` per ``cfg.topk_method``;
+      default for f32), then ``smallest_k`` per ``cfg.topk_method`` — with
+      the 1-D tile id vector, so "exact" can take the lane-bin selection
+      (ops/topk.py) instead of sorting the tile;
     - "mixed": the compress-and-rerank two-pass pipeline (ops/rerank.py) —
       a DEFAULT-precision bf16 compress dot overfetches 4k candidates, a
       HIGHEST rerank of the gathered survivors finishes exactly. The tile's

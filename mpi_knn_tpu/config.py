@@ -67,7 +67,11 @@ class KNNConfig:
         distance — the reference's semantics, which also drops exact duplicate
         points (``sqrt(S) != 0``, ``/root/reference/knn-serial.c:86``).
       zero_eps: threshold for ``exclude_zero`` in squared-distance space.
-      topk_method: ``exact`` (``lax.top_k``), ``approx``
+      topk_method: ``exact`` (the exact k smallest: per tile, for a small k
+        over a wide tile, the lane-bin selection — per-lane partial
+        selection on the VPU, an exactness certificate, ``lax.top_k`` only
+        as a rare per-tile fallback; ``lax.top_k`` everywhere else — the
+        choice follows the shapes alone, same values either way), ``approx``
         (``lax.approx_min_k``, the TPU-optimized partial reduction from the
         TPU-KNN paper — see PAPERS.md), ``approx-rerank`` (the paper's
         peak-FLOPs recipe: unaggregated approx preselect of 4k candidates

@@ -4,19 +4,41 @@ insert-and-qsort neighbor list (SURVEY.md C3).
 The reference keeps, per query, NN=30 slots initialized to INFINITY and
 re-``qsort``s all 30 on every accepted candidate
 (``/root/reference/knn-serial.c:57-63,86-91``) — O(k log k) *per candidate*.
-Here a whole (q_tile × c_tile) distance tile is reduced at once with
-``lax.top_k`` and cross-tile/cross-round state is merged associatively::
+Here a whole (q_tile × c_tile) distance tile is reduced at once to its k
+smallest and cross-tile/cross-round state is merged associatively::
 
     merge(carry, tile) = top_k(concat(carry, top_k(tile)))
 
 which is exactly the property the distributed ring needs (merge is
 commutative/associative over candidate sets — tested in test_topk.py).
 
+The per-tile reduction of a wide tile and a small k does not sort the tile
+(``lax.top_k`` is a full sort of every row: 3.6 ms for 4096 × 8192 on a v5e,
+65-80 % of the device's time, PERF.md §6 PR 27). ``smallest_k``'s "exact"
+method picks, by a rule on the shapes alone (``lane_bin_depth``), the
+**lane-bin selection**: (1) *bins* — the c columns are c/128 groups of 128
+lanes, and every (row, lane) keeps the R smallest of its c/128 values with
+their ids by compare-exchange on the VPU (``ops/lane_bin.py``, a Pallas
+kernel with no dot in it); (2) *finish* — the k smallest of the R·128
+candidates a row, by k passes of row-min and knock-out; (3) *certificate* —
+with tau the k-th smallest candidate, if every lane's R-th kept value is
+>= tau, nothing that was dropped can belong to the answer; (4) *fallback* —
+a ``lax.cond`` runs the full-width ``lax.top_k`` for a tile step in which
+some row fails the certificate (R of its k-1 smallest in one lane: at
+k = 10, R = 5 and random placement 4.7e-7 a row). The answer is exact for any
+data; only the speed depends on how neighbours fall into lanes. The scopes
+``bins``, ``finish`` and ``fallback`` sit under the caller's ``knn.select``
+in a trace. Merges, the cascade and IVF (2-D ids) keep ``lax.top_k``.
+
 All distances flow in "smaller is better" space; +inf marks invalid slots and
 ``INVALID_ID`` (−1) marks their ids.
 """
 
 from __future__ import annotations
+
+import importlib
+import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +116,123 @@ def preselect_smallest(dists: jax.Array, n: int, half_width: bool = False):
     return pos
 
 
+# --- lane-bin partial selection (the engaged form of method="exact") -------
+
+_LANES = 128  # the bins' column groups are a vreg's 128 lanes wide
+_MIN_BIN_WIDTH = 1024  # narrower tiles: one lax.top_k is already cheap
+_MAX_BIN_DEPTH = 8
+_MAX_BIN_K = _LANES  # the finish kernel answers in 128-lane accumulators
+# the rule keeps the EXPECTED share of tile steps that take the fallback
+# (neighbours falling into lanes at random) under this
+_MAX_FALLBACK_SHARE = 0.01
+
+
+def lane_bin_depth(q: int, c: int, k: int, ids_ndim: int = 1) -> int | None:
+    """The engage rule of ``smallest_k(method="exact")``: the per-lane depth
+    R of the lane-bin selection for a (q, c) tile and k, or None when the
+    call keeps the full-width ``lax.top_k``.
+
+    Engaged when ``ids`` is the 1-D (c,) tile id vector (the per-tile call;
+    merges, the cascade and IVF pass 2-D ids), ``c`` is a multiple of 128
+    and at least 1024, k is at most 128 (the finish kernel's accumulators
+    are one vreg wide; only tiles of a few rows get that far under the
+    next condition), and some R <= 8 (and below the group count) keeps the
+    expected flagged share of tile steps under 1 %: a row is flagged when R
+    of its k-1 smallest share a lane, which for neighbours placed at random
+    has probability about C(k-1, R) / 128^(R-1); a tile step falls back when
+    any of its q rows is flagged. k = 10 gives R = 5 at 1024 and 4096 rows
+    and R = 4 at 64; k in the hundreds bypasses."""
+    if (ids_ndim != 1 or c % _LANES or c < _MIN_BIN_WIDTH
+            or k > _MAX_BIN_K):
+        return None
+    for depth in range(1, min(_MAX_BIN_DEPTH, c // _LANES - 1) + 1):
+        p_row = math.comb(k - 1, depth) / _LANES ** (depth - 1)
+        if q * p_row < _MAX_FALLBACK_SHARE:
+            return depth
+    return None
+
+
+def lane_bin_flagged_share(dists, k: int) -> tuple[float, float] | None:
+    """Host-side counter of the lane-bin selection on one real (q, c) tile:
+    (share of rows flagged, 1.0 if the tile step would take the fallback
+    else 0.0), or None where the rule bypasses the tile. What the tests and
+    chip checks call; the chunk programs themselves report nothing (their
+    fallback shows in a trace under ``knn.select/fallback``)."""
+    dists = jnp.asarray(dists)
+    q, c = dists.shape
+    depth = lane_bin_depth(q, c, k)
+    if depth is None:
+        return None
+    flagged = _lane_bin_select(
+        dists, jnp.arange(c, dtype=jnp.int32), k, depth)[2]
+    return float(jnp.mean(flagged)), float(jnp.any(flagged))
+
+
+def _lane_bin_select(dists: jax.Array, ids: jax.Array, k: int, depth: int):
+    """``ops/lane_bin.py lane_bin_select``, imported at the first call:
+    pallas costs ~0.8 s to import, and a process that never selects from a
+    wide tile should not pay it."""
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_select
+
+    return lane_bin_select(dists, ids, k, depth)
+
+
+_lane_bin_import: threading.Thread | None = None
+
+
+def start_lane_bin_import() -> None:
+    """Start importing ``ops/lane_bin.py`` on a thread, once a process; the
+    first trace's own ``import`` joins it through the module lock. Called by
+    the owners of the chunk programs (``api._all_knn``, ``serve.build_index``)
+    ahead of their corpus passes: those wait on tiny compiles and on the
+    device, which hides most of the 0.8-2 s that ``jax.experimental.pallas``
+    takes to import (PERF.md §6, PR 27)."""
+    global _lane_bin_import
+    if _lane_bin_import is None:
+        _lane_bin_import = threading.Thread(
+            target=importlib.import_module,
+            args=("mpi_knn_tpu.ops.lane_bin",),
+            name="tknn-import-lane-bin", daemon=True,
+        )
+        _lane_bin_import.start()
+
+
+def _top_k_with_ids(dists: jax.Array, ids: jax.Array, k: int):
+    """The full-width exact selection: ``lax.top_k`` over every column, the
+    survivors' ids gathered after. ``ids`` (c,) or (q, c)."""
+    neg, pos = jax.lax.top_k(-dists, k)
+    vals = -neg
+    return vals, _survivor_ids(ids, pos, vals)
+
+
+@jax.named_scope("knn.ids")
+def _survivor_ids(ids: jax.Array, pos: jax.Array, vals: jax.Array):
+    """The ids at the survivors' column positions; slots that hold +inf are
+    by definition invalid. ``ids`` (c,) or (q, c)."""
+    if ids.ndim == 1:
+        out_ids = jnp.take(ids, pos, axis=0)
+    else:
+        out_ids = jnp.take_along_axis(ids, pos, axis=-1)
+    return jnp.where(jnp.isinf(vals), INVALID_ID, out_ids)
+
+
+def _lane_bin_smallest_k(dists: jax.Array, ids: jax.Array, k: int, depth: int):
+    """Exact k smallest of a wide tile without sorting it: lane-bin
+    candidates, narrow finish, certificate; the full-width ``lax.top_k``
+    runs for this tile step only if some row is flagged."""
+    vals, out_ids, flagged = _lane_bin_select(dists, ids, k, depth)
+    with jax.named_scope("finish"):
+        any_flagged = jnp.any(flagged)
+    # the scope holds the cond and all its branch holds, nothing else: its
+    # device time in a trace is what failed certificates cost
+    with jax.named_scope("fallback"):
+        return jax.lax.cond(
+            any_flagged,
+            lambda: _top_k_with_ids(dists, ids, k),
+            lambda: (vals, out_ids),
+        )
+
+
 def smallest_k(
     dists: jax.Array,
     ids: jax.Array,
@@ -108,7 +247,11 @@ def smallest_k(
       dists: (q, c) distances.
       ids: (c,) or (q, c) int32 global candidate ids.
       k: how many to keep. If k > c the result is padded with (+inf, -1).
-      method: "exact" = lax.top_k on negated distances; "approx" =
+      method: "exact" = the exact k smallest, ascending: the lane-bin
+        selection (module docstring) where ``lane_bin_depth`` engages it —
+        ``ids`` 1-D, c a multiple of 128 and >= 1024, k small — else
+        ``lax.top_k`` on negated distances. Same values either way; among
+        exactly equal distances the two may order ids differently. "approx" =
         lax.approx_min_k (TPU-optimized partial reduction, PAPERS.md TPU-KNN);
         "block" = EXACT two-level reduction — per-``block``-column top-k
         (narrow sorts) followed by a top-k over the nb·k survivors. Every
@@ -135,6 +278,10 @@ def smallest_k(
       (q, k) dists ascending, (q, k) ids.
     """
     q, c = dists.shape
+    if method == "exact":
+        depth = lane_bin_depth(q, c, k, ids.ndim)
+        if depth is not None:
+            return _lane_bin_smallest_k(dists, ids, k, depth)
     if ids.ndim == 1:
         with jax.named_scope("knn.ids"):  # the tile-id plane
             ids = jnp.broadcast_to(ids[None, :], (q, c))
@@ -175,14 +322,8 @@ def smallest_k(
     if method == "approx" and c > k:
         dists, ids = _pad_lanes(dists, ids)
         vals, pos = jax.lax.approx_min_k(dists, k, recall_target=recall_target)
-    else:
-        neg, pos = jax.lax.top_k(-dists, k)
-        vals = -neg
-    with jax.named_scope("knn.ids"):  # the survivors' id gather
-        out_ids = jnp.take_along_axis(ids, pos, axis=-1)
-        # slots that hold +inf are by definition invalid
-        out_ids = jnp.where(jnp.isinf(vals), INVALID_ID, out_ids)
-    return vals, out_ids
+        return vals, _survivor_ids(ids, pos, vals)
+    return _top_k_with_ids(dists, ids, k)
 
 
 def cascade_smallest_k(

@@ -37,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ops.distance import sq_norms
+from mpi_knn_tpu.ops.topk import start_lane_bin_import
 from mpi_knn_tpu.parallel.partition import (
     make_global_ids,
     pad_rows_any,
@@ -158,6 +159,7 @@ def build_index(
     from mpi_knn_tpu.api import resolve_backend
     from mpi_knn_tpu.obs.spans import span as _flight_span
 
+    start_lane_bin_import()  # under the corpus passes below
     cfg = (config or KNNConfig()).replace(**overrides)
     if not isinstance(corpus, jax.Array):
         corpus = np.asarray(corpus)

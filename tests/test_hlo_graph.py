@@ -193,3 +193,69 @@ ENTRY %main.1 (a.1: f32[4], b.1: f32[4]) -> f32[4] {
     sl = backward_slice(m, "inner.1", "d.1")
     names = {n for _, n in sl}
     assert {"a.1", "b.1"} <= names
+
+
+# --- the serial chunk program's per-tile selection, as XLA compiles it -----
+
+
+def _chunk_program(k, q=64, c=8192, dim=16, tiles=2):
+    """Optimized HLO of ``knn_chunk_update`` at one (q, c) tile shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.backends.serial import knn_chunk_update
+    from mpi_knn_tpu.config import KNNConfig
+
+    s = jax.ShapeDtypeStruct
+    cfg = KNNConfig(k=k, query_tile=q, corpus_tile=c)
+    return knn_chunk_update.lower(
+        s((1, q, dim), jnp.float32), s((1, q), jnp.int32),
+        s((tiles, c, dim), jnp.float32), s((tiles, c), jnp.int32),
+        s((1, q, k), jnp.float32), s((1, q, k), jnp.int32), cfg=cfg,
+    ).compile().as_text()
+
+
+def _selection_ops(text):
+    """(scope path, opcode, result type, operand types) of every instruction
+    under the ``knn.select`` scope."""
+    import re
+
+    out = []
+    for comp in parse_hlo(text).computations.values():
+        for ins in comp.instructions.values():
+            m = re.search(r'op_name="([^"]*)"', ins.attrs)
+            if m and "knn.select" in m.group(1):
+                operands = " ".join(
+                    comp.instructions[o].type_str
+                    for o in ins.operands if o in comp.instructions
+                )
+                out.append((m.group(1), ins.opcode, ins.type_str, operands))
+    return out
+
+
+def test_chunk_program_selects_without_a_tile_wide_sort():
+    """k = 10 over 8192 columns: the lane-bin selection is engaged. Outside
+    the fallback branch the selection holds no sort / top-k over a c-wide
+    operand and no s32[q, c] id plane, and the three scopes a trace reads
+    are in the op names. k = 256 bypasses: the full-width top-k as ever."""
+    wide = "[64,8192]"
+    ops = _selection_ops(_chunk_program(k=10))
+    scopes = {s for s, *_ in ops}
+    for want in ("knn.select/bins", "knn.select/finish", "knn.select/fallback"):
+        assert any(want in s for s in scopes), want
+    sorts = [
+        (s, op) for s, op, _, operands in ops
+        if (op == "sort" or "top_k" in s) and wide in operands
+    ]
+    assert sorts and all("knn.select/fallback" in s for s, _ in sorts), sorts
+    planes = [
+        (s, op) for s, op, ty, _ in ops
+        if "s32" + wide in ty and "knn.select/fallback" not in s
+    ]
+    assert not planes, planes
+
+    ops = _selection_ops(_chunk_program(k=256))
+    assert not any(
+        w in s for s, *_ in ops for w in ("/bins", "/finish", "/fallback"))
+    assert any(
+        "knn.select/top_k" in s and wide in operands for s, _, _, operands in ops)

@@ -1,3 +1,4 @@
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -5,6 +6,8 @@ import pytest
 from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
     init_topk,
+    lane_bin_depth,
+    lane_bin_flagged_share,
     mask_tile,
     merge_topk,
     smallest_k,
@@ -238,3 +241,188 @@ def test_approx_rerank_nondivisible_width_padded(rng):
     np.testing.assert_allclose(np.asarray(got_d), want_d, rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(got_i), want_i)
     assert (np.asarray(got_i) >= 0).all()
+
+
+# --- the lane-bin selection: the engaged form of method="exact" -------------
+
+
+def _full_width(d, ids, k):
+    """What every engaged call must equal: lax.top_k over the whole row."""
+    neg, pos = jax.lax.top_k(-jnp.asarray(d), k)
+    vals = np.asarray(-neg)
+    out = np.asarray(ids)[np.asarray(pos)]
+    return vals, np.where(np.isinf(vals), INVALID_ID, out)
+
+
+def _tile_ids(c, contiguous):
+    """A tile's id vector: a contiguous run, or a shuffled sparse one whose
+    last 37 columns are padding (-1, which mask_tile holds at +inf)."""
+    if contiguous:
+        return np.arange(c, dtype=np.int32) + 70_000
+    ids = np.random.default_rng(c).permutation(3 * c)[:c].astype(np.int32)
+    ids[-37:] = INVALID_ID
+    return ids
+
+
+@pytest.mark.parametrize("contiguous", [True, False], ids=["contig", "padded"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
+@pytest.mark.parametrize("k", [1, 10, 16])
+@pytest.mark.parametrize("q,c", [(8, 1024), (64, 2048), (256, 1024), (8, 8192)])
+def test_lane_bin_selection_equals_full_width_top_k(q, c, k, dtype, contiguous):
+    """The engaged path against lax.top_k: distances bit-equal, ids equal
+    wherever a row's k+1 smallest are distinct (all rows, on continuous
+    data)."""
+    assert lane_bin_depth(q, c, k) is not None
+    rng = np.random.default_rng([q, c, k])
+    d = rng.standard_normal((q, c)).astype(dtype)
+    ids = _tile_ids(c, contiguous)
+    d[:, ids < 0] = np.inf
+    got_d, got_i = jax.jit(smallest_k, static_argnums=2)(
+        jnp.asarray(d), jnp.asarray(ids), k)
+    want_d, want_i = _full_width(d, ids, k)
+    assert got_d.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+def test_lane_bin_rows_short_of_k_finite_values(rng):
+    """Rows with fewer than k finite values end in (+inf, INVALID_ID), an
+    all-NaN row returns what lax.top_k returns for it, and a row of equal
+    values returns k of them with distinct ids."""
+    q, c, k = 16, 1024, 10
+    d = rng.standard_normal((q, c)).astype(np.float32)
+    d[0, 3:] = np.inf  # 3 finite values
+    d[1, :] = np.inf  # none
+    d[2, :] = np.nan
+    d[3, :] = 2.5
+    ids = np.arange(c, dtype=np.int32)
+    got_d, got_i = smallest_k(jnp.asarray(d), jnp.asarray(ids), k)
+    got_d, got_i = np.asarray(got_d), np.asarray(got_i)
+    want_d, want_i = _full_width(d, ids, k)
+    np.testing.assert_array_equal(got_d, want_d)
+    np.testing.assert_array_equal(got_i[:2], want_i[:2])
+    np.testing.assert_array_equal(got_i[4:], want_i[4:])
+    assert np.isinf(got_d[0, 3:]).all() and (got_i[0, 3:] == INVALID_ID).all()
+    assert (got_i[1] == INVALID_ID).all() and np.isnan(got_d[2]).all()
+    assert (got_d[3] == 2.5).all() and len(set(got_i[3])) == k
+
+
+@pytest.mark.parametrize("extra", [0, 1], ids=["R-in-a-lane", "R+1-in-a-lane"])
+def test_lane_bin_planted_collision_is_flagged_and_exact(rng, extra):
+    """R (and R+1) of a row's k smallest in ONE lane: more than the bins
+    keep, so the certificate flags the row and the tile step's answer comes
+    from the full-width fallback, still exact. One group fewer is kept
+    whole and flags nothing."""
+    q, c, k = 64, 2048, 10
+    depth = lane_bin_depth(q, c, k)
+    d = rng.standard_normal((q, c)).astype(np.float32)
+    ids = np.arange(c, dtype=np.int32)
+    held = d.copy()
+    held[5, 7:7 + 128 * (depth - 1):128] = -50.0 - np.arange(depth - 1)
+    assert lane_bin_flagged_share(held, k) == (0.0, 0.0)
+    lane = 7 + 128 * np.arange(depth + 1 + extra)  # columns 7, 135, 263, ...
+    d[5, lane] = -50.0 - np.arange(len(lane))
+    share_rows, tile = lane_bin_flagged_share(d, k)
+    assert (share_rows, tile) == (1 / q, 1.0)
+    got_d, got_i = smallest_k(jnp.asarray(d), jnp.asarray(ids), k)
+    want_d, want_i = _full_width(d, ids, k)
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+@pytest.mark.parametrize(
+    "q,c,k,ids_ndim,depth",
+    [
+        # the three cells' per-tile calls
+        (4096, 8192, 10, 1, 5),
+        (1024, 8192, 10, 1, 5),
+        (64, 8192, 10, 1, 4),
+        # k = 1: each lane's minimum is enough, nothing can be flagged
+        (4096, 8192, 1, 1, 1),
+        (256, 2048, 16, 1, 5),
+        (1024, 8192, 32, 1, 7),
+        (8, 1024, 10, 1, 4),
+        # bypassed: 2-D ids (merges, the cascade, IVF)
+        (4096, 8192, 10, 2, None),
+        # bypassed: the stream schedule's carry || tile, and narrow tiles
+        (4096, 8192 + 10, 10, 1, None),
+        (4096, 896, 10, 1, None),
+        (64, 512, 10, 1, None),
+        # bypassed: k beyond what 8 slots a lane can certify
+        (1024, 8192, 100, 1, None),
+        (4096, 8192, 256, 1, None),
+        # 1024 columns are 8 groups: at most 7 slots a lane
+        (4096, 1024, 32, 1, 7),
+        (4096, 1024, 48, 1, None),
+        # a tile of a few rows: the expected share would let k past 128,
+        # the finish kernel's 128-lane accumulators do not
+        (1, 8192, 128, 1, 8),
+        (1, 8192, 129, 1, None),
+        (1, 8192, 152, 1, None),
+        (2, 2048, 128, 1, 8),
+        (2, 2048, 130, 1, None),
+        (8, 8192, 118, 1, 8),
+        (8, 8192, 119, 1, None),
+    ],
+)
+def test_lane_bin_engage_rule(q, c, k, ids_ndim, depth):
+    assert lane_bin_depth(q, c, k, ids_ndim) == depth
+
+
+@pytest.mark.parametrize("q,c,k", [(1, 8192, 128), (1, 8192, 129),
+                                   (2, 2048, 128), (2, 2048, 130)])
+def test_few_rows_and_k_at_the_accumulator_width(q, c, k):
+    """A one- or two-row tile engages up to k = 128, every lane of the
+    finish kernel's accumulators in use, and bypasses from 129: the exact k
+    smallest on both sides of the boundary."""
+    assert (lane_bin_depth(q, c, k) is not None) == (k <= 128)
+    d = np.random.default_rng([q, c, k]).standard_normal((q, c))
+    d = d.astype(np.float32)
+    ids = _tile_ids(c, contiguous=True)
+    got_d, got_i = smallest_k(jnp.asarray(d), jnp.asarray(ids), k)
+    want_d, want_i = _full_width(d, ids, k)
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+def test_query_knn_with_two_rows_and_k_past_the_accumulators(rng):
+    """The serving path at a shape the rule once engaged and the kernel
+    could not hold (2-row query tile, k = 130): answers as the full-width
+    path gives them."""
+    from mpi_knn_tpu import KNNConfig, build_index, query_knn
+
+    X = rng.standard_normal((2048, 16)).astype(np.float32)
+    Q = rng.standard_normal((2, 16)).astype(np.float32)
+    cfg = KNNConfig(k=130, corpus_tile=2048, query_tile=2, backend="serial")
+    res = query_knn(Q, build_index(X, cfg), cfg)
+    d2 = ((Q[:, None, :].astype(np.float64) - X[None]) ** 2).sum(-1)
+    want = np.argsort(d2, axis=1)[:, :130]
+    np.testing.assert_array_equal(np.asarray(res.ids), want)
+
+
+def test_lane_bin_import_starts_once_and_lands():
+    """The early import of the kernels: one thread a process however often
+    the chunk programs' owners ask, and the module is there once it ends."""
+    import sys
+
+    from mpi_knn_tpu.ops import topk
+
+    topk.start_lane_bin_import()
+    thread = topk._lane_bin_import
+    topk.start_lane_bin_import()
+    assert topk._lane_bin_import is thread
+    thread.join(timeout=60)
+    assert not thread.is_alive() and "mpi_knn_tpu.ops.lane_bin" in sys.modules
+
+
+def test_bypassed_calls_keep_the_full_width_path(rng):
+    """2-D ids and k beyond the rule give the same values as ever."""
+    d = rng.standard_normal((16, 2048)).astype(np.float32)
+    ids = np.arange(2048, dtype=np.int32)
+    ids2 = np.broadcast_to(ids, d.shape)
+    assert lane_bin_flagged_share(d, 300) is None
+    for k, i in [(10, ids2), (300, ids)]:
+        got_d, got_i = smallest_k(jnp.asarray(d), jnp.asarray(i), k)
+        want_d, want_i = _np_smallest_k(d, ids2, k)
+        np.testing.assert_array_equal(np.asarray(got_d), want_d)
+        np.testing.assert_array_equal(np.asarray(got_i), want_i)
