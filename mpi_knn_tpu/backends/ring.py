@@ -71,6 +71,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.obs import metrics as obs_metrics
+from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.distance import sq_norms
 from mpi_knn_tpu.ops.quant import (
     dequantize_rows,
@@ -88,11 +90,14 @@ from mpi_knn_tpu.ops.pallas_ring import (
     fused_round_dma,
 )
 from mpi_knn_tpu.parallel.mesh import make_ring_mesh
-from mpi_knn_tpu.parallel.partition import (
-    make_global_ids,
-    pad_rows_any,
-    pad_to_multiple,
-)
+from mpi_knn_tpu.parallel.partition import pad_rows_any, pad_to_multiple
+from mpi_knn_tpu.types import INVALID_ID
+
+
+# HLO scopes of the ring's own operations, stable like ``knn.dist``: the
+# trace's collective-permutes and a round's compute carry these names
+PERMUTE_SCOPE = "knn.ring/permute"
+ROUND_SCOPE = "knn.ring/round"
 
 
 def bidir_rounds(num_dev: int) -> tuple[int, int]:
@@ -270,10 +275,36 @@ def _ring_knn_local(
     def _rot(x, p):
         """ppermute one traveler part; scale slots are None when the
         transfer is not quantized (None = empty pytree, nothing moves)."""
-        return None if x is None else jax.lax.ppermute(x, axis, p)
+        if x is None:
+            return None
+        with jax.named_scope(PERMUTE_SCOPE):
+            return jax.lax.ppermute(x, axis, p)
+
+    def tiled(x):
+        """(b, ...) -> (b / c_tile, c_tile, ...): a traveller as the stack
+        of tiles that ``compute`` walks. The XLA ring rotates the stack, not
+        the rows: the v5e compiler keeps a (b, d) scan carry rows-minor and
+        then pays, in EVERY round, a copy of the whole block into the tile
+        stack's layout — at 1 048 576 x 784 rows a chip two more blocks alive
+        (13.2 GiB of temporaries, which does not fit) and a fifth of a
+        second; as a stack the block is laid out once, ahead of the scan
+        (6.9 GiB; PERF.md §6, PR 28). The fused kernels take rows."""
+        if x is None or fused:
+            return x
+        return x.reshape(b // c_tile, c_tile, *x.shape[1:])
+
+    def rows(x):
+        """``tiled``'s inverse, for the travellers a single round returns."""
+        if x is None or fused:
+            return x
+        return x.reshape(b, *x.shape[2:])
 
     q_tiles = queries.reshape(q_local // q_tile, q_tile, dim)
     qid_tiles = query_ids.reshape(q_local // q_tile, q_tile)
+    block, block_ids, block_scale = map(
+        tiled, (block, block_ids, block_scale))
+    block_bwd, block_bwd_ids, block_bwd_scale = map(
+        tiled, (block_bwd, block_bwd_ids, block_bwd_scale))
 
     if carry_in is not None:
         carry_d = carry_in[0].reshape(q_local // q_tile, q_tile, cfg.k)
@@ -290,6 +321,7 @@ def _ring_knn_local(
         carry_d = jax.lax.pcast(carry_d, vary, to="varying")
         carry_i = jax.lax.pcast(carry_i, vary, to="varying")
 
+    @jax.named_scope(ROUND_SCOPE)
     def compute(blk, blk_ids, blk_scl, cd, ci):
         """Tiled (q_local × b) step: all query tiles against all block tiles."""
         if fused:
@@ -318,9 +350,8 @@ def _ring_knn_local(
             # below are recomputed from the dequantized rows, so distances
             # are exact w.r.t. the quantized values
             blk = dequantize_rows(blk, blk_scl, "int8", dim)
-        blk = blk.astype(queries.dtype)  # no-op unless ring_transfer_dtype
-        blk_tiles = blk.reshape(b // c_tile, c_tile, dim)
-        blk_id_tiles = blk_ids.reshape(b // c_tile, c_tile)
+        # the travellers are tile stacks already (``tiled`` above)
+        blk_tiles = blk.astype(queries.dtype)  # no-op unless ring_transfer_dtype
         blk_sq = (
             jax.vmap(sq_norms)(blk_tiles)
             if cfg.metric == "l2"
@@ -334,7 +365,7 @@ def _ring_knn_local(
             # (same code path as serial); the cross-ROUND merge is inherently
             # streaming — each rotation step merges into the carry
             return merge_tiles_into_carry(
-                q_x, q_ids, q_sq, blk_tiles, blk_id_tiles, blk_sq,
+                q_x, q_ids, q_sq, blk_tiles, blk_ids, blk_sq,
                 cd0, ci0, cfg,
             )
 
@@ -368,9 +399,9 @@ def _ring_knn_local(
             # permute and compute both depend only on the incoming block —
             # XLA overlaps the ICI transfer with the distance matmul (the
             # quantized scale vector rides the same schedule)
-            nxt = jax.lax.ppermute(blk, axis, perm)
+            nxt = _rot(blk, perm)
             nscl = _rot(scl, perm)
-            nxt_ids = jax.lax.ppermute(blk_ids, axis, perm)
+            nxt_ids = _rot(blk_ids, perm)
             cd, ci = compute(blk, blk_ids, scl, cd, ci)
         else:
             # blocking parity: the collective is sequenced *after* the compute
@@ -387,9 +418,9 @@ def _ring_knn_local(
             blk, scl, blk_ids, cd, ci = jax.lax.optimization_barrier(
                 (blk, scl, blk_ids, cd, ci)
             )
-            nxt = jax.lax.ppermute(blk, axis, perm)
+            nxt = _rot(blk, perm)
             nscl = _rot(scl, perm)
-            nxt_ids = jax.lax.ppermute(blk_ids, axis, perm)
+            nxt_ids = _rot(blk_ids, perm)
         return (nxt, nscl, nxt_ids, cd, ci), None
 
     rounds, bwd_limit = bidir_rounds(num_dev)
@@ -422,12 +453,12 @@ def _ring_knn_local(
         if overlap:
             # all permutes depend only on the incoming blocks; the two
             # directions ride the two halves of each full-duplex ICI link
-            nfb = jax.lax.ppermute(fblk, axis, perm)
+            nfb = _rot(fblk, perm)
             nfs = _rot(fscl, perm)
-            nfi = jax.lax.ppermute(fids, axis, perm)
-            nbb = jax.lax.ppermute(bblk, axis, perm_bwd)
+            nfi = _rot(fids, perm)
+            nbb = _rot(bblk, perm_bwd)
             nbs = _rot(bscl, perm_bwd)
-            nbi = jax.lax.ppermute(bids, axis, perm_bwd)
+            nbi = _rot(bids, perm_bwd)
             cd, ci = merge(cd, ci)
         else:
             cd, ci = merge(cd, ci)
@@ -436,12 +467,12 @@ def _ring_knn_local(
                     (fblk, fscl, fids, bblk, bscl, bids, cd, ci)
                 )
             )
-            nfb = jax.lax.ppermute(fblk, axis, perm)
+            nfb = _rot(fblk, perm)
             nfs = _rot(fscl, perm)
-            nfi = jax.lax.ppermute(fids, axis, perm)
-            nbb = jax.lax.ppermute(bblk, axis, perm_bwd)
+            nfi = _rot(fids, perm)
+            nbb = _rot(bblk, perm_bwd)
             nbs = _rot(bscl, perm_bwd)
-            nbi = jax.lax.ppermute(bids, axis, perm_bwd)
+            nbi = _rot(bids, perm_bwd)
         return (nfb, nfs, nfi, nbb, nbs, nbi, cd, ci), None
 
     if fused and cfg.ring_fused_rotation == "grid":
@@ -500,15 +531,17 @@ def _ring_knn_local(
                          block_bwd_scale, block_bwd_ids,
                          carry_d, carry_i)
                     )
-                nfb = jax.lax.ppermute(block, axis, perm)
+                nfb = _rot(block, perm)
                 nfs = _rot(block_scale, perm)
-                nfi = jax.lax.ppermute(block_ids, axis, perm)
-                nbb = jax.lax.ppermute(block_bwd, axis, perm_bwd)
+                nfi = _rot(block_ids, perm)
+                nbb = _rot(block_bwd, perm_bwd)
                 nbs = _rot(block_bwd_scale, perm_bwd)
-                nbi = jax.lax.ppermute(block_bwd_ids, axis, perm_bwd)
+                nbi = _rot(block_bwd_ids, perm_bwd)
             else:
                 nfb, nfs, nfi = block, block_scale, block_ids
                 nbb, nbs, nbi = block_bwd, block_bwd_scale, block_bwd_ids
+            nfb, nfs, nfi, nbb, nbs, nbi = map(
+                rows, (nfb, nfs, nfi, nbb, nbs, nbi))
             out_d = carry_d.reshape(q_local, cfg.k)
             out_i = carry_i.reshape(q_local, cfg.k)
             if quantized:
@@ -526,6 +559,7 @@ def _ring_knn_local(
                 block, block_ids, block_scale, carry_d, carry_i
             )
             nxt, nscl, nxt_ids = block, block_scale, block_ids
+        nxt, nscl, nxt_ids = map(rows, (nxt, nscl, nxt_ids))
         out_d = carry_d.reshape(q_local, cfg.k)
         out_i = carry_i.reshape(q_local, cfg.k)
         if quantized:
@@ -759,6 +793,16 @@ def ring_serve_sharded(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("sharding", "m", "c_pad"))
+def _global_ids_on(sharding: NamedSharding, m: int, c_pad: int) -> jax.Array:
+    """The corpus's global id row (``make_global_ids``' rule), made where
+    its shards live: at 4 M rows the host array was 16 MB to build and send
+    on every call of a sliced job."""
+    row = jnp.arange(c_pad, dtype=jnp.int32)
+    return jax.lax.with_sharding_constraint(
+        jnp.where(row < m, row, INVALID_ID), sharding)
+
+
 def all_knn_ring(
     corpus: np.ndarray,
     queries: np.ndarray,
@@ -789,40 +833,61 @@ def all_knn_ring(
     # small problems so padding never exceeds P·tile rows; the per-tile
     # memory cap (cfg.max_tile_elems) is applied inside ring_tiles.
     q_tile, c_tile, q_pad, c_pad = ring_tiles(cfg, m, nq, dp, ring_n)
+    rounds = bidir_rounds(ring_n)[0] if cfg.ring_schedule == "bidir" else ring_n
+    wire_bytes = ring_wire_bytes_per_batch(cfg, c_pad, dim, ring_n)
 
-    corpus_p = pad_rows_any(corpus, c_pad, dtype=dtype)
-    corpus_scale = None
-    if cfg.ring_transfer_dtype == "int8":
-        # quantize ONCE at shard time (the EQuARX recipe): the rotation
-        # program receives (codes, scales) as inputs and only ever
-        # dequantizes — the quantization reduce never enters the compiled
-        # ring, so the overlap schedule's permutes stay compute-independent
-        corpus_p, corpus_scale = quantize_ring_block(corpus_p)
-    corpus_ids = jnp.asarray(make_global_ids(m, c_pad))
-    queries_p = pad_rows_any(queries, q_pad, dtype=dtype)
-    qids_p = pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32)
+    # entry until the last dispatch has returned, inside knn:api.all_knn
+    with obs_spans.span(
+        "call", cat="ring", devices=dp * ring_n, rounds=rounds,
+        rows_per_block=c_pad // ring_n, wire_bytes=wire_bytes,
+    ):
+        # a device corpus that already lies as the ring wants it passes
+        # through all of this untouched: no pad, no cast, and device_put
+        # onto the sharding it has returns the array it was given
+        corpus_p = pad_rows_any(corpus, c_pad, dtype=dtype)
+        corpus_scale = None
+        if cfg.ring_transfer_dtype == "int8":
+            # quantize ONCE at shard time (the EQuARX recipe): the rotation
+            # program receives (codes, scales) as inputs and only ever
+            # dequantizes — the quantization reduce never enters the
+            # compiled ring, so the overlap schedule's permutes stay
+            # compute-independent
+            corpus_p, corpus_scale = quantize_ring_block(corpus_p)
+        queries_p = pad_rows_any(queries, q_pad, dtype=dtype)
+        qids_p = pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32)
 
-    c_sharding = NamedSharding(mesh, P(axis))
-    q_sharding = NamedSharding(mesh, _query_spec(q_axis, axis))
-    corpus_p = jax.device_put(corpus_p, c_sharding)
-    corpus_ids = jax.device_put(corpus_ids, c_sharding)
-    if corpus_scale is not None:
-        corpus_scale = jax.device_put(corpus_scale, c_sharding)
-    queries_p = jax.device_put(queries_p, q_sharding)
-    qids_p = jax.device_put(qids_p, q_sharding)
+        c_sharding = NamedSharding(mesh, P(axis))
+        q_sharding = NamedSharding(mesh, _query_spec(q_axis, axis))
+        corpus_p = jax.device_put(corpus_p, c_sharding)
+        corpus_ids = _global_ids_on(c_sharding, m, c_pad)
+        if corpus_scale is not None:
+            corpus_scale = jax.device_put(corpus_scale, c_sharding)
+        queries_p = jax.device_put(queries_p, q_sharding)
+        qids_p = jax.device_put(qids_p, q_sharding)
 
-    best_d, best_i = _ring_knn_sharded(
-        queries_p,
-        qids_p,
-        corpus_p,
-        corpus_ids,
-        cfg,
-        overlap,
-        mesh,
-        axis,
-        q_tile,
-        c_tile,
-        q_axis=q_axis,
-        corpus_scale=corpus_scale,
-    )
-    return best_d[:nq], best_i[:nq]
+        best_d, best_i = _ring_knn_sharded(
+            queries_p,
+            qids_p,
+            corpus_p,
+            corpus_ids,
+            cfg,
+            overlap,
+            mesh,
+            axis,
+            q_tile,
+            c_tile,
+            q_axis=q_axis,
+            corpus_scale=corpus_scale,
+        )
+        # static per layout, added at dispatch: no device read
+        reg = obs_metrics.get_registry()
+        reg.counter("ring_calls_total", help="all_knn_ring calls").inc()
+        reg.counter(
+            "ring_rounds_total", help="rotation rounds dispatched"
+        ).inc(rounds)
+        reg.counter(
+            "ring_wire_bytes_total",
+            help="bytes all devices send over the interconnect "
+            "(ring_wire_bytes_per_batch a call)",
+        ).inc(wire_bytes)
+        return best_d[:nq], best_i[:nq]

@@ -12,7 +12,15 @@ public ``tsl/profiler/protobuf/xplane.proto``)::
                                .event_metadata = 4 (map<int64, XEventMetadata>)
     XLine.name = 2, .timestamp_ns = 3, .events = 4
     XEvent.metadata_id = 1, .offset_ps = 2, .duration_ps = 3
-    XEventMetadata.id = 1, .name = 2, .display_name = 3
+    XEventMetadata.id = 1, .name = 2, .display_name = 3, .stats = 5
+    XPlane.stat_metadata = 5 (map<int64, XStatMetadata>)
+    XStatMetadata.id = 1, .name = 2
+    XStat.metadata_id = 1, .str_value = 5, .ref_value = 7
+
+An event whose metadata carries the ``tf_op`` stat — on a TPU's device
+planes the HLO ``op_name``, which holds the program's ``knn.*`` scopes
+(``jax.named_scope``) — gets it as ``scope``; the event's name there is
+the bare HLO line, which has no scope in it.
 
 Unknown fields (every other number the real schema carries) are skipped
 by wire type, exactly as a generated proto reader would. Truncated or
@@ -96,9 +104,26 @@ def _fields(buf: memoryview):
         yield fno, wt, v
 
 
+SCOPE_STAT = "tf_op"  # the stat that holds an operation's HLO op_name
+
+
+def _stat(buf: memoryview):
+    """(stat metadata id, its string or the id it refers to) of one XStat."""
+    sid, value = None, None
+    for sf, _, sv in _fields(buf):
+        if sf == 1:
+            sid = sv
+        elif sf == 5:
+            value = bytes(sv).decode("utf-8", "replace")
+        elif sf == 7:
+            value = sv
+    return sid, value
+
+
 def parse_xplane_bytes(raw: bytes) -> list[dict]:
     """Parse one serialized XSpace; returns
-    ``[{plane, line, name, start_ps, dur_ps}]`` for every event."""
+    ``[{plane, line, name, start_ps, dur_ps}]`` for every event, with
+    ``scope`` where the event's metadata has the ``tf_op`` stat."""
     out = []
     for fno, _, plane_buf in _fields(memoryview(raw)):
         if fno != 1:  # XSpace.planes
@@ -106,13 +131,15 @@ def parse_xplane_bytes(raw: bytes) -> list[dict]:
         plane_name = ""
         lines = []
         meta = {}
+        meta_stats = {}  # event metadata id -> [(stat metadata id, value)]
+        stat_names = {}  # stat metadata id -> name
         for pf, _, pv in _fields(plane_buf):
             if pf == 2:
                 plane_name = bytes(pv).decode("utf-8", "replace")
             elif pf == 3:
                 lines.append(pv)
             elif pf == 4:  # map entry: key=1 varint, value=2 XEventMetadata
-                mid, mname = None, ""
+                mid, mname, stats = None, "", []
                 for mf, _, mv in _fields(pv):
                     if mf == 1:
                         mid = mv
@@ -122,8 +149,28 @@ def parse_xplane_bytes(raw: bytes) -> list[dict]:
                                 mname = bytes(ev).decode("utf-8", "replace")
                             elif ef == 3:  # display_name wins if present
                                 mname = bytes(ev).decode("utf-8", "replace")
+                            elif ef == 5:
+                                stats.append(_stat(ev))
                 if mid is not None:
                     meta[mid] = mname
+                    meta_stats[mid] = stats
+            elif pf == 5:  # map entry: key=1 varint, value=2 XStatMetadata
+                for mf, _, mv in _fields(pv):
+                    if mf == 2:
+                        sid, sname = None, ""
+                        for ef, _, ev in _fields(mv):
+                            if ef == 1:
+                                sid = ev
+                            elif ef == 2:
+                                sname = bytes(ev).decode("utf-8", "replace")
+                        stat_names[sid] = sname
+        scopes = {}
+        for mid, stats in meta_stats.items():
+            for sid, value in stats:
+                if stat_names.get(sid) == SCOPE_STAT:
+                    # a string, or a reference to another stat's name
+                    scopes[mid] = (value if isinstance(value, str)
+                                   else stat_names.get(value, ""))
         for line_buf in lines:
             line_name = ""
             ts_ns = 0
@@ -155,6 +202,8 @@ def parse_xplane_bytes(raw: bytes) -> list[dict]:
                         "dur_ps": dur_ps,
                     }
                 )
+                if scopes.get(mid):
+                    out[-1]["scope"] = scopes[mid]
     return out
 
 

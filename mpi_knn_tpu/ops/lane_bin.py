@@ -54,6 +54,24 @@ def _row_block(q: int, most: int) -> int:
                 if b <= most and q % b == 0)
 
 
+def _out(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel output's type: under a ``shard_map`` that checks varying
+    axes (the ring's), it varies over every mesh axis an operand varies
+    over; elsewhere the set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _plain(x: jax.Array) -> jax.Array:
+    """``x`` as a value computed in the kernel. Under a checked ``shard_map``
+    jax 0.9.0 leaves the operands' varying axes on a kernel's refs
+    (``AbstractRef.update_vma`` does nothing) while whatever the body
+    computes has none, so a loop whose carry starts as a ref's contents
+    would change type in its first step. A bitcast to the same type moves
+    nothing and gives the read the type of the loop's own values."""
+    return jax.lax.bitcast_convert_type(x, x.dtype)
+
+
 def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
     """One (rows, cols) block of the tile: insert its cols/128 column groups
     into the per-(row, lane) sorted lists of ``depth`` that the two output
@@ -101,7 +119,8 @@ def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
 
         kept = lax.fori_loop(
             0, groups // unroll, insert,
-            (*(kd_ref[r, sl] for sl in slots), *(ki_ref[r, sl] for sl in slots)),
+            (*(_plain(kd_ref[r, sl]) for sl in slots),
+             *(_plain(ki_ref[r, sl]) for sl in slots)),
         )
         for j, sl in enumerate(slots):
             kd_ref[r, sl] = kept[j]
@@ -133,6 +152,7 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int):
         w for w in range(min(c, _BLOCK_COLS), 0, -_LANES) if c % w == 0
     )
     out_block = pl.BlockSpec((rows, depth * _LANES), lambda i, j: (i, 0))
+    ids = ids.astype(jnp.int32)[None, :]
     return pl.pallas_call(
         functools.partial(_lane_bin_kernel, depth=depth),
         grid=(q // rows, c // cols),
@@ -142,14 +162,14 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int):
         ],
         out_specs=[out_block, out_block],
         out_shape=[
-            jax.ShapeDtypeStruct((q, depth * _LANES), dists.dtype),
-            jax.ShapeDtypeStruct((q, depth * _LANES), jnp.int32),
+            _out((q, depth * _LANES), dists.dtype, ids, dists),
+            _out((q, depth * _LANES), jnp.int32, ids, dists),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(),
-    )(ids.astype(jnp.int32)[None, :], dists)
+    )(ids, dists)
 
 
 def _lane_bin_finish_kernel(cd_ref, ci_ref, od_ref, oi_ref, flag_ref,
@@ -228,9 +248,9 @@ def lane_bin_finish(cand_d: jax.Array, cand_i: jax.Array, k: int):
         in_specs=[cand, cand],
         out_specs=[out, out, pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((q, k), cand_d.dtype),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
-            jax.ShapeDtypeStruct((q, 1), jnp.int32),
+            _out((q, k), cand_d.dtype, cand_d, cand_i),
+            _out((q, k), jnp.int32, cand_d, cand_i),
+            _out((q, 1), jnp.int32, cand_d, cand_i),
         ],
         scratch_shapes=[
             pltpu.VMEM((rows, w), cand_d.dtype),

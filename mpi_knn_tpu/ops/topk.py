@@ -220,6 +220,14 @@ def _lane_bin_smallest_k(dists: jax.Array, ids: jax.Array, k: int, depth: int):
     """Exact k smallest of a wide tile without sorting it: lane-bin
     candidates, narrow finish, certificate; the full-width ``lax.top_k``
     runs for this tile step only if some row is flagged."""
+    if jax.typeof(dists).vma and jax.default_backend() != "tpu":
+        # off the TPU the kernels are interpreted, and jax 0.9.0 cannot
+        # interpret a kernel on device-varying operands under a shard_map
+        # that checks varying axes (the XLA ring's): its interpreter binds
+        # the body's constants unvarying against them. Same values, the
+        # full-width way; on the chip Mosaic compiles the kernels there
+        # (tests/test_pallas.py compiles the ring's program for the v5e).
+        return _top_k_with_ids(dists, ids, k)
     vals, out_ids, flagged = _lane_bin_select(dists, ids, k, depth)
     with jax.named_scope("finish"):
         any_flagged = jnp.any(flagged)

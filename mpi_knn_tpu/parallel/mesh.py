@@ -5,8 +5,11 @@ by hand from point-to-point sends (``/root/reference/mpi-knn-parallel_blocking.c
 124-147``), with the partition size coming from argv and the ring size from
 MPI — two sources of truth that silently corrupt when they disagree
 (SURVEY.md §5 Q6). Here the mesh is the single source of truth: a 1-D
-``jax.sharding.Mesh`` whose axis order follows the physical device order, so
-``lax.ppermute`` steps ride neighboring ICI links. Multi-host runs build the
+``jax.sharding.Mesh`` over ``jax.devices()`` in list order. On a 2 x 2 v5e
+host that order (coordinates (0,0), (1,0), (0,1), (1,1)) is not a walk over
+neighbouring coordinates, and it does not have to be: one hop of a 0.82 GB
+block a chip took 21.3 ms (38.8 GB/s a chip) in list order and in both
+coordinate orders alike (PERF.md §6, PR 28). Multi-host runs build the
 same mesh over ``jax.devices()`` after ``jax.distributed.initialize`` (see
 mpi_knn_tpu.parallel.distributed).
 """

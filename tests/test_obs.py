@@ -440,6 +440,38 @@ def test_xplane_display_name_wins_and_unknown_metadata_is_labeled():
     assert evs[1]["name"] == "meta:42"  # unknown id labeled, not dropped
 
 
+def test_xplane_scope_is_the_tf_op_stat_of_the_events_metadata():
+    """A TPU's device planes name an operation by its bare HLO line; the
+    ``op_name`` with the program's ``knn.*`` scopes is the ``tf_op`` stat of
+    the event's metadata, a string or a reference to a stat metadata's
+    name. An event without it keeps the five keys it always had."""
+    op = "jit(f)/shard_map/while/body/knn.ring/permute/ppermute:"
+    by_ref = "jit(f)/knn.dist/dot_general:"
+
+    def stat_meta(sid, name):  # map<id, XStatMetadata>
+        return _ld(5, _vf(1, sid) + _ld(2, _vf(1, sid) + _ld(2, name.encode())))
+
+    def meta_with(mid, name, stats):
+        xmeta = _vf(1, mid) + _ld(2, name.encode()) + stats
+        return _ld(4, _vf(1, mid) + _ld(2, xmeta))
+
+    raw = _plane(
+        "/device:TPU:0",
+        stat_meta(7, "tf_op") + stat_meta(8, "hlo_category")
+        + stat_meta(9, by_ref)
+        + meta_with(1, "%collective-permute-start = ...",
+                    _ld(5, _vf(1, 8) + _ld(5, b"data formatting"))
+                    + _ld(5, _vf(1, 7) + _ld(5, op.encode())))
+        + meta_with(2, "%fusion.26 = ...", _ld(5, _vf(1, 7) + _vf(7, 9)))
+        + _meta(3, "%copy.1 = ...")
+        + _line("XLA Ops", 0,
+                _event(1, 0, 5) + _event(2, 5, 7) + _event(3, 12, 1)),
+    )
+    evs = parse_xplane_bytes(raw)
+    assert [e.get("scope") for e in evs] == [op, by_ref, None]
+    assert set(evs[2]) == {"plane", "line", "name", "start_ps", "dur_ps"}
+
+
 def test_xplane_unknown_fields_skipped_by_wire_type():
     """Fields the real schema carries beyond our subset must be skipped
     exactly as a generated proto reader would — varint, fixed64, fixed32

@@ -276,3 +276,60 @@ def test_kernel_tiles_compile_under_mosaic_for_the_v5e(dim, variant):
         )
         assert "tpu_custom_call" in lowered.as_text()
         lowered.compile()  # raises with Mosaic's message if it does not fit
+
+
+@pytest.mark.parametrize("backend", ["ring-overlap", "ring"])
+def test_ring_program_with_lane_bin_kernels_compiles_for_four_v5e(
+        backend, monkeypatch):
+    """The deployment ``mnist8m-784-l2-ring4`` at its tile shape (4096 x
+    8192 x 784, ``high``), two tiles a chip: the lane-bin kernels are typed
+    for the XLA ring's ``shard_map``, whose varying-axes check stays on
+    (the CPU mesh cannot run them there: ``ops/topk.py``), Mosaic compiles
+    them inside the ring's program, and the travelling block is laid out
+    once, ahead of the rounds, not copied in each of them (PERF.md §6,
+    PR 28: at 1 048 576 rows a chip that copy did not fit)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import ring
+    from tests.conftest import TPU_MODE
+
+    if TPU_MODE:
+        pytest.skip("needs four chips; chip_smoke.py runs the ring there")
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 — no compile-only TPU client here
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    # the kernels and the selection ask the backend whether to interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    cfg = KNNConfig(
+        k=10, backend=backend, num_devices=4, matmul_precision="high",
+        query_tile=4096, corpus_tile=8192, merge_schedule="twolevel")
+    mesh = Mesh(np.asarray(devices), (cfg.mesh_axis,))
+    by_rows = NamedSharding(mesh, P(cfg.mesh_axis))
+    tiles, dim = 2, 784
+    m, nq = 4 * tiles * cfg.corpus_tile, 4 * cfg.query_tile
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=by_rows)
+
+    with jax.enable_x64(False):
+        lowered = ring._ring_knn_sharded.lower(
+            arg((nq, dim), jnp.float32), arg((nq,), jnp.int32),
+            arg((m, dim), jnp.float32), arg((m,), jnp.int32),
+            cfg, backend == "ring-overlap", mesh, cfg.mesh_axis,
+            cfg.query_tile, cfg.corpus_tile)
+        text = lowered.as_text()
+        assert "tpu_custom_call" in text  # bins and finish, under shard_map
+        assert ring.PERMUTE_SCOPE in lowered.as_text(debug_info=True)
+        hlo = lowered.compile().as_text()
+    # what travels is the stack of tiles, so no round lays the block out anew
+    permutes = [ln for ln in hlo.splitlines()
+                if " collective-permute-start(" in ln and "f32[" in ln]
+    assert permutes and all(
+        f"f32[{tiles},{cfg.corpus_tile},{dim}]" in ln for ln in permutes)
