@@ -207,17 +207,28 @@ def compute_phases(args, platform, out, record) -> None:
     Xc = Xd - jnp.mean(Xd, axis=0)
     flagged = lane_bin_flagged_share(
         pairwise_dist(Xc[:cfg.query_tile], Xc[-cfg.corpus_tile:]), K)
+    # the one-pass rule's data test on THIS device: centred whole-number
+    # rows are bf16 numbers, the same rows + 0.25 are not (a test by
+    # rounding read True for both inside a TPU fusion, which tier-1 on the
+    # CPU cannot see: PERF.md §6, PR 29)
+    from mpi_knn_tpu.ops.distance import center_corpus
+
+    W = jnp.asarray(np.random.default_rng(2).integers(
+        0, 256, (4096, X.shape[1])).astype(np.float32))
+    rule_facts = [bool(center_corpus(w)[2]) for w in (W, W + 0.25)]
     ok = (
         s_ids.shape == (m, K)
         and np.isfinite(s_dists).all()
         and recall >= RECALL_GATE
+        and rule_facts == [True, False]
     )
     record(
         "allknn", ok, t0,
         f"data={source} shape={list(X.shape)} k={K} first_call_s="
         f"{compile_s:.2f} warm_call_s={warm_s:.3f} recall@{K}={recall:.4f} "
         f"compile_cache_entries={entries}->{cache_entries()} "
-        f"select_flagged_rows_and_tile={flagged}",
+        f"select_flagged_rows_and_tile={flagged} "
+        f"onepass_facts_whole_frac={rule_facts}",
         first_call_s=round(compile_s, 3), warm_call_s=round(warm_s, 4),
         recall=round(float(recall), 5),
     )

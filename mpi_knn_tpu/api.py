@@ -84,21 +84,24 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
             # a *valid* candidate id, so self-exclusion is a no-op
             q_ids = np.full(q_arr.shape[0], -1, dtype=np.int32)
 
+    fact = steps = None
     if cfg.center and cfg.metric == "l2":
         from mpi_knn_tpu.ops.distance import center_for_l2
 
-        corpus, q_arr = center_for_l2(corpus, q_arr, all_pairs=queries is None)
+        corpus, q_arr, fact, _ = center_for_l2(
+            corpus, q_arr, all_pairs=queries is None)
 
     backend = resolve_backend(cfg, mesh)
     if backend == "serial":
         from mpi_knn_tpu.backends.serial import all_knn_serial
 
-        d, i = all_knn_serial(corpus, q_arr, q_ids, cfg)
+        d, i, steps = all_knn_serial(corpus, q_arr, q_ids, cfg, fact)
     elif backend in ("ring", "ring-overlap"):
         from mpi_knn_tpu.backends.ring import all_knn_ring
 
-        d, i = all_knn_ring(
-            corpus, q_arr, q_ids, cfg, mesh=mesh, overlap=(backend == "ring-overlap")
+        d, i, steps = all_knn_ring(
+            corpus, q_arr, q_ids, cfg, mesh=mesh,
+            overlap=(backend == "ring-overlap"), fact=fact,
         )
     elif backend == "pallas":
         from mpi_knn_tpu.backends.pallas_backend import all_knn_pallas
@@ -106,7 +109,7 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
         d, i = all_knn_pallas(corpus, q_arr, q_ids, cfg)
     else:
         raise ValueError(f"unknown backend {backend!r}")
-    return KNNResult(dists=d, ids=i)
+    return KNNResult(dists=d, ids=i, dist_steps=steps)
 
 
 def build_index(corpus, config: Optional[KNNConfig] = None, mesh=None,

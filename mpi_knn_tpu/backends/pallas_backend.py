@@ -263,7 +263,7 @@ def all_knn_pallas(
         if bool(jax.device_get(any_zero)):
             from mpi_knn_tpu.backends.serial import all_knn_serial
 
-            return all_knn_serial(corpus, queries, query_ids, cfg)
+            return all_knn_serial(corpus, queries, query_ids, cfg)[:2]
         # normalize on device (jnp), once when queries IS corpus (the
         # all-pairs reference workload) — no host round-trip of the corpus
         corpus = _l2_normalize(corpus)

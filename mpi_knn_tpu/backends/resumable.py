@@ -50,10 +50,20 @@ def all_knn_resumable(
     all_pairs = queries is corpus or (
         queries.shape == corpus.shape and np.shares_memory(queries, corpus)
     )
+    onepass = None
     if cfg.center and cfg.metric == "l2":
-        from mpi_knn_tpu.ops.distance import center_for_l2
+        from mpi_knn_tpu.ops.distance import (
+            center_for_l2,
+            offset_is_whole,
+            onepass_fact,
+        )
 
-        corpus, queries = center_for_l2(corpus, queries, all_pairs)
+        corpus, queries, fact, mu = center_for_l2(corpus, queries, all_pairs)
+        onepass = onepass_fact(cfg, fact)
+        if offset_is_whole(mu):
+            # a whole-number corpus is centred by its ROUNDED mean; a
+            # carry saved under the plain mean differs by fp noise
+            fp += ":ctr-whole"
 
     nq = queries.shape[0]
     q_tile, c_tile = effective_tiles(cfg, corpus.shape[0], nq)
@@ -80,7 +90,8 @@ def all_knn_resumable(
 
     for t0 in range(start_tile, tiles, save_every):
         t1 = min(t0 + save_every, tiles)
-        carry_d, carry_i = knn_chunk_update(
+        # with the fact a third output counts the one-pass query tiles
+        carry_d, carry_i, *_ = knn_chunk_update(
             q_tiles,
             qid_tiles,
             corpus_tiles[t0:t1],
@@ -88,6 +99,7 @@ def all_knn_resumable(
             carry_d,
             carry_i,
             cfg,
+            onepass,
         )
         if checkpoint_dir is not None:
             carry_d.block_until_ready()

@@ -73,7 +73,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
-from mpi_knn_tpu.ops.distance import sq_norms
+from mpi_knn_tpu.ops.distance import bf16_exact, onepass_fact, sq_norms
 from mpi_knn_tpu.ops.quant import (
     dequantize_rows,
     quantize_rows,
@@ -82,7 +82,9 @@ from mpi_knn_tpu.ops.quant import (
 from mpi_knn_tpu.ops.topk import init_topk
 from mpi_knn_tpu.backends.serial import (
     cap_corpus_tile,
+    dist_steps,
     merge_tiles_into_carry,
+    onepass_rule,
 )
 from mpi_knn_tpu.ops.pallas_ring import (
     fused_block_merge,
@@ -189,6 +191,10 @@ def _ring_knn_local(
     merge_bwd: bool = False,  # bidir single-round only: merge the backward
     # traveler too (False on the degenerate rounds — r=0 and, for even P,
     # the antipodal round)
+    onepass=None,  # whole rotations of the XLA float ring only: the corpus
+    # side of the one-pass rule (backends.serial.masked_dist_tile), one
+    # replicated bool scalar; adds a third output, this device's tile steps
+    # by the branch they took (backends.serial.dist_steps), shape (1, 2)
 ):
     """Per-device body under shard_map: rotate corpus blocks around the ring,
     merging each into the local top-k carry.
@@ -301,6 +307,10 @@ def _ring_knn_local(
 
     q_tiles = queries.reshape(q_local // q_tile, q_tile, dim)
     qid_tiles = query_ids.reshape(q_local // q_tile, q_tile)
+    # each query tile's verdict holds for every round: the travelling
+    # blocks are parts of the one corpus the fact speaks for
+    q_one = None if onepass is None else (
+        onepass & jax.vmap(bf16_exact)(q_tiles))
     block, block_ids, block_scale = map(
         tiled, (block, block_ids, block_scale))
     block_bwd, block_bwd_ids, block_bwd_scale = map(
@@ -359,17 +369,18 @@ def _ring_knn_local(
         )
 
         def per_query_tile(args):
-            q_x, q_ids, cd0, ci0 = args
+            q_x, q_ids, cd0, ci0, one = args
             q_sq = sq_norms(q_x) if cfg.metric == "l2" else None
             # within a round the block's tiles merge per cfg.merge_schedule
             # (same code path as serial); the cross-ROUND merge is inherently
             # streaming — each rotation step merges into the carry
             return merge_tiles_into_carry(
                 q_x, q_ids, q_sq, blk_tiles, blk_ids, blk_sq,
-                cd0, ci0, cfg,
+                cd0, ci0, cfg, one,
             )
 
-        return jax.lax.map(per_query_tile, (q_tiles, qid_tiles, cd, ci))
+        return jax.lax.map(
+            per_query_tile, (q_tiles, qid_tiles, cd, ci, q_one))
 
     def step(state, _):
         blk, scl, blk_ids, cd, ci = state
@@ -424,6 +435,14 @@ def _ring_knn_local(
         return (nxt, nscl, nxt_ids, cd, ci), None
 
     rounds, bwd_limit = bidir_rounds(num_dev)
+
+    def finish(cd, ci):
+        out = cd.reshape(q_local, cfg.k), ci.reshape(q_local, cfg.k)
+        if q_one is None:
+            return out
+        # a query tile meets every corpus tile once a rotation, whatever
+        # the schedule
+        return *out, dist_steps(q_one, num_dev * (b // c_tile)).reshape(1, 2)
 
     def bidir_step(state, r):
         """One full-duplex round: the forward traveler (block i−r) always
@@ -578,7 +597,7 @@ def _ring_knn_local(
              block, block_scale, block_ids, carry_d, carry_i),
             jnp.arange(rounds),
         )
-        return carry_d.reshape(q_local, cfg.k), carry_i.reshape(q_local, cfg.k)
+        return finish(carry_d, carry_i)
 
     # P steps: own block once, then each of the P-1 received blocks — the
     # correct rotation the reference missed (SURVEY.md Q1). The final
@@ -587,7 +606,7 @@ def _ring_knn_local(
         step, (block, block_scale, block_ids, carry_d, carry_i),
         None, length=num_dev
     )
-    return carry_d.reshape(q_local, cfg.k), carry_i.reshape(q_local, cfg.k)
+    return finish(carry_d, carry_i)
 
 
 def parse_ring_mesh(mesh: Mesh):
@@ -685,6 +704,7 @@ def _ring_knn_sharded(
     c_tile,
     q_axis=None,
     corpus_scale=None,
+    onepass=None,
 ):
     """Shard-mapped ring. On a 1-D mesh queries and corpus share the ring
     axis (the reference's layout). On a 2-D (dp × ring) mesh queries shard
@@ -692,7 +712,9 @@ def _ring_knn_sharded(
     dp group runs an independent ring over its replica of the corpus.
     ``corpus_scale`` is the per-row scale vector of an int8-quantized
     corpus (``ring_transfer_dtype="int8"``; quantized at shard time by the
-    host wrapper), sharded like the corpus."""
+    host wrapper), sharded like the corpus. ``onepass`` is
+    :func:`_ring_knn_local`'s, replicated; with it the third output holds
+    one row of step counts a device."""
     body = functools.partial(
         _ring_knn_local,
         cfg=cfg,
@@ -704,27 +726,27 @@ def _ring_knn_sharded(
     )
     qspec = _query_spec(q_axis, axis)
     cspec = P(axis)
-    if corpus_scale is None:
-        fn = ring_shard_map(
-            body,
-            cfg,
-            mesh,
-            in_specs=(qspec, qspec, cspec, cspec),
-            out_specs=(qspec, qspec),
-        )
-        return fn(queries, query_ids, corpus, corpus_ids)
+    # the optional operands, as keywords of the one body
+    extra = {
+        name: (value, spec)
+        for name, value, spec in (
+            ("block_scale", corpus_scale, cspec), ("onepass", onepass, P()))
+        if value is not None
+    }
 
-    def with_scale(q, qi, c, cids, cscl):
-        return body(q, qi, c, cids, block_scale=cscl)
+    def local(q, qi, c, cids, *rest):
+        return body(q, qi, c, cids, **dict(zip(extra, rest)))
 
     fn = ring_shard_map(
-        with_scale,
+        local,
         cfg,
         mesh,
-        in_specs=(qspec, qspec, cspec, cspec, cspec),
-        out_specs=(qspec, qspec),
+        in_specs=(qspec, qspec, cspec, cspec,
+                  *(spec for _, spec in extra.values())),
+        out_specs=(qspec, qspec, *((qspec,) if "onepass" in extra else ())),
     )
-    return fn(queries, query_ids, corpus, corpus_ids, corpus_scale)
+    return fn(queries, query_ids, corpus, corpus_ids,
+              *(value for value, _ in extra.values()))
 
 
 def ring_serve_sharded(
@@ -810,10 +832,15 @@ def all_knn_ring(
     cfg: KNNConfig,
     mesh: Mesh | None = None,
     overlap: bool = True,
+    fact=None,
 ):
     """Host-side wrapper: build/validate the mesh, shard corpus and queries
     over the ring axis (ids/labels as separate arrays — no augmented-row
-    smuggling, SURVEY.md C6), run the sharded ring, strip padding."""
+    smuggling, SURVEY.md C6), run the sharded ring, strip padding; returns
+    (dists, ids, ``backends.serial.dist_steps``). ``fact`` is
+    ``center_for_l2``'s, the corpus side of the one-pass rule; the XLA ring
+    at a float wire takes it, every other form runs the program it always
+    ran."""
     if mesh is None:
         mesh = make_ring_mesh(cfg.num_devices, axis_name=cfg.mesh_axis)
     q_axis, axis, dp, ring_n = parse_ring_mesh(mesh)
@@ -833,6 +860,8 @@ def all_knn_ring(
     # small problems so padding never exceeds P·tile rows; the per-tile
     # memory cap (cfg.max_tile_elems) is applied inside ring_tiles.
     q_tile, c_tile, q_pad, c_pad = ring_tiles(cfg, m, nq, dp, ring_n)
+    takes_rule = (cfg.ring_fusion == "xla" and cfg.ring_transfer_dtype is None
+                  and onepass_rule(cfg, q_tile))
     rounds = bidir_rounds(ring_n)[0] if cfg.ring_schedule == "bidir" else ring_n
     wire_bytes = ring_wire_bytes_per_batch(cfg, c_pad, dim, ring_n)
 
@@ -865,7 +894,10 @@ def all_knn_ring(
         queries_p = jax.device_put(queries_p, q_sharding)
         qids_p = jax.device_put(qids_p, q_sharding)
 
-        best_d, best_i = _ring_knn_sharded(
+        # read last: the shards' placement is queued behind the centring
+        # pass that the read waits for (a form without the rule reads nothing)
+        onepass = onepass_fact(cfg, fact) if takes_rule else None
+        best_d, best_i, *steps = _ring_knn_sharded(
             queries_p,
             qids_p,
             corpus_p,
@@ -878,6 +910,7 @@ def all_knn_ring(
             c_tile,
             q_axis=q_axis,
             corpus_scale=corpus_scale,
+            onepass=onepass,
         )
         # static per layout, added at dispatch: no device read
         reg = obs_metrics.get_registry()
@@ -890,4 +923,6 @@ def all_knn_ring(
             help="bytes all devices send over the interconnect "
             "(ring_wire_bytes_per_batch a call)",
         ).inc(wire_bytes)
-        return best_d[:nq], best_i[:nq]
+        return best_d[:nq], best_i[:nq], (
+            steps[0] if steps else dist_steps(
+                q_pad // q_tile, c_pad // c_tile))

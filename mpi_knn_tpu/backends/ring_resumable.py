@@ -325,9 +325,14 @@ def all_knn_ring_resumable(
         # instead of silently merging mixed-centering carries (ADVICE r1).
         fp += f":ctr-{'dev' if isinstance(corpus, jax.Array) else 'host'}"
 
-        from mpi_knn_tpu.ops.distance import center_for_l2
+        from mpi_knn_tpu.ops.distance import center_for_l2, offset_is_whole
 
-        corpus, queries = center_for_l2(corpus, queries, all_pairs)
+        corpus, queries, _, mu = center_for_l2(corpus, queries, all_pairs)
+        if offset_is_whole(mu):
+            # a whole-number corpus is centred by its ROUNDED mean; a
+            # carry saved under the plain mean differs by fp noise. (The
+            # rounds here keep the configured dot: exact on such rows.)
+            fp += ":whole"
 
     m, dim = corpus.shape
     nq = queries.shape[0]

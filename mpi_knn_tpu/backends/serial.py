@@ -18,6 +18,7 @@ backends run ``knn_tile_step`` against each rotating block.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -25,7 +26,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ops.distance import pairwise_dist, sq_norms
+from mpi_knn_tpu.ops.distance import (
+    bf16_exact,
+    onepass_applies,
+    onepass_fact,
+    pairwise_dist,
+    pairwise_sq_l2,
+    sq_norms,
+)
 from mpi_knn_tpu.ops.rerank import compress_rerank_tile
 from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
@@ -40,6 +48,43 @@ from mpi_knn_tpu.parallel.partition import (
 )
 
 
+# the branches of the one-pass rule, as the trace names them, nested in
+# ``knn.dist`` (a program without the rule keeps plain ``knn.dist``). Full
+# ``knn.*`` names, so that a reduction of the trace by innermost ``knn.*``
+# scope (the ring cell's ``ring_scopes``) keeps the branches apart.
+ONEPASS_SCOPE = "knn.dist_onepass"
+MULTIPASS_SCOPE = "knn.dist_multipass"
+# query-tile height from which a tile program carries the rule's branch.
+# The conditional costs one copy of the corpus tile a step (8 bytes an
+# element at the HBM rate) and saves passes - 1 of the dot's 2·q FLOPs an
+# element: on the v5e (197 TFLOP/s, 819 GB/s) it breaks even at q = 962 /
+# (passes - 1) rows, 481 at ``high`` and 192 at ``highest``; below, a step
+# is bound by reading its tile at any pass count and gains nothing. The
+# branch also costs set-up: a program that carries it traces and lowers its
+# step twice, ~0.4 s at every process start on the chip's host (PERF.md §6,
+# PR 29). Twice the break-even of ``high`` pays for both.
+ONEPASS_MIN_ROWS = 1024
+
+
+def onepass_rule(cfg: KNNConfig, q_rows: int) -> bool:
+    """Whether a tile program of ``q_rows``-row query tiles carries the
+    one-pass branch: ``ops.distance.onepass_applies`` and the height."""
+    return onepass_applies(cfg) and q_rows >= ONEPASS_MIN_ROWS
+
+
+def dist_steps(took, steps: int):
+    """A dispatch's tile steps by the path of their distance dot, int32
+    ``[one-pass, multi-pass]``: what ``KNNResult.dist_steps`` and the
+    counter ``knn_dist_tile_steps_total`` hold. ``took`` is one verdict a
+    query-tile merge (a bool vector, made inside a program that carries
+    the branch) or, for a program without the branch, their number; each
+    merge meets ``steps`` corpus tiles."""
+    if isinstance(took, int):
+        return np.array([0, took * steps], dtype=np.int32)
+    one = jnp.sum(took, dtype=jnp.int32)
+    return jnp.stack([one, took.size - one]) * steps
+
+
 @jax.named_scope("knn.dist")
 def masked_dist_tile(
     q_x: jax.Array,
@@ -49,32 +94,46 @@ def masked_dist_tile(
     blk_ids: jax.Array,
     blk_sq: jax.Array | None,
     cfg: KNNConfig,
+    onepass: bool | None = None,
 ) -> jax.Array:
     """(q_tile × c_tile) masked distances: metric kernel → padding/self/zero
     exclusion masks. The compute half shared by both merge schedules and the
-    ring backends."""
-    d = pairwise_dist(
-        q_x,
-        blk,
-        metric=cfg.metric,
-        x_sq=q_sq,
-        y_sq=blk_sq,
-        precision=cfg.matmul_precision,
-    )
-    if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
-        pair_scale = q_sq[:, None] + blk_sq[None, :]
-    else:
-        # cosine distances live in [0, 2]; constant scale for the zero test
-        pair_scale = jnp.asarray(2.0, dtype=d.dtype)
-    return mask_tile(
-        d,
-        blk_ids,
-        query_ids=q_ids if cfg.exclude_self else None,
-        exclude_self=cfg.exclude_self,
-        exclude_zero=cfg.exclude_zero,
-        zero_eps=cfg.zero_eps,
-        scale=pair_scale,
-    )
+    ring backends.
+
+    ``onepass`` (static) says which branch of the one-pass rule this step
+    is traced for (:func:`merge_tiles_into_carry` holds the ``lax.cond``):
+    True, both operands are bf16 numbers and the dot is one bf16 x bf16
+    pass, which for them returns what the configured precision returns;
+    False, the dot at ``cfg.matmul_precision``; None, the same in a program
+    that carries no such branch. Norms and masks are the same in all."""
+    scope = contextlib.nullcontext() if onepass is None else jax.named_scope(
+        ONEPASS_SCOPE if onepass else MULTIPASS_SCOPE)
+    with scope:
+        if onepass:
+            d = pairwise_sq_l2(q_x, blk, x_sq=q_sq, y_sq=blk_sq, onepass=True)
+        else:
+            d = pairwise_dist(
+                q_x,
+                blk,
+                metric=cfg.metric,
+                x_sq=q_sq,
+                y_sq=blk_sq,
+                precision=cfg.matmul_precision,
+            )
+        if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
+            pair_scale = q_sq[:, None] + blk_sq[None, :]
+        else:
+            # cosine distances live in [0, 2]; constant scale for the zero test
+            pair_scale = jnp.asarray(2.0, dtype=d.dtype)
+        return mask_tile(
+            d,
+            blk_ids,
+            query_ids=q_ids if cfg.exclude_self else None,
+            exclude_self=cfg.exclude_self,
+            exclude_zero=cfg.exclude_zero,
+            zero_eps=cfg.zero_eps,
+            scale=pair_scale,
+        )
 
 
 def local_tile_topk(
@@ -86,6 +145,7 @@ def local_tile_topk(
     blk_sq: jax.Array | None,
     cfg: KNNConfig,
     out_dtype,
+    onepass: bool | None = None,
 ):
     """One corpus tile's (q, k) survivors — the per-tile reduction both
     merge schedules share, switched on ``cfg.precision_policy``:
@@ -105,16 +165,30 @@ def local_tile_topk(
             q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg
         )
         return ld.astype(out_dtype), li
-    d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg)
-    with jax.named_scope("knn.select"):
-        return smallest_k(
-            d.astype(out_dtype),
-            blk_ids,
-            cfg.k,
-            method=cfg.topk_method,
-            recall_target=cfg.recall_target,
-            block=cfg.topk_block,
-        )
+    d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
+    # under the one-pass rule both branches select the same way from tiles
+    # of one type: a nested jit is traced and lowered once for the two
+    select = _select_tile if onepass is None else _select_tile_once
+    return select(
+        d.astype(out_dtype), blk_ids, cfg.k, cfg.topk_method,
+        cfg.recall_target, cfg.topk_block,
+    )
+
+
+@jax.named_scope("knn.select")
+def _select_tile(d, blk_ids, k, method, recall_target, block):
+    return smallest_k(
+        d, blk_ids, k, method=method, recall_target=recall_target, block=block)
+
+
+# The selection kernels' bodies are the costly part of a tile program's
+# tracing and lowering, which run at every process start (PERF.md §6, PR 27):
+# traced twice, a program that carries the rule cost 1.7 s of `setup_s` in
+# the all-kNN cell. Programs without the rule do NOT go through the nested
+# jit: a server warms its buckets on ten threads, and all of them tracing
+# through one jitted function cost its warm-up 1.5 s (PERF.md §6, PR 29).
+_select_tile_once = jax.jit(
+    _select_tile, static_argnames=("k", "method", "recall_target", "block"))
 
 
 def knn_tile_step(
@@ -127,6 +201,7 @@ def knn_tile_step(
     carry_d: jax.Array,
     carry_i: jax.Array,
     cfg: KNNConfig,
+    onepass: bool | None = None,
 ):
     """One fused (query_tile × corpus_tile) step: distances → masks → merged
     top-k, streamed into the carry. The ring backends' per-round body (a
@@ -140,7 +215,8 @@ def knn_tile_step(
         all_d = jnp.concatenate([carry_d, ld], axis=-1)
         all_i = jnp.concatenate([carry_i, li], axis=-1)
     else:
-        d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg)
+        d = masked_dist_tile(
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
         all_d = jnp.concatenate([carry_d, d.astype(carry_d.dtype)], axis=-1)
         with jax.named_scope("knn.ids"):
             tile_ids = jnp.broadcast_to(blk_ids[None, :], d.shape)
@@ -165,6 +241,7 @@ def knn_chunk_update(
     carry_d: jax.Array,  # (QT, q_tile, k)
     carry_i: jax.Array,
     cfg: KNNConfig,
+    onepass: jax.Array | None = None,
 ):
     """Merge a chunk of corpus tiles into the per-query top-k carry: scan
     over corpus tiles inside a map over query tiles. The one compiled core
@@ -178,7 +255,7 @@ def knn_chunk_update(
         chunk_sq = jnp.zeros(chunk_tiles.shape[:2], dtype=acc)
     return serve_chunk(
         q_tiles, qid_tiles, carry_d, carry_i,
-        chunk_tiles, chunk_ids, chunk_sq, cfg,
+        chunk_tiles, chunk_ids, chunk_sq, onepass, cfg=cfg,
     )
 
 
@@ -190,6 +267,8 @@ def serve_chunk(
     tiles: jax.Array,  # (T, c_tile, d) RESIDENT corpus tiles
     tile_ids: jax.Array,  # (T, c_tile)
     tile_sqs: jax.Array,  # (T, c_tile) norms precomputed at index build
+    onepass: jax.Array | None = None,  # the corpus side of the one-pass rule
+    *,
     cfg: KNNConfig,
 ):
     """One serving batch against a device-resident corpus index: the
@@ -200,16 +279,33 @@ def serve_chunk(
     the top-k merge. The serving engine (``serve.engine``) AOT-compiles
     this per row bucket with ``carry_d``/``carry_i`` donated; argument
     order therefore keeps the batch-owned buffers first and the resident
-    index last."""
+    index last.
+
+    ``onepass`` (a bool scalar on the device: every centred corpus element
+    is a bf16 number) puts both branches of :func:`masked_dist_tile` into
+    the program; each query tile adds its own half of the verdict, and a
+    third output counts the tile steps by the branch they took
+    (:func:`dist_steps`, what the engagement counter reads). None: the
+    program and its two outputs as they always were; so too where the rule
+    does not apply (:func:`onepass_rule`: a small bucket,
+    ``precision_policy="mixed"`` as a serving rung)."""
+    if not onepass_rule(cfg, q_tiles.shape[1]):
+        onepass = None
 
     def per_query_tile(args):
         q_x, q_ids, cd, ci = args
         q_sq = sq_norms(q_x) if cfg.metric == "l2" else None
-        return merge_tiles_into_carry(
-            q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg
+        one = None if onepass is None else onepass & bf16_exact(q_x)
+        out = merge_tiles_into_carry(
+            q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one
         )
+        return out if one is None else (*out, one)
 
-    return jax.lax.map(per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    out = jax.lax.map(per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    if onepass is None:
+        return out
+    best_d, best_i, took = out
+    return best_d, best_i, dist_steps(took, tiles.shape[0])
 
 
 def merge_tiles_into_carry(
@@ -222,6 +318,7 @@ def merge_tiles_into_carry(
     carry_d: jax.Array,  # (q_tile, k)
     carry_i: jax.Array,
     cfg: KNNConfig,
+    onepass: jax.Array | None = None,
 ):
     """Merge a stack of corpus tiles into one query tile's top-k carry, per
     ``cfg.merge_schedule``. The single implementation behind the serial
@@ -243,16 +340,49 @@ def merge_tiles_into_carry(
     HIGHEST rerank finishes it, and what reaches the merges here is already
     exact — the schedules, the cascade, and the ring's per-round streaming
     merge are untouched by the policy.
+
+    ``onepass`` is the one-pass rule's verdict on this merge's operands, a
+    bool scalar made on the device (``ops.distance.bf16_exact`` of the
+    centred corpus AND of the query tile), the same for every tile of the
+    stack; None — the rule does not apply (:func:`onepass_rule`), or the
+    corpus is known not to qualify — is the program as it always was.
+    Given, every tile step is a ``lax.cond`` over two whole steps —
+    distances AND their reduction to k survivors — that differ in the
+    distance dot alone (:func:`masked_dist_tile`). Where the conditional
+    sits was decided by what the v5e compiler does with it (PERF.md §6,
+    PR 29): around the dot alone, a tile small enough for fast memory
+    leaves it, because the conditional's output does not live there;
+    around the whole scan, the f32 -> bf16 narrowing of the WHOLE stack is
+    hoisted out of the loop, 2.3 GiB and 28 ms at every call of the
+    all-kNN cell. Around the step, the tile is laid out ahead of the
+    conditional (one copy of the tile a step, which :func:`onepass_rule`
+    weighs) and the narrowing stays in the dot's own fusion.
     """
+
+    def either(step, *operands):
+        """``step(*operands, onepass)``: under the rule, a conditional over
+        the step traced for each branch."""
+        if onepass is None:
+            return step(*operands, None)
+        return jax.lax.cond(
+            onepass,
+            lambda *o: step(*o, True),
+            lambda *o: step(*o, False),
+            *operands,
+        )
+
     if cfg.merge_schedule == "twolevel":
 
         def local(_, tile):
-            blk, blk_ids, blk_sq = tile
             # per-tile reduction honors cfg.precision_policy (exact single
             # pass vs compress-and-rerank); either way k exact-f32
             # survivors per tile feed the level-2 cascade
-            return None, local_tile_topk(
-                q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, carry_d.dtype
+            return None, either(
+                lambda blk, blk_ids, blk_sq, one: local_tile_topk(
+                    q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg,
+                    carry_d.dtype, one,
+                ),
+                *tile,
             )
 
         _, (ld, li) = jax.lax.scan(local, None, (tiles, tile_ids, tile_sqs))
@@ -277,9 +407,13 @@ def merge_tiles_into_carry(
             )
 
     def step(carry, tile):
-        blk, blk_ids, blk_sq = tile
         return (
-            knn_tile_step(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, *carry, cfg),
+            either(
+                lambda blk, blk_ids, blk_sq, cd, ci, one: knn_tile_step(
+                    q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cd, ci, cfg, one
+                ),
+                *tile, *carry,
+            ),
             None,
         )
 
@@ -335,9 +469,12 @@ def all_knn_serial(
     queries: np.ndarray,
     query_ids: np.ndarray,
     cfg: KNNConfig,
+    fact=None,
 ):
     """Host-side wrapper: pad to tile multiples, run the jitted core, strip
-    padding. Returns ((q, k) dists, (q, k) ids) device arrays."""
+    padding. Returns ((q, k) dists, (q, k) ids, :func:`dist_steps`), the
+    first two device arrays. ``fact`` is ``center_for_l2``'s, the corpus
+    side of the one-pass rule."""
     nq = queries.shape[0]
     q_tile, c_tile = effective_tiles(cfg, corpus.shape[0], nq)
     q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, q_pad = prepare_tiles(
@@ -347,11 +484,18 @@ def all_knn_serial(
     acc = jnp.float64 if q_tiles.dtype == jnp.float64 else jnp.float32
     carry_d, carry_i = init_topk_tiles(q_pad // q_tile, q_tile, cfg.k,
                                        dtype=acc)
+    # read last: the tile stack's copy is queued behind the centring pass
+    # that the read waits for, and the device has work while the host
+    # dispatches (a program without the rule reads nothing)
+    onepass = onepass_fact(cfg, fact) if onepass_rule(cfg, q_tile) else None
 
-    best_d, best_i = knn_chunk_update(
-        q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, carry_d, carry_i, cfg
+    best_d, best_i, *steps = knn_chunk_update(
+        q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, carry_d, carry_i,
+        cfg, onepass,
     )
     return (
         best_d.reshape(q_pad, cfg.k)[:nq],
         best_i.reshape(q_pad, cfg.k)[:nq],
+        steps[0] if steps else dist_steps(
+            q_pad // q_tile, corpus_tiles.shape[0]),
     )

@@ -103,6 +103,10 @@ class KNNConfig:
     # None = auto: HIGHEST for f32/f64 inputs (recall-parity anchor; TPU's
     # DEFAULT truncates f32 operands to bf16 — measured ~0.3% recall@10 loss),
     # DEFAULT for bf16 inputs. Explicit "default"/"high"/"highest" overrides.
+    # Where both centred operands of a tile step are bf16 numbers already
+    # (whole-number rows: ops/distance.py bf16_exact, observed in the data,
+    # no setting) the multi-pass dot would multiply zeros and one pass
+    # returns the same sums: backends/serial.py masked_dist_tile.
     matmul_precision: Optional[str] = None
     # distance-pipeline precision structure (ops/rerank.py):
     # "exact"  — one-pass distances with the dot at matmul_precision
@@ -123,7 +127,8 @@ class KNNConfig:
     # L2 distances are translation-invariant, so results are mathematically
     # unchanged — but cancellation error in the matmul form scales with the
     # *centered* norms, which keeps fp noise (and the relative zero-distance
-    # threshold) tight even when the data sits far from the origin.
+    # threshold) tight even when the data sits far from the origin. A
+    # whole-number corpus is centred by its mean rounded to whole numbers.
     center: bool = True
     exclude_self: bool = True
     exclude_zero: bool = True

@@ -19,9 +19,113 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def bf16_exact(x):
+    """Whether every element of ``x`` is a bfloat16 number (whole numbers
+    of magnitude <= 256 all are; NaN never is): a bool scalar on the device for a device
+    array (or a tracer), a Python bool for a host one. For such operands
+    the hi + lo [+ lo2] pieces a ``high`` / ``highest`` dot splits them into
+    have all-zero lo pieces, so ONE bf16 x bf16 pass with float32
+    accumulation returns what the multi-pass dot returns.
+
+    On the device the test reads the bit pattern: bfloat16 is float32's
+    upper half, so a bf16 number is a float32 whose low 16 bits are zero.
+    Rounding to bfloat16 and comparing, as the host does, is NOT a test
+    there: inside a fusion the TPU compiler may keep the float32 ->
+    bfloat16 -> float32 round trip in float32 (it allows excess precision),
+    and the comparison then holds for every ``x`` — on the v5e fractional
+    rows took the one-pass dot and answered 4e-3 off (PERF.md §6, PR 29).
+    A device array wider than float32 is not examined (False: the rule
+    takes float32 programs only)."""
+    if isinstance(x, jax.Array):
+        if x.dtype.itemsize > 4:
+            return jnp.asarray(False)
+        bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+        return jnp.all((bits & 0xFFFF == 0) & (x == x))  # NaN never is
+    x = np.asarray(x)
+    # through float32: a bf16 number survives it, and ml_dtypes narrows
+    # float32 several times faster than float64
+    x32 = x.astype(np.float32)
+    return bool(
+        (x32 == x).all()
+        and (x32.astype(jnp.bfloat16).astype(np.float32) == x32).all()
+    )
+
+
+def onepass_applies(cfg) -> bool:
+    """The static side of the one-pass rule: the configurations whose tile
+    programs may carry the one-pass branch at all. Everything else (cosine,
+    the f64 debug mode, a bf16 corpus, ``precision_policy="mixed"``, an
+    uncentred run, a dot that is one pass already) keeps its program as it
+    is. The dynamic side is the data's: :func:`bf16_exact` of both centred
+    operands."""
+    return (
+        cfg.metric == "l2"
+        and cfg.center
+        and cfg.dtype == "float32"
+        and cfg.precision_policy == "exact"
+        and cfg.matmul_precision != "default"
+    )
+
+
+def onepass_fact(cfg, fact):
+    """What a tile program is handed as the corpus side of the rule, from
+    the ``fact`` of :func:`center_corpus`: a TRUE bool scalar — the program
+    then carries both branches and each query tile decides its own side —
+    or None: no branch, the program it always was, where the rule does not
+    apply to ``cfg`` or the corpus does not qualify. A device fact is READ
+    here (``bool(fact)`` waits for the centring pass that made it, nothing
+    else): call this last before the program's dispatch, with the work
+    that needs no verdict already queued behind that pass, and the device
+    does not idle. Under an outer ``jit`` the fact is a tracer and cannot
+    be read: it goes into the program, which decides on the device."""
+    if fact is None or not onepass_applies(cfg):
+        return None
+    if isinstance(fact, jax.core.Tracer):
+        return fact
+    return jnp.asarray(fact) if bool(fact) else None
+
+
+@jax.jit
+def _center_on_device(corpus):
+    """``(corpus - mu, mu, fact)`` for a device corpus, one program and two
+    passes over it. ``mu`` is the mean, or — where every element is a whole
+    number — the mean rounded to whole numbers: L2 is invariant to the
+    translation either way, a whole-number offset keeps whole-number rows
+    whole, and whole numbers of magnitude <= 256 are bf16 numbers.
+    ``fact`` says whether every centred element is one
+    (:func:`bf16_exact`)."""
+    acc = _acc_dtype(corpus)
+    mu = jnp.mean(corpus, axis=0, dtype=acc)
+    # a column sum like the mean's own (sums of one shape over one operand
+    # are what XLA fuses into a single pass): the fractional parts add up
+    # to zero where there are none, and to NaN or more anywhere else
+    frac = jnp.sum(jnp.abs(corpus - jnp.rint(corpus)), axis=0, dtype=acc)
+    mu = jnp.where(jnp.all(frac == 0), jnp.rint(mu), mu)
+    centred = corpus - mu
+    return centred, mu, bf16_exact(centred)
+
+
+def center_corpus(corpus):
+    """``(corpus - mu, mu, fact)``: what ``center_for_l2`` and
+    ``serve.build_index`` do to a corpus. ``mu`` is the corpus mean (f32
+    accumulation on the device, f64 on the host, as ever), rounded to whole
+    numbers where the corpus holds nothing else; ``fact`` is the corpus
+    side of the one-pass rule, whether every centred element is a bf16
+    number — a device bool scalar for a device corpus (no host wait), a
+    Python bool for a host one."""
+    if isinstance(corpus, jax.Array):
+        return _center_on_device(corpus)
+    corpus = np.asarray(corpus)
+    mu = np.asarray(corpus, dtype=np.float64).mean(axis=0)
+    if (corpus == np.rint(corpus)).all():
+        mu = np.rint(mu)
+    centred = corpus - mu
+    return centred, mu, bf16_exact(centred)
+
+
 @jax.named_scope("knn.center")
 def center_for_l2(corpus, queries, all_pairs: bool):
-    """Mean-center corpus (and queries consistently) before L2 distances.
+    """Center corpus (and queries consistently) before L2 distances.
 
     Translation leaves L2 distances unchanged, but cancellation error in the
     ‖x‖²+‖y‖²−2xy matmul form scales with the *centered* norms — centering
@@ -31,20 +135,30 @@ def center_for_l2(corpus, queries, all_pairs: bool):
     centered on device (no host bounce; f64 stays f64 when x64 is on), host
     inputs keep the f64 mean for the debug mode.
 
+    The offset is :func:`center_corpus`'s: the mean, or the mean rounded
+    to whole numbers for a whole-number corpus. Returns ``(corpus, queries,
+    fact, mu)``; ``fact`` says whether every centred corpus element is a
+    bf16 number, the corpus side of the one-pass rule that
+    ``backends.serial.masked_dist_tile`` applies; ``mu`` is the offset that
+    was subtracted (the resumable drivers fold :func:`offset_is_whole` into
+    their run identity: a carry made under the mean must not resume under
+    the rounded mean).
+
     The two paths accumulate the mean at different precisions, so centered
     values for the SAME data differ by fp noise across residencies —
     bit-identical checkpoint resume holds per-residency only, and
     ring_resumable folds the residency into the run fingerprint so a
     cross-residency resume restarts rather than merging mixed carries.
     """
-    if isinstance(corpus, jax.Array):
-        acc = jnp.float64 if corpus.dtype == jnp.float64 else jnp.float32
-        mu = jnp.mean(corpus, axis=0, dtype=acc)
-    else:
-        mu = np.asarray(corpus, dtype=np.float64).mean(axis=0)
-    corpus = corpus - mu
+    corpus, mu, fact = center_corpus(corpus)
     queries = corpus if all_pairs else queries - mu
-    return corpus, queries
+    return corpus, queries, fact, mu
+
+
+def offset_is_whole(mu) -> bool:
+    """Whether a centring offset is the rounded one (a fractional corpus's
+    mean is whole in no real case). Waits for a device offset."""
+    return bool((mu == np.rint(mu)).all())
 
 
 def _acc_dtype(x: jax.Array) -> jnp.dtype:
@@ -83,18 +197,27 @@ def pairwise_sq_l2(
     x_sq: jax.Array | None = None,
     y_sq: jax.Array | None = None,
     precision: str | None = None,
+    onepass: bool = False,
 ) -> jax.Array:
     """Squared L2 distances between all rows of x (q, d) and y (c, d) -> (q, c).
 
     The −2·X·Yᵀ term is a single MXU matmul (``preferred_element_type`` forces
     f32/f64 accumulation even for bf16 inputs). Precomputed squared norms may
     be passed in so tiled callers hoist them out of the tile loop.
+
+    ``onepass`` (static) is for operands the caller knows to be bf16 numbers
+    (:func:`bf16_exact`): the dot's operands are narrowed to bf16, which
+    loses nothing, and the MXU runs one pass whatever ``precision`` says.
+    The norms still come from the operands as given.
     """
     acc = _acc_dtype(x)
     if x_sq is None:
         x_sq = sq_norms(x)
     if y_sq is None:
         y_sq = sq_norms(y)
+    if onepass:
+        x, y = x.astype(jnp.bfloat16), y.astype(jnp.bfloat16)
+        precision = jax.lax.Precision.DEFAULT
     xy = jax.lax.dot_general(
         x,
         y,

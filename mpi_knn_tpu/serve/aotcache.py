@@ -107,6 +107,11 @@ def index_facts(index) -> dict:
         v = getattr(index, name, None)
         if v is not None:
             facts[name] = int(v)
+    if getattr(index, "onepass", None) is not None:
+        # the batch program takes the fact as one more argument and holds
+        # both branches of the one-pass rule (absent otherwise, so every
+        # other entry's address is unchanged)
+        facts["onepass"] = True
     mesh = getattr(index, "mesh", None)
     if mesh is not None:
         facts["mesh"] = {

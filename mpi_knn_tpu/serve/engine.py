@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
+from mpi_knn_tpu.backends.serial import dist_steps
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
@@ -242,7 +243,7 @@ def _resident_args(index) -> tuple:
     from the flattened argument list."""
     b = index.backend
     if b == "serial":
-        return (index.tiles, index.tile_ids, index.tile_sqs)
+        return (index.tiles, index.tile_ids, index.tile_sqs, index.onepass)
     if b in ("ring", "ring-overlap"):
         return (index.corpus_sharded, index.corpus_ids_sharded,
                 index.corpus_scales_sharded)
@@ -359,7 +360,7 @@ def _serial_lowered(index: CorpusIndex, cfg: KNNConfig, bucket: int):
         sds((qt, q_tile, cfg.k), acc),
         sds((qt, q_tile, cfg.k), jnp.int32),
         *_resident_args(index),
-        cfg,
+        cfg=cfg,
     )
     return lowered, q_pad, q_tile
 
@@ -846,9 +847,12 @@ def _prep_queries(index: CorpusIndex, cfg: KNNConfig, exec_: _BucketExec, q):
 
 def _run(index: CorpusIndex, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
     """Issue one padded batch on the compiled executable; returns padded
-    ((q_pad, k) dists, ids, exchange_stats-or-None) device results
-    (async — not synchronized here). The stats slot is populated only by
-    the sharded-clustered backend (its per-shard (N_STATS·S,) vector).
+    ((q_pad, k) dists, ids, exchange_stats-or-None, dist_steps-or-None)
+    device results (async — not synchronized here). The stats slot is
+    populated only by the sharded-clustered backend (its per-shard
+    (N_STATS·S,) vector); ``dist_steps`` is the batch's
+    ``backends.serial.dist_steps``, for the layouts whose batches run
+    ``masked_dist_tile`` (serial, ring).
     Dispatch serializes with live mutation on the per-index mutation
     lock — the resident args are read and the batch enqueued as one
     atomic step w.r.t. any in-place store update."""
@@ -861,7 +865,9 @@ def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
     if exec_.backend == "serial":
         qt = exec_.q_pad // exec_.q_tile
         carry_d, carry_i = init_topk_tiles(qt, exec_.q_tile, cfg.k, dtype=acc)
-        d, i = exec_.compiled(
+        # an index that holds the one-pass fact has a third output: the
+        # batch's tile steps by the branch they took
+        d, i, *steps = exec_.compiled(
             q2d.reshape(qt, exec_.q_tile, index.dim),
             qids.reshape(qt, exec_.q_tile),
             carry_d,
@@ -872,6 +878,7 @@ def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
             d.reshape(exec_.q_pad, cfg.k),
             i.reshape(exec_.q_pad, cfg.k),
             None,
+            steps[0] if steps else dist_steps(qt, index.tiles.shape[0]),
         )
     if exec_.backend == "ivf":
         qt = exec_.q_pad // exec_.q_tile
@@ -889,6 +896,7 @@ def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
             d.reshape(exec_.q_pad, cfg.k),
             i.reshape(exec_.q_pad, cfg.k),
             None,
+            None,
         )
     if exec_.backend == "ivf-sharded":
         # q2d arrives pre-tiled (QT, q_tile, d) on the query sharding
@@ -900,6 +908,7 @@ def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
             d.reshape(exec_.q_pad, cfg.k),
             i.reshape(exec_.q_pad, cfg.k),
             stats,
+            None,
         )
     if exec_.backend in ("ring", "ring-overlap"):
         # scratch born directly under the query sharding (no allocate-
@@ -909,12 +918,15 @@ def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
         d, i = exec_.compiled(
             q2d, qids, carry_d, carry_i, *_resident_args(index),
         )
-        return d, i, None
+        return d, i, None, dist_steps(
+            exec_.q_pad // exec_.q_tile,
+            index.corpus_sharded.shape[0] // index.c_tile,
+        )
     carry_d, carry_i = init_topk(exec_.q_pad, cfg.k, dtype=acc)
     d, i = exec_.compiled(
         q2d, qids, carry_d, carry_i, *_resident_args(index)
     )
-    return d, i, None
+    return d, i, None, None
 
 
 @dataclasses.dataclass
@@ -957,6 +969,9 @@ class BatchResult:
     # the batch's dispatch→retire span (obs.spans handle, None when
     # nothing records): the phases of the batch name it as their parent
     span: object = None
+    # serial / ring batches: the tile steps by the path of their distance
+    # dot (backends.serial.dist_steps), counted at retire
+    dist_steps: object = None
 
     @functools.cached_property
     def dists(self) -> np.ndarray:
@@ -1006,12 +1021,16 @@ def query_knn(
     bucket = bucket_rows(nq, cfg.query_bucket)
     exec_ = get_executable(index, cfg, bucket)
     q2d, qids, rows = _prep_queries(index, cfg, exec_, queries)
-    d, i, stats = _run(index, cfg, exec_, q2d, qids)
+    d, i, stats, steps = _run(index, cfg, exec_, q2d, qids)
     if stats is not None:
         _count_exchange(stats, exec_.exchange_bytes)
+    d, i, steps = jax.device_get((d, i, steps))
+    if steps is not None:
+        obs_metrics.get_registry().count_dist_steps(steps)
     return KNNResult(
-        dists=np.asarray(jax.device_get(d))[:rows],
-        ids=np.asarray(jax.device_get(i))[:rows],
+        dists=np.asarray(d)[:rows],
+        ids=np.asarray(i)[:rows],
+        dist_steps=steps,
     )
 
 
@@ -1736,6 +1755,10 @@ class ServeSession:
             deadline_breached=res.deadline_breached, **extra,
         )
         maybe_beat(f"serve-batch-{res.seq}")
+        if res.dist_steps is not None:
+            # the batch is synchronized: its count is on hand, eight bytes
+            # after the answers' own D2H, never a wait of its own
+            self._metrics.count_dist_steps(res.dist_steps)
         self._metrics.counter(
             "serve_batches_total", help="batches retired"
         ).inc()
@@ -1764,8 +1787,9 @@ class ServeSession:
         with self.phase("prep", seq=self._seq, parent=span):
             q2d, qids, rows = _prep_queries(self.index, cfg, exec_, queries)
         with self.phase("enqueue", seq=self._seq, parent=span):
-            d, i, stats = _run(self.index, cfg, exec_, q2d, qids)
-        return bucket, rows, poison_topk(d), i, stats, exec_.exchange_bytes
+            d, i, stats, steps = _run(self.index, cfg, exec_, q2d, qids)
+        return (bucket, rows, poison_topk(d), i, stats,
+                exec_.exchange_bytes, steps)
 
     def submit(self, queries, tenants=None) -> list[BatchResult]:
         """Dispatch one batch; ``tenants`` is an optional
@@ -1826,7 +1850,7 @@ class ServeSession:
                     max_s=pol.backoff_max_s,
                     retryable=pol.retryable,
                 )
-                bucket, rows, d, i, stats, xbytes = out.value
+                bucket, rows, d, i, stats, xbytes, steps = out.value
                 retries, backoffs = out.attempts - 1, out.backoffs
                 with self._stats_lock:
                     self.retries_total += retries
@@ -1840,9 +1864,8 @@ class ServeSession:
                         help="transient dispatch failures retried",
                     ).inc(retries)
             else:
-                bucket, rows, d, i, stats, xbytes = self._dispatch(
-                    queries, cfg, sid
-                )
+                bucket, rows, d, i, stats, xbytes, steps = (
+                    self._dispatch(queries, cfg, sid))
                 retries, backoffs = 0, ()
         except Exception as e:
             # a RAISED dispatch failure (retries exhausted, non-retryable
@@ -1860,6 +1883,7 @@ class ServeSession:
             stats_padded=stats,
             exchange_bytes=xbytes,
             span=sid,
+            dist_steps=steps,
         )
         self._seq += 1
         self._inflight.append((res, t0))

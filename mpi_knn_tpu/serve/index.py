@@ -36,7 +36,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ops.distance import sq_norms
+from mpi_knn_tpu.obs import metrics as obs_metrics
+from mpi_knn_tpu.ops.distance import (
+    center_corpus,
+    onepass_applies,
+    sq_norms,
+)
 from mpi_knn_tpu.ops.topk import start_lane_bin_import
 from mpi_knn_tpu.parallel.partition import (
     make_global_ids,
@@ -64,6 +69,14 @@ class CorpusIndex:
     tiles: jax.Array | None = None  # (T, c_tile, d)
     tile_ids: jax.Array | None = None  # (T, c_tile)
     tile_sqs: jax.Array | None = None  # (T, c_tile)
+    # the corpus side of the one-pass rule (backends.serial
+    # masked_dist_tile): a bool scalar on the device, TRUE when the index
+    # was built — every centred element a bf16 number — and handed to every
+    # batch program, which then carries both branches; an upsert of a row
+    # that is not turns it false in place, with no recompile. None (the
+    # rule does not apply, or the corpus did not qualify at build): the
+    # batch program has no branch and is the one it always was.
+    onepass: jax.Array | None = None
     corpus_padded: jax.Array | None = None  # (c_pad, d) — pallas layout
     # ring layout
     mesh: Mesh | None = None
@@ -173,17 +186,23 @@ def build_index(
 def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
 
     mu = None
+    onepass = None
     if cfg.center and cfg.metric == "l2":
-        # same mean construction as ops.distance.center_for_l2, computed
+        # ops.distance.center_for_l2's own offset and centring, computed
         # ONCE here: f64 on host, accumulation dtype on device. Queries
-        # are centered per batch with this stored mean, so serving math is
-        # bit-identical to a fresh all_knn over the same residency.
-        if isinstance(corpus, jax.Array):
-            acc = jnp.float64 if corpus.dtype == jnp.float64 else jnp.float32
-            mu = jnp.mean(corpus, axis=0, dtype=acc)
-        else:
-            mu = np.asarray(corpus, dtype=np.float64).mean(axis=0)
-        corpus = corpus - mu
+        # are centered per batch with this stored offset, so serving math
+        # is bit-identical to a fresh all_knn over the same residency.
+        corpus, mu, fact = center_corpus(corpus)
+        # a fact of the index, read once here (a build may wait): an index
+        # over data that does not qualify compiles today's program only
+        if backend == "serial" and onepass_applies(cfg) and bool(fact):
+            onepass = jax.device_put(np.bool_(True))
+    obs_metrics.get_registry().gauge(
+        "serve_index_onepass",
+        help="1 when every centred element of the resident corpus is a "
+        "bf16 number, so batches of such queries take the one-pass "
+        "distance dot (the serial layout's rule); else 0",
+    ).set(float(onepass is not None))
 
     if backend in ("ring", "ring-overlap"):
         from mpi_knn_tpu.backends.ring import parse_ring_mesh, ring_tiles
@@ -280,5 +299,5 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     return CorpusIndex(
         cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
         c_tile=c_tile, mu=mu, tiles=tiles, tile_ids=tile_ids,
-        tile_sqs=tile_sqs,
+        tile_sqs=tile_sqs, onepass=onepass,
     )

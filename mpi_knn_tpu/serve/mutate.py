@@ -74,6 +74,7 @@ from mpi_knn_tpu.ivf.mutate import (
 )
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
+from mpi_knn_tpu.ops.distance import bf16_exact
 from mpi_knn_tpu.resilience.heartbeat import maybe_beat
 
 __all__ = [
@@ -561,6 +562,11 @@ def upsert_rows(index, ids, rows, config: KNNConfig | None = None) -> dict:
                 index.tiles, index.tile_ids, index.tile_sqs = (
                     tiles, tile_ids, tile_sqs
                 )
+                if index.onepass is not None and not bf16_exact(rows):
+                    # the corpus side of the one-pass rule no longer
+                    # holds: the same programs take their other branch
+                    index.onepass = jax.device_put(np.bool_(False))
+                    reg.gauge("serve_index_onepass").set(0.0)
             else:
                 out = ex(*args, *_store_args(index))
                 _swap_store(index, *_normalize_store_out(index, out))

@@ -31,10 +31,18 @@ class KNNResult:
         1-based ids, ``/root/reference/knn-serial.c:89``; use ``one_based()``
         for parity output). ``INVALID_ID`` marks unfilled slots (k > valid
         candidates).
+      dist_steps: int32 (..., 2), the call's tile steps by the path of their
+        distance dot, ``[one-pass, multi-pass]`` (``backends/serial.py
+        masked_dist_tile``), one row a device where the rows are counted on
+        the ring's devices; comes with the answer, costs no wait of its
+        own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
+        ``knn_dist_tile_steps_total``. None from the paths that run no such
+        tile step (the Pallas backends).
     """
 
     dists: jax.Array
     ids: jax.Array
+    dist_steps: jax.Array | None = None
 
     @property
     def k(self) -> int:

@@ -79,3 +79,20 @@ def debug_nans():
         yield
     finally:
         jax.config.update("jax_debug_nans", False)
+
+
+@pytest.fixture
+def dist_steps():
+    """``dist_steps()`` -> (one-pass, multi-pass) tile steps a server has
+    counted so far (``knn_dist_tile_steps_total``; a one-shot call carries
+    its own on ``KNNResult.dist_steps``)."""
+    from mpi_knn_tpu.obs.metrics import DIST_STEPS, get_registry
+
+    def read():
+        reg = get_registry()
+        return tuple(
+            reg.counter(DIST_STEPS, labels={"path": p}).value
+            for p in ("onepass", "multipass")
+        )
+
+    return read

@@ -82,6 +82,40 @@ def test_resumable_matches_serial(tmp_path, rng):
     np.testing.assert_array_equal(np.asarray(i), np.asarray(base.ids))
 
 
+def test_resumable_never_resumes_a_carry_made_under_the_other_offset(
+        tmp_path, rng):
+    """PR 29 centres a whole-number corpus by its ROUNDED mean: a carry a
+    run saved under the plain mean (every run before it) differs by fp
+    noise and must not be merged into. The whole-number offset is part of
+    the run's identity; its own checkpoints resume."""
+    from mpi_knn_tpu.utils.checkpoint import KNNCheckpoint, save_checkpoint
+
+    X = rng.integers(0, 256, (96, 8)).astype(np.float32)
+    cfg = KNNConfig(k=6, query_tile=16, corpus_tile=16, backend="serial")
+    qids = np.arange(len(X), dtype=np.int32)
+    ck = tmp_path / "ck"
+    old_fp = fingerprint(X, X, cfg)  # what the parent wrote for this run
+    save_checkpoint(ck, KNNCheckpoint(
+        carry_d=np.zeros((6, 16, 6), np.float32),  # poison: all "found"
+        carry_i=np.zeros((6, 16, 6), np.int32),
+        tiles_done=6, fingerprint=old_fp))
+    seen = []
+    d, i = all_knn_resumable(
+        X, X, qids, cfg, checkpoint_dir=ck, save_every=3,
+        progress_cb=lambda done, total: seen.append(done))
+    assert seen == [3, 6]  # restarted from tile 0, not resumed at 6
+    base = all_knn(X, config=cfg)
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(base.dists))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(base.ids))
+    assert load_checkpoint(ck, old_fp) is None
+    state = load_checkpoint(ck, old_fp + ":ctr-whole")
+    assert state is not None and state.tiles_done == 6
+    # fractional data keeps the identity it always had
+    Xf = X + 0.25
+    all_knn_resumable(Xf, Xf, qids, cfg, checkpoint_dir=tmp_path / "ckf")
+    assert load_checkpoint(tmp_path / "ckf", fingerprint(Xf, Xf, cfg))
+
+
 def test_checkpoint_resume_continues_not_restarts(tmp_path):
     """Kill after round 1, resume: result identical, and the resumed run must
     start from the saved tile cursor."""

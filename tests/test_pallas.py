@@ -333,3 +333,137 @@ def test_ring_program_with_lane_bin_kernels_compiles_for_four_v5e(
                 if " collective-permute-start(" in ln and "f32[" in ln]
     assert permutes and all(
         f"f32[{tiles},{cfg.corpus_tile},{dim}]" in ln for ln in permutes)
+
+
+# ---------------------------------------------------------------------------
+# the one-pass rule's programs at the cells' sizes, compiled for the v5e
+# (PR 29): which dot each branch holds, and what the branch costs in memory
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    from tests.conftest import TPU_MODE
+
+    if TPU_MODE:
+        pytest.skip("compile-only checks; the cells run these on the chip")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # noqa: BLE001 — no compile-only TPU client here
+        pytest.skip(f"no compile-only TPU topology: {e}")
+
+
+def _dist_dots(hlo: str) -> dict:
+    """The distance dots of a compiled program by their branch's scope
+    under ``knn.dist`` (``knn.dist_<branch>``; "" for none) -> (operand
+    element types, operand_precision or None)."""
+    import re
+
+    fusions = dict(re.findall(
+        r"^\s*%(\S+) = (\w+)\[[^\n]*? fusion\(", hlo, flags=re.M))
+    params = dict(re.findall(
+        r"^\s*%(\S+) = (\w+)\[[^\n]*? parameter\(", hlo, flags=re.M))
+    dots = {}
+    for ln in hlo.splitlines():
+        m = re.search(r" convolution\(%(\S+), %(\S+)\).*?(?:operand_precision"
+                      r"=\{(\w+),\w+\})?, metadata=\{op_name=\"[^\"]*"
+                      r"knn\.dist/(?:knn\.dist_(\w+)/)?dot_general", ln)
+        if m:
+            kinds = tuple(fusions.get(o) or params.get(o) for o in m.group(1, 2))
+            dots[m.group(4) or ""] = (kinds, m.group(3))
+    return dots
+
+
+@pytest.mark.parametrize("cell,q,tiles,dim,precision,temp_gib", [
+    # allknn-mnist8m: 13.85 GiB of 15.75 at the peak leaves no room for the
+    # bf16 copy of the stack (2.30 GiB) that XLA hoists out of the scan when
+    # the conditional sits around the whole scan, or for the `default`
+    # path's 3.19 GiB: the engaged program needs today's 1.04 GiB
+    ("allknn-mnist8m", 4096, 192, 784, "high", 1.1),
+    # serve-bigann10m-bulk: one 1024-row bucket over the resident stack
+    ("serve-bigann10m-bulk", 1024, 1221, 128, "highest", 1.3),
+])
+def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
+        v5e_devices, monkeypatch, cell, q, tiles, dim, precision, temp_gib):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = KNNConfig(k=10, backend="serial", matmul_precision=precision,
+                    query_tile=q, corpus_tile=8192)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((tiles, 8192, dim), jnp.float32), arg((tiles, 8192), jnp.int32),
+            arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32))
+    with jax.enable_x64(False):
+        plain = serial.knn_chunk_update.lower(*args, cfg).compile()
+        ruled = serial.knn_chunk_update.lower(
+            *args, cfg, arg((), jnp.bool_)).compile()
+    # today's program: one dot, at the configured precision, plain scope
+    assert _dist_dots(plain.as_text()) == {"": (("f32", "f32"), precision)}
+    # under the rule: the engaged branch holds ONE bf16 x bf16 -> f32 dot
+    # (no operand_precision: a DEFAULT dot), the other the configured one
+    assert _dist_dots(ruled.as_text()) == {
+        "onepass": (("bf16", "bf16"), None),
+        "multipass": (("f32", "f32"), precision),
+    }
+    temps = [c.memory_analysis().temp_size_in_bytes / 2**30
+             for c in (plain, ruled)]
+    assert temps[1] <= temps[0] + 0.1 and temps[1] <= temp_gib, (cell, temps)
+
+
+def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(
+        v5e_devices, monkeypatch):
+    """``ring4-mnist8m`` at its size, 128 tiles a chip: both branches inside
+    the ring's ``shard_map`` (``check_vma`` on), the fact replicated, and the
+    temporaries what they were (12.99 GiB of 15.75 at the peak on the chip:
+    one bf16 copy of the travelling block, 1.53 GiB, would still fit; the
+    program asks for none)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import ring
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = KNNConfig(
+        k=10, backend="ring-overlap", num_devices=4, matmul_precision="high",
+        query_tile=4096, corpus_tile=8192, merge_schedule="twolevel")
+    mesh = Mesh(np.asarray(v5e_devices), (cfg.mesh_axis,))
+    by_rows = NamedSharding(mesh, P(cfg.mesh_axis))
+    tiles, dim = 128, 784
+    m, nq = 4 * tiles * cfg.corpus_tile, 4 * cfg.query_tile
+
+    def arg(shape, dtype, sharding=by_rows):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = (arg((nq, dim), jnp.float32), arg((nq,), jnp.int32),
+            arg((m, dim), jnp.float32), arg((m,), jnp.int32),
+            cfg, True, mesh, cfg.mesh_axis, cfg.query_tile, cfg.corpus_tile)
+    with jax.enable_x64(False):
+        plain = ring._ring_knn_sharded.lower(*args).compile()
+        ruled = ring._ring_knn_sharded.lower(
+            *args, onepass=arg((), jnp.bool_, NamedSharding(mesh, P()))
+        ).compile()
+    assert _dist_dots(ruled.as_text()) == {
+        "onepass": (("bf16", "bf16"), None),
+        "multipass": (("f32", "f32"), "high"),
+    }
+    temps = [c.memory_analysis().temp_size_in_bytes / 2**30
+             for c in (plain, ruled)]
+    assert temps[1] <= temps[0] + 0.1 and temps[1] <= 7.0, temps
+    # the wire is what it was: float32 tile stacks travel
+    permutes = [ln for ln in ruled.as_text().splitlines()
+                if " collective-permute-start(" in ln and "[128,8192,784]" in ln]
+    assert permutes and all("f32[128,8192,784]" in ln for ln in permutes)

@@ -246,3 +246,55 @@ def test_ring_respects_tiling(rng):
         np.asarray(ring.dists), np.asarray(serial.dists), rtol=1e-5, atol=1e-5
     )
     assert _as_sets(ring.ids) == _as_sets(serial.ids)
+
+
+@pytest.mark.parametrize("schedule", ["uni", "bidir"])
+@pytest.mark.parametrize("backend", ["ring", "ring-overlap"])
+def test_ring_takes_one_pass_on_whole_number_rows_and_agrees_with_serial(
+        rng, backend, schedule):
+    """PR 29: the corpus side of the one-pass rule is reduced across the
+    ring in the centring pass, each chip's query tiles decide their own
+    side, and the answer is the serial backend's and an int64
+    computation's, exactly. 1024-row query tiles: ``ONEPASS_MIN_ROWS``."""
+    import jax.numpy as jnp
+
+    X = rng.integers(0, 256, (4096, 16)).astype(np.float32)
+    kw = dict(k=7, query_tile=1024, corpus_tile=128, matmul_precision="high")
+    serial = all_knn(X, backend="serial", **kw)
+    for corpus in (X, jnp.asarray(X)):
+        ring = all_knn(corpus, backend=backend, num_devices=4,
+                       ring_schedule=schedule, **kw)
+        # one row of counts a device
+        assert np.asarray(ring.dist_steps).tolist() == [[32, 0]] * 4
+        np.testing.assert_array_equal(
+            np.asarray(ring.dists), np.asarray(serial.dists))
+    # (whole-number distances tie, and ids may differ among equal ones)
+    d = ((X[:, None].astype(np.int64) - X[None].astype(np.int64)) ** 2).sum(-1)
+    np.fill_diagonal(d, np.iinfo(np.int64).max)
+    d[d == 0] = np.iinfo(np.int64).max  # duplicates are excluded by value
+    np.testing.assert_array_equal(
+        np.asarray(serial.dists), np.sort(d, axis=1)[:, :7].astype(np.float32))
+    # a wire that narrows the block keeps the program it always ran
+    narrow = all_knn(jnp.asarray(X), backend=backend, num_devices=4,
+                     ring_schedule=schedule, ring_transfer_dtype="bfloat16",
+                     **kw)
+    assert np.asarray(narrow.dist_steps).tolist() == [0, 4 * 32]
+
+
+@pytest.mark.parametrize("schedule", ["uni", "bidir"])
+def test_dp_by_ring_mesh_takes_one_pass_on_whole_number_rows(
+        rng, schedule):
+    """The corpus fact is replicated over BOTH mesh axes, each device's
+    query tiles decide their own side (2 x 4 devices, 1024-row tiles)."""
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.parallel.mesh import make_mesh2d
+
+    X = rng.integers(0, 256, (8192, 16)).astype(np.float32)
+    kw = dict(k=7, query_tile=1024, corpus_tile=128, matmul_precision="high")
+    serial = all_knn(X, backend="serial", **kw)
+    ring = all_knn(jnp.asarray(X), backend="ring-overlap",
+                   mesh=make_mesh2d(2, 4), ring_schedule=schedule, **kw)
+    assert np.asarray(ring.dist_steps).tolist() == [[64, 0]] * 8
+    np.testing.assert_array_equal(
+        np.asarray(ring.dists), np.asarray(serial.dists))

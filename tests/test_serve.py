@@ -498,3 +498,102 @@ def test_tenant_composition_aggregates_parts(rng):
     assert st["latency_sum_s"] == st["latency_max_s"]  # ONE observation
     with pytest.raises(ValueError, match="metrics label"):
         session.submit(_data(rng, m=8), tenants=(('bad"id', 8),))
+
+
+# ---------------------------------------------------------------------------
+# the one-pass rule in serving (PR 29): the corpus side is a fact of the
+# index, the query side is decided per batch inside the one program a bucket
+
+
+@pytest.mark.parametrize("on_device", [False, True], ids=["host", "device"])
+@pytest.mark.parametrize("kind", ["whole", "gauss"])
+def test_serving_is_bit_identical_to_all_knn_under_the_one_pass_rule(
+        rng, dist_steps, kind, on_device):
+    """Whole-number rows: the index holds the fact, 1024-row batches take
+    the one-pass dot, a batch with one fractional row takes the configured
+    dot from the SAME executable (no compile), and every answer is a fresh
+    ``all_knn``'s bit for bit. Gaussian rows: no fact, today's program."""
+    draw = (
+        (lambda m: rng.integers(0, 256, (m, 32)).astype(np.float32))
+        if kind == "whole" else (lambda m: _data(rng, m=m, d=32) * 50)
+    )
+    X, Q = draw(1024), draw(1024)
+    Qf = Q.copy()
+    Qf[17, 5] += 0.001
+    put = jnp.asarray if on_device else (lambda a: a)
+    cfg = _cfg("serial", k=10, query_tile=1024, corpus_tile=256,
+               query_bucket=1024, matmul_precision="highest")
+    idx = build_index(put(X), cfg)
+    assert (idx.onepass is not None) == (kind == "whole")
+    query_knn(put(Q), idx)  # warm the bucket and its glue
+    from mpi_knn_tpu.obs.metrics import watch_compiles
+
+    for batch, path in ((Q, 0), (Qf, 1), (Q, 0)):
+        before = dist_steps()
+        with watch_compiles() as compiles:
+            got = query_knn(put(batch), idx)
+        after = dist_steps()
+        moved = [a - b for a, b in zip(after, before)]
+        want_path = path if kind == "whole" else 1
+        assert moved[want_path] == 4 and moved[1 - want_path] == 0
+        if not on_device:  # a device batch's centring is an eager program
+            assert compiles == []
+        want = all_knn(put(X), queries=put(batch), config=cfg)
+        np.testing.assert_array_equal(
+            np.asarray(want.dists), np.asarray(got.dists))
+        np.testing.assert_array_equal(
+            np.asarray(want.ids), np.asarray(got.ids))
+
+
+def test_small_buckets_of_a_whole_number_index_keep_todays_program(
+        rng, dist_steps):
+    """Under ``ONEPASS_MIN_ROWS`` the branch is not worth its copy of the
+    tile and its set-up: the bucket's program has no branch (two outputs) though the index holds
+    the fact, and its answers are still exact."""
+    X = rng.integers(0, 256, (512, 16)).astype(np.float32)
+    Q = rng.integers(0, 256, (16, 16)).astype(np.float32)
+    cfg = _cfg("serial", k=4, matmul_precision="highest")
+    idx = build_index(X, cfg)
+    assert idx.onepass is not None
+    before = dist_steps()
+    got = query_knn(Q, idx)
+    after = dist_steps()
+    assert after[0] == before[0] and after[1] > before[1]
+    ref = np.sort(((Q[:, None].astype(np.int64) - X[None].astype(np.int64))
+                   ** 2).sum(-1), axis=1)[:, :4]
+    np.testing.assert_array_equal(got.dists, ref.astype(np.float32))
+
+
+def test_upsert_of_a_fractional_row_turns_the_index_fact_off_in_place(
+        rng, dist_steps):
+    """The fact is a device scalar the batch programs take as an argument:
+    an upserted row that is not a bf16 number flips it, the warm executable
+    takes its other branch, nothing recompiles, the answer holds the row."""
+    from mpi_knn_tpu.obs.metrics import get_registry, watch_compiles
+    from mpi_knn_tpu.serve.mutate import upsert_rows, warm_mutation
+
+    X = rng.integers(0, 256, (1024, 16)).astype(np.float32)
+    Q = rng.integers(0, 256, (1024, 16)).astype(np.float32)
+    cfg = _cfg("serial", k=4, query_tile=1024, corpus_tile=256,
+               query_bucket=1024, bucket_headroom=0.25, mutation_bucket=8,
+               matmul_precision="highest")
+    idx = build_index(X, cfg)
+    warm_mutation(idx, cfg, sizes=[8])
+    query_knn(Q, idx)
+    assert bool(idx.onepass)
+    assert get_registry().gauge("serve_index_onepass").value == 1.0
+    upsert_rows(idx, [5000], rng.integers(0, 256, (1, 16)).astype(np.float32))
+    assert bool(idx.onepass)  # a whole-number row keeps the fact
+    row = Q[3:4] + 0.3
+    with watch_compiles() as compiles:
+        upsert_rows(idx, [5001], row)
+        before = dist_steps()
+        got = query_knn(Q, idx)
+        after = dist_steps()
+    assert compiles == []
+    assert not bool(idx.onepass)
+    assert get_registry().gauge("serve_index_onepass").value == 0.0
+    assert after[0] == before[0] and after[1] - before[1] == 5
+    assert got.ids[3, 0] == 5001
+    # the matmul form at norms of 1e5: cancellation noise, not a miss
+    np.testing.assert_allclose(got.dists[3, 0], 16 * 0.3 ** 2, atol=0.05)
