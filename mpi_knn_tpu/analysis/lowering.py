@@ -911,7 +911,7 @@ def _lower_serve(target: LintTarget):
     cache would compile — a parallel lint-only reimplementation could
     drift and certify a program nobody serves."""
     from mpi_knn_tpu.serve import build_index
-    from mpi_knn_tpu.serve.engine import SCRATCH_PARAMS, lower_bucket
+    from mpi_knn_tpu.serve.engine import lower_bucket
 
     # degradation-ladder rung programs are ordinary cells of the same
     # cache, lowered at the rung's knob values: the bucket/2 rung halves
@@ -926,10 +926,6 @@ def _lower_serve(target: LintTarget):
         # drops to 1 probe, and at the safe route cap that HALVES both
         # the exchange budget and the rerank working set — the rung must
         # fit its own smaller per-shard bound
-        from mpi_knn_tpu.serve.engine import (
-            SHARDED_SCRATCH_PARAMS,
-            lower_bucket,
-        )
         from mpi_knn_tpu.ivf.sharded import sharded_query_shapes
 
         if target.metric != "l2" or target.dtype != "float32":
@@ -952,7 +948,9 @@ def _lower_serve(target: LintTarget):
             **_ivf_sharded_meta(index, cfg, q_tile, route_cap, q_pad,
                                 bucket),
             "serve": True,
-            "donated_params": SHARDED_SCRATCH_PARAMS if cfg.donate else (),
+            "donated_params": (
+                index.layout.donate_argnums if cfg.donate else ()
+            ),
             "resident_bytes": serve_resident_bytes(index),
         }
         return lowered, cfg, meta
@@ -976,7 +974,9 @@ def _lower_serve(target: LintTarget):
         meta = {
             **_ivf_meta(index, cfg, q_tile, q_pad, bucket),
             "serve": True,
-            "donated_params": SCRATCH_PARAMS if cfg.donate else (),
+            "donated_params": (
+                index.layout.donate_argnums if cfg.donate else ()
+            ),
             "resident_bytes": serve_resident_bytes(index),
         }
         return lowered, cfg, meta
@@ -1058,7 +1058,9 @@ def _lower_serve(target: LintTarget):
         "serve": True,
         # R5: the scratch params MUST carry the donation in the header,
         # and nothing in the batch program may copy the resident corpus
-        "donated_params": SCRATCH_PARAMS if index.cfg.donate else (),
+        "donated_params": (
+            index.layout.donate_argnums if index.cfg.donate else ()
+        ),
         "resident_bytes": serve_resident_bytes(index),
         **_mixed_meta(target, q_tile, index.c_tile),
         **frontend_meta,

@@ -71,6 +71,7 @@ from dataclasses import dataclass, field
 
 from mpi_knn_tpu.analysis import ledger as _ledger
 from mpi_knn_tpu.utils.hlo_graph import HloModule, parse_hlo
+from mpi_knn_tpu.utils.pjrt import pjrt_memory_stats  # noqa: F401 — its old home
 
 # ---------------------------------------------------------------------------
 # shape pricing (kept self-contained: rules.py imports THIS module for R7,
@@ -549,26 +550,6 @@ def peak_budget_bytes(meta: dict, analysis: MemoryAnalysis) -> int:
     else:
         out_allow = analysis.output_bytes
     return analysis.args_bytes + out_allow + temp_budget_bytes(meta)
-
-
-def pjrt_memory_stats(compiled) -> dict | None:
-    """The PJRT side of the cross-check, from one already-compiled
-    executable (zero extra compiles, zero device reads). ``None`` when
-    the runtime cannot answer — absent, never fake zeros."""
-    try:
-        ma = compiled.memory_analysis()
-        return {
-            "argument_bytes": int(ma.argument_size_in_bytes),
-            "output_bytes": int(ma.output_size_in_bytes),
-            "alias_bytes": int(ma.alias_size_in_bytes),
-            "temp_bytes": int(ma.temp_size_in_bytes),
-            "peak_bytes": int(
-                ma.argument_size_in_bytes + ma.output_size_in_bytes
-                - ma.alias_size_in_bytes + ma.temp_size_in_bytes
-            ),
-        }
-    except Exception:  # pragma: no cover - runtime-dependent
-        return None
 
 
 def crosscheck_pjrt(analysis: MemoryAnalysis, pjrt: dict) -> list[str]:

@@ -54,7 +54,11 @@ import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ivf.kmeans import kmeans
-from mpi_knn_tpu.ivf.search import search_ivf
+from mpi_knn_tpu.ivf.search import (
+    ivf_query_shapes,
+    ivf_serve_chunk,
+    search_ivf,
+)
 from mpi_knn_tpu.ops.distance import sq_norms
 from mpi_knn_tpu.ops.quant import (
     QUANT_DTYPES,
@@ -63,6 +67,7 @@ from mpi_knn_tpu.ops.quant import (
     row_wire_bytes,
 )
 from mpi_knn_tpu.parallel.partition import pad_to_multiple
+from mpi_knn_tpu.serve.index import BatchLayout
 
 # held-out sample size for recall-targeted nprobe tuning (the CLI/bench
 # recall-gate convention: enough rows for a stable estimate, cheap enough
@@ -79,12 +84,51 @@ TUNE_SAMPLE = 256
 IVF_DTYPES = ("float32", "bfloat16") + QUANT_DTYPES
 
 
+class IVFLayout(BatchLayout):
+    """The batch program of a clustered store: centroid table + padded
+    buckets resident, queries and scratch as (qt, q_tile, ·) stacks in
+    float32 whatever the store's at-rest width (bf16-rounding the queries
+    would change the math against the one-shot ``search_ivf``)."""
+
+    static_argnames = ("cfg", "nprobe")
+    tiled = True
+
+    def serve_fn(self):
+        return ivf_serve_chunk
+
+    def bucket_shapes(self, index, cfg, bucket):
+        q_tile, q_pad = ivf_query_shapes(
+            cfg, cfg.nprobe, index.bucket_cap, index.dim, bucket
+        )
+        return q_pad, q_tile
+
+    def resident(self, index):
+        return (index.centroids, index.centroid_sqs, index.buckets,
+                index.bucket_ids, index.bucket_sqs, index.bucket_scales)
+
+    def statics(self, index, cfg, bucket):
+        # concrete: compatible_cfg resolves None to the tuned default
+        return dict(cfg=cfg, nprobe=cfg.nprobe)
+
+    def query_dtype(self, cfg):
+        return jnp.dtype("float32")
+
+    carry_dtype = query_dtype
+
+    def stamp_gauges(self, index, cfg, registry):
+        registry.gauge(
+            "ivf_at_rest_bytes",
+            help="resident bytes of the clustered bucket store "
+            "(codes + scales for quantized stores)",
+        ).set(index.nbytes_resident)
+
+
 @dataclasses.dataclass
 class IVFIndex:
     """Resident clustered-index state for one (corpus, config) pair.
 
     Duck-types the corner of ``serve.CorpusIndex`` the serving engine
-    touches (``backend``/``cfg``/``mu``/``m``/``dim``/``_cache``/
+    touches (``layout``/``backend``/``cfg``/``mu``/``m``/``dim``/``_cache``/
     ``compatible_cfg``/``nbytes_resident``), so the bucketed AOT
     executable cache, ``ServeSession`` and ``api.query_knn`` serve it
     unchanged.
@@ -107,6 +151,7 @@ class IVFIndex:
     bucket_scales: jax.Array | None = None  # (P, cap) f32, quantized only
     tuned_recall: float | None = None  # measured recall@k at `nprobe`
     backend: str = "ivf"
+    layout = IVFLayout()  # one for the kind: a class attribute, no field
     # per-index executable cache: {(bucket, cfg) -> engine._BucketExec}
     _cache: dict = dataclasses.field(default_factory=dict)
 

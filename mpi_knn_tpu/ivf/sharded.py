@@ -74,7 +74,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ivf.index import IVFIndex, _refuse_inert_knobs
+from mpi_knn_tpu.ivf.index import IVFIndex, IVFLayout, _refuse_inert_knobs
 from mpi_knn_tpu.ivf.search import finish_candidates, score_centroids
 from mpi_knn_tpu.ops.quant import (
     QUANT_DTYPES,
@@ -379,11 +379,65 @@ _ivf_sharded_jit = jax.jit(
 # The resident sharded index
 
 
+class ShardedIVFLayout(IVFLayout):
+    """The clustered batch program under ``shard_map``: a prepared batch
+    is already (qt, q_tile, d) on the query sharding, and the per-shard
+    exchange stats ride as a THIRD donated scratch so all three outputs
+    alias donated inputs."""
+
+    static_argnames = ("cfg", "nprobe", "mesh", "axis", "shards", "route_cap")
+    donate_argnums = (2, 3, 4)
+    pretiled = True
+    exchange_stats = True
+
+    def serve_fn(self):
+        return ivf_sharded_serve_chunk
+
+    def _shapes(self, index, cfg, bucket):
+        return sharded_query_shapes(
+            cfg, cfg.nprobe, index.bucket_cap, index.dim, bucket, index.shards
+        )
+
+    def bucket_shapes(self, index, cfg, bucket):
+        q_tile, q_pad, _ = self._shapes(index, cfg, bucket)
+        return q_pad, q_tile
+
+    def statics(self, index, cfg, bucket):
+        return dict(
+            cfg=cfg, nprobe=cfg.nprobe, mesh=index.mesh, axis=index.axis,
+            shards=index.shards,
+            route_cap=self._shapes(index, cfg, bucket)[2],
+        )
+
+    def query_sharding(self, index):
+        return NamedSharding(index.mesh, P(index.axis))
+
+    def query_side(self, index, cfg, q_pad, q_tile):
+        return super().query_side(index, cfg, q_pad, q_tile) + [
+            jax.ShapeDtypeStruct(
+                (N_STATS * index.shards,), jnp.int32,
+                sharding=self.query_sharding(index),
+            )
+        ]
+
+    def carry_maker(self, index, cfg, q_pad, q_tile):
+        return scratch_maker(
+            q_pad // q_tile, q_tile, cfg.k, index.shards, index.mesh,
+            index.axis,
+        )
+
+    def exchange_bytes(self, index, cfg, bucket, q_pad, q_tile):
+        return (q_pad // q_tile) * exchange_bytes_per_tile(
+            index.shards, self._shapes(index, cfg, bucket)[2],
+            index.bucket_cap, *exchange_wire_args(index),
+        )
+
+
 @dataclasses.dataclass
 class ShardedIVFIndex:
     """Mesh-resident sharded clustered index. Duck-types the engine corner
-    of :class:`~mpi_knn_tpu.ivf.index.IVFIndex` (``backend``/``cfg``/
-    ``mu``/``m``/``dim``/``_cache``/``compatible_cfg``/
+    of :class:`~mpi_knn_tpu.ivf.index.IVFIndex` (``layout``/``backend``/
+    ``cfg``/``mu``/``m``/``dim``/``_cache``/``compatible_cfg``/
     ``nbytes_resident``) so the bucketed AOT executable cache,
     ``ServeSession`` and ``api.query_knn`` serve it unchanged."""
 
@@ -406,6 +460,7 @@ class ShardedIVFIndex:
     bucket_scales: jax.Array | None = None  # sharded; quantized stores only
     tuned_recall: float | None = None
     backend: str = "ivf-sharded"
+    layout = ShardedIVFLayout()  # one for the kind: a class attribute
     _cache: dict = dataclasses.field(default_factory=dict)
 
     @property

@@ -56,14 +56,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
 
-from mpi_knn_tpu.backends.serial import dist_steps
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
-from mpi_knn_tpu.ops.topk import init_topk, init_topk_tiles, merge_topk
-from mpi_knn_tpu.parallel.partition import pad_rows_any, pad_to_multiple
+from mpi_knn_tpu.parallel.partition import pad_rows_any
 from mpi_knn_tpu.resilience.faults import fault_point, poison_topk
 from mpi_knn_tpu.resilience.heartbeat import maybe_beat
 from mpi_knn_tpu.resilience.ladder import (
@@ -75,6 +72,7 @@ from mpi_knn_tpu.resilience.ladder import (
 from mpi_knn_tpu.resilience.retry import retry_with_backoff
 from mpi_knn_tpu.serve.index import CorpusIndex
 from mpi_knn_tpu.types import KNNResult
+from mpi_knn_tpu.utils.pjrt import pjrt_memory_stats
 from mpi_knn_tpu.utils.timing import device_sync
 
 
@@ -92,104 +90,10 @@ def bucket_rows(n: int, base: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Per-backend serving functions, jitted ONCE per donation mode at module
-# level. All three share the argument convention (queries, query_ids,
-# carry_d, carry_i, <resident index arrays...>) so the scratch donation is
-# uniformly donate_argnums=(2, 3) and the lint engine can lower the exact
-# objects the production cache compiles.
-
-
-def _pallas_serve_fn(
-    queries_p, query_ids, carry_d, carry_i, corpus_p,
-    cfg, q_tile, c_tile, m_corpus, variant,
-):
-    """Pallas batch step: the fused kernel in query mode, its result merged
-    into the (all-inf) donated scratch — a bit-exact no-op merge whose sole
-    purpose is giving the scratch buffers an output to alias (the serial
-    and ring paths thread the scratch through the reduction naturally)."""
-    from mpi_knn_tpu.backends.pallas_backend import _pallas_all_knn
-
-    del query_ids  # query mode: queries carry no corpus identity
-    d, i = _pallas_all_knn(
-        queries_p, corpus_p, cfg, q_tile, c_tile, m_corpus, False, variant
-    )
-    return merge_topk(carry_d, carry_i, d, i, method="exact")
-
-
-def _make_jits(fun, static_argnames):
-    return {
-        donate: jax.jit(
-            fun,
-            static_argnames=static_argnames,
-            donate_argnums=(2, 3) if donate else (),
-        )
-        for donate in (False, True)
-    }
-
-
-def _serial_jits():
-    from mpi_knn_tpu.backends.serial import serve_chunk
-
-    return _make_jits(serve_chunk, ("cfg",))
-
-
-def _ring_jits():
-    from mpi_knn_tpu.backends.ring import ring_serve_sharded
-
-    return _make_jits(
-        ring_serve_sharded,
-        ("cfg", "overlap", "mesh", "axis", "q_tile", "c_tile", "q_axis"),
-    )
-
-
-def _pallas_jits():
-    return _make_jits(
-        _pallas_serve_fn,
-        ("cfg", "q_tile", "c_tile", "m_corpus", "variant"),
-    )
-
-
-def _ivf_jits():
-    from mpi_knn_tpu.ivf.search import ivf_serve_chunk
-
-    return _make_jits(ivf_serve_chunk, ("cfg", "nprobe"))
-
-
-def _ivf_sharded_jits():
-    # the sharded-clustered serve fn carries a THIRD donated scratch (the
-    # per-shard exchange-stats vector) so its three outputs all alias
-    # donated inputs — donate_argnums=(2, 3, 4), not the uniform (2, 3)
-    from mpi_knn_tpu.ivf.sharded import ivf_sharded_serve_chunk
-
-    return {
-        donate: jax.jit(
-            ivf_sharded_serve_chunk,
-            static_argnames=(
-                "cfg", "nprobe", "mesh", "axis", "shards", "route_cap"
-            ),
-            donate_argnums=(2, 3, 4) if donate else (),
-        )
-        for donate in (False, True)
-    }
-
-
-@functools.lru_cache(maxsize=None)
-def _jits(backend: str):
-    if backend == "serial":
-        return _serial_jits()
-    if backend in ("ring", "ring-overlap"):
-        return _ring_jits()
-    if backend == "pallas":
-        return _pallas_jits()
-    if backend == "ivf":
-        return _ivf_jits()
-    if backend == "ivf-sharded":
-        return _ivf_sharded_jits()
-    raise ValueError(f"no serving path for backend {backend!r}")
-
-
-# ---------------------------------------------------------------------------
-# Executable cache
+# Executable cache. What a kind's batch program is — the function to jit,
+# its statics and donation, its argument shapes, its scratch — is its
+# layout's to say (``serve.index.BatchLayout``, carried by the index);
+# everything below reads ``index.layout`` and names no kind.
 
 
 @dataclasses.dataclass
@@ -202,21 +106,16 @@ class _BucketExec:
     q_pad: int
     q_tile: int
     cfg: KNNConfig
-    backend: str
-    q_sharding: object | None = None  # ring: NamedSharding for query-side
-    # the (q_pad,) all−1 query-id vector is identical for every batch of
-    # this executable (serving queries carry no corpus identity) and is
-    # NOT donated — built once here instead of re-uploaded per submit
-    qids: jax.Array | None = None
-    # ring/ivf-sharded only: a once-compiled carry initializer with the
-    # query sharding as out_shardings — the scratch IS donated (fresh
-    # buffers per batch), but building it on the default device and
-    # resharding would pay an allocate-then-copy on every submit
-    make_carry: object | None = None
-    # ivf-sharded only: the resolved static route cap and the (static)
-    # bytes its four all-to-alls move per batch — stamped into the
-    # exchange-bytes counter without reading the device
-    route_cap: int | None = None
+    q_sharding: object | None  # NamedSharding of the query side, if any
+    # the all −1 query-id vector is identical for every batch of this
+    # executable (serving queries carry no corpus identity) and is NOT
+    # donated — built once here instead of re-uploaded per submit
+    qids: jax.Array
+    # the layout's maker of one batch's donated scratch (fresh buffers
+    # per call: the executable consumes them)
+    make_carry: object
+    # the (static) bytes a sharded batch's all-to-alls move — stamped
+    # into the exchange-bytes counter without reading the device
     exchange_bytes: int | None = None
     # how this cell's executable came to exist: "compiled" (a real XLA
     # compile in this process) or "cache-hit" (revived from the
@@ -230,285 +129,49 @@ class _BucketExec:
     peak_hbm_bytes: int = 0
 
 
-def _acc_dtype(cfg: KNNConfig):
-    return jnp.float64 if cfg.dtype == "float64" else jnp.float32
-
-
-def _resident_args(index) -> tuple:
-    """The index-side arguments of one batch program, in call order — the
-    ONE place that order lives: the lowered builders, the dispatch path
-    (``_run``) and the persistent-cache signature check all consume this,
-    so the three can never drift. ``None`` entries (e.g. the scales array
-    of an unquantized ring index) are empty pytree nodes that jax drops
-    from the flattened argument list."""
-    b = index.backend
-    if b == "serial":
-        return (index.tiles, index.tile_ids, index.tile_sqs, index.onepass)
-    if b in ("ring", "ring-overlap"):
-        return (index.corpus_sharded, index.corpus_ids_sharded,
-                index.corpus_scales_sharded)
-    if b == "pallas":
-        return (index.corpus_padded,)
-    # ivf / ivf-sharded share the clustered store layout
-    return (index.centroids, index.centroid_sqs, index.buckets,
-            index.bucket_ids, index.bucket_sqs, index.bucket_scales)
-
-
-def _serial_bucket_shapes(index, cfg: KNNConfig, bucket: int):
-    q_tile = min(cfg.query_tile, pad_to_multiple(bucket, 8))
-    return pad_to_multiple(bucket, q_tile), q_tile
-
-
-def _pallas_bucket_shapes(index, cfg: KNNConfig, bucket: int):
-    q_tile = min(max(8, pad_to_multiple(cfg.query_tile, 8)), 512,
-                 pad_to_multiple(bucket, 8))
-    return pad_to_multiple(bucket, q_tile), q_tile
-
-
-def _ring_bucket_shapes(index, cfg: KNNConfig, bucket: int):
-    q_tile, q_pad = ring_query_shapes(index, cfg, bucket)
-    return q_pad, q_tile
-
-
-def _ivf_bucket_shapes(index, cfg: KNNConfig, bucket: int):
-    from mpi_knn_tpu.ivf.search import ivf_query_shapes
-
-    q_tile, q_pad = ivf_query_shapes(
-        cfg, cfg.nprobe, index.bucket_cap, index.dim, bucket
-    )
-    return q_pad, q_tile
-
-
-def _ivf_sharded_bucket_shapes(index, cfg: KNNConfig, bucket: int):
-    from mpi_knn_tpu.ivf.sharded import sharded_query_shapes
-
-    q_tile, q_pad, _ = sharded_query_shapes(
-        cfg, cfg.nprobe, index.bucket_cap, index.dim, bucket, index.shards
-    )
-    return q_pad, q_tile
-
-
-_BUCKET_SHAPES = {
-    "serial": _serial_bucket_shapes,
-    "ring": _ring_bucket_shapes,
-    "ring-overlap": _ring_bucket_shapes,
-    "pallas": _pallas_bucket_shapes,
-    "ivf": _ivf_bucket_shapes,
-    "ivf-sharded": _ivf_sharded_bucket_shapes,
-}
-
-
 def bucket_shapes(index, cfg: KNNConfig, bucket: int):
     """``(q_pad, q_tile)`` of one (bucket, config) cell — pure shape
-    math, shared by the lowered builders below and the persistent-cache
-    hit path (which must build a dispatchable :class:`_BucketExec`
-    WITHOUT tracing or lowering anything)."""
-    return _BUCKET_SHAPES[index.backend](index, cfg, bucket)
+    math, shared by the lowering below and the persistent-cache hit path
+    (which must build a dispatchable :class:`_BucketExec` WITHOUT tracing
+    or lowering anything)."""
+    return index.layout.bucket_shapes(index, cfg, bucket)
+
+
+def _batch_args(index, cfg: KNNConfig, bucket: int):
+    """One cell's ``(q_pad, q_tile, array arguments in call order)``: the
+    layout's query side, then its resident arrays."""
+    lay = index.layout
+    q_pad, q_tile = lay.bucket_shapes(index, cfg, bucket)
+    return q_pad, q_tile, (
+        *lay.query_side(index, cfg, q_pad, q_tile), *lay.resident(index)
+    )
 
 
 def expected_args(index, cfg: KNNConfig, bucket: int) -> list:
     """The flattened ``(shape, dtype)`` input signature the cell's
-    executable must carry, derived from the same shape helpers and
-    resident-arg order the lowering uses. The persistent AOT cache
-    checks a loaded executable's ``args_info`` against this, so even a
-    fingerprint collision cannot put a mismatched program on the
-    dispatch path."""
-    q_pad, q_tile = bucket_shapes(index, cfg, bucket)
-    acc = str(jnp.dtype(_acc_dtype(cfg)))
-    i32 = "int32"
-    b = index.backend
-    if b in ("serial", "ivf", "ivf-sharded"):
-        qt = q_pad // q_tile
-        qdt = str(jnp.dtype(cfg.dtype)) if b == "serial" else "float32"
-        carry = acc if b == "serial" else "float32"
-        args = [
-            ((qt, q_tile, index.dim), qdt),
-            ((qt, q_tile), i32),
-            ((qt, q_tile, cfg.k), carry),
-            ((qt, q_tile, cfg.k), i32),
-        ]
-        if b == "ivf-sharded":
-            from mpi_knn_tpu.ivf.sharded import N_STATS
-
-            args.append(((N_STATS * index.shards,), i32))
-    else:
-        qdt = "float32" if b == "pallas" else str(jnp.dtype(cfg.dtype))
-        carry = "float32" if b == "pallas" else acc
-        args = [
-            ((q_pad, index.dim), qdt),
-            ((q_pad,), i32),
-            ((q_pad, cfg.k), carry),
-            ((q_pad, cfg.k), i32),
-        ]
-    args.extend(
-        (tuple(int(s) for s in a.shape), str(a.dtype))
-        for a in _resident_args(index)
+    executable must carry: the very arguments :func:`lower_bucket` lowers
+    with. The persistent AOT cache checks a loaded executable's
+    ``args_info`` against this, so even a fingerprint collision cannot
+    put a mismatched program on the dispatch path."""
+    return [
+        (tuple(int(s) for s in a.shape), str(jnp.dtype(a.dtype)))
+        for a in _batch_args(index, cfg, bucket)[2]
         if a is not None
-    )
-    return args
+    ]
 
 
-def _serial_lowered(index: CorpusIndex, cfg: KNNConfig, bucket: int):
-    q_pad, q_tile = _serial_bucket_shapes(index, cfg, bucket)
-    qt = q_pad // q_tile
-    acc = _acc_dtype(cfg)
-    dtype = jnp.dtype(cfg.dtype)
-    sds = jax.ShapeDtypeStruct
-    lowered = _jits("serial")[cfg.donate].lower(
-        sds((qt, q_tile, index.dim), dtype),
-        sds((qt, q_tile), jnp.int32),
-        sds((qt, q_tile, cfg.k), acc),
-        sds((qt, q_tile, cfg.k), jnp.int32),
-        *_resident_args(index),
-        cfg=cfg,
-    )
-    return lowered, q_pad, q_tile
-
-
-def ring_query_shapes(index: CorpusIndex, cfg: KNNConfig, bucket: int):
-    """Per-bucket query tiling against the index's FIXED corpus layout.
-
-    ``ring_tiles`` would re-derive c_tile from the bucket's q_tile, but the
-    resident corpus was padded once at build time — so here only the query
-    side moves, and the per-step tile cap is honored by shrinking q_tile
-    against the frozen c_tile (the cap stays hard either way)."""
-    q_axis, axis, dp, ring_n = index.ring_meta
-    num_dev = dp * ring_n
-    q_tile = min(cfg.query_tile, -(-bucket // num_dev))
-    while q_tile > 1 and q_tile * index.c_tile > cfg.max_tile_elems:
-        q_tile = max(1, q_tile // 2)
-    q_pad = pad_to_multiple(bucket, num_dev * q_tile)
-    return q_tile, q_pad
-
-
-def _ring_lowered(index: CorpusIndex, cfg: KNNConfig, bucket: int):
-    from mpi_knn_tpu.backends.ring import _query_spec
-
-    q_axis, axis, dp, ring_n = index.ring_meta
-    q_tile, q_pad = ring_query_shapes(index, cfg, bucket)
-    qsh = NamedSharding(index.mesh, _query_spec(q_axis, axis))
-    acc = _acc_dtype(cfg)
-    dtype = jnp.dtype(cfg.dtype)
-    sds = jax.ShapeDtypeStruct
-    lowered = _jits(index.backend)[cfg.donate].lower(
-        sds((q_pad, index.dim), dtype, sharding=qsh),
-        sds((q_pad,), jnp.int32, sharding=qsh),
-        sds((q_pad, cfg.k), acc, sharding=qsh),
-        sds((q_pad, cfg.k), jnp.int32, sharding=qsh),
-        *_resident_args(index),
-        cfg,
-        index.backend == "ring-overlap",
-        index.mesh,
-        axis,
-        q_tile,
-        index.c_tile,
-        q_axis=q_axis,
-    )
-    return lowered, q_pad, q_tile
-
-
-def _pallas_lowered(index: CorpusIndex, cfg: KNNConfig, bucket: int):
-    q_pad, q_tile = _pallas_bucket_shapes(index, cfg, bucket)
-    variant = cfg.pallas_variant
-    if variant == "sweep" and cfg.k > index.c_tile:
-        variant = "tiles"  # same corner routing as all_knn_pallas
-    sds = jax.ShapeDtypeStruct
-    lowered = _jits("pallas")[cfg.donate].lower(
-        sds((q_pad, index.dim), jnp.float32),
-        sds((q_pad,), jnp.int32),
-        sds((q_pad, cfg.k), jnp.float32),
-        sds((q_pad, cfg.k), jnp.int32),
-        *_resident_args(index),
-        cfg,
-        q_tile,
-        index.c_tile,
-        index.m,
-        variant,
-    )
-    return lowered, q_pad, q_tile
-
-
-def _ivf_lowered(index, cfg: KNNConfig, bucket: int):
-    """Per-batch program for a clustered (IVF) index — same tiled layout
-    and scratch-donation convention as the serial cell, with the resident
-    arrays being the centroid table and the padded bucket store
-    (``mpi_knn_tpu.ivf``). ``cfg.nprobe`` is concrete here
-    (``IVFIndex.compatible_cfg`` resolves None to the tuned default)."""
-    from mpi_knn_tpu.ivf.search import ivf_query_shapes
-
-    nprobe = cfg.nprobe
-    q_tile, q_pad = ivf_query_shapes(
-        cfg, nprobe, index.bucket_cap, index.dim, bucket
-    )
-    qt = q_pad // q_tile
-    sds = jax.ShapeDtypeStruct
-    lowered = _jits("ivf")[cfg.donate].lower(
-        sds((qt, q_tile, index.dim), jnp.float32),
-        sds((qt, q_tile), jnp.int32),
-        sds((qt, q_tile, cfg.k), jnp.float32),
-        sds((qt, q_tile, cfg.k), jnp.int32),
-        *_resident_args(index),
-        cfg,
-        nprobe,
-    )
-    return lowered, q_pad, q_tile
-
-
-def _ivf_sharded_lowered(index, cfg: KNNConfig, bucket: int):
-    """Per-batch program for a sharded clustered index — the routed
-    two-stage search under shard_map, with the per-shard exchange stats
-    as a third donated scratch (``ivf/sharded.py``)."""
-    from mpi_knn_tpu.ivf.sharded import N_STATS, sharded_query_shapes
-
-    nprobe = cfg.nprobe
-    q_tile, q_pad, route_cap = sharded_query_shapes(
-        cfg, nprobe, index.bucket_cap, index.dim, bucket, index.shards
-    )
-    qt = q_pad // q_tile
-    qsh = NamedSharding(index.mesh, jax.sharding.PartitionSpec(index.axis))
-    sds = jax.ShapeDtypeStruct
-    lowered = _jits("ivf-sharded")[cfg.donate].lower(
-        sds((qt, q_tile, index.dim), jnp.float32, sharding=qsh),
-        sds((qt, q_tile), jnp.int32, sharding=qsh),
-        sds((qt, q_tile, cfg.k), jnp.float32, sharding=qsh),
-        sds((qt, q_tile, cfg.k), jnp.int32, sharding=qsh),
-        sds((N_STATS * index.shards,), jnp.int32, sharding=qsh),
-        *_resident_args(index),
-        cfg,
-        nprobe,
-        index.mesh,
-        index.axis,
-        index.shards,
-        route_cap,
-    )
-    return lowered, q_pad, q_tile
-
-
-_LOWER_BUILDERS = {
-    "serial": _serial_lowered,
-    "ring": _ring_lowered,
-    "ring-overlap": _ring_lowered,
-    "pallas": _pallas_lowered,
-    "ivf": _ivf_lowered,
-    "ivf-sharded": _ivf_sharded_lowered,
-}
-
-
-def lower_bucket(index: CorpusIndex, cfg: KNNConfig, bucket: int):
+def lower_bucket(index, cfg: KNNConfig, bucket: int):
     """The per-batch program for one (bucket, config) cell as a
     ``jax.stages.Lowered`` — the exact object the executable cache
     compiles, exposed so the lint engine (``analysis.lowering``) inspects
     production lowerings rather than a parallel reimplementation. Returns
     ``(lowered, q_pad, q_tile)``."""
-    return _LOWER_BUILDERS[index.backend](index, cfg, bucket)
-
-
-# donate_argnums of every serving function (the carry scratch); the lint
-# engine's R5 reads this to know which parameters MUST carry an alias.
-# The sharded-clustered fn adds the exchange-stats scratch as a third
-# donated param so all three of its outputs alias donated inputs.
-SCRATCH_PARAMS = (2, 3)
-SHARDED_SCRATCH_PARAMS = (2, 3, 4)
+    q_pad, q_tile, args = _batch_args(index, cfg, bucket)
+    lay = index.layout
+    lowered = lay.jit(cfg.donate).lower(
+        *args, **lay.statics(index, cfg, bucket)
+    )
+    return lowered, q_pad, q_tile
 
 
 def _fingerprint_cfg(cfg: KNNConfig) -> KNNConfig:
@@ -659,24 +322,7 @@ def _build_executable(
     # the 2×/4×/8× byte cuts of bf16/int8 transfer and bf16/int8/int4
     # at-rest stores are visible in `mpi-knn metrics` / `--report`
     # next to the recall they paid.
-    if index.backend in ("ring", "ring-overlap"):
-        from mpi_knn_tpu.backends.ring import ring_wire_bytes_per_batch
-
-        ring_n = index.ring_meta[3]
-        reg.gauge(
-            "ring_transfer_wire_bytes",
-            help="bytes one batch's full corpus rotation moves over "
-            "the interconnect, at the wire dtype (static per "
-            "executable)",
-        ).set(ring_wire_bytes_per_batch(
-            cfg, index.corpus_sharded.shape[0], index.dim, ring_n,
-        ))
-    if index.backend in ("ivf", "ivf-sharded"):
-        reg.gauge(
-            "ivf_at_rest_bytes",
-            help="resident bytes of the clustered bucket store "
-            "(codes + scales for quantized stores)",
-        ).set(index.nbytes_resident)
+    index.layout.stamp_gauges(index, cfg, reg)
     # peak-HBM gauge (ISSUE 15): the max static peak across this
     # index's built cells, from the executables' own buffer assignment
     # — the ledger's figure for the production shapes, stamped at
@@ -695,72 +341,29 @@ def _finish_executable(
     source: str,
 ) -> _BucketExec:
     """Wrap a ready executable (freshly compiled OR revived from disk)
-    with the dispatch-side state every batch needs — query shardings,
-    the constant query-id vector, the carry initializer, the sharded
-    exchange accounting. All of it is shape math and small device
+    with the dispatch-side state every batch needs — the query sharding,
+    the constant query-id vector, the scratch maker, the sharded exchange
+    accounting. All of it is the layout's shape math and small device
     constants, none of it needs the lowering."""
-    qsh = None
-    route_cap = exchange_bytes = None
-    if index.backend in ("ring", "ring-overlap"):
-        from mpi_knn_tpu.backends.ring import _query_spec
-
-        q_axis = index.ring_meta[0]
-        qsh = NamedSharding(
-            index.mesh, _query_spec(q_axis, index.ring_meta[1])
-        )
-    # the constant query-id vector is built in numpy and device_put (a
-    # transfer, never an XLA program): on a persistent-cache hit the
-    # whole cell build must count ZERO backend compiles, and an eager
-    # jnp.full here would compile a tiny fill executable
-    qids = jax.device_put(np.full((q_pad,), -1, np.int32))
-    make_carry = None
-    if qsh is not None:
-        qids = jax.device_put(np.full((q_pad,), -1, np.int32), qsh)
-        make_carry = jax.jit(
-            functools.partial(
-                init_topk, q_pad, cfg.k, dtype=_acc_dtype(cfg)
-            ),
-            out_shardings=(qsh, qsh),
-        )
-    if index.backend == "ivf-sharded":
-        from jax.sharding import PartitionSpec
-        from mpi_knn_tpu.ivf.sharded import (
-            exchange_bytes_per_tile,
-            exchange_wire_args,
-            scratch_maker,
-            sharded_query_shapes,
-        )
-
-        qsh = NamedSharding(index.mesh, PartitionSpec(index.axis))
-        qt = q_pad // q_tile
-        _, _, route_cap = sharded_query_shapes(
-            cfg, cfg.nprobe, index.bucket_cap, index.dim, bucket,
-            index.shards,
-        )
-        wire_dim, wire_itemsize, wire_scale = exchange_wire_args(
-            index
-        )
-        exchange_bytes = qt * exchange_bytes_per_tile(
-            index.shards, route_cap, index.bucket_cap, wire_dim,
-            wire_itemsize, wire_scale,
-        )
-        qids = jax.device_put(
-            np.full((qt, q_tile), -1, np.int32), qsh
-        )
-        make_carry = scratch_maker(
-            qt, q_tile, cfg.k, index.shards, index.mesh, index.axis
-        )
+    lay = index.layout
+    qsh = lay.query_sharding(index)
     # the executable's static peak HBM (ISSUE 15) — PJRT answers from
     # the compiled binary's own buffer assignment, so the figure costs
     # zero device reads and is identical for a fresh compile and an
     # AOT-cache revival of the same program
-    from mpi_knn_tpu.analysis.memory import pjrt_memory_stats
-
     stats = pjrt_memory_stats(compiled)
     return _BucketExec(
-        compiled, bucket, q_pad, q_tile, cfg, index.backend,
-        q_sharding=qsh, qids=qids, make_carry=make_carry,
-        route_cap=route_cap, exchange_bytes=exchange_bytes,
+        compiled, bucket, q_pad, q_tile, cfg,
+        q_sharding=qsh,
+        # built in numpy and device_put (a transfer, never an XLA
+        # program), in the shape a prepared batch has: on a persistent-
+        # cache hit the whole cell build must count ZERO backend
+        # compiles, and an eager jnp.full here would compile a tiny fill
+        qids=jax.device_put(
+            np.full(lay.prepared_rows(q_pad, q_tile), -1, np.int32), qsh
+        ),
+        make_carry=lay.carry_maker(index, cfg, q_pad, q_tile),
+        exchange_bytes=lay.exchange_bytes(index, cfg, bucket, q_pad, q_tile),
         source=source,
         peak_hbm_bytes=stats["peak_bytes"] if stats else 0,
     )
@@ -785,25 +388,21 @@ def index_peak_hbm_bytes(index) -> int:
 # Batch preparation and dispatch
 
 
-def _prep_queries(index: CorpusIndex, cfg: KNNConfig, exec_: _BucketExec, q):
+def _prep_queries(index, cfg: KNNConfig, exec_: _BucketExec, q):
     """Center + pad one batch to the executable's padded row count and move
     it on device, engine-owned. Host batches are centered/padded in numpy
     (one H2D of a bucket-stable shape — no per-raw-size device programs);
     device batches stay on device (ops cached per raw shape after first
-    sight). Returns (q2d, qids, rows)."""
+    sight). Returns (queries, qids, rows)."""
     rows = q.shape[0]
     if rows > exec_.q_pad:
         raise ValueError(
             f"batch of {rows} rows exceeds the executable's bucket "
             f"({exec_.q_pad} padded rows)"
         )
-    # an IVF index's dtype is the bucket store's AT-REST width; its search
-    # computes (and takes queries) in f32 — bf16-rounding the queries here
-    # would silently change the math vs the one-shot search_ivf path
-    dtype = (
-        jnp.float32 if exec_.backend in ("ivf", "ivf-sharded")
-        else jnp.dtype(cfg.dtype)
-    )
+    lay = index.layout
+    dtype = lay.query_dtype(cfg)
+    shape = lay.prepared_rows(exec_.q_pad, exec_.q_tile) + (index.dim,)
     on_device = isinstance(q, jax.Array)
     if cfg.center and cfg.metric == "l2" and index.mu is not None:
         # same op order as all_knn's center_for_l2 on each residency, so
@@ -811,122 +410,57 @@ def _prep_queries(index: CorpusIndex, cfg: KNNConfig, exec_: _BucketExec, q):
         q = q - index.mu if (on_device or isinstance(index.mu, jax.Array)) \
             else np.asarray(q) - index.mu
         on_device = isinstance(q, jax.Array)
-    if exec_.backend == "ivf-sharded":
-        # tiles shaped on host when possible (one H2D straight onto the
-        # query sharding, zero per-shape reshape programs); a device
-        # batch pays a shard-local reshape op, cached per bucket shape
-        qt = exec_.q_pad // exec_.q_tile
-        if on_device:
-            q3 = pad_rows_any(q, exec_.q_pad, dtype=dtype).reshape(
-                qt, exec_.q_tile, index.dim
-            )
-        else:
-            qh = np.asarray(q, dtype=dtype)
-            q3 = np.pad(qh, ((0, exec_.q_pad - rows), (0, 0))).reshape(
-                qt, exec_.q_tile, index.dim
-            )
-        return jax.device_put(q3, exec_.q_sharding), exec_.qids, rows
     if on_device:
-        q2d = pad_rows_any(q, exec_.q_pad, dtype=dtype)
+        qp = pad_rows_any(q, exec_.q_pad, dtype=dtype)
+        if lay.pretiled:
+            # a shard-local reshape op, cached per bucket shape
+            qp = qp.reshape(shape)
         if exec_.q_sharding is not None:
-            q2d = jax.device_put(q2d, exec_.q_sharding)
+            qp = jax.device_put(qp, exec_.q_sharding)
     else:
         qh = np.asarray(q)
-        pad = exec_.q_pad - rows
-        if pad:
-            qh = np.pad(qh, ((0, pad), (0, 0)))
+        if rows < exec_.q_pad:
+            qh = np.pad(qh, ((0, exec_.q_pad - rows), (0, 0)))
         if exec_.q_sharding is not None:
-            # one transfer, straight onto the ring sharding: casting on
-            # host first avoids the default-device upload that a
-            # jnp.asarray → device_put resharding pair would pay twice
-            q2d = jax.device_put(qh.astype(dtype), exec_.q_sharding)
+            # one transfer, straight onto the query sharding, tiles shaped
+            # on host: casting here first avoids the default-device upload
+            # that a jnp.asarray → device_put resharding pair would pay
+            # twice, and a host reshape is no device program
+            qp = jax.device_put(
+                qh.astype(dtype).reshape(shape), exec_.q_sharding
+            )
         else:
-            q2d = jnp.asarray(qh, dtype=dtype)
-    return q2d, exec_.qids, rows
+            qp = jnp.asarray(qh, dtype=dtype)
+    return qp, exec_.qids, rows
 
 
-def _run(index: CorpusIndex, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
-    """Issue one padded batch on the compiled executable; returns padded
+def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
+    """Issue one prepared batch on the compiled executable; returns padded
     ((q_pad, k) dists, ids, exchange_stats-or-None, dist_steps-or-None)
-    device results (async — not synchronized here). The stats slot is
-    populated only by the sharded-clustered backend (its per-shard
-    (N_STATS·S,) vector); ``dist_steps`` is the batch's
-    ``backends.serial.dist_steps``, for the layouts whose batches run
-    ``masked_dist_tile`` (serial, ring).
+    device results (async — not synchronized here). The stats slot is the
+    per-shard (N_STATS·S,) vector of a layout with ``exchange_stats``;
+    ``dist_steps`` is the batch's ``backends.serial.dist_steps``, for the
+    layouts whose batches run ``masked_dist_tile``.
     Dispatch serializes with live mutation on the per-index mutation
     lock — the resident args are read and the batch enqueued as one
     atomic step w.r.t. any in-place store update."""
+    lay = index.layout
     with mutation_lock(index):
-        return _run_locked(index, cfg, exec_, q2d, qids)
-
-
-def _run_locked(index, cfg: KNNConfig, exec_: _BucketExec, q2d, qids):
-    acc = _acc_dtype(cfg)
-    if exec_.backend == "serial":
-        qt = exec_.q_pad // exec_.q_tile
-        carry_d, carry_i = init_topk_tiles(qt, exec_.q_tile, cfg.k, dtype=acc)
-        # an index that holds the one-pass fact has a third output: the
-        # batch's tile steps by the branch they took
-        d, i, *steps = exec_.compiled(
-            q2d.reshape(qt, exec_.q_tile, index.dim),
-            qids.reshape(qt, exec_.q_tile),
-            carry_d,
-            carry_i,
-            *_resident_args(index),
+        scratch = exec_.make_carry()
+        if lay.tiled and not lay.pretiled:
+            tiles = lay.rows(exec_.q_pad, exec_.q_tile)
+            q, qids = q.reshape(*tiles, index.dim), qids.reshape(tiles)
+        d, i, *rest = exec_.compiled(
+            q, qids, *scratch, *lay.resident(index)
         )
+        if lay.tiled:
+            d = d.reshape(exec_.q_pad, cfg.k)
+            i = i.reshape(exec_.q_pad, cfg.k)
         return (
-            d.reshape(exec_.q_pad, cfg.k),
-            i.reshape(exec_.q_pad, cfg.k),
-            None,
-            steps[0] if steps else dist_steps(qt, index.tiles.shape[0]),
+            d, i,
+            rest[0] if lay.exchange_stats else None,
+            lay.batch_dist_steps(index, exec_.q_pad, exec_.q_tile, rest),
         )
-    if exec_.backend == "ivf":
-        qt = exec_.q_pad // exec_.q_tile
-        carry_d, carry_i = init_topk_tiles(
-            qt, exec_.q_tile, cfg.k, dtype=jnp.float32
-        )
-        d, i = exec_.compiled(
-            q2d.reshape(qt, exec_.q_tile, index.dim),
-            qids.reshape(qt, exec_.q_tile),
-            carry_d,
-            carry_i,
-            *_resident_args(index),
-        )
-        return (
-            d.reshape(exec_.q_pad, cfg.k),
-            i.reshape(exec_.q_pad, cfg.k),
-            None,
-            None,
-        )
-    if exec_.backend == "ivf-sharded":
-        # q2d arrives pre-tiled (QT, q_tile, d) on the query sharding
-        carry_d, carry_i, stats0 = exec_.make_carry()
-        d, i, stats = exec_.compiled(
-            q2d, qids, carry_d, carry_i, stats0, *_resident_args(index),
-        )
-        return (
-            d.reshape(exec_.q_pad, cfg.k),
-            i.reshape(exec_.q_pad, cfg.k),
-            stats,
-            None,
-        )
-    if exec_.backend in ("ring", "ring-overlap"):
-        # scratch born directly under the query sharding (no allocate-
-        # then-reshard per batch); fresh buffers every call because the
-        # executable consumes them (donation)
-        carry_d, carry_i = exec_.make_carry()
-        d, i = exec_.compiled(
-            q2d, qids, carry_d, carry_i, *_resident_args(index),
-        )
-        return d, i, None, dist_steps(
-            exec_.q_pad // exec_.q_tile,
-            index.corpus_sharded.shape[0] // index.c_tile,
-        )
-    carry_d, carry_i = init_topk(exec_.q_pad, cfg.k, dtype=acc)
-    d, i = exec_.compiled(
-        q2d, qids, carry_d, carry_i, *_resident_args(index)
-    )
-    return d, i, None, None
 
 
 @dataclasses.dataclass
@@ -1174,7 +708,7 @@ class ServeSession:
         # story (routed/dropped totals, static exchange bytes, per-shard
         # served-request load) for the CLI report; None elsewhere
         self.exchange: dict | None = None
-        if getattr(index, "backend", None) == "ivf-sharded":
+        if index.layout.exchange_stats:
             self.exchange = {
                 "shards": index.shards,
                 "routed_total": 0,
@@ -1821,12 +1355,12 @@ class ServeSession:
         label, cfg = self._current_rung()
         # the batch span opens BEFORE the dispatch attempt: a hang inside
         # the dispatch leaves an OPEN "batch" record in the flight file —
-        # the kill diagnosis a supervisor banks (ISSUE 7). Sharded-
-        # clustered sessions stamp the shard topology on the span: an
+        # the kill diagnosis a supervisor banks (ISSUE 7). Sessions with
+        # an exchange stamp the shard topology on the span: an
         # open span plus the last retired batch's per-shard exchange
         # event is how a flight reader attributes a hang to a shard.
         span_attrs = {}
-        if self.index.backend == "ivf-sharded":
+        if self.index.layout.exchange_stats:
             span_attrs["shards"] = self.index.shards
         if tenants is not None:
             # the batch span carries the tenant composition: a hang's

@@ -28,6 +28,7 @@ never collide on a cache entry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -35,6 +36,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from mpi_knn_tpu.backends.serial import (
+    cap_corpus_tile,
+    dist_steps,
+    serve_chunk,
+)
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.ops.distance import (
@@ -42,12 +48,262 @@ from mpi_knn_tpu.ops.distance import (
     onepass_applies,
     sq_norms,
 )
-from mpi_knn_tpu.ops.topk import start_lane_bin_import
+from mpi_knn_tpu.ops.topk import (
+    init_topk,
+    init_topk_tiles,
+    merge_topk,
+    start_lane_bin_import,
+)
 from mpi_knn_tpu.parallel.partition import (
     make_global_ids,
     pad_rows_any,
     pad_to_multiple,
 )
+
+
+class BatchLayout:
+    """What one index kind's batch program is and how it is called. One
+    instance per kind, chosen when the index is built and carried by it
+    (``index.layout``): ``serve.engine`` lowers, signature-checks, prepares
+    and dispatches every kind through these answers and never asks a
+    kind's name, so a new kind is one subclass beside its arrays.
+
+    Every program is called ``fn(queries, query_ids, *scratch, *resident,
+    **statics)``. The defaults are the flat dense convention: ``(q_pad, d)``
+    rows in the config's dtype on the default device, an eager
+    ``(q_pad, k)`` scratch pair in the accumulation dtype, two outputs."""
+
+    static_argnames: tuple = ("cfg",)
+    donate_argnums: tuple = (2, 3)  # the scratch; R5 wants each one aliased
+    tiled = False  # the program takes (qt, q_tile, ·) stacks, not rows ...
+    pretiled = False  # ... and a prepared batch already has that shape
+    exchange_stats = False  # third output: the per-shard exchange stats
+
+    def serve_fn(self):
+        """The function the engine jits."""
+        raise NotImplementedError
+
+    def bucket_shapes(self, index, cfg: KNNConfig, bucket: int):
+        """``(q_pad, q_tile)`` of one (bucket, config) cell: shape math."""
+        raise NotImplementedError
+
+    def resident(self, index) -> tuple:
+        """The index-side arguments in call order. ``None`` entries (the
+        scales of an unquantized store) are empty pytree nodes that jax
+        drops from the flattened argument list."""
+        raise NotImplementedError
+
+    def statics(self, index, cfg: KNNConfig, bucket: int) -> dict:
+        return {"cfg": cfg}
+
+    def query_dtype(self, cfg: KNNConfig):
+        return jnp.dtype(cfg.dtype)
+
+    def carry_dtype(self, cfg: KNNConfig):
+        return jnp.dtype("float64" if cfg.dtype == "float64" else "float32")
+
+    def query_sharding(self, index):
+        return None
+
+    @functools.lru_cache(maxsize=None)  # layouts are per-kind constants
+    def jit(self, donate: bool):
+        return jax.jit(
+            self.serve_fn(),
+            static_argnames=self.static_argnames,
+            donate_argnums=self.donate_argnums if donate else (),
+        )
+
+    def rows(self, q_pad: int, q_tile: int) -> tuple:
+        """Leading shape of the program's batch-owned arguments."""
+        return (q_pad // q_tile, q_tile) if self.tiled else (q_pad,)
+
+    def prepared_rows(self, q_pad: int, q_tile: int) -> tuple:
+        """Leading shape of a prepared batch: flat rows, which a tiled
+        program gets reshaped at dispatch, unless the layout wants its
+        batches tiled from the start (on a sharding, where a reshape
+        would be a program)."""
+        return self.rows(q_pad, q_tile) if self.pretiled else (q_pad,)
+
+    def query_side(self, index, cfg: KNNConfig, q_pad: int, q_tile: int):
+        """The batch-owned arguments (queries, ids, scratch) as
+        ``ShapeDtypeStruct``s on their sharding: the ONE description the
+        lowering and the persistent cache's signature check both read."""
+        rows = self.rows(q_pad, q_tile)
+        sds = functools.partial(
+            jax.ShapeDtypeStruct, sharding=self.query_sharding(index)
+        )
+        return [
+            sds(rows + (index.dim,), self.query_dtype(cfg)),
+            sds(rows, jnp.int32),
+            sds(rows + (cfg.k,), self.carry_dtype(cfg)),
+            sds(rows + (cfg.k,), jnp.int32),
+        ]
+
+    def carry_maker(self, index, cfg: KNNConfig, q_pad: int, q_tile: int):
+        """A zero-argument maker of one batch's donated scratch (fresh
+        buffers every call: the executable consumes them). Eager on the
+        default device; on a query sharding a once-compiled program that
+        bears the scratch there, because building it on the default device
+        and resharding would allocate and copy on every batch."""
+        init = functools.partial(
+            init_topk_tiles if self.tiled else init_topk,
+            *self.rows(q_pad, q_tile), cfg.k, dtype=self.carry_dtype(cfg),
+        )
+        qsh = self.query_sharding(index)
+        return init if qsh is None else jax.jit(
+            init, out_shardings=(qsh, qsh)
+        )
+
+    def exchange_bytes(self, index, cfg, bucket, q_pad, q_tile):
+        """Static bytes one batch's exchange collectives move, or None."""
+        return None
+
+    def stamp_gauges(self, index, cfg: KNNConfig, registry) -> None:
+        """The kind's compression gauges, stamped at build (shape math)."""
+
+    def batch_dist_steps(self, index, q_pad: int, q_tile: int, rest: tuple):
+        """The batch's ``backends.serial.dist_steps`` for the kinds whose
+        batches run ``masked_dist_tile``; ``rest`` is what the program
+        returned beyond (dists, ids)."""
+        return None
+
+
+class SerialLayout(BatchLayout):
+    """The tile stack + ids + norms of one device (``backends.serial``)."""
+
+    tiled = True
+
+    def serve_fn(self):
+        return serve_chunk
+
+    def bucket_shapes(self, index, cfg, bucket):
+        q_tile = min(cfg.query_tile, pad_to_multiple(bucket, 8))
+        return pad_to_multiple(bucket, q_tile), q_tile
+
+    def resident(self, index):
+        return (index.tiles, index.tile_ids, index.tile_sqs, index.onepass)
+
+    def batch_dist_steps(self, index, q_pad, q_tile, rest):
+        # an index that holds the one-pass fact has a third output: the
+        # batch's tile steps by the branch they took
+        return rest[0] if rest else dist_steps(
+            q_pad // q_tile, index.tiles.shape[0]
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class RingLayout(BatchLayout):
+    """The padded corpus sharded over the ring axis (``backends.ring``),
+    blocking or overlapped rotation."""
+
+    overlap: bool
+    static_argnames = (
+        "cfg", "overlap", "mesh", "axis", "q_tile", "c_tile", "q_axis"
+    )
+
+    def serve_fn(self):
+        from mpi_knn_tpu.backends.ring import ring_serve_sharded
+
+        return ring_serve_sharded
+
+    def bucket_shapes(self, index, cfg, bucket):
+        # ``ring_tiles`` would re-derive c_tile from the bucket's q_tile,
+        # but the resident corpus was padded once at build time: only the
+        # query side moves, and the per-step tile cap is honored by
+        # shrinking q_tile against the frozen c_tile
+        _, _, dp, ring_n = index.ring_meta
+        num_dev = dp * ring_n
+        q_tile = min(cfg.query_tile, -(-bucket // num_dev))
+        while q_tile > 1 and q_tile * index.c_tile > cfg.max_tile_elems:
+            q_tile = max(1, q_tile // 2)
+        return pad_to_multiple(bucket, num_dev * q_tile), q_tile
+
+    def resident(self, index):
+        return (index.corpus_sharded, index.corpus_ids_sharded,
+                index.corpus_scales_sharded)
+
+    def statics(self, index, cfg, bucket):
+        q_axis, axis, _, _ = index.ring_meta
+        return dict(
+            cfg=cfg, overlap=self.overlap, mesh=index.mesh, axis=axis,
+            q_tile=self.bucket_shapes(index, cfg, bucket)[1],
+            c_tile=index.c_tile, q_axis=q_axis,
+        )
+
+    def query_sharding(self, index):
+        from mpi_knn_tpu.backends.ring import _query_spec
+
+        return NamedSharding(index.mesh, _query_spec(*index.ring_meta[:2]))
+
+    def stamp_gauges(self, index, cfg, registry):
+        from mpi_knn_tpu.backends.ring import ring_wire_bytes_per_batch
+
+        registry.gauge(
+            "ring_transfer_wire_bytes",
+            help="bytes one batch's full corpus rotation moves over "
+            "the interconnect, at the wire dtype (static per "
+            "executable)",
+        ).set(ring_wire_bytes_per_batch(
+            cfg, index.corpus_sharded.shape[0], index.dim,
+            index.ring_meta[3],
+        ))
+
+    def batch_dist_steps(self, index, q_pad, q_tile, rest):
+        return dist_steps(
+            q_pad // q_tile, index.corpus_sharded.shape[0] // index.c_tile
+        )
+
+
+def _pallas_serve_fn(
+    queries_p, query_ids, carry_d, carry_i, corpus_p,
+    cfg, q_tile, c_tile, m_corpus, variant,
+):
+    """Pallas batch step: the fused kernel in query mode, its result merged
+    into the (all-inf) donated scratch — a bit-exact no-op merge whose sole
+    purpose is giving the scratch buffers an output to alias (the serial
+    and ring paths thread the scratch through the reduction naturally)."""
+    from mpi_knn_tpu.backends.pallas_backend import _pallas_all_knn
+
+    del query_ids  # query mode: queries carry no corpus identity
+    d, i = _pallas_all_knn(
+        queries_p, corpus_p, cfg, q_tile, c_tile, m_corpus, False, variant
+    )
+    return merge_topk(carry_d, carry_i, d, i, method="exact")
+
+
+class PallasLayout(BatchLayout):
+    """The padded f32 corpus of the fused kernels (``ops.pallas_knn``)."""
+
+    static_argnames = ("cfg", "q_tile", "c_tile", "m_corpus", "variant")
+
+    def serve_fn(self):
+        return _pallas_serve_fn
+
+    def bucket_shapes(self, index, cfg, bucket):
+        q_tile = min(max(8, pad_to_multiple(cfg.query_tile, 8)), 512,
+                     pad_to_multiple(bucket, 8))
+        return pad_to_multiple(bucket, q_tile), q_tile
+
+    def resident(self, index):
+        return (index.corpus_padded,)
+
+    def statics(self, index, cfg, bucket):
+        variant = cfg.pallas_variant
+        if variant == "sweep" and cfg.k > index.c_tile:
+            variant = "tiles"  # same corner routing as all_knn_pallas
+        return dict(
+            cfg=cfg, q_tile=self.bucket_shapes(index, cfg, bucket)[1],
+            c_tile=index.c_tile, m_corpus=index.m, variant=variant,
+        )
+
+    def query_dtype(self, cfg):
+        return jnp.dtype("float32")  # the kernels compute in float32
+
+    carry_dtype = query_dtype
+
+
+SERIAL, PALLAS = SerialLayout(), PallasLayout()
+RING, RING_OVERLAP = RingLayout(overlap=False), RingLayout(overlap=True)
 
 
 @dataclasses.dataclass
@@ -65,6 +321,7 @@ class CorpusIndex:
     dim: int
     c_tile: int
     mu: object | None  # centering mean (host f64 or device), or None
+    layout: BatchLayout  # the kind's batch program, chosen at build
     # serial/pallas layout
     tiles: jax.Array | None = None  # (T, c_tile, d)
     tile_ids: jax.Array | None = None  # (T, c_tile)
@@ -218,7 +475,7 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
 
             raise blocking_undefined_on_mesh_error(mesh.axis_names)
         # corpus-side padding only: the query-side tile/pad is bucket-
-        # dependent and computed per executable (engine.ring_query_shapes);
+        # dependent and computed per executable (RingLayout.bucket_shapes);
         # ring_tiles with nq=query_bucket fixes c_tile/c_pad for the index
         _, c_tile, _, c_pad = ring_tiles(cfg, m, cfg.query_bucket, dp, ring_n)
         dtype = jnp.dtype(cfg.dtype)
@@ -239,6 +496,7 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
         return CorpusIndex(
             cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
             c_tile=c_tile, mu=mu, mesh=mesh,
+            layout=RING_OVERLAP if backend == "ring-overlap" else RING,
             ring_meta=(q_axis, axis, dp, ring_n),
             corpus_sharded=corpus_p, corpus_ids_sharded=corpus_ids,
             corpus_scales_sharded=corpus_scales,
@@ -263,13 +521,11 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
         corpus_p = pad_rows_any(corpus, c_pad, dtype=jnp.float32)
         return CorpusIndex(
             cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
-            c_tile=c_tile, mu=mu, corpus_padded=corpus_p,
+            c_tile=c_tile, mu=mu, layout=PALLAS, corpus_padded=corpus_p,
         )
 
     # serial: the tile stack + ids + NORMS, all resident (norms are the
     # O(m·d) reduction all_knn redoes per call — here they are index state)
-    from mpi_knn_tpu.backends.serial import cap_corpus_tile
-
     dtype = jnp.dtype(cfg.dtype)
     c_tile = cap_corpus_tile(
         cfg.query_tile,
@@ -298,6 +554,6 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     )
     return CorpusIndex(
         cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
-        c_tile=c_tile, mu=mu, tiles=tiles, tile_ids=tile_ids,
+        c_tile=c_tile, mu=mu, layout=SERIAL, tiles=tiles, tile_ids=tile_ids,
         tile_sqs=tile_sqs, onepass=onepass,
     )

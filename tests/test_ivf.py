@@ -462,7 +462,7 @@ def test_sift32k_recall_target_with_sublinear_probed_bytes():
         hlo_texts,
     )
     from mpi_knn_tpu.data.synthetic import make_sift_like
-    from mpi_knn_tpu.serve.engine import SCRATCH_PARAMS, lower_bucket
+    from mpi_knn_tpu.serve.engine import lower_bucket
 
     X = make_sift_like(m=32768, d=128, seed=0)
     cfg = KNNConfig(k=K, partitions=64, kmeans_iters=10, query_bucket=256)
@@ -495,7 +495,7 @@ def test_sift32k_recall_target_with_sublinear_probed_bytes():
     meta = {
         **_ivf_meta(idx, serve_cfg, q_tile, q_pad, 256),
         "serve": True,
-        "donated_params": SCRATCH_PARAMS,
+        "donated_params": idx.layout.donate_argnums,
         "resident_bytes": idx.nbytes_resident,
     }
     probe_budget_bytes = meta["budget_elems"] * meta["acc_bytes"]

@@ -707,10 +707,7 @@ def test_sift32k_sharded_acceptance(compile_counter):
     from mpi_knn_tpu.data.synthetic import make_sift_like
     from mpi_knn_tpu.ivf.sharded import sharded_query_shapes
     from mpi_knn_tpu.serve import ServeSession
-    from mpi_knn_tpu.serve.engine import (
-        SHARDED_SCRATCH_PARAMS,
-        lower_bucket,
-    )
+    from mpi_knn_tpu.serve.engine import lower_bucket
 
     X = make_sift_like(m=32768, d=128, seed=0)
     cfg = KNNConfig(k=K, partitions=64, kmeans_iters=10, query_bucket=256,
@@ -758,7 +755,7 @@ def test_sift32k_sharded_acceptance(compile_counter):
     meta = {
         **_ivf_sharded_meta(sidx, serve_cfg, q_tile, route_cap, q_pad, 256),
         "serve": True,
-        "donated_params": SHARDED_SCRATCH_PARAMS,
+        "donated_params": sidx.layout.donate_argnums,
         "resident_bytes": sidx.nbytes_resident,
     }
     assert sidx.probe_bytes < 0.25 * sidx.shard_nbytes_resident, (

@@ -444,7 +444,7 @@ def test_sift32k_int4_acceptance_gate():
     )
     from mpi_knn_tpu.data.synthetic import make_sift_like
     from mpi_knn_tpu.ivf import build_ivf_index, search_ivf
-    from mpi_knn_tpu.serve.engine import SCRATCH_PARAMS, lower_bucket
+    from mpi_knn_tpu.serve.engine import lower_bucket
 
     X = make_sift_like(m=32768, d=128, seed=0)
     cfg = KNNConfig(k=K, partitions=64, kmeans_iters=10, query_bucket=256,
@@ -480,7 +480,7 @@ def test_sift32k_int4_acceptance_gate():
     meta = {
         **_ivf_meta(idx, serve_cfg, q_tile, q_pad, 256),
         "serve": True,
-        "donated_params": SCRATCH_PARAMS,
+        "donated_params": idx.layout.donate_argnums,
         # the f32-EQUIVALENT copy threshold: a quantized store's own
         # wire-width probe gather legitimately exceeds the compressed
         # residency (see lowering.serve_resident_bytes)

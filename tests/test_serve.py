@@ -11,6 +11,8 @@ diverge), so any difference is a real divergence, not noise. Data is
 random normal — no distance ties, so merge order cannot permute ids.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -401,6 +403,57 @@ def test_get_executable_shapes(rng):
         ex = get_executable(idx, idx.cfg, bucket)
         assert ex.q_pad >= bucket
         assert ex.q_pad % ex.q_tile == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _index_of(backend):
+    """One small index per backend name, built the way its own tests build
+    it (the clustered kinds as test_ivf / test_ivf_sharded do)."""
+    X = _data(np.random.default_rng(0))
+    if backend.startswith("ivf"):
+        from mpi_knn_tpu.ivf import build_ivf_index
+
+        return build_ivf_index(X, KNNConfig(
+            k=4, partitions=8, nprobe=2, query_bucket=16,
+            ivf_shards=4 if backend == "ivf-sharded" else None,
+        ))
+    return build_index(X, _cfg(backend))
+
+
+@pytest.mark.parametrize("bucket", [16, 256])
+@pytest.mark.parametrize(
+    "backend",
+    ["serial", "ring", "ring-overlap", "pallas", "ivf", "ivf-sharded"],
+)
+def test_layout_contract(backend, bucket):
+    """What a kind's layout says of its batch program is what the program
+    lowered from it carries: the signature the persistent cache checks,
+    the shapes a cache hit rebuilds its dispatch state from, and the
+    donated scratch."""
+    from mpi_knn_tpu.analysis.rules import donor_params, output_aliases
+    from mpi_knn_tpu.serve.engine import (
+        bucket_shapes,
+        expected_args,
+        lower_bucket,
+    )
+    from mpi_knn_tpu.utils.hlo_graph import parse_hlo
+
+    idx = _index_of(backend)
+    assert idx.backend == backend
+    cfg = idx.compatible_cfg(idx.cfg)
+    lowered, q_pad, q_tile = lower_bucket(idx, cfg, bucket)
+    assert bucket_shapes(idx, cfg, bucket) == (q_pad, q_tile)
+    args = jax.tree.leaves(lowered.args_info)
+    assert expected_args(idx, cfg, bucket) == [
+        (tuple(a.shape), str(a.dtype)) for a in args
+    ]
+    donated = idx.layout.donate_argnums
+    assert tuple(n for n, a in enumerate(args) if a.donated) == donated
+    # and the module header carries one alias (or, before a sharded
+    # program is optimized, one buffer_donor) for each of them
+    mod = parse_hlo(lowered.compiler_ir(dialect="hlo").as_hlo_text())
+    carried = set(output_aliases(mod).values()) | donor_params(mod)
+    assert len(carried) == len(donated)
 
 
 # ---------------------------------------------------------------------------
