@@ -55,6 +55,7 @@ import sys
 import tempfile
 import time
 import traceback
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -100,6 +101,33 @@ def compare_neighbors(ids, dists, ref_ids, ref_dists) -> tuple[float, str]:
 
 # ---------------------------------------------------------------------------
 # the compute child: device, allknn, pallas, ring — one process, one chip
+
+
+def per_call_passes(X, nq: int, cfg):
+    """The first ``nq`` rows of device array ``X`` against all of it, by the
+    passes every call made over its corpus before one was kept
+    (``center_for_l2`` + ``prepare_tiles`` + ``knn_chunk_update``)."""
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.backends.serial import (
+        effective_tiles,
+        knn_chunk_update,
+        onepass_rule,
+        prepare_tiles,
+    )
+    from mpi_knn_tpu.ops.distance import center_for_l2, onepass_fact
+    from mpi_knn_tpu.ops.topk import init_topk_tiles
+
+    corpus, queries, fact, _ = center_for_l2(X, X[:nq], all_pairs=False)
+    q_tile, c_tile = effective_tiles(cfg, X.shape[0], nq)
+    q_tiles, qid_tiles, c_tiles, c_ids, q_pad = prepare_tiles(
+        corpus, queries, np.arange(nq, dtype=np.int32), cfg, q_tile, c_tile)
+    carry = init_topk_tiles(q_pad // q_tile, q_tile, cfg.k, dtype=jnp.float32)
+    one = onepass_fact(cfg, fact) if onepass_rule(cfg, q_tile) else None
+    d, i, *_ = knn_chunk_update(
+        q_tiles, qid_tiles, c_tiles, c_ids, *carry, cfg, one)
+    return SimpleNamespace(ids=i.reshape(q_pad, cfg.k)[:nq],
+                           dists=d.reshape(q_pad, cfg.k)[:nq])
 
 
 def compute_child(args) -> int:
@@ -216,11 +244,41 @@ def compute_phases(args, platform, out, record) -> None:
     W = jnp.asarray(np.random.default_rng(2).integers(
         0, 256, (4096, X.shape[1])).astype(np.float32))
     rule_facts = [bool(center_corpus(w)[2]) for w in (W, W + 0.25)]
+    # a sliced job over ONE device array prepares its corpus once: the
+    # second call brings nothing new and answers what the first did, and
+    # both what the all-pairs call (prepared, used, dropped) answered for
+    # those rows; on fractional rows (no exact arithmetic to hide behind)
+    # a remembered corpus answers bit for bit what the per-call passes do
+    from mpi_knn_tpu.obs.metrics import get_registry
+
+    def prepared():
+        return [int(get_registry().counter(
+            "knn_corpus_prepare_total", labels={"result": r}).value)
+            for r in ("miss", "hit")]
+
+    nq = min(cfg.query_tile, m)
+    rows = np.arange(nq, dtype=np.int32)
+    before = prepared()
+    sliced = [all_knn(Xd, queries=Xd[:nq], query_ids=rows, config=cfg)
+              for _ in range(2)]
+    prepare = [b - a for a, b in zip(before, prepared())]
+    frac = W + 0.25
+    kept = [all_knn(frac, queries=frac[:1024], query_ids=rows[:1024],
+                    config=cfg) for _ in range(2)][1]
+    sliced_equal = [
+        bool(np.array_equal(np.asarray(a.ids), np.asarray(b.ids))
+             and np.array_equal(np.asarray(a.dists), np.asarray(b.dists)))
+        for a, b in ((sliced[1], sliced[0]),
+                     (sliced[1], SimpleNamespace(ids=s_ids[:nq],
+                                                 dists=s_dists[:nq])),
+                     (kept, per_call_passes(frac, 1024, cfg)))]
     ok = (
         s_ids.shape == (m, K)
         and np.isfinite(s_dists).all()
         and recall >= RECALL_GATE
         and rule_facts == [True, False]
+        and prepare == [1, 1]
+        and all(sliced_equal)
     )
     record(
         "allknn", ok, t0,
@@ -228,7 +286,9 @@ def compute_phases(args, platform, out, record) -> None:
         f"{compile_s:.2f} warm_call_s={warm_s:.3f} recall@{K}={recall:.4f} "
         f"compile_cache_entries={entries}->{cache_entries()} "
         f"select_flagged_rows_and_tile={flagged} "
-        f"onepass_facts_whole_frac={rule_facts}",
+        f"onepass_facts_whole_frac={rule_facts} "
+        f"prepare={'hit' if prepare == [1, 1] else prepare} "
+        f"sliced_equal_first_allpairs_percall={sliced_equal}",
         first_call_s=round(compile_s, 3), warm_call_s=round(warm_s, 4),
         recall=round(float(recall), 5),
     )

@@ -29,6 +29,8 @@ _EXPORTS = {
     "KNNConfig": "mpi_knn_tpu.config",
     "KNNResult": "mpi_knn_tpu.types",
     "all_knn": "mpi_knn_tpu.api",
+    "prepare_corpus": "mpi_knn_tpu.api",
+    "PreparedCorpus": "mpi_knn_tpu.api",
     "build_index": "mpi_knn_tpu.api",
     "query_knn": "mpi_knn_tpu.api",
     "knn_classify": "mpi_knn_tpu.api",
@@ -36,7 +38,14 @@ _EXPORTS = {
 }
 
 if typing.TYPE_CHECKING:  # static analyzers see the eager imports
-    from mpi_knn_tpu.api import all_knn, build_index, knn_classify, query_knn
+    from mpi_knn_tpu.api import (
+        PreparedCorpus,
+        all_knn,
+        build_index,
+        knn_classify,
+        prepare_corpus,
+        query_knn,
+    )
     from mpi_knn_tpu.config import KNNConfig
     from mpi_knn_tpu.models.classifier import KNNClassifier
     from mpi_knn_tpu.types import KNNResult
@@ -62,6 +71,8 @@ __all__ = [
     "KNNConfig",
     "KNNResult",
     "all_knn",
+    "prepare_corpus",
+    "PreparedCorpus",
     "build_index",
     "query_knn",
     "knn_classify",

@@ -6,12 +6,16 @@ One entry point replaces the reference's three copy-pasted ``main()``s
 
 from __future__ import annotations
 
+import threading
+import weakref
 from typing import Optional
 
 import jax
 import numpy as np
 
+from mpi_knn_tpu.backends.serial import PreparedCorpus
 from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.topk import start_lane_bin_import
 from mpi_knn_tpu.ops.vote import classify_from_labels
@@ -25,6 +29,122 @@ def resolve_backend(cfg: KNNConfig, mesh=None) -> str:
     return "ring-overlap" if n > 1 else "serial"
 
 
+class _LastCorpus:
+    """The one corpus :func:`all_knn` remembers: the last concrete device
+    array it prepared for a call with ``queries``, held by weak identity —
+    a ``jax.Array`` is immutable, so the object IS its contents — with the
+    :class:`PreparedCorpus` made from it. The entry, and the device memory
+    of its arrays, go when the caller drops the array or another corpus
+    (or another form of this one) is prepared."""
+
+    def __init__(self):
+        # re-entrant: the weak reference's callback can run inside any
+        # allocation, one made under the lock by this thread among them
+        self._lock = threading.RLock()
+        self._ref = self._prepared = None
+
+    def get(self, corpus, form: dict) -> Optional[PreparedCorpus]:
+        with self._lock:
+            if (self._ref is not None and self._ref() is corpus
+                    and self._prepared.form == form):
+                return self._prepared
+        return None
+
+    def put(self, corpus, prepared: PreparedCorpus) -> None:
+        ref = weakref.ref(corpus, self._drop)
+        with self._lock:
+            self._ref, self._prepared = ref, prepared
+
+    def _drop(self, ref) -> None:
+        with self._lock:
+            if self._ref is ref:
+                self._ref = self._prepared = None
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ref = self._prepared = None
+
+
+_remembered = _LastCorpus()
+
+
+def _count_prepare(result: str) -> None:
+    obs_metrics.get_registry().counter(
+        "knn_corpus_prepare_total",
+        help="all_knn calls by what became of the corpus side: hit (a "
+        "prepared corpus was brought or remembered), miss (prepared and "
+        "kept), bypass (prepared, used and dropped: a host array, a tracer, "
+        "an all-pairs call, a form with nothing to keep)",
+        labels={"result": result},
+    ).inc()
+
+
+def _form_and_maker(backend: str, cfg: KNNConfig, m, dim, nq, mesh):
+    """``(form, prepare)`` of a backend: every fact that shapes its
+    prepared corpus for ``nq``-row calls, and the function that makes
+    one."""
+    if backend == "serial":
+        from mpi_knn_tpu.backends.serial import prepare_serial, serial_form
+
+        return serial_form(cfg, m, dim, nq), prepare_serial
+    if backend in ("ring", "ring-overlap"):
+        from mpi_knn_tpu.backends.ring import prepare_ring, ring_form
+
+        return ring_form(cfg, m, dim, nq, mesh, backend), prepare_ring
+    if backend == "pallas":
+        raise ValueError(
+            "backend='pallas' has no prepared form (its kernels take the "
+            "corpus as rows and derive ids from grid position); call "
+            "all_knn with the array, or prepare for 'serial'")
+    raise ValueError(f"unknown backend {backend!r}")
+
+
+def _prepare(corpus, cfg: KNNConfig, form: dict, make) -> PreparedCorpus:
+    with obs_spans.span(
+        "prepare", cat="api", rows=int(corpus.shape[0]),
+        bytes=int(corpus.size) * corpus.dtype.itemsize,
+    ):
+        return make(corpus, cfg, form)
+
+
+def prepare_corpus(
+    corpus,
+    config: Optional[KNNConfig] = None,
+    mesh=None,
+    query_rows: Optional[int] = None,
+    **overrides,
+) -> PreparedCorpus:
+    """The corpus side of :func:`all_knn`, made once and handed back:
+    ``all_knn(corpus=<the handle>, queries=...)`` then runs only the query
+    side and the tile program. For callers who want the handle in their own
+    hands; a sliced job over ONE device array gets the same from
+    :func:`all_knn` itself, which remembers the last corpus it prepared.
+
+    Args:
+      corpus: (m, d) point matrix, host or device. The handle holds its own
+        device arrays (the centred tile stack, ids and norms; on a ring the
+        centred shards): later changes to a host array do not reach it.
+      config, overrides: as :func:`all_knn`. What shapes the prepared form
+        — backend, ``dtype``, ``metric``, ``center``, the tile sizes, the
+        mesh, the ring's wire — must be the later calls' too; a call whose
+        form differs is refused, not answered from the wrong arrays.
+      mesh: optional jax.sharding.Mesh for the ring backends.
+      query_rows: rows of the calls to come (the corpus tile follows the
+        query rows as well: ``max_tile_elems`` caps their product). Default:
+        the corpus's own rows.
+    """
+    cfg = (config or KNNConfig()).replace(**overrides)
+    start_lane_bin_import()  # under the corpus passes below
+    if not isinstance(corpus, jax.Array):
+        corpus = np.asarray(corpus)
+    m, dim = corpus.shape
+    form, make = _form_and_maker(
+        resolve_backend(cfg, mesh), cfg, m, dim,
+        m if query_rows is None else int(query_rows), mesh)
+    _count_prepare("miss")
+    return _prepare(corpus, cfg, form, make)
+
+
 def all_knn(
     corpus,
     queries=None,
@@ -36,7 +156,8 @@ def all_knn(
     """All-kNN search.
 
     Args:
-      corpus: (m, d) point matrix.
+      corpus: (m, d) point matrix, or a :func:`prepare_corpus` handle (then
+        ``queries`` is required).
       queries: (q, d) query matrix, or None for all-pairs leave-one-out mode —
         the reference's workload: every corpus point queries the whole corpus
         with itself excluded (``/root/reference/knn-serial.c:72-93``).
@@ -52,8 +173,30 @@ def all_knn(
     Returns:
       KNNResult with (q, k) distances (sortable space, ascending) and 0-based
       global ids.
+
+    **The corpus is prepared once, not once a call.** What a call derives
+    from its corpus (centring, the tile stack, ids, norms, the one-pass
+    fact; on a ring the centred shards) is a :class:`PreparedCorpus`, and
+    ``all_knn`` remembers the last one it made: ONE entry, keyed by the
+    corpus array's identity (held weakly) and every fact that shapes the
+    prepared form, and only for a concrete device array (``jax.Array``: it
+    is immutable) in a call with ``queries``. So a job that walks one
+    device array slice by slice — ``all_knn(X, queries=X[lo:lo + q],
+    query_ids=...)`` — pays the corpus passes in its first call and runs
+    the query side and the tile program in every later one, bit for bit
+    the same answers. The entry holds the prepared arrays on the device
+    (about the corpus's own size) until the caller drops the array,
+    another corpus or another form is prepared; a host array (mutable), a
+    traced corpus, an all-pairs call and ``backend="pallas"`` are prepared,
+    used and dropped, as ever. No option turns this on or off: counter
+    ``knn_corpus_prepare_total{result="hit"|"miss"|"bypass"}`` and span
+    ``knn:api.prepare`` say what a call did.
     """
     cfg = (config or KNNConfig()).replace(**overrides)
+    if queries is None and isinstance(corpus, PreparedCorpus):
+        raise ValueError(
+            "a prepared corpus answers queries: pass queries=... (the "
+            "all-pairs job needs the rows themselves)")
     # entry until the last dispatch has returned (the result is not waited for)
     with obs_spans.span(
         "all_knn", cat="api", rows=len(corpus if queries is None else queries)
@@ -61,12 +204,45 @@ def all_knn(
         return _all_knn(corpus, queries, cfg, mesh, query_ids)
 
 
+def _corpus_side(corpus, cfg: KNNConfig, form: dict, make,
+                 sliced: bool) -> PreparedCorpus:
+    """The corpus side of one call: brought, remembered, or prepared now —
+    and then remembered where nothing can change under the entry: a
+    concrete device array, in a call that is one slice of a job."""
+    if isinstance(corpus, PreparedCorpus):
+        if corpus.form != form:
+            differ = {k: (corpus.form.get(k), form.get(k))
+                      for k in corpus.form.keys() | form.keys()
+                      if corpus.form.get(k) != form.get(k)}
+            raise ValueError(
+                "this call's form is not the prepared corpus's (prepared, "
+                f"this call): {differ}; prepare_corpus takes the call's "
+                "config, mesh and query_rows")
+        _count_prepare("hit")
+        return corpus
+    keep = (sliced and isinstance(corpus, jax.Array)
+            and not isinstance(corpus, jax.core.Tracer))
+    prepared = _remembered.get(corpus, form) if keep else None
+    if prepared is not None:
+        _count_prepare("hit")
+        return prepared
+    prepared = _prepare(corpus, cfg, form, make)
+    # nothing to keep where the handle holds the caller's own array (it
+    # would pin its key) or tracers (prepared under an outer jit)
+    keep = keep and not any(
+        a is corpus or isinstance(a, jax.core.Tracer)
+        for a in vars(prepared).values())
+    if keep:
+        _remembered.put(corpus, prepared)
+    _count_prepare("miss" if keep else "bypass")
+    return prepared
+
+
 def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
     start_lane_bin_import()  # under the corpus passes below
-    on_device = isinstance(corpus, jax.Array)
-    if not on_device:
+    if not isinstance(corpus, (PreparedCorpus, jax.Array)):
         corpus = np.asarray(corpus)
-    m = corpus.shape[0]
+    m, dim = corpus.shape
 
     if queries is None:
         q_arr = corpus
@@ -84,31 +260,22 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
             # a *valid* candidate id, so self-exclusion is a no-op
             q_ids = np.full(q_arr.shape[0], -1, dtype=np.int32)
 
-    fact = steps = None
-    if cfg.center and cfg.metric == "l2":
-        from mpi_knn_tpu.ops.distance import center_for_l2
-
-        corpus, q_arr, fact, _ = center_for_l2(
-            corpus, q_arr, all_pairs=queries is None)
-
     backend = resolve_backend(cfg, mesh)
-    if backend == "serial":
-        from mpi_knn_tpu.backends.serial import all_knn_serial
-
-        d, i, steps = all_knn_serial(corpus, q_arr, q_ids, cfg, fact)
-    elif backend in ("ring", "ring-overlap"):
-        from mpi_knn_tpu.backends.ring import all_knn_ring
-
-        d, i, steps = all_knn_ring(
-            corpus, q_arr, q_ids, cfg, mesh=mesh,
-            overlap=(backend == "ring-overlap"), fact=fact,
-        )
-    elif backend == "pallas":
+    if backend == "pallas" and not isinstance(corpus, PreparedCorpus):
         from mpi_knn_tpu.backends.pallas_backend import all_knn_pallas
 
+        if cfg.center and cfg.metric == "l2":
+            from mpi_knn_tpu.ops.distance import center_for_l2
+
+            corpus, q_arr, _, _ = center_for_l2(
+                corpus, q_arr, all_pairs=queries is None)
+        _count_prepare("bypass")
         d, i = all_knn_pallas(corpus, q_arr, q_ids, cfg)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+        return KNNResult(dists=d, ids=i)
+
+    form, make = _form_and_maker(backend, cfg, m, dim, q_arr.shape[0], mesh)
+    prepared = _corpus_side(corpus, cfg, form, make, sliced=queries is not None)
+    d, i, steps = prepared.search(q_arr, q_ids, cfg)
     return KNNResult(dists=d, ids=i, dist_steps=steps)
 
 

@@ -63,17 +63,23 @@ changes, and the carry stays exact f32 across rounds.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
-from mpi_knn_tpu.ops.distance import bf16_exact, onepass_fact, sq_norms
+from mpi_knn_tpu.ops.distance import (
+    bf16_exact,
+    center_corpus,
+    onepass_applies,
+    onepass_fact,
+    sq_norms,
+)
 from mpi_knn_tpu.ops.quant import (
     dequantize_rows,
     quantize_rows,
@@ -81,6 +87,7 @@ from mpi_knn_tpu.ops.quant import (
 )
 from mpi_knn_tpu.ops.topk import init_topk
 from mpi_knn_tpu.backends.serial import (
+    PreparedCorpus,
     cap_corpus_tile,
     dist_steps,
     merge_tiles_into_carry,
@@ -825,104 +832,135 @@ def _global_ids_on(sharding: NamedSharding, m: int, c_pad: int) -> jax.Array:
         jnp.where(row < m, row, INVALID_ID), sharding)
 
 
-def all_knn_ring(
-    corpus: np.ndarray,
-    queries: np.ndarray,
-    query_ids: np.ndarray,
-    cfg: KNNConfig,
-    mesh: Mesh | None = None,
-    overlap: bool = True,
-    fact=None,
-):
-    """Host-side wrapper: build/validate the mesh, shard corpus and queries
-    over the ring axis (ids/labels as separate arrays — no augmented-row
-    smuggling, SURVEY.md C6), run the sharded ring, strip padding; returns
-    (dists, ids, ``backends.serial.dist_steps``). ``fact`` is
-    ``center_for_l2``'s, the corpus side of the one-pass rule; the XLA ring
-    at a float wire takes it, every other form runs the program it always
-    ran."""
+def ring_form(cfg: KNNConfig, m: int, dim: int, nq: int,
+              mesh: Mesh | None, backend: str) -> dict:
+    """The form of a :class:`RingCorpus` for ``nq``-row calls
+    (``backends.serial.serial_form``'s counterpart): builds and validates
+    the mesh, and derives the tile and the padding from the query rows
+    too."""
     if mesh is None:
         mesh = make_ring_mesh(cfg.num_devices, axis_name=cfg.mesh_axis)
-    q_axis, axis, dp, ring_n = parse_ring_mesh(mesh)
-    if not overlap and q_axis is not None:
+    q_axis, _, dp, ring_n = parse_ring_mesh(mesh)
+    if backend == "ring" and q_axis is not None:
         # VERDICT r5 weak #3: on a dp×ring mesh the blocking barrier can pin
         # only the block, so "blocking" would silently run the overlap
         # schedule — a hard error, not a silent mislabel (see DESIGN.md §3)
         raise blocking_undefined_on_mesh_error(mesh.axis_names)
-
-    m, dim = corpus.shape
-    nq = queries.shape[0]
-    dtype = jnp.dtype(cfg.dtype)
-
     # pad both corpus and query axes so each device's shard divides cleanly
     # into on-device tiles (the reference silently required P | m,
     # SURVEY.md Q6 — we pad + mask). Tiles shrink to the shard size for
     # small problems so padding never exceeds P·tile rows; the per-tile
     # memory cap (cfg.max_tile_elems) is applied inside ring_tiles.
-    q_tile, c_tile, q_pad, c_pad = ring_tiles(cfg, m, nq, dp, ring_n)
-    takes_rule = (cfg.ring_fusion == "xla" and cfg.ring_transfer_dtype is None
-                  and onepass_rule(cfg, q_tile))
-    rounds = bidir_rounds(ring_n)[0] if cfg.ring_schedule == "bidir" else ring_n
-    wire_bytes = ring_wire_bytes_per_batch(cfg, c_pad, dim, ring_n)
+    _, c_tile, _, c_pad = ring_tiles(cfg, m, nq, dp, ring_n)
+    return dict(
+        backend=backend, m=m, dim=dim, dtype=cfg.dtype, metric=cfg.metric,
+        center=cfg.center,
+        # the XLA ring at a float wire takes the one-pass rule; every other
+        # form runs the program it always ran
+        onepass_applies=(onepass_applies(cfg) and cfg.ring_fusion == "xla"
+                         and cfg.ring_transfer_dtype is None),
+        c_tile=c_tile, c_pad=c_pad, mesh=mesh, wire=cfg.ring_transfer_dtype,
+    )
 
-    # entry until the last dispatch has returned, inside knn:api.all_knn
-    with obs_spans.span(
-        "call", cat="ring", devices=dp * ring_n, rounds=rounds,
-        rows_per_block=c_pad // ring_n, wire_bytes=wire_bytes,
-    ):
-        # a device corpus that already lies as the ring wants it passes
-        # through all of this untouched: no pad, no cast, and device_put
-        # onto the sharding it has returns the array it was given
-        corpus_p = pad_rows_any(corpus, c_pad, dtype=dtype)
-        corpus_scale = None
-        if cfg.ring_transfer_dtype == "int8":
-            # quantize ONCE at shard time (the EQuARX recipe): the rotation
-            # program receives (codes, scales) as inputs and only ever
-            # dequantizes — the quantization reduce never enters the
-            # compiled ring, so the overlap schedule's permutes stay
-            # compute-independent
-            corpus_p, corpus_scale = quantize_ring_block(corpus_p)
-        queries_p = pad_rows_any(queries, q_pad, dtype=dtype)
-        qids_p = pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32)
 
-        c_sharding = NamedSharding(mesh, P(axis))
-        q_sharding = NamedSharding(mesh, _query_spec(q_axis, axis))
-        corpus_p = jax.device_put(corpus_p, c_sharding)
-        corpus_ids = _global_ids_on(c_sharding, m, c_pad)
-        if corpus_scale is not None:
-            corpus_scale = jax.device_put(corpus_scale, c_sharding)
-        queries_p = jax.device_put(queries_p, q_sharding)
-        qids_p = jax.device_put(qids_p, q_sharding)
+@dataclasses.dataclass(frozen=True, eq=False)
+class RingCorpus(PreparedCorpus):
+    """The ring's form: the centred corpus padded and placed on the ring's
+    sharding, its id row, and the int8 wire's scales where that wire is
+    configured. What a round does to an arriving block (its norms, the
+    scan's copy) is the rotation program's, every call."""
 
-        # read last: the shards' placement is queued behind the centring
-        # pass that the read waits for (a form without the rule reads nothing)
-        onepass = onepass_fact(cfg, fact) if takes_rule else None
-        best_d, best_i, *steps = _ring_knn_sharded(
-            queries_p,
-            qids_p,
-            corpus_p,
-            corpus_ids,
-            cfg,
-            overlap,
-            mesh,
-            axis,
-            q_tile,
-            c_tile,
-            q_axis=q_axis,
-            corpus_scale=corpus_scale,
-            onepass=onepass,
-        )
-        # static per layout, added at dispatch: no device read
-        reg = obs_metrics.get_registry()
-        reg.counter("ring_calls_total", help="all_knn_ring calls").inc()
-        reg.counter(
-            "ring_rounds_total", help="rotation rounds dispatched"
-        ).inc(rounds)
-        reg.counter(
-            "ring_wire_bytes_total",
-            help="bytes all devices send over the interconnect "
-            "(ring_wire_bytes_per_batch a call)",
-        ).inc(wire_bytes)
-        return best_d[:nq], best_i[:nq], (
-            steps[0] if steps else dist_steps(
-                q_pad // q_tile, c_pad // c_tile))
+    corpus_p: jax.Array  # (c_pad, d) rows over the ring axis (int8: codes)
+    corpus_ids: jax.Array  # (c_pad,)
+    corpus_scale: jax.Array | None  # (c_pad,) f32, the int8 wire only
+
+    def search(self, queries, query_ids, cfg: KNNConfig):
+        mesh, c_pad = self.form["mesh"], self.form["c_pad"]
+        q_axis, axis, dp, ring_n = parse_ring_mesh(mesh)
+        nq = queries.shape[0]
+        q_tile, _, q_pad, _ = ring_tiles(cfg, self.m, nq, dp, ring_n)
+        rounds = (bidir_rounds(ring_n)[0] if cfg.ring_schedule == "bidir"
+                  else ring_n)
+        wire_bytes = ring_wire_bytes_per_batch(cfg, c_pad, self.dim, ring_n)
+        if self.mu is not None:
+            queries = queries - self.mu  # center_for_l2's own subtraction
+
+        # entry until the last dispatch has returned, inside knn:api.all_knn
+        with obs_spans.span(
+            "call", cat="ring", devices=dp * ring_n, rounds=rounds,
+            rows_per_block=c_pad // ring_n, wire_bytes=wire_bytes,
+        ):
+            dtype = jnp.dtype(cfg.dtype)
+            q_sharding = NamedSharding(mesh, _query_spec(q_axis, axis))
+            queries_p = jax.device_put(
+                pad_rows_any(queries, q_pad, dtype=dtype), q_sharding)
+            qids_p = jax.device_put(
+                pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32),
+                q_sharding)
+            best_d, best_i, *steps = _ring_knn_sharded(
+                queries_p,
+                qids_p,
+                self.corpus_p,
+                self.corpus_ids,
+                cfg,
+                self.form["backend"] == "ring-overlap",
+                mesh,
+                axis,
+                q_tile,
+                self.c_tile,
+                q_axis=q_axis,
+                corpus_scale=self.corpus_scale,
+                onepass=self.onepass if onepass_rule(cfg, q_tile) else None,
+            )
+            # static per layout, added at dispatch: no device read
+            reg = obs_metrics.get_registry()
+            reg.counter("ring_calls_total", help="ring searches").inc()
+            reg.counter(
+                "ring_rounds_total", help="rotation rounds dispatched"
+            ).inc(rounds)
+            reg.counter(
+                "ring_wire_bytes_total",
+                help="bytes all devices send over the interconnect "
+                "(ring_wire_bytes_per_batch a call)",
+            ).inc(wire_bytes)
+            return best_d[:nq], best_i[:nq], (
+                steps[0] if steps else dist_steps(
+                    q_pad // q_tile, c_pad // self.c_tile))
+
+
+def prepare_ring(corpus, cfg: KNNConfig, form: dict) -> RingCorpus:
+    """Every pass a ring call makes over its corpus, once
+    (``backends.serial.prepare_serial``'s counterpart): centre, pad, shard
+    the corpus over the ring axis (ids as a separate row — no
+    augmented-row smuggling, SURVEY.md C6), quantize for the int8 wire.
+    ``form`` is :func:`ring_form`'s for the calls to come."""
+    mesh, c_pad = form["mesh"], form["c_pad"]
+    axis = parse_ring_mesh(mesh)[1]
+    m, dim = corpus.shape
+    mu = fact = None
+    if cfg.center and cfg.metric == "l2":
+        corpus, mu, fact = center_corpus(corpus)
+    # a device corpus that already lies as the ring wants it passes through
+    # all of this untouched: no pad, no cast, and device_put onto the
+    # sharding it has returns the array it was given
+    corpus_p = pad_rows_any(corpus, c_pad, dtype=jnp.dtype(cfg.dtype))
+    del corpus
+    corpus_scale = None
+    if cfg.ring_transfer_dtype == "int8":
+        # quantize ONCE at shard time (the EQuARX recipe): the rotation
+        # program receives (codes, scales) as inputs and only ever
+        # dequantizes — the quantization reduce never enters the compiled
+        # ring, so the overlap schedule's permutes stay compute-independent
+        corpus_p, corpus_scale = quantize_ring_block(corpus_p)
+    c_sharding = NamedSharding(mesh, P(axis))
+    corpus_p = jax.device_put(corpus_p, c_sharding)
+    corpus_ids = _global_ids_on(c_sharding, m, c_pad)
+    if corpus_scale is not None:
+        corpus_scale = jax.device_put(corpus_scale, c_sharding)
+    # read last: the shards' placement is queued behind the centring pass
+    # that the read waits for (a form without the rule reads nothing)
+    onepass = onepass_fact(cfg, fact) if form["onepass_applies"] else None
+    return RingCorpus(
+        form, m, dim, form["c_tile"], mu, onepass,
+        corpus_p, corpus_ids, corpus_scale,
+    )

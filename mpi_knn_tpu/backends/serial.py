@@ -10,15 +10,20 @@ O(query_tile × corpus_tile + q × k) instead of the reference's full
 m × NN neighbour matrix on the *stack* (~28.8 MB of VLAs,
 ``/root/reference/knn-serial.c:54-55``).
 
-``knn_chunk_update`` is the single jitted core: the plain serial path calls
-it once over all corpus tiles; the resumable driver (backends.resumable)
-calls it per checkpoint round with the carry threaded through; the ring
-backends run ``knn_tile_step`` against each rotating block.
+``serve_chunk`` is the one core: the plain serial path prepares its corpus
+once (:class:`SerialCorpus`: the tile stack, ids and norms) and runs it
+under a jit over all corpus tiles (``_search_stack``), as the serving
+engine does over a resident index; ``knn_chunk_update`` is the same body
+with the chunk's norms computed inside, which the resumable driver
+(backends.resumable) calls per checkpoint round with the carry threaded
+through; the ring backends run ``knn_tile_step`` against each rotating
+block.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 
 import jax
@@ -28,6 +33,7 @@ import numpy as np
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ops.distance import (
     bf16_exact,
+    center_corpus,
     onepass_applies,
     onepass_fact,
     pairwise_dist,
@@ -248,15 +254,25 @@ def knn_chunk_update(
     behind both the serial backend and the resumable driver — the serving
     path's :func:`serve_chunk` IS this body with the chunk norms hoisted
     to index state, so the two can never drift."""
-    acc = jnp.float64 if q_tiles.dtype == jnp.float64 else jnp.float32
-    if cfg.metric == "l2":
-        chunk_sq = jax.vmap(sq_norms)(chunk_tiles)
-    else:
-        chunk_sq = jnp.zeros(chunk_tiles.shape[:2], dtype=acc)
     return serve_chunk(
         q_tiles, qid_tiles, carry_d, carry_i,
-        chunk_tiles, chunk_ids, chunk_sq, onepass, cfg=cfg,
+        chunk_tiles, chunk_ids, stack_norms(chunk_tiles, cfg.metric),
+        onepass, cfg=cfg,
     )
+
+
+def stack_norms(tiles: jax.Array, metric: str) -> jax.Array:
+    """(T, c_tile) squared row norms of a (T, c_tile, d) tile stack; zeros
+    for cosine, whose kernel normalizes its own operands. Always traced
+    (inside :func:`knn_chunk_update`, or under :data:`_stack_norms`): the
+    eager reduction gives other bits than the traced one on the CPU."""
+    if metric == "l2":
+        return jax.vmap(sq_norms)(tiles)
+    acc = jnp.float64 if tiles.dtype == jnp.float64 else jnp.float32
+    return jnp.zeros(tiles.shape[:2], dtype=acc)
+
+
+_stack_norms = jax.jit(stack_norms, static_argnames=("metric",))
 
 
 def serve_chunk(
@@ -444,58 +460,161 @@ def effective_tiles(cfg: KNNConfig, m: int, nq: int) -> tuple[int, int]:
 
 
 @jax.named_scope("knn.retile")
-def prepare_tiles(corpus, queries, query_ids, cfg: KNNConfig, q_tile, c_tile):
-    """Pad + reshape corpus/query arrays into device tile stacks. Host numpy
-    inputs are padded on host then transferred once; device inputs are padded
-    with on-device ops (no device→host round trip)."""
+def tile_corpus(corpus, cfg: KNNConfig, c_tile: int):
+    """``(tiles, tile ids)``: the corpus padded to whole tiles and reshaped
+    into the device tile stack, with its global id rows. A host array is
+    padded on the host and transferred once; a device array is padded with
+    on-device ops (no device -> host round trip)."""
     m, dim = corpus.shape
-    nq = queries.shape[0]
-    dtype = jnp.dtype(cfg.dtype)
-
     c_pad = pad_to_multiple(m, c_tile)
-    q_pad = pad_to_multiple(nq, q_tile)
+    tiles = pad_rows_any(corpus, c_pad, dtype=jnp.dtype(cfg.dtype)).reshape(
+        -1, c_tile, dim)
+    return tiles, jnp.asarray(make_global_ids(m, c_pad).reshape(-1, c_tile))
 
-    corpus_tiles = pad_rows_any(corpus, c_pad, dtype=dtype).reshape(-1, c_tile, dim)
-    corpus_tile_ids = jnp.asarray(make_global_ids(m, c_pad).reshape(-1, c_tile))
-    q_tiles = pad_rows_any(queries, q_pad, dtype=dtype).reshape(-1, q_tile, dim)
+
+@jax.named_scope("knn.retile")
+def tile_queries(queries, query_ids, cfg: KNNConfig, q_tile: int):
+    """``(query tiles, query id tiles, padded rows)``: :func:`tile_corpus`
+    for the query side (padding rows carry id -1)."""
+    nq, dim = queries.shape
+    q_pad = pad_to_multiple(nq, q_tile)
+    q_tiles = pad_rows_any(queries, q_pad, dtype=jnp.dtype(cfg.dtype)).reshape(
+        -1, q_tile, dim)
     qid_tiles = pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32).reshape(
         -1, q_tile
     )
+    return q_tiles, qid_tiles, q_pad
+
+
+def prepare_tiles(corpus, queries, query_ids, cfg: KNNConfig, q_tile, c_tile):
+    """Pad + reshape corpus/query arrays into device tile stacks:
+    :func:`tile_corpus` and :func:`tile_queries` for callers that tile both
+    sides at once (the resumable driver, the lowering)."""
+    corpus_tiles, corpus_tile_ids = tile_corpus(corpus, cfg, c_tile)
+    q_tiles, qid_tiles, q_pad = tile_queries(queries, query_ids, cfg, q_tile)
     return q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, q_pad
 
 
-def all_knn_serial(
-    corpus: np.ndarray,
-    queries: np.ndarray,
-    query_ids: np.ndarray,
-    cfg: KNNConfig,
-    fact=None,
-):
-    """Host-side wrapper: pad to tile multiples, run the jitted core, strip
-    padding. Returns ((q, k) dists, (q, k) ids, :func:`dist_steps`), the
-    first two device arrays. ``fact`` is ``center_for_l2``'s, the corpus
-    side of the one-pass rule."""
-    nq = queries.shape[0]
-    q_tile, c_tile = effective_tiles(cfg, corpus.shape[0], nq)
-    q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, q_pad = prepare_tiles(
-        corpus, queries, query_ids, cfg, q_tile, c_tile
+@dataclasses.dataclass(frozen=True, eq=False)
+class PreparedCorpus:
+    """What a call derives from its corpus and nothing else: the corpus
+    side of ``api.all_knn``, made once (``api.prepare_corpus``) and
+    searched by any number of calls. The serial form is
+    :class:`SerialCorpus`, the ring's ``backends.ring.RingCorpus``; the
+    arrays are what a call has always made, by the same functions in the
+    same order, so a search over a prepared corpus answers bit for bit
+    what a call that prepares its own answers."""
+
+    # every fact that shaped the arrays, by name (:func:`serial_form`,
+    # ``backends.ring.ring_form``): a call whose own form differs cannot
+    # use them
+    form: dict
+    m: int
+    dim: int
+    c_tile: int
+    # the centring offset (``ops.distance.center_corpus``); None: not centred
+    mu: object
+    # the corpus side of the one-pass rule, READ ON THE HOST at the
+    # preparation (``onepass_fact``): a TRUE device scalar, or None
+    onepass: jax.Array | None
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        """The corpus's own (rows, dim)."""
+        return self.m, self.dim
+
+    def search(self, queries, query_ids, cfg: KNNConfig):
+        """``((q, k) dists, (q, k) ids, dist_steps)`` of ``queries``
+        (uncentred, as the caller has them) against this corpus. The query
+        side and the tile program only: no pass over the corpus, no host
+        read of a device value."""
+        raise NotImplementedError
+
+
+def serial_form(cfg: KNNConfig, m: int, dim: int, nq: int) -> dict:
+    """The form of a :class:`SerialCorpus` for ``nq``-row calls: every fact
+    that shapes its arrays. The corpus tile follows the query rows too
+    (:func:`effective_tiles` caps the tile's elements)."""
+    return dict(
+        backend="serial", m=m, dim=dim, dtype=cfg.dtype, metric=cfg.metric,
+        center=cfg.center, onepass_applies=onepass_applies(cfg),
+        c_tile=effective_tiles(cfg, m, nq)[1],
     )
 
+
+@functools.partial(jax.jit, static_argnames=("cfg", "q_tile"))
+def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass, *,
+                  cfg: KNNConfig, q_tile: int):
+    """The one per-call program of a search over a prepared stack:
+    :func:`serve_chunk` under a jit with ``cfg`` static, as
+    :func:`knn_chunk_update` is, with the query side's tiling, the carry's
+    start and the answers' untiling inside it. They were eager dispatches
+    under the corpus passes; with those gone the device would wait for
+    each of them (a call's host gap read 4.9 ms with them outside, PERF.md
+    §6, PR 31)."""
+    nq = queries.shape[0]
+    q_tiles, qid_tiles, q_pad = tile_queries(queries, query_ids, cfg, q_tile)
     acc = jnp.float64 if q_tiles.dtype == jnp.float64 else jnp.float32
     carry_d, carry_i = init_topk_tiles(q_pad // q_tile, q_tile, cfg.k,
                                        dtype=acc)
-    # read last: the tile stack's copy is queued behind the centring pass
-    # that the read waits for, and the device has work while the host
-    # dispatches (a program without the rule reads nothing)
-    onepass = onepass_fact(cfg, fact) if onepass_rule(cfg, q_tile) else None
+    best_d, best_i, *steps = serve_chunk(
+        q_tiles, qid_tiles, carry_d, carry_i, tiles, tile_ids, tile_sqs,
+        onepass, cfg=cfg,
+    )
+    return (best_d.reshape(q_pad, cfg.k)[:nq],
+            best_i.reshape(q_pad, cfg.k)[:nq], *steps)
 
-    best_d, best_i, *steps = knn_chunk_update(
-        q_tiles, qid_tiles, corpus_tiles, corpus_tile_ids, carry_d, carry_i,
-        cfg, onepass,
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class SerialCorpus(PreparedCorpus):
+    """The serial form: the centred tile stack, its id tiles and its norms
+    (zeros for cosine), what ``serve.CorpusIndex`` keeps for a served
+    corpus. No reference to a centred (m, d) copy: the stack is the only
+    one."""
+
+    tiles: jax.Array  # (T, c_tile, d)
+    tile_ids: jax.Array  # (T, c_tile)
+    tile_sqs: jax.Array  # (T, c_tile)
+
+    def search(self, queries, query_ids, cfg: KNNConfig):
+        nq = queries.shape[0]
+        q_tile = effective_tiles(cfg, self.m, nq)[0]
+        if self.mu is not None:
+            queries = queries - self.mu  # center_for_l2's own subtraction
+        best_d, best_i, *steps = _search_stack(
+            queries, query_ids, self.tiles, self.tile_ids, self.tile_sqs,
+            self.onepass if onepass_rule(cfg, q_tile) else None,
+            cfg=cfg, q_tile=q_tile,
+        )
+        return best_d, best_i, steps[0] if steps else dist_steps(
+            pad_to_multiple(nq, q_tile) // q_tile, self.tiles.shape[0])
+
+
+def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:
+    """Every pass a call makes over its corpus, once: centre (the offset
+    and the one-pass fact with it), pad and tile, norm. ``form`` is
+    :func:`serial_form`'s for the calls to come. The first call's memory
+    peak is here — the caller's array, the centred copy and the stack —
+    and the centred copy goes before the read of the fact waits."""
+    m, dim = corpus.shape
+    c_tile = form["c_tile"]
+    mu = fact = None
+    if cfg.center and cfg.metric == "l2":
+        corpus, mu, fact = center_corpus(corpus)
+    tiles, tile_ids = tile_corpus(corpus, cfg, c_tile)
+    del corpus
+    tile_sqs = _stack_norms(tiles, cfg.metric)
+    # read last: the stack's copy and its norms are queued behind the
+    # centring pass that the read waits for
+    return SerialCorpus(
+        form, m, dim, c_tile, mu, onepass_fact(cfg, fact),
+        tiles, tile_ids, tile_sqs,
     )
-    return (
-        best_d.reshape(q_pad, cfg.k)[:nq],
-        best_i.reshape(q_pad, cfg.k)[:nq],
-        steps[0] if steps else dist_steps(
-            q_pad // q_tile, corpus_tiles.shape[0]),
-    )
+
+
+def all_knn_serial(corpus, queries, query_ids, cfg: KNNConfig):
+    """One whole call on arrays as the caller has them: prepare the corpus,
+    search it, drop it. Returns ((q, k) dists, (q, k) ids,
+    :func:`dist_steps`), the first two device arrays."""
+    form = serial_form(cfg, *corpus.shape, queries.shape[0])
+    return prepare_serial(corpus, cfg, form).search(queries, query_ids, cfg)

@@ -422,6 +422,48 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     assert temps[1] <= temps[0] + 0.1 and temps[1] <= temp_gib, (cell, temps)
 
 
+def test_search_over_a_prepared_stack_compiles_for_the_v5e(
+        v5e_devices, monkeypatch):
+    """``allknn-mnist8m`` since ISSUE 31: the per-call program takes the
+    stack's norms as an input (``serial._search_stack``: ``serve_chunk``
+    under a jit, the query side's tiling inside it) and needs no more
+    scratch than ``knn_chunk_update``, which computes them; the norms' own program, run once a corpus, squares no
+    copy of the 4.6 GiB stack."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, tiles, dim = 4096, 192, 784
+    cfg = KNNConfig(k=10, backend="serial", matmul_precision="high",
+                    query_tile=q, corpus_tile=8192)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    queries = (arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32))
+    stack = (arg((tiles, 8192, dim), jnp.float32),
+             arg((tiles, 8192), jnp.int32))
+    carry = (arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32))
+    fact = arg((), jnp.bool_)
+    with jax.enable_x64(False):
+        per_call = serial.knn_chunk_update.lower(
+            *queries, *stack, *carry, cfg, fact).compile()
+        search = serial._search_stack.lower(
+            arg((q, dim), jnp.float32), arg((q,), jnp.int32), *stack,
+            arg((tiles, 8192), jnp.float32), fact, cfg=cfg, q_tile=q,
+        ).compile()
+        norms = serial._stack_norms.lower(stack[0], "l2").compile()
+    assert _dist_dots(search.as_text()) == _dist_dots(per_call.as_text())
+    gib = [c.memory_analysis().temp_size_in_bytes / 2**30
+           for c in (per_call, search, norms)]
+    assert gib[1] <= gib[0] + 0.01 and gib[2] <= 0.1, gib
+
+
 def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(
         v5e_devices, monkeypatch):
     """``ring4-mnist8m`` at its size, 128 tiles a chip: both branches inside
