@@ -17,6 +17,10 @@ Phases, each printing one line with its wall time:
 - ``pallas``   both Pallas variants, compiled by Mosaic (the lowering must
                hold a ``tpu_custom_call``), against the serial backend on
                the same rows.
+- ``cosine``   the prepared cosine path (ISSUE 32) on fractional rows of
+               lengths 0.5-2x their own: a resident index's answers equal
+               the one-shot call's bit for bit, and the direct form's in
+               float64 on the host.
 - ``ring``     with more than one chip: both ring schedules over
                min(4, chips) devices against the serial result, the
                shardings spanning that many devices, and the fused
@@ -306,6 +310,65 @@ def compute_phases(args, platform, out, record) -> None:
     )
     np.savez(os.path.join(args.work, "expect.npz"), queries=Q,
              ids=np.asarray(expect.ids), dists=np.asarray(expect.dists))
+
+    # -- cosine ------------------------------------------------------------
+    # the prepared cosine path on THIS device: fractional rows, each at a
+    # length of its own (with unit rows 1 - q.c is right whatever is
+    # skipped), served from a resident index against the one-shot call,
+    # and both against the direct form in float64 on the host
+    t0 = time.perf_counter()
+    from mpi_knn_tpu import build_index, query_knn
+
+    crng = np.random.default_rng(3)
+    c_rows = min(m, 16384)
+    C = ((X[:c_rows] + 0.25) * np.exp(crng.uniform(
+        np.log(0.5), np.log(2.0), (c_rows, 1)))).astype(np.float32)
+    n_cq = 1100  # one full 1024-row query tile and a ragged one
+    CQ = ((C[crng.integers(0, c_rows, n_cq)]
+           + crng.standard_normal((n_cq, C.shape[1])) * 8.0)
+          * np.exp(crng.uniform(np.log(0.5), np.log(2.0), (n_cq, 1)))
+          ).astype(np.float32)
+    ccfg = KNNConfig(k=K, backend="serial", metric="cosine",
+                     query_tile=SERVE_TILES[0], corpus_tile=SERVE_TILES[1],
+                     exclude_zero=False, query_bucket=64)
+    cos_steps = get_registry().counter(
+        "knn_dist_tile_steps_total", labels={"path": "cosine"})
+    steps_before = cos_steps.value
+    Cd = jax.device_put(jnp.asarray(C))
+    one_shot = all_knn(Cd, queries=CQ, config=ccfg)
+    served = query_knn(CQ, build_index(Cd, ccfg))
+    bit_equal = bool(
+        np.array_equal(np.asarray(served.ids), np.asarray(one_shot.ids))
+        and np.array_equal(np.asarray(served.dists),
+                           np.asarray(one_shot.dists)))
+    c64, q64 = C.astype(np.float64), CQ[:64].astype(np.float64)
+    c_len = np.linalg.norm(c64, axis=1)
+    direct = 1.0 - (q64 @ c64.T) / (
+        np.linalg.norm(q64, axis=1)[:, None] * c_len[None, :])
+    want_i = np.argsort(direct, axis=1, kind="stable")[:, :K]
+    want_d = np.take_along_axis(direct, want_i, axis=1)
+    got_d = np.asarray(served.dists)[:64].astype(np.float64)
+    # a slot naming another row is a hit where its distance ties the k-th
+    got_i = np.asarray(served.ids)[:64]
+    cos_recall = float((
+        (got_i[:, :, None] == want_i[:, None, :]).any(axis=2)
+        | (np.abs(got_d - want_d[:, -1:]) <= 2e-6)).mean())
+    # absolute: pixel rows are nearly parallel (distances of 1e-3 and
+    # under), and a float32 similarity near 1 resolves 6e-8
+    cos_err = float(np.max(np.abs(got_d - want_d)))
+    record(
+        "cosine",
+        bit_equal and cos_recall >= RECALL_GATE and cos_err <= 2e-6
+        and cos_steps.value > steps_before,
+        t0,
+        f"corpus={list(C.shape)} queries={n_cq} fractional rows of length "
+        f"{c_len.min():.0f}-{c_len.max():.0f} "
+        f"served_equals_all_knn_bit_for_bit={bit_equal} "
+        f"recall@{K}_vs_float64={cos_recall:.5f} "
+        f"dist_abs_err_max={cos_err:.2e} "
+        f"cosine_tile_steps={int(cos_steps.value - steps_before)}",
+        recall=round(float(cos_recall), 5),
+    )
 
     def lowers_to_mosaic(fn, *arrays) -> bool:
         """Whether the program ``fn`` traces to holds a compiled Pallas

@@ -102,7 +102,7 @@ def _form_and_maker(backend: str, cfg: KNNConfig, m, dim, nq, mesh):
 def _prepare(corpus, cfg: KNNConfig, form: dict, make) -> PreparedCorpus:
     with obs_spans.span(
         "prepare", cat="api", rows=int(corpus.shape[0]),
-        bytes=int(corpus.size) * corpus.dtype.itemsize,
+        bytes=int(corpus.size) * corpus.dtype.itemsize, metric=cfg.metric,
     ):
         return make(corpus, cfg, form)
 

@@ -369,11 +369,9 @@ def _ring_knn_local(
             blk = dequantize_rows(blk, blk_scl, "int8", dim)
         # the travellers are tile stacks already (``tiled`` above)
         blk_tiles = blk.astype(queries.dtype)  # no-op unless ring_transfer_dtype
-        blk_sq = (
-            jax.vmap(sq_norms)(blk_tiles)
-            if cfg.metric == "l2"
-            else jnp.zeros(blk_tiles.shape[:2], dtype=acc)
-        )
+        # cosine: no corpus-side state travels with a block, so a round's
+        # tile steps normalise their own operands (masked_dist_tile)
+        blk_sq = jax.vmap(sq_norms)(blk_tiles) if cfg.metric == "l2" else None
 
         def per_query_tile(args):
             q_x, q_ids, cd0, ci0, one = args
@@ -925,7 +923,7 @@ class RingCorpus(PreparedCorpus):
             ).inc(wire_bytes)
             return best_d[:nq], best_i[:nq], (
                 steps[0] if steps else dist_steps(
-                    q_pad // q_tile, c_pad // self.c_tile))
+                    q_pad // q_tile, c_pad // self.c_tile, cfg.metric))
 
 
 def prepare_ring(corpus, cfg: KNNConfig, form: dict) -> RingCorpus:

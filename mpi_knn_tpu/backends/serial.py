@@ -32,13 +32,16 @@ import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ops.distance import (
+    COSINE_SCOPE,
     bf16_exact,
     center_corpus,
+    cosine_inv_norms,
     onepass_applies,
     onepass_fact,
     pairwise_dist,
     pairwise_sq_l2,
     sq_norms,
+    unit_rows,
 )
 from mpi_knn_tpu.ops.rerank import compress_rerank_tile
 from mpi_knn_tpu.ops.topk import (
@@ -78,15 +81,18 @@ def onepass_rule(cfg: KNNConfig, q_rows: int) -> bool:
     return onepass_applies(cfg) and q_rows >= ONEPASS_MIN_ROWS
 
 
-def dist_steps(took, steps: int):
+def dist_steps(took, steps: int, metric: str = "l2"):
     """A dispatch's tile steps by the path of their distance dot, int32
-    ``[one-pass, multi-pass]``: what ``KNNResult.dist_steps`` and the
-    counter ``knn_dist_tile_steps_total`` hold. ``took`` is one verdict a
+    ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``:
+    what ``KNNResult.dist_steps`` and the counter
+    ``knn_dist_tile_steps_total`` hold. ``took`` is one verdict a
     query-tile merge (a bool vector, made inside a program that carries
     the branch) or, for a program without the branch, their number; each
     merge meets ``steps`` corpus tiles."""
     if isinstance(took, int):
-        return np.array([0, took * steps], dtype=np.int32)
+        n = took * steps
+        return np.array([0, n] if metric == "l2" else [0, 0, n],
+                        dtype=np.int32)
     one = jnp.sum(took, dtype=jnp.int32)
     return jnp.stack([one, took.size - one]) * steps
 
@@ -111,9 +117,19 @@ def masked_dist_tile(
     True, both operands are bf16 numbers and the dot is one bf16 x bf16
     pass, which for them returns what the configured precision returns;
     False, the dot at ``cfg.matmul_precision``; None, the same in a program
-    that carries no such branch. Norms and masks are the same in all."""
-    scope = contextlib.nullcontext() if onepass is None else jax.named_scope(
-        ONEPASS_SCOPE if onepass else MULTIPASS_SCOPE)
+    that carries no such branch. Norms and masks are the same in all.
+
+    Cosine (scope ``knn.dist_cosine``): ``blk_sq`` given is the corpus
+    side prepared — the rows' inverse norms (:func:`stack_norms`) — and
+    ``q_x`` then holds unit rows (:func:`serve_chunk` makes them once a
+    query tile): the step scales its dot and normalises nothing. None (the
+    ring's rounds) normalises both operands here, every step."""
+    if onepass is not None:
+        scope = jax.named_scope(ONEPASS_SCOPE if onepass else MULTIPASS_SCOPE)
+    elif cfg.metric == "cosine":
+        scope = jax.named_scope(COSINE_SCOPE)
+    else:
+        scope = contextlib.nullcontext()
     with scope:
         if onepass:
             d = pairwise_sq_l2(q_x, blk, x_sq=q_sq, y_sq=blk_sq, onepass=True)
@@ -262,14 +278,14 @@ def knn_chunk_update(
 
 
 def stack_norms(tiles: jax.Array, metric: str) -> jax.Array:
-    """(T, c_tile) squared row norms of a (T, c_tile, d) tile stack; zeros
-    for cosine, whose kernel normalizes its own operands. Always traced
-    (inside :func:`knn_chunk_update`, or under :data:`_stack_norms`): the
-    eager reduction gives other bits than the traced one on the CPU."""
-    if metric == "l2":
-        return jax.vmap(sq_norms)(tiles)
-    acc = jnp.float64 if tiles.dtype == jnp.float64 else jnp.float32
-    return jnp.zeros(tiles.shape[:2], dtype=acc)
+    """The (T, c_tile) per-row state a metric's tile step wants from a
+    (T, c_tile, d) tile stack, made once a corpus: squared row norms for
+    L2, inverse row norms for cosine (``ops.distance.cosine_inv_norms``:
+    the step then scales its dot and never normalises a corpus tile).
+    One reduction over the stack, no copy of it. Always traced (inside
+    :func:`knn_chunk_update`, or under :data:`_stack_norms`): the eager
+    reduction gives other bits than the traced one on the CPU."""
+    return jax.vmap(sq_norms if metric == "l2" else cosine_inv_norms)(tiles)
 
 
 _stack_norms = jax.jit(stack_norms, static_argnames=("metric",))
@@ -282,7 +298,7 @@ def serve_chunk(
     carry_i: jax.Array,
     tiles: jax.Array,  # (T, c_tile, d) RESIDENT corpus tiles
     tile_ids: jax.Array,  # (T, c_tile)
-    tile_sqs: jax.Array,  # (T, c_tile) norms precomputed at index build
+    tile_sqs: jax.Array,  # (T, c_tile) stack_norms, made at index build
     onepass: jax.Array | None = None,  # the corpus side of the one-pass rule
     *,
     cfg: KNNConfig,
@@ -304,13 +320,24 @@ def serve_chunk(
     (:func:`dist_steps`, what the engagement counter reads). None: the
     program and its two outputs as they always were; so too where the rule
     does not apply (:func:`onepass_rule`: a small bucket,
-    ``precision_policy="mixed"`` as a serving rung)."""
+    ``precision_policy="mixed"`` as a serving rung).
+
+    Cosine: the query side's unit rows are made HERE, once a query tile
+    and ahead of its scan (scope ``knn.qunit``), where L2 norms its query
+    tile — inside the batch program, so a served batch, a one-shot call
+    and a resumable round run one arithmetic and no host pass or extra
+    dispatch prepares a batch; ``tile_sqs`` holds the corpus rows' inverse
+    norms, so no tile step normalises anything."""
     if not onepass_rule(cfg, q_tiles.shape[1]):
         onepass = None
 
     def per_query_tile(args):
         q_x, q_ids, cd, ci = args
-        q_sq = sq_norms(q_x) if cfg.metric == "l2" else None
+        q_sq = None
+        if cfg.metric == "l2":
+            q_sq = sq_norms(q_x)
+        else:
+            q_x = unit_rows(q_x)
         one = None if onepass is None else onepass & bf16_exact(q_x)
         out = merge_tiles_into_carry(
             q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one
@@ -568,9 +595,9 @@ def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass, *,
 @dataclasses.dataclass(frozen=True, eq=False)
 class SerialCorpus(PreparedCorpus):
     """The serial form: the centred tile stack, its id tiles and its norms
-    (zeros for cosine), what ``serve.CorpusIndex`` keeps for a served
-    corpus. No reference to a centred (m, d) copy: the stack is the only
-    one."""
+    (:func:`stack_norms`: squared for L2, inverse for cosine), what
+    ``serve.CorpusIndex`` keeps for a served corpus. No reference to a
+    centred (m, d) copy: the stack is the only one."""
 
     tiles: jax.Array  # (T, c_tile, d)
     tile_ids: jax.Array  # (T, c_tile)
@@ -587,7 +614,8 @@ class SerialCorpus(PreparedCorpus):
             cfg=cfg, q_tile=q_tile,
         )
         return best_d, best_i, steps[0] if steps else dist_steps(
-            pad_to_multiple(nq, q_tile) // q_tile, self.tiles.shape[0])
+            pad_to_multiple(nq, q_tile) // q_tile, self.tiles.shape[0],
+            cfg.metric)
 
 
 def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:

@@ -38,6 +38,7 @@ import time
 
 from mpi_knn_tpu.config import (
     BACKENDS,
+    METRICS,
     PRECISION_POLICIES,
     KNNConfig,
 )
@@ -56,6 +57,11 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    "'synthetic:MxDcC', 'sift:M', *.fvecs/bvecs, .mat)")
     d.add_argument("--limit", type=int, default=None)
     d.add_argument("--k", type=int, default=30)
+    d.add_argument("--metric", choices=METRICS, default="l2",
+                   help="distance of the served index, as `mpi-knn query "
+                   "--metric` (cosine: the index keeps its rows' inverse "
+                   "norms; refused loudly by the pallas and clustered "
+                   "layouts)")
     d.add_argument("--backend", choices=BACKENDS, default="auto")
     d.add_argument("--devices", type=int, default=None,
                    help="ring size for distributed backends")
@@ -203,6 +209,7 @@ def serve_main(argv=None) -> int:
     try:
         cfg = KNNConfig(
             k=args.k,
+            metric=args.metric,
             backend=args.backend,
             dtype=args.dtype,
             query_tile=args.query_tile,

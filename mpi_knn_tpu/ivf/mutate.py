@@ -53,7 +53,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ops.distance import pairwise_sq_l2, sq_norms
+from mpi_knn_tpu.ops.distance import (
+    cosine_inv_norms,
+    pairwise_sq_l2,
+    sq_norms,
+)
 from mpi_knn_tpu.ops.quant import QUANT_DTYPES, dequantize_rows, quantize_rows
 
 
@@ -272,12 +276,9 @@ def store_rows_and_sqs(rows: jax.Array, cfg: KNNConfig, dim: int):
         return codes, scales, sqs
     at_rest = rows.astype(jnp.dtype(cfg.dtype))
     if cfg.metric != "l2":
-        # cosine tile stacks carry zero norms (the metric kernel
-        # normalizes internally) — mirror the build exactly
-        return at_rest, None, jnp.zeros(
-            rows.shape[:1],
-            dtype=jnp.float64 if cfg.dtype == "float64" else jnp.float32,
-        )
+        # a cosine tile stack keeps its rows' inverse norms
+        # (backends.serial.stack_norms) — mirror the build exactly
+        return at_rest, None, cosine_inv_norms(at_rest)
     return at_rest, None, sq_norms(at_rest)
 
 

@@ -55,6 +55,7 @@ COMPILE_BUCKETS_S = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 
 # tile steps by the distance dot's path (MetricsRegistry.count_dist_steps)
 DIST_STEPS = "knn_dist_tile_steps_total"
+DIST_PATHS = ("onepass", "multipass", "cosine")  # a count's columns
 
 JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a program found in jax's persistent compilation cache (at jax 0.9.0 the
@@ -336,21 +337,24 @@ class MetricsRegistry:
 
     def count_dist_steps(self, steps) -> None:
         """Add a dispatch's tile steps to ``knn_dist_tile_steps_total
-        {path="onepass"|"multipass"}``. ``steps`` is what the tile programs
-        report with their answer (``KNNResult.dist_steps``,
-        ``BatchResult.dist_steps``): ints ``[one-pass, multi-pass]``, one
-        row a device. The device decides the path, so call this where the
+        {path="onepass"|"multipass"|"cosine"}``. ``steps`` is what the tile
+        programs report with their answer (``KNNResult.dist_steps``,
+        ``BatchResult.dist_steps``): ints ``[one-pass, multi-pass]`` — from
+        a cosine program ``[0, 0, cosine]``, a static count — one row a
+        device. The device decides the L2 path, so call this where the
         answer has been fetched (a server's retire, a job's end): reading
         a count that is not ready waits for its program."""
         import numpy as np
 
-        onepass, multipass = np.asarray(steps).reshape(-1, 2).sum(axis=0)
-        for path, n in (("onepass", onepass), ("multipass", multipass)):
+        steps = np.asarray(steps)
+        by_path = steps.reshape(-1, steps.shape[-1]).sum(axis=0)
+        for path, n in zip(DIST_PATHS, by_path):
             self.counter(
                 DIST_STEPS,
                 help="tile steps dispatched, by the path of the distance "
-                "dot: one bf16 pass (both operands bf16 numbers) or the "
-                "configured multi-pass dot",
+                "dot: one bf16 pass (both operands bf16 numbers), the "
+                "configured multi-pass dot, or the cosine dot (a scaled "
+                "dot at the configured precision over prepared operands)",
                 labels={"path": path},
             ).inc(int(n))
 

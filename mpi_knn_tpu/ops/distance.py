@@ -243,24 +243,67 @@ def _l2_normalize(x: jax.Array, eps: float = _NORM_EPS) -> jax.Array:
     return x.astype(acc) / n[:, None]
 
 
+# the cosine dot and its scaling as the trace names them, inside
+# ``knn.dist`` (``backends.serial.masked_dist_tile`` opens it), and the
+# query side's normalisation, once a query tile ahead of the tile steps
+COSINE_SCOPE = "knn.dist_cosine"
+QUNIT_SCOPE = "knn.qunit"
+
+
+@jax.named_scope(QUNIT_SCOPE)
+def unit_rows(x: jax.Array) -> jax.Array:
+    """The rows of ``x`` (q, d) at unit length: the query side of a
+    prepared cosine search, made once for a query tile and not in its tile
+    steps. Normalised at full precision, held in ``x``'s own dtype (the
+    dot's other operand has it). A row at or under the ``_NORM_EPS`` clamp
+    is not unit (a zero row stays zero, at distance 1 from everything)."""
+    return _l2_normalize(x).astype(x.dtype)
+
+
+def cosine_inv_norms(x: jax.Array) -> jax.Array:
+    """1 / |row| for the rows of ``x`` (r, d) -> (r,), the norm clamped as
+    :func:`_l2_normalize` clamps it: the corpus side of a prepared cosine
+    search, what a tile stack keeps in the slot where an L2 stack keeps its
+    squared norms (``backends.serial.stack_norms``). A square root and a
+    division, not ``rsqrt``: made once a corpus, and the answer's last
+    bits hang on it."""
+    return 1.0 / jnp.sqrt(jnp.maximum(sq_norms(x), _NORM_EPS))
+
+
 def pairwise_cosine(
-    x: jax.Array, y: jax.Array, precision: str | None = None
+    x: jax.Array,
+    y: jax.Array,
+    precision: str | None = None,
+    y_inv: jax.Array | None = None,
 ) -> jax.Array:
     """Cosine *distance* (1 − cosine similarity), (q, d) × (c, d) -> (q, c).
 
-    Normalization happens on device; the inner product is one MXU matmul.
-    Range [0, 2]; smaller = more similar, so the same top-k machinery applies.
+    The inner product is one MXU matmul. Range [0, 2]; smaller = more
+    similar, so the same top-k machinery applies.
+
+    Two forms of one value. Without ``y_inv`` both operands are normalised
+    here, on the device, at every call: the form of a caller that brings
+    rows as it has them (the ring's rounds, ``ops/`` users). With ``y_inv``
+    (:func:`cosine_inv_norms` of ``y``'s rows, kept by whoever keeps ``y``)
+    **``x`` holds unit rows already** (:func:`unit_rows`) and ``y`` is
+    touched by the dot alone: ``d = max(1 - (x . y) * y_inv, 0)`` — no
+    pass over a (c, d) tile to norm it, none to divide it, no second tile
+    written for the dot to read. A row of ``y`` under the clamp has a huge
+    ``y_inv`` and a zero dot: distance 1, as in the first form.
     """
     acc = _acc_dtype(x)
-    xn = _l2_normalize(x)
-    yn = _l2_normalize(y)
+    precision = _dot_precision(x, precision)  # by the rows as brought
+    if y_inv is None:
+        x, y = _l2_normalize(x), _l2_normalize(y)
     sim = jax.lax.dot_general(
-        xn,
-        yn,
+        x,
+        y,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=acc,
-        precision=_dot_precision(x, precision),
+        precision=precision,
     )
+    if y_inv is not None:
+        sim = sim * y_inv[None, :].astype(acc)
     return jnp.maximum(1.0 - sim, 0.0)
 
 
@@ -276,5 +319,7 @@ def pairwise_dist(
     if metric == "l2":
         return pairwise_sq_l2(x, y, x_sq=x_sq, y_sq=y_sq, precision=precision)
     if metric == "cosine":
-        return pairwise_cosine(x, y, precision=precision)
+        # the corpus side's slot holds 1 / |row| for cosine
+        # (``cosine_inv_norms``); given, ``x`` holds unit rows already
+        return pairwise_cosine(x, y, precision=precision, y_inv=y_sq)
     raise ValueError(f"unknown metric {metric!r}")

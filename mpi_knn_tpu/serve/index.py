@@ -37,17 +37,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.backends.serial import (
+    _stack_norms,
     cap_corpus_tile,
     dist_steps,
     serve_chunk,
 )
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
-from mpi_knn_tpu.ops.distance import (
-    center_corpus,
-    onepass_applies,
-    sq_norms,
-)
+from mpi_knn_tpu.ops.distance import center_corpus, onepass_applies
 from mpi_knn_tpu.ops.topk import (
     init_topk,
     init_topk_tiles,
@@ -187,7 +184,7 @@ class SerialLayout(BatchLayout):
         # an index that holds the one-pass fact has a third output: the
         # batch's tile steps by the branch they took
         return rest[0] if rest else dist_steps(
-            q_pad // q_tile, index.tiles.shape[0]
+            q_pad // q_tile, index.tiles.shape[0], index.cfg.metric
         )
 
 
@@ -250,7 +247,8 @@ class RingLayout(BatchLayout):
 
     def batch_dist_steps(self, index, q_pad, q_tile, rest):
         return dist_steps(
-            q_pad // q_tile, index.corpus_sharded.shape[0] // index.c_tile
+            q_pad // q_tile, index.corpus_sharded.shape[0] // index.c_tile,
+            index.cfg.metric,
         )
 
 
@@ -436,7 +434,8 @@ def build_index(
     m, dim = corpus.shape
     backend = resolve_backend(cfg, mesh)
     with _flight_span("index-build", cat="index", backend=backend,
-                      m=int(m), dim=int(dim)):
+                      rows=int(m), dim=int(dim), metric=cfg.metric,
+                      bytes=int(corpus.size) * corpus.dtype.itemsize):
         return _build_index_resident(corpus, cfg, mesh, backend, m, dim)
 
 
@@ -460,6 +459,12 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
         "bf16 number, so batches of such queries take the one-pass "
         "distance dot (the serial layout's rule); else 0",
     ).set(float(onepass is not None))
+    obs_metrics.get_registry().gauge(
+        "serve_index_cosine",
+        help="1 when the resident index answers by cosine distance (the "
+        "serial layout then holds the corpus rows' inverse norms, and "
+        "its batches' tile steps count under path=\"cosine\"); else 0",
+    ).set(float(cfg.metric == "cosine"))
 
     if backend in ("ring", "ring-overlap"):
         from mpi_knn_tpu.backends.ring import parse_ring_mesh, ring_tiles
@@ -542,16 +547,13 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     )
     tiles = pad_rows_any(corpus, c_pad, dtype=dtype).reshape(-1, c_tile, dim)
     tile_ids = jnp.asarray(make_global_ids(m, c_pad).reshape(-1, c_tile))
-    # same norm construction as knn_chunk_update (zeros for cosine, where
-    # the metric kernel normalizes internally), computed UNDER JIT: the
-    # eager-mode reduction produces different bits than the traced one on
-    # CPU, and serving must be bit-identical to a fresh all_knn call
-    acc = jnp.float64 if dtype == jnp.float64 else jnp.float32
-    tile_sqs = (
-        jax.jit(jax.vmap(sq_norms))(tiles)
-        if cfg.metric == "l2"
-        else jnp.zeros(tiles.shape[:2], dtype=acc)
-    )
+    # knn_chunk_update's own norm construction (squared norms for L2; for
+    # cosine the rows' INVERSE norms, so that no batch normalises a corpus
+    # tile): a reduction over the stack, no third corpus-sized array,
+    # computed UNDER JIT — the eager-mode reduction produces different
+    # bits than the traced one on CPU, and serving must be bit-identical
+    # to a fresh all_knn call
+    tile_sqs = _stack_norms(tiles, cfg.metric)
     return CorpusIndex(
         cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
         c_tile=c_tile, mu=mu, layout=SERIAL, tiles=tiles, tile_ids=tile_ids,

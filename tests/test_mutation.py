@@ -186,12 +186,16 @@ def test_refusals_on_immutable_layouts(rng):
 # Serial (dense) layout
 
 
-def test_serial_upsert_delete_roundtrip(rng):
+@pytest.mark.parametrize("metric", ["l2", "cosine"])
+def test_serial_upsert_delete_roundtrip(rng, metric):
+    """(Cosine: an upserted row's slot gets the row's inverse norm, the
+    build's own state — left at the old zeros it would sit at distance 1
+    from its own query.)"""
     X, _ = _blobs(rng, m=200)
     idx = build_index(X, KNNConfig(
         k=5, backend="serial", query_bucket=32, query_tile=32,
         corpus_tile=64, mutation_bucket=32, exclude_zero=False,
-        bucket_headroom=0.5,
+        bucket_headroom=0.5, metric=metric,
     ))
     assert idx.live_rows == 200
     new = rng.standard_normal((9, 16)).astype(np.float32)

@@ -90,7 +90,15 @@ def test_pallas_cosine_duplicate_exclusion(rng, variant):
                   query_tile=32, corpus_tile=64)
     ids = np.asarray(pal.ids)
     assert 60 not in ids[5] and 5 not in ids[60]
-    np.testing.assert_array_equal(ids, np.asarray(ser.ids))
+    # rows 5 and 60 tie exactly for every other query, and which of a tied
+    # pair comes first (or alone, at the k-th place) is the rounding's: the
+    # serial step scales its dot by the row's inverse norm, the kernels
+    # normalise the row first. So the two count as one row here.
+
+    def as_one(a):
+        return np.sort(np.where(a == 60, 5, a), axis=1)
+
+    np.testing.assert_array_equal(as_one(ids), as_one(np.asarray(ser.ids)))
 
 
 def test_pallas_rejects_unknown_variant(rng):
@@ -462,6 +470,58 @@ def test_search_over_a_prepared_stack_compiles_for_the_v5e(
     gib = [c.memory_analysis().temp_size_in_bytes / 2**30
            for c in (per_call, search, norms)]
     assert gib[1] <= gib[0] + 0.01 and gib[2] <= 0.1, gib
+
+
+def test_cosine_batch_program_compiles_for_the_v5e(v5e_devices, monkeypatch):
+    """``serve-dbpedia1m-cos-bulk`` at its size (ISSUE 32): one 1024-row
+    bucket over 123 resident tiles of 8192 x 1536 float32 and their inverse
+    norms. The tile step holds ONE dot, float32 at ``highest`` under
+    ``knn.dist_cosine``, fed by the stack's slice itself — no divide, no
+    root and no second 8192 x 1536 tile anywhere in the scan; the query
+    side's normalisation is ``knn.qunit``'s, outside it. The program's
+    scratch stays under 0.2 GiB beside the 5.77 GiB stack, and the norms'
+    own program, run once an index, copies none of it."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, tiles, dim = 1024, 123, 1536
+    cfg = KNNConfig(k=10, backend="serial", metric="cosine", query_tile=q,
+                    corpus_tile=8192, exclude_zero=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    stack = arg((tiles, 8192, dim), jnp.float32)
+    with jax.enable_x64(False):
+        batch = jax.jit(
+            serial.serve_chunk, static_argnames=("cfg",), donate_argnums=(2, 3)
+        ).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32),
+            stack, arg((tiles, 8192), jnp.int32),
+            arg((tiles, 8192), jnp.float32), None, cfg=cfg,
+        ).compile()
+        norms = serial._stack_norms.lower(stack, "cosine").compile()
+    hlo = batch.as_text()
+    assert _dist_dots(hlo) == {"cosine": (("f32", "f32"), "highest")}
+    normalising = [ln for ln in hlo.splitlines()
+                   if re.search(r" (divide|sqrt|rsqrt)\(", ln)]
+    assert normalising and all("knn.qunit" in ln for ln in normalising)
+    # nothing computes a corpus tile: the only instructions of that shape
+    # slice the stack for the dot (a fusion inside the dot's own fusion)
+    made = {m.group(1) for m in re.finditer(
+        rf"= f32\[8192,{dim}\]\S* ([a-z-]+)\(", hlo)}
+    assert made <= {"parameter", "dynamic-slice", "bitcast", "fusion"}, made
+    assert batch.memory_analysis().temp_size_in_bytes <= 0.2 * 2**30
+    assert norms.memory_analysis().temp_size_in_bytes <= 0.1 * 2**30
 
 
 def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(

@@ -148,7 +148,8 @@ def test_hit_miss_handle_and_parent_path_are_bit_identical(
         assert np.array_equal(np.asarray(got.ids), want_i)
         assert np.array_equal(np.asarray(got.dists), want_d)
     # the one-pass rule engaged where the data allows it, and nowhere else
-    steps = np.asarray(hit.dist_steps).reshape(-1, 2).sum(axis=0)
+    steps = np.asarray(hit.dist_steps)
+    steps = steps.reshape(-1, steps.shape[-1]).sum(axis=0)
     assert np.array_equal(
         np.asarray(miss.dist_steps), np.asarray(hit.dist_steps))
     assert (steps[0] > 0) == (whole and form == "l2-centred")

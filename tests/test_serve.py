@@ -101,12 +101,15 @@ def test_query_parity_metrics_serial(rng, metric):
     )
 
 
-def test_bucket_boundary_sizes(rng):
+@pytest.mark.parametrize("metric", ["l2", "cosine"])
+def test_bucket_boundary_sizes(rng, metric):
     """Batch sizes straddling every bucket boundary (1, b−1, b, b+1, and
     the next bucket's boundary) all pad+mask to the all_knn answer — a
-    ragged batch is bit-identical to its unpadded self."""
+    ragged batch is bit-identical to its unpadded self. (Cosine: the
+    padding rows are zero rows, which the query side's normalisation
+    leaves zero.)"""
     X = _data(rng)
-    cfg = _cfg("serial")
+    cfg = _cfg("serial", metric=metric)
     idx = build_index(X, cfg)
     Qfull = _data(rng, m=40)
     for n in (1, 15, 16, 17, 31, 32, 33):
@@ -139,13 +142,16 @@ def test_device_and_host_queries_bit_identical(rng):
         )
 
 
-def test_device_resident_corpus_index(rng):
+@pytest.mark.parametrize("metric", ["l2", "cosine"])
+def test_device_resident_corpus_index(rng, metric):
     """An index built from a device-resident corpus serves the same
     answers as all_knn over that device corpus (per-residency parity —
-    the centering mean is residency-specific by documented contract)."""
+    the centering mean is residency-specific by documented contract; the
+    cosine stack's inverse norms are one jitted function's on both
+    sides)."""
     X, Q = _data(rng), _data(rng, m=24)
     Xd = jax.device_put(jnp.asarray(X))
-    cfg = _cfg("serial")
+    cfg = _cfg("serial", metric=metric)
     want = all_knn(Xd, queries=Q, config=cfg)
     idx = build_index(Xd, cfg)
     got = query_knn(Q, idx)
