@@ -275,8 +275,8 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
 
     form, make = _form_and_maker(backend, cfg, m, dim, q_arr.shape[0], mesh)
     prepared = _corpus_side(corpus, cfg, form, make, sliced=queries is not None)
-    d, i, steps = prepared.search(q_arr, q_ids, cfg)
-    return KNNResult(dists=d, ids=i, dist_steps=steps)
+    d, i, counts = prepared.search(q_arr, q_ids, cfg)
+    return KNNResult(dists=d, ids=i, **counts._asdict())
 
 
 def build_index(corpus, config: Optional[KNNConfig] = None, mesh=None,

@@ -89,7 +89,10 @@ from mpi_knn_tpu.ops.topk import init_topk
 from mpi_knn_tpu.backends.serial import (
     PreparedCorpus,
     cap_corpus_tile,
+    TileCounts,
+    carried_depth,
     dist_steps,
+    tile_counts,
     merge_tiles_into_carry,
     onepass_rule,
 )
@@ -200,8 +203,11 @@ def _ring_knn_local(
     # the antipodal round)
     onepass=None,  # whole rotations of the XLA float ring only: the corpus
     # side of the one-pass rule (backends.serial.masked_dist_tile), one
-    # replicated bool scalar; adds a third output, this device's tile steps
-    # by the branch they took (backends.serial.dist_steps), shape (1, 2)
+    # replicated bool scalar; adds a third output, this device's
+    # backends.serial.TileCounts: its tile steps by the branch they took
+    # and, where the rounds' scans carry the lane-bin lists, its
+    # (query tile, round) merges by what became of the carried selection,
+    # shape (1, 2) each
 ):
     """Per-device body under shard_map: rotate corpus blocks around the ring,
     merging each into the local top-k carry.
@@ -340,7 +346,11 @@ def _ring_knn_local(
 
     @jax.named_scope(ROUND_SCOPE)
     def compute(blk, blk_ids, blk_scl, cd, ci):
-        """Tiled (q_local × b) step: all query tiles against all block tiles."""
+        """Tiled (q_local × b) step: all query tiles against all block
+        tiles. Returns the carry and, third, one verdict a query tile where
+        the round's scans carried the lane-bin lists (``backends.serial
+        merge_tiles_into_carry``: flagged rows were answered again), else
+        None."""
         if fused:
             # the fused Pallas kernel replaces the whole per-round merge —
             # dequant/upcast, masked tile distances and the carry top-k all
@@ -359,7 +369,7 @@ def _ring_knn_local(
                 q_tile=q_tile,
                 c_tile=c_tile,
             )
-            return fd.reshape(cd.shape), fi.reshape(ci.shape)
+            return fd.reshape(cd.shape), fi.reshape(ci.shape), None
         if blk_scl is not None:
             # the int8 dequant: ONE convert out of the code domain and ONE
             # multiply by the block's scale vector, feeding every distance
@@ -388,7 +398,9 @@ def _ring_knn_local(
             per_query_tile, (q_tiles, qid_tiles, cd, ci, q_one))
 
     def step(state, _):
-        blk, scl, blk_ids, cd, ci = state
+        # ``rescanned``: the whole rotation's count of re-scanned merges,
+        # where it is kept (below), else nothing
+        blk, scl, blk_ids, cd, ci, *rescanned = state
         if fused_dma:
             # collective-matmul round: ONE kernel issues the async remote
             # copies of the resident block and runs the distance sweep —
@@ -418,7 +430,7 @@ def _ring_knn_local(
             nxt = _rot(blk, perm)
             nscl = _rot(scl, perm)
             nxt_ids = _rot(blk_ids, perm)
-            cd, ci = compute(blk, blk_ids, scl, cd, ci)
+            cd, ci, again = compute(blk, blk_ids, scl, cd, ci)
         else:
             # blocking parity: the collective is sequenced *after* the compute
             # via an explicit barrier, modelling the reference's
@@ -430,24 +442,31 @@ def _ring_knn_local(
             # which found exactly that bug in the pre-r5 code). On a
             # multi-axis mesh this threading is type-impossible (the raise
             # above), so reaching here means the 1-D ring.
-            cd, ci = compute(blk, blk_ids, scl, cd, ci)
+            cd, ci, again = compute(blk, blk_ids, scl, cd, ci)
             blk, scl, blk_ids, cd, ci = jax.lax.optimization_barrier(
                 (blk, scl, blk_ids, cd, ci)
             )
             nxt = _rot(blk, perm)
             nscl = _rot(scl, perm)
             nxt_ids = _rot(blk_ids, perm)
-        return (nxt, nscl, nxt_ids, cd, ci), None
+        return (nxt, nscl, nxt_ids, cd, ci,
+                *(n + jnp.sum(again, dtype=jnp.int32) for n in rescanned)
+                ), None
 
     rounds, bwd_limit = bidir_rounds(num_dev)
 
-    def finish(cd, ci):
+    def finish(cd, ci, rescanned=None):
         out = cd.reshape(q_local, cfg.k), ci.reshape(q_local, cfg.k)
         if q_one is None:
             return out
         # a query tile meets every corpus tile once a rotation, whatever
-        # the schedule
-        return *out, dist_steps(q_one, num_dev * (b // c_tile)).reshape(1, 2)
+        # the schedule, and merges once a round
+        merges = num_dev * q_one.size
+        return *out, TileCounts(
+            dist_steps(q_one, num_dev * (b // c_tile)).reshape(1, 2),
+            None if rescanned is None else jnp.stack(
+                [merges - rescanned, rescanned]).reshape(1, 2),
+        )
 
     def bidir_step(state, r):
         """One full-duplex round: the forward traveler (block i−r) always
@@ -461,7 +480,7 @@ def _ring_knn_local(
         do_bwd = jnp.logical_and(r >= 1, r < bwd_limit)
 
         def merge_bwd_traveler(cd, ci):
-            return compute(bblk, bids, bscl, cd, ci)
+            return compute(bblk, bids, bscl, cd, ci)[:2]
 
         def skip(cd, ci):
             return cd, ci
@@ -471,7 +490,7 @@ def _ring_knn_local(
             # backward merge is round-dependent, so the heavy per-tile
             # reduction is traced once per branch role, not duplicated
             # across both cond branches
-            cd, ci = compute(fblk, fids, fscl, cd, ci)
+            cd, ci, _ = compute(fblk, fids, fscl, cd, ci)
             return jax.lax.cond(do_bwd, merge_bwd_traveler, skip, cd, ci)
 
         if overlap:
@@ -538,11 +557,11 @@ def _ring_knn_local(
                     "bidir int8 single-round needs the backward traveler's "
                     "scale vector (block_bwd_scale)"
                 )
-            carry_d, carry_i = compute(
+            carry_d, carry_i, _ = compute(
                 block, block_ids, block_scale, carry_d, carry_i
             )
             if merge_bwd:
-                carry_d, carry_i = compute(
+                carry_d, carry_i, _ = compute(
                     block_bwd, block_bwd_ids, block_bwd_scale,
                     carry_d, carry_i,
                 )
@@ -579,7 +598,7 @@ def _ring_knn_local(
                 (block, block_scale, block_ids, carry_d, carry_i), None
             )
         else:
-            carry_d, carry_i = compute(
+            carry_d, carry_i, _ = compute(
                 block, block_ids, block_scale, carry_d, carry_i
             )
             nxt, nscl, nxt_ids = block, block_scale, block_ids
@@ -607,11 +626,18 @@ def _ring_knn_local(
     # P steps: own block once, then each of the P-1 received blocks — the
     # correct rotation the reference missed (SURVEY.md Q1). The final
     # permute's output is unused; XLA dead-code-eliminates it.
-    (_, _, _, carry_d, carry_i), _ = jax.lax.scan(
-        step, (block, block_scale, block_ids, carry_d, carry_i),
+    # where the device's counts go out (``onepass``) and the rounds' scans
+    # carry the lists, the rotation counts its re-scanned merges
+    rescanned = ()
+    if q_one is not None and not fused and carried_depth(
+            cfg, q_tile, c_tile, varying=True) is not None:
+        rescanned = (jax.lax.pcast(
+            jnp.int32(0), tuple(vary_axes) or (axis,), to="varying"),)
+    (_, _, _, carry_d, carry_i, *rescanned), _ = jax.lax.scan(
+        step, (block, block_scale, block_ids, carry_d, carry_i, *rescanned),
         None, length=num_dev
     )
-    return finish(carry_d, carry_i)
+    return finish(carry_d, carry_i, *rescanned)
 
 
 def parse_ring_mesh(mesh: Mesh):
@@ -719,7 +745,7 @@ def _ring_knn_sharded(
     corpus (``ring_transfer_dtype="int8"``; quantized at shard time by the
     host wrapper), sharded like the corpus. ``onepass`` is
     :func:`_ring_knn_local`'s, replicated; with it the third output holds
-    one row of step counts a device."""
+    one row of each of its counts a device."""
     body = functools.partial(
         _ring_knn_local,
         cfg=cfg,
@@ -895,7 +921,7 @@ class RingCorpus(PreparedCorpus):
             qids_p = jax.device_put(
                 pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32),
                 q_sharding)
-            best_d, best_i, *steps = _ring_knn_sharded(
+            best_d, best_i, *counts = _ring_knn_sharded(
                 queries_p,
                 qids_p,
                 self.corpus_p,
@@ -921,9 +947,8 @@ class RingCorpus(PreparedCorpus):
                 help="bytes all devices send over the interconnect "
                 "(ring_wire_bytes_per_batch a call)",
             ).inc(wire_bytes)
-            return best_d[:nq], best_i[:nq], (
-                steps[0] if steps else dist_steps(
-                    q_pad // q_tile, c_pad // self.c_tile, cfg.metric))
+            return best_d[:nq], best_i[:nq], tile_counts(
+                counts, q_pad // q_tile, c_pad // self.c_tile, cfg.metric)
 
 
 def prepare_ring(corpus, cfg: KNNConfig, form: dict) -> RingCorpus:

@@ -25,6 +25,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +47,11 @@ from mpi_knn_tpu.ops.distance import (
 from mpi_knn_tpu.ops.rerank import compress_rerank_tile
 from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
+    init_topk,
     init_topk_tiles,
+    lane_bin_depth,
     mask_tile,
+    merge_topk,
     smallest_k,
 )
 from mpi_knn_tpu.parallel.partition import (
@@ -97,6 +101,57 @@ def dist_steps(took, steps: int, metric: str = "l2"):
     return jnp.stack([one, took.size - one]) * steps
 
 
+class TileCounts(typing.NamedTuple):
+    """What a tile program counts on the device and returns beside its
+    answer, third of its outputs (a program that counts neither returns
+    two); a field is None where the program does not count it."""
+
+    # :func:`dist_steps`, from a program that carries the one-pass branch
+    dist_steps: jax.Array | None = None
+    # :func:`select_tiles`, from a program whose scans carry the lists
+    select_tiles: jax.Array | None = None
+
+
+def select_tiles(rescanned: jax.Array):
+    """A dispatch's query-tile merges by what became of the carried
+    selection, int32 ``[carried, rescanned]``: what
+    ``KNNResult.select_tiles`` and the counter
+    ``knn_select_query_tiles_total`` hold. ``rescanned`` is one verdict a
+    merge (:func:`merge_tiles_into_carry`'s third output): some row failed
+    the certificate and the flagged rows were answered again."""
+    again = jnp.sum(rescanned, dtype=jnp.int32)
+    return jnp.stack([rescanned.size - again, again])
+
+
+def tile_counts(rest: tuple, merges: int, steps: int,
+                metric: str) -> TileCounts:
+    """A dispatch's :class:`TileCounts` from what its program returned
+    beyond (dists, ids): in a program without the one-pass branch each of
+    the ``merges`` query-tile merges met its ``steps`` corpus tiles on the
+    one path the program holds, a static count."""
+    counts = rest[0] if rest else TileCounts()
+    if counts.dist_steps is None:
+        counts = counts._replace(
+            dist_steps=dist_steps(merges, steps, metric))
+    return counts
+
+
+def carried_depth(cfg: KNNConfig, q_rows: int, c_tile: int,
+                  varying: bool = False) -> int | None:
+    """The lane-bin depth at which a ``twolevel`` merge of (q_rows x c_tile)
+    tile steps carries its lists through the scan, or None: the per-tile
+    program. The test ``smallest_k`` makes of a tile with 1-D ids, where
+    the exact policy and method put every tile of the stack through it.
+    ``varying``: the operands vary over a checked ``shard_map``'s axes (the
+    XLA ring), under which the kernels run on the TPU only (``ops/topk.py
+    _lane_bin_smallest_k``)."""
+    if (cfg.merge_schedule != "twolevel" or cfg.precision_policy != "exact"
+            or cfg.topk_method != "exact"
+            or (varying and jax.default_backend() != "tpu")):
+        return None
+    return lane_bin_depth(q_rows, c_tile, cfg.k)
+
+
 @jax.named_scope("knn.dist")
 def masked_dist_tile(
     q_x: jax.Array,
@@ -131,31 +186,38 @@ def masked_dist_tile(
     else:
         scope = contextlib.nullcontext()
     with scope:
-        if onepass:
-            d = pairwise_sq_l2(q_x, blk, x_sq=q_sq, y_sq=blk_sq, onepass=True)
-        else:
-            d = pairwise_dist(
-                q_x,
-                blk,
-                metric=cfg.metric,
-                x_sq=q_sq,
-                y_sq=blk_sq,
-                precision=cfg.matmul_precision,
-            )
-        if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
-            pair_scale = q_sq[:, None] + blk_sq[None, :]
-        else:
-            # cosine distances live in [0, 2]; constant scale for the zero test
-            pair_scale = jnp.asarray(2.0, dtype=d.dtype)
-        return mask_tile(
-            d,
-            blk_ids,
-            query_ids=q_ids if cfg.exclude_self else None,
-            exclude_self=cfg.exclude_self,
-            exclude_zero=cfg.exclude_zero,
-            zero_eps=cfg.zero_eps,
-            scale=pair_scale,
+        return _masked_dist_tile(
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
+
+
+def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass):
+    """:func:`masked_dist_tile` under no scope of its own (the re-scan of
+    flagged rows sits in ``knn.select/fallback`` with all it runs)."""
+    if onepass:
+        d = pairwise_sq_l2(q_x, blk, x_sq=q_sq, y_sq=blk_sq, onepass=True)
+    else:
+        d = pairwise_dist(
+            q_x,
+            blk,
+            metric=cfg.metric,
+            x_sq=q_sq,
+            y_sq=blk_sq,
+            precision=cfg.matmul_precision,
         )
+    if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
+        pair_scale = q_sq[:, None] + blk_sq[None, :]
+    else:
+        # cosine distances live in [0, 2]; constant scale for the zero test
+        pair_scale = jnp.asarray(2.0, dtype=d.dtype)
+    return mask_tile(
+        d,
+        blk_ids,
+        query_ids=q_ids if cfg.exclude_self else None,
+        exclude_self=cfg.exclude_self,
+        exclude_zero=cfg.exclude_zero,
+        zero_eps=cfg.zero_eps,
+        scale=pair_scale,
+    )
 
 
 def local_tile_topk(
@@ -211,6 +273,20 @@ def _select_tile(d, blk_ids, k, method, recall_target, block):
 # through one jitted function cost its warm-up 1.5 s (PERF.md §6, PR 29).
 _select_tile_once = jax.jit(
     _select_tile, static_argnames=("k", "method", "recall_target", "block"))
+
+
+@jax.named_scope("knn.select")
+def _insert_tile(lists_d, lists_i, d, blk_ids, depth):
+    # imported where a program first carries the lists, as ``ops/topk.py``
+    # imports it where one first selects from a wide tile (pallas: ~0.8 s)
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_insert
+
+    return lane_bin_insert((lists_d, lists_i), d, blk_ids, depth)
+
+
+# as :data:`_select_tile_once`: one trace of the bins kernel for the two
+# branches of the one-pass rule
+_insert_tile_once = jax.jit(_insert_tile, static_argnames=("depth",))
 
 
 def knn_tile_step(
@@ -315,12 +391,17 @@ def serve_chunk(
 
     ``onepass`` (a bool scalar on the device: every centred corpus element
     is a bf16 number) puts both branches of :func:`masked_dist_tile` into
-    the program; each query tile adds its own half of the verdict, and a
-    third output counts the tile steps by the branch they took
-    (:func:`dist_steps`, what the engagement counter reads). None: the
-    program and its two outputs as they always were; so too where the rule
-    does not apply (:func:`onepass_rule`: a small bucket,
-    ``precision_policy="mixed"`` as a serving rung).
+    the program; each query tile adds its own half of the verdict. None:
+    the program without the branch; so too where the rule does not apply
+    (:func:`onepass_rule`: a small bucket, ``precision_policy="mixed"`` as
+    a serving rung).
+
+    A program that counts on the device returns a third output,
+    :class:`TileCounts`: the tile steps by the branch they took (the
+    one-pass rule's: :func:`dist_steps`) and the query tiles by what became
+    of the selection their scans carried (:func:`select_tiles`: every
+    program :func:`carried_depth` engages). A program that counts neither
+    returns two, as it always did.
 
     Cosine: the query side's unit rows are made HERE, once a query tile
     and ahead of its scan (scope ``knn.qunit``), where L2 norms its query
@@ -339,16 +420,19 @@ def serve_chunk(
         else:
             q_x = unit_rows(q_x)
         one = None if onepass is None else onepass & bf16_exact(q_x)
-        out = merge_tiles_into_carry(
+        return *merge_tiles_into_carry(
             q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one
-        )
-        return out if one is None else (*out, one)
+        ), one
 
-    out = jax.lax.map(per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
-    if onepass is None:
-        return out
-    best_d, best_i, took = out
-    return best_d, best_i, dist_steps(took, tiles.shape[0])
+    best_d, best_i, rescanned, took = jax.lax.map(
+        per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    counts = TileCounts(
+        None if took is None else dist_steps(took, tiles.shape[0]),
+        None if rescanned is None else select_tiles(rescanned),
+    )
+    if counts == TileCounts():
+        return best_d, best_i
+    return best_d, best_i, counts
 
 
 def merge_tiles_into_carry(
@@ -367,13 +451,31 @@ def merge_tiles_into_carry(
     ``cfg.merge_schedule``. The single implementation behind the serial
     chunk scan and the ring backends' per-round block loop (the schedules
     must match or the ring's per-round cost diverges from serial's).
+    Returns ``(dists, ids, rescanned)``: the merged carry and, from an
+    engaged ``twolevel`` program, a bool scalar — some row failed the
+    selection's certificate and the flagged rows were answered again — else
+    None.
 
-    - "twolevel": level 1 — independent local top-k per corpus tile (no
-      carry dependence between scan steps, so XLA can pipeline the sort of
-      tile t with the matmul of tile t+1); level 2 — ONE narrow cascade
-      merge over the incoming carry plus every tile's k survivors,
-      (n_tiles+1)·k columns instead of a (carry ‖ c_tile)-wide reduction
-      per tile. Measured faster on v5e (BASELINE.md r3), now the default.
+    - "twolevel", where the lane-bin rule engages for the stack's tiles
+      (:func:`carried_depth`: the exact policy and method, k <= 128, tiles
+      >= 1024 wide — every cell's program): the scan over the tiles
+      carries the per-(row, lane) lists of the selection, a step is the
+      distance tile and *bins* into them, and after the scan ONE *finish*
+      turns them into the stack's k survivors, one certificate says
+      whether anything dropped anywhere could have belonged, the rows it
+      flags are scanned again exactly (:func:`_rescan_flagged`), and a
+      2k-column exact merge joins the survivors to the incoming carry
+      (:func:`_merge_carried`). No per-tile top-k, no (T, q, k) stack of
+      survivors and no cascade exist in such a program; its third output
+      says whether the query tile was re-scanned.
+    - "twolevel" elsewhere (k > 128, ``mixed``, another ``topk_method``,
+      narrow tiles, the ring's interpreted form off the TPU): level 1 —
+      independent local top-k per corpus tile (no carry dependence between
+      scan steps, so XLA can pipeline the sort of tile t with the matmul
+      of tile t+1); level 2 — ONE narrow cascade merge over the incoming
+      carry plus every tile's k survivors, (n_tiles+1)·k columns instead of
+      a (carry ‖ c_tile)-wide reduction per tile. Measured faster on v5e
+      (BASELINE.md r3), now the default.
     - "stream": carry threaded through the tile scan — the reference's
       accumulate-as-you-go shape (``knn-serial.c:86-91``), batched.
 
@@ -390,11 +492,14 @@ def merge_tiles_into_carry(
     stack; None — the rule does not apply (:func:`onepass_rule`), or the
     corpus is known not to qualify — is the program as it always was.
     Given, every tile step is a ``lax.cond`` over two whole steps —
-    distances AND their reduction to k survivors — that differ in the
-    distance dot alone (:func:`masked_dist_tile`). Where the conditional
-    sits was decided by what the v5e compiler does with it (PERF.md §6,
-    PR 29): around the dot alone, a tile small enough for fast memory
-    leaves it, because the conditional's output does not live there;
+    distances AND their selection (the reduction to k survivors; in an
+    engaged program *bins* into the carried lists, which the conditional
+    takes and returns: the v5e compiler updates them in place through it,
+    ``tests/test_pallas.py`` reads that in the compiled program) — that
+    differ in the distance dot alone (:func:`masked_dist_tile`). Where the
+    conditional sits was decided by what the v5e compiler does with it
+    (PERF.md §6, PR 29): around the dot alone, a tile small enough for fast
+    memory leaves it, because the conditional's output does not live there;
     around the whole scan, the f32 -> bf16 narrowing of the WHOLE stack is
     hoisted out of the loop, 2.3 GiB and 28 ms at every call of the
     all-kNN cell. Around the step, the tile is laid out ahead of the
@@ -414,7 +519,15 @@ def merge_tiles_into_carry(
             *operands,
         )
 
+    stack = (tiles, tile_ids, tile_sqs)
     if cfg.merge_schedule == "twolevel":
+        depth = carried_depth(
+            cfg, carry_d.shape[0], tiles.shape[1],
+            bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma))
+        if depth is not None:
+            return _merge_carried(
+                q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
+                either, nested=onepass is not None)
 
         def local(_, tile):
             # per-tile reduction honors cfg.precision_policy (exact single
@@ -428,13 +541,13 @@ def merge_tiles_into_carry(
                 *tile,
             )
 
-        _, (ld, li) = jax.lax.scan(local, None, (tiles, tile_ids, tile_sqs))
+        _, (ld, li) = jax.lax.scan(local, None, stack)
         n_tiles = ld.shape[0]
         q_rows = carry_d.shape[0]
         with jax.named_scope("knn.merge"):
             ld = jnp.moveaxis(ld, 0, 1).reshape(q_rows, n_tiles * cfg.k)
             li = jnp.moveaxis(li, 0, 1).reshape(q_rows, n_tiles * cfg.k)
-            return cascade_smallest_k(
+            return *cascade_smallest_k(
                 jnp.concatenate([carry_d, ld], axis=-1),
                 jnp.concatenate([carry_i, li], axis=-1),
                 cfg.k,
@@ -447,7 +560,7 @@ def merge_tiles_into_carry(
                     else "exact"
                 ),
                 block=cfg.topk_block,
-            )
+            ), None
 
     def step(carry, tile):
         return (
@@ -460,8 +573,134 @@ def merge_tiles_into_carry(
             None,
         )
 
-    out, _ = jax.lax.scan(step, (carry_d, carry_i), (tiles, tile_ids, tile_sqs))
-    return out
+    out, _ = jax.lax.scan(step, (carry_d, carry_i), stack)
+    return *out, None
+
+
+def _varying_like(x: jax.Array, *operands):
+    """``x``, a constant, typed as varying over every mesh axis that an
+    operand varies over (a checked ``shard_map``: a loop's carry has one
+    type from its first step); elsewhere ``x`` as it is."""
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    return jax.lax.pcast(x, tuple(vma), to="varying") if vma else x
+
+
+def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
+                   either, nested):
+    """The engaged ``twolevel`` merge (:func:`merge_tiles_into_carry`): the
+    scan over the stack's tiles carries the lane-bin lists — a step is the
+    distance tile and *bins* into them, under the one-pass rule's
+    conditional, which takes and returns the lists — and after it ONE
+    *finish* gives the stack's k survivors and the certificate, and the
+    narrow exact merge joins them to the incoming carry. ``nested``: the
+    step is traced twice, so *bins* goes through its nested jit."""
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_lists, lane_bin_result
+
+    q_rows, k = carry_d.shape
+    insert = _insert_tile_once if nested else _insert_tile
+
+    def dist_tile(blk, blk_ids, blk_sq, one, scoped=True):
+        dist = masked_dist_tile if scoped else _masked_dist_tile
+        return dist(
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, one,
+        ).astype(carry_d.dtype)
+
+    def step(lists, tile):
+        return either(
+            lambda blk, blk_ids, blk_sq, ld, li, one: insert(
+                ld, li, dist_tile(blk, blk_ids, blk_sq, one), blk_ids,
+                depth=depth),
+            *tile, *lists,
+        ), None
+
+    lists, _ = jax.lax.scan(
+        step,
+        tuple(_varying_like(x, q_x, stack[0])
+              for x in lane_bin_lists(q_rows, depth, carry_d.dtype)),
+        stack,
+    )
+    with jax.named_scope("knn.select"):
+        vals, ids, flagged = lane_bin_result(lists, q_rows, k)
+        with jax.named_scope("finish"):
+            rescanned = jnp.any(flagged)
+        # the scope holds the re-scan and nothing else: its device time in
+        # a trace is what failed certificates cost
+        with jax.named_scope("fallback"):
+            vals, ids = _rescan_flagged(
+                flagged, vals, ids,
+                functools.partial(dist_tile, scoped=False), stack, either)
+    with jax.named_scope("knn.merge"):
+        return *merge_topk(carry_d, carry_i, vals, ids), rescanned
+
+
+# rows a pass of the re-scan answers: one sublane tile of a float32 vreg
+_RESCAN_ROWS = 8
+
+
+def _rescan_flagged(flagged, vals, ids, dist_tile, stack, either):
+    """``(vals, ids)`` with every ``flagged`` row answered again, exactly:
+    the certificate's way out of a carried scan. While rows are flagged,
+    the stack is walked once more for a handful of them: the step's own
+    distance tile (``dist_tile``, under ``either``: the values the lists
+    were filled from), the handful's rows of it, and the full-width
+    ``lax.top_k`` of (survivors ‖ rows), as the ``stream`` schedule merges.
+    No kernel in it, so it adds a few XLA operations to a program's set-up;
+    it runs for a few query tiles in a thousand (``ops/topk.py
+    lane_bin_depth``) and then costs the stack's distance tiles once more a
+    pass, whatever flagged the rows (a collision in one lane; fewer than k
+    finite candidates, where the full-width selection has always decided
+    the answer) and however many there are.
+
+    Its form is what the v5e compiler left room for (PERF.md §6, PR 33;
+    ``tests/test_pallas.py -k one_pass_rule`` holds it): a second loop over the
+    stack beside the scan, or passes as a loop around a scan, and the
+    compiler lays the WHOLE stack out anew ahead of both (at d = 784 a
+    copy of 4.6 GiB, which the all-kNN cell has no room for); one flat
+    loop inside a conditional keeps the scan's own lay-out of a tile a
+    step. So: one loop over (pass, tile), which commits a pass's answers
+    and picks the next rows at the pass's last tile. The whole query
+    tile's distances are computed though a few rows are wanted: the dot
+    the scan runs."""
+    q_rows, k = vals.shape
+    g = min(_RESCAN_ROWS, q_rows)
+    n_tiles = stack[0].shape[0]
+
+    def pick(todo):
+        # flagged rows first, the lowest first; where fewer than g are
+        # left, rows that passed are answered again with them: the same
+        # values
+        return jax.lax.top_k(todo.astype(jnp.int32), g)[1]
+
+    def fresh():
+        return tuple(_varying_like(x, vals, stack[0])
+                     for x in init_topk(g, k, vals.dtype))
+
+    def step(state):
+        i, todo, rows, best, vals, ids = state
+        t = i % n_tiles
+        tile = tuple(
+            jax.lax.dynamic_index_in_dim(x, t, keepdims=False) for x in stack)
+        d = either(dist_tile, *tile)[rows]
+        best = merge_topk(
+            *best, d, jnp.broadcast_to(tile[1][None, :], d.shape))
+        last = t == n_tiles - 1
+        done = jnp.where(last, rows, q_rows)  # out of range: nothing written
+        todo = todo.at[done].set(False, mode="drop")
+        vals = vals.at[done].set(best[0], mode="drop")
+        ids = ids.at[done].set(best[1], mode="drop")
+        rows = jnp.where(last, pick(todo), rows)
+        best = tuple(jnp.where(last, a, b) for a, b in zip(fresh(), best))
+        return i + 1, todo, rows, best, vals, ids
+
+    return jax.lax.cond(
+        jnp.any(flagged),
+        lambda: jax.lax.while_loop(
+            lambda state: jnp.any(state[1]) | (state[0] % n_tiles != 0),
+            step,
+            (jnp.int32(0), flagged, pick(flagged), fresh(), vals, ids),
+        )[4:],
+        lambda: (vals, ids),
+    )
 
 
 def cap_corpus_tile(q_tile: int, c_tile: int, max_tile_elems: int) -> int:
@@ -551,7 +790,7 @@ class PreparedCorpus:
         return self.m, self.dim
 
     def search(self, queries, query_ids, cfg: KNNConfig):
-        """``((q, k) dists, (q, k) ids, dist_steps)`` of ``queries``
+        """``((q, k) dists, (q, k) ids, TileCounts)`` of ``queries``
         (uncentred, as the caller has them) against this corpus. The query
         side and the tile program only: no pass over the corpus, no host
         read of a device value."""
@@ -584,12 +823,12 @@ def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass, *,
     acc = jnp.float64 if q_tiles.dtype == jnp.float64 else jnp.float32
     carry_d, carry_i = init_topk_tiles(q_pad // q_tile, q_tile, cfg.k,
                                        dtype=acc)
-    best_d, best_i, *steps = serve_chunk(
+    best_d, best_i, *counts = serve_chunk(
         q_tiles, qid_tiles, carry_d, carry_i, tiles, tile_ids, tile_sqs,
         onepass, cfg=cfg,
     )
     return (best_d.reshape(q_pad, cfg.k)[:nq],
-            best_i.reshape(q_pad, cfg.k)[:nq], *steps)
+            best_i.reshape(q_pad, cfg.k)[:nq], *counts)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -608,14 +847,14 @@ class SerialCorpus(PreparedCorpus):
         q_tile = effective_tiles(cfg, self.m, nq)[0]
         if self.mu is not None:
             queries = queries - self.mu  # center_for_l2's own subtraction
-        best_d, best_i, *steps = _search_stack(
+        best_d, best_i, *counts = _search_stack(
             queries, query_ids, self.tiles, self.tile_ids, self.tile_sqs,
             self.onepass if onepass_rule(cfg, q_tile) else None,
             cfg=cfg, q_tile=q_tile,
         )
-        return best_d, best_i, steps[0] if steps else dist_steps(
-            pad_to_multiple(nq, q_tile) // q_tile, self.tiles.shape[0],
-            cfg.metric)
+        return best_d, best_i, tile_counts(
+            counts, pad_to_multiple(nq, q_tile) // q_tile,
+            self.tiles.shape[0], cfg.metric)
 
 
 def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:
@@ -643,6 +882,6 @@ def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:
 def all_knn_serial(corpus, queries, query_ids, cfg: KNNConfig):
     """One whole call on arrays as the caller has them: prepare the corpus,
     search it, drop it. Returns ((q, k) dists, (q, k) ids,
-    :func:`dist_steps`), the first two device arrays."""
+    :class:`TileCounts`), the first two device arrays."""
     form = serial_form(cfg, *corpus.shape, queries.shape[0])
     return prepare_serial(corpus, cfg, form).search(queries, query_ids, cfg)

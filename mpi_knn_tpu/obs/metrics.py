@@ -56,6 +56,10 @@ COMPILE_BUCKETS_S = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 # tile steps by the distance dot's path (MetricsRegistry.count_dist_steps)
 DIST_STEPS = "knn_dist_tile_steps_total"
 DIST_PATHS = ("onepass", "multipass", "cosine")  # a count's columns
+# query-tile merges by what became of the selection their scans carried
+# (MetricsRegistry.count_select_tiles)
+SELECT_TILES = "knn_select_query_tiles_total"
+SELECT_PATHS = ("carried", "rescanned")  # a count's columns
 
 JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a program found in jax's persistent compilation cache (at jax 0.9.0 the
@@ -344,19 +348,36 @@ class MetricsRegistry:
         device. The device decides the L2 path, so call this where the
         answer has been fetched (a server's retire, a job's end): reading
         a count that is not ready waits for its program."""
+        self._count_columns(
+            DIST_STEPS, DIST_PATHS, steps,
+            "tile steps dispatched, by the path of the distance "
+            "dot: one bf16 pass (both operands bf16 numbers), the "
+            "configured multi-pass dot, or the cosine dot (a scaled "
+            "dot at the configured precision over prepared operands)",
+        )
+
+    def count_select_tiles(self, tiles) -> None:
+        """Add a dispatch's query-tile merges to
+        ``knn_select_query_tiles_total{path="carried"|"rescanned"}``.
+        ``tiles`` is ``KNNResult.select_tiles`` / ``BatchResult
+        .select_tiles``: ints ``[carried, rescanned]``, one row a device,
+        from a program whose scans carry the lane-bin lists. The device
+        decides, so call this where :meth:`count_dist_steps` is called."""
+        self._count_columns(
+            SELECT_TILES, SELECT_PATHS, tiles,
+            "query-tile merges whose scan over the corpus tiles carried "
+            "the lane-bin lists, by what became of the selection: the "
+            "carried answer kept, or rows that failed the certificate "
+            "answered again by a re-scan",
+        )
+
+    def _count_columns(self, name, paths, counts, help) -> None:
         import numpy as np
 
-        steps = np.asarray(steps)
-        by_path = steps.reshape(-1, steps.shape[-1]).sum(axis=0)
-        for path, n in zip(DIST_PATHS, by_path):
-            self.counter(
-                DIST_STEPS,
-                help="tile steps dispatched, by the path of the distance "
-                "dot: one bf16 pass (both operands bf16 numbers), the "
-                "configured multi-pass dot, or the cosine dot (a scaled "
-                "dot at the configured precision over prepared operands)",
-                labels={"path": path},
-            ).inc(int(n))
+        counts = np.asarray(counts)
+        by_path = counts.reshape(-1, counts.shape[-1]).sum(axis=0)
+        for path, n in zip(paths, by_path):
+            self.counter(name, help=help, labels={"path": path}).inc(int(n))
 
 
 _default_registry = MetricsRegistry()

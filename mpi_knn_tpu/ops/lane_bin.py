@@ -72,13 +72,16 @@ def _plain(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x, x.dtype)
 
 
-def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
+def _lane_bin_kernel(ids_ref, d_ref, *lists, depth: int):
     """One (rows, cols) block of the tile: insert its cols/128 column groups
     into the per-(row, lane) sorted lists of ``depth`` that the two output
     blocks hold across the column axis of the grid. A strip of rows keeps
     its 2·depth list entries in registers while the groups stream through
     ``depth`` compare-exchange stages; the candidate's id rides the same
-    selects."""
+    selects. ``lists`` is the two output blocks, after the two blocks of the
+    incoming lists where the call carries them (aliased to the outputs):
+    the row block's first column step then starts from those."""
+    kd_ref, ki_ref = lists[-2:]
     rows, cols = d_ref.shape
     groups = cols // _LANES
     unroll = next(u for u in (4, 2, 1) if groups % u == 0)
@@ -89,8 +92,12 @@ def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
 
     @pl.when(lax.eq(first, first.dtype.type(0)))
     def _():
-        kd_ref[...] = lax.full(kd_ref.shape, _INF, kd_ref.dtype)
-        ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, ki_ref.dtype)
+        if len(lists) == 4:
+            kd_ref[...] = lists[0][...]
+            ki_ref[...] = lists[1][...]
+        else:
+            kd_ref[...] = lax.full(kd_ref.shape, _INF, kd_ref.dtype)
+            ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, ki_ref.dtype)
 
     def strip(s, carry):
         r = pl.ds(pl.multiple_of(lax.mul(s, s.dtype.type(_STRIP)), _STRIP),
@@ -108,8 +115,9 @@ def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
                 vi = lax.broadcast_in_dim(
                     ids_ref[:, lanes], v.shape, (0, 1))
                 for j in range(depth):
-                    # strict <: among equal values the earlier group stays
-                    # ahead; NaN compares false and is never kept
+                    # strict <: among equal values the earlier group (and
+                    # the earlier tile's, in carried lists) stays ahead;
+                    # NaN compares false and is never kept
                     lt = lax.lt(v, kept_d[j])
                     kept_d[j], v = (lax.select(lt, v, kept_d[j]),
                                     lax.select(lt, kept_d[j], v))
@@ -131,7 +139,8 @@ def _lane_bin_kernel(ids_ref, d_ref, kd_ref, ki_ref, *, depth: int):
 
 
 @jax.named_scope("bins")
-def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int):
+def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int,
+                        lists=None):
     """Reduce a (q, c) tile to (q, depth·128) candidates with no sort, no
     gather and no (q, c) id plane: view the columns as c/128 groups of 128
     lanes and, for every (row, lane), keep the ``depth`` smallest of its
@@ -143,6 +152,14 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int):
     hundred fusions (PERF.md §6, PR 27). ``q`` a multiple of 16, ``c`` of 128,
     ``depth`` below c/128.
 
+    ``lists`` — ((q, depth·128) distances, (q, depth·128) int32 ids), what
+    an earlier call returned — is what the lists start from in place of
+    (+inf, ``INVALID_ID``): the tile is inserted into them, so a scan that
+    carries them over the tiles of a stack ends with every (row, lane)'s
+    ``depth`` smallest of the WHOLE stack (``backends/serial.py
+    merge_tiles_into_carry``). They are aliased to the outputs: a caller
+    that lets them die with the call has them updated in place.
+
     Returns ((q, depth·128) distances, (q, depth·128) ids); columns
     [j·128, (j+1)·128) hold every lane's (j+1)-th smallest, so the last 128
     are what the certificate reads."""
@@ -153,23 +170,26 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int):
     )
     out_block = pl.BlockSpec((rows, depth * _LANES), lambda i, j: (i, 0))
     ids = ids.astype(jnp.int32)[None, :]
+    lists = tuple(lists or ())
     return pl.pallas_call(
         functools.partial(_lane_bin_kernel, depth=depth),
         grid=(q // rows, c // cols),
         in_specs=[
             pl.BlockSpec((1, cols), lambda i, j: (0, j)),
             pl.BlockSpec((rows, cols), lambda i, j: (i, j)),
+            *(out_block for _ in lists),
         ],
         out_specs=[out_block, out_block],
         out_shape=[
-            _out((q, depth * _LANES), dists.dtype, ids, dists),
-            _out((q, depth * _LANES), jnp.int32, ids, dists),
+            _out((q, depth * _LANES), dists.dtype, ids, dists, *lists),
+            _out((q, depth * _LANES), jnp.int32, ids, dists, *lists),
         ],
+        input_output_aliases={2: 0, 3: 1} if lists else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(),
-    )(ids, dists)
+    )(ids, dists, *lists)
 
 
 def _lane_bin_finish_kernel(cd_ref, ci_ref, od_ref, oi_ref, flag_ref,
@@ -265,13 +285,36 @@ def lane_bin_finish(cand_d: jax.Array, cand_i: jax.Array, k: int):
     return vals, out_ids, flagged[:, 0] != 0
 
 
+def lane_bin_lists(q: int, depth: int, dtype=jnp.float32):
+    """Empty lists for ``q`` rows at depth ``depth``: ((q', depth·128)
+    +inf, (q', depth·128) ``INVALID_ID``), q' = q up to whole strips. What
+    a scan that carries the lists starts from."""
+    shape = (q + -q % _STRIP, depth * _LANES)
+    return (jnp.full(shape, _INF, dtype),
+            jnp.full(shape, INVALID_ID, jnp.int32))
+
+
+def lane_bin_insert(lists, dists: jax.Array, ids: jax.Array, depth: int):
+    """``lists`` (:func:`lane_bin_lists`, or what an earlier call returned)
+    with the (q, c) tile ``dists`` (ids (c,)) inserted, updated in place
+    where they die with the call: the one thing a tile step of a carried
+    scan selects. None: a tile's own lists, from empty."""
+    pad = -dists.shape[0] % _STRIP  # the kernels walk whole strips
+    if pad:  # zero rows flag nothing
+        dists = jnp.pad(dists, ((0, pad), (0, 0)))
+    return tuple(lane_bin_candidates(dists, ids, depth, lists))
+
+
+def lane_bin_result(lists, q: int, k: int):
+    """What the lists hold for their first ``q`` rows: ((q, k) vals
+    ascending, (q, k) ids, (q,) flagged) — exact for every row that is not
+    flagged, over everything that was inserted."""
+    vals, out_ids, flagged = lane_bin_finish(*lists, k)
+    return vals[:q], out_ids[:q], flagged[:q]
+
+
 def lane_bin_select(dists: jax.Array, ids: jax.Array, k: int, depth: int):
     """Bins, finish and certificate of a (q, c) tile: ((q, k) vals ascending,
     (q, k) ids, (q,) flagged); exact for every row that is not flagged."""
-    q = dists.shape[0]
-    pad = -q % _STRIP  # the kernels walk whole strips; zero rows flag nothing
-    if pad:
-        dists = jnp.pad(dists, ((0, pad), (0, 0)))
-    vals, out_ids, flagged = lane_bin_finish(
-        *lane_bin_candidates(dists, ids, depth), k)
-    return vals[:q], out_ids[:q], flagged[:q]
+    return lane_bin_result(
+        lane_bin_insert(None, dists, ids, depth), dists.shape[0], k)

@@ -30,6 +30,15 @@ data; only the speed depends on how neighbours fall into lanes. The scopes
 ``bins``, ``finish`` and ``fallback`` sit under the caller's ``knn.select``
 in a trace. Merges, the cascade and IVF (2-D ids) keep ``lax.top_k``.
 
+Nothing in the mechanism is per tile. A scan over the tiles of a stack can
+carry the lists (``ops/lane_bin.py lane_bin_insert``): they are then every
+(row, lane)'s R smallest of the WHOLE stack, ONE finish (``lane_bin_result``)
+gives the
+stack's k smallest, and the same certificate says whether anything dropped
+anywhere could have belonged — with the same chance a row, whatever the
+stack's width. That is what ``backends/serial.py merge_tiles_into_carry``
+runs where this rule engages for its tiles: a tile step is *bins* only.
+
 All distances flow in "smaller is better" space; +inf marks invalid slots and
 ``INVALID_ID`` (−1) marks their ids.
 """
@@ -122,8 +131,9 @@ _LANES = 128  # the bins' column groups are a vreg's 128 lanes wide
 _MIN_BIN_WIDTH = 1024  # narrower tiles: one lax.top_k is already cheap
 _MAX_BIN_DEPTH = 8
 _MAX_BIN_K = _LANES  # the finish kernel answers in 128-lane accumulators
-# the rule keeps the EXPECTED share of tile steps that take the fallback
-# (neighbours falling into lanes at random) under this
+# the rule keeps the EXPECTED share of selections that take the fallback
+# (neighbours falling into lanes at random) under this: of query tiles
+# where a scan carries the lists, of tile steps in a per-tile call
 _MAX_FALLBACK_SHARE = 0.01
 
 
@@ -137,11 +147,13 @@ def lane_bin_depth(q: int, c: int, k: int, ids_ndim: int = 1) -> int | None:
     and at least 1024, k is at most 128 (the finish kernel's accumulators
     are one vreg wide; only tiles of a few rows get that far under the
     next condition), and some R <= 8 (and below the group count) keeps the
-    expected flagged share of tile steps under 1 %: a row is flagged when R
+    expected flagged share of query tiles under 1 %: a row is flagged when R
     of its k-1 smallest share a lane, which for neighbours placed at random
-    has probability about C(k-1, R) / 128^(R-1); a tile step falls back when
-    any of its q rows is flagged. k = 10 gives R = 5 at 1024 and 4096 rows
-    and R = 4 at 64; k in the hundreds bypasses."""
+    has probability about C(k-1, R) / 128^(R-1), whether the k-1 are a
+    tile's or a whole stack's; a selection falls back when any of its q rows
+    is flagged — once a query tile where a scan carries the lists over the
+    stack, once a tile step in a per-tile call. k = 10 gives R = 5 at 1024
+    and 4096 rows and R = 4 at 64; k in the hundreds bypasses."""
     if (ids_ndim != 1 or c % _LANES or c < _MIN_BIN_WIDTH
             or k > _MAX_BIN_K):
         return None
@@ -156,8 +168,10 @@ def lane_bin_flagged_share(dists, k: int) -> tuple[float, float] | None:
     """Host-side counter of the lane-bin selection on one real (q, c) tile:
     (share of rows flagged, 1.0 if the tile step would take the fallback
     else 0.0), or None where the rule bypasses the tile. What the tests and
-    chip checks call; the chunk programs themselves report nothing (their
-    fallback shows in a trace under ``knn.select/fallback``)."""
+    chip checks call for ONE tile; a chunk program that carries the lists
+    over its stack counts its own query tiles (``backends/serial.py
+    select_tiles``), and its re-scans show in a trace under
+    ``knn.select/fallback``."""
     dists = jnp.asarray(dists)
     q, c = dists.shape
     depth = lane_bin_depth(q, c, k)

@@ -436,10 +436,10 @@ def _prep_queries(index, cfg: KNNConfig, exec_: _BucketExec, q):
 
 def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
     """Issue one prepared batch on the compiled executable; returns padded
-    ((q_pad, k) dists, ids, exchange_stats-or-None, dist_steps-or-None)
+    ((q_pad, k) dists, ids, exchange_stats-or-None, TileCounts)
     device results (async — not synchronized here). The stats slot is the
     per-shard (N_STATS·S,) vector of a layout with ``exchange_stats``;
-    ``dist_steps`` is the batch's ``backends.serial.dist_steps``, for the
+    the last is the batch's ``backends.serial.TileCounts``, filled for the
     layouts whose batches run ``masked_dist_tile``.
     Dispatch serializes with live mutation on the per-index mutation
     lock — the resident args are read and the batch enqueued as one
@@ -459,7 +459,7 @@ def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
         return (
             d, i,
             rest[0] if lay.exchange_stats else None,
-            lay.batch_dist_steps(index, exec_.q_pad, exec_.q_tile, rest),
+            lay.batch_counts(index, exec_.q_pad, exec_.q_tile, rest),
         )
 
 
@@ -504,8 +504,11 @@ class BatchResult:
     # nothing records): the phases of the batch name it as their parent
     span: object = None
     # serial / ring batches: the tile steps by the path of their distance
-    # dot (backends.serial.dist_steps), counted at retire
+    # dot (backends.serial.dist_steps) and, from a program whose scans
+    # carry the lane-bin lists, the query-tile merges by what became of
+    # the carried selection (backends.serial.select_tiles), counted at retire
     dist_steps: object = None
+    select_tiles: object = None
 
     @functools.cached_property
     def dists(self) -> np.ndarray:
@@ -555,17 +558,25 @@ def query_knn(
     bucket = bucket_rows(nq, cfg.query_bucket)
     exec_ = get_executable(index, cfg, bucket)
     q2d, qids, rows = _prep_queries(index, cfg, exec_, queries)
-    d, i, stats, steps = _run(index, cfg, exec_, q2d, qids)
+    d, i, stats, counts = _run(index, cfg, exec_, q2d, qids)
     if stats is not None:
         _count_exchange(stats, exec_.exchange_bytes)
-    d, i, steps = jax.device_get((d, i, steps))
-    if steps is not None:
-        obs_metrics.get_registry().count_dist_steps(steps)
+    d, i, counts = jax.device_get((d, i, counts))
+    _count_tiles(obs_metrics.get_registry(), counts)
     return KNNResult(
         dists=np.asarray(d)[:rows],
         ids=np.asarray(i)[:rows],
-        dist_steps=steps,
+        **counts._asdict(),
     )
+
+
+def _count_tiles(registry, counts) -> None:
+    """Add a fetched batch's ``backends.serial.TileCounts`` (or a
+    ``BatchResult``'s two fields of the same names) to ``registry``."""
+    if counts.dist_steps is not None:
+        registry.count_dist_steps(counts.dist_steps)
+    if counts.select_tiles is not None:
+        registry.count_select_tiles(counts.select_tiles)
 
 
 def _count_exchange(stats, exchange_bytes: int | None,
@@ -1289,10 +1300,9 @@ class ServeSession:
             deadline_breached=res.deadline_breached, **extra,
         )
         maybe_beat(f"serve-batch-{res.seq}")
-        if res.dist_steps is not None:
-            # the batch is synchronized: its count is on hand, eight bytes
-            # after the answers' own D2H, never a wait of its own
-            self._metrics.count_dist_steps(res.dist_steps)
+        # the batch is synchronized: its counts are on hand, a few bytes
+        # after the answers' own D2H, never a wait of their own
+        _count_tiles(self._metrics, res)
         self._metrics.counter(
             "serve_batches_total", help="batches retired"
         ).inc()
@@ -1321,9 +1331,9 @@ class ServeSession:
         with self.phase("prep", seq=self._seq, parent=span):
             q2d, qids, rows = _prep_queries(self.index, cfg, exec_, queries)
         with self.phase("enqueue", seq=self._seq, parent=span):
-            d, i, stats, steps = _run(self.index, cfg, exec_, q2d, qids)
+            d, i, stats, counts = _run(self.index, cfg, exec_, q2d, qids)
         return (bucket, rows, poison_topk(d), i, stats,
-                exec_.exchange_bytes, steps)
+                exec_.exchange_bytes, counts)
 
     def submit(self, queries, tenants=None) -> list[BatchResult]:
         """Dispatch one batch; ``tenants`` is an optional
@@ -1384,7 +1394,7 @@ class ServeSession:
                     max_s=pol.backoff_max_s,
                     retryable=pol.retryable,
                 )
-                bucket, rows, d, i, stats, xbytes, steps = out.value
+                bucket, rows, d, i, stats, xbytes, counts = out.value
                 retries, backoffs = out.attempts - 1, out.backoffs
                 with self._stats_lock:
                     self.retries_total += retries
@@ -1398,7 +1408,7 @@ class ServeSession:
                         help="transient dispatch failures retried",
                     ).inc(retries)
             else:
-                bucket, rows, d, i, stats, xbytes, steps = (
+                bucket, rows, d, i, stats, xbytes, counts = (
                     self._dispatch(queries, cfg, sid))
                 retries, backoffs = 0, ()
         except Exception as e:
@@ -1417,7 +1427,7 @@ class ServeSession:
             stats_padded=stats,
             exchange_bytes=xbytes,
             span=sid,
-            dist_steps=steps,
+            **counts._asdict(),
         )
         self._seq += 1
         self._inflight.append((res, t0))

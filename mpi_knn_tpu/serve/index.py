@@ -37,10 +37,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.backends.serial import (
+    TileCounts,
     _stack_norms,
     cap_corpus_tile,
-    dist_steps,
     serve_chunk,
+    tile_counts,
 )
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
@@ -158,11 +159,11 @@ class BatchLayout:
     def stamp_gauges(self, index, cfg: KNNConfig, registry) -> None:
         """The kind's compression gauges, stamped at build (shape math)."""
 
-    def batch_dist_steps(self, index, q_pad: int, q_tile: int, rest: tuple):
-        """The batch's ``backends.serial.dist_steps`` for the kinds whose
+    def batch_counts(self, index, q_pad: int, q_tile: int, rest: tuple):
+        """The batch's ``backends.serial.TileCounts`` for the kinds whose
         batches run ``masked_dist_tile``; ``rest`` is what the program
         returned beyond (dists, ids)."""
-        return None
+        return TileCounts()
 
 
 class SerialLayout(BatchLayout):
@@ -180,12 +181,11 @@ class SerialLayout(BatchLayout):
     def resident(self, index):
         return (index.tiles, index.tile_ids, index.tile_sqs, index.onepass)
 
-    def batch_dist_steps(self, index, q_pad, q_tile, rest):
-        # an index that holds the one-pass fact has a third output: the
-        # batch's tile steps by the branch they took
-        return rest[0] if rest else dist_steps(
-            q_pad // q_tile, index.tiles.shape[0], index.cfg.metric
-        )
+    def batch_counts(self, index, q_pad, q_tile, rest):
+        # a program that counts on the device (the one-pass branch taken,
+        # the carried selections re-scanned) has a third output
+        return tile_counts(
+            rest, q_pad // q_tile, index.tiles.shape[0], index.cfg.metric)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,11 +245,10 @@ class RingLayout(BatchLayout):
             index.ring_meta[3],
         ))
 
-    def batch_dist_steps(self, index, q_pad, q_tile, rest):
-        return dist_steps(
-            q_pad // q_tile, index.corpus_sharded.shape[0] // index.c_tile,
-            index.cfg.metric,
-        )
+    def batch_counts(self, index, q_pad, q_tile, rest):
+        return tile_counts(
+            (), q_pad // q_tile,
+            index.corpus_sharded.shape[0] // index.c_tile, index.cfg.metric)
 
 
 def _pallas_serve_fn(

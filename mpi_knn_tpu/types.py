@@ -39,11 +39,21 @@ class KNNResult:
         own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
         ``knn_dist_tile_steps_total``. None from the paths that run no such
         tile step (the Pallas backends).
+      select_tiles: int32 (..., 2), the call's query-tile merges by what
+        became of the selection their scans carried, ``[carried,
+        rescanned]`` (``backends/serial.py merge_tiles_into_carry``:
+        rescanned, some row failed the lane-bin certificate and the flagged
+        rows were answered again), one row a ring device; comes with the
+        answer as ``dist_steps`` does, and
+        ``MetricsRegistry.count_select_tiles`` adds it to
+        ``knn_select_query_tiles_total``. None from a program whose scans
+        carry no lists.
     """
 
     dists: jax.Array
     ids: jax.Array
     dist_steps: jax.Array | None = None
+    select_tiles: jax.Array | None = None
 
     @property
     def k(self) -> int:

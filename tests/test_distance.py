@@ -3,6 +3,7 @@ import numpy as np
 import pytest
 
 from mpi_knn_tpu.ops.distance import pairwise_cosine, pairwise_dist, pairwise_sq_l2
+from tests.oracle import int_sq_l2
 
 
 def _np_sq_l2(x, y):
@@ -85,8 +86,7 @@ def test_metric_dispatch(rng):
 
 
 def _int_sq_l2_topk(q, c, k):
-    d = ((q[:, None, :].astype(np.int64) - c[None].astype(np.int64)) ** 2).sum(-1)
-    return np.sort(d, axis=1)[:, :k]
+    return np.sort(int_sq_l2(q, c), axis=1)[:, :k]
 
 
 # (query rows, tile rows) a one-pass program needs: ONEPASS_MIN_ROWS
@@ -174,8 +174,7 @@ def test_all_knn_under_an_outer_jit_decides_inside_the_program(rng, backend):
         np.asarray(traced.dists)[:, 0],
         np.sort(np.where(
             np.eye(2048, dtype=bool), np.iinfo(np.int64).max,
-            ((np.asarray(x)[:, None].astype(np.int64)
-              - np.asarray(x)[None].astype(np.int64)) ** 2).sum(-1),
+            int_sq_l2(x, x),
         ), axis=1)[:, 0].astype(np.float32))
 
 
@@ -271,13 +270,14 @@ def test_one_fractional_query_row_takes_the_old_path_for_its_tile(rng):
     )
     carry = lambda: init_topk_tiles(2, 1024, 10, dtype=jnp.float32)  # noqa: E731
     d0, i0 = knn_chunk_update(*args, *carry(), cfg)
-    d1, i1, steps = knn_chunk_update(*args, *carry(), cfg, jnp.asarray(True))
-    assert steps.tolist() == [4, 4]
+    d1, i1, counts = knn_chunk_update(*args, *carry(), cfg, jnp.asarray(True))
+    # 256-column tiles are under the carried selection's rule: no such count
+    assert counts.dist_steps.tolist() == [4, 4] and counts.select_tiles is None
     np.testing.assert_array_equal(np.asarray(d1), np.asarray(d0))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
     # and a corpus that does not qualify sends every tile down the old path
-    d2, i2, steps = knn_chunk_update(*args, *carry(), cfg, jnp.asarray(False))
-    assert steps.tolist() == [0, 8]
+    d2, i2, counts = knn_chunk_update(*args, *carry(), cfg, jnp.asarray(False))
+    assert counts.dist_steps.tolist() == [0, 8]
     np.testing.assert_array_equal(np.asarray(d2), np.asarray(d0))
 
 

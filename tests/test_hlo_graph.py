@@ -6,6 +6,8 @@ syntaxes (``%``-prefixed dump format vs the bare-name
 ``compiler_ir("hlo")`` format), ``} // name`` computation closers, and the
 result-type capture the memory rule depends on."""
 
+import pytest
+
 from mpi_knn_tpu.utils.hlo_graph import backward_slice, parse_hlo
 
 _BRANCHY = """\
@@ -198,7 +200,7 @@ ENTRY %main.1 (a.1: f32[4], b.1: f32[4]) -> f32[4] {
 # --- the serial chunk program's per-tile selection, as XLA compiles it -----
 
 
-def _chunk_program(k, q=64, c=8192, dim=16, tiles=2):
+def _chunk_program(k, q=64, c=8192, dim=16, tiles=3, **knn):
     """Optimized HLO of ``knn_chunk_update`` at one (q, c) tile shape."""
     import jax
     import jax.numpy as jnp
@@ -207,7 +209,7 @@ def _chunk_program(k, q=64, c=8192, dim=16, tiles=2):
     from mpi_knn_tpu.config import KNNConfig
 
     s = jax.ShapeDtypeStruct
-    cfg = KNNConfig(k=k, query_tile=q, corpus_tile=c)
+    cfg = KNNConfig(k=k, query_tile=q, corpus_tile=c, **knn)
     return knn_chunk_update.lower(
         s((1, q, dim), jnp.float32), s((1, q), jnp.int32),
         s((tiles, c, dim), jnp.float32), s((tiles, c), jnp.int32),
@@ -215,16 +217,16 @@ def _chunk_program(k, q=64, c=8192, dim=16, tiles=2):
     ).compile().as_text()
 
 
-def _selection_ops(text):
+def _scoped_ops(text, scope=""):
     """(scope path, opcode, result type, operand types) of every instruction
-    under the ``knn.select`` scope."""
+    whose op name holds ``scope``."""
     import re
 
     out = []
     for comp in parse_hlo(text).computations.values():
         for ins in comp.instructions.values():
             m = re.search(r'op_name="([^"]*)"', ins.attrs)
-            if m and "knn.select" in m.group(1):
+            if m and scope in m.group(1):
                 operands = " ".join(
                     comp.instructions[o].type_str
                     for o in ins.operands if o in comp.instructions
@@ -233,29 +235,66 @@ def _selection_ops(text):
     return out
 
 
-def test_chunk_program_selects_without_a_tile_wide_sort():
-    """k = 10 over 8192 columns: the lane-bin selection is engaged. Outside
-    the fallback branch the selection holds no sort / top-k over a c-wide
-    operand and no s32[q, c] id plane, and the three scopes a trace reads
-    are in the op names. k = 256 bypasses: the full-width top-k as ever."""
-    wide = "[64,8192]"
-    ops = _selection_ops(_chunk_program(k=10))
-    scopes = {s for s, *_ in ops}
+def _wide_sorts(ops, c=8192):
+    """The scopes of the sorts / top-ks over rows at least ``c`` wide."""
+    import re
+
+    return [
+        s for s, op, _, operands in ops
+        if (op == "sort" or "top_k" in s) and any(
+            int(w) >= c for w in re.findall(r"\[\d+,(\d+)\]", operands))
+    ]
+
+
+def test_engaged_chunk_program_finishes_once_a_query_tile():
+    """k = 10 over 8192 columns: the lists ride the scan (ISSUE 33). The
+    scan body holds *bins* and no *finish*; the program holds no (T, q, k)
+    survivor stack and no s32[q, c] id plane; the one sort over a c-wide
+    row is the re-scan's, over a handful of rows under
+    ``knn.select/fallback``; and the three scopes a trace reads are in the
+    op names."""
+    text = _chunk_program(k=10)
+    ops = _scoped_ops(text)
     for want in ("knn.select/bins", "knn.select/finish", "knn.select/fallback"):
-        assert any(want in s for s in scopes), want
-    sorts = [
-        (s, op) for s, op, _, operands in ops
-        if (op == "sort" or "top_k" in s) and wide in operands
-    ]
-    assert sorts and all("knn.select/fallback" in s for s, _ in sorts), sorts
-    planes = [
-        (s, op) for s, op, ty, _ in ops
-        if "s32" + wide in ty and "knn.select/fallback" not in s
-    ]
+        assert any(want in s for s, *_ in ops), want
+
+    def loops_around(scope):
+        return {s[:s.index(scope)].count("while/body")
+                for s, *_ in ops if scope in s}
+
+    # finish sits outside the loop that holds bins: after the scan
+    assert max(loops_around("knn.select/finish")) < min(
+        loops_around("knn.select/bins"))
+    assert "[3,64,10]" not in text  # the survivors of three tiles
+    sorts = _wide_sorts(ops)
+    assert sorts and all("knn.select/fallback" in s for s in sorts), sorts
+    assert all("[64," not in operands for s, _, _, operands in ops
+               if "knn.select/fallback" in s and "top_k" in s)
+    planes = [(s, op) for s, op, ty, _ in ops
+              if "s32[64,8192]" in ty and "knn.select" in s
+              and "knn.select/fallback" not in s]
     assert not planes, planes
 
-    ops = _selection_ops(_chunk_program(k=256))
+
+@pytest.mark.parametrize("knn", [
+    dict(k=256), dict(k=10, precision_policy="mixed"),
+    dict(k=10, merge_schedule="stream"), dict(k=10, topk_method="block"),
+], ids=lambda knn: ",".join(f"{a}={b}" for a, b in knn.items()))
+def test_programs_the_rule_does_not_engage_keep_the_per_tile_form(knn):
+    """k beyond the rule, ``mixed``, ``stream`` and another method: no
+    lists, no finish, no re-scan — the selection each always ran (their
+    lowered programs are the parent's byte for byte:
+    ``scripts/lowered_hashes.py``, CHANGES.md PR 33)."""
+    from mpi_knn_tpu.backends.serial import carried_depth
+    from mpi_knn_tpu.config import KNNConfig
+
+    assert carried_depth(KNNConfig(**knn), 64, 8192) is None
+    text = _chunk_program(**knn)
+    ops = _scoped_ops(text)
     assert not any(
         w in s for s, *_ in ops for w in ("/bins", "/finish", "/fallback"))
-    assert any(
-        "knn.select/top_k" in s and wide in operands for s, _, _, operands in ops)
+    if knn.get("merge_schedule") != "stream":
+        assert f"[3,64,{knn['k']}]" in text  # twolevel's survivor stack
+    if knn["k"] == 256:
+        assert any("knn.select/top_k" in s and "[64,8192]" in operands
+                   for s, _, _, operands in ops)

@@ -9,6 +9,7 @@ import pytest
 
 from mpi_knn_tpu import all_knn
 from mpi_knn_tpu.parallel.mesh import make_ring_mesh
+from tests.oracle import int_sq_l2
 
 
 def _data(rng, m=96, d=12):
@@ -269,7 +270,7 @@ def test_ring_takes_one_pass_on_whole_number_rows_and_agrees_with_serial(
         np.testing.assert_array_equal(
             np.asarray(ring.dists), np.asarray(serial.dists))
     # (whole-number distances tie, and ids may differ among equal ones)
-    d = ((X[:, None].astype(np.int64) - X[None].astype(np.int64)) ** 2).sum(-1)
+    d = int_sq_l2(X, X)
     np.fill_diagonal(d, np.iinfo(np.int64).max)
     d[d == 0] = np.iinfo(np.int64).max  # duplicates are excluded by value
     np.testing.assert_array_equal(

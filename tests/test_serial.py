@@ -1,5 +1,9 @@
 """Serial backend parity vs the reference-semantics oracle (SURVEY.md §4)."""
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -222,3 +226,148 @@ def test_classifier_label_validation(rng):
     clf = KNNClassifier(k=3, num_classes=2)
     with pytest.raises(ValueError):
         clf.fit(X, y)  # labels reach 2 >= num_classes
+
+
+# --- the carried selection: finish once a query tile (ISSUE 33) -------------
+# One small shape for every case (32 rows, 4 tiles of 1024 columns, k = 10):
+# the interpreted kernels compile once, for the carried and the per-tile form.
+
+_CQ, _CT, _CC, _CD, _CK = 32, 4, 1024, 16, 10
+
+
+def _carried_cfg(**kw):
+    return KNNConfig(k=_CK, backend="serial", query_tile=_CQ,
+                     corpus_tile=_CC, center=False, **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_program(per_tile: bool):
+    """``serve_chunk`` over the small shape under a jit of its own: the
+    engaged (carried) program, or — the rule held off — the per-tile
+    program that every engaged call ran before."""
+    from mpi_knn_tpu.backends import serial
+
+    def run(*args):
+        with pytest.MonkeyPatch.context() as mp:
+            if per_tile:
+                mp.setattr(serial, "carried_depth", lambda *a, **k: None)
+            return serial.serve_chunk(*args, cfg=_carried_cfg())
+
+    return jax.jit(run)
+
+
+def _carried_case(name):
+    """(queries, corpus rows, ids, incoming carry) of one case; whole-number
+    rows, so every distance is exact and equal values mean equal bits."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    q = rng.integers(-20, 20, (_CQ, _CD)).astype(np.float32)
+    c = rng.integers(-20, 20, (_CT * _CC, _CD)).astype(np.float32)
+    ids = np.arange(_CT * _CC, dtype=np.int32)
+    carry_d = np.full((_CQ, _CK), np.inf, np.float32)
+    carry_i = np.full((_CQ, _CK), -1, np.int32)
+    if name == "incoming-carry":
+        # a resumable chunk's, a ring round's: some slots beat the stack
+        carry_d[::2, :4] = np.arange(1, 5, dtype=np.float32)
+        carry_i[::2, :4] = 900_000 + np.arange(4)
+    elif name == "padded-ids":
+        ids[-300:] = -1
+    elif name == "nan-and-short-rows":
+        q[3] = np.nan
+        ids[7:] = -1  # seven candidates in all: every row is short of k
+    elif name == "cross-tile-collision":
+        # R + 2 of row 5's nearest in ONE lane, one or two a tile: no
+        # tile's own certificate sees more than two of them
+        for j in range(6):
+            at = (j % _CT) * _CC + 37 + 128 * (j // _CT)
+            c[at] = q[5]
+            c[at, j] += j + 1.0
+    return q, c, ids, carry_d, carry_i
+
+
+@pytest.mark.parametrize("name,rescanned", [
+    ("plain", 0), ("incoming-carry", 0), ("padded-ids", 0),
+    ("nan-and-short-rows", 1), ("cross-tile-collision", 1),
+])
+def test_carried_selection_equals_per_tile_and_full_width(name, rescanned):
+    """The engaged ``twolevel`` merge — lists carried through the scan, one
+    finish, the certificate once a query tile, flagged rows re-scanned —
+    against the per-tile program and against ``lax.top_k`` over the whole
+    stack's distances: values equal; ids equal wherever a row's distances
+    are distinct. The counter reads which query tiles were re-scanned."""
+    from mpi_knn_tpu.backends import serial
+
+    q, c, ids, carry_d, carry_i = _carried_case(name)
+    assert serial.carried_depth(_carried_cfg(), _CQ, _CC) == 4
+    args = (jnp.asarray(q)[None], jnp.full((1, _CQ), -1, jnp.int32),
+            jnp.asarray(carry_d)[None], jnp.asarray(carry_i)[None],
+            jnp.asarray(c.reshape(_CT, _CC, _CD)),
+            jnp.asarray(ids.reshape(_CT, _CC)),
+            jnp.asarray((c * c).sum(1).reshape(_CT, _CC)))
+    got_d, got_i, counts = _chunk_program(False)(*args)
+    old_d, old_i = _chunk_program(True)(*args)
+    assert counts.dist_steps is None
+    assert np.asarray(counts.select_tiles).tolist() == [1 - rescanned,
+                                                        rescanned]
+    got_d, got_i = np.asarray(got_d)[0], np.asarray(got_i)[0]
+    np.testing.assert_array_equal(got_d, np.asarray(old_d)[0])
+    # the full-width answer over (incoming carry ‖ every tile)
+    d = ((q[:, None, :] - c[None]) ** 2).sum(-1)
+    d[:, ids < 0] = np.inf
+    d[d <= 0] = np.inf  # query mode drops zero distances
+    all_d = np.concatenate([carry_d, d], axis=1)
+    all_i = np.concatenate([carry_i, np.broadcast_to(ids, d.shape)], axis=1)
+    order = np.argsort(all_d, axis=1, kind="stable")[:, :_CK]
+    want_d = np.take_along_axis(all_d, order, 1)
+    finite = ~np.isnan(q).any(1)
+    np.testing.assert_array_equal(got_d[finite], want_d[finite])
+    assert np.isnan(got_d[~finite]).all() or np.isinf(got_d[~finite]).all()
+    want_i = np.where(np.isinf(want_d), -1, np.take_along_axis(all_i, order, 1))
+    for r in np.flatnonzero(finite):
+        # slots whose distance is the row's alone name one candidate
+        vals, n = np.unique(all_d[r][np.isfinite(all_d[r])],
+                            return_counts=True)
+        alone = np.isin(want_d[r], vals[n == 1]) | np.isinf(want_d[r])
+        np.testing.assert_array_equal(got_i[r][alone], want_i[r][alone])
+    if name == "cross-tile-collision":
+        assert got_d[5, :6].tolist() == [1.0, 4.0, 9.0, 16.0, 25.0, 36.0]
+
+
+def test_the_counter_of_carried_selections_comes_with_the_answer():
+    """``knn_select_query_tiles_total{path="carried"|"rescanned"}``: a
+    one-shot call carries its count on ``KNNResult.select_tiles``, a served
+    batch's is added at retire, after the batch's own sync; a program whose
+    scans carry no lists counts nothing. Read on the planted collision: one
+    query tile, re-scanned."""
+    from mpi_knn_tpu import build_index, query_knn
+    from mpi_knn_tpu.obs import metrics as obs_metrics
+    from mpi_knn_tpu.serve import ServeSession
+
+    reg = obs_metrics.MetricsRegistry()
+    reg.count_select_tiles(np.array([[3, 1], [2, 0]]))  # one row a device
+    assert [reg.counter(obs_metrics.SELECT_TILES, labels={"path": p}).value
+            for p in obs_metrics.SELECT_PATHS] == [5, 1]
+
+    def counted():
+        reg = obs_metrics.get_registry()
+        return [reg.counter(obs_metrics.SELECT_TILES, labels={"path": p}).value
+                for p in obs_metrics.SELECT_PATHS]
+
+    q, c, *_ = _carried_case("cross-tile-collision")
+    cfg = _carried_cfg(query_bucket=_CQ)
+    one_shot = all_knn(c, queries=q, config=cfg)
+    assert np.asarray(one_shot.select_tiles).tolist() == [0, 1]
+    narrow = all_knn(c, queries=q, config=cfg.replace(corpus_tile=512))
+    assert narrow.select_tiles is None
+    index = build_index(c, cfg)
+    before = counted()
+    served = query_knn(q, index)
+    assert np.asarray(served.select_tiles).tolist() == [0, 1]
+    session = ServeSession(index)
+    batch, = session.submit(q) + session.drain()
+    assert np.asarray(batch.select_tiles).tolist() == [0, 1]
+    assert [b - a for a, b in zip(before, counted())] == [0, 2]
+    for got in (served, batch):
+        np.testing.assert_array_equal(
+            np.asarray(got.dists), np.asarray(one_shot.dists))
+        np.testing.assert_array_equal(
+            np.asarray(got.ids), np.asarray(one_shot.ids))

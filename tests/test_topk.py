@@ -1,3 +1,5 @@
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -328,6 +330,84 @@ def test_lane_bin_planted_collision_is_flagged_and_exact(rng, extra):
     want_d, want_i = _full_width(d, ids, k)
     np.testing.assert_array_equal(np.asarray(got_d), want_d)
     np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "depth"))
+def _carried(stack_d, stack_ids, k, depth):
+    """The lists carried over a (T, q, c) stack of tiles, then one finish:
+    what an engaged ``merge_tiles_into_carry`` selects with."""
+    from mpi_knn_tpu.ops.lane_bin import (
+        lane_bin_insert,
+        lane_bin_lists,
+        lane_bin_result,
+    )
+
+    q = stack_d.shape[1]
+    lists, _ = jax.lax.scan(
+        lambda lists, tile: (lane_bin_insert(lists, *tile, depth), None),
+        lane_bin_lists(q, depth, stack_d.dtype), (stack_d, stack_ids))
+    return lane_bin_result(lists, q, k)
+
+
+@pytest.mark.parametrize("q,tiles,c,k,contiguous", [
+    (8, 5, 1024, 1, True), (24, 3, 2048, 10, False), (64, 4, 1024, 16, False)])
+def test_carried_lists_equal_full_width_top_k_of_the_stack(
+        q, tiles, c, k, contiguous):
+    """Lists carried over the tiles of a stack (rows not a whole strip, -1
+    ids, k 1 / 10 / 16) hold the stack's k smallest: bit-equal to
+    ``lax.top_k`` over the concatenated tiles, ids too (continuous data: no
+    equal distances), nothing flagged. (Against the per-tile program:
+    ``tests/test_serial.py``.)"""
+    depth = lane_bin_depth(q, c, k)
+    rng = np.random.default_rng([q, c, k])
+    d = rng.standard_normal((tiles, q, c)).astype(np.float32)
+    ids = np.stack([_tile_ids(c, contiguous) + 3 * c * t * (
+        _tile_ids(c, contiguous) >= 0) for t in range(tiles)]).astype(np.int32)
+    d[np.broadcast_to((ids < 0)[:, None, :], d.shape)] = np.inf
+    got_d, got_i, flagged = _carried(jnp.asarray(d), jnp.asarray(ids), k, depth)
+    assert not np.asarray(flagged).any()
+    wide = np.moveaxis(d, 0, 1).reshape(q, tiles * c)
+    want_d, want_i = _full_width(wide, ids.reshape(-1), k)
+    np.testing.assert_array_equal(np.asarray(got_d), want_d)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
+
+
+@pytest.mark.parametrize("planted,flags", [(3, False), (4, True), (5, True)],
+                         ids=["R-1-in-a-lane", "R-in-a-lane", "R+1-in-a-lane"])
+def test_carried_lists_flag_a_collision_no_tile_sees(rng, planted, flags):
+    """R (and R + 1) of a row's k - 1 smallest in ONE lane, one or two a
+    tile: no tile's own certificate flags anything, the carried lists'
+    does, for that row alone; one fewer is kept whole. Rows short of k
+    finite values, NaN rows and a row of equal values flag as a tile's do."""
+    q, tiles, c, k = 16, 4, 1024, 10
+    depth = lane_bin_depth(q, c, k)
+    assert depth == 4
+    d = rng.standard_normal((tiles, q, c)).astype(np.float32)
+    for j in range(planted):
+        d[j % tiles, 5, 7 + 128 * (j // tiles)] = -50.0 - j
+    d[:, 1, :] = np.inf  # no finite value
+    d[:, 2, :] = np.nan
+    d[:, 3, :] = 2.5
+    d[:, 4, :] = np.inf
+    d[2, 4, 100:103] = [3.0, 1.0, 2.0]  # three finite values
+    assert all(lane_bin_flagged_share(d[t][[0, 3, 5, 6] * 4], k) == (0.0, 0.0)
+               for t in range(tiles))
+    ids = np.arange(tiles * c, dtype=np.int32).reshape(tiles, c)
+    got_d, got_i, flagged = map(np.asarray, _carried(
+        jnp.asarray(d), jnp.asarray(ids), k, depth))
+    want = np.zeros(q, bool)
+    want[[1, 2, 4]] = True  # the k-th candidate is not finite
+    want[5] = flags
+    np.testing.assert_array_equal(flagged, want)
+    wide = np.moveaxis(d, 0, 1).reshape(q, tiles * c)
+    want_d, want_i = _full_width(wide, ids.reshape(-1), k)
+    sound = ~want
+    np.testing.assert_array_equal(got_d[sound], want_d[sound])
+    keep = sound.copy()
+    keep[3] = False  # equal values: any k distinct ids
+    np.testing.assert_array_equal(got_i[keep], want_i[keep])
+    assert (got_d[3] == 2.5).all() and len(set(got_i[3])) == k
+    assert got_d[4, :3].tolist() == [1.0, 2.0, 3.0]
 
 
 @pytest.mark.parametrize(
