@@ -547,6 +547,7 @@ def serve_phase(args, platform: str, work: str) -> tuple[bool, str]:
         "--query-tile", str(SERVE_TILES[0]),
         "--corpus-tile", str(SERVE_TILES[1]),
         "--bucket", "256", "--max-batch-rows", "1024",
+        "--bucket-headroom", "0.02", "--mutation-bucket", "64",
         "--port", "0", "--ready-file", ready, "-q",
     ]
     with open(os.path.join(work, "serve.log"), "wb") as log:
@@ -601,11 +602,15 @@ def serve_phase(args, platform: str, work: str) -> tuple[bool, str]:
         if after != before:
             return False, (f"requests compiled {after - before:g} "
                            "executables after warm-up")
+        wrote = write_then_search(url, expect)
+        if wrote:
+            return False, wrote
         proc.send_signal(signal.SIGTERM)
         rc = proc.wait(timeout=60)
         if rc != 0:
             return False, f"SIGTERM shutdown exited {rc}"
         return True, (
+            "raw_upsert+delete_seen_by_search=True "
             f"requests={sent} rows={list(SERVE_ROWS)} tenants=2 errors=0 "
             f"ids_equal_all_knn={sum(equal) / (2 * sum(SERVE_ROWS)):.5f} "
             f"buckets_warmed={health['warming']['total']}"
@@ -616,6 +621,58 @@ def serve_phase(args, platform: str, work: str) -> tuple[bool, str]:
         if proc.poll() is None:
             os.killpg(proc.pid, signal.SIGKILL)
             proc.wait()
+
+
+def post_raw(url: str, path: str, body: bytes):
+    import json
+    import urllib.error
+    import urllib.request
+
+    req = urllib.request.Request(
+        url + path, data=body, method="POST",
+        headers={"Content-Type": "application/octet-stream",
+                 "X-Tenant": "smoke-writer"})
+    try:
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            return resp.status, json.loads(resp.read())
+    except urllib.error.HTTPError as e:
+        return e.code, {}
+
+
+def write_then_search(url: str, expect) -> str:
+    """One raw upsert, one raw delete, a search that sees both, on the
+    server's own platform (ISSUE 34). Two rows go in 64 grey levels of one
+    pixel from two query rows (nearer than any corpus row, and far enough
+    that float32's matmul form does not round the distance to the zero
+    that ``exclude_zero`` hides), under new ids; one of them and the first query's old
+    nearest neighbour go out again. "" when all is as it must be."""
+    q = np.asarray(expect["queries"][:2], dtype=np.float32)
+    rows = q.copy()
+    rows[:, 0] += 64.0  # squared distance 4096
+    new = np.array([1_000_000, 1_000_001], dtype="<i4")
+    status, doc = post_raw(url, "/upsert",
+                           new.tobytes() + rows.astype("<f4").tobytes())
+    if status != 200 or doc.get("upserted") != 2:
+        return f"raw /upsert answered {status} {doc}"
+    status, doc = loadgen.post_query(url, "smoke-0", q, timeout_s=120.0)
+    if status != 200 or [r[0] for r in doc["ids"]] != new.tolist():
+        return f"the search after the upsert did not see it: {status} {doc}"
+    if max(abs(r[0] - 4096.0) for r in doc["dists"]) > 4.0:
+        return f"the upserted rows are not 4096 away: {doc['dists']}"
+    old = int(expect["ids"][0][0])
+    gone = np.array([1_000_000, old], dtype="<i4")
+    status, doc = post_raw(url, "/delete", gone.tobytes())
+    if status != 200 or doc.get("deleted") != 2:
+        return f"raw /delete answered {status} {doc}"
+    status, doc = loadgen.post_query(url, "smoke-1", q, timeout_s=120.0)
+    if status != 200:
+        return f"the search after the delete answered {status}"
+    if set(gone.tolist()) & {i for r in doc["ids"] for i in r}:
+        return f"a deleted id came back: {doc['ids']}"
+    if doc["ids"][0][0] != int(expect["ids"][0][1]) or doc["ids"][1][0] != \
+            1_000_001:
+        return f"the search after the delete is not what is left: {doc['ids']}"
+    return ""
 
 
 def main(argv=None) -> int:

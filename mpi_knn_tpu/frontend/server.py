@@ -195,7 +195,22 @@ class Frontend:
             sizes = list(warm_sizes)
 
         def _warm():
+            from mpi_knn_tpu.serve.mutate import (
+                supports_mutation,
+                warm_mirror,
+                warm_mutation,
+            )
+
+            mirror = None
             try:
+                if supports_mutation(self.session.index):
+                    # the host mirror of the id plane, beside the serve
+                    # programs' warm-up (numpy and a device fetch: the
+                    # GIL is free for most of it)
+                    mirror = threading.Thread(
+                        target=warm_mirror, args=(self.session.index,),
+                        name="frontend-mirror", daemon=True)
+                    mirror.start()
                 if sizes:
                     from mpi_knn_tpu.serve.engine import query_knn
 
@@ -209,12 +224,8 @@ class Frontend:
                 # the mutation cells too (ISSUE 14): a cold upsert would
                 # otherwise compile while HOLDING the mutation lock —
                 # stalling batch dispatch exactly once, at the worst time
-                from mpi_knn_tpu.serve.mutate import (
-                    supports_mutation,
-                    warm_mutation,
-                )
-
-                if supports_mutation(self.session.index):
+                if mirror is not None:
+                    mirror.join()
                     warm_mutation(self.session.index, self.session.cfg)
             finally:
                 # a failed warm releases the gate anyway: the same
@@ -675,6 +686,24 @@ DEFAULT_TENANT = "default"
 SEQ_HEADER = "X-Mutation-Seq"
 
 
+def raw_rows(raw: bytes, dim: int, ids: bool):
+    """The one parser of the raw request form (little-endian, the row
+    count from the body's length): ``(ids-or-None, (n, dim) float32
+    rows)``. With ``ids`` the body is n int32 ids followed by the n rows
+    (/upsert); without, the rows alone (/query)."""
+    per_row = 4 * dim + (4 if ids else 0)
+    if len(raw) % per_row:
+        raise ValueError(
+            f"raw body of {len(raw)} bytes is not a whole number of "
+            f"{'int32 id + ' if ids else ''}dim={dim} float32 rows "
+            f"({per_row} bytes each)"
+        )
+    n = len(raw) // per_row
+    head = 4 * n if ids else 0
+    rows = np.frombuffer(raw, dtype="<f4", offset=head).reshape(n, dim)
+    return (np.frombuffer(raw, dtype="<i4", count=n) if ids else None), rows
+
+
 def _http_handler(frontend: Frontend, request_timeout_s: float,
                   quiet: bool = True):
     """The BaseHTTPRequestHandler subclass bound to one frontend —
@@ -704,23 +733,23 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             if not quiet:
                 BaseHTTPRequestHandler.log_message(self, fmt, *args)
 
+        def _read_body(self):
+            """(body bytes, whether it is the raw form): every POST
+            route takes JSON or ``application/octet-stream``."""
+            n = int(self.headers.get("Content-Length") or 0)
+            if n <= 0:
+                raise ValueError("empty request body")
+            ctype = (self.headers.get("Content-Type") or "").split(";")[0]
+            return self.rfile.read(n), ctype == "application/octet-stream"
+
         def _read_queries(self):
             """(rows, dim) f32 from the request body: JSON
             ``{"queries": [[...], ...]}`` or raw little-endian f32 rows
             at the index dim (``application/octet-stream``)."""
-            n = int(self.headers.get("Content-Length") or 0)
-            if n <= 0:
-                raise ValueError("empty request body")
-            raw = self.rfile.read(n)
-            ctype = (self.headers.get("Content-Type") or "").split(";")[0]
+            raw, is_raw = self._read_body()
             dim = frontend.session.index.dim
-            if ctype == "application/octet-stream":
-                if len(raw) % (4 * dim):
-                    raise ValueError(
-                        f"raw f32 body of {len(raw)} bytes is not a "
-                        f"whole number of dim={dim} rows"
-                    )
-                return np.frombuffer(raw, dtype="<f4").reshape(-1, dim)
+            if is_raw:
+                return raw_rows(raw, dim, ids=False)[1]
             doc = json.loads(raw)
             q = np.asarray(doc["queries"], dtype=np.float32)
             if q.ndim != 2 or q.shape[1] != dim:
@@ -745,11 +774,40 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             self.end_headers()
             self.wfile.write(body)
 
-        def _read_json(self) -> dict:
-            n = int(self.headers.get("Content-Length") or 0)
-            if n <= 0:
-                raise ValueError("empty request body")
-            return json.loads(self.rfile.read(n))
+        def _read_mutation(self):
+            """(ids, rows-or-None) of a write's body. JSON for small
+            callers: ``{"ids": [...], "rows": [[...]]}`` to /upsert,
+            ``{"ids": [...]}`` to /delete. Raw
+            (``application/octet-stream``, little-endian, the count from
+            ``Content-Length``) as ``/query`` takes it: n int32 ids, then
+            for /upsert n float32 rows at the index dim."""
+            raw, is_raw = self._read_body()
+            upsert = self.path == "/upsert"
+            dim = frontend.session.index.dim
+            if is_raw:
+                if upsert:
+                    return raw_rows(raw, dim, ids=True)
+                if len(raw) % 4:
+                    raise ValueError(
+                        f"raw int32 body of {len(raw)} bytes is not a "
+                        "whole number of ids"
+                    )
+                return np.frombuffer(raw, dtype="<i4"), None
+            doc = json.loads(raw)
+            ids = doc["ids"]
+            if not upsert:
+                return ids, None
+            rows = np.asarray(doc["rows"], dtype=np.float32)
+            if rows.ndim != 2 or rows.shape[1] != dim:
+                raise ValueError(
+                    f"rows shape {rows.shape} does not match "
+                    f"index dim {dim}"
+                )
+            if len(ids) != rows.shape[0]:
+                raise ValueError(
+                    f"{len(ids)} ids but {rows.shape[0]} rows"
+                )
+            return ids, rows
 
         def _refuse_mutation(self, status: int, doc: dict, seq) -> None:
             """Send a DETERMINISTIC refusal (400/507): the seq is
@@ -767,32 +825,22 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
 
         def _do_mutation(self, tenant: str) -> None:
             """POST /upsert {"ids": [...], "rows": [[...]]} and
-            POST /delete {"ids": [...]} — tenant-attributed (X-Tenant),
+            POST /delete {"ids": [...]}, or their raw forms
+            (``_read_mutation``) — tenant-attributed (X-Tenant),
             429-governed through the scheduler's shared budget,
             dispatched synchronously (the mutation lock serializes with
             batch dispatch). Headroom overflow on the serial layout
             surfaces as 507 (no re-cluster pass to absorb it); clustered
             layouts compact-and-retry inside the session."""
             from mpi_knn_tpu.ivf.mutate import BucketOverflowError
+            from mpi_knn_tpu.serve.mutate import mutation_phase
 
             seq = None
             try:
                 seq_h = self.headers.get(SEQ_HEADER)
                 seq = None if seq_h is None else int(seq_h)
-                doc = self._read_json()
-                ids = doc["ids"]
-                if self.path == "/upsert":
-                    dim = frontend.session.index.dim
-                    rows = np.asarray(doc["rows"], dtype=np.float32)
-                    if rows.ndim != 2 or rows.shape[1] != dim:
-                        raise ValueError(
-                            f"rows shape {rows.shape} does not match "
-                            f"index dim {dim}"
-                        )
-                    if len(ids) != rows.shape[0]:
-                        raise ValueError(
-                            f"{len(ids)} ids but {rows.shape[0]} rows"
-                        )
+                with mutation_phase("parse"):
+                    ids, rows = self._read_mutation()
             except (ValueError, KeyError, TypeError) as e:
                 self._refuse_mutation(400, {"error": str(e)}, seq)
                 return
@@ -821,7 +869,9 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
         def do_POST(self):  # noqa: N802 — stdlib handler convention
             tenant = self.headers.get(TENANT_HEADER, DEFAULT_TENANT)
             if self.path in ("/upsert", "/delete"):
-                self._do_mutation(tenant)
+                with obs_spans.span("request", cat="http",
+                                    route=self.path[1:]):
+                    self._do_mutation(tenant)
                 return
             if self.path != "/query":
                 self._json(404, {"error": f"no such route {self.path}"})

@@ -77,7 +77,12 @@ class BucketOverflowError(RuntimeError):
 
 
 class Freelist:
-    """Per-bucket free-slot stacks + the id → (partition, slot) map.
+    """The host mirror of slot occupancy, in array form: which slots are
+    used (``used``, one bool a slot), how many are free in each bucket
+    (``free_count``), and each live id's flat slot ``partition * cap +
+    slot`` (a table indexed by id). Made with numpy from one fetch of the
+    id plane: at 10 M rows a fraction of a second and ~60 MB, where one
+    dict entry and one tuple a row took tens of seconds and gigabytes.
 
     Derived from ``bucket_ids`` (id −1 = free), never stored: any saved
     artifact — including pre-mutation ones — reconstructs it exactly.
@@ -88,6 +93,11 @@ class Freelist:
     ``tombstones`` counts deleted-not-yet-reused slots (an upsert that
     reclaims a tombstoned slot decrements it); the compaction triggers
     read ``max_fill`` and ``tombstone_fraction`` from here.
+
+    The table covers ids below ``dense_limit`` (eight times the slots,
+    at least 2**22: ids that follow the corpus's size); an id beyond it
+    goes into a dict, the one per-row path left, for callers that hash
+    their ids over all of int32.
     """
 
     def __init__(self, bucket_ids: np.ndarray, partitions: int):
@@ -101,38 +111,116 @@ class Freelist:
         # must be out of range of the padded store)
         self.total = int(ids.shape[0])
         self.cap = int(ids.shape[1])
-        # free stacks in REVERSE slot order so .pop() yields the lowest
-        # free slot (deterministic, replayable allocation)
-        self.free: list[list[int]] = [
-            sorted(np.flatnonzero(ids[p] < 0).tolist(), reverse=True)
-            for p in range(self.partitions)
-        ]
-        self.pos: dict[int, tuple[int, int]] = {}
-        for p in range(self.partitions):
-            for s in np.flatnonzero(ids[p] >= 0):
-                self.pos[int(ids[p, s])] = (p, int(s))
+        real = ids[: self.partitions]
+        self.used = real >= 0
+        self.free_count = self.cap - self.used.sum(axis=1, dtype=np.int64)
+        flat = np.flatnonzero(self.used.reshape(-1))
+        live_ids = real.reshape(-1)[flat]
+        self.live = int(flat.size)
+        self.dense_limit = max(1 << 22, 8 * self.total * self.cap)
+        self._far: dict[int, int] = {}
+        if self.live and int(live_ids.max()) >= self.dense_limit:
+            far = live_ids >= self.dense_limit
+            self._far = dict(zip(live_ids[far].tolist(), flat[far].tolist()))
+            flat, live_ids = flat[~far], live_ids[~far]
+        # (flat slots fit int32 up to 2**31 slots: 4 bytes an id)
+        self._slot = np.full(
+            int(live_ids.max()) + 1 if live_ids.size else 0, -1,
+            np.int32 if self.total * self.cap < 2**31 else np.int64)
+        self._slot[live_ids] = flat
         self.tombstones = 0
-        self._tomb_free = [0] * self.partitions
+        self._tomb_free = np.zeros(self.partitions, np.int64)
 
     @property
-    def live(self) -> int:
-        return len(self.pos)
+    def nbytes(self) -> int:
+        """Host bytes of the mirror's arrays."""
+        return int(self.used.nbytes + self.free_count.nbytes
+                   + self._slot.nbytes + self._tomb_free.nbytes)
+
+    def lookup(self, ids) -> np.ndarray:
+        """Flat slot of each id (int64), −1 where the id is not live."""
+        ids = np.asarray(ids, dtype=np.int64).reshape(-1)
+        out = np.full(ids.shape[0], -1, np.int64)
+        near = (ids >= 0) & (ids < self._slot.shape[0])
+        out[near] = self._slot[ids[near]]
+        if self._far:
+            for i in np.flatnonzero(ids >= self.dense_limit):
+                out[i] = self._far.get(int(ids[i]), -1)
+        return out
+
+    def where(self, rid: int):
+        """(partition, slot) of one live id, else None."""
+        flat = int(self.lookup([rid])[0])
+        return None if flat < 0 else divmod(flat, self.cap)
+
+    def live_ids(self) -> np.ndarray:
+        """Every live id, ascending."""
+        near = np.flatnonzero(self._slot >= 0)
+        if not self._far:
+            return near
+        return np.concatenate([near, np.sort(np.fromiter(
+            self._far, np.int64, len(self._far)))])
+
+    def lowest_free(self, partition: int, count: int = 1) -> np.ndarray:
+        """The ``count`` lowest free slots of one bucket."""
+        return np.flatnonzero(~self.used[partition])[:count]
+
+    def _record(self, ids: np.ndarray, flat: np.ndarray) -> None:
+        near = ids < self.dense_limit
+        if near.any():
+            top = int(ids[near].max()) + 1
+            if top > self._slot.shape[0]:
+                grown = np.full(
+                    min(self.dense_limit, max(top, 2 * self._slot.shape[0])),
+                    -1, self._slot.dtype)
+                grown[: self._slot.shape[0]] = self._slot
+                self._slot = grown
+            self._slot[ids[near]] = flat[near]
+        for i in np.flatnonzero(~near):
+            self._far[int(ids[i])] = int(flat[i])
+
+    def occupy(self, ids: np.ndarray, flat: np.ndarray) -> None:
+        """Commit an allocation: ``ids`` now live at free slots ``flat``
+        (an id that was live elsewhere has had its old slot released by
+        the caller)."""
+        was = self.lookup(ids)
+        self.used.reshape(-1)[flat] = True
+        parts, counts = np.unique(flat // self.cap, return_counts=True)
+        self.free_count[parts] -= counts
+        reused = np.minimum(self._tomb_free[parts], counts)
+        self._tomb_free[parts] -= reused
+        self.tombstones -= int(reused.sum())
+        self._record(ids, flat)
+        self.live += int((was < 0).sum())
+
+    def release(self, flat: np.ndarray, ids: np.ndarray | None = None
+                ) -> None:
+        """Commit tombstones: slots ``flat`` are free again; ``ids``
+        (where given) leave the index."""
+        self.used.reshape(-1)[flat] = False
+        parts, counts = np.unique(flat // self.cap, return_counts=True)
+        self.free_count[parts] += counts
+        self._tomb_free[parts] += counts
+        self.tombstones += int(flat.size)
+        if ids is not None:
+            near = ids < self.dense_limit
+            self._slot[ids[near]] = -1
+            for rid in ids[~near].tolist():
+                self._far.pop(rid, None)
+            self.live -= int(ids.shape[0])
 
     @property
     def max_fill(self) -> float:
         """Largest bucket fill fraction (used slots / cap)."""
         if not self.partitions:
             return 0.0
-        return max(
-            (self.cap - len(f)) / self.cap for f in self.free
-        )
+        return float(self.cap - self.free_count.min()) / self.cap
 
     @property
     def tombstone_fraction(self) -> float:
         return self.tombstones / max(1, self.live)
 
     def stats(self) -> dict:
-        used = [self.cap - len(f) for f in self.free]
         return {
             "live": self.live,
             "tombstones": self.tombstones,
@@ -140,30 +228,63 @@ class Freelist:
             "partitions": self.partitions,
             "max_fill": round(self.max_fill, 6),
             "tombstone_fraction": round(self.tombstone_fraction, 6),
-            "free_slots": int(sum(len(f) for f in self.free)),
-            "max_used": max(used) if used else 0,
+            "free_slots": int(self.free_count.sum()),
+            "max_used": (int(self.cap - self.free_count.min())
+                         if self.partitions else 0),
         }
 
 
 def freelist_of(index) -> Freelist:
     """The index's cached freelist, derived on first use from the
-    resident id plane (one small host fetch). Cached on the instance
-    like ``_cache`` — mutation plans commit into it. Works for both
-    mutable layouts: the clustered bucket store (per-partition buckets)
-    and the serial tile stack (every tile is a "bucket" of c_tile
-    slots)."""
+    resident id plane (one host fetch of 4 bytes a slot, inside the span
+    ``knn:index.freelist-build``). ``warm_mutation`` makes it during
+    set-up; a writer that finds none makes it before it takes the
+    mutation lock, never under it. Cached on the instance like
+    ``_cache`` — mutation plans commit into it. Works for both mutable
+    layouts: the clustered bucket store (per-partition buckets) and the
+    serial tile stack (every tile is a "bucket" of c_tile slots)."""
     fl = index.__dict__.get("_freelist")
     if fl is None:
-        if getattr(index, "tiles", None) is not None:
-            ids = np.asarray(jax.device_get(index.tile_ids))
-            fl = Freelist(ids, ids.shape[0])
-        else:
-            fl = Freelist(
-                np.asarray(jax.device_get(index.bucket_ids)),
-                index.partitions,
-            )
+        from mpi_knn_tpu.obs import spans as obs_spans
+
+        dense = getattr(index, "tiles", None) is not None
+        plane = index.tile_ids if dense else index.bucket_ids
+        with obs_spans.span("freelist-build", cat="index",
+                            rows=int(plane.size),
+                            bytes=int(plane.size) * 4):
+            ids = np.asarray(jax.device_get(plane))
+            fl = Freelist(ids, ids.shape[0] if dense else index.partitions)
         index.__dict__["_freelist"] = fl
     return fl
+
+
+def _lowest_free_slots(fl: Freelist, parts: np.ndarray) -> np.ndarray:
+    """A free slot for each entry of ``parts``: the entries that name
+    one bucket get its lowest free slots in their own order. One cumsum
+    and one search over the touched buckets' ``used`` rows. Raises
+    :class:`BucketOverflowError` naming the buckets that lack slots."""
+    uniq, inv, counts = np.unique(parts, return_inverse=True,
+                                  return_counts=True)
+    short = counts > fl.free_count[uniq]
+    if short.any():
+        overflow = uniq[short].tolist()
+        raise BucketOverflowError(
+            f"bucket headroom exhausted for partition(s) "
+            f"{overflow} (cap={fl.cap}); compact the index "
+            "(re-cluster rebalances and re-derives headroom) and retry",
+            partitions=overflow,
+        )
+    order = np.argsort(inv, kind="stable")
+    rank = np.empty(inv.shape[0], np.int64)  # place among its bucket's
+    rank[order] = np.arange(inv.shape[0]) - np.repeat(
+        np.cumsum(counts) - counts, counts)
+    # free slots counted along each touched bucket, the buckets' counts
+    # strung into one ascending sequence so that one search serves all
+    stride = fl.cap + 1
+    free_before = np.cumsum(~fl.used[uniq], axis=1) + (
+        np.arange(uniq.shape[0]) * stride)[:, None]
+    at = np.searchsorted(free_before.reshape(-1), rank + 1 + inv * stride)
+    return at - inv * fl.cap
 
 
 def plan_upsert(fl: Freelist, ids: np.ndarray, parts: np.ndarray):
@@ -178,91 +299,59 @@ def plan_upsert(fl: Freelist, ids: np.ndarray, parts: np.ndarray):
     fresh slot allocated. ``ids`` must be unique within one chunk (the
     orchestration dedupes — duplicate scatter indices would race).
     Raises :class:`BucketOverflowError` (freelist untouched) when any
-    target bucket is out of free slots."""
-    n = len(ids)
-    part = np.empty(n, np.int32)
-    slot = np.empty(n, np.int32)
-    clear_part = np.full(n, fl.total, np.int32)  # default: drop
-    clear_slot = np.zeros(n, np.int32)
-    taken: dict[int, int] = {}  # partition -> slots consumed this plan
-    moves: list[tuple] = []  # (rid, old_pos|None, new_p, new_s)
-    overflow = set()
-    for i, (rid, p) in enumerate(zip(ids, parts)):
-        rid, p = int(rid), int(p)
-        old = fl.pos.get(rid)
-        if old is not None and old[0] == p:
-            # in-place update: reuse the id's own occupied slot (the
-            # row/norm/scale scatter replaces the payload, the id
-            # scatter rewrites the same id)
-            part[i], slot[i] = p, old[1]
-            continue
-        if old is not None:
-            clear_part[i], clear_slot[i] = old
-        depth = taken.get(p, 0)
-        stack = fl.free[p]
-        if depth >= len(stack):
-            overflow.add(p)
-            continue
-        s = int(stack[-1 - depth])
-        taken[p] = depth + 1
-        part[i], slot[i] = p, s
-        moves.append((rid, old, p, s))
-    if overflow:
-        raise BucketOverflowError(
-            f"bucket headroom exhausted for partition(s) "
-            f"{sorted(overflow)} (cap={fl.cap}); compact the index "
-            "(re-cluster rebalances and re-derives headroom) and retry",
-            partitions=sorted(overflow),
-        )
+    target bucket is out of free slots. A bounded number of numpy calls
+    a chunk: nothing iterates over rows or over all buckets."""
+    ids = np.asarray(ids, dtype=np.int64).reshape(-1)
+    parts = np.asarray(parts, dtype=np.int64).reshape(-1)
+    old = fl.lookup(ids)
+    old_part, old_slot = np.divmod(old, fl.cap)
+    stay = (old >= 0) & (old_part == parts)  # in-place update: the id's
+    # own occupied slot (the row/norm/scale scatter replaces the payload,
+    # the id scatter rewrites the same id)
+    moved = (old >= 0) & ~stay
+    fresh = ~stay
+    slot = np.where(stay, old_slot, 0)
+    if fresh.any():
+        slot[fresh] = _lowest_free_slots(fl, parts[fresh])
+    clear_part = np.where(moved, old_part, fl.total).astype(np.int32)
+    clear_slot = np.where(moved, old_slot, 0).astype(np.int32)
 
     def commit():
-        for rid, old, p, s in moves:
-            if old is not None:
-                op, os_ = old
-                fl.free[op].append(int(os_))
-                fl.free[op].sort(reverse=True)
-                fl._tomb_free[op] += 1
-                fl.tombstones += 1
-            fl.free[p].remove(s)
-            if fl._tomb_free[p] > 0:
-                fl._tomb_free[p] -= 1
-                fl.tombstones -= 1
-            fl.pos[rid] = (p, s)
+        if fresh.any():
+            fl.occupy(ids[fresh], parts[fresh] * fl.cap + slot[fresh])
+        if moved.any():
+            fl.release(old[moved])
 
-    return part, slot, clear_part, clear_slot, commit
+    return (parts.astype(np.int32), slot.astype(np.int32), clear_part,
+            clear_slot, commit)
 
 
 def plan_delete(fl: Freelist, ids: np.ndarray):
     """(part, slot, commit, missing): scatter index vectors tombstoning
     every LIVE id in ``ids`` (unknown ids are counted in ``missing`` and
     dropped — deleting an absent id is idempotent, not an error)."""
-    n = len(ids)
-    part = np.full(n, fl.total, np.int32)  # default: drop
-    slot = np.zeros(n, np.int32)
-    found = []
-    missing = 0
-    for i, rid in enumerate(ids):
-        old = fl.pos.get(int(rid))
-        if old is None:
-            missing += 1
-            continue
-        part[i], slot[i] = old
-        found.append(int(rid))
+    ids = np.asarray(ids, dtype=np.int64).reshape(-1)
+    flat = fl.lookup(ids)
+    found = flat >= 0
+    part = np.where(found, flat // fl.cap, fl.total).astype(np.int32)
+    slot = np.where(found, flat % fl.cap, 0).astype(np.int32)
 
     def commit():
-        for rid in found:
-            p, s = fl.pos.pop(rid)
-            fl.free[p].append(s)
-            fl.free[p].sort(reverse=True)
-            fl._tomb_free[p] += 1
-            fl.tombstones += 1
+        if found.any():
+            fl.release(flat[found], ids[found])
 
-    return part, slot, commit, missing
+    return part, slot, commit, int((~found).sum())
 
 
 # ---------------------------------------------------------------------------
 # Device programs (jitted once at module level, store args donated — the
 # serving engine's convention, extended to mutation)
+
+
+# kernel scopes of the scatter programs in the HLO ``op_name``, so a
+# device trace can say what the writes cost
+UPSERT_SCOPE = "knn.mutate/upsert"
+DELETE_SCOPE = "knn.mutate/delete"
 
 
 def store_rows_and_sqs(rows: jax.Array, cfg: KNNConfig, dim: int):
@@ -307,17 +396,19 @@ def ivf_upsert_chunk(
     donated input (R5's contract over the mutation programs) and the
     only new payload materialized is the (B, ·) chunk itself (R2-strict's
     touched-bucket budget)."""
-    at_rest, scales, sqs = store_rows_and_sqs(rows, cfg, rows.shape[-1])
-    bucket_ids = bucket_ids.at[clear_part, clear_slot].set(-1, mode="drop")
-    bucket_ids = bucket_ids.at[part, slot].set(new_ids, mode="drop")
-    buckets = buckets.at[part, slot].set(at_rest, mode="drop")
-    bucket_sqs = bucket_sqs.at[part, slot].set(
-        sqs.astype(bucket_sqs.dtype), mode="drop"
-    )
-    if bucket_scales is not None:
-        bucket_scales = bucket_scales.at[part, slot].set(
-            scales, mode="drop"
+    with jax.named_scope(UPSERT_SCOPE):
+        at_rest, scales, sqs = store_rows_and_sqs(rows, cfg, rows.shape[-1])
+        bucket_ids = bucket_ids.at[clear_part, clear_slot].set(
+            -1, mode="drop")
+        bucket_ids = bucket_ids.at[part, slot].set(new_ids, mode="drop")
+        buckets = buckets.at[part, slot].set(at_rest, mode="drop")
+        bucket_sqs = bucket_sqs.at[part, slot].set(
+            sqs.astype(bucket_sqs.dtype), mode="drop"
         )
+        if bucket_scales is not None:
+            bucket_scales = bucket_scales.at[part, slot].set(
+                scales, mode="drop"
+            )
     return buckets, bucket_ids, bucket_sqs, bucket_scales
 
 
@@ -325,7 +416,8 @@ def ivf_delete_chunk(part, slot, bucket_ids):
     """One donated tombstone chunk: ids at the given slots go to −1
     (``mask_tile`` makes them +inf candidates — never answers). Row data
     stays resident and masked; the freelist reclaims the slots."""
-    return bucket_ids.at[part, slot].set(-1, mode="drop")
+    with jax.named_scope(DELETE_SCOPE):
+        return bucket_ids.at[part, slot].set(-1, mode="drop")
 
 
 def ivf_compact_assign(buckets, bucket_scales, centroids, centroid_sqs,
@@ -410,15 +502,12 @@ def gather_live_sample(index, limit: int = COMPACT_SAMPLE) -> np.ndarray:
     via a SMALL device gather — the tune_nprobe precedent: the retrain
     must not round-trip the whole store through the host."""
     fl = freelist_of(index)
-    ids = sorted(fl.pos)
-    if not ids:
+    ids = fl.live_ids()
+    if not ids.size:
         raise ValueError("cannot compact an empty index (no live rows)")
     take = np.linspace(0, len(ids) - 1, num=min(limit, len(ids)),
                        dtype=np.int64)
-    flat = np.array(
-        [fl.pos[ids[i]][0] * fl.cap + fl.pos[ids[i]][1] for i in take],
-        dtype=np.int64,
-    )
+    flat = fl.lookup(ids[take])
     sel = index.buckets.reshape(-1, index.buckets.shape[-1])[
         jnp.asarray(flat)
     ]

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import dataclasses
 import functools
 import os
@@ -221,13 +222,52 @@ def mutation_lock(index) -> threading.Lock:
     per batch would add a cross-index serialization point); the dict
     read is atomic under the GIL and the mutex only arbitrates the
     one-time creation."""
-    lk = index.__dict__.get("_mutation_lock")
+    return _index_lock(index, "_mutation_lock")
+
+
+def _index_lock(index, name: str) -> threading.Lock:
+    lk = index.__dict__.get(name)
     if lk is None:
         with _KEYLOCK_MUTEX:
-            lk = index.__dict__.setdefault(
-                "_mutation_lock", threading.Lock()
-            )
+            lk = index.__dict__.setdefault(name, threading.Lock())
     return lk
+
+
+def writer_lock(index) -> threading.Lock:
+    """The per-index WRITERS' lock: one mutation at a time plans against
+    the freelist, copies its chunk to the device and then takes
+    :func:`mutation_lock` for its scatter's dispatch and the mirror's
+    commit. Batch dispatch never takes it, so a batch waits for a
+    write's dispatch at most, not for its plan or its copy. Order:
+    writer lock, then mutation lock."""
+    return _index_lock(index, "_writer_lock")
+
+
+@contextlib.contextmanager
+def held(lock: threading.Lock, side: str):
+    """Hold ``lock``; what the caller waited for it goes to
+    ``mutation_lock_wait_seconds_total{side=...}`` (and one to its
+    ``mutation_lock_waits_total``) after the lock is released: ``side="batch"`` is a batch
+    dispatch, ``"mutation"`` a write."""
+    t = time.perf_counter()
+    lock.acquire()
+    waited = time.perf_counter() - t
+    try:
+        yield
+    finally:
+        lock.release()
+        reg = obs_metrics.get_registry()
+        reg.counter(
+            "mutation_lock_wait_seconds_total",
+            help="seconds spent waiting for an index's mutation lock, by "
+            "who waited: a batch dispatch or a write",
+            labels={"side": side},
+        ).inc(waited)
+        reg.counter(
+            "mutation_lock_waits_total",
+            help="acquisitions behind mutation_lock_wait_seconds_total",
+            labels={"side": side},
+        ).inc()
 
 
 def get_executable(
@@ -445,7 +485,7 @@ def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
     lock — the resident args are read and the batch enqueued as one
     atomic step w.r.t. any in-place store update."""
     lay = index.layout
-    with mutation_lock(index):
+    with held(mutation_lock(index), "batch"):
         scratch = exec_.make_carry()
         if lay.tiled and not lay.pretiled:
             tiles = lay.rows(exec_.q_pad, exec_.q_tile)

@@ -124,27 +124,77 @@ def _require_mutable(index) -> None:
 def serial_upsert_chunk(
     rows, new_ids, tpos, spos, clear_t, clear_s,
     tiles, tile_ids, tile_sqs,  # DONATED resident tile stack
-    cfg: KNNConfig,
+    cfg: KNNConfig, by_tile: bool = False,
 ):
     """Donated in-place upsert into the serial tile stack: headroom rows
     (id −1 padding) absorb new rows at (tile, slot) positions the flat
     freelist allocated; updated ids clear their old slot first. The
     at-rest cast and the per-row norms are the build's own math
-    (``ivf.mutate.store_rows_and_sqs``)."""
-    from mpi_knn_tpu.ivf.mutate import store_rows_and_sqs
+    (``ivf.mutate.store_rows_and_sqs``). ``by_tile``: how the rows reach
+    the stack (:func:`scatter_rows_by_tile`), chosen by the layout the
+    device keeps the stack in (:func:`stack_rests_row_major`)."""
+    from mpi_knn_tpu.ivf.mutate import UPSERT_SCOPE, store_rows_and_sqs
 
-    at_rest, _, sqs = store_rows_and_sqs(rows, cfg, rows.shape[-1])
-    tile_ids = tile_ids.at[clear_t, clear_s].set(-1, mode="drop")
-    tile_ids = tile_ids.at[tpos, spos].set(new_ids, mode="drop")
-    tiles = tiles.at[tpos, spos].set(at_rest, mode="drop")
-    tile_sqs = tile_sqs.at[tpos, spos].set(
-        sqs.astype(tile_sqs.dtype), mode="drop"
-    )
+    with jax.named_scope(UPSERT_SCOPE):
+        at_rest, _, sqs = store_rows_and_sqs(rows, cfg, rows.shape[-1])
+        tile_ids = tile_ids.at[clear_t, clear_s].set(-1, mode="drop")
+        tile_ids = tile_ids.at[tpos, spos].set(new_ids, mode="drop")
+        if by_tile:
+            tiles = scatter_rows_by_tile(tiles, tpos, spos, at_rest)
+        else:
+            tiles = tiles.at[tpos, spos].set(at_rest, mode="drop")
+        tile_sqs = tile_sqs.at[tpos, spos].set(
+            sqs.astype(tile_sqs.dtype), mode="drop"
+        )
     return tiles, tile_ids, tile_sqs
 
 
+def scatter_rows_by_tile(tiles, tpos, spos, rows):
+    """``tiles.at[tpos, spos].set(rows, mode="drop")``, one touched tile
+    at a time: take the tile out of the stack, scatter the chunk's rows
+    that belong to it, put it back. For a stack whose rows the device
+    does not keep contiguous: a TPU keeps a (T, c, d) float32 stack with
+    d off the 128-lane grid rows-minor (d = 100: ``{1,0,2}``, no lane
+    padding), and the one-scatter form is then compiled as a copy of the
+    whole stack into a row-major buffer, the scatter, and a copy back
+    (d = 100 at 1224 tiles: 5.13 GB of temporaries and two passes over
+    the stack a chunk, read in the program compiled for the v5e). A
+    tile's slice is what the search's own scan takes every step, in the
+    layout the stack has; the temporaries here are one tile's."""
+    n_tiles, c_tile = tiles.shape[:2]
+    # ascending; the drop sentinel (n_tiles) sorts last and fills the rest
+    touched = jnp.unique(tpos, size=tpos.shape[0], fill_value=n_tiles)
+
+    def one_tile(i, stack):
+        t = touched[i]
+        tile = jax.lax.dynamic_index_in_dim(stack, t, keepdims=False)
+        tile = tile.at[jnp.where(tpos == t, spos, c_tile)].set(
+            rows, mode="drop")
+        return jax.lax.dynamic_update_index_in_dim(stack, tile, t, 0)
+
+    return jax.lax.fori_loop(0, jnp.sum(touched < n_tiles), one_tile, tiles)
+
+
+def stack_rests_row_major(index) -> bool:
+    """Whether the device keeps the serial tile stack row-major (a row's
+    ``dim`` elements contiguous: the default layout, every backend but
+    the TPU at a ``dim`` off its lane grid). Read once from the resident
+    array and kept on the index: a later reader may find the buffer
+    donated away."""
+    known = index.__dict__.get("_stack_row_major")
+    if known is None:
+        try:
+            order = tuple(index.tiles.format.layout.major_to_minor)
+        except Exception:  # noqa: BLE001 — no layout to read: the default
+            order = None
+        known = order is None or order == tuple(range(index.tiles.ndim))
+        index.__dict__["_stack_row_major"] = known
+    return known
+
+
 serial_upsert_jit = jax.jit(
-    serial_upsert_chunk, static_argnames=("cfg",), donate_argnums=(6, 7, 8)
+    serial_upsert_chunk, static_argnames=("cfg", "by_tile"),
+    donate_argnums=(6, 7, 8),
 )
 SERIAL_UPSERT_DONATED = (6, 7, 8)
 # the serial delete is the clustered delete program over (tile, slot) —
@@ -287,7 +337,9 @@ def lower_mutation(index, cfg: KNNConfig, bucket: int, kind: str):
         )
     if kind == KIND_UPSERT:
         if index.backend == "serial":
-            return serial_upsert_jit.lower(*chunk, *store, cfg=index.cfg)
+            return serial_upsert_jit.lower(
+                *chunk, *store, cfg=index.cfg,
+                by_tile=not stack_rests_row_major(index))
         return upsert_jit.lower(*chunk, *store, cfg=index.cfg)
     if kind == KIND_DELETE:
         ids_plane = store[1]  # the id plane (tile_ids / bucket_ids)
@@ -378,6 +430,17 @@ def get_mutation_executable(index, cfg: KNNConfig, bucket: int, kind: str):
     return compiled
 
 
+def warm_mirror(index) -> None:
+    """Make the host mirror of the id plane now, during set-up, so that
+    the first write finds it (at 10 M rows its fetch and build are half
+    a second that no search should wait behind). ``Frontend.start`` runs
+    this on a thread of its own beside the serve programs' warm-up."""
+    from mpi_knn_tpu.serve.engine import writer_lock
+
+    with writer_lock(index):
+        freelist_of(index)
+
+
 def warm_mutation(index, cfg: KNNConfig | None = None,
                   sizes=(None,)) -> dict:
     """Pre-build the mutation cells for the given chunk sizes (None =
@@ -387,6 +450,7 @@ def warm_mutation(index, cfg: KNNConfig | None = None,
 
     cfg = cfg or index.cfg
     built = 0
+    warm_mirror(index)
     for n in sizes:
         bucket = bucket_rows(
             n if n is not None else cfg.mutation_bucket, cfg.mutation_bucket
@@ -456,10 +520,12 @@ def _pad_chunk(arr: np.ndarray, bucket: int, fill) -> np.ndarray:
 
 
 def _put_chunk(index, *arrays):
-    rep = _replicated(index)
-    if rep is None:
-        return arrays
-    return tuple(jax.device_put(a, rep) for a in arrays)
+    """The chunk-side args on the device (replicated over a sharded
+    index's mesh), copied BEFORE the mutation lock is taken: handed to
+    the executable as host arrays they would be copied inside its call,
+    under the lock."""
+    # one call for the whole tuple: jax batches a pytree's transfers
+    return jax.device_put(arrays, _replicated(index))
 
 
 def _swap_store(index, buckets, bucket_ids, bucket_sqs, bucket_scales):
@@ -477,21 +543,58 @@ def mutation_stats(index) -> dict:
     return freelist_of(index).stats()
 
 
-def _stamp_gauges(index, reg) -> None:
-    fl = freelist_of(index)
+def _stamp_gauges(reg, stats: dict) -> None:
     reg.gauge(
         "index_live_rows", help="live (non-tombstoned) rows in the index"
-    ).set(fl.live)
+    ).set(stats["live"])
     reg.gauge(
         "index_tombstone_fraction",
         help="tombstoned slots as a fraction of live rows (a compaction "
         "trigger)",
-    ).set(fl.tombstone_fraction)
+    ).set(stats["tombstone_fraction"])
     reg.gauge(
         "index_max_bucket_fill",
         help="largest bucket fill fraction (headroom exhaustion — a "
         "compaction trigger)",
-    ).set(fl.max_fill)
+    ).set(stats["max_fill"])
+
+
+def mutation_phase(name: str, **attrs):
+    """A phase of one write: span ``knn:mutate.<name>`` whose seconds go
+    to ``mutation_phase_seconds_total{phase=<name>}``. The phases:
+    ``parse`` (the body's decoding in the HTTP handler; validation,
+    centring and padding here), ``plan`` (slots from the freelist),
+    ``h2d`` (the chunk's copy to the device), ``dispatch`` (the donated
+    scatter's call and the store's swap) and ``commit`` (the mirror's),
+    the last two under the mutation lock."""
+    return obs_spans.span(
+        name, cat="mutate",
+        sink=obs_metrics.get_registry().counter(
+            "mutation_phase_seconds_total",
+            help="host seconds of live mutation by phase (parse, plan, "
+            "h2d, dispatch, commit)",
+            labels={"phase": name},
+        ).inc,
+        **attrs,
+    )
+
+
+def _note_mutation(reg, kind: str, rows: int, chunk: int, t0: float):
+    reg.counter(
+        f"mutation_{kind}_total",
+        help="rows upserted into live indices" if kind == "upserts"
+        else "rows tombstoned in live indices",
+    ).inc(rows)
+    reg.histogram(
+        "mutation_chunk_rows",
+        help="rows per mutation chunk (upsert+delete)",
+        buckets=CHUNK_ROW_BUCKETS,
+    ).observe(chunk)
+    reg.histogram(
+        "mutation_latency_seconds",
+        help="wall time of one mutation call (plan + donated dispatch + "
+        "commit)",
+    ).observe(time.perf_counter() - t0)
 
 
 def upsert_rows(index, ids, rows, config: KNNConfig | None = None) -> dict:
@@ -500,92 +603,104 @@ def upsert_rows(index, ids, rows, config: KNNConfig | None = None) -> dict:
     placement scored on device (clustered layouts), slots from the
     freelist, ONE donated scatter, store swapped in place. Existing ids
     are updated (old slot tombstoned when the row moves partitions).
-    Returns a stats dict; raises :class:`BucketOverflowError` when
-    headroom is exhausted (the freelist and store are untouched — compact
-    and retry)."""
-    from mpi_knn_tpu.serve.engine import bucket_rows, mutation_lock
+    Writers take turns on the writers' lock; the mutation lock, which
+    batch dispatch shares, is held for the scatter's dispatch and the
+    mirror's commit alone. Returns a stats dict; raises
+    :class:`BucketOverflowError` when headroom is exhausted (the
+    freelist and store are untouched — compact and retry)."""
+    from mpi_knn_tpu.serve.engine import (
+        bucket_rows,
+        held,
+        mutation_lock,
+        writer_lock,
+    )
 
     _require_mutable(index)
-    ids = np.asarray(ids, dtype=np.int32).reshape(-1)
-    if (ids < 0).any():
-        raise ValueError("upsert ids must be >= 0 (id -1 is the padding/"
-                         "tombstone sentinel)")
-    rows = _center_rows(index, rows)
-    if rows.shape[0] != ids.shape[0]:
-        raise ValueError(
-            f"{ids.shape[0]} ids but {rows.shape[0]} rows"
-        )
-    ids, rows = _dedupe_last(ids, rows)
-    n = int(ids.shape[0])
     cfg = config or index.cfg
-    bucket = bucket_rows(n, cfg.mutation_bucket)
     reg = obs_metrics.get_registry()
     t0 = time.perf_counter()
-    with obs_spans.span("upsert", cat="mutate", rows=n, bucket=bucket,
+    serial = index.backend == "serial"
+    with obs_spans.span("upsert", cat="mutate", rows=int(np.size(ids)),
                         backend=index.backend):
-        with mutation_lock(index):
-            fl = freelist_of(index)
+        with mutation_phase("parse"):
+            ids = np.asarray(ids, dtype=np.int32).reshape(-1)
+            if (ids < 0).any():
+                raise ValueError("upsert ids must be >= 0 (id -1 is the "
+                                 "padding/tombstone sentinel)")
+            rows = _center_rows(index, rows)
+            if rows.shape[0] != ids.shape[0]:
+                raise ValueError(
+                    f"{ids.shape[0]} ids but {rows.shape[0]} rows"
+                )
+            ids, rows = _dedupe_last(ids, rows)
+            n = int(ids.shape[0])
+            bucket = bucket_rows(n, cfg.mutation_bucket)
             rows_p = _pad_chunk(rows, bucket, 0.0)
-            if index.backend == "serial":
-                # dense layout: no clustering — the freelist's buckets
-                # are the corpus tiles, any free slot will do (lowest
-                # tile first, deterministic); ids already live update
-                # their own tile IN PLACE and consume no slot, so a
-                # zero-headroom index still absorbs pure updates
-                parts = _serial_pick_tiles(fl, ids)
-            else:
-                ex = get_mutation_executable(index, cfg, bucket, KIND_ASSIGN)
-                (rows_d,) = _put_chunk(index, rows_p)
-                parts = np.asarray(jax.device_get(
-                    ex(rows_d, index.centroids, index.centroid_sqs)
-                ))[:n]
-            part, slot, clear_p, clear_s, commit = plan_upsert(
-                fl, ids, parts
-            )
-            sentinel = fl.total if index.backend != "serial" else (
-                index.tiles.shape[0]
-            )
-            args = _put_chunk(
-                index,
-                rows_p,
-                _pad_chunk(ids, bucket, -1),
-                _pad_chunk(part, bucket, sentinel),
-                _pad_chunk(slot, bucket, 0),
-                _pad_chunk(clear_p, bucket, sentinel),
-                _pad_chunk(clear_s, bucket, 0),
+            # the corpus side of the one-pass rule stops holding with the
+            # first row that is no bf16 number; asked of the rows only
+            # while the index still holds the fact
+            breaks_onepass = (
+                serial and index.onepass is not None
+                and index.__dict__.get("_onepass_holds", True)
+                and not bf16_exact(rows)
             )
             ex = get_mutation_executable(index, cfg, bucket, KIND_UPSERT)
-            if index.backend == "serial":
-                tiles, tile_ids, tile_sqs = ex(
-                    *args, index.tiles, index.tile_ids, index.tile_sqs
+        with writer_lock(index):
+            with mutation_phase("plan"):
+                fl = freelist_of(index)
+                if serial:
+                    # dense layout: no clustering — the freelist's buckets
+                    # are the corpus tiles, any free slot will do (lowest
+                    # tile first, deterministic); ids already live update
+                    # their own tile IN PLACE and consume no slot, so a
+                    # zero-headroom index still absorbs pure updates
+                    parts = _serial_pick_tiles(fl, ids)
+                    sentinel = index.tiles.shape[0]
+                else:
+                    assign = get_mutation_executable(
+                        index, cfg, bucket, KIND_ASSIGN)
+                    parts = np.asarray(jax.device_get(assign(
+                        *_put_chunk(index, rows_p), index.centroids,
+                        index.centroid_sqs,
+                    )))[:n]
+                    sentinel = fl.total
+                part, slot, clear_p, clear_s, commit = plan_upsert(
+                    fl, ids, parts
                 )
-                index.tiles, index.tile_ids, index.tile_sqs = (
-                    tiles, tile_ids, tile_sqs
+            with mutation_phase("h2d"):
+                args = _put_chunk(
+                    index,
+                    rows_p,
+                    _pad_chunk(ids, bucket, -1),
+                    _pad_chunk(part, bucket, sentinel),
+                    _pad_chunk(slot, bucket, 0),
+                    _pad_chunk(clear_p, bucket, sentinel),
+                    _pad_chunk(clear_s, bucket, 0),
                 )
-                if index.onepass is not None and not bf16_exact(rows):
-                    # the corpus side of the one-pass rule no longer
-                    # holds: the same programs take their other branch
-                    index.onepass = jax.device_put(np.bool_(False))
-                    reg.gauge("serve_index_onepass").set(0.0)
-            else:
-                out = ex(*args, *_store_args(index))
-                _swap_store(index, *_normalize_store_out(index, out))
-            commit()
-        _stamp_gauges(index, reg)
-    reg.counter(
-        "mutation_upserts_total", help="rows upserted into live indices"
-    ).inc(n)
-    reg.histogram(
-        "mutation_chunk_rows",
-        help="rows per mutation chunk (upsert+delete)",
-        buckets=CHUNK_ROW_BUCKETS,
-    ).observe(n)
-    reg.histogram(
-        "mutation_latency_seconds",
-        help="wall time of one mutation call (plan + donated dispatch + "
-        "commit)",
-    ).observe(time.perf_counter() - t0)
-    return {"upserted": n, "bucket": bucket, **freelist_of(index).stats()}
+                off = (jax.device_put(np.bool_(False))
+                       if breaks_onepass else None)
+            with held(mutation_lock(index), "mutation"):
+                with mutation_phase("dispatch"):
+                    if serial:
+                        index.tiles, index.tile_ids, index.tile_sqs = ex(
+                            *args, index.tiles, index.tile_ids,
+                            index.tile_sqs
+                        )
+                        if off is not None:
+                            # the same programs take their other branch
+                            index.onepass = off
+                            index.__dict__["_onepass_holds"] = False
+                    else:
+                        out = ex(*args, *_store_args(index))
+                        _swap_store(index, *_normalize_store_out(index, out))
+                with mutation_phase("commit"):
+                    commit()
+            stats = fl.stats()
+        if breaks_onepass:
+            reg.gauge("serve_index_onepass").set(0.0)
+        _stamp_gauges(reg, stats)
+    _note_mutation(reg, "upserts", n, n, t0)
+    return {"upserted": n, "bucket": bucket, **stats}
 
 
 def _normalize_store_out(index, out):
@@ -604,30 +719,20 @@ def _serial_pick_tiles(fl, ids: np.ndarray) -> np.ndarray:
     Raises the shared overflow error when the new rows outnumber the
     free slots — the serial layout has no compactor; rebuild with more
     ``bucket_headroom``."""
-    parts = np.empty(len(ids), np.int32)
-    new_rows = []
-    for i, rid in enumerate(ids):
-        old = fl.pos.get(int(rid))
-        if old is not None:
-            parts[i] = old[0]
-        else:
-            new_rows.append(i)
-    avail = [(p, len(f)) for p, f in enumerate(fl.free)]
-    j = 0
-    for p, cnt in avail:
-        take = min(cnt, len(new_rows) - j)
-        for i in new_rows[j:j + take]:
-            parts[i] = p
-        j += take
-        if j == len(new_rows):
-            break
-    if j < len(new_rows):
-        raise BucketOverflowError(
-            f"serial tile stack is full ({fl.live} live rows, "
-            f"{len(new_rows) - j} new rows do not fit): rebuild the "
-            "index with a larger bucket_headroom (the dense layout has "
-            "no re-cluster pass)",
-        )
+    old = fl.lookup(ids)
+    parts = old // fl.cap
+    new = old < 0
+    n_new = int(new.sum())
+    if n_new:
+        room = np.cumsum(fl.free_count)  # one entry a tile
+        if n_new > room[-1]:
+            raise BucketOverflowError(
+                f"serial tile stack is full ({fl.live} live rows, "
+                f"{n_new - int(room[-1])} new rows do not fit): rebuild "
+                "the index with a larger bucket_headroom (the dense "
+                "layout has no re-cluster pass)",
+            )
+        parts[new] = np.searchsorted(room, np.arange(n_new), side="right")
     return parts
 
 
@@ -636,52 +741,50 @@ def delete_rows(index, ids, config: KNNConfig | None = None) -> dict:
     (``mask_tile`` guarantees they are never again returned), the
     freelist reclaims the slots for future upserts. Unknown ids are
     counted and skipped (idempotent). Returns a stats dict."""
-    from mpi_knn_tpu.serve.engine import bucket_rows, mutation_lock
+    from mpi_knn_tpu.serve.engine import (
+        bucket_rows,
+        held,
+        mutation_lock,
+        writer_lock,
+    )
 
     _require_mutable(index)
-    ids = np.asarray(ids, dtype=np.int32).reshape(-1)
-    ids, _ = _dedupe_last(ids, None)
-    n = int(ids.shape[0])
     cfg = config or index.cfg
-    bucket = bucket_rows(max(1, n), cfg.mutation_bucket)
     reg = obs_metrics.get_registry()
     t0 = time.perf_counter()
-    with obs_spans.span("delete", cat="mutate", rows=n, bucket=bucket,
+    serial = index.backend == "serial"
+    with obs_spans.span("delete", cat="mutate", rows=int(np.size(ids)),
                         backend=index.backend):
-        with mutation_lock(index):
-            fl = freelist_of(index)
-            part, slot, commit, missing = plan_delete(fl, ids)
-            sentinel = fl.total
-            args = _put_chunk(
-                index,
-                _pad_chunk(part, bucket, sentinel),
-                _pad_chunk(slot, bucket, 0),
-            )
+        with mutation_phase("parse"):
+            ids = np.asarray(ids, dtype=np.int32).reshape(-1)
+            ids, _ = _dedupe_last(ids, None)
+            n = int(ids.shape[0])
+            bucket = bucket_rows(max(1, n), cfg.mutation_bucket)
             ex = get_mutation_executable(index, cfg, bucket, KIND_DELETE)
-            if index.backend == "serial":
-                index.tile_ids = ex(*args, index.tile_ids)
-            else:
-                index.bucket_ids = ex(*args, index.bucket_ids)
-            commit()
-        _stamp_gauges(index, reg)
+        with writer_lock(index):
+            with mutation_phase("plan"):
+                fl = freelist_of(index)
+                part, slot, commit, missing = plan_delete(fl, ids)
+            with mutation_phase("h2d"):
+                args = _put_chunk(
+                    index,
+                    _pad_chunk(part, bucket, fl.total),
+                    _pad_chunk(slot, bucket, 0),
+                )
+            with held(mutation_lock(index), "mutation"):
+                with mutation_phase("dispatch"):
+                    if serial:
+                        index.tile_ids = ex(*args, index.tile_ids)
+                    else:
+                        index.bucket_ids = ex(*args, index.bucket_ids)
+                with mutation_phase("commit"):
+                    commit()
+            stats = fl.stats()
+        _stamp_gauges(reg, stats)
     deleted = n - missing
-    reg.counter(
-        "mutation_deletes_total", help="rows tombstoned in live indices"
-    ).inc(deleted)
-    reg.histogram(
-        "mutation_chunk_rows",
-        help="rows per mutation chunk (upsert+delete)",
-        buckets=CHUNK_ROW_BUCKETS,
-    ).observe(n)
-    reg.histogram(
-        "mutation_latency_seconds",
-        help="wall time of one mutation call (plan + donated dispatch + "
-        "commit)",
-    ).observe(time.perf_counter() - t0)
-    return {
-        "deleted": deleted, "missing": missing, "bucket": bucket,
-        **freelist_of(index).stats(),
-    }
+    _note_mutation(reg, "deletes", deleted, n, t0)
+    return {"deleted": deleted, "missing": missing, "bucket": bucket,
+            **stats}
 
 
 # ---------------------------------------------------------------------------
@@ -700,7 +803,7 @@ def compact_index(index, config: KNNConfig | None = None,
     every compiled serve/mutation cell stays valid; a forced cap growth
     clears the in-memory cell cache (the documented recompile path).
     Returns the compaction stats."""
-    from mpi_knn_tpu.serve.engine import mutation_lock
+    from mpi_knn_tpu.serve.engine import mutation_lock, writer_lock
 
     _require_mutable(index)
     if index.backend == "serial":
@@ -743,7 +846,9 @@ def compact_index(index, config: KNNConfig | None = None,
         # layout, one donated scatter, atomic swap — all O(store) device
         # work at memory speed, no training, no compiles on the common
         # path (cap growth compiles in-lock: rare, documented)
-        with mutation_lock(index):
+        # (the writers' lock first: a write that has planned against the
+        # old store must not dispatch into the new one)
+        with writer_lock(index), mutation_lock(index):
             dst_part, dst_slot, new_cap, stats = plan_compact(
                 index, cfg, centroids, centroid_sqs, min_cap=min_cap
             )
@@ -780,7 +885,7 @@ def compact_index(index, config: KNNConfig | None = None,
                 index.__dict__.pop("_cache_key_locks", None)
             index.__dict__.pop("_freelist", None)  # re-derive from store
             maybe_beat("compact-swap")
-        _stamp_gauges(index, reg)
+        _stamp_gauges(reg, freelist_of(index).stats())
     wall = time.perf_counter() - t0
     reg.counter(
         "compactions_total", help="background/manual compaction passes run"

@@ -352,3 +352,101 @@ def test_flight_spans_join_request_to_batch_to_phases(tmp_path):
         assert mine, f"no {phase} span for batch {bseq}"
         assert all(s["parent"] == batch["span"] for s in mine)
     assert not named("pump", "idle")  # kept out of the flight record
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 34: a write's body in the form /query already takes
+
+
+@pytest.fixture(scope="module")
+def writable():
+    """(server, index): a server over an index built with headroom."""
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(512, DIM)).astype(np.float32)
+    index = build_index(X, KNNConfig(
+        k=4, backend="serial", query_bucket=64, corpus_tile=128,
+        query_tile=64, bucket_headroom=0.25, mutation_bucket=32,
+        exclude_zero=False))
+    fe = Frontend(ServeSession(index, resilience=ResiliencePolicy()),
+                  SLOPolicy(max_batch_rows=64, max_wait_s=0.002,
+                            max_queue_rows=8192)).start()
+    srv = FrontendHTTPServer(fe, port=0).start()
+    yield srv, index
+    srv.stop()
+    fe.stop()
+
+
+RAW = {"Content-Type": "application/octet-stream", "X-Tenant": "raw-writer"}
+JSON = {"Content-Type": "application/json", "X-Tenant": "json-writer"}
+
+
+def test_raw_write_bodies_equal_the_json_ones(writable):
+    """The same rows under two id ranges, one range written raw (int32 ids,
+    then float32 rows at the index width) and one as JSON: the same
+    acknowledgement, the same answers distance for distance; then both
+    ranges deleted, one raw and one as JSON, and neither comes back."""
+    srv, index = writable
+    rng = np.random.default_rng(9)
+    rows = (rng.normal(size=(8, DIM)) + 7.0).astype("<f4")
+    raw_ids = np.arange(5000, 5008, dtype="<i4")
+    json_ids = np.arange(6000, 6008)
+    s1, d1 = _post(srv.url, "/upsert",
+                   raw_ids.tobytes() + rows.tobytes(), RAW)
+    s2, d2 = _post(srv.url, "/upsert", json.dumps(
+        {"ids": json_ids.tolist(), "rows": rows.tolist()}).encode(), JSON)
+    assert s1 == s2 == 200 and d1["upserted"] == d2["upserted"] == 8
+    assert d2["live"] == d1["live"] + 8
+    _, doc = _post(srv.url, "/query", rows.tobytes(), RAW)
+    ids, dists = np.asarray(doc["ids"]), np.asarray(doc["dists"])
+    # each row's two nearest are its two copies, at the same distance
+    assert (np.sort(ids[:, :2], axis=1)
+            == np.stack([raw_ids, json_ids], axis=1)).all()
+    assert (dists[:, 0] == dists[:, 1]).all()
+    s1, d1 = _post(srv.url, "/delete", raw_ids.tobytes(), RAW)
+    s2, d2 = _post(srv.url, "/delete",
+                   json.dumps({"ids": json_ids.tolist()}).encode(), JSON)
+    assert s1 == s2 == 200 and d1["deleted"] == d2["deleted"] == 8
+    assert d1["missing"] == d2["missing"] == 0
+    _, doc = _post(srv.url, "/query", rows.tobytes(), RAW)
+    assert not (np.asarray(doc["ids"]) >= 5000).any()
+    samples = _scrape(srv.url)
+    for phase in ("parse", "plan", "h2d", "dispatch", "commit"):
+        assert samples[
+            f'mutation_phase_seconds_total{{phase="{phase}"}}'] > 0
+    assert samples['mutation_lock_waits_total{side="mutation"}'] >= 4
+    assert samples['mutation_lock_waits_total{side="batch"}'] >= 2
+
+
+@pytest.mark.parametrize("path,body,why", [
+    ("/upsert", b"\x00" * (4 + 4 * DIM + 3), "not a whole number"),
+    ("/upsert", np.arange(3, dtype="<i4").tobytes()
+     + np.zeros((3, DIM + 1), "<f4").tobytes(), "not a whole number"),
+    ("/upsert", np.array([-1], "<i4").tobytes()
+     + np.zeros((1, DIM), "<f4").tobytes(), "must be >= 0"),
+    ("/delete", b"\x00" * 6, "not a whole number"),
+    ("/upsert", b"", "empty"),
+])
+def test_raw_write_refusals_are_400(writable, path, body, why):
+    """Wrong length, wrong width (a body that is no whole number of id +
+    row records), id -1, an empty body: 400 with the reason, nothing
+    written."""
+    srv, index = writable
+    live = index.live_rows
+    with pytest.raises(urllib.error.HTTPError) as ei:
+        _post(srv.url, path, body, RAW)
+    assert ei.value.code == 400
+    assert why in json.loads(ei.value.read())["error"]
+    assert index.live_rows == live
+
+
+def test_one_parser_for_the_raw_form():
+    from mpi_knn_tpu.frontend.server import raw_rows
+
+    rows = np.arange(12, dtype="<f4").reshape(3, 4)
+    ids = np.array([7, 8, 9], "<i4")
+    got_ids, got = raw_rows(ids.tobytes() + rows.tobytes(), 4, ids=True)
+    assert (got_ids == ids).all() and (got == rows).all()
+    none, got = raw_rows(rows.tobytes(), 4, ids=False)
+    assert none is None and (got == rows).all()
+    with pytest.raises(ValueError):
+        raw_rows(rows.tobytes(), 4, ids=True)  # 48 bytes, 20 a record

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
 
 from mpi_knn_tpu.config import KNNConfig  # noqa: E402
 from mpi_knn_tpu.ivf import (  # noqa: E402
@@ -37,6 +38,8 @@ from mpi_knn_tpu.ivf.mutate import (  # noqa: E402
     BucketOverflowError,
     Freelist,
     freelist_of,
+    plan_delete,
+    plan_upsert,
     should_compact,
 )
 from mpi_knn_tpu.ivf.search import search_ivf  # noqa: E402
@@ -76,9 +79,9 @@ def test_freelist_derivation_and_determinism():
     ids[2, 0] = 99
     fl = Freelist(ids, 3)
     assert fl.live == 6
-    assert fl.pos[10] == (0, 0) and fl.pos[99] == (2, 0)
+    assert fl.where(10) == (0, 0) and fl.where(99) == (2, 0)
     # lowest free slot first, deterministically
-    assert fl.free[0][-1] == 5 and fl.free[1][-1] == 0
+    assert fl.lowest_free(0)[0] == 5 and fl.lowest_free(1)[0] == 0
     assert fl.max_fill == 5 / 8
     assert fl.tombstones == 0
 
@@ -219,7 +222,7 @@ def test_serial_inplace_update_needs_no_headroom(rng):
         backend="serial", query_bucket=16, corpus_tile=64,
         bucket_headroom=0.0, mutation_bucket=16, exclude_zero=False,
     ))
-    assert sum(len(f) for f in freelist_of(idx).free) == 0  # full stack
+    assert freelist_of(idx).free_count.sum() == 0  # full stack
     moved = (X[:4] + 0.5).astype(np.float32)
     st = sm.upsert_rows(idx, np.arange(4), moved)
     assert st["upserted"] == 4 and st["live"] == 64
@@ -233,7 +236,7 @@ def test_serial_overflow_is_loud(rng):
         backend="serial", query_bucket=16, corpus_tile=64,
         bucket_headroom=0.0, mutation_bucket=16,
     ))
-    free = sum(len(f) for f in freelist_of(idx).free)
+    free = int(freelist_of(idx).free_count.sum())
     with pytest.raises(BucketOverflowError, match="tile stack is full"):
         sm.upsert_rows(
             idx, np.arange(10**6, 10**6 + free + 1),
@@ -332,7 +335,7 @@ def test_post_churn_recall_matches_fresh_rebuild(rng):
     rid = np.arange(10000, 10128)
     sm.upsert_rows(idx, rid, repl)
     # the live set, as arrays (centered frame is handled by the index)
-    live_ids = np.array(sorted(freelist_of(idx).pos))
+    live_ids = freelist_of(idx).live_ids()
     rows_by_id = {int(i): X[i] for i in range(512) if i not in set(dead)}
     rows_by_id.update({int(i): r for i, r in zip(rid, repl)})
     live_rows = np.stack([rows_by_id[int(i)] for i in live_ids])
@@ -549,11 +552,11 @@ def test_mutated_index_roundtrips_bit_identically(rng, tmp_path):
     # the freelist re-derives: same occupancy, tombstoned slots free
     fa, fb = freelist_of(idx), freelist_of(back)
     assert fa.live == fb.live
-    assert [sorted(f) for f in fa.free] == [sorted(f) for f in fb.free]
+    assert (fa.used == fb.used).all()
     # and the reloaded index keeps mutating
     sm.upsert_rows(back, [5], rng.standard_normal((1, 16))
                    .astype(np.float32))
-    assert back.live_rows == fa.live + (0 if 5 in fa.pos else 1)
+    assert back.live_rows == fa.live + (0 if fa.where(5) is not None else 1)
 
 
 def test_legacy_pre_mutation_artifact_loads_with_headroom(rng, tmp_path):
@@ -584,7 +587,7 @@ def test_legacy_pre_mutation_artifact_loads_with_headroom(rng, tmp_path):
     back = load_ivf_index(legacy)
     fl = freelist_of(back)
     assert fl.live == 256
-    assert sum(len(f) for f in fl.free) == \
+    assert fl.free_count.sum() == \
         back.partitions * back.bucket_cap - 256
     sm.upsert_rows(back, [7777], rng.standard_normal((1, 16))
                    .astype(np.float32))
@@ -712,3 +715,220 @@ def test_mutation_interleaves_with_dispatch_depth(rng):
     assert len(done) == 6
     for res in done:
         assert np.isfinite(res.dists).all()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 34: the host mirror in array form, the tile-by-tile upsert, what a
+# write holds the lock for
+
+
+def _random_plane(rs, parts, cap, fill):
+    ids = np.full((parts, cap), -1, np.int32)
+    used = rs.random((parts, cap)) < fill
+    ids[used] = rs.permutation(4 * parts * cap)[: int(used.sum())]
+    return ids
+
+
+@pytest.mark.parametrize("seed,parts,cap,fill", [
+    (0, 4, 16, 0.5), (1, 9, 32, 0.8), (2, 3, 64, 0.2), (3, 16, 8, 0.6),
+    (4, 1, 128, 0.9),
+])
+def test_array_mirror_chooses_the_old_freelist_s_slots(seed, parts, cap,
+                                                       fill):
+    """Random insert / update / move / delete chunks against the dict
+    freelist of before ISSUE 34 (``tests/freelist_oracle.py``): the same
+    scatter vectors chunk for chunk, the same position for every live id
+    and the same free slots in every bucket after each commit — the replay
+    contract (lowest bucket's lowest free slot first)."""
+    from tests import freelist_oracle as oracle
+
+    rs = np.random.default_rng(seed)
+    plane = _random_plane(rs, parts, cap, fill)
+    new, old = Freelist(plane, parts), oracle.DictFreelist(plane, parts)
+    for step in range(40):
+        live = np.array(sorted(old.pos), dtype=np.int64)
+        if step % 3 == 2 and live.size:
+            ids = np.unique(np.concatenate([
+                rs.choice(live, size=min(live.size, 5), replace=False),
+                rs.integers(10**6, 10**6 + 50, size=2)]))  # two unknown
+            got, want = plan_delete(new, ids), oracle.plan_delete(old, ids)
+            for g, w in zip(got[:2], want[:2]):
+                assert (g == w).all()
+            assert got[3] == want[3]
+            got[2](), want[2]()
+        else:
+            fresh = rs.integers(0, 8 * parts * cap, size=rs.integers(1, 9))
+            again = (rs.choice(live, size=min(live.size, 4), replace=False)
+                     if live.size else live)
+            ids = np.unique(np.concatenate([fresh, again]))
+            to = rs.integers(0, parts, size=ids.size)
+            try:
+                want = oracle.plan_upsert(old, ids, to)
+            except OverflowError:
+                with pytest.raises(BucketOverflowError):
+                    plan_upsert(new, ids, to)
+                continue
+            got = plan_upsert(new, ids, to)
+            for g, w in zip(got[:4], want[:4]):
+                assert (g == w).all(), step
+            got[4](), want[4]()
+        assert new.live == old.live
+        live = np.array(sorted(old.pos), dtype=np.int64)
+        assert (new.live_ids() == live).all()
+        at = new.lookup(live)
+        assert [divmod(int(f), cap) for f in at] == [old.pos[int(i)]
+                                                     for i in live]
+        for p in range(parts):
+            assert new.lowest_free(p, cap).tolist() == sorted(old.free[p])
+        assert new.stats()["free_slots"] == sum(map(len, old.free))
+        assert new.max_fill == old.max_fill
+
+
+def test_mirror_for_two_million_ids_is_arrays_and_takes_under_a_second():
+    """The build-time bound: 2 M ids in under a second, and nothing a row
+    on the heap (a dict entry and a tuple a row were ~200 bytes each)."""
+    import time
+
+    plane = np.arange(2_097_152, dtype=np.int32).reshape(256, 8192)
+    plane[-6:] = -1  # headroom
+    took = []
+    for _ in range(3):  # (this sandbox pays seconds for memory's first touch)
+        t = time.perf_counter()
+        fl = Freelist(plane, 256)
+        took.append(time.perf_counter() - t)
+    assert min(took) < 1.0, took
+    assert fl.live == 250 * 8192 and not fl._far
+    assert fl.nbytes < 8 * plane.size  # ~5 bytes a slot
+    t = time.perf_counter()
+    part, slot, *_, commit = plan_upsert(
+        fl, np.arange(3_000_000, 3_001_024), np.full(1024, 250))
+    commit()
+    assert time.perf_counter() - t < 0.1
+    assert (part == 250).all() and (slot == np.arange(1024)).all()
+
+
+def test_ids_past_the_table_keep_working():
+    """Ids hashed over all of int32 (beyond ``dense_limit``) live in the
+    mirror's dict, beside the table."""
+    plane = np.full((2, 8), -1, np.int32)
+    plane[0, :3] = [5, 2**31 - 7, 9]
+    fl = Freelist(plane, 2)
+    assert fl.where(2**31 - 7) == (0, 1) and fl.live == 3
+    far = np.array([2**31 - 9, 2**30], dtype=np.int64)
+    *_, commit = plan_upsert(fl, far, np.array([1, 1]))
+    commit()
+    assert fl.where(2**31 - 9) == (1, 0) and fl.where(2**30) == (1, 1)
+    assert fl.live_ids().tolist() == [5, 9, 2**30, 2**31 - 9, 2**31 - 7]
+    part, slot, commit, missing = plan_delete(
+        fl, np.array([2**31 - 7, 2**31 - 9, 4], dtype=np.int64))
+    commit()
+    assert missing == 1 and fl.live == 3
+    assert fl.where(2**31 - 7) is None and fl.lowest_free(0)[0] == 1
+
+
+@pytest.mark.parametrize("dim,c_tile,chunk", [(100, 64, 32), (16, 128, 8)])
+def test_upsert_tile_by_tile_equals_the_one_scatter(rng, dim, c_tile, chunk):
+    """``by_tile`` (the form for a stack the device keeps rows-minor: a TPU
+    at a width off its lane grid) writes what the one scatter writes, slot
+    for slot, padding rows dropped, several tiles touched in one chunk."""
+    tiles = rng.standard_normal((6, c_tile, dim)).astype(np.float32)
+    ids = np.arange(6 * c_tile, dtype=np.int32).reshape(6, c_tile)
+    sqs = (tiles ** 2).sum(-1)
+    rows = rng.standard_normal((chunk, dim)).astype(np.float32)
+    n = chunk - 3  # three padding rows
+    flat = rng.choice(6 * c_tile, size=n, replace=False)
+    tpos = np.concatenate([flat // c_tile, np.full(3, 6)]).astype(np.int32)
+    spos = np.concatenate([flat % c_tile, np.zeros(3)]).astype(np.int32)
+    new_ids = np.arange(10_000, 10_000 + chunk, dtype=np.int32)
+    drop = np.full(chunk, 6, np.int32)
+    zero = np.zeros(chunk, np.int32)
+    cfg = KNNConfig(k=5, backend="serial")
+    out = [sm.serial_upsert_jit(
+        rows, new_ids, tpos, spos, drop, zero, jnp.asarray(tiles),
+        jnp.asarray(ids), jnp.asarray(sqs), cfg=cfg, by_tile=by_tile)
+        for by_tile in (False, True)]
+    for a, b in zip(*out):
+        assert (np.asarray(a) == np.asarray(b)).all()
+    assert (np.asarray(out[1][0])[tpos[:n], spos[:n]] == rows[:n]).all()
+    assert len(set(tpos[:n].tolist())) > 1
+
+
+def test_a_batch_sees_the_store_wholly_before_or_after_a_write(rng):
+    """A batch dispatched while a write holds the mutation lock waits for
+    it (``mutation_lock_wait_seconds_total{side="batch"}`` moves) and
+    answers from the store as the write left it: rows, ids and norms
+    together."""
+    import threading
+
+    from mpi_knn_tpu.obs.metrics import get_registry
+    from mpi_knn_tpu.serve.engine import mutation_lock
+
+    X = (rng.standard_normal((512, 100)) * 0.3).astype(np.float32)
+    idx = build_index(X, KNNConfig(
+        k=3, backend="serial", corpus_tile=128, query_bucket=32,
+        mutation_bucket=32, bucket_headroom=0.25, exclude_zero=False))
+    ses = ServeSession(idx)
+    ses.warm([32])
+    sm.warm_mutation(idx)
+    assert "_freelist" in idx.__dict__  # made in set-up, not by a write
+    new = (rng.standard_normal((32, 100)) * 0.3).astype(np.float32) + 4.0
+    new_ids = np.arange(9000, 9032)
+    query_knn(new, idx, ses.cfg)  # the read path once, before the clock
+    entered, release = threading.Event(), threading.Event()
+    real = sm.mutation_phase
+
+    def slow_commit(name, **attrs):
+        if name == "commit":  # under the lock, after the dispatch
+            entered.set()
+            assert release.wait(30)
+        return real(name, **attrs)
+
+    def waited():
+        return get_registry().snapshot()["metrics"].get(
+            'mutation_lock_wait_seconds_total{side="batch"}',
+            {}).get("value", 0.0)
+
+    sm.mutation_phase = slow_commit
+    try:
+        writer = threading.Thread(
+            target=lambda: sm.upsert_rows(idx, new_ids, new))
+        writer.start()
+        assert entered.wait(30) and mutation_lock(idx).locked()
+        before = waited()
+        got = []
+        reader = threading.Thread(
+            target=lambda: got.append(query_knn(new, idx, ses.cfg)))
+        reader.start()
+        reader.join(0.5)
+        assert reader.is_alive()  # the batch waits for the write
+        release.set()
+        writer.join(30), reader.join(30)
+    finally:
+        sm.mutation_phase = real
+        release.set()
+    d, i = np.asarray(got[0].dists), np.asarray(got[0].ids)
+    assert (i[:, 0] == new_ids).all()  # wholly after: ids, rows and norms
+    assert np.abs(d[:, 0]).max() < 0.05  # (the matmul form near |x|^2 = 1600)
+    assert waited() - before > 0.2
+
+
+@pytest.mark.parametrize("m,c_tile,headroom", [(1000, 128, 0.1),
+                                               (1024, 128, 0.25),
+                                               (300, 128, 0.0),
+                                               # under one tile: no whole
+                                               # tile to slice out
+                                               (100, 128, 0.0)])
+def test_stack_with_headroom_is_built_tile_by_tile(rng, m, c_tile, headroom):
+    """A device corpus that needs padding goes into its stack by one
+    program, tile by tile (no padded copy beside the stack): the same
+    rows, ids and norms as the host build's pad + reshape."""
+    X = rng.standard_normal((m, 24)).astype(np.float32)
+    cfg = KNNConfig(k=3, backend="serial", corpus_tile=c_tile,
+                    query_bucket=32, bucket_headroom=headroom)
+    host, dev = build_index(X, cfg), build_index(jnp.asarray(X), cfg)
+    assert dev.tiles.shape == host.tiles.shape
+    assert dev.tiles.shape[0] * c_tile >= int(np.ceil(m * (1 + headroom)))
+    assert np.allclose(np.asarray(dev.tiles), np.asarray(host.tiles),
+                       atol=1e-6)
+    assert (np.asarray(dev.tile_ids) == np.asarray(host.tile_ids)).all()
+    assert (np.asarray(dev.tiles).reshape(-1, 24)[m:] == 0).all()

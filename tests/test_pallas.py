@@ -427,6 +427,9 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     # serve-bigann10m-bulk: one 1024-row bucket over the resident stack
     # (0.03 GiB; 1.2 with the survivor stack)
     ("serve-bigann10m-bulk", 1024, 1221, 128, "highest", 0.1),
+    # stream-msturing10m-runbook (ISSUE 34): the same bucket over 1224
+    # tiles (24 of them headroom) of a width off the lane grid, d = 100
+    ("stream-msturing10m-runbook", 1024, 1224, 100, "highest", 0.1),
 ])
 def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
         v5e_devices, monkeypatch, cell, q, tiles, dim, precision, temp_gib):
@@ -609,3 +612,87 @@ def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(
     permutes = [ln for ln in ruled.as_text().splitlines()
                 if " collective-permute-start(" in ln and "[128,8192,784]" in ln]
     assert permutes and all("f32[128,8192,784]" in ln for ln in permutes)
+
+
+# ---------------------------------------------------------------------------
+# the mutation scatters at the streaming cell's size, compiled for the v5e
+# (ISSUE 34): the stack is aliased, not copied
+
+
+@pytest.mark.parametrize("kind", ["upsert", "delete"])
+def test_mutation_scatter_aliases_the_stack_on_the_v5e(v5e_devices, kind):
+    """``stream-msturing10m-runbook``: a 1024-row chunk into 1224 tiles of
+    8192 x 100 float32 (4.78 GiB as the chip lays it out, 128 lanes a
+    row). Every donated store array comes back as the same buffer
+    (``input_output_alias``), the program's temporaries are the chunk's
+    and one tile's (no corpus-sized one), and its scatters carry
+    ``knn.mutate/<kind>``. The device keeps this stack rows-minor
+    (``{1,0,2}``: no lane padding, 4.01 GB), where the one-scatter form
+    compiles to a copy of the stack out and back (5.13 GB of temporaries):
+    the upsert goes tile by tile (``mutate.scatter_rows_by_tile``)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.serve import mutate
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    tiles, c_tile, dim, chunk = 1224, 8192, 100, 1024
+    cfg = KNNConfig(k=10, backend="serial", corpus_tile=c_tile)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    ids_plane = arg((tiles, c_tile), jnp.int32)
+    vec = arg((chunk,), jnp.int32)
+    with jax.enable_x64(False):
+        if kind == "upsert":
+            program = mutate.serial_upsert_jit.lower(
+                arg((chunk, dim), jnp.float32), vec, vec, vec, vec, vec,
+                arg((tiles, c_tile, dim), jnp.float32), ids_plane,
+                arg((tiles, c_tile), jnp.float32), cfg=cfg, by_tile=True,
+            ).compile()
+            donated = 3
+        else:
+            program = mutate.serial_delete_jit.lower(
+                vec, vec, ids_plane).compile()
+            donated = 1
+    hlo = program.as_text()
+    alias = re.search(r"input_output_alias=\{([^\n]*?)\}, entry", hlo)
+    assert alias and alias.group(1).count("may-alias") + alias.group(
+        1).count("must-alias") == donated, hlo[:400]
+    mem = program.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= (
+        tiles * c_tile * 4 * (1 + (dim + 1) * (kind == "upsert")))
+    if kind == "upsert":
+        assert "f32[1224,8192,100]{1,0,2:" in hlo  # the rest layout
+    assert f"knn.mutate/{kind}" in hlo
+    # no copy of a store-sized array anywhere in the program
+    assert not re.search(rf"= \w+\[{tiles},{c_tile}[\],][^\n]* copy\(", hlo)
+
+
+def test_stack_with_headroom_builds_on_the_v5e_without_a_second_copy(
+        v5e_devices):
+    """``stream-msturing10m-runbook``'s build: 9 830 400 x 100 rows into
+    1224 tiles (24 of headroom). The device keeps the two shapes in
+    different layouts (``{0,1}`` and ``{1,0,2}``), so a pad + reshape is a
+    padded copy AND its re-layout (8.3 GB of temporaries; done eagerly the
+    build held four corpus-sized arrays and died 3.4 GB short); tile by
+    tile the program's temporaries are one tile's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu.serve import index as serve_index
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    program = serve_index._pad_and_tile.lower(
+        jax.ShapeDtypeStruct((9_830_400, 100), jnp.float32, sharding=one),
+        c_pad=10_027_008, c_tile=8192, dtype=jnp.dtype("float32")).compile()
+    mem = program.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes == 1224 * 8192 * 100 * 4  # no lane pad
