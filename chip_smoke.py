@@ -402,12 +402,15 @@ def compute_phases(args, platform, out, record) -> None:
     planted = X.astype(np.float32).copy()
     planted[at] = RQ[0]
     planted[at, np.arange(n_plant)] += 16.0 * (1 + np.arange(n_plant))
-    counts, close = [], []
+    counts, close, chunks = [], [], []
     for corpus in (X.astype(np.float32), planted):
         got = all_knn(jax.device_put(jnp.asarray(corpus)), queries=RQ,
                       config=rcfg)
         counts.append(None if got.select_tiles is None
                       else np.asarray(got.select_tiles).tolist())
+        # what *bins* did with the batch's chunks under the row bound
+        chunks.append(None if got.bins_chunks is None
+                      else np.asarray(got.bins_chunks).tolist())
         c64, q64 = corpus.astype(np.float64), RQ[:64].astype(np.float64)
         direct = ((q64 ** 2).sum(1)[:, None] + (c64 ** 2).sum(1)[None, :]
                   - 2.0 * q64 @ c64.T)
@@ -417,14 +420,25 @@ def compute_phases(args, platform, out, record) -> None:
             rtol=1e-5, atol=2.0)))
     # the planted rows, nearest first: more of one lane than the lists hold
     found = np.asarray(got.ids)[0, :min(n_plant, K)].tolist()
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_chunks
+    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides
+
+    # every chunk inserted or skipped where the bound rides (the full-size
+    # run's 1024 x 8192 tiles), no count where it does not (--tiny)
+    n_chunks = n_tiles * lane_bin_chunks(q_tile, c_tile)
+    rides = lane_bin_bound_rides(q_tile, c_tile)
     record(
         "rescan",
         depth is not None and counts == [[1, 0], [0, 1]] and all(close)
-        and found == at[:K].tolist(),
+        and found == at[:K].tolist()
+        and all((sum(c) == n_chunks) if rides else c is None
+                for c in chunks),
         t0,
         f"tile={q_tile}x{c_tile} depth={depth} planted={n_plant} rows of "
         f"lane {lane} over {min(n_plant, n_tiles)} tiles "
         f"select_tiles_plain_planted={counts} "
+        f"bound_rides={rides} bins_chunks_inserted_skipped={chunks[0]} "
+        f"bins_skipped_share={(chunks[0] or [0, 0])[1] / n_chunks:.4f} "
         f"planted_rows_found_in_order={found == at[:K].tolist()} "
         f"dists_close_to_float64_plain_planted={close}",
     )

@@ -85,7 +85,7 @@ from mpi_knn_tpu.ops.quant import (
     quantize_rows,
     row_wire_bytes,
 )
-from mpi_knn_tpu.ops.topk import init_topk
+from mpi_knn_tpu.ops.topk import init_topk, lane_bin_bound_rides
 from mpi_knn_tpu.backends.serial import (
     PreparedCorpus,
     cap_corpus_tile,
@@ -347,10 +347,12 @@ def _ring_knn_local(
     @jax.named_scope(ROUND_SCOPE)
     def compute(blk, blk_ids, blk_scl, cd, ci):
         """Tiled (q_local × b) step: all query tiles against all block
-        tiles. Returns the carry and, third, one verdict a query tile where
-        the round's scans carried the lane-bin lists (``backends.serial
-        merge_tiles_into_carry``: flagged rows were answered again), else
-        None."""
+        tiles. Returns the carry and, third, what the round's scans counted
+        where they carried the lane-bin lists (``backends.serial
+        merge_tiles_into_carry``), a pair: one verdict a query tile
+        (flagged rows were answered again) and one ``[inserted, skipped]``
+        a query tile (the chunks of its distance tiles in *bins*); else
+        (None, None)."""
         if fused:
             # the fused Pallas kernel replaces the whole per-round merge —
             # dequant/upcast, masked tile distances and the carry top-k all
@@ -369,7 +371,7 @@ def _ring_knn_local(
                 q_tile=q_tile,
                 c_tile=c_tile,
             )
-            return fd.reshape(cd.shape), fi.reshape(ci.shape), None
+            return fd.reshape(cd.shape), fi.reshape(ci.shape), (None, None)
         if blk_scl is not None:
             # the int8 dequant: ONE convert out of the code domain and ONE
             # multiply by the block's scale vector, feeding every distance
@@ -394,13 +396,15 @@ def _ring_knn_local(
                 cd0, ci0, cfg, one,
             )
 
-        return jax.lax.map(
+        cd, ci, *counted = jax.lax.map(
             per_query_tile, (q_tiles, qid_tiles, cd, ci, q_one))
+        return cd, ci, counted
 
     def step(state, _):
-        # ``rescanned``: the whole rotation's count of re-scanned merges,
-        # where it is kept (below), else nothing
-        blk, scl, blk_ids, cd, ci, *rescanned = state
+        # ``tally``: the whole rotation's count of re-scanned merges and
+        # its ``[inserted, skipped]`` chunks, where they are kept (below),
+        # else nothing
+        blk, scl, blk_ids, cd, ci, *tally = state
         if fused_dma:
             # collective-matmul round: ONE kernel issues the async remote
             # copies of the resident block and runs the distance sweep —
@@ -430,7 +434,7 @@ def _ring_knn_local(
             nxt = _rot(blk, perm)
             nscl = _rot(scl, perm)
             nxt_ids = _rot(blk_ids, perm)
-            cd, ci, again = compute(blk, blk_ids, scl, cd, ci)
+            cd, ci, counted = compute(blk, blk_ids, scl, cd, ci)
         else:
             # blocking parity: the collective is sequenced *after* the compute
             # via an explicit barrier, modelling the reference's
@@ -442,7 +446,7 @@ def _ring_knn_local(
             # which found exactly that bug in the pre-r5 code). On a
             # multi-axis mesh this threading is type-impossible (the raise
             # above), so reaching here means the 1-D ring.
-            cd, ci, again = compute(blk, blk_ids, scl, cd, ci)
+            cd, ci, counted = compute(blk, blk_ids, scl, cd, ci)
             blk, scl, blk_ids, cd, ci = jax.lax.optimization_barrier(
                 (blk, scl, blk_ids, cd, ci)
             )
@@ -450,12 +454,12 @@ def _ring_knn_local(
             nscl = _rot(scl, perm)
             nxt_ids = _rot(blk_ids, perm)
         return (nxt, nscl, nxt_ids, cd, ci,
-                *(n + jnp.sum(again, dtype=jnp.int32) for n in rescanned)
-                ), None
+                *(n + jnp.sum(new, axis=0, dtype=jnp.int32)
+                  for n, new in zip(tally, counted))), None
 
     rounds, bwd_limit = bidir_rounds(num_dev)
 
-    def finish(cd, ci, rescanned=None):
+    def finish(cd, ci, rescanned=None, chunks=None):
         out = cd.reshape(q_local, cfg.k), ci.reshape(q_local, cfg.k)
         if q_one is None:
             return out
@@ -466,6 +470,7 @@ def _ring_knn_local(
             dist_steps(q_one, num_dev * (b // c_tile)).reshape(1, 2),
             None if rescanned is None else jnp.stack(
                 [merges - rescanned, rescanned]).reshape(1, 2),
+            None if chunks is None else chunks.reshape(1, 2),
         )
 
     def bidir_step(state, r):
@@ -627,17 +632,22 @@ def _ring_knn_local(
     # correct rotation the reference missed (SURVEY.md Q1). The final
     # permute's output is unused; XLA dead-code-eliminates it.
     # where the device's counts go out (``onepass``) and the rounds' scans
-    # carry the lists, the rotation counts its re-scanned merges
-    rescanned = ()
+    # carry the lists, the rotation counts its re-scanned merges and, where
+    # the row bound rides them too, the chunks its *bins* inserted and
+    # skipped
+    tally = ()
     if q_one is not None and not fused and carried_depth(
             cfg, q_tile, c_tile, varying=True) is not None:
-        rescanned = (jax.lax.pcast(
-            jnp.int32(0), tuple(vary_axes) or (axis,), to="varying"),)
-    (_, _, _, carry_d, carry_i, *rescanned), _ = jax.lax.scan(
-        step, (block, block_scale, block_ids, carry_d, carry_i, *rescanned),
+        tally = tuple(
+            jax.lax.pcast(jnp.zeros(shape, jnp.int32),
+                          tuple(vary_axes) or (axis,), to="varying")
+            for shape in ((), (2,))[:1 + lane_bin_bound_rides(
+                q_tile, c_tile, jnp.dtype(carry_d.dtype).itemsize)])
+    (_, _, _, carry_d, carry_i, *tally), _ = jax.lax.scan(
+        step, (block, block_scale, block_ids, carry_d, carry_i, *tally),
         None, length=num_dev
     )
-    return finish(carry_d, carry_i, *rescanned)
+    return finish(carry_d, carry_i, *tally)
 
 
 def parse_ring_mesh(mesh: Mesh):

@@ -49,6 +49,7 @@ from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
     init_topk,
     init_topk_tiles,
+    lane_bin_bound_rides,
     lane_bin_depth,
     mask_tile,
     merge_topk,
@@ -110,6 +111,10 @@ class TileCounts(typing.NamedTuple):
     dist_steps: jax.Array | None = None
     # :func:`select_tiles`, from a program whose scans carry the lists
     select_tiles: jax.Array | None = None
+    # int32 ``[inserted, skipped]``: the chunks of the distance tiles (16
+    # rows x 1024 columns) by what became of them in *bins* under the row
+    # bound (:func:`_merge_carried`), from a program whose scans carry one
+    bins_chunks: jax.Array | None = None
 
 
 def select_tiles(rescanned: jax.Array):
@@ -276,17 +281,43 @@ _select_tile_once = jax.jit(
 
 
 @jax.named_scope("knn.select")
-def _insert_tile(lists_d, lists_i, d, blk_ids, depth):
+def _insert_tile(lists_d, lists_i, bound, d, blk_ids, depth):
     # imported where a program first carries the lists, as ``ops/topk.py``
     # imports it where one first selects from a wide tile (pallas: ~0.8 s)
     from mpi_knn_tpu.ops.lane_bin import lane_bin_insert
 
-    return lane_bin_insert((lists_d, lists_i), d, blk_ids, depth)
+    return lane_bin_insert((lists_d, lists_i), d, blk_ids, depth, bound)
 
 
 # as :data:`_select_tile_once`: one trace of the bins kernel for the two
 # branches of the one-pass rule
 _insert_tile_once = jax.jit(_insert_tile, static_argnames=("depth",))
+
+
+# the most tile steps between two refreshes of the row bound
+_REFRESH_EVERY = 16
+
+
+def bound_refreshes(n_tiles: int) -> np.ndarray:
+    """(n_tiles,) bool: the tile steps of a carried scan that take the row
+    bound anew from the lists before they insert (``ops/lane_bin.py
+    lane_bin_bound``). After t tiles of rows in any exchangeable order a
+    new value stays under a bound taken at tile t' with probability
+    k / (width · t'), so what a stale bound lets through grows with t / t'
+    and not with t - t': the steps lie a quarter of their index apart —
+    and never more than 16 tiles, because a corpus ordered by cluster is not
+    exchangeable: when the first tile of a cluster nearer than any before
+    comes, every chunk of it passes until the next refresh (the streaming
+    cell, on a seed that brings a query's own cluster late: 4780 rows/s
+    without the cap, 5172 with it; a refresh is 15 us of a 1024-row scan's
+    55 us steps, 92 a 1221-tile scan: 2 % of it, 1.3 % of the bulk cell's
+    rate: PERF.md §6, PR 35)."""
+    due = np.zeros(n_tiles, dtype=bool)
+    t = 1
+    while t < n_tiles:
+        due[t] = True
+        t += max(1, min(t // 4, _REFRESH_EVERY))
+    return due
 
 
 def knn_tile_step(
@@ -400,8 +431,10 @@ def serve_chunk(
     :class:`TileCounts`: the tile steps by the branch they took (the
     one-pass rule's: :func:`dist_steps`) and the query tiles by what became
     of the selection their scans carried (:func:`select_tiles`: every
-    program :func:`carried_depth` engages). A program that counts neither
-    returns two, as it always did.
+    program :func:`carried_depth` engages) and, where a row bound rides
+    those scans, the chunks *bins* inserted and skipped under it
+    (``bins_chunks``). A program that counts none of them returns two, as
+    it always did.
 
     Cosine: the query side's unit rows are made HERE, once a query tile
     and ahead of its scan (scope ``knn.qunit``), where L2 norms its query
@@ -424,11 +457,12 @@ def serve_chunk(
             q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one
         ), one
 
-    best_d, best_i, rescanned, took = jax.lax.map(
+    best_d, best_i, rescanned, chunks, took = jax.lax.map(
         per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
     counts = TileCounts(
         None if took is None else dist_steps(took, tiles.shape[0]),
         None if rescanned is None else select_tiles(rescanned),
+        None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
     )
     if counts == TileCounts():
         return best_d, best_i
@@ -451,10 +485,12 @@ def merge_tiles_into_carry(
     ``cfg.merge_schedule``. The single implementation behind the serial
     chunk scan and the ring backends' per-round block loop (the schedules
     must match or the ring's per-round cost diverges from serial's).
-    Returns ``(dists, ids, rescanned)``: the merged carry and, from an
-    engaged ``twolevel`` program, a bool scalar — some row failed the
-    selection's certificate and the flagged rows were answered again — else
-    None.
+    Returns ``(dists, ids, rescanned, chunks)``: the merged carry and,
+    from an engaged ``twolevel`` program, a bool scalar — some row failed
+    the selection's certificate and the flagged rows were answered again —
+    and, where the row bound rides the scan too, int32 ``[inserted,
+    skipped]``, the chunks of the distance tiles by what became of them in
+    *bins*; else None.
 
     - "twolevel", where the lane-bin rule engages for the stack's tiles
       (:func:`carried_depth`: the exact policy and method, k <= 128, tiles
@@ -467,7 +503,11 @@ def merge_tiles_into_carry(
       2k-column exact merge joins the survivors to the incoming carry
       (:func:`_merge_carried`). No per-tile top-k, no (T, q, k) stack of
       survivors and no cascade exist in such a program; its third output
-      says whether the query tile was re-scanned.
+      says whether the query tile was re-scanned. Where the tile's shape
+      allows (``ops/topk.py lane_bin_bound_rides``: 256 to 2048 rows at
+      8192 columns) a row bound rides the scan beside the lists and *bins*
+      inserts only the chunks that hold a value at or under it: the same
+      answer, bit for bit, and a fourth output that counts the chunks.
     - "twolevel" elsewhere (k > 128, ``mixed``, another ``topk_method``,
       narrow tiles, the ring's interpreted form off the TPU): level 1 —
       independent local top-k per corpus tile (no carry dependence between
@@ -560,7 +600,7 @@ def merge_tiles_into_carry(
                     else "exact"
                 ),
                 block=cfg.topk_block,
-            ), None
+            ), None, None
 
     def step(carry, tile):
         return (
@@ -574,7 +614,7 @@ def merge_tiles_into_carry(
         )
 
     out, _ = jax.lax.scan(step, (carry_d, carry_i), stack)
-    return *out, None
+    return *out, None, None
 
 
 def _varying_like(x: jax.Array, *operands):
@@ -593,10 +633,24 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     conditional, which takes and returns the lists — and after it ONE
     *finish* gives the stack's k survivors and the certificate, and the
     narrow exact merge joins them to the incoming carry. ``nested``: the
-    step is traced twice, so *bins* goes through its nested jit."""
-    from mpi_knn_tpu.ops.lane_bin import lane_bin_lists, lane_bin_result
+    step is traced twice, so *bins* goes through its nested jit.
+
+    Where ``lane_bin_bound_rides`` the scan also carries a ROW BOUND, an
+    upper bound on every row's final k-th smallest value of THIS stack,
+    taken from the lists themselves (``lane_bin_bound``: the finish kernel
+    over their first column group, under a ``lax.cond`` at
+    :func:`bound_refreshes`' steps; it only falls), and the count of the
+    chunks *bins* inserted under it."""
+    from mpi_knn_tpu.ops.lane_bin import (
+        lane_bin_bound,
+        lane_bin_chunks,
+        lane_bin_lists,
+        lane_bin_no_bound,
+        lane_bin_result,
+    )
 
     q_rows, k = carry_d.shape
+    n_tiles, c_tile = stack[0].shape[:2]
     insert = _insert_tile_once if nested else _insert_tile
 
     def dist_tile(blk, blk_ids, blk_sq, one, scoped=True):
@@ -605,20 +659,51 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
             q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, one,
         ).astype(carry_d.dtype)
 
-    def step(lists, tile):
-        return either(
-            lambda blk, blk_ids, blk_sq, ld, li, one: insert(
-                ld, li, dist_tile(blk, blk_ids, blk_sq, one), blk_ids,
-                depth=depth),
-            *tile, *lists,
-        ), None
+    def varying(x):
+        return _varying_like(x, q_x, stack[0])
 
-    lists, _ = jax.lax.scan(
-        step,
-        tuple(_varying_like(x, q_x, stack[0])
-              for x in lane_bin_lists(q_rows, depth, carry_d.dtype)),
-        stack,
-    )
+    lists = tuple(map(varying, lane_bin_lists(q_rows, depth, carry_d.dtype)))
+    if not lane_bin_bound_rides(q_rows, c_tile, carry_d.dtype.itemsize):
+        def step(lists, tile):
+            return either(
+                lambda blk, blk_ids, blk_sq, ld, li, one: insert(
+                    ld, li, None, dist_tile(blk, blk_ids, blk_sq, one),
+                    blk_ids, depth=depth),
+                *tile, *lists,
+            ), None
+
+        lists, _ = jax.lax.scan(step, lists, stack)
+        chunks = None
+    else:
+        def step(state, tile):
+            *lists, bound, inserted = state
+            *tile, due = tile
+            with jax.named_scope("knn.select"):
+                bound = jax.lax.cond(
+                    due,
+                    lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
+                    lambda: bound)
+            *lists, n = either(
+                lambda blk, blk_ids, blk_sq, ld, li, b, one: insert(
+                    ld, li, b, dist_tile(blk, blk_ids, blk_sq, one),
+                    blk_ids, depth=depth),
+                *tile, *lists, bound,
+            )
+            return (*lists, bound, inserted + n), None
+
+        # the bound starts at +inf and not at the incoming carry's k-th
+        # column: that bounds the MERGED answer, while the lists answer for
+        # this stack alone, and a stack with fewer than k values under it
+        # would leave its rows short of k candidates, flagged one and all
+        (*lists, _, inserted), _ = jax.lax.scan(
+            step,
+            (*lists,
+             varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
+             varying(jnp.int32(0))),
+            (*stack, bound_refreshes(n_tiles)),
+        )
+        chunks = jnp.stack(
+            [inserted, n_tiles * lane_bin_chunks(q_rows, c_tile) - inserted])
     with jax.named_scope("knn.select"):
         vals, ids, flagged = lane_bin_result(lists, q_rows, k)
         with jax.named_scope("finish"):
@@ -630,7 +715,7 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 flagged, vals, ids,
                 functools.partial(dist_tile, scoped=False), stack, either)
     with jax.named_scope("knn.merge"):
-        return *merge_topk(carry_d, carry_i, vals, ids), rescanned
+        return *merge_topk(carry_d, carry_i, vals, ids), rescanned, chunks
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg

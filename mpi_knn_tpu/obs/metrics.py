@@ -60,6 +60,10 @@ DIST_PATHS = ("onepass", "multipass", "cosine")  # a count's columns
 # (MetricsRegistry.count_select_tiles)
 SELECT_TILES = "knn_select_query_tiles_total"
 SELECT_PATHS = ("carried", "rescanned")  # a count's columns
+# chunks of the distance tiles by what became of them in *bins* under the
+# row bound (MetricsRegistry.count_bins_chunks)
+BINS_CHUNKS = "knn_select_bins_chunks_total"
+BINS_PATHS = ("inserted", "skipped")  # a count's columns
 
 JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a program found in jax's persistent compilation cache (at jax 0.9.0 the
@@ -369,6 +373,21 @@ class MetricsRegistry:
             "the lane-bin lists, by what became of the selection: the "
             "carried answer kept, or rows that failed the certificate "
             "answered again by a re-scan",
+        )
+
+    def count_bins_chunks(self, chunks) -> None:
+        """Add a dispatch's chunks to ``knn_select_bins_chunks_total
+        {path="inserted"|"skipped"}``. ``chunks`` is ``KNNResult
+        .bins_chunks`` / ``BatchResult.bins_chunks``: ints ``[inserted,
+        skipped]``, one row a device, from a program whose scans carry a
+        row bound beside the lane-bin lists. The device decides, so call
+        this where :meth:`count_dist_steps` is called."""
+        self._count_columns(
+            BINS_CHUNKS, BINS_PATHS, chunks,
+            "chunks (16 rows x 1024 columns) of the distance tiles of "
+            "scans that carry the lane-bin lists, by what became of them "
+            "in bins: inserted through the compare-exchange network, or "
+            "skipped because no value was at or under its row's bound",
         )
 
     def _count_columns(self, name, paths, counts, help) -> None:

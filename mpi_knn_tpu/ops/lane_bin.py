@@ -6,7 +6,27 @@ belonged. Selection-only Pallas kernels — no dot in either — over blocks of
 the tile that XLA's matmul fusion writes; Mosaic on the TPU, the same
 bodies interpreted elsewhere, so CPU tests run what the chip runs. Imports
 nothing of ``ops/topk.py``, which imports this module when it first selects
-from a wide tile (pallas is ~0.8 s to import)."""
+from a wide tile (pallas is ~0.8 s to import).
+
+Where a scan carries the lists over the tiles of a stack, a ROW BOUND can
+ride beside them (``lane_bin_bound`` / ``lane_bin_candidates_under``; the
+rule, ``ops/topk.py lane_bin_bound_rides``): (1) the bound is one value a
+row that the row's FINAL k-th smallest cannot pass — the k-th smallest of
+any k values the row has seen, here of its lane minima — taken anew from
+the lists at a few of the scan's steps, and it only falls; (2) *bins* asks
+of every chunk of the tile (a strip of 16 rows x 1024 columns) whether any
+value is at or under its row's bound, a load, a compare and a select a vreg
+in place of 25 operations, and runs the compare-exchange network, the id
+broadcast and the lists' load and store for the chunks that hold one; (3)
+the answer does not change: a dropped value is above a bound that is at or
+above the final k-th smallest, hence larger than every list entry at or
+under the final bound, so those entries keep their slots; the entries under
+a bound never become fewer once it is taken (a lane evicts one only for a
+smaller one), so k of them are there at the end and the k smallest
+candidates, tau and the lanes' last values under tau — ``(vals, ids,
+flagged)`` of ``lane_bin_result`` — are what they are without the test;
+only slots above the final bound may hold other values; (4) the kernel
+counts the chunks it inserted (``knn_select_bins_chunks_total``)."""
 
 from __future__ import annotations
 
@@ -72,6 +92,24 @@ def _plain(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x, x.dtype)
 
 
+def _insert_group(ids_ref, d_ref, r, lanes, kept_d: list, kept_i: list):
+    """One column group of the strip ``r`` through the compare-exchange
+    stages of its lists (``kept_d`` / ``kept_i``, one vreg pair a slot,
+    updated in place), the candidate's id riding the same selects."""
+    lax = jax.lax
+    v = d_ref[r, lanes]
+    vi = lax.broadcast_in_dim(ids_ref[:, lanes], v.shape, (0, 1))
+    for j in range(len(kept_d)):
+        # strict <: among equal values the earlier group (and the earlier
+        # tile's, in carried lists) stays ahead; NaN compares false and is
+        # never kept
+        lt = lax.lt(v, kept_d[j])
+        kept_d[j], v = (lax.select(lt, v, kept_d[j]),
+                        lax.select(lt, kept_d[j], v))
+        kept_i[j], vi = (lax.select(lt, vi, kept_i[j]),
+                         lax.select(lt, kept_i[j], vi))
+
+
 def _lane_bin_kernel(ids_ref, d_ref, *lists, depth: int):
     """One (rows, cols) block of the tile: insert its cols/128 column groups
     into the per-(row, lane) sorted lists of ``depth`` that the two output
@@ -111,18 +149,7 @@ def _lane_bin_kernel(ids_ref, d_ref, *lists, depth: int):
                 lanes = pl.ds(
                     pl.multiple_of(lax.mul(g, g.dtype.type(_LANES)), _LANES),
                     _LANES)
-                v = d_ref[r, lanes]
-                vi = lax.broadcast_in_dim(
-                    ids_ref[:, lanes], v.shape, (0, 1))
-                for j in range(depth):
-                    # strict <: among equal values the earlier group (and
-                    # the earlier tile's, in carried lists) stays ahead;
-                    # NaN compares false and is never kept
-                    lt = lax.lt(v, kept_d[j])
-                    kept_d[j], v = (lax.select(lt, v, kept_d[j]),
-                                    lax.select(lt, kept_d[j], v))
-                    kept_i[j], vi = (lax.select(lt, vi, kept_i[j]),
-                                     lax.select(lt, kept_i[j], vi))
+                _insert_group(ids_ref, d_ref, r, lanes, kept_d, kept_i)
             return (*kept_d, *kept_i)
 
         kept = lax.fori_loop(
@@ -136,6 +163,182 @@ def _lane_bin_kernel(ids_ref, d_ref, *lists, depth: int):
         return carry
 
     lax.fori_loop(0, rows // _STRIP, strip, 0)
+
+
+def _bits_set(x: jax.Array) -> jax.Array:
+    """The set bits of a non-negative int32 scalar, by halving sums: Mosaic
+    counts the bits of vectors only."""
+    lax, i32 = jax.lax, jnp.int32
+
+    def fold(x, shift, mask):
+        return lax.add(lax.bitwise_and(x, i32(mask)), lax.bitwise_and(
+            lax.shift_right_logical(x, i32(shift)), i32(mask)))
+
+    for shift, mask in ((1, 0x55555555), (2, 0x33333333), (4, 0x0F0F0F0F),
+                        (8, 0x00FF00FF), (16, 0x0000FFFF)):
+        x = fold(x, shift, mask)
+    return x
+
+
+# the column groups a chunk of the testing kernel holds: a strip of 16 rows
+# x 8 groups is 16 vregs to test (a load, a compare and a select each)
+# against 400 vector operations to insert at depth 5 (4 and 16 groups read
+# the same step on the chip: PERF.md §6, PR 35)
+_CHUNK_GROUPS = 8
+
+
+def _lane_bin_bounded_kernel(ids_ref, d_ref, b_ref, ld_ref, li_ref, kd_ref,
+                             ki_ref, n_ref, hit_ref, *, depth: int,
+                             chunk_groups: int):
+    """:func:`_lane_bin_kernel` under a row bound: ``b_ref`` (rows, 128)
+    holds, in every lane of a row, a value that the row's final k-th
+    smallest cannot pass. First every strip of the block asks of each of its
+    column chunks (``chunk_groups`` groups wide) whether any value is at or
+    under its row's bound: a load, a compare and a select a vreg, the
+    answers packed a bit a (strip, chunk) into 32-bit words, and a word
+    crosses to scalar memory (``hit_ref``) by ONE chain of rotations and
+    one transfer. (A reduction a chunk cost as much as the network it was
+    to save, and a chain a strip more: PERF.md §6, PR 35.) Then a strip
+    with a bit set loads its lists, runs the compare-exchange network over
+    the chunks whose bits are set, and stores them; the other strips and
+    chunks cost a scalar test. ``n_ref`` (1, 1), scalar memory: the chunks
+    inserted, summed over the grid (a sum outside the kernel is one more
+    XLA operation a step: 0.8 us of a 57 us step, PERF.md §6, PR 35)."""
+    rows, cols = d_ref.shape
+    strips = rows // _STRIP
+    n_chunks = cols // _LANES // chunk_groups
+    per_word = 32 // n_chunks  # strips whose chunks share a word
+    n_words = -(-strips // per_word)
+    # a chunk's groups in one basic block where they are eight: the chains
+    # of so short a loop would not fill the VLIW schedule
+    unroll = next(u for u in (8, 4, 2, 1) if chunk_groups % u == 0)
+    slots = [slice(j * _LANES, (j + 1) * _LANES) for j in range(depth)]
+    lax = jax.lax
+    block, first = pl.program_id(0), pl.program_id(1)
+    i32 = jnp.int32
+    half = _STRIP // 2
+
+    @pl.when(lax.eq(first, first.dtype.type(0)))
+    def _():
+        kd_ref[...] = ld_ref[...]
+        ki_ref[...] = li_ref[...]
+
+    @pl.when(lax.eq(lax.add(block, first), first.dtype.type(0)))
+    def _():
+        n_ref[0, 0] = i32(0)
+
+    def rows_of(s):
+        return pl.ds(pl.multiple_of(lax.mul(s, s.dtype.type(_STRIP)), _STRIP),
+                     _STRIP)
+
+    def lanes_of(g):
+        return pl.ds(pl.multiple_of(lax.mul(g, g.dtype.type(_LANES)), _LANES),
+                     _LANES)
+
+    def place(s):
+        """(word, first bit) of strip ``s``'s chunks."""
+        return (lax.div(s, s.dtype.type(per_word)),
+                lax.mul(lax.rem(s, s.dtype.type(per_word)),
+                        s.dtype.type(n_chunks)))
+
+    def as_i32(i):  # a loop index is int64 under jax_enable_x64
+        return lax.convert_element_type(i, i32)
+
+    def test(s, words):
+        s = as_i32(s)
+        r = rows_of(s)
+        bound = b_ref[r, :]
+        zero = lax.full(bound.shape, 0, i32)
+
+        def chunk_of(chunk, bits):
+            chunk = as_i32(chunk)
+
+            def group(u, bit):
+                g = lax.add(lax.mul(chunk, i32(chunk_groups)), as_i32(u))
+                # <=: a tie with the bound is kept; NaN compares false
+                return lax.select(lax.le(d_ref[r, lanes_of(g)], bound),
+                                  lax.broadcast(lax.shift_left(i32(1), chunk),
+                                                bound.shape), bit)
+
+            return lax.bitwise_or(bits, lax.fori_loop(
+                0, chunk_groups, group, zero, unroll=True))
+
+        bits = lax.fori_loop(0, n_chunks, chunk_of, zero, unroll=True)
+        bits = lax.bitwise_or(lax.slice(bits, (0, 0), (half, _LANES)),
+                              lax.slice(bits, (half, 0), (_STRIP, _LANES)))
+        word, bit = place(s)
+        bits = lax.shift_left(bits, lax.broadcast(bit, bits.shape))
+        return tuple(
+            lax.select(lax.broadcast(lax.eq(word, word.dtype.type(w)),
+                                     bits.shape),
+                       lax.bitwise_or(acc, bits), acc)
+            for w, acc in enumerate(words))
+
+    words = lax.fori_loop(
+        0, strips, test,
+        tuple(lax.full((half, _LANES), 0, i32) for _ in range(n_words)))
+    # OR over a word's elements by rotations; the words' chains interleave
+    for axis, size in ((1, _LANES), (0, half)):
+        shift = size // 2
+        while shift:
+            words = tuple(lax.bitwise_or(x, pltpu.roll(x, shift, axis))
+                          for x in words)
+            shift //= 2
+    for w, x in enumerate(words):
+        hit_ref[w] = lax.squeeze(lax.slice(x, (0, 0), (1, 1)), (0, 1))
+
+    def strip(s, inserted):
+        s = as_i32(s)
+        r = rows_of(s)
+        word, bit = place(s)
+        bits = lax.bitwise_and(
+            lax.shift_right_logical(hit_ref[word], bit),
+            i32((1 << n_chunks) - 1))
+
+        def chunk_of(chunk, carry):
+            chunk = as_i32(chunk)
+
+            @pl.when(lax.ne(lax.bitwise_and(
+                lax.shift_right_logical(bits, chunk), i32(1)), i32(0)))
+            def _():
+                def insert(step, kept):
+                    kept_d, kept_i = list(kept[:depth]), list(kept[depth:])
+                    for u in range(unroll):
+                        g = lax.add(
+                            lax.mul(chunk, i32(chunk_groups)),
+                            lax.add(lax.mul(as_i32(step), i32(unroll)),
+                                    i32(u)))
+                        _insert_group(ids_ref, d_ref, r, lanes_of(g),
+                                      kept_d, kept_i)
+                    return (*kept_d, *kept_i)
+
+                # the lists go through memory a chunk: vregs carried through
+                # a conditional cost several times this load and store
+                kept = lax.fori_loop(
+                    0, chunk_groups // unroll, insert,
+                    (*(_plain(kd_ref[r, sl]) for sl in slots),
+                     *(_plain(ki_ref[r, sl]) for sl in slots)),
+                )
+                for j, sl in enumerate(slots):
+                    kd_ref[r, sl] = kept[j]
+                    ki_ref[r, sl] = kept[depth + j]
+
+            return carry
+
+        @pl.when(lax.ne(bits, i32(0)))
+        def _():
+            lax.fori_loop(0, n_chunks, chunk_of, 0)
+
+        return lax.add(inserted, _bits_set(bits))
+
+    n_ref[0, 0] = lax.add(
+        n_ref[0, 0], lax.fori_loop(0, strips, strip, i32(0)))
+
+
+def _tile_blocks(q: int, c: int) -> tuple[int, int]:
+    """The (rows, cols) block of the bins kernels over a (q, c) tile."""
+    return _row_block(q, _BLOCK_ROWS), next(
+        w for w in range(min(c, _BLOCK_COLS), 0, -_LANES) if c % w == 0)
 
 
 @jax.named_scope("bins")
@@ -164,10 +367,7 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int,
     [j·128, (j+1)·128) hold every lane's (j+1)-th smallest, so the last 128
     are what the certificate reads."""
     q, c = dists.shape
-    rows = _row_block(q, _BLOCK_ROWS)
-    cols = next(
-        w for w in range(min(c, _BLOCK_COLS), 0, -_LANES) if c % w == 0
-    )
+    rows, cols = _tile_blocks(q, c)
     out_block = pl.BlockSpec((rows, depth * _LANES), lambda i, j: (i, 0))
     ids = ids.astype(jnp.int32)[None, :]
     lists = tuple(lists or ())
@@ -190,6 +390,78 @@ def lane_bin_candidates(dists: jax.Array, ids: jax.Array, depth: int,
         ),
         interpret=_interpret(),
     )(ids, dists, *lists)
+
+
+def chunk_groups(c: int) -> int:
+    """The column groups a chunk of the testing kernel holds for c-column
+    tiles: the divisor of a column block's groups nearest to
+    :data:`_CHUNK_GROUPS` (8 for every width that is a multiple of 1024)."""
+    groups = _tile_blocks(_STRIP, c)[1] // _LANES
+    return min((g for g in range(1, groups + 1) if groups % g == 0),
+               key=lambda g: max(g, _CHUNK_GROUPS) / min(g, _CHUNK_GROUPS))
+
+
+def lane_bin_chunks(q: int, c: int) -> int:
+    """The chunks the testing kernel makes of a (q, c) tile: what a call's
+    inserted and skipped chunks add up to."""
+    return (q + -q % _STRIP) // _STRIP * (c // _LANES // chunk_groups(c))
+
+
+def lane_bin_no_bound(q: int, dtype=jnp.float32) -> jax.Array:
+    """The bound that skips nothing, (q', 128) +inf for the rows of
+    :func:`lane_bin_lists`: what a scan starts from."""
+    return jnp.full((q + -q % _STRIP, _LANES), _INF, dtype)
+
+
+@jax.named_scope("bins")
+def lane_bin_candidates_under(bound: jax.Array, dists: jax.Array,
+                              ids: jax.Array, depth: int, lists):
+    """:func:`lane_bin_candidates` into ``lists`` under a row bound:
+    ``bound`` (q, 128) holds in every lane of a row an upper bound on the
+    row's FINAL k-th smallest value (the k-th smallest of any k values the
+    row has seen is one: :func:`lane_bin_bound`). A chunk — a strip of 16
+    rows x :func:`chunk_groups` column groups — goes through the network
+    only if it holds a value at or under its row's bound. Returns
+    (distances, ids, inserted): the lists and, int32, the chunks that were
+    inserted, of :func:`lane_bin_chunks`.
+
+    Every value the test drops is above a bound that is at or above the
+    final k-th smallest, so it is larger than every list entry at or under
+    the final bound and changes no rank among them: those entries sit in
+    the same slots as without the test, and (vals, ids, flagged) of
+    :func:`lane_bin_result` are the same (``ops/topk.py``); slots above the
+    final bound may hold other values."""
+    q, c = dists.shape
+    rows, cols = _tile_blocks(q, c)
+    out_block = pl.BlockSpec((rows, depth * _LANES), lambda i, j: (i, 0))
+    ids = ids.astype(jnp.int32)[None, :]
+    operands = (ids, dists, bound, *lists)
+    kd, ki, inserted = pl.pallas_call(
+        functools.partial(_lane_bin_bounded_kernel, depth=depth,
+                          chunk_groups=chunk_groups(c)),
+        grid=(q // rows, c // cols),
+        in_specs=[
+            pl.BlockSpec((1, cols), lambda i, j: (0, j)),
+            pl.BlockSpec((rows, cols), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, _LANES), lambda i, j: (i, 0)),
+            out_block, out_block,
+        ],
+        out_specs=[out_block, out_block,
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_shape=[
+            _out((q, depth * _LANES), dists.dtype, *operands),
+            _out((q, depth * _LANES), jnp.int32, *operands),
+            _out((1, 1), jnp.int32, *operands),
+        ],
+        # the words of a block's (strip, chunk) bits
+        scratch_shapes=[pltpu.SMEM((rows // _STRIP,), jnp.int32)],
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=_interpret(),
+    )(*operands)
+    return kd, ki, inserted[0, 0]
 
 
 def _lane_bin_finish_kernel(cd_ref, ci_ref, od_ref, oi_ref, flag_ref,
@@ -252,17 +524,14 @@ def _lane_bin_finish_kernel(cd_ref, ci_ref, od_ref, oi_ref, flag_ref,
     flag_ref[...] = lax.max(short, lax.sub(lax.full_like(finite, 1), finite))
 
 
-@jax.named_scope("finish")
-def lane_bin_finish(cand_d: jax.Array, cand_i: jax.Array, k: int):
-    """k smallest of the narrow candidate rows, ascending, with their ids
-    (equal distances: the lower id first; ids are distinct wherever the
-    distance is finite, as a tile's are), and the exactness certificate.
-    ``k`` at most 128. Returns ((q, k) vals, (q, k) ids, (q,) flagged)."""
-    q, w = cand_d.shape
+def _finish(cand_d: jax.Array, cand_i: jax.Array, k: int, w: int):
+    """The finish kernel over the first ``w`` columns of the candidate
+    rows: ((q, k) vals, (q, k) ids, (q, 1) flags)."""
+    q = cand_d.shape[0]
     rows = _row_block(q, _FINISH_ROWS)
     cand = pl.BlockSpec((rows, w), lambda i: (i, 0))
     out = pl.BlockSpec((rows, k), lambda i: (i, 0))
-    vals, out_ids, flagged = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_lane_bin_finish_kernel, k=k),
         grid=(q // rows,),
         in_specs=[cand, cand],
@@ -282,7 +551,29 @@ def lane_bin_finish(cand_d: jax.Array, cand_i: jax.Array, k: int):
         ),
         interpret=_interpret(),
     )(cand_d, cand_i)
+
+
+@jax.named_scope("finish")
+def lane_bin_finish(cand_d: jax.Array, cand_i: jax.Array, k: int):
+    """k smallest of the narrow candidate rows, ascending, with their ids
+    (equal distances: the lower id first; ids are distinct wherever the
+    distance is finite, as a tile's are), and the exactness certificate.
+    ``k`` at most 128. Returns ((q, k) vals, (q, k) ids, (q,) flagged)."""
+    vals, out_ids, flagged = _finish(cand_d, cand_i, k, cand_d.shape[1])
     return vals, out_ids, flagged[:, 0] != 0
+
+
+@jax.named_scope("bound")
+def lane_bin_bound(lists, k: int) -> jax.Array:
+    """(q, 128), in every lane of a row the k-th smallest of the row's lane
+    minima (the lists' first 128 columns; +inf while fewer than k lanes
+    hold a value): k of the values the row has seen are at or under it, so
+    the row's final k-th smallest is too. The finish kernel over one
+    column group instead of ``depth``: the k-th smallest of ALL the
+    candidates is the same value unless two of the k smallest share a lane,
+    and is never larger."""
+    vals = _finish(*lists, k, _LANES)[0]
+    return jnp.broadcast_to(vals[:, k - 1:], (vals.shape[0], _LANES))
 
 
 def lane_bin_lists(q: int, depth: int, dtype=jnp.float32):
@@ -294,14 +585,21 @@ def lane_bin_lists(q: int, depth: int, dtype=jnp.float32):
             jnp.full(shape, INVALID_ID, jnp.int32))
 
 
-def lane_bin_insert(lists, dists: jax.Array, ids: jax.Array, depth: int):
+def lane_bin_insert(lists, dists: jax.Array, ids: jax.Array, depth: int,
+                    bound: jax.Array | None = None):
     """``lists`` (:func:`lane_bin_lists`, or what an earlier call returned)
     with the (q, c) tile ``dists`` (ids (c,)) inserted, updated in place
     where they die with the call: the one thing a tile step of a carried
-    scan selects. None: a tile's own lists, from empty."""
+    scan selects. None: a tile's own lists, from empty.
+
+    ``bound`` ((q', 128), the lists' rows: :func:`lane_bin_bound`) takes
+    the testing kernel (:func:`lane_bin_candidates_under`) and returns
+    (distances, ids, chunks inserted); without it the lists alone."""
     pad = -dists.shape[0] % _STRIP  # the kernels walk whole strips
     if pad:  # zero rows flag nothing
         dists = jnp.pad(dists, ((0, pad), (0, 0)))
+    if bound is not None:
+        return lane_bin_candidates_under(bound, dists, ids, depth, lists)
     return tuple(lane_bin_candidates(dists, ids, depth, lists))
 
 

@@ -39,6 +39,27 @@ anywhere could have belonged — with the same chance a row, whatever the
 stack's width. That is what ``backends/serial.py merge_tiles_into_carry``
 runs where this rule engages for its tiles: a tile step is *bins* only.
 
+Such a scan can also know how good a row's answer already is. Where
+``lane_bin_bound_rides`` says so (by the tile's shape), a ROW BOUND rides
+beside the lists: one value a row that the row's final k-th smallest cannot
+pass — the k-th smallest of any k values the row has seen, here of its lane
+minima (``ops/lane_bin.py lane_bin_bound``), taken anew at a few steps and
+only falling — and *bins* runs its compare-exchange network on the chunks
+of the tile (16 rows x 1024 columns) that hold a value AT OR UNDER it,
+which after t tiles is about one in t / 20. Nothing that ``lane_bin_result``
+returns changes: a dropped value is above a bound at or above the final
+k-th smallest, so it is larger than every list entry at or under the final
+bound and moves none of them; entries under a bound never become fewer
+after it is taken (a lane evicts one only for a smaller one), so k of them
+remain and they are the k smallest candidates; a lane whose last kept value
+is under tau holds R values under tau in either program, so the same rows
+are flagged and the re-scan runs exactly when it did. Only list slots ABOVE
+the final bound may hold other values. The bound must bound the answer of
+the stack the LISTS cover: an incoming carry's k-th column bounds the
+merged answer only, and a stack with fewer than k values under it would
+leave its rows short of k candidates, flagged one and all — a scan starts
+from +inf.
+
 All distances flow in "smaller is better" space; +inf marks invalid slots and
 ``INVALID_ID`` (−1) marks their ids.
 """
@@ -162,6 +183,26 @@ def lane_bin_depth(q: int, c: int, k: int, ids_ndim: int = 1) -> int | None:
         if q * p_row < _MAX_FALLBACK_SHARE:
             return depth
     return None
+
+
+# the tiles a carried scan's *bins* tests against a row bound, by what the
+# chip measured at 8192 columns (PERF.md §6, PR 35): from 256 rows (a step
+# 6 % shorter; at 64 rows the kernel's fixed costs pass what is left to
+# save) to 2048 (27 % shorter; the 1024-row tile, 32 MiB, is the cells').
+# A 4096-row tile, 128 MiB, streams from HBM, where the test's strips and
+# chunks cost more than the arithmetic they save: 12 % longer
+_BOUND_MIN_ROWS = 256
+_BOUND_MAX_TILE_BYTES = 64 << 20
+
+
+def lane_bin_bound_rides(q: int, c: int, itemsize: int = 4) -> bool:
+    """Whether a scan that carries the lane-bin lists over (q, c) tiles
+    also carries a row bound, under which *bins* inserts only the chunks
+    that hold a value at or under it (``ops/lane_bin.py
+    lane_bin_candidates_under``): by the tile's shape alone, as
+    :func:`lane_bin_depth` chooses the depth."""
+    return (q >= _BOUND_MIN_ROWS
+            and q * c * itemsize <= _BOUND_MAX_TILE_BYTES)
 
 
 def lane_bin_flagged_share(dists, k: int) -> tuple[float, float] | None:

@@ -546,9 +546,12 @@ class BatchResult:
     # serial / ring batches: the tile steps by the path of their distance
     # dot (backends.serial.dist_steps) and, from a program whose scans
     # carry the lane-bin lists, the query-tile merges by what became of
-    # the carried selection (backends.serial.select_tiles), counted at retire
+    # the carried selection (backends.serial.select_tiles) and the chunks
+    # of its tiles that *bins* inserted or skipped under the row bound
+    # (backends.serial.TileCounts.bins_chunks), counted at retire
     dist_steps: object = None
     select_tiles: object = None
+    bins_chunks: object = None
 
     @functools.cached_property
     def dists(self) -> np.ndarray:
@@ -612,11 +615,13 @@ def query_knn(
 
 def _count_tiles(registry, counts) -> None:
     """Add a fetched batch's ``backends.serial.TileCounts`` (or a
-    ``BatchResult``'s two fields of the same names) to ``registry``."""
+    ``BatchResult``'s fields of the same names) to ``registry``."""
     if counts.dist_steps is not None:
         registry.count_dist_steps(counts.dist_steps)
     if counts.select_tiles is not None:
         registry.count_select_tiles(counts.select_tiles)
+    if counts.bins_chunks is not None:
+        registry.count_bins_chunks(counts.bins_chunks)
 
 
 def _count_exchange(stats, exchange_bytes: int | None,

@@ -48,12 +48,19 @@ class KNNResult:
         ``MetricsRegistry.count_select_tiles`` adds it to
         ``knn_select_query_tiles_total``. None from a program whose scans
         carry no lists.
+      bins_chunks: int32 (..., 2), the chunks (16 rows x 1024 columns) of
+        the call's distance tiles by what became of them in *bins* under
+        the row bound that rides such a scan, ``[inserted, skipped]``
+        (``backends/serial.py _merge_carried``), one row a ring device;
+        ``MetricsRegistry.count_bins_chunks`` adds it to
+        ``knn_select_bins_chunks_total``. None where no bound rides.
     """
 
     dists: jax.Array
     ids: jax.Array
     dist_steps: jax.Array | None = None
     select_tiles: jax.Array | None = None
+    bins_chunks: jax.Array | None = None
 
     @property
     def k(self) -> int:

@@ -392,9 +392,16 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     MB read and written again at 4096 rows: half of what the change wins)
     — no (T, q, k) stack of survivors is left, and no loop asks for the
     tile stack in another layout (the re-scan once did: a copy of the
-    whole stack, 4.6 GiB at d = 784)."""
+    whole stack, 4.6 GiB at d = 784). Where the row bound rides the scan
+    (ISSUE 35: the 1024-row programs) *bins* takes it as a third operand
+    ahead of the lists, and the finish kernel appears a second time — over
+    the lists' first 128 columns, scope ``bound`` — in the conditional that
+    takes the bound anew at a few of the scan's steps."""
     import re
 
+    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides
+
+    bounded = lane_bin_bound_rides(q, 8192)
     lists = rf"\[{q},640\]"
     lines = hlo.splitlines()
     copies = [ln for ln in lines
@@ -402,12 +409,14 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     assert not copies, copies
     bins = [ln for ln in lines if "tpu_custom_call" in ln
             and re.search(rf"= \(f32{lists}\S*, s32{lists}", ln)]
+    first = 3 if bounded else 2  # after ids, the tile (and the bound)
     assert bins and all(
-        "output_to_operand_aliasing={{0}: (2, {}), {1}: (3, {})}" in ln
-        for ln in bins), bins
-    finishes = [ln for ln in lines if "tpu_custom_call" in ln
+        f"output_to_operand_aliasing={{{{0}}: ({first}, {{}}), "
+        f"{{1}}: ({first + 1}, {{}})}}" in ln for ln in bins), bins
+    finishes = [ln.split()[0] for ln in lines if "tpu_custom_call" in ln
                 and re.search(rf"= \(f32\[{q},10\]", ln)]
-    assert len(finishes) == 1, finishes
+    assert sorted(name.rstrip(".0123456789") for name in finishes) == (
+        ["%bound", "%finish"] if bounded else ["%finish"]), finishes
     assert f"[{tiles},{q},10]" not in hlo
     # (the ring's rounds copy the travelling block in the layout it has,
     # rows minor at d = 784, as they always did: PERF.md §5)

@@ -1,5 +1,6 @@
 """Serial backend parity vs the reference-semantics oracle (SURVEY.md §4)."""
 
+import contextlib
 import functools
 
 import jax
@@ -231,17 +232,20 @@ def test_classifier_label_validation(rng):
 # --- the carried selection: finish once a query tile (ISSUE 33) -------------
 # One small shape for every case (32 rows, 4 tiles of 1024 columns, k = 10):
 # the interpreted kernels compile once, for the carried and the per-tile form.
+# Since ISSUE 35 a second height, 256 rows: the shortest tile whose scan also
+# carries the row bound that *bins* tests its chunks against.
 
 _CQ, _CT, _CC, _CD, _CK = 32, 4, 1024, 16, 10
+_BQ = 256
 
 
-def _carried_cfg(**kw):
-    return KNNConfig(k=_CK, backend="serial", query_tile=_CQ,
+def _carried_cfg(rows=_CQ, **kw):
+    return KNNConfig(k=_CK, backend="serial", query_tile=rows,
                      corpus_tile=_CC, center=False, **kw)
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk_program(per_tile: bool):
+def _chunk_program(per_tile: bool, rows: int = _CQ):
     """``serve_chunk`` over the small shape under a jit of its own: the
     engaged (carried) program, or — the rule held off — the per-tile
     program that every engaged call ran before."""
@@ -251,20 +255,22 @@ def _chunk_program(per_tile: bool):
         with pytest.MonkeyPatch.context() as mp:
             if per_tile:
                 mp.setattr(serial, "carried_depth", lambda *a, **k: None)
-            return serial.serve_chunk(*args, cfg=_carried_cfg())
+            return serial.serve_chunk(*args, cfg=_carried_cfg(rows))
 
     return jax.jit(run)
 
 
-def _carried_case(name):
+def _carried_case(name, rows=_CQ):
     """(queries, corpus rows, ids, incoming carry) of one case; whole-number
     rows, so every distance is exact and equal values mean equal bits."""
+    from mpi_knn_tpu.ops.topk import lane_bin_depth
+
     rng = np.random.default_rng(sum(map(ord, name)))
-    q = rng.integers(-20, 20, (_CQ, _CD)).astype(np.float32)
+    q = rng.integers(-20, 20, (rows, _CD)).astype(np.float32)
     c = rng.integers(-20, 20, (_CT * _CC, _CD)).astype(np.float32)
     ids = np.arange(_CT * _CC, dtype=np.int32)
-    carry_d = np.full((_CQ, _CK), np.inf, np.float32)
-    carry_i = np.full((_CQ, _CK), -1, np.int32)
+    carry_d = np.full((rows, _CK), np.inf, np.float32)
+    carry_i = np.full((rows, _CK), -1, np.int32)
     if name == "incoming-carry":
         # a resumable chunk's, a ring round's: some slots beat the stack
         carry_d[::2, :4] = np.arange(1, 5, dtype=np.float32)
@@ -277,37 +283,51 @@ def _carried_case(name):
     elif name == "cross-tile-collision":
         # R + 2 of row 5's nearest in ONE lane, one or two a tile: no
         # tile's own certificate sees more than two of them
-        for j in range(6):
+        for j in range(lane_bin_depth(rows, _CC, _CK) + 2):
             at = (j % _CT) * _CC + 37 + 128 * (j // _CT)
             c[at] = q[5]
             c[at, j] += j + 1.0
     return q, c, ids, carry_d, carry_i
 
 
-@pytest.mark.parametrize("name,rescanned", [
-    ("plain", 0), ("incoming-carry", 0), ("padded-ids", 0),
-    ("nan-and-short-rows", 1), ("cross-tile-collision", 1),
+@pytest.mark.parametrize("name,rescanned,rows", [
+    ("plain", 0, _CQ), ("incoming-carry", 0, _CQ), ("padded-ids", 0, _CQ),
+    ("nan-and-short-rows", 1, _CQ), ("cross-tile-collision", 1, _CQ),
+    # under the row bound (ISSUE 35)
+    ("plain", 0, _BQ), ("incoming-carry", 0, _BQ), ("padded-ids", 0, _BQ),
+    ("nan-and-short-rows", 1, _BQ), ("cross-tile-collision", 1, _BQ),
 ])
-def test_carried_selection_equals_per_tile_and_full_width(name, rescanned):
+def test_carried_selection_equals_per_tile_and_full_width(
+        name, rescanned, rows):
     """The engaged ``twolevel`` merge — lists carried through the scan, one
     finish, the certificate once a query tile, flagged rows re-scanned —
     against the per-tile program and against ``lax.top_k`` over the whole
     stack's distances: values equal; ids equal wherever a row's distances
-    are distinct. The counter reads which query tiles were re-scanned."""
+    are distinct. The counter reads which query tiles were re-scanned. At
+    256 rows the scan also carries the row bound and *bins* skips the
+    chunks that hold nothing under it: the same answers, the re-scan's
+    too, and a count of the chunks."""
     from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides
 
-    q, c, ids, carry_d, carry_i = _carried_case(name)
-    assert serial.carried_depth(_carried_cfg(), _CQ, _CC) == 4
-    args = (jnp.asarray(q)[None], jnp.full((1, _CQ), -1, jnp.int32),
+    q, c, ids, carry_d, carry_i = _carried_case(name, rows)
+    assert serial.carried_depth(_carried_cfg(rows), rows, _CC) == (
+        4 if rows == _CQ else 5)
+    assert lane_bin_bound_rides(rows, _CC) == (rows == _BQ)
+    args = (jnp.asarray(q)[None], jnp.full((1, rows), -1, jnp.int32),
             jnp.asarray(carry_d)[None], jnp.asarray(carry_i)[None],
             jnp.asarray(c.reshape(_CT, _CC, _CD)),
             jnp.asarray(ids.reshape(_CT, _CC)),
             jnp.asarray((c * c).sum(1).reshape(_CT, _CC)))
-    got_d, got_i, counts = _chunk_program(False)(*args)
-    old_d, old_i = _chunk_program(True)(*args)
+    got_d, got_i, counts = _chunk_program(False, rows)(*args)
+    old_d, old_i = _chunk_program(True, rows)(*args)
     assert counts.dist_steps is None
     assert np.asarray(counts.select_tiles).tolist() == [1 - rescanned,
                                                         rescanned]
+    if rows == _BQ:  # one chunk a strip of 16 rows and a tile
+        assert np.asarray(counts.bins_chunks).sum() == _CT * rows // 16
+    else:
+        assert counts.bins_chunks is None
     got_d, got_i = np.asarray(got_d)[0], np.asarray(got_i)[0]
     np.testing.assert_array_equal(got_d, np.asarray(old_d)[0])
     # the full-width answer over (incoming carry ‖ every tile)
@@ -371,3 +391,83 @@ def test_the_counter_of_carried_selections_comes_with_the_answer():
             np.asarray(got.dists), np.asarray(one_shot.dists))
         np.testing.assert_array_equal(
             np.asarray(got.ids), np.asarray(one_shot.ids))
+
+
+def test_the_counter_of_bins_chunks_comes_with_the_answer(monkeypatch):
+    """``knn_select_bins_chunks_total{path="inserted"|"skipped"}`` (ISSUE
+    35): what *bins* did with the chunks of the distance tiles under the
+    row bound that rides the scan. A one-shot call carries ``[inserted,
+    skipped]`` on ``KNNResult.bins_chunks``, a served batch's is added at
+    retire, after the batch's own sync — nothing is fetched or counted
+    inside ``knn:batch.enqueue`` — and the two add up to the
+    chunks of the scan. A corpus that every query meets in descending order
+    of distance inserts them all; a program whose scans carry no lists
+    counts nothing, and so does one whose tiles are too short for the bound
+    (``ops/topk.py lane_bin_bound_rides``)."""
+    from mpi_knn_tpu import build_index, query_knn
+    from mpi_knn_tpu.obs import metrics as obs_metrics
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_chunks
+    from mpi_knn_tpu.serve import ServeSession
+
+    reg = obs_metrics.MetricsRegistry()
+    reg.count_bins_chunks(np.array([[3, 1], [2, 6]]))  # one row a device
+    assert [reg.counter(obs_metrics.BINS_CHUNKS, labels={"path": p}).value
+            for p in obs_metrics.BINS_PATHS] == [5, 7]
+
+    def counted():
+        reg = obs_metrics.get_registry()
+        return [reg.counter(obs_metrics.BINS_CHUNKS, labels={"path": p}).value
+                for p in obs_metrics.BINS_PATHS]
+
+    chunks = _CT * lane_bin_chunks(_BQ, _CC)
+    cfg = _carried_cfg(_BQ, query_bucket=_BQ)
+    q, c, *_ = _carried_case("plain", _BQ)
+    one_shot = all_knn(c, queries=q, config=cfg)
+    assert np.asarray(one_shot.bins_chunks).sum() == chunks
+    narrow = all_knn(c, queries=q, config=cfg.replace(corpus_tile=512))
+    assert narrow.bins_chunks is None
+    short = all_knn(c, queries=q[:_CQ], config=_carried_cfg())
+    assert short.select_tiles is not None and short.bins_chunks is None
+    # row j is (N - j) e_0 and no query has a component along e_0: every
+    # query meets the corpus in descending order of distance
+    far = np.zeros((_CT * _CC, _CD), np.float32)
+    far[:, 0] = np.arange(_CT * _CC, 0, -1)
+    q[:, 0] = 0.0
+    assert np.asarray(
+        all_knn(far, queries=q, config=cfg).bins_chunks).tolist() == [chunks, 0]
+
+    index = build_index(far, cfg)
+    before = counted()
+    served = query_knn(q, index)
+    assert np.asarray(served.bins_chunks).tolist() == [chunks, 0]
+    assert [b - a for a, b in zip(before, counted())] == [chunks, 0]
+    session = ServeSession(index)
+    # the phases open on the dispatching thread whenever the counter is fed,
+    # and what it is fed
+    open_phases, fed = [], []
+    phase = session.phase
+
+    def tracked(name, *args, **kw):
+        @contextlib.contextmanager
+        def span():
+            open_phases.append(name)
+            try:
+                with phase(name, *args, **kw) as h:
+                    yield h
+            finally:
+                open_phases.remove(name)
+        return span()
+
+    count = obs_metrics.MetricsRegistry.count_bins_chunks
+    monkeypatch.setattr(session, "phase", tracked)
+    monkeypatch.setattr(
+        obs_metrics.MetricsRegistry, "count_bins_chunks",
+        lambda self, n: (fed.append((list(open_phases), n)), count(self, n)))
+    retired = session.submit(q)
+    assert retired == [] and fed == []  # dispatched, nothing counted yet
+    batch, = session.drain()
+    assert np.asarray(batch.bins_chunks).tolist() == [chunks, 0]
+    (phases, n), = fed
+    # the batch is synchronised: the count is on hand, no wait of its own
+    assert "enqueue" not in phases and n.is_ready()
+    assert [b - a for a, b in zip(before, counted())] == [2 * chunks, 0]

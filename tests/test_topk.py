@@ -410,6 +410,167 @@ def test_carried_lists_flag_a_collision_no_tile_sees(rng, planted, flags):
     assert got_d[4, :3].tolist() == [1.0, 2.0, 3.0]
 
 
+@functools.partial(jax.jit, static_argnames=("k", "depth", "refresh"))
+def _carried_under_a_bound(stack_d, stack_ids, start, k, depth, refresh=True):
+    """:func:`_carried` the way an engaged ``merge_tiles_into_carry`` runs
+    it since ISSUE 35: a row bound rides the scan beside the lists, taken
+    anew from them at ``backends/serial.py bound_refreshes``' steps, and
+    *bins* inserts the chunks that hold a value at or under it. Returns
+    (vals, ids, flagged, lists, chunks inserted)."""
+    from mpi_knn_tpu.backends.serial import bound_refreshes
+    from mpi_knn_tpu.ops.lane_bin import (
+        lane_bin_bound,
+        lane_bin_insert,
+        lane_bin_lists,
+        lane_bin_no_bound,
+        lane_bin_result,
+    )
+
+    tiles, q, _ = stack_d.shape
+
+    def step(state, tile):
+        *lists, bound, inserted = state
+        *tile, due = tile
+        bound = jax.lax.cond(
+            due, lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
+            lambda: bound)
+        *lists, n = lane_bin_insert(lists, *tile, depth, bound)
+        return (*lists, bound, inserted + n), None
+
+    due = bound_refreshes(tiles) if refresh else np.zeros(tiles, bool)
+    (*lists, _, inserted), _ = jax.lax.scan(
+        step,
+        (*lane_bin_lists(q, depth, stack_d.dtype),
+         jnp.minimum(lane_bin_no_bound(q, stack_d.dtype), start[:, None]),
+         jnp.int32(0)),
+        (stack_d, stack_ids, due))
+    return *lane_bin_result(lists, q, k), lists, inserted
+
+
+def _bound_case(data, q, tiles, c, k, rng):
+    """A (tiles, q, c) stack of distance tiles of one kind."""
+    if data == "fractional":
+        # a row's neighbours in the first tile, a few rows' best in a late
+        # one: those chunks insert, the others hold nothing under the bound
+        d = 1.0 + rng.random((tiles, q, c), dtype=np.float32)
+        lanes = rng.permuted(np.tile(np.arange(c), (q, 1)), axis=1)[:, :k + 2]
+        d[0][np.arange(q)[:, None], lanes] = rng.random(
+            (q, k + 2), dtype=np.float32)
+        d[tiles - 1, ::64, 5] = -1.0
+    elif data == "ties":
+        # whole numbers, most rows' k-th smallest shared by many columns
+        d = rng.integers(0, 6, (tiles, q, c)).astype(np.float32)
+    elif data in ("descending", "ascending"):
+        # every row's values in (reverse) order over the whole stack
+        d = np.sort(rng.random((q, tiles * c), dtype=np.float32), axis=1)
+        if data == "descending":
+            d = d[:, ::-1]
+        d = np.ascontiguousarray(np.moveaxis(d.reshape(q, tiles, c), 1, 0))
+    elif data == "inf-and-nan":
+        # tombstones and padding (+inf columns), a NaN row, a row short of k
+        d = rng.random((tiles, q, c), dtype=np.float32)
+        d[:, :, rng.random(c) < 0.3] = np.inf
+        d[1, :, 900:] = np.inf
+        d[:, 3, :] = np.nan
+        d[:, 6, :] = np.inf
+        d[2, 6, 40:44] = [4.0, 2.0, 1.0, 3.0]
+    else:  # "collision": depth + 1 of a row's smallest in one lane
+        d = rng.random((tiles, q, c), dtype=np.float32)
+        for j in range(6):
+            d[j % tiles, 9, 77 + 128 * (j // tiles)] = -5.0 - j
+    return d
+
+
+@pytest.mark.parametrize("start", ["from-inf", "from-finite"])
+@pytest.mark.parametrize("data", ["fractional", "ties", "descending",
+                                  "ascending", "inf-and-nan", "collision"])
+@pytest.mark.parametrize("q,tiles,c", [(64, 8, 2048), (1024, 6, 2048),
+                                       (4096, 3, 1024)],
+                         ids=["64-rows-depth-4", "1024-rows-depth-5",
+                              "4096-rows-depth-5"])
+def test_carried_lists_under_a_row_bound_answer_as_without_it(
+        q, tiles, c, data, start):
+    """ISSUE 35: what *bins* skips under the row bound changes nothing that
+    ``lane_bin_result`` returns — ``vals``, ``ids`` and ``flagged`` are
+    those of the scan without a bound, bit for bit, on any data; rows that
+    pass the certificate hold the full-width answer (what the re-scan gives
+    the flagged ones). The bound starts at +inf, as ``_merge_carried``
+    starts it, or finite: each row's final k-th smallest, the tightest
+    value a caller may hand in (a tie with the bound is kept). From +inf
+    a descending corpus inserts every chunk, an ascending one few."""
+    k = 10
+    depth = lane_bin_depth(q, c, k)
+    assert depth == (4 if q == 64 else 5)
+    rng = np.random.default_rng([q, len(data), start == "from-inf"])
+    d = _bound_case(data, q, tiles, c, k, rng)
+    ids = np.arange(tiles * c, dtype=np.int32).reshape(tiles, c)
+    wide = np.moveaxis(d, 0, 1).reshape(q, tiles * c)
+    want_d, want_i = _full_width(wide, ids.reshape(-1), k)
+    bound = np.full(q, np.inf, np.float32)
+    if start == "from-finite":
+        bound = np.where(np.isfinite(want_d[:, k - 1]), want_d[:, k - 1],
+                         np.inf).astype(np.float32)
+    plain = tuple(map(np.asarray, _carried(
+        jnp.asarray(d), jnp.asarray(ids), k, depth)))
+    *got, _, inserted = _carried_under_a_bound(
+        jnp.asarray(d), jnp.asarray(ids), jnp.asarray(bound), k, depth)
+    got = tuple(map(np.asarray, got))
+    for a, b in zip(plain, got):
+        np.testing.assert_array_equal(a, b)
+    flagged = got[2]
+    if data == "collision":
+        assert flagged.tolist() == [r == 9 for r in range(q)]
+    elif data == "inf-and-nan":
+        assert flagged[3] and flagged[6]
+    elif data != "ties":  # equal values may share a lane
+        assert not flagged.any()
+    np.testing.assert_array_equal(got[0][~flagged], want_d[~flagged])
+    if data != "ties":  # equal distances: any of the tied ids
+        np.testing.assert_array_equal(got[1][~flagged], want_i[~flagged])
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_chunks
+
+    chunks = tiles * lane_bin_chunks(q, c)
+    inserted = int(inserted)
+    if data == "descending" and start == "from-inf":
+        assert inserted == chunks
+    elif data == "descending":  # told the answer, it waits for the end
+        assert inserted <= chunks // tiles
+    elif data == "ascending":
+        # the first tile's, then (from +inf) what the first refresh lets by
+        assert inserted <= (chunks // tiles) * (1 if start != "from-inf" else 2)
+    elif data == "fractional":  # the first tile's and the planted ones
+        assert 0 < inserted <= (chunks // tiles) * 3 // 2
+    else:
+        assert 0 < inserted <= chunks
+
+
+def test_a_bound_of_inf_and_no_bound_fill_the_same_lists(rng):
+    """A bound that skips nothing (+inf, never refreshed) and a call
+    without a bound — the parent's kernel — leave the same lists, slot for
+    slot, and every chunk is counted as inserted."""
+    from mpi_knn_tpu.ops.lane_bin import (
+        lane_bin_chunks,
+        lane_bin_insert,
+        lane_bin_lists,
+    )
+
+    q, tiles, c, k, depth = 48, 3, 2048, 10, 4
+    d = rng.standard_normal((tiles, q, c)).astype(np.float32)
+    d[1, :, 500:600] = np.inf
+    ids = np.arange(tiles * c, dtype=np.int32).reshape(tiles, c)
+    lists = lane_bin_lists(q, depth)
+    for t in range(tiles):
+        lists = lane_bin_insert(lists, jnp.asarray(d[t]), jnp.asarray(ids[t]),
+                                depth)
+    *_, under, inserted = _carried_under_a_bound(
+        jnp.asarray(d), jnp.asarray(ids), jnp.full(q, jnp.inf, jnp.float32), k,
+        depth,
+        refresh=False)
+    for a, b in zip(lists, under):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(inserted) == tiles * lane_bin_chunks(q, c)
+
+
 @pytest.mark.parametrize(
     "q,c,k,ids_ndim,depth",
     [
