@@ -153,6 +153,14 @@ def default_guards() -> GuardMap:
         instance_per_thread="http-handler",
     )
     g.classes["frontend.server.FrontendHTTPServer"] = ClassGuard()
+    g.classes["frontend.server.Occupancy"] = ClassGuard(
+        # handler threads at their entry and exit, /metrics at its read
+        guarded={"_inside": "_lock", "_since": "_lock"},
+    )
+    g.classes["frontend.server._Phases"] = ClassGuard(
+        # one a request, made and used by its handler alone
+        instance_per_thread="http-handler",
+    )
     g.classes["frontend.server._tuned_server_class.TunedHTTPServer"] = (
         ClassGuard(
             guarded={
@@ -240,6 +248,9 @@ def default_guards() -> GuardMap:
             "_inflight": "dispatch-pump",
             "_seq": "dispatch-pump",
             "_consecutive_breaches": "dispatch-pump",
+            # the phases' seconds of a pump turn (ISSUE 36): fed by the
+            # spans of the driving thread, taken by it once a turn
+            "_phase_s": "dispatch-pump",
         },
         waivers={
             "warm_report": "written once by the warm thread before "
@@ -317,6 +328,8 @@ def default_guards() -> GuardMap:
         "serve.engine.ServeSession._metrics": "obs.metrics.MetricsRegistry",
         "frontend.server.FrontendHTTPServer.frontend":
             "frontend.server.Frontend",
+        "frontend.server.FrontendHTTPServer.occupancy":
+            "frontend.server.Occupancy",
         "frontend.router.Router.membership":
             "frontend.router.Membership",
         "frontend.router.Router.log": "frontend.router.MutationLog",
@@ -326,8 +339,9 @@ def default_guards() -> GuardMap:
             "frontend.router.Router",
     })
     g.name_types["frontend.server"] = {
-        # the handler closure's captured front end
+        # the handler closure's captured front end and occupancy counter
         "frontend": "frontend.server.Frontend",
+        "occupancy": "frontend.server.Occupancy",
     }
     g.name_types["frontend.router"] = {
         # the handler closure's captured router

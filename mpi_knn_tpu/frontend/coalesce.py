@@ -49,6 +49,7 @@ No jax (and no numpy) at module load: payloads are opaque here.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import itertools
 from collections import deque
 
@@ -82,6 +83,10 @@ class CoalescedBatch:
     reason: str  # "fill" | "deadline" | "flush"
     formed_s: float
     oldest_wait_s: float
+    # when the batch became dispatchable: what a pump that polled at that
+    # instant would have formed it at (``formed_s - ripe_s`` is how late
+    # the poll came; the queue wait of a request is policy + that lag)
+    ripe_s: float
 
     @property
     def tenants(self) -> dict:
@@ -170,6 +175,18 @@ class Coalescer:
         heads = [q[0] for q in self._queues.values() if q]
         return min(heads, key=lambda r: r.seq) if heads else None
 
+    def _filled_s(self) -> float:
+        """Arrival of the request that brought the pending rows to
+        ``max_batch_rows``, in admission order (call only when they have
+        got there)."""
+        rows = 0
+        # each tenant's queue is in admission order already
+        for r in heapq.merge(*self._queues.values(), key=lambda r: r.seq):
+            rows += r.rows
+            if rows >= self.max_batch_rows:
+                break
+        return r.arrival_s
+
     def next_deadline_s(self) -> float | None:
         """When the oldest pending request's wait budget expires (the
         wake-up time a pump should sleep until); None when idle."""
@@ -192,6 +209,15 @@ class Coalescer:
         if not (fill or expired or flush):
             return None
         reason = "fill" if fill else ("deadline" if expired else "flush")
+        # dispatchable since the first condition that held: the filling
+        # request's arrival, the oldest request's deadline (a filled
+        # queue whose oldest had expired before was ripe then), or, for
+        # a flush, only now
+        ripe = now
+        if fill:
+            ripe = self._filled_s()
+        if expired:
+            ripe = min(ripe, oldest.arrival_s + self.max_wait_s)
 
         # rotation order: first-seen tenant order, started at the oldest
         # request's tenant — the deadline-ordered guarantee (the request
@@ -226,5 +252,5 @@ class Coalescer:
         self._pending_rows -= rows
         return CoalescedBatch(
             parts=tuple(parts), rows=rows, reason=reason, formed_s=now,
-            oldest_wait_s=now - oldest.arrival_s,
+            oldest_wait_s=now - oldest.arrival_s, ripe_s=ripe,
         )

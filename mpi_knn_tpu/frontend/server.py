@@ -58,24 +58,28 @@ class Ticket:
     """One admitted request's rendezvous: the submitting thread waits on
     ``result``; the pump fulfills (or fails) it at retire."""
 
-    __slots__ = ("request", "_event", "_dists", "_ids", "_error", "done_s")
+    __slots__ = ("request", "_clock", "_event", "_dists", "_ids", "_error",
+                 "done_s")
 
-    def __init__(self, request):
+    def __init__(self, request, clock=time.monotonic):
         self.request = request
+        self._clock = clock
         self._event = threading.Event()
         self._dists = None
         self._ids = None
         self._error = None
-        self.done_s = None  # time.monotonic() at fulfill (loadgen's clock)
+        # the front end's clock at fulfill: the one ``arrival_s`` is on
+        # (time.monotonic unless injected: loadgen's clock)
+        self.done_s = None
 
     def _fulfill(self, dists, ids) -> None:
         self._dists, self._ids = dists, ids
-        self.done_s = time.monotonic()
+        self.done_s = self._clock()
         self._event.set()
 
     def _fail(self, error: BaseException) -> None:
         self._error = error
-        self.done_s = time.monotonic()
+        self.done_s = self._clock()
         self._event.set()
 
     def done(self) -> bool:
@@ -297,7 +301,7 @@ class Frontend:
             )
             if isinstance(out, Rejection):
                 return out
-            ticket = Ticket(out)
+            ticket = Ticket(out, self._clock)
             self._tickets[out.seq] = ticket
             self._work.notify()
             return ticket
@@ -484,7 +488,13 @@ class Frontend:
     def _run(self) -> None:
         try:
             session = self.session
+            turn_s = time.perf_counter()
             while True:
+                # what no phase of the last turn covered is ``other``: the
+                # turn's wall on the spans' own clock, less their seconds
+                now_s = time.perf_counter()
+                session.phase_remainder(now_s - turn_s)
+                turn_s = now_s
                 with self._lock:
                     stopping = self._stop
                     # a poll forms batches only out of pending rows: one
@@ -508,15 +518,17 @@ class Frontend:
                     # nothing formed: retire in-flight work so results
                     # are not held hostage to the NEXT batch arriving
                     # (dispatch-ahead depth > 1 would otherwise strand
-                    # the last batch of a lull in the pipeline)
+                    # the last batch of a lull in the pipeline). The span
+                    # says WHY the pump sat in ``wait``: its children
+                    # feed the phases, it feeds none
                     if self._dispatched:
-                        for res in self.session.drain():
-                            self._scatter(res)
+                        with obs_spans.span("drain", cat="pump",
+                                            batches=len(self._dispatched)):
+                            for res in self.session.drain():
+                                self._scatter(res)
                     with self._lock:
-                        if self._stop and not (
-                            self._dispatched
-                            or self.scheduler.coalescer.pending_rows
-                        ):
+                        held = self.scheduler.coalescer.pending_rows
+                        if self._stop and not (self._dispatched or held):
                             return
                         wake = self.scheduler.next_wake_s()
                         timeout = (
@@ -524,8 +536,12 @@ class Frontend:
                             else max(0.0, wake - self._clock())
                         )
                         if not self._stop:
+                            # ``idle``: nothing pending, nothing in
+                            # flight; ``hold``: the coalescer keeps rows
+                            # younger than max_wait_s for co-travellers
                             with session.phase(
-                                "idle", cat="pump", flight=False
+                                "hold" if held else "idle", cat="pump",
+                                flight=False,
                             ):
                                 self._work.wait(timeout=min(timeout, 0.05))
         except BaseException as e:  # noqa: BLE001 — fail tickets, re-raise
@@ -571,10 +587,19 @@ class Frontend:
         )
         for r in batch.parts:
             waited.observe(now - r.arrival_s)
+        # dispatch lag: how long the batch had been dispatchable when the
+        # pump got to it (a wait is the policy's hold + this)
+        lag_s = max(0.0, now - batch.ripe_s)
+        self._metrics().histogram(
+            "frontend_dispatch_lag_seconds",
+            help="per batch: dispatchable (filled, or its oldest request's "
+            "deadline) to handed to the engine",
+        ).observe(lag_s)
         obs_spans.end_span(
             forming, seq=self.session.next_seq, rows=batch.rows,
             requests=len(batch.parts), reason=batch.reason,
             oldest_wait_ms=round(batch.oldest_wait_s * 1e3, 3),
+            lag_ms=round(lag_s * 1e3, 3),
             request_seqs=[r.seq for r in batch.parts],
         )
         self._dispatched.append(batch)
@@ -680,6 +705,98 @@ def _tuned_server_class():
 
     return TunedHTTPServer
 
+class Occupancy:
+    """Seconds in which a request was inside the server, and seconds in
+    which none was: ``frontend_occupancy_seconds_total{state="occupied"}``
+    while at least one POST of a serving route (/query, /upsert, /delete)
+    is inside its handler, ``{state="empty"}`` otherwise. It splits an idle
+    device between the callers (nothing was asked) and the server (it sat
+    on work), over any window, traced or not. A count under one lock; the
+    clock is read where the count crosses between 0 and 1 and at
+    :meth:`settle` (every ``/metrics`` read), so the two states add up to
+    the time since construction. Overlapping requests count once."""
+
+    def __init__(self, clock=time.monotonic):
+        self._clock = clock
+        self._lock = threading.Lock()
+        self._inside = 0
+        self._since = clock()
+
+    def _move(self, step: int) -> None:
+        """Move the count by ``step`` (0: a reading alone) and, where that
+        crosses between empty and occupied or is a reading, hand the
+        seconds since the last one to the state they were spent in."""
+        with self._lock:
+            was = self._inside
+            self._inside = was + step
+            if step and (was == 0) == (self._inside == 0):
+                return
+            now = self._clock()
+            obs_metrics.get_registry().counter(
+                "frontend_occupancy_seconds_total",
+                help="seconds with (occupied) and without (empty) a "
+                "request of a serving route inside its handler",
+                labels={"state": "occupied" if was else "empty"},
+            ).inc(max(0.0, now - self._since))
+            self._since = now
+
+    def settle(self) -> None:
+        self._move(0)
+
+    def __enter__(self) -> "Occupancy":
+        self._move(+1)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._move(-1)
+
+
+class _Phases:
+    """The phases of one request on its handler's thread, children of its
+    ``request`` span: ``knn:http.read`` / ``.admit`` / ``.await`` /
+    ``.wake`` / ``.encode`` / ``.write``, each feeding
+    ``frontend_request_phase_seconds_total{phase, route}``. It opens with
+    ``read`` at the reading ``at`` (the request span's own); :meth:`next`
+    ends the open phase and begins the next at ONE reading of the clock, so
+    a request whose phases follow one another without a break (/query) is
+    partitioned exactly: the phases' seconds add up to its span's."""
+
+    __slots__ = ("_clock", "_parent", "_route", "_open", "_at", "attrs")
+
+    def __init__(self, clock, parent, route: str, at: float):
+        self._clock, self._parent, self._route = clock, parent, route
+        self._at = at
+        self.attrs: dict = {}  # what every phase from now on carries
+        self._begin("read", at, {})
+
+    def _begin(self, name: str, now: float, attrs: dict) -> None:
+        self._open = obs_spans.begin_span(
+            name, cat="http", parent=self._parent, at=now,
+            sink=obs_metrics.get_registry().counter(
+                "frontend_request_phase_seconds_total",
+                help="seconds of the handler threads by phase of a "
+                "request and route",
+                labels={"phase": name, "route": self._route},
+            ).inc,
+            **self.attrs, **attrs,
+        )
+
+    def next(self, name: str, at: float | None = None, **attrs) -> None:
+        """Begin phase ``name`` where the open one ends: now, or at the
+        reading ``at`` taken elsewhere on the same clock (a request's
+        ``arrival_s``, a ticket's ``done_s``)."""
+        self._begin(name, self.end(at), attrs)
+
+    def end(self, at: float | None = None) -> float:
+        """End the open phase without beginning another (a write's middle
+        is ``knn:mutate.*``); the reading it ended at, never behind the
+        one before it: no phase is negative."""
+        self._at = max(self._at, self._clock() if at is None else at)
+        obs_spans.end_span(self._open, at=self._at)
+        self._open = None
+        return self._at
+
+
 TENANT_HEADER = "X-Tenant"
 DEFAULT_TENANT = "default"
 # the router's per-index mutation sequence number (ISSUE 18)
@@ -705,29 +822,39 @@ def raw_rows(raw: bytes, dim: int, ids: bool):
 
 
 def _http_handler(frontend: Frontend, request_timeout_s: float,
-                  quiet: bool = True):
+                  quiet: bool, occupancy: Occupancy):
     """The BaseHTTPRequestHandler subclass bound to one frontend —
-    built by closure (stdlib handlers have no constructor channel)."""
+    built by closure (stdlib handlers have no constructor channel). The
+    handlers read the front end's clock: ``arrival_s`` and ``done_s``
+    are on it."""
     from http.server import BaseHTTPRequestHandler
+
+    clock = frontend._clock
 
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
 
-        def _json(self, status: int, doc: dict) -> None:
-            body = (json.dumps(doc) + "\n").encode()
+        def _send(self, status: int, body: bytes, ctype: str,
+                  headers=()) -> None:
             self.send_response(status)
-            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Type", ctype)
+            for name, value in headers:
+                self.send_header(name, value)
             self.send_header("Content-Length", str(len(body)))
             self.end_headers()
             self.wfile.write(body)
 
+        def _json(self, status: int, doc: dict, phases: _Phases | None = None,
+                  headers=()) -> None:
+            """``phases``: the request's, in ``encode`` since before
+            ``doc`` was made; its ``write`` begins with the body done."""
+            body = (json.dumps(doc) + "\n").encode()
+            if phases is not None:
+                phases.next("write")
+            self._send(status, body, "application/json", headers)
+
         def _text(self, status: int, text: str, ctype: str) -> None:
-            body = text.encode()
-            self.send_response(status)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
+            self._send(status, text.encode(), ctype)
 
         def log_message(self, fmt, *args):  # noqa: A003
             if not quiet:
@@ -759,20 +886,16 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                 )
             return q
 
-        def _reject(self, out: Rejection) -> None:
-            self.send_response(out.status)
-            body = (json.dumps({
+        def _reject(self, out: Rejection, phases: _Phases) -> None:
+            phases.next("encode")
+            self._json(out.status, {
                 "error": out.reason,
                 "detail": out.detail,
                 "tenant": out.tenant,
                 "retry_after_s": out.retry_after_s,
-            }) + "\n").encode()
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Retry-After",
-                             str(max(0.0, out.retry_after_s)))
-            self.send_header("Content-Length", str(len(body)))
-            self.end_headers()
-            self.wfile.write(body)
+            }, phases, headers=(
+                ("Retry-After", str(max(0.0, out.retry_after_s))),
+            ))
 
         def _read_mutation(self):
             """(ids, rows-or-None) of a write's body. JSON for small
@@ -809,21 +932,23 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                 )
             return ids, rows
 
-        def _refuse_mutation(self, status: int, doc: dict, seq) -> None:
+        def _refuse_mutation(self, status: int, doc: dict, seq,
+                             phases: _Phases) -> None:
             """Send a DETERMINISTIC refusal (400/507): the seq is
             consumed (the router acks these — a replay could only
             repeat them, so the stream position must move past), unless
             it would leave a gap, which downgrades the answer to a 409
             the router never acks."""
+            phases.next("encode")
             note = frontend._note_refused(seq)
             if note is not None and note.pop("gap", False):
-                self._json(409, {"error": "seq-gap", **note})
+                self._json(409, {"error": "seq-gap", **note}, phases)
                 return
             if note is not None:
                 doc = {**doc, **note}
-            self._json(status, doc)
+            self._json(status, doc, phases)
 
-        def _do_mutation(self, tenant: str) -> None:
+        def _do_mutation(self, tenant: str, phases: _Phases) -> None:
             """POST /upsert {"ids": [...], "rows": [[...]]} and
             POST /delete {"ids": [...]}, or their raw forms
             (``_read_mutation``) — tenant-attributed (X-Tenant),
@@ -831,7 +956,9 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             dispatched synchronously (the mutation lock serializes with
             batch dispatch). Headroom overflow on the serial layout
             surfaces as 507 (no re-cluster pass to absorb it); clustered
-            layouts compact-and-retry inside the session."""
+            layouts compact-and-retry inside the session. Of the
+            request's phases a write has ``read``, ``encode`` and
+            ``write``: its middle is ``knn:mutate.*``."""
             from mpi_knn_tpu.ivf.mutate import BucketOverflowError
             from mpi_knn_tpu.serve.mutate import mutation_phase
 
@@ -842,8 +969,9 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                 with mutation_phase("parse"):
                     ids, rows = self._read_mutation()
             except (ValueError, KeyError, TypeError) as e:
-                self._refuse_mutation(400, {"error": str(e)}, seq)
+                self._refuse_mutation(400, {"error": str(e)}, seq, phases)
                 return
+            phases.end()
             try:
                 if self.path == "/upsert":
                     out = frontend.upsert(tenant, ids, rows, seq=seq)
@@ -852,76 +980,110 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             except BucketOverflowError as e:
                 self._refuse_mutation(
                     507, {"error": "headroom-exhausted",
-                          "detail": str(e)}, seq,
+                          "detail": str(e)}, seq, phases,
                 )
                 return
             except ValueError as e:
-                self._refuse_mutation(400, {"error": str(e)}, seq)
+                self._refuse_mutation(400, {"error": str(e)}, seq, phases)
                 return
             except Exception as e:  # noqa: BLE001 — serving error
-                self._json(500, {"error": f"{type(e).__name__}: {e}"})
+                phases.next("encode")
+                self._json(500, {"error": f"{type(e).__name__}: {e}"},
+                           phases)
                 return
             if isinstance(out, Rejection):
-                self._reject(out)
+                self._reject(out, phases)
                 return
-            self._json(200, out)
+            phases.next("encode")
+            self._json(200, out, phases)
 
         def do_POST(self):  # noqa: N802 — stdlib handler convention
-            tenant = self.headers.get(TENANT_HEADER, DEFAULT_TENANT)
-            if self.path in ("/upsert", "/delete"):
-                with obs_spans.span("request", cat="http",
-                                    route=self.path[1:]):
-                    self._do_mutation(tenant)
-                return
-            if self.path != "/query":
+            route = self.path[1:]
+            if route not in ("query", "upsert", "delete"):
                 self._json(404, {"error": f"no such route {self.path}"})
                 return
-            # body read to response written, whatever the answer
-            span = obs_spans.begin_span(
-                "request", cat="http",
+            tenant = self.headers.get(TENANT_HEADER, DEFAULT_TENANT)
+            with occupancy:
+                if route == "query":
+                    self._do_query(tenant)
+                    return
+                with obs_spans.span("request", cat="http",
+                                    route=route) as request:
+                    phases = _Phases(clock, request, route, clock())
+                    try:
+                        self._do_mutation(tenant, phases)
+                    finally:
+                        phases.end()
+
+        def _do_query(self, tenant: str) -> None:
+            """Answer one POST /query inside its ``request`` span: body
+            read to response written, whatever the answer, the six phases
+            from consecutive readings of one clock."""
+            t0 = clock()
+            request = obs_spans.begin_span(
+                "request", cat="http", at=t0,
                 sink=obs_metrics.get_registry().histogram(
                     "frontend_request_seconds",
                     help="per /query request: body read to response "
                     "written, on the handler's thread",
                 ).observe,
             )
+            phases = _Phases(clock, request, "query", t0)
             seen = {}
             try:
-                seen = self._do_query(tenant)
+                seen = self._answer_query(tenant, phases)
             finally:
-                obs_spans.end_span(span, **seen)
+                obs_spans.end_span(request, at=phases.end(), **seen)
 
-        def _do_query(self, tenant: str) -> dict:
-            """Answer one POST /query; returns what the request's span
-            learns on the way (the admitted request's ``seq`` joins it
-            to its batch, and the status)."""
+        def _answer_query(self, tenant: str, phases: _Phases) -> dict:
+            """What the request's span learns on the way (the admitted
+            request's ``seq`` joins it to its batch, and the status)."""
             try:
                 q = self._read_queries()
             except (ValueError, KeyError, TypeError) as e:
-                self._json(400, {"error": str(e)})
+                phases.next("encode")
+                self._json(400, {"error": str(e)}, phases)
                 return {"status": 400}
+            phases.next("admit")
             out = frontend.submit(tenant, q)
             if isinstance(out, Rejection):
-                self._reject(out)
+                self._reject(out, phases)
                 return {"status": out.status}
-            seen = {"seq": out.request.seq, "rows": out.request.rows}
+            request = out.request
+            seen = {"seq": request.seq, "rows": request.rows}
+            phases.attrs = {"seq": request.seq}
+            # admitted when the scheduler stamped it: queue wait, batch
+            # and reply from there on are the ``await``
+            phases.next("await", at=request.arrival_s)
+            status, doc = 200, None
             try:
                 dists, ids = out.result(timeout=request_timeout_s)
             except TimeoutError as e:
-                self._json(504, {"error": str(e)})
-                return {**seen, "status": 504}
+                status, doc = 504, {"error": str(e)}
             except Exception as e:  # serving error (sentinel, …)
-                self._json(500, {"error": f"{type(e).__name__}: {e}"})
-                return {**seen, "status": 500}
-            self._json(200, {
-                "rows": int(ids.shape[0]),
-                "dists": [[float(v) for v in row] for row in dists],
-                "ids": ids.tolist(),
-            })
-            return {**seen, "status": 200}
+                status, doc = 500, {"error": f"{type(e).__name__}: {e}"}
+            done_s = out.done_s  # None: timed out unfulfilled
+            woke = clock()
+            if done_s is not None:
+                # fulfilled on the pump's thread; since then this one
+                # waited for the hand-over and the GIL. On the trace the
+                # wait is the tail of ``knn:http.await`` (a blocked thread
+                # cannot annotate): ``wake`` there is a mark carrying it
+                phases.next("wake", at=done_s,
+                            us=int((woke - done_s) * 1e6))
+            phases.next("encode", at=woke)
+            if doc is None:
+                doc = {
+                    "rows": int(ids.shape[0]),
+                    "dists": [[float(v) for v in row] for row in dists],
+                    "ids": ids.tolist(),
+                }
+            self._json(status, doc, phases)
+            return {**seen, "status": status}
 
         def do_GET(self):  # noqa: N802
             if self.path == "/metrics":
+                occupancy.settle()
                 self._text(
                     200, obs_metrics.get_registry().to_prometheus(),
                     "text/plain; version=0.0.4",
@@ -943,8 +1105,11 @@ class FrontendHTTPServer:
                  port: int = 0, request_timeout_s: float = 30.0,
                  quiet: bool = True):
         self.frontend = frontend
+        self.occupancy = Occupancy(frontend._clock)
         self._httpd = _tuned_server_class()(
-            (host, port), _http_handler(frontend, request_timeout_s, quiet)
+            (host, port),
+            _http_handler(frontend, request_timeout_s, quiet,
+                          self.occupancy),
         )
         self._httpd.daemon_threads = True
         self._thread = threading.Thread(

@@ -56,7 +56,9 @@ Instrumented code uses the module-level :func:`span`/:func:`event`/
 - a metric, when the call site passes ``sink=``: a callable given the
   span's ``perf_counter`` seconds at its end (a counter's ``inc``, a
   histogram's ``observe``) — every duration on ``/metrics`` that belongs
-  to a span comes from that span's own two clock reads.
+  to a span comes from that span's own two clock reads. A call site that
+  chains spans hands each its reading (``at=``): the end of one is the
+  beginning of the next, so the chain partitions its parent exactly.
 
 No jax import anywhere in this module.
 """
@@ -142,6 +144,9 @@ class FlightRecorder:
 
     def begin(self, name: str, cat: str = "", parent: int | None = None,
               **attrs) -> int:
+        """``ts`` is when the record is written: for a span handed an
+        earlier reading (:func:`begin_span`'s ``at=``) the begin lies
+        ``dur_s`` before its end record's ``ts``."""
         sid = next(self._ids)
         rec = {
             "ev": "B",
@@ -163,14 +168,18 @@ class FlightRecorder:
         self._write(rec)
         return sid
 
-    def end(self, sid: int, **attrs) -> None:
+    def end(self, sid: int, dur_s: float | None = None, **attrs) -> None:
+        """``dur_s``: the span's seconds where its call site read the
+        clock itself (``at=``); else measured here."""
         with self._lock:
             t0 = self._open_t0.pop(sid, None)
+        if dur_s is None:
+            dur_s = 0.0 if t0 is None else time.perf_counter() - t0
         rec = {
             "ev": "E",
             "span": sid,
             "ts": time.time(),
-            "dur_s": 0.0 if t0 is None else time.perf_counter() - t0,
+            "dur_s": dur_s,
         }
         if attrs:
             rec["attrs"] = attrs
@@ -269,19 +278,24 @@ _UNSAFE_IN_ANNOTATION = frozenset("#,=\n")  # the trace's own separators
 class _Span:
     """One open span and where its end has to go."""
 
-    __slots__ = ("rec", "sid", "ann", "sink", "t0")
+    __slots__ = ("rec", "sid", "ann", "sink", "t0", "chained")
 
 
 def begin_span(name: str, cat: str = "", *, sink=None,
                parent: _Span | None = None, flight: bool = True,
-               **attrs) -> _Span | None:
+               at: float | None = None, **attrs) -> _Span | None:
     """Begin a span that :func:`end_span` ends, possibly at a different
     call site (serve dispatch → retire). ``parent`` is another span's
     handle, open or ended (None: the enclosing :func:`span` of this
     thread, if any); ``sink`` gets the span's seconds at its end;
     ``flight=False`` keeps it out of the flight record (an idle wait
     twenty times a second would turn the ring over and push out what a
-    kill diagnosis needs). None when there is nowhere to write: no
+    kill diagnosis needs). ``at`` is the call site's own reading of its
+    clock for the span's beginning — the one that ended the span before
+    it — and obliges :func:`end_span` to be handed its end the same way:
+    the sink and the flight record's ``dur_s`` then get the difference of
+    the two readings (the annotation stays on the profiler's clock, from
+    this call to that one). None when there is nowhere to write: no
     recorder, no jax, no sink."""
     rec = _active if flight else None
     jp = sys.modules.get("jax.profiler")
@@ -302,23 +316,33 @@ def begin_span(name: str, cat: str = "", *, sink=None,
                 ids["parent"] = up
         h.ann = jp.TraceAnnotation(f"{TRACE_PREFIX}{cat}.{name}", **ids)
         h.ann.__enter__()
-    h.t0 = time.perf_counter() if sink is not None else 0.0
+    h.chained = at is not None
+    if h.chained:
+        h.t0 = at
+    else:
+        h.t0 = time.perf_counter() if sink is not None else 0.0
     return h
 
 
-def end_span(h: _Span | None, **attrs) -> None:
+def end_span(h: _Span | None, at: float | None = None, **attrs) -> None:
     """End a span; ``attrs`` are what was only known by now (a request's
-    ``seq`` after admission, a batch's latency)."""
+    ``seq`` after admission, a batch's latency). ``at``: the call site's
+    reading for the end of a span begun with one."""
     if h is None:
         return
+    dur_s = None  # the flight record measures its own, unless chained
+    if h.chained:
+        if at is None:
+            raise ValueError("a span begun with at= ends with at=")
+        dur_s = at - h.t0
     if h.sink is not None:
-        h.sink(time.perf_counter() - h.t0)
+        h.sink(dur_s if h.chained else time.perf_counter() - h.t0)
     if h.ann is not None:
         if attrs:
             h.ann.set_metadata(**_scalars(attrs))
         h.ann.__exit__(None, None, None)
     if h.rec is not None:
-        h.rec.end(h.sid, **attrs)
+        h.rec.end(h.sid, dur_s=dur_s, **attrs)
 
 
 def _scalars(attrs: dict) -> dict:

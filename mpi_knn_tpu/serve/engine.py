@@ -727,6 +727,9 @@ class ServeSession:
         self._rung = 0
         self._consecutive_breaches = 0
         self._seq = 0
+        # seconds of the driving thread that its phases have covered since
+        # phase_remainder last took them
+        self._phase_s = 0.0
         # cold-start readiness (ISSUE 12): warm() publishes per-cell
         # progress here — /healthz's warming block and the front end's
         # per-bucket admission read it (possibly from other threads)
@@ -785,17 +788,34 @@ class ServeSession:
         dispatching thread, which alone submits)."""
         return self._seq
 
+    def _phase_counter(self, phase: str):
+        return self._metrics.counter(
+            "serve_batch_phase_seconds_total",
+            help="seconds of the dispatching thread by phase: idle, hold, "
+            "coalesce, prep, enqueue, wait, d2h, reply, other",
+            labels={"phase": phase},
+        )
+
     def phase_sink(self, phase: str):
         """``sink=`` of a span of the thread that drives this session (the
         front end's pump): its seconds go to
         ``serve_batch_phase_seconds_total{phase=...}``, so the phases of a
         window say where that thread's time went."""
-        return self._metrics.counter(
-            "serve_batch_phase_seconds_total",
-            help="seconds of the dispatching thread by phase: idle, "
-            "coalesce, prep, enqueue, wait, d2h, reply",
-            labels={"phase": phase},
-        ).inc
+        inc = self._phase_counter(phase).inc
+
+        def sink(seconds: float) -> None:
+            inc(seconds)
+            self._phase_s += seconds
+
+        return sink
+
+    def phase_remainder(self, wall_s: float) -> None:
+        """Once a turn of the driving thread's loop: what of the turn's
+        ``wall_s`` (on the spans' clock) no phase covered — lock waits,
+        bookkeeping, time off the CPU — goes to phase ``other``, so the
+        phases partition that thread's time by construction."""
+        covered, self._phase_s = self._phase_s, 0.0
+        self._phase_counter("other").inc(max(0.0, wall_s - covered))
 
     def phase(self, name: str, cat: str = "batch", **attrs):
         """The span of one phase of the dispatching thread around a

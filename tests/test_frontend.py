@@ -93,6 +93,39 @@ def test_burst_forms_multiple_batches_in_one_poll():
     assert [b.rows for b in batches] == [32, 32, 32]
 
 
+# ISSUE 36: a formed batch says since when it was dispatchable (ripe_s);
+# the pump's dispatch lag is its own clock less that
+
+
+@pytest.mark.parametrize("max_rows, max_wait_s, arrivals, polled, flush, want", [
+    # polled at its deadline: no lag
+    (128, 0.010, [(0.0, 16)], 0.010, False, [("deadline", 0.010)]),
+    # polled 5 ms late: ripe since the deadline
+    (128, 0.010, [(0.0, 16)], 0.015, False, [("deadline", 0.010)]),
+    # fill: ripe since the arrival of the request that filled it (the
+    # third), whatever came after
+    (64, 10.0, [(0.0, 32), (0.001, 16), (0.003, 16), (0.004, 16)], 0.007,
+     False, [("fill", 0.003)]),
+    # a queue that filled after its oldest had expired was ripe at the expiry
+    (64, 0.002, [(0.0, 32), (0.010, 32)], 0.012, False, [("fill", 0.002)]),
+    # two batches of one poll: the second filled later
+    (64, 10.0, [(0.0, 32), (0.001, 32), (0.002, 32), (0.003, 32)], 0.004,
+     False, [("fill", 0.001), ("fill", 0.003)]),
+    # a flush is ripe only now
+    (128, 10.0, [(0.0, 8)], 0.5, True, [("flush", 0.5)]),
+])
+def test_ripe_s_is_when_the_batch_became_dispatchable(
+        max_rows, max_wait_s, arrivals, polled, flush, want):
+    co = Coalescer(max_batch_rows=max_rows, max_wait_s=max_wait_s)
+    for i, (at, rows) in enumerate(arrivals):
+        co.admit(f"t{i}", None, rows, now=at)
+    got = []
+    while (b := co.pop_ready(polled, flush=flush)) is not None:
+        got.append((b.reason, b.ripe_s))
+        assert b.formed_s == polled and b.ripe_s <= polled
+    assert got == [(r, pytest.approx(t, abs=1e-12)) for r, t in want]
+
+
 def test_oversized_and_empty_requests_raise_at_admit():
     co = Coalescer(max_batch_rows=32, max_wait_s=0.0)
     with pytest.raises(ValueError, match="exceeds max_batch_rows"):

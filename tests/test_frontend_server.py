@@ -220,7 +220,8 @@ def test_mutation_seq_gap_is_409_and_refusals_consume_position(served):
 # ---------------------------------------------------------------------------
 # ISSUE 26: the serving path's spans on /metrics and in the flight record
 
-_PHASES = ("idle", "coalesce", "prep", "enqueue", "wait", "d2h", "reply")
+_PHASES = ("idle", "hold", "coalesce", "prep", "enqueue", "wait", "d2h",
+           "reply", "other")
 
 
 def _scrape(url):
@@ -273,23 +274,26 @@ def test_served_batch_moves_every_new_sample(served):
 
 
 def test_pump_phases_add_up_to_the_pump_threads_wall_time(served):
-    """The phases are a partition of the pump thread's time: over a
-    window with traffic their seconds sum to the window's length within
-    10 % (what is left out is bookkeeping between the spans; the window's
-    two ends may each cut one idle wait of at most 50 ms)."""
+    """The phases are a partition of the pump thread's time by
+    construction: what no span of a loop turn covered is ``other``,
+    however large a loaded host makes it. Over a window with traffic
+    their seconds sum to the window's length, but for the turn that each
+    of the window's two ends cuts: an idle wait of at most 50 ms."""
     import time
 
     srv, fe, _ = served
+    time.sleep(0.12)  # the pump in its idle waits at both ends
     reg_before = _phase_seconds(_scrape(srv.url))
     t0 = time.perf_counter()
     q = np.ones((3, DIM), np.float32)
     while time.perf_counter() - t0 < 2.0:
         fe.submit("t26w", q).result(timeout=30)
         time.sleep(0.01)
+    time.sleep(0.12)
     wall = time.perf_counter() - t0
     reg_after = _phase_seconds(_scrape(srv.url))
     total = sum(reg_after[p] - reg_before[p] for p in _PHASES)
-    assert abs(total - wall) <= 0.10 * wall, (total, wall)
+    assert abs(total - wall) <= 2 * 0.05, (total, wall)
 
 
 def test_flight_spans_join_request_to_batch_to_phases(tmp_path):
@@ -450,3 +454,246 @@ def test_one_parser_for_the_raw_form():
     assert none is None and (got == rows).all()
     with pytest.raises(ValueError):
         raw_rows(rows.tobytes(), 4, ids=True)  # 48 bytes, 20 a record
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: the request's life, the dispatch lag and the server's occupancy
+# in the program's own clock — on injected clocks, so nothing here asserts a
+# wall-clock tolerance
+
+_REQUEST_PHASES = ("read", "admit", "await", "wake", "encode", "write")
+
+
+class _Ticking:
+    """A clock that moves 1/1024 s at every reading, from any thread:
+    every duration is a whole number of ticks, so sums are exact."""
+
+    def __init__(self):
+        import itertools
+
+        self._reads = itertools.count(1)
+
+    def __call__(self) -> float:
+        return next(self._reads) / 1024.0
+
+
+class _Set:
+    """A clock that reads what the test sets."""
+
+    t = 0.0
+
+    def __call__(self) -> float:
+        return self.t
+
+
+def _tiny_index():
+    rng = np.random.default_rng(36)
+    X = rng.normal(size=(256, DIM)).astype(np.float32)
+    return X, build_index(
+        X, KNNConfig(k=4, backend="serial", query_bucket=16,
+                     corpus_tile=128, query_tile=16))
+
+
+def _moved(before, after, name):
+    return after.get(name, 0.0) - before.get(name, 0.0)
+
+
+def _phase_sample(phase, route="query"):
+    return ('frontend_request_phase_seconds_total'
+            f'{{phase="{phase}",route="{route}"}}')
+
+
+@pytest.fixture(scope="module")
+def phased(tmp_path_factory):
+    """One /query through a server on a ticking clock, under a flight
+    recorder: ``(flight records, /metrics before, /metrics after)``."""
+    from mpi_knn_tpu.obs.spans import (
+        FlightRecorder,
+        read_flight,
+        set_recorder,
+    )
+
+    X, index = _tiny_index()
+    flight = tmp_path_factory.mktemp("phased") / "flight.jsonl"
+    set_recorder(FlightRecorder(str(flight), fresh=True))
+    try:
+        fe = Frontend(
+            ServeSession(index, resilience=ResiliencePolicy()),
+            SLOPolicy(max_batch_rows=16, max_wait_s=0.002,
+                      max_queue_rows=1024),
+            clock=_Ticking(),
+        ).start()
+        srv = FrontendHTTPServer(fe, port=0).start()
+        try:
+            before = _scrape(srv.url)
+            status, _ = _post(
+                srv.url, "/query", X[:4].astype("<f4").tobytes(),
+                {"Content-Type": "application/octet-stream",
+                 "X-Tenant": "phased"},
+            )
+            assert status == 200
+            after = _scrape(srv.url)
+        finally:
+            srv.stop()
+            fe.stop()
+    finally:
+        set_recorder(None)
+    return read_flight(str(flight)), before, after
+
+
+def test_six_request_phases_sum_to_the_request_span(phased):
+    """The phases come from consecutive readings of one clock: on
+    /metrics they add up to ``frontend_request_seconds_sum``, in the
+    flight record to the ``request`` span's own ``dur_s``, to the tick."""
+    from mpi_knn_tpu.obs.spans import reconstruct_spans
+
+    records, before, after = phased
+    assert _moved(before, after, "frontend_request_seconds_count") == 1
+    by_phase = {p: _moved(before, after, _phase_sample(p))
+                for p in _REQUEST_PHASES}
+    assert all(v > 0.0 for v in by_phase.values()), by_phase
+    # the registry's counters hold other tests' seconds too: exact to the
+    # rounding of their sums
+    assert sum(by_phase.values()) == pytest.approx(
+        _moved(before, after, "frontend_request_seconds_sum"), abs=1e-9)
+    spans, _ = reconstruct_spans(records)
+    (request,) = [s for s in spans
+                  if (s["cat"], s["name"]) == ("http", "request")]
+    children = [s for s in spans if s["parent"] == request["span"]]
+    assert sum(s["dur_s"] for s in children) == request["dur_s"]
+    assert request["dur_s"] * 1024 == round(request["dur_s"] * 1024)
+
+
+def test_request_phases_are_children_of_the_request_carrying_its_seq(phased):
+    from mpi_knn_tpu.obs.spans import reconstruct_spans, validate_flight
+
+    records, _, _ = phased
+    assert validate_flight(records) == []
+    spans, _ = reconstruct_spans(records)
+    (request,) = [s for s in spans
+                  if (s["cat"], s["name"]) == ("http", "request")]
+    seq = request["end_attrs"]["seq"]
+    by_name = {s["name"]: s for s in spans
+               if s["cat"] == "http" and s["name"] != "request"}
+    assert tuple(by_name) == _REQUEST_PHASES  # in the order they ran
+    for name, s in by_name.items():
+        assert s["parent"] == request["span"], name
+        assert s["dur_s"] > 0.0, name
+    for name in _REQUEST_PHASES[2:]:  # await onwards
+        assert by_name[name]["attrs"]["seq"] == seq, name
+    assert "seq" not in by_name["read"]["attrs"]
+    # the join to the batch stays as it is
+    (coalesce,) = [s for s in spans if (s["cat"], s["name"])
+                   == ("pump", "coalesce") and s["end_attrs"].get("rows")]
+    assert coalesce["end_attrs"]["request_seqs"] == [seq]
+    assert coalesce["end_attrs"]["lag_ms"] >= 0.0
+    # the drain that retired the batch is in the record; the holds are not
+    assert [s for s in spans if (s["cat"], s["name"]) == ("pump", "drain")]
+    assert not [s for s in spans if s["name"] in ("hold", "idle")]
+
+
+def test_occupancy_covers_the_requests_of_every_serving_route(phased,
+                                                              writable):
+    """``occupied`` holds the whole handler of a request, so at least its
+    span; a write is inside the server too, and has the phases a write
+    has, under its route."""
+    _, before, after = phased
+    occupied = 'frontend_occupancy_seconds_total{state="occupied"}'
+    assert (_moved(before, after, occupied)
+            >= _moved(before, after, "frontend_request_seconds_sum") > 0.0)
+    assert _moved(
+        before, after, 'frontend_occupancy_seconds_total{state="empty"}') > 0
+
+    srv, _ = writable
+    before = _scrape(srv.url)
+    ids = np.arange(7000, 7004, dtype="<i4")
+    rows = np.full((4, DIM), 3.0, "<f4")
+    assert _post(srv.url, "/upsert", ids.tobytes() + rows.tobytes(),
+                 RAW)[0] == 200
+    assert _post(srv.url, "/delete", ids.tobytes(), RAW)[0] == 200
+    after = _scrape(srv.url)
+    assert _moved(before, after, occupied) > 0.0
+    for route in ("upsert", "delete"):
+        for phase in ("read", "encode", "write"):
+            assert _moved(before, after, _phase_sample(phase, route)) > 0.0
+        assert _phase_sample("await", route) not in after
+    assert _moved(before, after, "frontend_request_seconds_count") == 0
+
+
+def test_empty_plus_occupied_is_the_elapsed_clock_overlaps_counted_once():
+    """Two requests overlap on two threads: the server is occupied from
+    the first one's entry to the last one's exit, once; the two states
+    add up to the clock's movement since the counter was made."""
+    import threading
+
+    from mpi_knn_tpu.frontend.server import Occupancy
+    from mpi_knn_tpu.obs.metrics import get_registry
+
+    def value(state):
+        return get_registry().counter(
+            "frontend_occupancy_seconds_total",
+            labels={"state": state}).value
+
+    clock = _Set()
+    clock.t = 100.0
+    occ = Occupancy(clock)
+    empty0, occupied0 = value("empty"), value("occupied")
+    second_in, first_out = threading.Event(), threading.Event()
+
+    def second():
+        clock.t = 101.5
+        with occ:
+            second_in.set()
+            assert first_out.wait(10)
+            clock.t = 103.0
+
+    clock.t = 101.0
+    t = threading.Thread(target=second)
+    with occ:
+        t.start()
+        assert second_in.wait(10)
+        clock.t = 102.0
+    first_out.set()
+    t.join(10)
+    assert not t.is_alive()
+    clock.t = 103.25
+    occ.settle()
+    assert value("empty") - empty0 == 1.0 + 0.25
+    assert value("occupied") - occupied0 == 2.0
+    clock.t = 104.0
+    occ.settle()
+    occ.settle()  # a reading with nothing to hand over
+    assert (value("empty") - empty0) + (value("occupied") - occupied0) \
+        == clock.t - 100.0
+
+
+@pytest.mark.parametrize("late_s", [0.0, 0.005])
+def test_dispatch_lag_is_the_pumps_clock_less_the_batchs_ripeness(late_s):
+    """A batch handed over at its deadline has no lag; one the pump got to
+    5 ms late has 5 ms, once a batch, and says so on its coalesce span's
+    histogram beside the queue wait (policy + lag)."""
+    from mpi_knn_tpu.obs.metrics import get_registry
+
+    X, index = _tiny_index()
+    clock = _Set()
+    fe = Frontend(  # never started: the test is the pump
+        ServeSession(index, resilience=ResiliencePolicy()),
+        SLOPolicy(max_batch_rows=16, max_wait_s=0.010, max_queue_rows=1024),
+        clock=clock,
+    )
+    fe.session.warm([16])  # its one bucket: admission does not say "warming"
+    lag = get_registry().histogram("frontend_dispatch_lag_seconds")
+    waited = get_registry().histogram("frontend_queue_wait_seconds")
+    n0, lag0, wait0 = lag.count, lag.sum, waited.sum
+    clock.t = 1.0
+    ticket = fe.submit("lagging", X[:3])
+    clock.t = 1.010 + late_s
+    (batch,) = fe.scheduler.poll(clock())
+    assert batch.reason == "deadline"
+    fe._dispatch(batch)
+    for res in fe.session.drain():
+        fe._scatter(res)
+    assert ticket.done() and ticket.done_s == clock.t
+    assert lag.count - n0 == 1
+    assert lag.sum - lag0 == pytest.approx(late_s, abs=1e-9)
+    assert waited.sum - wait0 == pytest.approx(0.010 + late_s, abs=1e-9)

@@ -1191,6 +1191,46 @@ def test_spans_module_stays_jax_free_and_inert_without_jax():
     subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
 
 
+def test_spans_chained_on_the_call_sites_readings_partition_their_parent(
+        tmp_path):
+    """``at=``: a call site hands each span of a chain ITS reading of its
+    clock, the end of one being the beginning of the next; the sinks and
+    the flight record's ``dur_s`` get the differences, so the chain adds
+    up to its parent to the bit, whatever ``perf_counter`` read meanwhile.
+    A span begun with a reading must be ended with one."""
+    from mpi_knn_tpu.obs import spans as spans_mod
+
+    path = str(tmp_path / "f.jsonl")
+    set_recorder(FlightRecorder(path))
+    took = {}
+    try:
+        request = spans_mod.begin_span(
+            "request", cat="http", at=2.0,
+            sink=lambda s: took.__setitem__("request", s))
+        read = spans_mod.begin_span(
+            "read", cat="http", parent=request, at=2.0,
+            sink=lambda s: took.__setitem__("read", s))
+        spans_mod.end_span(read, at=2.25)
+        write = spans_mod.begin_span(
+            "write", cat="http", parent=request, at=2.25, seq=3,
+            sink=lambda s: took.__setitem__("write", s))
+        with pytest.raises(ValueError, match="at="):
+            spans_mod.end_span(write)
+        spans_mod.end_span(write, at=2.75)
+        spans_mod.end_span(request, at=2.75, status=200)
+    finally:
+        set_recorder(None)
+    assert took == {"read": 0.25, "write": 0.5, "request": 0.75}
+    records = read_flight(path)
+    assert validate_flight(records) == []
+    spans, _ = reconstruct_spans(records)
+    by_name = {s["name"]: s for s in spans}
+    assert {n: s["dur_s"] for n, s in by_name.items()} == took
+    assert by_name["write"]["parent"] == by_name["request"]["span"]
+    assert by_name["write"]["attrs"] == {"seq": 3}
+    assert by_name["request"]["end_attrs"] == {"status": 200}
+
+
 def test_span_feeds_flight_record_sink_and_profiler_annotation(tmp_path):
     """One call site, three sinks: under a recorder and a profiler
     session a span is a flight record with its parent, a ``knn:<cat>.
