@@ -47,6 +47,7 @@ from mpi_knn_tpu.ops.distance import (
 from mpi_knn_tpu.ops.rerank import compress_rerank_tile
 from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
+    fused_scan_engages,
     init_topk,
     init_topk_tiles,
     lane_bin_bound_rides,
@@ -68,6 +69,10 @@ from mpi_knn_tpu.parallel.partition import (
 # scope (the ring cell's ``ring_scopes``) keeps the branches apart.
 ONEPASS_SCOPE = "knn.dist_onepass"
 MULTIPASS_SCOPE = "knn.dist_multipass"
+# the one-pass branch where one kernel walks the whole stack
+# (:func:`fused_rule`): the dot, the masks, the bound's test and *bins* of
+# every tile step are inside it
+FUSED_SCOPE = "knn.fused"
 # query-tile height from which a tile program carries the rule's branch.
 # The conditional costs one copy of the corpus tile a step (8 bytes an
 # element at the HBM rate) and saves passes - 1 of the dot's 2·q FLOPs an
@@ -86,20 +91,24 @@ def onepass_rule(cfg: KNNConfig, q_rows: int) -> bool:
     return onepass_applies(cfg) and q_rows >= ONEPASS_MIN_ROWS
 
 
-def dist_steps(took, steps: int, metric: str = "l2"):
+def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False):
     """A dispatch's tile steps by the path of their distance dot, int32
-    ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``:
-    what ``KNNResult.dist_steps`` and the counter
-    ``knn_dist_tile_steps_total`` hold. ``took`` is one verdict a
-    query-tile merge (a bool vector, made inside a program that carries
-    the branch) or, for a program without the branch, their number; each
-    merge meets ``steps`` corpus tiles."""
+    ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``,
+    from one whose one-pass steps run inside the fused kernel
+    (:func:`fused_rule`) ``[0, multi-pass, 0, fused]``: what
+    ``KNNResult.dist_steps`` and the counter ``knn_dist_tile_steps_total``
+    hold. ``took`` is one verdict a query-tile merge (a bool vector, made
+    inside a program that carries the branch) or, for a program without
+    the branch, their number; each merge meets ``steps`` corpus tiles."""
     if isinstance(took, int):
         n = took * steps
         return np.array([0, n] if metric == "l2" else [0, 0, n],
                         dtype=np.int32)
     one = jnp.sum(took, dtype=jnp.int32)
-    return jnp.stack([one, took.size - one]) * steps
+    multi = took.size - one
+    if fused:
+        return jnp.stack([0, multi, 0, one]) * steps
+    return jnp.stack([one, multi]) * steps
 
 
 class TileCounts(typing.NamedTuple):
@@ -155,6 +164,22 @@ def carried_depth(cfg: KNNConfig, q_rows: int, c_tile: int,
             or (varying and jax.default_backend() != "tpu")):
         return None
     return lane_bin_depth(q_rows, c_tile, cfg.k)
+
+
+def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
+               varying: bool = False) -> bool:
+    """Whether the one-pass branch of an engaged merge of (q_rows x
+    c_tile) tile steps at width ``dim`` is ONE kernel over the whole stack
+    (``ops/fused_scan.py``): the program carries the one-pass rule
+    (:func:`onepass_rule`) and the lists (:func:`carried_depth`), and the
+    shapes pass ``ops/topk.py fused_scan_engages`` (the bound rides; the
+    kernel's VMEM; a stack that rests row-major). Not under a checked
+    ``shard_map`` (``varying``: the ring's rounds keep the scan)."""
+    if varying or not onepass_rule(cfg, q_rows):
+        return False
+    depth = carried_depth(cfg, q_rows, c_tile)
+    return depth is not None and fused_scan_engages(
+        q_rows, c_tile, dim, depth, jnp.dtype(cfg.dtype).itemsize)
 
 
 @jax.named_scope("knn.dist")
@@ -460,7 +485,10 @@ def serve_chunk(
     best_d, best_i, rescanned, chunks, took = jax.lax.map(
         per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
     counts = TileCounts(
-        None if took is None else dist_steps(took, tiles.shape[0]),
+        None if took is None else dist_steps(
+            took, tiles.shape[0], fused=fused_rule(
+                cfg, q_tiles.shape[1], *tiles.shape[1:],
+                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))),
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
     )
@@ -561,13 +589,15 @@ def merge_tiles_into_carry(
 
     stack = (tiles, tile_ids, tile_sqs)
     if cfg.merge_schedule == "twolevel":
-        depth = carried_depth(
-            cfg, carry_d.shape[0], tiles.shape[1],
-            bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma))
+        varying = bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma)
+        depth = carried_depth(cfg, carry_d.shape[0], tiles.shape[1], varying)
         if depth is not None:
+            fused = onepass is not None and fused_rule(
+                cfg, carry_d.shape[0], *tiles.shape[1:], varying)
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                either, nested=onepass is not None)
+                either, onepass if fused else None,
+                nested=onepass is not None)
 
         def local(_, tile):
             # per-tile reduction honors cfg.precision_policy (exact single
@@ -626,7 +656,7 @@ def _varying_like(x: jax.Array, *operands):
 
 
 def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                   either, nested):
+                   either, fused, nested):
     """The engaged ``twolevel`` merge (:func:`merge_tiles_into_carry`): the
     scan over the stack's tiles carries the lane-bin lists — a step is the
     distance tile and *bins* into them, under the one-pass rule's
@@ -640,7 +670,16 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     taken from the lists themselves (``lane_bin_bound``: the finish kernel
     over their first column group, under a ``lax.cond`` at
     :func:`bound_refreshes`' steps; it only falls), and the count of the
-    chunks *bins* inserted under it."""
+    chunks *bins* inserted under it.
+
+    ``fused`` (:func:`fused_rule`; the one-pass verdict, or None): the
+    conditional sits ONCE around the scan and its one-pass branch is one
+    kernel that walks the stack (``ops/fused_scan.py``, scope
+    ``knn.fused``): the same lists and the same count, with no slice of a
+    tile, no distance tile and no list crossing HBM in a step. The other
+    branch is the scan of multi-pass steps. (Around the whole scan XLA
+    hoisted the one-pass branch's narrowing of the stack out of the loop,
+    PERF.md §6, PR 29; a narrowing inside a kernel cannot be.)"""
     from mpi_knn_tpu.ops.lane_bin import (
         lane_bin_bound,
         lane_bin_chunks,
@@ -675,33 +714,45 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         lists, _ = jax.lax.scan(step, lists, stack)
         chunks = None
     else:
-        def step(state, tile):
-            *lists, bound, inserted = state
-            *tile, due = tile
-            with jax.named_scope("knn.select"):
-                bound = jax.lax.cond(
-                    due,
-                    lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
-                    lambda: bound)
-            *lists, n = either(
-                lambda blk, blk_ids, blk_sq, ld, li, b, one: insert(
-                    ld, li, b, dist_tile(blk, blk_ids, blk_sq, one),
-                    blk_ids, depth=depth),
-                *tile, *lists, bound,
-            )
-            return (*lists, bound, inserted + n), None
+        def scan(either):
+            def step(state, tile):
+                *lists, bound, inserted = state
+                *tile, due = tile
+                with jax.named_scope("knn.select"):
+                    bound = jax.lax.cond(
+                        due,
+                        lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
+                        lambda: bound)
+                *lists, n = either(
+                    lambda blk, blk_ids, blk_sq, ld, li, b, one: insert(
+                        ld, li, b, dist_tile(blk, blk_ids, blk_sq, one),
+                        blk_ids, depth=depth),
+                    *tile, *lists, bound,
+                )
+                return (*lists, bound, inserted + n), None
 
-        # the bound starts at +inf and not at the incoming carry's k-th
-        # column: that bounds the MERGED answer, while the lists answer for
-        # this stack alone, and a stack with fewer than k values under it
-        # would leave its rows short of k candidates, flagged one and all
-        (*lists, _, inserted), _ = jax.lax.scan(
-            step,
-            (*lists,
-             varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
-             varying(jnp.int32(0))),
-            (*stack, bound_refreshes(n_tiles)),
-        )
+            # the bound starts at +inf and not at the incoming carry's k-th
+            # column: that bounds the MERGED answer, while the lists answer
+            # for this stack alone, and a stack with fewer than k values
+            # under it would leave its rows short of k candidates, flagged
+            # one and all
+            (*out, _, inserted), _ = jax.lax.scan(
+                step,
+                (*lists,
+                 varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
+                 varying(jnp.int32(0))),
+                (*stack, bound_refreshes(n_tiles)),
+            )
+            return (*out, inserted)
+
+        if fused is None:
+            *lists, inserted = scan(either)
+        else:
+            *lists, inserted = jax.lax.cond(
+                fused,
+                lambda: _fused_scan(
+                    q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth),
+                lambda: scan(lambda step, *o: step(*o, False)))
         chunks = jnp.stack(
             [inserted, n_tiles * lane_bin_chunks(q_rows, c_tile) - inserted])
     with jax.named_scope("knn.select"):
@@ -716,6 +767,20 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 functools.partial(dist_tile, scoped=False), stack, either)
     with jax.named_scope("knn.merge"):
         return *merge_topk(carry_d, carry_i, vals, ids), rescanned, chunks
+
+
+@jax.named_scope(FUSED_SCOPE)
+def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth):
+    """``ops/fused_scan.py fused_scan`` for ``cfg``: the engaged scan's
+    one-pass branch over the whole stack as one kernel, ``(lists_d,
+    lists_i, chunks inserted)``."""
+    from mpi_knn_tpu.ops.fused_scan import fused_scan
+
+    return fused_scan(
+        q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs,
+        bound_refreshes(tiles.shape[0]), k=cfg.k, depth=depth,
+        exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
+        zero_eps=cfg.zero_eps)
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg

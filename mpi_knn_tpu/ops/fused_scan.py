@@ -1,0 +1,374 @@
+"""One kernel that walks a tile stack: the one-pass distance dot, its masks,
+the row bound's test and the lane-bin insertion for every corpus tile of a
+(T, c_tile, d) stack in ONE Pallas call (``ops/topk.py`` has the selection's
+mechanism, ``ops/lane_bin.py`` the kernels this one shares its test and its
+network with, ``backends/serial.py _merge_carried`` the scan it replaces
+where ``ops/topk.py fused_scan_engages``).
+
+What leaves the tile step with it. The XLA scan's step is a slice of the
+tile, a dot fusion that writes the (q, c_tile) distance tile, the *bins*
+kernel that reads it back, and the lists (q, depth·128 distances and ids)
+crossing HBM both ways, every step. Here the grid walks the tiles: a tile is
+fetched from the stack where it rests by its ``BlockSpec`` index, narrowed
+to bf16 in the kernel a piece at a time (``chunk_groups`` column groups,
+1024 columns of an 8192-column tile: one chunk of the bound's test; both
+operands are bf16 numbers in this branch: lossless; a narrowing inside a
+Mosaic kernel cannot be hoisted out of the loop as XLA hoisted the whole
+stack's), and met with the whole query tile on the MXU (DEFAULT precision,
+float32 accumulation). The tile's distances live in VMEM scratch and
+nowhere else; the lists and the bound live in VMEM scratch from the first
+tile to the last and are written to HBM once, by an explicit copy at the
+last grid step.
+
+Same values as the scan it replaces, bit for bit on whole-number rows:
+``max(x_sq - 2 xy + y_sq, 0)`` in ``pairwise_sq_l2``'s order (the query
+side comes in as ``-2 x`` in bf16, which is exact, and ``a - b`` is
+``a + (-b)``); ``mask_tile``'s masks (a padding or tombstoned id < 0, folded
+with ``y_sq`` into one vector a piece: ``x + inf`` is ``inf``;
+``exclude_zero`` by the pair's scale; ``exclude_self`` by id); the bound
+taken anew at ``bound_refreshes``' tiles from the lists' lane minima as
+``lane_bin_bound`` takes it (the k-th smallest with multiplicity: which of
+two equal minima is knocked out first does not change the value); chunks
+tested and inserted in ascending column order a row, so ties keep the
+earlier column and the earlier tile ahead.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from mpi_knn_tpu.ops.lane_bin import (
+    _FINISH_ROWS,
+    _I32_MAX,
+    _INF,
+    _LANES,
+    _STRIP,
+    _as_i32,
+    _hit_place,
+    _hit_scratch,
+    _hit_words,
+    _insert_hit_chunks,
+    _interpret,
+    _lanes_of,
+    _out,
+    _pack_hits,
+    _row_block,
+    _rows_of,
+    chunk_groups,
+)
+from mpi_knn_tpu.ops.topk import _ZERO_RTOL_DEFAULT
+from mpi_knn_tpu.types import INVALID_ID
+
+# the rows of the id and norm planes a block holds: a (1, c_tile) block of
+# a (T, c_tile) plane is no legal block (the second-minor block dimension
+# is a multiple of 8 or the whole), so the block is the 8 tiles around the
+# one wanted and the kernel picks its row
+_PLANE_ROWS = 8
+# VMEM beyond the kernel's own buffers that the call asks for
+_VMEM_HEADROOM = 24 << 20
+
+
+def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
+                       ysq_ref, kd_out, ki_out, n_ref, kd_ref, ki_ref, b_ref,
+                       d_ref, bits_ref, idrow_ref, ycol_ref, work_ref,
+                       hit_ref, word_ref, cnt_ref, sem, *, k: int,
+                       depth: int, groups: int, exclude_self: bool,
+                       exclude_zero: bool, zero_eps: float):
+    """Grid step t of :func:`fused_scan`: tile t against the whole query
+    tile. ``due_ref`` (T,) int32 in scalar memory: the tiles that take the
+    bound anew first. ``qn_ref`` (q, d) bf16 holds -2 x, ``xsq_ref`` (q,
+    128) the query norms in every lane, ``qid_ref`` (q, 128) the query ids
+    (read under ``exclude_self``); ``c_ref`` (1, c_tile, d) is tile t;
+    ``ids_ref`` / ``ysq_ref`` (8, c_tile) the planes' rows around t. The
+    lists (``kd_ref`` / ``ki_ref``), the bound (``b_ref``), the tile's
+    dots and then distances (``d_ref`` (q, c_tile)) and the chunks' bits
+    (``bits_ref`` (q, 128)) are scratch.
+
+    The step, in the order the vector unit can afford it. (1) A piece at
+    a time (a chunk's columns), the dot on the MXU and, on its result as
+    it comes, the bound's test WITHOUT the masks and the clamp: ``z = x_sq
+    - 2 xy + y_sq'`` at or under the bound — two adds, a compare and an OR
+    a vreg, in one basic block with the dot, so the two units overlap; the
+    clamp cannot change the answer (a bound is never negative) and a mask
+    only takes values away, so the chunks this marks hold every chunk that
+    is to be inserted. (2) The marked chunks alone (one in twelve, later
+    in a scan) get their distances made whole in place — the clamp, the
+    zero and self masks — and are tested again, exactly. (3) The network
+    over the chunks that passed, as *bins* runs it."""
+    lax, i32 = jax.lax, jnp.int32
+    q, c_tile = d_ref.shape
+    strips = q // _STRIP
+    piece = groups * _LANES
+    n_chunks = c_tile // piece
+    t = _as_i32(pl.program_id(0))
+    f32 = d_ref.dtype
+    strip_shape = (_STRIP, _LANES)
+
+    @pl.when(lax.eq(t, i32(0)))
+    def _():
+        kd_ref[...] = lax.full(kd_ref.shape, _INF, f32)
+        ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, i32)
+        b_ref[...] = lax.full(b_ref.shape, _INF, f32)
+        cnt_ref[0] = i32(0)
+
+    @pl.when(lax.ne(due_ref[t], i32(0)))
+    def _():
+        # lane_bin_bound's value, from the lists where they are: the k-th
+        # smallest of a row's lane minima, by k passes of row-min and
+        # knock-out over a block of rows
+        rows = work_ref.shape[0]
+        lane = lax.broadcasted_iota(i32, (rows, _LANES), 1)
+        big = lax.full(lane.shape, _I32_MAX, i32)
+        inf = lax.full(lane.shape, _INF, f32)
+
+        def row_min(x):
+            return lax.expand_dims(lax.reduce_min(x, (1,)), (1,))
+
+        def wide(col):
+            return lax.broadcast_in_dim(col, lane.shape, (0, 1))
+
+        def block(i, carry):
+            r = pl.ds(pl.multiple_of(lax.mul(_as_i32(i), i32(rows)), rows),
+                      rows)
+            work_ref[...] = kd_ref[r, :_LANES]
+
+            def one_pass(_, m):
+                d = work_ref[...]
+                m = row_min(d)
+                first = row_min(lax.select(lax.eq(d, wide(m)), lane, big))
+                work_ref[...] = lax.select(lax.eq(lane, wide(first)), inf, d)
+                return m
+
+            m = lax.fori_loop(0, k, one_pass, lax.full((rows, 1), _INF, f32))
+            b_ref[r, :] = lax.min(b_ref[r, :], wide(m))
+            return carry
+
+        lax.fori_loop(0, q // rows, block, 0)
+
+    # the tile's row of the planes; what is per column in one vector:
+    # y_sq, +inf where the id says padding or tombstone
+    row = pl.ds(lax.rem(t, i32(_PLANE_ROWS)), 1)
+    idrow_ref[...] = ids_ref[row, :]
+    ycol_ref[...] = lax.select(
+        lax.lt(idrow_ref[...], lax.full(idrow_ref.shape, 0, i32)),
+        lax.full(ycol_ref.shape, _INF, f32), ysq_ref[row, :])
+
+    bits_ref[...] = lax.full(bits_ref.shape, 0, i32)
+
+    def dot_and_test(j, carry):
+        j = _as_i32(j)
+        cols = pl.ds(pl.multiple_of(lax.mul(j, i32(piece)), piece), piece)
+        m = lax.dot_general(
+            qn_ref[...],
+            lax.convert_element_type(c_ref[0, cols, :], jnp.bfloat16),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=f32,
+            precision=lax.Precision.DEFAULT,
+        )
+        d_ref[:, cols] = m
+        xs, bound = xsq_ref[...], b_ref[...]
+        under = None
+        for g in range(groups):
+            ys = ycol_ref[:, _lanes_of(lax.add(lax.mul(j, i32(groups)),
+                                               i32(g)))]
+            z = lax.add(
+                lax.add(xs, lax.slice(m, (0, g * _LANES),
+                                      (q, (g + 1) * _LANES))),
+                lax.broadcast_in_dim(ys, xs.shape, (0, 1)))
+            le = lax.le(z, bound)  # NaN compares false
+            under = le if under is None else lax.bitwise_or(under, le)
+        bits_ref[...] = lax.bitwise_or(bits_ref[...], lax.select(
+            under, lax.broadcast(lax.shift_left(i32(1), j), xs.shape),
+            lax.full(xs.shape, 0, i32)))
+        return carry
+
+    lax.fori_loop(0, n_chunks, dot_and_test, 0)
+    _pack_hits(lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
+
+    def value(r, g):
+        """The strip's distances of column group g: ``pairwise_sq_l2``'s
+        arithmetic and ``mask_tile``'s masks, left in ``d_ref`` for the
+        network."""
+        lanes = _lanes_of(g)
+        xs = xsq_ref[r, :]
+        ys = lax.broadcast_in_dim(ycol_ref[:, lanes], strip_shape, (0, 1))
+        v = lax.max(lax.add(lax.add(xs, d_ref[r, lanes]), ys),
+                    lax.full(strip_shape, 0, f32))
+        invalid = None
+        if exclude_zero:
+            # mask_tile's threshold for float32, by the pair's scale
+            thresh = (lax.full(strip_shape, zero_eps, f32) if zero_eps > 0.0
+                      else lax.mul(
+                          lax.full(strip_shape, _ZERO_RTOL_DEFAULT, f32),
+                          lax.add(xs, ys)))
+            invalid = lax.le(v, thresh)
+        if exclude_self:
+            own = lax.eq(lax.broadcast_in_dim(
+                idrow_ref[:, lanes], strip_shape, (0, 1)), qid_ref[r, :])
+            invalid = own if invalid is None else lax.bitwise_or(invalid, own)
+        if invalid is not None:
+            v = lax.select(invalid, lax.full(strip_shape, _INF, f32), v)
+        d_ref[r, lanes] = v
+        return v
+
+    def make_whole(s, carry):
+        s = _as_i32(s)
+        r = _rows_of(s)
+        word, bit = _hit_place(s, n_chunks)
+        marked = lax.bitwise_and(
+            lax.shift_right_logical(hit_ref[word], bit),
+            i32((1 << n_chunks) - 1))
+
+        @pl.when(lax.ne(marked, i32(0)))
+        def _():
+            bound = b_ref[r, :]
+            bits_ref[r, :] = lax.full(strip_shape, 0, i32)
+
+            def chunk_of(chunk, carry):
+                chunk = _as_i32(chunk)
+
+                @pl.when(lax.ne(lax.bitwise_and(
+                    lax.shift_right_logical(marked, chunk), i32(1)), i32(0)))
+                def _():
+                    bit = lax.broadcast(lax.shift_left(i32(1), chunk),
+                                        strip_shape)
+
+                    def group(u, bits):
+                        g = lax.add(lax.mul(chunk, i32(groups)), _as_i32(u))
+                        # <=: a tie with the bound is kept
+                        return lax.select(lax.le(value(r, g), bound), bit,
+                                          bits)
+
+                    bits_ref[r, :] = lax.bitwise_or(
+                        bits_ref[r, :], lax.fori_loop(
+                            0, groups, group,
+                            lax.full(strip_shape, 0, i32), unroll=True))
+
+                return carry
+
+            lax.fori_loop(0, n_chunks, chunk_of, 0)
+
+        return carry
+
+    lax.fori_loop(0, strips, make_whole, 0)
+    _pack_hits(lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
+    cnt_ref[0] = lax.add(cnt_ref[0], _insert_hit_chunks(
+        idrow_ref, d_ref, kd_ref, ki_ref, hit_ref, strips, n_chunks, groups,
+        depth))
+
+    @pl.when(lax.eq(t, _as_i32(lax.sub(pl.num_programs(0), 1))))
+    def _():
+        n_ref[0, 0] = cnt_ref[0]
+        copies = [pltpu.make_async_copy(kd_ref, kd_out, sem.at[0]),
+                  pltpu.make_async_copy(ki_ref, ki_out, sem.at[1])]
+        for copy in copies:
+            copy.start()
+        for copy in copies:
+            copy.wait()
+
+
+def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
+    """The VMEM :func:`fused_scan` holds for (q, d) query tiles against
+    (c_tile, d) float32 corpus tiles, in bytes: the lists, the bound and
+    the chunks' bits, the tile's distances, the tile in its two buffers
+    and a piece's bf16 copy, the query side in its two buffers, the
+    planes' rows. What the engage rule weighs and ``vmem_limit_bytes`` is
+    set from."""
+    piece = chunk_groups(c_tile) * _LANES
+    lists = 2 * q * depth * _LANES * 4
+    words = _hit_words(q // _STRIP, c_tile // piece) * (_STRIP // 2)
+    bound_and_bits = (2 * q + words) * _LANES * 4
+    dists = q * c_tile * 4
+    stack = 2 * c_tile * d * 4 + piece * d * 2
+    query = 2 * (q * d * 2 + 2 * q * _LANES * 4)
+    planes = 2 * 2 * _PLANE_ROWS * c_tile * 4 + 2 * c_tile * 4
+    work = _row_block(q, _FINISH_ROWS) * _LANES * 4
+    return lists + bound_and_bits + dists + stack + query + planes + work
+
+
+def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
+               tiles: jax.Array, tile_ids: jax.Array, tile_sqs: jax.Array,
+               due, *, k: int, depth: int, exclude_self: bool,
+               exclude_zero: bool, zero_eps: float):
+    """The carried scan of ``backends/serial.py _merge_carried`` over a
+    whole stack, in its one-pass branch and under the row bound, as one
+    kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
+    multiple of 16), ``q_ids`` (q,), ``q_sq`` (q,) their squared norms;
+    ``tiles`` (T, c_tile, d) float32 rows that are bf16 numbers,
+    ``tile_ids`` / ``tile_sqs`` (T, c_tile); ``due`` (T,) bool, the tiles
+    ahead of which the bound is taken anew (``bound_refreshes``). Returns
+    ((q, depth·128) distances, (q, depth·128) ids, chunks inserted): what
+    the scan's lists and its count end as (for rows that hold a NaN the
+    count may differ: a NaN is under no bound, +inf is under +inf)."""
+    q, d = q_x.shape
+    n_tiles, c_tile, _ = tiles.shape
+    groups = chunk_groups(c_tile)
+    width = depth * _LANES
+    strips = q // _STRIP
+    f32 = jnp.float32
+    operands = (q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs)
+    # -2 x: a power of two scales a bf16 number exactly, so the dot returns
+    # -2 xy to the bit and ``x_sq - 2 xy`` is one add
+    qn = (q_x * -2.0).astype(jnp.bfloat16)
+    xsq = jnp.broadcast_to(q_sq.astype(f32)[:, None], (q, _LANES))
+    qid = jnp.broadcast_to(q_ids.astype(jnp.int32)[:, None], (q, _LANES))
+    whole = lambda t, due: (0, 0)  # noqa: E731
+    plane = pl.BlockSpec((_PLANE_ROWS, c_tile),
+                         lambda t, due: (t // _PLANE_ROWS, 0))
+    kd, ki, n = pl.pallas_call(
+        functools.partial(
+            _fused_scan_kernel, k=k, depth=depth, groups=groups,
+            exclude_self=exclude_self, exclude_zero=exclude_zero,
+            zero_eps=zero_eps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((q, d), whole),
+                pl.BlockSpec((q, _LANES), whole),
+                pl.BlockSpec((q, _LANES), whole),
+                # the tile where it rests in the stack, by its index
+                pl.BlockSpec((1, c_tile, d), lambda t, due: (t, 0, 0)),
+                plane, plane,
+            ],
+            out_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((q, width), f32),
+                pltpu.VMEM((q, width), jnp.int32),
+                pltpu.VMEM((q, _LANES), f32),
+                pltpu.VMEM((q, c_tile), f32),
+                pltpu.VMEM((q, _LANES), jnp.int32),
+                pltpu.VMEM((1, c_tile), jnp.int32),
+                pltpu.VMEM((1, c_tile), f32),
+                pltpu.VMEM((_row_block(q, _FINISH_ROWS), _LANES), f32),
+                # the words of the tile's (strip, chunk) bits; the count
+                *_hit_scratch(strips, c_tile // _LANES // groups),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            _out((q, width), f32, *operands),
+            _out((q, width), jnp.int32, *operands),
+            _out((1, 1), jnp.int32, *operands),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the arithmetic above, and room for what Mosaic keeps of its
+            # own (a piece's dot as a value, spills)
+            vmem_limit_bytes=fused_scan_vmem_bytes(q, c_tile, d, depth)
+            + _VMEM_HEADROOM,
+        ),
+        interpret=_interpret(),
+    )(jnp.asarray(due, jnp.int32), qn, xsq, qid, tiles,
+      tile_ids.astype(jnp.int32), tile_sqs.astype(f32))
+    return kd, ki, n[0, 0]

@@ -187,130 +187,161 @@ def _bits_set(x: jax.Array) -> jax.Array:
 _CHUNK_GROUPS = 8
 
 
-def _lane_bin_bounded_kernel(ids_ref, d_ref, b_ref, ld_ref, li_ref, kd_ref,
-                             ki_ref, n_ref, hit_ref, *, depth: int,
-                             chunk_groups: int):
-    """:func:`_lane_bin_kernel` under a row bound: ``b_ref`` (rows, 128)
-    holds, in every lane of a row, a value that the row's final k-th
-    smallest cannot pass. First every strip of the block asks of each of its
-    column chunks (``chunk_groups`` groups wide) whether any value is at or
-    under its row's bound: a load, a compare and a select a vreg, the
-    answers packed a bit a (strip, chunk) into 32-bit words, and a word
-    crosses to scalar memory (``hit_ref``) by ONE chain of rotations and
-    one transfer. (A reduction a chunk cost as much as the network it was
-    to save, and a chain a strip more: PERF.md §6, PR 35.) Then a strip
-    with a bit set loads its lists, runs the compare-exchange network over
-    the chunks whose bits are set, and stores them; the other strips and
-    chunks cost a scalar test. ``n_ref`` (1, 1), scalar memory: the chunks
-    inserted, summed over the grid (a sum outside the kernel is one more
-    XLA operation a step: 0.8 us of a 57 us step, PERF.md §6, PR 35)."""
-    rows, cols = d_ref.shape
-    strips = rows // _STRIP
-    n_chunks = cols // _LANES // chunk_groups
-    per_word = 32 // n_chunks  # strips whose chunks share a word
-    n_words = -(-strips // per_word)
-    # a chunk's groups in one basic block where they are eight: the chains
-    # of so short a loop would not fill the VLIW schedule
-    unroll = next(u for u in (8, 4, 2, 1) if chunk_groups % u == 0)
-    slots = [slice(j * _LANES, (j + 1) * _LANES) for j in range(depth)]
-    lax = jax.lax
-    block, first = pl.program_id(0), pl.program_id(1)
-    i32 = jnp.int32
-    half = _STRIP // 2
+def _rows_of(s):
+    """The rows of strip ``s`` of a block."""
+    return pl.ds(pl.multiple_of(
+        jax.lax.mul(s, s.dtype.type(_STRIP)), _STRIP), _STRIP)
 
-    @pl.when(lax.eq(first, first.dtype.type(0)))
-    def _():
-        kd_ref[...] = ld_ref[...]
-        ki_ref[...] = li_ref[...]
 
-    @pl.when(lax.eq(lax.add(block, first), first.dtype.type(0)))
-    def _():
-        n_ref[0, 0] = i32(0)
+def _lanes_of(g):
+    """The lanes of column group ``g`` of a block."""
+    return pl.ds(pl.multiple_of(
+        jax.lax.mul(g, g.dtype.type(_LANES)), _LANES), _LANES)
 
-    def rows_of(s):
-        return pl.ds(pl.multiple_of(lax.mul(s, s.dtype.type(_STRIP)), _STRIP),
-                     _STRIP)
 
-    def lanes_of(g):
-        return pl.ds(pl.multiple_of(lax.mul(g, g.dtype.type(_LANES)), _LANES),
-                     _LANES)
+def _as_i32(i):  # a loop index is int64 under jax_enable_x64
+    return jax.lax.convert_element_type(i, jnp.int32)
 
-    def place(s):
-        """(word, first bit) of strip ``s``'s chunks."""
-        return (lax.div(s, s.dtype.type(per_word)),
-                lax.mul(lax.rem(s, s.dtype.type(per_word)),
+
+def _hit_place(s, n_chunks: int):
+    """(word, first bit) of strip ``s``'s chunks in the hit words: the
+    chunks of 32 // n_chunks strips share a 32-bit word."""
+    per_word = 32 // n_chunks
+    return (jax.lax.div(s, s.dtype.type(per_word)),
+            jax.lax.mul(jax.lax.rem(s, s.dtype.type(per_word)),
                         s.dtype.type(n_chunks)))
 
-    def as_i32(i):  # a loop index is int64 under jax_enable_x64
-        return lax.convert_element_type(i, i32)
 
-    def test(s, words):
-        s = as_i32(s)
-        r = rows_of(s)
+def _hit_words(strips: int, n_chunks: int) -> int:
+    """The 32-bit words that hold a block's (strip, chunk) bits."""
+    return -(-strips // (32 // n_chunks))
+
+
+def _bound_bits(value, b_ref, n_chunks: int, chunk_groups: int):
+    """The bound's test of a strip, for :func:`_pack_hits`: rows -> (16,
+    128) int32 with bit ``chunk`` set wherever ``value(rows, group)`` (the
+    strip's (16, 128) values of a column group) of one of the chunk's
+    ``chunk_groups`` groups is at or under the row's bound (``b_ref``
+    (rows, 128)): a compare and a select a vreg."""
+    lax, i32 = jax.lax, jnp.int32
+
+    def strip_bits(r):
         bound = b_ref[r, :]
         zero = lax.full(bound.shape, 0, i32)
 
         def chunk_of(chunk, bits):
-            chunk = as_i32(chunk)
+            chunk = _as_i32(chunk)
 
             def group(u, bit):
-                g = lax.add(lax.mul(chunk, i32(chunk_groups)), as_i32(u))
+                g = lax.add(lax.mul(chunk, i32(chunk_groups)), _as_i32(u))
                 # <=: a tie with the bound is kept; NaN compares false
-                return lax.select(lax.le(d_ref[r, lanes_of(g)], bound),
+                return lax.select(lax.le(value(r, g), bound),
                                   lax.broadcast(lax.shift_left(i32(1), chunk),
                                                 bound.shape), bit)
 
             return lax.bitwise_or(bits, lax.fori_loop(
                 0, chunk_groups, group, zero, unroll=True))
 
-        bits = lax.fori_loop(0, n_chunks, chunk_of, zero, unroll=True)
+        return lax.fori_loop(0, n_chunks, chunk_of, zero, unroll=True)
+
+    return strip_bits
+
+
+def _pack_hits(strip_bits, hit_ref, word_ref, strips: int, n_chunks: int):
+    """A block's (strip, chunk) answers from vector to scalar memory:
+    ``strip_bits(rows)`` is (16, 128) int32 with bit ``chunk`` set in any
+    element that says yes; the answers are packed a bit a (strip, chunk)
+    into 32-bit words, gathered a word's eight rows in ``word_ref``
+    (:func:`_hit_words` x 8, 128: a select a word a strip in registers was
+    2500 vector operations a 64-strip block, PERF.md §6, PR 37), and the
+    words cross to scalar memory (``hit_ref``) by ONE chain of rotations
+    and one transfer a word. (A reduction a chunk cost as much as the
+    network it was to save, and a chain a strip more: PERF.md §6, PR 35.)"""
+    lax, i32 = jax.lax, jnp.int32
+    half = _STRIP // 2
+    n_words = _hit_words(strips, n_chunks)
+    word_ref[...] = lax.full(word_ref.shape, 0, i32)
+
+    def test(s, carry):
+        s = _as_i32(s)
+        bits = strip_bits(_rows_of(s))
         bits = lax.bitwise_or(lax.slice(bits, (0, 0), (half, _LANES)),
                               lax.slice(bits, (half, 0), (_STRIP, _LANES)))
-        word, bit = place(s)
-        bits = lax.shift_left(bits, lax.broadcast(bit, bits.shape))
-        return tuple(
-            lax.select(lax.broadcast(lax.eq(word, word.dtype.type(w)),
-                                     bits.shape),
-                       lax.bitwise_or(acc, bits), acc)
-            for w, acc in enumerate(words))
+        word, bit = _hit_place(s, n_chunks)
+        rows = pl.ds(pl.multiple_of(lax.mul(word, i32(half)), half), half)
+        word_ref[rows, :] = lax.bitwise_or(
+            word_ref[rows, :],
+            lax.shift_left(bits, lax.broadcast(bit, bits.shape)))
+        return carry
 
-    words = lax.fori_loop(
-        0, strips, test,
-        tuple(lax.full((half, _LANES), 0, i32) for _ in range(n_words)))
-    # OR over a word's elements by rotations; the words' chains interleave
+    lax.fori_loop(0, strips, test, 0)
+    # OR over a word's elements by rotations, the words stacked so that a
+    # rotation is one operation for them all (a chain a word was 320
+    # operations to trace and lower, twice a kernel: 0.4 s of a program's
+    # set-up on the chip's host, PERF.md §6, PR 37). Rows rotate across the
+    # words' borders, and a word's last row still gathers its own rows alone
+    x = word_ref[...]
     for axis, size in ((1, _LANES), (0, half)):
         shift = size // 2
         while shift:
-            words = tuple(lax.bitwise_or(x, pltpu.roll(x, shift, axis))
-                          for x in words)
+            x = lax.bitwise_or(x, pltpu.roll(x, shift, axis))
             shift //= 2
-    for w, x in enumerate(words):
-        hit_ref[w] = lax.squeeze(lax.slice(x, (0, 0), (1, 1)), (0, 1))
+    for w in range(n_words):
+        row = (w + 1) * half - 1
+        hit_ref[w] = lax.squeeze(
+            lax.slice(x, (row, 0), (row + 1, 1)), (0, 1))
+
+
+def _hit_scratch(strips: int, n_chunks: int):
+    """The scratch of :func:`_pack_hits` for a block of ``strips`` strips:
+    the words in scalar memory and their rows in vector memory."""
+    n_words = _hit_words(strips, n_chunks)
+    return [pltpu.SMEM((n_words,), jnp.int32),
+            pltpu.VMEM((n_words * (_STRIP // 2), _LANES), jnp.int32)]
+
+
+def _insert_hit_chunks(ids_ref, d_ref, kd_ref, ki_ref, hit_ref, strips: int,
+                       n_chunks: int, chunk_groups: int, depth: int):
+    """What follows :func:`_pack_hits`: a strip with a bit set in
+    ``hit_ref`` loads its lists (``kd_ref`` / ``ki_ref``), runs the
+    compare-exchange network over the chunks of ``d_ref`` whose bits are
+    set (ids from ``ids_ref`` (1, cols)), and stores them; the other strips
+    and chunks cost a scalar test. Returns the chunks inserted (int32)."""
+    lax, i32 = jax.lax, jnp.int32
+    # a chunk's groups in one basic block where they are eight: the chains
+    # of so short a loop would not fill the VLIW schedule
+    unroll = next(u for u in (8, 4, 2, 1) if chunk_groups % u == 0)
+    slots = [slice(j * _LANES, (j + 1) * _LANES) for j in range(depth)]
 
     def strip(s, inserted):
-        s = as_i32(s)
-        r = rows_of(s)
-        word, bit = place(s)
+        s = _as_i32(s)
+        r = _rows_of(s)
+        word, bit = _hit_place(s, n_chunks)
         bits = lax.bitwise_and(
             lax.shift_right_logical(hit_ref[word], bit),
             i32((1 << n_chunks) - 1))
 
         def chunk_of(chunk, carry):
-            chunk = as_i32(chunk)
+            chunk = _as_i32(chunk)
 
             @pl.when(lax.ne(lax.bitwise_and(
                 lax.shift_right_logical(bits, chunk), i32(1)), i32(0)))
             def _():
                 def insert(step, kept):
-                    kept_d, kept_i = list(kept[:depth]), list(kept[depth:])
-                    for u in range(unroll):
+                    # the network is traced once and unrolled as it is
+                    # lowered: the same block, an eighth of the tracing
+                    def group(u, kept):
+                        kept_d, kept_i = (list(kept[:depth]),
+                                          list(kept[depth:]))
                         g = lax.add(
                             lax.mul(chunk, i32(chunk_groups)),
-                            lax.add(lax.mul(as_i32(step), i32(unroll)),
-                                    i32(u)))
-                        _insert_group(ids_ref, d_ref, r, lanes_of(g),
+                            lax.add(lax.mul(_as_i32(step), i32(unroll)),
+                                    _as_i32(u)))
+                        _insert_group(ids_ref, d_ref, r, _lanes_of(g),
                                       kept_d, kept_i)
-                    return (*kept_d, *kept_i)
+                        return (*kept_d, *kept_i)
+
+                    return lax.fori_loop(0, unroll, group, kept, unroll=True)
 
                 # the lists go through memory a chunk: vregs carried through
                 # a conditional cost several times this load and store
@@ -331,8 +362,43 @@ def _lane_bin_bounded_kernel(ids_ref, d_ref, b_ref, ld_ref, li_ref, kd_ref,
 
         return lax.add(inserted, _bits_set(bits))
 
-    n_ref[0, 0] = lax.add(
-        n_ref[0, 0], lax.fori_loop(0, strips, strip, i32(0)))
+    return lax.fori_loop(0, strips, strip, i32(0))
+
+
+def _lane_bin_bounded_kernel(ids_ref, d_ref, b_ref, ld_ref, li_ref, kd_ref,
+                             ki_ref, n_ref, hit_ref, word_ref, *, depth: int,
+                             chunk_groups: int):
+    """:func:`_lane_bin_kernel` under a row bound: ``b_ref`` (rows, 128)
+    holds, in every lane of a row, a value that the row's final k-th
+    smallest cannot pass. First the test (:func:`_bound_bits`: a load, a
+    compare and a select a vreg; :func:`_pack_hits`), then the network
+    over the chunks that hold a value at or under the bound
+    (:func:`_insert_hit_chunks`).
+    ``n_ref`` (1, 1), scalar memory: the chunks inserted, summed over the
+    grid (a sum outside the kernel is one more XLA operation a step: 0.8 us
+    of a 57 us step, PERF.md §6, PR 35)."""
+    rows, cols = d_ref.shape
+    strips = rows // _STRIP
+    n_chunks = cols // _LANES // chunk_groups
+    lax = jax.lax
+    block, first = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(lax.eq(first, first.dtype.type(0)))
+    def _():
+        kd_ref[...] = ld_ref[...]
+        ki_ref[...] = li_ref[...]
+
+    @pl.when(lax.eq(lax.add(block, first), first.dtype.type(0)))
+    def _():
+        n_ref[0, 0] = jnp.int32(0)
+
+    _pack_hits(
+        _bound_bits(lambda r, g: d_ref[r, _lanes_of(g)], b_ref, n_chunks,
+                    chunk_groups),
+        hit_ref, word_ref, strips, n_chunks)
+    n_ref[0, 0] = lax.add(n_ref[0, 0], _insert_hit_chunks(
+        ids_ref, d_ref, kd_ref, ki_ref, hit_ref, strips, n_chunks,
+        chunk_groups, depth))
 
 
 def _tile_blocks(q: int, c: int) -> tuple[int, int]:
@@ -453,8 +519,8 @@ def lane_bin_candidates_under(bound: jax.Array, dists: jax.Array,
             _out((q, depth * _LANES), jnp.int32, *operands),
             _out((1, 1), jnp.int32, *operands),
         ],
-        # the words of a block's (strip, chunk) bits
-        scratch_shapes=[pltpu.SMEM((rows // _STRIP,), jnp.int32)],
+        scratch_shapes=_hit_scratch(
+            rows // _STRIP, cols // _LANES // chunk_groups(c)),
         input_output_aliases={3: 0, 4: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")

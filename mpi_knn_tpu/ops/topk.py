@@ -205,6 +205,43 @@ def lane_bin_bound_rides(q: int, c: int, itemsize: int = 4) -> bool:
             and q * c * itemsize <= _BOUND_MAX_TILE_BYTES)
 
 
+# the VMEM the fused scan's own buffers may take: half of a v5e core's 128
+# MiB (the call asks for 24 MiB more, for what Mosaic keeps of its own: a
+# piece's dot as a value, spills)
+_FUSED_VMEM_BYTES = 64 << 20
+
+
+def fused_scan_engages(q: int, c: int, d: int, depth: int,
+                       itemsize: int = 4) -> bool:
+    """Whether the one-pass branch of a scan that carries the lane-bin
+    lists over (q, c) tiles of float32 rows ``d`` wide runs as ONE kernel
+    over the whole stack (``ops/fused_scan.py``), by the shapes alone:
+
+    - the row bound rides the scan (:func:`lane_bin_bound_rides`: the
+      kernel IS *bins* under the bound with the dot in front), over whole
+      strips of 16 rows;
+    - *memory*: the lists, the bound, the tile's distances, two buffers of
+      the tile and the query side fit the kernel's share of VMEM
+      (``fused_scan_vmem_bytes``, from which ``vmem_limit_bytes`` is set:
+      at 1024 rows, 8192 columns, d = 128, 5.2 MB of lists + 33.6 MB of
+      distances + 8.7 MB of tile + 2.6 MB of query side + 2.3 MB of
+      bound, bits, hit words and planes = 52.4 MB; 2048 rows, or
+      d = 1536, do not pass);
+    - *the stack's layout at rest*: a kernel's operand is taken
+      row-major, and the v5e keeps a (T, c, d) float32 stack row-major
+      only for d on the 128-lane grid (128, 1536); at d = 100 or 784 it
+      rests rows-minor (PERF.md §6, PR 34) and the compiler would re-lay
+      ALL of it ahead of the call. So d % 128 == 0 and nothing else.
+
+    Where it says no, the scan of tile steps stays as it is."""
+    if (itemsize != 4 or q % 16 or d % _LANES
+            or not lane_bin_bound_rides(q, c, itemsize)):
+        return False
+    from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
+
+    return fused_scan_vmem_bytes(q, c, d, depth) <= _FUSED_VMEM_BYTES
+
+
 def lane_bin_flagged_share(dists, k: int) -> tuple[float, float] | None:
     """Host-side counter of the lane-bin selection on one real (q, c) tile:
     (share of rows flagged, 1.0 if the tile step would take the fallback

@@ -34,7 +34,9 @@ class KNNResult:
       dist_steps: int32 (..., 2), the call's tile steps by the path of their
         distance dot, ``[one-pass, multi-pass]`` (``backends/serial.py
         masked_dist_tile``), or (..., 3) ``[0, 0, cosine]`` from a cosine
-        call, one row a device where the rows are counted on
+        call, or (..., 4) ``[0, multi-pass, 0, fused]`` from a call whose
+        one-pass steps ran inside the kernel that walks the whole stack
+        (``ops/fused_scan.py``), one row a device where the rows are counted on
         the ring's devices; comes with the answer, costs no wait of its
         own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
         ``knn_dist_tile_steps_total``. None from the paths that run no such

@@ -1,0 +1,178 @@
+"""The fused scan (``ops/fused_scan.py``, ISSUE 37): one kernel that walks a
+tile stack — the one-pass dot, the masks, the bound's test and *bins* of
+every tile step — returns what the engaged scan of tile steps returns, bit
+for bit on whole-number rows; the rule that engages it, by the shapes; the
+counter that says it ran. The kernel body is interpreted here; where the
+shape rule would keep a width or a height out, the tests force the kernel
+in (and the bound onto the scan it is compared with)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mpi_knn_tpu import KNNConfig, all_knn
+from mpi_knn_tpu.backends import serial
+from mpi_knn_tpu.obs.metrics import DIST_PATHS, DIST_STEPS, MetricsRegistry
+from mpi_knn_tpu.ops.distance import sq_norms
+from mpi_knn_tpu.ops.topk import fused_scan_engages, lane_bin_depth
+
+K = 10
+C_TILE = 1024  # one chunk a tile: the interpreter's time goes with it
+
+
+def _case(q, d, tiles, c_tile=C_TILE, seed=0):
+    """A whole-number stack and query tile that meet everything a merge
+    can meet: tombstoned ids inside a tile and padding ids at the stack's
+    end, queries that ARE corpus rows (a self match by id, a zero
+    distance by value), ties at the bound (few distinct distances), a row
+    whose neighbours share a lane (it fails the certificate), and a carry
+    that is not empty."""
+    rng = np.random.default_rng([seed, q, d, tiles])
+    X = rng.integers(-6, 7, (tiles, c_tile, d)).astype(np.float32)
+    X[2::3] += 16.0  # every third tile lies far off: the bound skips it
+    X = X.reshape(tiles * c_tile, d)
+    Q = rng.integers(-6, 7, (q, d)).astype(np.float32)
+    ids = np.arange(tiles * c_tile, dtype=np.int32)
+    # queries 8.. are corpus rows under their own ids; 16.. under others'
+    rows = rng.choice(min(tiles, 2) * c_tile, size=16, replace=False)
+    Q[8:24] = X[rows]
+    q_ids = np.arange(q, dtype=np.int32) + tiles * c_tile
+    q_ids[8:16] = ids[rows[:8]]
+    # row 0: eight near neighbours in lane 5 of the first tile's groups
+    Q[0] = 0.0
+    lane = 5 + 128 * np.arange(8)
+    X[lane] = 0.0
+    X[lane, np.arange(8) % d] = 1.0
+    X[lane[4:], (np.arange(4) + 1) % d] = 1.0
+    ids[rng.choice(tiles * c_tile, size=5, replace=False)] = -1  # tombstones
+    ids[-37:] = -1  # padding
+    # an incoming carry: the answer of another small stack
+    carry_d = np.sort(rng.integers(20, 60, (q, K)).astype(np.float32), axis=1)
+    carry_i = rng.integers(10**6, 2 * 10**6, (q, K)).astype(np.int32)
+    return (jnp.asarray(Q), jnp.asarray(q_ids),
+            jnp.asarray(X.reshape(tiles, c_tile, d)),
+            jnp.asarray(ids.reshape(tiles, c_tile)),
+            jnp.asarray(carry_d), jnp.asarray(carry_i))
+
+
+def _merge(monkeypatch, fused, cfg, q_x, q_ids, tiles, tile_ids, cd, ci):
+    """``merge_tiles_into_carry`` under a true one-pass verdict, as the
+    fused kernel or as the scan of tile steps under the bound."""
+    monkeypatch.setattr(serial, "fused_rule", lambda *a, **k: fused)
+    monkeypatch.setattr(serial, "lane_bin_bound_rides", lambda *a: True)
+
+    @jax.jit
+    def run(q_x, q_ids, tiles, tile_ids, cd, ci):
+        return serial.merge_tiles_into_carry(
+            q_x, q_ids, sq_norms(q_x), tiles, tile_ids,
+            serial.stack_norms(tiles, "l2"), cd, ci, cfg, jnp.asarray(True))
+
+    return jax.tree.map(np.asarray, run(q_x, q_ids, tiles, tile_ids, cd, ci))
+
+
+@pytest.mark.parametrize("q,d,tiles", [
+    *((q, d, 17) for q in (64, 256, 1024) for d in (100, 128, 784)),
+    # (the interpreter's time goes with the rows: refreshes at tiles 1, 2)
+    *((4096, d, 3) for d in (100, 128, 784)),
+    (64, 128, 1), (256, 100, 1), (1024, 128, 1),
+    (64, 128, 40), (256, 100, 40), (1024, 128, 40),
+])
+def test_fused_scan_returns_what_the_scan_of_tile_steps_returns(
+        monkeypatch, q, d, tiles):
+    cfg = KNNConfig(k=K, query_tile=q, corpus_tile=C_TILE,
+                    exclude_self=True, exclude_zero=True)
+    assert lane_bin_depth(q, C_TILE, K) is not None
+    case = _case(q, d, tiles)
+    scan = _merge(monkeypatch, False, cfg, *case)
+    fused = _merge(monkeypatch, True, cfg, *case)
+    for name, a, b in zip(("vals", "ids", "rescanned", "chunks"), scan, fused):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    vals, ids, rescanned, chunks = fused
+    assert rescanned  # row 0's neighbours share a lane
+    assert chunks.sum() == tiles * (q // 16) and chunks[0] > 0
+    assert (chunks[1] > 0) == (tiles > 2)  # the far tiles' chunks
+    assert (ids >= 0).all()  # no tombstone, no padding row
+    # a query that is a corpus row does not get that row: not under its own
+    # id, not at distance zero
+    assert (vals[8:24] > 0).all()
+
+
+@pytest.mark.parametrize("exclude_self,exclude_zero,c_tile", [
+    (False, False, C_TILE), (False, True, 2048), (True, False, 4096),
+    (True, True, 8192)])  # the cells' tile: eight chunks
+def test_fused_scan_masks_and_several_chunks_a_tile(
+        monkeypatch, exclude_self, exclude_zero, c_tile):
+    q, d, tiles = 64, 128, 9
+    cfg = KNNConfig(k=K, query_tile=q, corpus_tile=c_tile,
+                    exclude_self=exclude_self, exclude_zero=exclude_zero)
+    case = _case(q, d, tiles, c_tile)
+    scan = _merge(monkeypatch, False, cfg, *case)
+    fused = _merge(monkeypatch, True, cfg, *case)
+    for name, a, b in zip(("vals", "ids", "rescanned", "chunks"), scan, fused):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    assert fused[3][1] > 0  # the far tiles' chunks
+
+
+@pytest.mark.parametrize("q,c,d,engages,why", [
+    (1024, 8192, 128, True, "the bulk cell's 1024-row program"),
+    (256, 8192, 128, True, "from the height at which the bound rides"),
+    (1024, 8192, 256, True, "a wider row on the lane grid"),
+    (1024, 1024, 128, True, "a narrow tile"),
+    (64, 8192, 128, False, "no bound rides a 64-row bucket's scan"),
+    (4096, 8192, 128, False, "no bound rides a 128 MiB tile"),
+    (2048, 8192, 128, False, "memory: 64 MiB of distances + 10 MB of lists"),
+    (1024, 8192, 1536, False, "memory: two 48 MiB buffers of the tile"),
+    (1024, 8192, 100, False, "a width-100 stack rests rows-minor"),
+    (1024, 8192, 784, False, "a width-784 stack rests rows-minor"),
+    (1032, 8192, 128, False, "the kernel walks whole strips of 16 rows"),
+])
+def test_the_shape_rule_of_the_fused_scan(q, c, d, engages, why):
+    depth = lane_bin_depth(q, c, K)
+    assert depth is not None
+    assert fused_scan_engages(q, c, d, depth) is engages, why
+
+
+@pytest.mark.parametrize("change,engages,why", [
+    ({}, True, "the bulk cell's configuration"),
+    ({"query_tile": 512}, False, "a program without the one-pass rule"),
+    ({"metric": "cosine"}, False, "cosine keeps its program"),
+    ({"precision_policy": "mixed"}, False, "rerank keeps its program"),
+    ({"k": 200}, False, "k beyond the lane-bin rule"),
+    ({"merge_schedule": "stream"}, False, "no carried lists"),
+    ({"matmul_precision": "default"}, False, "one pass already"),
+])
+def test_which_programs_take_the_fused_scan(change, engages, why):
+    cfg = KNNConfig(**{**dict(k=K, query_tile=1024, corpus_tile=8192),
+                       **change})
+    assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128) is engages, why
+    # under a checked shard_map (the ring's rounds) the scan stays
+    assert not serial.fused_rule(cfg, cfg.query_tile, 8192, 128, True)
+
+
+def test_a_call_counts_its_fused_steps_and_matches_the_ring():
+    """A one-shot call over whole-number rows at d = 128 takes the kernel
+    in every tile step and says so (``dist_steps``' fourth column, the
+    counter's ``path="fused"``); fractional queries take the multi-pass
+    scan of the same program; the ring's rounds over the same rows (a
+    checked ``shard_map`` on four CPU devices: the scan stays) answer the
+    same."""
+    rng = np.random.default_rng(3)
+    X = rng.integers(0, 200, (4096, 128)).astype(np.float32)
+    kw = dict(k=K, query_tile=1024, corpus_tile=1024)
+    res = all_knn(X, backend="serial", **kw)
+    assert np.asarray(res.dist_steps).tolist() == [0, 0, 0, 16]
+    assert np.asarray(res.bins_chunks).sum() == 16 * 64
+    registry = MetricsRegistry()
+    registry.count_dist_steps(res.dist_steps)
+    counted = {p: registry.counter(DIST_STEPS, labels={"path": p}).value
+               for p in DIST_PATHS}
+    assert counted == {"onepass": 0, "multipass": 0, "cosine": 0, "fused": 16}
+    ring = all_knn(X, backend="ring-overlap", num_devices=4, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(ring.dists), np.asarray(res.dists))
+    # (among equal distances the full-width selection may order ids
+    # otherwise)
+    assert (np.asarray(ring.ids) == np.asarray(res.ids)).mean() > 0.999
+    frac = all_knn(X, queries=X[:1024] + 0.25, backend="serial", **kw)
+    assert np.asarray(frac.dist_steps).tolist() == [0, 4, 0, 0]

@@ -407,7 +407,10 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     copies = [ln for ln in lines
               if re.search(rf"= [fs]32{lists}\S* copy\(", ln)]
     assert not copies, copies
+    # (the kernel that walks the whole stack, ISSUE 37, makes the lists:
+    # it takes none)
     bins = [ln for ln in lines if "tpu_custom_call" in ln
+            and "knn.fused" not in ln
             and re.search(rf"= \(f32{lists}\S*, s32{lists}", ln)]
     first = 3 if bounded else 2  # after ids, the tile (and the bound)
     assert bins and all(
@@ -423,6 +426,44 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     stack_copies = [ln for ln in lines if re.search(
         rf"= f32\[{tiles},8192,\d+\]\{{2,1,0\S* copy\(", ln)]
     assert not stack_copies, stack_copies
+
+
+def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
+                                       dim: int):
+    """A program whose one-pass branch is the fused scan (ISSUE 37), as
+    the v5e compiler leaves it: the one-pass rule's conditional sits ONCE,
+    outside any loop over the corpus tiles (the only loop above it is the
+    map over query tiles), its engaged branch is one Mosaic call that
+    takes the stack and the id and norm planes as they rest — no
+    ``dynamic-slice`` and no copy of a tile, no (q, 8192) float32 distance
+    tile, no loop — and the other branch is the scan of multi-pass steps
+    with *bins* inside it."""
+    import re
+
+    blocks = re.split(r"\n(?=(?:ENTRY )?%\S+ \([^\n]*\) -> [^\n]* \{\n)",
+                      hlo)
+    calls = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "knn.fused" in ln]
+    assert len(calls) == 1, calls
+    op_name = re.search(r'op_name="([^"]*)"', calls[0]).group(1)
+    assert re.fullmatch(
+        r"jit\(knn_chunk_update\)/while/body/closed_call/cond/"
+        r"branch_1_fun/knn\.fused/pallas_call", op_name), op_name
+    branch, = [b for b in blocks if calls[0] in b]
+    assert f"f32[{tiles},8192,{dim}]" in branch.splitlines()[0]
+    operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
+    stack = re.sub(r"/\*[^*]*\*/", "", operands).split(", ")[4]
+    assert re.search(
+        rf"{re.escape(stack)} = f32\[{tiles},8192,{dim}\]\S* "
+        r"get-tuple-element\(", branch), stack
+    for gone in ("dynamic-slice", " while(", f"f32[{q},8192]", " copy("):
+        assert gone not in branch, gone
+    # the other branch: the scan, its steps' dot at the configured
+    # precision and *bins*
+    bins = [ln for ln in hlo.splitlines()
+            if "tpu_custom_call" in ln and "/bins/" in ln]
+    assert bins and all(
+        "cond/branch_0_fun/while/body" in ln for ln in bins), bins
 
 
 @pytest.mark.parametrize("cell,q,tiles,dim,precision,temp_gib", [
@@ -467,11 +508,19 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     # today's program: one dot, at the configured precision, plain scope
     assert _dist_dots(plain.as_text()) == {"": (("f32", "f32"), precision)}
     # under the rule: the engaged branch holds ONE bf16 x bf16 -> f32 dot
-    # (no operand_precision: a DEFAULT dot), the other the configured one
+    # (no operand_precision: a DEFAULT dot), the other the configured one;
+    # where one kernel walks the stack (ISSUE 37: the bulk cell's shape)
+    # the engaged branch's dot is inside it
+    fused = serial.fused_rule(cfg, q, 8192, dim)
+    assert fused == (cell == "serve-bigann10m-bulk")
     assert _dist_dots(ruled.as_text()) == {
-        "onepass": (("bf16", "bf16"), None),
+        **({} if fused else {"onepass": (("bf16", "bf16"), None)}),
         "multipass": (("f32", "f32"), precision),
     }
+    if fused:
+        _assert_one_kernel_walks_the_stack(ruled.as_text(), q, tiles, dim)
+    else:
+        assert "knn.fused" not in ruled.as_text()
     temps = [c.memory_analysis().temp_size_in_bytes / 2**30
              for c in (plain, ruled)]
     assert temps[1] <= temps[0] + 0.1 and temps[1] <= temp_gib, (cell, temps)
