@@ -83,12 +83,21 @@ FUSED_SCOPE = "knn.fused"
 # step twice, ~0.4 s at every process start on the chip's host (PERF.md §6,
 # PR 29). Twice the break-even of ``high`` pays for both.
 ONEPASS_MIN_ROWS = 1024
+# the same for a tagged index's filtered programs (:func:`serve_chunk_filtered`):
+# a coalesced batch splits by regime (``serve/tags.py``), so its scan part
+# is rarely a whole 1024-row bucket, and without the branch a 512-row part
+# would run the float32 dot in six passes and cost what 1024 rows cost.
+# From the break-even of ``highest`` up; only those programs pay the
+# second trace.
+FILTER_ONEPASS_MIN_ROWS = 256
 
 
-def onepass_rule(cfg: KNNConfig, q_rows: int) -> bool:
+def onepass_rule(cfg: KNNConfig, q_rows: int, filtered: bool = False) -> bool:
     """Whether a tile program of ``q_rows``-row query tiles carries the
-    one-pass branch: ``ops.distance.onepass_applies`` and the height."""
-    return onepass_applies(cfg) and q_rows >= ONEPASS_MIN_ROWS
+    one-pass branch: ``ops.distance.onepass_applies`` and the height
+    (``filtered``: the program masks by a predicate)."""
+    return onepass_applies(cfg) and q_rows >= (
+        FILTER_ONEPASS_MIN_ROWS if filtered else ONEPASS_MIN_ROWS)
 
 
 def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False):
@@ -192,10 +201,16 @@ def masked_dist_tile(
     blk_sq: jax.Array | None,
     cfg: KNNConfig,
     onepass: bool | None = None,
+    keep: jax.Array | None = None,
 ) -> jax.Array:
     """(q_tile × c_tile) masked distances: metric kernel → padding/self/zero
     exclusion masks. The compute half shared by both merge schedules and the
     ring backends.
+
+    ``keep`` (a tagged index's batches: :func:`filter_words`) is the
+    predicate's plane for this tile step, (q_tile, c_tile / 32) uint32
+    words, a bit a (query row, corpus slot): the fourth mask, expanded and
+    applied with the other three (``ops/topk.py mask_tile``).
 
     ``onepass`` (static) says which branch of the one-pass rule this step
     is traced for (:func:`merge_tiles_into_carry` holds the ``lax.cond``):
@@ -217,10 +232,11 @@ def masked_dist_tile(
         scope = contextlib.nullcontext()
     with scope:
         return _masked_dist_tile(
-            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass, keep)
 
 
-def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass):
+def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass,
+                      keep=None):
     """:func:`masked_dist_tile` under no scope of its own (the re-scan of
     flagged rows sits in ``knn.select/fallback`` with all it runs)."""
     if onepass:
@@ -247,7 +263,49 @@ def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass):
         exclude_zero=cfg.exclude_zero,
         zero_eps=cfg.zero_eps,
         scale=pair_scale,
+        keep=None if keep is None else filter_keep(keep, blk.shape[0]),
     )
+
+
+_FILTER_NEEDS_EXACT = (
+    "a filtered batch needs precision_policy='exact': the compress pass of "
+    "'mixed' overfetches by value and knows no predicate")
+
+# the predicate's part of a tile step, as the trace names it: the words'
+# expansion to the (q_tile, c_tile) plane (the gather of a batch's words
+# from the index's bitsets sits ahead of the scan, under the same name)
+FILTER_MASK_SCOPE = "knn.filter_mask"
+
+
+def filter_words(tag_bits: jax.Array, q_tags: jax.Array) -> jax.Array:
+    """(T, q_tile, c_tile / 32) uint32: for every corpus tile the words of
+    a query tile's predicate, a bit a (query row, slot of the tile), set
+    where the slot's row holds EVERY tag of the query row. ``tag_bits``
+    (F + 1, T, c_tile / 32) is a tagged index's bitsets
+    (``serve/tags.py``: one row a frequent tag, the last row all ones),
+    ``q_tags`` (q_tile, W) int32 the query rows' bitset rows (F: no
+    constraint). Made once a query tile, ahead of its scan over the
+    stack, whose steps each take one tile's (q_tile, c_tile / 32) slice."""
+    with jax.named_scope(FILTER_MASK_SCOPE):
+        words = tag_bits[q_tags[:, 0]]
+        for w in range(1, q_tags.shape[1]):
+            words = words & tag_bits[q_tags[:, w]]
+        return jnp.swapaxes(words, 0, 1)
+
+
+def filter_keep(words: jax.Array, c_tile: int) -> jax.Array:
+    """The (q_tile, c_tile) bool plane of a tile step's predicate from its
+    words (:func:`filter_words`): slot ``c`` of the tile is bit ``c //
+    (c_tile / 32)`` of word ``c % (c_tile / 32)``, so that the plane is the
+    words shifted 32 times, whole, laid side by side: no lane moves. (The
+    v5e compiler lays the broadcast words out anew, one copy a step in
+    fast memory, and shifts and tests inside the step's distance fusion;
+    written as 32 shifted pieces concatenated it runs 32 small fusions a
+    step: read in the program compiled for the chip.)"""
+    with jax.named_scope(FILTER_MASK_SCOPE):
+        shifts = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
+        bits = (words[:, None, :] >> shifts) & jnp.uint32(1)
+        return bits.reshape(words.shape[0], c_tile) != 0
 
 
 def local_tile_topk(
@@ -260,6 +318,7 @@ def local_tile_topk(
     cfg: KNNConfig,
     out_dtype,
     onepass: bool | None = None,
+    keep: jax.Array | None = None,
 ):
     """One corpus tile's (q, k) survivors — the per-tile reduction both
     merge schedules share, switched on ``cfg.precision_policy``:
@@ -275,11 +334,14 @@ def local_tile_topk(
       carry/checkpoint algebra is policy-independent.
     """
     if cfg.precision_policy == "mixed":
+        if keep is not None:
+            raise ValueError(_FILTER_NEEDS_EXACT)
         ld, li = compress_rerank_tile(
             q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg
         )
         return ld.astype(out_dtype), li
-    d = masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
+    d = masked_dist_tile(
+        q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass, keep)
     # under the one-pass rule both branches select the same way from tiles
     # of one type: a nested jit is traced and lowered once for the two
     select = _select_tile if onepass is None else _select_tile_once
@@ -356,6 +418,7 @@ def knn_tile_step(
     carry_i: jax.Array,
     cfg: KNNConfig,
     onepass: bool | None = None,
+    keep: jax.Array | None = None,
 ):
     """One fused (query_tile × corpus_tile) step: distances → masks → merged
     top-k, streamed into the carry. The ring backends' per-round body (a
@@ -364,13 +427,14 @@ def knn_tile_step(
         # two-pass tile reduction to k exact survivors first, then a narrow
         # (2k-wide) merge into the carry — the carry itself stays exact
         ld, li = local_tile_topk(
-            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, carry_d.dtype
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, carry_d.dtype,
+            keep=keep,
         )
         all_d = jnp.concatenate([carry_d, ld], axis=-1)
         all_i = jnp.concatenate([carry_i, li], axis=-1)
     else:
         d = masked_dist_tile(
-            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass)
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass, keep)
         all_d = jnp.concatenate([carry_d, d.astype(carry_d.dtype)], axis=-1)
         with jax.named_scope("knn.ids"):
             tile_ids = jnp.broadcast_to(blk_ids[None, :], d.shape)
@@ -434,6 +498,7 @@ def serve_chunk(
     onepass: jax.Array | None = None,  # the corpus side of the one-pass rule
     *,
     cfg: KNNConfig,
+    filt: tuple | None = None,
 ):
     """One serving batch against a device-resident corpus index: the
     queries-vs-corpus generalization of :func:`knn_chunk_update` with the
@@ -466,35 +531,63 @@ def serve_chunk(
     tile — inside the batch program, so a served batch, a one-shot call
     and a resumable round run one arithmetic and no host pass or extra
     dispatch prepares a batch; ``tile_sqs`` holds the corpus rows' inverse
-    norms, so no tile step normalises anything."""
-    if not onepass_rule(cfg, q_tiles.shape[1]):
+    norms, so no tile step normalises anything.
+
+    ``filt`` (:func:`serve_chunk_filtered`, a tagged index's batches):
+    ``(q_tags (QT, q_tile, W), tag_bits)``, a predicate a query row; its
+    words ride the scan beside the stack (:func:`filter_words`) and every
+    tile step masks by them. None: the program as it always was."""
+    if not onepass_rule(cfg, q_tiles.shape[1], filtered=filt is not None):
         onepass = None
 
     def per_query_tile(args):
-        q_x, q_ids, cd, ci = args
+        q_x, q_ids, cd, ci, *q_tags = args
         q_sq = None
         if cfg.metric == "l2":
             q_sq = sq_norms(q_x)
         else:
             q_x = unit_rows(q_x)
         one = None if onepass is None else onepass & bf16_exact(q_x)
+        words = filter_words(filt[1], *q_tags) if q_tags else None
         return *merge_tiles_into_carry(
-            q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one
+            q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one,
+            words,
         ), one
 
     best_d, best_i, rescanned, chunks, took = jax.lax.map(
-        per_query_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+        per_query_tile,
+        (q_tiles, qid_tiles, carry_d, carry_i) + (
+            () if filt is None else (filt[0],)))
     counts = TileCounts(
         None if took is None else dist_steps(
             took, tiles.shape[0], fused=fused_rule(
                 cfg, q_tiles.shape[1], *tiles.shape[1:],
-                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))),
+                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))
+            and filt is None),
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
     )
     if counts == TileCounts():
         return best_d, best_i
     return best_d, best_i, counts
+
+
+def serve_chunk_filtered(
+    q_tiles, qid_tiles, carry_d, carry_i,
+    q_tags: jax.Array,  # (QT, q_tile, W) int32 bitset rows, F = none
+    tiles, tile_ids, tile_sqs, onepass,
+    tag_bits: jax.Array,  # (F + 1, T, c_tile / 32) uint32, RESIDENT
+    *,
+    cfg: KNNConfig,
+):
+    """:func:`serve_chunk` for a tagged index (``serve/tags.py``): every
+    query row brings a predicate, the conjunction of at most W frequent
+    tags, and a corpus row is a candidate for it only where its bag holds
+    them all. The batch-owned operand sits with the batch-owned buffers,
+    the bitsets last with the resident index."""
+    return serve_chunk(
+        q_tiles, qid_tiles, carry_d, carry_i, tiles, tile_ids, tile_sqs,
+        onepass, cfg=cfg, filt=(q_tags, tag_bits))
 
 
 def merge_tiles_into_carry(
@@ -508,6 +601,7 @@ def merge_tiles_into_carry(
     carry_i: jax.Array,
     cfg: KNNConfig,
     onepass: jax.Array | None = None,
+    words: jax.Array | None = None,  # (T, q_tile, c_tile / 32)
 ):
     """Merge a stack of corpus tiles into one query tile's top-k carry, per
     ``cfg.merge_schedule``. The single implementation behind the serial
@@ -587,12 +681,14 @@ def merge_tiles_into_carry(
             *operands,
         )
 
-    stack = (tiles, tile_ids, tile_sqs)
+    # a predicate's words ride the scan as a fourth plane of the stack, a
+    # tile's slice a step (:func:`filter_words`)
+    stack = (tiles, tile_ids, tile_sqs) + (() if words is None else (words,))
     if cfg.merge_schedule == "twolevel":
         varying = bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma)
         depth = carried_depth(cfg, carry_d.shape[0], tiles.shape[1], varying)
         if depth is not None:
-            fused = onepass is not None and fused_rule(
+            fused = onepass is not None and words is None and fused_rule(
                 cfg, carry_d.shape[0], *tiles.shape[1:], varying)
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
@@ -604,9 +700,9 @@ def merge_tiles_into_carry(
             # pass vs compress-and-rerank); either way k exact-f32
             # survivors per tile feed the level-2 cascade
             return None, either(
-                lambda blk, blk_ids, blk_sq, one: local_tile_topk(
+                lambda blk, blk_ids, blk_sq, *keep_one: local_tile_topk(
                     q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg,
-                    carry_d.dtype, one,
+                    carry_d.dtype, keep_one[-1], *keep_one[:-1],
                 ),
                 *tile,
             )
@@ -632,11 +728,14 @@ def merge_tiles_into_carry(
                 block=cfg.topk_block,
             ), None, None
 
+    n_stack = len(stack)
+
     def step(carry, tile):
         return (
             either(
-                lambda blk, blk_ids, blk_sq, cd, ci, one: knn_tile_step(
-                    q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cd, ci, cfg, one
+                lambda *o: knn_tile_step(
+                    q_x, q_ids, q_sq, *o[:3], *o[n_stack:-1], cfg, o[-1],
+                    *o[3:n_stack],
                 ),
                 *tile, *carry,
             ),
@@ -692,10 +791,13 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     n_tiles, c_tile = stack[0].shape[:2]
     insert = _insert_tile_once if nested else _insert_tile
 
-    def dist_tile(blk, blk_ids, blk_sq, one, scoped=True):
+    n_stack = len(stack)  # 3, and a predicate's words where a batch has one
+
+    def dist_tile(*tile_one, scoped=True):
+        *tile, one = tile_one
         dist = masked_dist_tile if scoped else _masked_dist_tile
         return dist(
-            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, one,
+            q_x, q_ids, q_sq, *tile[:3], cfg, one, *tile[3:],
         ).astype(carry_d.dtype)
 
     def varying(x):
@@ -705,9 +807,9 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     if not lane_bin_bound_rides(q_rows, c_tile, carry_d.dtype.itemsize):
         def step(lists, tile):
             return either(
-                lambda blk, blk_ids, blk_sq, ld, li, one: insert(
-                    ld, li, None, dist_tile(blk, blk_ids, blk_sq, one),
-                    blk_ids, depth=depth),
+                lambda *o: insert(
+                    *o[n_stack:-1], None,
+                    dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
                 *tile, *lists,
             ), None
 
@@ -724,9 +826,9 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                         lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
                         lambda: bound)
                 *lists, n = either(
-                    lambda blk, blk_ids, blk_sq, ld, li, b, one: insert(
-                        ld, li, b, dist_tile(blk, blk_ids, blk_sq, one),
-                        blk_ids, depth=depth),
+                    lambda *o: insert(
+                        *o[n_stack:-1],
+                        dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
                     *tile, *lists, bound,
                 )
                 return (*lists, bound, inserted + n), None

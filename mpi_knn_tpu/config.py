@@ -312,6 +312,12 @@ class KNNConfig:
     # instead of allocating per batch. Off only for debugging (donated
     # inputs are invalidated after the call).
     donate: bool = True
+    # tags a query row may carry against an index built with tags
+    # (``build_index(..., tags=)``, serve/tags.py): the static width of the
+    # filtered batch program's per-row operand. An index without tags
+    # never reads it, and it is no part of such an index's executable
+    # fingerprint (serve/aotcache.py).
+    max_query_tags: int = 2
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
@@ -538,6 +544,9 @@ class KNNConfig:
                 "compact_tombstone_fraction must be > 0, got "
                 f"{self.compact_tombstone_fraction}"
             )
+        if self.max_query_tags < 1:
+            raise ValueError(
+                f"max_query_tags must be >= 1, got {self.max_query_tags}")
         if self.topk_block < 1:
             raise ValueError(f"topk_block must be >= 1, got {self.topk_block}")
         if self.k < 1:

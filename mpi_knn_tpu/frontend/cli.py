@@ -36,6 +36,8 @@ import sys
 import threading
 import time
 
+import numpy as np
+
 from mpi_knn_tpu.config import (
     BACKENDS,
     METRICS,
@@ -89,6 +91,17 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    "scatters fill without a recompile. 0.0 (default) = "
                    "zero-rent frozen corpus; 0.25-0.5 for mutable ones "
                    "(headroom rows ride the fixed-shape FLOPs)")
+    d.add_argument("--tags", default=None, metavar="NPZ",
+                   help="a bag of tag ids a corpus row, as a CSR in an "
+                   ".npz (arrays `indptr` of rows + 1 offsets and "
+                   "`indices`): POST /query may then carry up to "
+                   "--max-query-tags tags a row (JSON `filters`, or int32 "
+                   "after the rows of a raw body under the header "
+                   "X-Filter-Tags) and a row is answered among the corpus "
+                   "rows whose bag holds them all. The dense serial layout "
+                   "only; the index is frozen (no /upsert, /delete)")
+    d.add_argument("--max-query-tags", type=int, default=2,
+                   help="tags a query row may carry (with --tags)")
     d.add_argument("--mutation-bucket", type=int, default=256,
                    help="base row bucket of the mutation executables "
                    "(chunks pad to mutation_bucket*2^j)")
@@ -224,6 +237,7 @@ def serve_main(argv=None) -> int:
             mutation_bucket=args.mutation_bucket,
             compact_fill_threshold=args.compact_fill_threshold,
             compact_tombstone_fraction=args.compact_tombstone_fraction,
+            max_query_tags=args.max_query_tags,
         )
         policy = SLOPolicy(
             max_batch_rows=args.max_batch_rows or args.bucket,
@@ -248,9 +262,11 @@ def serve_main(argv=None) -> int:
             # background compactor supervises
             from mpi_knn_tpu.ivf import build_ivf_index
 
-            index = build_ivf_index(X, cfg)
+            index = build_ivf_index(
+                X, cfg, tags=args.tags and np.load(args.tags))
         else:
-            index = build_index(X, cfg)
+            index = build_index(
+                X, cfg, tags=args.tags and np.load(args.tags))
         # a ResiliencePolicy (even the default) builds the degradation
         # ladder the queue-driven shed walks; without one the session
         # would have only its full rung
@@ -280,6 +296,8 @@ def serve_main(argv=None) -> int:
             f"max_wait={args.max_wait_ms}ms (index+bind {build_s:.2f}s, "
             "warming in background)"
         )
+        if getattr(index, "tags", None) is not None:
+            print(f"[mpi-knn serve] tags {json.dumps(index.tags.summary())}")
         print(f"[mpi-knn serve] listening on {server.url}", flush=True)
     if args.ready_file:
         # atomic publish (utils.atomicio, host-lint rule H4): the CI

@@ -68,6 +68,10 @@ class FrontendRequest:
     rows: int
     arrival_s: float
     seq: int
+    # a predicate a row beside the payload, as opaque as it ((rows, tags)
+    # tag ids for the server, against an index built with tags); None:
+    # the request has none. It rides with the rows through ``slices``.
+    filters: object = None
 
     def wait_s(self, now: float) -> float:
         return now - self.arrival_s
@@ -136,7 +140,7 @@ class Coalescer:
     # -- admission --------------------------------------------------------
 
     def admit(self, tenant: str, queries, rows: int,
-              now: float) -> FrontendRequest:
+              now: float, filters=None) -> FrontendRequest:
         """Enqueue one request (admission control — depth/rate — is the
         scheduler's job and has already happened). Oversized and empty
         requests are caller bugs here and raise."""
@@ -151,7 +155,7 @@ class Coalescer:
             )
         req = FrontendRequest(
             tenant=str(tenant), queries=queries, rows=rows,
-            arrival_s=now, seq=next(self._seq),
+            arrival_s=now, seq=next(self._seq), filters=filters,
         )
         self._queues.setdefault(req.tenant, deque()).append(req)
         self._pending_rows += rows

@@ -218,7 +218,8 @@ class FrontendScheduler:
         self._buckets[tenant] = [tokens - 1.0, now]
         return None
 
-    def submit(self, tenant: str, queries, rows: int, now: float):
+    def submit(self, tenant: str, queries, rows: int, now: float,
+               filters=None):
         """Admit one request or refuse it: returns a
         :class:`~mpi_knn_tpu.frontend.coalesce.FrontendRequest` (admitted
         — it WILL be served) or a :class:`Rejection`. Decisions are
@@ -248,7 +249,7 @@ class FrontendScheduler:
         rej = self._take_token(tenant, now)
         if rej is not None:
             return rej
-        req = self.coalescer.admit(tenant, queries, rows, now)
+        req = self.coalescer.admit(tenant, queries, rows, now, filters)
         self.admitted += 1
         self._metrics.counter(
             "frontend_requests_total",

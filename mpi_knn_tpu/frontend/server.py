@@ -259,14 +259,23 @@ class Frontend:
 
     # -- client side ------------------------------------------------------
 
-    def submit(self, tenant: str, queries):
+    def submit(self, tenant: str, queries, filters=None):
         """Admit one request (non-blocking): a :class:`Ticket` to wait
-        on, or the scheduler's structured :class:`Rejection`."""
+        on, or the scheduler's structured :class:`Rejection`. ``filters``:
+        a predicate a row, (rows, tags) tag ids with -1 for none, against
+        an index built with tags; one the index cannot honour raises
+        ``ValueError`` (the HTTP layer's 400), it is never ignored."""
         queries = np.ascontiguousarray(queries, dtype=np.float32)
         if queries.ndim != 2:
             raise ValueError(
                 f"queries must be (rows, dim), got shape {queries.shape}"
             )
+        if filters is not None or getattr(
+                self.session.index, "tags", None) is not None:
+            from mpi_knn_tpu.serve.engine import check_filters, plan_filters
+
+            filters = check_filters(
+                self.session.index, filters, queries.shape[0])
         if not self._serving_ready.is_set() and not \
                 self.session.coalesced_ready(
                     queries.shape[0], self.policy.max_batch_rows
@@ -289,6 +298,12 @@ class Frontend:
                 retry_after_s=0.5,
                 status=503,
             )
+        if filters is not None:
+            # planned here, on the submitting thread (an HTTP handler's)
+            # and once the request is known to be servable: requests are
+            # planned side by side and the pump, which every batch waits
+            # for, only joins their plans
+            filters = plan_filters(self.session.index, filters)
         with self._lock:
             if self._stop or self._crashed is not None:
                 return Rejection(
@@ -297,7 +312,7 @@ class Frontend:
                     status=503,
                 )
             out = self.scheduler.submit(
-                tenant, queries, queries.shape[0], self._clock()
+                tenant, queries, queries.shape[0], self._clock(), filters
             )
             if isinstance(out, Rejection):
                 return out
@@ -459,6 +474,10 @@ class Frontend:
                 # live-mutation posture (ISSUE 14): the session window's
                 # upsert/delete/compaction counts
                 "mutation": posture.get("mutation", {}),
+                # an index built with tags (ISSUE 39): what its two
+                # regimes keep and where the threshold lies; absent else
+                **({"tags": ses.index.tags.summary()}
+                   if getattr(ses.index, "tags", None) is not None else {}),
                 # router mutation high-water mark (ISSUE 18): the probe
                 # loop reads per-replica lag from here
                 "applied_seq": self._applied_seq,
@@ -603,7 +622,17 @@ class Frontend:
             request_seqs=[r.seq for r in batch.parts],
         )
         self._dispatched.append(batch)
-        for res in self.session.submit(q, tenants=batch.composition()):
+        filters = None
+        if getattr(self.session.index, "tags", None) is not None:
+            # the predicates ride with the rows, planned at admission:
+            # requests of any tenants, with and without them, make one
+            # batch, whose plan is the requests' plans joined
+            from mpi_knn_tpu.serve.tags import merge_plans
+
+            with self.session.phase("plan", requests=len(batch.parts)):
+                filters = merge_plans([r.filters for r in batch.parts])
+        for res in self.session.submit(q, tenants=batch.composition(),
+                                       filters=filters):
             self._scatter(res)
 
     def _scatter(self, res) -> None:
@@ -803,22 +832,56 @@ DEFAULT_TENANT = "default"
 SEQ_HEADER = "X-Mutation-Seq"
 
 
-def raw_rows(raw: bytes, dim: int, ids: bool):
+# /query's raw form with a predicate a row: this header says how many
+# int32 tag ids a row (-1: none) follow the rows in the body; without it
+# the body is rows alone, as it always was
+FILTER_HEADER = "X-Filter-Tags"
+
+
+def raw_rows(raw: bytes, dim: int, ids: bool, tags: int = 0):
     """The one parser of the raw request form (little-endian, the row
     count from the body's length): ``(ids-or-None, (n, dim) float32
     rows)``. With ``ids`` the body is n int32 ids followed by the n rows
-    (/upsert); without, the rows alone (/query)."""
-    per_row = 4 * dim + (4 if ids else 0)
+    (/upsert); without, the rows alone (/query). ``tags`` > 0 (/query
+    with ``X-Filter-Tags``): n x tags int32 tag ids follow the rows, and a
+    third element is returned, the (n, tags) filters."""
+    per_row = 4 * dim + (4 if ids else 0) + 4 * tags
     if len(raw) % per_row:
         raise ValueError(
             f"raw body of {len(raw)} bytes is not a whole number of "
-            f"{'int32 id + ' if ids else ''}dim={dim} float32 rows "
+            f"{'int32 id + ' if ids else ''}dim={dim} float32 rows"
+            f"{f' + {tags} int32 tags' if tags else ''} "
             f"({per_row} bytes each)"
         )
     n = len(raw) // per_row
     head = 4 * n if ids else 0
-    rows = np.frombuffer(raw, dtype="<f4", offset=head).reshape(n, dim)
-    return (np.frombuffer(raw, dtype="<i4", count=n) if ids else None), rows
+    rows = np.frombuffer(
+        raw, dtype="<f4", offset=head, count=n * dim).reshape(n, dim)
+    first = np.frombuffer(raw, dtype="<i4", count=n) if ids else None
+    if not tags:
+        return first, rows
+    return first, rows, np.frombuffer(
+        raw, dtype="<i4", offset=head + 4 * n * dim).reshape(n, tags)
+
+
+def json_filters(doc: dict, rows: int):
+    """The (rows, widest) int32 filters of a JSON /query body's
+    ``"filters"`` (a list of tag ids a row, ``[]`` for none), -1 padded;
+    None where the body has none."""
+    lists = doc.get("filters")
+    if lists is None:
+        return None
+    if not isinstance(lists, list) or len(lists) != rows or not all(
+            isinstance(row, list) for row in lists):
+        raise ValueError(
+            f"filters must be one list of tag ids a query row ({rows})")
+    out = np.full((rows, max(map(len, lists), default=0)), -1, np.int64)
+    for i, row in enumerate(lists):
+        if not all(isinstance(t, int) and not isinstance(t, bool)
+                   and 0 <= t < 2 ** 31 for t in row):
+            raise ValueError(f"filters[{i}]: tag ids are whole numbers >= 0")
+        out[i, :len(row)] = row
+    return out
 
 
 def _http_handler(frontend: Frontend, request_timeout_s: float,
@@ -870,13 +933,22 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             return self.rfile.read(n), ctype == "application/octet-stream"
 
         def _read_queries(self):
-            """(rows, dim) f32 from the request body: JSON
-            ``{"queries": [[...], ...]}`` or raw little-endian f32 rows
-            at the index dim (``application/octet-stream``)."""
+            """``((rows, dim) f32, filters-or-None)`` from the request
+            body: JSON ``{"queries": [[...], ...], "filters": [[t], [t,
+            u], []]}`` or raw little-endian f32 rows at the index dim
+            (``application/octet-stream``), followed, where the header
+            ``X-Filter-Tags: W`` says so, by W int32 tag ids a row (-1:
+            none)."""
             raw, is_raw = self._read_body()
             dim = frontend.session.index.dim
             if is_raw:
-                return raw_rows(raw, dim, ids=False)[1]
+                width = self.headers.get(FILTER_HEADER)
+                if width is None:
+                    return raw_rows(raw, dim, ids=False)[1], None
+                if not width.isdigit() or not 0 < int(width) <= 64:
+                    raise ValueError(
+                        f"{FILTER_HEADER}: {width!r} is not a tag count")
+                return raw_rows(raw, dim, ids=False, tags=int(width))[1:]
             doc = json.loads(raw)
             q = np.asarray(doc["queries"], dtype=np.float32)
             if q.ndim != 2 or q.shape[1] != dim:
@@ -884,7 +956,7 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                     f"queries shape {q.shape} does not match index "
                     f"dim {dim}"
                 )
-            return q
+            return q, json_filters(doc, q.shape[0])
 
         def _reject(self, out: Rejection, phases: _Phases) -> None:
             phases.next("encode")
@@ -1039,13 +1111,13 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             """What the request's span learns on the way (the admitted
             request's ``seq`` joins it to its batch, and the status)."""
             try:
-                q = self._read_queries()
+                q, filters = self._read_queries()
+                phases.next("admit")
+                out = frontend.submit(tenant, q, filters)
             except (ValueError, KeyError, TypeError) as e:
                 phases.next("encode")
                 self._json(400, {"error": str(e)}, phases)
                 return {"status": 400}
-            phases.next("admit")
-            out = frontend.submit(tenant, q)
             if isinstance(out, Rejection):
                 self._reject(out, phases)
                 return {"status": out.status}

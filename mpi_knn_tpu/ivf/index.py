@@ -290,6 +290,13 @@ def build_ivf_index(
     """
     from mpi_knn_tpu.serve.index import CorpusIndex
 
+    if overrides.pop("tags", None) is not None or getattr(
+            corpus, "tags", None) is not None:
+        raise ValueError(
+            "a clustered (ivf) index takes no tags: its probes know no "
+            "predicate, and a filter that thins a probed bucket loses "
+            "recall silently — serve a tagged corpus from the dense serial "
+            "layout (build_index(..., tags=))")
     cfg = (config or KNNConfig()).replace(**overrides)
     if cfg.ivf_shards is not None:
         # the sharded-clustered axis: train here (single-device math —

@@ -496,6 +496,7 @@ def mask_tile(
     exclude_zero: bool = True,
     zero_eps: float = 0.0,
     scale: jax.Array | None = None,
+    keep: jax.Array | None = None,
 ) -> jax.Array:
     """Apply validity/exclusion masks to a (q, c) distance tile.
 
@@ -508,7 +509,10 @@ def mask_tile(
       points — kept for recall parity (SURVEY.md Q3). With the default
       ``zero_eps=0`` the threshold is *relative*: ``rtol · scale`` when a
       per-pair magnitude ``scale`` (q, c) — e.g. ``x_sq + y_sq`` — is given,
-      else a strict ``d <= 0`` test.
+      else a strict ``d <= 0`` test;
+    - a predicate's plane ``keep`` (q, c) bool, where a tagged index's
+      batch brings one: a candidate the query row's predicate does not
+      hold for is forced to +inf like the others.
     """
     q, c = dists.shape
     if cand_ids.ndim == 1:
@@ -525,4 +529,6 @@ def mask_tile(
         invalid = invalid | (dists <= thresh)
     if exclude_self and query_ids is not None:
         invalid = invalid | (cand_ids == query_ids[:, None])
+    if keep is not None:
+        invalid = invalid | ~keep
     return jnp.where(invalid, _INF, dists)

@@ -107,6 +107,15 @@ def index_facts(index) -> dict:
         v = getattr(index, name, None)
         if v is not None:
             facts[name] = int(v)
+    tags = getattr(index, "tags", None)
+    if tags is not None:
+        # a tagged index's programs take a predicate a query row and the
+        # bitsets (absent otherwise: no other entry's address moves)
+        facts["tags"] = {
+            "bitsets": [int(s) for s in tags.tag_bits.shape],
+            "pack": int(tags.pack),
+            "copy": tags.src is not None,
+        }
     if getattr(index, "onepass", None) is not None:
         # the batch program takes the fact as one more argument and holds
         # both branches of the one-pass rule (absent otherwise, so every
@@ -156,8 +165,13 @@ def fingerprint_facts(index, cfg, bucket: int, kind: str = "serve") -> dict:
     is unchanged."""
     from mpi_knn_tpu.serve.engine import _fingerprint_cfg
 
+    cfg_doc = dataclasses.asdict(_fingerprint_cfg(cfg))
+    if getattr(index, "tags", None) is None:
+        # read by a tagged index's programs alone: left out elsewhere, so
+        # every entry of an index without tags keeps its address
+        del cfg_doc["max_query_tags"]
     doc = {
-        "cfg": dataclasses.asdict(_fingerprint_cfg(cfg)),
+        "cfg": cfg_doc,
         "bucket": int(bucket),
         "index": index_facts(index),
         "platform": platform_facts(),

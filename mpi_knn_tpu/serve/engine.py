@@ -474,7 +474,7 @@ def _prep_queries(index, cfg: KNNConfig, exec_: _BucketExec, q):
     return qp, exec_.qids, rows
 
 
-def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
+def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids, extra=()):
     """Issue one prepared batch on the compiled executable; returns padded
     ((q_pad, k) dists, ids, exchange_stats-or-None, TileCounts)
     device results (async — not synchronized here). The stats slot is the
@@ -483,7 +483,9 @@ def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
     layouts whose batches run ``masked_dist_tile``.
     Dispatch serializes with live mutation on the per-index mutation
     lock — the resident args are read and the batch enqueued as one
-    atomic step w.r.t. any in-place store update."""
+    atomic step w.r.t. any in-place store update. ``extra``: the
+    batch-owned operands a layout's program takes after the scratch (a
+    tagged index's predicate rows, :func:`_prep_tags`)."""
     lay = index.layout
     with held(mutation_lock(index), "batch"):
         scratch = exec_.make_carry()
@@ -491,7 +493,7 @@ def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
             tiles = lay.rows(exec_.q_pad, exec_.q_tile)
             q, qids = q.reshape(*tiles, index.dim), qids.reshape(tiles)
         d, i, *rest = exec_.compiled(
-            q, qids, *scratch, *lay.resident(index)
+            q, qids, *scratch, *extra, *lay.resident(index)
         )
         if lay.tiled:
             d = d.reshape(exec_.q_pad, cfg.k)
@@ -501,6 +503,199 @@ def _run(index, cfg: KNNConfig, exec_: _BucketExec, q, qids):
             rest[0] if lay.exchange_stats else None,
             lay.batch_counts(index, exec_.q_pad, exec_.q_tile, rest),
         )
+
+
+def _prep_tags(index, exec_: _BucketExec, scan_tags) -> tuple:
+    """The predicate operand of a tagged index's scan dispatch: the rows'
+    bitset rows (``serve.tags.Plan.scan_tags``) padded to the executable's
+    rows with "no tag" and tiled as the queries are."""
+    rows, width = scan_tags.shape
+    padded = np.full((exec_.q_pad, width), index.tags.n_bitsets, np.int32)
+    padded[:rows] = scan_tags
+    return (jnp.asarray(padded.reshape(
+        *index.layout.rows(exec_.q_pad, exec_.q_tile), width)),)
+
+
+GATHER_KIND = "filter-gather"
+
+
+class _GatherCell:
+    """What ``ServeSession.warm`` reports a gather program as: compiled
+    here (the persistent AOT cache keeps batch programs only)."""
+
+    source = "compiled"
+
+
+def lower_gather(index, cfg: KNNConfig, rows: int, slots: int):
+    """A tagged index's gather program for dispatches of ``rows`` segments
+    of ``slots`` candidates (``serve.tags.gather_finish``) as a
+    ``jax.stages.Lowered``."""
+    from mpi_knn_tpu.serve.tags import gather_finish
+
+    tags = index.tags
+    src = tags.src if tags.src is not None else index.tiles
+    sds = jax.ShapeDtypeStruct
+    return jax.jit(
+        gather_finish, static_argnames=("cfg", "dim", "pack")
+    ).lower(
+        sds((rows, index.dim), index.layout.query_dtype(cfg)),
+        sds((rows, slots), jnp.int32), sds(src.shape, src.dtype),
+        cfg=cfg, dim=index.dim, pack=tags.pack,
+    )
+
+
+def get_gather_executable(index, cfg: KNNConfig, rows: int, slots: int):
+    """The compiled gather program of one dispatch shape, built at most
+    once an index: a cell of the same cache as the batch programs, counted
+    with them (``serve_executables_compiled_total``)."""
+    key = (GATHER_KIND, (rows, slots), _fingerprint_cfg(cfg))
+    compiled = index._cache.get(key)
+    if compiled is not None:
+        return compiled
+    with _key_lock(index, key):
+        compiled = index._cache.get(key)
+        if compiled is None:
+            obs_metrics.install_jax_compile_listener()
+            with obs_spans.span("compile", cat="compile", bucket=rows,
+                                backend=index.backend, kind=GATHER_KIND):
+                compiled = lower_gather(index, cfg, rows, slots).compile()
+            obs_metrics.get_registry().counter(
+                "serve_executables_compiled_total",
+                help="(bucket, config) cells compiled by the serve cache",
+            ).inc()
+            index._cache[key] = compiled
+    return compiled
+
+
+def _run_gather(index, cfg: KNNConfig, queries, cand):
+    """Issue one gather dispatch (``serve.tags.GatherPart``): ``queries``
+    the part's rows as the caller has them, ``cand`` (R, M) its candidate
+    slots. Returns padded ((R, k) dists, ids) device results (async)."""
+    compiled = get_gather_executable(index, cfg, *cand.shape)
+    q = np.zeros((cand.shape[0], index.dim), np.float32)
+    q[:queries.shape[0]] = queries
+    if cfg.center and cfg.metric == "l2" and index.mu is not None:
+        q = q - np.asarray(index.mu)
+    tags = index.tags
+    # no mutation lock: an index with tags is frozen, its arrays never move
+    return compiled(
+        jnp.asarray(q, dtype=index.layout.query_dtype(cfg)),
+        jnp.asarray(cand),
+        tags.src if tags.src is not None else index.tiles)
+
+
+def check_filters(index, filters, rows: int):
+    """A batch's predicates as the engine takes them: (rows,
+    max_query_tags) int32 tag ids, -1 for none — or None for an index
+    without tags. A predicate the index cannot honour is an error, never
+    ignored: filters against an index without tags, more tags a row than
+    the index was built for, an id under -1. (An id the index has never
+    seen is not one: it matches nothing.)"""
+    if getattr(index, "tags", None) is None:  # a clustered index has no field
+        if filters is not None:
+            raise ValueError(
+                "this index was built without tags and cannot honour a "
+                "filter (build it with tags= / --tags)")
+        return None
+    width = index.tags.width
+    if filters is None:
+        return np.full((rows, width), -1, np.int32)
+    f = np.asarray(filters)
+    if f.ndim != 2 or f.shape[0] != rows or not np.issubdtype(
+            f.dtype, np.integer):
+        raise ValueError(
+            f"filters must be whole numbers, (rows, tags a row) = ({rows}, "
+            f"<= {width}); got {f.dtype} {f.shape}")
+    if f.shape[1] > width:
+        raise ValueError(
+            f"{f.shape[1]} tags a query row, but the index was built for "
+            f"max_query_tags={width}")
+    if f.size and f.min() < -1:
+        raise ValueError("a tag id is >= 0 (-1: no tag)")
+    out = np.full((rows, width), -1, np.int32)
+    out[:, :f.shape[1]] = f
+    return out
+
+
+def _dispatch_planned(index, cfg: KNNConfig, queries, plan, phase):
+    """Dispatch a planned batch of a tagged index (``serve.tags.Plan``):
+    its scan part on the bucket's executable, then each gather part.
+    ``phase(name)`` is the caller's span maker around the host's prep and
+    the enqueues. Returns ``(bucket, TileCounts, parts)``, ``parts`` as
+    ``BatchResult.parts`` takes them; the batch is counted here
+    (:func:`_count_plan`)."""
+    from mpi_knn_tpu.backends.serial import TileCounts
+
+    parts, bucket, counts, slots = [], 0, TileCounts(), 0
+    n_scan = plan.scan_rows.size
+    if n_scan:
+        q = queries if n_scan == queries.shape[0] else queries[plan.scan_rows]
+        bucket = bucket_rows(n_scan, cfg.query_bucket)
+        exec_ = get_executable(index, cfg, bucket)
+        with phase("prep"):
+            q2d, qids, _ = _prep_queries(index, cfg, exec_, q)
+            extra = _prep_tags(index, exec_, plan.scan_tags)
+        with phase("enqueue"):
+            d, i, _, counts = _run(index, cfg, exec_, q2d, qids, extra)
+        parts.append((d, i, plan.scan_rows))
+    for part in plan.parts():
+        with phase("enqueue"):
+            d, i = _run_gather(index, cfg, queries[part.rows], part.cand)
+        parts.append((d, i, part.rows))
+        slots += part.cand.size
+    _count_plan(obs_metrics.get_registry(), plan, int(n_scan > 0),
+                len(parts) - int(n_scan > 0), slots)
+    return bucket, counts, tuple(parts)
+
+
+def plan_filters(index, filters, **attrs):
+    """The ``serve.tags.Plan`` of some rows' filters (``check_filters``'s),
+    made inside the span ``knn:filter.plan`` on the calling thread — a
+    request's at its admission (``Frontend.submit``, the handler's thread),
+    a batch's where a caller hands the session raw filters — its seconds
+    into ``filter_plan_seconds_total``."""
+    sink = obs_metrics.get_registry().counter(
+        "filter_plan_seconds_total",
+        help="host seconds planning query rows' predicates (look-ups, "
+        "intersections, the split by regime, padding), on whichever "
+        "thread planned",
+    ).inc
+    with obs_spans.span("plan", cat="filter", sink=sink,
+                        rows=int(filters.shape[0]), **attrs):
+        return index.tags.plan(filters)
+
+
+def _count_plan(registry, plan, scans: int, gathers: int, slots: int) -> None:
+    """A dispatched batch's rows by regime, its candidates and the
+    dispatches it took, into ``registry``."""
+    from mpi_knn_tpu.serve.tags import REGIMES
+
+    by_regime = np.bincount(plan.regime, minlength=len(REGIMES))
+    for name, n in zip(REGIMES, by_regime):
+        registry.counter(
+            "filter_rows_total",
+            help="query rows of a tagged index by what became of them: "
+            "none (no tag: scanned unmasked), scan (frequent tags: the "
+            "masked scan), gather (a rare tag: candidates gathered and "
+            "finished), empty (nothing matches: answered on the host)",
+            labels={"regime": name},
+        ).inc(int(n))
+    registry.counter(
+        "filter_candidates_total",
+        help="candidate slots of the gather regime's rows, before padding",
+    ).inc(plan.candidates)
+    registry.counter(
+        "filter_gather_slots_total",
+        help="candidate slots the gather programs read, padding included "
+        "(over filter_candidates_total: the regime's padding)",
+    ).inc(slots)
+    for name, n in (("scan", scans), ("gather", gathers)):
+        registry.counter(
+            "filter_dispatches_total",
+            help="device dispatches of a tagged index's batches by regime "
+            "(filter_rows_total over this: the mean rows of one)",
+            labels={"regime": name},
+        ).inc(n)
 
 
 @dataclasses.dataclass
@@ -552,13 +747,60 @@ class BatchResult:
     dist_steps: object = None
     select_tiles: object = None
     bins_chunks: object = None
+    # a tagged index's batch: ``((dists_padded, ids_padded, positions),
+    # ...)``, one entry a device dispatch (the scan part, then the gather
+    # parts), ``positions`` the batch rows its leading rows answer. Rows
+    # that no entry names matched nothing. ``dists_padded``/``ids_padded``
+    # then name the LAST dispatch (the device runs them in order: its end
+    # is the batch's), None where every row was answered on the host.
+    parts: tuple | None = None
+    k: int = 0  # the answers' width, for rows no dispatch answered
+
+    @property
+    def padded_rows(self) -> int:
+        """Rows of the padded dispatches behind this batch."""
+        if self.parts is None:
+            return self.dists_padded.shape[0]
+        return sum(d.shape[0] for d, _, _ in self.parts)
+
+    @functools.cached_property
+    def _assembled(self) -> tuple:
+        """A tagged index's answers in batch order, from its parts. A row
+        that several entries answer (its candidates went out in several
+        segments) gets the k smallest of them all, ties by the lower id."""
+        k = self.k
+        dists = np.full((self.rows, k), np.inf, np.float32)
+        ids = np.full((self.rows, k), -1, np.int32)
+        if self.parts:
+            got = [(np.asarray(d)[: len(pos)], np.asarray(i)[: len(pos)], pos)
+                   for (d, i), pos in zip(
+                       jax.device_get([p[:2] for p in self.parts]),
+                       (p[2] for p in self.parts))]
+            d, i, pos = (np.concatenate(x) for x in zip(*got))
+            if len(np.unique(pos)) < len(pos):
+                flat = np.repeat(pos, k)
+                order = np.lexsort((i.ravel(), d.ravel(), flat))
+                flat = flat[order]
+                rank = np.arange(flat.size) - np.searchsorted(flat, flat)
+                keep = order[rank < k]
+                dists[flat[rank < k], rank[rank < k]] = d.ravel()[keep]
+                ids[flat[rank < k], rank[rank < k]] = i.ravel()[keep]
+            else:
+                dists[pos], ids[pos] = d, i
+        # a slot past a row's matches is empty: it names no row
+        ids[np.isinf(dists)] = -1
+        return dists, ids
 
     @functools.cached_property
     def dists(self) -> np.ndarray:
+        if self.parts is not None:
+            return self._assembled[0]
         return np.asarray(jax.device_get(self.dists_padded))[: self.rows]
 
     @functools.cached_property
     def ids(self) -> np.ndarray:
+        if self.parts is not None:
+            return self._assembled[1]
         return np.asarray(jax.device_get(self.ids_padded))[: self.rows]
 
     @functools.cached_property
@@ -580,6 +822,7 @@ def query_knn(
     queries,
     index: CorpusIndex,
     config: KNNConfig | None = None,
+    filters=None,
     **overrides,
 ) -> KNNResult:
     """One-shot query batch against a resident index (the serving analogue
@@ -598,6 +841,17 @@ def query_knn(
         (config or index.cfg).replace(**overrides)
     )
     nq = queries.shape[0]
+    filters = check_filters(index, filters, nq)
+    if filters is not None:
+        # a tagged index (``filters``: (nq, max_query_tags) tag ids, -1
+        # none): planned and dispatched by regime, as a session's batch is
+        plan = plan_filters(index, filters)
+        bucket, counts, parts = _dispatch_planned(
+            index, cfg, queries, plan, lambda name: contextlib.nullcontext())
+        res = BatchResult(None, None, nq, bucket, parts=parts, k=cfg.k)
+        counts = jax.device_get(counts)
+        _count_tiles(obs_metrics.get_registry(), counts)
+        return KNNResult(dists=res.dists, ids=res.ids, **counts._asdict())
     bucket = bucket_rows(nq, cfg.query_bucket)
     exec_ = get_executable(index, cfg, bucket)
     q2d, qids, rows = _prep_queries(index, cfg, exec_, queries)
@@ -792,7 +1046,7 @@ class ServeSession:
         return self._metrics.counter(
             "serve_batch_phase_seconds_total",
             help="seconds of the dispatching thread by phase: idle, hold, "
-            "coalesce, prep, enqueue, wait, d2h, reply, other",
+            "coalesce, plan, prep, enqueue, wait, d2h, reply, other",
             labels={"phase": phase},
         )
 
@@ -893,6 +1147,15 @@ class ServeSession:
             distinct.setdefault((bucket, _fingerprint_cfg(cfg)),
                                 (bucket, cfg))
         cells = list(distinct.values())
+        if getattr(self.index, "tags", None) is not None:
+            # a tagged index's gather programs, one a candidate bucket
+            # (and rung): beside the batch programs, in the same pool
+            seen = {}
+            for _, cfg in self.ladder:
+                seen.setdefault(_fingerprint_cfg(cfg), cfg)
+            cells += [(GATHER_KIND, shape, cfg) for cfg in seen.values()
+                      for shape in self.index.tags.gather_shapes()]
+            raw = raw + cells[len(distinct):]
         total = len(cells)
         with self._warm_lock:
             self.warm_state = {"total": total, "ready": 0, "done": False}
@@ -902,9 +1165,18 @@ class ServeSession:
         )
 
         def _one(cell):
-            bucket, cfg = cell
-            existed = (bucket, _fingerprint_cfg(cfg)) in self.index._cache
-            exec_ = get_executable(self.index, cfg, bucket)
+            *kind, bucket, cfg = cell
+            key = (*kind, bucket, _fingerprint_cfg(cfg))
+            existed = key in self.index._cache
+            if kind:
+                # its first dispatch too (all padding): a gather program
+                # is first met in the middle of a planned batch
+                device_sync(_run_gather(
+                    self.index, cfg, np.zeros((1, self.index.dim)),
+                    np.full(bucket, -1, np.int32)))
+                exec_ = _GatherCell()
+            else:
+                exec_ = get_executable(self.index, cfg, bucket)
             with self._warm_lock:
                 self.warm_state["ready"] += 1
                 ready = self.warm_state["ready"]
@@ -1041,7 +1313,10 @@ class ServeSession:
         with self.phase("d2h", seq=res.seq, parent=res.span):
             d = res.dists  # strips padding; cached: the one D2H of dists
         bad_nan = bool(np.isnan(d).any())
-        bad_inf = bool(d.size) and bool(np.isinf(d).all(axis=1).any())
+        # a tagged index answers a row that nothing matches with k empty
+        # slots: all-inf is an answer there, NaN still is not
+        bad_inf = (res.parts is None and bool(d.size)
+                   and bool(np.isinf(d).all(axis=1).any()))
         if bad_inf and not bad_nan and res.exchange is not None \
                 and res.exchange[:, 1].sum() > 0:
             # sharded batch under probe-cap overflow: a query whose every
@@ -1378,19 +1653,38 @@ class ServeSession:
             "serve_padded_rows_total",
             help="rows of the padded batches retired (bucket height); "
             "serve_queries_total over this is the fill ratio",
-        ).inc(res.dists_padded.shape[0])
+        ).inc(res.padded_rows)
         self._metrics.histogram(
             "serve_batch_latency_seconds",
             help="per-batch dispatch→device_sync latency",
         ).observe(res.latency_s)
         return res
 
-    def _dispatch(self, queries, cfg: KNNConfig, span=None):
+    def _dispatch(self, queries, cfg: KNNConfig, span=None, filters=None):
         """One dispatch attempt under ``cfg`` (a ladder rung's config).
         The fault site models a transient transport failure; the poison
         hook injects a NaN into the returned tile for sentinel tests.
-        ``span`` is the batch's span, parent of the two phases here."""
+        ``span`` is the batch's span, parent of the two phases here.
+        ``filters`` (a tagged index): the batch's ``serve.tags.Plan``, or
+        ``check_filters``'s array, planned here in a third phase ahead of
+        the two, ``plan`` (around ``knn:filter.plan``); the batch is
+        dispatched by regime and the last element returned is
+        ``BatchResult.parts``."""
         fault_point("serve-batch")
+        if filters is not None:
+            plan = filters
+            if not hasattr(plan, "regime"):  # raw filters: planned here
+                with self.phase("plan", seq=self._seq, parent=span):
+                    plan = plan_filters(self.index, filters, seq=self._seq)
+            bucket, counts, parts = _dispatch_planned(
+                self.index, cfg, queries, plan,
+                lambda name: self.phase(name, seq=self._seq, parent=span))
+            d, i = parts[-1][:2] if parts else (None, None)
+            if parts:
+                parts = ((poison_topk(parts[0][0]), *parts[0][1:]),
+                         *parts[1:])
+            return (bucket, queries.shape[0], d, i, None, None, counts,
+                    parts)
         bucket = bucket_rows(queries.shape[0], cfg.query_bucket)
         exec_ = get_executable(self.index, cfg, bucket)
         with self.phase("prep", seq=self._seq, parent=span):
@@ -1398,15 +1692,25 @@ class ServeSession:
         with self.phase("enqueue", seq=self._seq, parent=span):
             d, i, stats, counts = _run(self.index, cfg, exec_, q2d, qids)
         return (bucket, rows, poison_topk(d), i, stats,
-                exec_.exchange_bytes, counts)
+                exec_.exchange_bytes, counts, None)
 
-    def submit(self, queries, tenants=None) -> list[BatchResult]:
+    def submit(self, queries, tenants=None, filters=None
+               ) -> list[BatchResult]:
         """Dispatch one batch; ``tenants`` is an optional
         ``((tenant, rows), ...)`` composition in row order (a coalesced
         multi-tenant batch from the serving front end) — it must sum to
         the batch's row count, or the per-tenant accounting would
-        silently mis-attribute."""
+        silently mis-attribute. ``filters``: a predicate a query row for
+        an index built with tags — (rows, tags) tag ids (``check_filters``;
+        None there: no row has one), or the batch's ``serve.tags.Plan``
+        where the caller planned already (the front end plans a request at
+        its admission and joins the plans) — refused for an index without."""
         t0 = time.perf_counter()
+        if not hasattr(filters, "regime"):  # not a Plan made at admission
+            filters = check_filters(
+                self.index, filters, int(queries.shape[0]))
+        elif len(filters.regime) != queries.shape[0]:
+            raise ValueError("the plan is not this batch's")
         if tenants is not None:
             tenants = tuple((str(t), int(n)) for t, n in tenants)
             for t, _ in tenants:
@@ -1453,13 +1757,13 @@ class ServeSession:
         try:
             if pol is not None and pol.max_retries > 0:
                 out = retry_with_backoff(
-                    lambda: self._dispatch(queries, cfg, sid),
+                    lambda: self._dispatch(queries, cfg, sid, filters),
                     retries=pol.max_retries,
                     base_s=pol.backoff_base_s,
                     max_s=pol.backoff_max_s,
                     retryable=pol.retryable,
                 )
-                bucket, rows, d, i, stats, xbytes, counts = out.value
+                bucket, rows, d, i, stats, xbytes, counts, parts = out.value
                 retries, backoffs = out.attempts - 1, out.backoffs
                 with self._stats_lock:
                     self.retries_total += retries
@@ -1473,8 +1777,8 @@ class ServeSession:
                         help="transient dispatch failures retried",
                     ).inc(retries)
             else:
-                bucket, rows, d, i, stats, xbytes, counts = (
-                    self._dispatch(queries, cfg, sid))
+                bucket, rows, d, i, stats, xbytes, counts, parts = (
+                    self._dispatch(queries, cfg, sid, filters))
                 retries, backoffs = 0, ()
         except Exception as e:
             # a RAISED dispatch failure (retries exhausted, non-retryable
@@ -1492,6 +1796,8 @@ class ServeSession:
             stats_padded=stats,
             exchange_bytes=xbytes,
             span=sid,
+            parts=parts,
+            k=cfg.k,
             **counts._asdict(),
         )
         self._seq += 1

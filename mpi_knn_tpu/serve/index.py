@@ -41,6 +41,7 @@ from mpi_knn_tpu.backends.serial import (
     _stack_norms,
     cap_corpus_tile,
     serve_chunk,
+    serve_chunk_filtered,
     tile_counts,
 )
 from mpi_knn_tpu.config import KNNConfig
@@ -188,6 +189,25 @@ class SerialLayout(BatchLayout):
             rest, q_pad // q_tile, index.tiles.shape[0], index.cfg.metric)
 
 
+class TaggedSerialLayout(SerialLayout):
+    """The serial layout of an index built with tags (``serve/tags.py``):
+    the batch program takes a predicate a query row — its frequent tags'
+    bitset rows, after the scratch — and the bitsets, after the stack."""
+
+    def serve_fn(self):
+        return serve_chunk_filtered
+
+    def query_side(self, index, cfg, q_pad, q_tile):
+        return [
+            *super().query_side(index, cfg, q_pad, q_tile),
+            jax.ShapeDtypeStruct(
+                self.rows(q_pad, q_tile) + (cfg.max_query_tags,), jnp.int32),
+        ]
+
+    def resident(self, index):
+        return (*super().resident(index), index.tags.tag_bits)
+
+
 @dataclasses.dataclass(frozen=True)
 class RingLayout(BatchLayout):
     """The padded corpus sharded over the ring axis (``backends.ring``),
@@ -300,6 +320,7 @@ class PallasLayout(BatchLayout):
 
 
 SERIAL, PALLAS = SerialLayout(), PallasLayout()
+TAGGED_SERIAL = TaggedSerialLayout()
 RING, RING_OVERLAP = RingLayout(overlap=False), RingLayout(overlap=True)
 
 
@@ -331,6 +352,10 @@ class CorpusIndex:
     # rule does not apply, or the corpus did not qualify at build): the
     # batch program has no branch and is the one it always was.
     onepass: jax.Array | None = None
+    # the bags of the rows (``serve.tags.TagIndex``), where the index was
+    # built with them: every batch then brings a predicate a query row,
+    # the layout is :class:`TaggedSerialLayout`, and the index is frozen
+    tags: object | None = None
     corpus_padded: jax.Array | None = None  # (c_pad, d) — pallas layout
     # ring layout
     mesh: Mesh | None = None
@@ -400,6 +425,15 @@ class CorpusIndex:
                 "query-side knobs: k/topk_method/merge_schedule/"
                 "precision_policy/query_bucket/dispatch_depth/donate)"
             )
+        if self.tags is not None and (
+                want.precision_policy != "exact"
+                or want.max_query_tags != self.cfg.max_query_tags):
+            raise ValueError(
+                "an index with tags serves precision_policy='exact' at the "
+                f"max_query_tags it was built with "
+                f"({self.cfg.max_query_tags}): the compress pass of 'mixed' "
+                "knows no predicate"
+            )
         if want.precision_policy == "mixed" and self.cfg.dtype != "float32":
             raise ValueError(
                 "precision_policy='mixed' cannot serve from a "
@@ -413,6 +447,7 @@ def build_index(
     corpus,
     config: Optional[KNNConfig] = None,
     mesh: Optional[Mesh] = None,
+    tags=None,
     **overrides,
 ) -> CorpusIndex:
     """Build a device-resident :class:`CorpusIndex` for query serving.
@@ -422,6 +457,12 @@ def build_index(
         tiled/sharded without a host bounce, same contract as ``all_knn``).
       config: build-time :class:`KNNConfig`; kwargs override fields.
       mesh: optional ring mesh for the distributed backends.
+      tags: optional bag of tag ids a corpus row, as a CSR (``(indptr,
+        indices)``, a mapping / ``.npz`` with those names, a scipy CSR
+        matrix): query rows may then carry up to ``max_query_tags`` tags
+        and are answered among the rows whose bag holds them all
+        (``serve/tags.py``). The dense ``serial`` layout only; the index
+        is frozen (no upsert, delete or compact).
     """
     from mpi_knn_tpu.api import resolve_backend
     from mpi_knn_tpu.obs.spans import span as _flight_span
@@ -432,10 +473,28 @@ def build_index(
         corpus = np.asarray(corpus)
     m, dim = corpus.shape
     backend = resolve_backend(cfg, mesh)
+    if tags is not None:
+        if backend != "serial":
+            raise ValueError(
+                f"an index with tags is served by the dense serial layout "
+                f"only; the {backend!r} layout has no predicate plane "
+                "(build with backend='serial')")
+        if cfg.bucket_headroom:
+            raise ValueError(
+                "an index with tags is frozen: bucket_headroom="
+                f"{cfg.bucket_headroom} reserves slots for upserts it "
+                "would refuse (build with bucket_headroom=0)")
     with _flight_span("index-build", cat="index", backend=backend,
                       rows=int(m), dim=int(dim), metric=cfg.metric,
                       bytes=int(corpus.size) * corpus.dtype.itemsize):
-        return _build_index_resident(corpus, cfg, mesh, backend, m, dim)
+        index = _build_index_resident(corpus, cfg, mesh, backend, m, dim)
+    if tags is not None:
+        from mpi_knn_tpu.serve.tags import build_tag_index
+
+        with _flight_span("tags-build", cat="index", rows=int(m)):
+            index.tags = build_tag_index(index, tags)
+        index.layout = TAGGED_SERIAL
+    return index
 
 
 @functools.partial(jax.jit, static_argnames=("c_pad", "c_tile", "dtype"))

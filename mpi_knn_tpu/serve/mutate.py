@@ -101,11 +101,22 @@ CHUNK_ROW_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
                      4096)
 
 
+TAGGED_FROZEN = (
+    "an index built with tags is frozen: a row written without a bag would "
+    "be a row no filtered query can reach, so upsert, delete and compact "
+    "are refused — rebuild the index (build_index(..., tags=)) from the "
+    "new rows and their bags"
+)
+
+
 def supports_mutation(index) -> bool:
-    return getattr(index, "backend", None) in MUTABLE_BACKENDS
+    return (getattr(index, "backend", None) in MUTABLE_BACKENDS
+            and getattr(index, "tags", None) is None)
 
 
 def _require_mutable(index) -> None:
+    if getattr(index, "tags", None) is not None:
+        raise ValueError(TAGGED_FROZEN)
     if not supports_mutation(index):
         raise ValueError(
             f"the {getattr(index, 'backend', None)!r} layout cannot honor "

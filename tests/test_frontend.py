@@ -491,3 +491,29 @@ def test_loadgen_post_counts_connection_errors():
         "t", np.zeros((1, 4), np.float32), timeout_s=2.0,
     )
     assert status == 0 and rows == 0
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 39: a predicate rides with a request's rows
+
+
+@pytest.mark.parametrize("through", ["coalescer", "scheduler"])
+def test_filters_ride_with_the_rows_through_slices(through):
+    """Requests of different tenants, with and without predicates, meet
+    in one batch; ``slices`` hands each its own back with its rows."""
+    if through == "coalescer":
+        c = Coalescer(max_batch_rows=8, max_wait_s=1.0)
+        admit = c.admit
+    else:
+        s = FrontendScheduler(SLOPolicy(max_batch_rows=8, max_wait_s=1.0,
+                                        max_queue_rows=64))
+        c, admit = s.coalescer, s.submit
+    admit("a", "rows-a", 3, 0.0, filters="tags-a")
+    admit("b", "rows-b", 2, 0.0)
+    admit("c", "rows-c", 3, 0.0, filters="tags-c")
+    batch = c.pop_ready(0.0)
+    assert batch.rows == 8 and batch.reason == "fill"
+    assert [(r.tenant, r.queries, r.filters, lo, hi)
+            for r, lo, hi in batch.slices()] == [
+        ("a", "rows-a", "tags-a", 0, 3), ("b", "rows-b", None, 3, 5),
+        ("c", "rows-c", "tags-c", 5, 8)]

@@ -697,3 +697,150 @@ def test_dispatch_lag_is_the_pumps_clock_less_the_batchs_ripeness(late_s):
     assert lag.count - n0 == 1
     assert lag.sum - lag0 == pytest.approx(late_s, abs=1e-9)
     assert waited.sum - wait0 == pytest.approx(0.010 + late_s, abs=1e-9)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 39: a predicate on every query row — the request forms of an index
+# built with tags, what is refused, and the pump's phases with ``plan``
+
+TAG_DIM, TAG_ROWS = 24, 2048
+
+
+@pytest.fixture(scope="module")
+def tagged():
+    """(server, frontend, corpus, membership): a server over an index
+    built with tags — tag 0 on a third of the rows (frequent), tag 1 on
+    forty (rare), tag 2 on three (fewer than k)."""
+    rng = np.random.default_rng(39)
+    X = rng.integers(0, 255, size=(TAG_ROWS, TAG_DIM)).astype(np.float32)
+    member = np.zeros((TAG_ROWS, 3), dtype=bool)
+    member[:, 0] = rng.random(TAG_ROWS) < 0.33
+    member[rng.choice(TAG_ROWS, 40, replace=False), 1] = True
+    member[rng.choice(TAG_ROWS, 3, replace=False), 2] = True
+    rows, tag = np.nonzero(member)
+    indptr = np.zeros(TAG_ROWS + 1, dtype=np.int64)
+    np.cumsum(np.bincount(rows, minlength=TAG_ROWS), out=indptr[1:])
+    index = build_index(
+        X, KNNConfig(k=4, backend="serial", query_bucket=64,
+                     corpus_tile=1024, query_tile=64, exclude_zero=False),
+        tags=(indptr, tag.astype(np.int32)))
+    fe = Frontend(
+        ServeSession(index, resilience=ResiliencePolicy()),
+        SLOPolicy(max_batch_rows=64, max_wait_s=0.002, max_queue_rows=8192),
+    ).start()
+    srv = FrontendHTTPServer(fe, port=0).start()
+    yield srv, fe, X, member
+    srv.stop()
+    fe.stop()
+
+
+def _nearest(X, member, q, tags, k=4):
+    ok = np.ones(TAG_ROWS, dtype=bool)
+    for t in tags:
+        ok &= member[:, t] if 0 <= t < 3 else False
+    d = ((X.astype(np.float64) - q) ** 2).sum(1)
+    order = np.argsort(np.where(ok, d, np.inf), kind="stable")[:k]
+    return [int(i) if ok[i] else -1 for i in order]
+
+
+def test_raw_and_json_filters_answer_alike(tagged):
+    srv, _, X, member = tagged
+    q = X[[5, 6, 7, 8, 9]] + 1.0
+    lists = [[0], [0, 1], [], [2], [999]]
+    status, doc = _post(
+        srv.url, "/query",
+        json.dumps({"queries": q.tolist(), "filters": lists}).encode(),
+        {"Content-Type": "application/json"})
+    assert status == 200
+    for row, tags in enumerate(lists):
+        want = _nearest(X, member, q[row], tags)
+        got = doc["ids"][row]
+        ties = [d for d in doc["dists"][row]]
+        assert got == want or sorted(ties) == ties, (row, got, want)
+        assert sum(i >= 0 for i in got) == sum(i >= 0 for i in want)
+    # fewer than k matches: the rows, then empty slots (-1, Infinity)
+    assert doc["ids"][3][3] == -1 and doc["dists"][3][3] == float("inf")
+    assert doc["ids"][4] == [-1] * 4  # an unknown tag matches nothing
+    wide = np.full((5, 2), -1, dtype="<i4")
+    for row, tags in enumerate(lists):
+        wide[row, :len(tags)] = tags
+    status, raw = _post(
+        srv.url, "/query", q.astype("<f4").tobytes() + wide.tobytes(),
+        {"Content-Type": "application/octet-stream", "X-Filter-Tags": "2"})
+    assert status == 200 and raw["ids"] == doc["ids"]
+    # without the header the body parses as it always did: rows alone
+    status, plain = _post(
+        srv.url, "/query", q.astype("<f4").tobytes(),
+        {"Content-Type": "application/octet-stream"})
+    assert status == 200 and plain["ids"][2] == doc["ids"][2]
+    assert plain["ids"][4] != [-1] * 4
+
+
+@pytest.mark.parametrize("body, headers, why", [
+    (json.dumps({"queries": [[0.0] * TAG_DIM], "filters": [[0, 1, 2]]}),
+     {}, "max_query_tags"),
+    (json.dumps({"queries": [[0.0] * TAG_DIM], "filters": [[0], [1]]}),
+     {}, "one list"),
+    (json.dumps({"queries": [[0.0] * TAG_DIM], "filters": [[-3]]}),
+     {}, "whole numbers"),
+    (json.dumps({"queries": [[0.0] * TAG_DIM], "filters": [[0.5]]}),
+     {}, "whole numbers"),
+    (json.dumps({"queries": [[0.0] * TAG_DIM], "filters": "0"}),
+     {}, "one list"),
+    (b"\x00" * (4 * TAG_DIM + 8), {"X-Filter-Tags": "3"}, "whole number of"),
+    (b"\x00" * (4 * TAG_DIM + 4), {"X-Filter-Tags": "x"}, "tag count"),
+    (np.zeros(TAG_DIM, "<f4").tobytes() + np.array([-2], "<i4").tobytes(),
+     {"X-Filter-Tags": "1"}, ">= 0"),
+], ids=["too-many", "rows-mismatch", "negative", "fraction", "not-lists",
+        "raw-length", "raw-header", "raw-negative"])
+def test_malformed_filters_are_400(tagged, body, headers, why):
+    srv, _, _, _ = tagged
+    raw = isinstance(body, bytes)
+    with pytest.raises(urllib.error.HTTPError) as ei:
+        _post(srv.url, "/query", body if raw else body.encode(),
+              {"Content-Type": "application/octet-stream" if raw
+               else "application/json", **headers})
+    assert ei.value.code == 400
+    assert why in json.loads(ei.value.read())["error"]
+
+
+def test_a_filter_an_index_cannot_honour_is_400_never_ignored(served):
+    srv, _, _ = served
+    q = np.zeros((1, DIM), "<f4")
+    for data, headers in (
+        (json.dumps({"queries": q.tolist(), "filters": [[1]]}).encode(),
+         {"Content-Type": "application/json"}),
+        (q.tobytes() + np.array([1], "<i4").tobytes(),
+         {"Content-Type": "application/octet-stream",
+          "X-Filter-Tags": "1"}),
+    ):
+        with pytest.raises(urllib.error.HTTPError) as ei:
+            _post(srv.url, "/query", data, headers)
+        assert ei.value.code == 400
+        assert "built without tags" in json.loads(ei.value.read())["error"]
+
+
+@pytest.mark.parametrize("path, body", [
+    ("/upsert", {"ids": [1], "rows": [[0.0] * TAG_DIM]}),
+    ("/delete", {"ids": [1]}),
+])
+def test_writes_to_a_tagged_index_are_refused(tagged, path, body):
+    srv, _, _, _ = tagged
+    with pytest.raises(urllib.error.HTTPError) as ei:
+        _post(srv.url, path, json.dumps(body).encode(),
+              {"Content-Type": "application/json"})
+    assert ei.value.code == 400
+    assert "built with tags is frozen" in json.loads(
+        ei.value.read())["error"]
+
+
+def test_the_raw_parser_takes_tags_after_the_rows():
+    from mpi_knn_tpu.frontend.server import raw_rows
+
+    rows = np.arange(12, dtype="<f4").reshape(3, 4)
+    tags = np.array([[1, -1], [2, 3], [-1, -1]], "<i4")
+    none, got, f = raw_rows(rows.tobytes() + tags.tobytes(), 4, ids=False,
+                            tags=2)
+    assert none is None and (got == rows).all() and (f == tags).all()
+    with pytest.raises(ValueError):
+        raw_rows(rows.tobytes() + tags.tobytes(), 4, ids=False, tags=3)
