@@ -176,18 +176,22 @@ def carried_depth(cfg: KNNConfig, q_rows: int, c_tile: int,
 
 
 def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
-               varying: bool = False) -> bool:
+               varying: bool = False) -> int | None:
     """Whether the one-pass branch of an engaged merge of (q_rows x
     c_tile) tile steps at width ``dim`` is ONE kernel over the whole stack
-    (``ops/fused_scan.py``): the program carries the one-pass rule
+    (``ops/fused_scan.py``) — the height of the row blocks it walks the
+    query tile in, or None: the program carries the one-pass rule
     (:func:`onepass_rule`) and the lists (:func:`carried_depth`), and the
-    shapes pass ``ops/topk.py fused_scan_engages`` (the bound rides; the
-    kernel's VMEM; a stack that rests row-major). Not under a checked
-    ``shard_map`` (``varying``: the ring's rounds keep the scan)."""
+    shapes pass ``ops/topk.py fused_scan_engages`` (a block height at
+    which the bound rides and the kernel's VMEM fits; a stack that rests
+    in a form the kernel takes). Not under a checked ``shard_map``
+    (``varying``: the ring's rounds keep the scan)."""
     if varying or not onepass_rule(cfg, q_rows):
-        return False
+        return None
     depth = carried_depth(cfg, q_rows, c_tile)
-    return depth is not None and fused_scan_engages(
+    if depth is None:
+        return None
+    return fused_scan_engages(
         q_rows, c_tile, dim, depth, jnp.dtype(cfg.dtype).itemsize)
 
 
@@ -560,10 +564,9 @@ def serve_chunk(
             () if filt is None else (filt[0],)))
     counts = TileCounts(
         None if took is None else dist_steps(
-            took, tiles.shape[0], fused=fused_rule(
+            took, tiles.shape[0], fused=filt is None and bool(fused_rule(
                 cfg, q_tiles.shape[1], *tiles.shape[1:],
-                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))
-            and filt is None),
+                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma)))),
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
     )
@@ -688,11 +691,13 @@ def merge_tiles_into_carry(
         varying = bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma)
         depth = carried_depth(cfg, carry_d.shape[0], tiles.shape[1], varying)
         if depth is not None:
-            fused = onepass is not None and words is None and fused_rule(
-                cfg, carry_d.shape[0], *tiles.shape[1:], varying)
+            block = None
+            if onepass is not None and words is None:
+                block = fused_rule(
+                    cfg, carry_d.shape[0], *tiles.shape[1:], varying)
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                either, onepass if fused else None,
+                either, onepass if block else None, block,
                 nested=onepass is not None)
 
         def local(_, tile):
@@ -755,7 +760,7 @@ def _varying_like(x: jax.Array, *operands):
 
 
 def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                   either, fused, nested):
+                   either, fused, block, nested):
     """The engaged ``twolevel`` merge (:func:`merge_tiles_into_carry`): the
     scan over the stack's tiles carries the lane-bin lists — a step is the
     distance tile and *bins* into them, under the one-pass rule's
@@ -771,14 +776,19 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     :func:`bound_refreshes`' steps; it only falls), and the count of the
     chunks *bins* inserted under it.
 
-    ``fused`` (:func:`fused_rule`; the one-pass verdict, or None): the
-    conditional sits ONCE around the scan and its one-pass branch is one
-    kernel that walks the stack (``ops/fused_scan.py``, scope
-    ``knn.fused``): the same lists and the same count, with no slice of a
-    tile, no distance tile and no list crossing HBM in a step. The other
-    branch is the scan of multi-pass steps. (Around the whole scan XLA
-    hoisted the one-pass branch's narrowing of the stack out of the loop,
-    PERF.md §6, PR 29; a narrowing inside a kernel cannot be.)"""
+    ``fused`` (the one-pass verdict where :func:`fused_rule` gives a
+    ``block`` height, else None): the conditional sits ONCE around the
+    scan and its one-pass branch is one kernel that walks the stack
+    (``ops/fused_scan.py``, scope ``knn.fused``), the query tile in blocks
+    of ``block`` rows: the same lists and the same count, with no slice of
+    a tile, no distance tile and no list crossing HBM in a step. The bound
+    rides at the block's height, so the rule is asked ahead of
+    ``lane_bin_bound_rides``: a 4096-row tile takes the kernel in four
+    blocks, and its other branch, the scan of multi-pass steps, stays the
+    unbounded one it was and counts every chunk as inserted. (Around the
+    whole scan XLA hoisted the one-pass branch's narrowing of the stack
+    out of the loop, PERF.md §6, PR 29; a narrowing inside a kernel cannot
+    be.)"""
     from mpi_knn_tpu.ops.lane_bin import (
         lane_bin_bound,
         lane_bin_chunks,
@@ -804,7 +814,9 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         return _varying_like(x, q_x, stack[0])
 
     lists = tuple(map(varying, lane_bin_lists(q_rows, depth, carry_d.dtype)))
-    if not lane_bin_bound_rides(q_rows, c_tile, carry_d.dtype.itemsize):
+    every_chunk = n_tiles * lane_bin_chunks(q_rows, c_tile)
+
+    def unbounded(either):
         def step(lists, tile):
             return either(
                 lambda *o: insert(
@@ -813,50 +825,54 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 *tile, *lists,
             ), None
 
-        lists, _ = jax.lax.scan(step, lists, stack)
-        chunks = None
-    else:
-        def scan(either):
-            def step(state, tile):
-                *lists, bound, inserted = state
-                *tile, due = tile
-                with jax.named_scope("knn.select"):
-                    bound = jax.lax.cond(
-                        due,
-                        lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
-                        lambda: bound)
-                *lists, n = either(
-                    lambda *o: insert(
-                        *o[n_stack:-1],
-                        dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
-                    *tile, *lists, bound,
-                )
-                return (*lists, bound, inserted + n), None
+        return jax.lax.scan(step, lists, stack)[0]
 
-            # the bound starts at +inf and not at the incoming carry's k-th
-            # column: that bounds the MERGED answer, while the lists answer
-            # for this stack alone, and a stack with fewer than k values
-            # under it would leave its rows short of k candidates, flagged
-            # one and all
-            (*out, _, inserted), _ = jax.lax.scan(
-                step,
-                (*lists,
-                 varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
-                 varying(jnp.int32(0))),
-                (*stack, bound_refreshes(n_tiles)),
+    def bounded(either):
+        def step(state, tile):
+            *lists, bound, inserted = state
+            *tile, due = tile
+            with jax.named_scope("knn.select"):
+                bound = jax.lax.cond(
+                    due,
+                    lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
+                    lambda: bound)
+            *lists, n = either(
+                lambda *o: insert(
+                    *o[n_stack:-1],
+                    dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
+                *tile, *lists, bound,
             )
-            return (*out, inserted)
+            return (*lists, bound, inserted + n), None
 
-        if fused is None:
-            *lists, inserted = scan(either)
-        else:
-            *lists, inserted = jax.lax.cond(
-                fused,
-                lambda: _fused_scan(
-                    q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth),
-                lambda: scan(lambda step, *o: step(*o, False)))
-        chunks = jnp.stack(
-            [inserted, n_tiles * lane_bin_chunks(q_rows, c_tile) - inserted])
+        # the bound starts at +inf and not at the incoming carry's k-th
+        # column: that bounds the MERGED answer, while the lists answer
+        # for this stack alone, and a stack with fewer than k values
+        # under it would leave its rows short of k candidates, flagged
+        # one and all
+        (*out, _, inserted), _ = jax.lax.scan(
+            step,
+            (*lists,
+             varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
+             varying(jnp.int32(0))),
+            (*stack, bound_refreshes(n_tiles)),
+        )
+        return (*out, inserted)
+
+    rides = lane_bin_bound_rides(q_rows, c_tile, carry_d.dtype.itemsize)
+    if fused is not None:
+        multipass = lambda step, *o: step(*o, False)  # noqa: E731
+        *lists, inserted = jax.lax.cond(
+            fused,
+            lambda: _fused_scan(
+                q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth, block=block),
+            lambda: bounded(multipass) if rides else (
+                *unbounded(multipass), varying(jnp.int32(every_chunk))))
+    elif rides:
+        *lists, inserted = bounded(either)
+    else:
+        lists, inserted = unbounded(either), None
+    chunks = None if inserted is None else jnp.stack(
+        [inserted, every_chunk - inserted])
     with jax.named_scope("knn.select"):
         vals, ids, flagged = lane_bin_result(lists, q_rows, k)
         with jax.named_scope("finish"):
@@ -872,17 +888,18 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
 
 
 @jax.named_scope(FUSED_SCOPE)
-def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth):
+def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
+                block):
     """``ops/fused_scan.py fused_scan`` for ``cfg``: the engaged scan's
-    one-pass branch over the whole stack as one kernel, ``(lists_d,
-    lists_i, chunks inserted)``."""
+    one-pass branch over the whole stack as one kernel, the query tile in
+    blocks of ``block`` rows, ``(lists_d, lists_i, chunks inserted)``."""
     from mpi_knn_tpu.ops.fused_scan import fused_scan
 
     return fused_scan(
         q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs,
         bound_refreshes(tiles.shape[0]), k=cfg.k, depth=depth,
         exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
-        zero_eps=cfg.zero_eps)
+        zero_eps=cfg.zero_eps, block=block)
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg

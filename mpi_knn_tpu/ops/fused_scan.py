@@ -20,6 +20,15 @@ nowhere else; the lists and the bound live in VMEM scratch from the first
 tile to the last and are written to HBM once, by an explicit copy at the
 last grid step.
 
+Two more forms of the same kernel, by the shapes (``ops/topk.py
+fused_scan_engages``). A width off the 128-lane grid rests on the device
+ROWS-MINOR, as (T, d, c_tile) (:func:`rests_rows_minor`): the kernel takes
+that view of the stack, which costs nothing, and a tile comes a piece
+(d x 1024 columns) a grid step — the piece is the dot's K x N operand as
+it lies. A query tile taller than the bound rides (4096 rows) is walked in
+row blocks, a leading grid axis: each block its own lists, bound and
+distances from the first tile to the last, the stack read once a block.
+
 Same values as the scan it replaces, bit for bit on whole-number rows:
 ``max(x_sq - 2 xy + y_sq, 0)`` in ``pairwise_sq_l2``'s order (the query
 side comes in as ``-2 x`` in bf16, which is exact, and ``a - b`` is
@@ -78,12 +87,19 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                        d_ref, bits_ref, idrow_ref, ycol_ref, work_ref,
                        hit_ref, word_ref, cnt_ref, sem, *, k: int,
                        depth: int, groups: int, exclude_self: bool,
-                       exclude_zero: bool, zero_eps: float):
-    """Grid step t of :func:`fused_scan`: tile t against the whole query
-    tile. ``due_ref`` (T,) int32 in scalar memory: the tiles that take the
+                       exclude_zero: bool, zero_eps: float, blocked: bool,
+                       rows_minor: bool):
+    """A grid step of :func:`fused_scan`: tile t against a block of query
+    rows — the whole query tile on the grid (tiles,); one of its row
+    blocks where ``blocked``, the grid's leading axis, the block's lists,
+    bound and distances starting anew at its first tile; where
+    ``rows_minor`` one 1024-column piece of tile t, the grid's last axis,
+    the tile's own work done at its first piece and the insertion at its
+    last. ``due_ref`` (T,) int32 in scalar memory: the tiles that take the
     bound anew first. ``qn_ref`` (q, d) bf16 holds -2 x, ``xsq_ref`` (q,
     128) the query norms in every lane, ``qid_ref`` (q, 128) the query ids
-    (read under ``exclude_self``); ``c_ref`` (1, c_tile, d) is tile t;
+    (read under ``exclude_self``); ``c_ref`` (1, c_tile, d) is tile t
+    (``rows_minor``: (1, d, 1024), a piece of it as it rests);
     ``ids_ref`` / ``ysq_ref`` (8, c_tile) the planes' rows around t. The
     lists (``kd_ref`` / ``ki_ref``), the bound (``b_ref``), the tile's
     dots and then distances (``d_ref`` (q, c_tile)) and the chunks' bits
@@ -105,68 +121,98 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     strips = q // _STRIP
     piece = groups * _LANES
     n_chunks = c_tile // piece
-    t = _as_i32(pl.program_id(0))
+    tile_axis = 1 if blocked else 0
+    t = _as_i32(pl.program_id(tile_axis))
     f32 = d_ref.dtype
     strip_shape = (_STRIP, _LANES)
+    # (the grid is read here, outside every conditional: the interpreter
+    # knows it nowhere else)
+    b = _as_i32(pl.program_id(0)) if blocked else None
+    j = _as_i32(pl.program_id(tile_axis + 1)) if rows_minor else None
 
-    @pl.when(lax.eq(t, i32(0)))
+    def last_tile():
+        return lax.eq(t, _as_i32(lax.sub(pl.num_programs(tile_axis), 1)))
+
+    # (on the grid (tiles,) it is read where it always was, after the
+    # insertion: that program's text is PR 37's)
+    leaves = last_tile() if rows_minor else None
+
+    def at_piece(which: int):
+        """What a tile step does once, ahead of piece ``which`` of its
+        dot: a tile of a rows-minor stack takes one grid step a piece,
+        and the others pass it by."""
+        if not rows_minor:
+            return lambda body: body()
+        return pl.when(lax.eq(j, i32(which)))
+
+    @at_piece(0)
     def _():
-        kd_ref[...] = lax.full(kd_ref.shape, _INF, f32)
-        ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, i32)
-        b_ref[...] = lax.full(b_ref.shape, _INF, f32)
-        cnt_ref[0] = i32(0)
+        @pl.when(lax.eq(t, i32(0)))
+        def _():
+            kd_ref[...] = lax.full(kd_ref.shape, _INF, f32)
+            ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, i32)
+            b_ref[...] = lax.full(b_ref.shape, _INF, f32)
+            if blocked:  # one count for the blocks together
+                @pl.when(lax.eq(b, i32(0)))
+                def _():
+                    cnt_ref[0] = i32(0)
+            else:
+                cnt_ref[0] = i32(0)
 
-    @pl.when(lax.ne(due_ref[t], i32(0)))
-    def _():
-        # lane_bin_bound's value, from the lists where they are: the k-th
-        # smallest of a row's lane minima, by k passes of row-min and
-        # knock-out over a block of rows
-        rows = work_ref.shape[0]
-        lane = lax.broadcasted_iota(i32, (rows, _LANES), 1)
-        big = lax.full(lane.shape, _I32_MAX, i32)
-        inf = lax.full(lane.shape, _INF, f32)
+        @pl.when(lax.ne(due_ref[t], i32(0)))
+        def _():
+            # lane_bin_bound's value, from the lists where they are: the
+            # k-th smallest of a row's lane minima, by k passes of row-min
+            # and knock-out over a block of rows
+            rows = work_ref.shape[0]
+            lane = lax.broadcasted_iota(i32, (rows, _LANES), 1)
+            big = lax.full(lane.shape, _I32_MAX, i32)
+            inf = lax.full(lane.shape, _INF, f32)
 
-        def row_min(x):
-            return lax.expand_dims(lax.reduce_min(x, (1,)), (1,))
+            def row_min(x):
+                return lax.expand_dims(lax.reduce_min(x, (1,)), (1,))
 
-        def wide(col):
-            return lax.broadcast_in_dim(col, lane.shape, (0, 1))
+            def wide(col):
+                return lax.broadcast_in_dim(col, lane.shape, (0, 1))
 
-        def block(i, carry):
-            r = pl.ds(pl.multiple_of(lax.mul(_as_i32(i), i32(rows)), rows),
-                      rows)
-            work_ref[...] = kd_ref[r, :_LANES]
+            def block(i, carry):
+                r = pl.ds(pl.multiple_of(
+                    lax.mul(_as_i32(i), i32(rows)), rows), rows)
+                work_ref[...] = kd_ref[r, :_LANES]
 
-            def one_pass(_, m):
-                d = work_ref[...]
-                m = row_min(d)
-                first = row_min(lax.select(lax.eq(d, wide(m)), lane, big))
-                work_ref[...] = lax.select(lax.eq(lane, wide(first)), inf, d)
-                return m
+                def one_pass(_, m):
+                    d = work_ref[...]
+                    m = row_min(d)
+                    first = row_min(lax.select(lax.eq(d, wide(m)), lane, big))
+                    work_ref[...] = lax.select(
+                        lax.eq(lane, wide(first)), inf, d)
+                    return m
 
-            m = lax.fori_loop(0, k, one_pass, lax.full((rows, 1), _INF, f32))
-            b_ref[r, :] = lax.min(b_ref[r, :], wide(m))
-            return carry
+                m = lax.fori_loop(
+                    0, k, one_pass, lax.full((rows, 1), _INF, f32))
+                b_ref[r, :] = lax.min(b_ref[r, :], wide(m))
+                return carry
 
-        lax.fori_loop(0, q // rows, block, 0)
+            lax.fori_loop(0, q // rows, block, 0)
 
-    # the tile's row of the planes; what is per column in one vector:
-    # y_sq, +inf where the id says padding or tombstone
-    row = pl.ds(lax.rem(t, i32(_PLANE_ROWS)), 1)
-    idrow_ref[...] = ids_ref[row, :]
-    ycol_ref[...] = lax.select(
-        lax.lt(idrow_ref[...], lax.full(idrow_ref.shape, 0, i32)),
-        lax.full(ycol_ref.shape, _INF, f32), ysq_ref[row, :])
+        # the tile's row of the planes; what is per column in one vector:
+        # y_sq, +inf where the id says padding or tombstone
+        row = pl.ds(lax.rem(t, i32(_PLANE_ROWS)), 1)
+        idrow_ref[...] = ids_ref[row, :]
+        ycol_ref[...] = lax.select(
+            lax.lt(idrow_ref[...], lax.full(idrow_ref.shape, 0, i32)),
+            lax.full(ycol_ref.shape, _INF, f32), ysq_ref[row, :])
 
-    bits_ref[...] = lax.full(bits_ref.shape, 0, i32)
+        bits_ref[...] = lax.full(bits_ref.shape, 0, i32)
 
     def dot_and_test(j, carry):
         j = _as_i32(j)
         cols = pl.ds(pl.multiple_of(lax.mul(j, i32(piece)), piece), piece)
         m = lax.dot_general(
             qn_ref[...],
-            lax.convert_element_type(c_ref[0, cols, :], jnp.bfloat16),
-            dimension_numbers=(((1,), (1,)), ((), ())),
+            lax.convert_element_type(
+                c_ref[0] if rows_minor else c_ref[0, cols, :], jnp.bfloat16),
+            dimension_numbers=(((1,), (0 if rows_minor else 1,)), ((), ())),
             preferred_element_type=f32,
             precision=lax.Precision.DEFAULT,
         )
@@ -187,8 +233,10 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
             lax.full(xs.shape, 0, i32)))
         return carry
 
-    lax.fori_loop(0, n_chunks, dot_and_test, 0)
-    _pack_hits(lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
+    if rows_minor:
+        dot_and_test(j, 0)
+    else:
+        lax.fori_loop(0, n_chunks, dot_and_test, 0)
 
     def value(r, g):
         """The strip's distances of column group g: ``pairwise_sq_l2``'s
@@ -255,28 +303,53 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
 
         return carry
 
-    lax.fori_loop(0, strips, make_whole, 0)
-    _pack_hits(lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
-    cnt_ref[0] = lax.add(cnt_ref[0], _insert_hit_chunks(
-        idrow_ref, d_ref, kd_ref, ki_ref, hit_ref, strips, n_chunks, groups,
-        depth))
-
-    @pl.when(lax.eq(t, _as_i32(lax.sub(pl.num_programs(0), 1))))
+    @at_piece(n_chunks - 1)
     def _():
-        n_ref[0, 0] = cnt_ref[0]
-        copies = [pltpu.make_async_copy(kd_ref, kd_out, sem.at[0]),
-                  pltpu.make_async_copy(ki_ref, ki_out, sem.at[1])]
-        for copy in copies:
-            copy.start()
-        for copy in copies:
-            copy.wait()
+        _pack_hits(
+            lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
+        lax.fori_loop(0, strips, make_whole, 0)
+        _pack_hits(
+            lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
+        cnt_ref[0] = lax.add(cnt_ref[0], _insert_hit_chunks(
+            idrow_ref, d_ref, kd_ref, ki_ref, hit_ref, strips, n_chunks,
+            groups, depth))
+
+        @pl.when(last_tile() if leaves is None else leaves)
+        def _():
+            n_ref[0, 0] = cnt_ref[0]
+            if blocked:  # the block's rows of the lists
+                rows = pl.ds(pl.multiple_of(lax.mul(b, i32(q)), q), q)
+                outs = kd_out.at[rows, :], ki_out.at[rows, :]
+            else:
+                outs = kd_out, ki_out
+            copies = [pltpu.make_async_copy(kd_ref, outs[0], sem.at[0]),
+                      pltpu.make_async_copy(ki_ref, outs[1], sem.at[1])]
+            for copy in copies:
+                copy.start()
+            for copy in copies:
+                copy.wait()
+
+
+def rests_rows_minor(d: int) -> bool:
+    """Whether a (T, c_tile, d) float32 stack the rule admits
+    (``ops/topk.py fused_scan_engages``: d a multiple of 8, c_tile of 128)
+    rests ROWS-MINOR on the device, as (T, d, c_tile) — the rows on the
+    lanes, d on the sublanes, nothing padded (784 = 98 x 8) — and the
+    kernel takes its tiles in that form: every width off the 128-lane
+    grid, at every tile count. There the view ``swapaxes(1, 2)`` is the
+    bytes at rest under another shape and the compiler moves nothing:
+    ``tests/test_pallas.py -k rest_layout`` reads the order in programs
+    compiled for the chip over a grid of shapes, ``-k one_pass_rule`` the
+    bitcast in the cells' own."""
+    return d % _LANES != 0
 
 
 def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
-    """The VMEM :func:`fused_scan` holds for (q, d) query tiles against
-    (c_tile, d) float32 corpus tiles, in bytes: the lists, the bound and
-    the chunks' bits, the tile's distances, the tile in its two buffers
-    and a piece's bf16 copy, the query side in its two buffers, the
+    """The VMEM :func:`fused_scan` holds for a block of (q, d) query rows
+    against (c_tile, d) float32 corpus tiles, in bytes: the lists, the
+    bound and the chunks' bits, the tile's distances, what it fetches of
+    the tile in its two buffers (the tile; of a rows-minor stack a piece
+    of it) and a piece's bf16 copy, the query side in its two buffers, the
     planes' rows. What the engage rule weighs and ``vmem_limit_bytes`` is
     set from."""
     piece = chunk_groups(c_tile) * _LANES
@@ -284,7 +357,8 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
     words = _hit_words(q // _STRIP, c_tile // piece) * (_STRIP // 2)
     bound_and_bits = (2 * q + words) * _LANES * 4
     dists = q * c_tile * 4
-    stack = 2 * c_tile * d * 4 + piece * d * 2
+    fetched = piece if rests_rows_minor(d) else c_tile
+    stack = 2 * fetched * d * 4 + piece * d * 2
     query = 2 * (q * d * 2 + 2 * q * _LANES * 4)
     planes = 2 * 2 * _PLANE_ROWS * c_tile * 4 + 2 * c_tile * 4
     work = _row_block(q, _FINISH_ROWS) * _LANES * 4
@@ -294,7 +368,7 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
 def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                tiles: jax.Array, tile_ids: jax.Array, tile_sqs: jax.Array,
                due, *, k: int, depth: int, exclude_self: bool,
-               exclude_zero: bool, zero_eps: float):
+               exclude_zero: bool, zero_eps: float, block: int):
     """The carried scan of ``backends/serial.py _merge_carried`` over a
     whole stack, in its one-pass branch and under the row bound, as one
     kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
@@ -304,12 +378,29 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     ahead of which the bound is taken anew (``bound_refreshes``). Returns
     ((q, depth·128) distances, (q, depth·128) ids, chunks inserted): what
     the scan's lists and its count end as (for rows that hold a NaN the
-    count may differ: a NaN is under no bound, +inf is under +inf)."""
+    count may differ: a NaN is under no bound, +inf is under +inf).
+
+    ``block`` (``ops/topk.py fused_scan_engages``' answer): the rows the
+    kernel holds at a time, q or q halved. A taller query tile is
+    walked in blocks of that height, a leading axis of the grid: each
+    block walks the stack from the first tile to the last with its own
+    lists and bound, which start anew at its first tile and leave at its
+    last (the next block's first tile is fetched meanwhile: the grid is
+    one pipeline), and the stack is read once a block — from 481 rows a
+    block's dot outlasts its tile's fetch. Rows do not meet in the lists,
+    the bound or a chunk (16 rows), so the blocks return the rows of the
+    whole tile's answer.
+
+    A width off the lane grid (:func:`rests_rows_minor`): the kernel takes
+    the stack as (T, d, c_tile), the form it rests in, a piece of a tile
+    (d x 1024 columns) a grid step: two whole tiles of 784 columns, 51 MB,
+    would not fit beside the distances."""
     q, d = q_x.shape
     n_tiles, c_tile, _ = tiles.shape
     groups = chunk_groups(c_tile)
+    piece = groups * _LANES
     width = depth * _LANES
-    strips = q // _STRIP
+    strips = block // _STRIP
     f32 = jnp.float32
     operands = (q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs)
     # -2 x: a power of two scales a bf16 number exactly, so the dot returns
@@ -317,24 +408,46 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     qn = (q_x * -2.0).astype(jnp.bfloat16)
     xsq = jnp.broadcast_to(q_sq.astype(f32)[:, None], (q, _LANES))
     qid = jnp.broadcast_to(q_ids.astype(jnp.int32)[:, None], (q, _LANES))
-    whole = lambda t, due: (0, 0)  # noqa: E731
+    blocked, rows_minor = block != q, rests_rows_minor(d)
+
+    def index(of):
+        """A ``BlockSpec`` index map from ``of(block, tile, piece)``, for
+        the axes the grid has."""
+        def index_map(*ids):
+            ids = list(ids[:-1])  # the last is the prefetched ``due``
+            b = ids.pop(0) if blocked else 0
+            return of(b, ids[0], ids[1] if rows_minor else 0)
+
+        return index_map
+
+    rows = index(lambda b, t, j: (b, 0))
     plane = pl.BlockSpec((_PLANE_ROWS, c_tile),
-                         lambda t, due: (t // _PLANE_ROWS, 0))
+                         index(lambda b, t, j: (t // _PLANE_ROWS, 0)))
+    grid = (n_tiles,)
+    if rows_minor:
+        # the stack where it rests, under the shape it rests in; a grid
+        # step a piece of a tile
+        tiles = jnp.swapaxes(tiles, 1, 2)
+        tile = pl.BlockSpec((1, d, piece), index(lambda b, t, j: (t, 0, j)))
+        grid += (c_tile // piece,)
+    else:
+        # the tile where it rests in the stack, by its index
+        tile = pl.BlockSpec((1, c_tile, d), index(lambda b, t, j: (t, 0, 0)))
+    if blocked:
+        grid = (q // block, *grid)
     kd, ki, n = pl.pallas_call(
         functools.partial(
             _fused_scan_kernel, k=k, depth=depth, groups=groups,
             exclude_self=exclude_self, exclude_zero=exclude_zero,
-            zero_eps=zero_eps),
+            zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_tiles,),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((q, d), whole),
-                pl.BlockSpec((q, _LANES), whole),
-                pl.BlockSpec((q, _LANES), whole),
-                # the tile where it rests in the stack, by its index
-                pl.BlockSpec((1, c_tile, d), lambda t, due: (t, 0, 0)),
-                plane, plane,
+                pl.BlockSpec((block, d), rows),
+                pl.BlockSpec((block, _LANES), rows),
+                pl.BlockSpec((block, _LANES), rows),
+                tile, plane, plane,
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -342,16 +455,16 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             scratch_shapes=[
-                pltpu.VMEM((q, width), f32),
-                pltpu.VMEM((q, width), jnp.int32),
-                pltpu.VMEM((q, _LANES), f32),
-                pltpu.VMEM((q, c_tile), f32),
-                pltpu.VMEM((q, _LANES), jnp.int32),
+                pltpu.VMEM((block, width), f32),
+                pltpu.VMEM((block, width), jnp.int32),
+                pltpu.VMEM((block, _LANES), f32),
+                pltpu.VMEM((block, c_tile), f32),
+                pltpu.VMEM((block, _LANES), jnp.int32),
                 pltpu.VMEM((1, c_tile), jnp.int32),
                 pltpu.VMEM((1, c_tile), f32),
-                pltpu.VMEM((_row_block(q, _FINISH_ROWS), _LANES), f32),
+                pltpu.VMEM((_row_block(block, _FINISH_ROWS), _LANES), f32),
                 # the words of the tile's (strip, chunk) bits; the count
-                *_hit_scratch(strips, c_tile // _LANES // groups),
+                *_hit_scratch(strips, c_tile // piece),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
@@ -362,10 +475,10 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
             _out((1, 1), jnp.int32, *operands),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             # the arithmetic above, and room for what Mosaic keeps of its
             # own (a piece's dot as a value, spills)
-            vmem_limit_bytes=fused_scan_vmem_bytes(q, c_tile, d, depth)
+            vmem_limit_bytes=fused_scan_vmem_bytes(block, c_tile, d, depth)
             + _VMEM_HEADROOM,
         ),
         interpret=_interpret(),

@@ -212,34 +212,60 @@ _FUSED_VMEM_BYTES = 64 << 20
 
 
 def fused_scan_engages(q: int, c: int, d: int, depth: int,
-                       itemsize: int = 4) -> bool:
+                       itemsize: int = 4) -> int | None:
     """Whether the one-pass branch of a scan that carries the lane-bin
     lists over (q, c) tiles of float32 rows ``d`` wide runs as ONE kernel
-    over the whole stack (``ops/fused_scan.py``), by the shapes alone:
+    over the whole stack (``ops/fused_scan.py``), by the shapes alone: the
+    height of the row blocks the kernel walks the query tile in, or None.
+    The height is q, or q halved until it passes (4096 and 2048 go to
+    1024), in whole strips of 16 rows:
 
-    - the row bound rides the scan (:func:`lane_bin_bound_rides`: the
-      kernel IS *bins* under the bound with the dot in front), over whole
-      strips of 16 rows;
-    - *memory*: the lists, the bound, the tile's distances, two buffers of
-      the tile and the query side fit the kernel's share of VMEM
-      (``fused_scan_vmem_bytes``, from which ``vmem_limit_bytes`` is set:
-      at 1024 rows, 8192 columns, d = 128, 5.2 MB of lists + 33.6 MB of
-      distances + 8.7 MB of tile + 2.6 MB of query side + 2.3 MB of
-      bound, bits, hit words and planes = 52.4 MB; 2048 rows, or
-      d = 1536, do not pass);
+    - *the row bound rides* a scan of tiles that tall
+      (:func:`lane_bin_bound_rides`: the kernel IS *bins* under the bound
+      with the dot in front): q itself from 256 to 2048 rows; a 4096-row
+      tile goes in four blocks of 1024, each with its own lists, bound and
+      count from the first tile to the last (the stack is read once a
+      block, which a block's dot outlasts from 481 rows);
+    - *memory*: the lists, the bound, the block's distances, two buffers
+      of what is fetched of the tile and the query side fit the kernel's
+      share of VMEM (``fused_scan_vmem_bytes``, from which
+      ``vmem_limit_bytes`` is set: at 1024 rows, 8192 columns, d = 128,
+      5.2 MB of lists + 33.6 MB of distances + 8.7 MB of tile + 2.6 MB of
+      query side + 2.3 MB of bound, bits, hit words and planes = 52.4 MB;
+      2048 rows do not pass and go in two blocks; d = 1536 does not pass
+      at any height the bound rides);
     - *the stack's layout at rest*: a kernel's operand is taken
-      row-major, and the v5e keeps a (T, c, d) float32 stack row-major
-      only for d on the 128-lane grid (128, 1536); at d = 100 or 784 it
-      rests rows-minor (PERF.md §6, PR 34) and the compiler would re-lay
-      ALL of it ahead of the call. So d % 128 == 0 and nothing else.
+      row-major, and the v5e rests a float32 (T, c, d) array in the order
+      that pads nothing under its (8, 128) tiles — a function of the shape
+      alone, whatever program reads it, read for every case below in
+      programs compiled for the chip (``tests/test_pallas.py -k
+      rest_layout``, and ``-k one_pass_rule`` for the cells' shapes). With
+      c a multiple of 128 (a carried scan's always is): d on the 128-lane
+      grid (128, 1536) rests row-major, ``{2,1,0}``; every other multiple
+      of 8 (784, 192, 104) rests as (T, d, c), ``{1,2,0}``, at EVERY tile
+      count (1, 3, 127, 128, 768, 1221: a multiple of 128 does not draw T
+      onto the lanes) — rows on the lanes, d on the sublanes — and there
+      the kernel takes the view ``swapaxes(1, 2)``, the bytes at rest
+      under another shape, a 1024-column piece of a tile a grid step
+      (``ops/fused_scan.py rests_rows_minor``). At any other width (100)
+      d would be padded on the sublanes, so the order follows the tile
+      count ((d, T, c), ``{1,0,2}``, at 1224 tiles; (T, d, c) at 3) and
+      the compiler would re-lay ALL of the stack ahead of the call:
+      d % 8 == 0 and nothing else.
 
-    Where it says no, the scan of tile steps stays as it is."""
-    if (itemsize != 4 or q % 16 or d % _LANES
-            or not lane_bin_bound_rides(q, c, itemsize)):
-        return False
+    Where it says None, the scan of tile steps stays as it is."""
+    if itemsize != 4 or d % 8:
+        return None
     from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
 
-    return fused_scan_vmem_bytes(q, c, d, depth) <= _FUSED_VMEM_BYTES
+    block = q
+    while block and block % 16 == 0:
+        if (lane_bin_bound_rides(block, c, itemsize)
+                and fused_scan_vmem_bytes(block, c, d, depth)
+                <= _FUSED_VMEM_BYTES):
+            return block
+        block //= 2
+    return None
 
 
 def lane_bin_flagged_share(dists, k: int) -> tuple[float, float] | None:

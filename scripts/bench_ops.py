@@ -72,6 +72,17 @@ Two families, one JSON artifact:
   retrain + build per mutation batch — the pre-PR "mutation"), in rows/s
   over the same batch so the ≥10× bar reads directly off the artifact.
 
+- ``--scan-step`` (alone, on whatever platform jax has — on the chip: one
+  process, no children): the SCAN micro-benchmark (PERF.md §6, PRs 35, 37
+  and 40: the go / no-go number of a change to the tile step). One
+  ``merge_tiles_into_carry`` of a ``--q`` x ``--d`` query tile over
+  ``--tiles`` tiles of ``--c`` clustered whole-number rows under a true
+  one-pass verdict, as the scan of XLA tile steps (``backends/serial.py
+  fused_rule`` answering None) and as the program the rule gives (the
+  kernel that walks the stack, where it engages), answers asserted equal
+  bit for bit and the kernel's chunk count equal to the bounded scan's;
+  rows ``scan_step`` with ``us_per_step`` = median / tiles.
+
 CPU numbers say nothing absolute about the TPU — what they pin is the
 RELATIVE trajectory per op across PRs, on the platform CI always has
 (the same rationale as ring_scaling_cpu.py). On a real chip the same
@@ -173,6 +184,97 @@ def _cold_start_child(spec: dict) -> int:
     return 0
 
 
+def _scan_step(args) -> int:
+    """The ``--scan-step`` rows (the module docstring has what they are)."""
+    import unittest.mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.config import KNNConfig
+    from mpi_knn_tpu.ops.distance import sq_norms
+
+    q, c, d, k, tiles = args.q, args.c, args.d, args.k, args.tiles
+    cfg = KNNConfig(k=k, backend="serial", matmul_precision="high",
+                    query_tile=q, corpus_tile=c, exclude_self=True)
+
+    @jax.jit
+    def make(key):
+        # MNIST-shaped rows (benchmark/datagen/clustered_u8.py's law: a
+        # class centre plus noise, whole numbers in [0, 255]), centred by
+        # a whole number, a tile at a time: no transient beside the stack
+        cen = jnp.rint(jax.random.uniform(key, (10, d)) * 255.0)
+
+        def tile(i):
+            k1, k2 = jax.random.split(jax.random.fold_in(key, i))
+            x = cen[jax.random.randint(k1, (c,), 0, 10)] + 25.0 * (
+                jax.random.normal(k2, (c, d), jnp.float32))
+            return jnp.clip(jnp.rint(x), 0.0, 255.0) - 128.0
+
+        return jax.lax.map(tile, jnp.arange(tiles))
+
+    stack = make(jax.random.key(0, impl="rbg"))
+    ids = jnp.arange(tiles * c, dtype=jnp.int32).reshape(tiles, c)
+    # the query tile is rows of the corpus under their own ids, as a slice
+    # of the all-pairs job is
+    # (the verdict is an operand: a constant would fold the rule's
+    # conditional out of the scan it is part of)
+    operands = (stack[tiles // 2, :q], ids[tiles // 2, :q], stack, ids,
+                serial._stack_norms(stack, "l2"), jnp.asarray(True))
+
+    def program(rule, rides):
+        with unittest.mock.patch.multiple(
+                serial, fused_rule=rule, lane_bin_bound_rides=rides):
+            @jax.jit
+            def run(q_x, q_ids, stack, ids, sqs, onepass):
+                return serial.merge_tiles_into_carry(
+                    q_x, q_ids, sq_norms(q_x), stack, ids, sqs,
+                    *serial.init_topk(q, k), cfg, onepass)
+
+            return run.lower(*operands).compile()
+
+    def no_kernel(*a, **kw):
+        return None
+
+    variants = {"scan": program(no_kernel, serial.lane_bin_bound_rides),
+                "rule": program(serial.fused_rule,
+                                serial.lane_bin_bound_rides)}
+    block = serial.fused_rule(cfg, q, c, d)
+    results, outs = [], {}
+    for name, run in variants.items():
+        times = _time(lambda: run(*operands)[0], args.reps)
+        outs[name] = jax.tree.map(np.asarray, run(*operands))
+        med = statistics.median(times)
+        results.append({
+            "op": "scan_step", "variant": name, "q": q, "c": c, "d": d,
+            "tiles": tiles, "block": block if name == "rule" else None,
+            "median_s": round(med, 6), "min_s": round(min(times), 6),
+            "us_per_step": round(med / tiles * 1e6, 2),
+            "rescanned": bool(outs[name][2]),
+            "chunks": None if outs[name][3] is None
+            else outs[name][3].tolist(),
+        })
+        print(json.dumps(results[-1]), flush=True)
+    for name, a, b in zip(("vals", "ids", "rescanned"),
+                          outs["scan"], outs["rule"]):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    if block and outs["scan"][3] is None:
+        # the count's witness: the scan of tile steps under the bound
+        bounded = jax.tree.map(np.asarray, program(
+            no_kernel, lambda *a: True)(*operands))
+        for a, b in zip(bounded, outs["rule"]):
+            np.testing.assert_array_equal(a, b)
+    doc = {"device": str(jax.devices()[0].device_kind),
+           "platform": jax.default_backend(), "equal": True,
+           "results": results}
+    out = pathlib.Path(args.out)
+    out.parent.mkdir(parents=True, exist_ok=True)
+    out.write_text(json.dumps(doc, indent=1) + "\n")
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default="measurements/bench_ops.json")
@@ -186,7 +288,15 @@ def main(argv=None) -> int:
                     "0 disables them (and the CPU forcing they need)")
     ap.add_argument("--cold-start-child", default=None,
                     help=argparse.SUPPRESS)  # JSON spec; see _cold_start_child
+    ap.add_argument("--scan-step", action="store_true",
+                    help="the scan micro-benchmark alone, on the platform "
+                    "jax has (the chip)")
+    ap.add_argument("--tiles", type=int, default=192,
+                    help="corpus tiles of the --scan-step stack")
     args = ap.parse_args(argv)
+
+    if args.scan_step:
+        return _scan_step(args)
 
     if args.cold_start_child:
         # fresh-process measurement body — must run before any platform

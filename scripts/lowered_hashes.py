@@ -4,6 +4,7 @@ two commits: which cells' programs a change touched, and which it left byte
 for byte as they were.
 
     python scripts/lowered_hashes.py <checkout> <out.json> [<dir for the texts>]
+    python scripts/lowered_hashes.py --cells <checkout> <out.json> [<dir>]
     python scripts/lowered_hashes.py --diff <a.json> <b.json>
 
 The text hashed is the StableHLO of ``jax.stages.Lowered.as_text()``: it
@@ -12,6 +13,20 @@ cells are ``analysis.lowering.default_targets()``, lowered as the lint
 lowers them, on eight virtual CPU devices; the checkout is put first on
 ``sys.path``, so run it once per checkout (a parent unpacked by ``git
 archive`` and the working tree), then ``--diff`` the two files.
+
+``--cells`` hashes the programs of the benchmark's cells instead, at the
+cells' own shapes: the lint's shapes are too small for what engages by
+shape (the row bound, the kernel that walks the stack). What a cell runs is
+asked of the code that runs it, over abstract operands (nothing is
+allocated): a serving cell's programs are its index kind's batch program
+(``serve.index``'s layouts, as ``serve.engine`` lowers them) at every
+bucket its traffic warms (``warm_sizes``), with and without the one-pass
+operand; a one-shot cell's is ``backends.serial._search_stack`` at its
+traffic's ``slice_rows``; a ring cell's the sharded call. Rows, width and
+``knn`` are the configuration file's. The text is the jaxpr
+(``jax.make_jaxpr``) traced as the chip traces it — ``jax.default_backend``
+answers "tpu", so the kernels are Mosaic calls and the ring carries them —
+kernel bodies included, source locations not.
 """
 
 from __future__ import annotations
@@ -20,6 +35,17 @@ import hashlib
 import json
 import os
 import sys
+
+
+def _record(out: dict, texts_dir: str | None, label: str, text: str,
+            suffix: str) -> None:
+    """``out[label]`` = the text's hash; the text under ``texts_dir``."""
+    out[label] = hashlib.sha256(text.encode()).hexdigest()
+    if texts_dir:
+        os.makedirs(texts_dir, exist_ok=True)
+        name = label.replace("/", "_") + suffix
+        with open(os.path.join(texts_dir, name), "w") as f:
+            f.write(text)
 
 
 def hashes(root: str, texts_dir: str | None) -> dict:
@@ -40,13 +66,103 @@ def hashes(root: str, texts_dir: str | None) -> dict:
         except lowering.UnsupportedTarget:
             out[t.label] = "unsupported"  # float64 without x64, and the like
             continue
-        text = lowered.as_text()
-        out[t.label] = hashlib.sha256(text.encode()).hexdigest()
-        if texts_dir:
-            os.makedirs(texts_dir, exist_ok=True)
-            name = t.label.replace("/", "_") + ".mlir"
-            with open(os.path.join(texts_dir, name), "w") as f:
-                f.write(text)
+        _record(out, texts_dir, t.label, lowered.as_text(), ".mlir")
+    return out
+
+
+def cell_hashes(root: str, texts_dir: str | None) -> dict:
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import functools
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mpi_knn_tpu.backends import ring, serial
+    from mpi_knn_tpu.config import KNNConfig
+    from mpi_knn_tpu.serve import index as serve_index
+    from mpi_knn_tpu.parallel.partition import pad_to_multiple
+
+    jax.default_backend = lambda: "tpu"
+    arg = jax.ShapeDtypeStruct
+
+    def stack(cfg, rows, dim, c_tile):
+        """The resident stack, its id and norm planes, the one-pass
+        verdict: the arguments every serial program ends with."""
+        tiles = pad_to_multiple(
+            int(np.ceil(rows * (1 + cfg.bucket_headroom))), c_tile) // c_tile
+        return (arg((tiles, c_tile, dim), jnp.float32),
+                arg((tiles, c_tile), jnp.int32),
+                arg((tiles, c_tile), jnp.float32), arg((), jnp.bool_))
+
+    def served(cfg, rows, dim, buckets):
+        """The batch programs of an index of the configuration's shape."""
+        c_tile = serial.effective_tiles(cfg, rows, cfg.query_tile)[1]
+        *resident, fact = stack(cfg, rows, dim, c_tile)
+        tags = cfg.max_query_tags and types.SimpleNamespace(
+            # (the planes' count is the data's: any traces the same text)
+            tag_bits=arg((cfg.max_query_tags + 1, resident[0].shape[0],
+                          c_tile // 32), jnp.uint32))
+        layout = serve_index.TAGGED_SERIAL if tags else serve_index.SERIAL
+        for onepass in ((fact, None) if cfg.metric == "l2" else (None,)):
+            index = serve_index.CorpusIndex(
+                cfg, "serial", rows, dim, c_tile, None, layout, *resident,
+                onepass=onepass, tags=tags or None)
+            for bucket in buckets:
+                q_pad, q_tile = layout.bucket_shapes(index, cfg, bucket)
+                yield f"bucket{bucket}{'-nofact' if onepass is None else ''}", (
+                    jax.make_jaxpr(functools.partial(
+                        layout.jit(False), **layout.statics(
+                            index, cfg, bucket)))(
+                        *layout.query_side(index, cfg, q_pad, q_tile),
+                        *layout.resident(index)))
+
+    def one_shot(cfg, rows, dim, nq):
+        q_tile, c_tile = serial.effective_tiles(cfg, rows, nq)
+        yield "call", jax.make_jaxpr(functools.partial(
+            serial._search_stack, cfg=cfg, q_tile=q_tile))(
+            arg((nq, dim), jnp.float32), arg((nq,), jnp.int32),
+            *stack(cfg, rows, dim, c_tile))
+
+    def ring_call(cfg, rows, dim, nq):
+        mesh = Mesh(np.asarray(jax.devices()[:cfg.num_devices]),
+                    (cfg.mesh_axis,))
+        by_rows = NamedSharding(mesh, P(cfg.mesh_axis))
+        yield "call", jax.make_jaxpr(
+            lambda *a: ring._ring_knn_sharded(
+                *a, cfg, True, mesh, cfg.mesh_axis, cfg.query_tile,
+                cfg.corpus_tile, onepass=jnp.asarray(True)))(
+            arg((nq, dim), jnp.float32, sharding=by_rows),
+            arg((nq,), jnp.int32, sharding=by_rows),
+            arg((rows, dim), jnp.float32, sharding=by_rows),
+            arg((rows,), jnp.int32, sharding=by_rows))
+
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    out = {}
+    for cell in bench["workloads"]:
+        with open(os.path.join(root, files[cell["config"]])) as f:
+            config = json.load(f)
+        with open(os.path.join(
+                root, "benchmark", "traffic", cell["traffic"] + ".json")) as f:
+            mix = json.load(f)
+        cfg = KNNConfig(**config["knn"])
+        shape = cfg, config["rows"], config["dim"]
+        if "warm_sizes" in mix:
+            programs = served(*shape, mix["warm_sizes"])
+        elif cfg.backend.startswith("ring"):
+            programs = ring_call(*shape, mix["slice_rows"])
+        else:
+            programs = one_shot(*shape, mix["slice_rows"])
+        for name, jaxpr in programs:
+            _record(out, texts_dir, f"{cell['name']}/{name}", str(jaxpr),
+                    ".jaxpr")
     return out
 
 
@@ -66,10 +182,13 @@ def diff(a_path: str, b_path: str) -> int:
 def main(argv: list[str]) -> int:
     if len(argv) == 3 and argv[0] == "--diff":
         return diff(argv[1], argv[2])
+    make = hashes
+    if argv and argv[0] == "--cells":
+        make, argv = cell_hashes, argv[1:]
     if len(argv) not in (2, 3):
         print(__doc__, file=sys.stderr)
         return 2
-    out = hashes(argv[0], argv[2] if len(argv) == 3 else None)
+    out = make(argv[0], argv[2] if len(argv) == 3 else None)
     with open(argv[1], "w") as f:
         json.dump(out, f, indent=1, sort_keys=True)
     print(f"{len(out)} cells -> {argv[1]}")

@@ -2,7 +2,9 @@
 tile stack — the one-pass dot, the masks, the bound's test and *bins* of
 every tile step — returns what the engaged scan of tile steps returns, bit
 for bit on whole-number rows; the rule that engages it, by the shapes; the
-counter that says it ran. The kernel body is interpreted here; where the
+counter that says it ran. Since ISSUE 40 also at widths off the lane grid
+(the tile taken rows-minor, a piece a grid step) and with the query tile
+walked in row blocks. The kernel body is interpreted here; where the
 shape rule would keep a width or a height out, the tests force the kernel
 in (and the bound onto the scan it is compared with)."""
 
@@ -56,10 +58,11 @@ def _case(q, d, tiles, c_tile=C_TILE, seed=0):
             jnp.asarray(carry_d), jnp.asarray(carry_i))
 
 
-def _merge(monkeypatch, fused, cfg, q_x, q_ids, tiles, tile_ids, cd, ci):
+def _merge(monkeypatch, block, cfg, q_x, q_ids, tiles, tile_ids, cd, ci):
     """``merge_tiles_into_carry`` under a true one-pass verdict, as the
-    fused kernel or as the scan of tile steps under the bound."""
-    monkeypatch.setattr(serial, "fused_rule", lambda *a, **k: fused)
+    fused kernel over row blocks of ``block`` rows or (None) as the scan
+    of tile steps under the bound."""
+    monkeypatch.setattr(serial, "fused_rule", lambda *a, **k: block)
     monkeypatch.setattr(serial, "lane_bin_bound_rides", lambda *a: True)
 
     @jax.jit
@@ -71,21 +74,28 @@ def _merge(monkeypatch, fused, cfg, q_x, q_ids, tiles, tile_ids, cd, ci):
     return jax.tree.map(np.asarray, run(q_x, q_ids, tiles, tile_ids, cd, ci))
 
 
-@pytest.mark.parametrize("q,d,tiles", [
-    *((q, d, 17) for q in (64, 256, 1024) for d in (100, 128, 784)),
-    # (the interpreter's time goes with the rows: refreshes at tiles 1, 2)
-    *((4096, d, 3) for d in (100, 128, 784)),
-    (64, 128, 1), (256, 100, 1), (1024, 128, 1),
-    (64, 128, 40), (256, 100, 40), (1024, 128, 40),
+@pytest.mark.parametrize("q,d,tiles,blocks", [
+    *((q, d, 17, 1) for q in (64, 256, 1024) for d in (100, 128, 784)),
+    # (the interpreter's time goes with the rows: refreshes at tiles 1, 2);
+    # the all-kNN cell's tile, in the four blocks the rule gives it
+    *((4096, d, 3, 4) for d in (100, 128, 784)),
+    (64, 128, 1, 1), (256, 100, 1, 1), (1024, 128, 1, 1),
+    (64, 128, 40, 1), (256, 100, 40, 1), (1024, 128, 40, 1),
+    # four row blocks (ISSUE 40): a block's edge runs through the queries
+    # that are corpus rows (8 .. 24: the self and zero masks on both sides
+    # of it), every block has rows tied at their bound, and the lists,
+    # the bound and the count start anew a block
+    (64, 784, 17, 4), (64, 100, 17, 4), (256, 104, 17, 4), (64, 128, 17, 4),
+    (64, 784, 1, 4), (128, 784, 40, 2),
 ])
 def test_fused_scan_returns_what_the_scan_of_tile_steps_returns(
-        monkeypatch, q, d, tiles):
+        monkeypatch, q, d, tiles, blocks):
     cfg = KNNConfig(k=K, query_tile=q, corpus_tile=C_TILE,
                     exclude_self=True, exclude_zero=True)
     assert lane_bin_depth(q, C_TILE, K) is not None
     case = _case(q, d, tiles)
-    scan = _merge(monkeypatch, False, cfg, *case)
-    fused = _merge(monkeypatch, True, cfg, *case)
+    scan = _merge(monkeypatch, None, cfg, *case)
+    fused = _merge(monkeypatch, q // blocks, cfg, *case)
     for name, a, b in zip(("vals", "ids", "rescanned", "chunks"), scan, fused):
         np.testing.assert_array_equal(a, b, err_msg=name)
     vals, ids, rescanned, chunks = fused
@@ -98,39 +108,85 @@ def test_fused_scan_returns_what_the_scan_of_tile_steps_returns(
     assert (vals[8:24] > 0).all()
 
 
-@pytest.mark.parametrize("exclude_self,exclude_zero,c_tile", [
-    (False, False, C_TILE), (False, True, 2048), (True, False, 4096),
-    (True, True, 8192)])  # the cells' tile: eight chunks
+@pytest.mark.parametrize("exclude_self,exclude_zero,c_tile,d,blocks", [
+    (False, False, C_TILE, 128, 1), (False, True, 2048, 128, 1),
+    (True, False, 4096, 128, 1),
+    (True, True, 8192, 128, 1),  # the cells' tile: eight chunks
+    # a rows-minor tile's pieces, a grid step each (ISSUE 40)
+    (False, False, 2048, 784, 1), (False, True, 4096, 200, 2),
+    (True, False, 8192, 784, 4), (True, True, 8192, 784, 1),
+])
 def test_fused_scan_masks_and_several_chunks_a_tile(
-        monkeypatch, exclude_self, exclude_zero, c_tile):
-    q, d, tiles = 64, 128, 9
+        monkeypatch, exclude_self, exclude_zero, c_tile, d, blocks):
+    q, tiles = 64, 9
     cfg = KNNConfig(k=K, query_tile=q, corpus_tile=c_tile,
                     exclude_self=exclude_self, exclude_zero=exclude_zero)
     case = _case(q, d, tiles, c_tile)
-    scan = _merge(monkeypatch, False, cfg, *case)
-    fused = _merge(monkeypatch, True, cfg, *case)
+    scan = _merge(monkeypatch, None, cfg, *case)
+    fused = _merge(monkeypatch, q // blocks, cfg, *case)
     for name, a, b in zip(("vals", "ids", "rescanned", "chunks"), scan, fused):
         np.testing.assert_array_equal(a, b, err_msg=name)
     assert fused[3][1] > 0  # the far tiles' chunks
 
 
-@pytest.mark.parametrize("q,c,d,engages,why", [
-    (1024, 8192, 128, True, "the bulk cell's 1024-row program"),
-    (256, 8192, 128, True, "from the height at which the bound rides"),
-    (1024, 8192, 256, True, "a wider row on the lane grid"),
-    (1024, 1024, 128, True, "a narrow tile"),
-    (64, 8192, 128, False, "no bound rides a 64-row bucket's scan"),
-    (4096, 8192, 128, False, "no bound rides a 128 MiB tile"),
-    (2048, 8192, 128, False, "memory: 64 MiB of distances + 10 MB of lists"),
-    (1024, 8192, 1536, False, "memory: two 48 MiB buffers of the tile"),
-    (1024, 8192, 100, False, "a width-100 stack rests rows-minor"),
-    (1024, 8192, 784, False, "a width-784 stack rests rows-minor"),
-    (1032, 8192, 128, False, "the kernel walks whole strips of 16 rows"),
+@pytest.mark.parametrize("q,c,d,block,why", [
+    (1024, 8192, 128, 1024, "the bulk cell's 1024-row program"),
+    (256, 8192, 128, 256, "from the height at which the bound rides"),
+    (1024, 8192, 256, 1024, "a wider row on the lane grid"),
+    (1024, 1024, 128, 1024, "a narrow tile"),
+    (64, 8192, 128, None, "no bound rides a 64-row bucket's scan"),
+    (4096, 8192, 128, 1024, "a 128 MiB tile in four blocks the bound rides"),
+    (2048, 8192, 128, 1024, "memory: 64 MiB of distances; two blocks"),
+    (1024, 8192, 1536, None, "memory: two 48 MiB buffers of the tile"),
+    (1024, 8192, 100, None, "the sublane grid: (d, T, c) at 1224 tiles"),
+    (1024, 8192, 784, 1024, "a width-784 stack rests as (T, d, c)"),
+    (4096, 8192, 784, 1024, "the all-kNN cell's tile"),
+    (1024, 8192, 192, 1024, "the filtered cell's width, without a predicate"),
+    (4096, 8192, 1536, None, "memory at every height the bound rides"),
+    (1032, 8192, 128, None, "the kernel walks whole strips of 16 rows"),
+    (4112, 8192, 128, None, "16 x 257 rows: no block height divides them"),
+    (4128, 8192, 128, None, "q is halved, not cut in thirds of 86 strips"),
+    (1536, 8192, 128, 768, "a height the kernel takes whole, twice"),
+    (512, 8192, 784, 512, "a 512-row program at a width off the lane grid"),
 ])
-def test_the_shape_rule_of_the_fused_scan(q, c, d, engages, why):
+def test_the_shape_rule_of_the_fused_scan(q, c, d, block, why):
     depth = lane_bin_depth(q, c, K)
     assert depth is not None
-    assert fused_scan_engages(q, c, d, depth) is engages, why
+    assert fused_scan_engages(q, c, d, depth) == block, why
+
+
+@pytest.mark.parametrize("q,d,grid,view,why", [
+    (1024, 128, "(24,)", False, "the bulk cell's: one block, a row-major "
+     "tile by its index — the program PR 37 wrote"),
+    (4096, 128, "(4, 24)", False, "row blocks lead the grid"),
+    (1024, 784, "(24, 8)", True, "a rows-minor tile a piece a grid step"),
+    (4096, 784, "(4, 24, 8)", True, "the all-kNN cell's: both"),
+])
+def test_the_grid_of_the_fused_scan_follows_the_shapes(q, d, grid, view, why):
+    """One kernel, its grid by the shapes: (row blocks where the rule gives
+    a block under q,) tiles (, a tile's pieces where the stack rests rows
+    minor — there, and only there, the stack goes in under the view
+    ``swapaxes(1, 2)``). Traced over abstract operands: nothing runs."""
+    import re
+
+    tiles, c = 24, 8192
+    depth = lane_bin_depth(q, c, K)
+    block = fused_scan_engages(q, c, d, depth)
+    assert block == 1024
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    from mpi_knn_tpu.ops.fused_scan import fused_scan
+
+    text = str(jax.make_jaxpr(lambda *operands: fused_scan(
+        *operands, serial.bound_refreshes(tiles), k=K, depth=depth,
+        exclude_self=True, exclude_zero=True, zero_eps=0.0, block=block))(
+        arg((q, d), jnp.float32), arg((q,), jnp.int32),
+        arg((q,), jnp.float32), arg((tiles, c, d), jnp.float32),
+        arg((tiles, c), jnp.int32), arg((tiles, c), jnp.float32)))
+    assert re.findall(r"grid=(\([^)]*\))", text) == [grid], why
+    assert ("transpose[" in text) == view, why
 
 
 @pytest.mark.parametrize("change,engages,why", [
@@ -145,34 +201,47 @@ def test_the_shape_rule_of_the_fused_scan(q, c, d, engages, why):
 def test_which_programs_take_the_fused_scan(change, engages, why):
     cfg = KNNConfig(**{**dict(k=K, query_tile=1024, corpus_tile=8192),
                        **change})
-    assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128) is engages, why
+    block = cfg.query_tile if engages else None
+    assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128) == block, why
     # under a checked shard_map (the ring's rounds) the scan stays
-    assert not serial.fused_rule(cfg, cfg.query_tile, 8192, 128, True)
+    assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128, True) is None
 
 
-def test_a_call_counts_its_fused_steps_and_matches_the_ring():
-    """A one-shot call over whole-number rows at d = 128 takes the kernel
-    in every tile step and says so (``dist_steps``' fourth column, the
-    counter's ``path="fused"``); fractional queries take the multi-pass
-    scan of the same program; the ring's rounds over the same rows (a
-    checked ``shard_map`` on four CPU devices: the scan stays) answer the
-    same."""
+@pytest.mark.parametrize("d,q_tile", [
+    (128, 1024),
+    # the all-kNN cell's form (ISSUE 40): a 4096-row query tile in four
+    # blocks, a width off the lane grid
+    (104, 4096),
+])
+def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
+    """A one-shot call over whole-number rows takes the kernel in every
+    tile step and says so (``dist_steps``' fourth column, the counter's
+    ``path="fused"``, counted a (query tile, corpus tile) whatever the
+    blocks); fractional queries take the multi-pass scan of the same
+    program; the ring's rounds over the same rows (a checked
+    ``shard_map`` on four CPU devices: the scan stays) answer the same."""
     rng = np.random.default_rng(3)
-    X = rng.integers(0, 200, (4096, 128)).astype(np.float32)
-    kw = dict(k=K, query_tile=1024, corpus_tile=1024)
+    X = rng.integers(0, 200, (4096, d)).astype(np.float32)
+    kw = dict(k=K, query_tile=q_tile, corpus_tile=1024)
+    steps = 4096 // q_tile * 4
     res = all_knn(X, backend="serial", **kw)
-    assert np.asarray(res.dist_steps).tolist() == [0, 0, 0, 16]
-    assert np.asarray(res.bins_chunks).sum() == 16 * 64
+    assert np.asarray(res.dist_steps).tolist() == [0, 0, 0, steps]
+    assert np.asarray(res.bins_chunks).sum() == steps * (q_tile // 16)
     registry = MetricsRegistry()
     registry.count_dist_steps(res.dist_steps)
     counted = {p: registry.counter(DIST_STEPS, labels={"path": p}).value
                for p in DIST_PATHS}
-    assert counted == {"onepass": 0, "multipass": 0, "cosine": 0, "fused": 16}
+    assert counted == {
+        "onepass": 0, "multipass": 0, "cosine": 0, "fused": steps}
     ring = all_knn(X, backend="ring-overlap", num_devices=4, **kw)
     np.testing.assert_array_equal(
         np.asarray(ring.dists), np.asarray(res.dists))
     # (among equal distances the full-width selection may order ids
     # otherwise)
     assert (np.asarray(ring.ids) == np.asarray(res.ids)).mean() > 0.999
-    frac = all_knn(X, queries=X[:1024] + 0.25, backend="serial", **kw)
+    frac = all_knn(X, queries=X[:q_tile] + 0.25, backend="serial", **kw)
     assert np.asarray(frac.dist_steps).tolist() == [0, 4, 0, 0]
+    if q_tile == 4096:
+        # no bound rides the multi-pass scan of a tile that tall: every
+        # chunk is inserted
+        assert np.asarray(frac.bins_chunks).tolist() == [4 * 256, 0]

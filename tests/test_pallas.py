@@ -437,7 +437,11 @@ def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
     takes the stack and the id and norm planes as they rest — no
     ``dynamic-slice`` and no copy of a tile, no (q, 8192) float32 distance
     tile, no loop — and the other branch is the scan of multi-pass steps
-    with *bins* inside it."""
+    with *bins* inside it. A width off the lane grid (ISSUE 40: d = 784)
+    rests as (tiles, dim, 8192), rows minor, and the kernel takes that
+    shape: the ``swapaxes`` in front of it is a BITCAST of the branch's
+    parameter, the bytes at rest under another name — no ``copy`` and no
+    ``transpose`` of the 4.59 GiB stack anywhere in the branch."""
     import re
 
     blocks = re.split(r"\n(?=(?:ENTRY )?%\S+ \([^\n]*\) -> [^\n]* \{\n)",
@@ -453,10 +457,19 @@ def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
     assert f"f32[{tiles},8192,{dim}]" in branch.splitlines()[0]
     operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
     stack = re.sub(r"/\*[^*]*\*/", "", operands).split(", ")[4]
+    if dim % 128:
+        view = re.search(
+            rf"{re.escape(stack)} = f32\[{tiles},{dim},8192\]\{{2,1,0\S* "
+            r"bitcast\((%\S+)\)", branch)
+        assert view, stack
+        stack = view.group(1)
     assert re.search(
         rf"{re.escape(stack)} = f32\[{tiles},8192,{dim}\]\S* "
         r"get-tuple-element\(", branch), stack
-    for gone in ("dynamic-slice", " while(", f"f32[{q},8192]", " copy("):
+    # (the prefetched refresh flags, a few hundred bytes, come by an
+    # asynchronous copy: ``copy-start``)
+    for gone in ("dynamic-slice", " while(", f"f32[{q},8192]", " copy(",
+                 " transpose("):
         assert gone not in branch, gone
     # the other branch: the scan, its steps' dot at the configured
     # precision and *bins*
@@ -466,20 +479,79 @@ def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
         "cond/branch_0_fun/while/body" in ln for ln in bins), bins
 
 
+@pytest.mark.parametrize("d", [8, 64, 100, 104, 128, 192, 200, 784, 1000,
+                               1536])
+def test_the_rest_layout_of_a_stack_follows_its_shape_alone(v5e_devices, d):
+    """What ``ops/topk.py fused_scan_engages`` says of a stack at rest,
+    READ in programs compiled for the v5e: a float32 (T, c, d) parameter's
+    layout is the same whatever the program does with it (its first
+    element; rows gathered out of it; the kernel's view of it) and, where
+    d is a multiple of 8, whatever the tile count — 1, 3, a prime, a
+    multiple of 128, the cells' 768 and 1221: row-major on the lane grid,
+    (T, d, c) off it, where ``swapaxes(1, 2)`` is then a bitcast. At a
+    width the sublane grid refuses (100) the order follows the tile count
+    and the rule keeps out."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu.ops.fused_scan import rests_rows_minor
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    uses = {
+        "first": lambda x: x[0, 0, 0],
+        "gather": lambda x: x.reshape(-1, d)[jnp.arange(64) * 7].sum(0),
+        "view": lambda x: jnp.swapaxes(x, 1, 2)[:, :, :128].sum(),
+    }
+
+    def layout(tiles, c, use):
+        hlo = jax.jit(uses[use]).lower(jax.ShapeDtypeStruct(
+            (tiles, c, d), jnp.float32, sharding=one)).compile().as_text()
+        return re.search(
+            rf"f32\[{tiles},{c},{d}\]\{{([\d,]+:T\([\d,]+\))\}} parameter\(0\)",
+            hlo).group(1)
+
+    read = {(tiles, c): layout(tiles, c, "first")
+            for c in (1024, 8192)
+            for tiles in (1, 3, 127, 128, 192, 768, 1221, 1224)
+            if tiles * c * d * 4 < 12e9}  # what a 16 GB chip can hold
+    assert len(read) >= 12
+    for (tiles, c) in ((3, 1024), (128, 8192)):
+        for use in ("gather", "view"):
+            assert layout(tiles, c, use) == read[tiles, c], (tiles, c, use)
+    if d % 8 == 0:
+        rest = "1,2,0" if rests_rows_minor(d) else "2,1,0"
+        assert set(read.values()) == {rest + ":T(8,128)"}, read
+    else:
+        assert read[3, 8192] == "1,2,0:T(8,128)", read
+        assert read[1224, 8192] == "1,0,2:T(8,128)", read
+
+
 @pytest.mark.parametrize("cell,q,tiles,dim,precision,temp_gib", [
     # allknn-mnist8m: 13.85 GiB of 15.75 at the peak leaves no room for the
     # bf16 copy of the stack (2.30 GiB) that XLA hoists out of the scan when
     # the conditional sits around the whole scan, for the `default` path's
     # 3.19 GiB, or for the float32 copy (4.6 GiB) it makes for a second loop
     # over the stack: since ISSUE 33 the program needs 0.19 GiB (the
-    # survivor stack and the cascade's sort scratch, 1.04 GiB, are gone)
-    ("allknn-mnist8m", 4096, 192, 784, "high", 0.3),
+    # survivor stack and the cascade's sort scratch, 1.04 GiB, are gone);
+    # since ISSUE 40 its one-pass branch is the kernel that walks the
+    # stack, four 1024-row blocks over the rows-minor tiles, and the
+    # program needs 0.15 GiB: no more than the parent's 0.19
+    ("allknn-mnist8m", 4096, 192, 784, "high", 0.19),
     # serve-bigann10m-bulk: one 1024-row bucket over the resident stack
     # (0.03 GiB; 1.2 with the survivor stack)
     ("serve-bigann10m-bulk", 1024, 1221, 128, "highest", 0.1),
     # stream-msturing10m-runbook (ISSUE 34): the same bucket over 1224
     # tiles (24 of them headroom) of a width off the lane grid, d = 100
     ("stream-msturing10m-runbook", 1024, 1224, 100, "highest", 0.1),
+    # shapes no cell runs, which the rule admits all the same (ISSUE 40):
+    # the filtered cell's stack (768 tiles, a multiple of 128, of d = 192)
+    # under a 1024-row bucket WITHOUT a predicate, and the all-kNN tile
+    # over 256 tiles — the view is the bitcast at these tile counts too
+    ("yfcc10m-no-predicate", 1024, 768, 192, "highest", 0.1),
+    ("mnist-256-tiles", 4096, 256, 784, "high", 0.19),
 ])
 def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
         v5e_devices, monkeypatch, cell, q, tiles, dim, precision, temp_gib):
@@ -509,10 +581,11 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     assert _dist_dots(plain.as_text()) == {"": (("f32", "f32"), precision)}
     # under the rule: the engaged branch holds ONE bf16 x bf16 -> f32 dot
     # (no operand_precision: a DEFAULT dot), the other the configured one;
-    # where one kernel walks the stack (ISSUE 37: the bulk cell's shape)
-    # the engaged branch's dot is inside it
+    # where one kernel walks the stack (ISSUE 37: the bulk cell's shape;
+    # ISSUE 40: the all-kNN cell's, in 1024-row blocks) the engaged
+    # branch's dot is inside it
     fused = serial.fused_rule(cfg, q, 8192, dim)
-    assert fused == (cell == "serve-bigann10m-bulk")
+    assert fused == (None if dim == 100 else 1024)
     assert _dist_dots(ruled.as_text()) == {
         **({} if fused else {"onepass": (("bf16", "bf16"), None)}),
         "multipass": (("f32", "f32"), precision),
