@@ -738,7 +738,11 @@ def _ivf_meta(index, cfg: KNNConfig, q_tile: int, q_pad: int,
 
 
 def _lower_ivf(target: LintTarget):
-    from mpi_knn_tpu.ivf.search import _ivf_serve_jit, ivf_query_shapes
+    from mpi_knn_tpu.ivf.search import (
+        PROBE_FIELDS,
+        _ivf_serve_jit,
+        ivf_query_shapes,
+    )
     from mpi_knn_tpu.ops.topk import init_topk_tiles
 
     if target.metric != "l2" or target.dtype != "float32":
@@ -759,6 +763,7 @@ def _lower_ivf(target: LintTarget):
         jnp.full((qt, q_tile), -1, jnp.int32),
         carry_d,
         carry_i,
+        jnp.zeros(PROBE_FIELDS, jnp.int32),
         index.centroids,
         index.centroid_sqs,
         index.buckets,

@@ -133,6 +133,10 @@ class TileCounts(typing.NamedTuple):
     # rows x 1024 columns) by what became of them in *bins* under the row
     # bound (:func:`_merge_carried`), from a program whose scans carry one
     bins_chunks: jax.Array | None = None
+    # int32 ``[probes, bucket_cap, live rows, distinct partitions, their
+    # live rows]``: what a clustered batch probed (``ivf/search.py
+    # probe_counts``), from a clustered index's program alone
+    ivf_probe: jax.Array | None = None
 
 
 def select_tiles(rescanned: jax.Array):

@@ -253,6 +253,25 @@ class KNNConfig:
     kmeans_iters: int = 25
     kmeans_init: str = "kmeans++"
     ivf_seed: int = 0
+    # rows the partitioner trains on: a seeded draw of this many distinct
+    # corpus rows (ivf/kmeans.py sample_rows, by ivf_seed), every row then
+    # assigned to its nearest trained centroid — FAISS's rule for an IVF
+    # coarse quantiser is at most 256 points a centroid. None = every row
+    # (training and assignment one program, as before the field existed).
+    kmeans_sample: Optional[int] = None
+    # slots of one padded list of a clustered store, stated. The store is
+    # (partitions, bucket_cap, d) whatever the lists hold, so its height
+    # IS its memory and its programs' shapes; unstated it is the largest
+    # list of THIS corpus (with headroom), an extreme value of thousands
+    # that Lloyd's objective does not see (2.3 ... 3.7 x the mean over 24
+    # seeds of one law). Stated, the build honours it: in every training
+    # round but the last two a list over 5/6 of it is split in two and one
+    # of the smallest given up for it (ivf/kmeans.py _split_largest; at
+    # most partitions/64 a round), and the store is this tall whatever the
+    # data. A list that outgrows it all the same raises the height (no row
+    # is ever dropped). None = plain Lloyd rounds, the largest list
+    # decides, as before the field existed.
+    bucket_cap: Optional[int] = None
     # --- sharded clustered index (mpi_knn_tpu.ivf.sharded) ---------------
     # ivf_shards: distribute the clustered index's bucket store over this
     # many ring-mesh devices (TPU-KNN's deployment shape): each device
@@ -500,6 +519,15 @@ class KNNConfig:
                 "k-means partitioner and the centroid score are L2 "
                 f"geometry (got metric={self.metric!r})"
             )
+        if self.kmeans_sample is not None and self.kmeans_sample < max(
+                1, self.partitions or 1):
+            raise ValueError(
+                "kmeans_sample must be at least the partitions it trains "
+                f"({self.partitions}), got {self.kmeans_sample}"
+            )
+        if self.bucket_cap is not None and self.bucket_cap < 1:
+            raise ValueError(
+                f"bucket_cap must be >= 1 slot, got {self.bucket_cap}")
         if self.kmeans_iters < 1:
             raise ValueError(
                 f"kmeans_iters must be >= 1, got {self.kmeans_iters}"

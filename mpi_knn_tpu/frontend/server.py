@@ -156,7 +156,8 @@ class Frontend:
     # -- lifecycle --------------------------------------------------------
 
     def start(self, warm_sizes=None, background: bool = False,
-              warm_parallel: int | None = None) -> "Frontend":
+              warm_parallel: int | None = None,
+              warm_writes: bool = True) -> "Frontend":
         """Start the pump; ``warm_sizes`` (row counts) pre-builds those
         buckets at EVERY ladder rung first — via the persistent AOT
         cache when one is active, across ``warm_parallel`` threads
@@ -186,7 +187,14 @@ class Frontend:
         a coalesced batch can land in any power-of-two bucket in that
         span (a ragged deadline dispatch, a lull), and per-bucket
         admission during warming is only safe when the span a request
-        could reach is entirely built."""
+        could reach is entirely built.
+
+        ``warm_writes=False`` leaves the write path cold (the host mirror
+        of the id plane, the upsert / delete / assign cells and, for a
+        clustered index, the compaction cell, which holds the store
+        twice): for a corpus that is served and never written, where the
+        warm-up is set-up time and memory spent on programs nobody calls.
+        A write still works; the first one builds what it needs."""
         if warm_sizes is None:
             base = self.session.cfg.query_bucket
             top = self.policy.max_batch_rows
@@ -207,7 +215,7 @@ class Frontend:
 
             mirror = None
             try:
-                if supports_mutation(self.session.index):
+                if warm_writes and supports_mutation(self.session.index):
                     # the host mirror of the id plane, beside the serve
                     # programs' warm-up (numpy and a device fetch: the
                     # GIL is free for most of it)

@@ -52,13 +52,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mpi_knn_tpu.backends.serial import TileCounts
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ivf.kmeans import kmeans
+from mpi_knn_tpu.ivf.kmeans import (
+    SPLIT_AT,
+    assign_rows,
+    column_sums,
+    kmeans,
+    sample_rows,
+)
 from mpi_knn_tpu.ivf.search import (
+    PROBE_FIELDS,
     ivf_query_shapes,
     ivf_serve_chunk,
     search_ivf,
 )
+from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.distance import sq_norms
 from mpi_knn_tpu.ops.quant import (
     QUANT_DTYPES,
@@ -91,10 +100,24 @@ class IVFLayout(BatchLayout):
     would change the math against the one-shot ``search_ivf``)."""
 
     static_argnames = ("cfg", "nprobe")
+    donate_argnums = (2, 3, 4)  # the carry pair and the probe counts' zeros
     tiled = True
 
     def serve_fn(self):
         return ivf_serve_chunk
+
+    def counts_scratch(self, index):
+        """The third donated scratch: where the batch program writes what
+        it counted (the sharded layout's is its exchange stats)."""
+        return jax.ShapeDtypeStruct((PROBE_FIELDS,), jnp.int32)
+
+    def query_side(self, index, cfg, q_pad, q_tile):
+        return super().query_side(index, cfg, q_pad, q_tile) + [
+            self.counts_scratch(index)]
+
+    def carry_maker(self, index, cfg, q_pad, q_tile):
+        carry = super().carry_maker(index, cfg, q_pad, q_tile)
+        return lambda: (*carry(), jnp.zeros(PROBE_FIELDS, jnp.int32))
 
     def bucket_shapes(self, index, cfg, bucket):
         q_tile, q_pad = ivf_query_shapes(
@@ -115,12 +138,32 @@ class IVFLayout(BatchLayout):
 
     carry_dtype = query_dtype
 
+    def batch_counts(self, index, q_pad, q_tile, rest):
+        # third output of the batch program: what it probed
+        return TileCounts(ivf_probe=rest[0])
+
     def stamp_gauges(self, index, cfg, registry):
         registry.gauge(
             "ivf_at_rest_bytes",
             help="resident bytes of the clustered bucket store "
             "(codes + scales for quantized stores)",
         ).set(index.nbytes_resident)
+        registry.gauge(
+            "ivf_bucket_cap",
+            help="slots of one partition's padded bucket: the largest "
+            "partition at the build, plus headroom",
+        ).set(index.bucket_cap)
+        registry.gauge(
+            "ivf_bucket_fill_pct",
+            help="rows of the build over the slots of the bucket store "
+            "(partitions x bucket_cap), in percent: what padding every "
+            "bucket to the largest leaves empty",
+        ).set(100.0 * index.m / (index.partitions * index.bucket_cap))
+        registry.gauge(
+            "serve_index_nprobe",
+            help="partitions a query row probes in the batch program "
+            "built last (a degraded rung's is smaller)",
+        ).set(cfg.nprobe)
 
 
 @dataclasses.dataclass
@@ -204,8 +247,8 @@ class IVFIndex:
         """
         frozen = (
             "backend", "metric", "dtype", "partitions", "kmeans_iters",
-            "kmeans_init", "ivf_seed", "center", "exclude_zero", "zero_eps",
-            "bucket_headroom",
+            "kmeans_init", "ivf_seed", "kmeans_sample", "center",
+            "exclude_zero", "zero_eps", "bucket_headroom", "bucket_cap",
         )
         want = cfg if cfg.backend != "auto" else cfg.replace(backend="serial")
         bad = [
@@ -325,6 +368,80 @@ def build_ivf_index(
     _refuse_inert_knobs(cfg)
     cfg = cfg.replace(backend="serial")
 
+    m, dim = (corpus.m, corpus.dim) if isinstance(corpus, CorpusIndex) \
+        else np.shape(corpus)
+    if cfg.partitions > m:
+        raise ValueError(
+            f"partitions={cfg.partitions} exceeds the corpus rows ({m})"
+        )
+    with obs_spans.span("index-build", cat="index", backend="ivf",
+                        rows=int(m), dim=int(dim), metric=cfg.metric,
+                        partitions=cfg.partitions):
+        if isinstance(corpus, jax.Array):
+            mu, centroids, buckets_f32, bucket_ids, cap = _store_on_device(
+                corpus, cfg)
+        else:
+            mu, centroids, buckets_f32, bucket_ids, cap = _store_on_host(
+                corpus, cfg)
+        return _finish_index(cfg, m, dim, cap, mu, centroids, buckets_f32,
+                             bucket_ids)
+
+
+def _bucket_cap(counts: np.ndarray, cfg: KNNConfig) -> int:
+    """The one static bucket height: the height the configuration states
+    (``bucket_cap``), else the largest partition with headroom — which
+    also decides where a partition outgrew the stated height.
+
+    Capacity headroom (ISSUE 14): spare slots per bucket are what buy
+    STATIC-SHAPE upserts — the freelist hands them out and a donated
+    scatter fills them in place, no recompile. The padding slots carry
+    id −1 (mask_tile: +inf candidates, never answers), so headroom
+    costs padded FLOPs/gather bytes, not correctness — set
+    bucket_headroom=0.0 for a frozen corpus."""
+    need = max(int(counts.max()), 1)
+    return pad_to_multiple(
+        max(1, int(np.ceil(need * (1.0 + cfg.bucket_headroom))),
+            cfg.bucket_cap or 0), 8
+    )
+
+
+def _fill_span(m: int, partitions: int, cap: int, dim: int):
+    """The span around a store's fill: what it writes and how full."""
+    return obs_spans.span(
+        "ivf-fill", cat="index", rows=int(m),
+        bytes=partitions * cap * (dim * 4 + 8), bucket_cap=cap,
+        fill_pct=int(round(100.0 * m / (partitions * cap))))
+
+
+def _train(rows, cfg: KNNConfig, m: int):
+    """The partitioner over ``rows`` (centred: the training sample, or
+    all ``m`` rows), inside its span. A stated ``bucket_cap`` is honoured
+    here: the rounds split what stands over ``SPLIT_AT`` of it."""
+    balance = None
+    if cfg.bucket_cap is not None:
+        if cfg.bucket_cap * cfg.partitions < m:
+            raise ValueError(
+                f"bucket_cap={cfg.bucket_cap} x partitions="
+                f"{cfg.partitions} slots cannot hold the corpus rows ({m})"
+            )
+        balance = SPLIT_AT * cfg.bucket_cap * cfg.partitions / m
+    with obs_spans.span("ivf-train", cat="index", rows=int(rows.shape[0]),
+                        partitions=cfg.partitions, iters=cfg.kmeans_iters):
+        res = kmeans(
+            rows, cfg.partitions, iters=cfg.kmeans_iters, seed=cfg.ivf_seed,
+            init=cfg.kmeans_init, balance=balance,
+        )
+        jax.block_until_ready(res.centroids)
+    return res
+
+
+def _store_on_host(corpus, cfg: KNNConfig):
+    """The store of a host corpus (or of a serial ``CorpusIndex``'s centred
+    tiles): centred by the float64 mean on the host, trained and assigned
+    on the device, filled by one numpy scatter and copied up. Returns
+    ``(mu, centroids, (P, cap, d) float32 buckets, (P, cap) ids, cap)``."""
+    from mpi_knn_tpu.serve.index import CorpusIndex
+
     mu = None
     if isinstance(corpus, CorpusIndex):
         rows, mu, built_cfg = _corpus_from_serve_index(corpus)
@@ -336,50 +453,139 @@ def build_ivf_index(
                 )
         X = rows  # already centered at serve-index build time
     else:
-        X = np.asarray(
-            corpus if not isinstance(corpus, jax.Array)
-            else jax.device_get(corpus),
-            dtype=np.float32,
-        )
+        X = np.asarray(corpus, dtype=np.float32)
         if cfg.center:
             mu = X.astype(np.float64).mean(axis=0)
             X = X - mu
     m, dim = X.shape
-    if cfg.partitions > m:
-        raise ValueError(
-            f"partitions={cfg.partitions} exceeds the corpus rows ({m})"
+    P = cfg.partitions
+    picked = sample_rows(m, cfg.kmeans_sample, cfg.ivf_seed)
+    if picked is None:
+        res = _train(X, cfg, m)
+        assign, counts = res.assignments, res.counts
+    else:
+        res = _train(X[picked], cfg, m)
+        with obs_spans.span("ivf-assign", cat="index", rows=int(m)):
+            assign, counts = assign_rows(
+                jnp.asarray(X, dtype=jnp.float32),
+                jnp.zeros(dim, jnp.float32), res.centroids)
+    assign, counts = np.asarray(assign), np.asarray(counts)
+    cap = _bucket_cap(counts, cfg)
+    with _fill_span(m, P, cap, dim):
+        buckets_np = np.zeros((P, cap, dim), dtype=np.float32)
+        ids_np = np.full((P, cap), -1, dtype=np.int32)
+        # vectorized scatter: rows sorted by cluster, each row's slot is
+        # its rank within its cluster (searchsorted finds the cluster's
+        # start) — a per-row Python loop here would make SIFT-scale
+        # builds interpreter-bound
+        order = np.argsort(assign, kind="stable")
+        sa = assign[order]
+        within = np.arange(m) - np.searchsorted(sa, sa)
+        buckets_np[sa, within] = X[order]
+        ids_np[sa, within] = order
+        buckets, ids = jnp.asarray(buckets_np), jnp.asarray(ids_np)
+    return mu, res.centroids, buckets, ids, cap
+
+
+# elements of the rows one step of the device fill gathers (32 MiB)
+FILL_ELEMS = 1 << 23
+
+
+def _store_on_device(corpus: jax.Array, cfg: KNNConfig):
+    """The store of a device corpus, without a host round trip and
+    without a second corpus-sized array beside the caller's and the
+    store: the mean from per-block sums (float64 only over the few KB
+    that cross the host), the partitioner trained on the seeded sample
+    (``kmeans_sample``; with every row it trains on one centred copy, as
+    the host path does), every row assigned block by block with the
+    centring inside the block, and the store filled a few partitions a
+    step by a gather of their rows (:func:`_fill_store`). The mean is the
+    float32 nearest the float64 one, so that a batch centred in float64 on
+    the host and the rows centred in float32 here are the same numbers.
+    Returns what :func:`_store_on_host` returns."""
+    m, dim = corpus.shape
+    P = cfg.partitions
+    mu = None
+    mu_dev = jnp.zeros(dim, jnp.float32)
+    if cfg.center:
+        sums = np.asarray(column_sums(corpus), dtype=np.float64)
+        mu = (sums.sum(axis=0) / m).astype(np.float32).astype(np.float64)
+        mu_dev = jnp.asarray(mu, dtype=jnp.float32)
+    picked = sample_rows(m, cfg.kmeans_sample, cfg.ivf_seed)
+    train = corpus if picked is None else corpus[jnp.asarray(picked)]
+    res = _train(train.astype(jnp.float32) - mu_dev, cfg, m)
+    del train
+    if picked is None:
+        assign, counts = res.assignments, res.counts
+    else:
+        with obs_spans.span("ivf-assign", cat="index", rows=int(m)):
+            assign, counts = assign_rows(corpus, mu_dev, res.centroids)
+            jax.block_until_ready(assign)
+    cap = _bucket_cap(np.asarray(counts), cfg)
+    with _fill_span(m, P, cap, dim):
+        # the rows in partition order, ascending id inside a partition
+        # (the host fill's order). Sorted on the host: 4 B a row each way,
+        # where the v5e compiler takes half a minute over a device sort
+        # of this length; 16-bit keys take numpy's radix sort
+        keys = np.asarray(assign)
+        if P <= 1 << 16:
+            keys = keys.astype(np.uint16)
+        order = jnp.asarray(
+            np.argsort(keys, kind="stable").astype(np.int32))
+        buckets, ids = _fill_store(
+            corpus, mu_dev, order, counts, cap=cap,
+            step=_fill_step(P, cap * dim))
+        jax.block_until_ready(buckets)
+    return mu, res.centroids, buckets, ids, cap
+
+
+def _fill_step(partitions: int, slot_elems: int) -> int:
+    """Partitions one step of the device fill writes: the largest divisor
+    of ``partitions`` whose buckets stay within ``FILL_ELEMS``."""
+    want = max(1, min(partitions, FILL_ELEMS // max(slot_elems, 1)))
+    return next(s for s in range(want, 0, -1) if partitions % s == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "step"))
+def _fill_store(corpus, mu, order, counts, cap: int, step: int):
+    """((P, cap, d) float32 buckets of ``corpus - mu``, (P, cap) int32
+    ids, -1 where a slot is empty) from ``order``, the row numbers in
+    partition order, and the rows a partition: ``step`` partitions at a
+    time — slot c of partition p is row ``order[start[p] + c]`` where
+    c < counts[p] — gathered from the corpus where it lies and written
+    into the store in place. No scatter, no sorted copy of the corpus:
+    the temporaries are one step's rows."""
+    m, dim = corpus.shape
+    P = counts.shape[0]
+    starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
+    slot = jnp.arange(cap, dtype=jnp.int32)
+
+    def one_step(s, carry):
+        buckets, ids = carry
+        p = s * step + jnp.arange(step, dtype=jnp.int32)
+        live = slot[None, :] < counts[p][:, None]  # (step, cap)
+        at = jnp.minimum(starts[p][:, None] + slot[None, :], m - 1)
+        src = jnp.where(live, order[at], 0)
+        rows = jnp.where(
+            live[:, :, None], corpus[src].astype(jnp.float32) - mu, 0.0)
+        return (
+            jax.lax.dynamic_update_slice_in_dim(
+                buckets, rows, s * step, axis=0),
+            jax.lax.dynamic_update_slice_in_dim(
+                ids, jnp.where(live, src, -1), s * step, axis=0),
         )
 
-    res = kmeans(
-        X, cfg.partitions, iters=cfg.kmeans_iters, seed=cfg.ivf_seed,
-        init=cfg.kmeans_init,
-    )
-    assign = np.asarray(res.assignments)
-    counts = np.asarray(res.counts)
+    return jax.lax.fori_loop(
+        0, P // step, one_step,
+        (jnp.zeros((P, cap, dim), jnp.float32),
+         jnp.full((P, cap), -1, jnp.int32)))
+
+
+def _finish_index(cfg, m, dim, cap, mu, centroids, buckets_f32,
+                  bucket_ids) -> IVFIndex:
+    """The index around a filled float32 store: the at-rest form, the
+    norms, the probe count."""
     P = cfg.partitions
-    # capacity headroom (ISSUE 14): spare slots per bucket are what buy
-    # STATIC-SHAPE upserts — the freelist hands them out and a donated
-    # scatter fills them in place, no recompile. The padding slots carry
-    # id −1 (mask_tile: +inf candidates, never answers), so headroom
-    # costs padded FLOPs/gather bytes, not correctness — set
-    # bucket_headroom=0.0 for a frozen corpus.
-    need = max(int(counts.max()), 1)
-    cap = pad_to_multiple(
-        max(1, int(np.ceil(need * (1.0 + cfg.bucket_headroom)))), 8
-    )
-
-    buckets_np = np.zeros((P, cap, dim), dtype=np.float32)
-    ids_np = np.full((P, cap), -1, dtype=np.int32)
-    # vectorized scatter: rows sorted by cluster, each row's slot is its
-    # rank within its cluster (searchsorted finds the cluster's start) —
-    # a per-row Python loop here would make SIFT-scale builds
-    # interpreter-bound
-    order = np.argsort(assign, kind="stable")
-    sa = assign[order]
-    within = np.arange(m) - np.searchsorted(sa, sa)
-    buckets_np[sa, within] = X[order]
-    ids_np[sa, within] = order
-
     bucket_scales = None
     if cfg.dtype in QUANT_DTYPES:
         # block-scaled quantized store: per-row codes + scales (padding
@@ -389,19 +595,18 @@ def build_ivf_index(
         # distance is exact w.r.t. the values actually stored
         buckets, bucket_scales = jax.jit(
             functools.partial(quantize_rows, dtype=cfg.dtype)
-        )(jnp.asarray(buckets_np))
+        )(buckets_f32)
         bucket_sqs = jax.jit(
             lambda c, s: jax.vmap(sq_norms)(
                 dequantize_rows(c, s, cfg.dtype, dim)
             )
         )(buckets, bucket_scales)
     else:
-        buckets = jnp.asarray(buckets_np).astype(jnp.dtype(cfg.dtype))
+        buckets = buckets_f32.astype(jnp.dtype(cfg.dtype))
         # norms from the AT-REST buckets, under jit (bit-parity with the
         # serial serve index's norm construction)
         bucket_sqs = jax.jit(jax.vmap(sq_norms))(buckets)
-    bucket_ids = jnp.asarray(ids_np)
-    centroids = res.centroids
+    del buckets_f32
     centroid_sqs = jax.jit(sq_norms)(centroids)
 
     index = IVFIndex(

@@ -44,6 +44,14 @@ from mpi_knn_tpu.ops.distance import pairwise_sq_l2, sq_norms
 # (q_tile × c_tile) tile. 2048 × k ≤ 2048 · m elements — far inside every
 # configured tile budget at realistic partition counts.
 ASSIGN_BLOCK = 2048
+# how far to either side of a split cluster's mean its two centres start,
+# as a share of the way to its farthest member (_split_largest)
+SPLIT_STEP = 1.0 / 16
+# the share of a stated store height (KNNConfig.bucket_cap) over which the
+# training rounds split a cluster: the sample's largest then reads up to
+# 1.03 x the threshold over all rows (the rounds after the last split, the
+# sample's noise), and the height leaves 1.2 x
+SPLIT_AT = 5.0 / 6
 
 
 @dataclasses.dataclass
@@ -87,6 +95,75 @@ def _assign_blocks(data, data_sq, centroids, block: int):
     return assign, min_d2
 
 
+def sample_rows(m: int, n: int | None, seed: int) -> np.ndarray | None:
+    """The training sample of a clustered build (``KNNConfig.
+    kmeans_sample``): ``n`` distinct row numbers of ``m``, ascending, from
+    the seed alone (numpy's generator: the same rows on every platform).
+    None where the sample is every row (``n`` None or >= ``m``)."""
+    if n is None or n >= m:
+        return None
+    rng = np.random.default_rng([int(seed), 0x1F])
+    return np.sort(rng.choice(m, size=int(n), replace=False)).astype(np.int32)
+
+
+def _over_blocks(rows, block: int, one):
+    """``one(block of rows)`` over the whole ``block``-row blocks of
+    ``rows``, each sliced where it lies (a block's own temporaries, never
+    a padded or re-laid copy of the array), then over the rows left:
+    ``(stacked results or None, the tail's result or None)``."""
+    m = rows.shape[0]
+    block = min(block, m)
+    full = m // block
+    head = tail = None
+    if full:
+        head = jax.lax.map(
+            lambda b: one(jax.lax.dynamic_slice_in_dim(
+                rows, b * block, block)),
+            jnp.arange(full, dtype=jnp.int32))
+    if m % block:
+        tail = one(rows[full * block:])
+    return head, tail
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def column_sums(rows, block: int = ASSIGN_BLOCK):
+    """(blocks, d) float32 sums of ``block`` rows each, the last block the
+    rows left over: the chunked half of a mean that crosses the host as a
+    few KB — the caller adds the blocks up in float64. Whole numbers up
+    to 255 add up exactly in a block of 65536 rows or fewer."""
+    head, tail = _over_blocks(
+        rows, block, lambda blk: jnp.sum(blk.astype(jnp.float32), axis=0))
+    parts = ([head] if head is not None else []) + (
+        [tail[None]] if tail is not None else [])
+    return jnp.concatenate(parts, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def assign_rows(rows, mu, centroids, block: int = ASSIGN_BLOCK):
+    """((m,) int32 nearest centroid of every row of ``rows - mu``, (k,)
+    int32 rows a centroid), block by block: :func:`_assign_blocks`'
+    distance tile and argmin with the centring inside the block, so that
+    no centred copy of ``rows`` stands beside it."""
+    k = centroids.shape[0]
+    cent_sq = sq_norms(centroids)
+
+    def one(blk):
+        blk = blk.astype(jnp.float32) - mu
+        dist = pairwise_sq_l2(
+            blk, centroids, x_sq=sq_norms(blk), y_sq=cent_sq,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        return jnp.argmin(dist, axis=-1).astype(jnp.int32)
+
+    head, tail = _over_blocks(rows, block, one)
+    parts = ([head.reshape(-1)] if head is not None else []) + (
+        [tail] if tail is not None else [])
+    assign = jnp.concatenate(parts)
+    counts = jax.ops.segment_sum(
+        jnp.ones_like(assign), assign, num_segments=k)
+    return assign, counts
+
+
 def _init_random(key, data, k: int):
     """k distinct data rows by a seeded permutation draw."""
     m = data.shape[0]
@@ -126,10 +203,41 @@ def _init_kmeanspp(key, data, data_sq, k: int):
     return cents
 
 
+def _split_largest(data, assign, min_d2, counts, new, over: float,
+                   most: int):
+    """Size balancing of one Lloyd round: each of the ``most`` largest
+    clusters that holds more than ``over`` times the mean is split — its
+    centre and that of one of the ``most`` smallest clusters (the j-th
+    largest pairs with the j-th smallest, which is given up) are put a
+    little to either side of its mean, along the line to its farthest
+    member, so the next round halves it and later rounds move the two to
+    their halves' means. (A centre planted ON a member would keep that
+    member alone: in many dimensions every other row is nearer the mean.)
+    Lloyd's objective does not see sizes — in a mixture of isotropic
+    classes a class seeded with few centres keeps them — while a store
+    padded to the largest cluster pays for the largest."""
+    m = data.shape[0]
+    k = counts.shape[0]
+    big_n, big = jax.lax.top_k(counts, most)
+    _, small = jax.lax.top_k(-counts, most)
+    far_d2 = jax.ops.segment_max(min_d2, assign, num_segments=k)
+    rows = jnp.arange(m, dtype=jnp.int32)
+    far_row = jax.ops.segment_min(
+        jnp.where(min_d2 >= far_d2[assign], rows, m), assign,
+        num_segments=k)
+    split = ((big_n.astype(jnp.float32) > over * (m / k))
+             & (counts[small] < big_n // 2))[:, None]
+    mean = new[big]
+    step = SPLIT_STEP * (data[jnp.minimum(far_row[big], m - 1)] - mean)
+    new = new.at[big].set(jnp.where(split, mean - step, mean))
+    return new.at[small].set(jnp.where(split, mean + step, new[small]))
+
+
 @functools.partial(
-    jax.jit, static_argnames=("k", "iters", "init", "block")
+    jax.jit, static_argnames=("k", "iters", "init", "block", "balance")
 )
-def _kmeans_jit(data, seed, k: int, iters: int, init: str, block: int):
+def _kmeans_jit(data, seed, k: int, iters: int, init: str, block: int,
+                balance: float | None = None):
     data = data.astype(jnp.float32)
     data_sq = sq_norms(data)
     key = jax.random.PRNGKey(seed)
@@ -138,7 +246,7 @@ def _kmeans_jit(data, seed, k: int, iters: int, init: str, block: int):
     else:
         centroids = _init_random(key, data, k)
 
-    def lloyd(centroids, _):
+    def lloyd(centroids, left):
         assign, min_d2 = _assign_blocks(data, data_sq, centroids, block)
         counts = jax.ops.segment_sum(
             jnp.ones_like(assign, dtype=jnp.int32), assign, num_segments=k
@@ -152,9 +260,20 @@ def _kmeans_jit(data, seed, k: int, iters: int, init: str, block: int):
         _, far_idx = jax.lax.top_k(min_d2, k)
         erank = jnp.clip(jnp.cumsum(empty) - 1, 0, k - 1)
         new = jnp.where(empty[:, None], data[far_idx[erank]], new)
+        if balance is not None:
+            # not in the last two rounds: a centre just planted is a
+            # member's own row until a round has moved it to a mean
+            new = jnp.where(
+                left > 2,
+                _split_largest(data, assign, min_d2, counts, new, balance,
+                               max(1, k // 64)),
+                new)
         return new, None
 
-    centroids, _ = jax.lax.scan(lloyd, centroids, None, length=iters)
+    centroids, _ = jax.lax.scan(
+        lloyd, centroids,
+        None if balance is None else jnp.arange(iters, 0, -1),
+        length=iters)
     assign, min_d2 = _assign_blocks(data, data_sq, centroids, block)
     counts = jax.ops.segment_sum(
         jnp.ones_like(assign, dtype=jnp.int32), assign, num_segments=k
@@ -170,10 +289,13 @@ def kmeans(
     seed: int = 0,
     init: str = "kmeans++",
     block: int = ASSIGN_BLOCK,
+    balance: float | None = None,
 ) -> KMeansResult:
     """Train a k-partition Lloyd's k-means on (m, d) data (host numpy or
     device array), single compiled executable, bit-deterministic per
-    ``seed``. Returns :class:`KMeansResult`."""
+    ``seed``. ``balance``: split, in every round but the last two, a
+    cluster over that multiple of the mean rows (:func:`_split_largest`;
+    None = plain Lloyd rounds). Returns :class:`KMeansResult`."""
     if init not in ("kmeans++", "random"):
         raise ValueError(f"unknown kmeans init {init!r}")
     m = int(np.shape(data)[0])
@@ -184,7 +306,8 @@ def kmeans(
     if not isinstance(data, jax.Array):
         data = jnp.asarray(np.asarray(data, dtype=np.float32))
     centroids, assign, counts, inertia = _kmeans_jit(
-        data, jnp.int32(seed), k, iters, init, min(block, m)
+        data, jnp.int32(seed), k, iters, init, min(block, m),
+        balance=balance,
     )
     return KMeansResult(
         centroids=centroids, assignments=assign, counts=counts,

@@ -102,6 +102,7 @@ def finish_candidates(q_x, q_ids, q_sq, rows, ids, sqs, cfg: KNNConfig):
     )
 
 
+@jax.named_scope("knn.ivf/score")
 def score_centroids(q_x, centroids, centroid_sqs, nprobe: int):
     """Stage-1 routing decision, shared with the sharded path: exact
     HIGHEST centroid score + static-shape top-nprobe. Returns
@@ -129,8 +130,9 @@ def ivf_query_tile(
     nprobe: int,
 ):
     """One query tile through the two-stage search → ((q_tile, k) dists
-    ascending, ids). The single tile body behind the one-shot wrapper,
-    the serving engine's bucket-cache cells, and the lint lowering.
+    ascending, ids, what the tile probed: :func:`tile_probe`). The single
+    tile body behind the one-shot wrapper, the serving engine's
+    bucket-cache cells, and the lint lowering.
 
     A quantized store (``cfg.dtype`` int8/int4) changes exactly one
     thing: the probe gather moves CODE lanes (1/4–1/8 the f32 bytes —
@@ -145,14 +147,54 @@ def ivf_query_tile(
     q_sq, probe = score_centroids(q_x, centroids, centroid_sqs, nprobe)
     cap = buckets.shape[1]
     v = nprobe * cap
-    rows = jnp.take(buckets, probe, axis=0).reshape(-1, v, buckets.shape[2])
-    ids = jnp.take(bucket_ids, probe, axis=0).reshape(-1, v)
-    sqs = jnp.take(bucket_sqs, probe, axis=0).reshape(-1, v)
-    if bucket_scales is not None:
-        scl = jnp.take(bucket_scales, probe, axis=0).reshape(-1, v)
-        rows = dequantize_rows(rows, scl, cfg.dtype, dim)
-    rows = rows.astype(acc)
-    return finish_candidates(q_x, q_ids, q_sq, rows, ids, sqs, cfg)
+    with jax.named_scope("knn.ivf/gather"):
+        rows = jnp.take(buckets, probe, axis=0).reshape(
+            -1, v, buckets.shape[2])
+        ids = jnp.take(bucket_ids, probe, axis=0).reshape(-1, v)
+        sqs = jnp.take(bucket_sqs, probe, axis=0).reshape(-1, v)
+        if bucket_scales is not None:
+            scl = jnp.take(bucket_scales, probe, axis=0).reshape(-1, v)
+            rows = dequantize_rows(rows, scl, cfg.dtype, dim)
+        rows = rows.astype(acc)
+    d, i = finish_candidates(q_x, q_ids, q_sq, rows, ids, sqs, cfg)
+    return d, i, tile_probe(probe, ids, centroids.shape[0])
+
+
+def tile_probe(probe, ids, partitions: int):
+    """What one query tile probed, from what its program holds anyway:
+    ``(live rows among the gathered slots, (P,) bool partitions probed,
+    (P,) int32 their live rows)``. ``ids`` are the gathered slots' ids
+    (q_tile, nprobe * cap), -1 where a slot is empty or dead; a partition
+    probed by several rows of the tile is marked once."""
+    live = (ids >= 0).reshape(*probe.shape, -1).sum(-1, dtype=jnp.int32)
+    flat = probe.reshape(-1)
+    return (
+        jnp.sum(live, dtype=jnp.int32),
+        jnp.zeros(partitions, jnp.bool_).at[flat].set(True),
+        jnp.zeros(partitions, jnp.int32).at[flat].max(live.reshape(-1)),
+    )
+
+
+PROBE_FIELDS = 5  # the width of a batch's probe counts (probe_counts)
+
+
+def probe_counts(q_rows: int, nprobe: int, cap: int, live, seen, part_live):
+    """A batch's ``TileCounts.ivf_probe`` from its tiles'
+    :func:`tile_probe` (stacked): int32 ``[probes issued (query rows x
+    nprobe, padding rows of the batch included: they probe too),
+    bucket_cap (a probe gathers that many slots), live rows among the
+    gathered slots, distinct partitions the batch touched, live rows of
+    those]``. The last two are what any implementation has to read once a
+    batch. (Slots are left to the reader, probes x bucket_cap: a 1024-row
+    batch gathers 1e8 of them and an int32 sum on the device would not
+    hold a large one's.)"""
+    return jnp.stack([
+        jnp.int32(q_rows * nprobe),
+        jnp.int32(cap),
+        jnp.sum(live, dtype=jnp.int32),
+        jnp.sum(jnp.any(seen, axis=0), dtype=jnp.int32),
+        jnp.sum(jnp.max(part_live, axis=0), dtype=jnp.int32),
+    ])
 
 
 def ivf_serve_chunk(
@@ -160,6 +202,7 @@ def ivf_serve_chunk(
     qid_tiles: jax.Array,  # (QT, q_tile)
     carry_d: jax.Array,  # (QT, q_tile, k) per-batch scratch (donatable)
     carry_i: jax.Array,
+    probed: jax.Array,  # (PROBE_FIELDS,) int32 zeros (donatable)
     centroids: jax.Array,
     centroid_sqs: jax.Array,
     buckets: jax.Array,
@@ -171,21 +214,26 @@ def ivf_serve_chunk(
 ):
     """One serving batch against a resident :class:`~mpi_knn_tpu.ivf.index.
     IVFIndex` — the engine's uniform (queries, query_ids, carry_d,
-    carry_i, <resident arrays…>) convention so the scratch donation stays
-    ``donate_argnums=(2, 3)``. The tile results merge into the (all-inf)
+    carry_i, probed, <resident arrays…>) convention, the scratch donated
+    (``donate_argnums=(2, 3, 4)``). The tile results merge into the (all-inf)
     donated scratch — a bit-exact no-op merge whose sole purpose is giving
     the scratch buffers an output to alias (the pallas serve path's
     trick)."""
 
     def per_tile(args):
         q_x, q_ids, cd_, ci_ = args
-        d, i = ivf_query_tile(
+        d, i, probed = ivf_query_tile(
             q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
             bucket_sqs, bucket_scales, cfg, nprobe,
         )
-        return merge_topk(cd_, ci_, d.astype(cd_.dtype), i, method="exact")
+        return (*merge_topk(cd_, ci_, d.astype(cd_.dtype), i,
+                            method="exact"), probed)
 
-    return jax.lax.map(per_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    d, i, tiles = jax.lax.map(
+        per_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    return d, i, probed + probe_counts(
+        q_tiles.shape[0] * q_tiles.shape[1], nprobe, buckets.shape[1],
+        *tiles)
 
 
 _ivf_serve_jit = jax.jit(
@@ -256,10 +304,11 @@ def run_query_tiles(index, q_tiles, qid_tiles, cfg: KNNConfig):
     carry_d, carry_i = init_topk_tiles(qt, q_tile, cfg.k, dtype=jnp.float32)
     return _ivf_serve_jit(
         q_tiles, qid_tiles, carry_d, carry_i,
-        index.centroids, index.centroid_sqs, index.buckets,
+        jnp.zeros(PROBE_FIELDS, jnp.int32), index.centroids,
+        index.centroid_sqs, index.buckets,
         index.bucket_ids, index.bucket_sqs, index.bucket_scales,
         cfg, cfg.nprobe,
-    )
+    )[:2]
 
 
 def search_ivf(index, queries, query_ids=None, config=None,

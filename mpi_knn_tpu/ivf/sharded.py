@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from mpi_knn_tpu.backends.serial import TileCounts
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ivf.index import IVFIndex, IVFLayout, _refuse_inert_knobs
 from mpi_knn_tpu.ivf.search import finish_candidates, score_centroids
@@ -412,13 +413,14 @@ class ShardedIVFLayout(IVFLayout):
     def query_sharding(self, index):
         return NamedSharding(index.mesh, P(index.axis))
 
-    def query_side(self, index, cfg, q_pad, q_tile):
-        return super().query_side(index, cfg, q_pad, q_tile) + [
-            jax.ShapeDtypeStruct(
-                (N_STATS * index.shards,), jnp.int32,
-                sharding=self.query_sharding(index),
-            )
-        ]
+    def counts_scratch(self, index):
+        return jax.ShapeDtypeStruct(
+            (N_STATS * index.shards,), jnp.int32,
+            sharding=self.query_sharding(index),
+        )
+
+    def batch_counts(self, index, q_pad, q_tile, rest):
+        return TileCounts()
 
     def carry_maker(self, index, cfg, q_pad, q_tile):
         return scratch_maker(

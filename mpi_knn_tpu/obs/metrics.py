@@ -395,6 +395,46 @@ class MetricsRegistry:
             "skipped because no value was at or under its row's bound",
         )
 
+    def count_ivf_probe(self, probed) -> None:
+        """Add a clustered batch's probe counts (``KNNResult.ivf_probe`` /
+        ``BatchResult.ivf_probe``: ints ``[probes, bucket_cap, live rows,
+        distinct partitions, their live rows]``, ``ivf/search.py
+        probe_counts``) to ``ivf_probe_slots_total``,
+        ``ivf_probe_live_rows_total``, ``ivf_probe_partitions_total
+        {kind="probes"|"distinct"}`` and ``ivf_probe_distinct_live_rows
+        _total``. The device counts, so call this where
+        :meth:`count_dist_steps` is called."""
+        import numpy as np
+
+        probes, cap, live, distinct, distinct_live = (
+            int(n) for n in np.asarray(probed).reshape(-1)[:5])
+        self.counter(
+            "ivf_probe_slots_total",
+            help="padded bucket slots the probe gathers read: query rows "
+            "of the padded batches x nprobe x bucket_cap",
+        ).inc(probes * cap)
+        self.counter(
+            "ivf_probe_live_rows_total",
+            help="live corpus rows among the gathered slots: the sum of "
+            "the probed partitions' rows, a (query row, probe) pair each",
+        ).inc(live)
+        for kind, n, what in (
+            ("probes", probes, "probes issued (query rows x nprobe)"),
+            ("distinct", distinct, "distinct partitions a batch touched, "
+             "summed over batches"),
+        ):
+            self.counter(
+                "ivf_probe_partitions_total",
+                help="partitions probed by clustered batches: " + what,
+                labels={"kind": kind},
+            ).inc(n)
+        self.counter(
+            "ivf_probe_distinct_live_rows_total",
+            help="live rows of the distinct partitions a batch touched, "
+            "summed over batches: what any implementation reads once a "
+            "batch",
+        ).inc(distinct_live)
+
     def _count_columns(self, name, paths, counts, help) -> None:
         import numpy as np
 

@@ -170,6 +170,11 @@ def fingerprint_facts(index, cfg, bucket: int, kind: str = "serve") -> dict:
         # read by a tagged index's programs alone: left out elsewhere, so
         # every entry of an index without tags keeps its address
         del cfg_doc["max_query_tags"]
+    for knob in ("kmeans_sample", "bucket_cap"):
+        # a clustered build's knobs, set by few: left out where unset, so
+        # every entry made before the fields existed keeps its address
+        if cfg_doc[knob] is None:
+            del cfg_doc[knob]
     doc = {
         "cfg": cfg_doc,
         "bucket": int(bucket),

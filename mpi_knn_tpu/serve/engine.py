@@ -747,6 +747,9 @@ class BatchResult:
     dist_steps: object = None
     select_tiles: object = None
     bins_chunks: object = None
+    # a clustered index's batch: what it probed (backends.serial
+    # .TileCounts.ivf_probe), counted at retire with the rest
+    ivf_probe: object = None
     # a tagged index's batch: ``((dists_padded, ids_padded, positions),
     # ...)``, one entry a device dispatch (the scan part, then the gather
     # parts), ``positions`` the batch rows its leading rows answer. Rows
@@ -876,6 +879,8 @@ def _count_tiles(registry, counts) -> None:
         registry.count_select_tiles(counts.select_tiles)
     if counts.bins_chunks is not None:
         registry.count_bins_chunks(counts.bins_chunks)
+    if counts.ivf_probe is not None:
+        registry.count_ivf_probe(counts.ivf_probe)
 
 
 def _count_exchange(stats, exchange_bytes: int | None,

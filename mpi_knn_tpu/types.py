@@ -56,6 +56,11 @@ class KNNResult:
         (``backends/serial.py _merge_carried``), one row a ring device;
         ``MetricsRegistry.count_bins_chunks`` adds it to
         ``knn_select_bins_chunks_total``. None where no bound rides.
+      ivf_probe: int32 (5,), what a clustered index's batch probed,
+        ``[probes, bucket_cap, live rows gathered, distinct partitions,
+        their live rows]`` (``ivf/search.py probe_counts``);
+        ``MetricsRegistry.count_ivf_probe`` adds it to the
+        ``ivf_probe_*_total`` counters. None from every other index.
     """
 
     dists: jax.Array
@@ -63,6 +68,7 @@ class KNNResult:
     dist_steps: jax.Array | None = None
     select_tiles: jax.Array | None = None
     bins_chunks: jax.Array | None = None
+    ivf_probe: jax.Array | None = None
 
     @property
     def k(self) -> int:
