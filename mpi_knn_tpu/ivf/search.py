@@ -20,6 +20,17 @@ Per query tile:
    split as ``ops/rerank.py``) and only the survivors hit the exact dot —
    the policies compose because stage 3 IS the shared rerank pipeline.
 
+**Bucket-major** (PR 42). Where the store's form allows
+(:func:`bucket_major_engages`: float32 rows, no scale table, the exact
+policy, d on the lane grid, the bucket a block where it rests) stages 2
+and 3 go over the LISTS a batch touches instead of over its query rows
+(:func:`bucket_major_tile`): the batch's probe table is turned over on the
+device, a kernel fetches each touched list once from the store where it
+rests and keeps each (query row, list) pair's k nearest slots
+(``ops/bucket_walk.py``), and the exact finish runs over a row's
+nprobe·k survivors. Same answers, the same counts; the per-row gather
+below is what every other store and policy keeps.
+
 Bucket padding slots carry id −1 → ``mask_tile`` forces them to +inf, so
 ragged partitions cost padded FLOPs but never wrong answers. Every point
 lives in exactly one partition, so probed candidates are duplicate-free
@@ -161,40 +172,223 @@ def ivf_query_tile(
 
 
 def tile_probe(probe, ids, partitions: int):
-    """What one query tile probed, from what its program holds anyway:
-    ``(live rows among the gathered slots, (P,) bool partitions probed,
-    (P,) int32 their live rows)``. ``ids`` are the gathered slots' ids
-    (q_tile, nprobe * cap), -1 where a slot is empty or dead; a partition
-    probed by several rows of the tile is marked once."""
+    """What one query tile probed row-major, from what its program holds
+    anyway: ``(live rows among the gathered slots, (P,) bool partitions
+    probed, (P,) int32 their live rows, work items walked: none)``. ``ids``
+    are the gathered slots' ids (q_tile, nprobe * cap), -1 where a slot is
+    empty or dead; a partition probed by several rows of the tile is marked
+    once."""
     live = (ids >= 0).reshape(*probe.shape, -1).sum(-1, dtype=jnp.int32)
     flat = probe.reshape(-1)
     return (
         jnp.sum(live, dtype=jnp.int32),
         jnp.zeros(partitions, jnp.bool_).at[flat].set(True),
         jnp.zeros(partitions, jnp.int32).at[flat].max(live.reshape(-1)),
+        jnp.int32(0),
     )
 
 
-PROBE_FIELDS = 5  # the width of a batch's probe counts (probe_counts)
+PROBE_FIELDS = 6  # the width of a batch's probe counts (probe_counts)
 
 
-def probe_counts(q_rows: int, nprobe: int, cap: int, live, seen, part_live):
-    """A batch's ``TileCounts.ivf_probe`` from its tiles'
-    :func:`tile_probe` (stacked): int32 ``[probes issued (query rows x
-    nprobe, padding rows of the batch included: they probe too),
-    bucket_cap (a probe gathers that many slots), live rows among the
-    gathered slots, distinct partitions the batch touched, live rows of
-    those]``. The last two are what any implementation has to read once a
-    batch. (Slots are left to the reader, probes x bucket_cap: a 1024-row
-    batch gathers 1e8 of them and an int32 sum on the device would not
-    hold a large one's.)"""
+def probe_counts(q_rows: int, nprobe: int, cap: int, live, seen, part_live,
+                 walked):
+    """A batch's ``TileCounts.ivf_probe`` from its tiles' counts
+    (:func:`tile_probe` / :func:`bucket_major_tile`'s, stacked): int32
+    ``[probes issued (query rows x nprobe, padding rows of the batch
+    included: they probe too), bucket_cap (a probe scans that many slots),
+    live rows among the probed slots, distinct partitions the batch
+    touched, live rows of those, work items walked]``. The fourth and fifth
+    are what any implementation has to read once a batch; the sixth is the
+    bucket-major program's (list, group of <= ``PROBE_GROUP`` query rows)
+    steps, 0 from the row-major program: it says which of the two answered
+    the batch, and probes over (work items x ``PROBE_GROUP``) is the
+    groups' fill. (Slots are left to the reader, probes x bucket_cap: a
+    1024-row batch scans 1e8 of them and an int32 sum on the device would
+    not hold a large one's.)"""
     return jnp.stack([
         jnp.int32(q_rows * nprobe),
         jnp.int32(cap),
         jnp.sum(live, dtype=jnp.int32),
         jnp.sum(jnp.any(seen, axis=0), dtype=jnp.int32),
         jnp.sum(jnp.max(part_live, axis=0), dtype=jnp.int32),
+        jnp.sum(walked, dtype=jnp.int32),
     ])
+
+
+# the query rows a work item of the bucket-major walk holds: a vreg's eight
+# sublanes. A list probed by n rows of a batch is ceil(n / PROBE_GROUP)
+# work items; the cell's batches probe a touched list 4.1 times on
+# average, and the kernel's selection, which bounds it, costs by the rows
+# of a group whether they are filled or not: 16 would double that for 7 %
+# fewer work items (PERF.md section 6, PR 42)
+PROBE_GROUP = 8
+# the kernel's share of VMEM: two buffers of a bucket and of what goes
+# with it (ops/bucket_walk.py bucket_walk_vmem_bytes)
+_WALK_VMEM_BYTES = 64 << 20
+
+
+def bucket_major_items(q_rows: int, nprobe: int, partitions: int) -> int:
+    """The work items W of a bucket-major batch of ``q_rows`` rows, a
+    static count: a list probed by n rows is ceil(n / PROBE_GROUP) items,
+    and over the lists that sums to at most (lists touched) + (probes //
+    PROBE_GROUP) whatever the skew — no probe can overflow a group, and
+    none is ever dropped."""
+    probes = q_rows * nprobe
+    return min(partitions, probes) + probes // PROBE_GROUP
+
+
+def bucket_major_engages(q_rows: int, nprobe: int, partitions: int,
+                         cap: int, dim: int, dtype: str = "float32",
+                         precision_policy: str = "exact") -> bool:
+    """Whether a batch of ``q_rows`` rows is answered BUCKET-MAJOR
+    (:func:`bucket_major_tile`), by the static shapes and the store's form
+    alone — the rule ``ops/topk.py fused_scan_engages`` is for the dense
+    scan:
+
+    - *the store's form*: float32 rows at rest with no scale table
+      (``dtype``: a quantised store's candidates are dequantised after
+      the gather, a bfloat16 bucket is a (16, 128)-tiled block; both keep
+      the row-major program), ranked exactly (``precision_policy``:
+      ``mixed`` is the row-major finish's overfetch);
+    - *the bucket is a block where it rests*: ``dim`` on the 128-lane grid
+      (a (P, cap, d) float32 store then rests row-major on the v5e:
+      ``ops/topk.py fused_scan_engages`` has the readings) and ``cap`` a
+      multiple of 8, and two buffers of it with what goes with it fit the
+      kernel's share of VMEM;
+    - (the caller's: k slots fit a list and the kernel's 128 lanes).
+
+    It is one algorithm at every batch height: its work items follow
+    ``q_rows`` (:func:`bucket_major_items`) and it fetches a list at most
+    as often as the row-major gather copies it. Where it says no, the
+    row-major tile body answers, as it stands."""
+    if (dtype != "float32" or precision_policy != "exact"
+            or dim % 128 or cap % 8 or q_rows < 1):
+        return False
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk_vmem_bytes
+
+    return bucket_walk_vmem_bytes(PROBE_GROUP, cap, dim) <= _WALK_VMEM_BYTES
+
+
+def _engages(cfg: KNNConfig, q_rows: int, nprobe: int, partitions: int,
+             cap: int, dim: int) -> bool:
+    return cfg.k <= min(cap, 128) and bucket_major_engages(
+        q_rows, nprobe, partitions, cap, dim, cfg.dtype,
+        cfg.precision_policy)
+
+
+def invert_probe(probe: jax.Array, partitions: int):
+    """The (Q, nprobe) probe table turned over on the device: its (query
+    row, list) pairs sorted by list (stable: a list's rows ascend) and
+    each list's run cut into work items of at most ``PROBE_GROUP`` rows.
+    Returns ``(item_lists (W,) int32 — the list of each work item, the
+    last real list's again past the real ones; item_rows (W, PROBE_GROUP)
+    int32 — the query row of each slot, -1 where a group is short;
+    pair_slot (Q, nprobe) int32 — where each pair's answer lies,
+    work item x PROBE_GROUP + slot; counts (P,) int32 — the rows that
+    probe each list; walked — the real work items)``, W =
+    :func:`bucket_major_items`. No scatter: sorted runs are found by
+    counting and everything else is a gather."""
+    i32 = jnp.int32
+    q_rows, nprobe = probe.shape
+    n = q_rows * nprobe
+    items = bucket_major_items(q_rows, nprobe, partitions)
+    lists, order = jax.lax.sort(
+        (probe.reshape(-1).astype(i32), jnp.arange(n, dtype=i32)),
+        num_keys=1, is_stable=True)
+    # (compare_all: one fused compare-and-count; the default's binary
+    # search is a loop of 14 small gathers, 0.44 ms a search on the v5e)
+    starts = jnp.searchsorted(
+        lists, jnp.arange(partitions, dtype=i32), side="left",
+        method="compare_all").astype(i32)
+    counts = jnp.diff(starts, append=jnp.full(1, n, i32))
+    items_of = (counts + (PROBE_GROUP - 1)) // PROBE_GROUP
+    ends = jnp.cumsum(items_of, dtype=i32)
+    bases = ends - items_of  # a list's first work item
+    walked = ends[-1]
+    w = jnp.arange(items, dtype=i32)
+    real = w < walked
+    item_lists = jnp.where(
+        real,
+        jnp.minimum(jnp.searchsorted(
+            ends, w, side="right", method="compare_all").astype(i32),
+            partitions - 1),
+        lists[-1])
+    # the item's first pair in the sorted order, and its slots' pairs
+    first = starts[item_lists] + (w - bases[item_lists]) * PROBE_GROUP
+    pos = first[:, None] + jnp.arange(PROBE_GROUP, dtype=i32)[None, :]
+    valid = real[:, None] & (
+        pos < (starts + counts)[item_lists][:, None])
+    at = jnp.clip(pos, 0, n - 1)
+    item_rows = jnp.where(valid, order[at] // nprobe, -1)
+    # the sort's inverse: each pair's rank in its list's run
+    rank = jnp.arange(n, dtype=i32) - starts[lists]
+    slot_sorted = (bases[lists] + rank // PROBE_GROUP) * PROBE_GROUP + (
+        rank % PROBE_GROUP)
+    _, pair_slot = jax.lax.sort((order, slot_sorted), num_keys=1)
+    return (item_lists, item_rows, pair_slot.reshape(q_rows, nprobe),
+            counts, walked)
+
+
+def bucket_major_tile(
+    q_x: jax.Array,  # (Q, d) one batch (or one tile of a tall one)
+    q_ids: jax.Array,  # (Q,)
+    centroids: jax.Array,
+    centroid_sqs: jax.Array,
+    buckets: jax.Array,  # (P, cap, d) float32
+    bucket_ids: jax.Array,
+    bucket_sqs: jax.Array,
+    cfg: KNNConfig,
+    nprobe: int,
+):
+    """:func:`ivf_query_tile`'s answer by a walk over the touched LISTS
+    instead of over the query rows: score as there; the probe table turned
+    over (:func:`invert_probe`); every work item's list fetched ONCE from
+    the store where it rests and met with its group of query rows on the
+    MXU (``ops/bucket_walk.py``, scope ``knn.ivf/gather``: still the only
+    place corpus payload enters the program), which keeps the k nearest
+    slots of each (query row, list) pair; and every row's nprobe x k
+    survivors gathered from the store by slot and finished by
+    :func:`finish_candidates` — the distances returned are computed by the
+    code that computes the row-major program's. Same ids: a row's k
+    nearest over its lists are among each list's k nearest. Returns
+    (dists, ids, (live pairs, (P,) lists probed, (P,) their live rows,
+    work items walked))."""
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk
+
+    acc, i32 = jnp.float32, jnp.int32
+    q_x = q_x.astype(acc)
+    q_rows, k = q_x.shape[0], cfg.k
+    partitions, cap, dim = buckets.shape
+    q_sq, probe = score_centroids(q_x, centroids, centroid_sqs, nprobe)
+    with jax.named_scope("knn.ivf/score"):
+        item_lists, item_rows, pair_slot, counts, walked = invert_probe(
+            probe, partitions)
+    with jax.named_scope("knn.ivf/gather"):
+        at = jnp.maximum(item_rows, 0)
+        slots = bucket_walk(
+            item_lists, walked, jnp.take(q_x, at, axis=0),
+            jnp.take(q_ids, at, axis=0) if cfg.exclude_self else None,
+            buckets, bucket_ids, bucket_sqs, k=k,
+            exclude_zero=cfg.exclude_zero, zero_eps=cfg.zero_eps)
+        # each pair's k slots, where its work item left them; -1 where a
+        # list holds fewer than k unmasked ones
+        slots = jnp.take(slots.reshape(-1, slots.shape[-1]),
+                         pair_slot.reshape(-1), axis=0)[:, :k]
+        short = (slots < 0).reshape(q_rows, nprobe * k)
+        slots = (probe.reshape(-1, 1).astype(i32) * cap
+                 + jnp.maximum(slots, 0)).reshape(q_rows, nprobe * k)
+        live = jnp.sum(bucket_ids >= 0, axis=1, dtype=i32)
+    with jax.named_scope("knn.rerank"):
+        rows = jnp.take(buckets.reshape(-1, dim), slots, axis=0).astype(acc)
+        ids = jnp.where(
+            short, -1, jnp.take(bucket_ids.reshape(-1), slots, axis=0))
+        sqs = jnp.take(bucket_sqs.reshape(-1), slots, axis=0)
+    d, i = finish_candidates(q_x, q_ids, q_sq, rows, ids, sqs, cfg)
+    seen = counts > 0
+    return d, i, (
+        jnp.sum(counts * live, dtype=i32), seen,
+        jnp.where(seen, live, 0), walked)
 
 
 def ivf_serve_chunk(
@@ -220,17 +414,30 @@ def ivf_serve_chunk(
     the scratch buffers an output to alias (the pallas serve path's
     trick)."""
 
+    partitions, cap, _ = buckets.shape
+    bucket_major = bucket_scales is None and _engages(
+        cfg, q_tiles.shape[1], nprobe, partitions, cap, centroids.shape[1])
+
     def per_tile(args):
         q_x, q_ids, cd_, ci_ = args
-        d, i, probed = ivf_query_tile(
-            q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
-            bucket_sqs, bucket_scales, cfg, nprobe,
-        )
+        if bucket_major:
+            d, i, probed = bucket_major_tile(
+                q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
+                bucket_sqs, cfg, nprobe)
+        else:
+            d, i, probed = ivf_query_tile(
+                q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
+                bucket_sqs, bucket_scales, cfg, nprobe,
+            )
         return (*merge_topk(cd_, ci_, d.astype(cd_.dtype), i,
                             method="exact"), probed)
 
-    d, i, tiles = jax.lax.map(
-        per_tile, (q_tiles, qid_tiles, carry_d, carry_i))
+    tiles = (q_tiles, qid_tiles, carry_d, carry_i)
+    if bucket_major and q_tiles.shape[0] == 1:  # one tile: no loop
+        d, i, tiles = jax.tree.map(
+            lambda x: x[None], per_tile(tuple(x[0] for x in tiles)))
+    else:
+        d, i, tiles = jax.lax.map(per_tile, tiles)
     return d, i, probed + probe_counts(
         q_tiles.shape[0] * q_tiles.shape[1], nprobe, buckets.shape[1],
         *tiles)
@@ -243,23 +450,34 @@ _ivf_serve_jit = jax.jit(
 
 def ivf_query_shapes(cfg: KNNConfig, nprobe: int, bucket_cap: int,
                      dim: int, nq: int) -> tuple[int, int]:
-    """(q_tile, q_pad) for an IVF batch: the probe gather materializes
-    q_tile·nprobe·bucket_cap·dim elements, so q_tile shrinks until that
-    stays inside ``cfg.max_tile_elems`` — the same hard per-step bound
+    """(q_tile, q_pad) for an IVF batch, by what its tile program
+    materialises — the hard per-step bound ``cfg.max_tile_elems`` that
     ``cap_corpus_tile`` enforces for the dense backends, applied to the
-    gather (the IVF path's dominant intermediate). Unlike the dense cap,
-    the per-ROW gather (nprobe·bucket_cap·dim) is fixed by the index
-    layout, so when even a single-query tile exceeds the budget there is
-    nothing left to shrink — that is refused loudly (the convention),
-    never silently materialized."""
+    IVF path's dominant intermediate; q_tile halves until that fits:
+
+    - *bucket-major* (:func:`bucket_major_engages`): the survivors the
+      finish gathers, q_tile x nprobe x k x dim. A batch is one query tile
+      wherever that fits (the cell's 1024 rows: 2.1e7): the walk fetches
+      a bucket where it rests, and no per-row gather exists to bound;
+    - *row-major*: the probe gather, q_tile x nprobe x bucket_cap x dim.
+
+    What one query row needs is fixed by the index layout, so when even a
+    single-row tile exceeds the budget there is nothing left to shrink —
+    that is refused loudly (the convention), never silently materialized."""
     q_tile = min(cfg.query_tile, pad_to_multiple(nq, 8))
-    per_row = max(1, nprobe * bucket_cap * dim)
-    while q_tile > 1 and q_tile * per_row > cfg.max_tile_elems:
+    partitions = cfg.partitions or 1
+
+    def elems(rows: int) -> int:
+        if _engages(cfg, rows, nprobe, partitions, bucket_cap, dim):
+            return rows * nprobe * cfg.k * dim
+        return rows * max(1, nprobe * bucket_cap * dim)
+
+    while q_tile > 1 and elems(q_tile) > cfg.max_tile_elems:
         q_tile = max(1, q_tile // 2)
-    if q_tile * per_row > cfg.max_tile_elems:
+    if elems(q_tile) > cfg.max_tile_elems:
         raise ValueError(
             f"one query row's probe gather (nprobe={nprobe} × bucket_cap="
-            f"{bucket_cap} × d={dim} = {per_row} elems) exceeds "
+            f"{bucket_cap} × d={dim}: {elems(q_tile)} elems) exceeds "
             f"max_tile_elems={cfg.max_tile_elems}; lower nprobe/partitions "
             "(bigger partitions mean bigger buckets), raise "
             "max_tile_elems, or use a dense backend for full scans"

@@ -398,24 +398,26 @@ class MetricsRegistry:
     def count_ivf_probe(self, probed) -> None:
         """Add a clustered batch's probe counts (``KNNResult.ivf_probe`` /
         ``BatchResult.ivf_probe``: ints ``[probes, bucket_cap, live rows,
-        distinct partitions, their live rows]``, ``ivf/search.py
-        probe_counts``) to ``ivf_probe_slots_total``,
+        distinct partitions, their live rows, work items walked]``,
+        ``ivf/search.py probe_counts``) to ``ivf_probe_slots_total``,
         ``ivf_probe_live_rows_total``, ``ivf_probe_partitions_total
-        {kind="probes"|"distinct"}`` and ``ivf_probe_distinct_live_rows
-        _total``. The device counts, so call this where
-        :meth:`count_dist_steps` is called."""
+        {kind="probes"|"distinct"}``, ``ivf_probe_distinct_live_rows
+        _total``, ``ivf_probe_groups_total`` and ``ivf_probe_batches_total
+        {path="bucket_major"|"row_major"}`` (the program that walked no
+        work item is the row-major one). The device counts, so call this
+        where :meth:`count_dist_steps` is called."""
         import numpy as np
 
-        probes, cap, live, distinct, distinct_live = (
-            int(n) for n in np.asarray(probed).reshape(-1)[:5])
+        probes, cap, live, distinct, distinct_live, walked = (
+            int(n) for n in np.asarray(probed).reshape(-1)[:6])
         self.counter(
             "ivf_probe_slots_total",
-            help="padded bucket slots the probe gathers read: query rows "
-            "of the padded batches x nprobe x bucket_cap",
+            help="padded bucket slots the probes scan: query rows of the "
+            "padded batches x nprobe x bucket_cap",
         ).inc(probes * cap)
         self.counter(
             "ivf_probe_live_rows_total",
-            help="live corpus rows among the gathered slots: the sum of "
+            help="live corpus rows among the probed slots: the sum of "
             "the probed partitions' rows, a (query row, probe) pair each",
         ).inc(live)
         for kind, n, what in (
@@ -434,6 +436,20 @@ class MetricsRegistry:
             "summed over batches: what any implementation reads once a "
             "batch",
         ).inc(distinct_live)
+        self.counter(
+            "ivf_probe_groups_total",
+            help="work items the bucket-major probe walked: a partition "
+            "and a group of at most ivf/search.py PROBE_GROUP query rows "
+            "that probe it, the partition fetched once however many; "
+            "probes over (groups x PROBE_GROUP) is the groups' fill",
+        ).inc(walked)
+        self.counter(
+            "ivf_probe_batches_total",
+            help="clustered batches by the program that answered them: "
+            "over the touched partitions (bucket_major) or over the query "
+            "rows, each gathering its partitions (row_major)",
+            labels={"path": "bucket_major" if walked else "row_major"},
+        ).inc(1)
 
     def _count_columns(self, name, paths, counts, help) -> None:
         import numpy as np

@@ -703,3 +703,238 @@ def test_config_round_trips_through_npz(rng, tmp_path):
     path = save_ivf_index(idx, str(tmp_path / "cfg"))
     idx2 = load_ivf_index(path)
     assert dataclasses.asdict(idx2.cfg) == dataclasses.asdict(idx.cfg)
+
+
+# ---------------------------------------------------------------------------
+# the bucket-major probe (ISSUE 42): the same answers as the row-major tile
+# body, the same five counts, and no probe dropped whatever the skew
+
+_WALK_P = 16  # lists of the parity index (d = 128: the bucket is a block)
+
+
+@pytest.fixture(scope="module")
+def walk_index():
+    """One small clustered index at d = 128 with what a served store
+    holds: a list emptied after the build, slots tombstoned (id -1) in two
+    others. ``exclude_self`` is a query-side knob, so both values of it run
+    against the one store."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(42)
+    X = _clustered(rng, m=4096, d=128, centers=24)
+    idx = build_ivf_index(X, KNNConfig(k=K, partitions=_WALK_P, nprobe=4))
+    ids = np.asarray(idx.bucket_ids).copy()
+    sizes = (ids >= 0).sum(axis=1)
+    ids[int(np.argsort(sizes)[_WALK_P // 2])] = -1  # an empty list
+    for p in np.argsort(sizes)[-2:]:  # dead slots in the two largest
+        ids[p, ::3] = -1
+    idx.bucket_ids = jnp.asarray(ids)
+    return idx, X
+
+
+def _walk_queries(idx, X, rows: int, skewed: bool):
+    """``rows`` centred query rows of the corpus with their ids, the last
+    eighth padding (zero rows, id -1: what the engine pads a batch with);
+    ``skewed``: every row a small step from ONE corpus row and none
+    padding, so all of them probe the same lists."""
+    rng = np.random.default_rng(rows)
+    pick = rng.choice(len(X), size=rows, replace=False)
+    q = X[pick] - idx.mu
+    if skewed:
+        q = q[:1] + 1e-3 * rng.standard_normal(q.shape).astype(np.float32)
+    q_ids = pick.astype(np.int32)
+    if not skewed:
+        pad = max(1, rows // 8)
+        q[-pad:], q_ids[-pad:] = 0.0, -1
+    return q.astype(np.float32), q_ids
+
+
+def _both_tiles(idx, q, q_ids, nprobe: int, exclude_self: bool):
+    import jax
+
+    from mpi_knn_tpu.ivf import search
+
+    cfg = idx.cfg.replace(nprobe=nprobe, exclude_self=exclude_self)
+    store = (idx.centroids, idx.centroid_sqs, idx.buckets, idx.bucket_ids,
+             idx.bucket_sqs)
+    row = jax.jit(lambda *a: search.ivf_query_tile(*a, None, cfg, nprobe))
+    walk = jax.jit(lambda *a: search.bucket_major_tile(*a, cfg, nprobe))
+    out = []
+    for fn in (row, walk):
+        d, i, counts = fn(q, q_ids, *store)
+        out.append((np.asarray(d), np.asarray(i), np.asarray(
+            search.probe_counts(len(q), nprobe, idx.bucket_cap,
+                                *(c[None] for c in counts)))))
+    _, probe = search.score_centroids(
+        q, idx.centroids, idx.centroid_sqs, nprobe)
+    return out[0], out[1], np.asarray(probe)
+
+
+def _assert_same_answers(row, walk, q, idx):
+    """Ids equal and distances within 1e-6 of the pair's scale (the unit
+    ``mask_tile``'s zero threshold is in: float32's rounding of ``q_sq - 2
+    q.x + x_sq`` follows the operands' size, and this backend's batched dot
+    sums in an order that follows the candidates' count — the
+    ``_batched_dot_bit_stable`` probe). Where two candidates sit closer
+    than that, the two programs may order them differently: a row's ids
+    may then differ in those slots alone."""
+    (d0, i0, _), (d1, i1, _) = row, walk
+    assert np.array_equal(np.isinf(d0), np.isinf(d1))
+    tol = 1e-6 * ((q * q).sum(axis=1) + float(np.max(idx.bucket_sqs)))
+    fin = np.isfinite(d0)
+    gap = np.abs(np.where(fin, d0, 0.0) - np.where(fin, d1, 0.0))
+    assert np.all(gap <= tol[:, None])
+    assert np.array_equal(np.where(fin, i0, -1) < 0,
+                          np.where(fin, i1, -1) < 0)
+    for r, s in np.argwhere((i0 != i1) & fin):
+        near = np.abs(d0[r] - d0[r, s]) <= 2 * tol[r]
+        last = np.flatnonzero(fin[r])[-1]
+        # a swap inside a near-tie, or a near-tie at the k-th place
+        assert (set(i0[r][near]) == set(i1[r][near])
+                or abs(d0[r, last] - d0[r, s]) <= 2 * tol[r]), (r, s)
+    assert np.mean(i0 == i1) > 0.99
+
+
+@pytest.mark.parametrize("exclude_self", [False, True])
+@pytest.mark.parametrize("nprobe", [1, 4, _WALK_P])
+@pytest.mark.parametrize("rows,skewed", [
+    (8, False), (64, False), (256, False), (256, True)])
+def test_bucket_major_matches_row_major(walk_index, rows, skewed, nprobe,
+                                        exclude_self):
+    from mpi_knn_tpu.ivf import search
+
+    idx, X = walk_index
+    q, q_ids = _walk_queries(idx, X, rows, skewed)
+    row, walk, probe = _both_tiles(idx, q, q_ids, nprobe, exclude_self)
+    _assert_same_answers(row, walk, q, idx)
+    # the five counts keep their meaning; the sixth is the walk's own
+    assert row[2][:5].tolist() == walk[2][:5].tolist()
+    assert row[2][5] == 0
+    per_list = np.bincount(probe.reshape(-1), minlength=_WALK_P)
+    groups = -(-per_list // search.PROBE_GROUP)
+    assert walk[2][5] == groups.sum()
+    assert groups.sum() <= search.bucket_major_items(rows, nprobe, _WALK_P)
+    if skewed:
+        # every row probes the same lists: each is rows / 8 work items, no
+        # probe truncated (an empty or dead slot answers +inf, not less)
+        assert len(np.unique(probe)) == nprobe
+        assert walk[2][5] == nprobe * (rows // search.PROBE_GROUP)
+    if nprobe == _WALK_P and not skewed and not exclude_self:
+        # every list probed: the serial backend's exact scan of the live
+        # rows (the zero mask hides a query's own row in both)
+        from mpi_knn_tpu import all_knn
+
+        live = np.asarray(idx.bucket_ids)
+        live = np.sort(live[live >= 0])
+        want = all_knn(X[live], queries=q + idx.mu,
+                       config=KNNConfig(k=K, backend="serial"))
+        wi, wd = live[np.asarray(want.ids)], np.asarray(want.dists)
+        hits = [len(set(a.tolist()) & set(b.tolist())) / K
+                for a, b in zip(wi, walk[1])]
+        assert np.mean(hits) >= 0.999, np.mean(hits)
+        np.testing.assert_allclose(walk[0], wd, rtol=1e-5, atol=2e-3)
+
+
+def test_bucket_major_engages_by_shapes_alone():
+    from mpi_knn_tpu.ivf.search import (
+        PROBE_GROUP,
+        bucket_major_engages,
+        bucket_major_items,
+        ivf_query_shapes,
+    )
+
+    cell = (1024, 16, 4096, 4728, 128)  # serve-bigann10m-ivf-bulk
+    assert bucket_major_engages(*cell)
+    assert bucket_major_engages(8, 1, 16, 672, 128)
+    for dtype in ("int8", "int4", "bfloat16"):
+        assert not bucket_major_engages(*cell, dtype=dtype)
+    assert not bucket_major_engages(*cell, precision_policy="mixed")
+    assert not bucket_major_engages(1024, 16, 4096, 4728, 100)  # off lanes
+    assert not bucket_major_engages(1024, 16, 4096, 4730, 128)  # no block
+    assert not bucket_major_engages(1024, 16, 64, 1 << 17, 128)  # VMEM
+    assert ivf_query_shapes(  # k past the kernel's 128 lanes: row-major
+        KNNConfig(k=200, partitions=4096, nprobe=16, query_tile=1024),
+        16, 4728, 128, 1024) == (16, 1024)
+    # W: a list probed by n rows is ceil(n / G) items, whatever the skew
+    assert bucket_major_items(1024, 16, 4096) == 4096 + 16384 // PROBE_GROUP
+    assert bucket_major_items(8, 1, 4096) == 8 + 1
+    # the cell's batch is ONE query tile; the row-major tile stays 16 rows
+    cfg = KNNConfig(k=K, partitions=4096, nprobe=16, query_tile=1024)
+    assert ivf_query_shapes(cfg, 16, 4728, 128, 1024) == (1024, 1024)
+    assert ivf_query_shapes(
+        cfg.replace(precision_policy="mixed"), 16, 4728, 128, 1024
+    ) == (16, 1024)
+    with pytest.raises(ValueError, match="max_tile_elems"):
+        ivf_query_shapes(cfg.replace(max_tile_elems=1 << 12), 16, 4728,
+                         128, 1024)
+
+
+@pytest.mark.parametrize("path,policy", [
+    ("bucket_major", "exact"), ("row_major", "mixed")])
+def test_served_batch_says_which_probe_answered(walk_index, path, policy,
+                                                monkeypatch):
+    from mpi_knn_tpu.obs import metrics as obs_metrics
+    from mpi_knn_tpu.ivf.search import PROBE_GROUP, bucket_major_items
+    from mpi_knn_tpu.serve import ServeSession
+
+    idx, X = walk_index
+    reg = obs_metrics.MetricsRegistry()
+    monkeypatch.setattr(obs_metrics, "_default_registry", reg)
+    sess = ServeSession(idx, config=idx.cfg.replace(
+        precision_policy=policy, query_bucket=64))
+    (out,) = list(sess.stream([X[:64]]))
+    probed = np.asarray(out.ivf_probe)
+    assert probed.shape == (6,) and probed[0] == 64 * 4
+    text = reg.to_prometheus().splitlines()
+    assert f'ivf_probe_batches_total{{path="{path}"}} 1.0' in text
+    assert not any(ln.startswith("ivf_probe_batches_total{")
+                   and path not in ln for ln in text)
+    assert f"ivf_probe_groups_total {float(probed[5])}" in text
+    if path == "row_major":
+        assert probed[5] == 0
+    else:
+        assert probed[3] <= probed[5] <= bucket_major_items(64, 4, _WALK_P)
+        assert probed[5] * PROBE_GROUP >= probed[0]
+
+
+@pytest.mark.parametrize("crowded", [False, True])
+def test_bucket_walk_selection_against_a_sort(crowded):
+    """The walk's kernel alone (interpreted) against a stable sort of the
+    masked distances: two lists of 520 slots (five vreg groups, the last
+    of 8 slots), dead slots, a tie, a list with fewer than k live slots.
+    ``crowded``: a query's five nearest rows sit in ONE lane (slots 5,
+    133, 261, 389, 517), descending by slot: what a selection that keeps
+    a few entries a lane would lose."""
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk
+
+    rng = np.random.default_rng(5)
+    lists, cap, d, group, k = 2, 520, 128, 8, 10
+    x = rng.integers(-40, 40, size=(lists, cap, d)).astype(np.float32)
+    q = rng.integers(-40, 40, size=(3, group, d)).astype(np.float32)
+    if crowded:
+        for n, slot in enumerate((517, 389, 261, 133, 5)):
+            x[0, slot] = q[0, 0] + (n + 1)  # nearer than anything random
+    x[0, 40] = x[0, 41]  # a tie: the earlier slot first
+    ids = np.arange(lists * cap, dtype=np.int32).reshape(lists, cap)
+    ids[0, ::7] = -1
+    ids[1, 6:] = -1  # six live slots: fewer than k
+    sqs = (x * x).sum(-1)
+    items = np.array([0, 0, 1, 1], np.int32)  # the fourth: past `walked`
+    rows = np.concatenate([q, q[:1]])
+    got = np.asarray(bucket_walk(
+        jnp.asarray(items), jnp.int32(3), jnp.asarray(rows), None,
+        jnp.asarray(x), jnp.asarray(ids), jnp.asarray(sqs), k=k,
+        exclude_zero=True, zero_eps=0.0))[:3, :, :k]
+    for w in range(3):
+        p = items[w]
+        dist = np.maximum(
+            (rows[w] ** 2).sum(-1)[:, None] - 2.0 * rows[w] @ x[p].T
+            + sqs[p][None, :], 0.0)
+        dist[:, ids[p] < 0] = np.inf
+        want = np.argsort(dist, axis=1, kind="stable")[:, :k]
+        finite = np.take_along_axis(dist, want, axis=1) < np.inf
+        assert finite.sum() == (group * k if p == 0 else group * 6)
+        assert np.array_equal(got[w][finite], want[finite]), w
+        assert np.all(got[w][~finite] == -1)  # no slot named twice

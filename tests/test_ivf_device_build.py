@@ -223,8 +223,9 @@ def test_probe_counts_against_a_hand_count(nprobe):
         partitions=4, nprobe=nprobe, kmeans_sample=None))
     q = whole_rows(8, m=50, d=16, centres=4)
     res = query_knn(q, idx)
-    probes, cap, live, distinct, distinct_live = np.asarray(
+    probes, cap, live, distinct, distinct_live, walked = np.asarray(
         res.ivf_probe).tolist()
+    assert walked == 0  # d = 16: the row-major program, no work items
     # by hand: the padded batch is one 64-row bucket; a padding row is a
     # row of zeros in the centred frame, and probes like any other
     qc = np.zeros((64, 16), np.float64)
@@ -241,14 +242,17 @@ def test_probe_counts_against_a_hand_count(nprobe):
 
 def test_probe_counters_arithmetic():
     reg = obs_metrics.MetricsRegistry()
-    reg.count_ivf_probe(np.array([128, 288, 33152, 4, 1024], np.int32))
-    reg.count_ivf_probe(np.array([64, 288, 100, 2, 500], np.int32))
+    reg.count_ivf_probe(np.array([128, 288, 33152, 4, 1024, 24], np.int32))
+    reg.count_ivf_probe(np.array([64, 288, 100, 2, 500, 0], np.int32))
     text = reg.to_prometheus()
     for line in ("ivf_probe_slots_total 55296.0",
                  "ivf_probe_live_rows_total 33252.0",
                  'ivf_probe_partitions_total{kind="probes"} 192.0',
                  'ivf_probe_partitions_total{kind="distinct"} 6.0',
-                 "ivf_probe_distinct_live_rows_total 1524.0"):
+                 "ivf_probe_distinct_live_rows_total 1524.0",
+                 "ivf_probe_groups_total 24.0",
+                 'ivf_probe_batches_total{path="bucket_major"} 1.0',
+                 'ivf_probe_batches_total{path="row_major"} 1.0'):
         assert line in text.splitlines(), (line, text)
 
 
@@ -263,7 +267,7 @@ def test_served_batches_count_what_they_probed():
         outs = list(sess.stream([X[:64], X[64:128]]))
     assert [o.rows for o in outs] == [64, 64]
     for o in outs:
-        assert np.asarray(o.ivf_probe).shape == (5,)
+        assert np.asarray(o.ivf_probe).shape == (6,)
     text = reg.to_prometheus().splitlines()
     assert f"ivf_probe_slots_total {float(128 * 2 * idx.bucket_cap)}" in text
     assert 'ivf_probe_partitions_total{kind="probes"} 256.0' in text

@@ -827,3 +827,73 @@ def test_stack_with_headroom_builds_on_the_v5e_without_a_second_copy(
     mem = program.memory_analysis()
     assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
     assert mem.output_size_in_bytes == 1224 * 8192 * 100 * 4  # no lane pad
+
+
+# ---------------------------------------------------------------------------
+# the clustered batch program at the cell's size, compiled for the v5e
+# (ISSUE 42): the walk fetches buckets where they rest, nothing copies the
+# store
+
+
+@pytest.mark.parametrize("exclude_self", [False, True])
+def test_bucket_major_batch_program_compiles_for_the_v5e(
+        v5e_devices, monkeypatch, exclude_self):
+    """``serve-bigann10m-ivf-bulk`` at its size: one 1024-row batch over
+    4096 lists of 4728 x 128 float32 slots (9.9e9 B), ``nprobe`` 16. The
+    batch is ONE query tile and its program holds the walk's kernel (a
+    Mosaic call under ``knn.ivf/gather``) over 6144 work items, which
+    returns slot numbers and no distance; the store enters it as it rests
+    — flat for the finish's gather by a bitcast, a bucket a grid step for
+    the kernel — so no instruction writes an array of the store's order
+    (the row-major program's ``jnp.take`` of whole buckets compiled to
+    three slices that copied all of it in every 16-row step: PERF.md
+    section 6, PR 41), and the temporaries stay under 0.5e9 B beside
+    10.07e9 B of arguments (the row-major program's: 4.09e9)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.ivf import search
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, lists, cap, dim, nprobe, k = 1024, 4096, 4728, 128, 16, 10
+    cfg = KNNConfig(k=k, partitions=lists, nprobe=nprobe, query_tile=q,
+                    exclude_self=exclude_self)
+    assert search.ivf_query_shapes(cfg, nprobe, cap, dim, q) == (q, q)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    with jax.enable_x64(False):
+        batch = jax.jit(
+            search.ivf_serve_chunk, static_argnames=("cfg", "nprobe"),
+            donate_argnums=(2, 3, 4),
+        ).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q, k), jnp.float32), arg((1, q, k), jnp.int32),
+            arg((search.PROBE_FIELDS,), jnp.int32),
+            arg((lists, dim), jnp.float32), arg((lists,), jnp.float32),
+            arg((lists, cap, dim), jnp.float32), arg((lists, cap), jnp.int32),
+            arg((lists, cap), jnp.float32), None, cfg=cfg, nprobe=nprobe,
+        ).compile()
+    hlo = batch.as_text()
+    items = search.bucket_major_items(q, nprobe, lists)
+    assert items == 6144
+    walk = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+            and f"s32[{items},{search.PROBE_GROUP},128]" in ln]
+    assert len(walk) == 1 and "knn.ivf/gather" in walk[0], walk
+    assert "mini-gather" not in hlo
+    assert " while(" not in hlo  # no step over query rows is left
+    width = {"f32": 4, "s32": 4, "u32": 4, "pred": 1, "bf16": 2}
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\S* ([\w-]+)\(", hlo):
+        kind, dims, op = m.groups()
+        size = width.get(kind, 4) * np.prod([int(n) for n in dims.split(",")])
+        assert size < 1e9 or op in ("parameter", "bitcast"), m.group(0)
+    mem = batch.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    for scope in ("knn.ivf/score", "knn.ivf/gather", "knn.rerank"):
+        assert scope in hlo
