@@ -510,7 +510,10 @@ def compute_phases(args, platform, out, record) -> None:
             backend, not bad and spread, t0,
             f"devices={nd} corpus_shards={sorted(d.id for d in shard_devices)}"
             f" rows_per_shard={sorted(shard_rows)} result_devices="
-            f"{result_devices} ids_equal_serial={equal:.5f}{bad}",
+            f"{result_devices} ids_equal_serial={equal:.5f}{bad}"
+            # by the distance dot's path, the devices' rows added up: on
+            # the chip a fourth column, the steps inside the fused scan
+            f" dist_steps={np.atleast_2d(got.dist_steps).sum(0).tolist()}",
         )
 
     # the fused collective-matmul rotation: on a TPU the kernel itself

@@ -92,6 +92,7 @@ from mpi_knn_tpu.backends.serial import (
     TileCounts,
     carried_depth,
     dist_steps,
+    fused_rule,
     tile_counts,
     merge_tiles_into_carry,
     onepass_rule,
@@ -204,10 +205,12 @@ def _ring_knn_local(
     onepass=None,  # whole rotations of the XLA float ring only: the corpus
     # side of the one-pass rule (backends.serial.masked_dist_tile), one
     # replicated bool scalar; adds a third output, this device's
-    # backends.serial.TileCounts: its tile steps by the branch they took
-    # and, where the rounds' scans carry the lane-bin lists, its
-    # (query tile, round) merges by what became of the carried selection,
-    # shape (1, 2) each
+    # backends.serial.TileCounts, a row each: its tile steps by the branch
+    # they took ((1, 2); (1, 4) where the one-pass branch is the kernel that
+    # walks the arriving stack, backends.serial.dist_steps) and, where the
+    # rounds' scans carry the lane-bin lists, its (query tile, round) merges
+    # by what became of the carried selection and, under a row bound, the
+    # chunks of its distance tiles, (1, 2) each
 ):
     """Per-device body under shard_map: rotate corpus blocks around the ring,
     merging each into the local top-k carry.
@@ -307,7 +310,11 @@ def _ring_knn_local(
         stack's layout — at 1 048 576 x 784 rows a chip two more blocks alive
         (13.2 GiB of temporaries, which does not fit) and a fifth of a
         second; as a stack the block is laid out once, ahead of the scan
-        (6.9 GiB; PERF.md §6, PR 28). The fused kernels take rows."""
+        (6.9 GiB; PERF.md §6, PR 28), and the kernel that walks a stack
+        (``ops/fused_scan.py``, the one-pass branch of a round's merge
+        where ``backends.serial.fused_rule`` engages) reads the arriving
+        block where it lands. The kernels of ``ring_fusion="fused"``
+        (``ops/pallas_ring.py``) take rows."""
         if x is None or fused:
             return x
         return x.reshape(b // c_tile, c_tile, *x.shape[1:])
@@ -324,6 +331,15 @@ def _ring_knn_local(
     # blocks are parts of the one corpus the fact speaks for
     q_one = None if onepass is None else (
         onepass & jax.vmap(bf16_exact)(q_tiles))
+    # the rounds' operands vary over the mesh under the XLA ring's checked
+    # ``shard_map`` (:func:`ring_shard_map`), as ``merge_tiles_into_carry``
+    # reads it
+    varying = bool(jax.typeof(queries).vma | jax.typeof(block).vma)
+    # whether the one-pass branch of a round's merge is the kernel that
+    # walks the arriving stack (the rule ``merge_tiles_into_carry`` asks of
+    # the same shapes): its steps count as ``fused`` and it counts chunks
+    kernel_walks = q_one is not None and not fused and bool(fused_rule(
+        cfg, q_tile, c_tile, dim, varying))
     block, block_ids, block_scale = map(
         tiled, (block, block_ids, block_scale))
     block_bwd, block_bwd_ids, block_bwd_scale = map(
@@ -467,7 +483,8 @@ def _ring_knn_local(
         # the schedule, and merges once a round
         merges = num_dev * q_one.size
         return *out, TileCounts(
-            dist_steps(q_one, num_dev * (b // c_tile)).reshape(1, 2),
+            dist_steps(q_one, num_dev * (b // c_tile),
+                       fused=kernel_walks).reshape(1, -1),
             None if rescanned is None else jnp.stack(
                 [merges - rescanned, rescanned]).reshape(1, 2),
             None if chunks is None else chunks.reshape(1, 2),
@@ -633,16 +650,17 @@ def _ring_knn_local(
     # permute's output is unused; XLA dead-code-eliminates it.
     # where the device's counts go out (``onepass``) and the rounds' scans
     # carry the lists, the rotation counts its re-scanned merges and, where
-    # the row bound rides them too, the chunks its *bins* inserted and
+    # the row bound rides them too (beside the scan's lists, or inside the
+    # kernel that walks the stack), the chunks its *bins* inserted and
     # skipped
     tally = ()
     if q_one is not None and not fused and carried_depth(
-            cfg, q_tile, c_tile, varying=True) is not None:
+            cfg, q_tile, c_tile, varying) is not None:
         tally = tuple(
             jax.lax.pcast(jnp.zeros(shape, jnp.int32),
                           tuple(vary_axes) or (axis,), to="varying")
-            for shape in ((), (2,))[:1 + lane_bin_bound_rides(
-                q_tile, c_tile, jnp.dtype(carry_d.dtype).itemsize)])
+            for shape in ((), (2,))[:1 + (kernel_walks or lane_bin_bound_rides(
+                q_tile, c_tile, jnp.dtype(carry_d.dtype).itemsize))])
     (_, _, _, carry_d, carry_i, *tally), _ = jax.lax.scan(
         step, (block, block_scale, block_ids, carry_d, carry_i, *tally),
         None, length=num_dev

@@ -171,8 +171,9 @@ def carried_depth(cfg: KNNConfig, q_rows: int, c_tile: int,
     program. The test ``smallest_k`` makes of a tile with 1-D ids, where
     the exact policy and method put every tile of the stack through it.
     ``varying``: the operands vary over a checked ``shard_map``'s axes (the
-    XLA ring), under which the kernels run on the TPU only (``ops/topk.py
-    _lane_bin_smallest_k``)."""
+    XLA ring), under which the kernels — *bins*, *finish* and the one that
+    walks a whole stack (:func:`fused_rule`) — run on the TPU only
+    (``ops/topk.py _lane_bin_smallest_k``)."""
     if (cfg.merge_schedule != "twolevel" or cfg.precision_policy != "exact"
             or cfg.topk_method != "exact"
             or (varying and jax.default_backend() != "tpu")):
@@ -189,11 +190,15 @@ def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
     (:func:`onepass_rule`) and the lists (:func:`carried_depth`), and the
     shapes pass ``ops/topk.py fused_scan_engages`` (a block height at
     which the bound rides and the kernel's VMEM fits; a stack that rests
-    in a form the kernel takes). Not under a checked ``shard_map``
-    (``varying``: the ring's rounds keep the scan)."""
-    if varying or not onepass_rule(cfg, q_rows):
+    in a form the kernel takes). ``varying`` (the operands vary over a
+    checked ``shard_map``'s axes: the XLA ring's rounds, whose arriving
+    block is the stack): the same answer on the TPU, where the kernel is
+    typed for the check (``ops/lane_bin.py _out``), and None elsewhere —
+    :func:`carried_depth`'s condition: jax's Pallas interpreter cannot run
+    under the check, so the CPU ring keeps the per-tile program it has."""
+    if not onepass_rule(cfg, q_rows):
         return None
-    depth = carried_depth(cfg, q_rows, c_tile)
+    depth = carried_depth(cfg, q_rows, c_tile, varying)
     if depth is None:
         return None
     return fused_scan_engages(

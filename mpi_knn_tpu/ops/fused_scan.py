@@ -3,7 +3,12 @@ the row bound's test and the lane-bin insertion for every corpus tile of a
 (T, c_tile, d) stack in ONE Pallas call (``ops/topk.py`` has the selection's
 mechanism, ``ops/lane_bin.py`` the kernels this one shares its test and its
 network with, ``backends/serial.py _merge_carried`` the scan it replaces
-where ``ops/topk.py fused_scan_engages``).
+where ``ops/topk.py fused_scan_engages``). Every caller of that merge's
+one-pass branch reaches it by the one rule (``backends/serial.py
+fused_rule``): the serial scan and the serving batch programs over a
+resident stack and, on the TPU, the XLA ring's rounds over the block that
+has just arrived, inside the ring's checked ``shard_map`` (the outputs
+carry the operands' varying axes, ``ops/lane_bin.py _out``).
 
 What leaves the tile step with it. The XLA scan's step is a slice of the
 tile, a dot fusion that writes the (q, c_tile) distance tile, the *bins*

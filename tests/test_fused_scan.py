@@ -203,8 +203,13 @@ def test_which_programs_take_the_fused_scan(change, engages, why):
                        **change})
     block = cfg.query_tile if engages else None
     assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128) == block, why
-    # under a checked shard_map (the ring's rounds) the scan stays
+    # under a checked shard_map (the ring's rounds) the same answer on
+    # the TPU; off it the interpreter cannot run there and the scan stays
     assert serial.fused_rule(cfg, cfg.query_tile, 8192, 128, True) is None
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        assert serial.fused_rule(
+            cfg, cfg.query_tile, 8192, 128, True) == block, why
 
 
 @pytest.mark.parametrize("d,q_tile", [
@@ -219,7 +224,8 @@ def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
     ``path="fused"``, counted a (query tile, corpus tile) whatever the
     blocks); fractional queries take the multi-pass scan of the same
     program; the ring's rounds over the same rows (a checked
-    ``shard_map`` on four CPU devices: the scan stays) answer the same."""
+    ``shard_map`` on four CPU devices: the per-tile program stays; with
+    the kernel, ``tests/test_ring_deployment.py``) answer the same."""
     rng = np.random.default_rng(3)
     X = rng.integers(0, 200, (4096, d)).astype(np.float32)
     kw = dict(k=K, query_tile=q_tile, corpus_tile=1024)

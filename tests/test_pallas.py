@@ -428,8 +428,9 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     assert not stack_copies, stack_copies
 
 
-def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
-                                       dim: int):
+def _assert_one_kernel_walks_the_stack(
+        hlo: str, q: int, tiles: int, dim: int,
+        under: str = r"jit\(knn_chunk_update\)/while/body/closed_call"):
     """A program whose one-pass branch is the fused scan (ISSUE 37), as
     the v5e compiler leaves it: the one-pass rule's conditional sits ONCE,
     outside any loop over the corpus tiles (the only loop above it is the
@@ -441,7 +442,8 @@ def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
     rests as (tiles, dim, 8192), rows minor, and the kernel takes that
     shape: the ``swapaxes`` in front of it is a BITCAST of the branch's
     parameter, the bytes at rest under another name — no ``copy`` and no
-    ``transpose`` of the 4.59 GiB stack anywhere in the branch."""
+    ``transpose`` of the 4.59 GiB stack anywhere in the branch. ``under``:
+    the scopes above the conditional (a ring's round holds it too)."""
     import re
 
     blocks = re.split(r"\n(?=(?:ENTRY )?%\S+ \([^\n]*\) -> [^\n]* \{\n)",
@@ -451,8 +453,7 @@ def _assert_one_kernel_walks_the_stack(hlo: str, q: int, tiles: int,
     assert len(calls) == 1, calls
     op_name = re.search(r'op_name="([^"]*)"', calls[0]).group(1)
     assert re.fullmatch(
-        r"jit\(knn_chunk_update\)/while/body/closed_call/cond/"
-        r"branch_1_fun/knn\.fused/pallas_call", op_name), op_name
+        under + r"/cond/branch_1_fun/knn\.fused/pallas_call", op_name), op_name
     branch, = [b for b in blocks if calls[0] in b]
     assert f"f32[{tiles},8192,{dim}]" in branch.splitlines()[0]
     operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
@@ -695,13 +696,36 @@ def test_cosine_batch_program_compiles_for_the_v5e(v5e_devices, monkeypatch):
     assert norms.memory_analysis().temp_size_in_bytes <= 0.1 * 2**30
 
 
+def _stack_moves(hlo: str, tiles: int, dim: int) -> list:
+    """The instructions of a compiled program that copy or transpose a
+    whole (tiles, 8192, dim) float32 stack, in either of its shapes."""
+    import re
+
+    return [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+            if re.search(rf"= f32\[{tiles},(8192,{dim}|{dim},8192)\]\S* "
+                         r"(copy|transpose)\(", ln)]
+
+
+@pytest.mark.parametrize("program", [
+    "ring-overlap", "ring", "dp-by-ring", "carry-in"])
 def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(
-        v5e_devices, monkeypatch):
-    """``ring4-mnist8m`` at its size, 128 tiles a chip: both branches inside
-    the ring's ``shard_map`` (``check_vma`` on), the fact replicated, and the
-    temporaries what they were (12.99 GiB of 15.75 at the peak on the chip:
-    one bf16 copy of the travelling block, 1.53 GiB, would still fit; the
-    program asks for none)."""
+        v5e_devices, monkeypatch, program):
+    """``ring4-mnist8m`` at its size, 128 tiles a chip (``ring-overlap``:
+    the cell's program): both branches inside the ring's ``shard_map``
+    (``check_vma`` on), the fact replicated. Since ISSUE 43 the one-pass
+    branch of a round's merge is the kernel that walks the ARRIVING stack
+    (``knn.fused``, typed under the check): no *bins* call, no slice and no
+    (4096, 8192) distance tile in it, the travelling block read where it
+    lands — it rests rows minor at d = 784, so the kernel's view of it is
+    a bitcast and a round copies the block no more often than the program
+    without the rule does; the wire is the float32 tile stack it was, the
+    multi-pass branch the scan it was, and the temporaries what they were
+    (12.99 GiB of 15.75 at the peak on the chip: one bf16 copy of the
+    travelling block, 1.53 GiB, would still fit; the program asks for
+    none). The other forms of the ring family must still compile at that
+    size: the blocking schedule (the kernel too), the dp x ring mesh (two
+    varying axes), and the serving program that threads ``carry_in`` and
+    is handed no fact (the scan stays)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -710,39 +734,82 @@ def test_ring_program_under_the_one_pass_rule_compiles_for_four_v5e(
     from mpi_knn_tpu.backends import ring
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    backend = "ring" if program == "ring" else "ring-overlap"
     cfg = KNNConfig(
-        k=10, backend="ring-overlap", num_devices=4, matmul_precision="high",
+        k=10, backend=backend, num_devices=4, matmul_precision="high",
         query_tile=4096, corpus_tile=8192, merge_schedule="twolevel")
-    mesh = Mesh(np.asarray(v5e_devices), (cfg.mesh_axis,))
-    by_rows = NamedSharding(mesh, P(cfg.mesh_axis))
     tiles, dim = 128, 784
-    m, nq = 4 * tiles * cfg.corpus_tile, 4 * cfg.query_tile
+    if program == "dp-by-ring":
+        mesh = Mesh(np.asarray(v5e_devices).reshape(2, 2),
+                    ("dp", cfg.mesh_axis))
+        q_axis, ring_n = "dp", 2
+    else:
+        mesh = Mesh(np.asarray(v5e_devices), (cfg.mesh_axis,))
+        q_axis, ring_n = None, 4
+    by_rows = NamedSharding(mesh, P(cfg.mesh_axis))
+    q_rows = NamedSharding(mesh, ring._query_spec(q_axis, cfg.mesh_axis))
+    m, nq = ring_n * tiles * cfg.corpus_tile, 4 * cfg.query_tile
 
     def arg(shape, dtype, sharding=by_rows):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    args = (arg((nq, dim), jnp.float32), arg((nq,), jnp.int32),
-            arg((m, dim), jnp.float32), arg((m,), jnp.int32),
-            cfg, True, mesh, cfg.mesh_axis, cfg.query_tile, cfg.corpus_tile)
+    queries = (arg((nq, dim), jnp.float32, q_rows),
+               arg((nq,), jnp.int32, q_rows))
+    corpus = (arg((m, dim), jnp.float32), arg((m,), jnp.int32))
+    static = (cfg, backend == "ring-overlap", mesh, cfg.mesh_axis,
+              cfg.query_tile, cfg.corpus_tile)
+    fact = arg((), jnp.bool_, NamedSharding(mesh, P()))
+    wire = f"f32[{tiles},{cfg.corpus_tile},{dim}]"
+
+    def permutes(hlo):
+        return [ln for ln in hlo.splitlines()
+                if " collective-permute-start(" in ln
+                and f"[{tiles},{cfg.corpus_tile},{dim}]" in ln]
+
     with jax.enable_x64(False):
-        plain = ring._ring_knn_sharded.lower(*args).compile()
+        if program == "carry-in":
+            served = jax.jit(
+                ring.ring_serve_sharded,
+                static_argnames=("cfg", "overlap", "mesh", "axis", "q_tile",
+                                 "c_tile", "q_axis"),
+            ).lower(
+                *queries, arg((nq, 10), jnp.float32, q_rows),
+                arg((nq, 10), jnp.int32, q_rows), *corpus, None, *static,
+            ).compile().as_text()
+            assert "knn.fused" not in served
+            assert permutes(served) and all(
+                wire in ln for ln in permutes(served))
+            return
         ruled = ring._ring_knn_sharded.lower(
-            *args, onepass=arg((), jnp.bool_, NamedSharding(mesh, P()))
-        ).compile()
-    assert _dist_dots(ruled.as_text()) == {
-        "onepass": (("bf16", "bf16"), None),
-        "multipass": (("f32", "f32"), "high"),
-    }
+            *queries, *corpus, *static, q_axis=q_axis, onepass=fact).compile()
+        if program == "ring-overlap":
+            plain = ring._ring_knn_sharded.lower(
+                *queries, *corpus, *static).compile()
+    hlo = ruled.as_text()
+    # the one-pass dot is inside the kernel; the other branch's is the
+    # configured one
+    assert _dist_dots(hlo) == {"multipass": (("f32", "f32"), "high")}
+    _assert_one_kernel_walks_the_stack(
+        hlo, cfg.query_tile, tiles, dim,
+        under=r"jit\(_ring_knn_sharded\)/shard_map/while/body/closed_call/"
+        r"knn\.ring/round/while/body/closed_call")
+    # the wire is what it was: float32 tile stacks travel
+    assert permutes(hlo) and all(wire in ln for ln in permutes(hlo))
+    assert ruled.memory_analysis().temp_size_in_bytes / 2**30 <= 7.0
+    if program != "ring-overlap":
+        return
+    # the cell's program beside the one without the rule (the parent's
+    # rounds): a round moves the block no more often, and the scan that
+    # stays is the scan it was
+    assert len(_stack_moves(hlo, tiles, dim)) <= len(
+        _stack_moves(plain.as_text(), tiles, dim)), _stack_moves(
+        hlo, tiles, dim)
     temps = [c.memory_analysis().temp_size_in_bytes / 2**30
              for c in (plain, ruled)]
-    assert temps[1] <= temps[0] + 0.1 and temps[1] <= 7.0, temps
-    for program in (plain, ruled):
+    assert temps[1] <= temps[0] + 0.1, temps
+    for compiled in (plain, ruled):
         _assert_the_lists_ride_the_scan(
-            program.as_text(), cfg.query_tile, tiles)
-    # the wire is what it was: float32 tile stacks travel
-    permutes = [ln for ln in ruled.as_text().splitlines()
-                if " collective-permute-start(" in ln and "[128,8192,784]" in ln]
-    assert permutes and all("f32[128,8192,784]" in ln for ln in permutes)
+            compiled.as_text(), cfg.query_tile, tiles)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from benchmark import reference, reference_sharded
 from mpi_knn_tpu import KNNConfig, all_knn
-from mpi_knn_tpu.backends import ring
+from mpi_knn_tpu.backends import ring, serial
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.topk import lane_bin_depth
@@ -175,3 +175,184 @@ def test_ring_span_and_counters_move_by_the_layouts_numbers(
     assert call["attrs"] == {"devices": DEVICES, "rounds": rounds,
                              "rows_per_block": M // DEVICES,
                              "wire_bytes": wire}
+
+
+# ---------------------------------------------------------------------------
+# the ring's rounds with the kernel that walks the arriving stack (ISSUE 43).
+# On the chip the XLA ring's checked ``shard_map`` holds it (``tests/
+# test_pallas.py -k ring_program`` compiles that for four v5e); jax's Pallas
+# interpreter cannot run under the check, so here the rotation runs under an
+# UNCHECKED ``shard_map`` on the CPU mesh of four, where the rule sees
+# operands that vary over nothing and engages as it does unsharded
+
+RING_Q = RING_C = 1024  # the heights from which the rule's program engages
+RING_TILES, RING_DIM = 2, 16  # corpus tiles a device
+
+
+def _rotation(kernel: bool, Q, q_ids, X, ids, carry=()):
+    """A whole rotation of ``ring._ring_knn_local`` over four CPU devices
+    under a true corpus fact, its one-pass branch the kernel (interpreted)
+    or, the rule answering None, the scan of tile steps it replaces:
+    ``(dists, ids, TileCounts)`` as numpy."""
+    cfg = KNNConfig(k=K, backend="ring-overlap", num_devices=DEVICES,
+                    query_tile=RING_Q, corpus_tile=RING_C,
+                    matmul_precision="high")
+    mesh = make_ring_mesh(DEVICES, axis_name=cfg.mesh_axis)
+    by_rows = P(cfg.mesh_axis)
+
+    def local(q, qi, c, ci, one, *carry):
+        return ring._ring_knn_local(
+            q, qi, c, ci, cfg=cfg, overlap=True, axis=cfg.mesh_axis,
+            q_tile=RING_Q, c_tile=RING_C, vary_axes=(cfg.mesh_axis,),
+            onepass=one, carry_in=carry or None)
+
+    with pytest.MonkeyPatch.context() as patch:
+        if not kernel:
+            for module in (serial, ring):
+                patch.setattr(module, "fused_rule", lambda *a, **k: None)
+        out = jax.jit(jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(by_rows,) * 4 + (P(),) + (by_rows,) * len(carry),
+            out_specs=(by_rows,) * 3, check_vma=False,
+        ))(Q, q_ids, X, ids, jnp.asarray(True), *carry)
+    return jax.tree.map(np.asarray, out)
+
+
+def _ring_case(what: str):
+    """Whole-number rows over four devices (two tiles each) and queries
+    that are corpus rows under their own ids — every device's queries have
+    their own rows, and their duplicates, in OTHER devices' blocks, so the
+    self mask works on ids that arrive with a later round — with what the
+    case names laid on top."""
+    rng = np.random.default_rng(43)
+    m = DEVICES * RING_TILES * RING_C
+    X = rng.integers(-6, 7, (m, RING_DIM)).astype(np.float32)
+    X[RING_C:2 * RING_C] += 16.0  # a tile far off: the bound skips chunks
+    ids = np.arange(m, dtype=np.int32)
+    q_ids = rng.permutation(m)[:DEVICES * RING_Q].astype(np.int32)
+    Q = X[q_ids].copy()
+    X[q_ids[:64] ^ 1] = Q[:64]  # duplicates under a neighbour's id
+    carry = ()
+    if what == "padded":  # the last device's last tile is mostly padding
+        X[-700:], ids[-700:] = 0.0, -1
+    elif what == "nan":  # corpus rows that are at no distance from any query
+        X[777], X[5000, 2] = np.nan, np.nan
+    elif what == "carry_in":  # a carry that another corpus's rotation left
+        carry = (np.sort(rng.integers(60, 260, (len(Q), K)), axis=1).astype(
+            np.float32), rng.integers(10**6, 2 * 10**6, (len(Q), K)).astype(
+            np.int32))
+    elif what == "fractional":  # the second device's tile is no bf16 number
+        Q[RING_Q + 7, 0] += 2.0 ** -10
+    return Q, q_ids, X, ids, carry
+
+
+@pytest.mark.parametrize("what", [
+    "self_across_shards", "padded", "nan", "carry_in", "fractional"])
+def test_a_round_with_the_kernel_equals_the_scan_it_replaces(what):
+    """The rotation's answer, its re-scanned merges and its chunk count
+    with the kernel in the rounds' one-pass branch are the scan's, bit for
+    bit (ids and tie order too); the tile steps move from the one-pass
+    column to the fused one, a device's row each."""
+    case = _ring_case(what)
+    scan_d, scan_i, scan_n = _rotation(False, *case)
+    d, i, n = _rotation(True, *case)
+    np.testing.assert_array_equal(d, scan_d)
+    np.testing.assert_array_equal(i, scan_i)
+    np.testing.assert_array_equal(n.select_tiles, scan_n.select_tiles)
+    steps = DEVICES * RING_TILES  # a device's query tile meets every tile
+    multi = [steps * (what == "fractional" and dev == 1)
+             for dev in range(DEVICES)]
+    assert scan_n.dist_steps.tolist() == [[steps - s, s] for s in multi]
+    assert n.dist_steps.tolist() == [[0, s, 0, steps - s] for s in multi]
+    # rounds x tiles x chunks a device, by what became of them
+    assert n.bins_chunks.shape == (DEVICES, 2)
+    assert (n.bins_chunks.sum(axis=1) == steps * (RING_Q // 16)).all()
+    np.testing.assert_array_equal(n.bins_chunks, scan_n.bins_chunks)
+    assert (n.bins_chunks[:, 1] > 0).all()  # the far tile's chunks
+    assert (i[np.isfinite(d)] >= 0).all() and (d[:, :-1] <= d[:, 1:])[
+        np.isfinite(d[:, 1:])].all()
+    own = i == np.asarray(case[1])[:, None]
+    assert not own.any() and (d[np.isfinite(d)] > 0).all()
+    if what == "nan":  # (a query tile that holds one is no bf16 number)
+        assert not np.isin(i, (777, 5000)).any()
+    if what == "carry_in":  # some of the other corpus's rows survive
+        assert (i >= 10**6).any() and (i < 10**6).any()
+
+
+@pytest.fixture
+def unchecked_ring(monkeypatch):
+    """``all_knn``'s ring under an unchecked ``shard_map``: what the rule
+    sees on the chip under the checked one, where the CPU can run it."""
+    monkeypatch.setattr(ring, "ring_shard_map", lambda body, cfg, mesh,
+                        in_specs, out_specs: jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False))
+    ring._ring_knn_sharded.clear_cache()
+    yield
+    ring._ring_knn_sharded.clear_cache()
+
+
+@pytest.mark.parametrize("backend", ["ring-overlap", "ring"])
+def test_a_ring_call_counts_its_fused_steps_a_device(
+        corpus, backend, unchecked_ring):
+    """``KNNResult.dist_steps`` of a ring call is one row a device:
+    ``[0, multi-pass, 0, fused]`` where the rule engaged, ``[one-pass,
+    multi-pass]`` where it did not (a bf16 wire keeps the program it
+    had); ``bins_chunks`` adds up to rounds x tiles x chunks a device;
+    the registry takes both forms."""
+    x = np.concatenate([corpus[:, :16]] * 2)  # 8192 rows: two tiles a device
+    kw = dict(k=K, backend=backend, num_devices=DEVICES, query_tile=RING_Q,
+              corpus_tile=RING_C, matmul_precision="high")
+    steps = DEVICES * RING_TILES
+    got = all_knn(jnp.asarray(x), queries=x[:DEVICES * RING_Q], **kw)
+    assert np.asarray(got.dist_steps).tolist() == [[0, 0, 0, steps]] * DEVICES
+    chunks = np.asarray(got.bins_chunks)
+    assert chunks.shape == (DEVICES, 2)
+    assert (chunks.sum(axis=1) == steps * (RING_Q // 16)).all()
+    assert np.asarray(got.select_tiles).sum(axis=1).tolist() == [
+        DEVICES] * DEVICES  # a merge a round
+    # a fractional query tile on one device: its steps are multi-pass
+    frac = x[:DEVICES * RING_Q].copy()
+    frac[2 * RING_Q + 1, 0] += 0.5
+    mixed = all_knn(jnp.asarray(x), queries=frac, **kw)
+    assert np.asarray(mixed.dist_steps).tolist() == [
+        [0, steps * (dev == 2), 0, steps * (dev != 2)]
+        for dev in range(DEVICES)]
+    serial_res = all_knn(x, queries=frac, **{**kw, "backend": "serial"})
+    np.testing.assert_array_equal(
+        np.asarray(mixed.dists), np.asarray(serial_res.dists))
+    # no fact travels with a narrowed wire: the two-column form, one row
+    narrow = all_knn(jnp.asarray(x), queries=x[:DEVICES * RING_Q],
+                     ring_transfer_dtype="bfloat16", **kw)
+    assert np.asarray(narrow.dist_steps).tolist() == [0, DEVICES * steps]
+    registry = obs_metrics.MetricsRegistry()
+    for res in (got, mixed, narrow):
+        registry.count_dist_steps(res.dist_steps)
+    registry.count_bins_chunks(got.bins_chunks)
+    counted = {p: registry.counter(
+        obs_metrics.DIST_STEPS, labels={"path": p}).value
+        for p in obs_metrics.DIST_PATHS}
+    assert counted == {"onepass": 0, "multipass": steps + DEVICES * steps,
+                       "cosine": 0, "fused": (2 * DEVICES - 1) * steps}
+    assert sum(registry.counter(
+        obs_metrics.BINS_CHUNKS, labels={"path": p}).value
+        for p in obs_metrics.BINS_PATHS) == chunks.sum()
+
+
+def test_a_checked_ring_on_the_cpu_keeps_the_two_column_form(corpus):
+    """Off the TPU the checked ``shard_map`` keeps the per-tile program
+    (the interpreter cannot run under the check): one row a device,
+    ``[one-pass, multi-pass]``, and no chunk count."""
+    x = np.concatenate([corpus[:, :16]] * 2)
+    got = all_knn(jnp.asarray(x), queries=x[:DEVICES * RING_Q], k=K,
+                  backend="ring-overlap", num_devices=DEVICES,
+                  query_tile=RING_Q, corpus_tile=RING_C,
+                  matmul_precision="high")
+    steps = DEVICES * RING_TILES
+    assert np.asarray(got.dist_steps).tolist() == [[steps, 0]] * DEVICES
+    assert got.bins_chunks is None
+    registry = obs_metrics.MetricsRegistry()
+    registry.count_dist_steps(got.dist_steps)
+    assert registry.counter(obs_metrics.DIST_STEPS,
+                            labels={"path": "onepass"}).value == (
+        DEVICES * steps)
