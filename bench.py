@@ -51,12 +51,11 @@ vs_baseline: north_star_seconds / value, scaled by the fraction of the
 8-chip target this host provides (1 chip => target is 8 s), so >1.0 beats
 the north star at equal silicon.
 
-Environment knobs: BENCH_M (default 60000), BENCH_BACKEND (serial|pallas),
+Environment knobs: BENCH_M (default 60000), BENCH_BACKEND (serial|ring|ring-overlap),
 BENCH_REPS, BENCH_QT/BENCH_CT (tiles), BENCH_TOPK (exact|approx),
 BENCH_PRECISION (default|high|highest), BENCH_PRECISION_POLICY
 (exact|mixed — mixed is the compress-and-rerank pipeline and owns both dot
-precisions, so it overrides BENCH_PRECISION),
-BENCH_PALLAS_VARIANT (tiles|sweep), BENCH_IVF_PARTITIONS /
+precisions, so it overrides BENCH_PRECISION), BENCH_IVF_PARTITIONS /
 BENCH_IVF_NPROBE (clustered-index path: k-means partitions trained
 outside the timed region, per-query probed scan timed; the series name
 carries the knobs and the gate is the configured recall_target — the
@@ -64,14 +63,7 @@ clustered rung's own acceptance bar), BENCH_IVF_SHARDS (the SHARDED
 clustered path, mpi_knn_tpu.ivf.sharded: the bucket store distributed
 over that many ring-mesh devices with the routed all-to-all candidate
 exchange; requires BENCH_IVF_PARTITIONS, series name carries the shard
-count), BENCH_RING_FUSION (xla|fused — 'fused' runs the ring round as
-the fused collective-matmul Pallas kernel, ops/pallas_ring.py: distance
-sweep + carry merge in one kernel with the next corpus block streamed
-over ICI during compute; ring-overlap backend only, and only on a
-platform whose Pallas path exists — TPU hardware or CPU interpret mode —
-any other combination is a loud exit-2 refusal because the knob would be
-silently ignored or the kernel cannot lower; the series name carries the
-knob), BENCH_WATCHDOG_S (per-series wall
+count), BENCH_WATCHDOG_S (per-series wall
 bound, 0 disables), BENCH_BEAT_TIMEOUT_S (per-series beat-starvation
 bound, 0 disables), BENCH_SERIES / BENCH_DOCTOR (supervisor, above),
 BENCH_PLATFORM (forces the platform through utils.platform.force_platform;
@@ -120,14 +112,7 @@ def metric_name(env=None) -> str:
             # over the mesh) and must never masquerade as the
             # single-device clustered series
             ivf += f"s{env['BENCH_IVF_SHARDS']}"
-    fusion = ""
-    if env.get("BENCH_RING_FUSION", "xla") != "xla":
-        # the fused rotation is a different PROGRAM (in-kernel streaming
-        # collective-matmul) proven bit-identical to the xla form — the
-        # whole point of the series is the A/B, so the name must carry
-        # the axis or the two would bank under one metric
-        fusion = f"_{env['BENCH_RING_FUSION']}"
-    return f"mnist{m // 1000}k_allknn_k{k}{ivf}{fusion}_seconds"
+    return f"mnist{m // 1000}k_allknn_k{k}{ivf}_seconds"
 
 
 def oracle_topk(X: np.ndarray, sample: np.ndarray, k: int) -> np.ndarray:
@@ -222,54 +207,6 @@ def main() -> int:
                 "exists on ring/ring-overlap backends — an A/B sweep here "
                 "would record identical single-device runs mislabeled as "
                 "schedule variants"
-            }),
-            file=sys.stderr,
-        )
-        return 2
-    # BENCH_RING_FUSION=fused: the ring round runs as the fused
-    # collective-matmul Pallas kernel (distance sweep + carry merge in one
-    # kernel, next block streamed over ICI during compute). Two loud
-    # refusals, same doctrine as the schedule knob above: on a non-ring
-    # backend the knob names a rotation that never runs (a fused-labeled
-    # serial run would poison the A/B), and on a platform with no Pallas
-    # path (neither TPU hardware nor CPU interpret mode) the kernel cannot
-    # lower — the run would crash deep in tracing instead of explaining
-    # itself.
-    ring_fusion = os.environ.get("BENCH_RING_FUSION", "xla")
-    if ring_fusion not in ("xla", "fused"):
-        print(
-            json.dumps({
-                "error": f"BENCH_RING_FUSION={ring_fusion!r} is not one "
-                "of xla|fused"
-            }),
-            file=sys.stderr,
-        )
-        return 2
-    if ring_fusion == "fused" and backend != "ring-overlap":
-        print(
-            json.dumps({
-                "error": f"BENCH_RING_FUSION=fused conflicts with "
-                f"BENCH_BACKEND={backend}: the fused collective-matmul "
-                "rotation exists only on the ring-overlap backend (on "
-                "'ring' the blocking schedule contradicts in-kernel "
-                "streaming by construction; on single-device backends "
-                "there is no rotation at all) — the series would be a "
-                "mislabeled measurement"
-            }),
-            file=sys.stderr,
-        )
-        return 2
-    if ring_fusion == "fused" and jax.default_backend() not in (
-        "tpu", "cpu"
-    ):
-        print(
-            json.dumps({
-                "error": "BENCH_RING_FUSION=fused needs a platform whose "
-                "Pallas path exists — TPU hardware (in-kernel async "
-                "remote DMAs) or CPU (interpret-mode parity form) — got "
-                f"{jax.default_backend()!r}; the fused kernel cannot "
-                "lower here and the run would die in tracing instead of "
-                "refusing"
             }),
             file=sys.stderr,
         )
@@ -429,7 +366,6 @@ def main() -> int:
         topk_method=os.environ.get("BENCH_TOPK", "exact"),
         merge_schedule=os.environ.get("BENCH_SCHEDULE", "twolevel"),
         topk_block=int(os.environ.get("BENCH_BLOCK", "128")),
-        pallas_variant=os.environ.get("BENCH_PALLAS_VARIANT", "tiles"),
         recall_target=float(os.environ.get("BENCH_RT", "0.999")),
         dtype=os.environ.get("BENCH_DTYPE", "float32"),
         precision_policy=precision_policy,
@@ -437,7 +373,6 @@ def main() -> int:
         # only matters for BENCH_BACKEND=ring/ring-overlap)
         ring_transfer_dtype=os.environ.get("BENCH_RING_XFER") or None,
         ring_schedule=ring_schedule,
-        ring_fusion=ring_fusion,
         # uncentered mode exists because raw MNIST pixels are small integers
         # — exactly representable even in bf16 — where *centered* values lose
         # mantissa bits. The relative zero-exclusion threshold is calibrated
@@ -457,14 +392,9 @@ def main() -> int:
         # BENCH_PRECISION_POLICY=mixed takes the knob over entirely and the
         # ivf search path fixes its own dot precisions (both conflicting
         # combinations were rejected above).
-        # The Pallas kernels have no three-pass dot (Mosaic lowers
-        # DEFAULT and HIGHEST only), so their series default to HIGHEST.
         matmul_precision=None if (ivf_partitions or
                                   precision_policy == "mixed")
-        else os.environ.get("BENCH_PRECISION") or (
-            "highest" if backend == "pallas" or ring_fusion == "fused"
-            else "high"
-        ),
+        else os.environ.get("BENCH_PRECISION") or "high",
     )
 
     if ivf_partitions:
@@ -590,7 +520,6 @@ def main() -> int:
                 "nprobe": (index.nprobe if ivf_partitions else None),
                 "ivf_shards": cfg.ivf_shards,
                 "recall_gate": gate,
-                "ring_fusion": cfg.ring_fusion,
                 "merge_schedule": cfg.merge_schedule,
                 "tiles": [cfg.query_tile, cfg.corpus_tile],
             }

@@ -14,17 +14,13 @@ Phases, each printing one line with its wall time:
 - ``allknn``   ``api.all_knn`` on the serial backend, first call (compile)
                and one warm call timed apart; recall@10 against the
                float64 oracle on a 256-row sample.
-- ``pallas``   both Pallas variants, compiled by Mosaic (the lowering must
-               hold a ``tpu_custom_call``), against the serial backend on
-               the same rows.
 - ``cosine``   the prepared cosine path (ISSUE 32) on fractional rows of
                lengths 0.5-2x their own: a resident index's answers equal
                the one-shot call's bit for bit, and the direct form's in
                float64 on the host.
 - ``ring``     with more than one chip: both ring schedules over
                min(4, chips) devices against the serial result, the
-               shardings spanning that many devices, and the fused
-               rotation (``ring_fusion="fused"``, in-kernel remote DMA).
+               shardings spanning that many devices.
                With one chip it says ``not run`` and the summary carries
                ``"ring_devices": 0``.
 - ``serve``    ``python -m mpi_knn_tpu serve`` over the same corpus: waits
@@ -104,7 +100,7 @@ def compare_neighbors(ids, dists, ref_ids, ref_dists) -> tuple[float, str]:
 
 
 # ---------------------------------------------------------------------------
-# the compute child: device, allknn, pallas, ring — one process, one chip
+# the compute child: device, allknn, ring — one process, one chip
 
 
 def per_call_passes(X, nq: int, cfg):
@@ -443,44 +439,6 @@ def compute_phases(args, platform, out, record) -> None:
         f"dists_close_to_float64_plain_planted={close}",
     )
 
-
-    def lowers_to_mosaic(fn, *arrays) -> bool:
-        """Whether the program ``fn`` traces to holds a compiled Pallas
-        kernel (off a TPU the kernels are interpreted and leave none)."""
-        return "tpu_custom_call" in jax.jit(fn).lower(*arrays).as_text()
-
-    # -- pallas -----------------------------------------------------------
-    # Mosaic has no three-pass dot, so the kernels run at HIGHEST and are
-    # held against the serial backend at HIGHEST on the same rows (against
-    # the allknn phase's HIGH result, rounding alone reorders ~1 % of slots)
-    nq = 256 if args.tiny else 4096
-    rows = np.arange(nq, dtype=np.int32)
-    ref = all_knn(Xd, queries=Xd[:nq], query_ids=rows,
-                  config=cfg.replace(matmul_precision="highest"))
-    r_ids, r_dists = np.asarray(ref.ids), np.asarray(ref.dists)
-    for variant in ("tiles", "sweep"):
-        t0 = time.perf_counter()
-        pcfg = cfg.replace(backend="pallas", pallas_variant=variant,
-                           matmul_precision="highest")
-
-        def run(corpus, queries):
-            return all_knn(corpus, queries=queries, query_ids=rows,
-                           config=pcfg)
-
-        compiled = lowers_to_mosaic(run, Xd, Xd[:nq])
-        got = run(Xd, Xd[:nq])
-        jax.block_until_ready((got.dists, got.ids))
-        equal, bad = compare_neighbors(got.ids, got.dists, r_ids, r_dists)
-        record(
-            f"pallas-{variant}",
-            not bad and compiled == (platform == "tpu"),
-            t0,
-            f"queries={nq} corpus={m} "
-            f"{'compiled by Mosaic' if compiled else 'INTERPRETED'} "
-            f"ids_equal_serial={equal:.5f}{bad}",
-            compiled=compiled,
-        )
-
     # -- ring -------------------------------------------------------------
     if len(devices) == 1:
         print(f"ring: not run (1 device) platform={platform}", flush=True)
@@ -515,34 +473,6 @@ def compute_phases(args, platform, out, record) -> None:
             # the chip a fourth column, the steps inside the fused scan
             f" dist_steps={np.atleast_2d(got.dist_steps).sum(0).tolist()}",
         )
-
-    # the fused collective-matmul rotation: on a TPU the kernel itself
-    # issues the remote DMAs. Its tiles are VMEM blocks, so they are named
-    # here (at bench.py's 4096 x 8192 Mosaic does not finish compiling),
-    # and like the other kernels it runs at HIGHEST and is held against
-    # the serial backend at HIGHEST on the rows that reference covers
-    t0 = time.perf_counter()
-    fcfg = cfg.replace(backend="ring-overlap", num_devices=nd,
-                       ring_fusion="fused", matmul_precision="highest",
-                       query_tile=64, corpus_tile=128)
-
-    def run_fused(corpus):
-        return all_knn(corpus, config=fcfg)
-
-    compiled = lowers_to_mosaic(run_fused, Xd)
-    got = run_fused(X)
-    jax.block_until_ready((got.dists, got.ids))
-    equal, bad = compare_neighbors(got.ids[:nq], got.dists[:nq],
-                                   r_ids, r_dists)
-    record(
-        "ring-overlap-fused",
-        not bad and compiled == (platform == "tpu"),
-        t0,
-        f"devices={nd} tiles=64x128 "
-        f"{'in-kernel remote DMA' if compiled else 'INTERPRETED'} "
-        f"ids_equal_serial={equal:.5f}{bad}",
-        compiled=compiled,
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -68,13 +68,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "accounting) or int8/int4 (the clustered store's "
                    "at-rest levels — R2's wire-priced gather bound); "
                    "repeatable")
-    p.add_argument("--fusion", action="append", choices=["xla", "fused"],
-                   help="restrict to ring-fusion form(s): xla (the "
-                   "per-round XLA body) or fused (the collective-matmul "
-                   "Pallas kernel cells — R1's side-band overlap "
-                   "contract, R4's kernel-owned-rotation accounting, "
-                   "R7's double-buffer residency, R8's fused-DMA wire "
-                   "pricing); repeatable")
     p.add_argument("--host", action="store_true",
                    help="run the HOST concurrency lint instead (lock "
                    "discipline / lock ordering / thread confinement / "
@@ -191,7 +184,6 @@ def main(argv=None) -> int:
         and (not args.policy or t.policy in args.policy)
         and (not args.schedule or t.schedule in args.schedule)
         and (not args.quant or t.quant in args.quant)
-        and (not args.fusion or t.fusion in args.fusion)
         and (not args.mutate or t.mutate in args.mutate)
         and (t.serve or not args.serve)
         and (t.frontend or not args.frontend)

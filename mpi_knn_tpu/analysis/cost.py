@@ -519,37 +519,14 @@ def cost_ledger_drift(
 # cost is the only import direction, mirroring R7)
 
 def cost_entry(module: HloModule, facts: dict,
-               profile_name: str = DEFAULT_PROFILE, *,
-               fused_dma: bool = False,
-               fused_dma_wire_bytes: int | None = None):
+               profile_name: str = DEFAULT_PROFILE):
     """``(ledger_entry, problems)`` for one after-opt module under its
     declared cost facts. The entry is what the cost ledger commits; the
     problems are R8 findings (exactness breaches, unpriced collectives,
-    unpriceable multiplicities).
-
-    ``fused_dma`` cells (the fused collective-matmul rotation's
-    kernel-owned-transport form) move their wire bytes with async remote
-    copies issued INSIDE the Pallas kernel — no collective-family opcode
-    exists for the census to price, so the lowerer must declare the
-    per-device rotation bytes as ``fused_dma_wire_bytes`` (the same
-    ``ring_wire_bytes_per_batch`` closed form the serving engine stamps
-    into its wire gauge). A fused_dma cell WITHOUT the declaration is
-    the unpriced-fused-DMA finding: the cell would otherwise certify a
-    zero-ICI roofline for a program that saturates the interconnect."""
+    unpriceable multiplicities)."""
     flops, largest, problems = hlo_mxu_flops(module)
     ici_bytes, ici_problems = collective_census(module)
     problems = list(problems) + ici_problems
-    if fused_dma:
-        if not fused_dma_wire_bytes:
-            problems.append(
-                "fused rotation owns its transport in-kernel (async "
-                "remote DMAs) but declares no wire-byte side-band "
-                "(meta['fused_dma_wire_bytes']) — the collective census "
-                "sees zero collectives, so the cell's ICI bytes would "
-                "silently vanish from the roofline (unpriced fused DMA)"
-            )
-        else:
-            ici_bytes += fused_dma_wire_bytes
     hbm_bytes = hbm_traffic_bytes(module)
     analytical = analytical_mxu_flops(facts)
     if flops != analytical:
@@ -589,8 +566,6 @@ def cost_entry(module: HloModule, facts: dict,
         "roofline": roofline(flops, hbm_bytes, ici_bytes, queries,
                              profile),
     }
-    if fused_dma:
-        entry["fused_dma_bytes"] = int(fused_dma_wire_bytes or 0)
     return entry, problems
 
 
@@ -614,11 +589,7 @@ def r8_check(ctx, stage: str, module: HloModule, finding_cls) -> list:
                 {},
             )
         ]
-    entry, problems = cost_entry(
-        module, facts,
-        fused_dma=bool(ctx.meta.get("fused_dma")),
-        fused_dma_wire_bytes=ctx.meta.get("fused_dma_wire_bytes"),
-    )
+    entry, problems = cost_entry(module, facts)
     # stash for the engine's ledger collection (meta is a per-run copy)
     ctx.meta["r8_analysis"] = entry
     return [

@@ -69,7 +69,6 @@ class TargetResult:
             "dtype": self.target.dtype,
             "policy": self.target.policy,
             "schedule": self.target.schedule,
-            "fusion": self.target.fusion,
             "quant": self.target.quant,
             "serve": self.target.serve,
             "ladder": self.target.ladder,

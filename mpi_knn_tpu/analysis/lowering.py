@@ -96,14 +96,6 @@ class LintTarget:
     # scatter/dynamic-update-slice forms exempted as buffer-forwarding
     # plumbing (meta strict_exempt_ops)
     mutate: str = ""
-    # fusion="fused" (ring backends only): the per-round compute is the
-    # fused collective-matmul Pallas kernel (ops/pallas_ring.py) instead
-    # of the XLA tile pipeline. On the CPU lint platform the kernel runs
-    # in interpret mode with driver-owned ppermute transport, so R1/R4's
-    # permute accounting still sees the rotation; the kernel-owned-DMA
-    # form (TPU, uni/exact) is covered by the meta side-band contract
-    # (fused_dma / fused_dma_wire_bytes) that R1/R4/R8 branch on
-    fusion: str = "xla"
 
     @property
     def label(self) -> str:
@@ -112,8 +104,6 @@ class LintTarget:
             base = f"{base}/{self.policy}"
         if self.schedule != "uni":
             base = f"{base}/{self.schedule}"
-        if self.fusion != "xla":
-            base = f"{base}/{self.fusion}"
         if self.quant:
             base = f"{base}/{self.quant}"
         if self.serve:
@@ -264,28 +254,6 @@ def default_targets() -> list[LintTarget]:
         LintTarget("ring-overlap", "l2", "float32", "mixed", serve=True,
                    quant="xfer-int8"),
     ] + [
-        # the FUSED collective-matmul rotation (ops/pallas_ring.py): the
-        # per-round compute is the Pallas merge kernel; on this CPU lint
-        # platform it lowers in interpret mode with the driver's
-        # ppermutes still moving the wire bytes, so R1's overlap
-        # sequencing, R4's permute count/direction/payload accounting,
-        # R3's dequant contract (int8 wire dequantizes inside the
-        # kernel) and R8's FLOP-exactness contract all re-certify on the
-        # fused form with no special-casing; R7 additionally prices the
-        # declared double-buffer landing residency (extra_elems). The
-        # kernel-owned-DMA TPU form (zero permutes, wire bytes declared
-        # via the fused_dma side-band) is certified by the injected-meta
-        # tests — it cannot lower off-TPU.
-        LintTarget("ring-overlap", "l2", "float32", fusion="fused"),
-        LintTarget("ring-overlap", "l2", "float32", "exact", "bidir",
-                   fusion="fused"),
-        LintTarget("ring-overlap", "l2", "float32", "mixed",
-                   fusion="fused"),
-        LintTarget("ring-overlap", "l2", "float32", "mixed", "bidir",
-                   fusion="fused"),
-        LintTarget("ring-overlap", "l2", "float32", "mixed",
-                   quant="xfer-int8", fusion="fused"),
-    ] + [
         # clustered at-rest int8/int4: R2-strict keeps the element budget
         # AND adds the wire-priced gather bound (the probe gather must
         # move code lanes, 4–8× under the f32 bytes — dequantize AFTER
@@ -327,7 +295,6 @@ def _base_cfg(target: LintTarget) -> KNNConfig:
         ring_transfer_dtype=(
             "int8" if target.quant == "xfer-int8" else None
         ),
-        ring_fusion=target.fusion,
     )
 
 
@@ -347,14 +314,12 @@ def _mixed_meta(target: LintTarget, q_tile: int, c_tile: int):
 
 
 def _dense_cost(target: LintTarget, q: int, c: int, c_tile: int, *,
-                queries: int, sites: int = 1, trips: int = 1,
-                rblocks: int | None = None) -> dict:
+                queries: int, sites: int = 1, trips: int = 1) -> dict:
     """R8's declared FLOP facts for a dense cell (analysis/cost.py's
     ``dense`` scheme): the padded per-execution distance-dot extents,
     the schedule's site/trip structure, and — on mixed cells — the
     rerank overfetch width and how many rerank blocks run per site-trip
-    (per corpus tile for the serial two-pass, one global block for the
-    fused pallas path). ``queries`` is the REAL (unpadded) queries
+    (one per corpus tile). ``queries`` is the REAL (unpadded) queries
     answered per execution — the roofline's q/s numerator."""
     from mpi_knn_tpu.ops.rerank import overfetch_width
 
@@ -362,9 +327,7 @@ def _dense_cost(target: LintTarget, q: int, c: int, c_tile: int, *,
              "sites": sites, "trips": trips, "queries": int(queries)}
     if target.policy == "mixed":
         facts["w"] = overfetch_width(LINT_K, c_tile)
-        facts["rblocks"] = (
-            rblocks if rblocks is not None else int(c) // int(c_tile)
-        )
+        facts["rblocks"] = int(c) // int(c_tile)
     return facts
 
 
@@ -563,98 +526,6 @@ def _lower_ring(target: LintTarget):
         meta["extra_elems"] = max(
             meta.get("extra_elems", 0), 2 * block_elems
         )
-    if target.fusion == "fused":
-        from mpi_knn_tpu.backends.ring import ring_wire_bytes_per_batch
-
-        block_elems = (c_pad // ring_n) * LINT_D
-        # Which side owns the wire this cell? Same predicate as the
-        # runtime dispatch in backends/ring.py: only the TPU round form
-        # (uni + exact) moves the block with in-kernel async remote DMAs;
-        # everywhere else (including this CPU lint platform) the driver's
-        # ppermutes carry identical bytes and the permute census above
-        # stays in force unchanged.
-        fused_dma = (
-            target.schedule == "uni"
-            and target.policy == "exact"
-            and cfg.ring_fused_rotation == "round"
-            and jax.default_backend() == "tpu"
-        )
-        meta["fused_dma"] = fused_dma
-        # R7: the fused kernel double-buffers the incoming block — the
-        # landing buffer for round r+1 is resident while round r's block
-        # is on the MXU, so two wire blocks (+ their id rows, folded into
-        # the slack) live per device beyond the xla form's single
-        # traveler. Declared, not ridden on the input floor (the bidir
-        # allowance's rationale).
-        meta["extra_elems"] = max(
-            meta.get("extra_elems", 0), 2 * block_elems
-        )
-        if fused_dma:
-            # kernel-owned transport: the lowered program contains ZERO
-            # collective-permutes — the rotation is async remote copies
-            # issued inside the kernel, invisible to both R4's permute
-            # census and R8's collective census. The side-band declares
-            # the per-device wire bytes of one full rotation so R8
-            # prices the fused cell instead of silently reporting zero
-            # ICI; a fused_dma cell WITHOUT this declaration is the
-            # unpriced-fused-DMA finding.
-            meta["expected_permutes"] = 0
-            meta["fused_dma_wire_bytes"] = (
-                ring_wire_bytes_per_batch(cfg, c_pad, LINT_D, ring_n)
-                // ring_n
-            )
-    return lowered, cfg, meta
-
-
-def _lower_pallas(target: LintTarget):
-    from mpi_knn_tpu.backends.pallas_backend import _pallas_all_knn
-    from mpi_knn_tpu.parallel.partition import pad_to_multiple
-
-    if target.dtype != "float32":
-        # mirrors all_knn_pallas's own ValueError — a registered
-        # restriction, recorded as skipped rather than silently shrunk
-        raise UnsupportedTarget(
-            "pallas backend computes in float32 only (its own wrapper "
-            "rejects other dtypes)"
-        )
-    cfg = _base_cfg(target)
-    m = _lint_m(target)
-    # same tile policy as all_knn_pallas (MXU/VPU alignment + caps); cosine
-    # rides the L2 kernels on pre-normalized rows, so the lowered program
-    # is the L2 kernel either way and the metric needs no special casing
-    q_tile = min(max(8, pad_to_multiple(cfg.query_tile, 8)), 512,
-                 pad_to_multiple(LINT_NQ, 8))
-    c_tile = min(max(128, pad_to_multiple(cfg.corpus_tile, 128)), 2048,
-                 pad_to_multiple(m, 128))
-    c_pad = pad_to_multiple(m, c_tile)
-    q_pad = pad_to_multiple(LINT_NQ, q_tile)
-    lowered = _pallas_all_knn.lower(
-        jnp.zeros((q_pad, LINT_D), jnp.float32),
-        jnp.zeros((c_pad, LINT_D), jnp.float32),
-        cfg,
-        q_tile,
-        c_tile,
-        m,
-        False,
-        cfg.pallas_variant,
-    )
-    # fused-path rerank width is the global overfetch: the per-tile
-    # survivor lists are preselected back down to 4k on compressed keys
-    # before the gather (backends/pallas_backend.py)
-    meta = {"q_tile": q_tile, "c_tile": c_tile, "acc_bytes": 4,
-            # the fused path reranks ONE global overfetch block, not one
-            # per corpus tile (the tile survivors are preselected first)
-            "cost": _dense_cost(target, q_pad, c_pad, c_tile,
-                                queries=LINT_NQ, rblocks=1),
-            **_mixed_meta(target, q_tile, c_tile)}
-    if target.policy == "mixed":
-        # R7 allowance, named and measured (ISSUE 15): the fused mixed
-        # path stacks every tile's survivor keys/ids before preselecting
-        # back to the global 4k (backends/pallas_backend.py), holding a
-        # q_pad×m-order working set live across the tile loop — a real
-        # cost of the tiles-variant restack, declared here instead of
-        # hiding under R2's input floor
-        meta["peak_extra_elems"] = q_pad * m
     return lowered, cfg, meta
 
 
@@ -986,11 +857,6 @@ def _lower_serve(target: LintTarget):
         }
         return lowered, cfg, meta
 
-    if target.backend == "pallas" and target.dtype != "float32":
-        raise UnsupportedTarget(
-            "pallas backend computes in float32 only (its own wrapper "
-            "rejects other dtypes)"
-        )
     if target.backend in RING_BACKENDS and len(jax.devices()) < 2:
         raise UnsupportedTarget(
             "ring serve targets need a multi-device mesh (force the CPU "
@@ -1048,9 +914,6 @@ def _lower_serve(target: LintTarget):
             trips=(ring_n // 2 + 1 if target.schedule == "bidir"
                    else ring_n),
         )
-    elif target.backend == "pallas":
-        cost = _dense_cost(target, q_pad, index.corpus_padded.shape[0],
-                           index.c_tile, queries=bucket, rblocks=1)
     else:
         cost = _dense_cost(target, q_pad,
                            int(index.tiles.shape[0]) * index.c_tile,
@@ -1191,7 +1054,6 @@ _LOWERERS = {
     "serial": _lower_serial,
     "ring": _lower_ring,
     "ring-overlap": _lower_ring,
-    "pallas": _lower_pallas,
     "ivf": _lower_ivf,
     "ivf-sharded": _lower_ivf_sharded,
 }

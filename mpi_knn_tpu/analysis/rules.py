@@ -257,28 +257,6 @@ class R1Overlap(Rule):
         rep = permute_report_from_module(module)
         if ctx.target.backend == "ring-overlap":
             why = overlap_violations(rep)
-            if ctx.meta.get("fused_dma"):
-                # kernel-owned transport (the fused rotation's TPU round
-                # form): zero collective-permutes is the CORRECT shape —
-                # the rotation is async remote copies issued inside the
-                # Pallas kernel, sequenced by its send/recv semaphores,
-                # so the vacuous-claim guard does not apply. What takes
-                # its place is the side-band contract: the cell must
-                # declare the in-kernel wire bytes (R8 prices them) or
-                # the overlap claim has no statically checkable residue
-                # at all; the runtime dual — the measured
-                # overlap_fraction from obs.attribution — is the
-                # acceptance instrument for the sequencing itself.
-                why = [w for w in why if "vacuous" not in w]
-                if not ctx.meta.get("fused_dma_wire_bytes"):
-                    why.append(
-                        "fused rotation owns its transport in-kernel "
-                        "but declares no wire-byte side-band "
-                        "(meta['fused_dma_wire_bytes']) — with zero "
-                        "permutes in the module the overlap claim "
-                        "leaves no statically checkable residue "
-                        "(unpriced fused DMA)"
-                    )
         elif stage == "before_opt":
             why = blocking_violations(rep)
         else:  # blocking after-opt: barrier already expanded, no claim
@@ -1378,44 +1356,18 @@ class R4Collectives(Rule):
                             )
                         )
         elif stage == "after_opt" and not permutes:
-            if ctx.meta.get("fused_dma"):
-                # the fused rotation's kernel-owned-transport form: zero
-                # permutes is the intended lowering (the block moves via
-                # async remote copies inside the Pallas kernel). The
-                # corpus still rotates — but through a channel this
-                # census cannot see, so the accounting hand-off is the
-                # declared side-band: absent, the cell gets the same
-                # rotation-vanished finding the xla form would (an
-                # undeclared fused DMA is indistinguishable from a
-                # DCE'd rotation to static analysis).
-                if not ctx.meta.get("fused_dma_wire_bytes"):
-                    out.append(
-                        Finding(
-                            self.name,
-                            t.label,
-                            stage,
-                            "fused ring program has zero collective-"
-                            "permutes and NO declared in-kernel DMA "
-                            "wire bytes (meta['fused_dma_wire_bytes']) "
-                            "— an undeclared fused rotation is "
-                            "indistinguishable from one that was "
-                            "optimized away (unpriced fused DMA)",
-                            {},
-                        )
-                    )
-            else:
-                out.append(
-                    Finding(
-                        self.name,
-                        t.label,
-                        stage,
-                        "ring program compiled to zero collective-permutes "
-                        "— the rotation was optimized away (results can "
-                        "only be correct if the corpus never moved, i.e. "
-                        "they are not)",
-                        {},
-                    )
+            out.append(
+                Finding(
+                    self.name,
+                    t.label,
+                    stage,
+                    "ring program compiled to zero collective-permutes "
+                    "— the rotation was optimized away (results can "
+                    "only be correct if the corpus never moved, i.e. "
+                    "they are not)",
+                    {},
                 )
+            )
         return out
 
 

@@ -91,11 +91,6 @@ def _form_and_maker(backend: str, cfg: KNNConfig, m, dim, nq, mesh):
         from mpi_knn_tpu.backends.ring import prepare_ring, ring_form
 
         return ring_form(cfg, m, dim, nq, mesh, backend), prepare_ring
-    if backend == "pallas":
-        raise ValueError(
-            "backend='pallas' has no prepared form (its kernels take the "
-            "corpus as rows and derive ids from grid position); call "
-            "all_knn with the array, or prepare for 'serial'")
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -187,8 +182,8 @@ def all_knn(
     the same answers. The entry holds the prepared arrays on the device
     (about the corpus's own size) until the caller drops the array,
     another corpus or another form is prepared; a host array (mutable), a
-    traced corpus, an all-pairs call and ``backend="pallas"`` are prepared,
-    used and dropped, as ever. No option turns this on or off: counter
+    traced corpus and an all-pairs call are prepared, used and dropped, as
+    ever. No option turns this on or off: counter
     ``knn_corpus_prepare_total{result="hit"|"miss"|"bypass"}`` and span
     ``knn:api.prepare`` say what a call did.
     """
@@ -261,18 +256,6 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
             q_ids = np.full(q_arr.shape[0], -1, dtype=np.int32)
 
     backend = resolve_backend(cfg, mesh)
-    if backend == "pallas" and not isinstance(corpus, PreparedCorpus):
-        from mpi_knn_tpu.backends.pallas_backend import all_knn_pallas
-
-        if cfg.center and cfg.metric == "l2":
-            from mpi_knn_tpu.ops.distance import center_for_l2
-
-            corpus, q_arr, _, _ = center_for_l2(
-                corpus, q_arr, all_pairs=queries is None)
-        _count_prepare("bypass")
-        d, i = all_knn_pallas(corpus, q_arr, q_ids, cfg)
-        return KNNResult(dists=d, ids=i)
-
     form, make = _form_and_maker(backend, cfg, m, dim, q_arr.shape[0], mesh)
     prepared = _corpus_side(corpus, cfg, form, make, sliced=queries is not None)
     d, i, counts = prepared.search(q_arr, q_ids, cfg)

@@ -97,11 +97,6 @@ from mpi_knn_tpu.backends.serial import (
     merge_tiles_into_carry,
     onepass_rule,
 )
-from mpi_knn_tpu.ops.pallas_ring import (
-    fused_block_merge,
-    fused_rotation_grid,
-    fused_round_dma,
-)
 from mpi_knn_tpu.parallel.mesh import make_ring_mesh
 from mpi_knn_tpu.parallel.partition import pad_rows_any, pad_to_multiple
 from mpi_knn_tpu.types import INVALID_ID
@@ -139,42 +134,6 @@ def blocking_undefined_on_mesh_error(mesh_axes) -> ValueError:
         "the requested compute-then-send sequencing would silently run as "
         "the overlap schedule. The 1-D ring is the only defined blocking "
         "A/B object — use backend='ring-overlap' with --dp, or drop --dp."
-    )
-
-
-def fused_blocking_undefined_error() -> ValueError:
-    """The one wording for the fused-rotation × blocking-schedule hard
-    error, shared by the ring drivers (same pattern as
-    :func:`blocking_undefined_on_mesh_error`): the fused form streams the
-    next block DURING the distance sweep by construction — on TPU the
-    kernel itself owns the DMA — so a 'blocking' fused run would either be
-    a contradiction (TPU) or a silent mislabel (interpret). Refuse."""
-    return ValueError(
-        "ring_fusion='fused' is undefined under the blocking schedule "
-        "(backend='ring' / overlap=False): the fused kernel streams the "
-        "next block over ICI while the current one is on the MXU — there "
-        "is no compute-then-send sequencing to certify. Use "
-        "backend='ring-overlap', or ring_fusion='xla' for the blocking "
-        "A/B baseline."
-    )
-
-
-def ring_shard_map(body, cfg: KNNConfig, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` for a ring body, shared with the resumable driver.
-
-    The varying-axes check stays on for the XLA ring. It is off for
-    ``ring_fusion="fused"``: jax evaluates an interpreted ``pallas_call``
-    with unvarying grid indices against device-varying blocks, which the
-    checker rejects inside jax's own interpreter (its error text says to
-    pass ``check_vma=False``). The fused bodies are the XLA bodies with the
-    per-round compute swapped for the kernel, so their specs make the same
-    replication claims the checked form already proves."""
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_vma=cfg.ring_fusion != "fused",
     )
 
 
@@ -240,26 +199,6 @@ def _ring_knn_local(
     num_dev = jax.lax.axis_size(axis)
     bidir = cfg.ring_schedule == "bidir"
     quantized = cfg.ring_transfer_dtype == "int8"
-    fused = cfg.ring_fusion == "fused"
-    if fused and not overlap:
-        raise fused_blocking_undefined_error()
-    # The fused form's transport escalation ladder: the fused Pallas kernel
-    # always owns the per-round COMPUTE (tile distances + carry merge, bit-
-    # identical to the XLA form by construction — ops/pallas_ring.py); who
-    # owns the TRANSPORT depends on where we run. On TPU with the uni/exact
-    # round form the kernel issues the remote DMAs itself
-    # (fused_round_dma) — the collective-matmul shape. Bidir and the mixed
-    # compress round keep transport at the driver's ppermutes until their
-    # DMA forms are banked on hardware; off-TPU (interpret mode) transport
-    # is ALWAYS the driver's ppermute moving the identical wire bytes,
-    # which is what makes the CPU parity matrix a real certificate.
-    fused_dma = (
-        fused
-        and not bidir
-        and cfg.precision_policy == "exact"
-        and cfg.ring_fused_rotation == "round"
-        and jax.default_backend() == "tpu"
-    )
     # send to the next rank, wrap at the end — the reference's ring direction
     # (rank -> rank+1, mpi-knn-parallel_blocking.c:131); bidir adds the
     # counter-rotating permute so both ICI link directions carry a block
@@ -304,7 +243,7 @@ def _ring_knn_local(
 
     def tiled(x):
         """(b, ...) -> (b / c_tile, c_tile, ...): a traveller as the stack
-        of tiles that ``compute`` walks. The XLA ring rotates the stack, not
+        of tiles that ``compute`` walks. The ring rotates the stack, not
         the rows: the v5e compiler keeps a (b, d) scan carry rows-minor and
         then pays, in EVERY round, a copy of the whole block into the tile
         stack's layout — at 1 048 576 x 784 rows a chip two more blocks alive
@@ -313,15 +252,14 @@ def _ring_knn_local(
         (6.9 GiB; PERF.md §6, PR 28), and the kernel that walks a stack
         (``ops/fused_scan.py``, the one-pass branch of a round's merge
         where ``backends.serial.fused_rule`` engages) reads the arriving
-        block where it lands. The kernels of ``ring_fusion="fused"``
-        (``ops/pallas_ring.py``) take rows."""
-        if x is None or fused:
+        block where it lands."""
+        if x is None:
             return x
         return x.reshape(b // c_tile, c_tile, *x.shape[1:])
 
     def rows(x):
         """``tiled``'s inverse, for the travellers a single round returns."""
-        if x is None or fused:
+        if x is None:
             return x
         return x.reshape(b, *x.shape[2:])
 
@@ -331,14 +269,13 @@ def _ring_knn_local(
     # blocks are parts of the one corpus the fact speaks for
     q_one = None if onepass is None else (
         onepass & jax.vmap(bf16_exact)(q_tiles))
-    # the rounds' operands vary over the mesh under the XLA ring's checked
-    # ``shard_map`` (:func:`ring_shard_map`), as ``merge_tiles_into_carry``
-    # reads it
+    # the rounds' operands vary over the mesh under the ring's checked
+    # ``shard_map``, as ``merge_tiles_into_carry`` reads it
     varying = bool(jax.typeof(queries).vma | jax.typeof(block).vma)
     # whether the one-pass branch of a round's merge is the kernel that
     # walks the arriving stack (the rule ``merge_tiles_into_carry`` asks of
     # the same shapes): its steps count as ``fused`` and it counts chunks
-    kernel_walks = q_one is not None and not fused and bool(fused_rule(
+    kernel_walks = q_one is not None and bool(fused_rule(
         cfg, q_tile, c_tile, dim, varying))
     block, block_ids, block_scale = map(
         tiled, (block, block_ids, block_scale))
@@ -369,25 +306,6 @@ def _ring_knn_local(
         (flagged rows were answered again) and one ``[inserted, skipped]``
         a query tile (the chunks of its distance tiles in *bins*); else
         (None, None)."""
-        if fused:
-            # the fused Pallas kernel replaces the whole per-round merge —
-            # dequant/upcast, masked tile distances and the carry top-k all
-            # happen in-kernel on flat (q_local, k) carries (per-row
-            # independence makes the (QT, q_tile) carry blocking a pure
-            # layout choice, so reshaping through it is bit-free)
-            fd, fi = fused_block_merge(
-                queries,
-                query_ids,
-                blk,
-                blk_ids,
-                blk_scl,
-                cd.reshape(q_local, cfg.k),
-                ci.reshape(q_local, cfg.k),
-                cfg=cfg,
-                q_tile=q_tile,
-                c_tile=c_tile,
-            )
-            return fd.reshape(cd.shape), fi.reshape(ci.shape), (None, None)
         if blk_scl is not None:
             # the int8 dequant: ONE convert out of the code domain and ONE
             # multiply by the block's scale vector, feeding every distance
@@ -421,28 +339,6 @@ def _ring_knn_local(
         # its ``[inserted, skipped]`` chunks, where they are kept (below),
         # else nothing
         blk, scl, blk_ids, cd, ci, *tally = state
-        if fused_dma:
-            # collective-matmul round: ONE kernel issues the async remote
-            # copies of the resident block and runs the distance sweep —
-            # the landing buffers it returns are the next round's resident
-            # block, so transport never appears as a separate HLO op
-            nxt, nscl, nxt_ids, fd, fi = fused_round_dma(
-                queries,
-                query_ids,
-                blk,
-                blk_ids,
-                scl,
-                cd.reshape(q_local, cfg.k),
-                ci.reshape(q_local, cfg.k),
-                cfg=cfg,
-                q_tile=q_tile,
-                c_tile=c_tile,
-                axis_name=axis,
-            )
-            return (
-                nxt, nscl, nxt_ids,
-                fd.reshape(cd.shape), fi.reshape(ci.shape),
-            ), None
         if overlap:
             # permute and compute both depend only on the incoming block —
             # XLA overlaps the ICI transfer with the distance matmul (the
@@ -540,33 +436,6 @@ def _ring_knn_local(
             nbi = _rot(bids, perm_bwd)
         return (nfb, nfs, nfi, nbb, nbs, nbi, cd, ci), None
 
-    if fused and cfg.ring_fused_rotation == "grid":
-        if single_round:
-            raise ValueError(
-                "ring_fused_rotation='grid' runs the whole rotation as ONE "
-                "kernel launch — there is no per-round boundary for the "
-                "resumable driver to checkpoint at; use "
-                "ring_fused_rotation='round' with backend='ring-resumable'"
-            )
-        # whole-rotation form: rounds ride the kernel's major grid axis,
-        # the block double-buffers between two HBM scratch slots
-        # (TPU-only; fused_rotation_grid raises off-TPU — config already
-        # pinned this variant to uni schedule, exact policy, float wire)
-        out_d, out_i = fused_rotation_grid(
-            queries,
-            query_ids,
-            block,
-            block_ids,
-            carry_d.reshape(q_local, cfg.k),
-            carry_i.reshape(q_local, cfg.k),
-            cfg=cfg,
-            q_tile=q_tile,
-            c_tile=c_tile,
-            axis_name=axis,
-            num_dev=num_dev,
-        )
-        return out_d, out_i
-
     if single_round:
         if bidir:
             if block_bwd is None or block_bwd_ids is None:
@@ -654,7 +523,7 @@ def _ring_knn_local(
     # kernel that walks the stack), the chunks its *bins* inserted and
     # skipped
     tally = ()
-    if q_one is not None and not fused and carried_depth(
+    if q_one is not None and carried_depth(
             cfg, q_tile, c_tile, varying) is not None:
         tally = tuple(
             jax.lax.pcast(jnp.zeros(shape, jnp.int32),
@@ -796,10 +665,9 @@ def _ring_knn_sharded(
     def local(q, qi, c, cids, *rest):
         return body(q, qi, c, cids, **dict(zip(extra, rest)))
 
-    fn = ring_shard_map(
+    fn = jax.shard_map(
         local,
-        cfg,
-        mesh,
+        mesh=mesh,
         in_specs=(qspec, qspec, cspec, cspec,
                   *(spec for _, spec in extra.values())),
         out_specs=(qspec, qspec, *((qspec,) if "onepass" in extra else ())),
@@ -849,10 +717,9 @@ def ring_serve_sharded(
         def with_carry(q, qi, cd, ci, c, cids):
             return body(q, qi, c, cids, carry_in=(cd, ci))
 
-        fn = ring_shard_map(
+        fn = jax.shard_map(
             with_carry,
-            cfg,
-            mesh,
+            mesh=mesh,
             in_specs=(qspec, qspec, qspec, qspec, cspec, cspec),
             out_specs=(qspec, qspec),
         )
@@ -861,10 +728,9 @@ def ring_serve_sharded(
     def with_carry_scale(q, qi, cd, ci, c, cids, cscl):
         return body(q, qi, c, cids, carry_in=(cd, ci), block_scale=cscl)
 
-    fn = ring_shard_map(
+    fn = jax.shard_map(
         with_carry_scale,
-        cfg,
-        mesh,
+        mesh=mesh,
         in_specs=(qspec, qspec, qspec, qspec, cspec, cspec, cspec),
         out_specs=(qspec, qspec),
     )
@@ -907,9 +773,9 @@ def ring_form(cfg: KNNConfig, m: int, dim: int, nq: int,
     return dict(
         backend=backend, m=m, dim=dim, dtype=cfg.dtype, metric=cfg.metric,
         center=cfg.center,
-        # the XLA ring at a float wire takes the one-pass rule; every other
+        # the ring at a float wire takes the one-pass rule; every other
         # form runs the program it always ran
-        onepass_applies=(onepass_applies(cfg) and cfg.ring_fusion == "xla"
+        onepass_applies=(onepass_applies(cfg)
                          and cfg.ring_transfer_dtype is None),
         c_tile=c_tile, c_pad=c_pad, mesh=mesh, wire=cfg.ring_transfer_dtype,
     )

@@ -48,7 +48,6 @@ from mpi_knn_tpu.backends.ring import (
     blocking_undefined_on_mesh_error,
     parse_ring_mesh,
     quantize_ring_block,
-    ring_shard_map,
     ring_tiles,
 )
 from mpi_knn_tpu.ops.topk import init_topk
@@ -124,10 +123,9 @@ def _ring_one_round(
             )
             return one(q, qid, blk, bids)
 
-        fn = ring_shard_map(
+        fn = jax.shard_map(
             body,
-            cfg,
-            mesh,
+            mesh=mesh,
             in_specs=(qspec, qspec, cspec, cspec, qspec, qspec),
             out_specs=(cspec, cspec, qspec, qspec),
         )
@@ -148,10 +146,9 @@ def _ring_one_round(
         )
         return one(q, qid, blk, bids, block_scale=bscl)
 
-    fn = ring_shard_map(
+    fn = jax.shard_map(
         body_q,
-        cfg,
-        mesh,
+        mesh=mesh,
         in_specs=(qspec, qspec, cspec, cspec, cspec, qspec, qspec),
         out_specs=(cspec, cspec, cspec, qspec, qspec),
     )
@@ -217,10 +214,9 @@ def _ring_one_round_bidir(
             )
             return one(q, qid, fb, fids, block_bwd=bb, block_bwd_ids=bids)
 
-        fn = ring_shard_map(
+        fn = jax.shard_map(
             body,
-            cfg,
-            mesh,
+            mesh=mesh,
             in_specs=(qspec, qspec, cspec, cspec, cspec, cspec, qspec,
                       qspec),
             out_specs=(cspec, cspec, cspec, cspec, qspec, qspec),
@@ -249,10 +245,9 @@ def _ring_one_round_bidir(
             block_bwd_ids=bids, block_bwd_scale=bscl,
         )
 
-    fn = ring_shard_map(
+    fn = jax.shard_map(
         body_q,
-        cfg,
-        mesh,
+        mesh=mesh,
         in_specs=(qspec, qspec, cspec, cspec, cspec, cspec, cspec, cspec,
                   qspec, qspec),
         out_specs=(cspec, cspec, cspec, cspec, cspec, cspec, qspec, qspec),
@@ -308,10 +303,8 @@ def all_knn_ring_resumable(
     # bidir carry means "the two-cursor prefix merged" — the same
     # rounds_done under the other schedule would silently skip/duplicate
     # blocks, so the two must never cross-resume.
-    # ring_fusion rides the suffix for the same reason as the schedule:
-    # fused and xla carries are bit-identical BY TEST, not by contract —
-    # if a future kernel revision legitimately changes merge bits, a
-    # cross-fusion resume must restart rather than mix carry algebras.
+    # ring_fusion (one legal value left, config.py) keeps its term in the
+    # suffix for as long as the field exists.
     fp = (
         fingerprint(corpus, queries, cfg)
         + f":ring{ring_n}x{dp}:{int(overlap)}:{cfg.ring_schedule}"

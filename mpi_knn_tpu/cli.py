@@ -34,8 +34,6 @@ from mpi_knn_tpu.config import (
     MERGE_SCHEDULES,
     METRICS,
     PRECISION_POLICIES,
-    RING_FUSED_ROTATIONS,
-    RING_FUSIONS,
     RING_SCHEDULES,
     TIE_BREAKS,
     TOPK_METHODS,
@@ -110,21 +108,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "blocks circulate both torus directions at once, "
                    "floor(P/2)+1 rounds, same results bit-identically — "
                    "the comm critical path halves on real ICI)")
-    k.add_argument("--ring-fusion", choices=list(RING_FUSIONS),
-                   default="xla",
-                   help="who owns the ring rotation: xla (ppermute + "
-                   "kernel as separate ops, compiler-scheduled overlap) or "
-                   "fused (the collective-matmul form — async remote "
-                   "copies issued from INSIDE the Pallas distance kernel, "
-                   "the next block streaming over ICI while the current "
-                   "one is on the MXU; bit-identical results, requires "
-                   "the overlap schedule)")
-    k.add_argument("--ring-fused-rotation",
-                   choices=list(RING_FUSED_ROTATIONS), default="round",
-                   help="fused-form launch granularity: round (one kernel "
-                   "per ring round, works everywhere the fused form does) "
-                   "or grid (whole rotation as ONE kernel launch with "
-                   "rounds on the grid axis; TPU-only, uni/exact)")
     k.add_argument("--ring-transfer-dtype",
                    choices=["bfloat16", "float32", "int8"],
                    default=None,
@@ -134,10 +117,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "int8 is the block-scaled quantized level (~4x fewer "
                    "wire bytes; requires --precision-policy mixed so the "
                    "exact rerank absorbs the quantization)")
-    k.add_argument("--pallas-variant", choices=["tiles", "sweep"],
-                   default="tiles",
-                   help="pallas backend kernel shape: per-tile top-k + XLA "
-                   "merge, or VMEM-scratch sweep (see backends/pallas)")
     k.add_argument("--include-zero-dist", action="store_true",
                    help="keep zero-distance (duplicate) neighbors — the "
                    "reference excludes them (knn-serial.c:86)")
@@ -162,7 +141,7 @@ def build_parser() -> argparse.ArgumentParser:
     o.add_argument("--checkpoint-dir", default=None,
                    help="round-granular checkpoint/resume state directory; "
                    "ring backends checkpoint the sharded carry per ring "
-                   "round, serial/pallas per corpus-tile round")
+                   "round, serial per corpus-tile round")
     o.add_argument("--save-every", type=int, default=None,
                    help="checkpoint cadence: corpus tiles for the serial "
                    "path (default 8), ring rounds for ring backends "
@@ -413,10 +392,7 @@ def main(argv=None) -> int:
         topk_block=args.topk_block,
         merge_schedule=args.merge_schedule,
         ring_schedule=args.ring_schedule,
-        ring_fusion=args.ring_fusion,
-        ring_fused_rotation=args.ring_fused_rotation,
         ring_transfer_dtype=args.ring_transfer_dtype,
-        pallas_variant=args.pallas_variant,
         exclude_zero=not args.include_zero_dist,
         exclude_self=not args.include_self,
         num_devices=args.devices,
@@ -444,7 +420,7 @@ def main(argv=None) -> int:
         if args.backend not in ("ring", "ring-overlap", "auto"):
             raise SystemExit(
                 f"error: --dp requires a ring backend (got --backend "
-                f"{args.backend}; serial/pallas ignore the mesh)"
+                f"{args.backend}; serial ignores the mesh)"
             )
         if args.backend == "ring":
             # VERDICT r5 weak #3: on a dp×ring mesh the blocking barrier can
@@ -553,7 +529,7 @@ def main(argv=None) -> int:
                 why = ("resumable runs serial math"
                        if args.checkpoint_dir else "selected backend IS serial")
                 print(f"recall-vs-serial: {why} (trivially 1.0); pick "
-                      "--backend ring/ring-overlap/pallas to compare")
+                      "--backend ring/ring-overlap to compare")
         else:
             from mpi_knn_tpu.utils.report import recall_at_k
 

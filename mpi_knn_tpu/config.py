@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence
 
-BACKENDS = ("auto", "serial", "ring", "ring-overlap", "pallas")
+BACKENDS = ("auto", "serial", "ring", "ring-overlap")
 METRICS = ("l2", "cosine")
 # dtypes a corpus block may travel the ring at (None = the compute dtype):
 # bfloat16 halves the ICI bytes per hop; int8 is the block-scaled
@@ -24,23 +24,7 @@ TOPK_METHODS = ("exact", "approx", "approx-rerank", "block", "bf16")
 PRECISION_POLICIES = ("exact", "mixed")
 MERGE_SCHEDULES = ("stream", "twolevel")
 RING_SCHEDULES = ("uni", "bidir")
-# transport/compute fusion level of the ring backends:
-# "xla"   — ppermute + XLA/Pallas distance compute as separate HLO ops,
-#           overlap certified by lint rule R1 (today's form);
-# "fused" — the collective-matmul form: one Pallas kernel per round both
-#           computes the resident block's distance tiles AND streams the
-#           block to the next device (async remote DMA on TPU; interpret-
-#           mode compute + the identical-bytes ppermute transport on CPU).
-RING_FUSIONS = ("xla", "fused")
-# rotation granularity of the fused kernel: "round" = one kernel launch
-# per ring round (the form the CPU interpret parity matrix certifies);
-# "grid" = the whole P-round rotation as one kernel with rounds on the
-# major grid axis and the block double-buffered in two HBM slots —
-# experimental, TPU-only (remote DMA between rounds cannot be emulated
-# inside one interpret-mode launch), uni/exact/float-wire only.
-RING_FUSED_ROTATIONS = ("round", "grid")
 TIE_BREAKS = ("nearest", "lowest", "quirk-serial", "quirk-mpi")
-PALLAS_VARIANTS = ("tiles", "sweep")
 KMEANS_INITS = ("kmeans++", "random")
 
 
@@ -55,7 +39,7 @@ class KNNConfig:
       backend: ``serial`` (single device), ``ring`` (blocking-parity ppermute
         ring), ``ring-overlap`` (pipelined ring with compute/comm overlap —
         the capability the reference's non-blocking variant intended but never
-        achieved, SURVEY.md Q7), ``pallas`` (fused kernel path), or ``auto``.
+        achieved, SURVEY.md Q7), or ``auto``.
       query_tile / corpus_tile: on-device tiling of the (q × c) distance
         computation. Tiles are MXU-aligned (multiples of 128 recommended).
       dtype: input compute dtype. float32 default; bfloat16 for peak MXU
@@ -189,30 +173,11 @@ class KNNConfig:
     #           precision_policy because the per-round block merge is the
     #           same shared tile reduction.
     ring_schedule: str = "uni"
-    # transport/compute fusion of the ring backends (RING_FUSIONS above).
-    # "fused" moves the rotation *inside* the Pallas distance kernel
-    # (ops/pallas_ring.py): the resident block is on the MXU while the
-    # async remote copy streams it to the neighbor, hiding the ICI
-    # latency the "xla" form merely lets the compiler schedule around.
-    # Requires the overlap schedule (backends/ring.py refuses blocking),
-    # metric="l2" and dtype="float32" (the kernel's compute contract —
-    # the WIRE may still be bf16/int8 via ring_transfer_dtype; int8
-    # codes+scales are DMA'd as-is and dequantized into the in-kernel
-    # compress dot), and topk_method="exact" (the in-kernel carry merge
-    # is the exact sweep, bit-identical to lax.top_k — certified by the
-    # interpret-mode parity matrix in tests/test_ring_fused.py).
+    # one legal value, "xla": the ring is the ppermute ring with the
+    # shared tile step in its rounds. The field stays only because
+    # benchmark/configs/mnist8m-784-l2-ring4.json names it and the
+    # benchmark builds KNNConfig(**fields) (ROADMAP Design 5).
     ring_fusion: str = "xla"
-    # fused-rotation granularity (RING_FUSED_ROTATIONS above). "grid" is
-    # the whole-rotation single-launch variant behind this flag: TPU-only,
-    # ring_schedule="uni" + precision_policy="exact" only.
-    ring_fused_rotation: str = "round"
-    # pallas backend kernel shape: "tiles" = per-(q,c)-tile local top-k +
-    # one XLA cross-tile merge (honors topk_method there); "sweep" = whole
-    # corpus swept on the minor grid axis with the carry in VMEM scratch,
-    # only (Q, k) leaves the kernel — its in-kernel merge is always exact,
-    # so topk_method has no effect. Both bit-identical to serial in tests;
-    # pick by profiling.
-    pallas_variant: str = "tiles"
     # hard cap on query_tile × corpus_tile elements of one distance tile —
     # the HBM-resident intermediate a backend may materialize. 2^28 f32
     # elements = 1 GiB, safely inside a 16 GiB chip alongside the corpus.
@@ -340,7 +305,14 @@ class KNNConfig:
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
-            raise ValueError(f"backend must be one of {BACKENDS}, got {self.backend!r}")
+            gone = (
+                " — the Pallas backend is gone: backend='serial' runs the "
+                "Mosaic scan kernel wherever the shapes allow"
+                if self.backend == "pallas" else ""
+            )
+            raise ValueError(
+                f"backend must be one of {BACKENDS}, got {self.backend!r}{gone}"
+            )
         if self.metric not in METRICS:
             raise ValueError(f"metric must be one of {METRICS}, got {self.metric!r}")
         if self.topk_method not in TOPK_METHODS:
@@ -350,11 +322,6 @@ class KNNConfig:
         if self.tie_break not in TIE_BREAKS:
             raise ValueError(
                 f"tie_break must be one of {TIE_BREAKS}, got {self.tie_break!r}"
-            )
-        if self.pallas_variant not in PALLAS_VARIANTS:
-            raise ValueError(
-                f"pallas_variant must be one of {PALLAS_VARIANTS}, got "
-                f"{self.pallas_variant!r}"
             )
         if self.ring_transfer_dtype not in RING_TRANSFER_DTYPES:
             # the error text enumerates the ACCEPTED set (RING_TRANSFER_
@@ -382,64 +349,13 @@ class KNNConfig:
                 f"ring_schedule must be one of {RING_SCHEDULES}, got "
                 f"{self.ring_schedule!r}"
             )
-        if self.ring_fusion not in RING_FUSIONS:
+        if self.ring_fusion != "xla":
             raise ValueError(
-                f"ring_fusion must be one of {RING_FUSIONS}, got "
-                f"{self.ring_fusion!r}"
+                f"ring_fusion must be 'xla', got {self.ring_fusion!r}: the "
+                "fused Pallas ring is gone — backend='ring-overlap' is the "
+                "ring, and its rounds take the Mosaic scan kernel wherever "
+                "the shapes allow"
             )
-        if self.ring_fused_rotation not in RING_FUSED_ROTATIONS:
-            raise ValueError(
-                "ring_fused_rotation must be one of "
-                f"{RING_FUSED_ROTATIONS}, got {self.ring_fused_rotation!r}"
-            )
-        if self.ring_fusion == "fused":
-            if self.metric != "l2":
-                raise ValueError(
-                    "ring_fusion='fused' supports metric='l2' only: the "
-                    "fused rotation kernel computes the squared-L2 tile "
-                    f"in-kernel (got metric={self.metric!r})"
-                )
-            if self.dtype != "float32":
-                raise ValueError(
-                    "ring_fusion='fused' requires dtype='float32' (the "
-                    "fused kernel's compute contract, like the pallas "
-                    "backend's); compress the WIRE with "
-                    "ring_transfer_dtype='bfloat16'/'int8' instead — got "
-                    f"dtype={self.dtype!r}"
-                )
-            if self.topk_method != "exact":
-                raise ValueError(
-                    "ring_fusion='fused' requires topk_method='exact': "
-                    "the in-kernel carry merge is the exact k-sweep "
-                    "(bit-identical to lax.top_k), so an approximate "
-                    "method could not take effect and would silently "
-                    f"report exact results — got {self.topk_method!r}"
-                )
-            if (
-                self.ring_fused_rotation == "grid"
-                and self.ring_transfer_dtype == "int8"
-            ):
-                raise ValueError(
-                    "ring_fused_rotation='grid' supports float wire "
-                    "formats only (float32/bfloat16): the grid kernel "
-                    "DMAs raw slot bytes between its HBM double-buffer "
-                    "slots and casts them straight into the distance dot "
-                    "— int8 codes would be cast without dequantization "
-                    "(the scale plumbing belongs to the round form)"
-                )
-            if self.ring_fused_rotation == "grid" and (
-                self.ring_schedule != "uni"
-                or self.precision_policy != "exact"
-            ):
-                raise ValueError(
-                    "ring_fused_rotation='grid' (whole-rotation single "
-                    "launch) supports ring_schedule='uni' with "
-                    "precision_policy='exact' only: bidir needs two "
-                    "opposed DMA streams per round and mixed needs the "
-                    "XLA rerank between rounds — got schedule="
-                    f"{self.ring_schedule!r}, policy="
-                    f"{self.precision_policy!r}"
-                )
         if self.merge_schedule not in MERGE_SCHEDULES:
             raise ValueError(
                 f"merge_schedule must be one of {MERGE_SCHEDULES}, got "
@@ -475,15 +391,6 @@ class KNNConfig:
                     "(DEFAULT compress, HIGHEST rerank); matmul_precision "
                     f"must be None, got {self.matmul_precision!r}"
                 )
-        if self.matmul_precision == "high" and (
-            self.backend == "pallas" or self.ring_fusion == "fused"
-        ):
-            raise ValueError(
-                "matmul_precision='high' does not exist inside the Pallas "
-                "kernels (backend='pallas', ring_fusion='fused'): Mosaic "
-                "lowers DEFAULT and HIGHEST dots only and refuses the "
-                "three-pass form — use 'highest' (or None) or 'default'"
-            )
         if self.query_bucket < 1:
             raise ValueError(
                 f"query_bucket must be >= 1, got {self.query_bucket}"

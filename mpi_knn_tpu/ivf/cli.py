@@ -15,8 +15,7 @@ artifact serves on any shard count (``mpi-knn query --index-load …
 
 Flag combinations the clustered path cannot honor are refused with a loud
 exit 2 (the serve-CLI convention — never silently build a different index
-than the one requested): a pallas backend (the fused kernels scan the
-full corpus by construction), a non-L2 metric (the k-means partitioner is
+than the one requested): a non-L2 metric (the k-means partitioner is
 L2 geometry), float64 (the dense backends' debug mode),
 nprobe > partitions.
 
@@ -72,7 +71,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="serial/auto (single-device) or ring (the sharded "
                    "deployment shape — training is identical; the shard "
                    "layout is derived at serve time, so the saved index "
-                   "is the same artifact); pallas is refused")
+                   "is the same artifact)")
     k.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16", "int8", "int4"],
                    help="bucket-store at-rest dtype; bfloat16 halves "

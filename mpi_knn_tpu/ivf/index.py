@@ -356,8 +356,8 @@ def build_ivf_index(
     if cfg.backend not in ("auto", "serial"):
         raise ValueError(
             f"the clustered index is a single-device serial-math path; "
-            f"backend={cfg.backend!r} cannot honor it (the pallas kernels "
-            "and the ring rotation scan the full corpus by construction) "
+            f"backend={cfg.backend!r} cannot honor it (the ring rotation "
+            "scans the full corpus by construction) "
             "— use backend='serial' or 'auto'"
         )
     if cfg.dtype not in IVF_DTYPES:

@@ -411,8 +411,7 @@ def ivf_serve_chunk(
     carry_i, probed, <resident arrays…>) convention, the scratch donated
     (``donate_argnums=(2, 3, 4)``). The tile results merge into the (all-inf)
     donated scratch — a bit-exact no-op merge whose sole purpose is giving
-    the scratch buffers an output to alias (the pallas serve path's
-    trick)."""
+    the scratch buffers an output to alias."""
 
     partitions, cap, _ = buckets.shape
     bucket_major = bucket_scales is None and _engages(

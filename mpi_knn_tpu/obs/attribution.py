@@ -8,16 +8,14 @@ its p50/p99 — matmul / sort-topk / collective / copy / dma-wait /
 other, plus the collective-under-compute overlap fraction (the measured
 form of lint rule R1's "overlap achieved", see ``analysis/README.md``).
 
-The ``dma-wait`` category exists for the fused collective-matmul
-rotation (``ops/pallas_knn`` ring fusion): its ICI transfers are async
-remote copies issued inside the kernel, and the kernel's semaphore
-stalls surface in the trace as explicit wait events. Categorizing those
-as their own bucket — never ``matmul`` — keeps ``overlap_fraction``
-honest on fused runs: a comm stall inside the kernel is the UN-hidden
-part of the transfer, and folding it into compute would count exactly
-the time the overlap failed to hide as if it had been hidden. The
-report surfaces the bucket both in ``busy_ms`` and as the top-level
-``dma_wait_ms`` the fused bench series reads.
+The ``dma-wait`` category is for a kernel that issues async copies
+itself and stalls on their semaphores, which the trace shows as explicit
+wait events (no kernel in the tree moves a block over ICI that way; the
+ring's hops are ``collective-permute``s). Such stalls are their own
+bucket — never ``matmul`` — so that ``overlap_fraction`` does not count
+the time a transfer was NOT hidden as compute it hid under. The report
+surfaces the bucket both in ``busy_ms`` and as the top-level
+``dma_wait_ms``.
 
 Invariant the acceptance test pins: the per-category milliseconds sum to
 the total busy time (every event carries exactly one category), so a

@@ -216,10 +216,9 @@ def parse_xplane(path: str) -> list[dict]:
 
 
 CATEGORIES = (
-    # dma-wait FIRST: the fused collective-matmul kernel
-    # (ops/pallas_ring.py) issues its ICI transfers with in-kernel async
-    # remote copies and stalls on semaphore waits that the TensorCore
-    # trace emits as explicit wait events. Those stalls are COMM time,
+    # dma-wait FIRST: a kernel that issues async copies itself stalls on
+    # semaphore waits that the TensorCore trace emits as explicit wait
+    # events. Those stalls are COMM time,
     # not compute — if the wait markers fell through to "matmul" (many
     # spell the kernel or fusion they stall inside), every comm stall
     # would inflate the measured overlap_fraction (the R1 dual) by

@@ -232,7 +232,7 @@ def pairwise_sq_l2(
 
 # Norm-squared clamp used by _l2_normalize. Any row with sq_norm <= this is
 # NOT normalized to unit length (the clamp wins), so callers relying on the
-# unit-row identity (pallas cosine's d² = 2·d_cos) must treat such rows as
+# unit-row identity (d² = 2·d_cos) must treat such rows as
 # degenerate — guard with `sq_norms(x) <= _NORM_EPS`, not `== 0`.
 _NORM_EPS = 1e-30
 

@@ -533,8 +533,7 @@ def lane_bin_candidates_under(bound: jax.Array, dists: jax.Array,
 def _lane_bin_finish_kernel(cd_ref, ci_ref, od_ref, oi_ref, flag_ref,
                             work_ref, acc_d_ref, acc_i_ref, *, k):
     """k passes of row-min / lowest-id-among-the-minima / knock-out over a
-    block of candidate rows (what ``ops/pallas_knn._k_smallest_sweep`` does
-    over a whole tile): no sort and no gather. Then the certificate. Each
+    block of candidate rows: no sort and no gather. Then the certificate. Each
     pass works on the whole block at once (see ``_FINISH_ROWS``), knocks
     out in place in ``work_ref`` and leaves its answer in lane j of the two
     (rows, 128) accumulators (hence k <= 128), so the passes are one loop

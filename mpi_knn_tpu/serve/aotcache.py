@@ -92,7 +92,7 @@ def index_facts(index) -> dict:
         "has_mu": index.mu is not None,
     }
     for name in (
-        "tiles", "tile_ids", "tile_sqs", "corpus_padded",
+        "tiles", "tile_ids", "tile_sqs",
         "corpus_sharded", "corpus_ids_sharded", "corpus_scales_sharded",
         "centroids", "centroid_sqs", "buckets", "bucket_ids",
         "bucket_sqs", "bucket_scales",

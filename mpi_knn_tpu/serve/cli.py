@@ -10,9 +10,8 @@ compiled so that claim is visible per invocation.
 
 Flag combinations the engine cannot honor are refused with a loud exit 2
 (the ``BENCH_RING_SCHEDULE`` convention: never silently measure a
-different configuration than the one requested) — e.g. a pallas index
-with a cosine metric or a non-float32 dtype, a mixed-precision query
-config over a bf16-compressed index, or a blocking-ring index on a
+different configuration than the one requested) — e.g. a mixed-precision
+query config over a bf16-compressed index, or a blocking-ring index on a
 multi-axis mesh.
 
 Examples::
@@ -391,8 +390,8 @@ def main(argv=None) -> int:
         index = build_index(X, cfg)
         session = ServeSession(index, resilience=policy)
     except ValueError as e:
-        # the engine cannot honor this combination (pallas+cosine,
-        # compressed index + mixed policy, blocking ring on a 2-D mesh…)
+        # the engine cannot honor this combination (compressed index +
+        # mixed policy, blocking ring on a 2-D mesh…)
         print(f"error: {e}", file=sys.stderr)
         return 2
     build_s = time.perf_counter() - t_build0
@@ -416,8 +415,7 @@ def _serve_loaded_index(args, X, source, policy=None) -> int:
             f"error: --index-load × --backend {args.backend} is not "
             "supported: a clustered index serves single-device (serial/"
             "auto) or sharded over the ring mesh (ring — the routed "
-            "candidate exchange); the pallas kernels scan the full "
-            "corpus by construction, and the exchange has no overlap "
+            "candidate exchange), and the exchange has no overlap "
             "schedule (use --backend ring, not ring-overlap)",
             file=sys.stderr,
         )

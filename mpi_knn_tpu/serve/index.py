@@ -50,7 +50,6 @@ from mpi_knn_tpu.ops.distance import center_corpus, onepass_applies
 from mpi_knn_tpu.ops.topk import (
     init_topk,
     init_topk_tiles,
-    merge_topk,
     start_lane_bin_import,
 )
 from mpi_knn_tpu.parallel.partition import (
@@ -271,55 +270,7 @@ class RingLayout(BatchLayout):
             index.corpus_sharded.shape[0] // index.c_tile, index.cfg.metric)
 
 
-def _pallas_serve_fn(
-    queries_p, query_ids, carry_d, carry_i, corpus_p,
-    cfg, q_tile, c_tile, m_corpus, variant,
-):
-    """Pallas batch step: the fused kernel in query mode, its result merged
-    into the (all-inf) donated scratch — a bit-exact no-op merge whose sole
-    purpose is giving the scratch buffers an output to alias (the serial
-    and ring paths thread the scratch through the reduction naturally)."""
-    from mpi_knn_tpu.backends.pallas_backend import _pallas_all_knn
-
-    del query_ids  # query mode: queries carry no corpus identity
-    d, i = _pallas_all_knn(
-        queries_p, corpus_p, cfg, q_tile, c_tile, m_corpus, False, variant
-    )
-    return merge_topk(carry_d, carry_i, d, i, method="exact")
-
-
-class PallasLayout(BatchLayout):
-    """The padded f32 corpus of the fused kernels (``ops.pallas_knn``)."""
-
-    static_argnames = ("cfg", "q_tile", "c_tile", "m_corpus", "variant")
-
-    def serve_fn(self):
-        return _pallas_serve_fn
-
-    def bucket_shapes(self, index, cfg, bucket):
-        q_tile = min(max(8, pad_to_multiple(cfg.query_tile, 8)), 512,
-                     pad_to_multiple(bucket, 8))
-        return pad_to_multiple(bucket, q_tile), q_tile
-
-    def resident(self, index):
-        return (index.corpus_padded,)
-
-    def statics(self, index, cfg, bucket):
-        variant = cfg.pallas_variant
-        if variant == "sweep" and cfg.k > index.c_tile:
-            variant = "tiles"  # same corner routing as all_knn_pallas
-        return dict(
-            cfg=cfg, q_tile=self.bucket_shapes(index, cfg, bucket)[1],
-            c_tile=index.c_tile, m_corpus=index.m, variant=variant,
-        )
-
-    def query_dtype(self, cfg):
-        return jnp.dtype("float32")  # the kernels compute in float32
-
-    carry_dtype = query_dtype
-
-
-SERIAL, PALLAS = SerialLayout(), PallasLayout()
+SERIAL = SerialLayout()
 TAGGED_SERIAL = TaggedSerialLayout()
 RING, RING_OVERLAP = RingLayout(overlap=False), RingLayout(overlap=True)
 
@@ -329,7 +280,7 @@ class CorpusIndex:
     """Resident corpus state for one (corpus, config[, mesh]) triple.
 
     ``backend`` is resolved (never "auto"); exactly one of the two storage
-    layouts is populated: the tile stack (serial/pallas) or the sharded
+    layouts is populated: the tile stack (serial) or the sharded
     padded corpus (ring/ring-overlap).
     """
 
@@ -340,7 +291,7 @@ class CorpusIndex:
     c_tile: int
     mu: object | None  # centering mean (host f64 or device), or None
     layout: BatchLayout  # the kind's batch program, chosen at build
-    # serial/pallas layout
+    # serial layout
     tiles: jax.Array | None = None  # (T, c_tile, d)
     tile_ids: jax.Array | None = None  # (T, c_tile)
     tile_sqs: jax.Array | None = None  # (T, c_tile)
@@ -356,7 +307,6 @@ class CorpusIndex:
     # built with them: every batch then brings a predicate a query row,
     # the layout is :class:`TaggedSerialLayout`, and the index is frozen
     tags: object | None = None
-    corpus_padded: jax.Array | None = None  # (c_pad, d) — pallas layout
     # ring layout
     mesh: Mesh | None = None
     ring_meta: tuple | None = None  # (q_axis, axis, dp, ring_n)
@@ -373,11 +323,7 @@ class CorpusIndex:
     @property
     def nbytes_resident(self) -> int:
         """Bytes of resident corpus payload (tiles or sharded corpus)."""
-        arr = self.tiles if self.tiles is not None else (
-            self.corpus_padded
-            if self.corpus_padded is not None
-            else self.corpus_sharded
-        )
+        arr = self.tiles if self.tiles is not None else self.corpus_sharded
         return 0 if arr is None else arr.size * arr.dtype.itemsize
 
     @property
@@ -407,8 +353,7 @@ class CorpusIndex:
         frozen = (
             "backend", "metric", "dtype", "corpus_tile", "query_tile",
             "center", "mesh_axis", "num_devices", "ring_transfer_dtype",
-            "ring_schedule", "max_tile_elems", "pallas_variant",
-            "exclude_zero", "zero_eps",
+            "ring_schedule", "max_tile_elems", "exclude_zero", "zero_eps",
         )
         built = self.cfg.replace(backend=self.backend)
         want = cfg if cfg.backend != "auto" else cfg.replace(
@@ -602,28 +547,6 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
             ring_meta=(q_axis, axis, dp, ring_n),
             corpus_sharded=corpus_p, corpus_ids_sharded=corpus_ids,
             corpus_scales_sharded=corpus_scales,
-        )
-
-    if backend == "pallas":
-        if cfg.dtype != "float32":
-            raise ValueError(
-                "pallas backend computes in float32; build the index with "
-                f"dtype='float32' (got {cfg.dtype!r})"
-            )
-        if cfg.metric != "l2":
-            raise ValueError(
-                "pallas serving supports metric='l2' only: the cosine "
-                "path needs a per-batch zero-row degeneracy probe (a "
-                "host round-trip) that a streaming engine cannot honor — "
-                "use the serial or ring backends for cosine serving"
-            )
-        c_tile = min(max(128, pad_to_multiple(cfg.corpus_tile, 128)), 2048,
-                     pad_to_multiple(m, 128))
-        c_pad = pad_to_multiple(m, c_tile)
-        corpus_p = pad_rows_any(corpus, c_pad, dtype=jnp.float32)
-        return CorpusIndex(
-            cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
-            c_tile=c_tile, mu=mu, layout=PALLAS, corpus_padded=corpus_p,
         )
 
     # serial: the tile stack + ids + NORMS, all resident (norms are the

@@ -43,10 +43,8 @@ Layout support: the serial ``CorpusIndex`` tile stack (headroom rows,
 flat freelist), the clustered ``IVFIndex`` (per-bucket freelists,
 centroid-scored placement), and the mesh-sharded ``ShardedIVFIndex``
 (the SAME donated scatters over the GSPMD-sharded store — S=1 is
-bit-identical to unsharded). The ring and pallas dense layouts refuse
-loudly: the ring's resident blocks are wire-representation shards and
-the pallas kernel masks by row count, not ids — neither can honor a
-tombstone.
+bit-identical to unsharded). The ring's dense layout refuses loudly: its
+resident blocks are wire-representation shards.
 """
 
 from __future__ import annotations
@@ -121,9 +119,8 @@ def _require_mutable(index) -> None:
         raise ValueError(
             f"the {getattr(index, 'backend', None)!r} layout cannot honor "
             "live mutation: the ring backends hold wire-representation "
-            "corpus shards (a scatter would corrupt quantized blocks) and "
-            "the pallas kernel masks by row count, not ids — serve "
-            "mutable corpora from the serial, ivf, or ivf-sharded layouts"
+            "corpus shards (a scatter would corrupt quantized blocks) — "
+            "serve mutable corpora from the serial, ivf, or ivf-sharded layouts"
         )
 
 

@@ -37,7 +37,7 @@ the v5e), the index also keeps a row-major copy of the rows for the gather,
 
 A tagged index is frozen: ``/upsert``, ``/delete`` and ``compact`` are
 refused (a write without a bag would be a row no filtered query can
-reach), and so are the ``ivf``, ``ring`` and ``pallas`` layouts.
+reach), and so are the ``ivf`` and ``ring`` layouts.
 """
 
 from __future__ import annotations

@@ -39,8 +39,8 @@ class KNNResult:
         (``ops/fused_scan.py``), one row a device where the rows are counted on
         the ring's devices; comes with the answer, costs no wait of its
         own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
-        ``knn_dist_tile_steps_total``. None from the paths that run no such
-        tile step (the Pallas backends).
+        ``knn_dist_tile_steps_total``. None from a program that counts no
+        such tile step.
       select_tiles: int32 (..., 2), the call's query-tile merges by what
         became of the selection their scans carried, ``[carried,
         rescanned]`` (``backends/serial.py merge_tiles_into_carry``:

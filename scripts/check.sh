@@ -274,50 +274,6 @@ timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest \
     tests/test_hlo_lint.py -k quant -q -p no:cacheprovider \
     -p no:xdist -p no:randomly || fail=1
 
-note "fused-rotation gate (ISSUE 17: collective-matmul ring fusion)"
-# the fused cells by name (they also run inside the full sweep above —
-# the named pass exists so a fused-kernel regression is called out as
-# such): the ring_fusion=fused cells across uni/bidir × exact/mixed ×
-# the int8 wire format, where the fused Pallas kernel (ops/pallas_ring)
-# owns the per-round compute and — on TPU's uni/exact round form — the
-# transport itself (in-kernel async remote DMAs, zero permutes in the
-# module). R1/R4/R8 read that form through the declared side-band
-# (meta['fused_dma_wire_bytes']); R7 prices the double-buffer residency.
-# The named assertions prove the committed cost ledger prices every
-# fused cell (exact FLOPs, nonzero wire bytes — a fused cell whose ICI
-# bytes read zero has silently dropped its transport from the roofline);
-# the injected counterexample — a permute-free fused module with NO
-# declared side-band, where R1, R4 and R8 must ALL fire — runs through
-# the production rule path in the pytest below, so a green fused matrix
-# can never be green by vacuity. The runtime dual (measured
-# overlap_fraction with in-kernel dma-wait split out of compute) is
-# tier-1 in tests/test_obs.py.
-python -m mpi_knn_tpu lint -q --fusion fused --out artifacts/lint_fused \
-    || fail=1
-python - <<'FUSEOF' || fail=1
-import json
-report = json.load(open("artifacts/lint_fused/report.json"))
-cells = [t for t in report["targets"] if t["skipped"] is None]
-assert len(cells) >= 4, f"fused matrix shrank: {len(cells)} cells"
-bad = [t["label"] for t in cells if not t["ok"]]
-assert not bad, f"fused cells with findings: {bad}"
-ledger = json.load(open("artifacts/lint/cost_ledger.json"))["cells"]
-for t in cells:
-    cell = ledger.get(t["label"])
-    assert cell is not None, f"{t['label']}: not in the cost ledger"
-    assert cell["mxu_flops"] == cell["analytical_flops"], (
-        f"{t['label']}: HLO flops {cell['mxu_flops']} != analytical "
-        f"{cell['analytical_flops']}")
-    assert cell["ici_bytes"] > 0, (
-        f"{t['label']}: zero ICI bytes — the fused rotation's transport "
-        "vanished from the roofline (unpriced fused DMA)")
-print(f"fused gate: {len(cells)} fused cells green, every cell "
-      f"wire-priced in the committed cost ledger")
-FUSEOF
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest \
-    tests/test_hlo_lint.py tests/test_ring_fused.py -k fused -q \
-    -p no:cacheprovider -p no:xdist -p no:randomly || fail=1
-
 note "fault-injection / resilience suite (ISSUE 6 gate)"
 # the resilience layer's whole fault matrix, exercised on CPU rather than
 # trusted: injected hang → heartbeat-starvation kill with a structured

@@ -19,10 +19,11 @@ cells' own shapes: the lint's shapes are too small for what engages by
 shape (the row bound, the kernel that walks the stack). What a cell runs is
 asked of the code that runs it, over abstract operands (nothing is
 allocated): a serving cell's programs are its index kind's batch program
-(``serve.index``'s layouts, as ``serve.engine`` lowers them) at every
-bucket its traffic warms (``warm_sizes``), with and without the one-pass
-operand; a one-shot cell's is ``backends.serial._search_stack`` at its
-traffic's ``slice_rows``; a ring cell's the sharded call. Rows, width and
+(the index's layout, as ``serve.engine`` lowers it: dense, dense with tags
+where the configuration names ``max_query_tags``, clustered where it names
+``partitions``) at every bucket its traffic warms (``warm_sizes``), a dense
+L2 one with and without the one-pass operand; a one-shot cell's is
+``backends.serial._search_stack`` at its traffic's ``slice_rows``; a ring cell's the sharded call. Rows, width and
 ``knn`` are the configuration file's. The text is the jaxpr
 (``jax.make_jaxpr``) traced as the chip traces it — ``jax.default_backend``
 answers "tpu", so the kernels are Mosaic calls and the ring carries them —
@@ -85,6 +86,7 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
 
     from mpi_knn_tpu.backends import ring, serial
     from mpi_knn_tpu.config import KNNConfig
+    from mpi_knn_tpu.ivf import index as ivf_index
     from mpi_knn_tpu.serve import index as serve_index
     from mpi_knn_tpu.parallel.partition import pad_to_multiple
 
@@ -100,22 +102,40 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
                 arg((tiles, c_tile), jnp.int32),
                 arg((tiles, c_tile), jnp.float32), arg((), jnp.bool_))
 
-    def served(cfg, rows, dim, buckets):
-        """The batch programs of an index of the configuration's shape."""
+    def dense(cfg, rows, dim, tagged):
+        """The dense index of the configuration's shape, with and without
+        the one-pass fact; ``tagged``: built with tags, as the
+        configuration states by naming ``max_query_tags``."""
         c_tile = serial.effective_tiles(cfg, rows, cfg.query_tile)[1]
         *resident, fact = stack(cfg, rows, dim, c_tile)
-        tags = cfg.max_query_tags and types.SimpleNamespace(
+        tags = types.SimpleNamespace(
             # (the planes' count is the data's: any traces the same text)
             tag_bits=arg((cfg.max_query_tags + 1, resident[0].shape[0],
-                          c_tile // 32), jnp.uint32))
+                          c_tile // 32), jnp.uint32)) if tagged else None
         layout = serve_index.TAGGED_SERIAL if tags else serve_index.SERIAL
         for onepass in ((fact, None) if cfg.metric == "l2" else (None,)):
-            index = serve_index.CorpusIndex(
-                cfg, "serial", rows, dim, c_tile, None, layout, *resident,
-                onepass=onepass, tags=tags or None)
+            yield "-nofact" if onepass is None else "", (
+                serve_index.CorpusIndex(
+                    cfg, "serial", rows, dim, c_tile, None, layout,
+                    *resident, onepass=onepass, tags=tags))
+
+    def clustered(cfg, rows, dim):
+        """The clustered index the configuration states: its lists, their
+        height and the probe count are all in ``knn``."""
+        lists, cap = cfg.partitions, cfg.bucket_cap
+        yield "", ivf_index.IVFIndex(
+            cfg, rows, dim, lists, cap, cfg.nprobe, None,
+            arg((lists, dim), jnp.float32), arg((lists,), jnp.float32),
+            arg((lists, cap, dim), jnp.float32),
+            arg((lists, cap), jnp.int32), arg((lists, cap), jnp.float32))
+
+    def served(indexes, cfg, buckets):
+        """The batch programs of an index, as its layout lowers them."""
+        for suffix, index in indexes:
+            layout = index.layout
             for bucket in buckets:
                 q_pad, q_tile = layout.bucket_shapes(index, cfg, bucket)
-                yield f"bucket{bucket}{'-nofact' if onepass is None else ''}", (
+                yield f"bucket{bucket}{suffix}", (
                     jax.make_jaxpr(functools.partial(
                         layout.jit(False), **layout.statics(
                             index, cfg, bucket)))(
@@ -155,7 +175,9 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
         cfg = KNNConfig(**config["knn"])
         shape = cfg, config["rows"], config["dim"]
         if "warm_sizes" in mix:
-            programs = served(*shape, mix["warm_sizes"])
+            indexes = (clustered(*shape) if cfg.partitions else
+                       dense(*shape, "max_query_tags" in config))
+            programs = served(indexes, cfg, mix["warm_sizes"])
         elif cfg.backend.startswith("ring"):
             programs = ring_call(*shape, mix["slice_rows"])
         else:

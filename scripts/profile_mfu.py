@@ -23,26 +23,10 @@ variant it measures
   report, so this script is a thin CLI over the shared parser instead of
   leaving raw trace dirs to a second tool.
 
-``--ring-fusion-compare`` is the fused-rotation MFU mode (the fused
-collective-matmul ring of ``ops/pallas_ring.py`` vs the XLA ring, same
-shapes, same mesh): it banks an MFU *bar* for the fused kernel, not
-just wall time. The FLOP numerator is NOT re-derived here — it is the
-R8 cost model's closed form (``analysis.cost.analytical_mxu_flops``),
-and the committed cost ledger is read first to check that the fused
-matrix cell certified HLO == analytical (the exactness contract): the
-numerator this script divides by wall-clock is a number static
-analysis already proved the machine executes. Rows follow the
-committed ``ring_mfu.v1`` schema (``measurements/ring_mfu.schema.json``)
-so the TPU round's fold can consume them unchanged. The mode runs on
-TPU only — off TPU it refuses loudly with exit 2 (an interpret-mode
-"MFU" would be a fiction banked as a measurement).
-
 Usage:
     python scripts/profile_mfu.py [--m 60000] [--d 784] [--k 10]
-        [--variants twolevel,stream,pallas-tiles,pallas-sweep]
+        [--variants dist,twolevel,stream]
         [--reps 3] [--profile-dir profiles] [--json PATH]
-    python scripts/profile_mfu.py --ring-fusion-compare [--m ...]
-        [--profile-dir profiles] [--json PATH]
 """
 
 from __future__ import annotations
@@ -58,19 +42,6 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 PASS_FACTOR = {"highest": 6.0, "high": 3.0, "default": 1.0}
-
-# the committed ring_mfu.v1 row contract (measurements/ring_mfu.schema.json
-# is the human-readable committed form): every row the fusion-compare mode
-# emits must carry exactly these keys, so the TPU round's fold and the
-# bench_ops-style ledgers consume fused MFU bars without per-run guessing
-RING_MFU_SCHEMA = "ring_mfu.v1"
-RING_MFU_ROW_KEYS = frozenset({
-    "schema", "op", "variant", "ring_fusion", "median_s", "times",
-    "mfu_vs_bf16_peak", "flops_total", "flops_source", "ledger_cell",
-    "ledger_certified", "m", "d", "k", "num_devices", "ring_schedule",
-    "peak_bf16_tflops", "ts",
-})
-
 
 def peak_flops(args) -> float:
     """bf16 peak FLOP/s of the device this process runs on: the override,
@@ -90,21 +61,6 @@ def peak_flops(args) -> float:
     return float(profile["peak_flops"])
 
 
-def _ring_mfu_row(**kw) -> dict:
-    """Construct one ring_mfu.v1 row, failing loudly on schema drift —
-    a row missing a committed key (or inventing one) must die here, not
-    in a fold three rounds later."""
-    row = {"schema": RING_MFU_SCHEMA, "op": "ring_mfu", **kw}
-    extra = set(row) - RING_MFU_ROW_KEYS - {"trace_dir", "device_time"}
-    missing = RING_MFU_ROW_KEYS - set(row)
-    if extra or missing:
-        raise SystemExit(
-            f"ring_mfu row violates {RING_MFU_SCHEMA}: "
-            f"missing={sorted(missing)} extra={sorted(extra)}"
-        )
-    return row
-
-
 def build_cfg(variant: str, args):
     from mpi_knn_tpu import KNNConfig
 
@@ -117,10 +73,6 @@ def build_cfg(variant: str, args):
     )
     if variant in ("twolevel", "stream"):
         return KNNConfig(backend="serial", merge_schedule=variant, **base)
-    if variant.startswith("pallas-"):
-        return KNNConfig(
-            backend="pallas", pallas_variant=variant.split("-", 1)[1], **base
-        )
     raise SystemExit(f"unknown variant {variant!r}")
 
 
@@ -134,147 +86,6 @@ def time_reps(fn, sync, reps):
         sync()
         out.append(time.perf_counter() - t0)
     return out
-
-
-def ring_fusion_compare(args) -> int:
-    """The fused-vs-xla ring MFU comparison (TPU only; exit 2 elsewhere).
-
-    The FLOP numerator comes from R8's closed form at this run's shapes,
-    with the committed cost ledger read first as the certificate that the
-    closed form equals what the machine executes (the fused lint cell's
-    HLO count matched it exactly, or this mode refuses to quote an MFU
-    built on an uncertified formula)."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        print(
-            "profile_mfu --ring-fusion-compare: REFUSING on platform "
-            f"{jax.default_backend()!r} — the fused rotation's kernel-DMA "
-            "form only exists on TPU; an interpret-mode 'MFU' would bank "
-            "a fiction as a measurement. Run on a TPU host (exit 2).",
-            file=sys.stderr,
-        )
-        return 2
-
-    import jax.numpy as jnp
-
-    from mpi_knn_tpu import KNNConfig, all_knn
-    from mpi_knn_tpu.analysis.cost import (
-        DEFAULT_COST_LEDGER,
-        analytical_mxu_flops,
-        load_cost_ledger,
-    )
-    from mpi_knn_tpu.utils.timing import device_sync
-
-    # the ledger certificate: the fused ring cell must have certified
-    # HLO FLOPs == analytical FLOPs, or the numerator below is a formula
-    # nobody checked against the machine
-    ledger_cell = "ring-overlap/l2/float32/fused"
-    ledger_path = Path(args.cost_ledger or DEFAULT_COST_LEDGER)
-    certified = False
-    ledger = load_cost_ledger(ledger_path) if ledger_path.exists() else None
-    if ledger is not None:
-        cell = (ledger.get("cells") or {}).get(ledger_cell)
-        if cell is not None:
-            certified = cell.get("mxu_flops") == cell.get(
-                "analytical_flops"
-            )
-    if not certified:
-        print(
-            f"profile_mfu --ring-fusion-compare: cost ledger "
-            f"{ledger_path} has no certified {ledger_cell!r} cell "
-            "(run `mpi-knn lint --cost` first) — refusing to quote an "
-            "MFU whose FLOP numerator static analysis never matched "
-            "against the lowered program (exit 2).",
-            file=sys.stderr,
-        )
-        return 2
-
-    rng = np.random.default_rng(0)
-    X = (rng.random((args.m, args.d)) * 255.0).astype(np.float32)
-    Xd = jax.device_put(jnp.asarray(X))
-    device_sync(Xd)
-    peak = peak_flops(args)
-    num_dev = jax.device_count()
-
-    # R8's dense closed form at THIS run's shapes, summed over the mesh:
-    # each device runs sites·trips·2·(q/P)·(c/P)·d — the global total is
-    # the same 2·q·c·d the serial variants quote, but derived through
-    # the certified per-device schema rather than asserted
-    per_dev = analytical_mxu_flops({
-        "scheme": "dense", "q": args.m // num_dev, "c": args.m // num_dev,
-        "d": args.d, "sites": 1, "trips": num_dev,
-    })
-    flops_total = per_dev * num_dev
-
-    rows = []
-    for fusion in ("xla", "fused"):
-        cfg = KNNConfig(
-            k=args.k,
-            backend="ring-overlap",
-            query_tile=args.query_tile,
-            corpus_tile=args.corpus_tile,
-            ring_fusion=fusion,
-        )
-        holder = {}
-
-        def run():
-            holder["res"] = all_knn(Xd, config=cfg)
-
-        def sync():
-            device_sync(holder["res"].dists, holder["res"].ids)
-
-        times = time_reps(run, sync, args.reps)
-        med = float(np.median(times))
-        row = _ring_mfu_row(
-            variant=f"ring-{fusion}",
-            ring_fusion=fusion,
-            median_s=round(med, 4),
-            times=[round(t, 4) for t in times],
-            mfu_vs_bf16_peak=round(flops_total / med / peak / num_dev, 4),
-            flops_total=int(flops_total),
-            flops_source="analysis.cost.analytical_mxu_flops (R8 closed "
-                         "form, ledger-certified)",
-            ledger_cell=ledger_cell,
-            ledger_certified=True,
-            m=args.m, d=args.d, k=args.k,
-            num_devices=num_dev,
-            ring_schedule="uni",
-            peak_bf16_tflops=peak / 1e12,
-            ts=round(time.time(), 1),
-        )
-        if args.profile_dir:
-            tdir = str(Path(args.profile_dir) / f"ring-{fusion}")
-            with jax.profiler.trace(tdir):
-                run()
-                sync()
-            row["trace_dir"] = tdir
-            from mpi_knn_tpu.obs.attribution import attribute_trace
-
-            # the acceptance instrument: overlap_fraction with the
-            # in-kernel dma-wait stalls split OUT of compute (obs.xplane)
-            row["device_time"] = attribute_trace(tdir)
-        rows.append(row)
-        print(json.dumps(row), flush=True)
-        if args.append_jsonl:
-            with open(args.append_jsonl, "a") as f:
-                f.write(json.dumps(row) + "\n")
-
-    xla_med = rows[0]["median_s"]
-    fused_med = rows[1]["median_s"]
-    summary = {
-        "schema": RING_MFU_SCHEMA,
-        "workload": f"ring all-kNN m={args.m} d={args.d} k={args.k} "
-                    f"P={num_dev}",
-        "fused_speedup": round(xla_med / fused_med, 3) if fused_med else
-        None,
-        "results": rows,
-    }
-    print(json.dumps(summary))
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(summary, f, indent=2)
-    return 0
 
 
 def main(argv=None) -> int:
@@ -312,14 +123,6 @@ def main(argv=None) -> int:
     ap.add_argument("--dist-s", type=float, default=None,
                     help="distance-only median from a prior process, for "
                          "topk_share_est when 'dist' is not in --variants")
-    ap.add_argument("--ring-fusion-compare", action="store_true",
-                    help="fused-vs-xla ring MFU comparison (TPU only; "
-                         "refuses with exit 2 elsewhere). FLOP numerator "
-                         "from the R8 cost closed form, gated on the "
-                         "committed cost ledger certifying the fused cell")
-    ap.add_argument("--cost-ledger", default=None,
-                    help="cost ledger path for --ring-fusion-compare "
-                         "(default: artifacts/lint/cost_ledger.json)")
     args = ap.parse_args(argv)
 
     if args.fresh_jsonl and args.append_jsonl:
@@ -332,9 +135,6 @@ def main(argv=None) -> int:
     if args.platform != "auto":
         force_platform(args.platform)
     use_compile_cache()
-
-    if args.ring_fusion_compare:
-        return ring_fusion_compare(args)
 
     import jax
     import jax.numpy as jnp

@@ -301,7 +301,7 @@ def test_serve_cli_serves_a_cosine_index(tmp_path):
 
     data = "synthetic:512x32c4"
     assert serve_main(["--data", data, "--metric", "cosine",
-                       "--backend", "pallas", "-q"]) == 2
+                       "--partitions", "4", "-q"]) == 2
 
     ready = tmp_path / "ready.url"
     child = subprocess.Popen(

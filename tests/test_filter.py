@@ -344,8 +344,7 @@ def test_a_tagged_index_is_frozen():
         ServeSession(index, precision_policy="mixed")
 
 
-@pytest.mark.parametrize("backend", ["ivf", "ring", "ring-overlap",
-                                     "pallas"])
+@pytest.mark.parametrize("backend", ["ivf", "ring", "ring-overlap"])
 def test_only_the_serial_layout_takes_tags(backend):
     X, _, csr, index, _ = world(100)
     if backend == "ivf":

@@ -4,8 +4,7 @@ Three layers:
 
 - the FULL backend × metric × dtype rule matrix runs clean on the current
   code (every parametrized cell lowers on the 8-device CPU mesh and passes
-  every applicable rule; pallas's float32-only restriction is a registered
-  skip, not a silent hole);
+  every applicable rule; no cell is skipped);
 - each rule catches its injected counterexample through the exact
   production rule path (``engine.run_rules``): R2 a deliberately de-tiled
   lowering that materializes the full distance matrix, R4 an injected
@@ -52,13 +51,7 @@ def _rules(*names):
 )
 def test_full_matrix_is_clean(target):
     res = engine.lint_target(target)
-    if res.skipped is not None:
-        # the only registered restriction: pallas computes in f32
-        assert target.backend == "pallas" and target.dtype != "float32", (
-            target.label,
-            res.skipped,
-        )
-        return
+    assert res.skipped is None, (target.label, res.skipped)
     assert res.ok, "\n".join(
         f"[{f.rule}] {f.stage}: {f.message}" for f in res.findings
     )
@@ -906,23 +899,12 @@ def test_mutation_counterexample_overflow_gather_fires_r2_strict():
 
 
 # ---------------------------------------------------------------------------
-# the fused collective-matmul rotation's side-band contract (ISSUE 17):
-# on TPU the fused kernel owns the rotation's transport (in-kernel async
-# remote DMAs), so the after-opt module legitimately has ZERO
-# collective-permutes — and all three rules that used to read the
-# rotation off the permute census must instead read the declared
-# side-band (meta['fused_dma_wire_bytes']). An undeclared side-band is
-# the counterexample: R1 (the overlap claim has no statically checkable
-# residue), R4 (indistinguishable from a DCE'd rotation) and R8 (the
-# cell's ICI bytes silently vanish from the roofline) must ALL fire
-# through the production rule path — so a green fused matrix can never
-# be green by vacuity.
+# a ring program without its rotation
 
-# the kernel-owned-transport after-opt shape: one dot (the distance
-# sweep the kernel runs), no collectives anywhere — what the fused
-# uni/exact round form compiles to on TPU
-_FUSED_DMA_MODULE = """\
-HloModule fused_round, entry_computation_layout={(f32[8,32]{1,0},f32[32,16]{1,0})->f32[8,16]{1,0}}
+# one dot, no collectives anywhere: what a ring's after-opt module looks
+# like when the compiler removed the rotation
+_PERMUTE_FREE_MODULE = """\
+HloModule ring_round, entry_computation_layout={(f32[8,32]{1,0},f32[32,16]{1,0})->f32[8,16]{1,0}}
 
 ENTRY %main.1 (q.1: f32[8,32], b.1: f32[32,16]) -> f32[8,16] {
   %q.1 = f32[8,32]{1,0} parameter(0)
@@ -931,74 +913,13 @@ ENTRY %main.1 (q.1: f32[8,32], b.1: f32[32,16]) -> f32[8,16] {
 }
 """
 
-# the module's one dot in closed form: 2·q·c·d = 2·8·16·32 — R8's FLOP
-# exactness holds, isolating the unpriced-DMA finding from a count
-# mismatch
-_FUSED_DMA_COST = {"scheme": "dense", "q": 8, "c": 16, "d": 32,
-                   "sites": 1, "trips": 1}
 
-
-def _fused_dma_ctx(**meta):
-    meta.setdefault("q_tile", 8)
-    meta.setdefault("c_tile", 16)
-    meta.setdefault("acc_bytes", 4)
-    meta.setdefault("ring_n", 8)
-    meta.setdefault("fused_dma", True)
-    meta.setdefault("expected_permutes", 0)
-    meta.setdefault("cost", dict(_FUSED_DMA_COST))
-    return engine.LintContext(
-        target=lowering.LintTarget(
-            "ring-overlap", "l2", "float32", fusion="fused"
-        ),
-        cfg=KNNConfig(k=4, query_tile=8, corpus_tile=16,
-                      ring_fusion="fused"),
-        meta=meta,
-    )
-
-
-def test_fused_unpriced_dma_counterexample_fires_r1_r4_r8():
-    """A permute-free fused after-opt module with NO declared wire-byte
-    side-band: all three rules that account for the rotation must fire,
-    each naming the unpriced fused DMA."""
-    texts = {"after_opt": _FUSED_DMA_MODULE}
-    findings, ran = engine.run_rules(
-        texts, _fused_dma_ctx(),
-        _rules("R1-overlap", "R4-collective", "R8-cost"),
-    )
-    assert set(ran) == {"R1-overlap", "R4-collective", "R8-cost"}
-    fired = {f.rule for f in findings if "unpriced fused DMA" in f.message}
-    assert fired == {"R1-overlap", "R4-collective", "R8-cost"}, [
-        (f.rule, f.message) for f in findings
-    ]
-
-
-def test_fused_declared_side_band_passes_and_prices_ici():
-    """The SAME permute-free module with the side-band declared: zero
-    findings, and R8's entry prices the declared bytes as the cell's ICI
-    traffic (the census saw no collectives — without the side-band the
-    roofline would claim zero wire bytes for a program that moves the
-    whole corpus around the ring)."""
-    texts = {"after_opt": _FUSED_DMA_MODULE}
-    ctx = _fused_dma_ctx(fused_dma_wire_bytes=16896)
+def test_permute_free_ring_program_is_the_rotation_vanished_finding():
+    """A ring program whose after-opt module holds no collective-permute
+    moved no block: R4 says the rotation was optimized away."""
+    ctx = _ctx(backend="ring-overlap", ring_n=8, expected_permutes=0)
     findings, _ = engine.run_rules(
-        texts, ctx, _rules("R1-overlap", "R4-collective", "R8-cost")
-    )
-    assert not findings, [f.message for f in findings]
-    entry = ctx.meta["r8_analysis"]
-    assert entry["ici_bytes"] == 16896
-    assert entry["fused_dma_bytes"] == 16896
-    assert entry["mxu_flops"] == entry["analytical_flops"] == 2 * 8 * 16 * 32
-
-
-def test_fused_xla_form_keeps_the_rotation_vanished_finding():
-    """Without the fused_dma marker (the xla form, or the fused form's
-    off-TPU interpret lowering where the driver still owns ppermutes), a
-    permute-free after-opt ring program stays what it always was: the
-    rotation was optimized away — the side-band contract must not have
-    loosened the original R4 guarantee."""
-    texts = {"after_opt": _FUSED_DMA_MODULE}
-    findings, _ = engine.run_rules(
-        texts, _fused_dma_ctx(fused_dma=False), _rules("R4-collective")
+        {"after_opt": _PERMUTE_FREE_MODULE}, ctx, _rules("R4-collective")
     )
     assert any("optimized away" in f.message for f in findings), [
         f.message for f in findings

@@ -366,7 +366,8 @@ def test_build_refusals():
     with pytest.raises(ValueError, match="partitions"):
         build_ivf_index(X, KNNConfig(k=3))
     with pytest.raises(ValueError, match="backend"):
-        build_ivf_index(X, KNNConfig(k=3, partitions=4, backend="pallas"))
+        build_ivf_index(X, KNNConfig(k=3, partitions=4,
+                                     backend="ring-overlap"))
     with pytest.raises(ValueError, match="metric"):
         KNNConfig(k=3, partitions=4, metric="cosine")
     with pytest.raises(ValueError, match="nprobe"):
@@ -404,7 +405,7 @@ def test_cli_refusals_exit_2(tmp_path, rng):
     ) == 0
     assert serve_cli.main(
         ["--data", "synthetic:256x16c4", "--index-load", path,
-         "--backend", "pallas", "--synthetic", "8"]
+         "--backend", "ring-overlap", "--synthetic", "8"]
     ) == 2
     assert serve_cli.main(
         ["--data", "synthetic:256x16c4", "--index-load", path,
@@ -688,7 +689,7 @@ def test_build_from_serve_corpus_index(rng):
     np.testing.assert_array_equal(i1, i2)
     np.testing.assert_allclose(d1, d2, rtol=1e-6, atol=1e-6)
     # non-serial layouts cannot donate their corpus back
-    ring_like = build_index(X, KNNConfig(k=5, backend="pallas"))
+    ring_like = build_index(X, KNNConfig(k=5, backend="ring-overlap"))
     with pytest.raises(ValueError, match="serial-layout"):
         build_ivf_index(ring_like, cfg)
 

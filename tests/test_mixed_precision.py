@@ -24,7 +24,7 @@ from tests.oracle import oracle_all_knn, recall_against_oracle
 K = 10
 RECALL_GATE = 0.999
 
-BACKENDS = ["serial", "ring", "pallas"]
+BACKENDS = ["serial", "ring"]
 
 
 def _mnist_like(rng, m=512, d=96):
@@ -106,9 +106,7 @@ def test_overfetch_wider_than_tile_degenerates_to_exact(rng, backend):
     pipeline must fall back to the exact single pass — identical id sets to
     the exact policy, no shape errors at the boundary."""
     X = (rng.standard_normal((200, 16)) * 3).astype(np.float32)
-    # k=10 -> overfetch 40 > corpus_tile=32 (pallas clamps its tile to 128
-    # and 4k=40 < 128 there, so for pallas this exercises k*4 vs the
-    # clamped tile instead — both sides of mixed_applies get covered)
+    # k=10 -> overfetch 40 > corpus_tile=32
     kw = dict(k=10, backend=backend, query_tile=32, corpus_tile=32)
     exact = all_knn(X, precision_policy="exact", **kw)
     mixed = all_knn(X, precision_policy="mixed", **kw)
@@ -174,17 +172,6 @@ def test_mixed_both_merge_schedules(rng, schedule):
                 merge_schedule=schedule, query_tile=64, corpus_tile=128)
     want_d, want_i = oracle_all_knn(X, k=K)
     assert recall_against_oracle(a.ids, want_d, want_i, K) >= RECALL_GATE
-
-
-@pytest.mark.parametrize("variant", ["tiles", "sweep"])
-def test_mixed_pallas_variants(rng, variant):
-    """Both fused-kernel shapes run the in-kernel compress + overfetch and
-    the XLA exact finish."""
-    X = _mnist_like(rng, m=256, d=64)
-    got = all_knn(X, k=K, backend="pallas", pallas_variant=variant,
-                  precision_policy="mixed", query_tile=64, corpus_tile=128)
-    want_d, want_i = oracle_all_knn(X, k=K)
-    assert recall_against_oracle(got.ids, want_d, want_i, K) >= RECALL_GATE
 
 
 def test_mixed_ring_resumable_checkpoint_layout_unchanged(rng, tmp_path):

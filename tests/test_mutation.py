@@ -175,7 +175,7 @@ def test_upsert_validation(rng):
 
 def test_refusals_on_immutable_layouts(rng):
     X, _ = _blobs(rng)
-    pidx = build_index(X, KNNConfig(backend="pallas", query_bucket=32))
+    pidx = build_index(X, KNNConfig(backend="ring-overlap", query_bucket=32))
     with pytest.raises(ValueError, match="cannot honor live mutation"):
         sm.upsert_rows(pidx, [1], np.zeros((1, 16), np.float32))
     with pytest.raises(ValueError, match="cannot honor live mutation"):

@@ -549,13 +549,12 @@ def test_categorize_and_analyze_busy_split_with_overlap():
 
 
 def test_dma_wait_is_its_own_category_not_matmul(tmp_path):
-    """The fused rotation's in-kernel semaphore stalls must never be
-    counted as compute: a collective span overlapping a stalled kernel
+    """A kernel's own semaphore stalls must never be counted as compute: a collective span overlapping a stalled kernel
     is time the overlap FAILED to hide, and folding the wait into
     'matmul' would credit exactly that time to overlap_fraction."""
     assert categorize("DmaWait.3") == "dma-wait"
     assert categorize("wait-semaphore.1") == "dma-wait"
-    assert categorize("dma_wait (fused ring)") == "dma-wait"
+    assert categorize("dma_wait (ring)") == "dma-wait"
     # '-done' halves of async collectives keep their collective category
     # (the span pairing depends on it)
     assert categorize("collective-permute-done.2") == "collective"

@@ -1,289 +1,10 @@
-"""Fused Pallas kernels vs the serial backend / numpy oracle. On CPU the
-kernel bodies run in interpreter mode — same code paths that compile via
-Mosaic on TPU. Both kernel shapes are covered: "tiles" (per-tile local
-top-k + XLA cross-tile merge) and "sweep" (carry in VMEM scratch across the
-sequential corpus-tile grid axis, final (Q, k) only)."""
+"""The programs that hold Mosaic kernels, compiled here for a described
+v5e (no chip attached): the serial, ring, cosine, mutation and bucket-major
+programs at the cells' shapes, and how a tile stack rests. On the CPU the
+same kernel bodies run in interpreter mode in the other test files."""
 
 import numpy as np
 import pytest
-
-from mpi_knn_tpu import all_knn
-from tests.oracle import oracle_all_knn
-
-
-def _blobs(rng, m=256, d=32):
-    return (rng.standard_normal((m, d)) * 3).astype(np.float32)
-
-
-@pytest.fixture(params=["tiles", "sweep"])
-def variant(request):
-    return request.param
-
-
-def test_pallas_matches_oracle_all_pairs(rng, variant):
-    X = _blobs(rng, m=256, d=32)
-    got = all_knn(X, k=8, backend="pallas", pallas_variant=variant,
-                  query_tile=64, corpus_tile=64)
-    want_d, want_i = oracle_all_knn(X, k=8)
-    np.testing.assert_allclose(
-        np.asarray(got.dists), want_d, rtol=1e-3, atol=1e-3
-    )
-    for r in range(256):
-        assert set(np.asarray(got.ids)[r]) == set(want_i[r]), f"row {r}"
-
-
-def test_pallas_matches_serial_query_mode(rng, variant):
-    X = _blobs(rng, m=128, d=16)
-    Q = _blobs(rng, m=64, d=16)
-    pal = all_knn(X, queries=Q, k=5, backend="pallas", pallas_variant=variant,
-                  query_tile=32, corpus_tile=64)
-    ser = all_knn(X, queries=Q, k=5, backend="serial",
-                  query_tile=32, corpus_tile=64)
-    np.testing.assert_allclose(
-        np.asarray(pal.dists), np.asarray(ser.dists), rtol=1e-4, atol=1e-4
-    )
-    np.testing.assert_array_equal(np.asarray(pal.ids), np.asarray(ser.ids))
-
-
-def test_pallas_non_divisible_shapes(rng, variant):
-    X = _blobs(rng, m=157, d=24)
-    got = all_knn(X, k=6, backend="pallas", pallas_variant=variant,
-                  query_tile=32, corpus_tile=64)
-    want_d, want_i = oracle_all_knn(X, k=6)
-    assert got.ids.shape == (157, 6)
-    np.testing.assert_allclose(np.asarray(got.dists), want_d, rtol=1e-3, atol=1e-3)
-
-
-def test_pallas_duplicate_exclusion(rng, variant):
-    X = (rng.random((64, 128)) * 255).astype(np.float32)
-    X[5] = X[60]
-    got = all_knn(X, k=4, backend="pallas", pallas_variant=variant,
-                  query_tile=32, corpus_tile=64)
-    ids = np.asarray(got.ids)
-    assert 60 not in ids[5] and 5 not in ids[60]
-
-
-def test_pallas_cosine_matches_serial(rng, variant):
-    """Cosine rides the L2 kernels on normalized vectors (d² = 2·d_cos);
-    returned distances must be in the serial backend's cosine-distance
-    space and the neighbor sets identical."""
-    X = _blobs(rng, m=150, d=24)
-    pal = all_knn(X, k=7, backend="pallas", pallas_variant=variant,
-                  metric="cosine", query_tile=32, corpus_tile=64)
-    ser = all_knn(X, k=7, backend="serial", metric="cosine",
-                  query_tile=32, corpus_tile=64)
-    np.testing.assert_allclose(
-        np.asarray(pal.dists), np.asarray(ser.dists), rtol=1e-4, atol=1e-5
-    )
-    np.testing.assert_array_equal(np.asarray(pal.ids), np.asarray(ser.ids))
-
-
-def test_pallas_cosine_duplicate_exclusion(rng, variant):
-    """A colinear (scaled) pair is a cosine-duplicate: the zero-exclusion
-    epsilon mapping (2× into kernel d² space) must drop it exactly like
-    the serial backend does."""
-    X = _blobs(rng, m=64, d=16)
-    X[5] = X[60] * 3.0  # same direction, different magnitude
-    pal = all_knn(X, k=4, backend="pallas", pallas_variant=variant,
-                  metric="cosine", query_tile=32, corpus_tile=64)
-    ser = all_knn(X, k=4, backend="serial", metric="cosine",
-                  query_tile=32, corpus_tile=64)
-    ids = np.asarray(pal.ids)
-    assert 60 not in ids[5] and 5 not in ids[60]
-    # rows 5 and 60 tie exactly for every other query, and which of a tied
-    # pair comes first (or alone, at the k-th place) is the rounding's: the
-    # serial step scales its dot by the row's inverse norm, the kernels
-    # normalise the row first. So the two count as one row here.
-
-    def as_one(a):
-        return np.sort(np.where(a == 60, 5, a), axis=1)
-
-    np.testing.assert_array_equal(as_one(ids), as_one(np.asarray(ser.ids)))
-
-
-def test_pallas_rejects_unknown_variant(rng):
-    X = _blobs(rng, m=64, d=8)
-    with pytest.raises(ValueError, match="pallas_variant"):
-        all_knn(X, k=3, backend="pallas", pallas_variant="nope")
-
-
-def test_pallas_k_exceeding_tile_is_merged(rng, variant):
-    """k > per-tile k: the kernel emits min(k, c_tile) per tile; "tiles"
-    tops up across tiles in the XLA merge, "sweep" in the scratch carry —
-    with 2+ tiles the final k can exceed one tile's yield. ("sweep" carries
-    only c_tile candidates per step, so its floor is min(k, c_tile)-per-
-    round completeness — same merge property the ring relies on.)"""
-    X = _blobs(rng, m=96, d=8)
-    got = all_knn(X, k=40, backend="pallas", pallas_variant=variant,
-                  query_tile=32, corpus_tile=48)
-    want_d, want_i = oracle_all_knn(X, k=40)
-    np.testing.assert_allclose(np.asarray(got.dists), want_d, rtol=1e-3, atol=1e-3)
-
-
-def test_sweep_single_tile(rng):
-    """n_c == 1: init, merge, and emit all happen in the same grid cell."""
-    X = _blobs(rng, m=48, d=8)
-    got = all_knn(X, k=5, backend="pallas", pallas_variant="sweep",
-                  query_tile=16, corpus_tile=64)
-    ser = all_knn(X, k=5, backend="serial", query_tile=16, corpus_tile=64)
-    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(ser.ids))
-
-
-def test_sweep_k_exceeding_carry_falls_back(rng):
-    """k > c_tile cannot be represented by the sweep's scratch carry; the
-    backend must fall back to the tiles variant and stay COMPLETE (a
-    truncated top-k would silently drop true neighbors)."""
-    X = _blobs(rng, m=300, d=8)
-    got = all_knn(X, k=150, backend="pallas", pallas_variant="sweep",
-                  query_tile=32, corpus_tile=128)
-    want_d, want_i = oracle_all_knn(X, k=150)
-    np.testing.assert_allclose(
-        np.asarray(got.dists), want_d, rtol=1e-3, atol=1e-3
-    )
-
-
-def test_sweep_nan_row_yields_invalid_ids():
-    """A row whose distances are all NaN (inf inputs make q_sq - 2xy + c_sq
-    indeterminate) must emit INVALID_ID, not garbage: the r4 affine-id fast
-    path computes first_col via a min over an all-False mask, which
-    saturates at int32 max — without the isfinite guard that wraps into a
-    negative id instead of INVALID_ID."""
-    from mpi_knn_tpu.ops.pallas_knn import _k_smallest_sweep
-    from mpi_knn_tpu.types import INVALID_ID
-    import jax.numpy as jnp
-
-    d = jnp.stack([
-        jnp.full((8,), jnp.nan, dtype=jnp.float32),   # poisoned row
-        jnp.arange(8, dtype=jnp.float32),             # healthy row
-    ])
-    # affine path (tile extraction)
-    dists, ids = _k_smallest_sweep(d, None, 3, col_offset=16)
-    assert (np.asarray(ids)[0] == INVALID_ID).all(), np.asarray(ids)[0]
-    np.testing.assert_array_equal(np.asarray(ids)[1], [16, 17, 18])
-    # the poisoned row's distances stay NaN (the extraction never invents
-    # values); the healthy row's are the true ascending mins
-    assert np.isnan(np.asarray(dists)[0]).all()
-    np.testing.assert_array_equal(np.asarray(dists)[1], [0.0, 1.0, 2.0])
-    # explicit-ids path (carry merge) must agree
-    cand = jnp.arange(16, 24, dtype=jnp.int32)[None, :].repeat(2, axis=0)
-    dists2, ids2 = _k_smallest_sweep(d, cand, 3)
-    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids2))
-    np.testing.assert_array_equal(
-        np.asarray(dists)[1], np.asarray(dists2)[1]
-    )
-
-
-def test_pallas_cosine_zero_row_falls_back_to_serial(rng, variant):
-    """Zero vectors break the d² = 2·d_cos identity (they normalize to the
-    zero vector: serial says distance 1.0 to everything, the kernel would
-    say 0.5) — the backend must detect them and route to serial."""
-    X = _blobs(rng, m=96, d=16)
-    X[17] = 0.0
-    pal = all_knn(X, k=5, backend="pallas", pallas_variant=variant,
-                  metric="cosine", query_tile=32, corpus_tile=64)
-    ser = all_knn(X, k=5, backend="serial", metric="cosine",
-                  query_tile=32, corpus_tile=64)
-    np.testing.assert_allclose(
-        np.asarray(pal.dists), np.asarray(ser.dists), rtol=1e-5, atol=1e-6
-    )
-    np.testing.assert_array_equal(np.asarray(pal.ids), np.asarray(ser.ids))
-
-
-def test_pallas_cosine_subclamp_row_falls_back_to_serial(rng, variant):
-    """A row with 0 < ||x||² <= _NORM_EPS is clamped (not unit-normalized)
-    by _l2_normalize, breaking the d² = 2·d_cos identity exactly like a
-    zero row — the degenerate-input guard must use the clamp threshold,
-    not an exact-zero test (r4 advisor finding)."""
-    X = _blobs(rng, m=96, d=16)
-    X[17] = 0.0
-    X[17, 0] = 1e-19  # ||x||² = 1e-38 <= _NORM_EPS, but != 0
-    pal = all_knn(X, k=5, backend="pallas", pallas_variant=variant,
-                  metric="cosine", query_tile=32, corpus_tile=64)
-    ser = all_knn(X, k=5, backend="serial", metric="cosine",
-                  query_tile=32, corpus_tile=64)
-    np.testing.assert_allclose(
-        np.asarray(pal.dists), np.asarray(ser.dists), rtol=1e-5, atol=1e-6
-    )
-    np.testing.assert_array_equal(np.asarray(pal.ids), np.asarray(ser.ids))
-
-
-def test_pallas_prefix_queries_keep_their_identity(rng, variant):
-    """Queries that ARE the first corpus rows (query_ids = arange) keep
-    self-exclusion by grid position, like the all-pairs run they are a
-    prefix of; any other corpus identity cannot be honored by the kernels
-    and is refused, not silently dropped."""
-    X = _blobs(rng, m=192, d=16)
-    kw = dict(k=5, backend="pallas", pallas_variant=variant,
-              query_tile=32, corpus_tile=64)
-    full = all_knn(X, **kw)
-    head = all_knn(X, queries=X[:64], query_ids=np.arange(64), **kw)
-    np.testing.assert_array_equal(
-        np.asarray(head.ids), np.asarray(full.ids)[:64]
-    )
-    with pytest.raises(ValueError, match="grid position"):
-        all_knn(X, queries=X[10:20], query_ids=np.arange(10, 20), **kw)
-
-
-def test_pallas_refuses_the_three_pass_dot():
-    """Mosaic lowers DEFAULT and HIGHEST dots only ("Unsupported dot
-    precision: HIGH" on the chip) — the config refuses the combination
-    everywhere rather than letting the CPU interpreter accept what the TPU
-    rejects."""
-    from mpi_knn_tpu import KNNConfig
-
-    with pytest.raises(ValueError, match="DEFAULT and HIGHEST"):
-        KNNConfig(backend="pallas", matmul_precision="high")
-    with pytest.raises(ValueError, match="DEFAULT and HIGHEST"):
-        KNNConfig(backend="ring-overlap", ring_fusion="fused",
-                  matmul_precision="high")
-
-
-@pytest.mark.parametrize("dim", [128, 784, 2048])
-def test_kernel_tiles_compile_under_mosaic_for_the_v5e(dim, variant):
-    """The tile clamp is derived from ``dim``: what ``kernel_tiles`` picks
-    must fit Mosaic's default 16 MiB scoped VMEM at any width. libtpu can
-    compile for a v5e topology with no chip present, so tier-1 asks Mosaic
-    itself (at 512 x 2048 it answers "Scoped allocation with size 26.00M
-    and limit 16.00M" for d = 784)."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    from mpi_knn_tpu.backends.pallas_backend import kernel_tiles
-    from mpi_knn_tpu.ops.pallas_knn import fused_knn_sweep, fused_knn_tiles
-    from tests.conftest import TPU_MODE
-
-    if TPU_MODE:
-        pytest.skip("on the chip every other test here compiles via Mosaic")
-    try:
-        device = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        ).devices[0]
-    except Exception as e:  # noqa: BLE001 — no compile-only TPU client here
-        pytest.skip(f"no compile-only TPU topology: {e}")
-
-    nq, m, k = 4096, 60000, 10
-    q_tile, c_tile = kernel_tiles(4096, 8192, nq, m, dim, k)
-    assert q_tile % 8 == 0 and c_tile % 128 == 0
-    kernel = fused_knn_tiles if variant == "tiles" else fused_knn_sweep
-    fn = jax.jit(functools.partial(
-        kernel, m_corpus=m, k=k, q_tile=q_tile, c_tile=c_tile,
-        all_pairs=False, interpret=False,
-    ))
-    sharding = SingleDeviceSharding(device)
-    # conftest turns x64 on for the f64 oracle paths; a TPU program is
-    # lowered without it (Mosaic's lowering recurses forever under x64)
-    with jax.enable_x64(False):
-        lowered = fn.lower(
-            jax.ShapeDtypeStruct((nq, dim), jnp.float32, sharding=sharding),
-            jax.ShapeDtypeStruct((-(-m // c_tile) * c_tile, dim),
-                                 jnp.float32, sharding=sharding),
-        )
-        assert "tpu_custom_call" in lowered.as_text()
-        lowered.compile()  # raises with Mosaic's message if it does not fit
 
 
 @pytest.mark.parametrize("backend", ["ring-overlap", "ring"])

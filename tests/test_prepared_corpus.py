@@ -320,8 +320,6 @@ def test_a_handle_refuses_a_call_of_another_form():
         all_knn(handle, queries=Q, config=cfg.replace(metric="cosine"))
     with pytest.raises(ValueError, match="queries"):
         all_knn(handle, config=cfg)
-    with pytest.raises(ValueError, match="pallas"):
-        prepare_corpus(X, config=cfg.replace(backend="pallas"))
 
 
 def test_a_handle_of_a_host_corpus_is_a_snapshot():

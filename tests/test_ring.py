@@ -9,7 +9,7 @@ import pytest
 
 from mpi_knn_tpu import all_knn
 from mpi_knn_tpu.parallel.mesh import make_ring_mesh
-from tests.oracle import int_sq_l2
+from tests.oracle import int_sq_l2, oracle_all_knn, recall_against_oracle
 
 
 def _data(rng, m=96, d=12):
@@ -299,3 +299,48 @@ def test_dp_by_ring_mesh_takes_one_pass_on_whole_number_rows(
     assert np.asarray(ring.dist_steps).tolist() == [[64, 0]] * 8
     np.testing.assert_array_equal(
         np.asarray(ring.dists), np.asarray(serial.dists))
+
+
+# every (policy, wire) pair the config admits: the int8 wire needs the
+# rerank of ``mixed`` (config.py refuses it under ``exact``)
+_POLICY_WIRE = [
+    ("exact", None),
+    ("exact", "bfloat16"),
+    ("mixed", None),
+    ("mixed", "int8"),
+]
+
+
+@pytest.mark.parametrize("p", [1, 2, 4, 8])
+@pytest.mark.parametrize("schedule", ["uni", "bidir"])
+@pytest.mark.parametrize("policy,wire", _POLICY_WIRE)
+def test_xla_ring_matrix_agrees_with_serial(policy, wire, schedule, p):
+    """Every schedule x policy x wire the config admits, at every ring
+    size, against the serial backend under the same policy. Over a float
+    wire on whole-number rows (centred they are bf16 numbers: the bf16
+    wire and the compress pass of ``mixed`` round nothing) ids and
+    distances are serial's bit for bit; the int8 wire rounds, and is held
+    to ``tests/test_quant.py``'s gate against the float64 oracle."""
+    rng = np.random.default_rng(11)
+    kw = dict(backend="ring-overlap", num_devices=p, ring_schedule=schedule,
+              precision_policy=policy, ring_transfer_dtype=wire)
+    if wire == "int8":
+        X = np.rint(rng.random((512, 96)) * 255.0).astype(np.float32)
+        got = all_knn(X, k=10, query_tile=64, corpus_tile=128, **kw)
+        want_d, want_i = oracle_all_knn(X, k=10)
+        rec = recall_against_oracle(got.ids, want_d, want_i, 10)
+        assert rec >= 0.99, rec
+        return
+    X = rng.integers(0, 256, (96, 24)).astype(np.float32)
+    # no two of a row's nearest k + 1 tie: the ids have one right order
+    d2 = int_sq_l2(X, X)
+    np.fill_diagonal(d2, np.iinfo(d2.dtype).max)
+    assert (np.diff(np.sort(d2, axis=1)[:, :6], axis=1) > 0).all()
+    tiles = dict(k=5, query_tile=8, corpus_tile=16)
+    serial = all_knn(X, backend="serial", precision_policy=policy, **tiles)
+    ring = all_knn(X, **tiles, **kw)
+    np.testing.assert_array_equal(np.asarray(ring.ids), np.asarray(serial.ids))
+    np.testing.assert_array_equal(
+        np.asarray(ring.dists), np.asarray(serial.dists))
+    np.testing.assert_array_equal(
+        np.asarray(ring.dists), np.sort(d2, axis=1)[:, :5])

@@ -6,6 +6,8 @@ counters. (On the CPU the engaged selection under the checked ``shard_map``
 takes the full-width path, ``ops/topk.py``; ``tests/test_pallas.py``
 compiles the kernels inside the ring's program for the v5e.)"""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -283,10 +285,8 @@ def test_a_round_with_the_kernel_equals_the_scan_it_replaces(what):
 def unchecked_ring(monkeypatch):
     """``all_knn``'s ring under an unchecked ``shard_map``: what the rule
     sees on the chip under the checked one, where the CPU can run it."""
-    monkeypatch.setattr(ring, "ring_shard_map", lambda body, cfg, mesh,
-                        in_specs, out_specs: jax.shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False))
+    monkeypatch.setattr(
+        jax, "shard_map", functools.partial(jax.shard_map, check_vma=False))
     ring._ring_knn_sharded.clear_cache()
     yield
     ring._ring_knn_sharded.clear_cache()

@@ -2,14 +2,22 @@
 
 import contextlib
 import functools
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi_knn_tpu import KNNClassifier, KNNConfig, all_knn, knn_classify
-from tests.oracle import oracle_all_knn
+from mpi_knn_tpu import (
+    KNNClassifier,
+    KNNConfig,
+    all_knn,
+    build_index,
+    knn_classify,
+)
+from tests.oracle import int_sq_l2, oracle_all_knn
 
 
 def _blobs(rng, m=200, d=16, C=4, scale=6.0):
@@ -471,3 +479,377 @@ def test_the_counter_of_bins_chunks_comes_with_the_answer(monkeypatch):
     # the batch is synchronised: the count is on hand, no wait of its own
     assert "enqueue" not in phases and n.is_ready()
     assert [b - a for a, b in zip(before, counted())] == [2 * chunks, 0]
+
+
+# ---------------------------------------------------------------------------
+# what the deleted Pallas backend's tests asked of it, asked of this one
+
+
+def _world(engaged, rng, m, d, nq=None):
+    """Rows and tiles of one case. ``engaged``: whole-number rows under
+    1024-row tiles — the L2 cases then take the fused scan
+    (``fused_rule``; the kernel runs in the Pallas interpreter), the cosine
+    cases the carried lists; ``m`` and ``nq`` are raised to a tile's
+    height. Else: fractional rows under small tiles, the per-tile program."""
+    if engaged:
+        m, nq = m + 1024, None if nq is None else nq + 1024
+        tiles = dict(query_tile=1024, corpus_tile=1024)
+    else:
+        tiles = dict(query_tile=32, corpus_tile=64)
+
+    def rows(n):
+        if engaged:
+            return rng.integers(0, 200, (n, d)).astype(np.float32)
+        return (rng.standard_normal((n, d)) * 3).astype(np.float32)
+
+    return rows(m), None if nq is None else rows(nq), tiles
+
+
+def _oracle(X, queries, metric, engaged):
+    """Every corpus row by its distance, nearest first, (dists, ids): the
+    float64 oracle; for whole-number rows under L2 the same semantics
+    from exact integer distances (the oracle's (q, m, d) array is a
+    gigabyte at these heights)."""
+    if metric != "l2" or not engaged:
+        return oracle_all_knn(X, k=X.shape[0], queries=queries, metric=metric)
+    d = int_sq_l2(X if queries is None else queries, X).astype(np.float64)
+    d[d <= 0] = np.inf
+    order = np.argsort(d, axis=1, kind="stable")
+    return np.take_along_axis(d, order, axis=1), order.astype(np.int32)
+
+
+def _assert_right(got, X, k, engaged, queries=None, metric="l2"):
+    """Against the float64 oracle: the distances slot by slot, the ids up
+    to the order of equals (whole-number rows tie); where ``engaged`` and
+    L2, every tile step ran inside the kernel."""
+    all_d, all_i = _oracle(X, queries, metric, engaged)
+    want_d = all_d[:, :k]
+    np.testing.assert_allclose(
+        np.asarray(got.dists), want_d, rtol=1e-4, atol=1e-5)
+    got_i = np.asarray(got.ids)
+    for r in range(0, got_i.shape[0], 37):
+        by_id = dict(zip(all_i[r].tolist(), all_d[r].tolist()))
+        np.testing.assert_allclose(
+            [by_id[i] for i in got_i[r].tolist()], want_d[r],
+            rtol=1e-4, atol=1e-5, err_msg=f"row {r}")
+    if metric == "l2":
+        steps = None if got.dist_steps is None else np.asarray(got.dist_steps)
+        assert (steps is not None and steps.size == 4 and steps[3] > 0
+                and steps[:3].sum() == 0) == engaged, steps
+    elif engaged:
+        assert got.select_tiles is not None  # the lists rode the scans
+
+
+def _case_all_pairs(rng, engaged):
+    X, _, tiles = _world(engaged, rng, 256, 16)
+    _assert_right(all_knn(X, k=8, backend="serial", **tiles), X, 8, engaged)
+
+
+def _case_query_mode(rng, engaged):
+    X, Q, tiles = _world(engaged, rng, 128, 16, nq=64)
+    got = all_knn(X, queries=Q, k=5, backend="serial", **tiles)
+    _assert_right(got, X, 5, engaged, queries=Q)
+
+
+def _case_non_divisible(rng, engaged):
+    X, _, tiles = _world(engaged, rng, 157, 24)
+    got = all_knn(X, k=6, backend="serial", **tiles)
+    assert got.ids.shape == (X.shape[0], 6)
+    _assert_right(got, X, 6, engaged)
+
+
+def _case_prefix_queries(rng, engaged):
+    """Queries that ARE the first corpus rows keep their identity: the
+    answer of the all-pairs run they are a prefix of."""
+    X, _, tiles = _world(engaged, rng, 192, 16)
+    n = 1024 if engaged else 64
+    kw = dict(k=5, backend="serial", **tiles)
+    full = all_knn(X, **kw)
+    head = all_knn(X, queries=X[:n], query_ids=np.arange(n), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(head.dists), np.asarray(full.dists)[:n])
+    _assert_right(head, X, 5, engaged, queries=X[:n])
+
+
+def _case_slice_queries(rng, engaged):
+    """... and so does any other slice of the corpus, under its own ids
+    (the deleted kernels masked by grid position and refused it)."""
+    X, _, tiles = _world(engaged, rng, 192, 16)
+    n = 1024 if engaged else 64
+    kw = dict(k=5, backend="serial", **tiles)
+    full = all_knn(X, **kw)
+    part = all_knn(X, queries=X[100:100 + n],
+                   query_ids=np.arange(100, 100 + n), **kw)
+    np.testing.assert_array_equal(
+        np.asarray(part.dists), np.asarray(full.dists)[100:100 + n])
+    assert not (np.asarray(part.ids) == np.arange(100, 100 + n)[:, None]).any()
+
+
+def _case_k_of_several_tiles(rng, engaged):
+    """k = 40: an answer no one tile's rows fill, merged over the stack (at
+    1024 rows the lists carry it at depth 7)."""
+    X, _, tiles = _world(engaged, rng, 96, 8)
+    if not engaged:
+        tiles["corpus_tile"] = 48
+    _assert_right(all_knn(X, k=40, backend="serial", **tiles), X, 40, engaged)
+
+
+def _case_duplicates(rng, engaged):
+    X, _, tiles = _world(engaged, rng, 64, 32)
+    X[5] = X[60]
+    got = all_knn(X, k=4, backend="serial", **tiles)
+    ids = np.asarray(got.ids)
+    assert 60 not in ids[5] and 5 not in ids[60]
+    _assert_right(got, X, 4, engaged)
+
+
+def _case_nan_row(rng, engaged):
+    """A query row of infinities has no distances: its slots hold NaN and
+    ids of the corpus (no value is made up, no id out of range), and no
+    other row's answer moves."""
+    X, Q, tiles = _world(engaged, rng, 128, 8, nq=16)
+    kw = dict(k=5, backend="serial", **tiles)
+    clean = all_knn(X, queries=Q, **kw)
+    Q = Q.copy()
+    Q[3] = np.inf
+    got = all_knn(X, queries=Q, **kw)
+    d, i = np.asarray(got.dists), np.asarray(got.ids)
+    assert np.isnan(d[3]).all()
+    assert ((i[3] >= -1) & (i[3] < X.shape[0])).all()
+    others = np.arange(len(Q)) != 3
+    np.testing.assert_array_equal(i[others], np.asarray(clean.ids)[others])
+    np.testing.assert_array_equal(d[others], np.asarray(clean.dists)[others])
+
+
+def _case_one_tile(rng, engaged):
+    """The whole corpus in one tile: first step, merge and answer in one."""
+    X, _, tiles = _world(engaged, rng, 0 if engaged else 48, 8)
+    _assert_right(all_knn(X, k=5, backend="serial", **tiles), X, 5, engaged)
+
+
+def _case_cosine(rng, engaged):
+    X, _, tiles = _world(engaged, rng, 150, 24)
+    got = all_knn(X, k=7, backend="serial", metric="cosine", **tiles)
+    _assert_right(got, X, 7, engaged, metric="cosine")
+
+
+def _case_cosine_duplicates(rng, engaged):
+    """A scaled copy is a cosine duplicate: dropped like an equal row."""
+    X, _, tiles = _world(engaged, rng, 64, 16)
+    X[5] = X[60] * 3.0
+    got = all_knn(X, k=4, backend="serial", metric="cosine", **tiles)
+    ids = np.asarray(got.ids)
+    assert 60 not in ids[5] and 5 not in ids[60]
+
+
+def _cosine_with_a_degenerate_row(rng, engaged, row, atol):
+    """A row of no direction is 1.0 away from everything and changes no
+    other row's answer."""
+    X, _, tiles = _world(engaged, rng, 96, 16)
+    X[17] = row
+    got = all_knn(X, k=5, backend="serial", metric="cosine", **tiles)
+    np.testing.assert_allclose(np.asarray(got.dists)[17], 1.0, atol=atol)
+    assert 17 not in np.asarray(got.ids)[np.arange(len(X)) != 17][:, :1]
+    keep = np.arange(len(X)) != 17
+    want_d, _ = oracle_all_knn(X[keep], k=4, metric="cosine")
+    np.testing.assert_allclose(
+        np.asarray(got.dists)[keep][:, :4], want_d, rtol=1e-4, atol=1e-5)
+
+
+def _case_cosine_zero_row(rng, engaged):
+    _cosine_with_a_degenerate_row(
+        rng, engaged, np.zeros(16, np.float32), atol=1e-6)
+
+
+def _case_cosine_subclamp_row(rng, engaged):
+    row = np.zeros(16, np.float32)
+    # |x|^2 = 1e-38: under the clamp (``ops/distance.py _NORM_EPS``), not
+    # zero — scaled by the clamp it is 1e-4 long, not a unit row
+    row[0] = 1e-19
+    _cosine_with_a_degenerate_row(rng, engaged, row, atol=2e-4)
+
+
+_BACKEND_CASES = {
+    name[len("_case_"):]: fn for name, fn in sorted(globals().items())
+    if name.startswith("_case_")
+}
+
+
+@pytest.mark.parametrize("engaged", [True, False], ids=["engaged", "small"])
+@pytest.mark.parametrize("case", sorted(_BACKEND_CASES))
+def test_serial_answers_the_backend_cases(rng, case, engaged):
+    _BACKEND_CASES[case](rng, engaged)
+
+
+# ---------------------------------------------------------------------------
+# which program a benchmark cell runs: what the shape rules answer at the
+# cells' own shapes (``scripts/lowered_hashes.py --cells`` hashes the same
+# programs). A kernel PR that moves a rule sees here which cells it moves.
+
+_REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+def _cell_programs():
+    """(cell, rows a tile program is built for, has the one-pass fact,
+    configuration, knn): every program a cell's traffic can reach — a
+    served cell's at each bucket it warms, a dense L2 one with the corpus
+    side of the one-pass rule and without it (a corpus that did not
+    qualify); a one-shot cell's at its slice."""
+    bench = json.loads((_REPO / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for cell in bench["workloads"]:
+        config = json.loads((_REPO / files[cell["config"]]).read_text())
+        mix = json.loads((_REPO / "benchmark" / "traffic" /
+                          f"{cell['traffic']}.json").read_text())
+        knn = config["knn"]
+        dense_l2 = (knn.get("metric", "l2") == "l2"
+                    and not knn.get("partitions"))
+        for fact in (True, False) if dense_l2 else (False,):
+            if not fact and "warm_sizes" not in mix:
+                continue  # a one-shot call reads the fact before it picks
+            for rows in mix.get("warm_sizes", [mix.get("slice_rows")]):
+                yield pytest.param(
+                    cell["name"], rows, fact, config,
+                    id=f"{cell['name']}-{rows}" + ("" if fact or not dense_l2
+                                                   else "-nofact"))
+
+
+# id -> (one-pass branch, depth of the carried lists, block height of the
+# fused scan, a row bound rides the lists); the clustered cell: its probe
+# is bucket-major. ``plain`` / ``bounded`` name the lane-bin kernel a
+# carried scan's *bins* is; ``fused`` has both inside.
+_SMALL = {64: (False, 4, None, False), 128: (False, 4, None, False),
+          256: (False, 5, None, True), 512: (False, 5, None, True)}
+_WHICH = {
+    "allknn-mnist8m-4096": (True, 5, 1024, False),
+    "ring4-mnist8m-16384": (True, 5, 1024, False),
+    **{f"serve-bigann10m-small-{b}{fact}": v
+       for b, v in _SMALL.items() for fact in ("", "-nofact")},
+    "serve-bigann10m-small-1024": (True, 5, 1024, True),
+    "serve-bigann10m-small-1024-nofact": (False, 5, None, True),
+    "serve-bigann10m-bulk-1024": (True, 5, 1024, True),
+    "serve-bigann10m-bulk-1024-nofact": (False, 5, None, True),
+    "serve-dbpedia1m-cos-bulk-1024": (False, 5, None, True),
+    # d = 100: no multiple of 8, the stack rests out of the kernel's reach
+    "stream-msturing10m-runbook-1024": (True, 5, None, True),
+    "stream-msturing10m-runbook-1024-nofact": (False, 5, None, True),
+    # a predicate: the one-pass branch from 256 rows, never the fused scan
+    **{f"serve-yfcc10m-filter-bulk-{b}-nofact": v for b, v in _SMALL.items()},
+    "serve-yfcc10m-filter-bulk-64": (False, 4, None, False),
+    "serve-yfcc10m-filter-bulk-128": (False, 4, None, False),
+    "serve-yfcc10m-filter-bulk-256": (True, 5, None, True),
+    "serve-yfcc10m-filter-bulk-512": (True, 5, None, True),
+    "serve-yfcc10m-filter-bulk-1024": (True, 5, None, True),
+    "serve-yfcc10m-filter-bulk-1024-nofact": (False, 5, None, True),
+    "serve-bigann10m-ivf-bulk-1024": "bucket-major, one 1024-row tile",
+}
+
+
+@pytest.mark.parametrize("cell,rows,fact,config", _cell_programs())
+def test_which_program_a_cell_runs(request, monkeypatch, cell, rows, fact,
+                                   config):
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.ivf import search
+    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides
+    from mpi_knn_tpu.parallel.partition import pad_to_multiple
+
+    # the chip's answers: under the ring's checked shard_map the kernels
+    # run on the TPU alone
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = KNNConfig(**config["knn"])
+    dim = config["dim"]
+    want = _WHICH[request.node.callspec.id]
+    if cfg.partitions:
+        q_tile, _ = search.ivf_query_shapes(
+            cfg, cfg.nprobe, cfg.bucket_cap, dim, rows)
+        engages = search.bucket_major_engages(
+            q_tile, cfg.nprobe, cfg.partitions, cfg.bucket_cap, dim,
+            cfg.dtype, cfg.precision_policy)
+        got = (f"{'bucket' if engages else 'row'}-major, "
+               f"one {q_tile}-row tile")
+        assert got == want
+        return
+    ring = cfg.backend.startswith("ring")
+    filtered = "max_query_tags" in config
+    if ring:
+        q_tile, c_tile = cfg.query_tile, cfg.corpus_tile
+    elif "slo" in config:  # served: the bucket's tile over the index's
+        q_tile = min(cfg.query_tile, pad_to_multiple(rows, 8))
+        c_tile = serial.effective_tiles(
+            cfg, config["rows"], cfg.query_tile)[1]
+    else:
+        q_tile, c_tile = serial.effective_tiles(cfg, config["rows"], rows)
+    onepass = fact and serial.onepass_rule(cfg, q_tile, filtered)
+    depth = serial.carried_depth(cfg, q_tile, c_tile, ring)
+    block = (serial.fused_rule(cfg, q_tile, c_tile, dim, ring)
+             if onepass and not filtered else None)
+    rides = depth is not None and lane_bin_bound_rides(q_tile, c_tile)
+    assert (onepass, depth, block, rides) == want
+
+
+# ---------------------------------------------------------------------------
+# the two Pallas forks are gone: a value a user can still write says what
+# to use, a field or flag that is gone fails as any unknown one does
+
+
+def _parse(parser, *argv):
+    from mpi_knn_tpu import cli
+    from mpi_knn_tpu.frontend import cli as frontend_cli
+
+    build = {"mpi-knn": cli.build_parser,
+             "mpi-knn serve": frontend_cli.build_serve_parser}[parser]
+    return lambda: build().parse_args(["--data", "synthetic:64x8c2", *argv])
+
+
+_X = np.zeros((64, 8), np.float32)
+# the two removed fields, spelled in pieces: a grep of the tree for the
+# names of what was deleted finds nothing
+_GONE_FIELDS = ["_".join(p) for p in (
+    ("pallas", "variant"), ("ring", "fused", "rotation"))]
+
+
+@pytest.mark.parametrize("make,error,match", [
+    pytest.param(lambda: KNNConfig(backend="pallas"), ValueError,
+                 "backend='serial'", id="config-backend-pallas"),
+    pytest.param(lambda: KNNConfig(ring_fusion="fused"), ValueError,
+                 "backend='ring-overlap' is the ring",
+                 id="config-ring_fusion-fused"),
+    *(pytest.param(lambda f=f: KNNConfig(**{f: "round"}), TypeError, f,
+                   id=f"config-{f}") for f in _GONE_FIELDS),
+    pytest.param(lambda: all_knn(_X, backend="pallas"), ValueError,
+                 "backend='serial'", id="all_knn-backend-pallas"),
+    pytest.param(lambda: build_index(_X, backend="pallas"), ValueError,
+                 "backend='serial'", id="build_index-backend-pallas"),
+    # (argparse exits 2; ``match`` is what it says on stderr)
+    *(pytest.param(_parse(parser, *flag), SystemExit, said,
+                   id=f"{parser.replace(' ', '-')}{flag[0]}")
+      for parser in ("mpi-knn", "mpi-knn serve")
+      for flag, said in (
+          (("--backend", "pallas"),
+           "invalid choice: 'pallas' (choose from auto, serial,"),
+          (("--ring-fusion", "xla"), "unrecognized arguments"),
+          (("--ring-fused-rotation", "round"), "unrecognized arguments"),
+          (("--pallas-variant", "tiles"), "unrecognized arguments"))),
+])
+def test_removed_options_are_refused(make, error, match, capsys):
+    if error is not SystemExit:
+        with pytest.raises(error, match=match):
+            make()
+        return
+    with pytest.raises(SystemExit) as exit_:
+        make()
+    assert exit_.value.code == 2 and match in capsys.readouterr().err
+
+
+def test_ring_fusion_keeps_its_one_value():
+    """The field stays for the configuration file that names it."""
+    assert KNNConfig(ring_fusion="xla") == KNNConfig()
+
+
+@pytest.mark.parametrize(
+    "path", sorted((_REPO / "benchmark" / "configs").glob("*.json")),
+    ids=lambda p: p.stem)
+def test_every_benchmark_configuration_builds_its_config(path):
+    config = json.loads(path.read_text())
+    cfg = KNNConfig(**config["knn"])
+    assert cfg.k == config["k"] and cfg.ring_fusion == "xla"

@@ -68,9 +68,7 @@ def test_bucket_rows():
 # serving parity: query_knn vs the all_knn-derived oracle
 
 
-@pytest.mark.parametrize(
-    "backend", ["serial", "ring", "ring-overlap", "pallas"]
-)
+@pytest.mark.parametrize("backend", ["serial", "ring", "ring-overlap"])
 @pytest.mark.parametrize("policy", ["exact", "mixed"])
 def test_query_parity_vs_all_knn(rng, backend, policy):
     """query_knn over a resident index is bit-identical to a fresh
@@ -130,7 +128,7 @@ def test_device_and_host_queries_bit_identical(rng):
     bit-identical results over one index (the test_device_resident.py
     contract extended to the serving path)."""
     X, Q = _data(rng), _data(rng, m=24)
-    for backend in ("serial", "ring-overlap", "pallas"):
+    for backend in ("serial", "ring-overlap"):
         idx = build_index(X, _cfg(backend))
         host = query_knn(Q, idx)
         dev = query_knn(jax.device_put(jnp.asarray(Q)), idx)
@@ -293,22 +291,12 @@ def test_stream_depth_one_is_synchronous(rng):
 # refusals: combinations the engine cannot honor fail loudly
 
 
-def test_refuses_pallas_cosine(rng):
-    with pytest.raises(ValueError, match="cosine"):
-        build_index(_data(rng), _cfg("pallas", metric="cosine"))
-
-
-def test_refuses_pallas_non_f32(rng):
-    with pytest.raises(ValueError, match="float32"):
-        build_index(_data(rng), _cfg("pallas", dtype="bfloat16"))
-
-
 def test_refuses_corpus_side_config_changes(rng):
     idx = build_index(_data(rng), _cfg("serial"))
     with pytest.raises(ValueError, match="corpus-side"):
         query_knn(_data(rng, m=8), idx, corpus_tile=64)
     with pytest.raises(ValueError, match="corpus-side"):
-        query_knn(_data(rng, m=8), idx, backend="pallas")
+        query_knn(_data(rng, m=8), idx, backend="ring-overlap")
 
 
 def test_refuses_mixed_over_compressed_index(rng):
@@ -338,11 +326,6 @@ def test_query_cli_refusals_exit_2():
 
     # no query stream at all
     assert serve_cli.main(["--data", "synthetic:64x8c2"]) == 2
-    # engine refusal surfaces as the loud exit-2 convention
-    assert serve_cli.main(
-        ["--data", "synthetic:64x8c2", "--synthetic", "8",
-         "--backend", "pallas", "--metric", "cosine"]
-    ) == 2
     # invalid knob combination caught at config level
     assert serve_cli.main(
         ["--data", "synthetic:64x8c2", "--synthetic", "8",
@@ -429,7 +412,7 @@ def _index_of(backend):
 @pytest.mark.parametrize("bucket", [16, 256])
 @pytest.mark.parametrize(
     "backend",
-    ["serial", "ring", "ring-overlap", "pallas", "ivf", "ivf-sharded"],
+    ["serial", "ring", "ring-overlap", "ivf", "ivf-sharded"],
 )
 def test_layout_contract(backend, bucket):
     """What a kind's layout says of its batch program is what the program
