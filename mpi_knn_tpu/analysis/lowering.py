@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpi_knn_tpu.config import BACKENDS, METRICS, KNNConfig
+from mpi_knn_tpu.config import BACKENDS, KNNConfig
 
 STAGES = ("before_opt", "after_opt")
 LINT_DTYPES = ("float32", "bfloat16", "float64")
@@ -44,6 +44,11 @@ LINT_QUANTS = ("xfer-int8", "int8", "int4")
 # (l2/float32 only — the IVF path's own contract) but share the CLI
 # filter namespace
 DENSE_LINT_BACKENDS = tuple(b for b in BACKENDS if b != "auto")
+# the metrics every dense backend, dtype, policy and schedule takes: the
+# distances. The inner product ("ip") reaches the serial backend at
+# float32 / exact alone (config.py refuses the rest), so its cells are
+# appended explicitly, as the clustered ones are
+DISTANCE_METRICS = ("l2", "cosine")
 LINT_BACKENDS = DENSE_LINT_BACKENDS + ("ivf", "ivf-sharded")
 
 # Small but structurally faithful: 8 query tiles, 8 corpus tiles, an 8-way
@@ -124,14 +129,20 @@ def default_targets() -> list[LintTarget]:
     return [
         LintTarget(b, m, d)
         for b in DENSE_LINT_BACKENDS
-        for m in METRICS
+        for m in DISTANCE_METRICS
         for d in LINT_DTYPES
+    ] + [
+        # the inner product: the one-shot program and the serve-cache form
+        # of the one layout that takes it (no norm plane among the
+        # operands; R5 certifies the scratch donation without it)
+        LintTarget("serial", "ip", "float32"),
+        LintTarget("serial", "ip", "float32", serve=True),
     ] + [
         # the mixed compress-and-rerank policy: float32 only (config.py
         # validation), every backend × metric
         LintTarget(b, m, "float32", "mixed")
         for b in DENSE_LINT_BACKENDS
-        for m in METRICS
+        for m in DISTANCE_METRICS
     ] + [
         # the bidirectional ring schedule: ring backends only, float32, both
         # policies — R4 certifies the counter-directed permute accounting
@@ -139,7 +150,7 @@ def default_targets() -> list[LintTarget]:
         # on the two-traveler step body
         LintTarget(b, m, "float32", p, "bidir")
         for b in RING_BACKENDS
-        for m in METRICS
+        for m in DISTANCE_METRICS
         for p in ("exact", "mixed")
     ] + [
         # the serving engine's per-batch programs (mpi_knn_tpu.serve):

@@ -26,6 +26,13 @@ def resolve_backend(cfg: KNNConfig, mesh=None) -> str:
     if cfg.backend != "auto":
         return cfg.backend
     n = cfg.num_devices or (len(mesh.devices.flat) if mesh is not None else len(jax.devices()))
+    if n > 1 and cfg.metric == "ip":
+        # what KNNConfig refuses of a named ring, for the ring "auto" means
+        raise ValueError(
+            f"metric='ip' runs on one device: backend='auto' over {n} "
+            "devices is the corpus ring, whose rounds have no "
+            "inner-product form yet — pass backend='serial'"
+        )
     return "ring-overlap" if n > 1 else "serial"
 
 

@@ -34,6 +34,7 @@ import numpy as np
 from mpi_knn_tpu.config import KNNConfig
 from mpi_knn_tpu.ops.distance import (
     COSINE_SCOPE,
+    IP_SCOPE,
     bf16_exact,
     center_corpus,
     cosine_inv_norms,
@@ -100,19 +101,27 @@ def onepass_rule(cfg: KNNConfig, q_rows: int, filtered: bool = False) -> bool:
         FILTER_ONEPASS_MIN_ROWS if filtered else ONEPASS_MIN_ROWS)
 
 
+# where a program without the one-pass branch counts its tile steps, by
+# metric: the column of ``obs.metrics.DIST_PATHS``
+_STATIC_PATH = {"l2": 1, "cosine": 2, "ip": 4}
+
+
 def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False):
     """A dispatch's tile steps by the path of their distance dot, int32
     ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``,
-    from one whose one-pass steps run inside the fused kernel
+    from an inner-product program ``[0, 0, 0, 0, ip]``, from one whose
+    one-pass steps run inside the fused kernel
     (:func:`fused_rule`) ``[0, multi-pass, 0, fused]``: what
     ``KNNResult.dist_steps`` and the counter ``knn_dist_tile_steps_total``
     hold. ``took`` is one verdict a query-tile merge (a bool vector, made
     inside a program that carries the branch) or, for a program without
     the branch, their number; each merge meets ``steps`` corpus tiles."""
     if isinstance(took, int):
-        n = took * steps
-        return np.array([0, n] if metric == "l2" else [0, 0, n],
-                        dtype=np.int32)
+        if metric not in _STATIC_PATH:
+            raise ValueError(f"unknown metric {metric!r}")
+        counts = np.zeros(_STATIC_PATH[metric] + 1, dtype=np.int32)
+        counts[-1] = took * steps
+        return counts
     one = jnp.sum(took, dtype=jnp.int32)
     multi = took.size - one
     if fused:
@@ -237,11 +246,18 @@ def masked_dist_tile(
     side prepared — the rows' inverse norms (:func:`stack_norms`) — and
     ``q_x`` then holds unit rows (:func:`serve_chunk` makes them once a
     query tile): the step scales its dot and normalises nothing. None (the
-    ring's rounds) normalises both operands here, every step."""
+    ring's rounds) normalises both operands here, every step.
+
+    Inner product (scope ``knn.dist_ip``): the step is the dot, negated,
+    and the masks; ``q_sq`` and ``blk_sq`` are None (neither side has a
+    norm in a score), nothing is clamped and there is no zero test
+    (``KNNConfig`` reads ``exclude_zero`` False under ``ip``)."""
     if onepass is not None:
         scope = jax.named_scope(ONEPASS_SCOPE if onepass else MULTIPASS_SCOPE)
     elif cfg.metric == "cosine":
         scope = jax.named_scope(COSINE_SCOPE)
+    elif cfg.metric == "ip":
+        scope = jax.named_scope(IP_SCOPE)
     else:
         scope = contextlib.nullcontext()
     with scope:
@@ -266,9 +282,14 @@ def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass,
         )
     if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
         pair_scale = q_sq[:, None] + blk_sq[None, :]
-    else:
+    elif cfg.metric in ("l2", "cosine"):
         # cosine distances live in [0, 2]; constant scale for the zero test
         pair_scale = jnp.asarray(2.0, dtype=d.dtype)
+    elif cfg.metric == "ip":
+        # an unbounded score has no scale, and no zero test to hand one to
+        pair_scale = None
+    else:
+        raise ValueError(f"unknown metric {cfg.metric!r}")
     return mask_tile(
         d,
         blk_ids,
@@ -487,18 +508,33 @@ def knn_chunk_update(
     )
 
 
-def stack_norms(tiles: jax.Array, metric: str) -> jax.Array:
+def stack_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
     """The (T, c_tile) per-row state a metric's tile step wants from a
     (T, c_tile, d) tile stack, made once a corpus: squared row norms for
     L2, inverse row norms for cosine (``ops.distance.cosine_inv_norms``:
-    the step then scales its dot and never normalises a corpus tile).
-    One reduction over the stack, no copy of it. Always traced (inside
-    :func:`knn_chunk_update`, or under :data:`_stack_norms`): the eager
-    reduction gives other bits than the traced one on the CPU."""
-    return jax.vmap(sq_norms if metric == "l2" else cosine_inv_norms)(tiles)
+    the step then scales its dot and never normalises a corpus tile),
+    NOTHING for an inner product (None: an empty node of the stack's
+    pytree, no operand of any program). One reduction over the stack, no
+    copy of it. Always traced (inside :func:`knn_chunk_update`, or under
+    :data:`_stack_norms`): the eager reduction gives other bits than the
+    traced one on the CPU."""
+    if metric == "ip":
+        return None
+    if metric == "l2":
+        return jax.vmap(sq_norms)(tiles)
+    if metric == "cosine":
+        return jax.vmap(cosine_inv_norms)(tiles)
+    raise ValueError(f"unknown metric {metric!r}")
 
 
 _stack_norms = jax.jit(stack_norms, static_argnames=("metric",))
+
+
+def resident_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
+    """:func:`stack_norms` of a stack that stays (a prepared corpus, a
+    served index), under its jit; for an inner product no program runs
+    and no plane is built."""
+    return None if metric == "ip" else _stack_norms(tiles, metric)
 
 
 def serve_chunk(
@@ -508,7 +544,8 @@ def serve_chunk(
     carry_i: jax.Array,
     tiles: jax.Array,  # (T, c_tile, d) RESIDENT corpus tiles
     tile_ids: jax.Array,  # (T, c_tile)
-    tile_sqs: jax.Array,  # (T, c_tile) stack_norms, made at index build
+    tile_sqs: jax.Array | None,  # (T, c_tile) stack_norms, made at index
+    # build; None under "ip"
     onepass: jax.Array | None = None,  # the corpus side of the one-pass rule
     *,
     cfg: KNNConfig,
@@ -559,8 +596,10 @@ def serve_chunk(
         q_sq = None
         if cfg.metric == "l2":
             q_sq = sq_norms(q_x)
-        else:
+        elif cfg.metric == "cosine":
             q_x = unit_rows(q_x)
+        elif cfg.metric != "ip":  # an inner product prepares nothing
+            raise ValueError(f"unknown metric {cfg.metric!r}")
         one = None if onepass is None else onepass & bf16_exact(q_x)
         words = filter_words(filt[1], *q_tags) if q_tags else None
         return *merge_tiles_into_carry(
@@ -609,7 +648,7 @@ def merge_tiles_into_carry(
     q_sq: jax.Array | None,
     tiles: jax.Array,  # (T, c_tile, d)
     tile_ids: jax.Array,  # (T, c_tile)
-    tile_sqs: jax.Array,  # (T, c_tile)
+    tile_sqs: jax.Array | None,  # (T, c_tile); None under "ip"
     carry_d: jax.Array,  # (q_tile, k)
     carry_i: jax.Array,
     cfg: KNNConfig,
@@ -957,8 +996,9 @@ def _rescan_flagged(flagged, vals, ids, dist_tile, stack, either):
     def step(state):
         i, todo, rows, best, vals, ids = state
         t = i % n_tiles
-        tile = tuple(
-            jax.lax.dynamic_index_in_dim(x, t, keepdims=False) for x in stack)
+        tile = tuple(  # None: the norms' slot of an inner-product stack
+            x if x is None else jax.lax.dynamic_index_in_dim(
+                x, t, keepdims=False) for x in stack)
         d = either(dist_tile, *tile)[rows]
         best = merge_topk(
             *best, d, jnp.broadcast_to(tile[1][None, :], d.shape))
@@ -1119,7 +1159,7 @@ class SerialCorpus(PreparedCorpus):
 
     tiles: jax.Array  # (T, c_tile, d)
     tile_ids: jax.Array  # (T, c_tile)
-    tile_sqs: jax.Array  # (T, c_tile)
+    tile_sqs: jax.Array | None  # (T, c_tile); None under "ip"
 
     def search(self, queries, query_ids, cfg: KNNConfig):
         nq = queries.shape[0]
@@ -1149,7 +1189,7 @@ def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:
         corpus, mu, fact = center_corpus(corpus)
     tiles, tile_ids = tile_corpus(corpus, cfg, c_tile)
     del corpus
-    tile_sqs = _stack_norms(tiles, cfg.metric)
+    tile_sqs = resident_norms(tiles, cfg.metric)
     # read last: the stack's copy and its norms are queued behind the
     # centring pass that the read waits for
     return SerialCorpus(

@@ -13,7 +13,7 @@ import dataclasses
 from typing import Optional, Sequence
 
 BACKENDS = ("auto", "serial", "ring", "ring-overlap")
-METRICS = ("l2", "cosine")
+METRICS = ("l2", "cosine", "ip")
 # dtypes a corpus block may travel the ring at (None = the compute dtype):
 # bfloat16 halves the ICI bytes per hop; int8 is the block-scaled
 # quantized level (codes + per-row f32 scales, ops/quant.py) at ~4× fewer
@@ -34,8 +34,14 @@ class KNNConfig:
 
     Attributes:
       k: neighbors per query (reference: compile-time ``NN=30``).
-      metric: ``l2`` (compared in squared space — same order, SURVEY.md Q10)
-        or ``cosine`` (1 − cosine similarity).
+      metric: ``l2`` (compared in squared space — same order, SURVEY.md Q10),
+        ``cosine`` (1 − cosine similarity) or ``ip`` (maximum inner
+        product: the engine's one ordering is "the k smallest, ascending",
+        so the distance under ``ip`` is the NEGATED inner product
+        ``-<q, c>``; a score, not a distance — no norms, no centring, no
+        clamp at zero and no zero test: ``exclude_zero`` reads False under
+        it whatever was passed, because a zero inner product is
+        orthogonality, not identity. The dense ``serial`` backend only).
       backend: ``serial`` (single device), ``ring`` (blocking-parity ppermute
         ring), ``ring-overlap`` (pipelined ring with compute/comm overlap —
         the capability the reference's non-blocking variant intended but never
@@ -315,6 +321,11 @@ class KNNConfig:
             )
         if self.metric not in METRICS:
             raise ValueError(f"metric must be one of {METRICS}, got {self.metric!r}")
+        if self.metric == "ip":
+            self._refuse_under_ip()
+            # the metric decides: a score of 0 is two orthogonal rows, not
+            # a row met again, so there is no zero test to switch on
+            object.__setattr__(self, "exclude_zero", False)
         if self.topk_method not in TOPK_METHODS:
             raise ValueError(
                 f"topk_method must be one of {TOPK_METHODS}, got {self.topk_method!r}"
@@ -424,7 +435,8 @@ class KNNConfig:
             raise ValueError(
                 "a clustered (IVF) index supports metric='l2' only: the "
                 "k-means partitioner and the centroid score are L2 "
-                f"geometry (got metric={self.metric!r})"
+                f"geometry (got metric={self.metric!r}) — leave partitions "
+                "unset for the exact dense index"
             )
         if self.kmeans_sample is not None and self.kmeans_sample < max(
                 1, self.partitions or 1):
@@ -486,6 +498,41 @@ class KNNConfig:
             raise ValueError(f"topk_block must be >= 1, got {self.topk_block}")
         if self.k < 1:
             raise ValueError(f"k must be >= 1, got {self.k}")
+
+    def _refuse_under_ip(self):
+        """What ``metric="ip"`` cannot be combined with yet, each with its
+        reason and what to pass instead (ROADMAP Reach keeps the list by
+        mechanism). A clustered index is refused where every metric but
+        L2 is (``partitions``, below)."""
+        if self.backend in ("ring", "ring-overlap"):
+            raise ValueError(
+                f"metric='ip' does not run on backend={self.backend!r}: a "
+                "ring round norms its travelling block for L2 or "
+                "normalises it for cosine and has no form that does "
+                "neither, and no reference or measurement holds one — use "
+                "backend='serial'"
+            )
+        if self.precision_policy != "exact":
+            raise ValueError(
+                "metric='ip' requires precision_policy='exact': the "
+                "compress pass of 'mixed' overfetches by a bf16 L2 or "
+                "cosine key (ops/rerank.py) and has no inner-product form"
+            )
+        if self.dtype not in ("float32", "float64"):
+            raise ValueError(
+                f"metric='ip' requires dtype='float32' (or the 'float64' "
+                f"debug mode), got {self.dtype!r}: a bfloat16 stack under "
+                "an unbounded score has no measured recall, and the "
+                "quantised at-rest levels ('int8'/'int4') belong to the "
+                "clustered store, which is L2's"
+            )
+        if self.bucket_headroom:
+            raise ValueError(
+                "an index under metric='ip' is frozen (serve/mutate.py "
+                f"IP_FROZEN): bucket_headroom={self.bucket_headroom} "
+                "reserves slots for upserts it would refuse — build with "
+                "bucket_headroom=0"
+            )
 
     def replace(self, **kw) -> "KNNConfig":
         return dataclasses.replace(self, **kw)

@@ -62,7 +62,10 @@ def build_serve_parser() -> argparse.ArgumentParser:
     d.add_argument("--metric", choices=METRICS, default="l2",
                    help="distance of the served index, as `mpi-knn query "
                    "--metric` (cosine: the index keeps its rows' inverse "
-                   "norms; refused loudly by the clustered layouts)")
+                   "norms; ip: exact maximum inner product, answered as "
+                   "the negated score ascending, no norms, nothing "
+                   "centred, the dense serial layout on one device and "
+                   "frozen; both refused loudly by the clustered layouts)")
     d.add_argument("--backend", choices=BACKENDS, default="auto")
     d.add_argument("--devices", type=int, default=None,
                    help="ring size for distributed backends")

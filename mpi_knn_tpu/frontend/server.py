@@ -1155,6 +1155,10 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             if doc is None:
                 doc = {
                     "rows": int(ids.shape[0]),
+                    # what ``dists`` holds, ascending: squared L2 ("l2"),
+                    # 1 - cosine similarity ("cosine"), or the NEGATED
+                    # inner product -<q, c> ("ip")
+                    "metric": frontend.session.cfg.metric,
                     "dists": [[float(v) for v in row] for row in dists],
                     "ids": ids.tolist(),
                 }

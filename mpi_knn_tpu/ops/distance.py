@@ -307,6 +307,32 @@ def pairwise_cosine(
     return jnp.maximum(1.0 - sim, 0.0)
 
 
+# the inner-product dot as the trace names it, inside ``knn.dist``
+IP_SCOPE = "knn.dist_ip"
+
+
+def pairwise_neg_ip(
+    x: jax.Array,
+    y: jax.Array,
+    precision: str | None = None,
+) -> jax.Array:
+    """Negated inner products ``-<x_i, y_j>``, (q, d) × (c, d) -> (q, c):
+    the ``ip`` metric in the engine's one ordering (smaller = nearer, the k
+    smallest ascending), so the same top-k machinery applies. The dot
+    alone, at the rows' precision: a score is not a distance — it is not
+    translation-invariant (nothing is centred), not bounded below (no
+    clamp: the nearest rows' values are the most negative ones) and has
+    no zero that means "the same row" (no zero test), and neither side
+    has a norm in it."""
+    return -jax.lax.dot_general(
+        x,
+        y,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=_acc_dtype(x),
+        precision=_dot_precision(x, precision),
+    )
+
+
 def pairwise_dist(
     x: jax.Array,
     y: jax.Array,
@@ -322,4 +348,6 @@ def pairwise_dist(
         # the corpus side's slot holds 1 / |row| for cosine
         # (``cosine_inv_norms``); given, ``x`` holds unit rows already
         return pairwise_cosine(x, y, precision=precision, y_inv=y_sq)
+    if metric == "ip":
+        return pairwise_neg_ip(x, y, precision=precision)
     raise ValueError(f"unknown metric {metric!r}")

@@ -38,13 +38,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi_knn_tpu.backends.serial import (
     TileCounts,
-    _stack_norms,
     cap_corpus_tile,
+    resident_norms,
     serve_chunk,
     serve_chunk_filtered,
     tile_counts,
 )
-from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.config import METRICS, KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.ops.distance import center_corpus, onepass_applies
 from mpi_knn_tpu.ops.topk import (
@@ -294,7 +294,7 @@ class CorpusIndex:
     # serial layout
     tiles: jax.Array | None = None  # (T, c_tile, d)
     tile_ids: jax.Array | None = None  # (T, c_tile)
-    tile_sqs: jax.Array | None = None  # (T, c_tile)
+    tile_sqs: jax.Array | None = None  # (T, c_tile); None under "ip" too
     # the corpus side of the one-pass rule (backends.serial
     # masked_dist_tile): a bool scalar on the device, TRUE when the index
     # was built — every centred element a bf16 number — and handed to every
@@ -424,6 +424,13 @@ def build_index(
                 f"an index with tags is served by the dense serial layout "
                 f"only; the {backend!r} layout has no predicate plane "
                 "(build with backend='serial')")
+        if cfg.metric == "ip":
+            raise ValueError(
+                "an index with tags answers by a distance: under "
+                "metric='ip' neither regime of the predicate (the masked "
+                "scan's one-pass branch, the gather's exact finish) has "
+                "an inner-product form or a reference — build without "
+                "tags, or with metric='l2'")
         if cfg.bucket_headroom:
             raise ValueError(
                 "an index with tags is frozen: bucket_headroom="
@@ -507,6 +514,16 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
         "serial layout then holds the corpus rows' inverse norms, and "
         "its batches' tile steps count under path=\"cosine\"); else 0",
     ).set(float(cfg.metric == "cosine"))
+    for name in METRICS:
+        obs_metrics.get_registry().gauge(
+            "serve_index_metric",
+            help="1 under the metric the resident index answers by: l2 "
+            "(squared L2), cosine (1 - cosine similarity) or ip (the "
+            "negated inner product; no norm plane, no centring, its "
+            "batches' tile steps count under path=\"ip\"); 0 under the "
+            "others",
+            labels={"metric": name},
+        ).set(float(cfg.metric == name))
 
     if backend in ("ring", "ring-overlap"):
         from mpi_knn_tpu.backends.ring import parse_ring_mesh, ring_tiles
@@ -573,7 +590,7 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     # computed UNDER JIT — the eager-mode reduction produces different
     # bits than the traced one on CPU, and serving must be bit-identical
     # to a fresh all_knn call
-    tile_sqs = _stack_norms(tiles, cfg.metric)
+    tile_sqs = resident_norms(tiles, cfg.metric)
     return CorpusIndex(
         cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
         c_tile=c_tile, mu=mu, layout=SERIAL, tiles=tiles, tile_ids=tile_ids,

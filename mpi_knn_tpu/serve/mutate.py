@@ -107,14 +107,29 @@ TAGGED_FROZEN = (
 )
 
 
+IP_FROZEN = (
+    "an index built with metric='ip' is frozen: the write path scatters a "
+    "row's norm beside it and an inner-product stack keeps none, and no "
+    "reference holds a changing corpus under a score — upsert, delete and "
+    "compact are refused; rebuild the index from the new rows"
+)
+
+
+def _metric(index) -> str | None:
+    return getattr(getattr(index, "cfg", None), "metric", None)
+
+
 def supports_mutation(index) -> bool:
     return (getattr(index, "backend", None) in MUTABLE_BACKENDS
-            and getattr(index, "tags", None) is None)
+            and getattr(index, "tags", None) is None
+            and _metric(index) != "ip")
 
 
 def _require_mutable(index) -> None:
     if getattr(index, "tags", None) is not None:
         raise ValueError(TAGGED_FROZEN)
+    if _metric(index) == "ip":
+        raise ValueError(IP_FROZEN)
     if not supports_mutation(index):
         raise ValueError(
             f"the {getattr(index, 'backend', None)!r} layout cannot honor "

@@ -25,8 +25,11 @@ class KNNResult:
 
     Attributes:
       dists: (q, k) float array. Distances in *sortable* space — squared L2 for
-        the ``l2`` metric (monotone in true L2, per SURVEY.md §5 Q10), or
-        ``1 − cosine`` for the ``cosine`` metric. Ascending along k.
+        the ``l2`` metric (monotone in true L2, per SURVEY.md §5 Q10),
+        ``1 − cosine`` for the ``cosine`` metric, the NEGATED inner
+        product ``-<q, c>`` for the ``ip`` metric (the rows of largest
+        inner product first, at the most negative values). Ascending along
+        k.
       ids: (q, k) int32 array of 0-based global corpus ids (the reference uses
         1-based ids, ``/root/reference/knn-serial.c:89``; use ``one_based()``
         for parity output). ``INVALID_ID`` marks unfilled slots (k > valid
@@ -34,7 +37,8 @@ class KNNResult:
       dist_steps: int32 (..., 2), the call's tile steps by the path of their
         distance dot, ``[one-pass, multi-pass]`` (``backends/serial.py
         masked_dist_tile``), or (..., 3) ``[0, 0, cosine]`` from a cosine
-        call, or (..., 4) ``[0, multi-pass, 0, fused]`` from a call whose
+        call, or (..., 5) ``[0, 0, 0, 0, ip]`` from an inner-product call,
+        or (..., 4) ``[0, multi-pass, 0, fused]`` from a call whose
         one-pass steps ran inside the kernel that walks the whole stack
         (``ops/fused_scan.py``), one row a device where the rows are counted on
         the ring's devices; comes with the answer, costs no wait of its

@@ -333,7 +333,8 @@ def test_a_ring_call_counts_its_fused_steps_a_device(
         obs_metrics.DIST_STEPS, labels={"path": p}).value
         for p in obs_metrics.DIST_PATHS}
     assert counted == {"onepass": 0, "multipass": steps + DEVICES * steps,
-                       "cosine": 0, "fused": (2 * DEVICES - 1) * steps}
+                       "cosine": 0, "fused": (2 * DEVICES - 1) * steps,
+                       "ip": 0}
     assert sum(registry.counter(
         obs_metrics.BINS_CHUNKS, labels={"path": p}).value
         for p in obs_metrics.BINS_PATHS) == chunks.sum()

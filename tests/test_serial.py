@@ -742,6 +742,8 @@ _WHICH = {
     "serve-yfcc10m-filter-bulk-1024": (True, 5, None, True),
     "serve-yfcc10m-filter-bulk-1024-nofact": (False, 5, None, True),
     "serve-bigann10m-ivf-bulk-1024": "bucket-major, one 1024-row tile",
+    # an inner product: never the one-pass rule (L2's), the carried lists
+    "serve-text2image10m-ip-bulk-1024": (False, 5, None, True),
 }
 
 
