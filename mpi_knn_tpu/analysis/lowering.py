@@ -652,6 +652,8 @@ def _lower_ivf(target: LintTarget):
         index.bucket_ids,
         index.bucket_sqs,
         index.bucket_scales,
+        index.onepass,
+        index.mean_frac,
         cfg,
         cfg.nprobe,
     )

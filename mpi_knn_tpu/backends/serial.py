@@ -143,7 +143,8 @@ class TileCounts(typing.NamedTuple):
     # bound (:func:`_merge_carried`), from a program whose scans carry one
     bins_chunks: jax.Array | None = None
     # int32 ``[probes, bucket_cap, live rows, distinct partitions, their
-    # live rows, work items walked]``: what a clustered batch probed
+    # live rows, work items walked, those in one pass]``: what a clustered
+    # batch probed
     # (``ivf/search.py probe_counts``), from a clustered index's program
     # alone
     ivf_probe: jax.Array | None = None

@@ -14,6 +14,19 @@ Layout (all device-resident after build):
   produce different bits than traced ones, and the degenerate
   nprobe == partitions scan is parity-tested against the serial backend).
 
+Centring: the rows rest as ``corpus - mu``, ``mu`` the corpus mean — or,
+where every element of the corpus is a whole number, the mean ROUNDED to
+whole numbers (``ops/distance.py center_corpus``'s rule: L2 is invariant
+to the translation, and whole rows stay whole). A float32 store whose
+every element is then a bfloat16 number (whole numbers up to 256 in
+magnitude are) carries that fact as ``IVFIndex.onepass``, and the
+bucket-major walk ranks such a store's lists against such query rows in
+one bf16 pass (``ivf/search.py``). What the rounding took off the mean
+rides with the fact (``IVFIndex.mean_frac``): the walk's finish takes it
+off the survivors and the query rows again, so every returned distance is
+computed from the operands it was computed from before the mean was
+rounded.
+
 ``dtype="bfloat16"`` stores buckets compressed at rest (half the HBM and
 half the probe-gather bytes; candidates upcast to f32 after the gather) —
 the same measured-recall contract as the compressed serve index.
@@ -63,12 +76,13 @@ from mpi_knn_tpu.ivf.kmeans import (
 )
 from mpi_knn_tpu.ivf.search import (
     PROBE_FIELDS,
+    bucket_major_engages,
     ivf_query_shapes,
     ivf_serve_chunk,
     search_ivf,
 )
 from mpi_knn_tpu.obs import spans as obs_spans
-from mpi_knn_tpu.ops.distance import sq_norms
+from mpi_knn_tpu.ops.distance import bf16_exact, sq_norms
 from mpi_knn_tpu.ops.quant import (
     QUANT_DTYPES,
     dequantize_rows,
@@ -76,7 +90,7 @@ from mpi_knn_tpu.ops.quant import (
     row_wire_bytes,
 )
 from mpi_knn_tpu.parallel.partition import pad_to_multiple
-from mpi_knn_tpu.serve.index import BatchLayout
+from mpi_knn_tpu.serve.index import BatchLayout, onepass_holds
 
 # held-out sample size for recall-targeted nprobe tuning (the CLI/bench
 # recall-gate convention: enough rows for a stable estimate, cheap enough
@@ -102,6 +116,7 @@ class IVFLayout(BatchLayout):
     static_argnames = ("cfg", "nprobe")
     donate_argnums = (2, 3, 4)  # the carry pair and the probe counts' zeros
     tiled = True
+    onepass_gauge = "ivf_index_onepass"
 
     def serve_fn(self):
         return ivf_serve_chunk
@@ -127,7 +142,8 @@ class IVFLayout(BatchLayout):
 
     def resident(self, index):
         return (index.centroids, index.centroid_sqs, index.buckets,
-                index.bucket_ids, index.bucket_sqs, index.bucket_scales)
+                index.bucket_ids, index.bucket_sqs, index.bucket_scales,
+                index.onepass, index.mean_frac)
 
     def statics(self, index, cfg, bucket):
         # concrete: compatible_cfg resolves None to the tuned default
@@ -159,6 +175,13 @@ class IVFLayout(BatchLayout):
             "(partitions x bucket_cap), in percent: what padding every "
             "bucket to the largest leaves empty",
         ).set(100.0 * index.m / (index.partitions * index.bucket_cap))
+        registry.gauge(
+            self.onepass_gauge,
+            help="1 while every element of the clustered store is a bf16 "
+            "number, so the bucket-major walk ranks batches of such query "
+            "rows in one bf16 pass (serve_index_onepass is the dense "
+            "index's); else 0",
+        ).set(float(onepass_holds(index)))
         registry.gauge(
             "serve_index_nprobe",
             help="partitions a query row probes in the batch program "
@@ -193,6 +216,20 @@ class IVFIndex:
     # when quantized — distances are exact w.r.t. the stored values)
     bucket_scales: jax.Array | None = None  # (P, cap) f32, quantized only
     tuned_recall: float | None = None  # measured recall@k at `nprobe`
+    # the store's side of the one-pass rule (:func:`store_onepass`): a
+    # bool scalar on the device, TRUE when the index was built — every
+    # element of the float32 store a bf16 number — and handed to every
+    # batch program, whose walk then holds both dots; an upsert of a row
+    # that is not turns it false in place, with no recompile. None (the
+    # store did not qualify at the build): the program has no branch.
+    onepass: jax.Array | None = None
+    # (d,) float32 on the device, beside a fact alone: the corpus mean
+    # less ``mu``, what rounding the mean to whole numbers took off. The
+    # walk's finish subtracts it from the survivors and the query rows
+    # (L2 does not see a common translation), so the returned distances
+    # come from the operands the unrounded mean left — the numbers, and
+    # the rounding, they had before the rule. None: ``mu`` is the mean.
+    mean_frac: jax.Array | None = None
     backend: str = "ivf"
     layout = IVFLayout()  # one for the kind: a class attribute, no field
     # per-index executable cache: {(bucket, cfg) -> engine._BucketExec}
@@ -266,6 +303,26 @@ class IVFIndex:
         if want.nprobe is None:
             want = want.replace(nprobe=self.nprobe)
         return want
+
+
+def store_onepass(cfg: KNNConfig, buckets, bucket_scales):
+    """The store's side of the one-pass rule, read ONCE from the filled
+    store: a TRUE bool scalar on the device where the bucket-major walk
+    can take the store (``ivf/search.py bucket_major_engages``'s form:
+    float32 rows, no scale table, the bucket a block where it rests) and
+    every element of it is a bf16 number (``ops.distance.bf16_exact``, by
+    bit pattern, in one fused pass: no store-sized temporary; padding
+    slots are zeros and qualify), else None — also where the kernel that
+    holds both dots, with its bfloat16 copies of a bucket and a group,
+    would pass the walk's share of VMEM that the plain one fits.
+    ``precision_policy`` is the query's to vary and is not asked."""
+    partitions, cap, dim = buckets.shape
+    if bucket_scales is not None or not bucket_major_engages(
+            1, 1, partitions, cap, dim, cfg.dtype, onepass=True):
+        return None
+    if not bool(jax.jit(bf16_exact)(buckets)):
+        return None
+    return jax.device_put(np.bool_(True))
 
 
 def _refuse_inert_knobs(cfg: KNNConfig) -> None:
@@ -377,14 +434,11 @@ def build_ivf_index(
     with obs_spans.span("index-build", cat="index", backend="ivf",
                         rows=int(m), dim=int(dim), metric=cfg.metric,
                         partitions=cfg.partitions):
-        if isinstance(corpus, jax.Array):
-            mu, centroids, buckets_f32, bucket_ids, cap = _store_on_device(
-                corpus, cfg)
-        else:
-            mu, centroids, buckets_f32, bucket_ids, cap = _store_on_host(
-                corpus, cfg)
+        store = _store_on_device if isinstance(corpus, jax.Array) \
+            else _store_on_host
+        mu, frac, centroids, buckets_f32, bucket_ids, cap = store(corpus, cfg)
         return _finish_index(cfg, m, dim, cap, mu, centroids, buckets_f32,
-                             bucket_ids)
+                             bucket_ids, frac)
 
 
 def _bucket_cap(counts: np.ndarray, cfg: KNNConfig) -> int:
@@ -437,12 +491,15 @@ def _train(rows, cfg: KNNConfig, m: int):
 
 def _store_on_host(corpus, cfg: KNNConfig):
     """The store of a host corpus (or of a serial ``CorpusIndex``'s centred
-    tiles): centred by the float64 mean on the host, trained and assigned
+    tiles): centred by the float64 mean on the host (rounded to whole
+    numbers where the corpus holds nothing else), trained and assigned
     on the device, filled by one numpy scatter and copied up. Returns
-    ``(mu, centroids, (P, cap, d) float32 buckets, (P, cap) ids, cap)``."""
+    ``(mu, frac, centroids, (P, cap, d) float32 buckets, (P, cap) ids,
+    cap)``; ``frac`` is what the rounding took off the mean
+    (:func:`_whole_mean`), None where nothing was rounded."""
     from mpi_knn_tpu.serve.index import CorpusIndex
 
-    mu = None
+    mu = frac = None
     if isinstance(corpus, CorpusIndex):
         rows, mu, built_cfg = _corpus_from_serve_index(corpus)
         for f in ("metric", "dtype", "center"):
@@ -456,6 +513,10 @@ def _store_on_host(corpus, cfg: KNNConfig):
         X = np.asarray(corpus, dtype=np.float32)
         if cfg.center:
             mu = X.astype(np.float64).mean(axis=0)
+            if all((blk == np.rint(blk)).all() for blk in (
+                    X[i:i + WHOLE_BLOCK]
+                    for i in range(0, X.shape[0], WHOLE_BLOCK))):
+                mu, frac = _whole_mean(mu)
             X = X - mu
     m, dim = X.shape
     P = cfg.partitions
@@ -484,7 +545,22 @@ def _store_on_host(corpus, cfg: KNNConfig):
         buckets_np[sa, within] = X[order]
         ids_np[sa, within] = order
         buckets, ids = jnp.asarray(buckets_np), jnp.asarray(ids_np)
-    return mu, res.centroids, buckets, ids, cap
+    return mu, frac, res.centroids, buckets, ids, cap
+
+
+# rows the host path tests for whole numbers at a time (no corpus-sized
+# temporary there either)
+WHOLE_BLOCK = 1 << 16
+
+
+def _whole_mean(mean):
+    """``(mu, frac)`` of a whole-number corpus from its float64 ``mean``:
+    the float32 nearest it — the number both paths hold — rounded to whole
+    numbers, and what the rounding took off, ``mean - mu``, each element
+    half a unit at most and exact in float32."""
+    mean = np.asarray(mean).astype(np.float32).astype(np.float64)
+    mu = np.rint(mean)
+    return mu, mean - mu
 
 
 # elements of the rows one step of the device fill gathers (32 MiB)
@@ -501,15 +577,21 @@ def _store_on_device(corpus: jax.Array, cfg: KNNConfig):
     centring inside the block, and the store filled a few partitions a
     step by a gather of their rows (:func:`_fill_store`). The mean is the
     float32 nearest the float64 one, so that a batch centred in float64 on
-    the host and the rows centred in float32 here are the same numbers.
+    the host and the rows centred in float32 here are the same numbers —
+    or, where every block of the corpus holds whole numbers alone
+    (``column_sums`` tests each beside its sum), the mean rounded to whole
+    numbers, as the host path rounds it.
     Returns what :func:`_store_on_host` returns."""
     m, dim = corpus.shape
     P = cfg.partitions
-    mu = None
+    mu = frac = None
     mu_dev = jnp.zeros(dim, jnp.float32)
     if cfg.center:
-        sums = np.asarray(column_sums(corpus), dtype=np.float64)
+        sums, whole = column_sums(corpus, whole=True)
+        sums = np.asarray(sums, dtype=np.float64)
         mu = (sums.sum(axis=0) / m).astype(np.float32).astype(np.float64)
+        if bool(np.asarray(whole).all()):
+            mu, frac = _whole_mean(mu)
         mu_dev = jnp.asarray(mu, dtype=jnp.float32)
     picked = sample_rows(m, cfg.kmeans_sample, cfg.ivf_seed)
     train = corpus if picked is None else corpus[jnp.asarray(picked)]
@@ -536,7 +618,7 @@ def _store_on_device(corpus: jax.Array, cfg: KNNConfig):
             corpus, mu_dev, order, counts, cap=cap,
             step=_fill_step(P, cap * dim))
         jax.block_until_ready(buckets)
-    return mu, res.centroids, buckets, ids, cap
+    return mu, frac, res.centroids, buckets, ids, cap
 
 
 def _fill_step(partitions: int, slot_elems: int) -> int:
@@ -582,9 +664,10 @@ def _fill_store(corpus, mu, order, counts, cap: int, step: int):
 
 
 def _finish_index(cfg, m, dim, cap, mu, centroids, buckets_f32,
-                  bucket_ids) -> IVFIndex:
+                  bucket_ids, frac=None) -> IVFIndex:
     """The index around a filled float32 store: the at-rest form, the
-    norms, the probe count."""
+    norms, the one-pass fact (with ``frac``, what rounding the mean took
+    off, beside it), the probe count."""
     P = cfg.partitions
     bucket_scales = None
     if cfg.dtype in QUANT_DTYPES:
@@ -615,7 +698,10 @@ def _finish_index(cfg, m, dim, cap, mu, centroids, buckets_f32,
         centroids=centroids, centroid_sqs=centroid_sqs,
         buckets=buckets, bucket_ids=bucket_ids, bucket_sqs=bucket_sqs,
         bucket_scales=bucket_scales,
+        onepass=store_onepass(cfg, buckets, bucket_scales),
     )
+    if index.onepass is not None and frac is not None and frac.any():
+        index.mean_frac = jnp.asarray(frac, dtype=jnp.float32)
     if cfg.nprobe is None:
         tuned, rec = tune_nprobe(index, cfg.recall_target, k=cfg.k)
         index.nprobe = tuned
@@ -759,6 +845,9 @@ def save_ivf_index(index, path: str) -> str:
                            else np.zeros(0, np.float32)),
             mu=(np.asarray(index.mu)
                 if index.mu is not None else np.zeros(0)),
+            mean_frac=(np.asarray(index.mean_frac)
+                       if index.mean_frac is not None
+                       else np.zeros(0, np.float32)),
         )
     os.replace(tmp, path)
     return path
@@ -811,6 +900,9 @@ def load_ivf_index(path: str, mmap: bool = True) -> IVFIndex:
         scales = jnp.asarray(z["bucket_scales"]).reshape(
             meta["partitions"], meta["bucket_cap"]
         )
+    onepass = store_onepass(cfg, buckets, scales)
+    # (absent from an older archive, whose mean was not rounded)
+    frac = z.get("mean_frac")
     return IVFIndex(
         cfg=cfg,
         m=meta["m"],
@@ -831,4 +923,7 @@ def load_ivf_index(path: str, mmap: bool = True) -> IVFIndex:
         bucket_ids=jnp.asarray(z["bucket_ids"]),
         bucket_sqs=jnp.asarray(z["bucket_sqs"]),
         bucket_scales=scales,
+        onepass=onepass,
+        mean_frac=(jnp.asarray(np.array(frac)) if onepass is not None
+                   and frac is not None and np.size(frac) else None),
     )

@@ -125,17 +125,25 @@ def _over_blocks(rows, block: int, one):
     return head, tail
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def column_sums(rows, block: int = ASSIGN_BLOCK):
+@functools.partial(jax.jit, static_argnames=("block", "whole"))
+def column_sums(rows, block: int = ASSIGN_BLOCK, whole: bool = False):
     """(blocks, d) float32 sums of ``block`` rows each, the last block the
     rows left over: the chunked half of a mean that crosses the host as a
     few KB — the caller adds the blocks up in float64. Whole numbers up
-    to 255 add up exactly in a block of 65536 rows or fewer."""
-    head, tail = _over_blocks(
-        rows, block, lambda blk: jnp.sum(blk.astype(jnp.float32), axis=0))
+    to 255 add up exactly in a block of 65536 rows or fewer. ``whole``:
+    also (blocks,) bool, whether a block holds whole numbers alone (NaN
+    is none) — the test that decides a whole-number mean, beside the sums
+    so that it too needs no corpus-sized temporary."""
+
+    def one(blk):
+        blk = blk.astype(jnp.float32)
+        sums = jnp.sum(blk, axis=0)
+        return (sums, jnp.all(blk == jnp.rint(blk))) if whole else sums
+
+    head, tail = _over_blocks(rows, block, one)
     parts = ([head] if head is not None else []) + (
-        [tail[None]] if tail is not None else [])
-    return jnp.concatenate(parts, axis=0)
+        [jax.tree.map(lambda x: x[None], tail)] if tail is not None else [])
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))

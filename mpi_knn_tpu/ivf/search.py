@@ -29,7 +29,17 @@ device, a kernel fetches each touched list once from the store where it
 rests and keeps each (query row, list) pair's k nearest slots
 (``ops/bucket_walk.py``), and the exact finish runs over a row's
 nprobe·k survivors. Same answers, the same counts; the per-row gather
-below is what every other store and policy keeps.
+below is what every other store and policy keeps. The walk's keys come
+from ONE bf16 x bf16 pass where the store and the batch's query rows are
+bf16 numbers (``ops/distance.py bf16_exact``: whole numbers up to 256 in
+magnitude are, and a whole-number corpus is centred by a whole-number
+mean, ``ivf/index.py``) — the dense scan's one-pass rule (PR 29), decided
+by the data: the store's fact is read once at the build
+(``IVFIndex.onepass``), the query rows' in every batch, and the six-pass
+dot ranks everything else as it did. The keys alone follow the rounded
+mean: the finish of such a store takes what the rounding took off
+(``IVFIndex.mean_frac``) from its operands again, and returns distances
+computed from the numbers the unrounded mean leaves.
 
 Bucket padding slots carry id −1 → ``mask_tile`` forces them to +inf, so
 ragged partitions cost padded FLOPs but never wrong answers. Every point
@@ -45,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
-from mpi_knn_tpu.ops.distance import pairwise_sq_l2, sq_norms
+from mpi_knn_tpu.ops.distance import bf16_exact, pairwise_sq_l2, sq_norms
 from mpi_knn_tpu.ops.quant import dequantize_rows
 from mpi_knn_tpu.ops.rerank import (
     mixed_applies,
@@ -174,8 +184,9 @@ def ivf_query_tile(
 def tile_probe(probe, ids, partitions: int):
     """What one query tile probed row-major, from what its program holds
     anyway: ``(live rows among the gathered slots, (P,) bool partitions
-    probed, (P,) int32 their live rows, work items walked: none)``. ``ids``
-    are the gathered slots' ids (q_tile, nprobe * cap), -1 where a slot is
+    probed, (P,) int32 their live rows, work items walked: none, and in
+    one pass: none)``. ``ids`` are the gathered slots' ids (q_tile,
+    nprobe * cap), -1 where a slot is
     empty or dead; a partition probed by several rows of the tile is marked
     once."""
     live = (ids >= 0).reshape(*probe.shape, -1).sum(-1, dtype=jnp.int32)
@@ -185,25 +196,30 @@ def tile_probe(probe, ids, partitions: int):
         jnp.zeros(partitions, jnp.bool_).at[flat].set(True),
         jnp.zeros(partitions, jnp.int32).at[flat].max(live.reshape(-1)),
         jnp.int32(0),
+        jnp.int32(0),
     )
 
 
-PROBE_FIELDS = 6  # the width of a batch's probe counts (probe_counts)
+PROBE_FIELDS = 7  # the width of a batch's probe counts (probe_counts)
 
 
 def probe_counts(q_rows: int, nprobe: int, cap: int, live, seen, part_live,
-                 walked):
+                 walked, walked_onepass):
     """A batch's ``TileCounts.ivf_probe`` from its tiles' counts
     (:func:`tile_probe` / :func:`bucket_major_tile`'s, stacked): int32
     ``[probes issued (query rows x nprobe, padding rows of the batch
     included: they probe too), bucket_cap (a probe scans that many slots),
     live rows among the probed slots, distinct partitions the batch
-    touched, live rows of those, work items walked]``. The fourth and fifth
+    touched, live rows of those, work items walked, work items walked in
+    one bf16 pass]``. The fourth and fifth
     are what any implementation has to read once a batch; the sixth is the
     bucket-major program's (list, group of <= ``PROBE_GROUP`` query rows)
     steps, 0 from the row-major program: it says which of the two answered
     the batch, and probes over (work items x ``PROBE_GROUP``) is the
-    groups' fill. (Slots are left to the reader, probes x bucket_cap: a
+    groups' fill; the seventh is the sixth where the one-pass rule held
+    for the batch (the store's fact and the batch's query rows: all of a
+    batch's work items take one side), else 0. (Slots are left to the
+    reader, probes x bucket_cap: a
     1024-row batch scans 1e8 of them and an int32 sum on the device would
     not hold a large one's.)"""
     return jnp.stack([
@@ -213,6 +229,7 @@ def probe_counts(q_rows: int, nprobe: int, cap: int, live, seen, part_live,
         jnp.sum(jnp.any(seen, axis=0), dtype=jnp.int32),
         jnp.sum(jnp.max(part_live, axis=0), dtype=jnp.int32),
         jnp.sum(walked, dtype=jnp.int32),
+        jnp.sum(walked_onepass, dtype=jnp.int32),
     ])
 
 
@@ -240,7 +257,8 @@ def bucket_major_items(q_rows: int, nprobe: int, partitions: int) -> int:
 
 def bucket_major_engages(q_rows: int, nprobe: int, partitions: int,
                          cap: int, dim: int, dtype: str = "float32",
-                         precision_policy: str = "exact") -> bool:
+                         precision_policy: str = "exact",
+                         onepass: bool = False) -> bool:
     """Whether a batch of ``q_rows`` rows is answered BUCKET-MAJOR
     (:func:`bucket_major_tile`), by the static shapes and the store's form
     alone — the rule ``ops/topk.py fused_scan_engages`` is for the dense
@@ -255,7 +273,10 @@ def bucket_major_engages(q_rows: int, nprobe: int, partitions: int,
       (a (P, cap, d) float32 store then rests row-major on the v5e:
       ``ops/topk.py fused_scan_engages`` has the readings) and ``cap`` a
       multiple of 8, and two buffers of it with what goes with it fit the
-      kernel's share of VMEM;
+      kernel's share of VMEM (``onepass``: asked of the kernel that holds
+      both dots, which keeps bfloat16 copies of a bucket and a group
+      besides — what ``ivf/index.py store_onepass`` asks before it grants
+      a store the fact);
     - (the caller's: k slots fit a list and the kernel's 128 lanes).
 
     It is one algorithm at every batch height: its work items follow
@@ -267,7 +288,8 @@ def bucket_major_engages(q_rows: int, nprobe: int, partitions: int,
         return False
     from mpi_knn_tpu.ops.bucket_walk import bucket_walk_vmem_bytes
 
-    return bucket_walk_vmem_bytes(PROBE_GROUP, cap, dim) <= _WALK_VMEM_BYTES
+    return bucket_walk_vmem_bytes(
+        PROBE_GROUP, cap, dim, onepass=onepass) <= _WALK_VMEM_BYTES
 
 
 def _engages(cfg: KNNConfig, q_rows: int, nprobe: int, partitions: int,
@@ -340,6 +362,8 @@ def bucket_major_tile(
     bucket_sqs: jax.Array,
     cfg: KNNConfig,
     nprobe: int,
+    onepass: jax.Array | None = None,  # the store's side of the one-pass rule
+    mean_frac: jax.Array | None = None,  # (d,) what the mean's rounding took
 ):
     """:func:`ivf_query_tile`'s answer by a walk over the touched LISTS
     instead of over the query rows: score as there; the probe table turned
@@ -351,9 +375,22 @@ def bucket_major_tile(
     survivors gathered from the store by slot and finished by
     :func:`finish_candidates` — the distances returned are computed by the
     code that computes the row-major program's. Same ids: a row's k
-    nearest over its lists are among each list's k nearest. Returns
-    (dists, ids, (live pairs, (P,) lists probed, (P,) their live rows,
-    work items walked))."""
+    nearest over its lists are among each list's k nearest.
+
+    ``onepass`` (``IVFIndex.onepass``: a bool scalar on the device, TRUE
+    while every element of the store is a bf16 number; None: the store
+    did not qualify, and the kernel holds the six-pass dot alone) is met
+    here with the same test of this batch's query rows: where both hold,
+    the walk's keys come from one bf16 pass — the same keys, bit for bit,
+    so the same slots. ``mean_frac`` (``IVFIndex.mean_frac``, beside a
+    fact alone: the corpus mean less the whole-number ``mu`` the store and
+    the query rows were centred by) is taken off the survivors and the
+    query rows ahead of the finish — L2 does not see the common
+    translation — so the finish reads the operands, fractional again,
+    that the unrounded mean left it, and their norms anew.
+
+    Returns (dists, ids, (live pairs, (P,) lists probed, (P,) their live
+    rows, work items walked, those walked in one pass))."""
     from mpi_knn_tpu.ops.bucket_walk import bucket_walk
 
     acc, i32 = jnp.float32, jnp.int32
@@ -364,13 +401,16 @@ def bucket_major_tile(
     with jax.named_scope("knn.ivf/score"):
         item_lists, item_rows, pair_slot, counts, walked = invert_probe(
             probe, partitions)
+        if onepass is not None:
+            onepass = onepass & bf16_exact(q_x)
     with jax.named_scope("knn.ivf/gather"):
         at = jnp.maximum(item_rows, 0)
         slots = bucket_walk(
             item_lists, walked, jnp.take(q_x, at, axis=0),
             jnp.take(q_ids, at, axis=0) if cfg.exclude_self else None,
             buckets, bucket_ids, bucket_sqs, k=k,
-            exclude_zero=cfg.exclude_zero, zero_eps=cfg.zero_eps)
+            exclude_zero=cfg.exclude_zero, zero_eps=cfg.zero_eps,
+            onepass=onepass)
         # each pair's k slots, where its work item left them; -1 where a
         # list holds fewer than k unmasked ones
         slots = jnp.take(slots.reshape(-1, slots.shape[-1]),
@@ -383,12 +423,17 @@ def bucket_major_tile(
         rows = jnp.take(buckets.reshape(-1, dim), slots, axis=0).astype(acc)
         ids = jnp.where(
             short, -1, jnp.take(bucket_ids.reshape(-1), slots, axis=0))
-        sqs = jnp.take(bucket_sqs.reshape(-1), slots, axis=0)
+        if mean_frac is None:
+            sqs = jnp.take(bucket_sqs.reshape(-1), slots, axis=0)
+        else:
+            q_x, rows = q_x - mean_frac, rows - mean_frac
+            q_sq, sqs = sq_norms(q_x), None  # the finish takes the rows'
     d, i = finish_candidates(q_x, q_ids, q_sq, rows, ids, sqs, cfg)
     seen = counts > 0
     return d, i, (
         jnp.sum(counts * live, dtype=i32), seen,
-        jnp.where(seen, live, 0), walked)
+        jnp.where(seen, live, 0), walked,
+        i32(0) if onepass is None else jnp.where(onepass, walked, 0))
 
 
 def ivf_serve_chunk(
@@ -403,6 +448,8 @@ def ivf_serve_chunk(
     bucket_ids: jax.Array,
     bucket_sqs: jax.Array,
     bucket_scales: jax.Array | None,
+    onepass: jax.Array | None,  # ``IVFIndex.onepass``
+    mean_frac: jax.Array | None,  # ``IVFIndex.mean_frac``
     cfg: KNNConfig,
     nprobe: int,
 ):
@@ -422,7 +469,7 @@ def ivf_serve_chunk(
         if bucket_major:
             d, i, probed = bucket_major_tile(
                 q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
-                bucket_sqs, cfg, nprobe)
+                bucket_sqs, cfg, nprobe, onepass, mean_frac)
         else:
             d, i, probed = ivf_query_tile(
                 q_x, q_ids, centroids, centroid_sqs, buckets, bucket_ids,
@@ -524,7 +571,7 @@ def run_query_tiles(index, q_tiles, qid_tiles, cfg: KNNConfig):
         jnp.zeros(PROBE_FIELDS, jnp.int32), index.centroids,
         index.centroid_sqs, index.buckets,
         index.bucket_ids, index.bucket_sqs, index.bucket_scales,
-        cfg, cfg.nprobe,
+        index.onepass, index.mean_frac, cfg, cfg.nprobe,
     )[:2]
 
 

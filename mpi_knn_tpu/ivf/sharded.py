@@ -403,6 +403,11 @@ class ShardedIVFLayout(IVFLayout):
         q_tile, q_pad, _ = self._shapes(index, cfg, bucket)
         return q_pad, q_tile
 
+    def resident(self, index):
+        # (no one-pass fact: the routed tile keeps the row-major program)
+        return (index.centroids, index.centroid_sqs, index.buckets,
+                index.bucket_ids, index.bucket_sqs, index.bucket_scales)
+
     def statics(self, index, cfg, bucket):
         return dict(
             cfg=cfg, nprobe=cfg.nprobe, mesh=index.mesh, axis=index.axis,

@@ -402,18 +402,20 @@ class MetricsRegistry:
     def count_ivf_probe(self, probed) -> None:
         """Add a clustered batch's probe counts (``KNNResult.ivf_probe`` /
         ``BatchResult.ivf_probe``: ints ``[probes, bucket_cap, live rows,
-        distinct partitions, their live rows, work items walked]``,
-        ``ivf/search.py probe_counts``) to ``ivf_probe_slots_total``,
+        distinct partitions, their live rows, work items walked, those
+        walked in one bf16 pass]``, ``ivf/search.py probe_counts``) to
+        ``ivf_probe_slots_total``,
         ``ivf_probe_live_rows_total``, ``ivf_probe_partitions_total
         {kind="probes"|"distinct"}``, ``ivf_probe_distinct_live_rows
-        _total``, ``ivf_probe_groups_total`` and ``ivf_probe_batches_total
+        _total``, ``ivf_probe_groups_total``, ``ivf_probe_groups_onepass
+        _total`` and ``ivf_probe_batches_total
         {path="bucket_major"|"row_major"}`` (the program that walked no
         work item is the row-major one). The device counts, so call this
         where :meth:`count_dist_steps` is called."""
         import numpy as np
 
-        probes, cap, live, distinct, distinct_live, walked = (
-            int(n) for n in np.asarray(probed).reshape(-1)[:6])
+        probes, cap, live, distinct, distinct_live, walked, onepass = (
+            int(n) for n in np.asarray(probed).reshape(-1)[:7])
         self.counter(
             "ivf_probe_slots_total",
             help="padded bucket slots the probes scan: query rows of the "
@@ -447,6 +449,13 @@ class MetricsRegistry:
             "that probe it, the partition fetched once however many; "
             "probes over (groups x PROBE_GROUP) is the groups' fill",
         ).inc(walked)
+        self.counter(
+            "ivf_probe_groups_onepass_total",
+            help="work items of ivf_probe_groups_total whose distance keys "
+            "came from one bf16 x bf16 pass: the store and the batch's "
+            "query rows were bf16 numbers (ivf_index_onepass), so the "
+            "pass returns what float32's six return",
+        ).inc(onepass)
         self.counter(
             "ivf_probe_batches_total",
             help="clustered batches by the program that answered them: "

@@ -7,7 +7,14 @@ this returns; ``ops/fused_scan.py`` is the pattern: a ``BlockSpec`` index
 map that reads a scalar-prefetched table).
 
 A step: the group's (group, d) rows against the (cap, d) bucket on the MXU
-at ``highest`` (float32 in six bf16 passes), ``max(q_sq - 2 q.x + x_sq, 0)``
+at ``highest`` (float32 in six bf16 passes) — or, where the caller hands
+the one-pass rule's verdict (``onepass``: the store and the batch's query
+rows are bf16 numbers, ``ops/distance.py bf16_exact``) and it is true, in
+ONE bf16 x bf16 pass over both narrowed in VMEM, accumulated in float32:
+the six passes' keys, bit for bit, since the pieces the other five
+multiply are zero. The kernel then holds both dots and a scalar read from
+scalar memory picks a work item's side; without a verdict it holds the
+six-pass dot alone. Then ``max(q_sq - 2 q.x + x_sq, 0)``
 and ``mask_tile``'s masks (an id < 0 is padding or a dead slot, the zero
 mask by the pair's scale, the self mask by id) — the (group, cap) block of
 masked distances lives in VMEM scratch and nowhere else — and the k
@@ -27,7 +34,9 @@ blocks written out and selected by one ``lax.top_k`` over the pairs' rows
 — the kernel fetch-bound, 15.1 ms of a 36.9 ms batch at the cell's shapes,
 the ``top_k`` 12.3; the passes here — 23.9 of 30.6, compute-bound: a pass
 is two lane reductions deep; one fused sweep a pass, and per-lane lists
-under a certificate, each slower than these passes and taken out.)
+under a certificate, each slower than these passes and taken out. PR 46,
+the kernel alone at the cell's shapes: a work item 5.84 us in six passes,
+3.91 in one, 3.89 with no branch in the kernel at all.)
 """
 
 from __future__ import annotations
@@ -59,21 +68,26 @@ _VMEM_HEADROOM = 16 << 20
 
 
 def bucket_walk_vmem_bytes(group: int, cap: int, d: int,
-                           itemsize: int = 4) -> int:
+                           itemsize: int = 4, onepass: bool = False) -> int:
     """The VMEM :func:`bucket_walk` holds, in bytes: a bucket, its rows of
     the two planes, the group's query rows (and ids) and slot numbers, each
-    in the pipeline's two buffers, and the group's block of distances."""
+    in the pipeline's two buffers, and the group's block of distances;
+    ``onepass``: the kernel carries the one-pass dot too, whose operands
+    are bfloat16 copies of a bucket and of a group's rows."""
     bucket = cap * d * itemsize
     planes = 2 * _PLANE_ROWS * cap * 4
     group_side = group * (d + 2 * _LANES) * 4
-    return 2 * (bucket + planes + group_side) + group * cap * 4
+    narrowed = (cap + group) * d * 2 if onepass else 0
+    return 2 * (bucket + planes + group_side) + group * cap * 4 + narrowed
 
 
-def _walk_kernel(lists_ref, walked_ref, q_ref, *refs, k: int,
-                 exclude_self: bool, exclude_zero: bool, zero_eps: float):
+def _walk_kernel(lists_ref, walked_ref, *refs, k: int, exclude_self: bool,
+                 exclude_zero: bool, zero_eps: float, onepass: bool):
     """A grid step of :func:`bucket_walk`: work item w. ``lists_ref`` (W,)
     and ``walked_ref`` (1,) int32 in scalar memory: each work item's list,
-    and how many of them are real. ``q_ref`` (1, group, d) the group's
+    and how many of them are real; under ``onepass`` a third, ``one_ref``
+    (1,) int32: whether this call's operands are bf16 numbers, so that the
+    dot takes one pass. ``q_ref`` (1, group, d) the group's
     query rows, ``qid_ref`` (1, group, 128) their ids in every lane (an
     operand under ``exclude_self`` alone); ``x_ref`` (1, cap, d) the list's
     bucket as it rests, ``ids_ref`` / ``xsq_ref`` (8, cap) the planes' rows
@@ -81,7 +95,9 @@ def _walk_kernel(lists_ref, walked_ref, q_ref, *refs, k: int,
     slots of each row in its first k lanes; ``d_ref`` (group, cap) scratch,
     the masked distances."""
     lax, f32, i32 = jax.lax, jnp.float32, jnp.int32
-    qid_ref = refs[0] if exclude_self else None
+    one_ref, refs = (refs[0], refs[1:]) if onepass else (None, refs)
+    q_ref = refs[0]
+    qid_ref = refs[1] if exclude_self else None
     x_ref, ids_ref, xsq_ref, out_ref, d_ref = refs[-5:]
     w = _as_i32(pl.program_id(0))
 
@@ -90,33 +106,62 @@ def _walk_kernel(lists_ref, walked_ref, q_ref, *refs, k: int,
         row = pl.ds(lax.rem(lists_ref[w], i32(_PLANE_ROWS)), 1)
         q = q_ref[0]
         shape = d_ref.shape
-        xy = lax.dot_general(
-            q, lax.convert_element_type(x_ref[0], f32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=f32,
-            precision=lax.Precision.HIGHEST,
-        )
-        q_sq = lax.broadcast_in_dim(
-            lax.reduce_sum(lax.mul(q, q), (1,)), shape, (0,))
-        ids = lax.broadcast_in_dim(ids_ref[row, :], shape, (0, 1))
-        x_sq = lax.broadcast_in_dim(xsq_ref[row, :], shape, (0, 1))
-        v = lax.max(
-            lax.add(lax.sub(q_sq, lax.mul(lax.full(shape, 2.0, f32), xy)),
-                    x_sq),
-            lax.full(shape, 0.0, f32))
-        invalid = lax.lt(ids, lax.full(shape, 0, i32))
-        if exclude_zero:
-            # mask_tile's threshold for float32, by the pair's scale
-            thresh = (lax.full(shape, zero_eps, f32) if zero_eps > 0.0
-                      else lax.mul(lax.full(shape, _ZERO_RTOL_DEFAULT, f32),
-                                   lax.add(q_sq, x_sq)))
-            invalid = lax.bitwise_or(invalid, lax.le(v, thresh))
-        if exclude_self:
-            own = lax.broadcast_in_dim(
-                lax.slice(qid_ref[0], (0, 0), (shape[0], 1)), shape, (0, 1))
-            invalid = lax.bitwise_or(invalid, lax.eq(ids, own))
-        inf = lax.full(shape, _INF, f32)
-        d_ref[...] = lax.select(invalid, inf, v)
+
+        def keys(narrow: bool):
+            """The block of masked distances into ``d_ref``, from the
+            six-pass dot — or, ``narrow``, from ONE pass over the group's
+            rows and the bucket narrowed to bfloat16, which loses nothing
+            of a bf16 number. Everything after the dot is one code."""
+            x = lax.convert_element_type(x_ref[0], f32)
+            lhs, rhs, precision = q, x, lax.Precision.HIGHEST
+            if narrow:
+                lhs, rhs = (lax.convert_element_type(a, jnp.bfloat16)
+                            for a in (q, x))
+                precision = lax.Precision.DEFAULT
+            xy = lax.dot_general(
+                lhs, rhs,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=f32,
+                precision=precision,
+            )
+            q_sq = lax.broadcast_in_dim(
+                lax.reduce_sum(lax.mul(q, q), (1,)), shape, (0,))
+            ids = lax.broadcast_in_dim(ids_ref[row, :], shape, (0, 1))
+            x_sq = lax.broadcast_in_dim(xsq_ref[row, :], shape, (0, 1))
+            v = lax.max(
+                lax.add(lax.sub(q_sq, lax.mul(lax.full(shape, 2.0, f32), xy)),
+                        x_sq),
+                lax.full(shape, 0.0, f32))
+            invalid = lax.lt(ids, lax.full(shape, 0, i32))
+            if exclude_zero:
+                # mask_tile's threshold for float32, by the pair's scale
+                thresh = (lax.full(shape, zero_eps, f32) if zero_eps > 0.0
+                          else lax.mul(
+                              lax.full(shape, _ZERO_RTOL_DEFAULT, f32),
+                              lax.add(q_sq, x_sq)))
+                invalid = lax.bitwise_or(invalid, lax.le(v, thresh))
+            if exclude_self:
+                own = lax.broadcast_in_dim(
+                    lax.slice(qid_ref[0], (0, 0), (shape[0], 1)), shape,
+                    (0, 1))
+                invalid = lax.bitwise_or(invalid, lax.eq(ids, own))
+            inf = lax.full(shape, _INF, f32)
+            d_ref[...] = lax.select(invalid, inf, v)
+            return inf
+
+        if onepass:
+            # the whole fill on either side: the dot's result then feeds
+            # the masks where it is made (around the dot alone the branch
+            # cost 0.17 us a work item on the chip, here 0.02)
+            def side(narrow: bool):
+                def fill():
+                    keys(narrow)
+                return fill
+
+            lax.cond(lax.ne(one_ref[0], i32(0)), side(True), side(False))
+            inf = lax.full(shape, _INF, f32)
+        else:
+            inf = keys(False)
 
         col = lax.broadcasted_iota(i32, shape, 1)
         lane = lax.broadcasted_iota(i32, out_ref.shape[1:], 1)
@@ -146,7 +191,7 @@ def bucket_walk(item_lists: jax.Array, walked: jax.Array,
                 group_rows: jax.Array, group_ids: jax.Array | None,
                 buckets: jax.Array, bucket_ids: jax.Array,
                 bucket_sqs: jax.Array, *, k: int, exclude_zero: bool,
-                zero_eps: float):
+                zero_eps: float, onepass: jax.Array | None = None):
     """The k nearest slots of every work item's rows in its list:
     ``item_lists`` (W,) int32 the list of each work item (one list's items
     side by side), ``walked`` int32 how many are real; ``group_rows`` (W,
@@ -156,20 +201,32 @@ def bucket_walk(item_lists: jax.Array, walked: jax.Array,
     (W, group, 128) int32: a row's k nearest slots of the list by masked
     distance, ascending, in the first k lanes (k <= 128), -1 past a list's
     unmasked slots where it has fewer; the blocks of the work items past
-    ``walked`` hold nothing meant."""
+    ``walked`` hold nothing meant.
+
+    ``onepass`` is the one-pass rule's verdict on this call's operands, a
+    bool scalar made on the device (``ops.distance.bf16_exact`` of
+    ``buckets`` and of the batch's query rows): the kernel then holds both
+    dots and every work item takes the one the scalar names — one bf16 x
+    bf16 pass where it is true, which for bf16 numbers returns the six
+    passes' keys. None: the kernel with the six-pass dot alone."""
     n_items, group, d = group_rows.shape
     cap = buckets.shape[1]
     f32 = jnp.float32
     exclude_self = group_ids is not None
     operands = (group_rows, buckets, bucket_ids, bucket_sqs)
 
-    # (index maps take the grid index, then the two prefetched scalars)
-    def item(w, lists, walked):
+    scalars = [item_lists.astype(jnp.int32),
+               jnp.reshape(walked, (1,)).astype(jnp.int32)]
+    if onepass is not None:
+        scalars.append(jnp.reshape(onepass, (1,)).astype(jnp.int32))
+
+    # (index maps take the grid index, then the prefetched scalars)
+    def item(w, *scalars):
         return w, 0, 0
 
     plane = pl.BlockSpec(
         (_PLANE_ROWS, cap),
-        lambda w, lists, walked: (lists[w] // _PLANE_ROWS, 0))
+        lambda w, lists, *_: (lists[w] // _PLANE_ROWS, 0))
     specs, args = [pl.BlockSpec((1, group, d), item)], [group_rows.astype(f32)]
     if exclude_self:
         specs.append(pl.BlockSpec((1, group, _LANES), item))
@@ -179,15 +236,16 @@ def bucket_walk(item_lists: jax.Array, walked: jax.Array,
     return pl.pallas_call(
         functools.partial(
             _walk_kernel, k=k, exclude_self=exclude_self,
-            exclude_zero=exclude_zero, zero_eps=zero_eps),
+            exclude_zero=exclude_zero, zero_eps=zero_eps,
+            onepass=onepass is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(n_items,),
             in_specs=[
                 *specs,
                 # the bucket where it rests in the store, by the list's index
                 pl.BlockSpec(
-                    (1, cap, d), lambda w, lists, walked: (lists[w], 0, 0)),
+                    (1, cap, d), lambda w, lists, *_: (lists[w], 0, 0)),
                 plane, plane,
             ],
             out_specs=pl.BlockSpec((1, group, _LANES), item),
@@ -197,9 +255,9 @@ def bucket_walk(item_lists: jax.Array, walked: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=bucket_walk_vmem_bytes(
-                group, cap, d, buckets.dtype.itemsize) + _VMEM_HEADROOM,
+                group, cap, d, buckets.dtype.itemsize,
+                onepass is not None) + _VMEM_HEADROOM,
         ),
         interpret=_interpret(),
-    )(item_lists.astype(jnp.int32),
-      jnp.reshape(walked, (1,)).astype(jnp.int32), *args, buckets,
-      bucket_ids.astype(jnp.int32), bucket_sqs.astype(f32))
+    )(*scalars, *args, buckets, bucket_ids.astype(jnp.int32),
+      bucket_sqs.astype(f32))

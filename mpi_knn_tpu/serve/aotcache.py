@@ -121,6 +121,9 @@ def index_facts(index) -> dict:
         # both branches of the one-pass rule (absent otherwise, so every
         # other entry's address is unchanged)
         facts["onepass"] = True
+    if getattr(index, "mean_frac", None) is not None:
+        # (a clustered store's: one more operand of the batch program)
+        facts["mean_frac"] = True
     mesh = getattr(index, "mesh", None)
     if mesh is not None:
         facts["mesh"] = {

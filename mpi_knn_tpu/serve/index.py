@@ -59,6 +59,15 @@ from mpi_knn_tpu.parallel.partition import (
 )
 
 
+def onepass_holds(index) -> bool:
+    """Whether ``index`` still holds the one-pass rule's corpus-side fact
+    (``index.onepass``, a device scalar of the kinds that keep one: read
+    here from its host shadow, never from the device). An upsert of a row
+    that is no bf16 number drops it for good (``serve/mutate.py``)."""
+    return getattr(index, "onepass", None) is not None and (
+        index.__dict__.get("_onepass_holds", True))
+
+
 class BatchLayout:
     """What one index kind's batch program is and how it is called. One
     instance per kind, chosen when the index is built and carried by it
@@ -76,6 +85,7 @@ class BatchLayout:
     tiled = False  # the program takes (qt, q_tile, ·) stacks, not rows ...
     pretiled = False  # ... and a prepared batch already has that shape
     exchange_stats = False  # third output: the per-shard exchange stats
+    onepass_gauge = "serve_index_onepass"  # what says the fact holds
 
     def serve_fn(self):
         """The function the engine jits."""

@@ -74,6 +74,7 @@ from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.distance import bf16_exact
 from mpi_knn_tpu.resilience.heartbeat import maybe_beat
+from mpi_knn_tpu.serve.index import onepass_holds
 
 __all__ = [
     "BucketOverflowError",
@@ -659,14 +660,11 @@ def upsert_rows(index, ids, rows, config: KNNConfig | None = None) -> dict:
             n = int(ids.shape[0])
             bucket = bucket_rows(n, cfg.mutation_bucket)
             rows_p = _pad_chunk(rows, bucket, 0.0)
-            # the corpus side of the one-pass rule stops holding with the
-            # first row that is no bf16 number; asked of the rows only
-            # while the index still holds the fact
-            breaks_onepass = (
-                serial and index.onepass is not None
-                and index.__dict__.get("_onepass_holds", True)
-                and not bf16_exact(rows)
-            )
+            # the corpus side of the one-pass rule (the dense index's, and
+            # a clustered store's: ``ivf/index.py store_onepass``) stops
+            # holding with the first row that is no bf16 number; asked of
+            # the rows only while the index still holds the fact
+            breaks_onepass = onepass_holds(index) and not bf16_exact(rows)
             ex = get_mutation_executable(index, cfg, bucket, KIND_UPSERT)
         with writer_lock(index):
             with mutation_phase("plan"):
@@ -709,18 +707,18 @@ def upsert_rows(index, ids, rows, config: KNNConfig | None = None) -> dict:
                             *args, index.tiles, index.tile_ids,
                             index.tile_sqs
                         )
-                        if off is not None:
-                            # the same programs take their other branch
-                            index.onepass = off
-                            index.__dict__["_onepass_holds"] = False
                     else:
                         out = ex(*args, *_store_args(index))
                         _swap_store(index, *_normalize_store_out(index, out))
+                    if off is not None:
+                        # the same programs take their other branch
+                        index.onepass = off
+                        index.__dict__["_onepass_holds"] = False
                 with mutation_phase("commit"):
                     commit()
             stats = fl.stats()
         if breaks_onepass:
-            reg.gauge("serve_index_onepass").set(0.0)
+            reg.gauge(index.layout.onepass_gauge).set(0.0)
         _stamp_gauges(reg, stats)
     _note_mutation(reg, "upserts", n, n, t0)
     return {"upserted": n, "bucket": bucket, **stats}

@@ -60,9 +60,11 @@ class KNNResult:
         (``backends/serial.py _merge_carried``), one row a ring device;
         ``MetricsRegistry.count_bins_chunks`` adds it to
         ``knn_select_bins_chunks_total``. None where no bound rides.
-      ivf_probe: int32 (6,), what a clustered index's batch probed,
+      ivf_probe: int32 (7,), what a clustered index's batch probed,
         ``[probes, bucket_cap, live rows probed, distinct partitions,
-        their live rows, work items walked (0: the row-major program)]``
+        their live rows, work items walked (0: the row-major program),
+        those walked in one bf16 pass (the store and the batch's query
+        rows bf16 numbers; else 0)]``
         (``ivf/search.py probe_counts``);
         ``MetricsRegistry.count_ivf_probe`` adds it to the
         ``ivf_probe_*_total`` counters. None from every other index.

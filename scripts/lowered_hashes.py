@@ -22,7 +22,8 @@ allocated): a serving cell's programs are its index kind's batch program
 (the index's layout, as ``serve.engine`` lowers it: dense, dense with tags
 where the configuration names ``max_query_tags``, clustered where it names
 ``partitions``) at every bucket its traffic warms (``warm_sizes``), a dense
-L2 one with and without the one-pass operand; a one-shot cell's is
+L2 one and a clustered one with and without the one-pass operand; a
+one-shot cell's is
 ``backends.serial._search_stack`` at its traffic's ``slice_rows``; a ring cell's the sharded call. Rows, width and
 ``knn`` are the configuration file's. The text is the jaxpr
 (``jax.make_jaxpr``) traced as the chip traces it — ``jax.default_backend``
@@ -121,13 +122,21 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
 
     def clustered(cfg, rows, dim):
         """The clustered index the configuration states: its lists, their
-        height and the probe count are all in ``knn``."""
+        height and the probe count are all in ``knn``; with and without
+        the store's one-pass fact."""
         lists, cap = cfg.partitions, cfg.bucket_cap
-        yield "", ivf_index.IVFIndex(
-            cfg, rows, dim, lists, cap, cfg.nprobe, None,
-            arg((lists, dim), jnp.float32), arg((lists,), jnp.float32),
-            arg((lists, cap, dim), jnp.float32),
-            arg((lists, cap), jnp.int32), arg((lists, cap), jnp.float32))
+        store = (arg((lists, dim), jnp.float32), arg((lists,), jnp.float32),
+                 arg((lists, cap, dim), jnp.float32),
+                 arg((lists, cap), jnp.int32), arg((lists, cap), jnp.float32))
+        # (a checkout from before PR 46 knows no fact: its one program
+        # goes under "-nofact", beside the later checkouts' own)
+        if hasattr(ivf_index.IVFIndex, "onepass"):
+            yield "", ivf_index.IVFIndex(
+                cfg, rows, dim, lists, cap, cfg.nprobe, None, *store,
+                onepass=arg((), jnp.bool_),
+                mean_frac=arg((dim,), jnp.float32))
+        yield "-nofact", ivf_index.IVFIndex(
+            cfg, rows, dim, lists, cap, cfg.nprobe, None, *store)
 
     def served(indexes, cfg, buckets):
         """The batch programs of an index, as its layout lowers them."""

@@ -46,7 +46,19 @@ def _isolated_cache(monkeypatch):
     leaves none behind (other suites must keep running cache-off)."""
     monkeypatch.delenv(aotcache.ENV_VAR, raising=False)
     aotcache.reset_for_tests()
+    # jax's own persistent cache off as well: an entry point that ran
+    # earlier in this process leaves it on (``use_compile_cache``), and on
+    # the CPU an executable that came out of it does not serialize whole
+    # (its reload ends in "Function ... not found")
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax_dir = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
     yield
+    jax.config.update("jax_compilation_cache_dir", jax_dir)
+    compilation_cache.reset_cache()
     aotcache.reset_for_tests()
 
 

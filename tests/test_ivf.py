@@ -724,13 +724,41 @@ def walk_index():
     rng = np.random.default_rng(42)
     X = _clustered(rng, m=4096, d=128, centers=24)
     idx = build_ivf_index(X, KNNConfig(k=K, partitions=_WALK_P, nprobe=4))
+    assert idx.onepass is None  # fractional rows: no branch anywhere
+    return _worn(idx), X
+
+
+def _worn(idx):
+    """``idx`` with a list emptied and slots tombstoned in two others."""
+    import jax.numpy as jnp
+
     ids = np.asarray(idx.bucket_ids).copy()
     sizes = (ids >= 0).sum(axis=1)
     ids[int(np.argsort(sizes)[_WALK_P // 2])] = -1  # an empty list
     for p in np.argsort(sizes)[-2:]:  # dead slots in the two largest
         ids[p, ::3] = -1
     idx.bucket_ids = jnp.asarray(ids)
-    return idx, X
+    return idx
+
+
+@pytest.fixture(scope="module")
+def whole_walk_index():
+    """:func:`walk_index` over WHOLE numbers 0-255 (descriptor-like): the
+    build centres by a whole-number mean, every stored element is a bf16
+    number and the index holds the one-pass fact (ISSUE 46)."""
+    rng = np.random.default_rng(46)
+    cen = rng.random((24, 128)) * 140.0
+    X = cen[rng.integers(0, 24, 4096)] + rng.standard_normal(
+        (4096, 128)) * 30.0
+    X = np.clip(np.rint(X), 0, 255).astype(np.float32)
+    idx = build_ivf_index(X, KNNConfig(k=K, partitions=_WALK_P, nprobe=4))
+    assert idx.onepass is not None and bool(idx.onepass)
+    assert np.array_equal(idx.mu, np.rint(idx.mu))
+    # what the rounding took off, for the finish to take off again
+    np.testing.assert_array_equal(
+        np.asarray(idx.mean_frac),
+        X.astype(np.float64).mean(axis=0).astype(np.float32) - idx.mu)
+    return _worn(idx), X
 
 
 def _walk_queries(idx, X, rows: int, skewed: bool):
@@ -741,7 +769,9 @@ def _walk_queries(idx, X, rows: int, skewed: bool):
     rng = np.random.default_rng(rows)
     pick = rng.choice(len(X), size=rows, replace=False)
     q = X[pick] - idx.mu
-    if skewed:
+    if skewed and idx.onepass is not None:  # whole numbers stay whole
+        q = q[:1] + rng.integers(-2, 3, q.shape)
+    elif skewed:
         q = q[:1] + 1e-3 * rng.standard_normal(q.shape).astype(np.float32)
     q_ids = pick.astype(np.int32)
     if not skewed:
@@ -836,6 +866,206 @@ def test_bucket_major_matches_row_major(walk_index, rows, skewed, nprobe,
         np.testing.assert_allclose(walk[0], wd, rtol=1e-5, atol=2e-3)
 
 
+def _walk_sides(idx, q, q_ids, nprobe: int, exclude_self: bool):
+    """The walk's kernel on both sides of the one-pass rule over one
+    batch's work items: ``(slots under the flag TRUE, under FALSE, under
+    no flag at all, work items walked)``, each (W, 8, 128) as the kernel
+    left them."""
+    import jax
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.ivf import search
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk
+
+    cfg = idx.cfg.replace(nprobe=nprobe, exclude_self=exclude_self)
+
+    @jax.jit
+    def sides(q, q_ids):
+        _, probe = search.score_centroids(
+            q, idx.centroids, idx.centroid_sqs, nprobe)
+        lists, rows, _, _, walked = search.invert_probe(probe, _WALK_P)
+        at = jnp.maximum(rows, 0)
+
+        def walk(flag):
+            return bucket_walk(
+                lists, walked, jnp.take(q, at, axis=0),
+                jnp.take(q_ids, at, axis=0) if exclude_self else None,
+                idx.buckets, idx.bucket_ids, idx.bucket_sqs, k=cfg.k,
+                exclude_zero=cfg.exclude_zero, zero_eps=cfg.zero_eps,
+                onepass=flag)
+
+        return (walk(jnp.asarray(True)), walk(jnp.asarray(False)),
+                walk(None), walked)
+
+    one, six, plain, walked = sides(jnp.asarray(q), jnp.asarray(q_ids))
+    return np.asarray(one), np.asarray(six), np.asarray(plain), int(walked)
+
+
+@pytest.mark.parametrize("exclude_self", [False, True])
+@pytest.mark.parametrize("nprobe", [1, 4, _WALK_P])
+@pytest.mark.parametrize("rows,skewed", [
+    (8, False), (64, False), (256, False), (256, True)])
+def test_the_walks_two_sides_name_the_same_slots(whole_walk_index, rows,
+                                                 skewed, nprobe,
+                                                 exclude_self):
+    """Whole-number store, whole-number query rows: the one-pass side's
+    slots are the six-pass side's in every lane — the -1s past a short or
+    emptied list, the dead slots, the padding rows, the self and zero
+    masks included — and the kernel with no branch names them too; the
+    answers of the batch program are then the same bits, and its seventh
+    count is its sixth."""
+    import jax
+
+    from mpi_knn_tpu.ivf import search
+    from mpi_knn_tpu.ops.distance import bf16_exact
+
+    idx, X = whole_walk_index
+    q, q_ids = _walk_queries(idx, X, rows, skewed)
+    assert bf16_exact(q) and bf16_exact(np.asarray(idx.buckets))
+    one, six, plain, walked = _walk_sides(idx, q, q_ids, nprobe,
+                                          exclude_self)
+    assert walked > 0
+    assert np.array_equal(one[:walked], six[:walked])
+    assert np.array_equal(six[:walked], plain[:walked])
+    if nprobe == _WALK_P:  # the emptied list is among them: all -1
+        assert (one[:walked, :, :K] == -1).all(axis=(1, 2)).any()
+    cfg = idx.cfg.replace(nprobe=nprobe, exclude_self=exclude_self)
+    store = (idx.centroids, idx.centroid_sqs, idx.buckets, idx.bucket_ids,
+             idx.bucket_sqs)
+    out = [jax.jit(lambda *a, f=fact: search.bucket_major_tile(
+        *a, cfg, nprobe, f))(q, q_ids, *store)
+        for fact in (idx.onepass, None)]
+    for a, b in zip(out[0][:2], out[1][:2]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(out[0][2][4]) == int(out[0][2][3]) == walked
+    assert int(out[1][2][4]) == 0 and int(out[1][2][3]) == walked
+
+
+@pytest.mark.parametrize("exclude_self", [False, True])
+@pytest.mark.parametrize("rows,nprobe", [(8, 1), (64, 4), (256, _WALK_P)])
+def test_fractional_query_rows_take_the_six_pass_side(whole_walk_index,
+                                                      rows, nprobe,
+                                                      exclude_self):
+    """A qualifying store, query rows that are no bf16 numbers: the flag
+    is false on the device, no work item counts as one pass, and the
+    answer is the row-major program's."""
+    idx, X = whole_walk_index
+    q, q_ids = _walk_queries(idx, X, rows, False)
+    q = q + np.float32(0.3)
+    cfg = idx.cfg.replace(nprobe=nprobe, exclude_self=exclude_self)
+    import jax
+
+    from mpi_knn_tpu.ivf import search
+
+    store = (idx.centroids, idx.centroid_sqs, idx.buckets, idx.bucket_ids,
+             idx.bucket_sqs)
+    d, i, counts = jax.jit(lambda *a: search.bucket_major_tile(
+        *a, cfg, nprobe, idx.onepass))(q, q_ids, *store)
+    assert int(counts[4]) == 0 < int(counts[3])
+    row, _, _ = _both_tiles(idx, q, q_ids, nprobe, exclude_self)
+    _assert_same_answers(row, (np.asarray(d), np.asarray(i), None), q, idx)
+
+
+@pytest.mark.parametrize("exclude_self", [False, True])
+@pytest.mark.parametrize("rows,nprobe", [(64, 1), (256, 4)])
+def test_the_finish_reads_the_operands_the_unrounded_mean_left(
+        whole_walk_index, rows, nprobe, exclude_self, monkeypatch):
+    """The rounded mean serves the KEYS. With ``mean_frac`` the finish
+    names the same ids at the same distances (to float32's rounding: L2
+    does not see the translation) — from fractional operands, as before
+    the rule: a finish on operands rounded to bfloat16's precision (the
+    clustered cell's control, ``benchmark/serve_launcher_ivf.py``) then
+    misses the distances by parts in a thousand, where on the store's
+    whole numbers alone it would return them to the bit and the control
+    could fail nothing."""
+    import jax
+
+    from mpi_knn_tpu.ivf import search
+
+    idx, X = whole_walk_index
+    q, q_ids = _walk_queries(idx, X, rows, False)
+    cfg = idx.cfg.replace(nprobe=nprobe, exclude_self=exclude_self)
+    store = (idx.centroids, idx.centroid_sqs, idx.buckets, idx.bucket_ids,
+             idx.bucket_sqs)
+
+    def answers(frac):
+        d, i, _ = jax.jit(lambda *a: search.bucket_major_tile(
+            *a, cfg, nprobe, idx.onepass, frac))(q, q_ids, *store)
+        return np.asarray(d), np.asarray(i)
+
+    (d_w, i_w), (d_f, i_f) = answers(None), answers(idx.mean_frac)
+    np.testing.assert_array_equal(i_f, i_w)
+    found = np.isfinite(d_w)
+    assert found.any() and np.array_equal(np.isfinite(d_f), found)
+    np.testing.assert_allclose(d_f[found], d_w[found], rtol=2e-6)
+
+    exact = search.rerank_exact_topk
+
+    def rounded(q_x, q_ids, q_sq, rows, *rest, **kw):
+        def bf16(x):
+            return jax.lax.reduce_precision(x, exponent_bits=8,
+                                            mantissa_bits=7)
+        return exact(bf16(q_x), q_ids, q_sq, bf16(rows), *rest, **kw)
+
+    monkeypatch.setattr(search, "rerank_exact_topk", rounded)
+    (r_w, _), (r_f, _) = answers(None), answers(idx.mean_frac)
+    np.testing.assert_array_equal(r_w, d_w)  # whole numbers: nothing lost
+    err = np.abs(r_f[found] - d_f[found]) / d_f[found]
+    assert err.max() > 1e-4, err.max()
+
+
+def test_the_fact_counts_the_one_pass_kernels_vmem():
+    """A store whose plain kernel just fits the walk's share of VMEM and
+    whose two-dot kernel, with its bfloat16 copies, would not: answered
+    bucket-major, granted no fact (and so compiled without the branch)."""
+    import jax.numpy as jnp
+
+    from mpi_knn_tpu.ivf.index import store_onepass
+    from mpi_knn_tpu.ivf.search import (
+        _WALK_VMEM_BYTES,
+        PROBE_GROUP,
+        bucket_major_engages,
+    )
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk_vmem_bytes
+
+    cfg = KNNConfig(k=K, partitions=1, nprobe=1)
+    for cap, fact in ((46000, True), (56656, False)):
+        plain = bucket_walk_vmem_bytes(PROBE_GROUP, cap, 128)
+        both = bucket_walk_vmem_bytes(PROBE_GROUP, cap, 128, onepass=True)
+        assert plain <= _WALK_VMEM_BYTES
+        assert (both <= _WALK_VMEM_BYTES) == fact
+        assert bucket_major_engages(8, 1, 1, cap, 128)
+        assert bucket_major_engages(8, 1, 1, cap, 128, onepass=True) == fact
+        got = store_onepass(cfg, jnp.ones((1, cap, 128), jnp.float32), None)
+        assert (got is not None and bool(got)) == fact
+
+
+def test_a_fractional_store_compiles_no_branch(walk_index, whole_walk_index):
+    """The fact decides the program: a fractional store's batch program
+    holds the walk's one dot and takes no flag; a qualifying store's holds
+    one dot more and one scalar argument more. And a fractional corpus is
+    centred by the mean it always was."""
+    from mpi_knn_tpu.serve.engine import expected_args, lower_bucket
+
+    texts = {}
+    for name, (idx, X) in (("fractional", walk_index),
+                           ("whole", whole_walk_index)):
+        cfg = idx.cfg.replace(query_bucket=64)
+        texts[name] = lower_bucket(idx, cfg, 64)[0].as_text()
+        args = expected_args(idx, cfg, 64)
+        assert (((), "bool") in args) == (name == "whole")
+        assert (((128,), "float32") in args) == (name == "whole")
+    dots = {n: t.count("stablehlo.dot_general") for n, t in texts.items()}
+    assert dots["whole"] == dots["fractional"] + 1, dots
+    assert "bf16" in texts["whole"] and "bf16" not in texts["fractional"]
+    idx, X = walk_index
+    np.testing.assert_array_equal(idx.mu, X.astype(np.float64).mean(axis=0))
+    live = np.asarray(idx.bucket_ids) >= 0
+    np.testing.assert_array_equal(
+        np.asarray(idx.buckets)[live],
+        (X[np.asarray(idx.bucket_ids)[live]] - idx.mu).astype(np.float32))
+
+
 def test_bucket_major_engages_by_shapes_alone():
     from mpi_knn_tpu.ivf.search import (
         PROBE_GROUP,
@@ -870,23 +1100,38 @@ def test_bucket_major_engages_by_shapes_alone():
                          128, 1024)
 
 
-@pytest.mark.parametrize("path,policy", [
-    ("bucket_major", "exact"), ("row_major", "mixed")])
-def test_served_batch_says_which_probe_answered(walk_index, path, policy,
+@pytest.mark.parametrize("path,policy,store,shift", [
+    ("bucket_major", "exact", "fractional", 0.0),
+    ("row_major", "mixed", "fractional", 0.0),
+    ("bucket_major", "exact", "whole", 0.0),
+    ("bucket_major", "exact", "whole", 0.25),  # fractional query rows
+    ("row_major", "mixed", "whole", 0.0),
+])
+def test_served_batch_says_which_probe_answered(walk_index, whole_walk_index,
+                                                path, policy, store, shift,
                                                 monkeypatch):
     from mpi_knn_tpu.obs import metrics as obs_metrics
     from mpi_knn_tpu.ivf.search import PROBE_GROUP, bucket_major_items
     from mpi_knn_tpu.serve import ServeSession
+    from mpi_knn_tpu.serve.index import onepass_holds
 
-    idx, X = walk_index
+    idx, X = walk_index if store == "fractional" else whole_walk_index
     reg = obs_metrics.MetricsRegistry()
     monkeypatch.setattr(obs_metrics, "_default_registry", reg)
+    # the gauges are stamped where an executable is built: build it here
+    idx._cache.clear()
     sess = ServeSession(idx, config=idx.cfg.replace(
         precision_policy=policy, query_bucket=64))
-    (out,) = list(sess.stream([X[:64]]))
+    (out,) = list(sess.stream([X[:64] + np.float32(shift)]))
     probed = np.asarray(out.ivf_probe)
-    assert probed.shape == (6,) and probed[0] == 64 * 4
+    # in one pass: every work item of the batch, or none
+    onepass = store == "whole" and not shift and path == "bucket_major"
+    assert probed[6] == (probed[5] if onepass else 0)
     text = reg.to_prometheus().splitlines()
+    assert f"ivf_probe_groups_onepass_total {float(probed[6])}" in text
+    assert f"ivf_index_onepass {float(store == 'whole')}" in text
+    assert onepass_holds(idx) == (store == "whole")
+    assert probed.shape == (7,) and probed[0] == 64 * 4
     assert f'ivf_probe_batches_total{{path="{path}"}} 1.0' in text
     assert not any(ln.startswith("ivf_probe_batches_total{")
                    and path not in ln for ln in text)

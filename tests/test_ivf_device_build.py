@@ -72,6 +72,182 @@ def test_device_build_is_the_host_build(sample, init):
         np.testing.assert_array_equal(a, b)
 
 
+# ---- the whole-number mean and the store's one-pass fact (ISSUE 46) --------
+
+@pytest.mark.parametrize("m,d,fact", [
+    (1000, 128, True),  # a mean that is no whole number before the rounding
+    (1024, 128, True),
+    (1000, 16, None),  # off the lane grid: the walk never takes this store
+])
+def test_a_whole_number_corpus_is_centred_by_a_whole_number_mean(m, d, fact):
+    """Host and device builds round the SAME mean and read the same fact:
+    the store holds whole numbers, every one a bf16 number."""
+    X = whole_rows(41, m=m, d=d)
+    cfg = cfg_for(kmeans_sample=256)
+    host = build_ivf_index(X, cfg)
+    dev = build_ivf_index(jnp.asarray(X), cfg)
+    plain = X.astype(np.float64).mean(axis=0)
+    np.testing.assert_array_equal(host.mu, np.rint(plain))
+    np.testing.assert_array_equal(dev.mu, host.mu)
+    assert host.mu.dtype == dev.mu.dtype == np.float64
+    if m == 1000:
+        assert not np.array_equal(plain, np.rint(plain))
+    for idx in (host, dev):
+        store = np.asarray(idx.buckets)
+        np.testing.assert_array_equal(store, np.rint(store))
+        assert np.abs(store).max() <= 256
+        assert (idx.onepass is None) == (fact is None)
+        # what the rounding took off rides with the fact, and alone with it
+        assert (idx.mean_frac is None) == (fact is None)
+        if fact:
+            assert isinstance(idx.onepass, jax.Array) and bool(idx.onepass)
+            frac = plain.astype(np.float32).astype(np.float64) - idx.mu
+            assert frac.any() and np.abs(frac).max() <= 0.5
+            assert idx.mean_frac.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(idx.mean_frac), frac)
+    for name in STORE:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(dev, name)), np.asarray(getattr(host, name)),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("on_device", [False, True])
+@pytest.mark.parametrize("what,center,fact", [
+    ("whole", True, True),
+    ("whole", False, True),  # 0-255 as they are: bf16 numbers too
+    ("to_1000", True, False),  # whole, but 9 bits and more: not bf16
+    ("half_planted", True, False),  # one 0.5: the mean is the plain mean
+    ("half_planted", False, True),  # 0.5 IS a bf16 number
+    ("third_planted", False, False),  # 0.3 is not: read from the bits
+    ("fractional", True, False),
+])
+def test_the_fact_is_read_from_the_stores_bits(what, center, fact,
+                                               on_device):
+    X = whole_rows(43, m=1024, d=128)
+    if what == "to_1000":
+        X = X * np.float32(4.0) - np.float32(3.0)
+    elif what == "half_planted":
+        X[517, 3] = 0.5
+    elif what == "third_planted":
+        X[517, 3] = 0.3
+    elif what == "fractional":
+        X = X + np.float32(0.125)  # bf16 numbers before centring, not after
+    idx = build_ivf_index(jnp.asarray(X) if on_device else X,
+                          cfg_for(center=center, kmeans_sample=256))
+    assert (idx.onepass is not None) == fact
+    whole = bool((X == np.rint(X)).all())
+    # nothing to take off where nothing was rounded, or no walk to rank by
+    assert (idx.mean_frac is not None) == (fact and whole and center)
+    if center:
+        assert np.array_equal(idx.mu, np.rint(idx.mu)) == whole
+        if not whole and not on_device:  # the mean it always was
+            np.testing.assert_array_equal(
+                idx.mu, X.astype(np.float64).mean(axis=0))
+    # the fact is the store's: every element's low 16 bits
+    bits = np.asarray(idx.buckets).view(np.uint32)
+    assert bool((bits & 0xFFFF == 0).all()) == fact
+
+
+def test_a_saved_index_keeps_what_the_rounding_took_off(tmp_path):
+    """The fact is read anew from the loaded store's bits; the fraction of
+    the mean cannot be, so the archive carries it (one from before it did
+    loads with none: its mean was not rounded)."""
+    from mpi_knn_tpu.ivf import load_ivf_index, save_ivf_index
+
+    X = whole_rows(44, m=1000, d=128)
+    idx = build_ivf_index(X, cfg_for(kmeans_sample=256))
+    back = load_ivf_index(save_ivf_index(idx, str(tmp_path / "whole")))
+    assert bool(back.onepass)
+    np.testing.assert_array_equal(
+        np.asarray(back.mean_frac), np.asarray(idx.mean_frac))
+    q = whole_rows(46, m=64, d=128)
+    for a, b in zip(search_ivf(back, q), search_ivf(idx, q)):
+        np.testing.assert_array_equal(a, b)
+    frac = build_ivf_index(X + np.float32(0.125), cfg_for(kmeans_sample=256))
+    back = load_ivf_index(save_ivf_index(frac, str(tmp_path / "frac")))
+    assert back.onepass is None and back.mean_frac is None
+
+
+def test_a_device_mean_of_a_fractional_corpus_is_the_float32_mean():
+    """What the device path did before the rule, number for number: the
+    float32 nearest the float64 mean of the blocks' sums."""
+    X = whole_rows(45, m=1000, d=16) + np.float32(0.25)
+    idx = build_ivf_index(jnp.asarray(X), cfg_for(kmeans_sample=256))
+    sums = np.asarray(column_sums(jnp.asarray(X)), np.float64)
+    want = (sums.sum(axis=0) / 1000).astype(np.float32).astype(np.float64)
+    np.testing.assert_array_equal(idx.mu, want)
+    both, whole = column_sums(jnp.asarray(X), whole=True)
+    np.testing.assert_array_equal(np.asarray(both), sums)
+    assert not np.asarray(whole).any()
+
+
+@pytest.mark.parametrize("m,block,bad", [
+    (1024, 256, None), (1000, 333, None), (1000, 333, 999), (1024, 256, 300),
+    (50, 50, 7)])
+def test_column_sums_say_which_blocks_are_whole(m, block, bad):
+    X = whole_rows(9, m=m, d=8)
+    if bad is not None:
+        X[bad, 2] += np.float32(0.5)
+    sums, whole = column_sums(jnp.asarray(X), block=block, whole=True)
+    np.testing.assert_array_equal(
+        np.asarray(sums), np.asarray(column_sums(jnp.asarray(X),
+                                                 block=block)))
+    want = np.ones(-(-m // block), bool)
+    if bad is not None:
+        want[bad // block] = False
+    np.testing.assert_array_equal(np.asarray(whole), want)
+
+
+def test_an_upsert_of_a_fractional_row_turns_the_fact_off_in_place():
+    """The fact is a device scalar the batch program takes: a row that is
+    no bf16 number after centring flips it for good, nothing recompiles,
+    the gauge reads 0 and the next batch — six passes — holds the row."""
+    from mpi_knn_tpu.obs.metrics import watch_compiles
+    from mpi_knn_tpu.serve.index import onepass_holds
+    from mpi_knn_tpu.serve import ServeSession
+    from mpi_knn_tpu.serve.mutate import upsert_rows, warm_mutation
+
+    X = whole_rows(47, m=2048, d=128)
+    q = whole_rows(48, m=64, d=128)
+    cfg = cfg_for(k=4, nprobe=8, kmeans_sample=512, bucket_headroom=0.25,
+                  mutation_bucket=8)
+    idx = build_ivf_index(jnp.asarray(X), cfg)
+    reg = obs_metrics.MetricsRegistry()
+    with _swap_registry(reg):
+        sess = ServeSession(idx)
+        warm_mutation(idx, cfg, sizes=[8])
+        (out,) = list(sess.stream([q]))
+        counts = np.asarray(out.ivf_probe)
+        assert counts[6] == counts[5] > 0 and onepass_holds(idx)
+        assert reg.gauge("ivf_index_onepass").value == 1.0
+        upsert_rows(idx, [5000], q[:1] + np.float32(1.0))
+        assert bool(idx.onepass) and onepass_holds(idx)  # whole: it holds
+        with watch_compiles() as compiles:
+            upsert_rows(idx, [5001], q[3:4] + np.float32(0.3))
+            (out,) = list(sess.stream([q]))
+        assert compiles == []
+        assert idx.onepass is not None and not bool(idx.onepass)
+        assert not onepass_holds(idx)
+        assert reg.gauge("ivf_index_onepass").value == 0.0
+        counts = np.asarray(out.ivf_probe)
+        assert counts[6] == 0 < counts[5]
+        upsert_rows(idx, [5002], q[5:6] + np.float32(2.0))
+        assert not bool(idx.onepass)  # a whole row does not bring it back
+    # every list probed: the reference over the rows the index now holds
+    rows = np.concatenate([X, q[:1] + 1.0, q[3:4] + np.float32(0.3)])
+    ids = np.concatenate([np.arange(2048), [5000, 5001]])
+    d2 = ((q[:, None].astype(np.float64) - rows[None].astype(np.float64))
+          ** 2).sum(-1)
+    d2[d2 == 0] = np.inf  # exclude_zero
+    order = np.argsort(d2, axis=1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(np.asarray(out.ids), ids[order])
+    np.testing.assert_allclose(
+        np.asarray(out.dists), np.take_along_axis(d2, order, axis=1),
+        rtol=1e-5, atol=0.25)  # the matmul form at norms of 4e5 (an ulp
+    # of their sum is 0.0625), on operands the finish made fractional
+    assert out.ids[3, 0] == 5001 and out.ids[0, 0] == 5000
+
+
 @pytest.mark.parametrize("m,parts,sample,headroom", [
     (1000, 7, 300, 0.0),  # no block of the passes divides the rows
     (4096, 16, 1024, 0.0),
@@ -223,9 +399,9 @@ def test_probe_counts_against_a_hand_count(nprobe):
         partitions=4, nprobe=nprobe, kmeans_sample=None))
     q = whole_rows(8, m=50, d=16, centres=4)
     res = query_knn(q, idx)
-    probes, cap, live, distinct, distinct_live, walked = np.asarray(
+    probes, cap, live, distinct, distinct_live, walked, onepass = np.asarray(
         res.ivf_probe).tolist()
-    assert walked == 0  # d = 16: the row-major program, no work items
+    assert walked == onepass == 0  # d = 16: row-major, no work items
     # by hand: the padded batch is one 64-row bucket; a padding row is a
     # row of zeros in the centred frame, and probes like any other
     qc = np.zeros((64, 16), np.float64)
@@ -242,16 +418,19 @@ def test_probe_counts_against_a_hand_count(nprobe):
 
 def test_probe_counters_arithmetic():
     reg = obs_metrics.MetricsRegistry()
-    reg.count_ivf_probe(np.array([128, 288, 33152, 4, 1024, 24], np.int32))
-    reg.count_ivf_probe(np.array([64, 288, 100, 2, 500, 0], np.int32))
+    reg.count_ivf_probe(
+        np.array([128, 288, 33152, 4, 1024, 24, 24], np.int32))
+    reg.count_ivf_probe(np.array([64, 288, 100, 2, 500, 0, 0], np.int32))
+    reg.count_ivf_probe(np.array([64, 288, 100, 2, 500, 5, 0], np.int32))
     text = reg.to_prometheus()
-    for line in ("ivf_probe_slots_total 55296.0",
-                 "ivf_probe_live_rows_total 33252.0",
-                 'ivf_probe_partitions_total{kind="probes"} 192.0',
-                 'ivf_probe_partitions_total{kind="distinct"} 6.0',
-                 "ivf_probe_distinct_live_rows_total 1524.0",
-                 "ivf_probe_groups_total 24.0",
-                 'ivf_probe_batches_total{path="bucket_major"} 1.0',
+    for line in ("ivf_probe_slots_total 73728.0",
+                 "ivf_probe_live_rows_total 33352.0",
+                 'ivf_probe_partitions_total{kind="probes"} 256.0',
+                 'ivf_probe_partitions_total{kind="distinct"} 8.0',
+                 "ivf_probe_distinct_live_rows_total 2024.0",
+                 "ivf_probe_groups_total 29.0",
+                 "ivf_probe_groups_onepass_total 24.0",
+                 'ivf_probe_batches_total{path="bucket_major"} 2.0',
                  'ivf_probe_batches_total{path="row_major"} 1.0'):
         assert line in text.splitlines(), (line, text)
 
@@ -267,7 +446,7 @@ def test_served_batches_count_what_they_probed():
         outs = list(sess.stream([X[:64], X[64:128]]))
     assert [o.rows for o in outs] == [64, 64]
     for o in outs:
-        assert np.asarray(o.ivf_probe).shape == (6,)
+        assert np.asarray(o.ivf_probe).shape == (7,)
     text = reg.to_prometheus().splitlines()
     assert f"ivf_probe_slots_total {float(128 * 2 * idx.bucket_cap)}" in text
     assert 'ivf_probe_partitions_total{kind="probes"} 256.0' in text

@@ -623,9 +623,10 @@ def test_stack_with_headroom_builds_on_the_v5e_without_a_second_copy(
 # store
 
 
+@pytest.mark.parametrize("fact", [False, True])
 @pytest.mark.parametrize("exclude_self", [False, True])
 def test_bucket_major_batch_program_compiles_for_the_v5e(
-        v5e_devices, monkeypatch, exclude_self):
+        v5e_devices, monkeypatch, exclude_self, fact):
     """``serve-bigann10m-ivf-bulk`` at its size: one 1024-row batch over
     4096 lists of 4728 x 128 float32 slots (9.9e9 B), ``nprobe`` 16. The
     batch is ONE query tile and its program holds the walk's kernel (a
@@ -635,8 +636,12 @@ def test_bucket_major_batch_program_compiles_for_the_v5e(
     the kernel — so no instruction writes an array of the store's order
     (the row-major program's ``jnp.take`` of whole buckets compiled to
     three slices that copied all of it in every 16-row step: PERF.md
-    section 6, PR 41), and the temporaries stay under 0.5e9 B beside
-    10.07e9 B of arguments (the row-major program's: 4.09e9)."""
+    section 6, PR 41), and the temporaries stay under 0.19e9 B beside
+    10.07e9 B of arguments (the row-major program's: 4.09e9). ``fact``:
+    the store holds the one-pass fact (ISSUE 46) — one bool argument more,
+    the kernel with both dots in it, the query rows' bit test beside the
+    score, the same temporaries to within that flag, and the kernel's
+    VMEM with the bfloat16 copies of a bucket and a group counted."""
     import re
 
     import jax
@@ -666,7 +671,10 @@ def test_bucket_major_batch_program_compiles_for_the_v5e(
             arg((search.PROBE_FIELDS,), jnp.int32),
             arg((lists, dim), jnp.float32), arg((lists,), jnp.float32),
             arg((lists, cap, dim), jnp.float32), arg((lists, cap), jnp.int32),
-            arg((lists, cap), jnp.float32), None, cfg=cfg, nprobe=nprobe,
+            arg((lists, cap), jnp.float32), None,
+            arg((), jnp.bool_) if fact else None,
+            arg((dim,), jnp.float32) if fact else None,
+            cfg=cfg, nprobe=nprobe,
         ).compile()
     hlo = batch.as_text()
     items = search.bucket_major_items(q, nprobe, lists)
@@ -682,6 +690,14 @@ def test_bucket_major_batch_program_compiles_for_the_v5e(
         size = width.get(kind, 4) * np.prod([int(n) for n in dims.split(",")])
         assert size < 1e9 or op in ("parameter", "bitcast"), m.group(0)
     mem = batch.memory_analysis()
-    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    # 0.1827e9 B (0.2082e9 with the self mask's ids), +32 KB under the fact
+    assert mem.temp_size_in_bytes < (0.215e9 if exclude_self else 0.19e9), (
+        mem.temp_size_in_bytes)
     for scope in ("knn.ivf/score", "knn.ivf/gather", "knn.rerank"):
         assert scope in hlo
+    from mpi_knn_tpu.ops.bucket_walk import bucket_walk_vmem_bytes
+
+    plain = bucket_walk_vmem_bytes(search.PROBE_GROUP, cap, dim)
+    both = bucket_walk_vmem_bytes(search.PROBE_GROUP, cap, dim, onepass=True)
+    assert both - plain == (cap + search.PROBE_GROUP) * dim * 2
+    assert both <= search._WALK_VMEM_BYTES
