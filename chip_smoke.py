@@ -18,6 +18,11 @@ Phases, each printing one line with its wall time:
                lengths 0.5-2x their own: a resident index's answers equal
                the one-shot call's bit for bit, and the direct form's in
                float64 on the host.
+- ``screen``   the certified screen (ISSUE 47) on this device's own
+               three-pass dot: a full 1024-row tile of fractional rows on
+               the lane grid answers as the six-pass program answers, a
+               tile's screen values stay under the bound, and a planted
+               crowd of near-equal neighbours is flagged and re-scanned.
 - ``ring``     with more than one chip: both ring schedules over
                min(4, chips) devices against the serial result, the
                shardings spanning that many devices.
@@ -375,6 +380,82 @@ def compute_phases(args, platform, out, record) -> None:
         f"dist_abs_err_max={cos_err:.2e} "
         f"cosine_tile_steps={int(cos_steps.value - steps_before)}",
         recall=round(float(cos_recall), 5),
+    )
+
+    # -- screen ------------------------------------------------------------
+    # the certified screen, on THIS device's three-pass dot (the CPU has
+    # none: every precision is float32's own there): fractional embedding-
+    # shaped rows on the lane grid, a full 1024-row tile. The screened
+    # program's answer against the six-pass program's (the rule patched
+    # off under another jit key), a tile's screen values against its
+    # six-pass values under the bound, and a planted crowd — 40 corpus rows
+    # within the bound of one another around query 0 — that must be
+    # flagged, re-scanned and answered as the six-pass program answers
+    t0 = time.perf_counter()
+    import unittest.mock
+
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.ops.distance import unit_rows
+
+    srng = np.random.default_rng(47)
+    s_dim, s_rows = 256, 2048 if args.tiny else 32768
+    cen = srng.standard_normal((64, s_dim))
+    cen /= np.linalg.norm(cen, axis=1, keepdims=True)
+    sigma = 0.5 / np.sqrt(s_dim)
+
+    def embed(n):
+        rows = cen[srng.integers(0, 64, n)] + sigma * srng.standard_normal(
+            (n, s_dim))
+        return (rows * np.exp(srng.uniform(
+            np.log(0.5), np.log(2.0), (n, 1)))).astype(np.float32)
+
+    SC, SQ = embed(s_rows), embed(1024)
+    crowd = srng.choice(s_rows, 40, replace=False)
+    SC[crowd] = SQ[0] * (1 + 1e-6 * srng.standard_normal((40, s_dim)))
+    scfg = KNNConfig(k=K, backend="serial", metric="cosine", query_tile=1024,
+                     corpus_tile=1024 if args.tiny else 8192,
+                     exclude_zero=False, exclude_self=False)
+    wide = serial.screen_rule(scfg, 1024, scfg.corpus_tile, s_dim)
+    SCd = jax.device_put(jnp.asarray(SC))
+    screened = all_knn(SCd, queries=SQ, config=scfg)
+    with unittest.mock.patch.object(serial, "screen_rule",
+                                    lambda *a, **k: None):
+        # (another static argument: a jit key of its own)
+        sixpass = all_knn(SCd, queries=SQ,
+                          config=scfg.replace(recall_target=0.96))
+    s_rows_counted = (None if screened.screen_rows is None
+                      else np.asarray(screened.screen_rows).tolist())
+    sd, si = np.asarray(screened.dists), np.asarray(screened.ids)
+    xd, xi = np.asarray(sixpass.dists), np.asarray(sixpass.ids)
+    dist_diff = float(np.abs(sd - xd).max())
+    # every returned distance is computed exactly, so an id can differ
+    # only where two rows tie to float32's last bits (the crowd's do)
+    ids_same = float((si == xi)[1:].mean())
+    q_unit = unit_rows(jnp.asarray(SQ))
+    inv = serial.cosine_inv_norms(SCd[:8192])  # (rows past the end: none)
+    tile_ids = jnp.arange(min(8192, s_rows), dtype=jnp.int32)
+    tile = [np.asarray(serial.masked_dist_tile(
+        q_unit, jnp.full((1024,), -1, jnp.int32), None,
+        SCd[:tile_ids.size], tile_ids, inv[:tile_ids.size], scfg,
+        screen=flag)) for flag in (True, False)]
+    eps = np.asarray(serial.screen_eps("cosine", s_dim, q_unit, None, None))
+    screen_err = float(np.abs(tile[0] - tile[1]).max())
+    record(
+        "screen",
+        wide == 3 * K + 2 and sixpass.screen_rows is None
+        and s_rows_counted is not None and sum(s_rows_counted) == 1024
+        and s_rows_counted[1] >= 1  # the crowd's row at least
+        and s_rows_counted[0] >= 900
+        and np.asarray(screened.select_tiles).tolist() == [0, 1]
+        and dist_diff <= 2e-6 and ids_same >= 0.999
+        and screen_err <= float(eps.min()),
+        t0,
+        f"corpus={list(SC.shape)} k'={wide} "
+        f"screen_rows_certified_flagged={s_rows_counted} "
+        f"select_tiles={np.asarray(screened.select_tiles).tolist()} "
+        f"dist_abs_diff_vs_six_pass_max={dist_diff:.2e} "
+        f"ids_equal_six_pass_rows_1_on={ids_same:.5f} "
+        f"screen_tile_abs_err_max={screen_err:.2e} under eps={eps.min():.2e}",
     )
 
     # -- rescan ------------------------------------------------------------

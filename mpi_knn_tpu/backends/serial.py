@@ -45,7 +45,7 @@ from mpi_knn_tpu.ops.distance import (
     sq_norms,
     unit_rows,
 )
-from mpi_knn_tpu.ops.rerank import compress_rerank_tile
+from mpi_knn_tpu.ops.rerank import compress_rerank_tile, rerank_exact_topk
 from mpi_knn_tpu.ops.topk import (
     cascade_smallest_k,
     fused_scan_engages,
@@ -62,6 +62,7 @@ from mpi_knn_tpu.parallel.partition import (
     pad_rows_any,
     pad_to_multiple,
 )
+from mpi_knn_tpu.types import INVALID_ID
 
 
 # the branches of the one-pass rule, as the trace names them, nested in
@@ -148,6 +149,11 @@ class TileCounts(typing.NamedTuple):
     # (``ivf/search.py probe_counts``), from a clustered index's program
     # alone
     ivf_probe: jax.Array | None = None
+    # int32 ``[certified, flagged]``: the query rows by the verdict of the
+    # SCREEN's certificate (:func:`screen_eps`; the lanes' flags count in
+    # ``select_tiles``), from a program whose scans rank in three passes
+    # (:func:`screen_rule`)
+    screen_rows: jax.Array | None = None
 
 
 def select_tiles(rescanned: jax.Array):
@@ -215,6 +221,171 @@ def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
         q_rows, c_tile, dim, depth, jnp.dtype(cfg.dtype).itemsize)
 
 
+# --- the certified screen ---------------------------------------------------
+# The pieces a bf16 pass multiplies are bfloat16 numbers, 8 significant
+# bits: whichever way the hardware cuts them (truncation or rounding),
+# |x - x1| <= 2^-7 |x| for the first piece x1 and |x - x1 - x2| <= 2^-14 |x|
+# for the second, |x - x1 - x2 - x3| <= 2^-21 |x| for the third.
+#
+# c_P, the THREE-pass dot (``high``: x1 y1 + x1 y2 + x2 y1) against the real
+# product, an element: x y - kept = x2 y2 + rx y + (x - rx) ry with rx, ry
+# the residues after two pieces, so
+#   |x2 y2|      <= (2^-7 + 2^-14)^2 |x y|  = 2^-14 (1 + 2^-7)^2 |x y|
+#   |rx y|       <= 2^-14 |x y|
+#   |(x - rx)ry| <= 2^-14 (1 + 2^-14) |x y|
+# together under 3.02 * 2^-14 |x y|; summed over the width, sum |x_i y_i| <=
+# |x| |y| (Cauchy-Schwarz): 3.02 * 2^-14 |x| |y|, rounded UP to 2^-12.
+_SCREEN_SPLIT = 2.0 ** -12
+# the SIX-pass dot (``highest``: the three above + x1 y3 + x3 y1 + x2 y2)
+# drops x2 y3 + x3 y2 + x3 y3 + rx y + (x - rx) ry with three-piece
+# residues: 2 * 2^-21 + 2^-28 + 2 * 2^-21 (1 + ...) < 4.1 * 2^-21 of |x| |y|,
+# rounded UP to 2^-18
+_FINISH_SPLIT = 2.0 ** -18
+# float32 accumulation. A product of two bf16 pieces is exact in float32
+# (16 significant bits). A P-pass dot is P one-pass dots — each adds its d
+# products in float32, in an order the hardware chooses — whose P results
+# are added in float32 (XLA's ``BF16_BF16_F32_X3`` / ``_X6``: "3 / 6
+# BF16_BF16_F32 matmuls"): a product passes through at most d - 1 + P - 1
+# <= d + 4 additions, each off by at most 2^-23 of its result (2^-24
+# rounding to nearest; 2^-23 covers an adder that truncates), so a dot is
+# off by at most (d + 4) 2^-23 sum |pieces' products| <= (d + 4) 2^-23
+# (1 + 2^-5) |x| |y|. The same bound holds for a float32 multiply-and-add
+# chain of d terms (each product rounded once, d - 1 additions).
+_ACC_UNIT = 2.0 ** -23
+# the roundings outside the dot (scaling by the inverse norm, 1 - sim, the
+# two additions of the L2 form) in the screen and in the finish, and the
+# rounding of the certificate's own comparison: at most 8 of them, each
+# 2^-23 of the value's scale
+_ELEMENTWISE = 8 * _ACC_UNIT
+# what the first-order terms above leave out, as one factor: the pieces'
+# products add up to (1 + 2^-5) |x| |y| at most, (1 + u)^n - 1 <= n u
+# (1 + 2^-7) while the width is at most 2^15, and |q|, R are themselves
+# float32 sums (off by (d / 2 + 2) 2^-24 <= 2^-9 of their value)
+_SCREEN_SLACK = 1.0 + 2.0 ** -4
+_SCREEN_MAX_DIM = 1 << 15
+
+
+def screen_width(k: int) -> int:
+    """k', the candidates a screened scan keeps a row: ``3k + 2``, 32 at
+    k = 10. The certificate needs a row's k-th and k'-th smallest values
+    :func:`screen_eps` apart (and the screen's own error on top), so
+    k' - k is how many neighbours a row may have inside that gap before
+    it is flagged — a count that goes with the neighbours' density at the
+    k-th, which grows about as k does. At the embedding cell's law (rows of
+    a class at cosine distance 0.2 +- 7e-3, ~984 a class, eps 6.5e-4) the
+    gap from the 10th to the 24th neighbour is 2.2e-3 on average and under
+    7e-4 for one row in 10^4 (20 000 rows drawn from the law: every tenth
+    batch would re-scan), to the 32nd 2.9e-3 and never under 1.1e-3: 32
+    costs the lists a seventh column group (``ops/topk.py
+    lane_bin_depth``: depth 6 at 24, 7 at 32) and buys four orders of
+    magnitude. A constant of the rule, no setting."""
+    return 3 * k + 2
+
+
+def screen_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int, *,
+                branch: bool = False, filtered: bool = False,
+                varying: bool = False) -> int | None:
+    """Whether an engaged merge of (q_rows x c_tile) tile steps at width
+    ``dim`` SCREENS: ranks by a three-pass dot, keeps k' candidates a row
+    and finishes them at the configured precision under a certificate
+    (:func:`_merge_carried`). Returns k' (:func:`screen_width`) or None, by
+    what the program and its operands are — no setting:
+
+    - the lists are carried (:func:`carried_depth`, asked for k' too:
+      ``twolevel``, the exact policy and method, k' <= 128);
+    - float32 rows whose dot resolves to ``highest``, six passes: three of
+      them are then what there is to save (``high``, ``default`` and bf16
+      stacks keep their programs: nothing is screened below the stated
+      precision);
+    - ``branch`` is False: the program carries no one-pass branch — a
+      whole-number corpus is ranked exactly in ONE pass already;
+    - q_rows >= ``ONEPASS_MIN_ROWS``: below, a step is bound by reading
+      its tile at any pass count and would only pay the finish;
+    - no predicate's words ride the scan (``filtered``), the operands do
+      not vary over a mesh (``varying``: the ring);
+    - dim % 128 == 0: the v5e rests such a stack row-major (``ops/topk.py
+      fused_scan_engages``), so a candidate's row is one contiguous read;
+      off the lane grid the stack rests rows-minor and a gathered row is
+      ``dim`` scalars. And dim <= 2^15, which :func:`screen_eps` assumes."""
+    if (branch or filtered or varying or q_rows < ONEPASS_MIN_ROWS
+            or dim % 128 or dim > _SCREEN_MAX_DIM
+            or cfg.dtype != "float32"
+            or cfg.matmul_precision not in (None, "highest")
+            or cfg.metric not in _STATIC_PATH):
+        return None
+    wide = screen_width(cfg.k)
+    if (carried_depth(cfg, q_rows, c_tile) is None
+            or lane_bin_depth(q_rows, c_tile, wide) is None):
+        return None
+    return wide
+
+
+def screen_eps(metric: str, dim: int, q_x: jax.Array,
+               q_sq: jax.Array | None, r_sq) -> jax.Array:
+    """(q,) float32: for every query row a WORST-CASE bound on |value the
+    three-pass screen ranked a corpus row by - value the six-pass finish
+    would give that row|, for ANY row of the stack. The certificate of a
+    screened merge: with ``s`` the k'-th smallest screen value of a row
+    and ``tau`` the k-th smallest finished value among its k' candidates,
+    every row that is NOT a candidate has a screen value >= ``s``, so its
+    finished value would be >= ``s - eps``; if ``tau + eps <= s`` no such
+    row can enter the first k, and the candidates' k smallest finished
+    values are the stack's.
+
+    ``eps = K |q| R' + E V`` with, against the real value of the same
+    float32 operands (the query row, the corpus row, the norm planes'
+    entries, which both dots read):
+
+    - ``K = (c_P + c_6 + 2 (d + 4) 2^-23) (1 + 2^-4)``: the products the
+      three-pass split drops (:data:`_SCREEN_SPLIT`, 2^-12), those the
+      six-pass split drops (:data:`_FINISH_SPLIT`, 2^-18), the float32
+      accumulation of BOTH dots (:data:`_ACC_UNIT`) — the certificate
+      compares a value of the one with a value of the other — and the
+      second-order terms (:data:`_SCREEN_SLACK`); each derived where it is
+      defined, above. At d = 1536: 6.5e-4.
+    - ``|q| R'``, the size of the dot: cosine ``|q|`` (the query's unit
+      row; a corpus row's norm times its stored inverse norm is at most 1,
+      the scaling cancels), inner product ``|q| R``, L2 ``2 |q| R`` (the
+      form's ``-2 q.c``, centred rows), R the largest row norm of the
+      stack (``r_sq`` its square).
+    - ``E V``, the roundings outside the dots (:data:`_ELEMENTWISE`):
+      cosine V = 1 (values in [0, 2]) — and 0 for an all-zero query row,
+      whose values are exactly 1 in both dots: a batch's padding rows tie
+      everywhere and must not be flagged —, inner product ``|q| R``, L2
+      ``(|q| + R)^2``.
+
+    On the CPU both dots are float32's own and the bound is merely loose.
+    A bound that is too loose costs re-scans; one that is too tight is a
+    wrong answer: nothing here is measured."""
+    if dim > _SCREEN_MAX_DIM:
+        raise ValueError(f"the screen's bound assumes dim <= 2^15: {dim}")
+    acc = jnp.float32
+    K = (_SCREEN_SPLIT + _FINISH_SPLIT
+         + 2 * (dim + 4) * _ACC_UNIT) * _SCREEN_SLACK
+    q_norm = jnp.sqrt(sq_norms(q_x) if q_sq is None else q_sq).astype(acc)
+    if metric == "cosine":
+        return K * q_norm + _ELEMENTWISE * (q_norm > 0)
+    r = jnp.sqrt(r_sq).astype(acc)
+    if metric == "ip":
+        return (K + _ELEMENTWISE) * q_norm * r
+    if metric == "l2":
+        return 2 * K * q_norm * r + _ELEMENTWISE * (q_norm + r) ** 2
+    raise ValueError(f"unknown metric {metric!r}")
+
+
+def largest_norm_sq(metric: str, tiles: jax.Array,
+                    tile_sqs: jax.Array | None):
+    """R^2 of :func:`screen_eps` for a stack: the largest squared row norm,
+    from the norm plane where the metric keeps squared norms (L2), from
+    the rows themselves where it keeps none (an inner product: one pass
+    over the stack), None where the bound needs none (cosine)."""
+    if metric == "l2":
+        return jnp.max(tile_sqs)
+    if metric == "ip":
+        return jnp.max(jax.vmap(sq_norms)(tiles))
+    return None
+
+
 @jax.named_scope("knn.dist")
 def masked_dist_tile(
     q_x: jax.Array,
@@ -226,10 +397,19 @@ def masked_dist_tile(
     cfg: KNNConfig,
     onepass: bool | None = None,
     keep: jax.Array | None = None,
+    screen: bool = False,
 ) -> jax.Array:
     """(q_tile × c_tile) masked distances: metric kernel → padding/self/zero
     exclusion masks. The compute half shared by both merge schedules and the
     ring backends.
+
+    ``screen`` (static; :func:`screen_rule`): the tile RANKS and returns
+    nothing — the dot runs at ``high``, three bf16 passes, under the same
+    scope, every value within :func:`screen_eps` of what the configured
+    precision gives; the masks by id (padding, self) are exact at any
+    precision and stay, the zero test by VALUE waits for the finish's
+    exact values (``ops/rerank.py``'s split: a true near neighbour must
+    not be dropped on the evidence of a rounded distance).
 
     ``keep`` (a tagged index's batches: :func:`filter_words`) is the
     predicate's plane for this tile step, (q_tile, c_tile / 32) uint32
@@ -263,11 +443,12 @@ def masked_dist_tile(
         scope = contextlib.nullcontext()
     with scope:
         return _masked_dist_tile(
-            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass, keep)
+            q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass, keep,
+            screen)
 
 
 def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass,
-                      keep=None):
+                      keep=None, screen=False):
     """:func:`masked_dist_tile` under no scope of its own (the re-scan of
     flagged rows sits in ``knn.select/fallback`` with all it runs)."""
     if onepass:
@@ -279,7 +460,7 @@ def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass,
             metric=cfg.metric,
             x_sq=q_sq,
             y_sq=blk_sq,
-            precision=cfg.matmul_precision,
+            precision="high" if screen else cfg.matmul_precision,
         )
     if cfg.metric == "l2" and q_sq is not None and blk_sq is not None:
         pair_scale = q_sq[:, None] + blk_sq[None, :]
@@ -296,7 +477,7 @@ def _masked_dist_tile(q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg, onepass,
         blk_ids,
         query_ids=q_ids if cfg.exclude_self else None,
         exclude_self=cfg.exclude_self,
-        exclude_zero=cfg.exclude_zero,
+        exclude_zero=cfg.exclude_zero and not screen,
         zero_eps=cfg.zero_eps,
         scale=pair_scale,
         keep=None if keep is None else filter_keep(keep, blk.shape[0]),
@@ -575,8 +756,9 @@ def serve_chunk(
     of the selection their scans carried (:func:`select_tiles`: every
     program :func:`carried_depth` engages) and, where a row bound rides
     those scans, the chunks *bins* inserted and skipped under it
-    (``bins_chunks``). A program that counts none of them returns two, as
-    it always did.
+    (``bins_chunks``), and, where the scans screen (:func:`screen_rule`),
+    the query rows by the screen's certificate (``screen_rows``). A
+    program that counts none of them returns two, as it always did.
 
     Cosine: the query side's unit rows are made HERE, once a query tile
     and ahead of its scan (scope ``knn.qunit``), where L2 norms its query
@@ -608,7 +790,7 @@ def serve_chunk(
             words,
         ), one
 
-    best_d, best_i, rescanned, chunks, took = jax.lax.map(
+    best_d, best_i, rescanned, chunks, screened, took = jax.lax.map(
         per_query_tile,
         (q_tiles, qid_tiles, carry_d, carry_i) + (
             () if filt is None else (filt[0],)))
@@ -619,6 +801,8 @@ def serve_chunk(
                 bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma)))),
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
+        screen_rows=None if screened is None else jnp.sum(
+            screened, axis=0, dtype=jnp.int32),
     )
     if counts == TileCounts():
         return best_d, best_i
@@ -660,12 +844,14 @@ def merge_tiles_into_carry(
     ``cfg.merge_schedule``. The single implementation behind the serial
     chunk scan and the ring backends' per-round block loop (the schedules
     must match or the ring's per-round cost diverges from serial's).
-    Returns ``(dists, ids, rescanned, chunks)``: the merged carry and,
-    from an engaged ``twolevel`` program, a bool scalar — some row failed
-    the selection's certificate and the flagged rows were answered again —
-    and, where the row bound rides the scan too, int32 ``[inserted,
-    skipped]``, the chunks of the distance tiles by what became of them in
-    *bins*; else None.
+    Returns ``(dists, ids, rescanned, chunks, screened)``: the merged
+    carry and, from an engaged ``twolevel`` program, a bool scalar — some
+    row failed a certificate (the lanes' or the screen's) and the flagged
+    rows were answered again — and, where the row bound rides the scan
+    too, int32 ``[inserted, skipped]``, the chunks of the distance tiles
+    by what became of them in *bins*, and, where the scan screens
+    (:func:`screen_rule`), int32 ``[certified, flagged]``, the query rows
+    by the screen's certificate; else None.
 
     - "twolevel", where the lane-bin rule engages for the stack's tiles
       (:func:`carried_depth`: the exact policy and method, k <= 128, tiles
@@ -683,6 +869,13 @@ def merge_tiles_into_carry(
       8192 columns) a row bound rides the scan beside the lists and *bins*
       inserts only the chunks that hold a value at or under it: the same
       answer, bit for bit, and a fourth output that counts the chunks.
+      Where :func:`screen_rule` says so (float32 rows at ``highest``, no
+      one-pass branch, 1024 rows or more, a row-major stack) the scan's
+      dot runs in three passes and keeps k' candidates a row, their rows
+      are gathered and finished at the configured precision, and a second
+      certificate (:func:`screen_eps`) sends what it cannot vouch for to
+      the same re-scan: the six-pass answer, the six passes spent on k'
+      rows a query and not on the stack.
     - "twolevel" elsewhere (k > 128, ``mixed``, another ``topk_method``,
       narrow tiles, the ring's interpreted form off the TPU): level 1 —
       independent local top-k per corpus tile (no carry dependence between
@@ -748,7 +941,11 @@ def merge_tiles_into_carry(
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 either, onepass if block else None, block,
-                nested=onepass is not None)
+                nested=onepass is not None,
+                screen=screen_rule(
+                    cfg, carry_d.shape[0], *tiles.shape[1:],
+                    branch=onepass is not None, filtered=words is not None,
+                    varying=varying))
 
         def local(_, tile):
             # per-tile reduction honors cfg.precision_policy (exact single
@@ -781,7 +978,7 @@ def merge_tiles_into_carry(
                     else "exact"
                 ),
                 block=cfg.topk_block,
-            ), None, None
+            ), None, None, None
 
     n_stack = len(stack)
 
@@ -798,7 +995,7 @@ def merge_tiles_into_carry(
         )
 
     out, _ = jax.lax.scan(step, (carry_d, carry_i), stack)
-    return *out, None, None
+    return *out, None, None, None
 
 
 def _varying_like(x: jax.Array, *operands):
@@ -810,7 +1007,7 @@ def _varying_like(x: jax.Array, *operands):
 
 
 def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                   either, fused, block, nested):
+                   either, fused, block, nested, screen=None):
     """The engaged ``twolevel`` merge (:func:`merge_tiles_into_carry`): the
     scan over the stack's tiles carries the lane-bin lists — a step is the
     distance tile and *bins* into them, under the one-pass rule's
@@ -838,7 +1035,20 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     unbounded one it was and counts every chunk as inserted. (Around the
     whole scan XLA hoisted the one-pass branch's narrowing of the stack
     out of the loop, PERF.md §6, PR 29; a narrowing inside a kernel cannot
-    be.)"""
+    be.)
+
+    ``screen`` (k' where :func:`screen_rule` says so, else None): the same
+    scan with everything that says k saying k' — the lists' depth, the
+    bound, the finish — over a distance tile whose dot runs in THREE
+    passes (:func:`masked_dist_tile`'s ``screen``), and the lists keep a
+    candidate's SLOT in the stack, not its id. Then the k' candidates'
+    rows are gathered and finished at the configured precision
+    (:func:`_finish_screened`: every returned distance is that code's or
+    the re-scan's, none the screen's), the screen's certificate
+    (:func:`screen_eps`) joins the lanes', and a row flagged by either is
+    answered again by the same re-scan, whose distance tile is the
+    configured precision's. A fifth output counts the rows by the
+    screen's verdict."""
     from mpi_knn_tpu.ops.lane_bin import (
         lane_bin_bound,
         lane_bin_chunks,
@@ -852,12 +1062,24 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     insert = _insert_tile_once if nested else _insert_tile
 
     n_stack = len(stack)  # 3, and a predicate's words where a batch has one
+    # what the lists answer for, and what the scan walks: under the screen
+    # k' values a row, and one plane more, every row's slot in the stack
+    # (an iota: the finish gathers by it, whatever ids a mutable index
+    # keeps there)
+    wide, walked, ids_at = k, stack, 1
+    if screen is not None:
+        wide, ids_at = screen, n_stack
+        depth = lane_bin_depth(q_rows, c_tile, wide)
+        walked = (*stack, jnp.arange(
+            n_tiles * c_tile, dtype=jnp.int32).reshape(n_tiles, c_tile))
+    n_walked = len(walked)
 
-    def dist_tile(*tile_one, scoped=True):
+    def dist_tile(*tile_one, scoped=True, screened=screen is not None):
         *tile, one = tile_one
         dist = masked_dist_tile if scoped else _masked_dist_tile
         return dist(
-            q_x, q_ids, q_sq, *tile[:3], cfg, one, *tile[3:],
+            q_x, q_ids, q_sq, *tile[:3], cfg, one, *tile[3:n_stack],
+            screen=screened,
         ).astype(carry_d.dtype)
 
     def varying(x):
@@ -870,12 +1092,12 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         def step(lists, tile):
             return either(
                 lambda *o: insert(
-                    *o[n_stack:-1], None,
-                    dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
+                    *o[n_walked:-1], None,
+                    dist_tile(*o[:n_stack], o[-1]), o[ids_at], depth=depth),
                 *tile, *lists,
             ), None
 
-        return jax.lax.scan(step, lists, stack)[0]
+        return jax.lax.scan(step, lists, walked)[0]
 
     def bounded(either):
         def step(state, tile):
@@ -884,12 +1106,12 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
             with jax.named_scope("knn.select"):
                 bound = jax.lax.cond(
                     due,
-                    lambda: jnp.minimum(bound, lane_bin_bound(lists, k)),
+                    lambda: jnp.minimum(bound, lane_bin_bound(lists, wide)),
                     lambda: bound)
             *lists, n = either(
                 lambda *o: insert(
-                    *o[n_stack:-1],
-                    dist_tile(*o[:n_stack], o[-1]), o[1], depth=depth),
+                    *o[n_walked:-1],
+                    dist_tile(*o[:n_stack], o[-1]), o[ids_at], depth=depth),
                 *tile, *lists, bound,
             )
             return (*lists, bound, inserted + n), None
@@ -904,7 +1126,7 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
             (*lists,
              varying(lane_bin_no_bound(q_rows, carry_d.dtype)),
              varying(jnp.int32(0))),
-            (*stack, bound_refreshes(n_tiles)),
+            (*walked, bound_refreshes(n_tiles)),
         )
         return (*out, inserted)
 
@@ -924,7 +1146,15 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     chunks = None if inserted is None else jnp.stack(
         [inserted, every_chunk - inserted])
     with jax.named_scope("knn.select"):
-        vals, ids, flagged = lane_bin_result(lists, q_rows, k)
+        vals, ids, flagged = lane_bin_result(lists, q_rows, wide)
+    screened = None
+    if screen is not None:
+        vals, ids, certified = _finish_screened(
+            q_x, q_ids, q_sq, stack, ids, vals[:, -1], cfg)
+        flagged = flagged | ~certified
+        passed = jnp.sum(certified, dtype=jnp.int32)
+        screened = jnp.stack([passed, q_rows - passed])
+    with jax.named_scope("knn.select"):
         with jax.named_scope("finish"):
             rescanned = jnp.any(flagged)
         # the scope holds the re-scan and nothing else: its device time in
@@ -932,9 +1162,46 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         with jax.named_scope("fallback"):
             vals, ids = _rescan_flagged(
                 flagged, vals, ids,
-                functools.partial(dist_tile, scoped=False), stack, either)
+                functools.partial(dist_tile, scoped=False, screened=False),
+                stack, either)
     with jax.named_scope("knn.merge"):
-        return *merge_topk(carry_d, carry_i, vals, ids), rescanned, chunks
+        return (*merge_topk(carry_d, carry_i, vals, ids), rescanned, chunks,
+                screened)
+
+
+def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg):
+    """What follows a screened scan (:func:`_merge_carried`): ``slots`` (q,
+    k') are each row's candidates, slot numbers of the stack viewed flat
+    (-1: the row had fewer), ``s`` (q,) its k'-th smallest SCREEN value.
+    Returns ``((q, k) dists, (q, k) ids, (q,) certified)``.
+
+    Scope ``knn.rerank``: the candidates' rows are read where the stack
+    rests (flat, a bitcast of a row-major stack: :func:`screen_rule`) with
+    their ids and their entries of the norm plane, and
+    ``ops/rerank.py rerank_exact_topk`` computes every distance anew at
+    HIGHEST — the tile step's own form on the same numbers — applies
+    ``mask_tile``'s masks to those values, the zero test among them, and
+    takes the k smallest. Scope ``knn.select/screen``: a row is certified
+    iff ``tau + eps <= s`` (``tau`` its k-th finished value,
+    :func:`screen_eps`) or ``s`` is +inf, nothing finite left out."""
+    tiles, tile_ids, tile_sqs = stack[:3]
+    dim = tiles.shape[-1]
+    with jax.named_scope("knn.rerank"):
+        at = jnp.maximum(slots, 0)
+        rows = jnp.take(tiles.reshape(-1, dim), at, axis=0)
+        cand_ids = jnp.where(
+            slots < 0, INVALID_ID, jnp.take(tile_ids.reshape(-1), at, axis=0))
+        cand_sq = None if tile_sqs is None else jnp.take(
+            tile_sqs.reshape(-1), at, axis=0)
+    vals, ids = rerank_exact_topk(
+        q_x, q_ids, q_sq, rows, cand_ids, cand_sq, cfg.k, metric=cfg.metric,
+        exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
+        zero_eps=cfg.zero_eps)
+    with jax.named_scope("knn.select"), jax.named_scope("screen"):
+        eps = screen_eps(cfg.metric, dim, q_x, q_sq,
+                         largest_norm_sq(cfg.metric, tiles, tile_sqs))
+        certified = (vals[:, -1] + eps <= s) | (s == jnp.inf)
+    return vals, ids, certified
 
 
 @jax.named_scope(FUSED_SCOPE)

@@ -65,6 +65,10 @@ SELECT_PATHS = ("carried", "rescanned")  # a count's columns
 # row bound (MetricsRegistry.count_bins_chunks)
 BINS_CHUNKS = "knn_select_bins_chunks_total"
 BINS_PATHS = ("inserted", "skipped")  # a count's columns
+# query rows by the verdict of the screen's certificate
+# (MetricsRegistry.count_screen_rows)
+SCREEN_ROWS = "knn_screen_rows_total"
+SCREEN_RESULTS = ("certified", "flagged")  # a count's columns
 
 JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a program found in jax's persistent compilation cache (at jax 0.9.0 the
@@ -399,6 +403,23 @@ class MetricsRegistry:
             "skipped because no value was at or under its row's bound",
         )
 
+    def count_screen_rows(self, rows) -> None:
+        """Add a dispatch's query rows to ``knn_screen_rows_total
+        {result="certified"|"flagged"}``. ``rows`` is ``KNNResult
+        .screen_rows`` / ``BatchResult.screen_rows``: ints ``[certified,
+        flagged]``, from a program whose scans rank in three bf16 passes
+        (``backends/serial.py screen_rule``). The device decides, so call
+        this where :meth:`count_dist_steps` is called."""
+        self._count_columns(
+            SCREEN_ROWS, SCREEN_RESULTS, rows,
+            "query rows (padding included) of scans that rank in three "
+            "bf16 passes and finish k' candidates a row at the configured "
+            "precision, by the screen's certificate: certified (the "
+            "finished k are provably the stack's), or flagged (answered "
+            "again by the re-scan at the configured precision)",
+            label="result",
+        )
+
     def count_ivf_probe(self, probed) -> None:
         """Add a clustered batch's probe counts (``KNNResult.ivf_probe`` /
         ``BatchResult.ivf_probe``: ints ``[probes, bucket_cap, live rows,
@@ -464,13 +485,14 @@ class MetricsRegistry:
             labels={"path": "bucket_major" if walked else "row_major"},
         ).inc(1)
 
-    def _count_columns(self, name, paths, counts, help) -> None:
+    def _count_columns(self, name, paths, counts, help,
+                       label: str = "path") -> None:
         import numpy as np
 
         counts = np.asarray(counts)
         by_path = counts.reshape(-1, counts.shape[-1]).sum(axis=0)
         for path, n in zip(paths, by_path):
-            self.counter(name, help=help, labels={"path": path}).inc(int(n))
+            self.counter(name, help=help, labels={label: path}).inc(int(n))
 
 
 _default_registry = MetricsRegistry()

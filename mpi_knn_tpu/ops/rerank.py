@@ -103,6 +103,18 @@ def compress_tile(
     return 1.0 - sim
 
 
+def _batched_dot(q_x, cand_rows, acc):
+    """(q, d) x (q, v, d) -> (q, v): every row against its own candidates,
+    at HIGHEST."""
+    return jax.lax.dot_general(
+        q_x,
+        cand_rows,
+        dimension_numbers=(((1,), (2,)), ((0,), (0,))),
+        preferred_element_type=acc,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
 @jax.named_scope("knn.rerank")
 def rerank_exact_topk(
     q_x: jax.Array,  # (q, d)
@@ -123,6 +135,15 @@ def rerank_exact_topk(
     Returns ((q, k) dists ascending, (q, k) ids) — same contract as
     ``smallest_k`` over an exactly-computed masked tile, which is what
     makes the pipeline drop-in for every backend's tile loop.
+
+    Cosine with ``cand_sq`` given is the PREPARED form of
+    ``ops.distance.pairwise_cosine``: ``cand_sq`` holds the candidates'
+    inverse norms (a cosine stack's norm plane) and ``q_x`` unit rows, and
+    the value is ``max(1 - (q . c) * inv, 0)`` — a tile step's own
+    arithmetic on the same numbers (the certified screen's finish,
+    ``backends/serial.py``). Without it both sides are normalised here.
+    ``metric="ip"``: the negated dot, nothing clamped, no scale for a zero
+    test (``ops.distance.pairwise_neg_ip``).
     """
     acc = jnp.float32
     if metric == "l2":
@@ -132,15 +153,16 @@ def rerank_exact_topk(
             cand_sq = jnp.sum(
                 cand_rows.astype(acc) * cand_rows.astype(acc), axis=-1
             )
-        xy = jax.lax.dot_general(
-            q_x,
-            cand_rows,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=acc,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        xy = _batched_dot(q_x, cand_rows, acc)
         d = jnp.maximum(q_sq[:, None] - 2.0 * xy + cand_sq, 0.0)
         pair_scale = q_sq[:, None] + cand_sq
+    elif metric == "cosine" and cand_sq is not None:
+        sim = _batched_dot(q_x, cand_rows, acc) * cand_sq.astype(acc)
+        d = jnp.maximum(1.0 - sim, 0.0)
+        pair_scale = jnp.asarray(2.0, dtype=d.dtype)
+    elif metric == "ip":
+        d = -_batched_dot(q_x, cand_rows, acc)
+        pair_scale = None
     elif metric == "cosine":
         qn = _l2_normalize(q_x)
         n = jnp.sqrt(
@@ -150,14 +172,7 @@ def rerank_exact_topk(
             )
         )
         rn = cand_rows.astype(acc) / n[..., None]
-        sim = jax.lax.dot_general(
-            qn,
-            rn,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=acc,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        d = jnp.maximum(1.0 - sim, 0.0)
+        d = jnp.maximum(1.0 - _batched_dot(qn, rn, acc), 0.0)
         pair_scale = jnp.asarray(2.0, dtype=d.dtype)
     else:
         raise ValueError(f"unknown metric {metric!r}")

@@ -747,6 +747,10 @@ class BatchResult:
     dist_steps: object = None
     select_tiles: object = None
     bins_chunks: object = None
+    # from a program whose scans screen: the query rows by the screen's
+    # certificate (backends.serial.TileCounts.screen_rows), counted with
+    # them
+    screen_rows: object = None
     # a clustered index's batch: what it probed (backends.serial
     # .TileCounts.ivf_probe), counted at retire with the rest
     ivf_probe: object = None
@@ -881,6 +885,8 @@ def _count_tiles(registry, counts) -> None:
         registry.count_bins_chunks(counts.bins_chunks)
     if counts.ivf_probe is not None:
         registry.count_ivf_probe(counts.ivf_probe)
+    if counts.screen_rows is not None:
+        registry.count_screen_rows(counts.screen_rows)
 
 
 def _count_exchange(stats, exchange_bytes: int | None,

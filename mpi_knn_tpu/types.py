@@ -68,6 +68,14 @@ class KNNResult:
         (``ivf/search.py probe_counts``);
         ``MetricsRegistry.count_ivf_probe`` adds it to the
         ``ivf_probe_*_total`` counters. None from every other index.
+      screen_rows: int32 (2,), the call's query rows (padding included) by
+        the verdict of the SCREEN's certificate, ``[certified, flagged]``
+        (``backends/serial.py screen_rule`` / ``screen_eps``: the scan
+        ranked in three bf16 passes, k' candidates a row were finished at
+        the configured precision, and a flagged row was answered again by
+        the six-pass re-scan); ``MetricsRegistry.count_screen_rows`` adds
+        it to ``knn_screen_rows_total``. None from a program that does
+        not screen.
     """
 
     dists: jax.Array
@@ -76,6 +84,7 @@ class KNNResult:
     select_tiles: jax.Array | None = None
     bins_chunks: jax.Array | None = None
     ivf_probe: jax.Array | None = None
+    screen_rows: jax.Array | None = None
 
     @property
     def k(self) -> int:

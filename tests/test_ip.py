@@ -425,7 +425,7 @@ def lowered_1024(metric: str):
     (not compiled) with the operands a resident index hands it."""
     cfg = KNNConfig(k=10, backend="serial", metric=metric, query_tile=1024,
                     corpus_tile=8192, query_bucket=64, exclude_zero=False)
-    d = 128
+    d = 200  # the cell's width: off the lane grid, nothing is screened
     f32, i32 = jnp.float32, jnp.int32
     sds = jax.ShapeDtypeStruct
     norms = None if metric == "ip" else sds((2, 8192), f32)

@@ -105,9 +105,11 @@ def _dist_dots(hlo: str) -> dict:
     return dots
 
 
-def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
+def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int,
+                                    k: int = 10):
     """An engaged program as the v5e compiler leaves it (ISSUE 33): *bins*
-    updates the (q, 640) lists in place — its outputs alias its list
+    updates the (q, 640) lists in place (``k``: what the lists answer for
+    — k' under the certified screen, ISSUE 47, whose lists are 896 wide) — its outputs alias its list
     operands and nothing copies a list, not the scan and not the one-pass
     rule's conditional, which takes and returns them (a copy a step is 21
     MB read and written again at 4096 rows: half of what the change wins)
@@ -120,10 +122,10 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
     takes the bound anew at a few of the scan's steps."""
     import re
 
-    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides
+    from mpi_knn_tpu.ops.topk import lane_bin_bound_rides, lane_bin_depth
 
     bounded = lane_bin_bound_rides(q, 8192)
-    lists = rf"\[{q},640\]"
+    lists = rf"\[{q},{128 * lane_bin_depth(q, 8192, k)}\]"
     lines = hlo.splitlines()
     copies = [ln for ln in lines
               if re.search(rf"= [fs]32{lists}\S* copy\(", ln)]
@@ -138,7 +140,7 @@ def _assert_the_lists_ride_the_scan(hlo: str, q: int, tiles: int):
         f"output_to_operand_aliasing={{{{0}}: ({first}, {{}}), "
         f"{{1}}: ({first + 1}, {{}})}}" in ln for ln in bins), bins
     finishes = [ln.split()[0] for ln in lines if "tpu_custom_call" in ln
-                and re.search(rf"= \(f32\[{q},10\]", ln)]
+                and re.search(rf"= \(f32\[{q},{k}\]", ln)]
     assert sorted(name.rstrip(".0123456789") for name in finishes) == (
         ["%bound", "%finish"] if bounded else ["%finish"]), finishes
     assert f"[{tiles},{q},10]" not in hlo
@@ -299,8 +301,15 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
         plain = serial.knn_chunk_update.lower(*args, cfg).compile()
         ruled = serial.knn_chunk_update.lower(
             *args, cfg, arg((), jnp.bool_)).compile()
-    # today's program: one dot, at the configured precision, plain scope
-    assert _dist_dots(plain.as_text()) == {"": (("f32", "f32"), precision)}
+    # the program without the branch: one dot, plain scope, at the
+    # configured precision — or, where the certified screen engages
+    # (ISSUE 47: float32 at ``highest``, 1024 rows, d % 128 == 0: what a
+    # bigann-shaped corpus of FRACTIONAL rows would run), at ``high`` with
+    # lists that answer for k' = 32
+    screen = serial.screen_rule(cfg, q, 8192, dim)
+    assert screen == (32 if cell == "serve-bigann10m-bulk" else None)
+    assert _dist_dots(plain.as_text()) == {
+        "": (("f32", "f32"), "high" if screen else precision)}
     # under the rule: the engaged branch holds ONE bf16 x bf16 -> f32 dot
     # (no operand_precision: a DEFAULT dot), the other the configured one;
     # where one kernel walks the stack (ISSUE 37: the bulk cell's shape;
@@ -319,8 +328,8 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     temps = [c.memory_analysis().temp_size_in_bytes / 2**30
              for c in (plain, ruled)]
     assert temps[1] <= temps[0] + 0.1 and temps[1] <= temp_gib, (cell, temps)
-    for program in (plain, ruled):
-        _assert_the_lists_ride_the_scan(program.as_text(), q, tiles)
+    _assert_the_lists_ride_the_scan(plain.as_text(), q, tiles, screen or 10)
+    _assert_the_lists_ride_the_scan(ruled.as_text(), q, tiles)
 
 
 def test_search_over_a_prepared_stack_compiles_for_the_v5e(
@@ -368,12 +377,19 @@ def test_search_over_a_prepared_stack_compiles_for_the_v5e(
 def test_cosine_batch_program_compiles_for_the_v5e(v5e_devices, monkeypatch):
     """``serve-dbpedia1m-cos-bulk`` at its size (ISSUE 32): one 1024-row
     bucket over 123 resident tiles of 8192 x 1536 float32 and their inverse
-    norms. The tile step holds ONE dot, float32 at ``highest`` under
-    ``knn.dist_cosine``, fed by the stack's slice itself — no divide, no
-    root and no second 8192 x 1536 tile anywhere in the scan; the query
-    side's normalisation is ``knn.qunit``'s, outside it. The program's
-    scratch stays under 0.2 GiB beside the 5.77 GiB stack, and the norms'
-    own program, run once an index, copies none of it."""
+    norms. The tile step holds ONE dot, float32 under ``knn.dist_cosine``,
+    fed by the stack's slice itself — no divide, no root and no second
+    8192 x 1536 tile anywhere in the scan; the query side's normalisation
+    is ``knn.qunit``'s, outside it. The program's scratch stays under 0.22
+    GiB beside the 5.77 GiB stack, and the norms' own program, run once an
+    index, copies none of it.
+
+    Since ISSUE 47 the program SCREENS: the scan's dot is ``high`` (three
+    passes), its lists answer for k' = 32, the candidates' rows are
+    gathered under ``knn.rerank`` from the stack viewed flat — a bitcast:
+    NOTHING copies or transposes the stack, and the 201 MB of gathered
+    rows are the scratch's largest part — and the re-scan's dot, the way
+    out of both certificates, is the configured ``highest``."""
     import re
 
     import jax
@@ -404,16 +420,32 @@ def test_cosine_batch_program_compiles_for_the_v5e(v5e_devices, monkeypatch):
         ).compile()
         norms = serial._stack_norms.lower(stack, "cosine").compile()
     hlo = batch.as_text()
-    assert _dist_dots(hlo) == {"cosine": (("f32", "f32"), "highest")}
+    assert serial.screen_rule(cfg, q, 8192, dim) == 32
+    assert _dist_dots(hlo) == {"cosine": (("f32", "f32"), "high")}
+    dots = re.findall(r"operand_precision=\{(\w+),\w+\}[^\n]*?op_name=\"([^\"]*)"
+                      r"/dot_general\"", hlo)
+    assert sorted((p, "fallback" in name) for p, name in dots) == [
+        ("high", False), ("highest", True)], dots
+    assert not _stack_moves(hlo, tiles, dim)
+    gathered = [ln for ln in hlo.splitlines()
+                if re.search(rf"= f32\[{q},32,{dim}\]\S* gather\(", ln)]
+    assert len(gathered) == 1 and "knn.rerank" in gathered[0], gathered
+    assert re.search(rf"f32\[{tiles * 8192},{dim}\]\{{1,0\S* parameter\(0\)",
+                     hlo)  # the gather's source: the flat view
+    _assert_the_lists_ride_the_scan(hlo, q, tiles, 32)
     normalising = [ln for ln in hlo.splitlines()
                    if re.search(r" (divide|sqrt|rsqrt)\(", ln)]
-    assert normalising and all("knn.qunit" in ln for ln in normalising)
+    # (and, once a batch, the root of the query rows' own norms in the
+    # screen's bound: (1024,) values)
+    assert normalising and all(
+        "knn.qunit" in ln or "knn.select/screen" in ln for ln in normalising)
     # nothing computes a corpus tile: the only instructions of that shape
     # slice the stack for the dot (a fusion inside the dot's own fusion)
     made = {m.group(1) for m in re.finditer(
         rf"= f32\[8192,{dim}\]\S* ([a-z-]+)\(", hlo)}
     assert made <= {"parameter", "dynamic-slice", "bitcast", "fusion"}, made
-    assert batch.memory_analysis().temp_size_in_bytes <= 0.2 * 2**30
+    # (0.196 GiB: the gathered rows' 0.1875 and the lists; 0.03 before)
+    assert batch.memory_analysis().temp_size_in_bytes <= 0.22 * 2**30
     assert norms.memory_analysis().temp_size_in_bytes <= 0.1 * 2**30
 
 

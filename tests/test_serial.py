@@ -715,9 +715,13 @@ def _cell_programs():
 
 
 # id -> (one-pass branch, depth of the carried lists, block height of the
-# fused scan, a row bound rides the lists); the clustered cell: its probe
-# is bucket-major. ``plain`` / ``bounded`` name the lane-bin kernel a
-# carried scan's *bins* is; ``fused`` has both inside.
+# fused scan, a row bound rides the lists[, k' of the certified screen]);
+# the clustered cell: its probe is bucket-major. ``plain`` / ``bounded``
+# name the lane-bin kernel a carried scan's *bins* is; ``fused`` has both
+# inside. Four wide: the program does not screen (ISSUE 47: float32 rows
+# at ``highest``, no one-pass branch, 1024 rows, d % 128 == 0 — the
+# embedding cell's bucket, and what a bigann-shaped corpus of FRACTIONAL
+# rows would run, which no cell's data is)
 _SMALL = {64: (False, 4, None, False), 128: (False, 4, None, False),
           256: (False, 5, None, True), 512: (False, 5, None, True)}
 _WHICH = {
@@ -726,10 +730,10 @@ _WHICH = {
     **{f"serve-bigann10m-small-{b}{fact}": v
        for b, v in _SMALL.items() for fact in ("", "-nofact")},
     "serve-bigann10m-small-1024": (True, 5, 1024, True),
-    "serve-bigann10m-small-1024-nofact": (False, 5, None, True),
+    "serve-bigann10m-small-1024-nofact": (False, 5, None, True, 32),
     "serve-bigann10m-bulk-1024": (True, 5, 1024, True),
-    "serve-bigann10m-bulk-1024-nofact": (False, 5, None, True),
-    "serve-dbpedia1m-cos-bulk-1024": (False, 5, None, True),
+    "serve-bigann10m-bulk-1024-nofact": (False, 5, None, True, 32),
+    "serve-dbpedia1m-cos-bulk-1024": (False, 5, None, True, 32),
     # d = 100: no multiple of 8, the stack rests out of the kernel's reach
     "stream-msturing10m-runbook-1024": (True, 5, None, True),
     "stream-msturing10m-runbook-1024-nofact": (False, 5, None, True),
@@ -786,7 +790,11 @@ def test_which_program_a_cell_runs(request, monkeypatch, cell, rows, fact,
     block = (serial.fused_rule(cfg, q_tile, c_tile, dim, ring)
              if onepass and not filtered else None)
     rides = depth is not None and lane_bin_bound_rides(q_tile, c_tile)
-    assert (onepass, depth, block, rides) == want
+    screen = None if depth is None else serial.screen_rule(
+        cfg, q_tile, c_tile, dim, branch=bool(onepass), filtered=filtered,
+        varying=ring)
+    assert (onepass, depth, block, rides) + (
+        () if screen is None else (screen,)) == want
 
 
 # ---------------------------------------------------------------------------
