@@ -33,6 +33,12 @@ def resolve_backend(cfg: KNNConfig, mesh=None) -> str:
             "devices is the corpus ring, whose rounds have no "
             "inner-product form yet — pass backend='serial'"
         )
+    if n > 1 and cfg.dtype == "uint8":
+        raise ValueError(
+            f"dtype='uint8' rests on one device: backend='auto' over {n} "
+            "devices is the corpus ring, whose rounds carry float blocks — "
+            "pass backend='serial'"
+        )
     return "ring-overlap" if n > 1 else "serial"
 
 

@@ -36,6 +36,7 @@ from mpi_knn_tpu.ops.distance import (
     COSINE_SCOPE,
     IP_SCOPE,
     bf16_exact,
+    byte_rows,
     center_corpus,
     cosine_inv_norms,
     onepass_applies,
@@ -44,6 +45,7 @@ from mpi_knn_tpu.ops.distance import (
     pairwise_sq_l2,
     sq_norms,
     unit_rows,
+    widen_rows,
 )
 from mpi_knn_tpu.ops.rerank import compress_rerank_tile, rerank_exact_topk
 from mpi_knn_tpu.ops.topk import (
@@ -75,6 +77,13 @@ MULTIPASS_SCOPE = "knn.dist_multipass"
 # (:func:`fused_rule`): the dot, the masks, the bound's test and *bins* of
 # every tile step are inside it
 FUSED_SCOPE = "knn.fused"
+# the one-pass branch over a BYTE stack (``dtype="uint8"``,
+# ``ops/distance.py widen_rows``), around whatever walks it — the kernel
+# (``knn.scan_u8/knn.fused``) or the scan's tile steps (``knn.scan_u8/
+# knn.dist/knn.dist_onepass``, ``knn.scan_u8/knn.select``): the device time
+# under it in a trace is what a batch spends over the bytes at rest. The
+# other branch, over the widened rows, keeps ``knn.dist_multipass``.
+U8_SCOPE = "knn.scan_u8"
 # query-tile height from which a tile program carries the rule's branch.
 # The conditional costs one copy of the corpus tile a step (8 bytes an
 # element at the HBM rate) and saves passes - 1 of the dot's 2·q FLOPs an
@@ -107,12 +116,15 @@ def onepass_rule(cfg: KNNConfig, q_rows: int, filtered: bool = False) -> bool:
 _STATIC_PATH = {"l2": 1, "cosine": 2, "ip": 4}
 
 
-def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False):
+def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False,
+               u8: bool = False):
     """A dispatch's tile steps by the path of their distance dot, int32
     ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``,
     from an inner-product program ``[0, 0, 0, 0, ip]``, from one whose
     one-pass steps run inside the fused kernel
-    (:func:`fused_rule`) ``[0, multi-pass, 0, fused]``: what
+    (:func:`fused_rule`) ``[0, multi-pass, 0, fused]``, from one whose
+    one-pass steps walk a byte stack (kernel or tile steps)
+    ``[0, multi-pass, 0, 0, 0, u8]``: what
     ``KNNResult.dist_steps`` and the counter ``knn_dist_tile_steps_total``
     hold. ``took`` is one verdict a query-tile merge (a bool vector, made
     inside a program that carries the branch) or, for a program without
@@ -125,6 +137,8 @@ def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False):
         return counts
     one = jnp.sum(took, dtype=jnp.int32)
     multi = took.size - one
+    if u8:
+        return jnp.stack([0, multi, 0, 0, 0, one]) * steps
     if fused:
         return jnp.stack([0, multi, 0, one]) * steps
     return jnp.stack([one, multi]) * steps
@@ -677,20 +691,23 @@ def knn_chunk_update(
     carry_i: jax.Array,
     cfg: KNNConfig,
     onepass: jax.Array | None = None,
+    offset: jax.Array | None = None,
 ):
     """Merge a chunk of corpus tiles into the per-query top-k carry: scan
     over corpus tiles inside a map over query tiles. The one compiled core
     behind both the serial backend and the resumable driver — the serving
     path's :func:`serve_chunk` IS this body with the chunk norms hoisted
-    to index state, so the two can never drift."""
+    to index state, so the two can never drift. ``offset``: a byte
+    stack's (:func:`serve_chunk`)."""
     return serve_chunk(
         q_tiles, qid_tiles, carry_d, carry_i,
-        chunk_tiles, chunk_ids, stack_norms(chunk_tiles, cfg.metric),
-        onepass, cfg=cfg,
+        chunk_tiles, chunk_ids, stack_norms(chunk_tiles, cfg.metric, offset),
+        onepass, offset, cfg=cfg,
     )
 
 
-def stack_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
+def stack_norms(tiles: jax.Array, metric: str,
+                offset: jax.Array | None = None) -> jax.Array | None:
     """The (T, c_tile) per-row state a metric's tile step wants from a
     (T, c_tile, d) tile stack, made once a corpus: squared row norms for
     L2, inverse row norms for cosine (``ops.distance.cosine_inv_norms``:
@@ -699,9 +716,14 @@ def stack_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
     pytree, no operand of any program). One reduction over the stack, no
     copy of it. Always traced (inside :func:`knn_chunk_update`, or under
     :data:`_stack_norms`): the eager reduction gives other bits than the
-    traced one on the CPU."""
+    traced one on the CPU. A byte stack's plane holds the norms of the
+    rows its tile steps see (``ops/distance.py widen_rows`` by ``offset``:
+    what a float32 stack of the same rows keeps), widened inside the
+    reduction."""
     if metric == "ip":
         return None
+    if tiles.dtype == jnp.uint8:
+        return jax.vmap(lambda tile: sq_norms(widen_rows(tile, offset)))(tiles)
     if metric == "l2":
         return jax.vmap(sq_norms)(tiles)
     if metric == "cosine":
@@ -712,11 +734,18 @@ def stack_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
 _stack_norms = jax.jit(stack_norms, static_argnames=("metric",))
 
 
-def resident_norms(tiles: jax.Array, metric: str) -> jax.Array | None:
+def resident_norms(tiles: jax.Array, metric: str,
+                   offset: jax.Array | None = None) -> jax.Array | None:
     """:func:`stack_norms` of a stack that stays (a prepared corpus, a
     served index), under its jit; for an inner product no program runs
     and no plane is built."""
-    return None if metric == "ip" else _stack_norms(tiles, metric)
+    if metric == "ip":
+        return None
+    # (two arguments for a float stack, as ever: benchmark/tests plant
+    # faults by replacing ``_stack_norms`` with a function of two)
+    if offset is None:
+        return _stack_norms(tiles, metric)
+    return _stack_norms(tiles, metric, offset)
 
 
 def serve_chunk(
@@ -729,6 +758,7 @@ def serve_chunk(
     tile_sqs: jax.Array | None,  # (T, c_tile) stack_norms, made at index
     # build; None under "ip"
     onepass: jax.Array | None = None,  # the corpus side of the one-pass rule
+    offset: jax.Array | None = None,  # (d,) what a BYTE stack is centred by
     *,
     cfg: KNNConfig,
     filt: tuple | None = None,
@@ -770,7 +800,15 @@ def serve_chunk(
     ``filt`` (:func:`serve_chunk_filtered`, a tagged index's batches):
     ``(q_tags (QT, q_tile, W), tag_bits)``, a predicate a query row; its
     words ride the scan beside the stack (:func:`filter_words`) and every
-    tile step masks by them. None: the program as it always was."""
+    tile step masks by them. None: the program as it always was.
+
+    A byte stack (``tiles`` uint8, ``dtype="uint8"``): every tile step
+    widens its tile to float32 and takes ``offset`` off
+    (``ops/distance.py widen_rows``) ahead of the dot it would run over a
+    float32 stack of the same rows — in the one-pass branch inside the
+    dot's own fusion or the kernel, so nothing but bytes crosses HBM; its
+    one-pass steps count in the sixth column (:func:`dist_steps`) and sit
+    under the scope :data:`U8_SCOPE`."""
     if not onepass_rule(cfg, q_tiles.shape[1], filtered=filt is not None):
         onepass = None
 
@@ -787,7 +825,7 @@ def serve_chunk(
         words = filter_words(filt[1], *q_tags) if q_tags else None
         return *merge_tiles_into_carry(
             q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, cd, ci, cfg, one,
-            words,
+            words, offset,
         ), one
 
     best_d, best_i, rescanned, chunks, screened, took = jax.lax.map(
@@ -798,7 +836,8 @@ def serve_chunk(
         None if took is None else dist_steps(
             took, tiles.shape[0], fused=filt is None and bool(fused_rule(
                 cfg, q_tiles.shape[1], *tiles.shape[1:],
-                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma)))),
+                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))),
+            u8=tiles.dtype == jnp.uint8),
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
         screen_rows=None if screened is None else jnp.sum(
@@ -839,6 +878,7 @@ def merge_tiles_into_carry(
     cfg: KNNConfig,
     onepass: jax.Array | None = None,
     words: jax.Array | None = None,  # (T, q_tile, c_tile / 32)
+    offset: jax.Array | None = None,  # (d,) of a byte stack
 ):
     """Merge a stack of corpus tiles into one query tile's top-k carry, per
     ``cfg.merge_schedule``. The single implementation behind the serial
@@ -913,7 +953,21 @@ def merge_tiles_into_carry(
     all-kNN cell. Around the step, the tile is laid out ahead of the
     conditional (one copy of the tile a step, which :func:`onepass_rule`
     weighs) and the narrowing stays in the dot's own fusion.
+
+    A byte stack (``tiles`` uint8, with its ``offset``): each step's tile
+    is widened (``ops/distance.py widen_rows``) where the step is traced,
+    ahead of the same distance code; the one-pass branch sits under
+    :data:`U8_SCOPE`.
     """
+    u8 = tiles.dtype == jnp.uint8
+    if u8 and words is not None:
+        raise ValueError("a byte stack takes no predicate's words")
+
+    def under_u8(fn):
+        """``fn`` under the byte stack's scope, where the stack is one."""
+        if not u8:
+            return fn
+        return lambda *o: jax.named_scope(U8_SCOPE)(fn)(*o)
 
     def either(step, *operands):
         """``step(*operands, onepass)``: under the rule, a conditional over
@@ -922,7 +976,7 @@ def merge_tiles_into_carry(
             return step(*operands, None)
         return jax.lax.cond(
             onepass,
-            lambda *o: step(*o, True),
+            under_u8(lambda *o: step(*o, True)),
             lambda *o: step(*o, False),
             *operands,
         )
@@ -941,7 +995,7 @@ def merge_tiles_into_carry(
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 either, onepass if block else None, block,
-                nested=onepass is not None,
+                nested=onepass is not None, offset=offset,
                 screen=screen_rule(
                     cfg, carry_d.shape[0], *tiles.shape[1:],
                     branch=onepass is not None, filtered=words is not None,
@@ -953,7 +1007,8 @@ def merge_tiles_into_carry(
             # survivors per tile feed the level-2 cascade
             return None, either(
                 lambda blk, blk_ids, blk_sq, *keep_one: local_tile_topk(
-                    q_x, q_ids, q_sq, blk, blk_ids, blk_sq, cfg,
+                    q_x, q_ids, q_sq, widen_rows(blk, offset), blk_ids,
+                    blk_sq, cfg,
                     carry_d.dtype, keep_one[-1], *keep_one[:-1],
                 ),
                 *tile,
@@ -986,7 +1041,8 @@ def merge_tiles_into_carry(
         return (
             either(
                 lambda *o: knn_tile_step(
-                    q_x, q_ids, q_sq, *o[:3], *o[n_stack:-1], cfg, o[-1],
+                    q_x, q_ids, q_sq, widen_rows(o[0], offset), *o[1:3],
+                    *o[n_stack:-1], cfg, o[-1],
                     *o[3:n_stack],
                 ),
                 *tile, *carry,
@@ -1007,7 +1063,7 @@ def _varying_like(x: jax.Array, *operands):
 
 
 def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
-                   either, fused, block, nested, screen=None):
+                   either, fused, block, nested, screen=None, offset=None):
     """The engaged ``twolevel`` merge (:func:`merge_tiles_into_carry`): the
     scan over the stack's tiles carries the lane-bin lists — a step is the
     distance tile and *bins* into them, under the one-pass rule's
@@ -1048,7 +1104,11 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     (:func:`screen_eps`) joins the lanes', and a row flagged by either is
     answered again by the same re-scan, whose distance tile is the
     configured precision's. A fifth output counts the rows by the
-    screen's verdict."""
+    screen's verdict.
+
+    ``offset``: a byte stack's (:func:`merge_tiles_into_carry`); every
+    distance tile is made from the widened tile, and the kernel widens its
+    own pieces."""
     from mpi_knn_tpu.ops.lane_bin import (
         lane_bin_bound,
         lane_bin_chunks,
@@ -1078,7 +1138,8 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         *tile, one = tile_one
         dist = masked_dist_tile if scoped else _masked_dist_tile
         return dist(
-            q_x, q_ids, q_sq, *tile[:3], cfg, one, *tile[3:n_stack],
+            q_x, q_ids, q_sq, widen_rows(tile[0], offset), *tile[1:3], cfg,
+            one, *tile[3:n_stack],
             screen=screened,
         ).astype(carry_d.dtype)
 
@@ -1136,7 +1197,8 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         *lists, inserted = jax.lax.cond(
             fused,
             lambda: _fused_scan(
-                q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth, block=block),
+                q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth, block=block,
+                offset=offset),
             lambda: bounded(multipass) if rides else (
                 *unbounded(multipass), varying(jnp.int32(every_chunk))))
     elif rides:
@@ -1204,19 +1266,24 @@ def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg):
     return vals, ids, certified
 
 
-@jax.named_scope(FUSED_SCOPE)
 def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
-                block):
+                block, offset=None):
     """``ops/fused_scan.py fused_scan`` for ``cfg``: the engaged scan's
     one-pass branch over the whole stack as one kernel, the query tile in
-    blocks of ``block`` rows, ``(lists_d, lists_i, chunks inserted)``."""
+    blocks of ``block`` rows, ``(lists_d, lists_i, chunks inserted)``,
+    under the scope ``knn.fused``. ``offset``: a uint8 stack's, whose
+    scope the kernel then sits in (``knn.scan_u8/knn.fused``)."""
     from mpi_knn_tpu.ops.fused_scan import fused_scan
 
-    return fused_scan(
-        q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs,
-        bound_refreshes(tiles.shape[0]), k=cfg.k, depth=depth,
-        exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
-        zero_eps=cfg.zero_eps, block=block)
+    with contextlib.ExitStack() as scopes:
+        if offset is not None:
+            scopes.enter_context(jax.named_scope(U8_SCOPE))
+        scopes.enter_context(jax.named_scope(FUSED_SCOPE))
+        return fused_scan(
+            q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs,
+            bound_refreshes(tiles.shape[0]), k=cfg.k, depth=depth,
+            exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
+            zero_eps=cfg.zero_eps, block=block, offset=offset)
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg
@@ -1331,8 +1398,9 @@ def tile_queries(queries, query_ids, cfg: KNNConfig, q_tile: int):
     for the query side (padding rows carry id -1)."""
     nq, dim = queries.shape
     q_pad = pad_to_multiple(nq, q_tile)
-    q_tiles = pad_rows_any(queries, q_pad, dtype=jnp.dtype(cfg.dtype)).reshape(
-        -1, q_tile, dim)
+    q_tiles = pad_rows_any(
+        queries, q_pad, dtype=jnp.dtype(cfg.compute_dtype)).reshape(
+            -1, q_tile, dim)
     qid_tiles = pad_rows_any(query_ids, q_pad, fill=-1, dtype=jnp.int32).reshape(
         -1, q_tile
     )
@@ -1396,8 +1464,8 @@ def serial_form(cfg: KNNConfig, m: int, dim: int, nq: int) -> dict:
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "q_tile"))
-def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass, *,
-                  cfg: KNNConfig, q_tile: int):
+def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass,
+                  offset=None, *, cfg: KNNConfig, q_tile: int):
     """The one per-call program of a search over a prepared stack:
     :func:`serve_chunk` under a jit with ``cfg`` static, as
     :func:`knn_chunk_update` is, with the query side's tiling, the carry's
@@ -1412,7 +1480,7 @@ def _search_stack(queries, query_ids, tiles, tile_ids, tile_sqs, onepass, *,
                                        dtype=acc)
     best_d, best_i, *counts = serve_chunk(
         q_tiles, qid_tiles, carry_d, carry_i, tiles, tile_ids, tile_sqs,
-        onepass, cfg=cfg,
+        onepass, offset, cfg=cfg,
     )
     return (best_d.reshape(q_pad, cfg.k)[:nq],
             best_i.reshape(q_pad, cfg.k)[:nq], *counts)
@@ -1428,15 +1496,21 @@ class SerialCorpus(PreparedCorpus):
     tiles: jax.Array  # (T, c_tile, d)
     tile_ids: jax.Array  # (T, c_tile)
     tile_sqs: jax.Array | None  # (T, c_tile); None under "ip"
+    # (d,) what a BYTE stack's tile steps widen by (``dtype="uint8"``:
+    # ``tiles`` is uint8, ``mu`` holds the same for the query side)
+    offset: jax.Array | None = None
 
     def search(self, queries, query_ids, cfg: KNNConfig):
         nq = queries.shape[0]
         q_tile = effective_tiles(cfg, self.m, nq)[0]
+        if self.tiles.dtype == jnp.uint8:
+            # (an all-pairs call brings the corpus's own bytes)
+            queries = queries.astype(np.float32)
         if self.mu is not None:
             queries = queries - self.mu  # center_for_l2's own subtraction
         best_d, best_i, *counts = _search_stack(
             queries, query_ids, self.tiles, self.tile_ids, self.tile_sqs,
-            self.onepass if onepass_rule(cfg, q_tile) else None,
+            self.onepass if onepass_rule(cfg, q_tile) else None, self.offset,
             cfg=cfg, q_tile=q_tile,
         )
         return best_d, best_i, tile_counts(
@@ -1453,6 +1527,18 @@ def prepare_serial(corpus, cfg: KNNConfig, form: dict) -> SerialCorpus:
     m, dim = corpus.shape
     c_tile = form["c_tile"]
     mu = fact = None
+    if cfg.dtype == "uint8":
+        # a byte stack: the rows' own bytes rest, checked and never
+        # rounded; the offset is kept aside for the tile steps
+        corpus, mu = byte_rows(corpus, cfg.center)
+        tiles, tile_ids = tile_corpus(corpus, cfg, c_tile)
+        del corpus
+        offset = None if mu is None else jnp.asarray(mu)
+        return SerialCorpus(
+            form, m, dim, c_tile, mu,
+            onepass_fact(cfg, None if mu is None else True),
+            tiles, tile_ids, resident_norms(tiles, cfg.metric, offset),
+            offset)
     if cfg.center and cfg.metric == "l2":
         corpus, mu, fact = center_corpus(corpus)
     tiles, tile_ids = tile_corpus(corpus, cfg, c_tile)

@@ -83,7 +83,12 @@ def build_parser() -> argparse.ArgumentParser:
     k.add_argument("--query-tile", type=int, default=1024)
     k.add_argument("--corpus-tile", type=int, default=2048)
     k.add_argument("--dtype", default="float32",
-                   choices=["float32", "bfloat16", "float64"])
+                   choices=["float32", "bfloat16", "float64", "uint8"],
+                   help="compute dtype; uint8: the corpus rests one byte "
+                   "an element (whole-number rows in [0, 255], checked, "
+                   "never rounded) and every tile step widens its tile: "
+                   "the float32 answers. L2, --backend serial, no "
+                   "--checkpoint-dir")
     k.add_argument("--precision-policy", choices=list(PRECISION_POLICIES),
                    default="exact",
                    help="distance-pipeline precision: exact (one-pass "
@@ -378,6 +383,11 @@ def main(argv=None) -> int:
             X = X[: args.limit]
             labels = labels[: args.limit] if labels is not None else None
 
+    if args.dtype == "uint8" and args.checkpoint_dir:
+        raise SystemExit(
+            "error: --dtype uint8 with --checkpoint-dir: the resumable "
+            "drivers re-tile a float corpus every round and keep no byte "
+            "stack — drop one of the two")
     cfg = KNNConfig(
         k=args.k,
         metric=args.metric,

@@ -49,7 +49,11 @@ class KNNConfig:
       query_tile / corpus_tile: on-device tiling of the (q × c) distance
         computation. Tiles are MXU-aligned (multiples of 128 recommended).
       dtype: input compute dtype. float32 default; bfloat16 for peak MXU
-        throughput; float64 as the tie-adjudication debug mode (SURVEY.md Q10).
+        throughput; float64 as the tie-adjudication debug mode (SURVEY.md Q10);
+        ``uint8`` is the dense ``serial`` backend's LOSSLESS at-rest form for
+        whole-number rows in [0, 255]: the tile stack rests one byte an
+        element, every tile step widens its tile and computes in float32
+        (``compute_dtype``), and the answers are the float32 index's.
       exclude_self: mask a candidate whose global id equals the query's own id
         (exact replacement for leave-one-out; robust under fp, unlike the
         reference's value test).
@@ -380,12 +384,16 @@ class KNNConfig:
         if self.dtype in ("int8", "int4") and self.partitions is None:
             raise ValueError(
                 f"dtype={self.dtype!r} is the clustered (IVF) store's "
-                "block-scaled AT-REST compression (ivf/index.py): the dense "
-                "backends have no dequantization path, so an integer "
-                "compute dtype would silently score raw codes — set "
-                "partitions to build a clustered index, or use "
+                "block-scaled, lossy AT-REST compression (ivf/index.py): "
+                "the dense backends' one narrow form is the lossless "
+                "dtype='uint8' (whole-number rows in [0, 255], one byte an "
+                "element, widened in every tile step), and they would "
+                "score raw codes — set partitions to build a clustered "
+                "index, dtype='uint8' for a byte-valued corpus, or use "
                 "ring_transfer_dtype='int8' for wire-only compression"
             )
+        if self.dtype == "uint8":
+            self._refuse_under_uint8()
         if self.precision_policy == "mixed":
             if self.dtype not in ("float32", "int8", "int4"):
                 raise ValueError(
@@ -533,6 +541,55 @@ class KNNConfig:
                 "reserves slots for upserts it would refuse — build with "
                 "bucket_headroom=0"
             )
+
+    def _refuse_under_uint8(self):
+        """What ``dtype="uint8"`` — the dense ``serial`` stack at one byte
+        an element — cannot be combined with yet, each with its reason and
+        what to pass instead (ROADMAP Reach keeps the list by mechanism)."""
+        if self.metric != "l2":
+            raise ValueError(
+                f"dtype='uint8' requires metric='l2', got {self.metric!r}: "
+                "a byte stack keeps the CENTRED rows' squared norms and its "
+                "tile steps widen by a whole-number offset; cosine's "
+                "inverse norms and an inner product's uncentred dot have "
+                "no widened form, reference or measurement — use "
+                "dtype='float32'"
+            )
+        if self.backend in ("ring", "ring-overlap"):
+            raise ValueError(
+                f"dtype='uint8' does not run on backend={self.backend!r}: "
+                "a ring round norms and rotates a float block and has no "
+                "form that carries bytes and their offset (the narrow wire "
+                "is ring_transfer_dtype) — use backend='serial'"
+            )
+        if self.partitions is not None:
+            raise ValueError(
+                "dtype='uint8' is the DENSE index's lossless at-rest form: "
+                "a clustered (IVF) store compresses at rest with the "
+                "block-scaled dtype='int8'/'int4' — leave partitions unset, "
+                "or pick one of those"
+            )
+        if self.precision_policy != "exact":
+            raise ValueError(
+                "dtype='uint8' requires precision_policy='exact': whole-"
+                "number rows are ranked exactly in ONE bf16 pass already, "
+                "and the compress pass of 'mixed' gathers float32 rows "
+                "from the stack for its rerank (ops/rerank.py)"
+            )
+        if self.bucket_headroom:
+            raise ValueError(
+                "an index at dtype='uint8' is frozen (serve/mutate.py "
+                f"U8_FROZEN): bucket_headroom={self.bucket_headroom} "
+                "reserves slots for upserts it would refuse — build with "
+                "bucket_headroom=0"
+            )
+
+    @property
+    def compute_dtype(self) -> str:
+        """The dtype query rows come in and tile steps compute in: ``dtype``
+        itself, but float32 over a ``uint8`` stack, whose bytes are an
+        at-rest form and no arithmetic's."""
+        return "float32" if self.dtype == "uint8" else self.dtype
 
     def replace(self, **kw) -> "KNNConfig":
         return dataclasses.replace(self, **kw)

@@ -6,6 +6,8 @@ ctypes like the MAT reader) with a pure-NumPy fallback.
 Format, per vector: little-endian int32 dimension d, then d components
 (float32 / uint8 / int32). All rows share d. fvecs/bvecs load as float32
 (bvecs widened); ivecs (ground-truth id files) load as int32.
+:func:`bvecs_blocks` hands a ``.bvecs`` file on as its own BYTES, a block
+of rows at a time, for a byte stack's build (``serve.build_index_blocks``).
 """
 
 from __future__ import annotations
@@ -135,3 +137,49 @@ def read_vecs(path, limit: Optional[int] = None) -> np.ndarray:
     if out is None:
         out = read_vecs_numpy(path, limit=limit)
     return out
+
+
+def bvecs_blocks(path, block_rows: int = 1 << 19,
+                 limit: Optional[int] = None):
+    """``((rows, dim), blocks)`` of a ``.bvecs`` file read as BYTES, a block
+    at a time: ``blocks(i)`` is rows ``[i * block_rows, (i + 1) *
+    block_rows)`` as a contiguous (n, dim) ``uint8`` array — None after the
+    last — so that ``serve.build_index_blocks((rows, dim), blocks,
+    KNNConfig(dtype="uint8", ...))`` never holds the file, and nothing is
+    widened on the host. The file's size fixes ``rows`` up front (``limit``
+    caps it); a block whose rows do not all state ``dim``, or a file whose
+    size is no whole number of rows, raises ``ValueError``."""
+    path = Path(path)
+    if _kind_for(path) != "b":
+        raise ValueError(f"{path}: not a .bvecs file")
+    size = path.stat().st_size
+    if size < 4:
+        raise ValueError(f"{path}: truncated dimension field at row 0")
+    dim = int(np.fromfile(path, dtype=np.int32, count=1)[0])
+    if dim <= 0 or dim > (1 << 24):
+        raise ValueError(f"{path}: implausible dimension {dim} at row 0")
+    stride = 4 + dim
+    rows = size // stride
+    if limit is not None:
+        rows = min(rows, int(limit))
+    elif size % stride:
+        raise ValueError(
+            f"{path}: truncated row {rows} (size {size} not a multiple of "
+            f"row stride {stride})")
+
+    def blocks(i: int):
+        lo = i * block_rows
+        if lo >= rows:
+            return None
+        n = min(block_rows, rows - lo)
+        mat = np.fromfile(path, dtype=np.uint8, count=n * stride,
+                          offset=lo * stride).reshape(n, stride)
+        dims = mat[:, :4].copy().view(np.int32).reshape(n)
+        if not (dims == dim).all():
+            bad = lo + int(np.argmax(dims != dim))
+            raise ValueError(
+                f"{path}: inconsistent dimension ({int(dims[bad - lo])} vs "
+                f"{dim}) at row {bad}")
+        return np.ascontiguousarray(mat[:, 4:])
+
+    return (rows, dim), blocks

@@ -70,7 +70,18 @@ def build_serve_parser() -> argparse.ArgumentParser:
     d.add_argument("--devices", type=int, default=None,
                    help="ring size for distributed backends")
     d.add_argument("--dtype", default="float32",
-                   choices=["float32", "bfloat16", "float64"])
+                   choices=["float32", "bfloat16", "float64", "uint8"],
+                   help="what the dense index rests as. uint8: whole-"
+                   "number rows in [0, 255] one byte an element, lossless "
+                   "(d + 8 resident bytes a row, 4 d + 8 as float32), "
+                   "widened in every tile step: the float32 index's "
+                   "answers. A .bvecs --data then reaches the build as "
+                   "bytes, a block at a time; any other corpus is checked "
+                   "on the device and refused with the first row that "
+                   "holds a fraction or leaves the range, never rounded. "
+                   "L2 on the serial layout of one device, frozen; "
+                   "refused with --metric cosine/ip, --partitions, --tags, "
+                   "--precision-policy mixed, a ring and headroom")
     d.add_argument("--query-tile", type=int, default=1024)
     d.add_argument("--corpus-tile", type=int, default=2048)
     d.add_argument("--precision-policy", choices=list(PRECISION_POLICIES),
@@ -218,9 +229,27 @@ def serve_main(argv=None) -> int:
     from mpi_knn_tpu.frontend.scheduler import SLOPolicy
     from mpi_knn_tpu.frontend.server import Frontend, FrontendHTTPServer
     from mpi_knn_tpu.resilience import ResiliencePolicy
-    from mpi_knn_tpu.serve import ServeSession, build_index
+    from mpi_knn_tpu.serve import (
+        ServeSession,
+        build_index,
+        build_index_blocks,
+    )
 
-    X, _, source = load_corpus(args.data, limit=args.limit)
+    blocks = None
+    if args.dtype == "uint8" and args.data.endswith(".bvecs"):
+        # the file's own bytes, a block at a time: nothing widened on the
+        # host, the corpus never held beside its stack
+        from mpi_knn_tpu.data.vecs import bvecs_blocks
+
+        try:
+            shape, blocks = bvecs_blocks(args.data, limit=args.limit)
+        except (FileNotFoundError, ValueError) as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 2
+        source = args.data
+    else:
+        X, _, source = load_corpus(args.data, limit=args.limit)
+        shape = X.shape
     try:
         cfg = KNNConfig(
             k=args.k,
@@ -266,6 +295,11 @@ def serve_main(argv=None) -> int:
 
             index = build_ivf_index(
                 X, cfg, tags=args.tags and np.load(args.tags))
+        elif blocks is not None:
+            if args.tags:
+                raise ValueError("an index with tags holds float32 rows "
+                                 "(--dtype uint8 takes no --tags)")
+            index = build_index_blocks(shape, blocks, cfg)
         else:
             index = build_index(
                 X, cfg, tags=args.tags and np.load(args.tags))
@@ -293,7 +327,7 @@ def serve_main(argv=None) -> int:
     build_s = time.perf_counter() - t0
     if not args.quiet:
         print(
-            f"[mpi-knn serve] {source} shape={list(X.shape)} "
+            f"[mpi-knn serve] {source} shape={list(shape)} "
             f"backend={index.backend} k={cfg.k} bucket={cfg.query_bucket} "
             f"max_wait={args.max_wait_ms}ms (index+bind {build_s:.2f}s, "
             "warming in background)"

@@ -56,7 +56,7 @@ COMPILE_BUCKETS_S = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 # tile steps by the distance dot's path (MetricsRegistry.count_dist_steps)
 DIST_STEPS = "knn_dist_tile_steps_total"
 # a count's columns
-DIST_PATHS = ("onepass", "multipass", "cosine", "fused", "ip")
+DIST_PATHS = ("onepass", "multipass", "cosine", "fused", "ip", "u8")
 # query-tile merges by what became of the selection their scans carried
 # (MetricsRegistry.count_select_tiles)
 SELECT_TILES = "knn_select_query_tiles_total"
@@ -370,7 +370,8 @@ class MetricsRegistry:
             "the configured precision over prepared operands), the one "
             "bf16 pass inside the kernel that walks the whole stack, or the "
             "inner-product dot (the dot alone at the configured precision, "
-            "negated)",
+            "negated), or the one bf16 pass over a byte stack's widened "
+            "tiles (dtype=uint8: kernel or tile steps)",
         )
 
     def count_select_tiles(self, tiles) -> None:

@@ -57,14 +57,106 @@ def onepass_applies(cfg) -> bool:
     the f64 debug mode, a bf16 corpus, ``precision_policy="mixed"``, an
     uncentred run, a dot that is one pass already) keeps its program as it
     is. The dynamic side is the data's: :func:`bf16_exact` of both centred
-    operands."""
+    operands — of which a byte stack's (``dtype="uint8"``) is settled by
+    its type: see :func:`widen_rows`."""
     return (
         cfg.metric == "l2"
         and cfg.center
-        and cfg.dtype == "float32"
+        and cfg.dtype in ("float32", "uint8")
         and cfg.precision_policy == "exact"
         and cfg.matmul_precision != "default"
     )
+
+
+# --- the byte stack: whole-number rows one byte an element at rest ----------
+# ONE form rests, at every width: the rows' own bytes (``uint8``), beside
+# the whole-number offset a float32 stack of the same rows would have been
+# centred by (``center_corpus``: the mean rounded to whole numbers, here
+# from exact integer column sums). A tile step widens its tile to float32
+# and takes the offset off AFTER widening (:func:`widen_rows`): the result
+# is a whole number of magnitude <= 255 — nine bits with its sign, which no
+# signed byte holds (so not ``int8`` after a shift by 128: at d = 784 the
+# float32 accumulation of the dot is exact only for CENTRED operands, 784 x
+# 255^2 = 5.1e7 > 2^24, and a centred byte is a 9-bit number) — and a bf16
+# number every time. So a byte stack IS the corpus side of the one-pass
+# rule, by type: nothing is read to know it, no upsert can undo it, and
+# the values every tile step sees, the norm plane, the zero test's scale
+# and therefore the answers are those of the float32 index of the same
+# rows, to the bit. At d = 128 the uncentred dot would be exact too (128 x
+# 255^2 = 8.3e6 < 2^24) and the subtraction could be saved; one rule at
+# every width was preferred to a second form whose zero test scales by
+# other norms.
+BYTE_MAX = 255.0
+
+
+def widen_rows(rows: jax.Array, offset: jax.Array | None) -> jax.Array:
+    """The float32 rows a byte stack's tile stands for: ``rows`` (.., d)
+    uint8 widened, ``offset`` (d,) float32 taken off (None: an uncentred
+    index). Any other ``rows`` pass as they are."""
+    if rows.dtype != jnp.uint8:
+        return rows
+    wide = rows.astype(jnp.float32)
+    return wide if offset is None else wide - offset
+
+
+def first_unfit_row(block: jax.Array) -> jax.Array:
+    """The first row of ``block`` (n, d) that a byte stack cannot hold
+    losslessly — an element that is no whole number in [0, 255]; NaN never
+    is — as an int32 scalar, ``n`` where every row fits. A ``uint8`` block
+    fits by type and is not read."""
+    n = block.shape[0]
+    if block.dtype == jnp.uint8:
+        return jnp.int32(n)
+    x = block.astype(jnp.float32) if not jnp.issubdtype(
+        block.dtype, jnp.floating) else block
+    fits = (x == jnp.rint(x)) & (x >= 0) & (x <= BYTE_MAX)
+    bad = ~jnp.all(fits, axis=-1)
+    return jnp.min(jnp.where(bad, jnp.arange(n, dtype=jnp.int32),
+                             jnp.int32(n)))
+
+
+def unfit_row_error(row: int) -> ValueError:
+    """What a build says of the first row :func:`first_unfit_row` names."""
+    return ValueError(
+        f"row {row} of the corpus holds an element that is no whole number "
+        "in [0, 255]: a byte stack (dtype='uint8') is lossless and rounds "
+        "nothing — build with dtype='float32'")
+
+
+def byte_rows(corpus, center: bool = True):
+    """``(rows, offset)`` of a whole corpus for a byte stack, one array at
+    a time (a one-shot call's form; a served index takes its rows in
+    blocks, ``serve/index.py build_index_blocks``, with the same check and
+    the same offset): the corpus as ``uint8`` — a ``uint8`` array as it
+    is, any other after :func:`first_unfit_row` has passed it, else
+    ``ValueError`` naming the first offending row — and the whole-number
+    offset (:func:`whole_offset`; None where ``center`` is off), (d,)
+    float32 on the host."""
+    if not isinstance(corpus, jax.Array):
+        corpus = np.asarray(corpus)
+    n = corpus.shape[0]
+    if corpus.dtype != jnp.uint8:
+        unfit = int(jax.jit(first_unfit_row)(corpus))
+        if unfit < n:
+            raise unfit_row_error(unfit)
+        corpus = corpus.astype(jnp.uint8)
+    if not center:
+        return corpus, None
+    # exact integer column sums: int32 holds 2^23 rows of bytes
+    step = 1 << 23
+    total = sum(
+        np.asarray(corpus[lo:lo + step].astype(jnp.int32).sum(axis=0),
+                   dtype=np.int64)
+        for lo in range(0, n, step))
+    return corpus, whole_offset(total, n)
+
+
+def whole_offset(col_sums, rows: int) -> np.ndarray:
+    """The whole-number offset of a byte stack from its exact integer
+    column sums: the mean rounded to whole numbers, (d,) float32 —
+    ``center_corpus``'s offset for the same rows held as floats."""
+    total = np.asarray(col_sums, dtype=np.float64)
+    return np.rint(total / max(int(rows), 1)).astype(np.float32)
 
 
 def onepass_fact(cfg, fact):

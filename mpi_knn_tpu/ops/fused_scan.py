@@ -88,12 +88,10 @@ _VMEM_HEADROOM = 24 << 20
 
 
 def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
-                       ysq_ref, kd_out, ki_out, n_ref, kd_ref, ki_ref, b_ref,
-                       d_ref, bits_ref, idrow_ref, ycol_ref, work_ref,
-                       hit_ref, word_ref, cnt_ref, sem, *, k: int,
+                       ysq_ref, *refs, k: int,
                        depth: int, groups: int, exclude_self: bool,
                        exclude_zero: bool, zero_eps: float, blocked: bool,
-                       rows_minor: bool):
+                       rows_minor: bool, widened: bool = False):
     """A grid step of :func:`fused_scan`: tile t against a block of query
     rows — the whole query tile on the grid (tiles,); one of its row
     blocks where ``blocked``, the grid's leading axis, the block's lists,
@@ -122,6 +120,12 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     zero and self masks — and are tested again, exactly. (3) The network
     over the chunks that passed, as *bins* runs it."""
     lax, i32 = jax.lax, jnp.int32
+    # a byte stack's kernel (``widened``) has one operand more, the (1, d)
+    # offset its bytes are centred by; a float32 stack's has none
+    mu_ref = refs[0] if widened else None
+    (kd_out, ki_out, n_ref, kd_ref, ki_ref, b_ref, d_ref, bits_ref,
+     idrow_ref, ycol_ref, work_ref, hit_ref, word_ref, cnt_ref,
+     sem) = refs[1:] if widened else refs
     q, c_tile = d_ref.shape
     strips = q // _STRIP
     piece = groups * _LANES
@@ -213,10 +217,19 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     def dot_and_test(j, carry):
         j = _as_i32(j)
         cols = pl.ds(pl.multiple_of(lax.mul(j, i32(piece)), piece), piece)
+        qn = qn_ref[...]
+        rows = c_ref[0] if rows_minor else c_ref[0, cols, :]
+        if widened:
+            # bytes at rest: widened here, a piece at a time, and centred
+            # by the whole-number offset — whole numbers of magnitude <=
+            # 255, bf16 numbers every one: what a float32 stack holds
+            rows = lax.sub(
+                lax.convert_element_type(
+                    lax.convert_element_type(rows, i32), f32),
+                lax.broadcast_in_dim(mu_ref[...], rows.shape, (0, 1)))
         m = lax.dot_general(
-            qn_ref[...],
-            lax.convert_element_type(
-                c_ref[0] if rows_minor else c_ref[0, cols, :], jnp.bfloat16),
+            qn,
+            lax.convert_element_type(rows, jnp.bfloat16),
             dimension_numbers=(((1,), (0 if rows_minor else 1,)), ((), ())),
             preferred_element_type=f32,
             precision=lax.Precision.DEFAULT,
@@ -349,12 +362,16 @@ def rests_rows_minor(d: int) -> bool:
     return d % _LANES != 0
 
 
-def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
+def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
+                          itemsize: int = 4) -> int:
     """The VMEM :func:`fused_scan` holds for a block of (q, d) query rows
-    against (c_tile, d) float32 corpus tiles, in bytes: the lists, the
+    against (c_tile, d) corpus tiles of ``itemsize`` bytes an element
+    (float32, or a byte stack's 1), in bytes: the lists, the
     bound and the chunks' bits, the tile's distances, what it fetches of
     the tile in its two buffers (the tile; of a rows-minor stack a piece
-    of it) and a piece's bf16 copy, the query side in its two buffers, the
+    of it) and a piece's bf16 copy — of a byte stack also the piece
+    widened to float32 ahead of it, and the offset's row in its two
+    buffers —, the query side in its two buffers, the
     planes' rows. What the engage rule weighs and ``vmem_limit_bytes`` is
     set from."""
     piece = chunk_groups(c_tile) * _LANES
@@ -363,7 +380,9 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
     bound_and_bits = (2 * q + words) * _LANES * 4
     dists = q * c_tile * 4
     fetched = piece if rests_rows_minor(d) else c_tile
-    stack = 2 * fetched * d * 4 + piece * d * 2
+    stack = 2 * fetched * d * itemsize + piece * d * 2
+    if itemsize == 1:
+        stack += piece * d * 4 + 2 * _PLANE_ROWS * d * 4
     query = 2 * (q * d * 2 + 2 * q * _LANES * 4)
     planes = 2 * 2 * _PLANE_ROWS * c_tile * 4 + 2 * c_tile * 4
     work = _row_block(q, _FINISH_ROWS) * _LANES * 4
@@ -373,7 +392,8 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int) -> int:
 def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                tiles: jax.Array, tile_ids: jax.Array, tile_sqs: jax.Array,
                due, *, k: int, depth: int, exclude_self: bool,
-               exclude_zero: bool, zero_eps: float, block: int):
+               exclude_zero: bool, zero_eps: float, block: int,
+               offset: jax.Array | None = None):
     """The carried scan of ``backends/serial.py _merge_carried`` over a
     whole stack, in its one-pass branch and under the row bound, as one
     kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
@@ -399,8 +419,19 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     A width off the lane grid (:func:`rests_rows_minor`): the kernel takes
     the stack as (T, d, c_tile), the form it rests in, a piece of a tile
     (d x 1024 columns) a grid step: two whole tiles of 784 columns, 51 MB,
-    would not fit beside the distances."""
+    would not fit beside the distances.
+
+    A BYTE stack (``tiles`` uint8, ``offset`` (d,) float32 the
+    whole-number offset its rows are centred by; ``ops/topk.py
+    fused_scan_engages`` admits it on the lane grid, where it rests
+    row-major under (32, 128) tiles): a tile is fetched as bytes, a quarter
+    of the float32 tile's, and a piece is widened and centred in VMEM ahead
+    of its narrowing to bf16 — the values a float32 stack of the same rows
+    holds, so the same lists. ``tile_sqs`` are the CENTRED rows' norms."""
     q, d = q_x.shape
+    widened = tiles.dtype.itemsize == 1
+    if widened != (offset is not None):
+        raise ValueError("a byte stack comes with its offset, and no other")
     n_tiles, c_tile, _ = tiles.shape
     groups = chunk_groups(c_tile)
     piece = groups * _LANES
@@ -444,7 +475,8 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
         functools.partial(
             _fused_scan_kernel, k=k, depth=depth, groups=groups,
             exclude_self=exclude_self, exclude_zero=exclude_zero,
-            zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor),
+            zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor,
+            widened=widened),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -453,6 +485,8 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 pl.BlockSpec((block, _LANES), rows),
                 pl.BlockSpec((block, _LANES), rows),
                 tile, plane, plane,
+                *([pl.BlockSpec((1, d), index(lambda b, t, j: (0, 0)))]
+                  if widened else []),
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -483,10 +517,12 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
             dimension_semantics=("arbitrary",) * len(grid),
             # the arithmetic above, and room for what Mosaic keeps of its
             # own (a piece's dot as a value, spills)
-            vmem_limit_bytes=fused_scan_vmem_bytes(block, c_tile, d, depth)
+            vmem_limit_bytes=fused_scan_vmem_bytes(
+                block, c_tile, d, depth, tiles.dtype.itemsize)
             + _VMEM_HEADROOM,
         ),
         interpret=_interpret(),
     )(jnp.asarray(due, jnp.int32), qn, xsq, qid, tiles,
-      tile_ids.astype(jnp.int32), tile_sqs.astype(f32))
+      tile_ids.astype(jnp.int32), tile_sqs.astype(f32),
+      *([offset.astype(f32)[None, :]] if widened else []))
     return kd, ki, n[0, 0]

@@ -214,7 +214,7 @@ _FUSED_VMEM_BYTES = 64 << 20
 def fused_scan_engages(q: int, c: int, d: int, depth: int,
                        itemsize: int = 4) -> int | None:
     """Whether the one-pass branch of a scan that carries the lane-bin
-    lists over (q, c) tiles of float32 rows ``d`` wide runs as ONE kernel
+    lists over (q, c) tiles of float32 (or byte) rows ``d`` wide runs as ONE kernel
     over the whole stack (``ops/fused_scan.py``), by the shapes alone: the
     height of the row blocks the kernel walks the query tile in, or None.
     The height is q, or q halved until it passes (4096 and 2048 go to
@@ -252,16 +252,28 @@ def fused_scan_engages(q: int, c: int, d: int, depth: int,
       count ((d, T, c), ``{1,0,2}``, at 1224 tiles; (T, d, c) at 3) and
       the compiler would re-lay ALL of the stack ahead of the call:
       d % 8 == 0 and nothing else.
+    - *a byte stack* (``itemsize`` 1: ``dtype="uint8"``, whole-number rows
+      one byte an element, widened and centred a piece at a time inside
+      the kernel): on the 128-lane grid alone, where a uint8 (T, c, d)
+      array rests row-major under (32, 128) tiles, ``{2,1,0:T(8,128)(4,1)}``
+      at every tile count, and the kernel's ``BlockSpec`` takes a tile
+      where it lies (read at d = 128 and 784, up to the 12 208 tiles a
+      chip holds, in programs compiled for the chip: ``tests/test_pallas.py
+      -k byte_stack``). Off the grid (192, 784) it rests rows-minor like
+      the float32 stack, a form this kernel's widening has not been read
+      in: the scan of tile steps takes those. A tile's two buffers are
+      2 x 1.05 MB, not 2 x 4.2 MB; the distances are float32 either way.
 
     Where it says None, the scan of tile steps stays as it is."""
-    if itemsize != 4 or d % 8:
+    if itemsize not in (1, 4) or d % 8 or (itemsize == 1 and d % _LANES):
         return None
     from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
 
     block = q
     while block and block % 16 == 0:
-        if (lane_bin_bound_rides(block, c, itemsize)
-                and fused_scan_vmem_bytes(block, c, d, depth)
+        # (the distance tile the bound rides is float32 whatever rests)
+        if (lane_bin_bound_rides(block, c)
+                and fused_scan_vmem_bytes(block, c, d, depth, itemsize)
                 <= _FUSED_VMEM_BYTES):
             return block
         block //= 2

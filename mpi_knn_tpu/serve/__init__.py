@@ -28,7 +28,11 @@ from mpi_knn_tpu.serve.engine import (
     get_executable,
     query_knn,
 )
-from mpi_knn_tpu.serve.index import CorpusIndex, build_index
+from mpi_knn_tpu.serve.index import (
+    CorpusIndex,
+    build_index,
+    build_index_blocks,
+)
 
 __all__ = [
     "BatchResult",
@@ -37,6 +41,7 @@ __all__ = [
     "aotcache",
     "bucket_rows",
     "build_index",
+    "build_index_blocks",
     "get_executable",
     "query_knn",
 ]

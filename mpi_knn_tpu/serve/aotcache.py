@@ -121,6 +121,10 @@ def index_facts(index) -> dict:
         # both branches of the one-pass rule (absent otherwise, so every
         # other entry's address is unchanged)
         facts["onepass"] = True
+    if getattr(index, "rest_offset", None) is not None:
+        # (a byte stack's: one more operand of the batch program, after
+        # the fact; the stack's own entry above carries the at-rest type)
+        facts["rest_offset"] = True
     if getattr(index, "mean_frac", None) is not None:
         # (a clustered store's: one more operand of the batch program)
         facts["mean_frac"] = True

@@ -46,7 +46,14 @@ from mpi_knn_tpu.backends.serial import (
 )
 from mpi_knn_tpu.config import METRICS, KNNConfig
 from mpi_knn_tpu.obs import metrics as obs_metrics
-from mpi_knn_tpu.ops.distance import center_corpus, onepass_applies
+from mpi_knn_tpu.ops.distance import (
+    bf16_exact,
+    center_corpus,
+    first_unfit_row,
+    onepass_applies,
+    unfit_row_error,
+    whole_offset,
+)
 from mpi_knn_tpu.ops.topk import (
     init_topk,
     init_topk_tiles,
@@ -105,7 +112,7 @@ class BatchLayout:
         return {"cfg": cfg}
 
     def query_dtype(self, cfg: KNNConfig):
-        return jnp.dtype(cfg.dtype)
+        return jnp.dtype(cfg.compute_dtype)
 
     def carry_dtype(self, cfg: KNNConfig):
         return jnp.dtype("float64" if cfg.dtype == "float64" else "float32")
@@ -198,6 +205,16 @@ class SerialLayout(BatchLayout):
             rest, q_pad // q_tile, index.tiles.shape[0], index.cfg.metric)
 
 
+class ByteSerialLayout(SerialLayout):
+    """The serial layout of a BYTE stack (``dtype="uint8"``): the batch
+    program takes the whole-number offset its tile steps widen by, last of
+    the resident arguments (``backends.serial.serve_chunk``'s ``offset``);
+    query rows are float32."""
+
+    def resident(self, index):
+        return (*super().resident(index), index.rest_offset)
+
+
 class TaggedSerialLayout(SerialLayout):
     """The serial layout of an index built with tags (``serve/tags.py``):
     the batch program takes a predicate a query row — its frequent tags'
@@ -281,6 +298,7 @@ class RingLayout(BatchLayout):
 
 
 SERIAL = SerialLayout()
+BYTE_SERIAL = ByteSerialLayout()
 TAGGED_SERIAL = TaggedSerialLayout()
 RING, RING_OVERLAP = RingLayout(overlap=False), RingLayout(overlap=True)
 
@@ -313,6 +331,11 @@ class CorpusIndex:
     # rule does not apply, or the corpus did not qualify at build): the
     # batch program has no branch and is the one it always was.
     onepass: jax.Array | None = None
+    # a BYTE stack's offset (``dtype="uint8"``: ``tiles`` is uint8): (d,)
+    # float32 on the device, the whole numbers every tile step takes off
+    # its widened tile (``ops/distance.py widen_rows``; ``mu`` holds the
+    # same for the query side). None: a float stack, centred at rest.
+    rest_offset: jax.Array | None = None
     # the bags of the rows (``serve.tags.TagIndex``), where the index was
     # built with them: every batch then brings a predicate a query row,
     # the layout is :class:`TaggedSerialLayout`, and the index is frozen
@@ -426,6 +449,15 @@ def build_index(
     cfg = (config or KNNConfig()).replace(**overrides)
     if not isinstance(corpus, jax.Array):
         corpus = np.asarray(corpus)
+    if cfg.dtype == "uint8":
+        # a byte stack has one builder: one array is its one-block case
+        if tags is not None:
+            raise ValueError(
+                "an index with tags holds float32 rows: the predicate's "
+                "gather regime finishes gathered rows of the stack as they "
+                "rest (serve/tags.py) and has no widened form — build "
+                "without tags, or with dtype='float32'")
+        return build_index_blocks(corpus.shape, (corpus,), cfg, mesh=mesh)
     m, dim = corpus.shape
     backend = resolve_backend(cfg, mesh)
     if tags is not None:
@@ -498,20 +530,8 @@ def _tile_stack(corpus, c_pad: int, c_tile: int, dtype):
         -1, c_tile, corpus.shape[1])
 
 
-def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
-
-    mu = None
-    onepass = None
-    if cfg.center and cfg.metric == "l2":
-        # ops.distance.center_for_l2's own offset and centring, computed
-        # ONCE here: f64 on host, accumulation dtype on device. Queries
-        # are centered per batch with this stored offset, so serving math
-        # is bit-identical to a fresh all_knn over the same residency.
-        corpus, mu, fact = center_corpus(corpus)
-        # a fact of the index, read once here (a build may wait): an index
-        # over data that does not qualify compiles today's program only
-        if backend == "serial" and onepass_applies(cfg) and bool(fact):
-            onepass = jax.device_put(np.bool_(True))
+def _stamp_index_gauges(cfg: KNNConfig, onepass) -> None:
+    """What a build says of its index on ``/metrics``, before the arrays."""
     obs_metrics.get_registry().gauge(
         "serve_index_onepass",
         help="1 when every centred element of the resident corpus is a "
@@ -534,6 +554,61 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
             "others",
             labels={"metric": name},
         ).set(float(cfg.metric == name))
+
+
+def _serial_index(cfg, m, dim, c_tile, mu, tiles, tile_ids, tile_sqs,
+                  onepass, layout=None, rest_offset=None) -> CorpusIndex:
+    """The dense serial index over a finished stack and its planes, with
+    the gauge that says what a row costs at rest."""
+    planes = sum(a.size * a.dtype.itemsize
+                 for a in (tiles, tile_ids, tile_sqs) if a is not None)
+    obs_metrics.get_registry().gauge(
+        "serve_index_rest_bytes_per_row",
+        help="resident bytes of the dense tile stack and its id and norm "
+        "planes over the stack's row slots: 4 d + 8 for float32 rows, d + "
+        "8 for a byte stack (dtype=uint8), 4 less under metric=ip",
+    ).set(planes / (tiles.shape[0] * tiles.shape[1]))
+    return CorpusIndex(
+        cfg=cfg.replace(backend="serial"), backend="serial", m=m, dim=dim,
+        c_tile=c_tile, mu=mu, tiles=tiles, tile_ids=tile_ids,
+        tile_sqs=tile_sqs, onepass=onepass, layout=layout or SERIAL,
+        rest_offset=rest_offset,
+    )
+
+
+def _serial_tiling(cfg: KNNConfig, m: int) -> tuple[int, int]:
+    """``(c_tile, c_pad)`` of a dense serial stack over ``m`` rows."""
+    c_tile = cap_corpus_tile(
+        cfg.query_tile,
+        min(cfg.corpus_tile, pad_to_multiple(m, 128)),
+        cfg.max_tile_elems,
+    )
+    # capacity headroom (ISSUE 14): extra id −1 rows beyond the corpus
+    # are the serial layout's upsert capacity — the mutation freelist
+    # fills them by donated in-place scatter with no shape change. They
+    # cost padded FLOPs per batch (masked, never answers); build with
+    # bucket_headroom=0.0 for a frozen corpus.
+    c_pad = pad_to_multiple(
+        max(m, int(np.ceil(m * (1.0 + cfg.bucket_headroom)))), c_tile
+    )
+    return c_tile, c_pad
+
+
+def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
+
+    mu = None
+    onepass = None
+    if cfg.center and cfg.metric == "l2":
+        # ops.distance.center_for_l2's own offset and centring, computed
+        # ONCE here: f64 on host, accumulation dtype on device. Queries
+        # are centered per batch with this stored offset, so serving math
+        # is bit-identical to a fresh all_knn over the same residency.
+        corpus, mu, fact = center_corpus(corpus)
+        # a fact of the index, read once here (a build may wait): an index
+        # over data that does not qualify compiles today's program only
+        if backend == "serial" and onepass_applies(cfg) and bool(fact):
+            onepass = jax.device_put(np.bool_(True))
+    _stamp_index_gauges(cfg, onepass)
 
     if backend in ("ring", "ring-overlap"):
         from mpi_knn_tpu.backends.ring import parse_ring_mesh, ring_tiles
@@ -579,19 +654,7 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     # serial: the tile stack + ids + NORMS, all resident (norms are the
     # O(m·d) reduction all_knn redoes per call — here they are index state)
     dtype = jnp.dtype(cfg.dtype)
-    c_tile = cap_corpus_tile(
-        cfg.query_tile,
-        min(cfg.corpus_tile, pad_to_multiple(m, 128)),
-        cfg.max_tile_elems,
-    )
-    # capacity headroom (ISSUE 14): extra id −1 rows beyond the corpus
-    # are the serial layout's upsert capacity — the mutation freelist
-    # fills them by donated in-place scatter with no shape change. They
-    # cost padded FLOPs per batch (masked, never answers); build with
-    # bucket_headroom=0.0 for a frozen corpus.
-    c_pad = pad_to_multiple(
-        max(m, int(np.ceil(m * (1.0 + cfg.bucket_headroom)))), c_tile
-    )
+    c_tile, c_pad = _serial_tiling(cfg, m)
     tiles = _tile_stack(corpus, c_pad, c_tile, dtype)
     tile_ids = jnp.asarray(make_global_ids(m, c_pad).reshape(-1, c_tile))
     # knn_chunk_update's own norm construction (squared norms for L2; for
@@ -601,8 +664,205 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     # bits than the traced one on CPU, and serving must be bit-identical
     # to a fresh all_knn call
     tile_sqs = resident_norms(tiles, cfg.metric)
-    return CorpusIndex(
-        cfg=cfg.replace(backend=backend), backend=backend, m=m, dim=dim,
-        c_tile=c_tile, mu=mu, layout=SERIAL, tiles=tiles, tile_ids=tile_ids,
-        tile_sqs=tile_sqs, onepass=onepass,
-    )
+    return _serial_index(
+        cfg, m, dim, c_tile, mu, tiles, tile_ids, tile_sqs, onepass)
+
+
+# --- rows handed over in blocks ---------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("sums",), donate_argnums=(0,))
+def _ingest_block(stack, block, row0, sums: bool):
+    """``stack`` (T, c_tile, d), donated, with the ``n`` rows of ``block``
+    written at row ``row0`` of the stack viewed flat, tile by tile as
+    :func:`_pad_and_tile` writes one array: the program's temporaries are
+    the block's (its rows at rest, and a copy with a tile of margin either
+    side to slice whole tiles from), whatever layouts the device keeps the
+    two shapes in. Beside it, what the build has to know of the block:
+    ``unfit``, the first row a byte stack cannot hold (``n``: none; of a
+    float stack always ``n``), and under ``sums`` the column sums (int32,
+    exact, of a byte stack's rows; else float32) and whether every element
+    is a whole number."""
+    n_tiles, c_tile, dim = stack.shape
+    n = block.shape[0]
+    bytes_rest = stack.dtype == jnp.uint8
+    unfit = first_unfit_row(block) if bytes_rest else jnp.int32(n)
+    rows = block.astype(stack.dtype)
+    col = whole = None
+    if sums and bytes_rest:
+        col = jnp.sum(rows, axis=0, dtype=jnp.int32)
+    elif sums:
+        col = jnp.sum(block, axis=0, dtype=jnp.float32)
+        whole = jnp.all(block == jnp.rint(block))
+    padded = jnp.pad(rows, ((c_tile, c_tile), (0, 0)))
+    first = row0 // c_tile
+
+    def one_tile(j, stack):
+        t = jnp.minimum(first + j, n_tiles - 1)
+        # the tile's first row, counted in the padded block: a tile the
+        # block does not reach lands in the margins and keeps what it has
+        start = jnp.clip(t * c_tile - row0 + c_tile, 0, n + c_tile)
+        piece = jax.lax.dynamic_slice_in_dim(padded, start, c_tile)
+        at = t * c_tile + jnp.arange(c_tile, dtype=jnp.int32) - row0
+        old = jax.lax.dynamic_index_in_dim(stack, t, keepdims=False)
+        new = jnp.where(((at >= 0) & (at < n))[:, None], piece, old)
+        return jax.lax.dynamic_update_index_in_dim(stack, new, t, 0)
+
+    touched = min(-(-n // c_tile) + 1, n_tiles)
+    return jax.lax.fori_loop(0, touched, one_tile, stack), unfit, col, whole
+
+
+@functools.partial(jax.jit, static_argnames=("m",), donate_argnums=(0,))
+def _centre_stack(stack, mu, m: int):
+    """A float stack of RAW rows centred in place by ``mu``, tile by tile
+    (the slots past row ``m`` stay zero, as a padded centred corpus has
+    them), and the one-pass rule's fact of the centred rows."""
+    n_tiles, c_tile, _ = stack.shape
+
+    def one_tile(t, carry):
+        stack, fact = carry
+        tile = jax.lax.dynamic_index_in_dim(stack, t, keepdims=False)
+        live = t * c_tile + jnp.arange(c_tile, dtype=jnp.int32) < m
+        centred = jnp.where(live[:, None], tile - mu.astype(tile.dtype), tile)
+        return (jax.lax.dynamic_update_index_in_dim(stack, centred, t, 0),
+                fact & bf16_exact(centred))
+
+    return jax.lax.fori_loop(0, n_tiles, one_tile, (stack, jnp.asarray(True)))
+
+
+@functools.partial(jax.jit, static_argnames=("m", "shape"))
+def _id_plane(m: int, shape: tuple):
+    """``make_global_ids`` on the device: a hundred million ids are no
+    host array to make and move."""
+    at = jnp.arange(shape[0] * shape[1], dtype=jnp.int32)
+    return jnp.where(at < m, at, -1).reshape(shape)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _live_norms(tile_sqs, tile_ids):
+    return jnp.where(tile_ids >= 0, tile_sqs, 0.0)
+
+
+def _each_block(blocks):
+    """``blocks`` as an iterator: an iterable of row blocks, or a callable
+    ``blocks(i)`` that returns block ``i`` and None after the last."""
+    if not callable(blocks):
+        yield from blocks
+        return
+    i = 0
+    while (block := blocks(i)) is not None:
+        yield block
+        i += 1
+
+
+def build_index_blocks(
+    shape,
+    blocks,
+    config: Optional[KNNConfig] = None,
+    mesh: Optional[Mesh] = None,
+    **overrides,
+) -> CorpusIndex:
+    """:func:`build_index` for rows handed over in BLOCKS: the dense
+    ``serial`` index over ``shape = (m, d)`` rows that arrive as (n_i, d)
+    arrays, in order, n_i of any sizes that add up to ``m`` — an iterable
+    of them, or a callable ``blocks(i)`` (None after the last), so that a
+    caller who reads a file or draws from a generator never holds the
+    corpus: the tile stack is allocated once, each block is written into
+    it in place (donated, tile by tile) and dropped, and the build's peak
+    is the stack, its id and norm planes and ONE block's temporaries. A
+    host block crosses to the device as it is (a ``uint8`` block as
+    bytes).
+
+    ``dtype="uint8"`` (a byte stack, ``ops/distance.py widen_rows``): a
+    ``uint8`` block rests as it is; any other is first checked ON THE
+    DEVICE — every element a whole number in [0, 255] — and a block that
+    fails raises ``ValueError`` naming the first offending row of the
+    corpus once the blocks are in: nothing is ever rounded. The offset is
+    the rounded mean from exact integer column sums.
+
+    ``dtype="float32"``: the raw rows rest first, the mean comes from the
+    blocks' float32 column sums added in float64 (rounded to whole numbers
+    for a whole-number corpus, as ``center_corpus`` rounds it) and the
+    stack is centred in place: the same index as :func:`build_index`'s to
+    the bit for whole-number rows, to the mean's last bits otherwise.
+    Other dtypes (a bfloat16 stack is centred BEFORE it is narrowed), the
+    ring backends, tags and headroom take :func:`build_index`."""
+    from mpi_knn_tpu.api import resolve_backend
+    from mpi_knn_tpu.obs.spans import span as _flight_span
+
+    start_lane_bin_import()  # under the corpus passes below
+    cfg = (config or KNNConfig()).replace(**overrides)
+    m, dim = (int(n) for n in shape)
+    backend = resolve_backend(cfg, mesh)
+    if backend != "serial":
+        raise ValueError(
+            f"rows come in blocks to the dense serial index only; the "
+            f"{backend!r} layout places its shards by one transfer of the "
+            "padded corpus (build with backend='serial')")
+    if cfg.dtype not in ("uint8", "float32"):
+        raise ValueError(
+            f"rows come in blocks at dtype='uint8' or 'float32', got "
+            f"{cfg.dtype!r}: a narrower float stack is centred before it "
+            "is narrowed, which takes the whole array (build_index)")
+    if cfg.bucket_headroom:
+        raise ValueError(
+            "rows come in blocks to a stack without headroom (an index "
+            "that takes writes takes build_index's one array)")
+    rest = jnp.dtype(cfg.dtype)
+    centred = cfg.center and cfg.metric == "l2"
+    c_tile, c_pad = _serial_tiling(cfg, m)
+    ingested = []  # (first row, rows, unfit, column sums, whole)
+    with _flight_span("index-build", cat="index", backend=backend, rows=m,
+                      dim=dim, metric=cfg.metric,
+                      bytes=m * dim * rest.itemsize):
+        tiles = jnp.zeros((c_pad // c_tile, c_tile, dim), rest)
+        at = 0
+        for block in _each_block(blocks):
+            if not isinstance(block, jax.Array):
+                block = np.asarray(block)
+            n = int(block.shape[0])
+            if block.ndim != 2 or block.shape[1] != dim or at + n > m:
+                raise ValueError(
+                    f"block of shape {block.shape} at row {at} does not "
+                    f"lie in a corpus of {(m, dim)}")
+            with _flight_span("block-ingest", cat="index", rows=n,
+                              bytes=n * dim * block.dtype.itemsize):
+                tiles, *facts = _ingest_block(
+                    tiles, block, np.int32(at), sums=centred)
+            del block
+            ingested.append((at, n, *facts))
+            at += n
+        if at != m:
+            raise ValueError(f"the blocks held {at} rows of {m}")
+        for first, n, unfit, _, _ in ingested:
+            if int(unfit) < n:  # (waits for the block's program, no more)
+                raise unfit_row_error(first + int(unfit))
+        mu = offset = onepass = None
+        if centred:
+            total = np.sum([np.asarray(col, dtype=np.float64)
+                            for *_, col, _ in ingested], axis=0)
+            if rest == jnp.uint8:
+                mu = whole_offset(total, m)
+                offset = jnp.asarray(mu)
+                fact = True
+            else:
+                mu = total / max(m, 1)
+                if all(bool(whole) for *_, whole in ingested):
+                    mu = np.rint(mu)
+                tiles, fact = _centre_stack(
+                    tiles, jnp.asarray(mu, jnp.float32), m=m)
+            mu = np.asarray(mu, dtype=np.float64)  # the query side's, as
+            # an index from a host array holds it
+            if onepass_applies(cfg) and bool(fact):
+                onepass = jax.device_put(np.bool_(True))
+        _stamp_index_gauges(cfg, onepass)
+        tile_ids = _id_plane(m, (c_pad // c_tile, c_tile))
+        tile_sqs = resident_norms(tiles, cfg.metric, offset)
+        if offset is not None:
+            # an empty slot's bytes are zeros, which widen to -offset: its
+            # norm reads 0 all the same, as a float stack's padding has it
+            tile_sqs = _live_norms(tile_sqs, tile_ids)
+    return _serial_index(
+        cfg, m, dim, c_tile, mu, tiles, tile_ids, tile_sqs, onepass,
+        layout=BYTE_SERIAL if rest == jnp.uint8 else SERIAL,
+        rest_offset=offset)

@@ -116,14 +116,27 @@ IP_FROZEN = (
 )
 
 
+U8_FROZEN = (
+    "an index built with dtype='uint8' is frozen: the write path scatters "
+    "float rows and their norms, and a byte stack takes a row only after "
+    "the build's own check (whole numbers in [0, 255]) against the offset "
+    "it was centred by — upsert, delete and compact are refused; rebuild "
+    "the index from the new rows"
+)
+
+
 def _metric(index) -> str | None:
     return getattr(getattr(index, "cfg", None), "metric", None)
+
+
+def _rests_bytes(index) -> bool:
+    return getattr(getattr(index, "cfg", None), "dtype", None) == "uint8"
 
 
 def supports_mutation(index) -> bool:
     return (getattr(index, "backend", None) in MUTABLE_BACKENDS
             and getattr(index, "tags", None) is None
-            and _metric(index) != "ip")
+            and _metric(index) != "ip" and not _rests_bytes(index))
 
 
 def _require_mutable(index) -> None:
@@ -131,6 +144,8 @@ def _require_mutable(index) -> None:
         raise ValueError(TAGGED_FROZEN)
     if _metric(index) == "ip":
         raise ValueError(IP_FROZEN)
+    if _rests_bytes(index):
+        raise ValueError(U8_FROZEN)
     if not supports_mutation(index):
         raise ValueError(
             f"the {getattr(index, 'backend', None)!r} layout cannot honor "

@@ -40,7 +40,9 @@ class KNNResult:
         call, or (..., 5) ``[0, 0, 0, 0, ip]`` from an inner-product call,
         or (..., 4) ``[0, multi-pass, 0, fused]`` from a call whose
         one-pass steps ran inside the kernel that walks the whole stack
-        (``ops/fused_scan.py``), one row a device where the rows are counted on
+        (``ops/fused_scan.py``), or (..., 6) ``[0, multi-pass, 0, 0, 0, u8]``
+        from a call over a byte stack (``dtype="uint8"``: its one-pass
+        steps, kernel or tile steps), one row a device where the rows are counted on
         the ring's devices; comes with the answer, costs no wait of its
         own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
         ``knn_dist_tile_steps_total``. None from a program that counts no

@@ -99,7 +99,9 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
         verdict: the arguments every serial program ends with."""
         tiles = pad_to_multiple(
             int(np.ceil(rows * (1 + cfg.bucket_headroom))), c_tile) // c_tile
-        return (arg((tiles, c_tile, dim), jnp.float32),
+        # (a byte stack where the configuration rests one: ISSUE 48)
+        rest = jnp.uint8 if cfg.dtype == "uint8" else jnp.float32
+        return (arg((tiles, c_tile, dim), rest),
                 arg((tiles, c_tile), jnp.int32),
                 arg((tiles, c_tile), jnp.float32), arg((), jnp.bool_))
 
@@ -114,11 +116,15 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
             tag_bits=arg((cfg.max_query_tags + 1, resident[0].shape[0],
                           c_tile // 32), jnp.uint32)) if tagged else None
         layout = serve_index.TAGGED_SERIAL if tags else serve_index.SERIAL
+        more = {}
+        if cfg.dtype == "uint8":  # its offset is one more resident operand
+            layout = serve_index.BYTE_SERIAL
+            more["rest_offset"] = arg((dim,), jnp.float32)
         for onepass in ((fact, None) if cfg.metric == "l2" else (None,)):
             yield "-nofact" if onepass is None else "", (
                 serve_index.CorpusIndex(
                     cfg, "serial", rows, dim, c_tile, None, layout,
-                    *resident, onepass=onepass, tags=tags))
+                    *resident, onepass=onepass, tags=tags, **more))
 
     def clustered(cfg, rows, dim):
         """The clustered index the configuration states: its lists, their

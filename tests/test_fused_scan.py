@@ -241,7 +241,8 @@ def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
     counted = {p: registry.counter(DIST_STEPS, labels={"path": p}).value
                for p in DIST_PATHS}
     assert counted == {
-        "onepass": 0, "multipass": 0, "cosine": 0, "fused": steps, "ip": 0}
+        "onepass": 0, "multipass": 0, "cosine": 0, "fused": steps, "ip": 0,
+        "u8": 0}
     ring = all_knn(X, backend="ring-overlap", num_devices=4, **kw)
     np.testing.assert_array_equal(
         np.asarray(ring.dists), np.asarray(res.dists))

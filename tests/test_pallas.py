@@ -733,3 +733,136 @@ def test_bucket_major_batch_program_compiles_for_the_v5e(
     both = bucket_walk_vmem_bytes(search.PROBE_GROUP, cap, dim, onepass=True)
     assert both - plain == (cap + search.PROBE_GROUP) * dim * 2
     assert both <= search._WALK_VMEM_BYTES
+
+
+# a BYTE stack (ISSUE 48: dtype="uint8"), in programs compiled for the v5e
+
+
+@pytest.mark.parametrize("d", [128, 784])
+def test_the_rest_layout_of_a_byte_stack(v5e_devices, d):
+    """What ``ops/topk.py fused_scan_engages`` says of a uint8 (T, c, d)
+    stack at rest, READ in programs compiled for the v5e: under (32, 128)
+    tiles — ``T(8,128)(4,1)`` — row-major on the lane grid at every tile
+    count up to the 12 208 a chip holds, rows-minor off it, where the rule
+    keeps the kernel out."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu.ops.topk import fused_scan_engages
+
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def layout(tiles, c):
+        hlo = jax.jit(lambda x: x[0, 0, 0]).lower(jax.ShapeDtypeStruct(
+            (tiles, c, d), jnp.uint8, sharding=one)).compile().as_text()
+        return re.search(
+            rf"u8\[{tiles},{c},{d}\]\{{([^}}]+)\}} parameter\(0\)",
+            hlo).group(1)
+
+    read = {(tiles, c): layout(tiles, c)
+            for c in (1024, 8192)
+            for tiles in (1, 3, 127, 128, 1221, 12208)
+            if tiles * c * d < 14e9}
+    assert len(read) >= 8
+    order = "2,1,0" if d % 128 == 0 else "1,2,0"
+    assert set(read.values()) == {order + ":T(8,128)(4,1)"}, read
+    assert fused_scan_engages(1024, 8192, d, 5, itemsize=1) == (
+        1024 if d % 128 == 0 else None)
+
+
+@pytest.mark.parametrize("tiles", [1221, 12208])
+def test_byte_stack_batch_program_compiles_for_the_v5e(
+        v5e_devices, monkeypatch, tiles):
+    """``serve-bigann100m-u8-bulk``'s 1024-row bucket over 12 208 byte
+    tiles (and the 10 M slice's 1 221): the one-pass branch is the kernel
+    that walks the stack, under ``knn.scan_u8/knn.fused``, and takes the
+    uint8 parameter where it rests — nothing copies, converts or re-lays
+    the 12.8e9 B ahead of the call; the other branch widens a tile a step
+    inside the scan; the program's temporaries are a batch's, and the
+    kernel's VMEM is what ``fused_scan_vmem_bytes`` says of a byte tile."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.ops.fused_scan import (
+        _VMEM_HEADROOM,
+        fused_scan_vmem_bytes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, dim = 1024, 128
+    cfg = KNNConfig(k=10, backend="serial", dtype="uint8", query_tile=q,
+                    corpus_tile=8192, exclude_self=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    assert serial.fused_rule(cfg, q, 8192, dim) == 1024
+    with jax.enable_x64(False):
+        compiled = jax.jit(serial.serve_chunk, static_argnames=("cfg",)).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32),
+            arg((tiles, 8192, dim), jnp.uint8), arg((tiles, 8192), jnp.int32),
+            arg((tiles, 8192), jnp.float32), arg((), jnp.bool_),
+            arg((dim,), jnp.float32), cfg=cfg).compile()
+    hlo = compiled.as_text()
+    stack = rf"u8\[{tiles},8192,{dim}\]"
+    # the stack's one definition is the parameter, row-major under the
+    # byte tiling; whatever else names its shape only passes it on
+    defs = [ln for ln in hlo.splitlines()
+            if re.search(rf"= {stack}\{{", ln)
+            and not re.search(r"parameter\(|get-tuple-element\(", ln)]
+    assert not defs, defs[:3]
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+               and "knn.scan_u8/knn.fused" in ln]
+    assert len(kernels) == 1 and re.search(stack, kernels[0]), kernels
+    assert "knn.dist_multipass" in hlo
+    limit = re.search(r'vmem_limit_bytes[\\"]*:\s*[\\"]*(\d+)', kernels[0])
+    want = fused_scan_vmem_bytes(q, 8192, dim, 5, itemsize=1)
+    assert want < fused_scan_vmem_bytes(q, 8192, dim, 5) <= 64 << 20
+    if limit:
+        assert int(limit.group(1)) == want + _VMEM_HEADROOM
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 2**30
+
+
+def test_block_ingest_program_holds_one_block_on_the_v5e(v5e_devices):
+    """A block-fed build's peak is the stack, its planes and ONE block's
+    temporaries (``serve/index.py build_index_blocks``): in the block
+    program compiled for the chip at the byte cell's shapes — 12 208 tiles,
+    a block of 56 — the donated stack is updated in place and the
+    temporaries stay under two blocks' bytes, whether the block comes as
+    bytes or as floats to be checked; the norms' program reads the bytes
+    and squares no widened copy of them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.serve import index as ix
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    tiles, c, d, n = 12208, 8192, 128, 56 * 8192
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    with jax.enable_x64(False):
+        for block_dtype in (jnp.uint8, jnp.float32):
+            mem = ix._ingest_block.lower(
+                arg((tiles, c, d), jnp.uint8), arg((n, d), block_dtype),
+                arg((), jnp.int32), sums=True).compile().memory_analysis()
+            assert mem.alias_size_in_bytes >= tiles * c * d
+            assert mem.temp_size_in_bytes < 2 * n * d * jnp.dtype(
+                block_dtype).itemsize, (block_dtype, mem.temp_size_in_bytes)
+        norms = serial._stack_norms.lower(
+            arg((tiles, c, d), jnp.uint8), "l2",
+            arg((d,), jnp.float32)).compile().memory_analysis()
+    assert norms.temp_size_in_bytes < 0.1 * 2**30

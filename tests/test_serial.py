@@ -748,6 +748,11 @@ _WHICH = {
     "serve-bigann10m-ivf-bulk-1024": "bucket-major, one 1024-row tile",
     # an inner product: never the one-pass rule (L2's), the carried lists
     "serve-text2image10m-ip-bulk-1024": (False, 5, None, True),
+    # a byte stack (ISSUE 48): the kernel at ``itemsize`` 1; a byte stack
+    # without the fact does not exist (it holds by type), the row says what
+    # the rule would answer
+    "serve-bigann100m-u8-bulk-1024": (True, 5, 1024, True),
+    "serve-bigann100m-u8-bulk-1024-nofact": (False, 5, None, True),
 }
 
 
