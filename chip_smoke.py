@@ -348,11 +348,37 @@ def compute_phases(args, platform, out, record) -> None:
     steps_before = cos_steps.value
     Cd = jax.device_put(jnp.asarray(C))
     one_shot = all_knn(Cd, queries=CQ, config=ccfg)
-    served = query_knn(CQ, build_index(Cd, ccfg))
-    bit_equal = bool(
-        np.array_equal(np.asarray(served.ids), np.asarray(one_shot.ids))
-        and np.array_equal(np.asarray(served.dists),
-                           np.asarray(one_shot.dists)))
+    cindex = build_index(Cd, ccfg)
+    served = query_knn(CQ, cindex)
+    rest_width = int(cindex.tiles.shape[-1])
+    cs_ids, co_ids = np.asarray(served.ids), np.asarray(one_shot.ids)
+    cs_d, co_d = np.asarray(served.dists), np.asarray(one_shot.dists)
+    if rest_width == cindex.dim:
+        agree_how = "bit_for_bit"
+        agree = bool(np.array_equal(cs_ids, co_ids)
+                     and np.array_equal(cs_d, co_d))
+    else:
+        # the index rests zero-padded on the lane grid and its scan screens
+        # (serve/index.py rest_width; the one-shot call keeps the rows'
+        # width): the same values up to float32's summation order. Slot
+        # for slot the two distances lie within 2e-6, and wherever the two
+        # ids differ the rows TIE: their float64 distances to that query,
+        # computed here on the host, lie within 2e-6 of each other (these
+        # pixel rows are nearly parallel and swap a few in a thousand)
+        agree_how = "to_2e-6_and_every_other_id_a_tie"
+        row, _ = differ = np.nonzero(cs_ids != co_ids)
+
+        def direct64(ids):
+            q, c = CQ[row].astype(np.float64), C[ids].astype(np.float64)
+            return 1.0 - (q * c).sum(1) / (
+                np.linalg.norm(q, axis=1) * np.linalg.norm(c, axis=1))
+
+        agree = bool(
+            np.max(np.abs(cs_d - co_d)) <= 2e-6
+            and (cs_ids >= 0).all() and (co_ids >= 0).all()
+            and np.all(np.abs(direct64(cs_ids[differ])
+                              - direct64(co_ids[differ])) <= 2e-6))
+        agree_how += f"(ids_differing={len(row)}_of_{cs_ids.size})"
     c64, q64 = C.astype(np.float64), CQ[:64].astype(np.float64)
     c_len = np.linalg.norm(c64, axis=1)
     direct = 1.0 - (q64 @ c64.T) / (
@@ -370,12 +396,13 @@ def compute_phases(args, platform, out, record) -> None:
     cos_err = float(np.max(np.abs(got_d - want_d)))
     record(
         "cosine",
-        bit_equal and cos_recall >= RECALL_GATE and cos_err <= 2e-6
+        agree and cos_recall >= RECALL_GATE and cos_err <= 2e-6
         and cos_steps.value > steps_before,
         t0,
         f"corpus={list(C.shape)} queries={n_cq} fractional rows of length "
         f"{c_len.min():.0f}-{c_len.max():.0f} "
-        f"served_equals_all_knn_bit_for_bit={bit_equal} "
+        f"served_agrees_with_all_knn={agree} how={agree_how} "
+        f"rest_width={rest_width} "
         f"recall@{K}_vs_float64={cos_recall:.5f} "
         f"dist_abs_err_max={cos_err:.2e} "
         f"cosine_tile_steps={int(cos_steps.value - steps_before)}",

@@ -61,6 +61,7 @@ from mpi_knn_tpu.ops.topk import (
 )
 from mpi_knn_tpu.parallel.partition import (
     make_global_ids,
+    pad_cols,
     pad_rows_any,
     pad_to_multiple,
 )
@@ -317,10 +318,14 @@ def screen_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int, *,
       its tile at any pass count and would only pay the finish;
     - no predicate's words ride the scan (``filtered``), the operands do
       not vary over a mesh (``varying``: the ring);
-    - dim % 128 == 0: the v5e rests such a stack row-major (``ops/topk.py
-      fused_scan_engages``), so a candidate's row is one contiguous read;
-      off the lane grid the stack rests rows-minor and a gathered row is
-      ``dim`` scalars. And dim <= 2^15, which :func:`screen_eps` assumes."""
+    - dim % 128 == 0, ``dim`` the width the stack RESTS at: the v5e rests
+      such a stack row-major (``ops/topk.py fused_scan_engages``), so a
+      candidate's row is one contiguous read; off the lane grid the stack
+      rests rows-minor and a gathered row is ``dim`` scalars — which is
+      why a build whose rows are off the grid asks this rule at the padded
+      width and, where it engages, rests the stack there
+      (``serve/index.py rest_width``). And dim <= 2^15, which
+      :func:`screen_eps` assumes."""
     if (branch or filtered or varying or q_rows < ONEPASS_MIN_ROWS
             or dim % 128 or dim > _SCREEN_MAX_DIM
             or cfg.dtype != "float32"
@@ -392,7 +397,11 @@ def largest_norm_sq(metric: str, tiles: jax.Array,
     """R^2 of :func:`screen_eps` for a stack: the largest squared row norm,
     from the norm plane where the metric keeps squared norms (L2), from
     the rows themselves where it keeps none (an inner product: one pass
-    over the stack), None where the bound needs none (cosine)."""
+    over the stack), None where the bound needs none (cosine). Every slot
+    counts, live or not: a free slot reads 0 and a deleted row's slot keeps
+    its norm until it is written again (a delete is the id plane's), so in
+    an index that takes writes this is an UPPER bound on the live rows' —
+    which is all the certificate asks of it."""
     if metric == "l2":
         return jnp.max(tile_sqs)
     if metric == "ip":
@@ -811,6 +820,10 @@ def serve_chunk(
     under the scope :data:`U8_SCOPE`."""
     if not onepass_rule(cfg, q_tiles.shape[1], filtered=filt is not None):
         onepass = None
+    # the batch meets the stack at the width it rests at (``serve/index.py
+    # rest_width``: zero columns, exact zeros in every dot and norm), and
+    # crosses to the device at its own
+    q_tiles = pad_cols(q_tiles, tiles.shape[-1])
 
     def per_query_tile(args):
         q_x, q_ids, cd, ci, *q_tags = args

@@ -359,16 +359,18 @@ def _refuse_inert_knobs(cfg: KNNConfig) -> None:
 def _corpus_from_serve_index(serve_index):
     """Centered corpus rows + mean back out of a serial-layout
     ``serve.CorpusIndex`` (the tile stack is the corpus, padded — strip
-    the sentinel rows)."""
+    the sentinel rows, and the zero columns of a stack that rests wider
+    than its rows: ``serve/index.py rest_width``)."""
     if serve_index.tiles is None:
         raise ValueError(
             "an IVF index can only be built from a serial-layout "
             "CorpusIndex (tiles resident on one device); the "
             f"{serve_index.backend!r} layout shards or fuses the corpus"
         )
-    rows = np.asarray(serve_index.tiles, dtype=np.float32).reshape(
-        -1, serve_index.dim
-    )[: serve_index.m]
+    tiles = serve_index.tiles
+    rows = np.asarray(tiles, dtype=np.float32).reshape(
+        -1, tiles.shape[-1]
+    )[: serve_index.m, : serve_index.dim]
     return rows, serve_index.mu, serve_index.cfg
 
 

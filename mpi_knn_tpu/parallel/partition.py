@@ -53,6 +53,21 @@ def pad_rows_any(x, target_rows: int, fill=0.0, dtype=None) -> jax.Array:
     return jnp.asarray(pad_rows(np.asarray(x), target_rows, fill=fill), dtype=dtype)
 
 
+def pad_cols(x, width: int):
+    """``x`` with its last axis zero-filled up to ``width`` columns, in
+    numpy or on the device as it came (a stack that rests wider than its
+    rows, ``serve/index.py rest_width``: zeros add exact zeros to every dot
+    and norm). ``x`` itself where it is that wide already: no operation
+    enters a traced program."""
+    extra = width - x.shape[-1]
+    if extra < 0:
+        raise ValueError(f"width {width} < columns {x.shape[-1]}")
+    if not extra:
+        return x
+    widths = [(0, 0)] * (x.ndim - 1) + [(0, extra)]
+    return (jnp if isinstance(x, jax.Array) else np).pad(x, widths)
+
+
 def make_global_ids(m: int, padded: int) -> np.ndarray:
     """0-based global ids for m real rows, INVALID_ID for padding rows."""
     ids = np.full(padded, INVALID_ID, dtype=np.int32)

@@ -121,6 +121,13 @@ def index_facts(index) -> dict:
         # both branches of the one-pass rule (absent otherwise, so every
         # other entry's address is unchanged)
         facts["onepass"] = True
+    tiles = getattr(index, "tiles", None)
+    if tiles is not None and int(tiles.shape[-1]) != int(index.dim):
+        # a stack that rests wider than its rows (serve/index.py
+        # rest_width): said in so many words beside the stack's own entry
+        # above, so an executable cached for the unpadded stack is never
+        # loaded (absent otherwise: no other entry's address moves)
+        facts["rest_width"] = int(tiles.shape[-1])
     if getattr(index, "rest_offset", None) is not None:
         # (a byte stack's: one more operand of the batch program, after
         # the fact; the stack's own entry above carries the at-rest type)

@@ -15,7 +15,10 @@ a stream of query points against a resident training corpus",
   own query H2D;
 - the centering mean is computed once and applied to each query batch, so
   serving results are bit-identical to a fresh ``all_knn`` call (which
-  derives the same mean from the same corpus);
+  derives the same mean from the same corpus) — wherever the stack rests
+  at its rows' width; a stack that rests zero-padded on the lane grid
+  (:func:`rest_width`) answers with the same values up to float32's
+  summation order;
 - bf16 compression is ``dtype="bfloat16"`` at build time: the resident
   tiles are stored (and computed) at half width, halving HBM residency —
   the same measured-recall contract as everywhere else in the framework.
@@ -29,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -40,6 +44,7 @@ from mpi_knn_tpu.backends.serial import (
     TileCounts,
     cap_corpus_tile,
     resident_norms,
+    screen_rule,
     serve_chunk,
     serve_chunk_filtered,
     tile_counts,
@@ -61,9 +66,11 @@ from mpi_knn_tpu.ops.topk import (
 )
 from mpi_knn_tpu.parallel.partition import (
     make_global_ids,
+    pad_cols,
     pad_rows_any,
     pad_to_multiple,
 )
+from mpi_knn_tpu.utils.logs import log
 
 
 def onepass_holds(index) -> bool:
@@ -481,7 +488,8 @@ def build_index(
     with _flight_span("index-build", cat="index", backend=backend,
                       rows=int(m), dim=int(dim), metric=cfg.metric,
                       bytes=int(corpus.size) * corpus.dtype.itemsize):
-        index = _build_index_resident(corpus, cfg, mesh, backend, m, dim)
+        index = _build_index_resident(
+            corpus, cfg, mesh, backend, m, dim, tagged=tags is not None)
     if tags is not None:
         from mpi_knn_tpu.serve.tags import build_tag_index
 
@@ -491,43 +499,154 @@ def build_index(
     return index
 
 
-@functools.partial(jax.jit, static_argnames=("c_pad", "c_tile", "dtype"))
-def _pad_and_tile(corpus, c_pad: int, c_tile: int, dtype):
-    """Tile by tile into a zeroed stack: the program's temporaries are one
-    tile's, whatever layouts the device keeps the two shapes in (as one
-    ``pad`` + ``reshape`` the v5e compiler takes 8.3 GB of them at
-    9.8 M x 100: a padded copy, then its re-layout)."""
+@functools.partial(
+    jax.jit, static_argnames=("c_pad", "c_tile", "dtype", "width"))
+def _pad_and_tile(corpus, c_pad: int, c_tile: int, dtype, width: int):
+    """Tile by tile into a zeroed stack ``width`` columns wide
+    (:func:`rest_width`: columns past the rows' own stay zero): the
+    program's temporaries are one tile's, whatever layouts the device keeps
+    the two shapes in (as one ``pad`` + ``reshape`` the v5e compiler takes
+    8.3 GB of them at 9.8 M x 100: a padded copy, then its re-layout)."""
     m, dim = corpus.shape
     full = m // c_tile
 
     def one_tile(t, stack):
         tile = jax.lax.dynamic_slice_in_dim(corpus, t * c_tile, c_tile)
         return jax.lax.dynamic_update_index_in_dim(
-            stack, tile.astype(dtype), t, 0)
+            stack, pad_cols(tile.astype(dtype), width), t, 0)
 
-    stack = jnp.zeros((c_pad // c_tile, c_tile, dim), dtype)
+    stack = jnp.zeros((c_pad // c_tile, c_tile, width), dtype)
     if full:  # a corpus under one tile has no whole tile to slice
         stack = jax.lax.fori_loop(0, full, one_tile, stack)
     if m % c_tile:
-        tail = jnp.pad(corpus[full * c_tile:].astype(dtype),
+        tail = jnp.pad(pad_cols(corpus[full * c_tile:].astype(dtype), width),
                        [(0, c_tile - m % c_tile), (0, 0)])
         stack = stack.at[full].set(tail)
     return stack
 
 
-def _tile_stack(corpus, c_pad: int, c_tile: int, dtype):
-    """The (tiles, c_tile, dim) stack of a corpus padded to ``c_pad``
-    rows. A device corpus that needs padding (headroom, a last tile) is
+def _tile_stack(corpus, c_pad: int, c_tile: int, dtype, width: int):
+    """The (tiles, c_tile, width) stack of a corpus padded to ``c_pad``
+    rows and, where the stack rests wider than its rows
+    (:func:`rest_width`), to ``width`` zero-filled columns. A device corpus
+    that needs either padding (headroom, a last tile, the lane grid) is
     padded and tiled by ONE program: done eagerly the padded copy stands
     beside the stack, and where the device keeps the two shapes in
     different layouts (a TPU at a width off its 128-lane grid) the
     reshape is a copy too — at 9.8 M x 100 the caller's array, the
     centred copy, the padded copy and the stack were 15.7 GB, and the
     build died 3.4 GB short on a v5e."""
-    if isinstance(corpus, jax.Array) and c_pad != corpus.shape[0]:
-        return _pad_and_tile(corpus, c_pad=c_pad, c_tile=c_tile, dtype=dtype)
+    m, dim = corpus.shape
+    if isinstance(corpus, jax.Array) and (c_pad, width) != (m, dim):
+        return _pad_and_tile(
+            corpus, c_pad=c_pad, c_tile=c_tile, dtype=dtype, width=width)
+    corpus = pad_cols(corpus, width)  # a host corpus: one host pad
     return pad_rows_any(corpus, c_pad, dtype=dtype).reshape(
-        -1, c_tile, corpus.shape[1])
+        -1, c_tile, width)
+
+
+_LANES = 128  # the TPU's lane grid: a float32 row of a multiple rests whole
+# What condition (c) of :func:`rest_width` leaves free beside the wider
+# stack, so that a build with room by a few bytes keeps the narrower form
+# and the layout does not turn on the allocator's last bytes: the build's
+# own program and the norm pass (64 MiB of temporaries at most, compiled for
+# the v5e: tests/test_pallas.py), then what serving needs beside the stack
+# — a batch program's temporaries (64 MiB each, a few in flight), its
+# operands, the upsert's (16 MiB) — and as much again for fragmentation
+REST_RESERVE_BYTES = 1 << 30
+
+
+def rest_width(cfg: KNNConfig, dim: int, c_tile: int, slots: int, *,
+               onepass: bool, tagged: bool = False,
+               free_bytes: int | None = None) -> tuple[int, int]:
+    """``(width, short)``: the columns a dense serial stack of ``slots``
+    row slots in tiles of ``c_tile`` RESTS at — the rows' own ``dim``, or
+    ``dim`` rounded up to the lane grid with the columns past ``dim`` zero
+    — and how (c) below fell out: the bytes the device was short by where
+    its room ALONE declined the wider form (positive), the bytes it had to
+    spare past the reserve where it granted it (negative or 0), 0 where
+    room was never asked. Asked once, at the build, from what the build can
+    observe; no setting. The stack rests padded iff
+
+    (a) ``dim`` is off the lane grid: there a TPU keeps a float32 (T, c,
+        d) stack rows-minor, a tile's slice is a copy every step and a
+        candidate's row ``dim`` scalar reads
+        (``serve/mutate.py stack_rests_row_major``);
+    (b) the certified screen would engage at the padded width for the
+        index's serving tile (``backends/serial.py screen_rule`` at
+        ``cfg.query_tile`` rows: float32 rows at ``highest``, a metric of
+        the static path, carried lists, >= 1024 query rows, no one-pass
+        branch — ``onepass``: a whole-number corpus already ranks in one
+        pass inside the fused kernel at its own width — and no predicate's
+        words, ``tagged``) — three passes for six are what the 28 % more
+        bytes at d = 100 buy; without the screen the padding would only
+        remove the slice;
+    (c) the device has room for the padded stack and its planes AND
+        :data:`REST_RESERVE_BYTES` beside what the build holds at that
+        moment (the caller's device array, the centred copy):
+        ``free_bytes``, from the device's own memory statistics
+        (:func:`device_free_bytes`); None — the backend reports none, the
+        CPU — assumes room.
+
+    Zero columns add exact zeros to every dot and every norm: a padded
+    index answers with the values of the unpadded rows up to float32's
+    summation order."""
+    wide = pad_to_multiple(dim, _LANES)
+    if wide == dim or screen_rule(cfg, cfg.query_tile, c_tile, wide,
+                                  branch=onepass, filtered=tagged) is None:
+        return dim, 0
+    if free_bytes is None:
+        return wide, 0
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    planes = 4 + (0 if cfg.metric == "ip" else 4)  # ids, norms: a slot
+    short = (slots * (wide * itemsize + planes) + REST_RESERVE_BYTES
+             - free_bytes)
+    return (dim if short > 0 else wide), short
+
+
+def device_free_bytes(corpus) -> int | None:
+    """Bytes the device that will hold the stack has free right now — its
+    limit less what is in use, by its own statistics — or None where the
+    backend reports none (the CPU). The device is the corpus's own where
+    the corpus is a device array, else the default one."""
+    device = (next(iter(corpus.devices())) if isinstance(corpus, jax.Array)
+              else jax.local_devices()[0])
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+
+
+def _rest_width_of_build(cfg, dim, c_tile, c_pad, onepass, tagged, corpus):
+    """:func:`rest_width` for a build, said where an operator reads it: one
+    log line and the gauge ``serve_index_rest_width``."""
+    free = device_free_bytes(corpus)
+    width, short = rest_width(
+        cfg, dim, c_tile, c_pad, onepass=onepass, tagged=tagged,
+        free_bytes=free)
+    obs_metrics.get_registry().gauge(
+        "serve_index_rest_width",
+        help="columns a row of the dense tile stack rests at: the rows' "
+        "own width, or that rounded up to the 128-lane grid (zero-filled) "
+        "where the certified screen then ranks in three passes and the "
+        "device has the room (serve/index.py rest_width)",
+    ).set(float(width))
+    if short > 0:
+        log.info(
+            "index rests at its rows' width %d: %d columns on the lane "
+            "grid and a reserve of %d bytes want %d bytes more than the "
+            "device has free", dim, pad_to_multiple(dim, _LANES),
+            REST_RESERVE_BYTES, short)
+    elif width != dim:
+        log.info(
+            "index rests zero-padded at %d columns (rows of %d): row-major "
+            "on the lane grid, screened scan; %s", width, dim,
+            f"{-short} bytes to spare past a reserve of "
+            f"{REST_RESERVE_BYTES}" if free is not None else "room "
+            "assumed (the backend reports no memory statistics)")
+    else:
+        log.info("index rests at its rows' width %d", dim)
+    return width
 
 
 def _stamp_index_gauges(cfg: KNNConfig, onepass) -> None:
@@ -565,8 +684,10 @@ def _serial_index(cfg, m, dim, c_tile, mu, tiles, tile_ids, tile_sqs,
     obs_metrics.get_registry().gauge(
         "serve_index_rest_bytes_per_row",
         help="resident bytes of the dense tile stack and its id and norm "
-        "planes over the stack's row slots: 4 d + 8 for float32 rows, d + "
-        "8 for a byte stack (dtype=uint8), 4 less under metric=ip",
+        "planes over the stack's row slots: 4 w + 8 for float32 rows at "
+        "the rest width w (serve_index_rest_width: d, or d rounded up to "
+        "the lane grid), d + 8 for a byte stack (dtype=uint8), 4 less "
+        "under metric=ip",
     ).set(planes / (tiles.shape[0] * tiles.shape[1]))
     return CorpusIndex(
         cfg=cfg.replace(backend="serial"), backend="serial", m=m, dim=dim,
@@ -594,7 +715,8 @@ def _serial_tiling(cfg: KNNConfig, m: int) -> tuple[int, int]:
     return c_tile, c_pad
 
 
-def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
+def _build_index_resident(corpus, cfg, mesh, backend, m, dim,
+                          tagged=False) -> CorpusIndex:
 
     mu = None
     onepass = None
@@ -655,7 +777,9 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
     # O(m·d) reduction all_knn redoes per call — here they are index state)
     dtype = jnp.dtype(cfg.dtype)
     c_tile, c_pad = _serial_tiling(cfg, m)
-    tiles = _tile_stack(corpus, c_pad, c_tile, dtype)
+    width = _rest_width_of_build(
+        cfg, dim, c_tile, c_pad, onepass is not None, tagged, corpus)
+    tiles = _tile_stack(corpus, c_pad, c_tile, dtype, width)
     tile_ids = jnp.asarray(make_global_ids(m, c_pad).reshape(-1, c_tile))
     # knn_chunk_update's own norm construction (squared norms for L2; for
     # cosine the rows' INVERSE norms, so that no batch normalises a corpus
@@ -673,7 +797,8 @@ def _build_index_resident(corpus, cfg, mesh, backend, m, dim) -> CorpusIndex:
 
 @functools.partial(jax.jit, static_argnames=("sums",), donate_argnums=(0,))
 def _ingest_block(stack, block, row0, sums: bool):
-    """``stack`` (T, c_tile, d), donated, with the ``n`` rows of ``block``
+    """``stack`` (T, c_tile, w), donated, with the ``n`` rows of ``block``
+    (zero-filled to the stack's width, :func:`rest_width`)
     written at row ``row0`` of the stack viewed flat, tile by tile as
     :func:`_pad_and_tile` writes one array: the program's temporaries are
     the block's (its rows at rest, and a copy with a tile of margin either
@@ -683,11 +808,11 @@ def _ingest_block(stack, block, row0, sums: bool):
     float stack always ``n``), and under ``sums`` the column sums (int32,
     exact, of a byte stack's rows; else float32) and whether every element
     is a whole number."""
-    n_tiles, c_tile, dim = stack.shape
+    n_tiles, c_tile, width = stack.shape
     n = block.shape[0]
     bytes_rest = stack.dtype == jnp.uint8
     unfit = first_unfit_row(block) if bytes_rest else jnp.int32(n)
-    rows = block.astype(stack.dtype)
+    rows = pad_cols(block.astype(stack.dtype), width)
     col = whole = None
     if sums and bytes_rest:
         col = jnp.sum(rows, axis=0, dtype=jnp.int32)
@@ -755,6 +880,14 @@ def _each_block(blocks):
         i += 1
 
 
+def _is_whole(block) -> bool:
+    """Every element of ``block`` a whole number (``x == rint(x)``), read
+    where the block is (numpy or the device)."""
+    xp = jnp if isinstance(block, jax.Array) else np
+    block = xp.asarray(block)
+    return bool(xp.all(block == xp.rint(block)))
+
+
 def build_index_blocks(
     shape,
     blocks,
@@ -784,7 +917,9 @@ def build_index_blocks(
     blocks' float32 column sums added in float64 (rounded to whole numbers
     for a whole-number corpus, as ``center_corpus`` rounds it) and the
     stack is centred in place: the same index as :func:`build_index`'s to
-    the bit for whole-number rows, to the mean's last bits otherwise.
+    the bit for whole-number rows, to the mean's last bits otherwise
+    (off the lane grid the FIRST block's rows say whether the stack rests
+    at its rows' width or zero-padded: :func:`rest_width`).
     Other dtypes (a bfloat16 stack is centred BEFORE it is narrowed), the
     ring backends, tags and headroom take :func:`build_index`."""
     from mpi_knn_tpu.api import resolve_backend
@@ -815,9 +950,27 @@ def build_index_blocks(
     with _flight_span("index-build", cat="index", backend=backend, rows=m,
                       dim=dim, metric=cfg.metric,
                       bytes=m * dim * rest.itemsize):
-        tiles = jnp.zeros((c_pad // c_tile, c_tile, dim), rest)
+        # Whether float32 rows are whole numbers is known after the last
+        # block, the stack's width before the first: the FIRST block
+        # speaks for the rest (read only where the answer can move the
+        # width). Whole numbers there and the stack rests at the rows'
+        # width, as build_index rests a whole-number corpus (it ranks in
+        # one pass already); should a later block hold a fraction the
+        # stack stays so and carries no fact — the program a fractional
+        # stack ran before there was a rule. A fraction there and no later
+        # block can grant the fact.
+        each = _each_block(blocks)
+        first = next(each, None)
+        whole = bool(dim % _LANES and centred and rest != jnp.uint8
+                     and onepass_applies(cfg) and first is not None
+                     and _is_whole(first))
+        width = _rest_width_of_build(
+            cfg, dim, c_tile, c_pad, whole, False, None)
+        each = itertools.chain(() if first is None else (first,), each)
+        del first  # (a block is dropped once it is in the stack)
+        tiles = jnp.zeros((c_pad // c_tile, c_tile, width), rest)
         at = 0
-        for block in _each_block(blocks):
+        for block in each:
             if not isinstance(block, jax.Array):
                 block = np.asarray(block)
             n = int(block.shape[0])
@@ -850,7 +1003,8 @@ def build_index_blocks(
                 if all(bool(whole) for *_, whole in ingested):
                     mu = np.rint(mu)
                 tiles, fact = _centre_stack(
-                    tiles, jnp.asarray(mu, jnp.float32), m=m)
+                    tiles, pad_cols(jnp.asarray(mu, jnp.float32), width),
+                    m=m)
             mu = np.asarray(mu, dtype=np.float64)  # the query side's, as
             # an index from a host array holds it
             if onepass_applies(cfg) and bool(fact):

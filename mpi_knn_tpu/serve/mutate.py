@@ -73,6 +73,7 @@ from mpi_knn_tpu.ivf.mutate import (
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.distance import bf16_exact
+from mpi_knn_tpu.parallel.partition import pad_cols
 from mpi_knn_tpu.resilience.heartbeat import maybe_beat
 from mpi_knn_tpu.serve.index import onepass_holds
 
@@ -167,14 +168,17 @@ def serial_upsert_chunk(
 ):
     """Donated in-place upsert into the serial tile stack: headroom rows
     (id −1 padding) absorb new rows at (tile, slot) positions the flat
-    freelist allocated; updated ids clear their old slot first. The
-    at-rest cast and the per-row norms are the build's own math
+    freelist allocated; updated ids clear their old slot first. The rows
+    are zero-filled to the width the stack rests at (``serve/index.py
+    rest_width``) ahead of the at-rest cast and the per-row norms, the
+    build's own math
     (``ivf.mutate.store_rows_and_sqs``). ``by_tile``: how the rows reach
     the stack (:func:`scatter_rows_by_tile`), chosen by the layout the
     device keeps the stack in (:func:`stack_rests_row_major`)."""
     from mpi_knn_tpu.ivf.mutate import UPSERT_SCOPE, store_rows_and_sqs
 
     with jax.named_scope(UPSERT_SCOPE):
+        rows = pad_cols(rows, tiles.shape[-1])
         at_rest, _, sqs = store_rows_and_sqs(rows, cfg, rows.shape[-1])
         tile_ids = tile_ids.at[clear_t, clear_s].set(-1, mode="drop")
         tile_ids = tile_ids.at[tpos, spos].set(new_ids, mode="drop")

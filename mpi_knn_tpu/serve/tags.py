@@ -418,6 +418,12 @@ def build_tag_index(index, tags, *, threshold: int | None = None,
         raise ValueError("an index with tags needs max_query_tags >= 1")
     indptr, indices = as_csr(tags, index.m)
     n_tiles, c_tile, dim = index.tiles.shape
+    if dim != index.dim:
+        raise ValueError(
+            f"this index rests zero-padded at {dim} columns (rows of "
+            f"{index.dim}): the gather's programs read the stack at the "
+            "rows' width — hand the tags to build_index(tags=), which "
+            "rests a tagged stack there (serve/index.py rest_width)")
     vocab = int(indices.max()) + 1 if indices.size else 1
     counts = np.bincount(indices, minlength=vocab).astype(np.int64)
     bitset_bytes = n_tiles * c_tile // 8

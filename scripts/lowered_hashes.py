@@ -39,6 +39,20 @@ import os
 import sys
 
 
+# what a v5e's ``memory_stats()["bytes_limit"]`` reads (chip run, PR 49)
+V5E_USABLE_BYTES = 16_909_336_064
+
+
+def v5e_free_at_build(rows: int, dim: int, metric: str) -> int:
+    """Bytes a v5e has free when a serving cell's build asks how wide its
+    stack may rest (``serve/index.py rest_width``, ISSUE 49) — a MODEL of
+    what the benchmark's launchers hold then, not a reading: the (rows,
+    dim) float32 array they hand over and, under L2, its centred copy.
+    The one place the model is written: the cells' programs here and the
+    tests that ask the rule at the cells' shapes use it."""
+    return V5E_USABLE_BYTES - (2 if metric == "l2" else 1) * rows * dim * 4
+
+
 def _record(out: dict, texts_dir: str | None, label: str, text: str,
             suffix: str) -> None:
     """``out[label]`` = the text's hash; the text under ``texts_dir``."""
@@ -93,12 +107,19 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
 
     jax.default_backend = lambda: "tpu"
     arg = jax.ShapeDtypeStruct
-
-    def stack(cfg, rows, dim, c_tile):
+    def stack(cfg, rows, dim, c_tile, fact=True, tagged=False):
         """The resident stack, its id and norm planes, the one-pass
-        verdict: the arguments every serial program ends with."""
+        verdict: the arguments every serial program ends with. The stack
+        is as wide as the checkout's build would rest it on a v5e
+        (:func:`v5e_free_at_build`; ``serve/index.py rest_width``, ISSUE
+        49; an older checkout rests every stack at its rows' width)."""
         tiles = pad_to_multiple(
             int(np.ceil(rows * (1 + cfg.bucket_headroom))), c_tile) // c_tile
+        if hasattr(serve_index, "rest_width"):
+            dim = serve_index.rest_width(
+                cfg, dim, c_tile, tiles * c_tile, onepass=fact,
+                tagged=tagged,
+                free_bytes=v5e_free_at_build(rows, dim, cfg.metric))[0]
         # (a byte stack where the configuration rests one: ISSUE 48)
         rest = jnp.uint8 if cfg.dtype == "uint8" else jnp.float32
         return (arg((tiles, c_tile, dim), rest),
@@ -110,17 +131,19 @@ def cell_hashes(root: str, texts_dir: str | None) -> dict:
         the one-pass fact; ``tagged``: built with tags, as the
         configuration states by naming ``max_query_tags``."""
         c_tile = serial.effective_tiles(cfg, rows, cfg.query_tile)[1]
-        *resident, fact = stack(cfg, rows, dim, c_tile)
-        tags = types.SimpleNamespace(
-            # (the planes' count is the data's: any traces the same text)
-            tag_bits=arg((cfg.max_query_tags + 1, resident[0].shape[0],
-                          c_tile // 32), jnp.uint32)) if tagged else None
-        layout = serve_index.TAGGED_SERIAL if tags else serve_index.SERIAL
+        layout = serve_index.TAGGED_SERIAL if tagged else serve_index.SERIAL
         more = {}
         if cfg.dtype == "uint8":  # its offset is one more resident operand
             layout = serve_index.BYTE_SERIAL
             more["rest_offset"] = arg((dim,), jnp.float32)
-        for onepass in ((fact, None) if cfg.metric == "l2" else (None,)):
+        for has_fact in ((True, False) if cfg.metric == "l2" else (False,)):
+            *resident, fact = stack(cfg, rows, dim, c_tile, has_fact, tagged)
+            onepass = fact if has_fact else None
+            tags = types.SimpleNamespace(
+                # (the planes' count is the data's: any traces the same
+                # text)
+                tag_bits=arg((cfg.max_query_tags + 1, resident[0].shape[0],
+                              c_tile // 32), jnp.uint32)) if tagged else None
             yield "-nofact" if onepass is None else "", (
                 serve_index.CorpusIndex(
                     cfg, "serial", rows, dim, c_tile, None, layout,

@@ -668,17 +668,26 @@ def test_default_ivf_lint_cells_are_clean():
             assert "R5-donation" in res.rules_run
 
 
-def test_build_from_serve_corpus_index(rng):
+@pytest.mark.parametrize("m,d,rests_at", [
+    (512, 24, 24),
+    # 2048-row tiles of fractional float32 rows off the lane grid rest
+    # zero-padded to it (ISSUE 49, ``serve/index.py rest_width``): the rows
+    # come back out of the stack at their own width
+    (2148, 100, 128),
+])
+def test_build_from_serve_corpus_index(rng, m, d, rests_at):
     """An IVFIndex built FROM a serial-layout serve.CorpusIndex (its
     centered resident tiles, no second centering pass) answers
     identically to one built from the raw array."""
     from mpi_knn_tpu.serve import build_index
 
-    X = _clustered(rng, m=512, d=24)
+    X = _clustered(rng, m=m, d=d)
     cfg = KNNConfig(k=5, partitions=8, nprobe=3)
     from_array = build_ivf_index(X, cfg)
     corpus_idx = build_index(X, KNNConfig(k=5, backend="serial"))
+    assert corpus_idx.tiles.shape[-1] == rests_at and corpus_idx.dim == d
     from_index = build_ivf_index(corpus_idx, cfg)
+    assert from_index.dim == d
     np.testing.assert_array_equal(
         np.asarray(from_array.bucket_ids),
         np.asarray(from_index.bucket_ids),

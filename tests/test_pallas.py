@@ -203,8 +203,8 @@ def _assert_one_kernel_walks_the_stack(
         "cond/branch_0_fun/while/body" in ln for ln in bins), bins
 
 
-@pytest.mark.parametrize("d", [8, 64, 100, 104, 128, 192, 200, 784, 1000,
-                               1536])
+@pytest.mark.parametrize("d", [8, 64, 100, 104, 128, 192, 200, 256, 784,
+                               1000, 1536])
 def test_the_rest_layout_of_a_stack_follows_its_shape_alone(v5e_devices, d):
     """What ``ops/topk.py fused_scan_engages`` says of a stack at rest,
     READ in programs compiled for the v5e: a float32 (T, c, d) parameter's
@@ -239,9 +239,13 @@ def test_the_rest_layout_of_a_stack_follows_its_shape_alone(v5e_devices, d):
 
     read = {(tiles, c): layout(tiles, c, "first")
             for c in (1024, 8192)
-            for tiles in (1, 3, 127, 128, 192, 768, 1221, 1224)
+            for tiles in (1, 3, 127, 128, 192, 768, 1157, 1221, 1224)
             if tiles * c * d * 4 < 12e9}  # what a 16 GB chip can hold
     assert len(read) >= 12
+    # the shapes ``serve/index.py rest_width`` pads to (ISSUE 49): the
+    # streaming cell's stack at 128 columns, the inner-product cell's at 256
+    for width, tiles in ((128, 1224), (256, 1157)):
+        assert d != width or (tiles, 8192) in read
     for (tiles, c) in ((3, 1024), (128, 8192)):
         for use in ("gather", "view"):
             assert layout(tiles, c, use) == read[tiles, c], (tiles, c, use)
@@ -633,7 +637,10 @@ def test_stack_with_headroom_builds_on_the_v5e_without_a_second_copy(
     different layouts (``{0,1}`` and ``{1,0,2}``), so a pad + reshape is a
     padded copy AND its re-layout (8.3 GB of temporaries; done eagerly the
     build held four corpus-sized arrays and died 3.4 GB short); tile by
-    tile the program's temporaries are one tile's."""
+    tile the program's temporaries are one tile's. Since ISSUE 49 this is
+    the build where ``serve/index.py rest_width`` declines (the cell's
+    control, a device too full); the padded one is read below
+    (``-k rest_layout``)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -643,10 +650,99 @@ def test_stack_with_headroom_builds_on_the_v5e_without_a_second_copy(
     one = SingleDeviceSharding(v5e_devices[0])
     program = serve_index._pad_and_tile.lower(
         jax.ShapeDtypeStruct((9_830_400, 100), jnp.float32, sharding=one),
-        c_pad=10_027_008, c_tile=8192, dtype=jnp.dtype("float32")).compile()
+        c_pad=10_027_008, c_tile=8192, dtype=jnp.dtype("float32"),
+        width=100).compile()
     mem = program.memory_analysis()
     assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
     assert mem.output_size_in_bytes == 1224 * 8192 * 100 * 4  # no lane pad
+
+
+def test_the_rest_layout_of_a_padded_stack_from_build_to_batch(
+        v5e_devices, monkeypatch):
+    """``stream-msturing10m-runbook`` since ISSUE 49: 9 830 400 x 100 rows
+    rest zero-padded at 128 columns (``serve/index.py rest_width``), 1224
+    tiles row-major on the lane grid, in every program that touches the
+    stack. The BUILD is still the one program that pads and tiles: its
+    temporaries a tile's, its output the 5.13e9 B stack and no copy beside
+    it. The UPSERT is the one-scatter form the d = 128 indexes take, every
+    store array aliased, nothing store-sized copied. The BATCH program
+    pads its 1024 x 100 query tile itself, screens (``high`` in the scan,
+    ``highest`` in the re-scan), gathers the candidates from the stack
+    viewed flat — a bitcast — and neither copies nor transposes the
+    stack; the d = 100 stack's per-step slice copy (``f32[1,8192,100]``)
+    is gone."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.serve import index as serve_index
+    from mpi_knn_tpu.serve import mutate
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, tiles, c_tile, dim, wide, chunk = 1024, 1224, 8192, 100, 128, 1024
+    cfg = KNNConfig(k=10, backend="serial", query_tile=q, corpus_tile=c_tile,
+                    exclude_zero=False)
+    assert serve_index.rest_width(
+        cfg, dim, c_tile, tiles * c_tile, onepass=False)[0] == wide
+    rest = rf"f32\[{tiles},{c_tile},{wide}\]\{{2,1,0:T\(8,128\)\}}"
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    stack = arg((tiles, c_tile, wide), jnp.float32)
+    ids_plane = arg((tiles, c_tile), jnp.int32)
+    norms = arg((tiles, c_tile), jnp.float32)
+    vec = arg((chunk,), jnp.int32)
+    with jax.enable_x64(False):
+        build = serve_index._pad_and_tile.lower(
+            arg((9_830_400, dim), jnp.float32), c_pad=tiles * c_tile,
+            c_tile=c_tile, dtype=jnp.dtype("float32"), width=wide).compile()
+        upsert = mutate.serial_upsert_jit.lower(
+            arg((chunk, dim), jnp.float32), vec, vec, vec, vec, vec,
+            stack, ids_plane, norms, cfg=cfg, by_tile=False).compile()
+        batch = jax.jit(
+            serial.serve_chunk, static_argnames=("cfg",), donate_argnums=(2, 3)
+        ).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32),
+            stack, ids_plane, norms, None, cfg=cfg).compile()
+
+    mem = build.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes == tiles * c_tile * wide * 4
+    assert re.search(rest, build.as_text())
+
+    hlo = upsert.as_text()
+    alias = re.search(r"input_output_alias=\{([^\n]*?)\}, entry", hlo)
+    assert alias and alias.group(1).count("may-alias") + alias.group(
+        1).count("must-alias") == 3, hlo[:400]
+    mem = upsert.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= tiles * c_tile * 4 * (wide + 2)
+    assert re.search(rest + r" parameter\(6\)", hlo)
+    assert "knn.mutate/upsert" in hlo
+    assert not re.search(rf"= \w+\[{tiles},{c_tile}[\],][^\n]* copy\(", hlo)
+
+    hlo = batch.as_text()
+    assert re.search(rest + r" parameter\(4\)", hlo)
+    assert not _stack_moves(hlo, tiles, wide)
+    assert f"f32[1,{c_tile},{dim}]" not in hlo and f",{dim}]" in hlo
+    dots = re.findall(r"operand_precision=\{(\w+),\w+\}[^\n]*?op_name=\"([^\"]*)"
+                      r"/dot_general\"", hlo)
+    assert sorted((p, "fallback" in name) for p, name in dots) == [
+        ("high", False), ("highest", True)], dots
+    gathered = [ln for ln in hlo.splitlines()
+                if re.search(rf"= f32\[{q},32,{wide}\]\S* gather\(", ln)]
+    assert len(gathered) == 1 and "knn.rerank" in gathered[0], gathered
+    assert re.search(
+        rf"f32\[{tiles * c_tile},{wide}\]\{{1,0\S* parameter\(0\)", hlo)
+    _assert_the_lists_ride_the_scan(hlo, q, tiles, 32)
+    assert batch.memory_analysis().temp_size_in_bytes <= 64 << 20
 
 
 # ---------------------------------------------------------------------------

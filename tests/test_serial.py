@@ -735,8 +735,11 @@ _WHICH = {
     "serve-bigann10m-bulk-1024-nofact": (False, 5, None, True, 32),
     "serve-dbpedia1m-cos-bulk-1024": (False, 5, None, True, 32),
     # d = 100: no multiple of 8, the stack rests out of the kernel's reach
+    # (whole-number rows there: no cell's data) ...
     "stream-msturing10m-runbook-1024": (True, 5, None, True),
-    "stream-msturing10m-runbook-1024-nofact": (False, 5, None, True),
+    # ... and fractional rows rest zero-padded at 128 columns, where the
+    # screen engages (ISSUE 49: ``serve/index.py rest_width``)
+    "stream-msturing10m-runbook-1024-nofact": (False, 5, None, True, 32),
     # a predicate: the one-pass branch from 256 rows, never the fused scan
     **{f"serve-yfcc10m-filter-bulk-{b}-nofact": v for b, v in _SMALL.items()},
     "serve-yfcc10m-filter-bulk-64": (False, 4, None, False),
@@ -747,6 +750,8 @@ _WHICH = {
     "serve-yfcc10m-filter-bulk-1024-nofact": (False, 5, None, True),
     "serve-bigann10m-ivf-bulk-1024": "bucket-major, one 1024-row tile",
     # an inner product: never the one-pass rule (L2's), the carried lists
+    # (d = 200 stays: a stack at 256 columns is 0.4e9 B more than the
+    # device has free beside the launcher's array)
     "serve-text2image10m-ip-bulk-1024": (False, 5, None, True),
     # a byte stack (ISSUE 48): the kernel at ``itemsize`` 1; a byte stack
     # without the fact does not exist (it holds by type), the row says what
@@ -785,9 +790,17 @@ def test_which_program_a_cell_runs(request, monkeypatch, cell, rows, fact,
     if ring:
         q_tile, c_tile = cfg.query_tile, cfg.corpus_tile
     elif "slo" in config:  # served: the bucket's tile over the index's
+        from mpi_knn_tpu.serve import index as serve_index
+        from scripts.lowered_hashes import v5e_free_at_build
+
         q_tile = min(cfg.query_tile, pad_to_multiple(rows, 8))
-        c_tile = serial.effective_tiles(
-            cfg, config["rows"], cfg.query_tile)[1]
+        c_tile, c_pad = serve_index._serial_tiling(cfg, config["rows"])
+        # ... at the width the build rests the stack at on a v5e
+        # (ISSUE 49)
+        dim = serve_index.rest_width(
+            cfg, dim, c_tile, c_pad, onepass=fact, tagged=filtered,
+            free_bytes=v5e_free_at_build(
+                config["rows"], dim, cfg.metric))[0]
     else:
         q_tile, c_tile = serial.effective_tiles(cfg, config["rows"], rows)
     onepass = fact and serial.onepass_rule(cfg, q_tile, filtered)
