@@ -251,6 +251,12 @@ def default_guards() -> GuardMap:
             # the phases' seconds of a pump turn (ISSUE 36): fed by the
             # spans of the driving thread, taken by it once a turn
             "_phase_s": "dispatch-pump",
+            # the overrun record (ISSUE 50): the cycle's phases, the
+            # sample that began it, an overrun the next retire closes —
+            # fed and taken where _phase_s is
+            "_cycle": "dispatch-pump",
+            "_cycle_from": "dispatch-pump",
+            "_overrun_open": "dispatch-pump",
         },
         waivers={
             "warm_report": "written once by the warm thread before "

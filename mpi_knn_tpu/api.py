@@ -15,6 +15,7 @@ import numpy as np
 
 from mpi_knn_tpu.backends.serial import PreparedCorpus
 from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.obs import host as obs_host
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.ops.topk import start_lane_bin_import
@@ -79,6 +80,66 @@ class _LastCorpus:
 
 
 _remembered = _LastCorpus()
+
+
+class _CallWatch:
+    """The overrun rule (``obs/host.py``) on consecutive :func:`all_knn`
+    calls that HIT one prepared corpus from one thread at one query height:
+    the entry-to-entry period against the running median of the periods
+    before it. A period is the call's own host span (entry to its last
+    dispatch's return) and what lay outside it — the device, its runtime or
+    the caller, for the result is not waited for here; the part with the
+    larger excess over its own median is where an overrun sat
+    (``dispatch`` / ``outside``). A miss, a bypass, another corpus, height
+    or thread begins anew."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rule = obs_host.OverrunRule()
+        self._report = obs_host.OverrunReport("knn_call", "api")
+        # the call before: (prepared's weakref, rows, thread, its entry's
+        # sample, its host span's seconds)
+        self._last = None
+        self._calls = 0
+
+    def note(self, prepared, rows: int, entry, host_s: float) -> None:
+        """One finished call: ``prepared`` is the corpus it hit (None: it
+        prepared its own), ``entry`` its :func:`~obs.host.host_sample` at
+        entry, ``host_s`` its span's seconds."""
+        with self._lock:
+            last, self._last = self._last, None
+            if prepared is None:
+                self._rule.reset()
+                return
+            self._calls += 1
+            tid = threading.get_ident()
+            self._last = (weakref.ref(prepared), rows, tid, entry, host_s)
+            if last is None:
+                self._rule.reset()
+                return
+            ref, last_rows, last_tid, began, dispatch_s = last
+            if ref() is not prepared or (last_rows, last_tid) != (rows, tid):
+                self._rule.reset()
+                return
+            parts = {"dispatch": dispatch_s,
+                     "outside": max(0.0, entry.at - began.at - dispatch_s)}
+            over = self._rule.judge(rows, parts)
+            if over is not None:
+                self._report(
+                    obs_metrics.get_registry(), over.where, over.excess_s,
+                    seq=self._calls - 1, rows=rows,
+                    median_ms=round(1e3 * over.median_s, 3),
+                    parts_ms={n: round(1e3 * v, 3)
+                              for n, v in parts.items()},
+                    **obs_host.host_delta(began, entry))
+
+    def reset(self) -> None:
+        with self._lock:
+            self._last = None
+            self._rule.reset()
+
+
+_calls = _CallWatch()
 
 
 def _count_prepare(result: str) -> None:
@@ -205,18 +266,37 @@ def all_knn(
         raise ValueError(
             "a prepared corpus answers queries: pass queries=... (the "
             "all-pairs job needs the rows themselves)")
+    obs_host.install_gc_hook()
+    entry = obs_host.host_sample()
+    rows = len(corpus if queries is None else queries)
+    observe = obs_metrics.get_registry().histogram(
+        "knn_call_host_seconds",
+        help="per all_knn call: entry to its last dispatch's return (the "
+        "result is not waited for)",
+    ).observe
+    host_s = []
+
+    def sink(seconds: float) -> None:
+        observe(seconds)
+        host_s.append(seconds)
+
     # entry until the last dispatch has returned (the result is not waited for)
-    with obs_spans.span(
-        "all_knn", cat="api", rows=len(corpus if queries is None else queries)
-    ):
-        return _all_knn(corpus, queries, cfg, mesh, query_ids)
+    try:
+        with obs_spans.span("all_knn", cat="api", rows=rows, sink=sink):
+            result, hit = _all_knn(corpus, queries, cfg, mesh, query_ids)
+    except BaseException:
+        _calls.reset()
+        raise
+    _calls.note(hit, rows, entry, host_s[0])
+    return result
 
 
 def _corpus_side(corpus, cfg: KNNConfig, form: dict, make,
-                 sliced: bool) -> PreparedCorpus:
+                 sliced: bool) -> tuple[PreparedCorpus, bool]:
     """The corpus side of one call: brought, remembered, or prepared now —
     and then remembered where nothing can change under the entry: a
-    concrete device array, in a call that is one slice of a job."""
+    concrete device array, in a call that is one slice of a job. With it,
+    whether it was a hit (brought or remembered)."""
     if isinstance(corpus, PreparedCorpus):
         if corpus.form != form:
             differ = {k: (corpus.form.get(k), form.get(k))
@@ -227,13 +307,13 @@ def _corpus_side(corpus, cfg: KNNConfig, form: dict, make,
                 f"this call): {differ}; prepare_corpus takes the call's "
                 "config, mesh and query_rows")
         _count_prepare("hit")
-        return corpus
+        return corpus, True
     keep = (sliced and isinstance(corpus, jax.Array)
             and not isinstance(corpus, jax.core.Tracer))
     prepared = _remembered.get(corpus, form) if keep else None
     if prepared is not None:
         _count_prepare("hit")
-        return prepared
+        return prepared, True
     prepared = _prepare(corpus, cfg, form, make)
     # nothing to keep where the handle holds the caller's own array (it
     # would pin its key) or tracers (prepared under an outer jit)
@@ -243,10 +323,11 @@ def _corpus_side(corpus, cfg: KNNConfig, form: dict, make,
     if keep:
         _remembered.put(corpus, prepared)
     _count_prepare("miss" if keep else "bypass")
-    return prepared
+    return prepared, False
 
 
-def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
+def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids):
+    """``(result, the prepared corpus the call hit or None)``."""
     start_lane_bin_import()  # under the corpus passes below
     if not isinstance(corpus, (PreparedCorpus, jax.Array)):
         corpus = np.asarray(corpus)
@@ -270,9 +351,11 @@ def _all_knn(corpus, queries, cfg: KNNConfig, mesh, query_ids) -> KNNResult:
 
     backend = resolve_backend(cfg, mesh)
     form, make = _form_and_maker(backend, cfg, m, dim, q_arr.shape[0], mesh)
-    prepared = _corpus_side(corpus, cfg, form, make, sliced=queries is not None)
+    prepared, hit = _corpus_side(
+        corpus, cfg, form, make, sliced=queries is not None)
     d, i, counts = prepared.search(q_arr, q_ids, cfg)
-    return KNNResult(dists=d, ids=i, **counts._asdict())
+    return (KNNResult(dists=d, ids=i, **counts._asdict()),
+            prepared if hit else None)
 
 
 def build_index(corpus, config: Optional[KNNConfig] = None, mesh=None,

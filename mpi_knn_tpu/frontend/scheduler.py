@@ -251,11 +251,6 @@ class FrontendScheduler:
             return rej
         req = self.coalescer.admit(tenant, queries, rows, now, filters)
         self.admitted += 1
-        self._metrics.counter(
-            "frontend_requests_total",
-            help="requests admitted into the coalescer",
-            labels={"tenant": tenant},
-        ).inc()
         return req
 
     def admit_mutation(self, tenant: str, rows: int, now: float):

@@ -600,11 +600,6 @@ class Frontend:
             help="coalesced rows per dispatched batch",
             buckets=_FILL_BUCKETS,
         ).observe(batch.rows)
-        self._metrics().counter(
-            "frontend_batches_total",
-            help="coalesced batches dispatched",
-            labels={"reason": batch.reason},
-        ).inc()
         # queue wait: admission (Frontend.submit stamped arrival_s) to
         # this hand-over, both on the pump's clock
         now = self._clock()

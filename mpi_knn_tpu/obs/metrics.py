@@ -280,6 +280,19 @@ class MetricsRegistry:
         # mixed-kind family under a single TYPE header (malformed
         # exposition a real scraper mis-types)
         self._kinds: dict[str, type] = {}
+        # callables run at the head of every snapshot (on_snapshot)
+        self._settlers: list = []
+
+    def on_snapshot(self, settle) -> None:
+        """Run ``settle(registry)`` at the head of every :meth:`snapshot`
+        (so of every ``/metrics`` read and every written report): for
+        totals that are kept where no lock may be taken — the garbage
+        collector's hook, ``obs/host.py`` — and carried into their
+        counters when somebody looks. Registered once however often it is
+        asked; :meth:`clear` keeps it."""
+        with self._lock:
+            if settle not in self._settlers:
+                self._settlers.append(settle)
 
     def _get_or_create(self, cls, name, help, **kw):
         base = name.split("{", 1)[0]
@@ -334,6 +347,10 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """JSON-able snapshot of every metric (sorted by name — the
         stable on-disk form ``mpi-knn metrics`` renders)."""
+        with self._lock:
+            settlers = list(self._settlers)
+        for settle in settlers:
+            settle(self)
         with self._lock:
             items = sorted(self._metrics.items())
         return {

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.obs import host as obs_host
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 from mpi_knn_tpu.parallel.partition import pad_rows_any
@@ -75,6 +76,11 @@ from mpi_knn_tpu.serve.index import CorpusIndex
 from mpi_knn_tpu.types import KNNResult
 from mpi_knn_tpu.utils.pjrt import pjrt_memory_stats
 from mpi_knn_tpu.utils.timing import device_sync
+
+
+# a usual ``wait`` shorter than this cannot tell a late device from a late
+# thread by the next batch's wait (ServeSession._close_overrun)
+WAIT_TELLS_S = 0.001
 
 
 def bucket_rows(n: int, base: int) -> int:
@@ -892,7 +898,7 @@ def _count_tiles(registry, counts) -> None:
 def _count_exchange(stats, exchange_bytes: int | None,
                     registry=None) -> np.ndarray:
     """Stamp one sharded batch's candidate-exchange story into the
-    metrics registry: routed candidate rows (histogram + counter),
+    metrics registry: routed candidate rows (counter),
     probe-cap overflow drops (counter — a nonzero here is recall being
     spent on routing skew), and the static exchange bytes. Returns the
     per-shard (S, N_STATS) array for callers that also want it."""
@@ -906,10 +912,6 @@ def _count_exchange(stats, exchange_bytes: int | None,
         "serve_exchange_routed_total",
         help="probe routes exchanged between shards (padded batches)",
     ).inc(routed)
-    reg.histogram(
-        "serve_exchange_routed_per_batch",
-        help="probe routes exchanged per sharded batch",
-    ).observe(routed)
     reg.counter(
         "serve_exchange_overflow_dropped_total",
         help="probes dropped at the static per-shard route cap "
@@ -983,6 +985,7 @@ class ServeSession:
         # observability: every session feeds the shared registry (the
         # compile capture must be live before warm()'s first compile)
         obs_metrics.install_jax_compile_listener()
+        obs_host.install_gc_hook()
         self._metrics = obs_metrics.get_registry()
         self.policy = resilience
         if resilience is not None:
@@ -995,6 +998,16 @@ class ServeSession:
         # seconds of the driving thread that its phases have covered since
         # phase_remainder last took them
         self._phase_s = 0.0
+        # the overrun record (obs/host.py): the phases' seconds of the
+        # cycle that the next retire ends, the driving thread's sample at
+        # the retire that began it, the cycles of each bucket height so
+        # far, and a ``wait`` overrun that the batch behind it has yet to
+        # tell apart (_judge closes it one retire later)
+        self._cycle: dict[str, float] = {}
+        self._cycle_from: obs_host.Sample | None = None
+        self._overruns = obs_host.OverrunRule()
+        self._overrun_open: tuple | None = None
+        self._report_overrun = obs_host.OverrunReport("serve_batch", "serve")
         # cold-start readiness (ISSUE 12): warm() publishes per-cell
         # progress here — /healthz's warming block and the front end's
         # per-bucket admission read it (possibly from other threads)
@@ -1071,6 +1084,7 @@ class ServeSession:
         def sink(seconds: float) -> None:
             inc(seconds)
             self._phase_s += seconds
+            self._cycle[phase] = self._cycle.get(phase, 0.0) + seconds
 
         return sink
 
@@ -1356,39 +1370,103 @@ class ServeSession:
                 rows=res.rows,
             )
 
-    def _note_latency(self, res: BatchResult) -> None:
-        """Deadline accounting at retire time: count CONSECUTIVE breaches
-        and shed one ladder rung when the policy's patience runs out. A
-        single slow batch (compile, GC pause) never degrades; a breach
-        streak does, and the event is recorded. Retry backoff sleeps are
-        EXCLUDED from the comparison (``latency_s`` itself stays the
+    def _judge(self, res: BatchResult) -> None:
+        """The one judge of a retired batch, at the end of its ``wait``:
+        the policy's deadline and the overrun rule read its numbers once.
+
+        **The deadline** (with a policy that has one): count CONSECUTIVE
+        breaches and shed one ladder rung when the policy's patience runs
+        out. A single slow batch (compile, GC pause) never degrades; a
+        breach streak does, and the event is recorded. Retry backoff sleeps
+        are EXCLUDED from the comparison (``latency_s`` itself stays the
         honest dispatch→sync total): backoff is self-inflicted waiting on
         a transient fault, not load — counting it would let two transport
         blips walk the one-way ladder and spend recall on a problem the
-        ladder's smaller programs cannot fix."""
+        ladder's smaller programs cannot fix.
+
+        **The overrun rule** (always; ``obs/host.py``): the driving thread's
+        busy seconds of the CYCLE this retire ends — from the same point of
+        the retire before, less what ``idle`` and ``hold`` took — against
+        the running median of the cycles of this bucket height. One that
+        overruns names the phase with the largest excess over its own
+        median; ``wait`` with a batch in flight behind it is told apart one
+        retire later by that batch's wait (:meth:`_close_overrun`). It
+        changes nothing the session does: counters, an event, a log line."""
+        now = obs_host.host_sample()
         pol = self.policy
-        if pol is None or pol.batch_deadline_s is None:
-            return
-        if res.latency_s - sum(res.backoffs) <= pol.batch_deadline_s:
-            self._consecutive_breaches = 0
-            return
-        res.deadline_breached = True
-        with self._stats_lock:
-            self.deadline_breaches += 1
-        self._consecutive_breaches += 1
+        if pol is not None and pol.batch_deadline_s is not None:
+            if res.latency_s - sum(res.backoffs) <= pol.batch_deadline_s:
+                self._consecutive_breaches = 0
+            else:
+                res.deadline_breached = True
+                with self._stats_lock:
+                    self.deadline_breaches += 1
+                self._consecutive_breaches += 1
+                self._metrics.counter(
+                    "serve_deadline_breaches_total",
+                    help="batches whose dispatch→sync latency overran the "
+                    "deadline",
+                ).inc()
+                if self._consecutive_breaches >= pol.degrade_after:
+                    self.shed_rung(
+                        reason="deadline-breach", after_batch=res.seq)
+        began, self._cycle_from = self._cycle_from, now
+        phases, self._cycle = self._cycle, {}
+        if began is None:
+            return  # the first retire: a cycle begins here
         self._metrics.counter(
-            "serve_deadline_breaches_total",
-            help="batches whose dispatch→sync latency overran the deadline",
-        ).inc()
-        if self._consecutive_breaches >= pol.degrade_after:
-            self.shed_rung(reason="deadline-breach", after_batch=res.seq)
+            "serve_pump_cpu_seconds_total",
+            help="CPU seconds of the thread that drives the session (the "
+            "front end's pump), from one retire to the next",
+        ).inc(max(0.0, now.cpu_s - began.cpu_s))
+        away = phases.pop("idle", 0.0) + phases.pop("hold", 0.0)
+        # the phases partition the thread's time: what no span covered
+        phases["other"] = max(
+            0.0, now.at - began.at - away - sum(phases.values()))
+        if self._overrun_open is not None:
+            self._close_overrun(phases.get("wait", 0.0))
+        over = self._overruns.judge(res.bucket, phases)
+        if over is None:
+            return
+        self._overrun_open = (over, {
+            "seq": res.seq, "bucket": res.bucket,
+            "median_ms": round(1e3 * over.median_s, 3),
+            "phases_ms": {n: round(1e3 * v, 3) for n, v in phases.items()},
+            "inflight": len(self._inflight),
+            **obs_host.host_delta(began, now),
+        })
+        if over.where != "wait" or not self._inflight:
+            self._close_overrun(None)
+
+    def _close_overrun(self, next_wait_s: float | None) -> None:
+        """Keep the open overrun. With a second batch in flight behind a
+        long ``wait``, that batch's own wait says who was late: near zero,
+        the device had finished both long before the thread came back
+        (``wait-host``: the interpreter lock, the scheduler, a stopped
+        process, or the runtime's completion reaching the thread late — the
+        host's side of ``device_sync``, not the device's compute); near the
+        usual wait, the device delivered late
+        (``wait-device``: the device, its runtime, the transfer). A host
+        stall that overruns took at least half a cycle, so it leaves the
+        next wait under half of its median; a usual wait too short to halve
+        tells nothing and the record says plain ``wait``."""
+        (over, fields), self._overrun_open = self._overrun_open, None
+        where = over.where
+        if next_wait_s is not None and over.where_median_s >= WAIT_TELLS_S:
+            late_host = next_wait_s < 0.5 * over.where_median_s
+            where = "wait-host" if late_host else "wait-device"
+        self._report_overrun(
+            self._metrics, where, over.excess_s,
+            next_wait_ms=(None if next_wait_s is None
+                          else round(1e3 * next_wait_s, 3)),
+            **fields)
 
     def shed_rung(self, *, reason: str = "deadline-breach",
                   after_batch: int | None = None) -> str | None:
         """Walk ONE rung down the degradation ladder, explicitly.
 
         Two callers: the session's own deadline machinery
-        (``_note_latency``, on a breach streak) and the serving front
+        (``_judge``, on a breach streak) and the serving front
         end's SLO scheduler (``mpi_knn_tpu.frontend.scheduler``, on
         sustained queue growth — overload is visible upstream of the
         per-batch latency there). Either way the event is recorded the
@@ -1558,7 +1636,7 @@ class ServeSession:
         with self._stats_lock:
             self.latencies.append(res.latency_s)
             self.queries_served += res.rows
-        self._note_latency(res)
+        self._judge(res)
         if self.policy is not None and self.policy.nan_sentinel:
             try:
                 self._check_sentinel(res)
@@ -1597,11 +1675,6 @@ class ServeSession:
                     help="query rows served per tenant (padding excluded)",
                     labels={"tenant": t},
                 ).inc(n)
-                self._metrics.counter(
-                    "serve_tenant_batches_total",
-                    help="batches carrying at least one row of this tenant",
-                    labels={"tenant": t},
-                ).inc()
         extra = {}
         if res.stats_padded is not None:
             # the candidate-exchange story, stamped at retire (the batch
