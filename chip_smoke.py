@@ -18,6 +18,10 @@ Phases, each printing one line with its wall time:
                lengths 0.5-2x their own: a resident index's answers equal
                the one-shot call's bit for bit, and the direct form's in
                float64 on the host.
+- ``fused_screen`` the screen inside the kernel (ISSUE 51): the same rows
+               under L2 — the kernel's lists against float64 under its own
+               eps, the answer against the six-pass program's, the steps'
+               counter, a planted crowd flagged and re-scanned.
 - ``screen``   the certified screen (ISSUE 47) on this device's own
                three-pass dot: a full 1024-row tile of fractional rows on
                the lane grid answers as the six-pass program answers, a
@@ -483,6 +487,75 @@ def compute_phases(args, platform, out, record) -> None:
         f"dist_abs_diff_vs_six_pass_max={dist_diff:.2e} "
         f"ids_equal_six_pass_rows_1_on={ids_same:.5f} "
         f"screen_tile_abs_err_max={screen_err:.2e} under eps={eps.min():.2e}",
+    )
+
+    # -- fused_screen ------------------------------------------------------
+    # the screen INSIDE the kernel (ISSUE 51), on THIS device's MXU: the
+    # same fractional rows under L2 (d = 256: the query tile in two
+    # blocks). The kernel's lists against float64 at the slots they name
+    # — three passes' error, under the kernel's own eps and far under one
+    # pass's —, the program's answer against the six-pass program's, its
+    # steps under ``dist_steps``' seventh column, and a crowd on a sphere
+    # around query 0 flagged and re-scanned
+    t0 = time.perf_counter()
+    from mpi_knn_tpu.ops.distance import sq_norms
+    from mpi_knn_tpu.ops.fused_scan import fused_scan
+    from mpi_knn_tpu.ops.topk import lane_bin_depth
+
+    FC = SC.copy()
+    ball = srng.standard_normal((40, s_dim))
+    FC[crowd] = SQ[0] + 0.05 * ball / np.linalg.norm(
+        ball, axis=1, keepdims=True)
+    fcfg = scfg.replace(metric="l2", center=False)
+    c_tile = fcfg.corpus_tile
+    block = serial.fused_screen_rule(fcfg, 1024, c_tile, s_dim)
+    FCd = jax.device_put(jnp.asarray(FC))
+    f_screened = all_knn(FCd, queries=SQ, config=fcfg)
+    with unittest.mock.patch.object(serial, "screen_rule",
+                                    lambda *a, **k: None):
+        f_sixpass = all_knn(FCd, queries=SQ,
+                            config=fcfg.replace(recall_target=0.96))
+    f_steps = np.atleast_2d(f_screened.dist_steps).sum(0).tolist()
+    f_rows = (None if f_screened.screen_rows is None
+              else np.asarray(f_screened.screen_rows).tolist())
+    f_diff = float(np.abs(np.asarray(f_screened.dists)
+                          - np.asarray(f_sixpass.dists)).max())
+    f_same = float((np.asarray(f_screened.ids)
+                    == np.asarray(f_sixpass.ids))[1:].mean())
+    stack = FCd.reshape(-1, c_tile, s_dim)
+    n_t = stack.shape[0]
+    q_sq, sqs = sq_norms(jnp.asarray(SQ)), serial.stack_norms(stack, "l2")
+    kd, ki, _ = fused_scan(
+        jnp.asarray(SQ), jnp.full((1024,), -1, jnp.int32), q_sq, stack,
+        jnp.arange(s_rows, dtype=jnp.int32).reshape(n_t, c_tile), sqs,
+        serial.bound_refreshes(n_t), k=wide,
+        depth=lane_bin_depth(1024, c_tile, wide), exclude_self=False,
+        exclude_zero=False, zero_eps=0.0, block=block or 1024, screen=True)
+    kd, ki = np.asarray(kd)[:, :128], np.asarray(ki)[:, :128]
+    held = np.isfinite(kd)
+    real = ((SQ.astype(np.float64)[:, None, :]
+             - FC.astype(np.float64)[np.maximum(ki, 0)]) ** 2).sum(-1)
+    k_err = np.abs(kd - real)[held]
+    f_eps = np.asarray(serial.screen_eps(
+        "l2", s_dim, jnp.asarray(SQ), q_sq, jnp.max(sqs), fused=True))
+    one_pass = 2.0 ** -8 * 2 * float(np.sqrt(q_sq.max() * sqs.max()))
+    record(
+        "fused_screen",
+        block is not None and f_steps == [0] * 6 + [n_t]
+        and f_rows is not None and sum(f_rows) == 1024 and f_rows[1] >= 1
+        and f_rows[0] >= 900
+        and np.asarray(f_screened.select_tiles).tolist() == [0, 1]
+        and f_diff <= 1e-5 and f_same >= 0.999
+        and held.sum() >= 32 * 1024
+        and float(k_err.max()) <= float(f_eps.min())
+        and float(k_err.max()) < 0.05 * one_pass,
+        t0,
+        f"corpus={list(FC.shape)} block={block} dist_steps={f_steps} "
+        f"screen_rows_certified_flagged={f_rows} "
+        f"dist_abs_diff_vs_six_pass_max={f_diff:.2e} "
+        f"ids_equal_six_pass_rows_1_on={f_same:.5f} "
+        f"kernel_list_abs_err_vs_float64_max={k_err.max():.2e} "
+        f"under eps={f_eps.min():.2e} (one pass's: {one_pass:.2e})",
     )
 
     # -- rescan ------------------------------------------------------------

@@ -138,18 +138,22 @@ _PER_ELEMENT_CALLERS = frozenset(
 )
 
 
+_KNOWN_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+
+
 def while_trip_count(module: HloModule, instr) -> int | None:
     """The static trip count of one ``while``: the integer constant its
     condition compares the induction variable against with ``LT`` —
     counted loops lowered from ``lax`` scans/maps/fori all print this
-    form. ``None`` when the bound is not statically visible."""
+    form. Where the condition does not show it (an XLA:CPU build that
+    wraps the compare in a fusion of its own, ``wrapped_compare``: met on
+    this sandbox's host in PR 51, where every lint cell lost its count),
+    the compiler's own annotation on the loop,
+    ``backend_config={"known_trip_count":{"n":...}}``. ``None`` when the
+    bound is not statically visible in either."""
     mc = _WHILE_COND_RE.search(instr.attrs)
-    if not mc:
-        return None
-    cond = module.computations.get(mc.group(1))
-    if cond is None:
-        return None
-    for ci in cond.instructions.values():
+    cond = module.computations.get(mc.group(1)) if mc else None
+    for ci in (cond.instructions.values() if cond is not None else ()):
         if ci.opcode != "compare" or "direction=LT" not in ci.attrs:
             continue
         for op in ci.operands:
@@ -158,7 +162,8 @@ def while_trip_count(module: HloModule, instr) -> int | None:
                 m = _INT_CONST_RE.match(src.operand_text)
                 if m:
                     return int(m.group(1))
-    return None
+    known = _KNOWN_TRIP_RE.search(instr.attrs)
+    return int(known.group(1)) if known else None
 
 
 def computation_multiplicities(module: HloModule) -> dict:

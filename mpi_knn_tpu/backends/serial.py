@@ -115,17 +115,21 @@ def onepass_rule(cfg: KNNConfig, q_rows: int, filtered: bool = False) -> bool:
 # where a program without the one-pass branch counts its tile steps, by
 # metric: the column of ``obs.metrics.DIST_PATHS``
 _STATIC_PATH = {"l2": 1, "cosine": 2, "ip": 4}
+# and where one whose screened steps run inside the kernel does
+_FUSED_SCREEN_PATH = 6
 
 
 def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False,
-               u8: bool = False):
+               u8: bool = False, fused_screen: bool = False):
     """A dispatch's tile steps by the path of their distance dot, int32
     ``[one-pass, multi-pass]`` — from a cosine program ``[0, 0, cosine]``,
     from an inner-product program ``[0, 0, 0, 0, ip]``, from one whose
     one-pass steps run inside the fused kernel
     (:func:`fused_rule`) ``[0, multi-pass, 0, fused]``, from one whose
     one-pass steps walk a byte stack (kernel or tile steps)
-    ``[0, multi-pass, 0, 0, 0, u8]``: what
+    ``[0, multi-pass, 0, 0, 0, u8]``, from one with no branch whose
+    screened steps run inside the fused kernel's three-pass form
+    (:func:`fused_screen_rule`) ``[0, 0, 0, 0, 0, 0, fused_screen]``: what
     ``KNNResult.dist_steps`` and the counter ``knn_dist_tile_steps_total``
     hold. ``took`` is one verdict a query-tile merge (a bool vector, made
     inside a program that carries the branch) or, for a program without
@@ -133,7 +137,9 @@ def dist_steps(took, steps: int, metric: str = "l2", fused: bool = False,
     if isinstance(took, int):
         if metric not in _STATIC_PATH:
             raise ValueError(f"unknown metric {metric!r}")
-        counts = np.zeros(_STATIC_PATH[metric] + 1, dtype=np.int32)
+        counts = np.zeros(
+            _FUSED_SCREEN_PATH + 1 if fused_screen
+            else _STATIC_PATH[metric] + 1, dtype=np.int32)
         counts[-1] = took * steps
         return counts
     one = jnp.sum(took, dtype=jnp.int32)
@@ -266,6 +272,25 @@ _FINISH_SPLIT = 2.0 ** -18
 # off by at most (d + 4) 2^-23 sum |pieces' products| <= (d + 4) 2^-23
 # (1 + 2^-5) |x| |y|. The same bound holds for a float32 multiply-and-add
 # chain of d terms (each product rounded once, d - 1 additions).
+#
+# The screen INSIDE the kernel (``ops/fused_scan.py``, its three-pass form;
+# :func:`fused_screen_rule`) is another program, and its term is its own.
+# The pieces: x1 is CUT from the float32's bits (|x - x1| < 2^-7 |x|), x - x1
+# is then exact in float32 and x2 is it rounded to nearest (|x - x1 - x2| <=
+# 2^-8 |x - x1| < 2^-15 |x|): inside the 2^-7 and 2^-14 that c_P assumes, so
+# :data:`_SCREEN_SPLIT` holds as it stands. The query side is -2 x: a power
+# of two scales both pieces exactly and the dot is -2 times the dot of x,
+# which the L2 form's ``2 K |q| R`` already says. The sum: ONE dot of K =
+# 3 d, the pieces side by side — its 3 d products (exact in float32, as
+# above) are added in float32 in whatever order the MXU's accumulator takes
+# them; in ANY summation tree of n terms a term passes through at most
+# n - 1 additions, so the dot is off by at most (3 d - 1) 2^-23 sum
+# |pieces' products|. (Three d-long sums and two additions, the form XLA
+# gives, put a product through d + 1: under the same bound.) Then the
+# kernel adds ``x_sq`` and ``y_sq`` in ``pairwise_sq_l2``'s order, two
+# additions and the clamp, which :data:`_ELEMENTWISE` counts as it counts
+# the XLA screen's; the bound's test on the unclamped sum only MARKS
+# chunks (a superset: a bound is never negative) and no value comes of it.
 _ACC_UNIT = 2.0 ** -23
 # the roundings outside the dot (scaling by the inverse norm, 1 - sim, the
 # two additions of the L2 form) in the screen and in the finish, and the
@@ -339,8 +364,41 @@ def screen_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int, *,
     return wide
 
 
+def fused_screen_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
+                      **facts) -> int | None:
+    """Whether the SCREENED scan of an engaged merge is one call of the
+    kernel that walks the whole stack, in its three-pass form
+    (``ops/fused_scan.py``) — the height of the row blocks it walks the
+    query tile in, or None: the XLA scan of three-pass tile steps, or no
+    screen at all. By what the program is, no setting: :func:`screen_rule`
+    engages (``facts``: its ``branch`` / ``filtered`` / ``varying``), the
+    metric is L2 (the kernel's value is ``x_sq - 2 xy + y_sq``; cosine
+    scales its dot by a plane and an inner product has no norm to test
+    against) and the shapes pass ``ops/topk.py fused_scan_engages`` at the
+    screen's depth with the three-pass form's own VMEM (1024 rows x 8192
+    columns at d = 128: the streaming cell's bucket; d = 1536 passes at no
+    height)."""
+    wide = screen_rule(cfg, q_rows, c_tile, dim, **facts)
+    if wide is None or cfg.metric != "l2":
+        return None
+    return fused_scan_engages(
+        q_rows, c_tile, dim, lane_bin_depth(q_rows, c_tile, wide), 4,
+        passes=3)
+
+
+def screen_additions(dim: int, fused: bool = False) -> int:
+    """The float32 additions a product passes through in the screen's dot
+    and in the finish's, together (the certificate compares a value of the
+    one with a value of the other), each worth :data:`_ACC_UNIT`: the
+    six-pass finish ``dim + 4``; the XLA screen (three one-pass dots and
+    two additions) ``dim + 4``; the kernel's (``fused``: one dot of K =
+    3 dim in an order that is the hardware's) ``3 dim - 1``. Derived above
+    :data:`_ACC_UNIT`; a constant of the program's form."""
+    return (3 * dim - 1 if fused else dim + 4) + dim + 4
+
+
 def screen_eps(metric: str, dim: int, q_x: jax.Array,
-               q_sq: jax.Array | None, r_sq) -> jax.Array:
+               q_sq: jax.Array | None, r_sq, fused: bool = False) -> jax.Array:
     """(q,) float32: for every query row a WORST-CASE bound on |value the
     three-pass screen ranked a corpus row by - value the six-pass finish
     would give that row|, for ANY row of the stack. The certificate of a
@@ -355,13 +413,16 @@ def screen_eps(metric: str, dim: int, q_x: jax.Array,
     float32 operands (the query row, the corpus row, the norm planes'
     entries, which both dots read):
 
-    - ``K = (c_P + c_6 + 2 (d + 4) 2^-23) (1 + 2^-4)``: the products the
+    - ``K = (c_P + c_6 + A 2^-23) (1 + 2^-4)``: the products the
       three-pass split drops (:data:`_SCREEN_SPLIT`, 2^-12), those the
       six-pass split drops (:data:`_FINISH_SPLIT`, 2^-18), the float32
-      accumulation of BOTH dots (:data:`_ACC_UNIT`) — the certificate
-      compares a value of the one with a value of the other — and the
-      second-order terms (:data:`_SCREEN_SLACK`); each derived where it is
-      defined, above. At d = 1536: 6.5e-4.
+      accumulation of BOTH dots (:data:`_ACC_UNIT`,
+      :func:`screen_additions`: ``A = 2 (d + 4)`` for the XLA screen,
+      ``4 d + 3`` where the screen ran inside the kernel, ``fused``) — the
+      certificate compares a value of the one with a value of the other —
+      and the second-order terms (:data:`_SCREEN_SLACK`); each derived
+      where it is defined, above. At d = 1536: 6.5e-4; at d = 128, 3.0e-4
+      and in the kernel 3.3e-4.
     - ``|q| R'``, the size of the dot: cosine ``|q|`` (the query's unit
       row; a corpus row's norm times its stored inverse norm is at most 1,
       the scaling cancels), inner product ``|q| R``, L2 ``2 |q| R`` (the
@@ -380,7 +441,7 @@ def screen_eps(metric: str, dim: int, q_x: jax.Array,
         raise ValueError(f"the screen's bound assumes dim <= 2^15: {dim}")
     acc = jnp.float32
     K = (_SCREEN_SPLIT + _FINISH_SPLIT
-         + 2 * (dim + 4) * _ACC_UNIT) * _SCREEN_SLACK
+         + screen_additions(dim, fused) * _ACC_UNIT) * _SCREEN_SLACK
     q_norm = jnp.sqrt(sq_norms(q_x) if q_sq is None else q_sq).astype(acc)
     if metric == "cosine":
         return K * q_norm + _ELEMENTWISE * (q_norm > 0)
@@ -845,18 +906,28 @@ def serve_chunk(
         per_query_tile,
         (q_tiles, qid_tiles, carry_d, carry_i) + (
             () if filt is None else (filt[0],)))
-    counts = TileCounts(
-        None if took is None else dist_steps(
+    varying = bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma)
+    if took is not None:
+        steps = dist_steps(
             took, tiles.shape[0], fused=filt is None and bool(fused_rule(
-                cfg, q_tiles.shape[1], *tiles.shape[1:],
-                bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma))),
-            u8=tiles.dtype == jnp.uint8),
+                cfg, q_tiles.shape[1], *tiles.shape[1:], varying)),
+            u8=tiles.dtype == jnp.uint8)
+    elif fused_screen_rule(cfg, q_tiles.shape[1], *tiles.shape[1:],
+                           filtered=filt is not None, varying=varying):
+        # the screened steps that ran inside the kernel: their own column,
+        # a static count (the program holds no other path)
+        steps = dist_steps(q_tiles.shape[0], tiles.shape[0], cfg.metric,
+                           fused_screen=True)
+    else:
+        steps = None
+    counts = TileCounts(
+        steps,
         None if rescanned is None else select_tiles(rescanned),
         None if chunks is None else jnp.sum(chunks, axis=0, dtype=jnp.int32),
         screen_rows=None if screened is None else jnp.sum(
             screened, axis=0, dtype=jnp.int32),
     )
-    if counts == TileCounts():
+    if all(c is None for c in counts):
         return best_d, best_i
     return best_d, best_i, counts
 
@@ -1005,14 +1076,17 @@ def merge_tiles_into_carry(
             if onepass is not None and words is None:
                 block = fused_rule(
                     cfg, carry_d.shape[0], *tiles.shape[1:], varying)
+            facts = dict(branch=onepass is not None,
+                         filtered=words is not None, varying=varying)
+            screen = screen_rule(
+                cfg, carry_d.shape[0], *tiles.shape[1:], **facts)
+            if screen is not None:
+                block = fused_screen_rule(
+                    cfg, carry_d.shape[0], *tiles.shape[1:], **facts)
             return _merge_carried(
                 q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 either, onepass if block else None, block,
-                nested=onepass is not None, offset=offset,
-                screen=screen_rule(
-                    cfg, carry_d.shape[0], *tiles.shape[1:],
-                    branch=onepass is not None, filtered=words is not None,
-                    varying=varying))
+                nested=onepass is not None, offset=offset, screen=screen)
 
         def local(_, tile):
             # per-tile reduction honors cfg.precision_policy (exact single
@@ -1117,7 +1191,12 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     (:func:`screen_eps`) joins the lanes', and a row flagged by either is
     answered again by the same re-scan, whose distance tile is the
     configured precision's. A fifth output counts the rows by the
-    screen's verdict.
+    screen's verdict. With a ``block`` height (:func:`fused_screen_rule`;
+    ``fused`` is None: such a program carries no one-pass branch) the
+    screened scan is ONE call of the kernel in its three-pass form, no
+    conditional around it: the same lists within the kernel's own error
+    (:func:`screen_eps` with ``fused``), slots made in the kernel, and
+    everything after the scan as it is.
 
     ``offset``: a byte stack's (:func:`merge_tiles_into_carry`); every
     distance tile is made from the widened tile, and the kernel widens its
@@ -1140,11 +1219,13 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     # (an iota: the finish gathers by it, whatever ids a mutable index
     # keeps there)
     wide, walked, ids_at = k, stack, 1
+    in_kernel = screen is not None and block is not None
     if screen is not None:
         wide, ids_at = screen, n_stack
         depth = lane_bin_depth(q_rows, c_tile, wide)
-        walked = (*stack, jnp.arange(
-            n_tiles * c_tile, dtype=jnp.int32).reshape(n_tiles, c_tile))
+        if not in_kernel:
+            walked = (*stack, jnp.arange(
+                n_tiles * c_tile, dtype=jnp.int32).reshape(n_tiles, c_tile))
     n_walked = len(walked)
 
     def dist_tile(*tile_one, scoped=True, screened=screen is not None):
@@ -1205,7 +1286,11 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
         return (*out, inserted)
 
     rides = lane_bin_bound_rides(q_rows, c_tile, carry_d.dtype.itemsize)
-    if fused is not None:
+    if in_kernel:
+        *lists, inserted = _fused_scan(
+            q_x, q_ids, q_sq, *stack, cfg=cfg, depth=depth, block=block,
+            screen=wide)
+    elif fused is not None:
         multipass = lambda step, *o: step(*o, False)  # noqa: E731
         *lists, inserted = jax.lax.cond(
             fused,
@@ -1225,7 +1310,7 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
     screened = None
     if screen is not None:
         vals, ids, certified = _finish_screened(
-            q_x, q_ids, q_sq, stack, ids, vals[:, -1], cfg)
+            q_x, q_ids, q_sq, stack, ids, vals[:, -1], cfg, in_kernel)
         flagged = flagged | ~certified
         passed = jnp.sum(certified, dtype=jnp.int32)
         screened = jnp.stack([passed, q_rows - passed])
@@ -1244,10 +1329,12 @@ def _merge_carried(q_x, q_ids, q_sq, stack, carry_d, carry_i, cfg, depth,
                 screened)
 
 
-def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg):
+def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg, fused=False):
     """What follows a screened scan (:func:`_merge_carried`): ``slots`` (q,
     k') are each row's candidates, slot numbers of the stack viewed flat
-    (-1: the row had fewer), ``s`` (q,) its k'-th smallest SCREEN value.
+    (-1: the row had fewer), ``s`` (q,) its k'-th smallest SCREEN value,
+    ``fused`` whether the kernel made them (:func:`fused_screen_rule`: the
+    certificate then holds the kernel's own error).
     Returns ``((q, k) dists, (q, k) ids, (q,) certified)``.
 
     Scope ``knn.rerank``: the candidates' rows are read where the stack
@@ -1274,18 +1361,21 @@ def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg):
         zero_eps=cfg.zero_eps)
     with jax.named_scope("knn.select"), jax.named_scope("screen"):
         eps = screen_eps(cfg.metric, dim, q_x, q_sq,
-                         largest_norm_sq(cfg.metric, tiles, tile_sqs))
+                         largest_norm_sq(cfg.metric, tiles, tile_sqs), fused)
         certified = (vals[:, -1] + eps <= s) | (s == jnp.inf)
     return vals, ids, certified
 
 
 def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
-                block, offset=None):
+                block, offset=None, screen=None):
     """``ops/fused_scan.py fused_scan`` for ``cfg``: the engaged scan's
     one-pass branch over the whole stack as one kernel, the query tile in
     blocks of ``block`` rows, ``(lists_d, lists_i, chunks inserted)``,
     under the scope ``knn.fused``. ``offset``: a uint8 stack's, whose
-    scope the kernel then sits in (``knn.scan_u8/knn.fused``)."""
+    scope the kernel then sits in (``knn.scan_u8/knn.fused``). ``screen``
+    (k'): the screened scan in the kernel's three-pass form — k' where
+    the one-pass form says k, slots for ids, and no zero test by value
+    (that waits for the finish's exact values, :func:`masked_dist_tile`)."""
     from mpi_knn_tpu.ops.fused_scan import fused_scan
 
     with contextlib.ExitStack() as scopes:
@@ -1294,9 +1384,11 @@ def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
         scopes.enter_context(jax.named_scope(FUSED_SCOPE))
         return fused_scan(
             q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs,
-            bound_refreshes(tiles.shape[0]), k=cfg.k, depth=depth,
-            exclude_self=cfg.exclude_self, exclude_zero=cfg.exclude_zero,
-            zero_eps=cfg.zero_eps, block=block, offset=offset)
+            bound_refreshes(tiles.shape[0]), k=screen or cfg.k, depth=depth,
+            exclude_self=cfg.exclude_self,
+            exclude_zero=cfg.exclude_zero and screen is None,
+            zero_eps=cfg.zero_eps, block=block, offset=offset,
+            screen=screen is not None)
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg

@@ -56,7 +56,8 @@ COMPILE_BUCKETS_S = (0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 # tile steps by the distance dot's path (MetricsRegistry.count_dist_steps)
 DIST_STEPS = "knn_dist_tile_steps_total"
 # a count's columns
-DIST_PATHS = ("onepass", "multipass", "cosine", "fused", "ip", "u8")
+DIST_PATHS = ("onepass", "multipass", "cosine", "fused", "ip", "u8",
+              "fused_screen")
 # query-tile merges by what became of the selection their scans carried
 # (MetricsRegistry.count_select_tiles)
 SELECT_TILES = "knn_select_query_tiles_total"
@@ -370,13 +371,16 @@ class MetricsRegistry:
 
     def count_dist_steps(self, steps) -> None:
         """Add a dispatch's tile steps to ``knn_dist_tile_steps_total
-        {path="onepass"|"multipass"|"cosine"|"fused"|"ip"}``. ``steps`` is what
+        {path="onepass"|"multipass"|"cosine"|"fused"|"ip"|"u8"|"fused_screen"}``.
+        ``steps`` is what
         the tile programs report with their answer (``KNNResult.dist_steps``,
         ``BatchResult.dist_steps``): ints ``[one-pass, multi-pass]`` — from
         a cosine program ``[0, 0, cosine]``, a static count; from a program
         whose one-pass steps run inside the kernel that walks the stack
         ``[0, multi-pass, 0, fused]``, from an inner-product program
-        ``[0, 0, 0, 0, ip]`` — one row a device. The device decides the L2 path, so call this where the
+        ``[0, 0, 0, 0, ip]``, from one whose screened steps run inside that
+        kernel's three-pass form ``[0, 0, 0, 0, 0, 0, fused_screen]`` — one
+        row a device. The device decides the L2 path, so call this where the
         answer has been fetched (a server's retire, a job's end): reading
         a count that is not ready waits for its program."""
         self._count_columns(
@@ -388,7 +392,10 @@ class MetricsRegistry:
             "bf16 pass inside the kernel that walks the whole stack, or the "
             "inner-product dot (the dot alone at the configured precision, "
             "negated), or the one bf16 pass over a byte stack's widened "
-            "tiles (dtype=uint8: kernel or tile steps)",
+            "tiles (dtype=uint8: kernel or tile steps), or the screen's "
+            "three bf16 passes inside that kernel (fractional float32 rows "
+            "on the lane grid under L2; the XLA screen's steps count under "
+            "multipass)",
         )
 
     def count_select_tiles(self, tiles) -> None:

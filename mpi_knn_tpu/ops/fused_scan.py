@@ -45,6 +45,16 @@ taken anew at ``bound_refreshes``' tiles from the lists' lane minima as
 two equal minima is knocked out first does not change the value); chunks
 tested and inserted in ascending column order a row, so ties keep the
 earlier column and the earlier tile ahead.
+
+A third form, by the program (``backends/serial.py fused_screen_rule``):
+the SCREENED scan of float32 rows that are no bf16 numbers, on the lane
+grid under L2. The dot is ``high``'s three bf16 products as ONE
+``dot_general`` of K = 3 d — the query side's two pieces made once a batch,
+a tile's a piece at a time in the kernel, laid side by side along K so that
+the MXU's accumulator takes the sum —, everything that says k says the
+screen's k', and the lists keep SLOTS. Its values are within
+``backends/serial.py screen_eps(..., fused=True)`` of the six-pass ones;
+none of them is returned (``_finish_screened``).
 """
 
 from __future__ import annotations
@@ -91,7 +101,8 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                        ysq_ref, *refs, k: int,
                        depth: int, groups: int, exclude_self: bool,
                        exclude_zero: bool, zero_eps: float, blocked: bool,
-                       rows_minor: bool, widened: bool = False):
+                       rows_minor: bool, widened: bool = False,
+                       passes: int = 1, slots: bool = False):
     """A grid step of :func:`fused_scan`: tile t against a block of query
     rows — the whole query tile on the grid (tiles,); one of its row
     blocks where ``blocked``, the grid's leading axis, the block's lists,
@@ -125,7 +136,11 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     mu_ref = refs[0] if widened else None
     (kd_out, ki_out, n_ref, kd_ref, ki_ref, b_ref, d_ref, bits_ref,
      idrow_ref, ycol_ref, work_ref, hit_ref, word_ref, cnt_ref,
-     sem) = refs[1:] if widened else refs
+     sem, *more) = refs[1:] if widened else refs
+    # the three-pass form's scratch: a piece's bf16 pieces side by side;
+    # where the lists keep slots, the tile's slot numbers
+    cat_ref = more.pop(0) if passes == 3 else None
+    slot_ref = more.pop(0) if slots else None
     q, c_tile = d_ref.shape
     strips = q // _STRIP
     piece = groups * _LANES
@@ -211,6 +226,12 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         ycol_ref[...] = lax.select(
             lax.lt(idrow_ref[...], lax.full(idrow_ref.shape, 0, i32)),
             lax.full(ycol_ref.shape, _INF, f32), ysq_ref[row, :])
+        if slots:
+            # what the lists keep of a column: its place in the stack
+            # viewed flat, from the grid's own index
+            slot_ref[...] = lax.add(
+                lax.broadcasted_iota(i32, slot_ref.shape, 1),
+                lax.broadcast(lax.mul(t, i32(c_tile)), slot_ref.shape))
 
         bits_ref[...] = lax.full(bits_ref.shape, 0, i32)
 
@@ -227,9 +248,28 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                 lax.convert_element_type(
                     lax.convert_element_type(rows, i32), f32),
                 lax.broadcast_in_dim(mu_ref[...], rows.shape, (0, 1)))
+        if passes == 3:
+            # float32 rows that are no bf16 numbers: the piece's two bf16
+            # pieces, the first CUT from the bits (the upper half of a
+            # float32 IS a bfloat16: no conversion for a compiler to see
+            # through), the second what the cut left, rounded; laid side
+            # by side along K as (hi, lo, hi) against the query side's
+            # (hi, hi, lo), so ONE dot of K = 3 d is hi.hi + hi.lo + lo.hi
+            # — ``high``'s three products, summed in the MXU's accumulator
+            width = rows.shape[1]
+            hi = lax.bitcast_convert_type(lax.bitwise_and(
+                lax.bitcast_convert_type(rows, i32),
+                lax.full(rows.shape, -65536, i32)), f32)
+            cat_ref[:, :width] = lax.convert_element_type(hi, jnp.bfloat16)
+            cat_ref[:, width:2 * width] = lax.convert_element_type(
+                lax.sub(rows, hi), jnp.bfloat16)
+            cat_ref[:, 2 * width:] = cat_ref[:, :width]
+            narrow = cat_ref[...]
+        else:
+            narrow = lax.convert_element_type(rows, jnp.bfloat16)
         m = lax.dot_general(
             qn,
-            lax.convert_element_type(rows, jnp.bfloat16),
+            narrow,
             dimension_numbers=(((1,), (0 if rows_minor else 1,)), ((), ())),
             preferred_element_type=f32,
             precision=lax.Precision.DEFAULT,
@@ -329,8 +369,8 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         _pack_hits(
             lambda r: bits_ref[r, :], hit_ref, word_ref, strips, n_chunks)
         cnt_ref[0] = lax.add(cnt_ref[0], _insert_hit_chunks(
-            idrow_ref, d_ref, kd_ref, ki_ref, hit_ref, strips, n_chunks,
-            groups, depth))
+            slot_ref if slots else idrow_ref, d_ref, kd_ref, ki_ref, hit_ref,
+            strips, n_chunks, groups, depth))
 
         @pl.when(last_tile() if leaves is None else leaves)
         def _():
@@ -348,6 +388,18 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                 copy.wait()
 
 
+def _bf16_cut(x: jax.Array) -> jax.Array:
+    """A float32 array CUT to bfloat16 numbers, as float32: the upper half
+    of the bits, by a mask. The first piece of the three-pass split; what
+    the cut leaves, ``x - cut``, is exact in float32. (A ``float32 ->
+    bfloat16 -> float32`` round trip is not used: the TPU compiler keeps
+    such a trip in float32 inside a fusion, and the second piece would be
+    zero.)"""
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000),
+        jnp.float32)
+
+
 def rests_rows_minor(d: int) -> bool:
     """Whether a (T, c_tile, d) float32 stack the rule admits
     (``ops/topk.py fused_scan_engages``: d a multiple of 8, c_tile of 128)
@@ -363,7 +415,7 @@ def rests_rows_minor(d: int) -> bool:
 
 
 def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
-                          itemsize: int = 4) -> int:
+                          itemsize: int = 4, passes: int = 1) -> int:
     """The VMEM :func:`fused_scan` holds for a block of (q, d) query rows
     against (c_tile, d) corpus tiles of ``itemsize`` bytes an element
     (float32, or a byte stack's 1), in bytes: the lists, the
@@ -373,18 +425,24 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
     widened to float32 ahead of it, and the offset's row in its two
     buffers —, the query side in its two buffers, the
     planes' rows. What the engage rule weighs and ``vmem_limit_bytes`` is
-    set from."""
+    set from. ``passes`` 3 (the three-pass form, whose lists keep slots):
+    a piece's copy is its bf16 pieces side by side, three widths, with
+    the float32 piece they are cut from and what the cut left; the query
+    side is three widths too; the tile's slot numbers are one row more."""
     piece = chunk_groups(c_tile) * _LANES
     lists = 2 * q * depth * _LANES * 4
     words = _hit_words(q // _STRIP, c_tile // piece) * (_STRIP // 2)
     bound_and_bits = (2 * q + words) * _LANES * 4
     dists = q * c_tile * 4
     fetched = piece if rests_rows_minor(d) else c_tile
-    stack = 2 * fetched * d * itemsize + piece * d * 2
+    stack = 2 * fetched * d * itemsize + piece * d * 2 * passes
     if itemsize == 1:
         stack += piece * d * 4 + 2 * _PLANE_ROWS * d * 4
-    query = 2 * (q * d * 2 + 2 * q * _LANES * 4)
+    query = 2 * (q * d * 2 * passes + 2 * q * _LANES * 4)
     planes = 2 * 2 * _PLANE_ROWS * c_tile * 4 + 2 * c_tile * 4
+    if passes == 3:
+        stack += 2 * piece * d * 4
+        planes += c_tile * 4
     work = _row_block(q, _FINISH_ROWS) * _LANES * 4
     return lists + bound_and_bits + dists + stack + query + planes + work
 
@@ -393,7 +451,7 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                tiles: jax.Array, tile_ids: jax.Array, tile_sqs: jax.Array,
                due, *, k: int, depth: int, exclude_self: bool,
                exclude_zero: bool, zero_eps: float, block: int,
-               offset: jax.Array | None = None):
+               offset: jax.Array | None = None, screen: bool = False):
     """The carried scan of ``backends/serial.py _merge_carried`` over a
     whole stack, in its one-pass branch and under the row bound, as one
     kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
@@ -427,11 +485,25 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     row-major under (32, 128) tiles): a tile is fetched as bytes, a quarter
     of the float32 tile's, and a piece is widened and centred in VMEM ahead
     of its narrowing to bf16 — the values a float32 stack of the same rows
-    holds, so the same lists. ``tile_sqs`` are the CENTRED rows' norms."""
+    holds, so the same lists. ``tile_sqs`` are the CENTRED rows' norms.
+
+    ``screen`` (``backends/serial.py fused_screen_rule``): the SCREENED
+    scan of that merge — float32 rows of any value on the lane grid,
+    ranked by ``high``'s three bf16 products (the query side's two pieces
+    made here, once; a tile's a piece at a time in the kernel), ``k`` the
+    screen's k' and ``depth`` its lists'; the lists keep a column's SLOT in
+    the stack viewed flat (``t * c_tile + column``), not its id, which is
+    read for its sign (and ``exclude_self``) alone. Every value is within
+    ``backends/serial.py screen_eps(..., fused=True)`` of the six-pass
+    one."""
     q, d = q_x.shape
     widened = tiles.dtype.itemsize == 1
     if widened != (offset is not None):
         raise ValueError("a byte stack comes with its offset, and no other")
+    if screen and (widened or rests_rows_minor(d)):
+        raise ValueError("the three-pass form takes float32 rows on the "
+                         "lane grid")
+    passes = 3 if screen else 1
     n_tiles, c_tile, _ = tiles.shape
     groups = chunk_groups(c_tile)
     piece = groups * _LANES
@@ -441,7 +513,12 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     operands = (q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs)
     # -2 x: a power of two scales a bf16 number exactly, so the dot returns
     # -2 xy to the bit and ``x_sq - 2 xy`` is one add
-    qn = (q_x * -2.0).astype(jnp.bfloat16)
+    qn = q_x * -2.0
+    if screen:
+        # (hi, hi, lo) along K: the pieces the kernel's (hi, lo, hi) meet
+        hi = _bf16_cut(qn.astype(f32))
+        qn = jnp.concatenate([hi, hi, qn - hi], axis=1)
+    qn = qn.astype(jnp.bfloat16)
     xsq = jnp.broadcast_to(q_sq.astype(f32)[:, None], (q, _LANES))
     qid = jnp.broadcast_to(q_ids.astype(jnp.int32)[:, None], (q, _LANES))
     blocked, rows_minor = block != q, rests_rows_minor(d)
@@ -476,12 +553,12 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
             _fused_scan_kernel, k=k, depth=depth, groups=groups,
             exclude_self=exclude_self, exclude_zero=exclude_zero,
             zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor,
-            widened=widened),
+            widened=widened, passes=passes, slots=screen),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((block, d), rows),
+                pl.BlockSpec((block, passes * d), rows),
                 pl.BlockSpec((block, _LANES), rows),
                 pl.BlockSpec((block, _LANES), rows),
                 tile, plane, plane,
@@ -506,6 +583,8 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 *_hit_scratch(strips, c_tile // piece),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,)),
+                *([pltpu.VMEM((piece, passes * d), jnp.bfloat16),
+                   pltpu.VMEM((1, c_tile), jnp.int32)] if screen else []),
             ],
         ),
         out_shape=[
@@ -518,7 +597,7 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
             # the arithmetic above, and room for what Mosaic keeps of its
             # own (a piece's dot as a value, spills)
             vmem_limit_bytes=fused_scan_vmem_bytes(
-                block, c_tile, d, depth, tiles.dtype.itemsize)
+                block, c_tile, d, depth, tiles.dtype.itemsize, passes)
             + _VMEM_HEADROOM,
         ),
         interpret=_interpret(),

@@ -212,7 +212,7 @@ _FUSED_VMEM_BYTES = 64 << 20
 
 
 def fused_scan_engages(q: int, c: int, d: int, depth: int,
-                       itemsize: int = 4) -> int | None:
+                       itemsize: int = 4, passes: int = 1) -> int | None:
     """Whether the one-pass branch of a scan that carries the lane-bin
     lists over (q, c) tiles of float32 (or byte) rows ``d`` wide runs as ONE kernel
     over the whole stack (``ops/fused_scan.py``), by the shapes alone: the
@@ -263,9 +263,18 @@ def fused_scan_engages(q: int, c: int, d: int, depth: int,
       the float32 stack, a form this kernel's widening has not been read
       in: the scan of tile steps takes those. A tile's two buffers are
       2 x 1.05 MB, not 2 x 4.2 MB; the distances are float32 either way.
+    - *the three-pass form* (``passes`` 3: the screened scan of float32
+      rows that are no bf16 numbers, ``backends/serial.py
+      fused_screen_rule``): float32 rows on the 128-lane grid alone — a
+      candidate's row is gathered from the stack where it rests row-major
+      — with its own VMEM: the lists at the screen's depth (7: 7.3 MB),
+      the query side and a piece's bf16 copy three widths wide (at 1024
+      rows, 8192 columns, d = 128: 57.2 MB).
 
     Where it says None, the scan of tile steps stays as it is."""
     if itemsize not in (1, 4) or d % 8 or (itemsize == 1 and d % _LANES):
+        return None
+    if passes != 1 and (passes != 3 or itemsize != 4 or d % _LANES):
         return None
     from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
 
@@ -273,8 +282,8 @@ def fused_scan_engages(q: int, c: int, d: int, depth: int,
     while block and block % 16 == 0:
         # (the distance tile the bound rides is float32 whatever rests)
         if (lane_bin_bound_rides(block, c)
-                and fused_scan_vmem_bytes(block, c, d, depth, itemsize)
-                <= _FUSED_VMEM_BYTES):
+                and fused_scan_vmem_bytes(block, c, d, depth, itemsize,
+                                          passes) <= _FUSED_VMEM_BYTES):
             return block
         block //= 2
     return None

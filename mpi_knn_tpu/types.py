@@ -42,7 +42,10 @@ class KNNResult:
         one-pass steps ran inside the kernel that walks the whole stack
         (``ops/fused_scan.py``), or (..., 6) ``[0, multi-pass, 0, 0, 0, u8]``
         from a call over a byte stack (``dtype="uint8"``: its one-pass
-        steps, kernel or tile steps), one row a device where the rows are counted on
+        steps, kernel or tile steps), or (..., 7) ``[0, 0, 0, 0, 0, 0,
+        fused_screen]`` from a call whose screened steps ran inside that
+        kernel's three-pass form (fractional float32 rows on the lane grid
+        under L2: ``backends/serial.py fused_screen_rule``), one row a device where the rows are counted on
         the ring's devices; comes with the answer, costs no wait of its
         own. ``obs.metrics.MetricsRegistry.count_dist_steps`` adds it to
         ``knn_dist_tile_steps_total``. None from a program that counts no

@@ -81,7 +81,12 @@ Two families, one JSON artifact:
   fused_rule`` answering None) and as the program the rule gives (the
   kernel that walks the stack, where it engages), answers asserted equal
   bit for bit and the kernel's chunk count equal to the bounded scan's;
-  rows ``scan_step`` with ``us_per_step`` = median / tiles.
+  rows ``scan_step`` with ``us_per_step`` = median / tiles. Where the
+  certified screen engages at the shapes (``--d`` on the lane grid), two
+  more rows over the same law's FRACTIONAL rows at ``highest``: ``screen``
+  (the XLA scan of three-pass tile steps) and ``fused_screen`` (the
+  rule's program: the kernel's three-pass form where
+  ``fused_screen_rule`` engages), answers asserted equal.
 
 CPU numbers say nothing absolute about the TPU — what they pin is the
 RELATIVE trajectory per op across PRs, on the platform CI always has
@@ -100,6 +105,7 @@ of ``{op, variant, median_s, min_s, reps_s}`` rows.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import pathlib
@@ -200,72 +206,104 @@ def _scan_step(args) -> int:
     cfg = KNNConfig(k=k, backend="serial", matmul_precision="high",
                     query_tile=q, corpus_tile=c, exclude_self=True)
 
-    @jax.jit
-    def make(key):
+    @functools.partial(jax.jit, static_argnames=("fractional",))
+    def make(key, fractional=False):
         # MNIST-shaped rows (benchmark/datagen/clustered_u8.py's law: a
         # class centre plus noise, whole numbers in [0, 255]), centred by
         # a whole number, a tile at a time: no transient beside the stack
+        # (``fractional``: the same law's rows as they are drawn)
         cen = jnp.rint(jax.random.uniform(key, (10, d)) * 255.0)
 
         def tile(i):
             k1, k2 = jax.random.split(jax.random.fold_in(key, i))
             x = cen[jax.random.randint(k1, (c,), 0, 10)] + 25.0 * (
                 jax.random.normal(k2, (c, d), jnp.float32))
+            if fractional:
+                return x - 128.0
             return jnp.clip(jnp.rint(x), 0.0, 255.0) - 128.0
 
         return jax.lax.map(tile, jnp.arange(tiles))
 
-    stack = make(jax.random.key(0, impl="rbg"))
     ids = jnp.arange(tiles * c, dtype=jnp.int32).reshape(tiles, c)
-    # the query tile is rows of the corpus under their own ids, as a slice
-    # of the all-pairs job is
-    # (the verdict is an operand: a constant would fold the rule's
-    # conditional out of the scan it is part of)
-    operands = (stack[tiles // 2, :q], ids[tiles // 2, :q], stack, ids,
-                serial._stack_norms(stack, "l2"), jnp.asarray(True))
 
-    def program(rule, rides):
-        with unittest.mock.patch.multiple(
-                serial, fused_rule=rule, lane_bin_bound_rides=rides):
+    def operands_of(stack, *verdict):
+        # the query tile is rows of the corpus under their own ids, as a
+        # slice of the all-pairs job is
+        return (stack[tiles // 2, :q], ids[tiles // 2, :q], stack, ids,
+                serial._stack_norms(stack, "l2"), *verdict)
+
+    def program(cfg, operands, **rules):
+        """The merge's program for ``operands``, lowered with ``serial``'s
+        rules replaced by ``rules`` (none: the rules' own program)."""
+        with (unittest.mock.patch.multiple(serial, **rules) if rules
+              else contextlib.nullcontext()):
             @jax.jit
-            def run(q_x, q_ids, stack, ids, sqs, onepass):
+            def run(q_x, q_ids, stack, ids, sqs, *onepass):
                 return serial.merge_tiles_into_carry(
                     q_x, q_ids, sq_norms(q_x), stack, ids, sqs,
-                    *serial.init_topk(q, k), cfg, onepass)
+                    *serial.init_topk(q, k), cfg, *onepass)
 
             return run.lower(*operands).compile()
 
     def no_kernel(*a, **kw):
         return None
 
-    variants = {"scan": program(no_kernel, serial.lane_bin_bound_rides),
-                "rule": program(serial.fused_rule,
-                                serial.lane_bin_bound_rides)}
-    block = serial.fused_rule(cfg, q, c, d)
     results, outs = [], {}
-    for name, run in variants.items():
-        times = _time(lambda: run(*operands)[0], args.reps)
-        outs[name] = jax.tree.map(np.asarray, run(*operands))
-        med = statistics.median(times)
-        results.append({
-            "op": "scan_step", "variant": name, "q": q, "c": c, "d": d,
-            "tiles": tiles, "block": block if name == "rule" else None,
-            "median_s": round(med, 6), "min_s": round(min(times), 6),
-            "us_per_step": round(med / tiles * 1e6, 2),
-            "rescanned": bool(outs[name][2]),
-            "chunks": None if outs[name][3] is None
-            else outs[name][3].tolist(),
-        })
-        print(json.dumps(results[-1]), flush=True)
+
+    def measure(variants, operands, blocks):
+        for name, run in variants.items():
+            times = _time(lambda: run(*operands)[0], args.reps)
+            outs[name] = out = jax.tree.map(np.asarray, run(*operands))
+            med = statistics.median(times)
+            results.append({
+                "op": "scan_step", "variant": name, "q": q, "c": c, "d": d,
+                "tiles": tiles, "block": blocks.get(name),
+                "median_s": round(med, 6), "min_s": round(min(times), 6),
+                "us_per_step": round(med / tiles * 1e6, 2),
+                "rescanned": bool(out[2]),
+                "chunks": None if out[3] is None else out[3].tolist(),
+                "screened": None if out[4] is None else out[4].tolist(),
+            })
+            print(json.dumps(results[-1]), flush=True)
+
+    stack = make(jax.random.key(0, impl="rbg"))
+    # (the verdict is an operand: a constant would fold the rule's
+    # conditional out of the scan it is part of)
+    operands = operands_of(stack, jnp.asarray(True))
+    measure({"scan": program(cfg, operands, fused_rule=no_kernel),
+             "rule": program(cfg, operands)},
+            operands, {"rule": serial.fused_rule(cfg, q, c, d)})
     for name, a, b in zip(("vals", "ids", "rescanned"),
                           outs["scan"], outs["rule"]):
         np.testing.assert_array_equal(a, b, err_msg=name)
-    if block and outs["scan"][3] is None:
+    if serial.fused_rule(cfg, q, c, d) and outs["scan"][3] is None:
         # the count's witness: the scan of tile steps under the bound
         bounded = jax.tree.map(np.asarray, program(
-            no_kernel, lambda *a: True)(*operands))
-        for a, b in zip(bounded, outs["rule"]):
+            cfg, operands, fused_rule=no_kernel,
+            lane_bin_bound_rides=lambda *a: True)(*operands))
+        for a, b in zip(bounded[:4], outs["rule"]):
             np.testing.assert_array_equal(a, b)
+
+    # the SCREENED scan (ISSUE 51): the same law's rows left fractional, at
+    # ``highest`` and with no one-pass verdict, as the XLA scan of
+    # three-pass tile steps (``fused_screen_rule`` answering None) and as
+    # the rule's program — the kernel's three-pass form where it engages.
+    # Every returned distance is the six-pass finish's in both, so the
+    # answers are asserted equal; the chunk counts may differ by the few
+    # chunks whose screen values straddle a bound in the last bits.
+    exact = cfg.replace(matmul_precision="highest")
+    if serial.screen_rule(exact, q, c, d) is not None:
+        del operands, stack  # the whole-number stack goes first
+        stack = make(jax.random.key(0, impl="rbg"), fractional=True)
+        operands = operands_of(stack)
+        measure({"screen": program(exact, operands,
+                                   fused_screen_rule=no_kernel),
+                 "fused_screen": program(exact, operands)},
+                operands, {"fused_screen": serial.fused_screen_rule(
+                    exact, q, c, d)})
+        for name, a, b in zip(("vals", "ids"),
+                              outs["screen"], outs["fused_screen"]):
+            np.testing.assert_array_equal(a, b, err_msg=name)
     doc = {"device": str(jax.devices()[0].device_kind),
            "platform": jax.default_backend(), "equal": True,
            "results": results}

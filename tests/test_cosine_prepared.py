@@ -258,7 +258,7 @@ def test_cosine_tile_steps_count_on_a_path_of_their_own():
     got = {p: reg.counter(obs_metrics.DIST_STEPS, labels={"path": p}).value
            for p in obs_metrics.DIST_PATHS}
     assert got == {"onepass": 5, "multipass": 1, "cosine": 7, "fused": 0,
-                   "ip": 0, "u8": 0}
+                   "ip": 0, "u8": 0, "fused_screen": 0}
 
 
 @pytest.mark.parametrize("metric", ["l2", "cosine"])

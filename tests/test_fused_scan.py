@@ -6,7 +6,10 @@ counter that says it ran. Since ISSUE 40 also at widths off the lane grid
 (the tile taken rows-minor, a piece a grid step) and with the query tile
 walked in row blocks. The kernel body is interpreted here; where the
 shape rule would keep a width or a height out, the tests force the kernel
-in (and the bound onto the scan it is compared with)."""
+in (and the bound onto the scan it is compared with). Since ISSUE 51 the
+kernel has a THREE-PASS form for fractional float32 rows (the screened
+scan's: k' for k, slots for ids), whose lists are held against the XLA
+screened scan's and against float64."""
 
 import jax
 import jax.numpy as jnp
@@ -242,7 +245,7 @@ def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
                for p in DIST_PATHS}
     assert counted == {
         "onepass": 0, "multipass": 0, "cosine": 0, "fused": steps, "ip": 0,
-        "u8": 0}
+        "u8": 0, "fused_screen": 0}
     ring = all_knn(X, backend="ring-overlap", num_devices=4, **kw)
     np.testing.assert_array_equal(
         np.asarray(ring.dists), np.asarray(res.dists))
@@ -255,3 +258,165 @@ def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
         # no bound rides the multi-pass scan of a tile that tall: every
         # chunk is inserted
         assert np.asarray(frac.bins_chunks).tolist() == [4 * 256, 0]
+
+
+# ---------------------------------------------------------------------------
+# the three-pass form (ISSUE 51): the screened scan of fractional rows
+
+
+WIDE = 32  # k' at k = 10 (``backends/serial.py screen_width``)
+
+
+def _fractional_case(q, d, tiles, dead_tile=None, seed=0):
+    """Fractional rows in classes (near neighbours at close, distinct
+    distances), ids that are no slot numbers, tombstones scattered and the
+    last tile SHORT (it ends in padding); ``dead_tile``: a whole tile
+    tombstoned. Queries 0 .. 15 are corpus rows under their own ids."""
+    rng = np.random.default_rng([seed, q, d, tiles])
+    n = tiles * C_TILE
+    cen = rng.normal(size=(16, d))
+    x = (cen[rng.integers(0, 16, n)] + 0.4 * rng.normal(size=(n, d))).astype(
+        np.float32)
+    qx = (cen[rng.integers(0, 16, q)] + 0.4 * rng.normal(size=(q, d))).astype(
+        np.float32)
+    ids = rng.permutation(n).astype(np.int32) + 7
+    q_ids = np.full(q, -1, np.int32)
+    rows = rng.choice(n - C_TILE, size=16, replace=False)
+    qx[:16], q_ids[:16] = x[rows], ids[rows]
+    ids[rng.choice(n, size=9, replace=False)] = -1
+    ids[-41:] = -1
+    x[-41:] = 0.0
+    ids = ids.reshape(tiles, C_TILE)
+    if dead_tile is not None:
+        ids[dead_tile] = -1
+    tiles_x = jnp.asarray(x.reshape(tiles, C_TILE, d))
+    return (jnp.asarray(qx), jnp.asarray(q_ids), sq_norms(jnp.asarray(qx)),
+            tiles_x, jnp.asarray(ids), serial.stack_norms(tiles_x, "l2"))
+
+
+def _xla_screened_lists(case, cfg, due, depth):
+    """The lists of the XLA screened scan (``_merge_carried``'s
+    ``bounded``), step by step from its own pieces: the three-pass distance
+    tile (float32's own dot on this backend), the bound taken anew where
+    ``due``, *bins* under it with the iota plane's slots."""
+    from mpi_knn_tpu.ops.lane_bin import (
+        lane_bin_bound, lane_bin_insert, lane_bin_lists, lane_bin_no_bound)
+
+    q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs = case
+    q = q_x.shape[0]
+    lists, bound, inserted = lane_bin_lists(q, depth), lane_bin_no_bound(q), 0
+    for t in range(tiles.shape[0]):
+        if due[t]:
+            bound = jnp.minimum(bound, lane_bin_bound(lists, WIDE))
+        dist = serial.masked_dist_tile(
+            q_x, q_ids, q_sq, tiles[t], tile_ids[t], tile_sqs[t], cfg,
+            screen=True)
+        *lists, n = lane_bin_insert(
+            lists, dist, t * C_TILE + jnp.arange(C_TILE, dtype=jnp.int32),
+            depth, bound)
+        inserted += int(n)
+    return lists, inserted
+
+
+@pytest.mark.parametrize("d,tiles,dead_tile,due,block,why", [
+    (128, 3, None, None, 1024, "1024 x (3 x 1024) x 128, a short last tile"),
+    (128, 4, 1, None, 1024, "a tombstoned tile"),
+    (128, 6, None, [0, 1, 0, 1, 1, 1], 1024, "the bound anew at four tiles"),
+    (256, 3, None, [0, 1, 1], 512, "a wider row, the query tile in two "
+     "blocks"),
+])
+def test_three_pass_lists_are_the_xla_screened_scans(
+        d, tiles, dead_tile, due, block, why):
+    """The kernel's three-pass form against the XLA screened scan, list
+    for list: a row's k' candidates are the same SLOTS as a set, every
+    value within the re-derived ``screen_eps`` of the float64 value of
+    the row its slot names (the kernel's dot really is three bf16 passes
+    here: the interpreter multiplies the pieces), no dead column and no
+    row under the query's own id among them, the chunk count the
+    bounded scan's but for chunks that straddle a bound in the last
+    bits."""
+    from mpi_knn_tpu.ops.fused_scan import fused_scan
+    from mpi_knn_tpu.ops.lane_bin import lane_bin_result
+
+    q = 1024
+    cfg = KNNConfig(k=K, query_tile=q, corpus_tile=C_TILE, exclude_self=True,
+                    exclude_zero=True, center=False)
+    depth = lane_bin_depth(q, C_TILE, WIDE)
+    assert depth == 7
+    case = _fractional_case(q, d, tiles, dead_tile)
+    q_x, q_ids, q_sq, stack, ids, sqs = case
+    due = serial.bound_refreshes(tiles) if due is None else np.array(
+        due, bool)
+    kd, ki, n = fused_scan(
+        *case, due, k=WIDE, depth=depth, exclude_self=True,
+        exclude_zero=False, zero_eps=0.0, block=block, screen=True)
+    want_lists, want_n = _xla_screened_lists(case, cfg, due, depth)
+    got = jax.tree.map(np.asarray, lane_bin_result((kd, ki), q, WIDE))
+    want = jax.tree.map(np.asarray, lane_bin_result(want_lists, q, WIDE))
+    np.testing.assert_array_equal(got[2], want[2], err_msg="lanes' flags")
+    # float64: every pair's real value, dead columns and own ids masked
+    flat, flat_ids = np.asarray(stack).reshape(-1, d), np.asarray(ids).ravel()
+    x64, c64 = np.asarray(q_x, np.float64), flat.astype(np.float64)
+    real = ((x64 ** 2).sum(-1)[:, None] - 2 * x64 @ c64.T
+            + (c64 ** 2).sum(-1)[None, :])
+    real[:, flat_ids < 0] = np.inf
+    real[np.asarray(q_ids)[:, None] == flat_ids[None, :]] = np.inf
+    eps = np.asarray(serial.screen_eps(
+        "l2", d, q_x, q_sq, jnp.max(sqs), fused=True), np.float64)
+    rows = np.arange(q)[:, None]
+    assert (got[1] >= 0).all() and np.isfinite(real[rows, got[1]]).all()
+    err = np.abs(got[0] - real[rows, got[1]])
+    assert (err <= eps[:, None]).all(), (err / eps[:, None]).max()
+    # the three passes are there: nearer than one pass could be (2^-8 of
+    # 2 |q| |c| an element), and no float32 dot's exactness
+    assert 1e-3 * eps.min() < err.max() < 0.2 * eps.min()
+    same = np.array([set(a) == set(b) for a, b in zip(got[1], want[1])])
+    # where the sets differ the (k'+1)-th real value is within the
+    # kernel's error of the k'-th: either is the screen's answer
+    order = np.sort(real, axis=1)
+    assert same.mean() > 0.99, same.mean()
+    assert ((order[:, WIDE] - order[:, WIDE - 1])[~same]
+            <= 2 * err.max()).all()
+    every = tiles * (q // 16)
+    assert abs(int(n) - want_n) <= 0.02 * every and 0 < int(n) <= every
+    if dead_tile is not None:  # under a finite bound +inf passes no test
+        assert int(n) <= every - q // 16
+
+
+def test_three_pass_rule_and_vmem():
+    """The three-pass form's own engage rule (``fused_scan_engages`` with
+    ``passes=3``): float32 on the lane grid, with ITS buffers counted —
+    the lists at depth 7, the query side and a piece's bf16 copy three
+    widths wide — under the kernel's 64 MiB; the one-pass answers are
+    what they were."""
+    from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
+    from mpi_knn_tpu.ops.topk import _FUSED_VMEM_BYTES
+
+    depth = lane_bin_depth(1024, 8192, WIDE)
+    one = fused_scan_vmem_bytes(1024, 8192, 128, depth)
+    three = fused_scan_vmem_bytes(1024, 8192, 128, depth, passes=3)
+    # two more widths of the query side (two buffers) and of a piece's
+    # copy, the float32 piece and what the cut left, the slots' row
+    assert three - one == (2 * 2 * 1024 * 128 * 2 + 2 * 1024 * 128 * 2
+                           + 2 * 1024 * 128 * 4 + 8192 * 4)
+    assert three < _FUSED_VMEM_BYTES
+    for q, c, d, block in [(1024, 8192, 128, 1024), (4096, 8192, 128, 1024),
+                           (1024, 8192, 256, 512), (1024, 8192, 1536, None),
+                           (1024, 8192, 100, None), (1024, 8192, 784, None),
+                           (64, 8192, 128, None)]:
+        assert fused_scan_engages(
+            q, c, d, lane_bin_depth(max(q, 1024), c, WIDE), 4,
+            passes=3) == block, (q, c, d)
+    assert fused_scan_engages(1024, 8192, 128, depth, 1, passes=3) is None
+    cfg = KNNConfig(k=K, query_tile=1024, corpus_tile=8192)
+    for change, facts, block in [
+            ({}, {}, 1024), ({"metric": "cosine"}, {}, None),
+            ({"metric": "ip"}, {}, None), ({"matmul_precision": "high"}, {},
+                                           None),
+            ({}, {"branch": True}, None), ({}, {"filtered": True}, None),
+            ({}, {"varying": True}, None)]:
+        assert serial.fused_screen_rule(
+            cfg.replace(**change), 1024, 8192, 128, **facts) == block
+    assert serial.screen_rule(cfg, 1024, 8192, 1536) == WIDE
+    assert serial.fused_screen_rule(cfg, 1024, 8192, 1536) is None
+    assert serial.fused_screen_rule(cfg, 512, 8192, 128) is None

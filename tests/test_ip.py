@@ -500,7 +500,7 @@ def test_ip_tile_steps_count_on_a_path_of_their_own():
     got = {p: reg.counter(obs_metrics.DIST_STEPS, labels={"path": p}).value
            for p in obs_metrics.DIST_PATHS}
     assert got == {"onepass": 5, "multipass": 1, "cosine": 0, "fused": 0,
-                   "ip": 9, "u8": 0}
+                   "ip": 9, "u8": 0, "fused_screen": 0}
 
 
 @pytest.mark.parametrize("metric", ["l2", "cosine", "ip"])

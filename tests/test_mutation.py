@@ -932,3 +932,68 @@ def test_stack_with_headroom_is_built_tile_by_tile(rng, m, c_tile, headroom):
                        atol=1e-6)
     assert (np.asarray(dev.tile_ids) == np.asarray(host.tile_ids)).all()
     assert (np.asarray(dev.tiles).reshape(-1, 24)[m:] == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# writes under the kernel's three-pass screen (ISSUE 51)
+
+
+def test_padded_index_under_the_fused_screen_answers_as_float64_does():
+    """The streaming cell's form in miniature — fractional d = 100 rows at
+    rest zero-padded to 128 columns, a 1024-row bucket, L2: every search
+    ranks INSIDE the kernel (``path="fused_screen"``, slots made from the
+    grid's index), and after upserts into free slots, updates of live ids
+    and deletes the answer is the float64 top-10 over the rows live then:
+    a written row is found at its slot, a tombstoned slot is read for its
+    sign and never returned."""
+    rng = np.random.default_rng(51)
+    d, c_tile, rows, k = 100, 1024, 1024, 10
+    n = 3 * c_tile - 50
+    cen = rng.normal(size=(16, d))
+
+    def draw(m):
+        return (cen[rng.integers(0, 16, m)]
+                + 0.5 * rng.normal(size=(m, d))).astype(np.float32)
+
+    x, q = draw(n), draw(rows)
+    index = build_index(x, KNNConfig(
+        k=k, backend="serial", query_tile=rows, corpus_tile=c_tile,
+        exclude_zero=False, bucket_headroom=0.3, mutation_bucket=256))
+    tiles = index.tiles.shape[0]
+    assert index.tiles.shape[-1] == 128 and index.dim == d
+
+    def check(got, live):
+        assert np.asarray(got.dist_steps).tolist() == [0] * 6 + [tiles]
+        assert np.asarray(got.screen_rows).sum() == rows
+        ids = np.fromiter(live, dtype=np.int64)
+        rows64 = np.stack([live[i] for i in ids]).astype(np.float64)
+        q64 = q.astype(np.float64)
+        real = ((q64 * q64).sum(1)[:, None] - 2 * q64 @ rows64.T
+                + (rows64 * rows64).sum(1)[None])
+        want = np.sort(real, axis=1)[:, :k]
+        # (relative to the pair's scale: the form cancels near a duplicate)
+        scale = np.maximum(want, 0.1 * (q64 * q64).sum(1)[:, None])
+        np.testing.assert_array_less(
+            np.abs(np.asarray(got.dists) - want) / scale, 2e-5)
+        best = ids[np.argsort(real, axis=1)[:, :k]]
+        assert (best == np.asarray(got.ids)).mean() > 0.999
+        return np.asarray(got.ids)
+
+    live = dict(enumerate(x))
+    check(query_knn(q, index), live)
+    fresh = draw(300)
+    fresh[:150] = q[:150] + 0.01 * rng.normal(size=(150, d)).astype(
+        np.float32)
+    moved = q[150:250] + 0.01 * rng.normal(size=(100, d)).astype(np.float32)
+    sm.upsert_rows(index, np.arange(n, n + 300), fresh)
+    sm.upsert_rows(index, np.arange(100), moved)
+    gone = np.arange(200, 700)
+    sm.delete_rows(index, gone)
+    live.update(zip(range(n, n + 300), fresh))
+    live.update(zip(range(100), moved))
+    for i in gone:
+        del live[int(i)]
+    ids = check(query_knn(q, index), live)
+    assert (ids[:150, 0] == np.arange(n, n + 150)).all()
+    assert (ids[150:250, 0] == np.arange(100)).all()
+    assert not np.isin(ids, gone).any()

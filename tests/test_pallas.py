@@ -308,12 +308,15 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     # the program without the branch: one dot, plain scope, at the
     # configured precision — or, where the certified screen engages
     # (ISSUE 47: float32 at ``highest``, 1024 rows, d % 128 == 0: what a
-    # bigann-shaped corpus of FRACTIONAL rows would run), at ``high`` with
-    # lists that answer for k' = 32
+    # bigann-shaped corpus of FRACTIONAL rows would run), NO XLA dot in
+    # the scan: under L2 on the lane grid the screened scan is the kernel
+    # in its three-pass form (ISSUE 51), lists that answer for k' = 32
     screen = serial.screen_rule(cfg, q, 8192, dim)
     assert screen == (32 if cell == "serve-bigann10m-bulk" else None)
-    assert _dist_dots(plain.as_text()) == {
-        "": (("f32", "f32"), "high" if screen else precision)}
+    in_kernel = serial.fused_screen_rule(cfg, q, 8192, dim)
+    assert in_kernel == (1024 if screen else None)
+    assert _dist_dots(plain.as_text()) == (
+        {} if in_kernel else {"": (("f32", "f32"), precision)})
     # under the rule: the engaged branch holds ONE bf16 x bf16 -> f32 dot
     # (no operand_precision: a DEFAULT dot), the other the configured one;
     # where one kernel walks the stack (ISSUE 37: the bulk cell's shape;
@@ -332,7 +335,13 @@ def test_serial_program_under_the_one_pass_rule_compiles_for_the_v5e(
     temps = [c.memory_analysis().temp_size_in_bytes / 2**30
              for c in (plain, ruled)]
     assert temps[1] <= temps[0] + 0.1 and temps[1] <= temp_gib, (cell, temps)
-    _assert_the_lists_ride_the_scan(plain.as_text(), q, tiles, screen or 10)
+    if in_kernel:  # the kernel makes the lists: no *bins*, no bound call
+        calls = sorted(ln.split()[0].rstrip(".0123456789")
+                       for ln in plain.as_text().splitlines()
+                       if "tpu_custom_call" in ln)
+        assert calls == ["%finish", "%knn.fused"], calls
+    else:
+        _assert_the_lists_ride_the_scan(plain.as_text(), q, tiles)
     _assert_the_lists_ride_the_scan(ruled.as_text(), q, tiles)
 
 
@@ -666,11 +675,17 @@ def test_the_rest_layout_of_a_padded_stack_from_build_to_batch(
     temporaries a tile's, its output the 5.13e9 B stack and no copy beside
     it. The UPSERT is the one-scatter form the d = 128 indexes take, every
     store array aliased, nothing store-sized copied. The BATCH program
-    pads its 1024 x 100 query tile itself, screens (``high`` in the scan,
-    ``highest`` in the re-scan), gathers the candidates from the stack
-    viewed flat — a bitcast — and neither copies nor transposes the
-    stack; the d = 100 stack's per-step slice copy (``f32[1,8192,100]``)
-    is gone."""
+    pads its 1024 x 100 query tile itself, screens, gathers the candidates
+    from the stack viewed flat — a bitcast — and neither copies nor
+    transposes the stack; the d = 100 stack's per-step slice copy
+    (``f32[1,8192,100]``) is gone. Since ISSUE 51 the screened scan is ONE
+    Mosaic call (``knn.fused``, the three-pass form: the lists it makes
+    are 896 wide and hold slots): outside the re-scan's conditional the
+    program holds no (1024, 8192) distance tile, no *bins* call, no
+    bound-refresh call and no loop over the stack, and the only XLA dot is
+    the re-scan's at ``highest``; the kernel's VMEM — the lists at depth
+    7, the query side and a piece's bf16 pieces three widths wide — is
+    under the rule's 64 MiB, and the call asks for that and its headroom."""
     import re
 
     import jax
@@ -735,14 +750,39 @@ def test_the_rest_layout_of_a_padded_stack_from_build_to_batch(
     dots = re.findall(r"operand_precision=\{(\w+),\w+\}[^\n]*?op_name=\"([^\"]*)"
                       r"/dot_general\"", hlo)
     assert sorted((p, "fallback" in name) for p, name in dots) == [
-        ("high", False), ("highest", True)], dots
+        ("highest", True)], dots
     gathered = [ln for ln in hlo.splitlines()
                 if re.search(rf"= f32\[{q},32,{wide}\]\S* gather\(", ln)]
     assert len(gathered) == 1 and "knn.rerank" in gathered[0], gathered
     assert re.search(
         rf"f32\[{tiles * c_tile},{wide}\]\{{1,0\S* parameter\(0\)", hlo)
-    _assert_the_lists_ride_the_scan(hlo, q, tiles, 32)
     assert batch.memory_analysis().temp_size_in_bytes <= 64 << 20
+    # the screened scan: one kernel over the stack where it rests
+    from mpi_knn_tpu.ops.fused_scan import (
+        _VMEM_HEADROOM, fused_scan_vmem_bytes)
+    from mpi_knn_tpu.ops.topk import _FUSED_VMEM_BYTES, lane_bin_depth
+
+    lines = hlo.splitlines()
+    calls = [ln for ln in lines if "tpu_custom_call" in ln]
+    fused = [ln for ln in calls if "knn.fused" in ln]
+    assert len(fused) == 1 and re.search(
+        rf"= \(f32\[{q},896\]\S*, s32\[{q},896\]", fused[0]), fused
+    operands = re.sub(r"/\*[^*]*\*/", "", re.search(
+        r"custom-call\(([^)]*)\)", fused[0]).group(1)).split(", ")
+    assert operands[4:] == ["%tiles.1", "%tile_ids.1", "%tile_sqs.1"], operands
+    assert sorted(ln.split()[0].rstrip(".0123456789") for ln in calls) == [
+        "%finish", "%knn.fused"], calls  # no bins, no bound
+    need = fused_scan_vmem_bytes(q, c_tile, wide, lane_bin_depth(
+        q, c_tile, 32), passes=3)
+    assert need <= _FUSED_VMEM_BYTES
+    assert f'"size":"{need + _VMEM_HEADROOM}"' in fused[0], fused[0][-400:]
+    # whatever still holds a distance tile or walks the stack is the
+    # re-scan's, under its conditional
+    whiles = [ln for ln in lines if " while(" in ln and "f32[1224," in ln]
+    assert whiles and all("knn.select/fallback" in ln for ln in whiles), whiles
+    made = [ln for ln in lines if re.search(
+        rf"= f32\[{q},{c_tile}\]\S* (fusion|convolution|custom-call)\(", ln)]
+    assert made and all("knn.select/fallback" in ln for ln in made), made
 
 
 # ---------------------------------------------------------------------------

@@ -334,7 +334,7 @@ def test_a_ring_call_counts_its_fused_steps_a_device(
         for p in obs_metrics.DIST_PATHS}
     assert counted == {"onepass": 0, "multipass": steps + DEVICES * steps,
                        "cosine": 0, "fused": (2 * DEVICES - 1) * steps,
-                       "ip": 0, "u8": 0}
+                       "ip": 0, "u8": 0, "fused_screen": 0}
     assert sum(registry.counter(
         obs_metrics.BINS_CHUNKS, labels={"path": p}).value
         for p in obs_metrics.BINS_PATHS) == chunks.sum()

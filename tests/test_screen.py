@@ -6,7 +6,12 @@ when that is the six-pass answer.
 
 On the CPU every precision is float32's own, so the proof is tested by
 PLANTING the error: the screen's distance tile is replaced by the exact
-values perturbed by up to ``screen_eps``, adversarially."""
+values perturbed by up to ``screen_eps``, adversarially. Since ISSUE 51 an
+L2 program of these shapes ranks INSIDE the kernel that walks the stack
+(``fused_screen_rule``; the interpreter really multiplies bf16 pieces
+there): a planted tile needs the XLA scan (``_merge`` patches the rule off
+where it plants), and the kernel's own accumulation term is held against
+an emulated split."""
 
 import functools
 import json
@@ -67,10 +72,12 @@ def _merge(monkeypatch, cfg, case, plant=None, screen=True):
     """``merge_tiles_into_carry`` of ``case`` from an empty carry, under a
     jit of its own: the program the rule gives (``screen``; False: the
     six-pass program, the rule patched off), the screen's distance tile
-    put through ``plant(values, tile ids) -> values`` where given."""
+    put through ``plant(values, tile ids) -> values`` where given (the XLA
+    scan's tile: a value cannot be planted inside the kernel)."""
     if not screen:
         monkeypatch.setattr(serial, "screen_rule", lambda *a, **k: None)
     if plant is not None:
+        monkeypatch.setattr(serial, "fused_screen_rule", lambda *a, **k: None)
         real = serial.masked_dist_tile
 
         def planted(*a, screen=False, **kw):
@@ -470,3 +477,159 @@ def test_eps_per_metric_form():
         rtol=1e-6)
     with pytest.raises(ValueError, match="2\\^15"):
         serial.screen_eps("l2", 1 << 16, q, None, jnp.float32(1.0))
+
+
+# ---------------------------------------------------------------------------
+# the screen inside the kernel (ISSUE 51): its own accumulation term, and
+# the certificate's way out with the kernel engaged
+
+
+def _two_pieces(v, cut):
+    """A float32 array's two bf16 pieces: as the kernel makes them
+    (``ops/fused_scan.py``: the first CUT from the bits, the second what
+    the cut left — exact in float32 — rounded to nearest), or both rounded,
+    or both cut."""
+    hi = _bf16_piece(v, truncate=cut != "round")
+    return hi, _bf16_piece(v - hi, truncate=cut == "cut")
+
+
+def _f32_sum(terms, order):
+    """``terms`` (rows, n) float32 added along n IN float32: one after
+    the other, in pairs (a tree), or 128 at a time and then the partial
+    sums one after the other (an accumulator behind a 128-deep array)."""
+    terms = np.ascontiguousarray(terms, np.float32)
+    if order == "chain":
+        return np.add.accumulate(terms, axis=1, dtype=np.float32)[:, -1]
+    if order == "blocks":
+        parts = np.stack([_f32_sum(terms[:, i:i + 128], "chain")
+                          for i in range(0, terms.shape[1], 128)], axis=1)
+        return _f32_sum(parts, "chain")
+    while terms.shape[1] > 1:
+        if terms.shape[1] % 2:
+            terms = np.concatenate(
+                [terms, np.zeros_like(terms[:, :1])], axis=1)
+        terms = terms[:, 0::2] + terms[:, 1::2]
+    return terms[:, 0]
+
+
+@pytest.mark.parametrize("d", [128, 1536])
+@pytest.mark.parametrize("cut", ["kernel", "round", "cut"])
+@pytest.mark.parametrize("order", ["chain", "blocks", "tree"])
+def test_kernel_accumulation_term_covers_an_emulated_split(d, cut, order):
+    """``screen_additions(d, fused=True)`` on paper against the kernel's
+    form emulated: the pieces as the kernel cuts them (and cut both other
+    ways), the 3 d products (exact in float32) laid side by side and added
+    in float32 in three orders — no order is the MXU's by contract — over
+    random pairs and pairs built to hurt (all of one sign, so no rounding
+    cancels; every dropped bit set). The sum's error stays under
+    ``(3 d - 1) 2^-23 sum |products|``, the whole dot's under ``(c_P +
+    (3 d - 1) 2^-23 (1 + 2^-5)) |x| |y|``; and the term is needed: the
+    built pairs' chain is off by more than one rounding."""
+    rng = np.random.default_rng([d, len(order), len(cut)])
+    n = 2000 if d == 128 else 300
+    x = rng.standard_normal((n, d), dtype=np.float32)
+    y = 0.8 * x + 0.6 * rng.standard_normal((n, d), dtype=np.float32)
+    low = 2.0**-7 - 2.0**-23  # just under the next bfloat16: what a cut drops
+    a = (1 + rng.integers(0, 2**7, (n, d)) * 2.0**-7 + low).astype(np.float32)
+    b = (1 + rng.integers(0, 2**7, (n, d)) * 2.0**-7 + low).astype(np.float32)
+    adds = serial.screen_additions(d, fused=True) - (d + 4)
+    assert adds == 3 * d - 1
+    worst = 0.0
+    for u, v in ((x, y), (a, b)):
+        u1, u2 = _two_pieces(u, cut)
+        v1, v2 = _two_pieces(v, cut)
+        # (hi, hi, lo) against (hi, lo, hi), as the kernel lays them
+        terms = np.concatenate([u1 * v1, u1 * v2, u2 * v1], axis=1)
+        exact = terms.astype(np.float64).sum(1)
+        np.testing.assert_array_equal(  # a product of two pieces is exact
+            terms.astype(np.float64), np.concatenate(
+                [u1.astype(np.float64) * v1, u1.astype(np.float64) * v2,
+                 u2.astype(np.float64) * v1], axis=1))
+        got = _f32_sum(terms, order).astype(np.float64)
+        mass = np.abs(terms.astype(np.float64)).sum(1)
+        acc = np.abs(got - exact) / mass
+        assert acc.max() <= adds * serial._ACC_UNIT
+        worst = max(worst, acc.max())
+        scale = np.sqrt(_rowdot(u, u) * _rowdot(v, v))
+        assert (mass <= (1 + 2.0**-5) * scale).all()
+        whole = np.abs(got - _rowdot(u, v)) / scale
+        assert whole.max() <= serial._SCREEN_SPLIT + adds * serial._ACC_UNIT * (
+            1 + 2.0**-5)
+    if order == "chain":  # one sign, one after the other
+        assert worst > 4 * 2.0**-24
+
+
+def test_eps_holds_the_kernels_term():
+    """``screen_eps(..., fused=True)``: the same form with the kernel's
+    additions, 4 d + 3 where the XLA screen has 2 (d + 4); larger, never
+    smaller, and by that term alone."""
+    q = jnp.asarray(np.ones((1, 8), np.float32))
+    for d in (128, 1536):
+        assert serial.screen_additions(d) == 2 * (d + 4)
+        assert serial.screen_additions(d, fused=True) == 4 * d + 3
+        xla, kernel = (float(serial.screen_eps(
+            "l2", d, q, sq_norms(q), jnp.float32(9.0), fused=f)[0])
+            for f in (False, True))
+        np.testing.assert_allclose(
+            kernel - xla, 2 * (2 * d - 5) * 2.0**-23 * (1 + 2.0**-4)
+            * np.sqrt(8) * 3, rtol=1e-4)
+    k128 = (2.0**-12 + 2.0**-18 + 515 * 2.0**-23) * (1 + 2.0**-4)
+    assert 3.2e-4 < k128 < 3.3e-4
+
+
+def _dist_step_counts():
+    reg = obs_metrics.get_registry()
+    return {p: reg.counter(obs_metrics.DIST_STEPS, labels={"path": p}).value
+            for p in obs_metrics.DIST_PATHS}
+
+
+def test_planted_near_tie_comes_back_through_the_rescan(monkeypatch):
+    """With the KERNEL engaged (an L2 index of fractional rows on the lane
+    grid): forty rows planted on a sphere around query row 0 — one real
+    distance, apart by roundings alone, so the 10th and the k'-th screen
+    values lie inside eps — cannot be certified; the row is flagged,
+    ``knn_screen_rows_total{result="flagged"}`` moves, the re-scan answers
+    it, and the served answer is the six-pass program's and float64's.
+    The steps count under ``path="fused_screen"`` and ``multipass`` stands
+    still."""
+    cfg = _cfg("l2", query_bucket=Q)
+    q_x, _, _, tiles, _, _ = _case("l2")
+    x = np.array(tiles).reshape(-1, D)[:N]
+    q = np.array(q_x)
+    rng = np.random.default_rng(5)
+    u = rng.normal(size=(40, D))
+    at = rng.choice(N, size=40, replace=False)
+    x[at] = (q[0] + 1.5 * u / np.linalg.norm(u, axis=1, keepdims=True)
+             ).astype(np.float32)
+    assert serial.fused_screen_rule(cfg, Q, C_TILE, D) == Q
+    index = build_index(x, cfg)
+
+    def counted():
+        reg = obs_metrics.get_registry()
+        return [reg.counter(obs_metrics.SCREEN_ROWS,
+                            labels={"result": r}).value
+                for r in obs_metrics.SCREEN_RESULTS]
+
+    rows, steps = counted(), _dist_step_counts()
+    got = query_knn(q, index)
+    rows = [b - a for a, b in zip(rows, counted())]
+    assert rows[0] + rows[1] == Q and 1 <= rows[1] < Q // 4, rows
+    assert np.asarray(got.screen_rows).tolist() == rows
+    assert np.asarray(got.select_tiles).tolist() == [0, 1]  # re-scanned
+    moved = {p: v - steps[p] for p, v in _dist_step_counts().items()}
+    assert moved == {**dict.fromkeys(obs_metrics.DIST_PATHS, 0),
+                     "fused_screen": TILES}
+    assert np.asarray(got.dist_steps).tolist() == [0] * 6 + [TILES]
+    # the six-pass program of the same index: the rule patched off
+    monkeypatch.setattr(serial, "screen_rule", lambda *a, **k: None)
+    want = query_knn(q, build_index(x, cfg.replace(recall_target=0.96)))
+    assert want.screen_rows is None
+    assert np.asarray(want.dist_steps).tolist() == [0, TILES]
+    np.testing.assert_array_equal(
+        np.asarray(got.dists)[0], np.asarray(want.dists)[0])
+    assert set(np.asarray(got.ids)[0]) <= set(at.tolist())
+    same = (np.asarray(got.ids) == np.asarray(want.ids)).mean()
+    assert same > 0.999, same
+    real = ((q[:64, None, :].astype(np.float64)
+             - x[np.asarray(got.ids)[:64]].astype(np.float64)) ** 2).sum(-1)
+    np.testing.assert_allclose(np.asarray(got.dists)[:64], real, rtol=5e-5)

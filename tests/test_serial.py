@@ -721,7 +721,9 @@ def _cell_programs():
 # inside. Four wide: the program does not screen (ISSUE 47: float32 rows
 # at ``highest``, no one-pass branch, 1024 rows, d % 128 == 0 — the
 # embedding cell's bucket, and what a bigann-shaped corpus of FRACTIONAL
-# rows would run, which no cell's data is)
+# rows would run, which no cell's data is). Six wide: the screened scan is
+# ONE call of the kernel's three-pass form (ISSUE 51: L2 on the lane grid,
+# its VMEM fits), the third entry its block height
 _SMALL = {64: (False, 4, None, False), 128: (False, 4, None, False),
           256: (False, 5, None, True), 512: (False, 5, None, True)}
 _WHICH = {
@@ -730,16 +732,20 @@ _WHICH = {
     **{f"serve-bigann10m-small-{b}{fact}": v
        for b, v in _SMALL.items() for fact in ("", "-nofact")},
     "serve-bigann10m-small-1024": (True, 5, 1024, True),
-    "serve-bigann10m-small-1024-nofact": (False, 5, None, True, 32),
+    "serve-bigann10m-small-1024-nofact": (
+        False, 5, 1024, True, 32, "fused_screen"),
     "serve-bigann10m-bulk-1024": (True, 5, 1024, True),
-    "serve-bigann10m-bulk-1024-nofact": (False, 5, None, True, 32),
+    "serve-bigann10m-bulk-1024-nofact": (
+        False, 5, 1024, True, 32, "fused_screen"),
     "serve-dbpedia1m-cos-bulk-1024": (False, 5, None, True, 32),
     # d = 100: no multiple of 8, the stack rests out of the kernel's reach
     # (whole-number rows there: no cell's data) ...
     "stream-msturing10m-runbook-1024": (True, 5, None, True),
     # ... and fractional rows rest zero-padded at 128 columns, where the
-    # screen engages (ISSUE 49: ``serve/index.py rest_width``)
-    "stream-msturing10m-runbook-1024-nofact": (False, 5, None, True, 32),
+    # screen engages (ISSUE 49: ``serve/index.py rest_width``) — inside
+    # the kernel (ISSUE 51): the cell's own program
+    "stream-msturing10m-runbook-1024-nofact": (
+        False, 5, 1024, True, 32, "fused_screen"),
     # a predicate: the one-pass branch from 256 rows, never the fused scan
     **{f"serve-yfcc10m-filter-bulk-{b}-nofact": v for b, v in _SMALL.items()},
     "serve-yfcc10m-filter-bulk-64": (False, 4, None, False),
@@ -811,8 +817,12 @@ def test_which_program_a_cell_runs(request, monkeypatch, cell, rows, fact,
     screen = None if depth is None else serial.screen_rule(
         cfg, q_tile, c_tile, dim, branch=bool(onepass), filtered=filtered,
         varying=ring)
-    assert (onepass, depth, block, rides) + (
-        () if screen is None else (screen,)) == want
+    in_kernel = None if depth is None else serial.fused_screen_rule(
+        cfg, q_tile, c_tile, dim, branch=bool(onepass), filtered=filtered,
+        varying=ring)
+    assert (onepass, depth, block or in_kernel, rides) + (
+        () if screen is None else (screen,)) + (
+        ("fused_screen",) if in_kernel else ()) == want
 
 
 # ---------------------------------------------------------------------------
