@@ -13,6 +13,14 @@ farther, other clusters farther still. Base block ``b`` belongs to cluster
 ``b // (base blocks / clusters)``; a block past the base rows belongs to the
 cluster the runbook gives it (``cluster_of_block``).
 
+The LAW — the cluster centres and every block's sub-centre — follows the
+configuration's ``law_seed`` where it names one, so that every ``--seed``
+meets the same geometry and with it the same work (how many of a tile's
+chunks lie under a row's bound goes with where the clusters lie: six seeds
+with a geometry each read 4 % apart, PERF.md §6, PR 53); the ROWS — base
+rows, inserted rows, query rows — follow ``--seed``. A configuration
+without the key draws the law from ``--seed`` too.
+
 Sub-centres are made on the host with numpy (they are small, and the
 jax-free driver needs them for its query rows); base rows are made on the
 device block by block, so that nothing corpus-sized crosses the host; the
@@ -25,8 +33,13 @@ from __future__ import annotations
 import numpy as np
 
 
+def law_seed(seed: int, spec: dict) -> int:
+    """The seed of the law: the configuration's, else the run's."""
+    return int(spec.get("law_seed", seed))
+
+
 def cluster_centres(seed: int, spec: dict, dim: int) -> np.ndarray:
-    rng = np.random.default_rng([int(seed), 0xC0])
+    rng = np.random.default_rng([law_seed(seed, spec), 0xC0])
     return (rng.standard_normal((int(spec["clusters"]), dim))
             * float(spec["cluster_sigma"])).astype(np.float32)
 
@@ -36,7 +49,7 @@ def sub_centres(seed: int, spec: dict, dim: int,
     """(blocks, dim) float32: every block's sub-centre, base blocks and
     the runbook's alike, in block order."""
     cen = cluster_centres(seed, spec, dim)
-    rng = np.random.default_rng([int(seed), 0xB1])
+    rng = np.random.default_rng([law_seed(seed, spec), 0xB1])
     g = rng.standard_normal((len(cluster_of_block), dim))
     return (cen[np.asarray(cluster_of_block)]
             + g * float(spec["sub_sigma"])).astype(np.float32)

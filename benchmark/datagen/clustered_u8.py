@@ -15,8 +15,11 @@ import numpy as np
 
 
 def centres(seed: int, spec: dict, dim: int) -> np.ndarray:
-    """(centres, dim) float32 class centres from the seed, on the host."""
-    rng = np.random.default_rng([int(seed), 0xC0])
+    """(centres, dim) float32 class centres, on the host: from the seed, or
+    from the configuration's ``law_seed`` where it names one — the LAW is
+    then the configuration's and only the ROWS the run's, for a cell whose
+    work goes with the geometry (the clustered cell's work items)."""
+    rng = np.random.default_rng([int(spec.get("law_seed", seed)), 0xC0])
     return (rng.random((int(spec["centres"]), dim))
             * float(spec["centre_scale"])).astype(np.float32)
 
@@ -24,8 +27,12 @@ def centres(seed: int, spec: dict, dim: int) -> np.ndarray:
 def host_rows(rng: np.random.Generator, n: int, cen: np.ndarray,
               spec: dict) -> np.ndarray:
     """``n`` fresh rows of the same shape as the corpus's, on the host:
-    the query rows of a serving mix."""
-    which = rng.integers(0, cen.shape[0], size=n)
+    the query rows of a serving mix. Under a ``law_seed`` the CLASS of each
+    row is the configuration's too (which classes a batch holds decides how
+    many lists it touches) and the noise about its centre the run's."""
+    law = spec.get("law_seed")
+    pick = rng if law is None else np.random.default_rng([int(law), 0x71])
+    which = pick.integers(0, cen.shape[0], size=n)
     x = cen[which] + rng.standard_normal((n, cen.shape[1])) * float(
         spec["sigma"])
     return np.clip(np.rint(x), 0.0, 255.0).astype(np.float32)

@@ -12,6 +12,13 @@ start (inserts) and from half a turn further on (deletes), so the hot
 ranges move and an inserted row lands in a slot another cluster's row left.
 The live count is the base count between cycles and ``range_rows`` more
 inside one.
+
+Where the configuration's data law names a ``law_seed`` the book follows it
+and not ``--seed``: which cluster the first range goes into and which
+blocks the pool rows are drawn near are the same in every run, as the
+clusters themselves are (``datagen/clustered_f32_stream.py``), so every
+seed walks the same book over other rows. The probe block follows
+``--seed``.
 """
 
 from __future__ import annotations
@@ -32,7 +39,8 @@ def plan(config: dict, mix: dict, seed: int) -> dict:
                          "divide")
     if (n_cycles // clusters + 1) * span > per_cluster // 2:
         raise ValueError("max_cycles would delete past half a cluster")
-    start = int(np.random.default_rng([int(seed), 0x5B]).integers(clusters))
+    law = int(spec.get("law_seed", seed))
+    start = int(np.random.default_rng([law, 0x5B]).integers(clusters))
     cycles = []
     for i in range(n_cycles):
         into = (start + i) % clusters
@@ -47,7 +55,7 @@ def plan(config: dict, mix: dict, seed: int) -> dict:
     base = np.repeat(np.arange(clusters), per_cluster // block)
     added = np.repeat([c["insert_cluster"] for c in cycles], span // block)
     return {"cycles": cycles, "rows": rows, "block_rows": block,
-            "ids": rows + n_cycles * span,
+            "ids": rows + n_cycles * span, "law_seed": law,
             "cluster_of_block": np.concatenate([base, added])}
 
 
@@ -84,7 +92,7 @@ def pool_targets(plan_: dict, mix: dict, seed: int) -> np.ndarray:
     base_blocks = plan_["rows"] // block
     per_cluster = base_blocks // len(set(plan_["cluster_of_block"].tolist()))
     # the upper half of a cluster's blocks is out of every delete's reach
-    rng = np.random.default_rng([int(seed), 0x7A])
+    rng = np.random.default_rng([int(plan_.get("law_seed", seed)), 0x7A])
     n = int(mix["query_pool_rows"])
     quiet = (rng.integers(0, base_blocks // per_cluster, size=n) * per_cluster
              + per_cluster // 2

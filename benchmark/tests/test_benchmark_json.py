@@ -40,13 +40,17 @@ def test_configs_name_their_files_and_sources():
     assert len({c["source"] for c in BENCH["configs"]}) == len(names)
 
 
-def test_cells_are_one_chip_and_name_files_that_exist():
+def test_cells_take_one_chip_or_four_and_name_files_that_exist():
     configs = {c["name"] for c in BENCH["configs"]}
     seen = set()
+    # four chips in at most a quarter of the cells, rounded down; one always
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 4)
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-        assert w["config"] in configs and w["chips"] == 1 and line(w["why"])
+        assert w["config"] in configs and w["chips"] in (1, 4)
+        assert line(w["why"])
         assert (w["config"], w["traffic"]) not in seen
         seen.add((w["config"], w["traffic"]))
         mix = json.load(open(os.path.join(

@@ -79,7 +79,8 @@ def test_cosine_cell_traced_line(copy):
     bench = json.load(open(os.path.join(copy, "BENCHMARK.json")))
     allowed = {m["name"] for m in bench["per_layer"]
                if CELL in m["workloads"]}
-    assert allowed == {"device_idle_pct.tput", "tile_roofline",
+    # at least these: a later PR may add a metric to the cell's list
+    assert allowed >= {"device_idle_pct.tput", "tile_roofline",
                        "cos_dist_us_per_step", "cos_rest_us_per_step"}
     assert set(last["metrics"]) <= allowed  # no device trace on the CPU
     assert last["correct"] is True

@@ -268,3 +268,35 @@ def test_opcount_names_the_bound():
     least, bound = opcount_ivf.least_seconds(1e12, 4.0e4, 128, PEAKS)
     assert bound == "compute" and least == pytest.approx(
         2 * 1e12 * 128 / 197e12)
+
+
+def test_the_law_is_the_configurations_and_the_rows_the_seeds():
+    """``data.law_seed`` fixes the class centres and the class of every
+    pool row (the geometry a batch's work items go with); the rows drawn
+    about the centres still follow the seed.
+    A configuration without it keeps centres that follow the seed: the
+    exact cells' files, which this one shares its generator with."""
+    from benchmark import harness
+
+    with open(os.path.join(harness.HERE, "configs", CONFIG + ".json")) as f:
+        config = json.load(f)
+    spec, dim = config["data"], config["dim"]
+    gen = harness.datagen_for(config)
+    assert "law_seed" in spec
+    a, b = (gen.centres(s, spec, dim) for s in (11, 3000000012))
+    assert a.shape == (spec["centres"], dim) and np.array_equal(a, b)
+    assert np.array_equal(a, gen.centres(spec["law_seed"], {
+        k: v for k, v in spec.items() if k != "law_seed"}, dim))
+    pools = [harness.query_pool(config, s, 64) for s in (11, 3000000012, 11)]
+    assert not np.array_equal(pools[0], pools[1])
+    assert np.array_equal(pools[0], pools[2])
+
+    def classes(rows):  # the nearest centre: the noise is half the spacing
+        d = ((rows[:, None, :] - a[None, :, :]) ** 2).sum(axis=2)
+        return d.argmin(axis=1)
+
+    assert np.array_equal(classes(pools[0]), classes(pools[1]))
+    assert len(set(classes(pools[0]))) > 32
+    free = {k: v for k, v in spec.items() if k != "law_seed"}
+    assert not np.array_equal(gen.centres(11, free, dim),
+                              gen.centres(12, free, dim))

@@ -83,3 +83,25 @@ def test_window_compiles_counts_compiles_and_cache_loads():
     assert read("window_compiles", {"jax_compiles_total": 2.0}) == 2.0
     assert read("window_compiles", {"jax_compiles_total": 1.0,
                                     "jax_cache_loads_total": 1.0}) == 2.0
+
+
+def test_cycle_stall_pct_is_the_share_of_the_window_in_long_cycles():
+    """The streaming cell's walker's own clock: cycles longer than 1.5
+    medians, over the window; nothing to read without three cycles."""
+    stall = load_by_path("layer_metrics", "cycle_stall_pct").read
+    typical = load_by_path("layer_metrics", "cycle_median_ms").read
+
+    def run(*seconds):
+        return {"stream": {"cycles": [{"s": s} for s in seconds]}}
+
+    even = [0.4] * 9
+    assert stall(run(*even)) == 0.0
+    assert stall(run(*even, 0.6)) == 0.0  # not over 1.5 medians
+    assert stall(run(*even, 1.4)) == pytest.approx(100 * 1.4 / 5.0)
+    assert typical(run(*even, 1.4)) == pytest.approx(400)
+    # a shift of the typical cycle is no stall
+    assert stall(run(*[0.5] * 10)) == 0.0
+    assert typical(run(*[0.5] * 10)) == pytest.approx(500)
+    for nothing in ({}, {"stream": None}, {"stream": {"window_s": 5.0}},
+                    run(0.4, 9.0)):
+        assert stall(nothing) is None and typical(nothing) is None

@@ -76,9 +76,10 @@ def test_ring_cell_traced_line(copy, four_devices):
     bench = json.load(open(os.path.join(copy, "BENCHMARK.json")))
     allowed = {m["name"] for m in bench["per_layer"]
                if CELL in m["workloads"]}
+    # at least these: a later PR may add a metric to the cell's list
     assert {"ring_collective_exposed_pct", "ring_wire_gbps",
             "ring_tile_roofline", "device_idle_pct.tput",
-            "call_host_gap_ms"} == allowed
+            "call_host_gap_ms"} <= allowed
     assert set(last["metrics"]) <= allowed  # no device trace on the CPU
     assert last["correct"] is True
 

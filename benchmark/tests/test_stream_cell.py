@@ -92,7 +92,7 @@ def test_stream_cell_end_to_end_line(copy):
     assert rc == 0, out[-3000:]
     assert last["correct"] is True and last["failed"] == 0, out[-3000:]
     assert last["attempted"] > 0
-    assert set(last["metrics"]) == {"rows_per_s", "setup_s"}
+    assert set(last["metrics"]) == {"stream_rows_per_s", "setup_s"}
     seen = checks(out)
     assert {"recall_at_k", "dist_rel_err_max", "deleted_id_returned",
             "compiled_in_window", "answers_misshapen_or_failed",
@@ -100,6 +100,23 @@ def test_stream_cell_end_to_end_line(copy):
             "probe_touched_by_deletes_share"} <= set(seen)
     assert all(seen.values())
     assert "launcher: host mirror of" in out
+    # the walker's own record: whole cycles that add up to the window,
+    # each the sum of its steps, and every answer checked before `correct`
+    said = {ln.split()[0]: json.loads(ln.split(" ", 1)[1])
+            for ln in out.splitlines()
+            if ln.startswith(("window {", "cycles {", "bodies {"))}
+    window, cycles = said["window"], said["cycles"]
+    assert len(cycles["s"]) == window["cycles"] >= 3
+    assert sum(cycles["s"]) == pytest.approx(window["window_s"], abs=1e-3)
+    for i, s in enumerate(cycles["s"]):
+        steps = cycles["insert"][i] + cycles["search"][i] + cycles["delete"][i]
+        assert steps == pytest.approx(s, abs=1e-4)
+        assert 0 <= cycles["empty"][i] <= s and cycles["turn"][i] >= 0
+    per_cycle = 1024 // 256 + 2 * (256 // 128)  # the cut mix's requests
+    assert window["attempted"] == per_cycle * window["cycles"]
+    assert window["answers_checked"] == 4 * window["cycles"]
+    assert f"checked {4 * window['cycles']} answers of" in out
+    assert said["bodies"]["cycles"] == 30  # all of the book's, up front
 
 
 def test_stream_cell_traced_line(copy):
@@ -108,12 +125,15 @@ def test_stream_cell_traced_line(copy):
     bench = json.load(open(os.path.join(copy, "BENCHMARK.json")))
     allowed = {m["name"] for m in bench["per_layer"]
                if CELL in m["workloads"]}
-    assert allowed == {"device_idle_pct.tput", "tile_roofline",
+    # at least these: a later PR may add a metric to the cell's list
+    assert allowed >= {"device_idle_pct.stream", "stream_tile_roofline",
                        "write_ms_per_krow", "write_lock_wait_ms",
-                       "write_step_share_pct", "mutate_scatter_roofline"}
+                       "write_step_share_pct", "mutate_scatter_roofline",
+                       "cycle_stall_pct", "cycle_median_ms"}
     # no device trace on the CPU: the host's and the program's own remain
-    assert {"write_ms_per_krow", "write_lock_wait_ms",
-            "write_step_share_pct"} <= set(last["metrics"]) <= allowed
+    assert {"write_ms_per_krow", "write_lock_wait_ms", "cycle_stall_pct",
+            "cycle_median_ms", "write_step_share_pct"} <= set(
+        last["metrics"]) <= allowed
     assert last["correct"] is True, out[-3000:]
 
 
@@ -159,6 +179,183 @@ def test_probes_far_from_every_touched_range_are_not_correct(copy):
     assert seen["recall_at_k"] and seen["deleted_id_returned"]
     assert not seen["probe_touched_by_inserts_share"]
     assert not seen["probe_touched_by_deletes_share"]
+
+
+# ---- the walker against a stub server: what it does inside the window ------
+
+
+class Stub:
+    """A server that acknowledges every write and answers every query
+    with ids ``600 + row * k + j`` (the upper half of a cluster's base ids
+    is out of every delete's reach), or what ``answer`` makes of that."""
+
+    def __init__(self, dim, k, answer=None):
+        import http.server
+        import threading
+
+        stub = self
+        self.dim, self.k, self.answer, self.queries = dim, k, answer, 0
+
+        class Handler(http.server.BaseHTTPRequestHandler):
+            protocol_version = "HTTP/1.1"
+
+            def log_message(self, *a):
+                pass
+
+            def do_POST(self):
+                body = self.rfile.read(int(self.headers["Content-Length"]))
+                if self.path == "/upsert":
+                    doc = {"upserted": len(body) // (4 + 4 * stub.dim)}
+                elif self.path == "/delete":
+                    doc = {"deleted": len(body) // 4}
+                else:
+                    rows = len(body) // (4 * stub.dim)
+                    ids = np.arange(rows * stub.k).reshape(rows, stub.k)
+                    doc = {"ids": (600 + ids).tolist(),
+                           "dists": (ids % stub.k + 1.0).tolist()}
+                    stub.queries += 1
+                    if stub.answer:
+                        doc = stub.answer(stub.queries, doc)
+                data = json.dumps(doc).encode()
+                self.send_response(200)
+                self.send_header("Content-Length", str(len(data)))
+                self.end_headers()
+                self.wfile.write(data)
+
+        self.server = http.server.ThreadingHTTPServer(("127.0.0.1", 0),
+                                                      Handler)
+        self.server.daemon_threads = True
+        threading.Thread(target=self.server.serve_forever,
+                         daemon=True).start()
+        self.url = "http://127.0.0.1:%d" % self.server.server_address[1]
+
+    def stop(self):
+        self.server.shutdown()
+        self.server.server_close()
+
+
+def stub_walk(answer=None, cycles=4, in_flight=2):
+    """A walk of ``cycles`` window cycles (one warm before) against the
+    stub; returns (driver module, walk, book, mix, what happened between
+    the window's first request and its last answer)."""
+    import threading
+
+    drv = load_by_path("drivers", "serve_stream")
+    gen = load_by_path("datagen", "clustered_f32_stream")
+    config = {"rows": 4096, "dim": 100, "k": 10,
+              "data": {"clusters": 4, "block_rows": 64, "cluster_sigma": 0.25,
+                       "sub_sigma": 0.15, "sigma": 0.08}}
+    mix = {"range_rows": 128, "max_cycles": 12, "warm_cycles": 1,
+           "checkpoints": [1, 3], "query_pool_rows": 64, "probe_rows": 16,
+           "write_rows_per_request": 64, "rows_per_request": 16,
+           "search_in_flight": in_flight}
+    book = runbook.plan(config, mix, 3)
+    subs = gen.sub_centres(3, config["data"], 100, book["cluster_of_block"])
+    pool = gen.query_rows(3, config["data"],
+                          runbook.pool_targets(book, mix, 3), subs)
+    seen = {"bodies": 0, "threads": [], "window": False}
+    made, started = gen.host_block, threading.Thread.start
+
+    def host_block(*a, **kw):
+        seen["bodies"] += seen["window"]
+        return made(*a, **kw)
+
+    def start(thread):
+        if seen["window"]:
+            seen["threads"].append(thread.name)
+        return started(thread)
+
+    gen.host_block = host_block
+    bodies = drv.write_bodies(config, mix, book, subs, gen, 3)
+    stub = Stub(100, 10, answer)
+    threading.Thread.start = start
+    try:
+        walk = drv.Walk(stub.url, mix, book, bodies, pool, 10.0)
+        t = walk.cycle(0, 0, False, 0.0)
+        seen["window"] = True
+        for w in range(1, cycles + 1):
+            t = walk.cycle(w, w, True, t)
+        seen["window"] = False
+        walk.close()
+    finally:
+        threading.Thread.start = started
+        stub.stop()
+    return drv, walk, book, mix, seen
+
+
+def test_nothing_is_made_and_no_thread_started_inside_the_window():
+    drv, walk, book, mix, seen = stub_walk()
+    assert seen["bodies"] == 0 and seen["threads"] == []
+    assert len(walk.cycles) == 4 and len(walk.answers) == 4 * 4
+    assert len(walk.requests) == 4 * (2 + 2) and all(
+        ok for _, ok, _ in walk.requests)
+    # an answer is kept as the bytes that came: nothing parsed on the way
+    assert all(isinstance(a[4], bytes) and a[3] == 200 for a in walk.answers)
+    # the deletions acknowledged before a step's first request: the cycles
+    # before it, the warm one among them
+    assert sorted({(w, gone) for w, gone, *_ in walk.answers}) == [
+        (1, 1), (2, 2), (3, 3), (4, 4)]
+
+
+def test_every_answer_of_the_window_reaches_the_checker():
+    drv, walk, book, mix, _ = stub_walk()
+    checked = drv.check_answers(walk.answers, book, mix, 10, probe_lo=16)
+    assert len(checked["requests"]) == len(walk.answers) == 16
+    assert all(ok for _, ok, _ in checked["requests"])
+    assert checked["deleted_returned"] == 0
+    assert sorted(checked["probe"]) == [1, 3]  # the mix's checkpoints
+    ids, dists = checked["probe"][3]
+    assert ids.shape == dists.shape == (16, 10)
+
+
+@pytest.mark.parametrize("fault, number", [
+    ("deleted_id_in_the_last_answer", "deleted_returned"),
+    ("an_answer_not_ascending", "requests"),
+    ("an_answer_cut_short", "requests"),
+])
+def test_a_wrong_answer_anywhere_in_the_window_is_caught(fault, number):
+    """Planted where the answer is produced, in the window's LAST search
+    step and off the probe block: the check put off to the window's end
+    still sees every answer, each against the deletes acknowledged before
+    its own step."""
+    def answer(n, doc):
+        if n == 4 + 16:  # the warm cycle's four, then the window's last
+            if fault == "deleted_id_in_the_last_answer":
+                doc["ids"][5][9] = gone_id[0]
+            elif fault == "an_answer_not_ascending":
+                doc["dists"][3][2] = 0.5
+            else:
+                doc["ids"] = doc["ids"][:-1]
+        return doc
+
+    config, mix = {"rows": 4096, "data": {"clusters": 4, "block_rows": 64}}, {
+        "range_rows": 128, "max_cycles": 12}
+    # an id of the range window cycle 3 deletes: live until then
+    gone_id = [runbook.plan(config, mix, 3)["cycles"][3]["delete"][0] + 7]
+    drv, walk, book, mix, _ = stub_walk(answer)
+    checked = drv.check_answers(walk.answers, book, mix, 10, probe_lo=16)
+    if number == "deleted_returned":
+        assert checked["deleted_returned"] == 1
+        # the same id in an answer BEFORE its delete was acknowledged is
+        # no fault: the check follows each step's own acknowledgements
+        early = [(w, min(gone, 3), lo, st, data)
+                 for w, gone, lo, st, data in walk.answers]
+        assert drv.check_answers(early, book, mix, 10, 16)[
+            "deleted_returned"] == 0
+    else:
+        assert [ok for _, ok, _ in checked["requests"]].count(False) == 1
+
+
+def test_cycle_seconds_partition_a_cycle():
+    drv = load_by_path("drivers", "serve_stream")
+    spans = [[(0.0, 1.0), (1.1, 2.0)],  # the writer: a turn-round of 0.1
+             [(2.2, 3.0), (3.05, 4.0)], [(2.2, 3.5)],  # two readers
+             [(4.2, 5.0)]]
+    c = drv.cycle_seconds(0.0, 5.0, {"insert": 2.0, "search": 2.1,
+                                     "delete": 0.9}, spans)
+    assert c["s"] == 5.0 and c["turn"] == pytest.approx(0.1 + 0.05)
+    # no request in flight: 1.0-1.1, 2.0-2.2, 4.0-4.2
+    assert c["empty"] == pytest.approx(0.5)
 
 
 # ---- the runbook's arithmetic ---------------------------------------------

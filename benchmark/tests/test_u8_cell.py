@@ -122,7 +122,8 @@ def test_u8_cell_traced_line(copy):
     bench = json.load(open(os.path.join(copy, "BENCHMARK.json")))
     allowed = {m["name"] for m in bench["per_layer"]
                if CELL in m["workloads"]}
-    assert allowed == {
+    # at least these: a later PR may add a metric to the cell's list
+    assert allowed >= {
         "device_idle_pct.tput", "server_empty_pct", "dispatch_lag_ms.tput",
         "request_edge_ms.tput", "u8_scan_roofline", "u8_scan_us_per_step",
         "u8_rest_bytes_per_row"}
