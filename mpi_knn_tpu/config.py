@@ -312,6 +312,14 @@ class KNNConfig:
     # never reads it, and it is no part of such an index's executable
     # fingerprint (serve/aotcache.py).
     max_query_tags: int = 2
+    # RANGE SEARCH (backends/range_scan.py): 0 — off, an index answers
+    # k-NN alone. N > 0 — the dense ``serial`` index under L2 over
+    # whole-number rows also answers requests that name a radius with
+    # EVERY live row at a squared distance strictly under it, and N is the
+    # most one query row may be answered with: a row with more is refused
+    # by name with its true count, never cut. A request without a radius
+    # is answered with its k nearest, as ever.
+    range_cap: int = 0
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
@@ -394,6 +402,8 @@ class KNNConfig:
             )
         if self.dtype == "uint8":
             self._refuse_under_uint8()
+        if self.range_cap:
+            self._refuse_under_range()
         if self.precision_policy == "mixed":
             if self.dtype not in ("float32", "int8", "int4"):
                 raise ValueError(
@@ -584,6 +594,60 @@ class KNNConfig:
                 "bucket_headroom=0"
             )
 
+    def _refuse_under_range(self):
+        """What range search (``range_cap`` > 0) does not run on yet, each
+        with its reason and what to pass instead (ROADMAP Reach keeps the
+        list by mechanism). What only the DATA can say is refused where the
+        data is seen: fractional float32 rows and a width whose sums could
+        pass 2^24 at the build (``serve/index.py refuse_range_build``),
+        a predicate there too, every write in ``serve/mutate.py
+        RANGE_FROZEN``."""
+        if self.range_cap < 0:
+            raise ValueError(
+                f"range_cap must be >= 0, got {self.range_cap}")
+        if self.metric != "l2":
+            raise ValueError(
+                f"range search requires metric='l2', got {self.metric!r}: a "
+                "radius is a squared L2 distance; cosine's 1 - similarity "
+                "and an inner product's negated score live on other scales "
+                "and have no reference, radius or measurement here — leave "
+                "range_cap at 0 under them"
+            )
+        if self.backend in ("ring", "ring-overlap"):
+            raise ValueError(
+                f"range search does not run on backend={self.backend!r}: a "
+                "ring round merges a fixed k a row into a carried (rows, k) "
+                "pair and has no form that hands on lists of no fixed "
+                "length — use backend='serial'"
+            )
+        if self.partitions is not None:
+            raise ValueError(
+                "range search runs over the DENSE index: a clustered (IVF) "
+                "store's probe visits nprobe lists and would miss rows "
+                "within the radius in the others, which 'every row' "
+                "forbids — leave partitions unset"
+            )
+        if self.precision_policy != "exact":
+            raise ValueError(
+                "range search requires precision_policy='exact': the "
+                "compress pass of 'mixed' ranks by a rounded key and a row "
+                "near the radius needs the exact value"
+            )
+        if self.dtype not in ("uint8", "float32"):
+            raise ValueError(
+                f"range search requires dtype='uint8' or 'float32' over "
+                f"whole-number rows, got {self.dtype!r}: a bfloat16 or "
+                "quantised stack rounds its rows, and a row near the "
+                "radius needs the exact value"
+            )
+        if self.bucket_headroom:
+            raise ValueError(
+                "an index that answers range search is frozen "
+                "(serve/mutate.py RANGE_FROZEN): bucket_headroom="
+                f"{self.bucket_headroom} reserves slots for upserts it "
+                "would refuse — build with bucket_headroom=0"
+            )
+
     @property
     def compute_dtype(self) -> str:
         """The dtype query rows come in and tile steps compute in: ``dtype``
@@ -593,3 +657,18 @@ class KNNConfig:
 
     def replace(self, **kw) -> "KNNConfig":
         return dataclasses.replace(self, **kw)
+
+
+class RangeCapError(ValueError):
+    """Query rows whose results pass ``range_cap``: ``rows`` is ``[(row,
+    true count), ...]``. Refused by name, never cut."""
+
+    def __init__(self, rows, cap: int):
+        self.rows, self.cap = list(rows), int(cap)
+        shown = ", ".join(f"row {r}: {n}" for r, n in self.rows[:8])
+        more = "" if len(self.rows) <= 8 else (
+            f" and {len(self.rows) - 8} more")
+        super().__init__(
+            f"{len(self.rows)} query row(s) have more than range_cap="
+            f"{cap} results within the radius ({shown}{more}); nothing is "
+            "cut: narrow the radius, or serve with a larger --range-cap")

@@ -104,6 +104,20 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    "scatters fill without a recompile. 0.0 (default) = "
                    "zero-rent frozen corpus; 0.25-0.5 for mutable ones "
                    "(headroom rows ride the fixed-shape FLOPs)")
+    d.add_argument("--range-cap", type=int, default=0,
+                   help="answer RANGE search too (0: k-NN alone): POST "
+                   "/query with a radius (JSON `radius`, or the header "
+                   "X-Radius beside a raw body; one squared L2 distance a "
+                   "request) is answered with EVERY corpus row strictly "
+                   "under it for each query row, in the big-ann suite's "
+                   "range format (`lims` of rows + 1 offsets, flat `dists` "
+                   "and `ids`); this is the most one query row may be "
+                   "answered with — a row with more fails its request "
+                   "with 422 naming the row and its true count, never "
+                   "cut. The dense serial index under L2 over whole-"
+                   "number rows (--dtype uint8, or float32 pixels), at "
+                   "most 256 wide, frozen (no /upsert, /delete); /query "
+                   "without a radius answers top-k as ever")
     d.add_argument("--tags", default=None, metavar="NPZ",
                    help="a bag of tag ids a corpus row, as a CSR in an "
                    ".npz (arrays `indptr` of rows + 1 offsets and "
@@ -269,6 +283,7 @@ def serve_main(argv=None) -> int:
             compact_fill_threshold=args.compact_fill_threshold,
             compact_tombstone_fraction=args.compact_tombstone_fraction,
             max_query_tags=args.max_query_tags,
+            range_cap=args.range_cap,
         )
         policy = SLOPolicy(
             max_batch_rows=args.max_batch_rows or args.bucket,

@@ -72,6 +72,10 @@ class FrontendRequest:
     # tag ids for the server, against an index built with tags); None:
     # the request has none. It rides with the rows through ``slices``.
     filters: object = None
+    # a RANGE request's squared radius (one number a request); None: a
+    # k-NN request. Requests of the two kinds never share a batch; range
+    # requests of different radii may (the bound is a row's).
+    radius: float | None = None
 
     def wait_s(self, now: float) -> float:
         return now - self.arrival_s
@@ -105,6 +109,17 @@ class CoalescedBatch:
         """((tenant, rows), ...) per PART in row order — the exact
         ``tenants=`` argument for ``ServeSession.submit``."""
         return tuple((r.tenant, r.rows) for r in self.parts)
+
+    @property
+    def radii(self):
+        """(rows,) float32, every row's squared radius, of a batch of
+        range requests; None of a k-NN batch (a batch is of one kind)."""
+        if self.parts[0].radius is None:
+            return None
+        import numpy as np
+
+        return np.concatenate([
+            np.full(r.rows, r.radius, np.float32) for r in self.parts])
 
     def slices(self):
         """Yield (request, start, stop) row slices into the stacked
@@ -140,7 +155,7 @@ class Coalescer:
     # -- admission --------------------------------------------------------
 
     def admit(self, tenant: str, queries, rows: int,
-              now: float, filters=None) -> FrontendRequest:
+              now: float, filters=None, radius=None) -> FrontendRequest:
         """Enqueue one request (admission control — depth/rate — is the
         scheduler's job and has already happened). Oversized and empty
         requests are caller bugs here and raise."""
@@ -156,6 +171,7 @@ class Coalescer:
         req = FrontendRequest(
             tenant=str(tenant), queries=queries, rows=rows,
             arrival_s=now, seq=next(self._seq), filters=filters,
+            radius=radius,
         )
         self._queues.setdefault(req.tenant, deque()).append(req)
         self._pending_rows += rows
@@ -240,6 +256,12 @@ class Coalescer:
                 if not q:
                     continue
                 head = q[0]
+                if (head.radius is None) != (oldest.radius is None):
+                    # a request of the other KIND (range beside k-NN:
+                    # another program) waits for a batch of its own; its
+                    # tenant's turn passes, nobody is overtaken within a
+                    # tenant, and the oldest of what waits heads the next
+                    continue
                 if rows + head.rows > self.max_batch_rows:
                     # first misfit closes the batch: skipping ahead to
                     # smaller requests would reorder service within the

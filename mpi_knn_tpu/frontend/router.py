@@ -81,6 +81,9 @@ from mpi_knn_tpu.obs import spans as obs_spans
 
 SEQ_HEADER = "X-Mutation-Seq"
 TENANT_HEADER = "X-Tenant"
+# a replica's /query headers that change what its raw body means
+# (frontend/server.py FILTER_HEADER, RADIUS_HEADER)
+QUERY_HEADERS = ("X-Filter-Tags", "X-Radius")
 DEFAULT_TENANT = "default"
 
 # membership states
@@ -712,7 +715,7 @@ class Router:
             self._inflight[name] = max(0, self._inflight.get(name, 0) - 1)
 
     def forward_query(self, tenant: str, body: bytes,
-                      ctype: str) -> tuple:
+                      ctype: str, passed: dict | None = None) -> tuple:
         """(status, headers, body) — proxy one query to the chosen
         replica; on a TRANSPORT failure (never an HTTP status) retry
         once on a different replica: queries are idempotent, and the
@@ -736,7 +739,8 @@ class Router:
             try:
                 status, headers, data = self._proxy(
                     name, url, "/query", body,
-                    {"Content-Type": ctype, TENANT_HEADER: tenant},
+                    {"Content-Type": ctype, TENANT_HEADER: tenant,
+                     **(passed or {})},
                     timeout_s=self.policy.request_timeout_s,
                 )
             except (OSError, http.client.HTTPException, ValueError,
@@ -988,8 +992,12 @@ def _router_handler(router: Router, quiet: bool = True):
                     self.headers.get("Content-Type")
                     or "application/octet-stream"
                 )
+                # what says how a raw body is to be read goes with it: a
+                # dropped radius would come back as a k-NN answer
+                passed = {h: self.headers[h] for h in QUERY_HEADERS
+                          if h in self.headers}
                 status, headers, data = router.forward_query(
-                    tenant, body, ctype
+                    tenant, body, ctype, passed
                 )
                 self._send(status, headers, data)
             elif self.path in ("/upsert", "/delete"):

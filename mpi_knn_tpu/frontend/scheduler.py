@@ -219,7 +219,7 @@ class FrontendScheduler:
         return None
 
     def submit(self, tenant: str, queries, rows: int, now: float,
-               filters=None):
+               filters=None, radius=None):
         """Admit one request or refuse it: returns a
         :class:`~mpi_knn_tpu.frontend.coalesce.FrontendRequest` (admitted
         — it WILL be served) or a :class:`Rejection`. Decisions are
@@ -249,7 +249,8 @@ class FrontendScheduler:
         rej = self._take_token(tenant, now)
         if rej is not None:
             return rej
-        req = self.coalescer.admit(tenant, queries, rows, now, filters)
+        req = self.coalescer.admit(tenant, queries, rows, now, filters,
+                                   radius)
         self.admitted += 1
         return req
 

@@ -43,6 +43,7 @@ from mpi_knn_tpu.frontend.scheduler import (
     Rejection,
     SLOPolicy,
 )
+from mpi_knn_tpu.config import RangeCapError
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
 
@@ -59,7 +60,7 @@ class Ticket:
     ``result``; the pump fulfills (or fails) it at retire."""
 
     __slots__ = ("request", "_clock", "_event", "_dists", "_ids", "_error",
-                 "done_s")
+                 "done_s", "_lims")
 
     def __init__(self, request, clock=time.monotonic):
         self.request = request
@@ -67,13 +68,14 @@ class Ticket:
         self._event = threading.Event()
         self._dists = None
         self._ids = None
+        self._lims = None  # a range request's offsets into the flat answer
         self._error = None
         # the front end's clock at fulfill: the one ``arrival_s`` is on
         # (time.monotonic unless injected: loadgen's clock)
         self.done_s = None
 
-    def _fulfill(self, dists, ids) -> None:
-        self._dists, self._ids = dists, ids
+    def _fulfill(self, dists, ids, lims=None) -> None:
+        self._dists, self._ids, self._lims = dists, ids, lims
         self.done_s = self._clock()
         self._event.set()
 
@@ -86,9 +88,12 @@ class Ticket:
         return self._event.is_set()
 
     def result(self, timeout: float | None = None):
-        """(dists, ids) for this request's rows — blocks until the
-        coalesced batch carrying it retires. Raises the serving error on
-        failure, TimeoutError on timeout."""
+        """(dists, ids) for this request's rows — of a RANGE request
+        (lims, dists, ids): row i's results are ``dists[lims[i]:lims[i +
+        1]]`` — blocks until the coalesced batch carrying it retires.
+        Raises the serving error on failure (``RangeCapError``: a row of
+        the request has more results than the cap), TimeoutError on
+        timeout."""
         if not self._event.wait(timeout):
             raise TimeoutError(
                 f"request seq={self.request.seq} not served within "
@@ -96,6 +101,8 @@ class Ticket:
             )
         if self._error is not None:
             raise self._error
+        if self._lims is not None:
+            return self._lims, self._dists, self._ids
         return self._dists, self._ids
 
 
@@ -267,17 +274,35 @@ class Frontend:
 
     # -- client side ------------------------------------------------------
 
-    def submit(self, tenant: str, queries, filters=None):
+    def submit(self, tenant: str, queries, filters=None, radius=None):
         """Admit one request (non-blocking): a :class:`Ticket` to wait
         on, or the scheduler's structured :class:`Rejection`. ``filters``:
         a predicate a row, (rows, tags) tag ids with -1 for none, against
         an index built with tags; one the index cannot honour raises
-        ``ValueError`` (the HTTP layer's 400), it is never ignored."""
+        ``ValueError`` (the HTTP layer's 400), it is never ignored.
+        ``radius``: a squared L2 radius — the request is a RANGE request
+        (every live row strictly under it, for every query row; an index
+        built with ``range_cap``), refused the same way where the index or
+        the rows cannot honour it."""
         queries = np.ascontiguousarray(queries, dtype=np.float32)
         if queries.ndim != 2:
             raise ValueError(
                 f"queries must be (rows, dim), got shape {queries.shape}"
             )
+        if radius is not None:
+            from mpi_knn_tpu.backends.range_scan import require_byte_rows
+            from mpi_knn_tpu.serve.engine import require_range
+
+            radius = float(radius)
+            if not (np.isfinite(radius) and radius > 0):
+                raise ValueError(
+                    f"radius must be a positive finite squared distance, "
+                    f"got {radius!r}")
+            if filters is not None:
+                raise ValueError("range search takes no predicate yet: "
+                                 "send the radius or the filters")
+            require_range(self.session.index, self.session.cfg)
+            require_byte_rows(queries)
         if filters is not None or getattr(
                 self.session.index, "tags", None) is not None:
             from mpi_knn_tpu.serve.engine import check_filters, plan_filters
@@ -320,7 +345,8 @@ class Frontend:
                     status=503,
                 )
             out = self.scheduler.submit(
-                tenant, queries, queries.shape[0], self._clock(), filters
+                tenant, queries, queries.shape[0], self._clock(), filters,
+                radius,
             )
             if isinstance(out, Rejection):
                 return out
@@ -635,12 +661,15 @@ class Frontend:
             with self.session.phase("plan", requests=len(batch.parts)):
                 filters = merge_plans([r.filters for r in batch.parts])
         for res in self.session.submit(q, tenants=batch.composition(),
-                                       filters=filters):
+                                       filters=filters, radii=batch.radii):
             self._scatter(res)
 
     def _scatter(self, res) -> None:
         batch = self._dispatched.pop(0)
         phase = self.session.phase
+        if res.range_out is not None:
+            self._scatter_range(batch, res)
+            return
         with phase("d2h", seq=res.seq, parent=res.span):
             # padding stripped; a session with the NaN sentinel on has
             # fetched dists at retire already (its own d2h span)
@@ -652,6 +681,32 @@ class Frontend:
                     t = self._tickets.pop(req.seq, None)
                     if t is not None:
                         t._fulfill(dists[start:stop], ids[start:stop])
+
+    def _scatter_range(self, batch, res) -> None:
+        """A range batch back to its requests BY OFFSETS: a request's rows
+        are a run of the batch's, so its results are one run of the flat
+        answer and its ``lims`` the batch's less their first. A request
+        that holds a row over the cap fails whole, by name
+        (``RangeCapError``: the rows as the request numbers them, each
+        with its true count); its neighbours in the batch are answered."""
+        phase = self.session.phase
+        with phase("d2h", seq=res.seq, parent=res.span):
+            lims, dists, ids, refused = res.range_answer  # cached at retire
+        with phase("reply", seq=res.seq, parent=res.span,
+                   requests=len(batch.parts)):
+            with self._lock:
+                for req, start, stop in batch.slices():
+                    t = self._tickets.pop(req.seq, None)
+                    if t is None:
+                        continue
+                    over = [(r - start, n) for r, n in refused
+                            if start <= r < stop]
+                    if over:
+                        t._fail(RangeCapError(over, res.range_cap))
+                        continue
+                    lo, hi = int(lims[start]), int(lims[stop])
+                    t._fulfill(dists[lo:hi], ids[lo:hi],
+                               lims[start:stop + 1] - lo)
 
     def _metrics(self):
         return obs_metrics.get_registry()
@@ -839,6 +894,10 @@ SEQ_HEADER = "X-Mutation-Seq"
 # int32 tag ids a row (-1: none) follow the rows in the body; without it
 # the body is rows alone, as it always was
 FILTER_HEADER = "X-Filter-Tags"
+# /query's raw form as a RANGE request: the squared L2 radius, one number a
+# request (the JSON form's key "radius"); without it the request is a k-NN
+# request, as it always was
+RADIUS_HEADER = "X-Radius"
 
 
 def raw_rows(raw: bytes, dim: int, ids: bool, tags: int = 0):
@@ -936,22 +995,27 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             return self.rfile.read(n), ctype == "application/octet-stream"
 
         def _read_queries(self):
-            """``((rows, dim) f32, filters-or-None)`` from the request
-            body: JSON ``{"queries": [[...], ...], "filters": [[t], [t,
-            u], []]}`` or raw little-endian f32 rows at the index dim
-            (``application/octet-stream``), followed, where the header
-            ``X-Filter-Tags: W`` says so, by W int32 tag ids a row (-1:
-            none)."""
+            """``((rows, dim) f32, filters-or-None, radius-or-None)`` from
+            the request body: JSON ``{"queries": [[...], ...], "filters":
+            [[t], [t, u], []], "radius": r}`` or raw little-endian f32
+            rows at the index dim (``application/octet-stream``),
+            followed, where the header ``X-Filter-Tags: W`` says so, by W
+            int32 tag ids a row (-1: none); the raw form names its radius
+            in the header ``X-Radius``."""
             raw, is_raw = self._read_body()
             dim = frontend.session.index.dim
             if is_raw:
+                radius = self.headers.get(RADIUS_HEADER)
+                if radius is not None:
+                    radius = float(radius)  # ValueError: a 400
                 width = self.headers.get(FILTER_HEADER)
                 if width is None:
-                    return raw_rows(raw, dim, ids=False)[1], None
+                    return raw_rows(raw, dim, ids=False)[1], None, radius
                 if not width.isdigit() or not 0 < int(width) <= 64:
                     raise ValueError(
                         f"{FILTER_HEADER}: {width!r} is not a tag count")
-                return raw_rows(raw, dim, ids=False, tags=int(width))[1:]
+                return (*raw_rows(raw, dim, ids=False, tags=int(width))[1:],
+                        radius)
             doc = json.loads(raw)
             q = np.asarray(doc["queries"], dtype=np.float32)
             if q.ndim != 2 or q.shape[1] != dim:
@@ -959,7 +1023,12 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                     f"queries shape {q.shape} does not match index "
                     f"dim {dim}"
                 )
-            return q, json_filters(doc, q.shape[0])
+            radius = doc.get("radius")
+            if radius is not None and (
+                    isinstance(radius, bool)
+                    or not isinstance(radius, (int, float))):
+                raise ValueError("radius is one number, a squared distance")
+            return q, json_filters(doc, q.shape[0]), radius
 
         def _reject(self, out: Rejection, phases: _Phases) -> None:
             phases.next("encode")
@@ -1114,9 +1183,9 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             """What the request's span learns on the way (the admitted
             request's ``seq`` joins it to its batch, and the status)."""
             try:
-                q, filters = self._read_queries()
+                q, filters, radius = self._read_queries()
                 phases.next("admit")
-                out = frontend.submit(tenant, q, filters)
+                out = frontend.submit(tenant, q, filters, radius)
             except (ValueError, KeyError, TypeError) as e:
                 phases.next("encode")
                 self._json(400, {"error": str(e)}, phases)
@@ -1130,11 +1199,17 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
             # admitted when the scheduler stamped it: queue wait, batch
             # and reply from there on are the ``await``
             phases.next("await", at=request.arrival_s)
-            status, doc = 200, None
+            status, doc, lims = 200, None, None
             try:
-                dists, ids = out.result(timeout=request_timeout_s)
+                *lims, dists, ids = out.result(timeout=request_timeout_s)
             except TimeoutError as e:
                 status, doc = 504, {"error": str(e)}
+            except RangeCapError as e:
+                # refused by name, never cut: the rows (as the request
+                # numbers them) with their true counts
+                status, doc = 422, {
+                    "error": "range-cap", "detail": str(e), "cap": e.cap,
+                    "rows": [[r, n] for r, n in e.rows]}
             except Exception as e:  # serving error (sentinel, …)
                 status, doc = 500, {"error": f"{type(e).__name__}: {e}"}
             done_s = out.done_s  # None: timed out unfulfilled
@@ -1147,7 +1222,21 @@ def _http_handler(frontend: Frontend, request_timeout_s: float,
                 phases.next("wake", at=done_s,
                             us=int((woke - done_s) * 1e6))
             phases.next("encode", at=woke)
-            if doc is None:
+            if doc is None and lims:
+                # the suite's range format: rows + 1 offsets and flat
+                # lists; ``tolist`` walks the arrays once, so the encode's
+                # cost follows the answer's length, not rows x anything
+                seen["results"] = int(ids.shape[0])
+                doc = {
+                    "rows": request.rows,
+                    "metric": frontend.session.cfg.metric,
+                    "radius": request.radius,
+                    "lims": lims[0].tolist(),
+                    "dists": dists.astype(np.float64).tolist(),
+                    "ids": ids.tolist(),
+                }
+                phases.attrs = {**phases.attrs, "results": seen["results"]}
+            elif doc is None:
                 doc = {
                     "rows": int(ids.shape[0]),
                     # what ``dists`` holds, ascending: squared L2 ("l2"),

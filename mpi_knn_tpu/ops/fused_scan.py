@@ -102,7 +102,8 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                        depth: int, groups: int, exclude_self: bool,
                        exclude_zero: bool, zero_eps: float, blocked: bool,
                        rows_minor: bool, widened: bool = False,
-                       passes: int = 1, slots: bool = False):
+                       passes: int = 1, slots: bool = False,
+                       ranged: bool = False):
     """A grid step of :func:`fused_scan`: tile t against a block of query
     rows — the whole query tile on the grid (tiles,); one of its row
     blocks where ``blocked``, the grid's leading axis, the block's lists,
@@ -129,18 +130,36 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     is to be inserted. (2) The marked chunks alone (one in twelve, later
     in a scan) get their distances made whole in place — the clamp, the
     zero and self masks — and are tested again, exactly. (3) The network
-    over the chunks that passed, as *bins* runs it."""
+    over the chunks that passed, as *bins* runs it.
+
+    ``ranged`` (range search, ``backends/range_scan.py``): one operand
+    more, ``rad_ref`` (q, 128), in every lane of a row the largest value
+    UNDER the row's radius. The bound starts there and is never taken
+    anew, so (2) tests every value against the radius itself; what passes
+    is inserted as ever — a lane keeps its ``depth`` smallest, so a lane
+    that more than ``depth`` values pass loses the largest — and COUNTED:
+    the exact test's answers are added up a row and a tile (``tc_ref`` (q,
+    128), a tile a lane) and leave by a copy every 128 tiles and at the
+    last (``tc_out`` (T / 128, q, 128)), so the caller knows of every row
+    how many values passed in each tile, whatever the lists kept."""
     lax, i32 = jax.lax, jnp.int32
     # a byte stack's kernel (``widened``) has one operand more, the (1, d)
     # offset its bytes are centred by; a float32 stack's has none
-    mu_ref = refs[0] if widened else None
-    (kd_out, ki_out, n_ref, kd_ref, ki_ref, b_ref, d_ref, bits_ref,
+    refs = list(refs)
+    mu_ref = refs.pop(0) if widened else None
+    rad_ref = refs.pop(0) if ranged else None
+    kd_out, ki_out, n_ref = refs[:3]
+    del refs[:3]
+    tc_out = refs.pop(0) if ranged else None
+    (kd_ref, ki_ref, b_ref, d_ref, bits_ref,
      idrow_ref, ycol_ref, work_ref, hit_ref, word_ref, cnt_ref,
-     sem, *more) = refs[1:] if widened else refs
+     sem, *more) = refs
     # the three-pass form's scratch: a piece's bf16 pieces side by side;
     # where the lists keep slots, the tile's slot numbers
     cat_ref = more.pop(0) if passes == 3 else None
     slot_ref = more.pop(0) if slots else None
+    # the ranged form's: the rows' counts by tile, a strip's by lane
+    tc_ref, c16_ref = more if ranged else (None, None)
     q, c_tile = d_ref.shape
     strips = q // _STRIP
     piece = groups * _LANES
@@ -175,7 +194,11 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         def _():
             kd_ref[...] = lax.full(kd_ref.shape, _INF, f32)
             ki_ref[...] = lax.full(ki_ref.shape, INVALID_ID, i32)
-            b_ref[...] = lax.full(b_ref.shape, _INF, f32)
+            if ranged:
+                b_ref[...] = rad_ref[...]
+                tc_ref[...] = lax.full(tc_ref.shape, 0, i32)
+            else:
+                b_ref[...] = lax.full(b_ref.shape, _INF, f32)
             if blocked:  # one count for the blocks together
                 @pl.when(lax.eq(b, i32(0)))
                 def _():
@@ -183,7 +206,9 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
             else:
                 cnt_ref[0] = i32(0)
 
-        @pl.when(lax.ne(due_ref[t], i32(0)))
+        # (a radius is not taken anew: the ranged form holds no such pass)
+        @(pl.when(lax.ne(due_ref[t], i32(0))) if not ranged
+          else (lambda body: None))
         def _():
             # lane_bin_bound's value, from the lists where they are: the
             # k-th smallest of a row's lane minima, by k passes of row-min
@@ -334,6 +359,8 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         def _():
             bound = b_ref[r, :]
             bits_ref[r, :] = lax.full(strip_shape, 0, i32)
+            if ranged:
+                c16_ref[...] = lax.full(strip_shape, 0, i32)
 
             def chunk_of(chunk, carry):
                 chunk = _as_i32(chunk)
@@ -350,14 +377,41 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                         return lax.select(lax.le(value(r, g), bound), bit,
                                           bits)
 
-                    bits_ref[r, :] = lax.bitwise_or(
-                        bits_ref[r, :], lax.fori_loop(
-                            0, groups, group,
-                            lax.full(strip_shape, 0, i32), unroll=True))
+                    def counted(u, bits_and_count):
+                        # ``group``, and the values that passed added up
+                        # lane by lane
+                        bits, count = bits_and_count
+                        g = lax.add(lax.mul(chunk, i32(groups)), _as_i32(u))
+                        under = lax.le(value(r, g), bound)
+                        return (lax.select(under, bit, bits), lax.add(
+                            count, lax.convert_element_type(under, i32)))
+
+                    if ranged:
+                        bits, count = lax.fori_loop(
+                            0, groups, counted,
+                            (lax.full(strip_shape, 0, i32),
+                             lax.full(strip_shape, 0, i32)), unroll=True)
+                        c16_ref[...] = lax.add(c16_ref[...], count)
+                        bits_ref[r, :] = lax.bitwise_or(bits_ref[r, :], bits)
+                    else:
+                        bits_ref[r, :] = lax.bitwise_or(
+                            bits_ref[r, :], lax.fori_loop(
+                                0, groups, group,
+                                lax.full(strip_shape, 0, i32), unroll=True))
 
                 return carry
 
             lax.fori_loop(0, n_chunks, chunk_of, 0)
+            if ranged:
+                # the strip's rows' counts of this tile, into the tile's
+                # lane of the rows' plane
+                row = lax.broadcast_in_dim(
+                    lax.reduce_sum(c16_ref[...], (1,)), strip_shape, (0,))
+                here = lax.eq(
+                    lax.broadcasted_iota(i32, strip_shape, 1),
+                    lax.broadcast(lax.rem(t, i32(_LANES)), strip_shape))
+                tc_ref[r, :] = lax.add(tc_ref[r, :], lax.select(
+                    here, row, lax.full(strip_shape, 0, i32)))
 
         return carry
 
@@ -371,6 +425,17 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         cnt_ref[0] = lax.add(cnt_ref[0], _insert_hit_chunks(
             slot_ref if slots else idrow_ref, d_ref, kd_ref, ki_ref, hit_ref,
             strips, n_chunks, groups, depth))
+
+        if ranged:
+            @pl.when(lax.bitwise_or(
+                lax.eq(lax.rem(t, i32(_LANES)), i32(_LANES - 1)),
+                last_tile()))
+            def _():
+                copy = pltpu.make_async_copy(
+                    tc_ref, tc_out.at[lax.div(t, i32(_LANES))], sem.at[2])
+                copy.start()
+                copy.wait()
+                tc_ref[...] = lax.full(tc_ref.shape, 0, i32)
 
         @pl.when(last_tile() if leaves is None else leaves)
         def _():
@@ -415,7 +480,8 @@ def rests_rows_minor(d: int) -> bool:
 
 
 def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
-                          itemsize: int = 4, passes: int = 1) -> int:
+                          itemsize: int = 4, passes: int = 1,
+                          ranged: bool = False) -> int:
     """The VMEM :func:`fused_scan` holds for a block of (q, d) query rows
     against (c_tile, d) corpus tiles of ``itemsize`` bytes an element
     (float32, or a byte stack's 1), in bytes: the lists, the
@@ -428,7 +494,9 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
     set from. ``passes`` 3 (the three-pass form, whose lists keep slots):
     a piece's copy is its bf16 pieces side by side, three widths, with
     the float32 piece they are cut from and what the cut left; the query
-    side is three widths too; the tile's slot numbers are one row more."""
+    side is three widths too; the tile's slot numbers are one row more.
+    ``ranged``: the rows' radii in their two buffers and their counts by
+    tile, three (q, 128) planes."""
     piece = chunk_groups(c_tile) * _LANES
     lists = 2 * q * depth * _LANES * 4
     words = _hit_words(q // _STRIP, c_tile // piece) * (_STRIP // 2)
@@ -444,6 +512,8 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
         stack += 2 * piece * d * 4
         planes += c_tile * 4
     work = _row_block(q, _FINISH_ROWS) * _LANES * 4
+    if ranged:
+        work += (3 * q + _STRIP) * _LANES * 4
     return lists + bound_and_bits + dists + stack + query + planes + work
 
 
@@ -451,7 +521,8 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                tiles: jax.Array, tile_ids: jax.Array, tile_sqs: jax.Array,
                due, *, k: int, depth: int, exclude_self: bool,
                exclude_zero: bool, zero_eps: float, block: int,
-               offset: jax.Array | None = None, screen: bool = False):
+               offset: jax.Array | None = None, screen: bool = False,
+               under: jax.Array | None = None):
     """The carried scan of ``backends/serial.py _merge_carried`` over a
     whole stack, in its one-pass branch and under the row bound, as one
     kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
@@ -495,7 +566,16 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     the stack viewed flat (``t * c_tile + column``), not its id, which is
     read for its sign (and ``exclude_self``) alone. Every value is within
     ``backends/serial.py screen_eps(..., fused=True)`` of the six-pass
-    one."""
+    one.
+
+    ``under`` (range search, ``backends/range_scan.py``): (q,) float32,
+    for every row the largest value UNDER its radius. The bound is that
+    from the first tile to the last (``due`` is not read), ``k`` answers
+    for nothing, and a fourth value is returned: (T / 128 rounded up, q,
+    128) int32, for every row the values at or under its bound by tile
+    (tile ``t`` in plane ``t // 128``, lane ``t % 128``) — all of them,
+    whatever the lists kept. The whole query tile a block, a stack on the
+    lane grid."""
     q, d = q_x.shape
     widened = tiles.dtype.itemsize == 1
     if widened != (offset is not None):
@@ -503,6 +583,10 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     if screen and (widened or rests_rows_minor(d)):
         raise ValueError("the three-pass form takes float32 rows on the "
                          "lane grid")
+    ranged = under is not None
+    if ranged and (screen or block != q or rests_rows_minor(d)):
+        raise ValueError("the ranged form takes the whole query tile a "
+                         "block, one pass, a stack on the lane grid")
     passes = 3 if screen else 1
     n_tiles, c_tile, _ = tiles.shape
     groups = chunk_groups(c_tile)
@@ -548,12 +632,16 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
         tile = pl.BlockSpec((1, c_tile, d), index(lambda b, t, j: (t, 0, 0)))
     if blocked:
         grid = (q // block, *grid)
-    kd, ki, n = pl.pallas_call(
+    # (the ranged form's names are given only where it is asked for: a
+    # k-NN program's text does not move)
+    extra = dict(ranged=True) if ranged else {}
+    planes = -(-n_tiles // _LANES)
+    kd, ki, n, *counts = pl.pallas_call(
         functools.partial(
             _fused_scan_kernel, k=k, depth=depth, groups=groups,
             exclude_self=exclude_self, exclude_zero=exclude_zero,
             zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor,
-            widened=widened, passes=passes, slots=screen),
+            widened=widened, passes=passes, slots=screen, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -564,11 +652,13 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 tile, plane, plane,
                 *([pl.BlockSpec((1, d), index(lambda b, t, j: (0, 0)))]
                   if widened else []),
+                *([pl.BlockSpec((block, _LANES), rows)] if ranged else []),
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
+                *([pl.BlockSpec(memory_space=pl.ANY)] if ranged else []),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block, width), f32),
@@ -582,26 +672,34 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 # the words of the tile's (strip, chunk) bits; the count
                 *_hit_scratch(strips, c_tile // piece),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((3 if ranged else 2,)),
                 *([pltpu.VMEM((piece, passes * d), jnp.bfloat16),
                    pltpu.VMEM((1, c_tile), jnp.int32)] if screen else []),
+                *([pltpu.VMEM((block, _LANES), jnp.int32),
+                   pltpu.VMEM((_STRIP, _LANES), jnp.int32)]
+                  if ranged else []),
             ],
         ),
         out_shape=[
             _out((q, width), f32, *operands),
             _out((q, width), jnp.int32, *operands),
             _out((1, 1), jnp.int32, *operands),
+            *([_out((planes, q, _LANES), jnp.int32, *operands)]
+              if ranged else []),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             # the arithmetic above, and room for what Mosaic keeps of its
             # own (a piece's dot as a value, spills)
             vmem_limit_bytes=fused_scan_vmem_bytes(
-                block, c_tile, d, depth, tiles.dtype.itemsize, passes)
+                block, c_tile, d, depth, tiles.dtype.itemsize, passes,
+                **extra)
             + _VMEM_HEADROOM,
         ),
         interpret=_interpret(),
     )(jnp.asarray(due, jnp.int32), qn, xsq, qid, tiles,
       tile_ids.astype(jnp.int32), tile_sqs.astype(f32),
-      *([offset.astype(f32)[None, :]] if widened else []))
-    return kd, ki, n[0, 0]
+      *([offset.astype(f32)[None, :]] if widened else []),
+      *([jnp.broadcast_to(under.astype(f32)[:, None], (q, _LANES))]
+        if ranged else []))
+    return (kd, ki, n[0, 0], *counts)

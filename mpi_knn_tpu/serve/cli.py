@@ -129,6 +129,18 @@ def build_parser() -> argparse.ArgumentParser:
     k.add_argument("--bucket", type=int, default=1024,
                    help="base row bucket: batches pad to bucket*2^j rows "
                    "and each (bucket, config) compiles exactly once")
+    k.add_argument("--range-cap", type=int, default=0,
+                   help="answer RANGE search too (0: k-NN alone): with "
+                   "--radius every query row gets EVERY corpus row at a "
+                   "squared L2 distance strictly under it; this is the "
+                   "most one row may be answered with — a row with more "
+                   "is refused by name, never cut. The dense serial index "
+                   "under L2 over whole-number rows (uint8, or float32 "
+                   "pixels), at most 256 wide, frozen")
+    k.add_argument("--radius", type=float, default=None,
+                   help="with --range-cap: stream the batches as range "
+                   "batches at this squared radius (whole-number query "
+                   "rows in [0, 255])")
     k.add_argument("--dispatch-depth", type=int, default=2,
                    help="max batches in flight (2 = double buffering)")
     k.add_argument("--no-donate", action="store_true",
@@ -378,7 +390,11 @@ def main(argv=None) -> int:
             query_bucket=args.bucket,
             dispatch_depth=args.dispatch_depth,
             donate=not args.no_donate,
+            range_cap=args.range_cap,
         )
+        if args.radius is not None and not args.range_cap:
+            raise ValueError("--radius wants --range-cap N: the most "
+                             "results one query row may be answered with")
     except ValueError as e:
         # invalid knob combination (e.g. mixed policy over a non-f32
         # dtype): loud usage error, never a silently-adjusted run
@@ -526,7 +542,8 @@ def _stream_and_report(args, session, index, X, source, build_s) -> int:
     t0 = time.perf_counter()
     n_batches = 0
     degraded_batches = 0
-    for res in session.stream(stream, tenant=args.tenant):
+    for res in session.stream(stream, tenant=args.tenant,
+                              radius=getattr(args, "radius", None)):
         n_batches += 1
         if res.degraded is not None:
             degraded_batches += 1
@@ -544,6 +561,11 @@ def _stream_and_report(args, session, index, X, source, build_s) -> int:
                 extra += " DEADLINE-BREACH"
             # res.seq IS the printed batch number: sentinel/degradation
             # provenance (batch seq=N) must point at this exact line
+            if res.range_out is not None:
+                lims, _, _, refused = res.range_answer
+                extra += f" results={int(lims[-1])}"
+                if refused:
+                    extra += f" REFUSED-OVER-CAP={len(refused)}"
             print(
                 f"batch {res.seq}: rows={res.rows} "
                 f"bucket={res.bucket} latency={res.latency_s * 1e3:.2f}ms"

@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpi_knn_tpu.config import KNNConfig
+from mpi_knn_tpu.config import KNNConfig, RangeCapError
 from mpi_knn_tpu.obs import host as obs_host
 from mpi_knn_tpu.obs import metrics as obs_metrics
 from mpi_knn_tpu.obs import spans as obs_spans
@@ -160,9 +160,13 @@ def expected_args(index, cfg: KNNConfig, bucket: int) -> list:
     with. The persistent AOT cache checks a loaded executable's
     ``args_info`` against this, so even a fingerprint collision cannot
     put a mismatched program on the dispatch path."""
+    return _signature(_batch_args(index, cfg, bucket)[2])
+
+
+def _signature(args) -> list:
     return [
         (tuple(int(s) for s in a.shape), str(jnp.dtype(a.dtype)))
-        for a in _batch_args(index, cfg, bucket)[2]
+        for a in args
         if a is not None
     ]
 
@@ -291,22 +295,41 @@ def get_executable(
     Thread-safe per cell: concurrent callers of the same cell serialize
     on a per-key lock (one compile), distinct cells build in parallel
     (the warm pool's whole point)."""
+    return _get_or_build(index, cfg, bucket, SERVE_KIND)
+
+
+SERVE_KIND = "serve"  # the k-NN batch programs: ``aotcache``'s default kind
+RANGE_KIND = "range"  # the range programs (``backends/range_scan.py``)
+
+
+def _get_or_build(index, cfg: KNNConfig, bucket: int, kind: str):
+    """The cell's executable from the index's cache, built under the
+    cell's lock where it is not there. The k-NN family keys its cells
+    ``(bucket, config)`` as ever, another family ``(kind, bucket,
+    config)``."""
     key = (bucket, _fingerprint_cfg(cfg))
+    if kind != SERVE_KIND:
+        key = (kind, *key)
     exec_ = index._cache.get(key)
     if exec_ is not None:
         return exec_
     with _key_lock(index, key):
         exec_ = index._cache.get(key)
         if exec_ is None:
-            exec_ = _build_executable(index, cfg, bucket)
+            exec_ = _build_executable(index, cfg, bucket, kind)
             index._cache[key] = exec_
     return exec_
 
 
 def _build_executable(
-    index: CorpusIndex, cfg: KNNConfig, bucket: int
+    index: CorpusIndex, cfg: KNNConfig, bucket: int, kind: str = SERVE_KIND
 ) -> _BucketExec:
     from mpi_knn_tpu.serve import aotcache
+
+    # a family is its lowering and the signature that lowering carries
+    lower, signature = (
+        (lower_range, range_expected_args) if kind == RANGE_KIND
+        else (lower_bucket, expected_args))
 
     # the central compile capture must be live BEFORE the compile it
     # is supposed to count (idempotent; jax is already imported here)
@@ -323,22 +346,23 @@ def _build_executable(
         if disk is not None:
             # the signature check rebuilds the cell's argspec from pure
             # shape math — a hit never lowers anything
-            fp = aotcache.fingerprint(index, cfg, bucket)
+            fp = aotcache.fingerprint(index, cfg, bucket, kind)
             compiled = disk.load(
-                fp, expect_args=expected_args(index, cfg, bucket)
+                fp, expect_args=signature(index, cfg, bucket)
             )
             cache_mode = "hit" if compiled is not None else "miss"
         if compiled is not None:
             q_pad, q_tile = bucket_shapes(index, cfg, bucket)
         else:
-            lowered, q_pad, q_tile = lower_bucket(index, cfg, bucket)
+            lowered, q_pad, q_tile = lower(index, cfg, bucket)
             compiled = lowered.compile()
             if disk is not None:
                 # best-effort (a full disk must not fail serving); meta
                 # carries the readable fingerprint for doctor/forensics
                 disk.store(
                     fp, compiled,
-                    meta=aotcache.fingerprint_facts(index, cfg, bucket),
+                    meta=aotcache.fingerprint_facts(
+                        index, cfg, bucket, kind),
                 )
         exec_ = _finish_executable(
             index, cfg, bucket, compiled, q_pad, q_tile,
@@ -590,6 +614,150 @@ def _run_gather(index, cfg: KNNConfig, queries, cand):
         tags.src if tags.src is not None else index.tiles)
 
 
+def _range_args(index, cfg: KNNConfig, bucket: int):
+    """``(q_pad, q_tile, arguments in call order)`` of a range cell: the
+    query rows, their ids and their bounds (no scratch: the answers are
+    no (rows, k) pair), then the layout's resident arrays."""
+    lay = index.layout
+    q_pad, q_tile = lay.bucket_shapes(index, cfg, bucket)
+    rows = lay.rows(q_pad, q_tile)
+    sds = jax.ShapeDtypeStruct
+    return q_pad, q_tile, (
+        sds(rows + (index.dim,), lay.query_dtype(cfg)),
+        sds(rows, jnp.int32), sds(rows, jnp.float32),
+        *lay.resident(index))
+
+
+@functools.lru_cache(maxsize=None)
+def _range_jit():
+    from mpi_knn_tpu.backends.range_scan import serve_chunk_range
+
+    return jax.jit(serve_chunk_range, static_argnames=("cfg",))
+
+
+def lower_range(index, cfg: KNNConfig, bucket: int):
+    """The range program of one (bucket, config) cell
+    (``backends/range_scan.py serve_chunk_range``) as a
+    ``jax.stages.Lowered``: ``(lowered, q_pad, q_tile)``."""
+    q_pad, q_tile, args = _range_args(index, cfg, bucket)
+    return _range_jit().lower(*args, cfg=cfg), q_pad, q_tile
+
+
+def require_range(index, cfg: KNNConfig) -> None:
+    """An index answers a request that names a radius only if it was
+    built to (``range_cap`` > 0: the build's checks — the dense serial
+    layout, no tags, whole-number rows, the width — are what make the
+    answer exact)."""
+    if not cfg.range_cap or not index.cfg.range_cap:
+        raise ValueError(
+            "this index answers k-NN alone: a request names a radius only "
+            "against an index built with range_cap > 0 (mpi-knn serve "
+            "--range-cap N; config.py _refuse_under_range lists what it "
+            "runs on)")
+
+
+def range_expected_args(index, cfg: KNNConfig, bucket: int) -> list:
+    """:func:`expected_args` of a range cell."""
+    return _signature(_range_args(index, cfg, bucket)[2])
+
+
+def get_range_executable(index, cfg: KNNConfig, bucket: int) -> _BucketExec:
+    """The range program of a (bucket, config) cell: a SECOND family of
+    executables beside :func:`get_executable`'s, built the same way
+    (:func:`_build_executable`), keyed apart in the index's cache
+    (``RANGE_KIND``) and on disk (``aotcache.fingerprint``'s ``kind``)."""
+    require_range(index, cfg)
+    return _get_or_build(index, cfg, bucket, RANGE_KIND)
+
+
+def _run_range(index, cfg: KNNConfig, exec_: _BucketExec, q, qids, under):
+    """Issue one prepared range batch: ``under`` (rows,) float32, the
+    rows' bounds (``backends/range_scan.py range_bound``), padded here
+    with a bound nothing is under. Returns the program's six device
+    outputs (async)."""
+    lay = index.layout
+    padded = np.full(exec_.q_pad, -1.0, np.float32)
+    padded[:under.shape[0]] = under
+    tiles = lay.rows(exec_.q_pad, exec_.q_tile)
+    with held(mutation_lock(index), "batch"):
+        return exec_.compiled(
+            q.reshape(*tiles, index.dim), qids.reshape(tiles),
+            jnp.asarray(padded.reshape(tiles)), *lay.resident(index))
+
+
+def query_range(queries, radius, index: CorpusIndex,
+                config: KNNConfig | None = None):
+    """One-shot range batch against a resident index built with
+    ``range_cap`` > 0: every live row at a squared L2 distance strictly
+    under ``radius`` (one number, or one a row), as ``(lims (rows + 1,),
+    dists, ids)`` — row i's results are ``dists[lims[i]:lims[i + 1]]``,
+    ascending, ties by the lower id. A row with more than ``range_cap``
+    results raises ``RangeCapError`` naming it: nothing is cut."""
+    from mpi_knn_tpu.backends.range_scan import range_bound, require_byte_rows
+
+    cfg = index.compatible_cfg(config or index.cfg)
+    require_range(index, cfg)
+    queries = np.asarray(queries, dtype=np.float32)
+    require_byte_rows(queries)
+    nq = queries.shape[0]
+    under = range_bound(np.broadcast_to(
+        np.asarray(radius, np.float32), (nq,)))
+    bucket = bucket_rows(nq, cfg.query_bucket)
+    exec_ = get_range_executable(index, cfg, bucket)
+    q2d, qids, rows = _prep_queries(index, cfg, exec_, queries)
+    res = BatchResult(None, None, rows, bucket, k=cfg.k,
+                      range_out=_run_range(
+                          index, cfg, exec_, q2d, qids, under),
+                      range_cap=cfg.range_cap)
+    lims, dists, ids, refused = res.range_answer
+    _count_range(obs_metrics.get_registry(), res)
+    if refused:
+        raise RangeCapError(refused, cfg.range_cap)
+    return lims, dists, ids
+
+
+RANGE_RESULT_BUCKETS = (0, 1, 10, 100, 1000, 8192, 65536)
+
+
+def _count_range(registry, res) -> None:
+    """A fetched range batch on ``/metrics``: rows, results, rows that
+    took the second path, rows refused over the cap, the tile steps by
+    who walked them, and the results a row."""
+    lims, _, _, refused = res.range_answer
+    counts = np.asarray(jax.device_get(res.range_out[5])).sum(axis=0)
+    registry.counter(
+        "knn_range_rows_total",
+        help="query rows answered by range search (padding excluded; "
+        "refused rows included)").inc(res.rows)
+    registry.counter(
+        "knn_range_results_total",
+        help="(query row, corpus row) pairs answered by range search"
+    ).inc(int(lims[-1]))
+    registry.counter(
+        "knn_range_overflow_rows_total",
+        help="range query rows whose results the lane lists could not "
+        "hold (or that had none), answered by the second, complete path "
+        "(scope knn.range_overflow)").inc(int(counts[2]))
+    registry.counter(
+        "knn_range_overflow_tiles_total",
+        help="corpus tiles the second path fetched for those rows"
+    ).inc(int(counts[4]))
+    registry.counter(
+        "knn_range_refused_rows_total",
+        help="range query rows with more results than range_cap: refused "
+        "by name, never cut").inc(len(refused))
+    for path, steps in (("range", counts[0]), ("range_counted", counts[1])):
+        registry.counter(
+            obs_metrics.DIST_STEPS,
+            help="distance tile steps by the path of their dot",
+            labels={"path": path}).inc(int(steps))
+    hist = registry.histogram(
+        "knn_range_results_per_row",
+        help="results of one range query row", buckets=RANGE_RESULT_BUCKETS)
+    for results in np.diff(lims).tolist():
+        hist.observe(results)
+
+
 def check_filters(index, filters, rows: int):
     """A batch's predicates as the engine takes them: (rows,
     max_query_tags) int32 tag ids, -1 for none — or None for an index
@@ -768,10 +936,19 @@ class BatchResult:
     # is the batch's), None where every row was answered on the host.
     parts: tuple | None = None
     k: int = 0  # the answers' width, for rows no dispatch answered
+    # a RANGE batch (``submit(..., radii=)``): the range program's device
+    # outputs (``backends/range_scan.py serve_chunk_range``); its answers
+    # are no (rows, k) pair but ``range_answer``. ``dists_padded`` /
+    # ``ids_padded`` then name the flat answers' first piece (what a
+    # retire waits for).
+    range_out: tuple | None = None
+    range_cap: int = 0
 
     @property
     def padded_rows(self) -> int:
         """Rows of the padded dispatches behind this batch."""
+        if self.range_out is not None:
+            return int(np.prod(self.range_out[0].shape))
         if self.parts is None:
             return self.dists_padded.shape[0]
         return sum(d.shape[0] for d, _, _ in self.parts)
@@ -805,13 +982,34 @@ class BatchResult:
         return dists, ids
 
     @functools.cached_property
+    def range_answer(self) -> tuple:
+        """``(lims (rows + 1,), dists, ids, refused)`` of a range batch:
+        row i's results are ``dists[lims[i]:lims[i + 1]]``, ascending,
+        ties by the lower id; ``refused`` is ``[(row, true count), ...]``
+        of the rows over the cap, which have none. One D2H of the counts
+        and the flat answers' first piece; the second is fetched only
+        where a query tile's results pass the first."""
+        from mpi_knn_tpu.backends.range_scan import assemble
+
+        n, head_d, head_i, rest_d, rest_i, _ = self.range_out
+        n, head_d, head_i = jax.device_get((n, head_d, head_i))
+        return assemble(
+            np.asarray(n), np.asarray(head_d), np.asarray(head_i),
+            lambda: tuple(map(np.asarray, jax.device_get((rest_d, rest_i)))),
+            self.rows, self.range_cap)
+
+    @functools.cached_property
     def dists(self) -> np.ndarray:
+        if self.range_out is not None:
+            return self.range_answer[1]
         if self.parts is not None:
             return self._assembled[0]
         return np.asarray(jax.device_get(self.dists_padded))[: self.rows]
 
     @functools.cached_property
     def ids(self) -> np.ndarray:
+        if self.range_out is not None:
+            return self.range_answer[2]
         if self.parts is not None:
             return self._assembled[1]
         return np.asarray(jax.device_get(self.ids_padded))[: self.rows]
@@ -1181,6 +1379,14 @@ class ServeSession:
             cells += [(GATHER_KIND, shape, cfg) for cfg in seen.values()
                       for shape in self.index.tags.gather_shapes()]
             raw = raw + cells[len(distinct):]
+        if self.cfg.range_cap:
+            # the range family, at the session's own configuration (a
+            # range batch knows no other rung): one cell a bucket, and its
+            # first dispatch, all padding
+            extra = [(RANGE_KIND, bucket, self.cfg) for bucket in sorted(
+                {bucket_rows(n, self.cfg.query_bucket) for n in sizes})]
+            cells += extra
+            raw = raw + extra
         total = len(cells)
         with self._warm_lock:
             self.warm_state = {"total": total, "ready": 0, "done": False}
@@ -1193,7 +1399,15 @@ class ServeSession:
             *kind, bucket, cfg = cell
             key = (*kind, bucket, _fingerprint_cfg(cfg))
             existed = key in self.index._cache
-            if kind:
+            if kind == [RANGE_KIND]:
+                exec_ = get_range_executable(self.index, cfg, bucket)
+                if not existed:
+                    device_sync(_run_range(
+                        self.index, cfg, exec_, *_prep_queries(
+                            self.index, cfg, exec_,
+                            np.zeros((1, self.index.dim), np.float32))[:2],
+                        np.zeros(0, np.float32))[0])
+            elif kind:
                 # its first dispatch too (all padding): a gather program
                 # is first met in the middle of a planned batch
                 device_sync(_run_gather(
@@ -1337,10 +1551,15 @@ class ServeSession:
         provenance an operator needs to find the batch."""
         with self.phase("d2h", seq=res.seq, parent=res.span):
             d = res.dists  # strips padding; cached: the one D2H of dists
-        bad_nan = bool(np.isnan(d).any())
+        # a range batch's reading: its distances are the flat results, every
+        # one under a finite radius, so NaN OR +inf among them is a poisoned
+        # tile; a row with NO result is an answer and trips nothing
+        ranged = res.range_out is not None
+        bad_nan = bool(np.isnan(d).any()) or (
+            ranged and bool(np.isinf(d).any()))
         # a tagged index answers a row that nothing matches with k empty
         # slots: all-inf is an answer there, NaN still is not
-        bad_inf = (res.parts is None and bool(d.size)
+        bad_inf = (res.parts is None and not ranged and bool(d.size)
                    and bool(np.isinf(d).all(axis=1).any()))
         if bad_inf and not bad_nan and res.exchange is not None \
                 and res.exchange[:, 1].sum() > 0:
@@ -1391,7 +1610,11 @@ class ServeSession:
         overruns names the phase with the largest excess over its own
         median; ``wait`` with a batch in flight behind it is told apart one
         retire later by that batch's wait (:meth:`_close_overrun`). It
-        changes nothing the session does: counters, an event, a log line."""
+        changes nothing the session does: counters, an event, a log line.
+        A RANGE batch is judged by both: the deadline as any batch (a
+        breach streak sheds a rung for the k-NN batches; range batches
+        keep the session's own configuration), the overrun rule against
+        the cycles of the range batches of its height alone."""
         now = obs_host.host_sample()
         pol = self.policy
         if pol is not None and pol.batch_deadline_s is not None:
@@ -1425,7 +1648,12 @@ class ServeSession:
             0.0, now.at - began.at - away - sum(phases.values()))
         if self._overrun_open is not None:
             self._close_overrun(phases.get("wait", 0.0))
-        over = self._overruns.judge(res.bucket, phases)
+        # a range batch's cycle is its own kind: its time goes with the
+        # results it found, and a k-NN batch of the same height is no
+        # measure of it
+        over = self._overruns.judge(
+            res.bucket if getattr(res, "range_out", None) is None
+            else (RANGE_KIND, res.bucket), phases)
         if over is None:
             return
         self._overrun_open = (over, {
@@ -1727,6 +1955,10 @@ class ServeSession:
         # the batch is synchronized: its counts are on hand, a few bytes
         # after the answers' own D2H, never a wait of their own
         _count_tiles(self._metrics, res)
+        if res.range_out is not None:
+            with self.phase("d2h", seq=res.seq, parent=sid):
+                res.range_answer  # noqa: B018 — fetched once, cached
+            _count_range(self._metrics, res)
         self._metrics.counter(
             "serve_batches_total", help="batches retired"
         ).inc()
@@ -1743,6 +1975,25 @@ class ServeSession:
             help="per-batch dispatch→device_sync latency",
         ).observe(res.latency_s)
         return res
+
+    def _dispatch_range(self, queries, radii, span=None):
+        """One dispatch attempt of a RANGE batch: always the session's
+        own configuration — the ladder's rungs trade recall or bucket
+        height for time, and a range answer is complete or refused —
+        through the range family of executables
+        (:func:`get_range_executable`)."""
+        from mpi_knn_tpu.backends.range_scan import range_bound
+
+        fault_point("serve-batch")
+        cfg = self.cfg
+        bucket = bucket_rows(queries.shape[0], cfg.query_bucket)
+        exec_ = get_range_executable(self.index, cfg, bucket)
+        with self.phase("prep", seq=self._seq, parent=span):
+            q2d, qids, rows = _prep_queries(self.index, cfg, exec_, queries)
+        with self.phase("enqueue", seq=self._seq, parent=span):
+            out = _run_range(self.index, cfg, exec_, q2d, qids,
+                             range_bound(radii))
+        return bucket, rows, out
 
     def _dispatch(self, queries, cfg: KNNConfig, span=None, filters=None):
         """One dispatch attempt under ``cfg`` (a ladder rung's config).
@@ -1778,7 +2029,7 @@ class ServeSession:
         return (bucket, rows, poison_topk(d), i, stats,
                 exec_.exchange_bytes, counts, None)
 
-    def submit(self, queries, tenants=None, filters=None
+    def submit(self, queries, tenants=None, filters=None, radii=None
                ) -> list[BatchResult]:
         """Dispatch one batch; ``tenants`` is an optional
         ``((tenant, rows), ...)`` composition in row order (a coalesced
@@ -1788,8 +2039,23 @@ class ServeSession:
         an index built with tags — (rows, tags) tag ids (``check_filters``;
         None there: no row has one), or the batch's ``serve.tags.Plan``
         where the caller planned already (the front end plans a request at
-        its admission and joins the plans) — refused for an index without."""
+        its admission and joins the plans) — refused for an index without.
+        ``radii``: (rows,) squared radii, one a query row — the batch is a
+        RANGE batch (every row one; an index built with ``range_cap``),
+        its result's answers are ``BatchResult.range_answer``."""
         t0 = time.perf_counter()
+        if radii is not None:
+            from mpi_knn_tpu.backends.range_scan import require_byte_rows
+
+            require_range(self.index, self.cfg)
+            radii = np.asarray(radii, dtype=np.float32).reshape(-1)
+            if radii.shape[0] != int(queries.shape[0]):
+                raise ValueError(
+                    f"{radii.shape[0]} radii for {int(queries.shape[0])} "
+                    "query rows: a range batch names one a row")
+            if filters is not None:
+                raise ValueError("range search takes no predicate yet")
+            require_byte_rows(queries)
         if not hasattr(filters, "regime"):  # not a Plan made at admission
             filters = check_filters(
                 self.index, filters, int(queries.shape[0]))
@@ -1838,16 +2104,29 @@ class ServeSession:
             rows=int(queries.shape[0]), rung=label, **span_attrs,
         )
         pol = self.policy
+        if radii is not None:
+            from mpi_knn_tpu.backends.serial import TileCounts
+
+            label = FULL_RUNG  # (a range batch knows no other rung)
+
+            def dispatch():
+                bucket, rows, out = self._dispatch_range(queries, radii, sid)
+                return (bucket, rows, poison_topk(out[1]), out[2], None,
+                        None, TileCounts(), None, out)
+        else:
+            def dispatch():
+                return (*self._dispatch(queries, cfg, sid, filters), None)
         try:
             if pol is not None and pol.max_retries > 0:
                 out = retry_with_backoff(
-                    lambda: self._dispatch(queries, cfg, sid, filters),
+                    dispatch,
                     retries=pol.max_retries,
                     base_s=pol.backoff_base_s,
                     max_s=pol.backoff_max_s,
                     retryable=pol.retryable,
                 )
-                bucket, rows, d, i, stats, xbytes, counts, parts = out.value
+                (bucket, rows, d, i, stats, xbytes, counts, parts,
+                 ranged) = out.value
                 retries, backoffs = out.attempts - 1, out.backoffs
                 with self._stats_lock:
                     self.retries_total += retries
@@ -1861,8 +2140,8 @@ class ServeSession:
                         help="transient dispatch failures retried",
                     ).inc(retries)
             else:
-                bucket, rows, d, i, stats, xbytes, counts, parts = (
-                    self._dispatch(queries, cfg, sid, filters))
+                (bucket, rows, d, i, stats, xbytes, counts, parts,
+                 ranged) = dispatch()
                 retries, backoffs = 0, ()
         except Exception as e:
             # a RAISED dispatch failure (retries exhausted, non-retryable
@@ -1882,6 +2161,9 @@ class ServeSession:
             span=sid,
             parts=parts,
             k=cfg.k,
+            range_out=None if ranged is None else (
+                ranged[0], d, *ranged[2:]),
+            range_cap=self.cfg.range_cap,
             **counts._asdict(),
         )
         self._seq += 1
@@ -1900,11 +2182,14 @@ class ServeSession:
             out.append(self._retire())
         return out
 
-    def stream(self, batches, tenant: str | None = None):
+    def stream(self, batches, tenant: str | None = None,
+               radius: float | None = None):
         """Serve an iterable of batches, yielding results in order.
         ``tenant`` tags every batch as one tenant's stream (single-tenant
         attribution — the ``mpi-knn query --tenant`` path); coalesced
-        multi-tenant batches use ``submit(..., tenants=...)`` directly."""
+        multi-tenant batches use ``submit(..., tenants=...)`` directly.
+        ``radius``: every batch is a RANGE batch at that squared radius
+        (``mpi-knn query --radius``)."""
         for q in batches:
             yield from self.submit(
                 q,
@@ -1912,6 +2197,8 @@ class ServeSession:
                     None if tenant is None
                     else ((tenant, int(q.shape[0])),)
                 ),
+                radii=None if radius is None else np.full(
+                    int(q.shape[0]), radius, np.float32),
             )
         yield from self.drain()
 

@@ -394,6 +394,7 @@ class CorpusIndex:
             "backend", "metric", "dtype", "corpus_tile", "query_tile",
             "center", "mesh_axis", "num_devices", "ring_transfer_dtype",
             "ring_schedule", "max_tile_elems", "exclude_zero", "zero_eps",
+            "range_cap",
         )
         built = self.cfg.replace(backend=self.backend)
         want = cfg if cfg.backend != "auto" else cfg.replace(
@@ -467,6 +468,7 @@ def build_index(
         return build_index_blocks(corpus.shape, (corpus,), cfg, mesh=mesh)
     m, dim = corpus.shape
     backend = resolve_backend(cfg, mesh)
+    refuse_range_build(cfg, dim, True, tags is not None, backend)
     if tags is not None:
         if backend != "serial":
             raise ValueError(
@@ -675,10 +677,51 @@ def _stamp_index_gauges(cfg: KNNConfig, onepass) -> None:
         ).set(float(cfg.metric == name))
 
 
+# the widest row whose sums stay whole numbers a float32 holds: every
+# term of a squared distance of whole numbers up to 255 apart is at most
+# 255^2, and RANGE_MAX_DIM of them stand under 2^24 (256 x 255^2 =
+# 16 646 400 < 16 777 216; 259 do not)
+RANGE_MAX_DIM = 256
+
+
+def refuse_range_build(cfg: KNNConfig, dim: int, onepass, tagged: bool,
+                       backend: str) -> None:
+    """What only a build can know of an index that is to answer range
+    search (``cfg.range_cap`` > 0; ``config.py _refuse_under_range`` holds
+    what the configuration alone says): each with its reason."""
+    if not cfg.range_cap:
+        return
+    if backend != "serial":
+        raise ValueError(
+            f"range search runs over the dense serial index; this build "
+            f"resolved to the {backend!r} layout (several devices under "
+            "backend='auto' make a ring) — build with backend='serial'")
+    if tagged:
+        raise ValueError(
+            "range search takes no predicate yet: the masked scan and the "
+            "gather regime of an index with tags answer a fixed k a row "
+            "(serve/tags.py) — build without tags, or with range_cap=0")
+    if dim > RANGE_MAX_DIM:
+        raise ValueError(
+            f"range search wants rows at most {RANGE_MAX_DIM} wide, got "
+            f"{dim}: beyond it a sum of a whole-number distance can pass "
+            "2^24, where float32 rounds, and a row AT the radius could be "
+            "answered as under it")
+    if onepass is None:
+        raise ValueError(
+            "range search needs whole-number rows (every centred element "
+            "a bf16 number: a byte corpus, pixels): over fractional "
+            "float32 rows the scan ranks in rounded passes (the screened "
+            "and six-pass forms) and a row near the radius needs the "
+            "exact finish, which has no range form yet — build with "
+            "range_cap=0")
+
+
 def _serial_index(cfg, m, dim, c_tile, mu, tiles, tile_ids, tile_sqs,
                   onepass, layout=None, rest_offset=None) -> CorpusIndex:
     """The dense serial index over a finished stack and its planes, with
     the gauge that says what a row costs at rest."""
+    refuse_range_build(cfg, dim, onepass, False, "serial")
     planes = sum(a.size * a.dtype.itemsize
                  for a in (tiles, tile_ids, tile_sqs) if a is not None)
     obs_metrics.get_registry().gauge(

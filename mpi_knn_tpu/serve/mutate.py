@@ -126,6 +126,15 @@ U8_FROZEN = (
 )
 
 
+RANGE_FROZEN = (
+    "an index that answers range search (range_cap > 0) is frozen: its "
+    "range programs rest on the build's checks (whole-number rows whose "
+    "sums stay under 2^24) and no reference holds lists of no fixed length "
+    "over a changing corpus — upsert, delete and compact are refused; "
+    "rebuild the index from the new rows, or build with range_cap=0"
+)
+
+
 def _metric(index) -> str | None:
     return getattr(getattr(index, "cfg", None), "metric", None)
 
@@ -134,10 +143,15 @@ def _rests_bytes(index) -> bool:
     return getattr(getattr(index, "cfg", None), "dtype", None) == "uint8"
 
 
+def _answers_range(index) -> bool:
+    return bool(getattr(getattr(index, "cfg", None), "range_cap", 0))
+
+
 def supports_mutation(index) -> bool:
     return (getattr(index, "backend", None) in MUTABLE_BACKENDS
             and getattr(index, "tags", None) is None
-            and _metric(index) != "ip" and not _rests_bytes(index))
+            and _metric(index) != "ip" and not _rests_bytes(index)
+            and not _answers_range(index))
 
 
 def _require_mutable(index) -> None:
@@ -147,6 +161,8 @@ def _require_mutable(index) -> None:
         raise ValueError(IP_FROZEN)
     if _rests_bytes(index):
         raise ValueError(U8_FROZEN)
+    if _answers_range(index):
+        raise ValueError(RANGE_FROZEN)
     if not supports_mutation(index):
         raise ValueError(
             f"the {getattr(index, 'backend', None)!r} layout cannot honor "

@@ -1002,3 +1002,67 @@ def test_block_ingest_program_holds_one_block_on_the_v5e(v5e_devices):
             arg((tiles, c, d), jnp.uint8), "l2",
             arg((d,), jnp.float32)).compile().memory_analysis()
     assert norms.temp_size_in_bytes < 0.1 * 2**30
+
+
+def test_range_program_compiles_for_the_v5e(v5e_devices, monkeypatch):
+    """``serve-ssnpp100m-range-bulk``'s 1024-row range program over 6104
+    byte tiles of 256 columns (``backends/range_scan.py``): the scan is the
+    fused kernel in its ranged form under ``knn.scan_range`` and takes the
+    uint8 stack where it rests; the second path fetches a tile by its
+    index and nothing copies, converts or re-lays the 12.8e9 B (a gather
+    of sixteen tiles at once did: the whole stack in eight pieces); the
+    program's temporaries are a batch's flat answers."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import range_scan
+    from mpi_knn_tpu.ops.fused_scan import (
+        _VMEM_HEADROOM,
+        fused_scan_vmem_bytes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    q, dim, tiles = 1024, 256, 6104
+    cfg = KNNConfig(k=10, backend="serial", dtype="uint8", query_tile=q,
+                    corpus_tile=8192, exclude_self=False, range_cap=8192)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    assert range_scan.range_engages(q, 8192, dim, 1)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            range_scan.serve_chunk_range, static_argnames=("cfg",)).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q), jnp.float32),
+            arg((tiles, 8192, dim), jnp.uint8), arg((tiles, 8192), jnp.int32),
+            arg((tiles, 8192), jnp.float32), arg((), jnp.bool_),
+            arg((dim,), jnp.float32), cfg=cfg).compile()
+    hlo = compiled.as_text()
+    stack = rf"u8\[{tiles},8192,{dim}\]"
+    defs = [ln for ln in hlo.splitlines()
+            if re.search(rf"= {stack}\{{", ln)
+            and not re.search(r"parameter\(|get-tuple-element\(", ln)]
+    assert not defs, defs[:3]
+    # no piece of the stack either: what the second path reads is a tile
+    pieces = [ln for ln in hlo.splitlines()
+              if re.search(rf"= u8\[{tiles},\d+,{dim}\]", ln)
+              and not re.search(r"parameter\(|get-tuple-element\(", ln)]
+    assert not pieces, pieces[:3]
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+               and range_scan.SCAN_SCOPE in ln]
+    assert len(kernels) == 1 and re.search(stack, kernels[0]), kernels
+    assert range_scan.OVERFLOW_SCOPE in hlo and range_scan.FINISH_SCOPE in hlo
+    limit = re.search(r'vmem_limit_bytes[\\"]*:\s*[\\"]*(\d+)', kernels[0])
+    want = fused_scan_vmem_bytes(q, 8192, dim, range_scan.RANGE_DEPTH,
+                                 itemsize=1, ranged=True)
+    assert want <= 64 << 20
+    if limit:
+        assert int(limit.group(1)) == want + _VMEM_HEADROOM
+    # two flat pieces of a batch's answers and the loop's copy of them
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30

@@ -764,6 +764,12 @@ _WHICH = {
     # the rule would answer
     "serve-bigann100m-u8-bulk-1024": (True, 5, 1024, True),
     "serve-bigann100m-u8-bulk-1024-nofact": (False, 5, None, True),
+    # the range cell's index answers k-NN too (a request without a
+    # radius): the byte cell's program at 256 columns; its RANGE program
+    # is another family (ISSUE 54: ``backends/range_scan.py``,
+    # ``tests/test_range.py``, ``tests/test_pallas.py -k range_program``)
+    "serve-ssnpp100m-range-bulk-1024": (True, 5, 1024, True),
+    "serve-ssnpp100m-range-bulk-1024-nofact": (False, 5, None, True),
 }
 
 
