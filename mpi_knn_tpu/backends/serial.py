@@ -219,7 +219,7 @@ def carried_depth(cfg: KNNConfig, q_rows: int, c_tile: int,
 
 
 def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
-               varying: bool = False) -> int | None:
+               varying: bool = False, filtered: bool = False) -> int | None:
     """Whether the one-pass branch of an engaged merge of (q_rows x
     c_tile) tile steps at width ``dim`` is ONE kernel over the whole stack
     (``ops/fused_scan.py``) — the height of the row blocks it walks the
@@ -232,14 +232,21 @@ def fused_rule(cfg: KNNConfig, q_rows: int, c_tile: int, dim: int,
     block is the stack): the same answer on the TPU, where the kernel is
     typed for the check (``ops/lane_bin.py _out``), and None elsewhere —
     :func:`carried_depth`'s condition: jax's Pallas interpreter cannot run
-    under the check, so the CPU ring keeps the per-tile program it has."""
-    if not onepass_rule(cfg, q_rows):
+    under the check, so the CPU ring keeps the per-tile program it has.
+    ``filtered`` (a predicate's words ride the scan, :func:`filter_words`):
+    the kernel takes them as one operand more and masks by them itself, at
+    every bucket height that carries the one-pass branch of a filtered
+    program and at which the bound rides (256, 512 and 1024 rows: 64 and
+    128 keep the scan of tile steps), over a float32 stack whose tiles'
+    words are whole lane-aligned vectors (``fused_scan_engages``)."""
+    if not onepass_rule(cfg, q_rows, filtered):
         return None
     depth = carried_depth(cfg, q_rows, c_tile, varying)
     if depth is None:
         return None
     return fused_scan_engages(
-        q_rows, c_tile, dim, depth, jnp.dtype(cfg.dtype).itemsize)
+        q_rows, c_tile, dim, depth, jnp.dtype(cfg.dtype).itemsize,
+        filtered=filtered)
 
 
 # --- the certified screen ---------------------------------------------------
@@ -870,7 +877,9 @@ def serve_chunk(
     ``filt`` (:func:`serve_chunk_filtered`, a tagged index's batches):
     ``(q_tags (QT, q_tile, W), tag_bits)``, a predicate a query row; its
     words ride the scan beside the stack (:func:`filter_words`) and every
-    tile step masks by them. None: the program as it always was.
+    tile step — or, where :func:`fused_rule` engages with them, the kernel
+    that walks the stack — masks by them. None: the program as it always
+    was.
 
     A byte stack (``tiles`` uint8, ``dtype="uint8"``): every tile step
     widens its tile to float32 and takes ``offset`` off
@@ -909,8 +918,9 @@ def serve_chunk(
     varying = bool(jax.typeof(q_tiles).vma | jax.typeof(tiles).vma)
     if took is not None:
         steps = dist_steps(
-            took, tiles.shape[0], fused=filt is None and bool(fused_rule(
-                cfg, q_tiles.shape[1], *tiles.shape[1:], varying)),
+            took, tiles.shape[0], fused=bool(fused_rule(
+                cfg, q_tiles.shape[1], *tiles.shape[1:], varying,
+                filtered=filt is not None)),
             u8=tiles.dtype == jnp.uint8)
     elif fused_screen_rule(cfg, q_tiles.shape[1], *tiles.shape[1:],
                            filtered=filt is not None, varying=varying):
@@ -1065,17 +1075,18 @@ def merge_tiles_into_carry(
             *operands,
         )
 
-    # a predicate's words ride the scan as a fourth plane of the stack, a
-    # tile's slice a step (:func:`filter_words`)
+    # a predicate's words ride the scan as a fourth plane of the stack
+    # (:func:`filter_words`): a tile's slice a step, or the kernel's operand
     stack = (tiles, tile_ids, tile_sqs) + (() if words is None else (words,))
     if cfg.merge_schedule == "twolevel":
         varying = bool(jax.typeof(q_x).vma | jax.typeof(tiles).vma)
         depth = carried_depth(cfg, carry_d.shape[0], tiles.shape[1], varying)
         if depth is not None:
             block = None
-            if onepass is not None and words is None:
+            if onepass is not None:
                 block = fused_rule(
-                    cfg, carry_d.shape[0], *tiles.shape[1:], varying)
+                    cfg, carry_d.shape[0], *tiles.shape[1:], varying,
+                    filtered=words is not None)
             facts = dict(branch=onepass is not None,
                          filtered=words is not None, varying=varying)
             screen = screen_rule(
@@ -1366,8 +1377,8 @@ def _finish_screened(q_x, q_ids, q_sq, stack, slots, s, cfg, fused=False):
     return vals, ids, certified
 
 
-def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
-                block, offset=None, screen=None):
+def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, words=None, *,
+                cfg, depth, block, offset=None, screen=None):
     """``ops/fused_scan.py fused_scan`` for ``cfg``: the engaged scan's
     one-pass branch over the whole stack as one kernel, the query tile in
     blocks of ``block`` rows, ``(lists_d, lists_i, chunks inserted)``,
@@ -1375,9 +1386,17 @@ def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
     scope the kernel then sits in (``knn.scan_u8/knn.fused``). ``screen``
     (k'): the screened scan in the kernel's three-pass form — k' where
     the one-pass form says k, slots for ids, and no zero test by value
-    (that waits for the finish's exact values, :func:`masked_dist_tile`)."""
+    (that waits for the finish's exact values, :func:`masked_dist_tile`).
+    ``words``: a predicate's (:func:`filter_words`), the stack's fourth
+    plane, which the kernel masks by as the scan's steps do."""
     from mpi_knn_tpu.ops.fused_scan import fused_scan
 
+    if words is not None:
+        # the kernel's operand is row-major int32; what that costs (the
+        # compiler rests the gathered words a query row a slab and copies
+        # them once a query tile) is the predicate's, and is named so
+        with jax.named_scope(FILTER_MASK_SCOPE):
+            words = jax.lax.bitcast_convert_type(words, jnp.int32)
     with contextlib.ExitStack() as scopes:
         if offset is not None:
             scopes.enter_context(jax.named_scope(U8_SCOPE))
@@ -1388,7 +1407,7 @@ def _fused_scan(q_x, q_ids, q_sq, tiles, tile_ids, tile_sqs, *, cfg, depth,
             exclude_self=cfg.exclude_self,
             exclude_zero=cfg.exclude_zero and screen is None,
             zero_eps=cfg.zero_eps, block=block, offset=offset,
-            screen=screen is not None)
+            screen=screen is not None, words=words)
 
 
 # rows a pass of the re-scan answers: one sublane tile of a float32 vreg

@@ -55,6 +55,15 @@ the MXU's accumulator takes the sum —, everything that says k says the
 screen's k', and the lists keep SLOTS. Its values are within
 ``backends/serial.py screen_eps(..., fused=True)`` of the six-pass ones;
 none of them is returned (``_finish_screened``).
+
+A fourth, by the operands (``backends/serial.py fused_rule`` with
+``filtered``): the MASKED scan of a tagged index. The predicate's words of
+a tile, a bit a (query row, slot), come in as a block beside the tile and
+a slot whose bit is clear reads +inf — in the exact re-test as one more
+mask, and in the test beside the dot too, so that a chunk is marked for a
+MATCHING value under the bound: the masked XLA scan's lists and count, bit
+for bit, with no plane of the mask, no slice and copy of the tile, no
+distance tile and no *bins* in a step.
 """
 
 from __future__ import annotations
@@ -103,7 +112,8 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
                        exclude_zero: bool, zero_eps: float, blocked: bool,
                        rows_minor: bool, widened: bool = False,
                        passes: int = 1, slots: bool = False,
-                       ranged: bool = False):
+                       ranged: bool = False, filtered: bool = False,
+                       sift: bool = True):
     """A grid step of :func:`fused_scan`: tile t against a block of query
     rows — the whole query tile on the grid (tiles,); one of its row
     blocks where ``blocked``, the grid's leading axis, the block's lists,
@@ -141,13 +151,27 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
     the exact test's answers are added up a row and a tile (``tc_ref`` (q,
     128), a tile a lane) and leave by a copy every 128 tiles and at the
     last (``tc_out`` (T / 128, q, 128)), so the caller knows of every row
-    how many values passed in each tile, whatever the lists kept."""
+    how many values passed in each tile, whatever the lists kept.
+
+    ``filtered`` (a tagged index's masked scan, ``backends/serial.py
+    filter_words``): one operand more, ``words_ref`` (1, q, c_tile / 32)
+    int32, the predicate's words of tile t for the block's rows — slot
+    ``c`` of the tile is bit ``c // (c_tile / 32)`` of word ``c % (c_tile
+    / 32)``, so the 128 columns of a column group are ONE bit of 128
+    lane-aligned words: a slice, an AND and a compare, no lane moves. A
+    slot whose bit is clear reads +inf: in (2) as one more of
+    ``mask_tile``'s masks and, where ``sift``, in (1) too — three vector
+    operations a vreg more beside the dot, and the chunks marked are those
+    that hold a MATCHING value under the bound, not any (under a sparse
+    predicate the bound is loose and most chunks hold one of the
+    others)."""
     lax, i32 = jax.lax, jnp.int32
     # a byte stack's kernel (``widened``) has one operand more, the (1, d)
     # offset its bytes are centred by; a float32 stack's has none
     refs = list(refs)
     mu_ref = refs.pop(0) if widened else None
     rad_ref = refs.pop(0) if ranged else None
+    words_ref = refs.pop(0) if filtered else None
     kd_out, ki_out, n_ref = refs[:3]
     del refs[:3]
     tc_out = refs.pop(0) if ranged else None
@@ -260,6 +284,15 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
 
         bits_ref[...] = lax.full(bits_ref.shape, 0, i32)
 
+    def misses(rows, group):
+        """Where the predicate's bit is clear, (rows, 128) bool: the rows'
+        slots of column group ``group`` of the tile."""
+        per = i32(words_ref.shape[2] // _LANES)
+        words = words_ref[0, rows, _lanes_of(lax.rem(group, per))]
+        bit = lax.shift_left(i32(1), lax.div(group, per))
+        return lax.eq(lax.bitwise_and(words, lax.broadcast(bit, words.shape)),
+                      lax.full(words.shape, 0, i32))
+
     def dot_and_test(j, carry):
         j = _as_i32(j)
         cols = pl.ds(pl.multiple_of(lax.mul(j, i32(piece)), piece), piece)
@@ -303,12 +336,15 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
         xs, bound = xsq_ref[...], b_ref[...]
         under = None
         for g in range(groups):
-            ys = ycol_ref[:, _lanes_of(lax.add(lax.mul(j, i32(groups)),
-                                               i32(g)))]
+            group = lax.add(lax.mul(j, i32(groups)), i32(g))
+            ys = ycol_ref[:, _lanes_of(group)]
             z = lax.add(
                 lax.add(xs, lax.slice(m, (0, g * _LANES),
                                       (q, (g + 1) * _LANES))),
                 lax.broadcast_in_dim(ys, xs.shape, (0, 1)))
+            if filtered and sift:
+                z = lax.select(misses(slice(None), group),
+                               lax.full(xs.shape, _INF, f32), z)
             le = lax.le(z, bound)  # NaN compares false
             under = le if under is None else lax.bitwise_or(under, le)
         bits_ref[...] = lax.bitwise_or(bits_ref[...], lax.select(
@@ -342,6 +378,9 @@ def _fused_scan_kernel(due_ref, qn_ref, xsq_ref, qid_ref, c_ref, ids_ref,
             own = lax.eq(lax.broadcast_in_dim(
                 idrow_ref[:, lanes], strip_shape, (0, 1)), qid_ref[r, :])
             invalid = own if invalid is None else lax.bitwise_or(invalid, own)
+        if filtered:
+            out = misses(r, g)
+            invalid = out if invalid is None else lax.bitwise_or(invalid, out)
         if invalid is not None:
             v = lax.select(invalid, lax.full(strip_shape, _INF, f32), v)
         d_ref[r, lanes] = v
@@ -481,7 +520,7 @@ def rests_rows_minor(d: int) -> bool:
 
 def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
                           itemsize: int = 4, passes: int = 1,
-                          ranged: bool = False) -> int:
+                          ranged: bool = False, filtered: bool = False) -> int:
     """The VMEM :func:`fused_scan` holds for a block of (q, d) query rows
     against (c_tile, d) corpus tiles of ``itemsize`` bytes an element
     (float32, or a byte stack's 1), in bytes: the lists, the
@@ -496,7 +535,8 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
     the float32 piece they are cut from and what the cut left; the query
     side is three widths too; the tile's slot numbers are one row more.
     ``ranged``: the rows' radii in their two buffers and their counts by
-    tile, three (q, 128) planes."""
+    tile, three (q, 128) planes. ``filtered``: a tile's words of the
+    predicate, (q, c_tile / 32), in their two buffers."""
     piece = chunk_groups(c_tile) * _LANES
     lists = 2 * q * depth * _LANES * 4
     words = _hit_words(q // _STRIP, c_tile // piece) * (_STRIP // 2)
@@ -514,6 +554,8 @@ def fused_scan_vmem_bytes(q: int, c_tile: int, d: int, depth: int,
     work = _row_block(q, _FINISH_ROWS) * _LANES * 4
     if ranged:
         work += (3 * q + _STRIP) * _LANES * 4
+    if filtered:
+        work += 2 * q * (c_tile // 32) * 4
     return lists + bound_and_bits + dists + stack + query + planes + work
 
 
@@ -522,7 +564,8 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                due, *, k: int, depth: int, exclude_self: bool,
                exclude_zero: bool, zero_eps: float, block: int,
                offset: jax.Array | None = None, screen: bool = False,
-               under: jax.Array | None = None):
+               under: jax.Array | None = None,
+               words: jax.Array | None = None, sift: bool = True):
     """The carried scan of ``backends/serial.py _merge_carried`` over a
     whole stack, in its one-pass branch and under the row bound, as one
     kernel: ``q_x`` (q, d) float32 query rows that are bf16 numbers (q a
@@ -575,7 +618,18 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
     128) int32, for every row the values at or under its bound by tile
     (tile ``t`` in plane ``t // 128``, lane ``t % 128``) — all of them,
     whatever the lists kept. The whole query tile a block, a stack on the
-    lane grid."""
+    lane grid.
+
+    ``words`` (a tagged index's masked scan): (T, q, c_tile / 32) int32,
+    for every tile the words of the query rows' predicate
+    (``backends/serial.py filter_words``' bits: a bit a (row, slot),
+    c_tile / 32 a multiple of 128). A tile's words are fetched beside it
+    (once a tile where the grid walks its pieces; a row block takes its
+    rows of them) and a slot whose bit is clear reads +inf, as the XLA
+    step's ``keep`` plane makes it: the masked scan's lists and count. A
+    float32 stack in the one-pass form. ``sift`` False leaves the bit out of the test beside
+    the dot (``scripts/bench_ops.py --scan-step --tags`` reads both: the
+    same lists either way)."""
     q, d = q_x.shape
     widened = tiles.dtype.itemsize == 1
     if widened != (offset is not None):
@@ -589,6 +643,13 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                          "block, one pass, a stack on the lane grid")
     passes = 3 if screen else 1
     n_tiles, c_tile, _ = tiles.shape
+    filtered = words is not None
+    if filtered and (widened or screen or ranged or c_tile % (32 * _LANES)
+                     or words.dtype != jnp.int32
+                     or words.shape != (n_tiles, q, c_tile // 32)):
+        raise ValueError("the filtered form takes a float32 stack in one "
+                         "pass and (T, q, c_tile / 32) int32 words, c_tile "
+                         "/ 32 a multiple of 128")
     groups = chunk_groups(c_tile)
     piece = groups * _LANES
     width = depth * _LANES
@@ -634,14 +695,16 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
         grid = (q // block, *grid)
     # (the ranged form's names are given only where it is asked for: a
     # k-NN program's text does not move)
-    extra = dict(ranged=True) if ranged else {}
+    extra = (dict(ranged=True) if ranged
+             else dict(filtered=True) if filtered else {})
     planes = -(-n_tiles // _LANES)
     kd, ki, n, *counts = pl.pallas_call(
         functools.partial(
             _fused_scan_kernel, k=k, depth=depth, groups=groups,
             exclude_self=exclude_self, exclude_zero=exclude_zero,
             zero_eps=zero_eps, blocked=blocked, rows_minor=rows_minor,
-            widened=widened, passes=passes, slots=screen, **extra),
+            widened=widened, passes=passes, slots=screen, **extra,
+            **(dict(sift=sift) if filtered else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -653,6 +716,9 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
                 *([pl.BlockSpec((1, d), index(lambda b, t, j: (0, 0)))]
                   if widened else []),
                 *([pl.BlockSpec((block, _LANES), rows)] if ranged else []),
+                *([pl.BlockSpec((1, block, c_tile // 32),
+                                index(lambda b, t, j: (t, b, 0)))]
+                  if filtered else []),
             ],
             out_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -701,5 +767,6 @@ def fused_scan(q_x: jax.Array, q_ids: jax.Array, q_sq: jax.Array,
       tile_ids.astype(jnp.int32), tile_sqs.astype(f32),
       *([offset.astype(f32)[None, :]] if widened else []),
       *([jnp.broadcast_to(under.astype(f32)[:, None], (q, _LANES))]
-        if ranged else []))
+        if ranged else []),
+      *([words] if filtered else []))
     return (kd, ki, n[0, 0], *counts)

@@ -212,7 +212,8 @@ _FUSED_VMEM_BYTES = 64 << 20
 
 
 def fused_scan_engages(q: int, c: int, d: int, depth: int,
-                       itemsize: int = 4, passes: int = 1) -> int | None:
+                       itemsize: int = 4, passes: int = 1,
+                       filtered: bool = False) -> int | None:
     """Whether the one-pass branch of a scan that carries the lane-bin
     lists over (q, c) tiles of float32 (or byte) rows ``d`` wide runs as ONE kernel
     over the whole stack (``ops/fused_scan.py``), by the shapes alone: the
@@ -270,11 +271,20 @@ def fused_scan_engages(q: int, c: int, d: int, depth: int,
       — with its own VMEM: the lists at the screen's depth (7: 7.3 MB),
       the query side and a piece's bf16 copy three widths wide (at 1024
       rows, 8192 columns, d = 128: 57.2 MB).
+    - *a predicate* (``filtered``: a tagged index's masked scan, whose
+      words are one operand more, ``backends/serial.py filter_words``): a
+      float32 stack in the one-pass form with c / 32 a multiple of 128 —
+      slot ``s`` of a tile is bit ``s // (c / 32)`` of word ``s % (c /
+      32)``, so a column group's bits are one bit of 128 whole lane-aligned
+      words only there (8192 columns: 256 words; 2048: 64, out) — and a
+      tile's words in their two buffers counted (1 MB at 512 rows).
 
     Where it says None, the scan of tile steps stays as it is."""
     if itemsize not in (1, 4) or d % 8 or (itemsize == 1 and d % _LANES):
         return None
     if passes != 1 and (passes != 3 or itemsize != 4 or d % _LANES):
+        return None
+    if filtered and (itemsize != 4 or passes != 1 or c % (32 * _LANES)):
         return None
     from mpi_knn_tpu.ops.fused_scan import fused_scan_vmem_bytes
 
@@ -282,8 +292,9 @@ def fused_scan_engages(q: int, c: int, d: int, depth: int,
     while block and block % 16 == 0:
         # (the distance tile the bound rides is float32 whatever rests)
         if (lane_bin_bound_rides(block, c)
-                and fused_scan_vmem_bytes(block, c, d, depth, itemsize,
-                                          passes) <= _FUSED_VMEM_BYTES):
+                and fused_scan_vmem_bytes(
+                    block, c, d, depth, itemsize, passes,
+                    filtered=filtered) <= _FUSED_VMEM_BYTES):
             return block
         block //= 2
     return None

@@ -86,7 +86,18 @@ Two families, one JSON artifact:
   more rows over the same law's FRACTIONAL rows at ``highest``: ``screen``
   (the XLA scan of three-pass tile steps) and ``fused_screen`` (the
   rule's program: the kernel's three-pass form where
-  ``fused_screen_rule`` engages), answers asserted equal.
+  ``fused_screen_rule`` engages), answers asserted equal. With ``--tags
+  <a filtered configuration's file>`` (``benchmark/configs/
+  yfcc10m-192-l2-filter.json``) the MASKED scan instead (PERF.md §6, PR
+  55), at that cell's own law: rows and bags of its generator
+  (``benchmark/datagen/clustered_u8_tags.py``, the file's ``data``), the
+  threshold ``serve/tags.py`` derives, and a query tile of the first
+  ``--q`` rows of the cell's pool that the plan sends to the scan regime,
+  with their words made inside the timed program (``filter_words``); rows
+  ``scan`` (the masked XLA steps), ``rule`` (the rule's program: the
+  kernel with the predicate inside) and ``rule_no_sift`` (the kernel with
+  the bit left out of the test beside its dot), lists and chunk counts
+  asserted equal, the tile's matched share of the slots beside them.
 
 CPU numbers say nothing absolute about the TPU — what they pin is the
 RELATIVE trajectory per op across PRs, on the platform CI always has
@@ -190,8 +201,119 @@ def _cold_start_child(spec: dict) -> int:
     return 0
 
 
+def _masked_scan_step(args) -> int:
+    """The ``--scan-step --tags`` rows (the module docstring has what they
+    are)."""
+    import types
+    import unittest.mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.datagen import clustered_u8_tags as gen
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.config import KNNConfig
+    from mpi_knn_tpu.ops import fused_scan as kernel
+    from mpi_knn_tpu.ops.distance import sq_norms
+    from mpi_knn_tpu.serve.tags import build_tag_index
+
+    q, c, d, tiles = args.q, args.c, args.d, args.tiles
+    config = json.loads(pathlib.Path(args.tags).read_text())
+    spec = config["data"]
+    cfg = KNNConfig(**{**config["knn"], "backend": "serial", "query_tile": q,
+                       "corpus_tile": c})
+    k, rows = cfg.k, tiles * c
+    which, indptr, indices, _ = gen.bags(rows, spec)
+    cen = jnp.asarray(gen.centres(0, spec, d))
+
+    @jax.jit
+    def make(key, which):
+        # ``gen.device_corpus``'s law (row i around centre ``which[i]``,
+        # rounded and clipped to a byte), centred by a whole number — bf16
+        # numbers — and made a tile at a time into the stack's own shape:
+        # no (rows, d) array beside it
+        def tile(args):
+            i, w = args
+            x = cen[w] + float(spec["sigma"]) * jax.random.normal(
+                jax.random.fold_in(key, i), (c, d), jnp.float32)
+            return jnp.clip(jnp.rint(x), 0.0, 255.0) - 128.0
+
+        return jax.lax.map(tile, (jnp.arange(tiles), which.reshape(tiles, c)))
+
+    stack = make(jax.random.key(0, impl="rbg"), jnp.asarray(which))
+    tags = build_tag_index(
+        types.SimpleNamespace(cfg=cfg, m=rows, tiles=stack, dim=d),
+        (indptr, indices), row_major_copy=False)
+    pool, filters = gen.query_pool(0, 8 * q, spec, d, which, indptr, indices)
+    plan = tags.plan(filters)
+    if len(plan.scan_rows) < q:
+        raise SystemExit(f"the pool's scan regime holds {len(plan.scan_rows)}"
+                         f" rows of {8 * q}: fewer than --q")
+    mine = plan.scan_rows[:q]
+    matched = gen.match_counts(indptr, indices, filters[mine]) / rows
+    ids = jnp.arange(rows, dtype=jnp.int32).reshape(tiles, c)
+    operands = (jnp.asarray(pool[mine] - 128.0), jnp.full(q, -1, jnp.int32),
+                stack, ids, serial._stack_norms(stack, "l2"), tags.tag_bits,
+                jnp.asarray(plan.scan_tags[:q]), jnp.asarray(True))
+
+    def program(module=None, **patched):
+        """The merge's program, lowered with ``module``'s names replaced
+        by ``patched`` (none: the rules' own program)."""
+        with (unittest.mock.patch.multiple(module, **patched) if module
+              else contextlib.nullcontext()):
+
+            @jax.jit
+            def run(q_x, q_ids, stack, ids, sqs, tag_bits, q_tags, onepass):
+                return serial.merge_tiles_into_carry(
+                    q_x, q_ids, sq_norms(q_x), stack, ids, sqs,
+                    *serial.init_topk(q, k), cfg, onepass,
+                    serial.filter_words(tag_bits, q_tags))
+
+            return run.lower(*operands).compile()
+
+    whole = kernel.fused_scan
+    variants = {
+        "scan": program(serial, fused_rule=lambda *a, **kw: None),
+        "rule": program(),
+        "rule_no_sift": program(
+            kernel, fused_scan=lambda *a, **kw: whole(*a, **kw, sift=False)),
+    }
+    block = serial.fused_rule(cfg, q, c, d, filtered=True)
+    results, outs = [], {}
+    for name, run in variants.items():
+        times = _time(lambda: run(*operands)[0], args.reps)
+        outs[name] = out = jax.tree.map(np.asarray, run(*operands))
+        med = statistics.median(times)
+        results.append({
+            "op": "masked_scan_step", "variant": name, "q": q, "c": c,
+            "d": d, "tiles": tiles, "block": None if name == "scan" else block,
+            "median_s": round(med, 6), "min_s": round(min(times), 6),
+            "us_per_step": round(med / tiles * 1e6, 2),
+            "rescanned": bool(out[2]), "chunks": out[3].tolist(),
+        })
+        print(json.dumps(results[-1]), flush=True)
+    for name in ("rule", "rule_no_sift"):
+        for what, a, b in zip(("vals", "ids", "rescanned", "chunks"),
+                              outs["scan"], outs[name]):
+            np.testing.assert_array_equal(a, b, err_msg=f"{name}: {what}")
+    doc = {"device": str(jax.devices()[0].device_kind),
+           "platform": jax.default_backend(), "equal": True,
+           "threshold": tags.threshold, "bitsets": tags.n_bitsets,
+           "matched_share": {"min": float(matched.min()),
+                             "median": float(np.median(matched)),
+                             "max": float(matched.max())},
+           "results": results}
+    out = pathlib.Path(args.out)
+    out.parent.mkdir(parents=True, exist_ok=True)
+    out.write_text(json.dumps(doc, indent=1) + "\n")
+    return 0
+
+
 def _scan_step(args) -> int:
     """The ``--scan-step`` rows (the module docstring has what they are)."""
+    if args.tags:
+        return _masked_scan_step(args)
     import unittest.mock
 
     import jax
@@ -331,6 +453,9 @@ def main(argv=None) -> int:
                     "jax has (the chip)")
     ap.add_argument("--tiles", type=int, default=192,
                     help="corpus tiles of the --scan-step stack")
+    ap.add_argument("--tags", default=None, metavar="CONFIG.json",
+                    help="with --scan-step: the masked scan at the law of "
+                    "this filtered configuration's data")
     args = ap.parse_args(argv)
 
     if args.scan_step:

@@ -95,8 +95,14 @@ def oracle(X, member, Q, filters, exclude_zero=True):
     return d, np.where(np.isinf(d), -1, order)
 
 
+# the narrowest corpus tile whose words of a predicate are whole vectors
+# (128 a row): from it up a filtered bucket of 256 rows or more takes the
+# kernel that walks the stack, the words its operand (ISSUE 55)
+KERNEL_TILE = 4096
+
+
 @functools.lru_cache(maxsize=None)
-def world(dim: int):
+def world(dim: int, tile: int = TILE):
     rng = np.random.default_rng(dim)
     member, csr = make_bags(dim)
     cen = rng.random((16, dim)) * 140
@@ -104,17 +110,26 @@ def world(dim: int):
                         + rng.standard_normal((ROWS, dim)) * 30), 0, 255
                 ).astype(np.float32)
     cfg = KNNConfig(k=K, backend="serial", query_tile=1024,
-                    corpus_tile=TILE, query_bucket=64, exclude_self=False,
+                    corpus_tile=tile, query_bucket=64, exclude_self=False,
                     exclude_zero=True, max_query_tags=2)
     index = build_index(X, cfg, tags=csr)
     return X, member, csr, index, cases(member, rng)
+
+
+def tile_of(bucket: int) -> int:
+    """The corpus tile of the index a ``bucket``-row batch is answered
+    from: the 512-row batches meet two tiles of ``KERNEL_TILE``, where
+    their scan part (three cases in ten: a 256-row dispatch) takes the
+    kernel; the others the eight tiles of ``TILE``, the masked scan of
+    tile steps at every height."""
+    return KERNEL_TILE if bucket == 512 else TILE
 
 
 @functools.lru_cache(maxsize=None)
 def answered(dim: int, bucket: int):
     """A batch of ``bucket`` rows that cycles through every case, through
     ``query_knn``; (filters, the system's answer, the oracle's)."""
-    X, member, _, index, by_case = world(dim)
+    X, member, _, index, by_case = world(dim, tile_of(bucket))
     rng = np.random.default_rng([dim, bucket])
     which = np.arange(bucket) % len(CASES)
     filters = np.array([by_case[CASES[c]] for c in which], dtype=np.int32)
@@ -137,12 +152,18 @@ def holds(member, ids, filters):
     return ok | (ids < 0)
 
 
-@pytest.mark.parametrize("bucket", [64, 1024])
+@pytest.mark.parametrize("bucket", [64, 512, 1024])
 @pytest.mark.parametrize("dim", [100, 192, 128])
 @pytest.mark.parametrize("case", CASES)
 def test_filtered_answers_match_the_oracle(case, dim, bucket):
-    _, member, _, index, _ = world(dim)
+    _, member, _, index, _ = world(dim, tile_of(bucket))
     which, filters, res, (ref_d, ref_i) = answered(dim, bucket)
+    # which program the scan part ran: the kernel's steps count apart
+    # (d = 100 is off the sublane grid: the scan of tile steps, as ever)
+    fused = bucket == 512 and dim != 100
+    assert np.asarray(res.dist_steps).tolist() == (
+        [0, 0, 0, 2] if fused else [2, 0] if bucket == 512
+        else [8, 0] if bucket == 1024 else [0, 8])
     rows = np.flatnonzero(which == CASES.index(case))
     assert rows.size
     d, i = res.dists[rows], res.ids[rows]

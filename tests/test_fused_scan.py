@@ -9,7 +9,9 @@ shape rule would keep a width or a height out, the tests force the kernel
 in (and the bound onto the scan it is compared with). Since ISSUE 51 the
 kernel has a THREE-PASS form for fractional float32 rows (the screened
 scan's: k' for k, slots for ids), whose lists are held against the XLA
-screened scan's and against float64."""
+screened scan's and against float64. Since ISSUE 55 a FILTERED form: a
+tagged index's words an operand, the lists and the count held against
+the masked XLA scan's and against float64."""
 
 import jax
 import jax.numpy as jnp
@@ -258,6 +260,155 @@ def test_a_call_counts_its_fused_steps_and_matches_the_ring(d, q_tile):
         # no bound rides the multi-pass scan of a tile that tall: every
         # chunk is inserted
         assert np.asarray(frac.bins_chunks).tolist() == [4 * 256, 0]
+
+
+# ---------------------------------------------------------------------------
+# the filtered form (ISSUE 55): a predicate's words an operand of the kernel
+
+F_TILE = 4096  # the narrowest tile whose words are whole vectors: 128 a row
+FEW = 5  # slots a sparse tag lies on: fewer than k
+
+
+def _tagged(q, tiles, tile_ids, seed=0):
+    """Bitsets over a (tiles, F_TILE) stack as a tagged index keeps them
+    (``serve/tags.py``: (F + 1, T, c_tile / 32) uint32, the last row all
+    ones) and a query tile's bitset rows (q, 2) that meet every kind of
+    predicate: no constraint (row 0, whose eight near neighbours share a
+    lane: flagged by the certificate), a tag on ``FEW`` slots (rows 1 and
+    9, the second a corpus row: fewer than k match, empty slots in the
+    answer), a tag on no slot (row 2), two tags with no slot in common
+    (row 3), one and two tags of every density elsewhere. The sparse
+    tag's slots and the dense tags' hold tombstoned and padding ids."""
+    rng = np.random.default_rng([seed, q, tiles])
+    slots = tiles * F_TILE
+    share = (0.5, 0.2, 0.05, 0.01)
+    member = rng.random((len(share), slots)) < np.array(share)[:, None]
+    dead = np.flatnonzero(np.asarray(tile_ids).reshape(-1) < 0)
+    member[0, dead] = True  # a set bit over a tombstone, over the padding
+    sparse = np.zeros(slots, dtype=bool)
+    sparse[np.r_[rng.choice(slots, FEW, replace=False), dead[:2]]] = True
+    apart = member[1] & ~member[0]
+    planes = np.stack([*member, sparse, np.zeros(slots, bool), apart,
+                       np.ones(slots, bool)])
+    n = len(planes) - 1  # the row of ones: no constraint
+    # slot c of a tile is bit c // W of word c % W
+    w = F_TILE // 32
+    bits = planes.reshape(len(planes), tiles, 32, w).astype(np.uint32)
+    tag_bits = (bits << np.arange(32, dtype=np.uint32)[:, None]).sum(
+        axis=2, dtype=np.uint32)
+    q_tags = np.stack([rng.integers(0, 4, q), np.where(
+        rng.random(q) < 0.5, n, rng.integers(0, 4, q))], axis=1)
+    q_tags[0] = n, n
+    q_tags[1] = q_tags[9] = 4, n
+    q_tags[2] = 5, n
+    q_tags[3] = 0, 6
+    keep = planes[q_tags[:, 0]] & planes[q_tags[:, 1]]
+    return (jnp.asarray(tag_bits), jnp.asarray(q_tags.astype(np.int32)),
+            keep.reshape(q, tiles, F_TILE))
+
+
+def _merge_filtered(monkeypatch, block, cfg, q_x, q_ids, tiles, tile_ids,
+                    tag_bits, q_tags, sift=True):
+    """:func:`_merge` with a predicate's words, into an empty carry: the
+    filtered kernel over row blocks of ``block`` rows or (None) the masked
+    scan of tile steps; ``sift`` False: the kernel with the bit left out
+    of the test beside the dot."""
+    from mpi_knn_tpu.ops import fused_scan as kernel
+
+    monkeypatch.setattr(serial, "fused_rule", lambda *a, **k: block)
+    monkeypatch.setattr(serial, "lane_bin_bound_rides", lambda *a: True)
+    if not sift:
+        whole = kernel.fused_scan
+        monkeypatch.setattr(kernel, "fused_scan",
+                            lambda *a, **kw: whole(*a, **kw, sift=False))
+
+    @jax.jit
+    def run(q_x, q_ids, tiles, tile_ids, tag_bits, q_tags):
+        return serial.merge_tiles_into_carry(
+            q_x, q_ids, sq_norms(q_x), tiles, tile_ids,
+            serial.stack_norms(tiles, "l2"),
+            *serial.init_topk(q_x.shape[0], cfg.k), cfg, jnp.asarray(True),
+            serial.filter_words(tag_bits, q_tags))
+
+    return jax.tree.map(
+        np.asarray, run(q_x, q_ids, tiles, tile_ids, tag_bits, q_tags)[:4])
+
+
+@pytest.mark.parametrize("q,d,tiles,blocks,sift", [
+    # the filtered cell's buckets at its width (rows-minor: a tile's words
+    # fetched once for its pieces) and on the lane grid (row-major)
+    *((q, d, 3, 1, True) for q in (256, 512, 1024) for d in (192, 128)),
+    # a taller query tile in row blocks: a block takes its rows of the words
+    (4096, 128, 1, 4, True), (64, 192, 3, 4, True),
+    # the bit left out of the test beside the dot: more chunks marked,
+    # the same lists and the same count after the exact test
+    (256, 192, 3, 1, False), (64, 128, 3, 2, False),
+])
+def test_filtered_fused_scan_returns_what_the_masked_scan_returns(
+        monkeypatch, q, d, tiles, blocks, sift):
+    cfg = KNNConfig(k=K, query_tile=q, corpus_tile=F_TILE,
+                    exclude_self=True, exclude_zero=True)
+    q_x, q_ids, stack, tile_ids, _, _ = _case(q, d, tiles, F_TILE)
+    tag_bits, q_tags, keep = _tagged(q, tiles, tile_ids)
+    case = (q_x, q_ids, stack, tile_ids, tag_bits, q_tags)
+    np.testing.assert_array_equal(  # the packing the kernel reads
+        np.asarray(serial.filter_keep(
+            serial.filter_words(tag_bits, q_tags)[-1], F_TILE)), keep[:, -1])
+    scan = _merge_filtered(monkeypatch, None, cfg, *case)
+    fused = _merge_filtered(monkeypatch, q // blocks, cfg, *case, sift=sift)
+    for name, a, b in zip(("vals", "ids", "rescanned", "chunks"), scan, fused):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    vals, ids, rescanned, chunks = fused
+    # float64, straight from the planes (whole-number rows: exact)
+    X = np.asarray(stack, np.float64).reshape(-1, d)
+    Q = np.asarray(q_x, np.float64)
+    d2 = (Q * Q).sum(1)[:, None] - 2.0 * Q @ X.T + (X * X).sum(1)[None]
+    all_ids = np.asarray(tile_ids).reshape(-1)
+    ok = (keep.reshape(q, -1) & (all_ids >= 0)[None] & (d2 > 0)
+          & (all_ids[None] != np.asarray(q_ids)[:, None]))
+    want = np.sort(np.where(ok, d2, np.inf), axis=1)[:, :K]
+    np.testing.assert_array_equal(vals, want.astype(np.float32))
+    assert ((ids < 0) == np.isinf(vals)).all()
+    hit = ids >= 0
+    assert ok[np.nonzero(hit)[0], ids[hit]].all()  # an id is its slot here
+    assert rescanned  # rows short of k candidates, row 0's shared lane
+    assert np.isfinite(vals[0]).all()  # no constraint
+    matched = ok[[1, 9]].sum(axis=1)
+    assert (matched < K).all() and (matched > 0).all()
+    assert (np.isfinite(vals[[1, 9]]).sum(axis=1) == matched).all()
+    assert np.isinf(vals[[2, 3]]).all()  # matched by nothing
+    assert chunks.sum() == tiles * (q // 16) * (F_TILE // 1024)
+    assert chunks[0] > 0
+
+
+@pytest.mark.parametrize("change,q,c,d,block,why", [
+    ({}, 256, 8192, 192, 256, "from the height the bound rides at"),
+    ({}, 512, 8192, 192, 512, "the filtered cell's scan part of a batch"),
+    ({}, 1024, 8192, 192, 1024, "a whole bucket in the scan regime"),
+    ({}, 512, 8192, 128, 512, "on the lane grid too"),
+    ({}, 4096, 8192, 128, 1024, "a taller tile in row blocks"),
+    ({}, 64, 8192, 192, None, "no one-pass branch under 256 rows"),
+    ({}, 128, 8192, 192, None, "no one-pass branch under 256 rows"),
+    ({"dtype": "uint8"}, 512, 8192, 128, None, "a byte stack has no words"),
+    ({}, 512, 2048, 192, None, "64 words a row: no whole vector"),
+    ({}, 512, 4096, 192, 512, "128 words a row: one"),
+    ({}, 512, 8192, 100, None, "the sublane grid, as without a predicate"),
+    ({"matmul_precision": "default"}, 512, 8192, 192, None,
+     "one pass already: no branch to take"),
+    ({"precision_policy": "mixed"}, 512, 8192, 192, None, "no lists"),
+])
+def test_which_filtered_programs_take_the_fused_scan(change, q, c, d, block,
+                                                     why):
+    """``fused_rule(..., filtered=True)``: by the program and its operands,
+    and the unfiltered answers as they were (512 rows: no branch)."""
+    cfg = KNNConfig(**{**dict(k=K, query_tile=q, corpus_tile=c), **change})
+    assert serial.fused_rule(cfg, q, c, d, filtered=True) == block, why
+    if q < serial.ONEPASS_MIN_ROWS:
+        assert serial.fused_rule(cfg, q, c, d) is None
+    # the three-pass form stays unfiltered
+    assert serial.screen_rule(cfg, 1024, c, 128, filtered=True) is None
+    depth = lane_bin_depth(min(q, 1024), c, K)
+    assert fused_scan_engages(q, c, d, depth, passes=3, filtered=True) is None
 
 
 # ---------------------------------------------------------------------------

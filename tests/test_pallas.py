@@ -969,6 +969,84 @@ def test_byte_stack_batch_program_compiles_for_the_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 2**30
 
 
+@pytest.mark.parametrize("q", [256, 512, 1024])
+def test_filtered_batch_program_compiles_for_the_v5e(v5e_devices,
+                                                     monkeypatch, q):
+    """``serve-yfcc10m-filter-bulk``'s scan dispatches (ISSUE 55: 512 rows
+    is the bucket its batches' scan parts take) over the tagged index's
+    768 tiles of d = 192: the one-pass branch is the kernel that walks the
+    stack with the predicate's words an operand — the rows-minor stack a
+    bitcast of the parameter, no slice or copy of a tile, no (q, 8192)
+    distance tile and no (q / 8, 8, 32, 256) plane of the words in it; the
+    words reach it by ONE copy a query tile (the compiler rests the
+    gathered words a query row a slab and the kernel's operand is
+    row-major), named for the predicate; the other branch keeps the masked
+    scan of tile steps; the kernel's VMEM is what ``fused_scan_vmem_bytes``
+    says with the words' two buffers."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from mpi_knn_tpu import KNNConfig
+    from mpi_knn_tpu.backends import serial
+    from mpi_knn_tpu.ops.fused_scan import (
+        _VMEM_HEADROOM,
+        fused_scan_vmem_bytes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e_devices[0])
+    tiles, dim, bitsets = 768, 192, 283
+    cfg = KNNConfig(k=10, backend="serial", query_tile=1024,
+                    corpus_tile=8192, exclude_self=False, max_query_tags=2)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    assert serial.fused_rule(cfg, q, 8192, dim, filtered=True) == q
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            serial.serve_chunk_filtered, static_argnames=("cfg",)).lower(
+            arg((1, q, dim), jnp.float32), arg((1, q), jnp.int32),
+            arg((1, q, 10), jnp.float32), arg((1, q, 10), jnp.int32),
+            arg((1, q, 2), jnp.int32),
+            arg((tiles, 8192, dim), jnp.float32),
+            arg((tiles, 8192), jnp.int32), arg((tiles, 8192), jnp.float32),
+            arg((), jnp.bool_), arg((bitsets + 1, tiles, 256), jnp.uint32),
+            cfg=cfg).compile()
+    hlo = compiled.as_text()
+    _assert_one_kernel_walks_the_stack(
+        hlo, q, tiles, dim,
+        under=r"jit\(serve_chunk_filtered\)/while/body/closed_call")
+    call, = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "knn.fused" in ln]
+    blocks = re.split(r"\n(?=(?:ENTRY )?%\S+ \([^\n]*\) -> [^\n]* \{\n)",
+                      hlo)
+    branch, = [b for b in blocks if call in b]
+    words = rf"s32\[{tiles},{q},256\]"
+    # the last of the kernel's operands, row-major
+    assert f"s32[{tiles},{q},256]{{2,1,0}}}}, " in call, call[:900]
+    made = [ln for ln in branch.splitlines() if re.search(rf"= {words}", ln)]
+    assert len(made) == 1 and "knn.filter_mask" in made[0], made
+    assert f"u32[{q // 8},8,32,256]" not in branch
+    # the masked scan of tile steps is the other branch, plane and all
+    assert re.search(
+        rf"= u32\[{q // 8},8,32,256\]\S* copy\(", hlo)
+    assert "cond/branch_0_fun/while/body" in hlo and "knn.filter_mask" in hlo
+    limit = re.search(r'vmem_limit_bytes[\\"]*:\s*[\\"]*(\d+)', call)
+    want = fused_scan_vmem_bytes(q, 8192, dim, 5, filtered=True)
+    assert want - fused_scan_vmem_bytes(q, 8192, dim, 5) == 2 * q * 256 * 4
+    if limit:
+        assert int(limit.group(1)) == want + _VMEM_HEADROOM
+    # the words twice (gathered, and in the kernel's order): 2 x 0.75 GiB
+    # at 1024 rows
+    words_gib = tiles * q * 256 * 4 / 2**30
+    assert compiled.memory_analysis().temp_size_in_bytes / 2**30 <= (
+        2 * words_gib + 0.05)
+
+
 def test_block_ingest_program_holds_one_block_on_the_v5e(v5e_devices):
     """A block-fed build's peak is the stack, its planes and ONE block's
     temporaries (``serve/index.py build_index_blocks``): in the block

@@ -746,13 +746,15 @@ _WHICH = {
     # the kernel (ISSUE 51): the cell's own program
     "stream-msturing10m-runbook-1024-nofact": (
         False, 5, 1024, True, 32, "fused_screen"),
-    # a predicate: the one-pass branch from 256 rows, never the fused scan
+    # a predicate: the one-pass branch from 256 rows, and from there the
+    # fused scan with the words its operand (ISSUE 55); 64 and 128 rows
+    # keep the masked scan of tile steps
     **{f"serve-yfcc10m-filter-bulk-{b}-nofact": v for b, v in _SMALL.items()},
     "serve-yfcc10m-filter-bulk-64": (False, 4, None, False),
     "serve-yfcc10m-filter-bulk-128": (False, 4, None, False),
-    "serve-yfcc10m-filter-bulk-256": (True, 5, None, True),
-    "serve-yfcc10m-filter-bulk-512": (True, 5, None, True),
-    "serve-yfcc10m-filter-bulk-1024": (True, 5, None, True),
+    "serve-yfcc10m-filter-bulk-256": (True, 5, 256, True),
+    "serve-yfcc10m-filter-bulk-512": (True, 5, 512, True),
+    "serve-yfcc10m-filter-bulk-1024": (True, 5, 1024, True),
     "serve-yfcc10m-filter-bulk-1024-nofact": (False, 5, None, True),
     "serve-bigann10m-ivf-bulk-1024": "bucket-major, one 1024-row tile",
     # an inner product: never the one-pass rule (L2's), the carried lists
@@ -817,8 +819,8 @@ def test_which_program_a_cell_runs(request, monkeypatch, cell, rows, fact,
         q_tile, c_tile = serial.effective_tiles(cfg, config["rows"], rows)
     onepass = fact and serial.onepass_rule(cfg, q_tile, filtered)
     depth = serial.carried_depth(cfg, q_tile, c_tile, ring)
-    block = (serial.fused_rule(cfg, q_tile, c_tile, dim, ring)
-             if onepass and not filtered else None)
+    block = (serial.fused_rule(cfg, q_tile, c_tile, dim, ring,
+                               filtered=filtered) if onepass else None)
     rides = depth is not None and lane_bin_bound_rides(q_tile, c_tile)
     screen = None if depth is None else serial.screen_rule(
         cfg, q_tile, c_tile, dim, branch=bool(onepass), filtered=filtered,
